@@ -56,7 +56,6 @@ _COLLECTIVE_QNS = {
 _SHARD_MAP_QNS = {
     "jax.shard_map",
     "jax.experimental.shard_map.shard_map",
-    "kubeflow_tpu.compat.shard_map",
 }
 
 

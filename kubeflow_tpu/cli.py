@@ -64,7 +64,7 @@ def _cluster_of(args):
         return None
     from kubeflow_tpu.runtime.topology import detect_local_cluster
 
-    return detect_local_cluster(num_chips=args.chips)
+    return detect_local_cluster(num_chips=args.chips, platform=args.platform)
 
 
 def cmd_server(args) -> int:
@@ -364,7 +364,8 @@ def build_parser() -> argparse.ArgumentParser:
     sp = sub.add_parser("server", help="run the platform in the foreground")
     sp.add_argument("--port", type=int, default=8134)
     sp.add_argument("--base-dir", default=None)
-    sp.add_argument("--platform", default="cpu")
+    sp.add_argument("--platform", default="cpu",
+                    help="where workers run: cpu (virtual devices) or tpu")
     sp.add_argument("--chips", type=int, default=None,
                     help="cluster size override (default: detect)")
     sp.set_defaults(fn=cmd_server)
@@ -463,7 +464,8 @@ def build_parser() -> argparse.ArgumentParser:
     sp.add_argument("-f", "--file", required=True)
     sp.add_argument("--timeout", type=float, default=600.0)
     sp.add_argument("--base-dir", default=None)
-    sp.add_argument("--platform", default="cpu")
+    sp.add_argument("--platform", default="cpu",
+                    help="where workers run: cpu (virtual devices) or tpu")
     sp.add_argument("--chips", type=int, default=None,
                     help="cluster size override (default: detect)")
     sp.set_defaults(fn=cmd_run)

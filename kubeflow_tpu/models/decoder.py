@@ -496,7 +496,8 @@ def decoder_loss(
         from kubeflow_tpu.ops import fused_xent
 
         fused = fused_xent.supported(
-            inputs.shape[0] * inputs.shape[1], cfg.hidden, cfg.vocab_size)
+            inputs.shape[0] * inputs.shape[1], cfg.hidden, cfg.vocab_size,
+            dtype=cfg.activation_dtype)
     if fused:
         hidden, _, aux = decoder_forward(
             params, inputs, cfg, attn_impl=attn_impl, mesh=mesh, rules=rules,

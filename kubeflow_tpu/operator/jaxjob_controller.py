@@ -43,7 +43,7 @@ from kubeflow_tpu.operator.controller import ReconcileResult
 from kubeflow_tpu.runtime.allocator import (
     GangAllocator, GangRequest, InsufficientCapacityError,
 )
-from kubeflow_tpu.runtime.bootstrap import free_port
+from kubeflow_tpu.runtime.bootstrap import EXIT_CONFIG_ERROR, free_port
 
 # Labels on Worker objects (≈ training.kubeflow.org/replica-{type,index}).
 LABEL_JOB = "training.tpu.kubeflow.dev/job-name"
@@ -292,18 +292,25 @@ class JAXJobController:
             return (f"{w.metadata.name}: exit={w.status.exit_code} "
                     f"{w.status.message}".strip())
 
+        # Root-cause attribution: when one worker dies, its gang peers die
+        # too (their collectives lose a participant) with exit codes that
+        # say nothing about the real cause. The EARLIEST failure is the
+        # root cause; only its exit code decides retryability.
+        root = min(failed, key=lambda w: (w.status.finish_time is None,
+                                          w.status.finish_time))
         retryable: bool
         if policy == RestartPolicy.NEVER:
+            retryable = False
+        elif (root.status.exit_code == EXIT_CONFIG_ERROR
+              and policy != RestartPolicy.ALWAYS):
+            # A config error (bad entrypoint, a tpu worker that found no
+            # TPU, a second worker on a host whose chips are held) is
+            # deterministic: a restart cannot cure it, so the job fails at
+            # once, with the worker's message.
             retryable = False
         elif policy in (RestartPolicy.ALWAYS, RestartPolicy.ON_FAILURE):
             retryable = True
         else:  # EXIT_CODE
-            # Root-cause attribution: when one worker dies, its gang peers
-            # die too (their collectives lose a participant) with exit codes
-            # that say nothing about the real cause. The EARLIEST failure is
-            # the root cause; only its exit code decides retryability.
-            root = min(failed, key=lambda w: (w.status.finish_time is None,
-                                              w.status.finish_time))
             retryable = _is_retryable_exit(root.status.exit_code)
             # A gang that died before ever running is a rendezvous/placement
             # failure — infrastructure, not the program (bootstrap.py notes

@@ -23,7 +23,7 @@ from kubeflow_tpu.core.events import EventRecorder, default_recorder
 from kubeflow_tpu.core.jobs import Worker, WorkerPhase
 from kubeflow_tpu.core.object import utcnow
 from kubeflow_tpu.core.store import NotFoundError, ObjectStore, EventType, Watch
-from kubeflow_tpu.runtime.bootstrap import WorkerEnv
+from kubeflow_tpu.runtime.bootstrap import EXIT_CONFIG_ERROR, WorkerEnv
 from kubeflow_tpu.runtime.procman import LocalProcessManager
 
 logger = logging.getLogger("kubeflow_tpu.operator.runtime")
@@ -93,7 +93,33 @@ class WorkerRuntime:
     def _proc_name(self, w: Worker) -> str:
         return f"{w.metadata.namespace}.{w.metadata.name}"
 
+    def _chips_holder(self) -> Optional[str]:
+        """On the tpu platform, the live worker that already holds this
+        host's chips. Nothing confines a worker to the chips the allocator
+        gave its job: every worker process opens ALL local chips, and a
+        chip belongs to one process at a time — so a second concurrent
+        worker on the host could only fail or hang inside libtpu. The
+        runtime refuses it up front, by name."""
+        if self.platform != "tpu":
+            return None
+        alive = self.procman.alive()
+        return alive[0] if alive else None
+
     def _launch(self, w: Worker, name: str) -> None:
+        holder = self._chips_holder()
+        if holder is not None:
+            w.status.phase = WorkerPhase.FAILED
+            w.status.exit_code = EXIT_CONFIG_ERROR
+            w.status.message = (
+                f"platform tpu: worker {holder} already holds this host's "
+                "chips. Workers are not confined to their allocated chips, "
+                "so one host runs one worker process at a time: use one "
+                "worker with all the chips (replicas: 1, tpu_chips: N) "
+                "instead of several workers per host")
+            w.status.finish_time = utcnow()
+            self._update_status(w)
+            self.recorder.warning(w, "ChipsHeld", w.status.message)
+            return
         tmpl = w.spec.template
         workdir = tmpl.working_dir or os.path.join(
             self.base_dir, w.metadata.namespace, w.metadata.name)
@@ -112,8 +138,8 @@ class WorkerRuntime:
             parallelism=w.spec.parallelism,
             platform=self.platform,
             # On the CPU emulation platform each worker fabricates its chip
-            # count as virtual XLA devices; on a real/sim TPU the PJRT plugin
-            # owns device discovery.
+            # count as virtual XLA devices; on the tpu platform the worker
+            # opens every chip of the host (see _chips_holder).
             virtual_devices=max(1, w.spec.resources.tpu_chips),
             heartbeat_file=hb_file,
             workdir=workdir,

@@ -25,6 +25,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from kubeflow_tpu.ops import auto_interpret
 from kubeflow_tpu.ops.attention import NEG_INF
 
 # Tuned on v5e at B=4/H=32/KH=8/S=2048/d=64 (the headline train shape):
@@ -37,10 +38,6 @@ from kubeflow_tpu.ops.attention import NEG_INF
 # tile-misaligned block.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_KV = 1024
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _fit_block(pref: int, s: int) -> int:
@@ -155,6 +152,7 @@ def _flash_fwd(q, k, v, *, causal, sm_scale, softcap, q_offset,
 
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=(b, h, nq, nkv),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d),
@@ -183,7 +181,7 @@ def _flash_fwd(q, k, v, *, causal, sm_scale, softcap, q_offset,
             jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
         ),
-        interpret=interpret if interpret is not None else _auto_interpret(),
+        interpret=interpret if interpret is not None else auto_interpret(),
     )(q, k, v)
     return o, lse[..., 0]
 
@@ -317,7 +315,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, sm_scale, softcap,
     n_rep = h // kh
     bq, bkv = _block_sizes(sq, skv, block_q, block_kv)
     nq, nkv = sq // bq, skv // bkv
-    interp = interpret if interpret is not None else _auto_interpret()
+    interp = interpret if interpret is not None else auto_interpret()
 
     # Rowsum(dO · O): the softmax-backward correction term, cheap in XLA.
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
@@ -330,6 +328,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, sm_scale, softcap,
         num_q_blocks=nq, num_groups=n_rep)
     dk, dv = pl.pallas_call(
         dkdv,
+        name="flash_attention_bwd_dkdv",
         grid=(b, kh, nkv, n_rep, nq),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d),
@@ -371,6 +370,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, sm_scale, softcap,
         q_offset=q_offset, block_q=bq, block_kv=bkv, num_kv_blocks=nkv)
     dq = pl.pallas_call(
         dqk,
+        name="flash_attention_bwd_dq",
         grid=(b, h, nq, nkv),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d),
@@ -509,9 +509,6 @@ def flash_attention_sharded(
 
     from jax.sharding import PartitionSpec as P
 
-    from kubeflow_tpu.compat import require_shard_map
-    shard_map = require_shard_map()
-
     shape = dict(mesh.shape)
     batch_axes = tuple(a for a in ("dcn", "data", "fsdp")
                        if shape.get(a, 1) > 1)
@@ -527,7 +524,7 @@ def flash_attention_sharded(
     bspec = batch_axes if batch_axes else None
     model_ax = "model" if tp > 1 else None
     spec = P(bspec, None, model_ax, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         _ft.partial(flash_attention, causal=causal,
                     logits_softcap=logits_softcap),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

@@ -36,7 +36,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from kubeflow_tpu.ops.fused_xent import _auto_interpret, _fit_dim
+from kubeflow_tpu.ops import VMEM_BUDGET_BYTES, auto_interpret
+from kubeflow_tpu.ops.fused_xent import _fit_dim
 
 # Row-block preference: bounds fp32 VMEM residency at [rows, D]; fitted
 # down to a divisor of the actual row count.
@@ -48,7 +49,7 @@ def norm_supported(rows: int, d: int,
                    interpret: Optional[bool] = None) -> bool:
     """Mosaic tiling guard (interpret takes anything): 128-lane hidden,
     8-sublane rows."""
-    interp = interpret if interpret is not None else _auto_interpret()
+    interp = interpret if interpret is not None else auto_interpret()
     if interp:
         return True
     return d % 128 == 0 and rows % 8 == 0
@@ -105,8 +106,20 @@ def _rms_bwd_kernel(x_ref, w_ref, rstd_ref, dh_ref, dx_ref, dw_ref,
         dw_ref[...] = dw_acc[:].astype(dw_ref.dtype)
 
 
-def _norm_blocks(rows: int, block_rows: Optional[int]) -> int:
-    return block_rows or _fit_dim(rows, DEFAULT_BLOCK_ROWS, 8)
+def _norm_blocks(rows: int, d: int, itemsize: int, tiles: int,
+                 block_rows: Optional[int], interpret: bool) -> int:
+    """Row block: the caller's, else the largest 8-aligned divisor under
+    the default — halved, when compiling for the chip, until the
+    ``tiles`` double-buffered ``[br, d]`` in/out blocks of the op's
+    hungriest kernel fit fast memory (add-RMSNorm at hidden 4096 wanted
+    17.9 MB of the 16 MB scoped limit at 256 rows)."""
+    if block_rows:
+        return block_rows
+    pref = DEFAULT_BLOCK_ROWS
+    if not interpret:
+        while pref > 8 and 2 * tiles * pref * d * itemsize > VMEM_BUDGET_BYTES:
+            pref //= 2
+    return _fit_dim(rows, pref, 8)
 
 
 def _rms_fwd_call(x2, r2, w2, eps, plus_one, br, interpret):
@@ -119,6 +132,7 @@ def _rms_fwd_call(x2, r2, w2, eps, plus_one, br, interpret):
     if r2 is None:
         return pl.pallas_call(
             functools.partial(_rms_fwd_kernel, eps=eps, plus_one=plus_one),
+            name="rmsnorm_fwd",
             grid=(nt,),
             in_specs=[row_spec, w_spec],
             out_specs=(row_spec, stat_spec),
@@ -128,6 +142,7 @@ def _rms_fwd_call(x2, r2, w2, eps, plus_one, br, interpret):
         )(x2, w2)
     y, o, rstd = pl.pallas_call(
         functools.partial(_residual_fwd_kernel, eps=eps, plus_one=plus_one),
+        name="add_rmsnorm_fwd",
         grid=(nt,),
         in_specs=[row_spec, row_spec, w_spec],
         out_specs=(row_spec, row_spec, stat_spec),
@@ -146,6 +161,7 @@ def _rms_bwd_call(x2, w2, rstd, dh2, plus_one, br, interpret):
     dx, dw = pl.pallas_call(
         functools.partial(_rms_bwd_kernel, plus_one=plus_one,
                           num_blocks=nt),
+        name="rmsnorm_bwd",
         grid=(nt,),
         in_specs=[
             row_spec,
@@ -212,8 +228,10 @@ def rmsnorm_fused(x: jax.Array, w: jax.Array, *, eps: float,
     """Fused RMSNorm over the last dim; ``x`` [..., D], ``w`` [D]."""
     d = x.shape[-1]
     x2 = x.reshape(-1, d)
-    interp = interpret if interpret is not None else _auto_interpret()
-    br = _norm_blocks(x2.shape[0], block_rows)
+    interp = interpret if interpret is not None else auto_interpret()
+    # Hungriest kernel: the backward's x, dh in and dx out.
+    br = _norm_blocks(x2.shape[0], d, x2.dtype.itemsize, 3, block_rows,
+                      interp)
     o = _rmsnorm(x2, w.reshape(1, d), eps, plus_one, br, interp)
     return o.reshape(x.shape)
 
@@ -225,8 +243,10 @@ def add_rmsnorm_fused(x: jax.Array, res: jax.Array, w: jax.Array, *,
     """Fused ``y = x + res; h = rmsnorm(y)``; returns ``(y, h)``."""
     d = x.shape[-1]
     x2, r2 = x.reshape(-1, d), res.reshape(-1, d)
-    interp = interpret if interpret is not None else _auto_interpret()
-    br = _norm_blocks(x2.shape[0], block_rows)
+    interp = interpret if interpret is not None else auto_interpret()
+    # Hungriest kernel: the forward's x, res in and y, h out.
+    br = _norm_blocks(x2.shape[0], d, x2.dtype.itemsize, 4, block_rows,
+                      interp)
     y, o = _add_rmsnorm(x2, r2, w.reshape(1, d), eps, plus_one, br, interp)
     return y.reshape(x.shape), o.reshape(x.shape)
 
@@ -282,6 +302,7 @@ def _swiglu(g2, u2, act, br, bm, interpret):
     spec = pl.BlockSpec((br, bm), lambda ti, mi: (ti, mi))
     return pl.pallas_call(
         functools.partial(_swiglu_fwd_kernel, act=act),
+        name="glu_fwd",
         grid=(rows // br, m // bm),
         in_specs=[spec, spec],
         out_specs=spec,
@@ -300,6 +321,7 @@ def _swiglu_vjp_bwd(act, br, bm, interpret, res, do2):
     spec = pl.BlockSpec((br, bm), lambda ti, mi: (ti, mi))
     dg, du = pl.pallas_call(
         functools.partial(_swiglu_bwd_kernel, act=act),
+        name="glu_bwd",
         grid=(rows // br, m // bm),
         in_specs=[spec, spec, spec],
         out_specs=(spec, spec),
@@ -321,6 +343,6 @@ def swiglu_fused(gate: jax.Array, up: jax.Array, *, act: str = "silu",
         raise ValueError(f"gate {gate.shape} != up {up.shape}")
     m = gate.shape[-1]
     g2, u2 = gate.reshape(-1, m), up.reshape(-1, m)
-    interp = interpret if interpret is not None else _auto_interpret()
+    interp = interpret if interpret is not None else auto_interpret()
     br, bm = _swiglu_blocks(g2.shape[0], m)
     return _swiglu(g2, u2, act, br, bm, interp).reshape(gate.shape)

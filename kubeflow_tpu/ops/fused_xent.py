@@ -38,15 +38,13 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from kubeflow_tpu.ops import VMEM_BUDGET_BYTES, auto_interpret
+
 # Tile preferences; fitted down to divisors of the actual dims. The row
 # block bounds the fp32 accumulators ([rows, 1] stats + [rows, bv] tile);
 # the vocab block bounds the resident head slice ([D, bv]).
 DEFAULT_BLOCK_ROWS = 256
 DEFAULT_BLOCK_VOCAB = 512
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _fit_dim(n: int, pref: int, align: int) -> int:
@@ -63,20 +61,68 @@ def _fit_dim(n: int, pref: int, align: int) -> int:
 
 
 def supported(rows: int, hidden: int, vocab: int,
-              interpret: Optional[bool] = None) -> bool:
-    """Whether the fused kernel can serve this (T, D, V) shape. On real
-    TPU the lane/sublane tiling needs 128-aligned hidden/vocab and
-    8-aligned rows; the interpreter takes anything."""
-    interp = interpret if interpret is not None else _auto_interpret()
+              interpret: Optional[bool] = None, *,
+              dtype=jnp.bfloat16, head_dtype=None) -> bool:
+    """Whether the fused kernel can serve this (T, D, V) shape with
+    ``dtype`` activations and a ``head_dtype`` head (default: the same).
+    On real TPU the lane/sublane tiling needs 128-aligned hidden/vocab
+    and 8-aligned rows, and some aligned tile has to fit the kernels'
+    fast memory (``_fit_vmem``); the interpreter takes anything."""
+    interp = interpret if interpret is not None else auto_interpret()
     if interp:
         return True
-    return hidden % 128 == 0 and vocab % 128 == 0 and rows % 8 == 0
+    if hidden % 128 or vocab % 128 or rows % 8:
+        return False
+    return _fit_vmem(rows, hidden, vocab, jnp.dtype(dtype).itemsize,
+                     jnp.dtype(head_dtype or dtype).itemsize) is not None
 
 
-def _blocks(rows: int, vocab: int, block_rows: Optional[int],
-            block_vocab: Optional[int]) -> tuple[int, int]:
+def _vmem_bytes(br: int, bv: int, d: int, hb: int, wb: int) -> int:
+    """Fast memory the hungriest of the three kernels keeps resident for
+    a ``(br, bv)`` tile: in/out blocks double-buffered plus the fp32
+    accumulator. ``hb``/``wb``: activation / head bytes per element."""
+    h, w = br * d * hb, d * bv * wb
+    fwd = 2 * (h + w)
+    bwd_dh = 2 * (h + w + h) + br * d * 4          # dh out + fp32 dh_acc
+    bwd_dw = 2 * (h + w + w) + d * bv * 4          # dw out + fp32 dw_acc
+    return max(fwd, bwd_dh, bwd_dw)
+
+
+def _fit_vmem(rows: int, d: int, vocab: int, hb: int, wb: int,
+              ) -> Optional[tuple[int, int]]:
+    """The largest aligned ``(br, bv)`` tile (by area, then by vocab
+    width) not over the defaults whose kernels fit ``VMEM_BUDGET_BYTES``;
+    None when none does. Static (trace-time) search over divisors."""
+    fits = [(br * bv, bv, br)
+            for br in range(8, min(DEFAULT_BLOCK_ROWS, rows) + 1, 8)
+            if rows % br == 0
+            for bv in range(128, min(DEFAULT_BLOCK_VOCAB, vocab) + 1, 128)
+            if vocab % bv == 0
+            and _vmem_bytes(br, bv, d, hb, wb) <= VMEM_BUDGET_BYTES]
+    if not fits:
+        return None
+    _, bv, br = max(fits)
+    return br, bv
+
+
+def _blocks(rows: int, d: int, vocab: int, hb: int, wb: int,
+            block_rows: Optional[int], block_vocab: Optional[int],
+            interpret: bool) -> tuple[int, int]:
+    """Tile sizes: the caller's, else the largest aligned divisors under
+    the defaults — shrunk, when compiling for the chip, until the kernels
+    fit fast memory (a float32 head at vocab 256128 wanted 17 MB of the
+    16 MB scoped limit in the d_head backward at the default tile)."""
     br = block_rows or _fit_dim(rows, DEFAULT_BLOCK_ROWS, 8)
     bv = block_vocab or _fit_dim(vocab, DEFAULT_BLOCK_VOCAB, 128)
+    if (not interpret and block_rows is None and block_vocab is None
+            and _vmem_bytes(br, bv, d, hb, wb) > VMEM_BUDGET_BYTES):
+        fit = _fit_vmem(rows, d, vocab, hb, wb)
+        if fit is None:
+            raise ValueError(
+                f"no aligned tile of (rows={rows}, hidden={d}, "
+                f"vocab={vocab}) fits the kernels' fast memory; check "
+                "fused_xent.supported() first")
+        br, bv = fit
     if rows % br or vocab % bv:
         raise ValueError(
             f"block sizes ({br}, {bv}) must divide (rows={rows}, "
@@ -148,6 +194,7 @@ def _xent_fwd(h, w, t, softcap, br, bv, interpret):
         vocab=vocab)
     return pl.pallas_call(
         kernel,
+        name="fused_xent_fwd",
         grid=(nt, nv),
         in_specs=[
             pl.BlockSpec((br, d), lambda ti, vi: (ti, 0)),
@@ -245,6 +292,7 @@ def _xent_bwd(h, w, t, lse, g, softcap, br, bv, interpret):
     dh = pl.pallas_call(
         functools.partial(_bwd_dh_kernel, softcap=softcap, block_vocab=bv,
                           num_vocab_blocks=nv),
+        name="fused_xent_bwd_dh",
         grid=(nt, nv),
         in_specs=[
             pl.BlockSpec((br, d), lambda ti, vi: (ti, 0)),
@@ -262,6 +310,7 @@ def _xent_bwd(h, w, t, lse, g, softcap, br, bv, interpret):
     dw = pl.pallas_call(
         functools.partial(_bwd_dw_kernel, softcap=softcap, block_vocab=bv,
                           num_row_blocks=nt),
+        name="fused_xent_bwd_dw",
         grid=(nv, nt),
         in_specs=[
             pl.BlockSpec((br, d), lambda vi, ti: (ti, 0)),
@@ -323,8 +372,9 @@ def fused_cross_entropy(
     h2 = hidden.reshape(-1, d)
     t2 = targets.reshape(-1, 1).astype(jnp.int32)
     rows, vocab = h2.shape[0], head.shape[1]
-    interp = interpret if interpret is not None else _auto_interpret()
-    br, bv = _blocks(rows, vocab, block_rows, block_vocab)
+    interp = interpret if interpret is not None else auto_interpret()
+    br, bv = _blocks(rows, d, vocab, h2.dtype.itemsize, head.dtype.itemsize,
+                     block_rows, block_vocab, interp)
     nll, correct = _fused_ce(h2, head, t2, logits_softcap, br, bv, interp)
     return (nll.reshape(targets.shape), correct.reshape(targets.shape))
 

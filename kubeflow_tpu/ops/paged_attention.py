@@ -36,11 +36,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from kubeflow_tpu.ops import auto_interpret
 from kubeflow_tpu.ops.attention import NEG_INF
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _kernel(table_ref, len_ref, q_ref, k_ref, v_ref, *rest,
@@ -168,6 +165,7 @@ def paged_decode_attention(
 
     out = pl.pallas_call(
         kernel,
+        name="paged_decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, mpp),
@@ -180,6 +178,6 @@ def paged_decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, 1, h, d), q.dtype),
-        interpret=interpret if interpret is not None else _auto_interpret(),
+        interpret=interpret if interpret is not None else auto_interpret(),
     )(table, lengths, *operands)
     return out

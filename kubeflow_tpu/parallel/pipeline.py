@@ -49,7 +49,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from kubeflow_tpu.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 StageFn = Callable[[Any, Any], Any]
@@ -165,7 +164,7 @@ def pipeline_apply(
 
         return jax.tree.map(collect, out)
 
-    return shard_map(
+    return jax.shard_map(
         worker, mesh=mesh,
         in_specs=(param_specs, x_specs),
         out_specs=x_specs,
@@ -384,10 +383,10 @@ def _pipeline_1f1b(stage_fn, stage_params, xs, *, mesh, num_microbatches,
         return (jax.tree.map(lambda d: d[None], dparams),
                 jax.tree.map(collect, dxs))
 
-    fwd_sm = shard_map(fwd_worker, mesh=mesh,
+    fwd_sm = jax.shard_map(fwd_worker, mesh=mesh,
                        in_specs=(param_specs, x_specs),
                        out_specs=x_specs, check_vma=False)
-    bwd_sm = shard_map(bwd_worker, mesh=mesh,
+    bwd_sm = jax.shard_map(bwd_worker, mesh=mesh,
                        in_specs=(param_specs, x_specs, x_specs),
                        out_specs=(param_specs, x_specs), check_vma=False)
 

@@ -40,7 +40,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from kubeflow_tpu.compat import axis_size as _axis_size, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from kubeflow_tpu.ops.attention import NEG_INF, _repeat_kv
@@ -104,7 +103,7 @@ def _ring_flash_fwd_impl(q, k, v, axis_name, causal, sm_scale, softcap,
                          interpret):
     from kubeflow_tpu.ops.flash_attention import _flash_fwd
 
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     qt = jnp.swapaxes(q, 1, 2)                     # [B,H,Sq,D]
     kt = jnp.swapaxes(k, 1, 2)                     # [B,KH,Skv,D] (raw GQA)
@@ -166,7 +165,7 @@ def _ring_flash_vjp_bwd(axis_name, causal, sm_scale, softcap, interpret,
     from kubeflow_tpu.ops.flash_attention import _flash_bwd_pallas
 
     q, k, v, out, lse = res
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
@@ -250,7 +249,7 @@ def ring_attention(
                            logits_softcap, interpret)
     if impl != "xla":
         raise ValueError(f"unknown ring attention impl {impl!r}")
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, s_local, h, d = q.shape
     # GQA expansion happens per-step inside _block_attn_step: the ring
@@ -303,7 +302,7 @@ def ulysses_attention(
     swap back (the DeepSpeed-Ulysses schedule, TPU-natively over ICI)."""
     from kubeflow_tpu.ops.attention import multi_head_attention
 
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     h, kh = q.shape[2], k.shape[2]
     if h % n or kh % n:
         raise ValueError(
@@ -325,7 +324,7 @@ def ulysses_attention(
 
 def _sharded(fn, mesh: Mesh, axis_name: str, batch_axes):
     spec = P(batch_axes, axis_name, None, None)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                      out_specs=spec, check_vma=False)
 
 

@@ -6,16 +6,19 @@ graph, on SQLite/MySQL. The rebuild keeps that native-parity component:
 ``native/metadata_store/metadata_store.cc`` (C++ on the system SQLite,
 flat C ABI) consumed here via ctypes — pybind11 isn't in the image.
 
-``MetadataStore(path)`` prefers the native library (building it on first use
-when a toolchain is present) and falls back to a pure-Python sqlite3
-implementation with identical semantics, so the platform works on
-toolchain-less hosts. ``backend="native"`` forces (and asserts) the C++ path.
+``MetadataStore(path)`` prefers the native library (built on first use, and
+again whenever the committed source differs from what built the library on
+disk) and falls back to a pure-Python sqlite3 implementation with identical
+semantics only on hosts with no toolchain; where there is one, a failed build
+is an error. ``backend="native"`` forces (and asserts) the C++ path.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import shutil
 import sqlite3 as _pysqlite
 import subprocess
 import threading
@@ -37,22 +40,65 @@ ART_UNKNOWN, ART_PENDING, ART_LIVE, ART_DELETED = range(4)
 EVENT_INPUT, EVENT_OUTPUT = 0, 1
 
 _build_lock = threading.Lock()
+# The library is a build product (git-ignored), so one found on disk may
+# predate the committed source. It is tied to the source by a stamp: the
+# sha256 of the files the Makefile's rule depends on, written beside the
+# library by the build that produced it.
+_STAMP_PATH = _LIB_PATH + ".src-sha256"
+_SRC_FILES = ("metadata_store.cc", "sqlite3_api.h", "Makefile")
+
+
+class NativeBuildError(Exception):
+    """The host has a toolchain and the committed source did not build."""
+
+
+def _source_digest() -> str:
+    h = hashlib.sha256()
+    for name in _SRC_FILES:
+        with open(os.path.join(_SRC_DIR, name), "rb") as f:
+            h.update(name.encode() + b"\0" + f.read() + b"\0")
+    return h.hexdigest()
+
+
+def _stamp() -> Optional[str]:
+    try:
+        with open(_STAMP_PATH) as f:
+            return f.read().strip()
+    except FileNotFoundError:
+        return None
 
 
 def _try_build_native() -> bool:
-    if os.path.exists(_LIB_PATH):
-        return True
+    """True when ``_LIB_PATH`` holds a library built from the committed
+    source, building it (again) when the source differs from what built
+    it. False only where there is nothing to build with: no source tree,
+    or no ``make``/compiler on the host — the pure-Python backend's case.
+    With a toolchain present a FAILED build raises: silently taking the
+    other backend would hide a broken source file behind passing runs."""
     if not os.path.isdir(_SRC_DIR):
-        return False
+        return os.path.exists(_LIB_PATH)     # installed without the source
+    digest = _source_digest()
     with _build_lock:
-        if os.path.exists(_LIB_PATH):
+        if os.path.exists(_LIB_PATH) and _stamp() == digest:
             return True
-        try:
-            subprocess.run(["make"], cwd=_SRC_DIR, check=True,
-                           capture_output=True, timeout=120)
-        except (OSError, subprocess.SubprocessError):
+        cxx = os.environ.get("CXX", "g++")
+        if shutil.which("make") is None or shutil.which(cxx) is None:
             return False
-    return os.path.exists(_LIB_PATH)
+        # Build beside the target and rename: another process may be
+        # loading the library that is there now.
+        tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+        try:
+            subprocess.run(["make", "-B", f"OUT={tmp}"], cwd=_SRC_DIR,
+                           check=True, capture_output=True, timeout=120)
+        except subprocess.CalledProcessError as exc:
+            raise NativeBuildError(
+                "building the native metadata store failed:\n"
+                + exc.stderr.decode(errors="replace")[-4000:]) from exc
+        os.replace(tmp, _LIB_PATH)
+        with open(tmp, "w") as f:     # same scratch name, now for the stamp
+            f.write(digest)
+        os.replace(tmp, _STAMP_PATH)
+    return True
 
 
 def _load_native() -> Optional[ctypes.CDLL]:
@@ -577,13 +623,12 @@ class MetadataStore:
         elif backend == "native":
             self._b = _NativeBackend(path)
             self.backend = "native"
+        elif native_library() is not None:    # raises on a failed build
+            self._b = _NativeBackend(path)
+            self.backend = "native"
         else:
-            try:
-                self._b = _NativeBackend(path)
-                self.backend = "native"
-            except RuntimeError:
-                self._b = _PythonBackend(path)
-                self.backend = "python"
+            self._b = _PythonBackend(path)
+            self.backend = "python"
 
     def close(self) -> None:
         self._b.close()

@@ -70,16 +70,14 @@ def build_mesh(
             allow_split_physical_axes=True)
         return Mesh(dev_array, MESH_AXES)
     # Auto axis types = classic GSPMD propagation (annotate params/inputs,
-    # XLA infers the rest and inserts collectives). JAX 0.9's default
+    # XLA infers the rest and inserts collectives). JAX's default
     # Explicit mode rejects ops whose output sharding is ambiguous (sharded
     # attention einsums, vocab-parallel gathers), which is exactly the work
     # we delegate to the compiler.
     try:
-        axis_types = (jax.sharding.AxisType.Auto,) * len(MESH_AXES)
-        return jax.make_mesh(sizes, MESH_AXES, devices=devices,
-                             axis_types=axis_types)
-    except (TypeError, AttributeError):
-        pass
+        return jax.make_mesh(
+            sizes, MESH_AXES, devices=devices,
+            axis_types=(jax.sharding.AxisType.Auto,) * len(MESH_AXES))
     except NotImplementedError:
         # Topology-aware assignment needs each logical axis to be a product
         # of physical torus axes (e.g. fsdp=8 over a 4x4x4 pod wants a
@@ -89,14 +87,6 @@ def build_mesh(
 
         dev_array = mesh_utils.create_device_mesh(
             sizes, devices=list(devices), allow_split_physical_axes=True)
-        return Mesh(dev_array, MESH_AXES)
-    try:
-        # JAX without AxisType but with make_mesh: keep the topology-aware
-        # device assignment (losing it silently reorders ICI neighbors).
-        return jax.make_mesh(sizes, MESH_AXES, devices=devices)
-    except (TypeError, AttributeError):
-        # Oldest fallback: raw reshape — plain Mesh is Auto there.
-        dev_array = np.asarray(devices).reshape(sizes)
         return Mesh(dev_array, MESH_AXES)
 
 

@@ -44,6 +44,22 @@ class ProcHandle:
         return time.time() - os.path.getmtime(self.heartbeat_file)
 
 
+PLATFORMS = ("cpu", "tpu")
+
+
+def platform_env(platform: str) -> dict[str, str]:
+    """This process's environment with the JAX platform of a child pinned
+    to ``platform``, in both directions: a ``cpu`` child never reaches
+    for the chip, and a ``tpu`` child does not inherit a parent's
+    ``JAX_PLATFORMS=cpu`` and quietly run on the host (it fails at
+    backend start instead when there is no chip)."""
+    if platform not in PLATFORMS:
+        raise ValueError(f"unknown platform {platform!r}; one of {PLATFORMS}")
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = platform
+    return env
+
+
 class LocalProcessManager:
     """Spawns workers as local subprocesses."""
 
@@ -55,7 +71,7 @@ class LocalProcessManager:
                extra_env: Optional[dict[str, str]] = None) -> ProcHandle:
         if name in self._procs and self._procs[name].poll() is None:
             raise RuntimeError(f"worker {name} already running")
-        env = dict(os.environ)
+        env = platform_env(wenv.platform)
         env.update(wenv.to_env())
         if extra_env:
             env.update(extra_env)

@@ -10,17 +10,21 @@ feeding a matmul decompose into overlap-friendly steps.
 
 Contract:
 
-- ``apply_xla_perf_flags()`` merges the set into ``$XLA_FLAGS`` WITHOUT
-  overriding any flag the operator already pinned there (name-level
-  merge), and must run before the JAX backend initializes — callers are
-  the worker bootstrap (hardware path), bench.py and the sweep scripts.
+- ``apply_xla_perf_flags()`` merges the set into ``$LIBTPU_INIT_ARGS``
+  WITHOUT overriding any flag the operator already pinned there
+  (name-level merge), and must run before the JAX backend initializes —
+  callers are the worker bootstrap (tpu platform) and bench.py.
+  ``$XLA_FLAGS`` is left alone: jaxlib parses it itself and aborts the
+  process on a flag it does not know ("Unknown flag in XLA_FLAGS"),
+  and these are libtpu's flags, not jaxlib's. libtpu reads
+  ``LIBTPU_INIT_ARGS`` and rejects an unknown name there too, so a
+  misspelt entry still fails loudly at backend start.
 - Escape hatch: ``KFTPU_XLA_PERF_FLAGS=off`` (or ``0``/``none``) skips
   the whole set; any other non-empty value REPLACES it verbatim (an
   operator debugging a miscompile can pin the exact flag set without
   editing code). Unset means the default set below.
 
-The flags are TPU-only (harmless but noisy elsewhere), so callers gate on
-the platform not being forced to CPU.
+The flags are TPU-only, so callers apply them on the tpu platform only.
 """
 
 from __future__ import annotations
@@ -44,11 +48,13 @@ PERF_FLAGS: dict[str, str] = {
 }
 
 ESCAPE_ENV = "KFTPU_XLA_PERF_FLAGS"
+# Where the installed stack accepts TPU-only flags (libtpu's own parser).
+FLAGS_ENV = "LIBTPU_INIT_ARGS"
 
 
 def xla_perf_flags(existing: str = "",
                    env_value: Optional[str] = None) -> str:
-    """The merged ``XLA_FLAGS`` value: ``existing`` plus every PERF_FLAG
+    """The merged ``LIBTPU_INIT_ARGS`` value: ``existing`` plus every PERF_FLAG
     whose name is not already present. Pure (testable) core of
     ``apply_xla_perf_flags``."""
     if env_value is not None and env_value.strip().lower() in (
@@ -64,16 +70,16 @@ def xla_perf_flags(existing: str = "",
 
 
 def apply_xla_perf_flags() -> bool:
-    """Merge the latency-hiding flag set into ``$XLA_FLAGS`` (idempotent,
-    never overrides operator-pinned flags). Returns True when anything
+    """Merge the latency-hiding flag set into ``$LIBTPU_INIT_ARGS``
+    (idempotent, never overrides operator-pinned flags). Returns True when anything
     was added. Must run before the JAX backend initializes; no-op under
     the ``KFTPU_XLA_PERF_FLAGS=off`` escape hatch."""
-    existing = os.environ.get("XLA_FLAGS", "")
+    existing = os.environ.get(FLAGS_ENV, "")
     merged = xla_perf_flags(
         existing,
         # contract: operator-facing knob — set by the user, never by the tree
         os.environ.get(ESCAPE_ENV))
     if merged != existing:
-        os.environ["XLA_FLAGS"] = merged
+        os.environ[FLAGS_ENV] = merged
         return True
     return False

@@ -39,7 +39,8 @@ class Client:
         )
         from kubeflow_tpu.runtime.topology import detect_local_cluster
 
-        cluster = (detect_local_cluster(num_chips=num_chips)
+        cluster = (detect_local_cluster(num_chips=num_chips,
+                                        platform=platform)
                    if num_chips else None)
         cp = ControlPlane(ControlPlaneConfig(
             base_dir=base_dir, platform=platform, cluster=cluster))

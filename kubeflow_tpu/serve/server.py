@@ -20,7 +20,8 @@ with a ``ModelRepository`` and the server adds the v2 repository API
 (``GET /v2/repository/index``, ``POST /v2/repository/models/{m}/load|
 unload``) and per-request routing with LRU load-on-demand.
 
-Plus /healthz (readiness) and /metrics (Prometheus text format).
+Plus /healthz (readiness), /metrics (Prometheus text format) and
+/debug/device (device, memory, compile cache, kernels per dispatched program).
 Threaded stdlib server: handlers block on the engine's request stream; the
 engine thread does the batching, so concurrency costs one OS thread per
 in-flight request — fine at platform scale, and zero dependencies.
@@ -539,17 +540,32 @@ class ModelServer:
         """Scrape-time registry over the live engine counters — the model
         server's half of the platform's single exposition path
         (obs/registry.py)."""
+        return serving_metrics_registry(self._live_engines(),
+                                        in_flight=self.in_flight)
+
+    def _live_engines(self) -> list[tuple[str, LLMEngine]]:
         engines: list[tuple[str, LLMEngine]] = []
         if self.engine is not None:
             engines.append((self.name, self.engine))
         elif self.repository is not None:
-            # peek only: a metrics scrape must not touch LRU recency or
-            # load anything.
+            # peek only: a scrape must not touch LRU recency or load
+            # anything.
             for item in self.repository.index():
                 entry = self.repository.peek(item["name"])
                 if entry is not None and entry.engine is not None:
                     engines.append((entry.name, entry.engine))
-        return serving_metrics_registry(engines, in_flight=self.in_flight)
+        return engines
+
+    def device_payload(self) -> dict:
+        """``GET /debug/device``: what this replica runs on, for a parent
+        that must not touch the chip itself — device, memory and compile
+        cache (runtime/device_report.py) plus, per model, the Pallas
+        kernels of each program the engine has dispatched."""
+        from kubeflow_tpu.runtime.device_report import device_report
+
+        return {**device_report(),
+                "programs": {name: dict(eng.program_kernels)
+                             for name, eng in self._live_engines()}}
 
     def metrics_text(self) -> str:
         return self.metrics_registry().render()
@@ -780,6 +796,8 @@ def _make_handler(server: ModelServer):
             if self.path == "/metrics":
                 self._text(200, server.metrics_text())
                 return
+            if self.path == "/debug/device":
+                return self._json(200, server.device_payload())
             if self.path.startswith("/debug/traces"):
                 return self._json(200, debug_traces_payload(self.path))
             if self.path.startswith("/debug/spans/export"):
@@ -977,8 +995,12 @@ def _make_handler(server: ModelServer):
                           "message": {"role": "assistant", "content": text}}
                 obj = "chat.completion"
             else:
+                # token_ids: what the engine emitted, before detokenizing —
+                # a client with its own tokenizer decodes these (the
+                # bundled byte tokenizer has no text for ids above 258).
                 choice = {"index": 0, "finish_reason": req.finish_reason,
-                          "text": text}
+                          "text": text,
+                          "token_ids": list(req.output_tokens)}
                 obj = "text_completion"
             self._json(200, {
                 "id": req.id, "object": obj, "created": int(time.time()),

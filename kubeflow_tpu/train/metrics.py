@@ -11,18 +11,18 @@ import sys
 import time
 from typing import Optional, TextIO
 
-from kubeflow_tpu.runtime.topology import GENERATIONS
-
 
 class Throughput:
     """Steady-state throughput over a sliding window (skips compile step)."""
 
     def __init__(self, tokens_per_step: float, num_chips: int,
-                 flops_per_token: float, generation: str = "v5e"):
+                 flops_per_token: float, peak_tflops: Optional[float]):
+        """``peak_tflops``: the chip's published bf16 peak, or None where
+        there is none (the CPU) — then no ``mfu`` is reported."""
         self.tokens_per_step = tokens_per_step
         self.num_chips = num_chips
         self.flops_per_token = flops_per_token
-        self.peak_flops = GENERATIONS.get(generation, GENERATIONS["v5e"]).bf16_tflops * 1e12
+        self.peak_flops = None if peak_tflops is None else peak_tflops * 1e12
         self._last: Optional[float] = None
         self._ema_dt: Optional[float] = None
 
@@ -46,8 +46,10 @@ class Throughput:
                 "step_time_ms": self._ema_dt * 1e3,
                 "tokens_per_sec": tps,
                 "tokens_per_sec_per_chip": tps / self.num_chips,
-                "mfu": (self.flops_per_token * tps) / (self.num_chips * self.peak_flops),
             }
+            if self.peak_flops is not None:
+                out["mfu"] = (self.flops_per_token * tps) / (
+                    self.num_chips * self.peak_flops)
         self._last = now
         return out
 

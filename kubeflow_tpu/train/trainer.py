@@ -23,7 +23,11 @@ import numpy as np
 from kubeflow_tpu.models.config import DecoderConfig, preset
 from kubeflow_tpu.obs.trace import get_tracer
 from kubeflow_tpu.runtime.bootstrap import EXIT_PREEMPTED
+from kubeflow_tpu.runtime.device_report import (
+    lowered_kernel_calls, write_device_report,
+)
 from kubeflow_tpu.runtime.sanitize import mark_compile_warm, recompile_report
+from kubeflow_tpu.runtime.topology import chip_for_device_kind
 from kubeflow_tpu.train.checkpoint import CheckpointManager, resume_from_tiers
 from kubeflow_tpu.train.data import DataConfig, make_data_source
 from kubeflow_tpu.train.metrics import MetricsEmitter, Throughput
@@ -70,7 +74,6 @@ class TrainerConfig:
     fault_injection: dict = dataclasses.field(default_factory=dict)
     seed: int = 0
     attn_impl: str = "xla"
-    generation: str = "v5e"                   # hardware gen for MFU math
     # jax.profiler window (SURVEY.md §5 tracing): trace steps
     # [profile_start_step, profile_start_step + profile_num_steps) into
     # <workdir>/trace, viewable with tensorboard-plugin-profile.
@@ -95,6 +98,11 @@ class Trainer:
         self.mesh = mesh
         self.process_id = process_id
         self.num_processes = num_processes
+        self.workdir = workdir
+        # Pallas kernel call sites of the step as lowered for the chip
+        # (runtime/device_report.py); stays empty off the TPU, where the
+        # kernels run in the interpreter and leave no custom call.
+        self.step_kernels: dict[str, int] = {}
 
         if cfg.debug_nans:
             jax.config.update("jax_debug_nans", True)
@@ -176,7 +184,10 @@ class Trainer:
             tokens_per_step=data_cfg.global_batch * data_cfg.seq_len,
             num_chips=mesh.devices.size,
             flops_per_token=self.model_cfg.flops_per_token(),
-            generation=cfg.generation,
+            # The peak comes from the device the mesh is made of, never
+            # from a default row (runtime/topology.py CHIPS).
+            peak_tflops=chip_for_device_kind(
+                mesh.devices.flat[0].device_kind).bf16_tflops,
         )
 
     # -- checkpoint/resume -----------------------------------------------------
@@ -300,6 +311,9 @@ class Trainer:
                         jax.profiler.stop_trace()
                         tracing = False
                 batch = stager.get(step)
+                if step == start and jax.default_backend() == "tpu":
+                    self.step_kernels = lowered_kernel_calls(
+                        self.task.step_fn, self.task.state, batch)
                 self.task.state, metrics = self.task.step_fn(self.task.state, batch)
                 if step == start:
                     # Training shapes are fixed: everything compiles on the
@@ -358,6 +372,8 @@ class Trainer:
                     on_step(step + 1, last_metrics)
             if self.ckpt is not None and self.ckpt.latest_step() != self.cfg.steps:
                 self.save(self.cfg.steps, force=True)
+            if self.workdir and self.process_id == 0:
+                self._write_device_report()
         finally:
             stager.close()
             if prev_sigterm is not None:
@@ -390,6 +406,24 @@ class Trainer:
                 "; ".join(f"{e['fn']} x{e['count']} at {e['site']}"
                           for e in rep["steady"]))
         return last_metrics
+
+    def _write_device_report(self) -> None:
+        """What this run ran on, for a parent that must not touch the chip:
+        device, memory, compile cache, the step's kernels, and where the
+        largest parameter's shards sit (sharded training that put
+        everything on the first device shows here)."""
+        big = max(jax.tree.leaves(self.task.state["params"]),
+                  key=lambda a: a.size)
+        write_device_report(
+            self.workdir,
+            programs={"train_step": self.step_kernels},
+            mesh={a: int(n) for a, n in self.mesh.shape.items() if n > 1},
+            largest_param={
+                "shape": list(big.shape),
+                "shard_shape": list(big.addressable_shards[0].data.shape),
+                "devices": sorted(s.device.id
+                                  for s in big.addressable_shards),
+            })
 
     # -- survivability (preemption / wedge / chaos hooks) ----------------------
 

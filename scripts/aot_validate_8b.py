@@ -16,6 +16,7 @@ multislice pods.
 Run: python scripts/aot_validate_8b.py   (one JSON line per config)
 """
 
+import dataclasses
 import json
 import os
 import sys
@@ -33,18 +34,38 @@ def _mesh_on(topology: str, axes: dict, *, num_slices: int = 1,
     if num_slices > 1:
         kw["num_slices"] = num_slices
     topo = topologies.get_topology_desc(topology, "tpu", **kw)
-    return build_mesh(axes, topo.devices)
+    # Fewer mesh slots than described chips: the first of them (one chip
+    # of a v5e:2x2 is how the one-chip programs are compiled).
+    n = 1
+    for size in axes.values():
+        n *= size
+    return build_mesh(axes, topo.devices[:n])
+
+
+def _mem_gb(compiled) -> dict:
+    m = compiled.memory_analysis()
+    gb = 1 << 30
+    return {
+        "argument_gb": round(m.argument_size_in_bytes / gb, 2),
+        "output_gb": round(m.output_size_in_bytes / gb, 2),
+        "temp_gb": round(m.temp_size_in_bytes / gb, 2),
+        "total_gb": round((m.argument_size_in_bytes + m.temp_size_in_bytes)
+                          / gb, 2),
+    }
 
 
 def train_step_analysis(topology: str, axes: dict, *, model="llama3-8b",
                         per_chip_batch=1, pp_layers=None, num_slices=1,
-                        seq_len=None):
+                        seq_len=None, model_overrides=None, optimizer=None):
     """Compile `model`'s train step for `axes` on `topology`; return per-chip
-    memory totals in GB from the compiled executable."""
+    memory totals in GB from the compiled executable, plus the Pallas
+    kernels in the lowered program (``kernels``). ``model_overrides`` /
+    ``optimizer``: DecoderConfig / OptimizerConfig fields, as a JAXJob's
+    config gives them."""
     import jax
 
     from kubeflow_tpu.models.config import preset
-    from kubeflow_tpu.train.data import DataConfig
+    from kubeflow_tpu.runtime.device_report import kernel_calls
     from kubeflow_tpu.train.optim import OptimizerConfig
     from kubeflow_tpu.train.step import make_state_init, setup_train
 
@@ -54,9 +75,12 @@ def train_step_analysis(topology: str, axes: dict, *, model="llama3-8b",
         over["pipeline_schedule"] = "1f1b"
     if seq_len:
         over["max_seq_len"] = seq_len
+    over.update(model_overrides or {})
     cfg = preset(model, **over)
-    task = setup_train(cfg, OptimizerConfig(total_steps=10), mesh,
-                       attn_impl="pallas", init_state=False)
+    task = setup_train(
+        cfg, OptimizerConfig.from_dict({"total_steps": 10,
+                                        **(optimizer or {})}),
+        mesh, attn_impl="pallas", init_state=False)
     state_sds = jax.eval_shape(make_state_init(cfg, task.optimizer))
     # Global batch: per_chip_batch per data shard; pipeline runs 2*pp
     # microbatches through the stages.
@@ -67,18 +91,88 @@ def train_step_analysis(topology: str, axes: dict, *, model="llama3-8b",
     global_batch = per_chip_batch * batch_shards * (2 * pp if pp > 1 else 1)
     batch_sds = jax.ShapeDtypeStruct((global_batch, cfg.max_seq_len + 1),
                                      jax.numpy.int32)
-    compiled = task.step_fn.lower(state_sds, batch_sds).compile()
-    m = compiled.memory_analysis()
-    gb = 1 << 30
+    lowered = task.step_fn.lower(state_sds, batch_sds)
     return {
         "params_b": round(cfg.num_params() / 1e9, 2),
-        "argument_gb": round(m.argument_size_in_bytes / gb, 2),
-        "output_gb": round(m.output_size_in_bytes / gb, 2),
-        "temp_gb": round(m.temp_size_in_bytes / gb, 2),
-        "total_gb": round((m.argument_size_in_bytes + m.temp_size_in_bytes)
-                          / gb, 2),
+        **_mem_gb(lowered.compile()),
         "global_batch": global_batch,
+        "kernels": kernel_calls(lowered.as_text()),
     }
+
+
+def paged_serve_analysis(topology: str, tp: int, *, model: str,
+                         overrides: dict, slots: int, max_len: int,
+                         page_size: int, num_pages: int, chunk: int,
+                         decode_steps: int, attn_impl: str):
+    """Compile the PAGED serving programs the engine dispatches —
+    ``paged_decode_multi`` (``decode_steps`` per dispatch) and one
+    ``paged_chunk_prefill`` at the widest context bucket — for ``model``
+    over ``tp`` chips (1 = one chip, no mesh), at a pool of ``num_pages``
+    pages of ``page_size``. Returns ``{"decode": {...}, "chunk_prefill":
+    {...}}``: per-chip memory in GB and the Pallas kernels in each lowered
+    program. Mirrors LLMEngine's paged set-up (serve/engine.py): under a
+    mesh the weights shard by the training rules and a KV-head count the
+    ``model`` axis does not divide replicates the pool."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
+
+    from kubeflow_tpu.models.config import preset
+    from kubeflow_tpu.models.decoder import (
+        decoder_param_specs, init_decoder_params)
+    from kubeflow_tpu.parallel.sharding import shard_params
+    from kubeflow_tpu.runtime.device_report import kernel_calls
+    from kubeflow_tpu.serve.paged import (
+        paged_chunk_prefill, paged_decode_multi)
+
+    mesh = _mesh_on(topology, {"model": tp})
+    cfg = preset(model, **overrides)
+    if tp > 1:
+        # LLMEngine's rule: no Mosaic norm/GLU kernels over sharded operands.
+        cfg = dataclasses.replace(cfg, fused_kernels="off")
+    params_sds = jax.eval_shape(
+        lambda: init_decoder_params(jax.random.PRNGKey(0), cfg))
+    if tp > 1:
+        psh = shard_params(params_sds, decoder_param_specs(cfg), mesh)
+        kv_ps = (PartitionSpec(None, None, None, "model", None)
+                 if cfg.n_kv_heads % tp == 0 else PartitionSpec())
+        rep = NamedSharding(mesh, PartitionSpec())
+        kv_sh = NamedSharding(mesh, kv_ps)
+    else:
+        kv_sh = rep = SingleDeviceSharding(mesh.devices.flat[0])
+        psh = jax.tree.map(lambda _: rep, params_sds)
+    params_sds = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        params_sds, psh)
+
+    def sds(shape, dtype, sh=rep):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    mpp = max_len // page_size
+    pool = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+    cache = {n: sds(pool, cfg.activation_dtype, kv_sh) for n in ("k", "v")}
+    i32, f32 = (lambda: sds((slots,), jnp.int32)), (
+        lambda: sds((slots,), jnp.float32))
+
+    decode = jax.jit(
+        lambda p, c, tbl, t, ln, lv, tmp, tk, tpp, st, bd, key:
+        paged_decode_multi(p, {**c, "table": tbl}, t, ln, lv, tmp, tk, tpp,
+                           st, bd, key, cfg, decode_steps,
+                           sample_mode="greedy", attn_impl=attn_impl),
+        donate_argnums=(1,)).lower(
+            params_sds, cache, sds((slots, mpp), jnp.int32), i32(), i32(),
+            sds((slots,), jnp.bool_), f32(), i32(), f32(), i32(), i32(),
+            sds((2,), jnp.uint32))
+    chunked = jax.jit(
+        lambda p, c, t, tr, st, vl: paged_chunk_prefill(
+            p, c, t, tr, st, vl, cfg, context_pages=mpp),
+        donate_argnums=(1,)).lower(
+            params_sds, cache, sds((1, chunk), jnp.int32),
+            sds((mpp,), jnp.int32), sds((), jnp.int32), sds((), jnp.int32))
+    return {name: {**_mem_gb(low.compile()),
+                   "kernels": kernel_calls(low.as_text())}
+            for name, low in (("decode", decode),
+                              ("chunk_prefill", chunked))}
 
 
 def serve_decode_analysis(topology: str, tp: int, *, model="llama3-8b",
@@ -103,8 +197,7 @@ def serve_decode_analysis(topology: str, tp: int, *, model="llama3-8b",
     if cfg.is_moe:
         # The engine's measured decode default: dense MoE (per-phase A/B in
         # serve/engine.py — zero-drop dispatch tied, dense is simpler).
-        import dataclasses as _dc
-        cfg = _dc.replace(cfg, moe_impl="dense")
+        cfg = dataclasses.replace(cfg, moe_impl="dense")
 
     def _abstract_params():
         p = init_decoder_params(jax.random.PRNGKey(0), cfg)
@@ -136,15 +229,8 @@ def serve_decode_analysis(topology: str, tp: int, *, model="llama3-8b",
         donate_argnums=(1,))
     compiled = fn.lower(params_sds, cache_sds, i32(), i32(), b1, f32(),
                         i32(), f32(), i32(), i32(), keys).compile()
-    m = compiled.memory_analysis()
-    gb = 1 << 30
-    return {
-        "params_b": round(cfg.num_params() / 1e9, 2),
-        "argument_gb": round(m.argument_size_in_bytes / gb, 2),
-        "temp_gb": round(m.temp_size_in_bytes / gb, 2),
-        "total_gb": round((m.argument_size_in_bytes + m.temp_size_in_bytes)
-                          / gb, 2),
-    }
+    return {"params_b": round(cfg.num_params() / 1e9, 2),
+            **_mem_gb(compiled)}
 
 
 CONFIGS = [

@@ -31,8 +31,8 @@ open-loop over the FULL protocol path — HTTP SSE against a real
   per-phase rollup.
 
 Writes the measured matrix to ``BENCH_SERVE_r01.json`` at the repo root
-(the serving twin of ``BENCH_r0x.json`` — one row per scenario with the
-full attribution report), prints one JSON object;
+(one row per scenario with the full attribution report), prints one
+JSON object;
 ``{"serve_perf_smoke": "ok"}`` is the gate line.
 """
 
@@ -321,7 +321,7 @@ def main() -> int:
         return fail(f"loadgen series missing from exposition: {missing}")
     result["loadgen_series"] = "ok"
 
-    # 4) Trajectory artifact — the serving BENCH_r0x twin.
+    # 4) Trajectory artifact.
     with open(args.out, "w") as f:
         json.dump({"schema": 1,
                    "generated_by": "scripts/serve_perf_smoke.py",

@@ -13,6 +13,21 @@ plugin is unavailable.
 import pytest
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _no_persistent_cache():
+    """An entry written for a described chip cannot be read back without
+    one: every later compile would warn and compile again."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
 def _topo(name):
     from jax.experimental import topologies
 
@@ -137,3 +152,81 @@ def test_serving_decode_8b_int8_fits_one_v5e_chip():
         max_len=2048, topo_kwargs={"chips_per_host_bounds": [1, 1, 1]})
     assert out["total_gb"] < 16.0, out
     assert out["argument_gb"] < 11.0, out    # int8 params ≈ 8 GB + KV
+
+
+# -- chip_smoke.py's own programs (ISSUE 21 (f)) -------------------------------
+#
+# Ask the compiler before the chip: the whole step programs of both smoke
+# phases, at the smoke's real sizes (imported from chip_smoke.py, so the two
+# cannot drift), compiled for a described v5e. Code that asks
+# ``jax.default_backend()`` sees the CPU here and would take its CPU branch
+# (XLA norms, interpreted kernels), so the TEST steers it — not an option of
+# the program. A compile that passes is not a chip run.
+
+
+@pytest.fixture()
+def as_tpu(monkeypatch):
+    import jax
+
+    _topo("v5e:2x2")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _smoke_train(sizes, fsdp):
+    import sys
+    sys.path.insert(0, ".")
+    import chip_smoke
+    from scripts.aot_validate_8b import train_step_analysis
+
+    conf = chip_smoke.train_job("t", sizes, seed=0, chips=fsdp, fsdp=fsdp)[
+        "spec"]["replica_specs"]["worker"]["template"]["config"]
+    over = dict(conf["model_overrides"])
+    return train_step_analysis(
+        "v5e:2x2", {"fsdp": fsdp}, model=conf["model"],
+        per_chip_batch=sizes["global_batch"] // fsdp,
+        seq_len=over.pop("max_seq_len"), model_overrides=over,
+        optimizer=conf["optimizer"])
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("sizes,fsdp,fused", [
+    ("TRAIN_ONE", 1, True), ("TRAIN_PAIR", 1, True),
+    ("TRAIN_FOUR", 4, False), ("TRAIN_PAIR", 4, False)])
+def test_smoke_train_step_compiles_for_v5e(as_tpu, sizes, fsdp, fused):
+    import chip_smoke
+
+    out = _smoke_train(getattr(chip_smoke, sizes), fsdp)
+    assert out["total_gb"] < 15.75, out          # one v5e chip's usable HBM
+    # One device: every fused layer + flash. Under the mesh: flash alone
+    # (through shard_map); the fused layers give way to XLA.
+    want = chip_smoke.FLASH + (chip_smoke.FUSED_TRAIN if fused else ())
+    assert set(out["kernels"]) == set(want), out["kernels"]
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("tp", [1, 4])
+def test_smoke_serving_programs_compile_for_v5e(as_tpu, tp):
+    import sys
+    sys.path.insert(0, ".")
+    import chip_smoke
+    from scripts.aot_validate_8b import paged_serve_analysis
+
+    b = chip_smoke.BATCHING
+    out = paged_serve_analysis(
+        "v5e:2x2", tp, model=chip_smoke.MODEL,
+        overrides=chip_smoke.SERVE_OVERRIDES, slots=b["max_batch_size"],
+        max_len=b["max_seq_len"], page_size=b["page_size"],
+        num_pages=b["max_pages"], chunk=b["chunked_prefill_tokens"],
+        decode_steps=b["decode_steps"],
+        attn_impl="pallas" if tp == 1 else "gather")
+    for prog in out.values():
+        assert prog["total_gb"] < 15.75, out
+    if tp == 1:
+        assert "paged_decode_attention" in out["decode"]["kernels"], out
+        for prog in out.values():
+            assert set(chip_smoke.FUSED_SERVE) <= set(prog["kernels"]), out
+    else:
+        # A Mosaic kernel over GSPMD-sharded operands is refused by the
+        # compiler; the TP engine must hand it none.
+        assert not out["decode"]["kernels"], out
+        assert not out["chunk_prefill"]["kernels"], out

@@ -1,0 +1,176 @@
+"""The main path's Pallas kernels compile for the chip, at real widths.
+
+Interpret mode (every other kernel test) cannot see what the TPU's
+compiler refuses: a tile not aligned to the lane/sublane grid, a kernel
+that wants more fast memory than its scoped limit, a block that does not
+divide. libtpu compiles for a chip that is DESCRIBED, not attached
+(``jax.experimental.topologies``), so these cases cost no chip time and
+guard every later PR. Each compiles ONE kernel (forward, or forward +
+backward through ``jax.grad``) for one device of a ``v5e:2x2`` and
+asserts the Mosaic custom call is in the compiled program — a kernel that
+quietly fell back to XLA or to the interpreter fails here.
+
+Two width sets: Gemma-2B's published shapes (8 query heads / 1 KV head x
+256, hidden 2048, MLP 16384, vocab 256128 — what ``chip_smoke.py`` runs)
+and Llama-3-8B's (32 / 8 heads x 128, hidden 4096, MLP 14336, vocab
+128256). Rows are the smoke's train step: batch 2 x sequence 2048.
+
+Nothing here runs on a device: a compile that passes is not a chip run.
+The whole-step compiles live in tests/test_aot_8b.py under ``slow``.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # or libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+ROWS = 2 * 2048
+WIDTHS = {
+    # name: (q heads, kv heads, head dim, hidden, mlp, vocab, act, plus_one)
+    "gemma-2b": (8, 1, 256, 2048, 16384, 256128, "gelu", True),
+    "llama3-8b": (32, 8, 128, 4096, 14336, 128256, "silu", False),
+}
+# The serving smoke's pool: 32 slots x 4096 tokens, 1024 pages of 128.
+SLOTS, PAGES, PAGE, MPP = 32, 1024, 128, 32
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e device; skip only where libtpu is truly absent
+    (the loud-fail rule of tests/test_aot_8b.py). The persistent compile
+    cache is off around the module: an entry written for a described
+    chip cannot be read back without one, and every later compile would
+    warn about it."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+    except Exception as exc:  # noqa: BLE001 — any failure is classified below
+        import importlib.util
+
+        if importlib.util.find_spec("libtpu") is not None:
+            pytest.fail(
+                f"libtpu is present but the AOT topology path broke: {exc}")
+        pytest.skip(f"no libtpu: TPU AOT topology unavailable: {exc}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _flash(w, grad):
+    from kubeflow_tpu.ops.flash_attention import flash_attention
+
+    h, kh, d = w[:3]
+    shapes = [((2, 2048, h, d), jnp.bfloat16),
+              ((2, 2048, kh, d), jnp.bfloat16),
+              ((2, 2048, kh, d), jnp.bfloat16)]
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    if not grad:
+        return fwd, shapes
+    return jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+                    argnums=(0, 1, 2)), shapes
+
+
+def _xent(w, grad, head_dtype=jnp.bfloat16):
+    from kubeflow_tpu.ops.fused_xent import fused_cross_entropy
+
+    hidden, vocab = w[3], w[5]
+    shapes = [((ROWS, hidden), jnp.bfloat16), ((hidden, vocab), head_dtype),
+              ((ROWS,), jnp.int32)]
+
+    def fwd(h, head, t):
+        return fused_cross_entropy(h, head, t, interpret=False)[0].sum()
+
+    return (jax.grad(fwd, argnums=(0, 1)) if grad else fwd), shapes
+
+
+def _norm(w, grad, add):
+    from kubeflow_tpu.ops import fused_norm
+
+    hidden, plus_one = w[3], w[7]
+    x = ((ROWS, hidden), jnp.bfloat16)
+    wt = ((hidden,), jnp.float32)
+    if add:
+        def fwd(x, r, wt):
+            y, h = fused_norm.add_rmsnorm_fused(
+                x, r, wt, eps=1e-6, plus_one=plus_one, interpret=False)
+            return (y.astype(jnp.float32) + h.astype(jnp.float32)).sum()
+        shapes, argnums = [x, x, wt], (0, 1, 2)
+    else:
+        def fwd(x, wt):
+            return fused_norm.rmsnorm_fused(
+                x, wt, eps=1e-6, plus_one=plus_one,
+                interpret=False).astype(jnp.float32).sum()
+        shapes, argnums = [x, wt], (0, 1)
+    return (jax.grad(fwd, argnums=argnums) if grad else fwd), shapes
+
+
+def _glu(w, grad):
+    from kubeflow_tpu.ops.fused_norm import swiglu_fused
+
+    mlp, act = w[4], w[6]
+    shapes = [((ROWS, mlp), jnp.bfloat16)] * 2
+
+    def fwd(g, u):
+        return swiglu_fused(g, u, act=act,
+                            interpret=False).astype(jnp.float32).sum()
+
+    return (jax.grad(fwd, argnums=(0, 1)) if grad else fwd), shapes
+
+
+def _paged(w, int8):
+    from kubeflow_tpu.ops.paged_attention import paged_decode_attention
+
+    h, kh, d = w[:3]
+    pool = ((PAGES, PAGE, kh, d), jnp.int8 if int8 else jnp.bfloat16)
+    shapes = [((SLOTS, 1, h, d), jnp.bfloat16), pool, pool,
+              ((SLOTS, MPP), jnp.int32), ((SLOTS,), jnp.int32)]
+    if not int8:
+        return (lambda q, k, v, t, ln: paged_decode_attention(
+            q, k, v, t, ln, interpret=False)), shapes
+    scale = ((PAGES, PAGE, kh), jnp.float32)
+    return (lambda q, k, v, t, ln, ks, vs: paged_decode_attention(
+        q, k, v, t, ln, pool_ks=ks, pool_vs=vs,
+        interpret=False)), shapes + [scale, scale]
+
+
+KERNELS = {
+    "flash_fwd": lambda w: _flash(w, False),
+    "flash_bwd": lambda w: _flash(w, True),
+    "xent_fwd": lambda w: _xent(w, False),
+    "xent_bwd": lambda w: _xent(w, True),
+    "rmsnorm_fwd": lambda w: _norm(w, False, False),
+    "rmsnorm_bwd": lambda w: _norm(w, True, False),
+    "add_rmsnorm_fwd": lambda w: _norm(w, False, True),
+    "add_rmsnorm_bwd": lambda w: _norm(w, True, True),
+    "glu_fwd": lambda w: _glu(w, False),
+    "glu_bwd": lambda w: _glu(w, True),
+    "paged_decode_bf16": lambda w: _paged(w, False),
+    "paged_decode_int8": lambda w: _paged(w, True),
+    # The case the compiler refused before fused_xent._blocks counted fast
+    # memory: a float32 head (the trainer casts its head to bf16; a caller
+    # that does not must still get a kernel that fits). The d_head
+    # backward's [D, bv] tiles at 4 bytes wanted 17 MB of a 16 MB scoped
+    # limit at Gemma-2B's vocab.
+    "xent_bwd_f32_head": lambda w: _xent(w, True, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+@pytest.mark.parametrize("kernel", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(chip, kernel, widths):
+    fn, shapes = KERNELS[kernel](WIDTHS[widths])
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -117,6 +117,54 @@ class TestFusedXent:
         assert _maxdiff(nll, rn) <= F32_TOL
 
 
+class TestFastMemoryFit:
+    """Tile choice when compiling for the chip (interpret=False) counts the
+    kernels' fast memory; the compile that proves the counted sizes fit is
+    tests/test_chip_compile.py. Pure arithmetic here — nothing compiles."""
+
+    ROWS, D, V = 4096, 2048, 256128            # the smoke's train step
+
+    def test_bf16_trainer_path_keeps_its_tile(self):
+        assert fused_xent._blocks(self.ROWS, self.D, self.V, 2, 2,
+                                  None, None, False) == (256, 384)
+        assert fused_xent.supported(self.ROWS, self.D, self.V, False)
+
+    def test_f32_head_shrinks_the_tile_instead_of_being_refused(self):
+        """The case the compiler refused: [D, bv] tiles of a float32 head
+        in the d_head backward, 17 MB against a 16 MB scoped limit."""
+        over = fused_xent._vmem_bytes(256, 384, self.D, 2, 4)
+        assert over > fused_xent.VMEM_BUDGET_BYTES
+        br, bv = fused_xent._blocks(self.ROWS, self.D, self.V, 2, 4,
+                                    None, None, False)
+        assert (br, bv) == (256, 128)
+        assert fused_xent._vmem_bytes(br, bv, self.D, 2, 4) \
+            <= fused_xent.VMEM_BUDGET_BYTES
+        assert fused_xent.supported(self.ROWS, self.D, self.V, False,
+                                    dtype=jnp.bfloat16,
+                                    head_dtype=jnp.float32)
+
+    def test_interpreter_keeps_the_default_tile(self):
+        # The CPU tests' tiles never depend on the chip's fast memory.
+        assert fused_xent._blocks(self.ROWS, self.D, self.V, 4, 4,
+                                  None, None, True) == (256, 384)
+
+    def test_supported_refuses_what_no_tile_can_hold(self):
+        # hidden 131072 in float32: even the smallest aligned tile is over.
+        assert fused_xent._fit_vmem(64, 131072, 1024, 4, 4) is None
+        assert not fused_xent.supported(64, 131072, 1024, False,
+                                        dtype=jnp.float32)
+        assert fused_xent.supported(64, 131072, 1024, True)    # interpreter
+
+    @pytest.mark.parametrize("d,tiles,want", [
+        (2048, 4, 256),     # Gemma-2B add-RMSNorm: unchanged
+        (4096, 3, 256),     # Llama-3-8B RMSNorm: unchanged
+        (4096, 4, 128),     # Llama-3-8B add-RMSNorm: 17.9 MB at 256 rows
+    ])
+    def test_norm_row_block(self, d, tiles, want):
+        assert fused_norm._norm_blocks(4096, d, 2, tiles, None, False) == want
+        assert fused_norm._norm_blocks(4096, d, 2, tiles, None, True) == 256
+
+
 # -- fused norm / swiglu kernels -----------------------------------------------
 
 def _ref_rmsnorm(x, w, plus_one=False, eps=1e-5):
@@ -287,7 +335,7 @@ class TestLossMemoryFootprint:
 
     @staticmethod
     def _avals(closed):
-        core = jax.core
+        import jax.extend.core as core
         seen = []
 
         def walk(jaxpr):
@@ -476,6 +524,10 @@ class TestDeviceBatchStager:
 # -- XLA perf flag merging -----------------------------------------------------
 
 class TestXlaPerfFlags:
+    """The TPU-only flags go where the installed stack accepts them:
+    LIBTPU_INIT_ARGS. jaxlib parses XLA_FLAGS itself and aborts the process
+    on a flag it does not know, so that variable is never touched."""
+
     def test_merges_without_overriding(self):
         from kubeflow_tpu.runtime.xla_flags import PERF_FLAGS, xla_perf_flags
 
@@ -493,12 +545,18 @@ class TestXlaPerfFlags:
         assert xla_perf_flags("--a=b", "0") == "--a=b"
         assert xla_perf_flags("--a=b", "--custom=1") == "--a=b --custom=1"
 
-    def test_apply_idempotent(self, monkeypatch):
+    def test_apply_idempotent_and_leaves_xla_flags_alone(self, monkeypatch):
+        import os
+
         from kubeflow_tpu.runtime import xla_flags
 
-        monkeypatch.setenv("XLA_FLAGS", "")
+        monkeypatch.setenv("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+        monkeypatch.setenv("LIBTPU_INIT_ARGS", "")
         monkeypatch.delenv(xla_flags.ESCAPE_ENV, raising=False)
         assert xla_flags.apply_xla_perf_flags() is True
-        first = __import__("os").environ["XLA_FLAGS"]
+        first = os.environ["LIBTPU_INIT_ARGS"]
+        assert all(name in first for name in xla_flags.PERF_FLAGS)
         assert xla_flags.apply_xla_perf_flags() is False
-        assert __import__("os").environ["XLA_FLAGS"] == first
+        assert os.environ["LIBTPU_INIT_ARGS"] == first
+        assert os.environ["XLA_FLAGS"] == \
+            "--xla_force_host_platform_device_count=8"

@@ -10,12 +10,6 @@ import pytest
 pytest.importorskip(
     "jax.experimental.pallas",
     reason="Pallas unavailable: the MoE dispatch path's kernels need it")
-from kubeflow_tpu.compat import HAS_SHARD_MAP, SHARD_MAP_NATIVE  # noqa: E402
-
-if not HAS_SHARD_MAP:
-    pytest.skip("this jax has no shard_map (native or experimental)",
-                allow_module_level=True)
-
 from kubeflow_tpu.models import layers as L
 from kubeflow_tpu.models.config import preset
 from kubeflow_tpu.models.decoder import (
@@ -114,10 +108,6 @@ def test_decoder_loss_trains_with_dispatch():
     assert np.isfinite(gn) and gn > 0
 
 
-@pytest.mark.skipif(
-    not SHARD_MAP_NATIVE,
-    reason="experimental shard_map fallback shifts the dispatch psum's "
-           "reduction order beyond the exact-equivalence tolerance")
 def test_dispatch_sharded_matches_unsharded():
     """dp×ep mesh: the expert dim of the dispatch buffers shards over the
     expert axis; sharded == unsharded."""

@@ -14,12 +14,6 @@ from jax.sharding import Mesh
 pytest.importorskip(
     "jax.experimental.pallas",
     reason="Pallas unavailable: flash/ring kernels need it")
-from kubeflow_tpu.compat import HAS_SHARD_MAP  # noqa: E402
-
-if not HAS_SHARD_MAP:
-    pytest.skip("this jax has no shard_map (native or experimental)",
-                allow_module_level=True)
-
 from kubeflow_tpu.ops.attention import multi_head_attention
 from kubeflow_tpu.ops.flash_attention import flash_attention
 
@@ -688,3 +682,19 @@ class TestShardedFlashTraining:
         loss, _ = jax.jit(lambda p: decoder_loss(
             p, tokens, cfg, mesh=mesh, attn_impl="pallas"))(params)
         assert np.isfinite(float(loss))
+
+
+def test_live_axis_size_under_shard_map():
+    """``jax.lax.axis_size`` (what ring attention sizes its ring with)
+    resolves to the real mesh axis size under an actual shard_map."""
+    import numpy as np
+    from jax.sharding import PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+
+    def body(x):
+        return x * jax.lax.axis_size("data")
+
+    out = jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                        out_specs=P("data"))(jnp.ones(4, jnp.int32))
+    assert list(jax.device_get(out)) == [2, 2, 2, 2]

@@ -6,6 +6,7 @@ import urllib.request
 
 import pytest
 import jax
+import jax.numpy as jnp
 
 from kubeflow_tpu.core.serving import BatchingSpec
 from kubeflow_tpu.models.config import preset
@@ -73,6 +74,52 @@ def test_openai_completions(server):
     assert out["object"] == "text_completion"
     assert out["usage"]["completion_tokens"] <= 5
     assert out["choices"][0]["finish_reason"] in ("length", "stop")
+    # The ids the engine emitted ride beside the text: a client with its
+    # own tokenizer decodes them (the byte tokenizer drops ids > 258).
+    ids = out["choices"][0]["token_ids"]
+    assert len(ids) == out["usage"]["completion_tokens"]
+    assert all(isinstance(t, int) and 0 <= t < 512 for t in ids)
+
+
+def test_introspected_dispatch_records_each_variant_once(server,
+                                                         monkeypatch):
+    """The wrapper the engine puts around its jitted programs on the TPU
+    (LLMEngine._introspected): one lowering per (token block shape, static
+    arguments) variant, the program's own result passed through."""
+    from kubeflow_tpu.runtime import device_report
+
+    lowerings = []
+    real = device_report.lowered_kernel_calls
+    monkeypatch.setattr(
+        device_report, "lowered_kernel_calls",
+        lambda fn, *a: lowerings.append(a[3]) or real(fn, *a))
+    eng = server.engine
+    toy = eng._introspected("toy", jax.jit(
+        lambda p, c, t, n: t * n + p + c, static_argnums=(3,)))
+    one, block = jnp.float32(1.0), jnp.ones((1, 8))
+    try:
+        for steps in (4, 4, 2):
+            out = toy(one, one, block, steps)
+            assert float(out[0, 0]) == steps + 2
+        assert lowerings == [4, 2]
+        assert eng.program_kernels["toy[1x8,4]"] == {}      # CPU: no kernel
+        assert set(eng.program_kernels) == {"toy[1x8,4]", "toy[1x8,2]"}
+    finally:
+        eng.program_kernels.clear()
+
+
+def test_debug_device_reports_what_the_replica_runs_on(server):
+    """GET /debug/device: the parent of a tpu replica must not touch the
+    chip, so the replica says what it runs on. On the CPU: the cpu device,
+    no memory counters, no compile cache, and no Pallas kernel in any
+    program (interpret mode leaves no custom call)."""
+    status, body = _get(server.url + "/debug/device")
+    rep = json.loads(body)
+    assert status == 200 and rep["platform"] == "cpu"
+    assert rep["device_count"] == len(jax.devices())
+    assert rep["memory"][0]["peak_bytes_in_use"] is None
+    assert rep["compile_cache"] is None
+    assert rep["programs"] == {"demo": {}}
 
 
 def test_openai_chat_completions(server):

@@ -16,12 +16,6 @@ import pytest
 pytest.importorskip(
     "jax.experimental.pallas",
     reason="Pallas unavailable: the sharded prefill path's kernels need it")
-from kubeflow_tpu.compat import HAS_SHARD_MAP  # noqa: E402
-
-if not HAS_SHARD_MAP:
-    pytest.skip("this jax has no shard_map (native or experimental)",
-                allow_module_level=True)
-
 from kubeflow_tpu.core.serving import BatchingSpec
 from kubeflow_tpu.models.config import preset
 from kubeflow_tpu.models.decoder import init_decoder_params

@@ -135,6 +135,10 @@ def test_text_training_resumes_mid_epoch(tmp_path):
 
     tr1 = make(steps=5)
     tr1.run()
+    # A finished run says what it ran on (runtime/device_report.py).
+    from kubeflow_tpu.runtime.device_report import read_device_report
+
+    assert read_device_report(str(tmp_path / "job"))["platform"] == "cpu"
 
     # Resume: picks up the step-5 checkpoint mid-epoch and continues.
     tr2 = make(steps=10)
