@@ -1,0 +1,292 @@
+"""A serving cell: the program's engine behind its real HTTP server on a
+loopback port, driven by the benchmark's own load generator in a child
+process. From the program it takes ``LLMEngine``, ``ModelServer``,
+``BatchingSpec``, the decoder preset and the engine's counters; everything
+that measures is the benchmark's.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import subprocess
+import sys
+import time
+
+from benchmark import correctness, flops, tracing
+from benchmark.device import (
+    CompileCounter, memory_peak_bytes, sleep_until,
+)
+from benchmark.stats import percentile
+from benchmark.traffic import build_plan, decode_ids, n_chunks
+from benchmark.weights import make_params
+
+
+class RunFailed(Exception):
+    """The run cannot report: it prints no result and exits non-zero."""
+
+
+class IdTokenizer:
+    """Text <-> token ids with no vocabulary in between: "17 4093 2" is the
+    three ids 17, 4093 and 2, so prompts reach the model over HTTP as ids
+    over its WHOLE vocabulary (the bundled byte tokenizer only ever embeds
+    259 of them). No id is an end-of-sequence: a request generates exactly
+    ``max_tokens`` tokens, which fixes the work per request."""
+
+    bos_id = 1
+    eos_id = -1
+
+    def __init__(self, vocab_size: int):
+        self.vocab_size = vocab_size
+
+    def encode(self, text: str) -> list[int]:
+        return decode_ids(text)
+
+    def decode(self, ids: list[int]) -> str:
+        return "".join(f"{int(t)} " for t in ids)
+
+
+def decoder_config(conf: dict):
+    """The program's ``DecoderConfig`` from the configuration file: the
+    preset it starts from plus every override, then held against the
+    published sizes in the same file, so the two cannot drift apart."""
+    from kubeflow_tpu.models.config import preset
+
+    prog = conf["program"]
+    cfg = preset(prog["preset"], **prog["overrides"])
+    same = {"hidden_size": cfg.hidden, "num_attention_heads": cfg.n_heads,
+            "num_key_value_heads": cfg.n_kv_heads, "head_dim": cfg.head_dim,
+            "intermediate_size": cfg.mlp_dim, "vocab_size": cfg.vocab_size,
+            "num_hidden_layers": cfg.n_layers, "rope_theta": cfg.rope_theta,
+            "rms_norm_eps": cfg.norm_eps,
+            "num_local_experts": cfg.num_experts,
+            "tie_word_embeddings": cfg.tie_embeddings}
+    if cfg.num_experts:
+        same["num_experts_per_tok"] = cfg.experts_per_token
+    for key, value in same.items():
+        if conf.get(key, 0 if key == "num_local_experts" else None) != value:
+            raise RunFailed(
+                f"{key}: the configuration file says {conf.get(key)!r}, the "
+                f"program's config built from it has {value!r}")
+    return cfg
+
+
+def engine_snapshot(engine) -> dict:
+    """Running sums and counts of the engine's own counters: they exist
+    whatever the traffic did (the conditional keys of
+    ``EngineMetrics.snapshot`` do not: PR 23)."""
+    m = engine.metrics
+    _, _, qd_sum, qd_n = m.queue_delay_histogram()
+    _, _, hg_sum, hg_n = m.host_gap_histogram()
+    return {"queue_delay_sum_s": qd_sum, "queue_delay_n": qd_n,
+            "host_gap_sum_s": hg_sum, "host_gap_n": hg_n,
+            "preemptions": m.preemptions, "shed": m.requests_shed,
+            "completed": m.requests_completed,
+            "decode_rounds": engine.decode_rounds}
+
+
+def required_programs(traffic: dict, batching) -> set[str]:
+    """Program variants (as ``LLMEngine.program_kernels`` names them) the
+    cell's traffic can reach: a chunk prefill per context bucket up to the
+    longest prompt plus the longest answer (a preempted request prefills
+    again with what it had generated), the decode dispatch at both its
+    lengths."""
+    from kubeflow_tpu.serve.paged import context_bucket
+
+    chunk, pg = batching.chunked_prefill_tokens, batching.page_size
+    mpp = batching.max_seq_len // pg
+    longest = sum(traffic[k].get("max", traffic[k].get("value"))
+                  for k in ("prompt_len", "output_len"))
+    # Any start position, not only multiples of the chunk: the radix prefix
+    # index resumes a prefill wherever an earlier prompt stopped matching,
+    # and with a 32k vocabulary a first-token match happens a few times a
+    # run.
+    need = {f"paged_chunk_prefill[1x{chunk},"
+            f"{context_bucket(pos, chunk, pg, mpp)}]"
+            for pos in range(int(longest))}
+    steps = {batching.decode_steps,
+             min(batching.decode_steps, batching.prefill_interleave_steps)}
+    return need | {f"paged_decode[{k},greedy]" for k in steps}
+
+
+def warm_first_token_sampler(engine, vocab: int) -> None:
+    """The engine samples the first tokens of every prefill that finished in
+    one admit pass together, padded to a power of two: as many shapes as
+    powers of two up to the slots, and which of them a run meets depends on
+    how arrivals fall. Traffic cannot be made to reach each one, so they are
+    warmed here, through the engine's own sampler and the same calls
+    ``LLMEngine._sample_first_batch`` makes. Before the engine starts."""
+    import jax.numpy as jnp
+
+    width = 1
+    while width <= engine.num_slots:
+        rows = [jnp.zeros((vocab,), jnp.float32)] * width
+        out = engine._sampler(
+            jnp.stack(rows), engine._next_key(),
+            jnp.asarray([0.0] * width, jnp.float32),
+            jnp.asarray([0] * width, jnp.int32),
+            jnp.asarray([1.0] * width, jnp.float32), "greedy")
+        out.block_until_ready()
+        width *= 2
+
+
+def reduce_requests(results: list[dict], seconds: float) -> dict:
+    """From the generator's stamps to what the cell reports. A request
+    failed if it errored, timed out, returned another number of tokens than
+    asked, or an id outside the vocabulary. A closed loop's request that
+    the end of the window cut was neither completed nor failed; the tokens
+    it was given inside the window are work the window did."""
+    done, failed, cut = [], [], 0
+    for r in results:
+        if r.get("cut"):
+            cut += 1
+        elif (r["ok"] and r["n_tokens"] == r["max_tokens"]
+              and r["ids_in_vocab"]):
+            done.append(r)
+        else:
+            failed.append(r)
+    in_window = [r for r in done if r["end"] <= seconds]
+    ttft = [(r["token_t"][0] - r["due"]) * 1e3 for r in done]
+    gaps = [(b - a) * 1e3 for r in done
+            for a, b in zip(r["token_t"], r["token_t"][1:])]
+    late = [(r["sent"] - r["due"]) * 1e3 for r in results]
+    # Work the window saw, token by token: a prompt counts when its first
+    # token arrives (its prefill is then done), a generated token when it
+    # arrives. A request the window's end cut has done part of its work
+    # inside the window, and that part counts; what was still being
+    # prefilled then does not.
+    served = done + [r for r in results if r.get("cut")]
+    prefilled = [r["prompt_len"] for r in served
+                 if r["token_t"] and r["token_t"][0] <= seconds]
+    generated = sum(1 for r in served for t in r["token_t"] if t <= seconds)
+    return {
+        "attempted": len(done) + len(failed), "failed": len(failed),
+        "cut": cut, "completed": len(done),
+        "completed_in_window": len(in_window),
+        "errors": sorted({str(r.get("error")) for r in failed})[:5],
+        "ttft_ms": ttft, "itl_ms": gaps, "late_ms": late,
+        "prompt_lens_in_window": prefilled,
+        "tokens_in_window": sum(prefilled) + generated,
+    }
+
+
+def run(manifest: dict, cell: dict, conf: dict, traffic: dict, *, seed: int,
+        seconds: float, trace: bool, dev: dict, t_start: float,
+        out_dir: str, log) -> dict:
+    import jax
+
+    from kubeflow_tpu.core.serving import BatchingSpec
+    from kubeflow_tpu.serve.engine import LLMEngine
+    from kubeflow_tpu.serve.server import ModelServer
+
+    compiles = CompileCounter()
+    cfg = decoder_config(conf)
+    batching = BatchingSpec(**traffic["engine"])
+    params = make_params(conf, seed, cfg.param_dtype)
+    engine = LLMEngine(cfg, batching, params=params, seed=seed & 0x7FFFFFFF)
+    log(f"engine built at {time.monotonic() - t_start:.1f}s: "
+        f"{flops.params_total(conf) / 1e9:.2f} B parameters, "
+        f"{engine._num_pages} pages of {engine.page_size}")
+
+    numbers = correctness.serving_numbers(engine, params, conf,
+                                          conf["correctness"], seed)
+    correct, lines = correctness.judge(numbers, conf["correctness"]["limits"])
+    for line in lines:
+        log(line)
+    log(f"compared beside: {json.dumps(numbers)}")
+    log(f"correctness done at {time.monotonic() - t_start:.1f}s")
+
+    warm_first_token_sampler(engine, conf["vocab_size"])
+    os.makedirs(out_dir, exist_ok=True)
+    plan = build_plan(traffic, seed=seed, seconds=seconds,
+                      vocab=conf["vocab_size"], model=cell["config"])
+    plan_path = os.path.join(out_dir, "plan.json")
+    results_path = os.path.join(out_dir, "loadgen.json")
+    with open(plan_path, "w") as f:
+        json.dump(plan, f)
+    server = ModelServer(cell["config"], engine,
+                         tokenizer=IdTokenizer(conf["vocab_size"]))
+    server.start()
+    child = subprocess.Popen(
+        [sys.executable, "-m", "benchmark.loadgen", plan_path, results_path,
+         server.url],
+        stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    traced = None
+    try:
+        if child.stdout.readline().strip() != "READY":
+            raise RunFailed("the load generator died during warm-up")
+        if engine.program_kernels:       # recorded on the TPU only
+            missing = required_programs(traffic, batching) \
+                - set(engine.program_kernels)
+            if missing:
+                raise RunFailed(
+                    f"warm-up did not reach {sorted(missing)}; it reached "
+                    f"{sorted(engine.program_kernels)}")
+        before = engine_snapshot(engine)
+        compiles.start()
+        child.stdin.write("GO\n")
+        child.stdin.flush()
+        t0 = time.monotonic()
+        setup_s = t0 - t_start
+        log(f"window opens, setup_s {setup_s:.3f}")
+        if trace:
+            t_on = t0 + float(traffic.get("trace_start_s", 0.4 * seconds))
+            sleep_until(t_on)
+            traced = tracing.record(
+                os.path.join(out_dir, "trace"),
+                float(traffic.get("trace_seconds", 3.0)))
+        sleep_until(t0 + seconds)
+        after = engine_snapshot(engine)
+        n_compiles = compiles.stop()
+        if child.stdout.readline().strip() != "DONE":
+            raise RunFailed("the load generator died in the window")
+        child.wait(timeout=30)
+    finally:
+        if child.poll() is None:
+            child.kill()
+            child.wait()
+        server.stop()
+    with open(results_path) as f:
+        gen = json.load(f)
+    if gen["warmup_errors"]:
+        raise RunFailed(f"warm-up failed: {gen['warmup_errors']}")
+    if n_compiles:
+        raise RunFailed(f"{n_compiles} program(s) compiled inside the "
+                        f"window: {compiles.names}")
+    red = reduce_requests(gen["results"], seconds)
+    log(f"requests: attempted {red['attempted']} failed {red['failed']} "
+        f"cut {red['cut']} completed in window {red['completed_in_window']} "
+        f"errors {red['errors']}")
+    if not red["completed"]:
+        raise RunFailed("no request completed")
+
+    values = {"setup_s": setup_s}
+    if traffic["kind"] == "open_loop":
+        values["itl_p95_ms"] = percentile(red["itl_ms"], 95)
+        log(f"ttft ms p50 {percentile(red['ttft_ms'], 50):.1f} "
+            f"p95 {percentile(red['ttft_ms'], 95):.1f} "
+            f"n {len(red['ttft_ms'])}; "
+            f"itl ms p50 {percentile(red['itl_ms'], 50):.2f} "
+            f"p95 {values['itl_p95_ms']:.1f} n {len(red['itl_ms'])}")
+    else:
+        values["serve_tokens_per_s"] = red["tokens_in_window"] / seconds
+        log(f"serve_tokens_per_s {values['serve_tokens_per_s']:.1f}")
+    chunk = batching.chunked_prefill_tokens
+    lens = red["prompt_lens_in_window"] or [r["prompt_len"]
+                                            for r in plan["requests"]]
+    run_record = {
+        "kind": traffic["kind"], "window_s": seconds, "config": conf,
+        "engine_before": before, "engine_after": after,
+        "loadgen": red, "trace": traced, "peaks": dev["peaks"],
+        "weight_bytes_per_param": jax.numpy.dtype(cfg.param_dtype).itemsize,
+        "prefill": {
+            "chunk": chunk, "mean_useful_flops_per_chunk":
+                sum(flops.prefill_flops(conf, n) for n in lens)
+                / sum(n_chunks(n, chunk) for n in lens)},
+        "values": values,
+    }
+    return {"correct": correct, "attempted": red["attempted"],
+            "failed": red["failed"], "values": values, "record": run_record,
+            "memory_peak_bytes": memory_peak_bytes(jax.local_devices()),
+            "traced": traced}
