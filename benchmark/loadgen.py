@@ -1,12 +1,18 @@
 """The load generator: a process of its own that never imports JAX (the chip
 belongs to the parent, and so does the parent's interpreter lock).
 
-    python3 -m benchmark.loadgen <plan.json> <results.json>
+    python3 -m benchmark.loadgen <plan.json> <results.json> <url> \
+        [<tail_plan.json> <tail_results.json>]
 
 Protocol on the standard streams: it sends the plan's warm-up stages, prints
 ``READY``, waits for a line on stdin (``GO``), takes t0 on the system-wide
 monotonic clock, runs the measured phase, writes the results and prints
-``DONE``. Every time it records is seconds after t0.
+``DONE``. Every time it records is seconds after t0. Given a tail plan
+(``--trace 2``: the traffic that is traced once the window has closed) it
+then waits for one more line (``TAIL``), takes a new t0, runs the tail plan
+until its ``seconds`` are up, cuts what is in flight then, writes the tail's
+results and prints ``TAILDONE``; without one it ends after ``DONE`` as it
+always did.
 
 It differs from ``kubeflow_tpu/loadgen/runner.py::ServerTarget`` where that
 one is unfit for a yardstick: a request is timed from the instant it was DUE,
@@ -124,20 +130,24 @@ def run_warmup(plan: dict, url) -> list[str]:
     return errors
 
 
-def run_open_loop(plan: dict, url, t0: float) -> list[dict]:
+def run_open_loop(plan: dict, url, t0: float, cut: bool = False
+                  ) -> list[dict]:
     """One thread per request, each asleep until its due instant: nothing
-    one request does can make the next one late except the interpreter."""
+    one request does can make the next one late except the interpreter.
+    ``cut`` (the tail): a request in flight when the plan's seconds are up
+    is cut there instead of drained."""
     reqs = plan["requests"]
     bodies = [_body(plan, r) for r in reqs]
     results: list = [None] * len(reqs)
-    give_up = plan["seconds"] + plan["drain_timeout_s"]
+    give_up = plan["seconds"] + (0.0 if cut else plan["drain_timeout_s"])
+    deadline = t0 + plan["seconds"] if cut else None
 
     def one(j):
         wait = t0 + reqs[j]["due_s"] - time.monotonic()
         if wait > 0:
             time.sleep(wait)
         results[j] = _finish(plan, reqs[j], send(
-            url, bodies[j], t0=t0, deadline=None,
+            url, bodies[j], t0=t0, deadline=deadline,
             timeout=max(1.0, give_up - reqs[j]["due_s"])))
 
     threads = [threading.Thread(target=one, args=(j,), daemon=True)
@@ -159,6 +169,7 @@ def run_closed_loop(plan: dict, url, t0: float) -> list[dict]:
     """``clients`` clients, each sending its next request when the last one
     ended, until the window ends; a request in flight then is cut."""
     reqs, n_clients = plan["requests"], plan["clients"]
+    base = plan.get("index_base", 0)    # the tail's indices start elsewhere
     deadline = t0 + plan["seconds"]
     results: list[dict] = []
     lock = threading.Lock()
@@ -169,7 +180,7 @@ def run_closed_loop(plan: dict, url, t0: float) -> list[dict]:
             # Sizes repeat when the pool is walked round; contents never do
             # (the tokens come from the request's index), so a second lap
             # is not served from the prefix cache.
-            req = dict(reqs[k % len(reqs)], i=k)
+            req = dict(reqs[k % len(reqs)], i=base + k)
             req["due_s"] = time.monotonic() - t0
             res = _finish(plan, req, send(
                 url, _body(plan, req), t0=t0, deadline=deadline,
@@ -188,22 +199,42 @@ def run_closed_loop(plan: dict, url, t0: float) -> list[dict]:
         return list(results)
 
 
-def main(argv) -> int:
-    plan_path, out_path, base_url = argv[1], argv[2], argv[3]
+def run_phase(plan_path: str, out_path: str, url, word: str, *,
+              tail: bool) -> bool:
+    """Load a plan, wait for ``word`` on stdin, run the plan and write its
+    results; False if another word (or none) came. The measured phase
+    warms up first and says ``READY``; the tail does neither, and cuts
+    what is in flight when its seconds are up."""
     with open(plan_path) as f:
         plan = json.load(f)
-    url = urlparse(base_url)
-    warm_errors = run_warmup(plan, url)
-    print("READY", flush=True)
-    if not sys.stdin.readline().startswith("GO"):
-        return 3
+    warm_errors = []
+    if not tail:
+        warm_errors = run_warmup(plan, url)
+        print("READY", flush=True)
+    if not sys.stdin.readline().startswith(word):
+        return False
     t0 = time.monotonic()
-    runner = run_open_loop if plan["kind"] == "open_loop" else run_closed_loop
-    results = runner(plan, url, t0) if not warm_errors else []
+    if warm_errors:
+        results = []
+    elif plan["kind"] == "open_loop":
+        results = run_open_loop(plan, url, t0, cut=tail)
+    else:
+        results = run_closed_loop(plan, url, t0)
     with open(out_path, "w") as f:
         json.dump({"t0": t0, "warmup_errors": warm_errors,
                    "results": results}, f)
+    return True
+
+
+def main(argv) -> int:
+    plan_path, out_path, base_url = argv[1], argv[2], argv[3]
+    url = urlparse(base_url)
+    if not run_phase(plan_path, out_path, url, "GO", tail=False):
+        return 3
     print("DONE", flush=True)
+    if len(argv) > 5 and run_phase(argv[4], argv[5], url, "TAIL",
+                                   tail=True):
+        print("TAILDONE", flush=True)
     return 0
 
 

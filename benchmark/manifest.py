@@ -104,7 +104,17 @@ def read_layer_metrics(manifest: dict, cell_name: str, run: dict) -> dict:
     return out
 
 
-def build_last_line(manifest: dict, cell_name: str, trace: bool, *,
+def declared_for_run(manifest: dict, cell_name: str, trace: int) -> dict:
+    """The metrics a run of this kind prints: ``--trace 0`` the end-to-end
+    ones, ``--trace 1`` the per-layer ones, ``--trace 2`` (measure first,
+    trace afterwards) both side by side."""
+    kinds = {0: ("end_to_end",), 1: ("per_layer",),
+             2: ("end_to_end", "per_layer")}[int(trace)]
+    return {name: m for kind in kinds
+            for name, m in declared(manifest, cell_name, kind).items()}
+
+
+def build_last_line(manifest: dict, cell_name: str, trace: int, *,
                     correct: bool, attempted: int, failed: int,
                     values: dict[str, float], device: dict,
                     breakdown: dict | None = None,
@@ -114,7 +124,7 @@ def build_last_line(manifest: dict, cell_name: str, trace: bool, *,
     cell and this kind of run, each a finite number with its unit.
     ``allow_missing`` exists for the CPU rehearsal alone, where a metric of
     the device has nothing to read; a chip run passes none."""
-    want = declared(manifest, cell_name, "per_layer" if trace else "end_to_end")
+    want = declared_for_run(manifest, cell_name, trace)
     metrics = {}
     for name, entry in want.items():
         if name not in values:

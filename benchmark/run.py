@@ -1,6 +1,6 @@
 """One process, one cell, one run.
 
-    python3 -m benchmark.run --workload <name> --seed <n> --seconds <s> --trace <0|1>
+    python3 -m benchmark.run --workload <name> --seed <n> --seconds <s> --trace <0|1|2>
 
 Builds the cell from its data files (BENCHMARK.json names the configuration's
 file; the traffic mix is ``benchmark/traffic/<mix>.json``), warms up the
@@ -8,8 +8,13 @@ cell's shapes, measures for ``--seconds``, compares outputs with the plain
 reference, and prints ONE JSON object as the last line of its standard
 output: ``correct``, ``attempted``, ``failed``, ``metrics`` and ``device``
 (and ``breakdown`` when traced). With ``--trace 0`` the metrics are the
-cell's end-to-end metrics, with ``--trace 1`` its per-layer metrics. That
-line is checked against BENCHMARK.json before it is printed; a run that
+cell's end-to-end metrics, with ``--trace 1`` its per-layer metrics (the
+profiler runs inside the measured window). ``--trace 2`` is ``--trace 0``
+with a tail: once the window has closed and its numbers are taken, a few
+seconds of the same traffic are traced, and the line holds both kinds of
+metric side by side, ``device.busy_s`` / ``window_s`` of the tail and a
+``breakdown`` whose idle gaps are named by the host's phase. That line is
+checked against BENCHMARK.json before it is printed; a run that
 cannot report it prints no result and exits non-zero. Everything else goes to
 standard error and to ``benchmark_out/<cell>/``.
 
@@ -38,21 +43,22 @@ def log(msg: str) -> None:
 
 
 def run_cell(manifest: dict, workload: str, *, seed: int, seconds: float,
-             trace: bool, allow_cpu: bool = False,
+             trace: int, allow_cpu: bool = False,
              t_start: float | None = None) -> dict:
     """Run one cell and return its checked last line. ``allow_cpu`` is the
     CPU rehearsal's: only a test passes it."""
     import importlib
 
-    from benchmark import device, tracing
+    from benchmark import device, hostspans, tracing
 
+    trace = int(trace)
     t_start = time.monotonic() if t_start is None else t_start
     cell = mf.cell(manifest, workload)
     conf = mf.load_config(manifest, cell["config"])
     traffic = mf.load_traffic(cell["traffic"])
     cache_dir = device.prepare_process(platform_is_tpu=not allow_cpu)
     dev = device.require_devices(cell["chips"], allow_cpu=allow_cpu)
-    log(f"{workload} seed {seed} seconds {seconds} trace {int(trace)} on "
+    log(f"{workload} seed {seed} seconds {seconds} trace {trace} on "
         f"{dev['count']} x {dev['kind']} ({dev['platform']}); compile cache "
         f"{cache_dir}")
     # A run leaves nothing for the next one to find (the trainer keeps a
@@ -74,18 +80,25 @@ def run_cell(manifest: dict, workload: str, *, seed: int, seconds: float,
     allow_missing: frozenset = frozenset()
     if trace:
         traced = res["traced"]
-        values = mf.read_layer_metrics(manifest, workload, res["record"])
+        layer = mf.read_layer_metrics(manifest, workload, res["record"])
+        values = {**values, **layer} if trace == 2 else layer
         if traced is not None and traced["devices"]:
             device_out["busy_s"] = tracing.busy_s(traced)
             device_out["window_s"] = tracing.traced_window_s(traced)
-            breakdown = {"device_ops": tracing.top_ops(traced),
-                         "idle_gaps": tracing.idle_gaps(traced)}
+            loop = hostspans.loop_thread(traced.get("host_spans"))
+            breakdown = {
+                "device_ops": tracing.top_ops(traced),
+                # Named by what the HOST was doing where the program's
+                # spans are in the trace behind the window; by the
+                # program the device waited for otherwise.
+                "idle_gaps": hostspans.idle_by_host_phase(traced, loop)
+                if trace == 2 and loop else tracing.idle_gaps(traced)}
         if dev["platform"] != "tpu":
             # The CPU rehearsal: a trace of the CPU holds no device plane,
             # and nothing may be printed under a device metric's name.
             allow_missing = frozenset(
                 set(mf.declared(manifest, workload, "per_layer"))
-                - set(values)) | {"busy_s", "window_s"}
+                - set(layer)) | {"busy_s", "window_s"}
     for name, value in sorted(values.items()):
         log(f"metric {name} = {value}")
     return mf.build_last_line(
@@ -100,11 +113,11 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), required=True)
     args = ap.parse_args(argv)
     try:
         line = run_cell(mf.load_manifest(), args.workload, seed=args.seed,
-                        seconds=args.seconds, trace=bool(args.trace),
+                        seconds=args.seconds, trace=args.trace,
                         t_start=t_start)
     except Exception as exc:           # boundary: report, print no result
         import traceback

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import json
 import os
+import shutil
 import subprocess
 import sys
 import time
@@ -83,6 +84,62 @@ def engine_snapshot(engine) -> dict:
             "preemptions": m.preemptions, "shed": m.requests_shed,
             "completed": m.requests_completed,
             "decode_rounds": engine.decode_rounds}
+
+
+def program_counters(**parts) -> dict | None:
+    """The program's own total snapshots (``LLMEngine.counters()``,
+    ``ModelServer.counters()``, ``Trainer.counters()``), whole, by part:
+    a reader works on the difference of two. None where the program has
+    no such snapshot."""
+    if not all(hasattr(obj, "counters") for obj in parts.values()):
+        return None
+    return {name: obj.counters() for name, obj in parts.items()}
+
+
+# The tail of a ``--trace 2`` run sends a second plan: another seed, and
+# request indices far from the measured plan's and the warm-up's, so that
+# no prompt of the tail shares a prefix with one the window cached.
+TAIL_SEED_XOR = 0x5A177A11
+TAIL_INDEX_BASE = 2 * 10**6
+
+
+def tail_plan(traffic: dict, *, seed: int, vocab: int, model: str) -> dict:
+    """The traffic that is traced once the window has closed: the same
+    distributions for ``trace_start_s`` of ramp and ``trace_seconds`` of
+    trace (and a second for the profiler to stop in)."""
+    seconds = float(traffic["trace_start_s"]) \
+        + float(traffic["trace_seconds"]) + 1.0
+    plan = build_plan(traffic, seed=int(seed) ^ TAIL_SEED_XOR,
+                      seconds=seconds, vocab=vocab, model=model)
+    plan["warmup"] = []
+    plan["index_base"] = TAIL_INDEX_BASE
+    for r in plan["requests"]:
+        r["i"] += TAIL_INDEX_BASE
+    return plan
+
+
+def trace_tail(child, traffic: dict, out_dir: str, log) -> dict:
+    """Behind the closed window: start and stop the profiler once for
+    nothing, set the tail's traffic going, trace ``trace_seconds`` of it
+    after ``trace_start_s`` of ramp. Returns the trace in plain form."""
+    t_warm = time.monotonic()
+    tracing.warm(os.path.join(out_dir, "trace_warm"))
+    child.stdin.write("TAIL\n")
+    child.stdin.flush()
+    t_tail = time.monotonic()
+    sleep_until(t_tail + float(traffic["trace_start_s"]))
+    t_on = time.monotonic()
+    traced = tracing.record(os.path.join(out_dir, "trace"),
+                            float(traffic["trace_seconds"]))
+    t_read = time.monotonic()
+    if child.stdout.readline().strip() != "TAILDONE":
+        raise RunFailed("the load generator died in the tail")
+    log(f"tail: profiler warm start {t_tail - t_warm:.3f}s, traced "
+        f"{traced['window_s']:.3f}s from {t_on - t_tail:.3f}s on, trace "
+        f"stopped and read in {t_read - t_on - traced['window_s']:.3f}s; "
+        f"{time.monotonic() - t_warm:.3f}s behind the closed window")
+    shutil.rmtree(os.path.join(out_dir, "trace"), ignore_errors=True)
+    return traced
 
 
 def required_programs(traffic: dict, batching) -> set[str]:
@@ -170,8 +227,37 @@ def reduce_requests(results: list[dict], seconds: float) -> dict:
     }
 
 
+def window_numbers(results_path: str, traffic: dict, seconds: float,
+                   setup_s: float, log) -> tuple[dict, dict]:
+    """The closed window's numbers from the generator's results: the
+    reduced requests and the cell's end-to-end values."""
+    with open(results_path) as f:
+        gen = json.load(f)
+    if gen["warmup_errors"]:
+        raise RunFailed(f"warm-up failed: {gen['warmup_errors']}")
+    red = reduce_requests(gen["results"], seconds)
+    log(f"requests: attempted {red['attempted']} failed {red['failed']} "
+        f"cut {red['cut']} completed in window {red['completed_in_window']} "
+        f"errors {red['errors']}")
+    if not red["completed"]:
+        raise RunFailed("no request completed")
+
+    values = {"setup_s": setup_s}
+    if traffic["kind"] == "open_loop":
+        values["itl_p95_ms"] = percentile(red["itl_ms"], 95)
+        log(f"ttft ms p50 {percentile(red['ttft_ms'], 50):.1f} "
+            f"p95 {percentile(red['ttft_ms'], 95):.1f} "
+            f"n {len(red['ttft_ms'])}; "
+            f"itl ms p50 {percentile(red['itl_ms'], 50):.2f} "
+            f"p95 {values['itl_p95_ms']:.1f} n {len(red['itl_ms'])}")
+    else:
+        values["serve_tokens_per_s"] = red["tokens_in_window"] / seconds
+        log(f"serve_tokens_per_s {values['serve_tokens_per_s']:.1f}")
+    return red, values
+
+
 def run(manifest: dict, cell: dict, conf: dict, traffic: dict, *, seed: int,
-        seconds: float, trace: bool, dev: dict, t_start: float,
+        seconds: float, trace: int, dev: dict, t_start: float,
         out_dir: str, log) -> dict:
     import jax
 
@@ -204,12 +290,19 @@ def run(manifest: dict, cell: dict, conf: dict, traffic: dict, *, seed: int,
     results_path = os.path.join(out_dir, "loadgen.json")
     with open(plan_path, "w") as f:
         json.dump(plan, f)
+    tail_args = []
+    if trace == 2:
+        tail_args = [os.path.join(out_dir, "tail_plan.json"),
+                     os.path.join(out_dir, "tail_loadgen.json")]
+        with open(tail_args[0], "w") as f:
+            json.dump(tail_plan(traffic, seed=seed, vocab=conf["vocab_size"],
+                                model=cell["config"]), f)
     server = ModelServer(cell["config"], engine,
                          tokenizer=IdTokenizer(conf["vocab_size"]))
     server.start()
     child = subprocess.Popen(
         [sys.executable, "-m", "benchmark.loadgen", plan_path, results_path,
-         server.url],
+         server.url] + tail_args,
         stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     traced = None
@@ -224,13 +317,14 @@ def run(manifest: dict, cell: dict, conf: dict, traffic: dict, *, seed: int,
                     f"warm-up did not reach {sorted(missing)}; it reached "
                     f"{sorted(engine.program_kernels)}")
         before = engine_snapshot(engine)
+        counters_before = program_counters(engine=engine, server=server)
         compiles.start()
         child.stdin.write("GO\n")
         child.stdin.flush()
         t0 = time.monotonic()
         setup_s = t0 - t_start
         log(f"window opens, setup_s {setup_s:.3f}")
-        if trace:
+        if trace == 1:
             t_on = t0 + float(traffic.get("trace_start_s", 0.4 * seconds))
             sleep_until(t_on)
             traced = tracing.record(
@@ -238,47 +332,40 @@ def run(manifest: dict, cell: dict, conf: dict, traffic: dict, *, seed: int,
                 float(traffic.get("trace_seconds", 3.0)))
         sleep_until(t0 + seconds)
         after = engine_snapshot(engine)
-        n_compiles = compiles.stop()
+        counters_after = program_counters(engine=engine, server=server)
+        if trace != 2:
+            compiles.stop()
         if child.stdout.readline().strip() != "DONE":
             raise RunFailed("the load generator died in the window")
+        # The window is closed: its numbers are taken here, before any
+        # tail starts.
+        red, values = window_numbers(results_path, traffic, seconds,
+                                     setup_s, log)
+        memory_peak = memory_peak_bytes(jax.local_devices())
+        if trace == 2:
+            # The compile counter stays on: a compile in the tail fails
+            # the run as one in the window does.
+            traced = trace_tail(child, traffic, out_dir, log)
+            compiles.stop()
         child.wait(timeout=30)
     finally:
         if child.poll() is None:
             child.kill()
             child.wait()
+        tracing.abort()
         server.stop()
-    with open(results_path) as f:
-        gen = json.load(f)
-    if gen["warmup_errors"]:
-        raise RunFailed(f"warm-up failed: {gen['warmup_errors']}")
-    if n_compiles:
-        raise RunFailed(f"{n_compiles} program(s) compiled inside the "
+    if compiles.count:
+        raise RunFailed(f"{compiles.count} program(s) compiled inside the "
                         f"window: {compiles.names}")
-    red = reduce_requests(gen["results"], seconds)
-    log(f"requests: attempted {red['attempted']} failed {red['failed']} "
-        f"cut {red['cut']} completed in window {red['completed_in_window']} "
-        f"errors {red['errors']}")
-    if not red["completed"]:
-        raise RunFailed("no request completed")
-
-    values = {"setup_s": setup_s}
-    if traffic["kind"] == "open_loop":
-        values["itl_p95_ms"] = percentile(red["itl_ms"], 95)
-        log(f"ttft ms p50 {percentile(red['ttft_ms'], 50):.1f} "
-            f"p95 {percentile(red['ttft_ms'], 95):.1f} "
-            f"n {len(red['ttft_ms'])}; "
-            f"itl ms p50 {percentile(red['itl_ms'], 50):.2f} "
-            f"p95 {values['itl_p95_ms']:.1f} n {len(red['itl_ms'])}")
-    else:
-        values["serve_tokens_per_s"] = red["tokens_in_window"] / seconds
-        log(f"serve_tokens_per_s {values['serve_tokens_per_s']:.1f}")
     chunk = batching.chunked_prefill_tokens
     lens = red["prompt_lens_in_window"] or [r["prompt_len"]
                                             for r in plan["requests"]]
     run_record = {
         "kind": traffic["kind"], "window_s": seconds, "config": conf,
         "engine_before": before, "engine_after": after,
+        "counters_before": counters_before, "counters_after": counters_after,
         "loadgen": red, "trace": traced, "peaks": dev["peaks"],
+        "host_spans": traced.get("host_spans") if traced else None,
         "weight_bytes_per_param": jax.numpy.dtype(cfg.param_dtype).itemsize,
         "prefill": {
             "chunk": chunk, "mean_useful_flops_per_chunk":
@@ -288,5 +375,4 @@ def run(manifest: dict, cell: dict, conf: dict, traffic: dict, *, seed: int,
     }
     return {"correct": correct, "attempted": red["attempted"],
             "failed": red["failed"], "values": values, "record": run_record,
-            "memory_peak_bytes": memory_peak_bytes(jax.local_devices()),
-            "traced": traced}
+            "memory_peak_bytes": memory_peak, "traced": traced}

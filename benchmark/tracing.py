@@ -1,12 +1,15 @@
 """From a profiler trace to numbers: the benchmark's own reduction of the
 ``.xplane.pb`` that ``jax.profiler`` writes, read with nothing but JAX
-(``jax.profiler.ProfileData``).
+(``jax.profiler.ProfileData``). The profiler itself is started and stopped
+through the program's one control, ``kubeflow_tpu/obs/profiler.py``.
 
 The trace is first brought to a small plain form (``load_xplane``), lists of
 ``[name, start_s, duration_s]`` per device: its XLA modules (one event per
 execution of a jitted program) and its XLA ops (one per executed operation).
 Every reduction below works on that form, so a test can hand-build one, and
-a trimmed recording of a real one is kept with the tests.
+a trimmed recording of a real one is kept with the tests. The program's own
+host spans ride along under ``host_spans`` (``benchmark/hostspans.py`` has
+their form and their reductions).
 
 Rules:
 - busy: the union of the op intervals of a device; a run's ``busy_s`` is the
@@ -34,16 +37,32 @@ COLLECTIVE = re.compile(
 # -- recording and loading -----------------------------------------------------
 
 def start(trace_dir: str) -> None:
-    """Start the profiler with the Python tracer off: it stamps every
-    Python call of every thread, which slows the host threads the run is
-    measuring and makes the trace large. Device events and the runtime's
-    own host events stay."""
-    import jax
+    """Start the profiler through the program's one control
+    (``kubeflow_tpu/obs/profiler.py``), with the Python tracer off: it
+    stamps every Python call of every thread, which slows the host threads
+    the run is measuring and makes the trace large. Device events, the
+    runtime's own host events and the program's spans stay."""
+    from kubeflow_tpu.obs import profiler
 
-    os.makedirs(trace_dir, exist_ok=True)
-    options = jax.profiler.ProfileOptions()
-    options.python_tracer_level = 0
-    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    profiler.start(trace_dir)
+
+
+def abort() -> None:
+    """Stop a trace that a failing run left open; nothing is read."""
+    from kubeflow_tpu.obs import profiler
+
+    profiler.stop()
+
+
+def warm(trace_dir: str) -> None:
+    """Start and stop the profiler once and throw that trace away: the
+    first start of a process costs seconds, which then fall into no
+    traced window (``--trace 2``)."""
+    import shutil
+
+    start(trace_dir)
+    abort()
+    shutil.rmtree(trace_dir, ignore_errors=True)
 
 
 def stop(trace_dir: str, window_s: float) -> dict:
@@ -51,9 +70,7 @@ def stop(trace_dir: str, window_s: float) -> dict:
     summary a person can read is left beside the trace directory."""
     import json
 
-    import jax
-
-    jax.profiler.stop_trace()
+    abort()
     trace = load_newest(trace_dir, window_s)
     with open(os.path.join(os.path.dirname(trace_dir),
                            "trace_summary.json"), "w") as f:
@@ -82,6 +99,8 @@ def load_newest(trace_dir: str, window_s: float) -> dict:
 def load_xplane(path: str, window_s: float) -> dict:
     from jax.profiler import ProfileData
 
+    from benchmark import hostspans
+
     data = ProfileData.from_file(path)
     devices, others = [], []
     origin = None
@@ -108,7 +127,8 @@ def load_xplane(path: str, window_s: float) -> dict:
                         for n, s, d in dev[key]]
     devices.sort(key=lambda d: d["name"])
     return {"window_s": float(window_s), "devices": devices,
-            "other_planes": others, "source": os.path.basename(path)}
+            "other_planes": others, "source": os.path.basename(path),
+            "host_spans": hostspans.from_profile(data, origin)}
 
 
 # -- interval arithmetic -------------------------------------------------------

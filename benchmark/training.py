@@ -2,7 +2,8 @@
 benchmark's weights and the benchmark's batches, timed between the trainer's
 own sync points (the ``log_every`` fetch of the metrics); the benchmark adds
 no synchronisation to the hot loop and ends the window by raising from
-``on_step``.
+``on_step``. With ``--trace 2`` it keeps ``on_step`` alive for ``trace_steps``
+more steps behind the closed window, with the profiler on, and raises then.
 """
 
 from __future__ import annotations
@@ -15,7 +16,7 @@ import numpy as np
 
 from benchmark import correctness, flops, tracing
 from benchmark.device import CompileCounter, memory_peak_bytes
-from benchmark.serving import RunFailed, decoder_config
+from benchmark.serving import RunFailed, decoder_config, program_counters
 from benchmark.traffic import train_batch
 from benchmark.weights import make_params, param_tree
 
@@ -54,7 +55,7 @@ def trainer_config(conf: dict, traffic: dict, seed: int):
 
 
 def run(manifest: dict, cell: dict, conf: dict, traffic: dict, *, seed: int,
-        seconds: float, trace: bool, dev: dict, t_start: float,
+        seconds: float, trace: int, dev: dict, t_start: float,
         out_dir: str, log) -> dict:
     import jax
     from kubeflow_tpu.models.decoder import decoder_param_specs
@@ -104,31 +105,53 @@ def run(manifest: dict, cell: dict, conf: dict, traffic: dict, *, seed: int,
     trace_at = int(traffic.get("trace_at_step", 4))
     trace_steps = int(traffic.get("trace_steps", 3))
     st = {"first": None, "t0": None, "marks": [], "setup_s": None,
-          "trace": None, "trace_on": None}
+          "trace": None, "trace_on": None, "closed": None,
+          "counters_before": None, "counters_after": None}
     trace_dir = os.path.join(out_dir, "trace")
+
+    def trace_on() -> None:
+        tracing.start(trace_dir)
+        st["trace_on"] = time.monotonic()
+
+    def trace_off() -> None:
+        window = time.monotonic() - st["trace_on"]
+        st["trace_on"] = None
+        st["trace"] = tracing.stop(trace_dir, window)
 
     def on_step(step: int, metrics: dict) -> None:
         now = time.monotonic()
         if step == 1:
             st["first"] = dict(metrics)
         if step == warm:
+            # The window's first mark, (warm, t0): the counters' window
+            # runs from here to the last mark, as ``elapsed`` does.
             st["t0"], st["setup_s"] = now, now - t_start
+            st["counters_before"] = program_counters(trainer=trainer)
             compiles.start()
             log(f"window opens, setup_s {st['setup_s']:.3f}")
         if st["t0"] is None:
             return
+        if st["closed"] is not None:
+            # The tail: the window closed at step ``closed`` and its marks
+            # stopped there; these steps are only traced.
+            if step >= st["closed"] + trace_steps:
+                trace_off()
+                raise _WindowOver()
+            return
         if step > warm and step % tcfg.log_every == 0:
             st["marks"].append((step, now))
-        if trace:
+            st["counters_after"] = program_counters(trainer=trainer)
+        if trace == 1:
             if step == warm + trace_at:
-                tracing.start(trace_dir)
-                st["trace_on"] = time.monotonic()
+                trace_on()
             elif step == warm + trace_at + trace_steps:
-                window = time.monotonic() - st["trace_on"]
-                st["trace_on"] = None
-                st["trace"] = tracing.stop(trace_dir, window)
+                trace_off()
         if now - st["t0"] >= seconds:
-            raise _WindowOver()
+            if trace != 2:
+                raise _WindowOver()
+            st["closed"] = step
+            tracing.warm(os.path.join(out_dir, "trace_warm"))
+            trace_on()
 
     try:
         trainer.run(on_step=on_step)
@@ -138,7 +161,7 @@ def run(manifest: dict, cell: dict, conf: dict, traffic: dict, *, seed: int,
         pass
     finally:
         if st["trace_on"] is not None:
-            jax.profiler.stop_trace()
+            tracing.abort()
     n_compiles = compiles.stop()
     if n_compiles:
         raise RunFailed(f"{n_compiles} program(s) compiled inside the "
@@ -183,8 +206,16 @@ def run(manifest: dict, cell: dict, conf: dict, traffic: dict, *, seed: int,
             f"temp {ma.temp_size_in_bytes})")
     values = {"setup_s": st["setup_s"],
               "train_tokens_per_s_chip": tokens_per_s_chip}
+    traced = st["trace"]
+    if trace == 2:
+        import shutil
+
+        shutil.rmtree(trace_dir, ignore_errors=True)
     record = {"kind": "train_steps", "window_s": elapsed, "config": conf,
-              "trace": st["trace"], "peaks": dev["peaks"], "values": values,
+              "trace": traced, "peaks": dev["peaks"], "values": values,
+              "counters_before": st["counters_before"],
+              "counters_after": st["counters_after"],
+              "host_spans": traced.get("host_spans") if traced else None,
               "train": {"tokens_per_s_chip": tokens_per_s_chip,
                         "seq_len": seq, "steps": steps,
                         "median_step_s": float(np.median(step_times))}}

@@ -23,9 +23,18 @@ span tree at WARNING); Chrome ``about:tracing`` / Perfetto JSON export; and
 
 Cost model: a span is a dict-sized Python object and a couple of lock-free
 contextvar ops (cross-thread spans take one lock on end); a traced request
-creates ~6 spans total — noise next to a single XLA dispatch. Engine-side
-instrumentation only runs for requests that carry a trace parent, so
-untraced traffic (e.g. bench_serve) pays nothing.
+creates ~6 spans total and up to ``MAX_EVENTS`` ``decode_round`` events.
+Engine-side instrumentation only runs for requests that carry a trace
+parent — but the tracer is ON by default and the model server roots a span
+for every POST, so every request through ``ModelServer`` is a traced
+request and pays all of that; only a direct ``engine.submit`` without a
+``trace_parent``, or ``get_tracer().enabled = False``, pays nothing. What
+it costs a benchmark request was measured once (PERF.md, Findings, PR 25).
+
+These spans are on the wall clock (``time.time()``) and are an operator's
+tool. The hot loops' phases on the PROFILER's clock, beside the device's
+ops, are ``obs/profiler.py``'s (``hot_span``); its anchor annotation lays
+the two on one timeline.
 
 Cross-thread propagation: contextvars do not flow into the engine scheduler
 thread, so the server attaches the request span's ``SpanContext`` to the

@@ -74,6 +74,8 @@ from kubeflow_tpu.serve.device_state import DEAD_SLOT, DecodeState
 from kubeflow_tpu.models import layers as L
 from kubeflow_tpu.models.config import DecoderConfig
 from kubeflow_tpu.models.decoder import Params, decoder_forward, init_decoder_params
+from kubeflow_tpu.obs import profiler as prof
+from kubeflow_tpu.obs.profiler import hot_span
 from kubeflow_tpu.obs.stats import quantile as _quantile
 from kubeflow_tpu.obs.trace import get_tracer
 
@@ -408,6 +410,10 @@ class Request:
     adopt: Optional[Any] = None
     # results
     output_tokens: list[int] = dataclasses.field(default_factory=list)
+    # Monotonic instant of the request's FIRST admission (None while it
+    # waits): admission to first token is the prefill phase
+    # (``LLMEngine.counters``).
+    admitted_time: Optional[float] = None
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     finish_reason: Optional[str] = None
@@ -502,6 +508,7 @@ class _InflightRound:
     active: list[tuple[int, "_Slot"]]
     k_steps: int
     gap_ms: Optional[float]             # host gap preceding this dispatch
+    round_id: int = 0                   # ties the dispatch span to its fetch
 
 
 def _pin2(out, pin):
@@ -1316,6 +1323,11 @@ class LLMEngine:
         self._last_ready_t: Optional[float] = None  # lockfree: scheduler-confined
         self.decode_rounds = 0          # lockfree: scheduler-confined counter
         self.first_token_fetches = 0    # lockfree: scheduler-confined counter
+        # Running sums behind ``counters()``; every key exists from here on.
+        self._prefill_phase_sum_s = 0.0     # lockfree: scheduler-confined counter
+        self._prefill_phase_n = 0           # lockfree: scheduler-confined counter
+        self._decode_steps_dispatched = 0   # lockfree: scheduler-confined counter
+        self._decode_tokens_emitted = 0     # lockfree: scheduler-confined counter
         self.waiting: "queue.Queue[Request]" = queue.Queue()
         self.metrics = EngineMetrics()
         # Bounded admission + queue-delay budget (load shedding): see
@@ -1371,6 +1383,33 @@ class LLMEngine:
                 for k, v in cache.items()}
 
     # -- submission ------------------------------------------------------------
+
+    def counters(self) -> dict[str, float]:
+        """One total snapshot of the engine's running sums and counts: a
+        flat dict whose keys all exist from construction on, whatever the
+        traffic did, and whose values only ever grow (``slots`` is the
+        constant the occupancy is taken against). A reader takes two
+        snapshots and works on the differences. The histograms' sums go in
+        as ``EngineMetrics`` keeps them."""
+        m = self.metrics
+        _, _, qd_sum, qd_n = m.queue_delay_histogram()
+        _, _, hg_sum, hg_n = m.host_gap_histogram()
+        return {
+            "slots": self.num_slots,
+            "queue_delay_sum_s": qd_sum, "queue_delay_n": qd_n,
+            "host_gap_sum_s": hg_sum, "host_gap_n": hg_n,
+            "preemptions": m.preemptions, "requests_shed": m.requests_shed,
+            "requests_completed": m.requests_completed,
+            "tokens_generated": m.tokens_generated,
+            "decode_rounds": self.decode_rounds,
+            "first_token_fetches": self.first_token_fetches,
+            # admission to first token, over first admissions
+            "prefill_phase_sum_s": self._prefill_phase_sum_s,
+            "prefill_phase_n": self._prefill_phase_n,
+            # sum of k_steps; tokens the consumed rounds handed to requests
+            "decode_steps_dispatched": self._decode_steps_dispatched,
+            "decode_tokens_emitted": self._decode_tokens_emitted,
+        }
 
     def queue_depth(self) -> int:
         """Requests waiting for a slot (admission queue + scheduler-side
@@ -1665,25 +1704,30 @@ class LLMEngine:
         otherwise individual rows stack here, padded to the next power of
         two so the sampler trace set stays log-bounded."""
         n = len(items)
-        if stacked is None:
-            width = 1
-            while width < n:
-                width *= 2
-            stacked = jnp.stack(
-                [it[3] for it in items] + [items[-1][3]] * (width - n))
-        width = stacked.shape[0]
-        params_list = [it[0].params for it in items]
-        padded = params_list + [SamplingParams()] * (width - n)
-        firsts = self._sampler(
-            stacked, self._next_key(),
-            jnp.asarray([p.temperature for p in padded], jnp.float32),
-            jnp.asarray([p.top_k for p in padded], jnp.int32),
-            jnp.asarray([p.top_p for p in padded], jnp.float32),
-            _mode_for(params_list))
-        vals = jax.device_get(firsts)
-        self.first_token_fetches += 1
-        for j, (req, slot_idx, plen, _) in enumerate(items):
-            self._admit_with_token(req, slot_idx, plen, int(vals[j]))
+        with hot_span(prof.ENGINE_SAMPLE_FIRST, n=n):
+            if stacked is None:
+                width = 1
+                while width < n:
+                    width *= 2
+                stacked = jnp.stack(
+                    [it[3] for it in items] + [items[-1][3]] * (width - n))
+            width = stacked.shape[0]
+            params_list = [it[0].params for it in items]
+            padded = params_list + [SamplingParams()] * (width - n)
+            firsts = self._sampler(
+                stacked, self._next_key(),
+                jnp.asarray([p.temperature for p in padded], jnp.float32),
+                jnp.asarray([p.top_k for p in padded], jnp.int32),
+                jnp.asarray([p.top_p for p in padded], jnp.float32),
+                _mode_for(params_list))
+            # Blocks until the prefill is done, which queues behind the
+            # decode round in flight: a wait for the device like the
+            # round's own fetch, and named like it.
+            with hot_span(prof.ENGINE_FETCH, first=n):
+                vals = jax.device_get(firsts)
+            self.first_token_fetches += 1
+            for j, (req, slot_idx, plen, _) in enumerate(items):
+                self._admit_with_token(req, slot_idx, plen, int(vals[j]))
 
     def _admit_with_token(self, req: Request, slot_idx: int, plen: int,
                           tok: int) -> None:
@@ -1697,6 +1741,10 @@ class LLMEngine:
                 _span_open(req, "engine.decode", slot=slot_idx)
         if req.first_token_time is None:
             req.first_token_time = time.monotonic()
+            if req.admitted_time is not None:
+                self._prefill_phase_sum_s += \
+                    req.first_token_time - req.admitted_time
+                self._prefill_phase_n += 1
         req.output_tokens.append(tok)
         req.stream.put(tok)
         # generated counts ALL emitted tokens — on re-admission after a
@@ -1722,6 +1770,11 @@ class LLMEngine:
     def _advance_one(self, ch: "_Chunking") -> int:
         """Run ONE chunk of one in-flight chunked prefill. Returns work done
         (0 when page-pool pressure defers the chunk to a later step)."""
+        with hot_span(prof.ENGINE_PREFILL_DISPATCH, slot=ch.slot,
+                      pos=ch.pos):
+            return self._advance_one_chunk(ch)
+
+    def _advance_one_chunk(self, ch: "_Chunking") -> int:
         req, slot_idx = ch.request, ch.slot
         C = self.chunk_size
         plen = len(req.prompt_tokens)
@@ -1921,7 +1974,8 @@ class LLMEngine:
         return n
 
     def _note_admitted(self, req: Request) -> Request:
-        self.metrics.observe_queue_delay(time.monotonic() - req.arrival,
+        req.admitted_time = time.monotonic()
+        self.metrics.observe_queue_delay(req.admitted_time - req.arrival,
                                          qos=req.qos)
         return req
 
@@ -2802,27 +2856,29 @@ class LLMEngine:
             # Pre-allocate pages covering every live slot's next k_steps
             # write positions (mid-dispatch page crossings must land on
             # mapped pages); under pool pressure, preempt youngest-first.
-            for i, s in list(active):
-                if self.slots[i] is not s:
-                    continue    # preempted by an earlier slot's allocation
-                upto = min(s.length + slack + k_steps, self.max_len)
-                while not self._ensure_pages(i, upto):
-                    if self._preempt_youngest(keep=i):
-                        continue
-                    # Sole survivor: shrink the dispatch to one step; init
-                    # guarantees one max-length sequence always fits, but
-                    # guard the next write position anyway.
-                    k_steps = 1
-                    if not self._ensure_pages(i, min(s.length + slack + 1,
-                                                     self.max_len)):
-                        self._preempt_slot(i)
-                    break
-            active = [(i, s) for i, s in enumerate(self.slots)
-                      if s is not None]
+            with hot_span(prof.ENGINE_ENSURE_PAGES):
+                for i, s in list(active):
+                    if self.slots[i] is not s:
+                        continue    # preempted by an earlier slot's allocation
+                    upto = min(s.length + slack + k_steps, self.max_len)
+                    while not self._ensure_pages(i, upto):
+                        if self._preempt_youngest(keep=i):
+                            continue
+                        # Sole survivor: shrink the dispatch to one step;
+                        # init guarantees one max-length sequence always
+                        # fits, but guard the next write position anyway.
+                        k_steps = 1
+                        if not self._ensure_pages(
+                                i, min(s.length + slack + 1, self.max_len)):
+                            self._preempt_slot(i)
+                        break
+                active = [(i, s) for i, s in enumerate(self.slots)
+                          if s is not None]
             if not active:
                 return False
         mode = _mode_for([s.request.params for _, s in active])
-        self._sync_decode_state()
+        with hot_span(prof.ENGINE_SYNC_STATE):
+            self._sync_decode_state()
         now = time.monotonic()
         gap = None
         if self._last_ready_t is not None:
@@ -2832,6 +2888,20 @@ class LLMEngine:
             gap = 0.0 if self._rounds else max(0.0, now - self._last_ready_t)
             self.metrics.observe_host_gap(gap)
         self.metrics.note_dispatch_depth(len(self._rounds))
+        round_id = self.decode_rounds
+        with hot_span(prof.ENGINE_DECODE_DISPATCH, round=round_id,
+                      k_steps=k_steps, live=len(active)):
+            out = self._dispatch_decode(k_steps, mode)
+        self.decode_rounds += 1
+        self._decode_steps_dispatched += k_steps
+        self._rounds.append(_InflightRound(
+            out=out, active=list(active), k_steps=k_steps,
+            gap_ms=None if gap is None else gap * 1e3, round_id=round_id))
+        return True
+
+    def _dispatch_decode(self, k_steps: int, mode: str):  # hot-loop
+        """Enqueue the decode program over the device-resident state and
+        adopt the state it returns; returns the token buffer's handle."""
         key = self._next_key()
         if self.paged:
             if self._lora is not None:
@@ -2854,11 +2924,7 @@ class LLMEngine:
                     self.params, self.cache, self._dstate.arrays, key, k_steps,
                     mode)
             self._dstate.adopt(st)
-        self.decode_rounds += 1
-        self._rounds.append(_InflightRound(
-            out=out, active=list(active), k_steps=k_steps,
-            gap_ms=None if gap is None else gap * 1e3))
-        return True
+        return out
 
     def _consume_round(self) -> int:  # hot-loop
         """Fetch and emit the oldest in-flight round's tokens. Slots whose
@@ -2866,8 +2932,16 @@ class LLMEngine:
         re-admitted) are MASKED — a cancelled request's output stream never
         contains post-cancel tokens. Returns tokens emitted."""
         rnd = self._rounds.pop(0)
-        out = np.asarray(jax.device_get(rnd.out))  # sync-point: the pipeline's one designed fetch per round
+        with hot_span(prof.ENGINE_FETCH, round=rnd.round_id):
+            out = np.asarray(jax.device_get(rnd.out))  # sync-point: the pipeline's one designed fetch per round
         self._last_ready_t = time.monotonic()
+        with hot_span(prof.ENGINE_EMIT, round=rnd.round_id):
+            emitted = self._emit_round(rnd, out)
+        self._decode_tokens_emitted += emitted
+        return emitted
+
+    def _emit_round(self, rnd: "_InflightRound", out) -> int:  # hot-loop
+        """Hand one fetched round's tokens to their requests."""
         emitted = 0
         for i, s in rnd.active:
             if self.slots[i] is not s or s.request.done.is_set():
@@ -3145,17 +3219,21 @@ class LLMEngine:
         round in flight). Under ``KFTPU_SANITIZE=1`` the decode pass runs
         with implicit transfers disallowed — the runtime half of the
         static device-hygiene rules."""
-        n = self._reap_abandoned() + self._enforce_queue_bound() \
-            + self._drain_handoff_releases() + self._admit()
+        with hot_span(prof.ENGINE_REAP):
+            n = self._reap_abandoned() + self._enforce_queue_bound() \
+                + self._drain_handoff_releases()
+        with hot_span(prof.ENGINE_ADMIT):
+            n += self._admit()
         if self._kvtier is not None:
             # Demotion scan (host tier): cold sharer-free prefix pages
             # hand off to the background migration thread in batches.
             # Interval-gated inside tick — idle 50 ms polls drive it —
             # and it yields to foreground traffic unless pool pressure
             # says demoting NOW is what saves the cached content.
-            busy = bool(self._backlog) or bool(self._chunkings) \
-                or any(s is not None for s in self.slots)
-            self._kvtier.tick(busy=busy)
+            with hot_span(prof.ENGINE_KVTIER_TICK):
+                busy = bool(self._backlog) or bool(self._chunkings) \
+                    or any(s is not None for s in self.slots)
+                self._kvtier.tick(busy=busy)
         with self._transfer_guard():
             n += self._decode_once()
         if n == 0:
@@ -3176,7 +3254,8 @@ class LLMEngine:
         while not self._stop.is_set():
             if self.step() == 0:
                 # idle: block until a request arrives
-                self._wake.wait(timeout=0.05)
+                with hot_span(prof.ENGINE_IDLE):
+                    self._wake.wait(timeout=0.05)
                 self._wake.clear()
 
     def stop(self, timeout: float = 10.0) -> bool:

@@ -20,8 +20,14 @@ with a ``ModelRepository`` and the server adds the v2 repository API
 (``GET /v2/repository/index``, ``POST /v2/repository/models/{m}/load|
 unload``) and per-request routing with LRU load-on-demand.
 
-Plus /healthz (readiness), /metrics (Prometheus text format) and
-/debug/device (device, memory, compile cache, kernels per dispatched program).
+Plus /healthz (readiness), /metrics (Prometheus text format),
+/debug/device (device, memory, compile cache, kernels per dispatched program)
+and /debug/profile (GET: is a profiler capture of this replica running;
+POST /debug/profile/start {"seconds": n} and POST /debug/profile/stop: a
+capture of the live replica through obs/profiler.py, the engine's phases in
+it as host spans; it goes to the server's own ``profile_dir``, never to a
+path a client names, and the server stops it itself after at most
+``PROFILE_MAX_SECONDS``).
 Threaded stdlib server: handlers block on the engine's request stream; the
 engine thread does the batching, so concurrency costs one OS thread per
 in-flight request — fine at platform scale, and zero dependencies.
@@ -31,8 +37,10 @@ from __future__ import annotations
 
 import http.client
 import json
+import os
 import queue
 import re
+import tempfile
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -44,6 +52,7 @@ from kubeflow_tpu.core.headers import (
     HANDOFF_DTYPE_HEADER, HANDOFF_WIRE_HEADER, MODEL_HEADER, QOS_HEADER,
     TRACE_HEADER,
 )
+from kubeflow_tpu.obs import profiler
 from kubeflow_tpu.obs.fleet import spans_export_payload
 from kubeflow_tpu.obs.registry import MetricsRegistry, contract_note_header
 from kubeflow_tpu.obs.trace import debug_traces_payload, get_tracer
@@ -57,6 +66,16 @@ from kubeflow_tpu.serve.retry import (
 )
 from kubeflow_tpu.serve.router import quiet_handle_error
 from kubeflow_tpu.serve.tokenizer import Tokenizer, get_tokenizer
+
+#: First-byte overhead histogram bucket upper bounds (seconds): what the
+#: server adds around the engine on a streamed completion, handler entry to
+#: ``engine.submit`` returned plus first token to first chunk written.
+FIRST_BYTE_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.1, 0.5)
+
+#: The longest capture ``POST /debug/profile/start`` may ask for, and the
+#: length of one that names none: the port is the one that serves inference,
+#: so a client that never sends /stop must not leave the profiler on.
+PROFILE_MAX_SECONDS = 30.0
 
 #: Handoff wire versions this server can adopt (serve/handoff.py): v1 =
 #: raw K/V planes, v2 = + int8 scale rows. A payload tagged with
@@ -193,7 +212,8 @@ class ModelServer:
                  transformer=None,
                  explainer=None,
                  host: str = "127.0.0.1", port: int = 0,
-                 grpc_port: Optional[int] = None):
+                 grpc_port: Optional[int] = None,
+                 profile_dir: Optional[str] = None):
         if (engine is None) == (repository is None):
             raise ValueError("pass exactly one of engine= or repository=")
         self.name = name                  # default model name
@@ -209,6 +229,18 @@ class ModelServer:
         self.explainer = explainer
         self._in_flight = 0             # guarded_by: _in_flight_lock
         self._in_flight_lock = threading.Lock()
+        # What the server adds to a streamed completion's first byte
+        # (``counters``, and a histogram on /metrics).
+        self._fbo_lock = threading.Lock()
+        self._fbo_counts = [0] * (len(FIRST_BYTE_BUCKETS) + 1)  # guarded_by: _fbo_lock
+        self._fbo_sum = 0.0             # guarded_by: _fbo_lock
+        self._fbo_n = 0                 # guarded_by: _fbo_lock
+        # Where /debug/profile captures go, and the timer that ends the one
+        # this server started.
+        self.profile_dir = profile_dir or os.path.join(
+            tempfile.gettempdir(), f"kftpu-profile-{name}")
+        self._profile_lock = threading.Lock()
+        self._profile_timer: Optional[threading.Timer] = None  # guarded_by: _profile_lock
         handler = _make_handler(self)
         self.httpd = ThreadingHTTPServer((host, port), handler)
         self.httpd.daemon_threads = True
@@ -239,6 +271,7 @@ class ModelServer:
 
         self.httpd.shutdown()
         self.httpd.server_close()
+        self.stop_profile()
         if self._thread is not None:
             self._thread.join(timeout=5.0)
             # KFTPU_SANITIZE=threads: the serve thread must be dead now
@@ -536,12 +569,66 @@ class ModelServer:
             stop_token=tokenizer.eos_id,
         )
 
+    def observe_first_byte_overhead(self, seconds: float) -> None:
+        with self._fbo_lock:
+            i = 0
+            while i < len(FIRST_BYTE_BUCKETS) \
+                    and seconds > FIRST_BYTE_BUCKETS[i]:
+                i += 1
+            self._fbo_counts[i] += 1
+            self._fbo_sum += seconds
+            self._fbo_n += 1
+
+    def first_byte_overhead_histogram(self) -> tuple[list[int], float, int]:
+        with self._fbo_lock:
+            return list(self._fbo_counts), self._fbo_sum, self._fbo_n
+
+    def start_profile(self, seconds: Optional[float] = None) -> dict:
+        """Start a profiler capture of this replica into ``profile_dir``
+        (obs/profiler.py: raises ``RuntimeError`` while one is active). It
+        ends with ``stop_profile`` or by itself after ``seconds``, at most
+        ``PROFILE_MAX_SECONDS``."""
+        seconds = min(float(seconds or PROFILE_MAX_SECONDS),
+                      PROFILE_MAX_SECONDS)
+        with self._profile_lock:
+            profiler.start(self.profile_dir)
+            timer = threading.Timer(seconds, lambda: self.stop_profile(timer))
+            timer.daemon = True
+            self._profile_timer = timer
+            timer.start()
+        return {"active": True, "dir": self.profile_dir, "seconds": seconds}
+
+    def stop_profile(self, only: Optional[threading.Timer] = None) -> dict:
+        """Stop the capture ``start_profile`` began (one begun elsewhere in
+        the process is not this server's to stop). A timer passes itself as
+        ``only`` and stops nothing but the capture it was set for."""
+        with self._profile_lock:
+            timer = self._profile_timer
+            if timer is None or only not in (None, timer):
+                return {"active": profiler.active(), "dir": ""}
+            self._profile_timer = None
+            timer.cancel()
+            return {"active": False, "dir": profiler.stop()}
+
+    def counters(self) -> dict[str, float]:
+        """One total snapshot of the server's own running sums and counts
+        (the engine has its own, ``LLMEngine.counters``): every key exists
+        from construction on and only ever grows."""
+        _, total, n = self.first_byte_overhead_histogram()
+        return {"first_byte_overhead_sum_s": total,
+                "first_byte_overhead_n": n}
+
     def metrics_registry(self) -> MetricsRegistry:
         """Scrape-time registry over the live engine counters — the model
         server's half of the platform's single exposition path
         (obs/registry.py)."""
-        return serving_metrics_registry(self._live_engines(),
-                                        in_flight=self.in_flight)
+        reg = serving_metrics_registry(self._live_engines(),
+                                       in_flight=self.in_flight)
+        counts, total, n = self.first_byte_overhead_histogram()
+        reg.histogram("kftpu_serving_first_byte_overhead_seconds",
+                      FIRST_BYTE_BUCKETS).set_cumulative(
+                          counts, total, n, model=self.name)
+        return reg
 
     def _live_engines(self) -> list[tuple[str, LLMEngine]]:
         engines: list[tuple[str, LLMEngine]] = []
@@ -800,6 +887,8 @@ def _make_handler(server: ModelServer):
                 return self._json(200, server.device_payload())
             if self.path.startswith("/debug/traces"):
                 return self._json(200, debug_traces_payload(self.path))
+            if self.path == "/debug/profile":
+                return self._json(200, {"active": profiler.active()})
             if self.path.startswith("/debug/spans/export"):
                 # Fleet-trace drain (obs/fleet.py): completed spans +
                 # this process's clock, for cross-host stitching.
@@ -837,6 +926,7 @@ def _make_handler(server: ModelServer):
         # -- POST --------------------------------------------------------------
 
         def do_POST(self) -> None:
+            self._t_entry = time.monotonic()
             server.track(1)
             tracer = get_tracer()
             contract_note_header(TRACE_HEADER, direction="read")
@@ -856,6 +946,9 @@ def _make_handler(server: ModelServer):
                     # Always drain the body first: HTTP/1.1 keep-alive
                     # breaks if unread bytes remain on the connection.
                     body = self._body()
+                    if self.path.startswith("/debug/profile/"):
+                        return self._profile(self.path.rsplit("/", 1)[1],
+                                             body)
                     repo = _REPO_ACTION.match(self.path)
                     if repo:
                         return self._repository_action(repo.group(1),
@@ -889,6 +982,18 @@ def _make_handler(server: ModelServer):
                 self._json(500, {"error": f"{type(exc).__name__}: {exc}"})
             finally:
                 server.track(-1)
+
+        def _profile(self, action: str, body: dict) -> None:
+            """Operator capture of the live replica (obs/profiler.py)."""
+            if action == "start":
+                try:
+                    return self._json(
+                        200, server.start_profile(body.get("seconds")))
+                except RuntimeError as exc:        # one capture at a time
+                    return self._json(409, {"error": str(exc)})
+            if action == "stop":
+                return self._json(200, server.stop_profile())
+            self._json(404, {"error": f"not found: {self.path}"})
 
         def _repository_action(self, name: str, action: str) -> None:
             if server.repository is None:
@@ -1023,15 +1128,22 @@ def _make_handler(server: ModelServer):
 
         def _stream_tokens(self, req, tokenizer, *, chat: bool,
                            model: Optional[str], timeout: float,
-                           with_token_ids: bool = False) -> None:
+                           with_token_ids: bool = False,
+                           entry_overhead_s: Optional[float] = None
+                           ) -> None:
             """Send SSE headers and stream one engine request's tokens
             to the client (the local-decode half of every streaming
             path: unified, decode-side adoption, and the recompute
             fallback). ``with_token_ids`` adds the raw token id to each
             chunk — the handoff relay uses it so a non-streaming caller
             can re-decode the WHOLE sequence at once (piecewise byte
-            decoding would mangle multi-byte characters)."""
+            decoding would mangle multi-byte characters).
+            ``entry_overhead_s`` (handler entry to ``engine.submit``
+            returned): given, the first chunk written adds the time since
+            the engine's first token and the sum is observed as the
+            server's first-byte overhead."""
             self._send_sse_headers()
+            first_byte_due = entry_overhead_s is not None
             try:
                 while True:
                     try:
@@ -1059,6 +1171,12 @@ def _make_handler(server: ModelServer):
                     self._chunk(json.dumps({"id": req.id, "object": "chunk",
                                             "model": model or server.name,
                                             **delta}))
+                    if first_byte_due:
+                        first_byte_due = False
+                        if req.first_token_time is not None:
+                            server.observe_first_byte_overhead(
+                                entry_overhead_s + time.monotonic()
+                                - req.first_token_time)
             except OSError:
                 # Client hung up mid-stream: free the slot and its KV
                 # pages now instead of decoding to completion for a
@@ -1092,13 +1210,15 @@ def _make_handler(server: ModelServer):
                                     trace_parent=get_tracer().current(),
                                     qos=self._qos(body),
                                     handoff=handoff_flag, adapter=adapter)
+                entry_overhead_s = time.monotonic() - self._t_entry
                 if wants_handoff:
                     return self._stream_disaggregated(
                         engine, tokenizer, req, toks, body, decode_url,
                         chat=chat, model=model, timeout=timeout,
                         decode_alts=self._decode_alts())
                 self._stream_tokens(req, tokenizer, chat=chat, model=model,
-                                    timeout=timeout)
+                                    timeout=timeout,
+                                    entry_overhead_s=entry_overhead_s)
 
         def _stream_disaggregated(self, engine, tokenizer, req,
                                   toks: list[int], body: dict,

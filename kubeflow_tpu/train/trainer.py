@@ -21,6 +21,8 @@ import jax
 import numpy as np
 
 from kubeflow_tpu.models.config import DecoderConfig, preset
+from kubeflow_tpu.obs import profiler
+from kubeflow_tpu.obs.profiler import hot_span
 from kubeflow_tpu.obs.trace import get_tracer
 from kubeflow_tpu.runtime.bootstrap import EXIT_PREEMPTED
 from kubeflow_tpu.runtime.device_report import (
@@ -74,9 +76,11 @@ class TrainerConfig:
     fault_injection: dict = dataclasses.field(default_factory=dict)
     seed: int = 0
     attn_impl: str = "xla"
-    # jax.profiler window (SURVEY.md §5 tracing): trace steps
+    # Profiler window (SURVEY.md §5 tracing): trace steps
     # [profile_start_step, profile_start_step + profile_num_steps) into
-    # <workdir>/trace, viewable with tensorboard-plugin-profile.
+    # <workdir>/trace, viewable with tensorboard-plugin-profile. A job that
+    # is already running is captured with ``Trainer.request_profile``; both
+    # go through obs/profiler.py.
     profile_start_step: Optional[int] = None
     profile_num_steps: int = 3
     # Debug mode (SURVEY.md §5 race-detection analogs): trap NaNs at the op
@@ -178,6 +182,13 @@ class Trainer:
             if ledger_dir and process_id == 0 else None)
         self.save_failures = 0
         self._preempted = threading.Event()
+        # Profiler capture (obs/profiler.py): a pending request
+        # (num_steps, trace_dir), and the step at which the capture this
+        # trainer started ends.
+        self._profile_request: Optional[tuple[int, str]] = None
+        self._profile_until: Optional[int] = None
+        # Running sum behind ``counters()``.
+        self._stage_wait_sum_s = 0.0
 
         self.emitter = MetricsEmitter(jsonl_path=metrics_path)
         self.throughput = Throughput(
@@ -262,6 +273,38 @@ class Trainer:
         return jax.make_array_from_process_local_data(
             self.task.batch_sharding, local_batch)
 
+    def counters(self) -> dict[str, float]:
+        """One total snapshot of the loop's running sums: every key exists
+        from construction on and only ever grows. ``stage_wait_sum_s`` is
+        the seconds the loop waited for its next batch."""
+        return {"stage_wait_sum_s": self._stage_wait_sum_s}
+
+    def request_profile(self, num_steps: Optional[int] = None,
+                        trace_dir: Optional[str] = None) -> None:
+        """Begin a profiler capture at the next step boundary of the
+        running job (any thread may ask); it ends ``num_steps`` steps
+        later. ``cfg.profile_start_step`` is the same request, made before
+        the job started."""
+        self._profile_request = (
+            int(num_steps or self.cfg.profile_num_steps),
+            trace_dir or self._trace_dir())
+
+    def _profile_boundary(self, step: int) -> None:
+        """At a step boundary: end the capture this trainer started when
+        its steps are up, start a requested one."""
+        if self._profile_until is not None:
+            if step < self._profile_until:
+                return
+            self._profile_until = None
+            profiler.stop()
+        if step == self.cfg.profile_start_step:
+            self.request_profile()
+        req, self._profile_request = self._profile_request, None
+        if req is not None and self.process_id == 0 \
+                and not profiler.active():
+            profiler.start(req[1])
+            self._profile_until = step + req[0]
+
     def run(self, *, on_step=None) -> dict:
         start = self.try_resume()
         if self.ledger is not None:
@@ -272,8 +315,6 @@ class Trainer:
                     "progress outran the resumed checkpoint", lost)
         last_metrics: dict = {}
         last_tick_step = start
-        prof = self.cfg.profile_start_step
-        tracing = False
         tracer = get_tracer()
         window_start = time.time()
         watchdog: Optional[StepWatchdog] = None
@@ -295,79 +336,78 @@ class Trainer:
             lambda s: self.make_global_batch(self.data.batch_at(s)),
             start=start, name="train-batch-stager")
         # try/finally so ANY exit from the loop — exception mid-window,
-        # preemption SystemExit — still stops an open jax.profiler trace,
+        # preemption SystemExit — still stops the profiler capture it began,
         # drains the async checkpoint managers (an in-flight save must not
         # be abandoned torn), and closes the metrics emitter.
         try:
             for step in range(start, self.cfg.steps):
-                if prof is not None and self.process_id == 0:
-                    # `tracing` guards both ends: a resume that lands inside
-                    # or past the window must not stop a trace it never
-                    # started.
-                    if step == prof:
-                        jax.profiler.start_trace(self._trace_dir())
-                        tracing = True
-                    elif tracing and step >= prof + self.cfg.profile_num_steps:
-                        jax.profiler.stop_trace()
-                        tracing = False
-                batch = stager.get(step)
-                if step == start and jax.default_backend() == "tpu":
-                    self.step_kernels = lowered_kernel_calls(
-                        self.task.step_fn, self.task.state, batch)
-                self.task.state, metrics = self.task.step_fn(self.task.state, batch)
-                if step == start:
-                    # Training shapes are fixed: everything compiles on the
-                    # first executed step, so under KFTPU_SANITIZE=recompile
-                    # any later compile is a dispatch-signature defect — the
-                    # runtime half of the F6xx rules. No-op when the
-                    # sanitizer is off.
-                    mark_compile_warm()
-                if watchdog is not None:
-                    watchdog.step_completed(step + 1)
-                if self._preempted.is_set():
-                    self._emergency_exit(step + 1)      # raises SystemExit
-                if (step + 1) % self.cfg.log_every == 0 or step + 1 == self.cfg.steps:
-                    metrics = {k: float(jax.device_get(v)) for k, v in metrics.items()}
-                    metrics.update(self.throughput.tick(step + 1 - last_tick_step))
-                    # COMMITTED checkpoints only (async saves that a teardown
-                    # would abort must not arm the elastic autoscaler): surfaced
-                    # through metrics.jsonl onto job status.
-                    if self.ckpt is not None:
-                        committed = self.ckpt.latest_committed_step()
-                        if committed is not None:
-                            metrics["last_checkpoint_step"] = committed
-                    # Goodput ledger (train/survival.py): restart/fallback/
-                    # emergency accounting riding every window onto job
-                    # status; the ledger's cumulative counters supersede the
-                    # attempt-local save_failures when present.
-                    metrics["checkpoint_save_failures"] = self.save_failures
-                    if self.ledger is not None:
-                        self.ledger.record_progress(step + 1)
-                        metrics.update(self.ledger.metrics(
-                            step + 1, self.throughput.ema_step_time_s))
-                    # One completed span per logged window (obs/trace.py): the
-                    # train loop's slice of the platform trace surface. Spans
-                    # are retrospective (explicit start) so the hot loop pays
-                    # nothing between log points; ``profiling=True`` marks
-                    # windows that overlapped a jax.profiler trace, tying the
-                    # span to the on-device timeline it summarizes.
-                    sp = tracer.start_span(
-                        "train.window", start=window_start,
-                        steps=f"{last_tick_step}-{step + 1}")
-                    for k in ("loss", "step_time_ms", "tokens_per_sec", "mfu"):
-                        if k in metrics:
-                            sp.set_attrs(**{k: round(float(metrics[k]), 6)})
-                    if tracing:
-                        sp.set_attrs(profiling=True)
-                    sp.end()
-                    window_start = time.time()
-                    last_tick_step = step + 1
-                    last_metrics = metrics
-                    if self.process_id == 0:
-                        self.emitter.emit(step + 1, metrics)
-                if self.cfg.checkpoint_every and (step + 1) % self.cfg.checkpoint_every == 0:
-                    self.save(step + 1)
-                self._maybe_injected_wedge(step + 1)
+                self._profile_boundary(step)
+                with profiler.hot_step(profiler.TRAIN_STEP, step):
+                    t_wait = time.monotonic()
+                    with hot_span(profiler.TRAIN_STAGE_WAIT, step=step):
+                        batch = stager.get(step)
+                    self._stage_wait_sum_s += time.monotonic() - t_wait
+                    if step == start and jax.default_backend() == "tpu":
+                        self.step_kernels = lowered_kernel_calls(
+                            self.task.step_fn, self.task.state, batch)
+                    with hot_span(profiler.TRAIN_DISPATCH, step=step):
+                        self.task.state, metrics = self.task.step_fn(self.task.state, batch)
+                    if step == start:
+                        # Training shapes are fixed: everything compiles on the
+                        # first executed step, so under KFTPU_SANITIZE=recompile
+                        # any later compile is a dispatch-signature defect — the
+                        # runtime half of the F6xx rules. No-op when the
+                        # sanitizer is off.
+                        mark_compile_warm()
+                    if watchdog is not None:
+                        watchdog.step_completed(step + 1)
+                    if self._preempted.is_set():
+                        self._emergency_exit(step + 1)      # raises SystemExit
+                    if (step + 1) % self.cfg.log_every == 0 or step + 1 == self.cfg.steps:
+                        with hot_span(profiler.TRAIN_SYNC, step=step):
+                            metrics = {k: float(jax.device_get(v)) for k, v in metrics.items()}
+                        with hot_span(profiler.TRAIN_LOG, step=step):
+                            metrics.update(self.throughput.tick(step + 1 - last_tick_step))
+                            # COMMITTED checkpoints only (async saves that a teardown
+                            # would abort must not arm the elastic autoscaler): surfaced
+                            # through metrics.jsonl onto job status.
+                            if self.ckpt is not None:
+                                committed = self.ckpt.latest_committed_step()
+                                if committed is not None:
+                                    metrics["last_checkpoint_step"] = committed
+                            # Goodput ledger (train/survival.py): restart/fallback/
+                            # emergency accounting riding every window onto job
+                            # status; the ledger's cumulative counters supersede the
+                            # attempt-local save_failures when present.
+                            metrics["checkpoint_save_failures"] = self.save_failures
+                            if self.ledger is not None:
+                                self.ledger.record_progress(step + 1)
+                                metrics.update(self.ledger.metrics(
+                                    step + 1, self.throughput.ema_step_time_s))
+                            # One completed span per logged window (obs/trace.py): the
+                            # train loop's slice of the platform trace surface. Spans
+                            # are retrospective (explicit start) so the hot loop pays
+                            # nothing between log points; ``profiling=True`` marks
+                            # windows that overlapped a profiler capture, tying the
+                            # span to the on-device timeline it summarizes.
+                            sp = tracer.start_span(
+                                "train.window", start=window_start,
+                                steps=f"{last_tick_step}-{step + 1}")
+                            for k in ("loss", "step_time_ms", "tokens_per_sec", "mfu"):
+                                if k in metrics:
+                                    sp.set_attrs(**{k: round(float(metrics[k]), 6)})
+                            if profiler.active():
+                                sp.set_attrs(profiling=True)
+                            sp.end()
+                            window_start = time.time()
+                            last_tick_step = step + 1
+                            last_metrics = metrics
+                            if self.process_id == 0:
+                                self.emitter.emit(step + 1, metrics)
+                    if self.cfg.checkpoint_every and (step + 1) % self.cfg.checkpoint_every == 0:
+                        with hot_span(profiler.TRAIN_CHECKPOINT, step=step):
+                            self.save(step + 1)
+                    self._maybe_injected_wedge(step + 1)
                 if on_step is not None:
                     on_step(step + 1, last_metrics)
             if self.ckpt is not None and self.ckpt.latest_step() != self.cfg.steps:
@@ -380,9 +420,10 @@ class Trainer:
                 signal.signal(signal.SIGTERM, prev_sigterm)
             if watchdog is not None:
                 watchdog.stop()
-            if tracing:
+            if self._profile_until is not None:
+                self._profile_until = None
                 try:
-                    jax.profiler.stop_trace()
+                    profiler.stop()
                 except Exception:
                     logger.exception("stopping profiler trace failed")
             for mgr in (self.ckpt, self.ckpt_emergency):

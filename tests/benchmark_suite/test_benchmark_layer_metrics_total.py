@@ -6,6 +6,7 @@ manifest declares."""
 import pytest
 
 from benchmark import manifest as mf
+from test_benchmark_program_readers import NEW, quiet_run
 
 MANIFEST = mf.load_manifest()
 PEAKS = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9}
@@ -24,8 +25,10 @@ CONFIG = mf.load_config(MANIFEST, MANIFEST["configs"][0]["name"])
 def empty_serving_run():
     """A served window in which nothing was sampled: no request was sent,
     the engine's counters did not move, the trace holds no program of
-    interest."""
-    return {"kind": "open_loop", "window_s": 40.0, "config": CONFIG,
+    interest; the program's own counters stood still and its scheduler left
+    no span."""
+    return {**quiet_run("any.chat"),
+            "kind": "open_loop", "window_s": 40.0, "config": CONFIG,
             "engine_before": dict(ZERO), "engine_after": dict(ZERO),
             "loadgen": {"late_ms": [], "ttft_ms": [], "itl_ms": []},
             "trace": QUIET_TRACE, "peaks": PEAKS, "weight_bytes_per_param": 2,
@@ -33,7 +36,8 @@ def empty_serving_run():
 
 
 def empty_training_run():
-    return {"kind": "train_steps", "window_s": 40.0, "config": CONFIG,
+    return {**quiet_run("any.train"),
+            "kind": "train_steps", "window_s": 40.0, "config": CONFIG,
             "trace": QUIET_TRACE, "peaks": PEAKS,
             "train": {"tokens_per_s_chip": 5000.0, "seq_len": 4096,
                       "steps": 10, "median_step_s": 1.0}}
@@ -49,6 +53,9 @@ STATED = {
     "step.decode_weight_bw_share.chat": 0.0,
     "step.prefill_mfu.batch": 0.0,
     "step.collective_exposed_share.train": 0.0,
+    # the readers of the program's own counters and spans
+    # (test_benchmark_program_readers.py has their sampled cases)
+    **{name: stated for name, (stated, _) in NEW.items()},
 }
 
 

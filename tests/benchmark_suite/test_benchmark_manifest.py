@@ -24,7 +24,8 @@ def _layer_metric_files():
 
 def test_top_level_keys_are_the_contracts():
     assert set(MANIFEST) == {"command", "paths", "run_seconds", "configs",
-                             "workloads", "end_to_end", "per_layer"}
+                             "workloads", "end_to_end", "per_layer",
+                             "trace_in_run"}
     assert MANIFEST["command"] == ["python3", "-m", "benchmark.run"]
     assert 1 <= MANIFEST["run_seconds"] <= 51
     assert len(json.dumps(MANIFEST)) < 64 * 1024
