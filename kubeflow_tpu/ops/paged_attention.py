@@ -18,6 +18,12 @@ a K-batched dot ([K, g, D] x [K, page, D] -> [K, g, page]). Unmapped (-1)
 and beyond-length pages are predicated off with ``pl.when`` (their index map
 clamps to page 0 — the DMA is wasted but never read).
 
+The pool operand is whatever ``[P, page, K, D]`` array the table's ids index.
+The decode step (serve/paged.py) hands in the WHOLE pool viewed flat
+``[L*P, page, K, D]`` with the layer's table offset by ``l*P``, so no
+per-layer slab is ever sliced out for the kernel: blocks are DMA'd from
+where the pages lie.
+
 int8 pools (``kv_cache_dtype="int8"``) ride the same grid with two extra
 per-page operands: the per-token-per-head scale planes ``[P, page, K]``
 (f32, ops/quantization.quantize_kv layout). The kernel dequantizes in

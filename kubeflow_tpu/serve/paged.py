@@ -24,7 +24,12 @@ Device side, the paged variants mirror the contiguous ones (engine.py): the
 page table rides into the dispatch as a ``[B, max_pages_per_slot]`` int32
 array; reads gather pages back into the ``[B, S, KV, Dh]`` layout XLA
 already tiles well, writes scatter ``(page, offset)`` with out-of-bounds
-drops for dead rows. The speculative verify dispatch
+drops for dead rows. The decode dispatch never takes a layer's slab out of
+the pool: it carries every plane whole, viewed flat ``[L*P, page, KV, Dh]``,
+through its step loop and its layer scan, writes layer ``l``'s rows in place
+at flat page ``l*P + page`` and reads through the table offset by ``l*P``
+(``_paged_decode_step``), so a step moves the rows it writes and the pages
+it attends to, not the pool. The speculative verify dispatch
 (serve/spec_decode.py ``paged_verify_step``) extends the same contract
 with a verify-length axis — k+1 (page, offset) writes per slot per round —
 and rejection rolls the page table back to the accepted length
@@ -302,8 +307,12 @@ class PageAllocator:
 # -- device-side paged steps ---------------------------------------------------
 #
 # Cache pytree: {"k": [L,P,pg,KV,Dh], "v": same, "table": [B, mpp] int32}
-# where mpp = max_seq_len // page. Table entries are page ids; -1 = unmapped
+# (int8 pools add the scale planes "ks"/"vs" [L,P,pg,KV] f32) where
+# mpp = max_seq_len // page. Table entries are page ids; -1 = unmapped
 # (reads are length-masked, writes aimed out of bounds and dropped).
+
+
+_PLANES = ("k", "v", "ks", "vs")     # pool planes; the scales iff int8
 
 
 def paged_gather(pool: jax.Array, table: jax.Array) -> jax.Array:  # traced
@@ -313,29 +322,37 @@ def paged_gather(pool: jax.Array, table: jax.Array) -> jax.Array:  # traced
     return pages.reshape(b, mpp * pool.shape[1], *pool.shape[2:])
 
 
-def _paged_decode_block(bp, x, positions, lengths, live, pool_k, pool_v,  # traced
-                        table, cfg: DecoderConfig, attn_impl: str = "gather",
-                        pool_ks=None, pool_vs=None, lora=None):
+def _paged_decode_block(bp, x, positions, lengths, live, pools, table,  # traced
+                        layer, num_pages: int, cfg: DecoderConfig,
+                        attn_impl: str = "gather", lora=None):
     """One transformer block for a [B,1] decode step against the page pool.
     Mirrors engine._decode_block; only the KV residency differs.
+
+    ``pools`` holds every plane of the WHOLE pool viewed flat —
+    ``k``/``v`` ``[L*P,pg,KV,Dh]`` and, iff the pool stores int8, the
+    per-token-per-head scales ``ks``/``vs`` ``[L*P,pg,KV]`` f32 — and
+    ``layer`` (a traced scalar) picks this block's ``num_pages`` (P) pages
+    out of it: page ``p`` of layer ``l`` is flat page ``l*P + p``. The
+    block writes its token's K/V rows into the planes it was handed and
+    returns them, so the caller can carry them through its loops and the
+    write lands in place; nothing here slices a layer's slab out or puts
+    one back.
 
     ``attn_impl``: "gather" materializes the slot's pages into the
     contiguous layout and runs the XLA decode attention (2× KV read);
     "pallas" reads pages directly via the paged-attention kernel
-    (ops/paged_attention.py — one DMA per page).
-
-    ``pool_ks``/``pool_vs`` ([P,pg,KV] f32, present iff the pool stores
-    int8): per-token-per-head dynamic scales. Writes quantize; reads
-    either gather+dequantize into the attention einsum's operand
-    ("gather") or ride the direct-page-read kernel, which dequantizes in
-    VMEM ("pallas") — the pool (the resident thing) holds 2× the tokens
-    per byte either way, and the kernel path also halves the per-step KV
-    HBM read."""
+    (ops/paged_attention.py — one DMA per page). int8 pools quantize on
+    write; reads either gather+dequantize into the attention einsum's
+    operand ("gather") or ride the kernel, which dequantizes in VMEM
+    ("pallas") — the pool (the resident thing) holds 2× the tokens per
+    byte either way, and the kernel path also halves the per-step KV HBM
+    read."""
     from kubeflow_tpu.serve.engine import _decode_attention
 
     dt = cfg.activation_dtype
-    kv_quant = pool_ks is not None
-    pg = pool_k.shape[1]
+    kv_quant = "ks" in pools
+    total, pg = pools["k"].shape[:2]
+    base = layer * num_pages
     h = L.rmsnorm(x, bp["ln1"], cfg)
     q = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wq"].astype(dt))
     k = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wk"].astype(dt))
@@ -348,50 +365,37 @@ def _paged_decode_block(bp, x, positions, lengths, live, pool_k, pool_v,  # trac
         v = L.apply_lora_layer(lora, "wv", h, v)
     q = L.rope(q, positions, cfg.rope_theta)
     k = L.rope(k, positions, cfg.rope_theta)
-    # Write position -> (page, offset); dead rows (and unmapped pages) aim
-    # out of bounds and DROP.
+    # Write position -> (flat page, offset). Dead rows and unmapped pages
+    # aim past the END of the flat pool and DROP: one past this layer's
+    # pages (base + P) is the next layer's page 0.
     bidx = jnp.arange(x.shape[0])
     page_slot = lengths // pg
     page_id = table[bidx, jnp.clip(page_slot, 0, table.shape[1] - 1)]
-    ok = live & (page_id >= 0)
-    pidx = jnp.where(ok, page_id, pool_k.shape[0])
+    pidx = jnp.where(live & (page_id >= 0), base + page_id, total)
     off = lengths % pg
-    nks = nvs = None
+    rows = {"k": k[:, 0], "v": v[:, 0]}
     if kv_quant:
         from kubeflow_tpu.ops.quantization import dequantize_kv, quantize_kv
 
-        kq, ks = quantize_kv(k[:, 0])
-        vq, vs = quantize_kv(v[:, 0])
-        nk = pool_k.at[pidx, off].set(kq, mode="drop")
-        nv = pool_v.at[pidx, off].set(vq, mode="drop")
-        nks = pool_ks.at[pidx, off].set(ks, mode="drop")
-        nvs = pool_vs.at[pidx, off].set(vs, mode="drop")
-        if attn_impl == "pallas":
-            from kubeflow_tpu.ops.paged_attention import (
-                paged_decode_attention,
-            )
+        rows["k"], rows["ks"] = quantize_kv(k[:, 0])
+        rows["v"], rows["vs"] = quantize_kv(v[:, 0])
+    pools = {name: pools[name].at[pidx, off].set(row, mode="drop")
+             for name, row in rows.items()}
+    # This layer's page table into the flat pool; -1 stays unmapped.
+    ltable = jnp.where(table >= 0, table + base, -1)
+    if attn_impl == "pallas":
+        from kubeflow_tpu.ops.paged_attention import paged_decode_attention
 
-            attn = paged_decode_attention(q, nk, nv, table, lengths,
-                                          pool_ks=nks, pool_vs=nvs)
-        else:
-            ck = dequantize_kv(paged_gather(nk, table),
-                               paged_gather(nks, table), dt)
-            cv = dequantize_kv(paged_gather(nv, table),
-                               paged_gather(nvs, table), dt)
-            attn = _decode_attention(q, ck, cv, lengths, cfg)
+        attn = paged_decode_attention(q, pools["k"], pools["v"], ltable,
+                                      lengths, pool_ks=pools.get("ks"),
+                                      pool_vs=pools.get("vs"))
     else:
-        nk = pool_k.at[pidx, off].set(k[:, 0], mode="drop")
-        nv = pool_v.at[pidx, off].set(v[:, 0], mode="drop")
-        if attn_impl == "pallas":
-            from kubeflow_tpu.ops.paged_attention import (
-                paged_decode_attention,
-            )
-
-            attn = paged_decode_attention(q, nk, nv, table, lengths)
-        else:
-            ck = paged_gather(nk, table)
-            cv = paged_gather(nv, table)
-            attn = _decode_attention(q, ck, cv, lengths, cfg)
+        ck = paged_gather(pools["k"], ltable)
+        cv = paged_gather(pools["v"], ltable)
+        if kv_quant:
+            ck = dequantize_kv(ck, paged_gather(pools["ks"], ltable), dt)
+            cv = dequantize_kv(cv, paged_gather(pools["vs"], ltable), dt)
+        attn = _decode_attention(q, ck, cv, lengths, cfg)
     proj = jnp.einsum("bshk,hkd->bsd", attn, bp["attn"]["wo"].astype(dt))
     if lora is not None and "wo" in lora["targets"]:
         proj = L.apply_lora_layer(
@@ -402,55 +406,52 @@ def _paged_decode_block(bp, x, positions, lengths, live, pool_k, pool_v,  # trac
         mlp_out, _ = L.moe_block(bp["mlp"], h, cfg)
     else:
         mlp_out = L.mlp_block(bp["mlp"], h, cfg)
-    return x + mlp_out, nk, nv, nks, nvs
+    return x + mlp_out, pools
 
 
 def _paged_decode_step(params: Params, cache: dict, tokens: jax.Array,  # traced
                        lengths: jax.Array, live: jax.Array,
                        cfg: DecoderConfig, attn_impl: str = "gather",
                        lora=None):
-    """One [B,1] decode step over the page pool (≈ engine._decode_step)."""
+    """One [B,1] decode step over the page pool (≈ engine._decode_step).
+
+    The pool is a CARRY of the layer scan, never a scanned input/output: a
+    scan's stacked outputs are a new buffer, so scanning over ``[L,P,...]``
+    copies every layer's slab out and back to write B rows of it. Each
+    plane is viewed flat ``[L*P,...]`` (merging the two leading dimensions
+    is a bitcast), carried beside ``x`` and written in place by the block
+    at ``layer*P + page``; the scanned inputs are the layer's weights, its
+    LoRA slice and its index. The pytree handed back is ``[L,P,...]``
+    again, so every other program sees the cache it always saw."""
     dt = cfg.activation_dtype
-    kv_quant = "ks" in cache
     x = params["embed"].astype(dt)[tokens[:, None]]
     if cfg.embed_scale:
         x = x * jnp.asarray(cfg.hidden ** 0.5, dt)
     positions = lengths[:, None]
     table = cache["table"]
-    lora_xs = L.slice_layers(lora)
+    n_layers, num_pages = cache["k"].shape[:2]
+    flat = {n: cache[n].reshape(-1, *cache[n].shape[2:])
+            for n in _PLANES if n in cache}
 
-    if kv_quant:
-        def body(x, scan_in):
-            bp, pk, pv, pks, pvs, lsl = scan_in
-            x, nk, nv, nks, nvs = _paged_decode_block(
-                bp, x, positions, lengths, live, pk, pv, table, cfg,
-                attn_impl=attn_impl, pool_ks=pks, pool_vs=pvs,
-                lora=L.layer_view(lora, lsl))
-            return x, (nk, nv, nks, nvs)
+    def body(carry, scan_in):
+        x, pools = carry
+        bp, lsl, layer = scan_in
+        return _paged_decode_block(
+            bp, x, positions, lengths, live, pools, table, layer, num_pages,
+            cfg, attn_impl=attn_impl, lora=L.layer_view(lora, lsl)), None
 
-        x, scanned = jax.lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"],
-                      cache["ks"], cache["vs"], lora_xs))
-    else:
-        def body(x, scan_in):
-            bp, pk, pv, lsl = scan_in
-            x, nk, nv, _, _ = _paged_decode_block(
-                bp, x, positions, lengths, live, pk, pv, table, cfg,
-                attn_impl=attn_impl, lora=L.layer_view(lora, lsl))
-            return x, (nk, nv)
-
-        x, scanned = jax.lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"], lora_xs))
-    nk, nv = scanned[0], scanned[1]
+    (x, flat), _ = jax.lax.scan(
+        body, (x, flat),
+        (params["layers"], L.slice_layers(lora),
+         jnp.arange(n_layers, dtype=jnp.int32)))
     x = L.rmsnorm(x, params["final_norm"], cfg)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = jnp.einsum("bsd,dv->bsv", x, head.astype(dt),
                         preferred_element_type=jnp.float32)[:, 0]
     if cfg.logits_softcap is not None:
         logits = jnp.tanh(logits / cfg.logits_softcap) * cfg.logits_softcap
-    out = {"k": nk, "v": nv, "table": table}
-    if kv_quant:
-        out["ks"], out["vs"] = scanned[2], scanned[3]
+    out = {n: p.reshape(cache[n].shape) for n, p in flat.items()}
+    out["table"] = table
     return logits, out
 
 
@@ -512,7 +513,7 @@ def copy_pages(cache: dict, src: jax.Array, dst: jax.Array) -> dict:  # traced
     dispatch instead of recomputing it. Out-of-range ``dst`` ids (the
     power-of-two pad) drop their writes."""
     out = dict(cache)
-    for name in ("k", "v", "ks", "vs"):
+    for name in _PLANES:
         pool = cache.get(name)
         if pool is None:
             continue

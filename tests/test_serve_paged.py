@@ -513,3 +513,200 @@ class TestPagedAttentionKernel:
             LLMEngine(cfg, BatchingSpec(
                 max_batch_size=2, max_seq_len=64, paged=True, page_size=16,
                 paged_attn_impl="flash"), params=params)
+
+
+# -- the decode step writes the pool in place (flat carry) ---------------------
+
+def _oracle_decode_step(params, cache, tokens, lengths, live, cfg, attn_impl):
+    """The decode step in its plain per-layer form, kept here as the
+    reference: for each layer slice that layer's ``[P,pg,KV,Dh]`` planes out
+    of the pool, write the token's row, attend over the slice, put the slice
+    back. Same building blocks and arithmetic as serve/paged.py's step; only
+    the pool's residency differs."""
+    from kubeflow_tpu.models import layers as L
+    from kubeflow_tpu.ops.paged_attention import paged_decode_attention
+    from kubeflow_tpu.ops.quantization import dequantize_kv, quantize_kv
+    from kubeflow_tpu.serve.engine import _decode_attention
+    from kubeflow_tpu.serve.paged import paged_gather
+
+    dt = cfg.activation_dtype
+    table = cache["table"]
+    pools = {n: p for n, p in cache.items() if n != "table"}
+    num_pages, pg = pools["k"].shape[1:3]
+    x = params["embed"].astype(dt)[tokens[:, None]]
+    positions = lengths[:, None]
+    page_id = table[jnp.arange(tokens.shape[0]),
+                    jnp.clip(lengths // pg, 0, table.shape[1] - 1)]
+    pidx = jnp.where(live & (page_id >= 0), page_id, num_pages)
+    off = lengths % pg
+    def one_layer(carry, scan_in):
+        x, pools = carry
+        bp, layer = scan_in
+        h = L.rmsnorm(x, bp["ln1"], cfg)
+        q = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wq"].astype(dt))
+        k = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wk"].astype(dt))
+        v = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wv"].astype(dt))
+        q = L.rope(q, positions, cfg.rope_theta)
+        k = L.rope(k, positions, cfg.rope_theta)
+        rows = {"k": k[:, 0], "v": v[:, 0]}
+        if "ks" in pools:
+            rows["k"], rows["ks"] = quantize_kv(k[:, 0])
+            rows["v"], rows["vs"] = quantize_kv(v[:, 0])
+        slab = {n: pools[n][layer].at[pidx, off].set(r, mode="drop")
+                for n, r in rows.items()}
+        if attn_impl == "pallas":
+            attn = paged_decode_attention(
+                q, slab["k"], slab["v"], table, lengths,
+                pool_ks=slab.get("ks"), pool_vs=slab.get("vs"))
+        else:
+            ck = paged_gather(slab["k"], table)
+            cv = paged_gather(slab["v"], table)
+            if "ks" in slab:
+                ck = dequantize_kv(ck, paged_gather(slab["ks"], table), dt)
+                cv = dequantize_kv(cv, paged_gather(slab["vs"], table), dt)
+            attn = _decode_attention(q, ck, cv, lengths, cfg)
+        x = x + jnp.einsum("bshk,hkd->bsd", attn,
+                           bp["attn"]["wo"].astype(dt))
+        x = x + L.mlp_block(bp["mlp"], L.rmsnorm(x, bp["ln2"], cfg), cfg)
+        return (x, {n: pools[n].at[layer].set(slab[n]) for n in pools}), None
+
+    (x, pools), _ = jax.lax.scan(
+        one_layer, (x, pools),
+        (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+    x = L.rmsnorm(x, params["final_norm"], cfg)
+    logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"].astype(dt),
+                        preferred_element_type=jnp.float32)[:, 0]
+    return logits, {**pools, "table": table}
+
+
+class TestDecodeWritesPoolInPlace:
+    """The decode step carries the WHOLE pool, viewed flat [L*P,...],
+    through its layer scan and writes a layer's rows at ``layer*P + page``
+    (serve/paged.py::_paged_decode_step). It must be bitwise what the
+    per-layer form computes, touch no page of another layer, and compile
+    to a dispatch that never copies the pool."""
+
+    PG, NP, MPP = 4, 12, 3          # page size, pages a layer, pages a slot
+
+    def _case(self, kv_dtype):
+        import numpy as np
+
+        cfg = preset("tiny", vocab_size=64, n_layers=3)
+        params = init_decoder_params(jax.random.PRNGKey(1), cfg)
+        rng = np.random.default_rng(7)
+        shape = (cfg.n_layers, self.NP, self.PG, cfg.n_kv_heads, cfg.head_dim)
+        if kv_dtype == "int8":
+            cache = {n: jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+                     for n in ("k", "v")}
+            for n in ("ks", "vs"):
+                cache[n] = jnp.asarray(
+                    rng.uniform(0.001, 0.02, shape[:-1]), jnp.float32)
+        else:
+            cache = {n: jnp.asarray(rng.normal(size=shape),
+                                    cfg.activation_dtype) for n in ("k", "v")}
+        # Page 0 belongs to no slot: under the flat view it is where a
+        # dropped write aimed at ``layer*P + P`` would land, one layer up.
+        # row 0 plain (its second page); row 1 DEAD on mapped pages; row 2
+        # live but its write page is unmapped; row 3 crosses from its first
+        # page to its second.
+        cache["table"] = jnp.asarray(
+            [[3, 5, -1], [7, 2, -1], [9, -1, -1], [4, 11, 8]], jnp.int32)
+        lengths = np.array([self.PG, 6, self.PG, self.PG - 2], np.int32)
+        live = np.array([True, False, True, True])
+        return cfg, params, cache, lengths, live
+
+    @pytest.mark.parametrize("attn_impl", ["gather", "pallas"])
+    @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+    def test_bitwise_equal_to_per_layer_oracle(self, kv_dtype, attn_impl):
+        import numpy as np
+
+        from kubeflow_tpu.serve.paged import _paged_decode_step
+
+        cfg, params, cache, lengths, live = self._case(kv_dtype)
+        initial = {n: np.asarray(p) for n, p in cache.items()}
+        table = initial["table"]
+        step = jax.jit(lambda c, t, ln, lv: _paged_decode_step(
+            params, c, t, ln, lv, cfg, attn_impl=attn_impl))
+        oracle = jax.jit(lambda c, t, ln, lv: _oracle_decode_step(
+            params, c, t, ln, lv, cfg, attn_impl))
+        # "gather" reads SOME page for an unmapped table entry inside the
+        # attended range (the clamp picks which: page 0 of the layer's slab
+        # there, of the flat pool here), so row 2's logits are garbage of
+        # two kinds; the kernel skips the entry and agrees on every row.
+        # No engine dispatches a live row in that state.
+        rows = [0, 1, 3] if attn_impl == "gather" else [0, 1, 2, 3]
+        ours, theirs = dict(cache), dict(cache)
+        written = set()
+        rng = np.random.default_rng(11)
+        for _ in range(4):
+            tokens = jnp.asarray(rng.integers(1, cfg.vocab_size, 4), jnp.int32)
+            ln, lv = jnp.asarray(lengths), jnp.asarray(live)
+            got, ours = step(ours, tokens, ln, lv)
+            want, theirs = oracle(theirs, tokens, ln, lv)
+            np.testing.assert_array_equal(np.asarray(got)[rows],
+                                          np.asarray(want)[rows])
+            assert set(ours) == set(theirs) == set(cache)
+            for n in cache:
+                assert ours[n].shape == cache[n].shape
+                np.testing.assert_array_equal(np.asarray(ours[n]),
+                                              np.asarray(theirs[n]))
+            for b in np.flatnonzero(live):
+                page = table[b, lengths[b] // self.PG]
+                if page >= 0:
+                    written.add((int(page), int(lengths[b] % self.PG)))
+            lengths = np.where(live, lengths + 1, lengths)
+        # Row 3 wrote on both sides of its page boundary; rows 1 and 2
+        # wrote nothing; every (page, offset) outside the written set is
+        # what it was IN EVERY LAYER, page 0 above all.
+        assert {p for p, _ in written} == {5, 4, 11}
+        for n in ("k", "v", "ks", "vs"):
+            if n not in cache:
+                continue
+            after = np.asarray(ours[n])
+            touched = np.zeros(after.shape[:3], bool)
+            for page, off in written:
+                touched[:, page, off] = True
+            changed = (after != initial[n]).reshape(*touched.shape, -1).any(-1)
+            assert not (changed & ~touched).any()
+            assert changed[:, [5, 4, 11]].any(axis=(1, 2)).all()
+
+    def test_compiled_dispatch_never_copies_the_pool(self):
+        """Optimized HLO of the 8-step dispatch with the pool donated, at a
+        pool large enough to dominate the program: no ``copy`` of a
+        pool-shaped operand, temporaries under ONE plane, both planes
+        aliased input to output. (The scanned-slab form read 3 such copies
+        and three planes of temporaries here.) float32, because the CPU
+        backend widens a bf16 scatter's whole operand to f32 and back: a
+        pool-sized artefact of this backend, not of the program."""
+        import re
+
+        from kubeflow_tpu.serve.paged import paged_decode_multi
+
+        cfg = preset("tiny", vocab_size=64, dtype="float32",
+                     param_dtype="float32")
+        params = init_decoder_params(jax.random.PRNGKey(1), cfg)
+        slots, pg, mpp, num_pages = 4, 16, 4, 2048
+        pool = (cfg.n_layers, num_pages, pg, cfg.n_kv_heads, cfg.head_dim)
+        plane = jax.ShapeDtypeStruct(pool, cfg.activation_dtype)
+        plane_bytes = plane.size * plane.dtype.itemsize
+        i32 = jax.ShapeDtypeStruct((slots,), jnp.int32)
+        f32 = jax.ShapeDtypeStruct((slots,), jnp.float32)
+        compiled = jax.jit(
+            lambda c, tbl, t, ln, lv, tmp, tk, tp, st, bd, key:
+            paged_decode_multi(params, {**c, "table": tbl}, t, ln, lv, tmp,
+                               tk, tp, st, bd, key, cfg, 8,
+                               sample_mode="greedy", attn_impl="gather"),
+            donate_argnums=(0,)).lower(
+                {"k": plane, "v": plane},
+                jax.ShapeDtypeStruct((slots, mpp), jnp.int32), i32, i32,
+                jax.ShapeDtypeStruct((slots,), jnp.bool_), f32, i32, f32,
+                i32, i32, jax.ShapeDtypeStruct((2,), jnp.uint32)).compile()
+        dims = "|".join(",".join(map(str, s)) for s in
+                        (pool, (pool[0] * pool[1],) + pool[2:], pool[1:]))
+        copies = re.findall(
+            rf"= \w+\[(?:{dims})\]\S* copy(?:-start)?\(", compiled.as_text())
+        assert not copies, copies
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < plane_bytes, (
+            mem.temp_size_in_bytes, plane_bytes)
+        assert mem.alias_size_in_bytes >= 2 * plane_bytes
