@@ -45,7 +45,7 @@ def serving(conf: dict, traffic: dict) -> dict:
     from jax.sharding import SingleDeviceSharding
     from scripts.aot_validate_8b import _mesh_on, paged_serve_analysis
 
-    from benchmark import reference, weights
+    from benchmark import architecture, weights
 
     e, prog = traffic["engine"], conf["program"]
     out = paged_serve_analysis(
@@ -58,15 +58,16 @@ def serving(conf: dict, traffic: dict) -> dict:
     dtype = jnp.dtype(prog["overrides"]["param_dtype"])
     p_sds = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=dev),
-        jax.eval_shape(lambda: weights.param_tree(
-            conf, jax.random.PRNGKey(0), dtype)))
+        weights.param_shapes(conf, dtype))
     plen, n_dec = max(conf["correctness"]["sequences"])
     toks = jax.ShapeDtypeStruct((plen + n_dec,), jnp.int32, sharding=dev)
+    logits = architecture.part(conf, "reference").logits
+    param_tree = architecture.part(conf, "weights").param_tree
     with jax.default_matmul_precision("highest"):
-        ref = jax.jit(lambda p, t: reference.logits(
+        ref = jax.jit(lambda p, t: logits(
             p, t, conf, last=520)).lower(p_sds, toks).compile()
     out["reference_logits"] = {**_gb(ref), "tokens": plen + n_dec}
-    init = jax.jit(lambda k: weights.param_tree(conf, k, dtype),
+    init = jax.jit(lambda k: param_tree(conf, k, dtype),
                    out_shardings=dev).lower(
         jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=dev)).compile()
     out["weights_init"] = _gb(init)
@@ -79,11 +80,10 @@ def training(conf: dict, traffic: dict) -> dict:
     from jax.sharding import NamedSharding, PartitionSpec
     from scripts.aot_validate_8b import _mesh_on, train_step_analysis
 
-    from kubeflow_tpu.models.config import preset
     from kubeflow_tpu.train.optim import OptimizerConfig
     from kubeflow_tpu.train.step import setup_train
 
-    from benchmark import correctness, weights
+    from benchmark import architecture, correctness, weights
 
     prog, mesh_axes = conf["program"], conf["mesh"]
     chips = 1
@@ -95,8 +95,8 @@ def training(conf: dict, traffic: dict) -> dict:
         seq_len=traffic["seq_len"], model_overrides=prog["overrides"],
         optimizer=conf["trainer"]["optimizer"])}
     mesh = _mesh_on(TOPOLOGY, mesh_axes)
-    cfg = preset(prog["preset"], **prog["overrides"],
-                 max_seq_len=traffic["seq_len"])
+    cfg = architecture.part(conf, "program").program_config(
+        conf, max_seq_len=traffic["seq_len"])
     task = setup_train(cfg, OptimizerConfig.from_dict(
         {"total_steps": 10, **conf["trainer"]["optimizer"]}), mesh,
         attn_impl="pallas", init_state=False)
@@ -104,8 +104,7 @@ def training(conf: dict, traffic: dict) -> dict:
     dtype = jnp.dtype(cfg.param_dtype)
     p_sds = jax.tree.map(
         lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-        jax.eval_shape(lambda: weights.param_tree(
-            conf, jax.random.PRNGKey(0), dtype)), p_sh)
+        weights.param_shapes(conf, dtype), p_sh)
     micro = conf["correctness"]["micro"]
     gb, seq = traffic["global_batch"], traffic["seq_len"]
     axes = tuple(a for a, n in mesh_axes.items() if n > 1)
@@ -116,7 +115,8 @@ def training(conf: dict, traffic: dict) -> dict:
         ref = jax.jit(run).lower(p_sds, jax.ShapeDtypeStruct(
             (gb // micro, micro, seq + 1), jnp.int32, sharding=rep)).compile()
     out["reference_loss_grad"] = {**_gb(ref), "micro": micro}
-    init = jax.jit(lambda k: weights.param_tree(conf, k, dtype),
+    param_tree = architecture.part(conf, "weights").param_tree
+    init = jax.jit(lambda k: param_tree(conf, k, dtype),
                    out_shardings=p_sh).lower(
         jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)).compile()
     out["weights_init"] = _gb(init)

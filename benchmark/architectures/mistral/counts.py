@@ -1,5 +1,7 @@
-"""Operations and bytes the ALGORITHM needs, from a configuration's published
-sizes (the keys of the model's own ``config.json``). Kept with the benchmark
+"""Operations and bytes a Mistral / Mixtral decoder NEEDS, from a
+configuration's published sizes (the keys of the model's own ``config.json``):
+the ``mistral`` architecture's counts, the old ``benchmark/flops.py`` moved
+whole. Kept with the benchmark
 so that every PR divides by the same count. Recomputation, padding and the
 MoE dispatch's capacity slack are work the program chose to do, not work the
 model needs: none of it is counted, so a utilization built on these counts

@@ -24,7 +24,7 @@ import json
 import sys
 import time
 
-from benchmark import correctness, reference
+from benchmark import architecture, correctness, reference
 from benchmark import manifest as mf
 
 
@@ -38,10 +38,9 @@ def serving_sides(conf: dict, traffic: dict, seed: int, sides,
     from kubeflow_tpu.core.serving import BatchingSpec
     from kubeflow_tpu.serve.engine import LLMEngine
 
-    from benchmark.serving import decoder_config
     from benchmark.weights import make_params
 
-    cfg = decoder_config(conf)
+    cfg = architecture.part(conf, "program").program_config(conf)
     spec = conf["correctness"]
     chunk = traffic["engine"]["chunked_prefill_tokens"]
     params = make_params(conf, seed, cfg.param_dtype)
@@ -86,23 +85,18 @@ def serving_sides(conf: dict, traffic: dict, seed: int, sides,
 
 
 def training_sides(conf: dict, traffic: dict, seed: int, devices) -> dict:
-    import jax
-
-    from kubeflow_tpu.models.decoder import decoder_param_specs
-    from kubeflow_tpu.parallel.sharding import shard_params
     from kubeflow_tpu.runtime.mesh import build_mesh
 
-    from benchmark.serving import decoder_config
     from benchmark.traffic import train_batch
-    from benchmark.weights import make_params, param_tree
+    from benchmark.weights import make_params, param_shapes
 
-    cfg = decoder_config(conf)
+    program = architecture.part(conf, "program")
+    cfg = program.program_config(conf)
     mesh = build_mesh(conf["mesh"], devices)
-    p_shape = jax.eval_shape(lambda: param_tree(
-        conf, jax.random.PRNGKey(0), jax.numpy.dtype(cfg.param_dtype)))
     params = make_params(
         conf, seed, cfg.param_dtype,
-        shardings=shard_params(p_shape, decoder_param_specs(cfg), mesh))
+        shardings=program.param_shardings(
+            cfg, mesh, param_shapes(conf, cfg.param_dtype)))
     batch = train_batch(seed, 0, traffic["global_batch"], traffic["seq_len"],
                         conf["vocab_size"])
     axes = tuple(a for a, n in conf["mesh"].items() if n > 1)

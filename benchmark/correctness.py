@@ -1,6 +1,6 @@
 """What decides ``correct``: the program's outputs against the plain
-reference (benchmark/reference.py), at the cell's own widths and cut depth,
-on weights made from the seed, outside the timed window.
+reference (the architecture's ``reference.py``), at the cell's own widths
+and cut depth, on weights made from the seed, outside the timed window.
 
 Serving compares LOGITS (with random weights the largest logit changes on
 rounding, so tokens say nothing): a seeded sequence goes through the
@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark import reference
+from benchmark import architecture, reference
 from benchmark.stats import percentile
 
 
@@ -89,8 +89,9 @@ def engine_logits(engine, tokens: np.ndarray, plen: int, n_decode: int):
 
 def reference_logits(params, tokens: np.ndarray, hf: dict, last: int,
                      quant=None):
-    fn = jax.jit(lambda p, t: reference.logits(
-        p, t, hf, quant or reference._same, last=last))
+    logits = architecture.part(hf, "reference").logits
+    fn = jax.jit(lambda p, t: logits(
+        p, t, hf, quant or reference.same, last=last))
     with jax.default_matmul_precision("highest"):
         return fn(params, jnp.asarray(tokens))
 
@@ -154,11 +155,12 @@ def loss_and_grad_norm_program(hf: dict, n_targets: int, *, quant=None,
     [P, micro, S + 1]) -> (mean loss, global gradient norm) over
     ``n_targets`` targets. Apart so that ``benchmark/aot_sizes.py`` lowers
     the same program for a described chip."""
-    q = quant or reference._same
+    q = quant or reference.same
+    sequence_nll = architecture.part(hf, "reference").sequence_nll
 
     def total_nll(p, mb):
         return jnp.sum(jax.vmap(
-            lambda t: reference.sequence_nll(p, t, hf, q),
+            lambda t: sequence_nll(p, t, hf, q),
             spmd_axis_name=batch_axes)(mb))
 
     def run(p, passes):

@@ -8,7 +8,7 @@ leaves early when every slot is done; its steps are counted in the trace, as
 the executions of the paged-attention kernel inside it over the layers. 0.0
 when the traced seconds hold no decode dispatch."""
 
-from benchmark import flops, tracing
+from benchmark import architecture, tracing
 from benchmark.stats import median
 
 DECLARATION = {"unit": "%", "better": "higher", "source": "device_trace",
@@ -30,7 +30,7 @@ def read(run: dict):
             per_step.append(dur / (n / layers))
     if not per_step:
         return 0.0
-    least = flops.decode_weight_bytes(
+    least = architecture.part(run["config"], "counts").decode_weight_bytes(
         run["config"], run["weight_bytes_per_param"]) \
         / run["peaks"]["hbm_bytes_per_s"]
     return 100.0 * least / median(per_step)

@@ -1,8 +1,9 @@
 """Utilisation of the chunk-prefill programs: the operations the prefilled
-tokens NEED (benchmark/flops.py: 2 per multiplied parameter with the top-k
-experts only, plus causal attention; padding of a prompt's last chunk and the
-dispatch's capacity slack are not needed and not counted) over the device
-time of those programs in the trace times the chip's bf16 peak.
+tokens NEED (the architecture's ``counts.py``: 2 per multiplied parameter
+with the top-k experts only, plus causal attention; padding of a prompt's
+last chunk and the dispatch's capacity slack are not needed and not counted)
+over the device time of those programs in the trace times the chip's bf16
+peak.
 
 The trace names a program and not its prompt, so the needed operations of one
 chunk are the window's mean: all the prompts completed in the window, over

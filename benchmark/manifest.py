@@ -4,8 +4,12 @@ files that belong to each name, and the check of the last line.
 The harness holds no table of cells, configurations or metrics in code. A
 cell names a configuration (whose ``file`` BENCHMARK.json gives) and a traffic
 mix (``benchmark/traffic/<mix>.json``); a per-layer metric is read by
-``benchmark/layer_metrics/<metric name>.py``. Adding one is adding files and
-entries.
+``benchmark/layer_metrics/<metric name>.py``; a configuration's file names
+its architecture, and whatever depends on the model's equations (weights,
+plain reference, counts, the mapping onto the program's config) is the four
+files of ``benchmark/architectures/<name>/`` (benchmark/architecture.py).
+Adding a cell, a metric, a configuration or an architecture is adding files
+and entries; no file that is there is edited.
 """
 
 from __future__ import annotations
@@ -14,6 +18,7 @@ import importlib.util
 import json
 import math
 import os
+import re
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -78,18 +83,23 @@ def declared(manifest: dict, cell_name: str, kind: str) -> dict[str, dict]:
             if "workloads" not in m or cell_name in m["workloads"]}
 
 
+def load_module_file(package: str, name: str, path: str):
+    """The module in the file ``path``, which a name from BENCHMARK.json or
+    a configuration chose (so it need not be an identifier)."""
+    spec = importlib.util.spec_from_file_location(
+        package + "." + re.sub(r"[^A-Za-z0-9_]", "_", name), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_layer_metric(name: str):
     """The reader module of one per-layer metric, found by the metric's
     name: ``read(run) -> float | None`` and ``DECLARATION``."""
     path = os.path.join(LAYER_METRICS_DIR, name + ".py")
     if not os.path.exists(path):
         raise ManifestError(f"per-layer metric {name!r} has no reader {path}")
-    spec = importlib.util.spec_from_file_location(
-        "benchmark.layer_metrics." + name.replace(".", "_").replace("-", "_"),
-        path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return load_module_file("benchmark.layer_metrics", name, path)
 
 
 def read_layer_metrics(manifest: dict, cell_name: str, run: dict) -> dict:

@@ -1,7 +1,8 @@
 """A serving cell: the program's engine behind its real HTTP server on a
 loopback port, driven by the benchmark's own load generator in a child
 process. From the program it takes ``LLMEngine``, ``ModelServer``,
-``BatchingSpec``, the decoder preset and the engine's counters; everything
+``BatchingSpec``, the engine's counters and, through the configuration's
+architecture (``benchmark/architecture.py``), its config object; everything
 that measures is the benchmark's.
 """
 
@@ -14,7 +15,7 @@ import subprocess
 import sys
 import time
 
-from benchmark import correctness, flops, tracing
+from benchmark import architecture, correctness, tracing
 from benchmark.device import (
     CompileCounter, memory_peak_bytes, sleep_until,
 )
@@ -45,31 +46,6 @@ class IdTokenizer:
 
     def decode(self, ids: list[int]) -> str:
         return "".join(f"{int(t)} " for t in ids)
-
-
-def decoder_config(conf: dict):
-    """The program's ``DecoderConfig`` from the configuration file: the
-    preset it starts from plus every override, then held against the
-    published sizes in the same file, so the two cannot drift apart."""
-    from kubeflow_tpu.models.config import preset
-
-    prog = conf["program"]
-    cfg = preset(prog["preset"], **prog["overrides"])
-    same = {"hidden_size": cfg.hidden, "num_attention_heads": cfg.n_heads,
-            "num_key_value_heads": cfg.n_kv_heads, "head_dim": cfg.head_dim,
-            "intermediate_size": cfg.mlp_dim, "vocab_size": cfg.vocab_size,
-            "num_hidden_layers": cfg.n_layers, "rope_theta": cfg.rope_theta,
-            "rms_norm_eps": cfg.norm_eps,
-            "num_local_experts": cfg.num_experts,
-            "tie_word_embeddings": cfg.tie_embeddings}
-    if cfg.num_experts:
-        same["num_experts_per_tok"] = cfg.experts_per_token
-    for key, value in same.items():
-        if conf.get(key, 0 if key == "num_local_experts" else None) != value:
-            raise RunFailed(
-                f"{key}: the configuration file says {conf.get(key)!r}, the "
-                f"program's config built from it has {value!r}")
-    return cfg
 
 
 def engine_snapshot(engine) -> dict:
@@ -266,12 +242,13 @@ def run(manifest: dict, cell: dict, conf: dict, traffic: dict, *, seed: int,
     from kubeflow_tpu.serve.server import ModelServer
 
     compiles = CompileCounter()
-    cfg = decoder_config(conf)
+    cfg = architecture.part(conf, "program").program_config(conf)
+    counts = architecture.part(conf, "counts")
     batching = BatchingSpec(**traffic["engine"])
     params = make_params(conf, seed, cfg.param_dtype)
     engine = LLMEngine(cfg, batching, params=params, seed=seed & 0x7FFFFFFF)
     log(f"engine built at {time.monotonic() - t_start:.1f}s: "
-        f"{flops.params_total(conf) / 1e9:.2f} B parameters, "
+        f"{counts.params_total(conf) / 1e9:.2f} B parameters, "
         f"{engine._num_pages} pages of {engine.page_size}")
 
     numbers = correctness.serving_numbers(engine, params, conf,
@@ -369,7 +346,7 @@ def run(manifest: dict, cell: dict, conf: dict, traffic: dict, *, seed: int,
         "weight_bytes_per_param": jax.numpy.dtype(cfg.param_dtype).itemsize,
         "prefill": {
             "chunk": chunk, "mean_useful_flops_per_chunk":
-                sum(flops.prefill_flops(conf, n) for n in lens)
+                sum(counts.prefill_flops(conf, n) for n in lens)
                 / sum(n_chunks(n, chunk) for n in lens)},
         "values": values,
     }

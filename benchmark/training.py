@@ -14,11 +14,11 @@ import time
 
 import numpy as np
 
-from benchmark import correctness, flops, tracing
+from benchmark import architecture, correctness, tracing
 from benchmark.device import CompileCounter, memory_peak_bytes
-from benchmark.serving import RunFailed, decoder_config, program_counters
+from benchmark.serving import RunFailed, program_counters
 from benchmark.traffic import train_batch
-from benchmark.weights import make_params, param_tree
+from benchmark.weights import make_params, param_shapes
 
 
 class _WindowOver(Exception):
@@ -58,22 +58,20 @@ def run(manifest: dict, cell: dict, conf: dict, traffic: dict, *, seed: int,
         seconds: float, trace: int, dev: dict, t_start: float,
         out_dir: str, log) -> dict:
     import jax
-    from kubeflow_tpu.models.decoder import decoder_param_specs
-    from kubeflow_tpu.parallel.sharding import shard_params
     from kubeflow_tpu.runtime.mesh import build_mesh
     from kubeflow_tpu.train.trainer import Trainer
 
     compiles = CompileCounter()
-    cfg = decoder_config(conf)
+    program = architecture.part(conf, "program")
+    cfg = program.program_config(conf)
     devices = jax.devices()[:dev["count"]]
     mesh = build_mesh(conf["mesh"], devices)
     tcfg = trainer_config(conf, traffic, seed)
     # The benchmark's weights and batches in place of the trainer's own: the
     # reference is given the same arrays and nothing of the program's. It
     # runs BEFORE the trainer exists, while the chips hold the weights alone.
-    p_shape = jax.eval_shape(lambda: param_tree(
-        conf, jax.random.PRNGKey(0), jax.numpy.dtype(cfg.param_dtype)))
-    p_sh = shard_params(p_shape, decoder_param_specs(cfg), mesh)
+    p_sh = program.param_shardings(
+        cfg, mesh, param_shapes(conf, cfg.param_dtype))
     params = make_params(conf, seed, cfg.param_dtype, shardings=p_sh)
     gb, seq = traffic["global_batch"], traffic["seq_len"]
     data = SeededBatches(seed, gb, seq, conf["vocab_size"])
@@ -98,7 +96,8 @@ def run(manifest: dict, cell: dict, conf: dict, traffic: dict, *, seed: int,
     del params
     trainer.data = data
     log(f"trainer built at {time.monotonic() - t_start:.1f}s: "
-        f"{flops.params_total(conf) / 1e9:.2f} B parameters, mesh "
+        f"{architecture.part(conf, 'counts').params_total(conf) / 1e9:.2f} "
+        f"B parameters, mesh "
         f"{conf['mesh']}, batch {gb} x {seq}")
 
     warm = int(traffic["warmup_steps"])

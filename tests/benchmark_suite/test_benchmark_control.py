@@ -13,20 +13,27 @@ from benchmark import manifest as mf
 SEEDS = [5, 2**31 + 6, 77]
 
 
-@pytest.mark.parametrize("config,traffic", [
-    ("rehearsal-tiny", "rehearsal-open"),
-    ("rehearsal-tiny-moe", "rehearsal-closed")])
-def test_serving_comparison_fails_one_precision_step_down(config, traffic):
+@pytest.mark.parametrize("config,traffic,controls", [
+    ("rehearsal-tiny", "rehearsal-open", ("reference_fp8", "program_int8")),
+    ("rehearsal-tiny-moe", "rehearsal-closed",
+     ("reference_fp8", "program_int8")),
+    # Another architecture, through its own reference. The program's int8
+    # path reads 0.0034-0.0042 at these widths beside a sound 0.0032-0.0038
+    # (twelve seeds, CPU): it separates nothing here, so the reference in
+    # float8 (0.036-0.041) is this fixture's control.
+    ("rehearsal-tiny-gemma", "rehearsal-open", ("reference_fp8",))])
+def test_serving_comparison_fails_one_precision_step_down(config, traffic,
+                                                          controls):
     conf = mf.load_json(f"benchmark/configs/{config}.json")
     limits = conf["correctness"]["limits"]
     tr = mf.load_traffic(traffic)
     sound, low = [], []
     for seed in SEEDS:
-        sides = control.serving_sides(
-            conf, tr, seed, ["program", "reference_fp8", "program_int8"])
+        sides = control.serving_sides(conf, tr, seed,
+                                      ["program", *controls])
         ok, _ = correctness.judge(sides["program"], limits)
         assert ok, sides["program"]
-        for side in ("program_int8", "reference_fp8"):
+        for side in controls:
             ok, lines = correctness.judge(sides[side], limits)
             assert not ok, (side, lines)
             for name in limits:          # each number fails by itself

@@ -1,18 +1,20 @@
 """The reduction from a trace to numbers, on hand-built traces, and the FLOP
-and byte functions against hand counts for one Mistral and one Mixtral
-layer."""
+and byte functions (the ``mistral`` architecture's ``counts.py``) against
+hand counts for one Mistral and one Mixtral layer."""
 
 import json
 import os
 
 import pytest
 
-from benchmark import flops, tracing
+from benchmark import architecture, tracing
 from benchmark import manifest as mf
 
 MANIFEST = mf.load_manifest()
 MISTRAL = mf.load_config(MANIFEST, "mistral-7b")
 MIXTRAL = mf.load_config(MANIFEST, "mixtral-8x7b")
+# Both files name the ``mistral`` architecture: its counts, through the seam.
+flops = architecture.part(MISTRAL, "counts")
 
 
 def trace_of(*devices, window=1.0):
