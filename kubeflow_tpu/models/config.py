@@ -39,7 +39,33 @@ class DecoderConfig:
     # compute (k/E of dense FLOPs; tokens over a full expert drop).
     # "dense": every expert computes every token, one-hot combine — the
     # FLOP-inefficient but drop-free oracle the dispatch path tests against.
+    # "sorted": rows sorted by expert into one grouped matmul per
+    # projection — k/E of the dense FLOPs and no capacity, so no token is
+    # ever dropped (a model published without a capacity).
     moe_impl: str = "dispatch"
+    # Expert width where it differs from the dense layers' ``mlp_dim``
+    # (0 = the same), experts every token passes through beside the routed
+    # ones, and how many leading layers keep a plain MLP of ``mlp_dim``.
+    moe_mlp_dim: int = 0
+    shared_experts: int = 0
+    leading_dense_layers: int = 0
+    # The router's score: "softmax" (Mixtral: top-k of the logits, softmax
+    # over the chosen) or "sigmoid" (scores sigmoid(logits) in float32,
+    # CHOSEN by score plus a learned bias, WEIGHTED by the score alone,
+    # normalised over the chosen when ``router_norm_topk``, then scaled).
+    router_score: str = "softmax"
+    router_norm_topk: bool = True
+    router_scale: float = 1.0
+    # Latent attention (MLA; kv_lora_rank > 0): queries through a
+    # ``q_lora_rank`` bottleneck, keys and values expanded per head from one
+    # ``kv_lora_rank`` latent row a token, beside ``qk_rope_dim`` rotary
+    # values shared by all heads. The cache holds the latent and the rotary
+    # row; ``head_dim`` is not read.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
     # Per-expert buffer size = capacity_factor * k * T / E (rounded up to a
     # multiple of 8 for TPU tiling). 1.0 = perfectly balanced load fits.
     capacity_factor: float = 1.25
@@ -83,26 +109,57 @@ class DecoderConfig:
     def is_moe(self) -> bool:
         return self.num_experts > 0
 
+    @property
+    def is_latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def expert_mlp_dim(self) -> int:
+        return self.moe_mlp_dim or self.mlp_dim
+
+    def _attn_params(self) -> int:
+        """One block's attention matrices (a latent block's two norms too)."""
+        d, h = self.hidden, self.n_heads
+        if self.is_latent:
+            r, q = self.kv_lora_rank, self.q_lora_rank
+            return (d * q + q + q * h * (self.qk_nope_dim + self.qk_rope_dim)
+                    + d * (r + self.qk_rope_dim) + r
+                    + r * h * (self.qk_nope_dim + self.v_head_dim)
+                    + h * self.v_head_dim * d)
+        return d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+
+    def _mlp_params(self, active: bool) -> int:
+        """One expert layer's (or, dense, one MLP's) matrices; ``active``
+        counts the experts one token multiplies against, not those held."""
+        d = self.hidden
+        if not self.is_moe:
+            return 3 * d * self.mlp_dim
+        per_expert = 3 * d * self.expert_mlp_dim
+        if active:      # the router's small product is left out, as before
+            return (self.experts_per_token + self.shared_experts) * per_expert
+        routing = d * self.num_experts + (
+            self.num_experts if self.router_score == "sigmoid" else 0)
+        return (self.num_experts + self.shared_experts) * per_expert + routing
+
     def num_params(self) -> int:
         """Parameter count (embedding included once if tied)."""
         d, v = self.hidden, self.vocab_size
-        attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
-        if self.is_moe:
-            mlp = self.num_experts * 3 * d * self.mlp_dim + d * self.num_experts
-        else:
-            mlp = 3 * d * self.mlp_dim
-        norms = 2 * d
-        per_layer = attn + mlp + norms
+        k = self.leading_dense_layers
+        layers = (self.n_layers - k) * (self._attn_params()
+                                        + self._mlp_params(False) + 2 * d) \
+            + k * (self._attn_params() + 3 * d * self.mlp_dim + 2 * d)
         embed = v * d if self.tie_embeddings else 2 * v * d
-        return self.n_layers * per_layer + embed + d
+        return layers + embed + d
 
     def flops_per_token(self) -> float:
         """Approximate training FLOPs/token (fwd+bwd ≈ 6N for dense; MoE
         counts only active experts)."""
         d = self.hidden
-        attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
-        mlp_active = (self.experts_per_token if self.is_moe else 1) * 3 * d * self.mlp_dim
-        dense_n = self.n_layers * (attn + mlp_active) + self.vocab_size * d
+        k = self.leading_dense_layers
+        dense_n = (self.n_layers - k) * (self._attn_params()
+                                         + self._mlp_params(True)) \
+            + k * (self._attn_params() + 3 * d * self.mlp_dim) \
+            + self.vocab_size * d
         return 6.0 * dense_n
 
 
@@ -133,6 +190,18 @@ PRESETS: dict[str, DecoderConfig] = {
         head_dim=128, mlp_dim=14336, max_seq_len=8192, rope_theta=1000000.0,
         num_experts=8, experts_per_token=2,
     ),
+    # GLM-4.7-Flash (zai-org config.json, model_type glm4_moe_lite: 47L,
+    # 2048h, 20 latent-attention heads, one dense layer of 10240 then 64
+    # sigmoid-routed experts of 1536, top-4, beside one shared expert)
+    "glm-4.7-flash": DecoderConfig(
+        vocab_size=154880, hidden=2048, n_layers=47, n_heads=20,
+        n_kv_heads=20, head_dim=256, mlp_dim=10240, max_seq_len=202752,
+        rope_theta=1000000.0, num_experts=64, experts_per_token=4,
+        moe_impl="sorted", moe_mlp_dim=1536, shared_experts=1,
+        leading_dense_layers=1, router_score="sigmoid",
+        router_norm_topk=True, router_scale=1.8, q_lora_rank=768,
+        kv_lora_rank=512, qk_nope_dim=192, qk_rope_dim=64, v_head_dim=256,
+    ),
     # tiny variants for tests/sim (structure-faithful, sized for 1 CPU core)
     "tiny": DecoderConfig(
         vocab_size=256, hidden=64, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -148,6 +217,16 @@ PRESETS: dict[str, DecoderConfig] = {
         vocab_size=256, hidden=64, n_layers=2, n_heads=4, n_kv_heads=2,
         head_dim=16, mlp_dim=128, max_seq_len=128,
         num_experts=4, experts_per_token=2,
+    ),
+    # GLM-4.7-Flash's structure at odd small ranks: 1 dense + 3 expert
+    # layers, 8 sigmoid-routed experts top-2 beside 1 shared, latent cache
+    "tiny-glm": DecoderConfig(
+        vocab_size=256, hidden=64, n_layers=4, n_heads=4, n_kv_heads=4,
+        head_dim=20, mlp_dim=160, max_seq_len=128, num_experts=8,
+        experts_per_token=2, moe_impl="sorted", moe_mlp_dim=48,
+        shared_experts=1, leading_dense_layers=1, router_score="sigmoid",
+        router_norm_topk=True, router_scale=1.8, q_lora_rank=24,
+        kv_lora_rank=40, qk_nope_dim=12, qk_rope_dim=8, v_head_dim=20,
     ),
 }
 

@@ -10,6 +10,7 @@ per the config policy (trades HBM for FLOPs — SURVEY.md task guidance).
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Optional
 
 import jax
@@ -38,22 +39,44 @@ def _init_block(key, cfg: DecoderConfig):
     return params, specs
 
 
+def layer_groups(cfg: DecoderConfig) -> list[tuple[str, DecoderConfig, int]]:
+    """The stack as groups of alike layers, in order: (the group's key in
+    the parameter tree, the config its blocks run under, its first layer's
+    index). A model whose layers are all alike is the one group "layers"
+    under its own config, as it always was; ``leading_dense_layers`` puts a
+    group "dense_layers" of plain-MLP blocks before the expert layers. Each
+    group is stacked on a leading axis and scanned on its own."""
+    k = cfg.leading_dense_layers
+    if not k:
+        return [("layers", cfg, 0)]
+    if not cfg.is_moe or not 0 < k < cfg.n_layers:
+        raise ValueError(
+            f"leading_dense_layers={k} needs an expert model of more than "
+            f"{k} layers (n_layers={cfg.n_layers})")
+    dense = dataclasses.replace(cfg, num_experts=0, leading_dense_layers=0,
+                                n_layers=k)
+    experts = dataclasses.replace(cfg, leading_dense_layers=0,
+                                  n_layers=cfg.n_layers - k)
+    return [("dense_layers", dense, 0), ("layers", experts, k)]
+
+
 def init_decoder_params(key: jax.Array, cfg: DecoderConfig) -> Params:
     k_embed, k_layers, k_head = jax.random.split(key, 3)
     tok, _ = L.init_embedding(k_embed, cfg)
 
-    if cfg.scan_layers:
-        # Stack per-layer params on a leading axis via vmapped init.
-        layer_keys = jax.random.split(k_layers, cfg.n_layers)
-        stacked = jax.vmap(lambda k: _init_block(k, cfg)[0])(layer_keys)
-        layers_params = stacked
-    else:
-        layers_params = [
-            _init_block(k, cfg)[0] for k in jax.random.split(k_layers, cfg.n_layers)
-        ]
+    layer_keys = jax.random.split(k_layers, cfg.n_layers)
+    stacks = {}
+    for name, gcfg, first in layer_groups(cfg):
+        keys = layer_keys[first:first + gcfg.n_layers]
+        if cfg.scan_layers:
+            # Stack per-layer params on a leading axis via vmapped init.
+            stacks[name] = jax.vmap(
+                lambda k, gcfg=gcfg: _init_block(k, gcfg)[0])(keys)
+        else:
+            stacks[name] = [_init_block(k, gcfg)[0] for k in keys]
 
     final_norm, _ = L.init_rmsnorm(cfg)
-    params: Params = {"embed": tok, "layers": layers_params, "final_norm": final_norm}
+    params: Params = {"embed": tok, **stacks, "final_norm": final_norm}
     if not cfg.tie_embeddings:
         params["lm_head"] = L._init(k_head, (cfg.hidden, cfg.vocab_size),
                                     cfg.weight_dtype)
@@ -80,19 +103,21 @@ def decoder_param_specs(cfg: DecoderConfig) -> Params:
 
     The stacked layer axis prepends the "layers" logical axis to every
     per-layer leaf when scanning."""
-    block_specs = _block_specs(cfg)
+    def stack_spec(s):
+        return ("layers",) + s
 
-    if cfg.scan_layers:
-        def stack_spec(s):
-            return ("layers",) + s
-        layer_specs = jax.tree.map(stack_spec, block_specs,
-                                   is_leaf=_is_spec_leaf)
-    else:
-        layer_specs = [block_specs] * cfg.n_layers
+    stacks = {}
+    for name, gcfg, _ in layer_groups(cfg):
+        block_specs = _block_specs(gcfg)
+        if cfg.scan_layers:
+            stacks[name] = jax.tree.map(stack_spec, block_specs,
+                                        is_leaf=_is_spec_leaf)
+        else:
+            stacks[name] = [block_specs] * gcfg.n_layers
 
     specs: Params = {
         "embed": ("vocab", "embed_table"),
-        "layers": layer_specs,
+        **stacks,
         "final_norm": ("norm",),
     }
     if not cfg.tie_embeddings:
@@ -104,7 +129,7 @@ def _block_forward(block_params, x, positions, cfg: DecoderConfig,
                    kv_cache=None, attn_impl="xla", mesh=None,
                    rules=DEFAULT_RULES, prefill=False,
                    expert_axis=None, seq_axis=None, tp_axis=None,
-                   valid_len=None, lora=None):
+                   valid_len=None, lora=None, expert_stack=None):
     h = L.rmsnorm(x, block_params["ln1"], cfg, mesh=mesh)
     attn_out, new_cache = L.attention_block(
         block_params["attn"], h, positions, cfg,
@@ -116,7 +141,8 @@ def _block_forward(block_params, x, positions, cfg: DecoderConfig,
     if cfg.is_moe:
         mlp_out, aux = L.moe_block(block_params["mlp"], h, cfg,
                                    expert_axis=expert_axis, seq_axis=seq_axis,
-                                   valid_len=valid_len, tp_axis=tp_axis)
+                                   valid_len=valid_len, tp_axis=tp_axis,
+                                   expert_stack=expert_stack)
     else:
         mlp_out, aux = (L.mlp_block(block_params["mlp"], h, cfg,
                                     tp_axis=tp_axis, mesh=mesh),
@@ -165,6 +191,68 @@ def _remat(fn, policy: str):
                 jax.checkpoint_policies.save_only_these_names(
                     "flash_out", "flash_lse")))
     raise ValueError(f"unknown remat policy {policy!r}")
+
+
+def _run_layers(layers, x, positions, cfg: DecoderConfig, planes: tuple,
+                plane_names: tuple, cache_len, *, attn_impl, mesh, rules,
+                prefill, valid_len, lora):
+    """One group of alike layers (``layer_groups``) over ``x``: scanned when
+    stacked, looped when a list. ``planes``: this group's slices of the
+    cache's stacked planes, in ``plane_names``' order (empty without a
+    cache). Returns (x, the planes as written, the group's summed aux)."""
+    def block(bp, x, cache, lr, expert_stack=None):
+        return _block_forward(
+            bp, x, positions, cfg, kv_cache=cache, attn_impl=attn_impl,
+            mesh=mesh, rules=rules, prefill=prefill, valid_len=valid_len,
+            lora=lr, expert_stack=expert_stack)
+
+    def cache_of(layer_planes):
+        if not plane_names:
+            return None
+        return {**dict(zip(plane_names, layer_planes)), "len": cache_len}
+
+    if cfg.scan_layers:
+        # Per-layer adapter slices ride the scan xs alongside the layer
+        # params (leading L axis); aidx/scale are loop invariants the
+        # body closes over (layers.layer_view).
+        # The expert leaves of a sorted expert group are not scanned: the
+        # body takes them whole with the layer's index (no slice of the
+        # stack is copied out for the grouped matmul).
+        layers, experts = L.split_expert_stack(layers, cfg)
+
+        def scan_body(carry, scan_in):
+            block_params, cache, lora_sl, layer = scan_in
+            out, new_cache, aux = block(
+                block_params, carry, cache, L.layer_view(lora, lora_sl),
+                None if experts is None else (experts, layer))
+            return out, (new_cache, aux)
+
+        body = _remat(scan_body, cfg.remat_policy)
+
+        # scan consumes the stacked [L, ...] cache leaves alongside params
+        def scan_layer(carry, scan_in):
+            block_params, layer_planes, lora_sl, layer = scan_in
+            out, (new_cache, aux) = body(
+                carry, (block_params, cache_of(layer_planes), lora_sl,
+                        layer))
+            written = tuple(new_cache[n] for n in plane_names)
+            return out, (written, aux)
+
+        x, (planes, auxs) = jax.lax.scan(
+            scan_layer, x, (layers, planes, L.slice_layers(lora),
+                            jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+        return x, planes, jnp.sum(auxs)
+
+    block_fn = _remat(block, cfg.remat_policy)
+    auxs, written = [], [[] for _ in plane_names]
+    for i, block_params in enumerate(layers):
+        x, new_cache, aux = block_fn(
+            block_params, x, cache_of(tuple(p[i] for p in planes)),
+            L.index_layer(lora, i))
+        auxs.append(aux)
+        for w, n in zip(written, plane_names):
+            w.append(new_cache[n])
+    return x, tuple(jnp.stack(w) for w in written), jnp.sum(jnp.stack(auxs))
 
 
 def decoder_forward(
@@ -227,6 +315,12 @@ def decoder_forward(
     # pytree (remat would trace it into an array).
     prefill = bool(kv_caches.get("prefill", False)) if kv_caches else False
 
+    # The cache's planes (k and v per head): every stacked [L, ...] leaf
+    # beside the scalar length and the static prefill marker.
+    plane_names = tuple(n for n in (kv_caches or {})
+                        if n not in ("len", "prefill"))
+    groups = layer_groups(cfg)
+
     pp = dict(mesh.shape).get("pipeline", 1) if mesh is not None else 1
     if pp > 1 and kv_caches is None:
         if custom_positions:
@@ -234,75 +328,39 @@ def decoder_forward(
                 "pipeline parallelism computes contiguous positions inside "
                 "the stage (1F1B streams inexact leaves only); custom "
                 "positions are not supported under pp>1")
+        if len(groups) > 1:
+            raise NotImplementedError(
+                "pipeline parallelism stages one stack of alike layers; "
+                "leading dense layers are not supported under pp>1")
         # Pipeline parallelism: the layer stack is staged over the
         # ``pipeline`` mesh axis and microbatches stream through via
         # ppermute (parallel/pipeline.py). Decode (kv_caches) stays on the
         # non-pp path — serving shards differently.
         x, aux_total = _pipeline_layers(params["layers"], x, positions, cfg,
                                         mesh, attn_impl)
-    elif cfg.scan_layers:
-        # Per-layer adapter slices ride the scan xs alongside the layer
-        # params (leading L axis); aidx/scale are loop invariants the
-        # body closes over (layers.layer_view).
-        lora_xs = L.slice_layers(lora)
+        groups = []
 
-        def scan_body(carry, scan_in):
-            x = carry
-            block_params, cache, lora_sl = scan_in
-            out, new_cache, aux = _block_forward(
-                block_params, x, positions, cfg,
-                kv_cache=cache, attn_impl=attn_impl, mesh=mesh, rules=rules,
-                prefill=prefill, valid_len=valid_len,
-                lora=L.layer_view(lora, lora_sl))
-            return out, (new_cache, aux)
-
-        body = _remat(scan_body, cfg.remat_policy)
-        if kv_caches is not None:
-            # scan consumes the stacked [L, ...] cache leaves alongside params
-            def scan_with_cache(carry, scan_in):
-                block_params, (ck, cv), lora_sl = scan_in
-                cache = {"k": ck, "v": cv, "len": kv_caches["len"]}
-                out, (new_cache, aux) = body(
-                    carry, (block_params, cache, lora_sl))
-                return out, ((new_cache["k"], new_cache["v"]), aux)
-            x, ((nk, nv), auxs) = jax.lax.scan(
-                scan_with_cache, x,
-                (params["layers"], (kv_caches["k"], kv_caches["v"]),
-                 lora_xs))
-            new_caches = {"k": nk, "v": nv,
-                          "len": kv_caches["len"] + tokens.shape[1]}
-        else:
-            def scan_no_cache(carry, scan_in):
-                block_params, lora_sl = scan_in
-                out, (_, aux) = body(carry, (block_params, None, lora_sl))
-                return out, aux
-            x, auxs = jax.lax.scan(scan_no_cache, x,
-                                   (params["layers"], lora_xs))
-        aux_total = jnp.sum(auxs)
-    else:
-        per_layer_aux = []
-        new_k, new_v = [], []
-        block_fn = _remat(
-            lambda bp, x, cache, lr: _block_forward(
-                bp, x, positions, cfg,
-                kv_cache=cache, attn_impl=attn_impl, mesh=mesh, rules=rules,
-                prefill=prefill, valid_len=valid_len, lora=lr),
-            cfg.remat_policy)
-        for i, block_params in enumerate(params["layers"]):
-            cache = None
-            if kv_caches is not None:
-                cache = {"k": kv_caches["k"][i], "v": kv_caches["v"][i],
-                         "len": kv_caches["len"]}
-            x, new_cache, aux = block_fn(block_params, x, cache,
-                                         L.index_layer(lora, i))
-            per_layer_aux.append(aux)
-            if new_cache is not None:
-                new_k.append(new_cache["k"])
-                new_v.append(new_cache["v"])
-        aux_total = jnp.sum(jnp.stack(per_layer_aux))
-        if kv_caches is not None:
-            new_caches = {"k": jnp.stack(new_k), "v": jnp.stack(new_v),
-                          "len": kv_caches["len"] + tokens.shape[1]}
+    new_planes: dict[str, list] = {n: [] for n in plane_names}
+    for name, gcfg, first in groups:
+        last = first + gcfg.n_layers
+        whole = len(groups) == 1        # one group: nothing is sliced
+        planes = tuple(kv_caches[n] if whole else kv_caches[n][first:last]
+                       for n in plane_names)
+        lora_g = lora if whole or lora is None else {
+            **lora, "targets": {t: (a[first:last], b[first:last])
+                                for t, (a, b) in lora["targets"].items()}}
+        x, planes, aux = _run_layers(
+            params[name], x, positions, gcfg, planes, plane_names,
+            kv_caches["len"] if kv_caches is not None else None,
+            attn_impl=attn_impl, mesh=mesh, rules=rules, prefill=prefill,
+            valid_len=valid_len, lora=lora_g)
+        aux_total = aux_total + aux
+        for n, plane in zip(plane_names, planes):
+            new_planes[n].append(plane)
+    if kv_caches is not None:
+        new_caches = {n: ps[0] if len(ps) == 1 else jnp.concatenate(ps)
+                      for n, ps in new_planes.items()}
+        new_caches["len"] = kv_caches["len"] + tokens.shape[1]
 
     x = L.rmsnorm(x, params["final_norm"], cfg, mesh=mesh)
     if skip_head:

@@ -175,6 +175,8 @@ def index_layer(lora: Optional[dict], i: int) -> Optional[dict]:
 # -- Attention block -----------------------------------------------------------
 
 def init_attention(key, cfg: DecoderConfig):
+    if cfg.is_latent:
+        return init_latent_attention(key, cfg)
     kq, kk, kv, ko = jax.random.split(key, 4)
     d = cfg.hidden
     params = {
@@ -213,6 +215,13 @@ def attention_block(
     runs over local heads (heads are independent), and the output
     projection's partial sum psums over the axis — the manual form of the
     split GSPMD derives from the sharding rules outside shard_map."""
+    if cfg.is_latent:
+        if tp_axis is not None or lora is not None:
+            raise NotImplementedError(
+                "latent attention under in-stage tensor parallelism or with "
+                "LoRA adapters")
+        return latent_attention_block(p, x, positions, cfg,
+                                      kv_cache=kv_cache, attn_impl=attn_impl)
     dt = cfg.activation_dtype
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(dt))
@@ -313,6 +322,145 @@ def attention_block(
     return checkpoint_name(proj, "attn_out"), new_cache
 
 
+# -- Latent attention (MLA) ----------------------------------------------------
+
+def init_latent_attention(key, cfg: DecoderConfig):
+    """Queries through a ``q_lora_rank`` bottleneck (``wqa``, a norm,
+    ``wqb``); keys and values from ONE ``kv_lora_rank`` latent row a token
+    (``wkva``'s first columns, a norm) expanded per head by ``wkvb`` into
+    ``qk_nope_dim`` key values and ``v_head_dim`` value values, beside
+    ``qk_rope_dim`` rotary key values (``wkva``'s last columns) that every
+    head shares."""
+    if cfg.q_lora_rank <= 0:
+        raise ValueError("latent attention needs q_lora_rank > 0")
+    kqa, kqb, kkva, kkvb, ko = jax.random.split(key, 5)
+    d, h, wdt = cfg.hidden, cfg.n_heads, cfg.weight_dtype
+    r, q = cfg.kv_lora_rank, cfg.q_lora_rank
+    params = {
+        "wqa": _init(kqa, (d, q), wdt),
+        "q_norm": jnp.ones((q,), wdt),
+        "wqb": _init(kqb, (q, h, cfg.qk_nope_dim + cfg.qk_rope_dim), wdt),
+        "wkva": _init(kkva, (d, r + cfg.qk_rope_dim), wdt),
+        "kv_norm": jnp.ones((r,), wdt),
+        "wkvb": _init(kkvb, (r, h, cfg.qk_nope_dim + cfg.v_head_dim), wdt),
+        "wo": _init(ko, (h, cfg.v_head_dim, d), wdt,
+                    scale=(h * cfg.v_head_dim) ** -0.5),
+    }
+    specs = {
+        "wqa": ("embed", None), "q_norm": ("norm",),
+        "wqb": (None, "heads", "head_dim"),
+        "wkva": ("embed", None), "kv_norm": ("norm",),
+        "wkvb": (None, "heads", "head_dim"),
+        "wo": ("heads", "head_dim", "embed"),
+    }
+    return params, specs
+
+
+def latent_row_width(cfg: DecoderConfig) -> int:
+    """Width of the ONE row a token keeps in a layer of the cache: the
+    latent and the rotary values side by side, padded with zeros to whole
+    128-value lanes (576 -> 640 at the published ranks), so that a page is
+    one aligned block for the device and for a kernel's one DMA."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_dim) // 128) * 128
+
+
+def _as_latent_row(parts: list, cfg: DecoderConfig) -> jax.Array:
+    """[.., r] and [.., rope] side by side, zeros up to the row's width."""
+    pad = latent_row_width(cfg) - cfg.kv_lora_rank - cfg.qk_rope_dim
+    zeros = jnp.zeros((*parts[0].shape[:-1], pad), parts[0].dtype)
+    return jnp.concatenate([*parts, zeros], axis=-1)
+
+
+def latent_qkv(p: dict, x: jax.Array, positions: jax.Array,
+               cfg: DecoderConfig):
+    """The block's projections of ``x`` [B,S,D]: per-head queries split
+    into (q_nope [B,S,H,nope], q_rope [B,S,H,rope], rotated) and this
+    token's CACHE row [B,S,W]: ``ckv`` (r values, after its norm), then
+    ``k_rope`` (after RoPE), then zeros up to ``latent_row_width``."""
+    dt = cfg.activation_dtype
+    r = cfg.kv_lora_rank
+    cq = rmsnorm(jnp.einsum("bsd,dq->bsq", x, p["wqa"].astype(dt)),
+                 p["q_norm"], cfg)
+    q = jnp.einsum("bsq,qhk->bshk", cq, p["wqb"].astype(dt))
+    q_nope, q_rope = q[..., :cfg.qk_nope_dim], q[..., cfg.qk_nope_dim:]
+    kva = jnp.einsum("bsd,dr->bsr", x, p["wkva"].astype(dt))
+    ckv = rmsnorm(kva[..., :r], p["kv_norm"], cfg)
+    k_rope = rope(kva[..., None, r:], positions, cfg.rope_theta)[:, :, 0]
+    return (q_nope, rope(q_rope, positions, cfg.rope_theta),
+            _as_latent_row([ckv, k_rope], cfg))
+
+
+def latent_scale(cfg: DecoderConfig) -> float:
+    return (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+
+
+def latent_query(p: dict, q_nope: jax.Array, q_rope: jax.Array,
+                 cfg: DecoderConfig) -> jax.Array:
+    """The ABSORBED query [..., H, W], laid out like a cache row: the key
+    expansion folded into the query (``q_lat = q_nope Wkb^T``), then
+    ``q_rope``, then zeros. Its product with a cache row is the head's
+    whole score ``q_nope . k_nope + q_rope . k_rope``."""
+    wkb = p["wkvb"].astype(cfg.activation_dtype)[..., :cfg.qk_nope_dim]
+    q_lat = jnp.einsum("...hk,rhk->...hr", q_nope, wkb)
+    return _as_latent_row([q_lat, q_rope], cfg)
+
+
+def latent_output(p: dict, o_row: jax.Array, cfg: DecoderConfig) -> jax.Array:
+    """The attended row [..., H, W] (softmax-weighted sum of cache rows)
+    expanded into per-head values [..., H, v_head_dim]: ``o_lat Wvb`` over
+    the row's latent part."""
+    wvb = p["wkvb"].astype(cfg.activation_dtype)[..., cfg.qk_nope_dim:]
+    return jnp.einsum("...hr,rhk->...hk", o_row[..., :cfg.kv_lora_rank], wvb)
+
+
+def latent_absorbed_attention(p: dict, q_nope, q_rope, rows, mask,
+                              cfg: DecoderConfig) -> jax.Array:
+    """Attention over cached rows WITHOUT expanding them per head, in plain
+    XLA (the kernels of ops/paged_attention.py compute the same sums page by
+    page). q_* [B,S,H,.]; rows [B,T,W]; ``mask`` broadcastable to
+    [B,H,S,T], True = attend. Returns [B,S,H,v_head_dim]."""
+    q = latent_query(p, q_nope, q_rope, cfg)                  # [B,S,H,W]
+    scores = jnp.einsum("bshw,btw->bhst", q, rows,
+                        preferred_element_type=jnp.float32)
+    scores = jnp.where(mask, scores * latent_scale(cfg), -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(rows.dtype)
+    o_row = jnp.einsum("bhst,btw->bshw", probs, rows)
+    return latent_output(p, o_row, cfg)
+
+
+def latent_attention_block(p: dict, x: jax.Array, positions: jax.Array,
+                           cfg: DecoderConfig, *,
+                           kv_cache: Optional[dict] = None,
+                           attn_impl: str = "xla"):
+    """Latent attention in its EXPANDED form (training, a whole prompt):
+    every token's keys and values per head, through the attention the other
+    models use. The cache of a latent model is paged and attended ABSORBED
+    (serve/paged.py: the chunk prefill and the decode step, which give the
+    same numbers); there is no contiguous one. Returns (out [B,S,D],
+    None)."""
+    if kv_cache is not None:
+        raise NotImplementedError(
+            "a latent cache is a page pool (serve/paged.py); the contiguous "
+            "cache holds K and V per head")
+    dt = cfg.activation_dtype
+    r = cfg.kv_lora_rank
+    q_nope, q_rope, row = latent_qkv(p, x, positions, cfg)
+    kv = jnp.einsum("bsr,rhk->bshk", row[..., :r], p["wkvb"].astype(dt))
+    k = jnp.concatenate(
+        [kv[..., :cfg.qk_nope_dim],
+         jnp.broadcast_to(row[:, :, None, r:r + cfg.qk_rope_dim],
+                          q_rope.shape)], axis=-1)
+    q = checkpoint_name(jnp.concatenate([q_nope, q_rope], -1), "q_rope")
+    k = checkpoint_name(k, "k_rope")
+    v = checkpoint_name(kv[..., cfg.qk_nope_dim:], "v_proj")
+    impl = attn_impl if attn_impl in ("xla", "pallas") else "xla"
+    if impl == "pallas" and v.shape[-1] != q.shape[-1]:
+        impl = "xla"                # the flash kernel takes one head width
+    out = multi_head_attention(q, k, v, causal=True, impl=impl)
+    proj = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
+    return checkpoint_name(proj, "attn_out"), None
+
+
 # -- MLP -----------------------------------------------------------------------
 
 def init_mlp(key, cfg: DecoderConfig):
@@ -364,7 +512,7 @@ def mlp_block(p: dict, x: jax.Array, cfg: DecoderConfig,
 
 def init_moe(key, cfg: DecoderConfig):
     kr, kg, ku, kd = jax.random.split(key, 4)
-    d, m, e = cfg.hidden, cfg.mlp_dim, cfg.num_experts
+    d, m, e = cfg.hidden, cfg.expert_mlp_dim, cfg.num_experts
     params = {
         "router": _init(kr, (d, e), cfg.weight_dtype),
         "gate": _init(kg, (e, d, m), cfg.weight_dtype, scale=d ** -0.5),
@@ -377,14 +525,80 @@ def init_moe(key, cfg: DecoderConfig):
         "up": ("expert", "embed", "expert_mlp"),
         "down": ("expert", "expert_mlp", "embed"),
     }
+    if cfg.router_score == "sigmoid":
+        # The correction bias moves the CHOICE of experts and never their
+        # weights; it is balanced outside the loss, so it starts at zero.
+        params["router_bias"] = jnp.zeros((e,), jnp.float32)
+        specs["router_bias"] = (None,)
+    if cfg.shared_experts:
+        # Every token's experts: one MLP as wide as all of them together.
+        ms = cfg.shared_experts * m
+        kg2, ku2, kd2 = jax.random.split(jax.random.fold_in(key, 1), 3)
+        params["shared"] = {
+            "gate": _init(kg2, (d, ms), cfg.weight_dtype),
+            "up": _init(ku2, (d, ms), cfg.weight_dtype),
+            "down": _init(kd2, (ms, d), cfg.weight_dtype, scale=ms ** -0.5),
+        }
+        specs["shared"] = {"gate": ("embed", "mlp"), "up": ("embed", "mlp"),
+                           "down": ("mlp", "embed")}
     return params, specs
+
+
+def route(p: dict, xf: jax.Array, cfg: DecoderConfig):
+    """The router on tokens ``xf`` [..., D]: (logits [..., E] float32, the
+    chosen experts [..., k], their weights [..., k] float32).
+
+    "softmax" (Mixtral): the top-k logits, softmax over the chosen.
+    "sigmoid": ``s = sigmoid(x Wr)`` computed in float32; the top-k of
+    ``s + b`` (``b`` the correction bias) are CHOSEN, the weights are ``s``
+    of the chosen WITHOUT ``b``, divided by their sum when
+    ``router_norm_topk``, times ``router_scale``."""
+    k = cfg.experts_per_token
+    if cfg.router_score == "softmax":
+        logits = jnp.einsum(
+            "...d,de->...e", xf,
+            p["router"].astype(cfg.activation_dtype)).astype(jnp.float32)
+        top_logits, idx = jax.lax.top_k(logits, k)
+        return logits, idx, jax.nn.softmax(top_logits, axis=-1)
+    if cfg.router_score != "sigmoid":
+        raise ValueError(f"unknown router_score {cfg.router_score!r}")
+    logits = jnp.einsum("...d,de->...e", xf.astype(jnp.float32),
+                        p["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(s + p["router_bias"].astype(jnp.float32), k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if cfg.router_norm_topk:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return logits, idx, w * cfg.router_scale
+
+
+EXPERT_LEAVES = ("gate", "up", "down")
+
+
+def split_expert_stack(layers: dict, cfg: DecoderConfig):
+    """A stacked group of layers as (what a scan slices a layer at a time,
+    the leaves a block takes WHOLE with its layer's index). The sorted
+    expert path hands its weights to a grouped-matmul kernel, and a kernel's
+    operand is a buffer of its own: a layer's [E, ...] slice of the stack
+    would be COPIED out for every layer of every step (1.2 GB a layer at 64
+    experts of 2048 x 1536). So those leaves stay whole, viewed
+    [L*E, ...], and the block addresses its layer's experts as groups
+    ``layer*E ..`` (``_moe_sorted``). (.., None) for every other model."""
+    if not (cfg.is_moe and cfg.moe_impl == "sorted"):
+        return layers, None
+    mlp = layers["mlp"]
+    whole = {k: mlp[k] for k in EXPERT_LEAVES}
+    return {**layers, "mlp": {k: v for k, v in mlp.items()
+                              if k not in whole}}, whole
 
 
 def moe_block(p: dict, x: jax.Array, cfg: DecoderConfig,
               expert_axis: Optional[str] = None,
               seq_axis: Optional[str] = None,
               valid_len: Optional[jax.Array] = None,
-              tp_axis: Optional[str] = None):
+              tp_axis: Optional[str] = None,
+              expert_stack: Optional[tuple] = None):
     """Top-k MoE (Mixtral semantics: softmax over the selected k logits).
 
     Dispatches on ``cfg.moe_impl``: "dispatch" (default) routes tokens into
@@ -403,15 +617,31 @@ def moe_block(p: dict, x: jax.Array, cfg: DecoderConfig,
     additionally hold this device's slice of the expert-mlp dim (the
     Megatron split applied INSIDE each expert); gate/up produce the local
     m-slice and down's partial products join the expert partials in one
-    psum over both axes."""
+    psum over both axes.
+
+    ``expert_stack`` (``split_expert_stack``): (the expert leaves of the
+    whole stacked group, this layer's index in it), where ``p`` holds the
+    rest; the sorted path alone takes it."""
     if cfg.moe_impl == "dispatch":
-        return _moe_dispatch(p, x, cfg, expert_axis=expert_axis,
-                             seq_axis=seq_axis, valid_len=valid_len,
-                             tp_axis=tp_axis)
-    if cfg.moe_impl != "dense":
+        out, aux = _moe_dispatch(p, x, cfg, expert_axis=expert_axis,
+                                 seq_axis=seq_axis, valid_len=valid_len,
+                                 tp_axis=tp_axis)
+    elif cfg.moe_impl == "sorted":
+        if expert_axis is not None or tp_axis is not None:
+            raise NotImplementedError(
+                "moe_impl 'sorted' inside a pipeline stage's shard_map "
+                "(expert or tensor parallel)")
+        out, aux = _moe_sorted(p, x, cfg, seq_axis=seq_axis,
+                               expert_stack=expert_stack)
+    elif cfg.moe_impl == "dense":
+        out, aux = _moe_dense(p, x, cfg, expert_axis=expert_axis,
+                              seq_axis=seq_axis, tp_axis=tp_axis)
+    else:
         raise ValueError(f"unknown moe_impl {cfg.moe_impl!r}")
-    return _moe_dense(p, x, cfg, expert_axis=expert_axis, seq_axis=seq_axis,
-                      tp_axis=tp_axis)
+    if cfg.shared_experts:
+        # Every token, beside whatever it was routed to.
+        out = out + mlp_block(p["shared"], x, cfg, tp_axis=tp_axis)
+    return out, aux
 
 
 def _moe_aux_loss(router_logits, onehot_sum, cfg: DecoderConfig,
@@ -420,7 +650,10 @@ def _moe_aux_loss(router_logits, onehot_sum, cfg: DecoderConfig,
     ``onehot_sum`` [B,S,E] = how many of the k choices hit each expert.
     ``valid`` [B,S] (optional) masks pad rows out of BOTH fractions and
     renormalizes by the valid-token count — pads route to whatever expert
-    the null embedding prefers and would otherwise read as imbalance."""
+    the null embedding prefers and would otherwise read as imbalance.
+    A sigmoid router is balanced through its bias and has no such loss."""
+    if cfg.router_score != "softmax":
+        return jnp.float32(0)
     probs = jax.nn.softmax(router_logits, axis=-1)                   # [B,S,E]
     if valid is not None:
         # Sum masked numerators and the valid count SEPARATELY across the
@@ -491,10 +724,7 @@ def _moe_dispatch(p: dict, x: jax.Array, cfg: DecoderConfig,
     e, k = cfg.num_experts, cfg.experts_per_token
     t = b * s
     xf = x.reshape(t, d)
-    router_logits = jnp.einsum(
-        "td,de->te", xf, p["router"].astype(dt)).astype(jnp.float32)
-    topk_logits, topk_idx = jax.lax.top_k(router_logits, k)          # [T,k]
-    topk_w = jax.nn.softmax(topk_logits, axis=-1)                    # [T,k]
+    router_logits, topk_idx, topk_w = route(p, xf, cfg)              # [T,k]
 
     c = moe_capacity(cfg, t)
     # Choice-major flattening: row r = (choice r // T) of token (r % T).
@@ -556,6 +786,80 @@ def _moe_dispatch(p: dict, x: jax.Array, cfg: DecoderConfig,
     return checkpoint_name(out, "mlp_out"), aux
 
 
+# Rows a tile of the grouped-matmul kernel holds. A group of fewer rows still
+# costs a whole tile of matrix work, which is cheap beside reading its
+# weights once: the sorted expert layer is bound by the experts' bytes.
+GROUPED_TILE_ROWS = 128
+
+
+def grouped_matmul(rows: jax.Array, w: jax.Array, sizes: jax.Array,
+                   cfg: DecoderConfig) -> jax.Array:
+    """``rows`` [M, K] sorted by group times ``w`` [G, K, N]: the rows of
+    group ``g`` (``sizes[g]`` of them, in order) against ``w[g]``; empty
+    groups cost nothing. With the fused kernels on and whole row tiles (a
+    chunk's rows; not a decode step's few), the Pallas grouped matmul with
+    tiles of this layer's own widths (each expert's weights are read about
+    once, which XLA's own ``ragged_dot`` kernel at these shapes is 2.5x
+    from: PERF.md, PR 28); ``jax.lax.ragged_dot`` otherwise."""
+    m, (k, n) = rows.shape[0], w.shape[1:]
+    if fused_kernels_on(cfg) and m % GROUPED_TILE_ROWS == 0:
+        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+        from kubeflow_tpu.ops import auto_interpret
+
+        return megablox.gmm(
+            rows, w, sizes, rows.dtype,
+            (GROUPED_TILE_ROWS, k, n if n % 512 else 512), None, None, False,
+            auto_interpret())
+    return jax.lax.ragged_dot(rows, w, sizes)
+
+
+def _moe_sorted(p: dict, x: jax.Array, cfg: DecoderConfig,
+                seq_axis: Optional[str] = None,
+                expert_stack: Optional[tuple] = None):
+    """Drop-free sparse experts: the k rows of every token are sorted by
+    expert and each projection is ONE grouped matmul over the sorted rows
+    (``grouped_matmul``: group ``e`` holds the rows routed to expert ``e``,
+    however many), so an expert's weights are read once and only the
+    chosen experts compute. No capacity exists, so nothing can overflow and
+    co-batched tokens cannot change each other's result. Pad rows of a
+    serving chunk are routed and computed like any other; they displace
+    nothing. With ``expert_stack`` the weights are the whole group's,
+    viewed [L*E, ...] (a bitcast), and this layer's experts are the groups
+    from ``layer*E`` on; every other group is empty."""
+    dt = cfg.activation_dtype
+    b, s, d = x.shape
+    e, k = cfg.num_experts, cfg.experts_per_token
+    t = b * s
+    xf = x.reshape(t, d)
+    router_logits, topk_idx, topk_w = route(p, xf, cfg)              # [T,k]
+    flat_e = topk_idx.reshape(-1)                                    # [Tk]
+    order = jnp.argsort(flat_e, stable=True)
+    sizes = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
+    w = p
+    if expert_stack is not None:
+        stack, layer = expert_stack
+        w = {n: a.reshape(-1, *a.shape[2:]) for n, a in stack.items()}
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((w["gate"].shape[0],), jnp.int32), sizes, (layer * e,))
+    rows = jnp.take(xf, order // k, axis=0)                          # [Tk,D]
+    gate = _act(grouped_matmul(rows, w["gate"].astype(dt), sizes, cfg),
+                cfg.hidden_act)
+    up = grouped_matmul(rows, w["up"].astype(dt), sizes, cfg)
+    y = grouped_matmul(gate * up, w["down"].astype(dt), sizes, cfg)  # [Tk,D]
+    # Back to token order: row r of the sorted rows is (token, choice)
+    # ``order[r]``; a scalar scatter inverts the permutation.
+    inv = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))
+    back = jnp.take(y, inv, axis=0).reshape(t, k, d)
+    out = jnp.einsum("tkd,tk->td", back, topk_w.astype(dt)).reshape(b, s, d)
+    aux = _moe_aux_loss(
+        router_logits.reshape(b, s, e),
+        jax.nn.one_hot(topk_idx, e, dtype=jnp.float32).sum(-2).reshape(
+            b, s, e), cfg, seq_axis)
+    return checkpoint_name(out, "mlp_out"), aux
+
+
 def _moe_dense(p: dict, x: jax.Array, cfg: DecoderConfig,
                expert_axis: Optional[str] = None,
                seq_axis: Optional[str] = None,
@@ -576,9 +880,7 @@ def _moe_dense(p: dict, x: jax.Array, cfg: DecoderConfig,
     the aux loss sees full-sequence statistics."""
     dt = cfg.activation_dtype
     e, k = cfg.num_experts, cfg.experts_per_token
-    router_logits = jnp.einsum("bsd,de->bse", x, p["router"].astype(dt)).astype(jnp.float32)
-    topk_logits, topk_idx = jax.lax.top_k(router_logits, k)          # [B,S,k]
-    topk_w = jax.nn.softmax(topk_logits, axis=-1)                    # mixtral: softmax over top-k
+    router_logits, topk_idx, topk_w = route(p, x, cfg)               # [B,S,k]
     onehot = jax.nn.one_hot(topk_idx, e, dtype=jnp.float32)          # [B,S,k,E]
     combine = jnp.einsum("bske,bsk->bse", onehot, topk_w)            # [B,S,E]
 

@@ -1,4 +1,5 @@
-"""Pallas TPU paged-attention decode kernel.
+"""Pallas TPU paged-attention decode kernels (per-head K/V pools; latent
+pools at the end of the file).
 
 The paged engine's XLA path reads KV twice per step: a gather materializes
 each slot's pages into the [B, S, K, D] layout, then attention reads the
@@ -187,3 +188,208 @@ def paged_decode_attention(
         interpret=interpret if interpret is not None else auto_interpret(),
     )(table, lengths, *operands)
     return out
+
+
+# -- latent (MLA) pools ----------------------------------------------------------
+#
+# A latent pool holds ONE row a token for all heads, ``[P, page, W]``: the
+# compressed key/value latent (after its norm), the rotary key values every
+# head shares, zeros up to whole 128-value lanes (models/layers.py::
+# latent_qkv). Attention over it is ABSORBED: the caller folds the key
+# expansion into the query (``layers.latent_query``: a query laid out like a
+# row, so a head's score is ONE product with the row) and expands the
+# attended row afterwards (``layers.latent_output``), so a kernel is
+# multi-query attention of H heads against one shared row that is also the
+# value, and no per-head K or V of the context ever exists. Two kernels, the
+# schedule of the one above (pages DMA'd from where they lie, blockwise
+# softmax, float32 accumulation): one query a slot (decode), and a chunk of
+# queries of one slot (chunk prefill).
+
+def _online_softmax_step(s, rows, m_ref, l_ref, acc_ref):
+    """One block of the running softmax: ``s`` [Q, T] masked scores (f32),
+    ``rows`` [T, W] the block's cache rows, which are also its values."""
+    m_prev = m_ref[:]                                # [Q, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)                           # [Q, T]
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=1, keepdims=True)
+    # Probabilities go to the MXU in the pool's type, as in the XLA form.
+    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+        p.astype(rows.dtype), rows, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)          # [Q, W]
+    m_ref[:] = m_new
+
+
+def _softmax_init(m_ref, l_ref, acc_ref):
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+
+
+def _softmax_result(l_ref, acc_ref, dtype):
+    l = l_ref[:]
+    safe = jnp.where(l == 0.0, 1.0, l)               # dead rows emit zeros
+    return (acc_ref[:] / safe).astype(dtype)
+
+
+def _latent_decode_kernel(table_ref, len_ref, q_ref, page_ref, o_ref,
+                          m_ref, l_ref, acc_ref, *, page_size: int,
+                          sm_scale: float, num_pages_per_slot: int):
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    pl.when(j == 0)(lambda: _softmax_init(m_ref, l_ref, acc_ref))
+    length = len_ref[b]                 # position being decoded (inclusive)
+    needed = jnp.logical_and(j * page_size <= length, table_ref[b, j] >= 0)
+
+    @pl.when(needed)
+    def _compute():
+        rows = page_ref[0]                           # [pg, W]
+        s = jax.lax.dot_general(
+            q_ref[0], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)      # [H, pg]
+        kv_pos = j * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (1, page_size), 1)
+        s = jnp.where(kv_pos <= length, s * sm_scale, NEG_INF)
+        _online_softmax_step(s, rows, m_ref, l_ref, acc_ref)
+
+    @pl.when(j == num_pages_per_slot - 1)
+    def _finalize():
+        o_ref[0] = _softmax_result(l_ref, acc_ref, o_ref.dtype)
+
+
+def paged_latent_decode_attention(
+    q: jax.Array,                 # [B, H, W]: layers.latent_query
+    pool: jax.Array,              # [P, page, W]
+    table: jax.Array,             # [B, mpp] int32 page ids (-1 = unmapped)
+    lengths: jax.Array,           # [B] position being decoded (attend <=)
+    *,
+    sm_scale: float,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Absorbed decode attention over a latent page pool; returns the
+    attended row [B, H, W] (the caller expands it: layers.latent_output).
+    Every page is read once, for all heads."""
+    b, h, w = q.shape
+    page = pool.shape[1]
+    mpp = table.shape[1]
+    kernel = functools.partial(
+        _latent_decode_kernel, page_size=page, sm_scale=sm_scale,
+        num_pages_per_slot=mpp)
+
+    def q_map(bi, ji, table_ref, len_ref):
+        return (bi, 0, 0)
+
+    def page_map(bi, ji, table_ref, len_ref):
+        # Unmapped pages clamp to page 0: the DMA happens but the compute
+        # predicate never reads it.
+        return (jnp.maximum(table_ref[bi, ji], 0), 0, 0)
+
+    return pl.pallas_call(
+        kernel,
+        name="paged_latent_decode_attention",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, mpp),
+            in_specs=[pl.BlockSpec((1, h, w), q_map),
+                      pl.BlockSpec((1, page, w), page_map)],
+            out_specs=pl.BlockSpec((1, h, w), q_map),
+            scratch_shapes=[
+                pltpu.VMEM((h, 1), jnp.float32),   # running max m
+                pltpu.VMEM((h, 1), jnp.float32),   # running denom l
+                pltpu.VMEM((h, w), jnp.float32),   # row accumulator
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, w), q.dtype),
+        interpret=interpret if interpret is not None else auto_interpret(),
+    )(table, lengths, q, pool)
+
+
+# Pages a grid step of the chunk kernel attends to at once: 4 pages of 128
+# are a 512-row block, which keeps the MXU's operands large; each is its own
+# operand (the same pool, another index map), DMA'd from where it lies.
+CHUNK_PAGES_PER_STEP = 4
+
+
+def _latent_chunk_kernel(table_ref, start_ref, q_ref, *rest, page_size: int,
+                         sm_scale: float, num_blocks: int):
+    n = CHUNK_PAGES_PER_STEP
+    page_refs, (o_ref, rows_ref, m_ref, l_ref, acc_ref) = rest[:n], rest[n:]
+    j = pl.program_id(1)
+    c = q_ref.shape[1]
+    block = n * page_size
+    pl.when(j == 0)(lambda: _softmax_init(m_ref, l_ref, acc_ref))
+    start = start_ref[0]                # position of the chunk's first query
+
+    # Causal skip: the whole block lies behind every query of the chunk.
+    @pl.when(j * block <= start + c - 1)
+    def _compute():
+        for i, ref in enumerate(page_refs):
+            rows_ref[i * page_size:(i + 1) * page_size, :] = ref[0]
+        rows = rows_ref[:]                           # [block, W]
+        s = jax.lax.dot_general(
+            q_ref[0], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)      # [C, block]
+        q_pos = start + jax.lax.broadcasted_iota(jnp.int32, (c, block), 0)
+        kv_pos = j * block + jax.lax.broadcasted_iota(
+            jnp.int32, (c, block), 1)
+        s = jnp.where(kv_pos <= q_pos, s * sm_scale, NEG_INF)
+        _online_softmax_step(s, rows, m_ref, l_ref, acc_ref)
+
+    @pl.when(j == num_blocks - 1)
+    def _finalize():
+        o_ref[0] = _softmax_result(l_ref, acc_ref, o_ref.dtype)
+
+
+def paged_latent_chunk_attention(
+    q: jax.Array,                 # [H, C, W]: one slot's chunk, head-major
+    pool: jax.Array,              # [P, page, W]
+    table_row: jax.Array,         # [n] int32: the slot's pages in order
+    start: jax.Array,             # scalar int32: position of query 0
+    *,
+    sm_scale: float,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Causal absorbed attention of a chunk of ``C`` queries (positions
+    ``start ..``) over ONE slot's latent pages, the chunk's own rows among
+    them (the caller writes them first); returns the attended rows
+    [H, C, W]. Pages behind the chunk's last query are skipped, so the cost
+    follows the context and not the table's length; a query attends to
+    positions <= its own, which are all mapped and written."""
+    h, c, w = q.shape
+    page = pool.shape[1]
+    n = CHUNK_PAGES_PER_STEP
+    num_blocks = -(-table_row.shape[0] // n)
+    table = jnp.pad(table_row, (0, num_blocks * n - table_row.shape[0]),
+                    constant_values=-1)
+    kernel = functools.partial(
+        _latent_chunk_kernel, page_size=page, sm_scale=sm_scale,
+        num_blocks=num_blocks)
+
+    def q_map(hi, ji, table_ref, start_ref):
+        return (hi, 0, 0)
+
+    def page_map(i):
+        return lambda hi, ji, table_ref, start_ref: (
+            jnp.maximum(table_ref[ji * n + i], 0), 0, 0)
+
+    return pl.pallas_call(
+        kernel,
+        name="paged_latent_chunk_attention",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(h, num_blocks),
+            in_specs=[pl.BlockSpec((1, c, w), q_map)]
+            + [pl.BlockSpec((1, page, w), page_map(i)) for i in range(n)],
+            out_specs=pl.BlockSpec((1, c, w), q_map),
+            scratch_shapes=[
+                pltpu.VMEM((n * page, w), pool.dtype),  # the block's rows
+                pltpu.VMEM((c, 1), jnp.float32),        # running max m
+                pltpu.VMEM((c, 1), jnp.float32),        # running denom l
+                pltpu.VMEM((c, w), jnp.float32),        # row accumulator
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((h, c, w), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret if interpret is not None else auto_interpret(),
+    )(table, jnp.reshape(start, (1,)).astype(jnp.int32), q, *([pool] * n))

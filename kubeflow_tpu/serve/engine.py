@@ -830,13 +830,16 @@ class LLMEngine:
             pre = b.moe_prefill_impl
             if pre == "auto":
                 pre = cfg.moe_impl          # the model's training-time path
-            if pre not in ("dispatch", "dense"):
+            if pre not in ("dispatch", "dense", "sorted"):
                 raise ValueError(
                     f"unknown moe_prefill_impl {b.moe_prefill_impl!r}")
             cfg_prefill = dataclasses.replace(cfg, moe_impl=pre)
             dec = b.moe_decode_impl
             if dec == "auto":
-                dec = "dense"
+                # A model whose own path is the drop-free sorted one keeps
+                # it (no capacity, so co-batched slots cannot change each
+                # other); dense for the capacity-dispatch models.
+                dec = "sorted" if cfg.moe_impl == "sorted" else "dense"
             if dec == "zero_drop":
                 # cf = E caps capacity at k*T: nothing can ever drop, so
                 # outputs are exactly the dense oracle's (tested) while the
@@ -844,13 +847,14 @@ class LLMEngine:
                 cfg_decode = dataclasses.replace(
                     cfg, moe_impl="dispatch",
                     capacity_factor=float(cfg.num_experts))
-            elif dec == "dense":
-                cfg_decode = dataclasses.replace(cfg, moe_impl="dense")
+            elif dec in ("dense", "sorted"):
+                cfg_decode = dataclasses.replace(cfg, moe_impl=dec)
             else:
                 raise ValueError(
                     f"unknown moe_decode_impl {b.moe_decode_impl!r}")
         self._cfg_prefill, self._cfg_decode = cfg_prefill, cfg_decode
         self.mesh = mesh if (mesh is not None and mesh.size > 1) else None
+        self._refuse_unsupported(cfg, b)
         if b.max_seq_len > cfg.max_seq_len:
             raise ValueError("batching.max_seq_len exceeds model max_seq_len")
         self.num_slots = b.max_batch_size
@@ -941,20 +945,17 @@ class LLMEngine:
             self._table = np.full((self.num_slots, self._mpp), -1, np.int32)
             self._slot_pages: list[list[int]] = [  # lockfree: scheduler-confined
                 [] for _ in range(self.num_slots)]
-            kv_dt = jnp.int8 if self.kv_quant else cfg.activation_dtype
+            from kubeflow_tpu.serve.paged import pool_planes
+
+            # The pool, plane by plane as the model describes it (k and v
+            # per head, int8 pools with their per-token-per-head scales:
+            # +4 bytes per token per kv head against the 2x density win on
+            # the Dh-wide vectors; a latent model's one padded row).
             self.cache = {  # lockfree: scheduler-confined (donated KV)
-                "k": self._zeros((cfg.n_layers, self._num_pages, pg,
-                                  cfg.n_kv_heads, cfg.head_dim), kv_dt),
-                "v": self._zeros((cfg.n_layers, self._num_pages, pg,
-                                  cfg.n_kv_heads, cfg.head_dim), kv_dt),
-            }
-            if self.kv_quant:
-                # Per-token-per-head dynamic scales: +4 bytes per token per
-                # kv head against the 2x density win on the Dh-wide vectors.
-                for n in ("ks", "vs"):
-                    self.cache[n] = self._zeros(
-                        (cfg.n_layers, self._num_pages, pg, cfg.n_kv_heads),
-                        jnp.float32, scale=True)
+                name: self._zeros(
+                    (cfg.n_layers, self._num_pages, pg, *trail), dt,
+                    scale=name in ("ks", "vs"))
+                for name, trail, dt in pool_planes(cfg, self.kv_quant)}
         else:
             self.cache = {  # lockfree: scheduler-confined (donated KV)
                 "k": self._zeros((cfg.n_layers, self.num_slots, self.max_len,
@@ -964,6 +965,11 @@ class LLMEngine:
                                   cfg.n_kv_heads, cfg.head_dim),
                                  cfg.activation_dtype),
             }
+
+        from kubeflow_tpu.serve.paged import pool_bytes_per_token
+
+        self._kv_bytes_per_token = pool_bytes_per_token(cfg, self.kv_quant)
+        self._kv_pool_bytes = int(sum(v.nbytes for v in self.cache.values()))
 
         # Compiled programs: donate the cache so it mutates in place in HBM.
         on_tpu = jax.default_backend() == "tpu"
@@ -1043,7 +1049,7 @@ class LLMEngine:
                 lambda p, c, t, tr, st, vl, ncp, lr=None, ai=None: _pin2(
                     paged_chunk_prefill(
                         p, c, t, tr, st, vl, cfg_prefill, context_pages=ncp,
-                        lora=lr, adapter_idx=ai),
+                        lora=lr, adapter_idx=ai, paged_attn_impl=pattn),
                     self._pin),
                 static_argnums=(6,), donate_argnums=(1,))
 
@@ -1141,7 +1147,9 @@ class LLMEngine:
             # each other's pages (the key simply won't match).
             fabric_sig = (f"L{cfg.n_layers}.H{cfg.n_kv_heads}"
                           f".D{cfg.head_dim}.P{self.page_size}"
-                          f".{'int8' if self.kv_quant else 'full'}")
+                          f".{'int8' if self.kv_quant else 'full'}"
+                          + (f".latent{cfg.kv_lora_rank}+{cfg.qk_rope_dim}"
+                             if cfg.is_latent else ""))
             self._kvtier = RadixPrefixIndex(
                 self._allocator, self.page_size,
                 host_pages=int(b.host_kv_pages),
@@ -1328,6 +1336,7 @@ class LLMEngine:
         self._prefill_phase_n = 0           # lockfree: scheduler-confined counter
         self._decode_steps_dispatched = 0   # lockfree: scheduler-confined counter
         self._decode_tokens_emitted = 0     # lockfree: scheduler-confined counter
+        self._decode_context_tokens = 0     # lockfree: scheduler-confined counter
         self.waiting: "queue.Queue[Request]" = queue.Queue()
         self.metrics = EngineMetrics()
         # Bounded admission + queue-delay budget (load shedding): see
@@ -1373,6 +1382,38 @@ class LLMEngine:
             return jnp.zeros(shape, dtype)
         return jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=sh)()
 
+    def _refuse_unsupported(self, cfg: DecoderConfig, b) -> None:
+        """Name, when the engine is built, each mechanism that cannot take
+        this model yet: a latent page pool (one row a token for all heads)
+        has no per-head K and V, which the int8 pool's scales, the handoff
+        payload, the host tier's wire format and the speculative verify
+        step are written over; a stack of more than one kind of layer is
+        not one ``params["layers"]``, which those and the weight quantizer,
+        the adapter buffers, the contiguous cache and the mesh's sharding
+        walk."""
+        if not (cfg.is_latent or cfg.leading_dense_layers):
+            return
+        what = ("a latent (ckv) KV pool" if cfg.is_latent
+                else "leading dense layers")
+        refused = {
+            "kv_cache_dtype=int8 (int8 KV)": b.kv_cache_dtype is not None,
+            f"role={b.role!r} (handoff export/adopt)": b.role != "unified",
+            "host_kv_pages / remote_kv_root (the host tier's wire format)":
+                bool(b.host_kv_pages) or b.remote_kv_root is not None,
+            f"speculative.mode={b.speculative.mode!r} (speculative verify)":
+                b.speculative.mode != "off",
+            f"lora.targets={list(b.lora.targets)} (LoRA targets that do "
+            "not exist in this block)": bool(b.lora.max_adapters),
+            "quantize=int8 (weight quantization)": b.quantize is not None,
+            "paged=False (the contiguous slot cache)": not b.paged,
+            "a mesh (tensor-parallel serving)": self.mesh is not None,
+        }
+        hit = [name for name, on in refused.items() if on]
+        if hit:
+            raise ValueError(
+                f"this model has {what}; not supported with it yet: "
+                + "; ".join(hit))
+
     def _pin(self, cache: dict) -> dict:
         if self._cache_sh is None:
             return cache
@@ -1409,6 +1450,13 @@ class LLMEngine:
             # sum of k_steps; tokens the consumed rounds handed to requests
             "decode_steps_dispatched": self._decode_steps_dispatched,
             "decode_tokens_emitted": self._decode_tokens_emitted,
+            # cache rows the dispatched steps attend to, summed over the
+            # live slots and the steps of every round
+            "decode_context_tokens": self._decode_context_tokens,
+            # constants: content bytes a token holds over all layers of
+            # the cache, and the cache's size on the device
+            "kv_bytes_per_token": self._kv_bytes_per_token,
+            "kv_pool_bytes": self._kv_pool_bytes,
         }
 
     def queue_depth(self) -> int:
@@ -1492,10 +1540,7 @@ class LLMEngine:
         measured from."""
         if not self.paged:
             return {}
-        pool_bytes = self.cache["k"].nbytes + self.cache["v"].nbytes
-        if self.kv_quant:
-            pool_bytes += (self.cache["ks"].nbytes
-                           + self.cache["vs"].nbytes)
+        pool_bytes = self._kv_pool_bytes
         tokens = self._num_pages * self.page_size
         return {
             "quant": int(self.kv_quant),
@@ -1562,6 +1607,10 @@ class LLMEngine:
         # the unified-fallback local decode).
         wants_handoff = (self.role == "prefill" if handoff is None
                          else bool(handoff))
+        if wants_handoff and self.cfg.is_latent:
+            raise ValueError(
+                "handoff export carries per-head K and V; a latent "
+                "(ckv) pool is not supported yet")
         req = Request(prompt_tokens=list(prompt_tokens),
                       params=params or SamplingParams(),
                       id=request_id or f"req-{next(self._id_gen)}",
@@ -1586,6 +1635,10 @@ class LLMEngine:
         uploads the KV into this engine's own pool instead of running
         prefill; the emitted stream starts at the SECOND token."""
         payload.validate()
+        if self.cfg.is_latent:
+            raise ValueError(
+                "handoff adopt carries per-head K and V; a latent "
+                "(ckv) pool is not supported yet")
         want = "int8" if self.kv_quant else None
         if payload.cache_dtype != want:
             # Mixed-dtype fleets fail loudly at the boundary (the caller
@@ -2889,11 +2942,17 @@ class LLMEngine:
             self.metrics.observe_host_gap(gap)
         self.metrics.note_dispatch_depth(len(self._rounds))
         round_id = self.decode_rounds
+        # Cache rows the round attends to, over its live slots and steps:
+        # step j attends to a slot's rows 0..length+slack+j.
+        context = sum(
+            k_steps * (s.length + slack) + k_steps * (k_steps + 1) // 2
+            for _, s in active)
         with hot_span(prof.ENGINE_DECODE_DISPATCH, round=round_id,
-                      k_steps=k_steps, live=len(active)):
+                      k_steps=k_steps, live=len(active), context=context):
             out = self._dispatch_decode(k_steps, mode)
         self.decode_rounds += 1
         self._decode_steps_dispatched += k_steps
+        self._decode_context_tokens += context
         self._rounds.append(_InflightRound(
             out=out, active=list(active), k_steps=k_steps,
             gap_ms=None if gap is None else gap * 1e3, round_id=round_id))

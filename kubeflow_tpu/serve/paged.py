@@ -6,8 +6,9 @@ batching, paged KV').
 Why paging matters on v5e: the contiguous slot cache reserves
 ``slots × max_seq_len`` HBM whether or not requests use it; high-density
 serving wants HBM proportional to *actual* tokens resident. Here KV lives in
-a fixed pool of pages ``[L, P, page, KV, Dh]``; each slot owns an ordered
-page list (its page table), and:
+a fixed pool of pages ``[L, P, page, KV, Dh]`` (``pool_planes``: K and V per
+head, their scales when int8, or a latent model's one row a token); each
+slot owns an ordered page list (its page table), and:
 
 - **Allocation** is a host-side free list with O(1) alloc/free between
   device steps — the device never sees allocation, only page-id arrays.
@@ -54,7 +55,7 @@ import numpy as np
 
 from kubeflow_tpu.models import layers as L
 from kubeflow_tpu.models.config import DecoderConfig
-from kubeflow_tpu.models.decoder import Params
+from kubeflow_tpu.models.decoder import Params, layer_groups
 
 
 # -- host-side page allocator --------------------------------------------------
@@ -306,13 +307,53 @@ class PageAllocator:
 
 # -- device-side paged steps ---------------------------------------------------
 #
-# Cache pytree: {"k": [L,P,pg,KV,Dh], "v": same, "table": [B, mpp] int32}
-# (int8 pools add the scale planes "ks"/"vs" [L,P,pg,KV] f32) where
-# mpp = max_seq_len // page. Table entries are page ids; -1 = unmapped
+# Cache pytree: one [L,P,pg,*trailing] array per PLANE of the pool, as
+# ``pool_planes`` describes them for the model, plus "table" [B, mpp] int32
+# where mpp = max_seq_len // page. Table entries are page ids; -1 = unmapped
 # (reads are length-masked, writes aimed out of bounds and dropped).
 
 
-_PLANES = ("k", "v", "ks", "vs")     # pool planes; the scales iff int8
+def pool_planes(cfg: DecoderConfig, kv_quant: bool = False) -> tuple:
+    """What one token holds in one layer of the page pool: (name, trailing
+    shape, type) per plane. The ONE description the pool is built from and
+    that the page copy, the decode write, the chunk's gather and scatter and
+    the bytes accounting walk.
+
+    Per-head K and V: "k"/"v" [KV, Dh] (int8 pools add the per-token
+    per-head scale planes "ks"/"vs" [KV] f32). Latent attention: ONE plane
+    "ckv" [W], the row of ``layers.latent_qkv``: the compressed latent
+    after its norm, the rotary key values after RoPE (shared by all
+    heads), zeros up to whole 128-value lanes (576 -> 640 at the published
+    ranks). One padded row and not two planes of 512 and 64: the chip's
+    compiler copies a 64-wide plane WHOLE, twice, around every decode
+    step's row write (its tiled layout has no 64-value rows), and a page is
+    then one aligned block and one DMA for the kernels (PERF.md, PR 28)."""
+    dt = cfg.activation_dtype
+    if cfg.is_latent:
+        if kv_quant:
+            raise ValueError("int8 KV over a latent (ckv) pool")
+        return (("ckv", (L.latent_row_width(cfg),), dt),)
+    kv = (cfg.n_kv_heads, cfg.head_dim)
+    if kv_quant:
+        f32 = jnp.dtype(jnp.float32)
+        return (("k", kv, jnp.dtype(jnp.int8)), ("v", kv, jnp.dtype(jnp.int8)),
+                ("ks", kv[:1], f32), ("vs", kv[:1], f32))
+    return (("k", kv, dt), ("v", kv, dt))
+
+
+def pool_bytes_per_token(cfg: DecoderConfig, kv_quant: bool = False) -> int:
+    """Bytes one token holds over all layers of the pool (a latent row's
+    padding included: 1280 a layer at the published ranks for 1152 of
+    content)."""
+    return cfg.n_layers * sum(
+        int(np.prod(trail)) * jnp.dtype(dt).itemsize
+        for _, trail, dt in pool_planes(cfg, kv_quant))
+
+
+def _planes_of(cache: dict) -> tuple:
+    """The names of a cache pytree's pool planes (everything but the page
+    table)."""
+    return tuple(n for n in cache if n != "table")
 
 
 def paged_gather(pool: jax.Array, table: jax.Array) -> jax.Array:  # traced
@@ -322,21 +363,71 @@ def paged_gather(pool: jax.Array, table: jax.Array) -> jax.Array:  # traced
     return pages.reshape(b, mpp * pool.shape[1], *pool.shape[2:])
 
 
+def _embed(params: Params, tokens: jax.Array, cfg: DecoderConfig):  # traced
+    x = params["embed"].astype(cfg.activation_dtype)[tokens]
+    if cfg.embed_scale:
+        x = x * jnp.asarray(cfg.hidden ** 0.5, cfg.activation_dtype)
+    return x
+
+
+def _head_logits(params: Params, x: jax.Array, cfg: DecoderConfig):  # traced
+    """Final norm and output head: [B,S,D] -> [B,S,V] float32."""
+    x = L.rmsnorm(x, params["final_norm"], cfg)
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.activation_dtype),
+                        preferred_element_type=jnp.float32)
+    if cfg.logits_softcap is not None:
+        logits = jnp.tanh(logits / cfg.logits_softcap) * cfg.logits_softcap
+    return logits
+
+
+def _feed_forward(bp, h, cfg: DecoderConfig, expert_stack=None,  # traced
+                  valid_len=None):
+    if cfg.is_moe:
+        return L.moe_block(bp["mlp"], h, cfg, valid_len=valid_len,
+                           expert_stack=expert_stack)[0]
+    return L.mlp_block(bp["mlp"], h, cfg)
+
+
+def _scan_layer_groups(params: Params, cfg: DecoderConfig, carry, block,  # traced
+                       lora=None):
+    """``carry`` through every layer, one scan per group of alike layers
+    (decoder.layer_groups): ``block(bp, carry, layer, gcfg, lora_view,
+    expert_stack) -> carry``, ``layer`` the index into the pool, which runs
+    through the groups. A sorted expert group's expert leaves are taken
+    whole, with the layer's index in the group (layers.split_expert_stack)."""
+    for name, gcfg, first in layer_groups(cfg):
+        stack, experts = L.split_expert_stack(params[name], gcfg)
+
+        def body(carry, scan_in, gcfg=gcfg, experts=experts, first=first):
+            bp, lsl, layer = scan_in
+            return block(bp, carry, layer, gcfg, L.layer_view(lora, lsl),
+                         None if experts is None
+                         else (experts, layer - first)), None
+
+        carry, _ = jax.lax.scan(
+            body, carry,
+            (stack, L.slice_layers(lora),
+             first + jnp.arange(gcfg.n_layers, dtype=jnp.int32)))
+    return carry
+
+
 def _paged_decode_block(bp, x, positions, lengths, live, pools, table,  # traced
                         layer, num_pages: int, cfg: DecoderConfig,
-                        attn_impl: str = "gather", lora=None):
+                        attn_impl: str = "gather", lora=None,
+                        expert_stack=None):
     """One transformer block for a [B,1] decode step against the page pool.
     Mirrors engine._decode_block; only the KV residency differs.
 
     ``pools`` holds every plane of the WHOLE pool viewed flat —
     ``k``/``v`` ``[L*P,pg,KV,Dh]`` and, iff the pool stores int8, the
-    per-token-per-head scales ``ks``/``vs`` ``[L*P,pg,KV]`` f32 — and
-    ``layer`` (a traced scalar) picks this block's ``num_pages`` (P) pages
-    out of it: page ``p`` of layer ``l`` is flat page ``l*P + p``. The
-    block writes its token's K/V rows into the planes it was handed and
-    returns them, so the caller can carry them through its loops and the
-    write lands in place; nothing here slices a layer's slab out or puts
-    one back.
+    per-token-per-head scales ``ks``/``vs`` ``[L*P,pg,KV]`` f32; a latent
+    pool's one plane ``ckv`` ``[L*P,pg,W]`` — and ``layer`` (a traced scalar)
+    picks this block's ``num_pages`` (P) pages out of it: page ``p`` of
+    layer ``l`` is flat page ``l*P + p``. The block writes its token's rows
+    into the planes it was handed and returns them, so the caller can carry
+    them through its loops and the write lands in place; nothing here
+    slices a layer's slab out or puts one back.
 
     ``attn_impl``: "gather" materializes the slot's pages into the
     contiguous layout and runs the XLA decode attention (2× KV read);
@@ -346,25 +437,12 @@ def _paged_decode_block(bp, x, positions, lengths, live, pools, table,  # traced
     operand ("gather") or ride the kernel, which dequantizes in VMEM
     ("pallas") — the pool (the resident thing) holds 2× the tokens per
     byte either way, and the kernel path also halves the per-step KV HBM
-    read."""
-    from kubeflow_tpu.serve.engine import _decode_attention
-
-    dt = cfg.activation_dtype
-    kv_quant = "ks" in pools
-    total, pg = pools["k"].shape[:2]
+    read. A latent pool is attended in the ABSORBED form (the key expansion
+    folded into the query, the value expansion applied to the attended
+    latent), so the step never holds per-head K or V of the context."""
+    total, pg = next(iter(pools.values())).shape[:2]
     base = layer * num_pages
     h = L.rmsnorm(x, bp["ln1"], cfg)
-    q = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wq"].astype(dt))
-    k = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wk"].astype(dt))
-    v = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wv"].astype(dt))
-    if lora is not None:
-        # Multi-adapter decode (serve/lora.py): per-row low-rank deltas
-        # on the shared projections; adapter_idx = -1 rows add exact 0.
-        q = L.apply_lora_layer(lora, "wq", h, q)
-        k = L.apply_lora_layer(lora, "wk", h, k)
-        v = L.apply_lora_layer(lora, "wv", h, v)
-    q = L.rope(q, positions, cfg.rope_theta)
-    k = L.rope(k, positions, cfg.rope_theta)
     # Write position -> (flat page, offset). Dead rows and unmapped pages
     # aim past the END of the flat pool and DROP: one past this layer's
     # pages (base + P) is the next layer's page 0.
@@ -373,6 +451,37 @@ def _paged_decode_block(bp, x, positions, lengths, live, pools, table,  # traced
     page_id = table[bidx, jnp.clip(page_slot, 0, table.shape[1] - 1)]
     pidx = jnp.where(live & (page_id >= 0), base + page_id, total)
     off = lengths % pg
+    # This layer's page table into the flat pool; -1 stays unmapped.
+    ltable = jnp.where(table >= 0, table + base, -1)
+    attend = _latent_decode_attention if cfg.is_latent \
+        else _kv_decode_attention
+    proj, pools = attend(bp["attn"], h, positions, lengths, pools, pidx, off,
+                         ltable, cfg, attn_impl, lora)
+    x = x + proj
+    h = L.rmsnorm(x, bp["ln2"], cfg)
+    return x + _feed_forward(bp, h, cfg, expert_stack), pools
+
+
+def _kv_decode_attention(a, h, positions, lengths, pools, pidx, off,  # traced
+                         ltable, cfg: DecoderConfig, attn_impl: str, lora):
+    """Per-head K/V: project, write this token's rows at (pidx, off), attend
+    to the slot's pages. Returns (the block's attention output [B,1,D], the
+    planes as written)."""
+    from kubeflow_tpu.serve.engine import _decode_attention
+
+    dt = cfg.activation_dtype
+    kv_quant = "ks" in pools
+    q = jnp.einsum("bsd,dhk->bshk", h, a["wq"].astype(dt))
+    k = jnp.einsum("bsd,dhk->bshk", h, a["wk"].astype(dt))
+    v = jnp.einsum("bsd,dhk->bshk", h, a["wv"].astype(dt))
+    if lora is not None:
+        # Multi-adapter decode (serve/lora.py): per-row low-rank deltas
+        # on the shared projections; adapter_idx = -1 rows add exact 0.
+        q = L.apply_lora_layer(lora, "wq", h, q)
+        k = L.apply_lora_layer(lora, "wk", h, k)
+        v = L.apply_lora_layer(lora, "wv", h, v)
+    q = L.rope(q, positions, cfg.rope_theta)
+    k = L.rope(k, positions, cfg.rope_theta)
     rows = {"k": k[:, 0], "v": v[:, 0]}
     if kv_quant:
         from kubeflow_tpu.ops.quantization import dequantize_kv, quantize_kv
@@ -381,8 +490,6 @@ def _paged_decode_block(bp, x, positions, lengths, live, pools, table,  # traced
         rows["v"], rows["vs"] = quantize_kv(v[:, 0])
     pools = {name: pools[name].at[pidx, off].set(row, mode="drop")
              for name, row in rows.items()}
-    # This layer's page table into the flat pool; -1 stays unmapped.
-    ltable = jnp.where(table >= 0, table + base, -1)
     if attn_impl == "pallas":
         from kubeflow_tpu.ops.paged_attention import paged_decode_attention
 
@@ -396,17 +503,42 @@ def _paged_decode_block(bp, x, positions, lengths, live, pools, table,  # traced
             ck = dequantize_kv(ck, paged_gather(pools["ks"], ltable), dt)
             cv = dequantize_kv(cv, paged_gather(pools["vs"], ltable), dt)
         attn = _decode_attention(q, ck, cv, lengths, cfg)
-    proj = jnp.einsum("bshk,hkd->bsd", attn, bp["attn"]["wo"].astype(dt))
+    proj = jnp.einsum("bshk,hkd->bsd", attn, a["wo"].astype(dt))
     if lora is not None and "wo" in lora["targets"]:
         proj = L.apply_lora_layer(
             lora, "wo", attn.reshape(attn.shape[0], 1, -1), proj)
-    x = x + proj
-    h = L.rmsnorm(x, bp["ln2"], cfg)
-    if cfg.is_moe:
-        mlp_out, _ = L.moe_block(bp["mlp"], h, cfg)
+    return proj, pools
+
+
+def _latent_decode_attention(a, h, positions, lengths, pools, pidx, off,  # traced
+                             ltable, cfg: DecoderConfig, attn_impl: str,
+                             lora):
+    """Latent attention's decode step, absorbed: write this token's cache
+    row, fold the key expansion into the query, attend over the slot's
+    pages ("pallas": the kernel reads each page once for all heads;
+    "gather": the same sums in XLA over the gathered rows), expand the
+    attended row into values. Same return as the per-head form."""
+    if lora is not None:
+        raise NotImplementedError("LoRA over latent attention projections")
+    q_nope, q_rope, row = L.latent_qkv(a, h, positions, cfg)
+    pools = {"ckv": pools["ckv"].at[pidx, off].set(row[:, 0], mode="drop")}
+    if attn_impl == "pallas":
+        from kubeflow_tpu.ops.paged_attention import (
+            paged_latent_decode_attention,
+        )
+
+        q = L.latent_query(a, q_nope[:, 0], q_rope[:, 0], cfg)   # [B,H,W]
+        o_row = paged_latent_decode_attention(
+            q, pools["ckv"], ltable, lengths, sm_scale=L.latent_scale(cfg))
+        attn = L.latent_output(a, o_row, cfg)[:, None]
     else:
-        mlp_out = L.mlp_block(bp["mlp"], h, cfg)
-    return x + mlp_out, pools
+        rows = paged_gather(pools["ckv"], ltable)          # [B, S, W]
+        mask = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :] \
+            <= lengths[:, None]                            # [B, S]
+        attn = L.latent_absorbed_attention(
+            a, q_nope, q_rope, rows, mask[:, None, None, :], cfg)
+    return jnp.einsum("bshk,hkd->bsd", attn,
+                      a["wo"].astype(cfg.activation_dtype)), pools
 
 
 def _paged_decode_step(params: Params, cache: dict, tokens: jax.Array,  # traced
@@ -423,33 +555,21 @@ def _paged_decode_step(params: Params, cache: dict, tokens: jax.Array,  # traced
     at ``layer*P + page``; the scanned inputs are the layer's weights, its
     LoRA slice and its index. The pytree handed back is ``[L,P,...]``
     again, so every other program sees the cache it always saw."""
-    dt = cfg.activation_dtype
-    x = params["embed"].astype(dt)[tokens[:, None]]
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.hidden ** 0.5, dt)
+    x = _embed(params, tokens[:, None], cfg)
     positions = lengths[:, None]
     table = cache["table"]
-    n_layers, num_pages = cache["k"].shape[:2]
-    flat = {n: cache[n].reshape(-1, *cache[n].shape[2:])
-            for n in _PLANES if n in cache}
+    planes = _planes_of(cache)
+    num_pages = cache[planes[0]].shape[1]
+    flat = {n: cache[n].reshape(-1, *cache[n].shape[2:]) for n in planes}
 
-    def body(carry, scan_in):
-        x, pools = carry
-        bp, lsl, layer = scan_in
+    def block(bp, carry, layer, gcfg, lora_view, expert_stack):
         return _paged_decode_block(
-            bp, x, positions, lengths, live, pools, table, layer, num_pages,
-            cfg, attn_impl=attn_impl, lora=L.layer_view(lora, lsl)), None
+            bp, carry[0], positions, lengths, live, carry[1], table, layer,
+            num_pages, gcfg, attn_impl=attn_impl, lora=lora_view,
+            expert_stack=expert_stack)
 
-    (x, flat), _ = jax.lax.scan(
-        body, (x, flat),
-        (params["layers"], L.slice_layers(lora),
-         jnp.arange(n_layers, dtype=jnp.int32)))
-    x = L.rmsnorm(x, params["final_norm"], cfg)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(dt),
-                        preferred_element_type=jnp.float32)[:, 0]
-    if cfg.logits_softcap is not None:
-        logits = jnp.tanh(logits / cfg.logits_softcap) * cfg.logits_softcap
+    x, flat = _scan_layer_groups(params, cfg, (x, flat), block, lora)
+    logits = _head_logits(params, x, cfg)[:, 0]
     out = {n: p.reshape(cache[n].shape) for n, p in flat.items()}
     out["table"] = table
     return logits, out
@@ -473,7 +593,7 @@ def paged_decode_multi(params: Params, cache: dict, tokens: jax.Array,  # traced
 
     b = tokens.shape[0]
     mpp = cache["table"].shape[1]
-    pg = cache["k"].shape[2]
+    pg = cache[_planes_of(cache)[0]].shape[2]
     max_len = mpp * pg
     out0 = jnp.full((b, num_steps), -1, jnp.int32)
     lr = (None if lora is None
@@ -507,16 +627,14 @@ def paged_decode_multi(params: Params, cache: dict, tokens: jax.Array,  # traced
 
 def copy_pages(cache: dict, src: jax.Array, dst: jax.Array) -> dict:  # traced
     """Page-to-page pool copy: ``dst[i] <- src[i]`` for every pool plane
-    (k/v and, when quantized, their scales) — the radix index's
-    copy-on-write primitive (serve/kvtier.py): a request diverging inside
-    a shared block gets a private copy of the partial tail in ONE
-    dispatch instead of recomputing it. Out-of-range ``dst`` ids (the
-    power-of-two pad) drop their writes."""
+    (k/v and, when quantized, their scales; a latent pool's one padded row)
+    — the radix index's copy-on-write primitive (serve/kvtier.py): a
+    request diverging inside a shared block gets a private copy of the
+    partial tail in ONE dispatch instead of recomputing it. Out-of-range
+    ``dst`` ids (the power-of-two pad) drop their writes."""
     out = dict(cache)
-    for name in _PLANES:
-        pool = cache.get(name)
-        if pool is None:
-            continue
+    for name in _planes_of(cache):
+        pool = cache[name]
         npages = pool.shape[1]
         d = jnp.where((dst >= 0) & (dst < npages), dst, npages)
         out[name] = pool.at[:, d].set(
@@ -542,7 +660,8 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
                         valid_len: jax.Array, cfg: DecoderConfig,
                         attn_impl: str = "xla",
                         context_pages: Optional[int] = None,
-                        lora=None, adapter_idx=None):
+                        lora=None, adapter_idx=None,
+                        paged_attn_impl: str = "gather"):
     """Prefill ONE chunk (``tokens`` [1,C], positions [start, start+C)) of a
     slot whose pages are ``table_row`` [mpp]; the chunk's K/V scatters back
     per token as (page, offset) writes off the table row — exactly the
@@ -554,7 +673,12 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
 
     The chunk attends to the slot's earlier KV by gathering the page table
     into the contiguous layout decoder_forward's cache path expects, then
-    scatters only the chunk's tokens back. ``context_pages`` (STATIC)
+    scatters only the chunk's tokens back; every plane of a per-head pool
+    (``pool_planes``) goes the same way. A latent pool takes the chunk as
+    the decode step takes a token (``_paged_latent_chunk_prefill``: rows
+    written in place, attention absorbed over the pages where they lie,
+    ``paged_attn_impl`` the engine's "gather" | "pallas"). ``context_pages``
+    (STATIC)
     bounds the gather to the pages actually covering [0, start+C): chunk
     cost then tracks the resident context, not max_len — without it a long
     prompt pays O(max_len²/C) in gathers (round-2 weak #4). The caller
@@ -562,37 +686,40 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
     Returns ([C,V] logits, cache)."""
     from kubeflow_tpu.models.decoder import decoder_forward
 
-    pg = cache["k"].shape[2]
+    if cfg.is_latent:
+        if lora is not None:
+            raise NotImplementedError(
+                "LoRA over latent attention projections")
+        return _paged_latent_chunk_prefill(
+            params, cache, tokens, table_row, start, valid_len, cfg,
+            paged_attn_impl, context_pages)
+    planes = _planes_of(cache)
+    pg = cache[planes[0]].shape[2]
     c = tokens.shape[1]
     kv_quant = "ks" in cache
     if context_pages is not None:
         # Static slice: the bucket must cover the chunk's own pages too
         # (the [start, start+C) update-slice window below).
         table_row = table_row[:min(context_pages, table_row.shape[0])]
-    # Gather the slot's visible cache row: [L,1,ctx*pg,K,D]. Pad the row by
-    # one chunk of scratch positions so the final chunk's C-wide
-    # dynamic_update_slice window can never clamp and overwrite earlier KV
-    # (prefix-cache hits start chunks at page — not chunk — alignment, so
-    # start + C may exceed the bucket edge). The scratch tail is
-    # causal-masked (kv position > any query position) and never scattered
-    # back to pages.
-    row_k = jax.vmap(lambda pool: paged_gather(pool, table_row[None]))(
-        cache["k"])
-    row_v = jax.vmap(lambda pool: paged_gather(pool, table_row[None]))(
-        cache["v"])
+    # Gather the slot's visible cache row, every plane: [L,1,ctx*pg,...].
+    # Pad the row by one chunk of scratch positions so the final chunk's
+    # C-wide dynamic_update_slice window can never clamp and overwrite
+    # earlier KV (prefix-cache hits start chunks at page — not chunk —
+    # alignment, so start + C may exceed the bucket edge). The scratch tail
+    # is causal-masked (kv position > any query position) and never
+    # scattered back to pages.
+    rows = {n: jax.vmap(lambda pool: paged_gather(pool, table_row[None]))(
+        cache[n]) for n in planes}
     if kv_quant:
         from kubeflow_tpu.ops.quantization import dequantize_kv, quantize_kv
 
         dt = cfg.activation_dtype
-        row_ks = jax.vmap(lambda pool: paged_gather(pool, table_row[None]))(
-            cache["ks"])
-        row_vs = jax.vmap(lambda pool: paged_gather(pool, table_row[None]))(
-            cache["vs"])
-        row_k = dequantize_kv(row_k, row_ks, dt)
-        row_v = dequantize_kv(row_v, row_vs, dt)
-    pad = [(0, 0), (0, 0), (0, c), (0, 0), (0, 0)]
-    caches = {"k": jnp.pad(row_k, pad), "v": jnp.pad(row_v, pad),
-              "len": start}
+        rows = {"k": dequantize_kv(rows["k"], rows["ks"], dt),
+                "v": dequantize_kv(rows["v"], rows["vs"], dt)}
+    caches = {n: jnp.pad(row, [(0, 0), (0, 0), (0, c)]
+                         + [(0, 0)] * (row.ndim - 3))
+              for n, row in rows.items()}
+    caches["len"] = start
     lr = None if lora is None else {**lora, "aidx": adapter_idx}
     logits, filled, _ = decoder_forward(params, tokens, cfg, kv_caches=caches,
                                         attn_impl=attn_impl,
@@ -601,24 +728,85 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
     # position start+i lands on table_row[(start+i)//pg] at offset
     # (start+i)%pg. Invalid rows (past valid_len, or an unmapped/-1 page)
     # aim out of bounds and drop.
-    written_k = jax.lax.dynamic_slice_in_dim(filled["k"], start, c,
-                                             axis=2)[:, 0]     # [L,C,K,D]
-    written_v = jax.lax.dynamic_slice_in_dim(filled["v"], start, c,
-                                             axis=2)[:, 0]
+    written = {n: jax.lax.dynamic_slice_in_dim(filled[n], start, c,
+                                               axis=2)[:, 0]   # [L,C,...]
+               for n in rows}
     pos = start + jnp.arange(c, dtype=jnp.int32)
     pslot = pos // pg
     page_id = table_row[jnp.clip(pslot, 0, table_row.shape[0] - 1)]
     ok = (jnp.arange(c, dtype=jnp.int32) < valid_len) & (page_id >= 0) \
         & (pslot < table_row.shape[0])
-    npages_pool = cache["k"].shape[1]
+    npages_pool = cache[planes[0]].shape[1]
     pidx = jnp.where(ok & (page_id < npages_pool), page_id, npages_pool)
     off = pos % pg
-    out = {}
     if kv_quant:
-        written_k, wks = quantize_kv(written_k)
-        written_v, wvs = quantize_kv(written_v)
-        out["ks"] = cache["ks"].at[:, pidx, off].set(wks, mode="drop")
-        out["vs"] = cache["vs"].at[:, pidx, off].set(wvs, mode="drop")
-    out["k"] = cache["k"].at[:, pidx, off].set(written_k, mode="drop")
-    out["v"] = cache["v"].at[:, pidx, off].set(written_v, mode="drop")
+        written["k"], written["ks"] = quantize_kv(written["k"])
+        written["v"], written["vs"] = quantize_kv(written["v"])
+    out = {n: cache[n].at[:, pidx, off].set(written[n], mode="drop")
+           for n in planes}
     return logits[0], out
+
+
+def _paged_latent_chunk_prefill(params: Params, cache: dict,  # traced
+                                tokens: jax.Array, table_row: jax.Array,
+                                start: jax.Array, valid_len: jax.Array,
+                                cfg: DecoderConfig, attn_impl: str,
+                                context_pages: Optional[int]):
+    """``paged_chunk_prefill`` over a latent pool, built like the decode
+    step and not like ``decoder_forward``'s cache path: the pool is carried
+    whole and flat ``[L*P, pg, W]`` through the layer scans, a layer writes
+    the chunk's ``C`` rows in place at ``(layer*P + page, offset)`` and
+    then attends, ABSORBED and causally, over the slot's pages where they
+    lie (the chunk's own among them): "pallas" through
+    ``paged_latent_chunk_attention``, which skips the pages behind the
+    chunk, "gather" through the same sums in XLA over the gathered rows.
+    Nothing gathers all layers' context up front, pads it, or puts a
+    layer's slab back. Same contract: only the first ``valid_len`` positions
+    write; ``context_pages`` bounds the pages looked at."""
+    dt = cfg.activation_dtype
+    pool = cache["ckv"]
+    num_pages, pg = pool.shape[1:3]
+    c = tokens.shape[1]
+    if context_pages is not None:
+        table_row = table_row[:min(context_pages, table_row.shape[0])]
+    pos = start + jnp.arange(c, dtype=jnp.int32)
+    pslot = pos // pg
+    page_id = table_row[jnp.clip(pslot, 0, table_row.shape[0] - 1)]
+    ok = (jnp.arange(c, dtype=jnp.int32) < valid_len) & (page_id >= 0) \
+        & (pslot < table_row.shape[0]) & (page_id < num_pages)
+    off = pos % pg
+
+    def block(bp, carry, layer, gcfg, _, expert_stack):
+        x, flat = carry
+        a = bp["attn"]
+        base = layer * num_pages
+        h = L.rmsnorm(x, bp["ln1"], gcfg)
+        q_nope, q_rope, row = L.latent_qkv(a, h, pos[None], gcfg)
+        pidx = jnp.where(ok, base + page_id, flat.shape[0])
+        flat = flat.at[pidx, off].set(row[0], mode="drop")
+        ltable = jnp.where(table_row >= 0, table_row + base, -1)
+        if attn_impl == "pallas":
+            from kubeflow_tpu.ops.paged_attention import (
+                paged_latent_chunk_attention,
+            )
+
+            q = L.latent_query(a, q_nope[0], q_rope[0], gcfg)  # [C,H,W]
+            o_row = paged_latent_chunk_attention(
+                jnp.swapaxes(q, 0, 1), flat, ltable, start,
+                sm_scale=L.latent_scale(gcfg))
+            attn = L.latent_output(a, jnp.swapaxes(o_row, 0, 1), gcfg)[None]
+        else:
+            rows = paged_gather(flat, ltable[None])        # [1, T, W]
+            causal = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :] \
+                <= pos[:, None]                            # [C, T]
+            attn = L.latent_absorbed_attention(
+                a, q_nope, q_rope, rows, causal[None, None], gcfg)
+        proj = jnp.einsum("bshk,hkd->bsd", attn, a["wo"].astype(dt))
+        x, h = L.add_rmsnorm(x, proj, bp["ln2"], gcfg)
+        return x + _feed_forward(bp, h, gcfg, expert_stack, valid_len), flat
+
+    x, flat = _scan_layer_groups(
+        params, cfg, (_embed(params, tokens, cfg),
+                      pool.reshape(-1, *pool.shape[2:])), block)
+    logits = _head_logits(params, x, cfg)
+    return logits[0], {"ckv": flat.reshape(pool.shape)}

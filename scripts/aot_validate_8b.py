@@ -123,7 +123,7 @@ def paged_serve_analysis(topology: str, tp: int, *, model: str,
     from kubeflow_tpu.parallel.sharding import shard_params
     from kubeflow_tpu.runtime.device_report import kernel_calls
     from kubeflow_tpu.serve.paged import (
-        paged_chunk_prefill, paged_decode_multi)
+        paged_chunk_prefill, paged_decode_multi, pool_planes)
 
     mesh = _mesh_on(topology, {"model": tp})
     cfg = preset(model, **overrides)
@@ -149,8 +149,9 @@ def paged_serve_analysis(topology: str, tp: int, *, model: str,
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
 
     mpp = max_len // page_size
-    pool = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
-    cache = {n: sds(pool, cfg.activation_dtype, kv_sh) for n in ("k", "v")}
+    cache = {n: sds((cfg.n_layers, num_pages, page_size, *trail), dt,
+                    kv_sh if len(trail) == 2 else rep)
+             for n, trail, dt in pool_planes(cfg)}
     i32, f32 = (lambda: sds((slots,), jnp.int32)), (
         lambda: sds((slots,), jnp.float32))
 
@@ -165,7 +166,8 @@ def paged_serve_analysis(topology: str, tp: int, *, model: str,
             sds((2,), jnp.uint32))
     chunked = jax.jit(
         lambda p, c, t, tr, st, vl: paged_chunk_prefill(
-            p, c, t, tr, st, vl, cfg, context_pages=mpp),
+            p, c, t, tr, st, vl, cfg, context_pages=mpp,
+            paged_attn_impl="pallas" if attn_impl == "pallas" else "gather"),
         donate_argnums=(1,)).lower(
             params_sds, cache, sds((1, chunk), jnp.int32),
             sds((mpp,), jnp.int32), sds((), jnp.int32), sds((), jnp.int32))

@@ -174,3 +174,66 @@ def test_kernel_compiles_for_v5e(chip, kernel, widths):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- GLM-4.7-Flash's widths: the latent decode kernel, the sorted experts -------
+
+def test_latent_kernels_compile_for_v5e(chip):
+    """20 heads against one shared 640-wide row (512 latent + 64 rotary +
+    padding), over the benchmark cell's pool viewed flat (7 layers x 2080
+    pages of 128): the decode kernel at 16 slots of 130 pages, the chunk
+    kernel at 512 queries over a slot's 130 pages."""
+    from kubeflow_tpu.ops.paged_attention import (
+        paged_latent_chunk_attention, paged_latent_decode_attention,
+    )
+
+    slots, h, w, pages, mpp, chunk = 16, 20, 640, 7 * 2080, 130, 512
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    pool = sds((pages, PAGE, w))
+    decode = jax.jit(lambda *a: paged_latent_decode_attention(
+        *a, sm_scale=256 ** -0.5, interpret=False)).lower(
+            sds((slots, h, w)), pool, sds((slots, mpp), jnp.int32),
+            sds((slots,), jnp.int32)).compile()
+    prefill = jax.jit(lambda *a: paged_latent_chunk_attention(
+        *a, sm_scale=256 ** -0.5, interpret=False)).lower(
+            sds((h, chunk, w)), pool, sds((mpp,), jnp.int32),
+            sds((), jnp.int32)).compile()
+    for compiled, name in ((decode, "paged_latent_decode_attention"),
+                           (prefill, "paged_latent_chunk_attention")):
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text and name in text
+
+
+@pytest.mark.parametrize("tokens", [512, 16])
+def test_sorted_experts_are_a_grouped_matmul_on_v5e(chip, tokens):
+    """64 experts of 1536, top-4. A chunk's 512 tokens go through the
+    Pallas grouped matmul (whole 128-row tiles), a decode step's 16 through
+    ``ragged_dot``, which the chip's compiler takes as a grouped matmul of
+    its own; neither computes every expert for every token."""
+    import dataclasses
+
+    from kubeflow_tpu.models import layers as L
+    from kubeflow_tpu.models.config import preset
+
+    cfg = dataclasses.replace(
+        preset("glm-4.7-flash", dtype="bfloat16", param_dtype="bfloat16"),
+        fused_kernels="on")
+    d, m, e = cfg.hidden, cfg.expert_mlp_dim, cfg.num_experts
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    p = {"router": sds((d, e)), "router_bias": sds((e,), jnp.float32),
+         "gate": sds((e, d, m)), "up": sds((e, d, m)), "down": sds((e, m, d)),
+         "shared": {"gate": sds((d, m)), "up": sds((d, m)),
+                    "down": sds((m, d))}}
+    compiled = jax.jit(lambda p, x: L.moe_block(p, x, cfg)[0]).lower(
+        p, sds((1, tokens, d))).compile()
+    text = compiled.as_text()
+    assert ("gmm" if tokens % 128 == 0 else "ragged-dot") in text, tokens
+    if tokens % 128:        # a kernel's tiles are not in XLA's count
+        need = 2.0 * tokens * (cfg.experts_per_token + 1) * 3 * d * m
+        assert compiled.cost_analysis()["flops"] < 2 * need

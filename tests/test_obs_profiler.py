@@ -151,14 +151,18 @@ ENGINE_KEYS = {
     "host_gap_n", "preemptions", "requests_shed", "requests_completed",
     "tokens_generated", "decode_rounds", "first_token_fetches",
     "prefill_phase_sum_s", "prefill_phase_n", "decode_steps_dispatched",
-    "decode_tokens_emitted"}
+    "decode_tokens_emitted", "decode_context_tokens", "kv_bytes_per_token",
+    "kv_pool_bytes"}
+ENGINE_CONSTANTS = {"slots", "kv_bytes_per_token", "kv_pool_bytes"}
 
 
 def test_engine_counters_exist_at_construction_and_only_grow(engine):
     before = engine.counters()
     assert set(before) == ENGINE_KEYS      # before any request
-    assert all(v == 0 for k, v in before.items() if k != "slots")
+    assert all(v == 0 for k, v in before.items()
+               if k not in ENGINE_CONSTANTS)
     assert before["slots"] == 4
+    assert before["kv_pool_bytes"] > before["kv_bytes_per_token"] > 0
     reqs = [engine.submit(list(range(1, 40 + i)),
                           SamplingParams(max_new_tokens=9))
             for i in range(3)]
@@ -178,6 +182,10 @@ def test_engine_counters_exist_at_construction_and_only_grow(engine):
         <= 4 * after["decode_rounds"]
     assert after["decode_tokens_emitted"] \
         <= after["decode_steps_dispatched"] * after["slots"]
+    # every dispatched step attends to at least a prompt's rows
+    assert after["decode_context_tokens"] \
+        >= 39 * after["decode_steps_dispatched"]
+    assert all(after[k] == before[k] for k in ENGINE_CONSTANTS)
 
 
 def test_engine_writes_its_phases_into_the_capture(engine, tmp_path):
@@ -233,6 +241,9 @@ def test_engine_writes_its_phases_into_the_capture(engine, tmp_path):
             assert dispatched[f[3]["round"]][1] < f[1]
     one = next(iter(dispatched.values()))[3]
     assert one["k_steps"] in (1, 2, 4) and 1 <= one["live"] <= 4
+    # the rows a round attends to: at least a prompt's a live slot a step
+    assert all(s[3]["context"] >= 49 * s[3]["live"] * s[3]["k_steps"]
+               for s in dispatched.values())
     assert 0.0 < hostspans.busy_share(sched, hostspans.ENGINE_BLOCKED) <= 100
 
 
