@@ -254,6 +254,10 @@ class BatchingSpec(BaseModel):
     paged_attn_impl: str = "auto"
     # Long prompts split into chunks with decode interleaving; this many may
     # chunk concurrently (no head-of-line blocking between long prompts).
+    # Paged: where one chunk leaves the model's weights under-used (an
+    # expert layer, a small chunk: engine.chunk_rows_per_weight), the
+    # chunks of all of them go to the device as ONE program a scheduler
+    # pass, so each weight is read once for the pass.
     max_concurrent_prefills: int = 2
     # Batched prefill: up to this many same-bucket waiting prompts share ONE
     # prefill dispatch (power-of-two group sizes bound the trace set),
@@ -316,11 +320,13 @@ class BatchingSpec(BaseModel):
     # "auto": Pallas flash kernel on TPU (forward-only prefill is where it
     # wins), XLA elsewhere; or force "pallas"/"xla".
     prefill_attn_impl: str = "auto"
-    # MoE expert path per phase. Prefill runs per-request ([1, bucket]) so
-    # capacity drops can never depend on co-batched neighbors — the
-    # training dispatch path is batch-independent by construction there,
-    # and "auto" uses it for MoE models ("dense" forces the every-expert
-    # oracle). Measured (bench_serve --workload moe, mixtral-0.8b p1024/
+    # MoE expert path per phase. A request's capacity drops can never
+    # depend on co-batched neighbors: one-shot prefill runs per request
+    # ([1, bucket]), and a paged chunk program that carries several
+    # prompts' chunks takes the capacity and the claiming order PER ROW
+    # (layers._moe_dispatch, capacity_per_row), so the training dispatch
+    # path is batch-independent there too. "auto" uses it for MoE models
+    # ("dense" forces the every-expert oracle). Measured (bench_serve --workload moe, mixtral-0.8b p1024/
     # gen32/c16, one-session A/B): dispatch prefill 7.0 vs dense 6.5 req/s
     # and p50 TTFT 907 vs 1068 ms (isolated block: 10-14x at T=512-2048 —
     # the engine-level win is smaller because queueing+decode share TTFT).

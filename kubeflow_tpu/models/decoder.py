@@ -129,7 +129,8 @@ def _block_forward(block_params, x, positions, cfg: DecoderConfig,
                    kv_cache=None, attn_impl="xla", mesh=None,
                    rules=DEFAULT_RULES, prefill=False,
                    expert_axis=None, seq_axis=None, tp_axis=None,
-                   valid_len=None, lora=None, expert_stack=None):
+                   valid_len=None, lora=None, expert_stack=None,
+                   moe_capacity_per_row=False):
     h = L.rmsnorm(x, block_params["ln1"], cfg, mesh=mesh)
     attn_out, new_cache = L.attention_block(
         block_params["attn"], h, positions, cfg,
@@ -142,7 +143,8 @@ def _block_forward(block_params, x, positions, cfg: DecoderConfig,
         mlp_out, aux = L.moe_block(block_params["mlp"], h, cfg,
                                    expert_axis=expert_axis, seq_axis=seq_axis,
                                    valid_len=valid_len, tp_axis=tp_axis,
-                                   expert_stack=expert_stack)
+                                   expert_stack=expert_stack,
+                                   capacity_per_row=moe_capacity_per_row)
     else:
         mlp_out, aux = (L.mlp_block(block_params["mlp"], h, cfg,
                                     tp_axis=tp_axis, mesh=mesh),
@@ -195,7 +197,7 @@ def _remat(fn, policy: str):
 
 def _run_layers(layers, x, positions, cfg: DecoderConfig, planes: tuple,
                 plane_names: tuple, cache_len, *, attn_impl, mesh, rules,
-                prefill, valid_len, lora):
+                prefill, valid_len, lora, moe_capacity_per_row=False):
     """One group of alike layers (``layer_groups``) over ``x``: scanned when
     stacked, looped when a list. ``planes``: this group's slices of the
     cache's stacked planes, in ``plane_names``' order (empty without a
@@ -204,7 +206,8 @@ def _run_layers(layers, x, positions, cfg: DecoderConfig, planes: tuple,
         return _block_forward(
             bp, x, positions, cfg, kv_cache=cache, attn_impl=attn_impl,
             mesh=mesh, rules=rules, prefill=prefill, valid_len=valid_len,
-            lora=lr, expert_stack=expert_stack)
+            lora=lr, expert_stack=expert_stack,
+            moe_capacity_per_row=moe_capacity_per_row)
 
     def cache_of(layer_planes):
         if not plane_names:
@@ -261,7 +264,7 @@ def decoder_forward(
     cfg: DecoderConfig,
     *,
     positions: Optional[jax.Array] = None,
-    kv_caches: Optional[dict] = None,  # {"k","v": [L,B,Smax,K,Dh], "len": scalar}
+    kv_caches: Optional[dict] = None,  # {"k","v": [L,B,Smax,K,Dh], "len": scalar | [B]}
     attn_impl: str = "xla",
     mesh=None,
     rules: LogicalRules = DEFAULT_RULES,
@@ -269,6 +272,7 @@ def decoder_forward(
     valid_len: Optional[jax.Array] = None,
     inputs_embeds: Optional[jax.Array] = None,
     lora: Optional[dict] = None,
+    moe_capacity_per_row: bool = False,
 ):
     """Returns (logits [B,S,V] float32, new_kv_caches|None, aux_loss).
     With ``skip_head``, returns the final-norm hidden states [B,S,D] instead
@@ -281,15 +285,19 @@ def decoder_forward(
     and positions. ``lora`` (multi-tenant serving, serve/lora.py):
     ``{"targets": {t: (a [L,S,din,r], b [L,S,r,dout])}, "aidx": [B],
     "scale": [S]}`` — each row's adapter delta applies inside every
-    attention block (rows with aidx = -1 add exact zero)."""
+    attention block (rows with aidx = -1 add exact zero).
+    ``kv_caches["len"]`` may be one start a row ([B]: the serving chunk of
+    several prompts, each at its own position; layers.attention_block).
+    ``moe_capacity_per_row``: the dispatch MoE path's capacity is taken
+    within each row (layers.moe_block), which that chunk sets."""
     custom_positions = positions is not None
     if positions is None:
         # Decode with a cache: absolute positions continue from the cache
         # length (RoPE angles and the causal mask must agree on the offset).
         offset = kv_caches["len"] if kv_caches is not None else 0
         positions = jnp.broadcast_to(
-            jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :] + offset,
-            tokens.shape)
+            jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
+            + jnp.reshape(offset, (-1, 1)), tokens.shape)
 
     dt = cfg.activation_dtype
     table = params["embed"]
@@ -353,7 +361,8 @@ def decoder_forward(
             params[name], x, positions, gcfg, planes, plane_names,
             kv_caches["len"] if kv_caches is not None else None,
             attn_impl=attn_impl, mesh=mesh, rules=rules, prefill=prefill,
-            valid_len=valid_len, lora=lora_g)
+            valid_len=valid_len, lora=lora_g,
+            moe_capacity_per_row=moe_capacity_per_row)
         aux_total = aux_total + aux
         for n, plane in zip(plane_names, planes):
             new_planes[n].append(plane)

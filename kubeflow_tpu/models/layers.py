@@ -195,13 +195,57 @@ def init_attention(key, cfg: DecoderConfig):
     return params, specs
 
 
+def _cached_attention_by_row(q, k, v, kv_cache: dict):  # traced
+    """The cache path for ONE START A ROW (``kv_cache["len"]`` [B]: the
+    serving chunk of several prompts, each at its own position). Every row
+    writes its K/V at its own start and attends as it would alone: a row's
+    attention cannot depend on its neighbours.
+
+    The rows share one cache length (the longest context's), and masked
+    scores cost what live ones cost. So a row attends over the SHORTEST
+    span of a fixed ladder that holds its context and its chunk's window
+    (``C * 2**j + C`` positions, the whole cache last: the lengths the
+    one-row chunk program is built at, serve/paged.py::context_bucket),
+    chosen on the device from its own start: a young prompt beside an old
+    one pays for its own context, and its sums are the ones it computes
+    alone. One row has one span, its own. Returns (out, the cache as
+    written)."""
+    start = kv_cache["len"]
+    ck, cv = kv_cache["k"], kv_cache["v"]
+    b, c = q.shape[:2]
+    for r in range(b):
+        at = (r, start[r], 0, 0)
+        ck = jax.lax.dynamic_update_slice(ck, k[r:r + 1], at)
+        cv = jax.lax.dynamic_update_slice(cv, v[r:r + 1], at)
+    spans, n = [], 2 * c
+    while b > 1 and n < ck.shape[1]:
+        spans.append(n)
+        n = 2 * (n - c) + c
+    spans.append(ck.shape[1])
+
+    def attend(span):
+        return lambda qr, kr, vr, at: multi_head_attention(
+            qr, kr[:, :span], vr[:, :span], causal=True, q_offset=at,
+            impl="xla")
+
+    branches = [attend(s) for s in spans]
+    out = []
+    for r in range(b):
+        too_short = sum((start[r] + 2 * c > s).astype(jnp.int32)
+                        for s in spans[:-1])
+        out.append(jax.lax.switch(too_short, branches, q[r:r + 1],
+                                  ck[r:r + 1], cv[r:r + 1], start[r]))
+    return jnp.concatenate(out), {"k": ck, "v": cv,
+                                  "len": start + q.shape[1]}
+
+
 def attention_block(
     p: dict,
     x: jax.Array,                       # [B,S,D]
     positions: jax.Array,               # [B,S]
     cfg: DecoderConfig,
     *,
-    kv_cache: Optional[dict] = None,    # {"k","v": [B,Smax,K,Dh]}, + "len": scalar
+    kv_cache: Optional[dict] = None,    # {"k","v": [B,Smax,K,Dh]}, + "len": scalar | [B]
     attn_impl: str = "xla",
     mesh=None,
     prefill: bool = False,              # static: cache start is known to be 0
@@ -241,7 +285,9 @@ def attention_block(
     v = checkpoint_name(v, "v_proj")
 
     new_cache = None
-    if kv_cache is not None:
+    if kv_cache is not None and jnp.ndim(kv_cache["len"]):
+        out, new_cache = _cached_attention_by_row(q, k, v, kv_cache)
+    elif kv_cache is not None:
         # Contiguous cache decode path: write new K/V at position `len`.
         start = kv_cache["len"]
         ck = jax.lax.dynamic_update_slice_in_dim(kv_cache["k"], k, start, axis=1)
@@ -598,7 +644,8 @@ def moe_block(p: dict, x: jax.Array, cfg: DecoderConfig,
               seq_axis: Optional[str] = None,
               valid_len: Optional[jax.Array] = None,
               tp_axis: Optional[str] = None,
-              expert_stack: Optional[tuple] = None):
+              expert_stack: Optional[tuple] = None,
+              capacity_per_row: bool = False):
     """Top-k MoE (Mixtral semantics: softmax over the selected k logits).
 
     Dispatches on ``cfg.moe_impl``: "dispatch" (default) routes tokens into
@@ -621,11 +668,18 @@ def moe_block(p: dict, x: jax.Array, cfg: DecoderConfig,
 
     ``expert_stack`` (``split_expert_stack``): (the expert leaves of the
     whole stacked group, this layer's index in it), where ``p`` holds the
-    rest; the sorted path alone takes it."""
+    rest; the sorted path alone takes it.
+
+    ``capacity_per_row`` (static; the serving chunk of several prompts sets
+    it): the dispatch path's capacity and claiming order are taken within
+    each row of ``x`` and not over the whole block, so a row keeps and drops
+    what it would alone (``_moe_dispatch``). The other paths have no
+    capacity and ignore it."""
     if cfg.moe_impl == "dispatch":
         out, aux = _moe_dispatch(p, x, cfg, expert_axis=expert_axis,
                                  seq_axis=seq_axis, valid_len=valid_len,
-                                 tp_axis=tp_axis)
+                                 tp_axis=tp_axis,
+                                 capacity_per_row=capacity_per_row)
     elif cfg.moe_impl == "sorted":
         if expert_axis is not None or tp_axis is not None:
             raise NotImplementedError(
@@ -693,7 +747,8 @@ def _moe_dispatch(p: dict, x: jax.Array, cfg: DecoderConfig,
                   expert_axis: Optional[str] = None,
                   seq_axis: Optional[str] = None,
                   valid_len: Optional[jax.Array] = None,
-                  tp_axis: Optional[str] = None):
+                  tp_axis: Optional[str] = None,
+                  capacity_per_row: bool = False):
     """Capacity-factor top-k dispatch (SURVEY.md §2.6 EP row: the TPU-native
     MoE data path; (U) training-operator-era Mixtral recipes route via NCCL
     all-to-all — here the routing is scatter/gather into static [E, C]
@@ -713,6 +768,13 @@ def _moe_dispatch(p: dict, x: jax.Array, cfg: DecoderConfig,
       microbatch competes for its own C slots, so drop patterns differ
       from a full-batch run (the standard GPipe×MoE trade) — equivalence
       across schedules holds exactly only when capacity is ample.
+    - ``capacity_per_row`` (serving: the chunks of several prompts in one
+      program) makes every ROW of ``x`` a dispatch batch of its own: an
+      expert holds ``moe_capacity(cfg, S)`` slots for each row, the
+      choice-major order runs within a row, and the buffers are
+      ``[E, B*c, D]``, so a row keeps and drops exactly the (token, choice)
+      pairs it keeps and drops alone while each expert's weights are still
+      read once for all rows. At one row the two are the same computation.
 
     With ``expert_axis`` (inside shard_map): weights hold the local expert
     slice; positions are computed on the replicated router output (identical
@@ -723,13 +785,20 @@ def _moe_dispatch(p: dict, x: jax.Array, cfg: DecoderConfig,
     b, s, d = x.shape
     e, k = cfg.num_experts, cfg.experts_per_token
     t = b * s
+    # ``g`` dispatch groups of ``tg`` tokens, each with its own capacity.
+    g = b if capacity_per_row else 1
+    tg = t // g
     xf = x.reshape(t, d)
     router_logits, topk_idx, topk_w = route(p, xf, cfg)              # [T,k]
 
-    c = moe_capacity(cfg, t)
-    # Choice-major flattening: row r = (choice r // T) of token (r % T).
-    flat_e = topk_idx.T.reshape(-1)                                  # [kT]
-    oh = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)                  # [kT,E]
+    c = moe_capacity(cfg, tg)
+    # Choice-major flattening within a group: row r of group i is
+    # (choice r // tg) of the group's token (r % tg).
+    def choice_major(a):                     # [T, k] -> [g, k*tg]
+        return jnp.swapaxes(a.reshape(g, tg, k), 1, 2).reshape(g, k * tg)
+
+    flat_e = choice_major(topk_idx)                                  # [g,k*tg]
+    oh = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)                  # [g,k*tg,E]
     valid_flat, valid_bs = None, None
     if valid_len is not None:
         # Padding rows claim no capacity (zeroed before the cumsum), are
@@ -738,11 +807,10 @@ def _moe_dispatch(p: dict, x: jax.Array, cfg: DecoderConfig,
         # pads as a catastrophically unbalanced router.
         vl = jnp.broadcast_to(jnp.atleast_1d(jnp.asarray(valid_len)), (b,))
         valid_bs = jnp.arange(s)[None, :] < vl[:, None]              # [B,S]
-        valid = valid_bs.reshape(t)
-        valid_flat = jnp.tile(valid, k)
-        oh = oh * valid_flat[:, None].astype(oh.dtype)
-    pos = jnp.cumsum(oh, axis=0) - 1
-    pos_in_e = jnp.take_along_axis(pos, flat_e[:, None], 1)[:, 0]    # [kT]
+        valid_flat = jnp.tile(valid_bs.reshape(g, tg), (1, k))
+        oh = oh * valid_flat[..., None].astype(oh.dtype)
+    pos = jnp.cumsum(oh, axis=1) - 1
+    pos_in_e = jnp.take_along_axis(pos, flat_e[..., None], 2)[..., 0]
     keep = pos_in_e < c
     if valid_flat is not None:
         keep = keep & valid_flat
@@ -752,26 +820,30 @@ def _moe_dispatch(p: dict, x: jax.Array, cfg: DecoderConfig,
         e_local = p["gate"].shape[0]
         offset = jax.lax.axis_index(expert_axis) * e_local
         keep = keep & (flat_e >= offset) & (flat_e < offset + e_local)
-    rows = jnp.where(keep, (flat_e - offset) * c + pos_in_e, e_local * c)
-    tok_of = jnp.tile(jnp.arange(t), k)                              # [kT]
+    # Expert ``x``'s buffer: group 0's c slots, then group 1's, ...
+    slot = jnp.arange(g, dtype=jnp.int32)[:, None] * c + pos_in_e
+    rows = jnp.where(keep, (flat_e - offset) * (g * c) + slot,
+                     e_local * g * c).reshape(-1)                    # [kT]
+    tok_of = (jnp.arange(g, dtype=jnp.int32)[:, None] * tg + jnp.tile(
+        jnp.arange(tg, dtype=jnp.int32), k)[None, :]).reshape(-1)    # [kT]
     # TPU lowers row-granular scatters poorly (measured 2.9× slower than
     # dense!): invert the slot permutation with a SCALAR scatter (cheap),
     # then fill the buffers with a row GATHER — empty slots read OOB and
     # fill with zeros.
-    row_of_slot = jnp.full((e_local * c,), t, jnp.int32).at[rows].set(
+    row_of_slot = jnp.full((e_local * g * c,), t, jnp.int32).at[rows].set(
         tok_of, mode="drop")
     buf = jnp.take(xf, row_of_slot, axis=0, mode="fill",
-                   fill_value=0).reshape(e_local, c, d)
+                   fill_value=0).reshape(e_local, g * c, d)
 
     gate = _act(jnp.einsum("ecd,edm->ecm", buf, p["gate"].astype(dt)),
                 cfg.hidden_act)
     up = jnp.einsum("ecd,edm->ecm", buf, p["up"].astype(dt))
     y = jnp.einsum("ecm,emd->ecd", gate * up,
-                   p["down"].astype(dt)).reshape(e_local * c, d)
+                   p["down"].astype(dt)).reshape(e_local * g * c, d)
 
     back = jnp.take(y, rows, axis=0, mode="fill", fill_value=0)      # [kT,D]
-    w_flat = topk_w.T.reshape(-1, 1).astype(dt)
-    out = (back * w_flat).reshape(k, t, d).sum(0).reshape(b, s, d)
+    w_flat = choice_major(topk_w).reshape(-1, 1).astype(dt)
+    out = (back * w_flat).reshape(g, k, tg, d).sum(1).reshape(b, s, d)
     # One combined reduction: expert partials (each shard computed its
     # local experts) and Megatron partials (down contracted a local
     # m-slice) sum over both axes at once.
@@ -781,7 +853,7 @@ def _moe_dispatch(p: dict, x: jax.Array, cfg: DecoderConfig,
 
     aux = _moe_aux_loss(
         router_logits.reshape(b, s, e),
-        oh.astype(jnp.float32).reshape(k, t, e).sum(0).reshape(b, s, e),
+        oh.astype(jnp.float32).reshape(g, k, tg, e).sum(1).reshape(b, s, e),
         cfg, seq_axis, valid=valid_bs)
     return checkpoint_name(out, "mlp_out"), aux
 

@@ -518,6 +518,32 @@ def _pin2(out, pin):
     return (out[0], pin(out[1])) + tuple(out[2:])
 
 
+def _row0(out):
+    """A one-row chunk program's ([1,C,V] logits, cache) as ([C,V], cache)."""
+    return (out[0][0],) + tuple(out[1:])
+
+
+#: Rows a bf16 weight matrix must multiply before the matrix work takes as
+#: long as reading the matrix: a row costs 2 FLOPs a parameter and the
+#: matrix 2 bytes a parameter, so rows = peak FLOP/s over peak bytes/s. On
+#: a v5e that is 197e12 / 819e9 = 240 rows; 256, the next whole tile. Below
+#: it a program is bound by its weights' bytes, and rows added to it are
+#: nearly free; at or above it they cost their own time.
+RIDGE_ROWS = 256
+
+
+def chunk_rows_per_weight(cfg: DecoderConfig, chunk: int) -> float:
+    """Rows the least-used weight matrix of the model multiplies in ONE
+    prefill chunk of ``chunk`` tokens: every token for a dense model, the
+    ``experts_per_token / num_experts`` share of them that one expert of an
+    expert layer sees (Mixtral at 512 tokens: 128; 4 of 64 experts: 32).
+    Against ``RIDGE_ROWS`` it decides whether the engine puts the chunks of
+    all in-flight prefills into one program."""
+    if not cfg.is_moe or cfg.moe_impl == "dense":   # every expert, every row
+        return chunk
+    return chunk * cfg.experts_per_token / cfg.num_experts
+
+
 # -- the engine ----------------------------------------------------------------
 
 #: Queue-delay histogram bucket upper bounds (seconds). Chosen to resolve
@@ -817,10 +843,14 @@ class LLMEngine:
         # Serving MoE must be batch-independent: a request's tokens must not
         # change because co-batched traffic filled an expert's capacity
         # buffer. Two phases, two resolutions (VERDICT r3 #3):
-        # - PREFILL runs per-request on a [1, bucket] block, so capacity
-        #   drops are a function of that request alone — the training
-        #   dispatch path applies as-is and WINS the on-chip serving A/B
-        #   (7.0 vs 6.5 req/s, p50 TTFT -15% at mixtral-0.8b p1024).
+        # - PREFILL: capacity drops are a function of the request alone.
+        #   One-shot prefill runs per request on a [1, bucket] block; the
+        #   paged chunk program may carry several prompts' chunks, and
+        #   takes the dispatch path's capacity and claiming order per row
+        #   (layers._moe_dispatch, capacity_per_row), so a prompt keeps and
+        #   drops what it would alone. The training dispatch path applies
+        #   and WINS the on-chip serving A/B (7.0 vs 6.5 req/s, p50 TTFT
+        #   -15% at mixtral-0.8b p1024).
         # - DECODE co-batches slots; dispatch is only batch-independent at
         #   zero-drop capacity (C = k*T). The same A/B measured it a tie
         #   within session noise, so dense (simpler, drop-free by
@@ -1027,6 +1057,9 @@ class LLMEngine:
             donate_argnums=(1,))
         self._chunkings: list[_Chunking] = []   # lockfree: scheduler-confined
         self.max_concurrent_prefills = max(1, int(b.max_concurrent_prefills))
+        # Chunks one prefill program takes: 1 unless the paged engine below
+        # builds the program over several prompts' chunks.
+        self._chunk_rows = 1
         if self.paged:
             from kubeflow_tpu.serve.paged import (
                 paged_chunk_prefill, paged_decode_multi,
@@ -1045,13 +1078,40 @@ class LLMEngine:
                     f"unknown paged_attn_impl {b.paged_attn_impl!r}; "
                     "one of auto|gather|pallas")
             self.paged_attn_impl = pattn    # resolved (post-auto) impl
-            self._paged_chunk = jax.jit(
-                lambda p, c, t, tr, st, vl, ncp, lr=None, ai=None: _pin2(
+            def _chunk_rows_fn(p, c, t, tr, st, vl, ncp, lr, ai):
+                return _pin2(
                     paged_chunk_prefill(
                         p, c, t, tr, st, vl, cfg_prefill, context_pages=ncp,
                         lora=lr, adapter_idx=ai, paged_attn_impl=pattn),
-                    self._pin),
+                    self._pin)
+
+            # ONE prompt's chunk: tokens [1,C], its table row, scalar start
+            # and valid length; [C,V] logits.
+            self._paged_chunk = jax.jit(
+                lambda p, c, t, tr, st, vl, ncp, lr=None, ai=None: _row0(
+                    _chunk_rows_fn(p, c, t, tr[None], st[None], vl[None],
+                                   ncp, lr, ai)),
                 static_argnums=(6,), donate_argnums=(1,))
+            # The chunks of ALL in-flight prefills in one program (tokens
+            # [B,C], a table row, a start and a valid length a row; [B,C,V]
+            # logits), so a scheduler pass reads every weight once. Built
+            # only where one chunk leaves the weights under-used
+            # (``chunk_rows_per_weight``): a dense model at 512 tokens
+            # dispatches exactly as it always did. It is dispatched at ONE
+            # static context, the whole table: a row's attention follows
+            # its own context whatever the table's length (the span ladder
+            # of layers._cached_attention_by_row; the latent kernel skips
+            # the pages behind its chunk), so a ladder of context buckets
+            # would spare only the gather of a per-head pool's rows, and
+            # each further program is loaded and run at every start
+            # (0.75 s warm, 5 s cold on a v5e: PERF.md, PR 29).
+            if self.max_concurrent_prefills > 1 and chunk_rows_per_weight(
+                    cfg_prefill, self.chunk_size) < RIDGE_ROWS:
+                self._chunk_rows = self.max_concurrent_prefills
+                self._paged_chunks = jax.jit(
+                    lambda p, c, t, tr, st, vl, ncp, lr=None, ai=None:
+                    _chunk_rows_fn(p, c, t, tr, st, vl, ncp, lr, ai),
+                    static_argnums=(6,), donate_argnums=(1,))
 
             def _paged_decode_fn(p, c, st, tbl, key, n, m, lr=None,
                                  _impl=pattn):
@@ -1198,6 +1258,7 @@ class LLMEngine:
             for attr, name in (("_prefill", "prefill"),
                                ("_prefill_chunk", "chunk_prefill"),
                                ("_paged_chunk", "paged_chunk_prefill"),
+                               ("_paged_chunks", "paged_chunk_prefill"),
                                ("_paged_decode_n", "paged_decode"),
                                ("_decode_n", "decode")):
                 if hasattr(self, attr):
@@ -1337,6 +1398,9 @@ class LLMEngine:
         self._decode_steps_dispatched = 0   # lockfree: scheduler-confined counter
         self._decode_tokens_emitted = 0     # lockfree: scheduler-confined counter
         self._decode_context_tokens = 0     # lockfree: scheduler-confined counter
+        self._prefill_programs_dispatched = 0   # lockfree: scheduler-confined counter
+        self._prefill_chunks_dispatched = 0     # lockfree: scheduler-confined counter
+        self._prefill_tokens_dispatched = 0     # lockfree: scheduler-confined counter
         self.waiting: "queue.Queue[Request]" = queue.Queue()
         self.metrics = EngineMetrics()
         # Bounded admission + queue-delay budget (load shedding): see
@@ -1370,6 +1434,25 @@ class LLMEngine:
         # None until stop() runs; False = the scheduler thread outlived its
         # join timeout and is leaked (it may hold live device buffers).
         self.stopped_clean: Optional[bool] = None
+        if self._chunk_rows > 1:
+            self._warm_chunk_rows()
+
+    def _warm_chunk_rows(self) -> None:
+        """Compile and run once, now, the program over several prompts'
+        chunks, on DEAD rows (no valid position, no page: nothing is
+        written). Traffic reaches several concurrent prefills only where
+        arrivals fall together, so no warm-up of a caller's can be relied on
+        to reach it; the program set is the engine's own, and fixed from
+        here on. Also warms the read of one row's last logits."""
+        rows, C = self._chunk_rows, self.chunk_size
+        lora = () if self._lora is None else (
+            self._lora.buffers, jnp.full((rows,), -1, jnp.int32))
+        logits, self.cache = self._paged_chunks(
+            self.params, self.cache, jnp.zeros((rows, C), jnp.int32),
+            jnp.full((rows, self._mpp), -1, jnp.int32),
+            jnp.zeros((rows,), jnp.int32), jnp.zeros((rows,), jnp.int32),
+            self._mpp, *lora)
+        jax.block_until_ready(logits[rows - 1, C - 1])
 
     # -- mesh-mode helpers -----------------------------------------------------
 
@@ -1453,6 +1536,12 @@ class LLMEngine:
             # cache rows the dispatched steps attend to, summed over the
             # live slots and the steps of every round
             "decode_context_tokens": self._decode_context_tokens,
+            # chunk-prefill programs dispatched, the prompts' chunks they
+            # carried (their ratio: how often several prefills shared one
+            # program) and the real tokens of those chunks, padding excluded
+            "prefill_programs_dispatched": self._prefill_programs_dispatched,
+            "prefill_chunks_dispatched": self._prefill_chunks_dispatched,
+            "prefill_tokens_dispatched": self._prefill_tokens_dispatched,
             # constants: content bytes a token holds over all layers of
             # the cache, and the cache's size on the device
             "kv_bytes_per_token": self._kv_bytes_per_token,
@@ -1820,41 +1909,94 @@ class LLMEngine:
             # export the slot's KV instead of decoding locally.
             self._export_handoff(slot_idx)
 
-    def _advance_one(self, ch: "_Chunking") -> int:
-        """Run ONE chunk of one in-flight chunked prefill. Returns work done
-        (0 when page-pool pressure defers the chunk to a later step)."""
-        with hot_span(prof.ENGINE_PREFILL_DISPATCH, slot=ch.slot,
-                      pos=ch.pos):
-            return self._advance_one_chunk(ch)
-
-    def _advance_one_chunk(self, ch: "_Chunking") -> int:
+    def _reserve_chunk_pages(self, ch: "_Chunking") -> bool:
+        """Pages for ``ch``'s next chunk (paged mode). False when page-pool
+        pressure defers the chunk to a later step; the other in-flight
+        prefills go on without it."""
         req, slot_idx = ch.request, ch.slot
-        C = self.chunk_size
-        plen = len(req.prompt_tokens)
-        real = min(C, plen - ch.pos)
-        chunk = np.zeros((1, C), np.int32)
-        chunk[0, :real] = req.prompt_tokens[ch.pos:ch.pos + real]
-        if self.paged:
-            if not self._ensure_pages(slot_idx, ch.pos + real):
-                # Pool pressure. A stalled chunking holds pages the decode
-                # preemption path can't see (its slot is None), so two
-                # growing prefills could deadlock each other: after a few
-                # starved attempts, abort this one — release its pages and
-                # requeue through the preempted lane, whose admission gate
-                # waits for room for the ENTIRE remaining run.
-                ch.stalls += 1
-                if ch.stalls >= 3:
-                    self._chunkings.remove(ch)
-                    # Chunks already written are real prefix KV — index
-                    # them before the pages release, so the resume's
-                    # match skips straight back here.
-                    self._kv_register(req.prompt_tokens, slot_idx, ch.pos)
-                    self._release_slot_pages(slot_idx)
-                    self._release_slot_adapter(slot_idx)
-                    self._preempted.append(req)
-                    self.metrics.note_preempted(req.qos)
-                return 0    # otherwise retry next scheduler step
+        real = min(self.chunk_size, len(req.prompt_tokens) - ch.pos)
+        if self._ensure_pages(slot_idx, ch.pos + real):
             ch.stalls = 0
+            return True
+        # Pool pressure. A stalled chunking holds pages the decode
+        # preemption path can't see (its slot is None), so two growing
+        # prefills could deadlock each other: after a few starved attempts,
+        # abort this one — release its pages and requeue through the
+        # preempted lane, whose admission gate waits for room for the
+        # ENTIRE remaining run.
+        ch.stalls += 1
+        if ch.stalls >= 3:
+            self._chunkings.remove(ch)
+            # Chunks already written are real prefix KV — index them
+            # before the pages release, so the resume's match skips
+            # straight back here.
+            self._kv_register(req.prompt_tokens, slot_idx, ch.pos)
+            self._release_slot_pages(slot_idx)
+            self._release_slot_adapter(slot_idx)
+            self._preempted.append(req)
+            self.metrics.note_preempted(req.qos)
+        return False    # otherwise retry next scheduler step
+
+    def _dispatch_chunks(self, group: "list[_Chunking]") -> None:
+        """ONE program for the next chunk of every prefill in ``group``
+        (their pages are reserved): row ``r`` carries ``group[r]``'s tokens,
+        table row, start and valid length. One prefill alone, or an engine
+        that built no program over several rows, takes the one-row program
+        it always took."""
+        C = self.chunk_size
+        rows = 1 if len(group) == 1 else self._chunk_rows
+        reals = [min(C, len(ch.request.prompt_tokens) - ch.pos)
+                 for ch in group]
+        chunk = np.zeros((rows, C), np.int32)
+        for r, (ch, real) in enumerate(zip(group, reals)):
+            chunk[r, :real] = ch.request.prompt_tokens[ch.pos:ch.pos + real]
+        lora = () if self._lora is None else (
+            self._lora.buffers,
+            jnp.asarray(np.asarray(
+                [self._slot_aidx[ch.slot] for ch in group]
+                + [-1] * (rows - len(group)), np.int32)))
+        with hot_span(prof.ENGINE_PREFILL_DISPATCH, slot=group[0].slot,
+                      pos=group[0].pos, chunks=len(group)):
+            if rows > 1:
+                # Rows past the group are DEAD: no valid position, no page.
+                table = np.full((rows, self._mpp), -1, np.int32)
+                start = np.zeros((rows,), np.int32)
+                valid = np.zeros((rows,), np.int32)
+                for r, (ch, real) in enumerate(zip(group, reals)):
+                    table[r] = self._table[ch.slot]
+                    start[r], valid[r] = ch.pos, real
+                logits, self.cache = self._paged_chunks(
+                    self.params, self.cache, jnp.asarray(chunk),
+                    jnp.asarray(table), jnp.asarray(start),
+                    jnp.asarray(valid), self._mpp, *lora)
+            else:
+                logits = self._dispatch_one_chunk(group[0], chunk, reals[0],
+                                                  lora)
+        self._prefill_programs_dispatched += 1
+        self._prefill_chunks_dispatched += len(group)
+        self._prefill_tokens_dispatched += sum(reals)
+        for r, (ch, real) in enumerate(zip(group, reals)):
+            req, plen = ch.request, len(ch.request.prompt_tokens)
+            ch.pos += real
+            if ch.pos < plen:
+                continue
+            self._chunkings.remove(ch)
+            if self.paged:
+                # Index the prompt's KV for cross-request reuse — LIVE:
+                # the owner keeps decoding while sharers match through
+                # these pages (decode writes start at plen, past every
+                # claimed position — COW by construction).
+                self._kv_register(req.prompt_tokens, ch.slot, plen)
+            # Logits index of the prompt's true last token in this chunk.
+            self._start_first_token(
+                req, ch.slot, plen,
+                logits[r, real - 1] if rows > 1 else logits[real - 1])
+
+    def _dispatch_one_chunk(self, ch: "_Chunking", chunk: np.ndarray,
+                            real: int, lora: tuple) -> jax.Array:
+        """The one-row chunk program, paged or contiguous: [C,V] logits."""
+        slot_idx = ch.slot
+        if self.paged:
             # Static context bucket (next power of two covering the pages
             # this chunk can see): chunk cost tracks ch.pos, not max_len,
             # with a log-bounded trace set. The chunk's writes address
@@ -1862,48 +2004,32 @@ class LLMEngine:
             # (the radix COW tail resume).
             from kubeflow_tpu.serve.paged import context_bucket
 
-            ctx = context_bucket(ch.pos, C, self.page_size, self._mpp)
-            if self._lora is not None:
-                logits, self.cache = self._paged_chunk(
-                    self.params, self.cache, jnp.asarray(chunk),
-                    jnp.asarray(self._table[slot_idx]), jnp.int32(ch.pos),
-                    jnp.int32(real), ctx, self._lora.buffers,
-                    jnp.asarray(np.asarray([self._slot_aidx[slot_idx]],
-                                           np.int32)))
-            else:
-                logits, self.cache = self._paged_chunk(
-                    self.params, self.cache, jnp.asarray(chunk),
-                    jnp.asarray(self._table[slot_idx]), jnp.int32(ch.pos),
-                    jnp.int32(real), ctx)
+            ctx = context_bucket(ch.pos, self.chunk_size, self.page_size,
+                                 self._mpp)
+            logits, self.cache = self._paged_chunk(
+                self.params, self.cache, jnp.asarray(chunk),
+                jnp.asarray(self._table[slot_idx]), jnp.int32(ch.pos),
+                jnp.int32(real), ctx, *lora)
         else:
-            if self._lora is not None:
-                logits, self.cache = self._prefill_chunk(
-                    self.params, self.cache, jnp.asarray(chunk),
-                    jnp.int32(slot_idx), jnp.int32(ch.pos),
-                    jnp.int32(real), self._lora.buffers,
-                    jnp.asarray(np.asarray([self._slot_aidx[slot_idx]],
-                                           np.int32)))
-            else:
-                logits, self.cache = self._prefill_chunk(
-                    self.params, self.cache, jnp.asarray(chunk),
-                    jnp.int32(slot_idx), jnp.int32(ch.pos), jnp.int32(real))
-        ch.pos += real
-        if ch.pos >= plen:
-            self._chunkings.remove(ch)
-            if self.paged:
-                # Index the prompt's KV for cross-request reuse — LIVE:
-                # the owner keeps decoding while sharers match through
-                # these pages (decode writes start at plen, past every
-                # claimed position — COW by construction).
-                self._kv_register(req.prompt_tokens, slot_idx, plen)
-            # Logits index of the prompt's true last token in this chunk.
-            self._start_first_token(req, slot_idx, plen, logits[real - 1])
-        return 1
+            logits, self.cache = self._prefill_chunk(
+                self.params, self.cache, jnp.asarray(chunk),
+                jnp.int32(slot_idx), jnp.int32(ch.pos), jnp.int32(real),
+                *lora)
+        return logits
 
-    def _advance_chunked(self) -> int:
-        """One chunk of EVERY in-flight chunked prefill (decode steps run
-        between calls — that's the whole point). Returns work done."""
-        return sum(self._advance_one(ch) for ch in list(self._chunkings))
+    def _advance_chunked(self, due: "Optional[list[_Chunking]]" = None) -> int:
+        """One chunk of every in-flight chunked prefill in ``due`` (all of
+        them unless given; decode steps run between calls — that's the whole
+        point), as few programs as the engine has rows for: where it built
+        the program over several prompts' chunks, all of them go to the
+        device together and every weight is read once for the pass. A
+        prefill whose pages cannot be had waits for a later pass and holds
+        nobody back. Returns the chunks dispatched."""
+        ready = [ch for ch in (list(self._chunkings) if due is None else due)
+                 if not self.paged or self._reserve_chunk_pages(ch)]
+        for i in range(0, len(ready), self._chunk_rows):
+            self._dispatch_chunks(ready[i:i + self._chunk_rows])
+        return len(ready)
 
     def _pages_for(self, tokens: int) -> int:
         return -(-min(tokens, self.max_len) // self.page_size)
@@ -2071,10 +2197,48 @@ class LLMEngine:
         """Prefill waiting requests into free slots. Returns admissions.
 
         One-shot admissions accumulate into same-bucket groups and flush as
-        batched prefill dispatches (``prefill_batch_max``) — the chunked
-        and paged paths dispatch per-request as before."""
-        n = self._advance_chunked()
+        batched prefill dispatches (``prefill_batch_max``). Chunked
+        prefills (every paged admission) are admitted FIRST and then every
+        in-flight one advances by one chunk, together where the engine has
+        the program for it (``_advance_chunked``): a pass above the knee
+        carries its prefills' chunks in one program, and not the older
+        ones' and then the newcomer's. A prefill that finished leaves its
+        lane to the next waiting request within the pass, as it always did
+        (short prompts do not queue behind the decode round for a lane):
+        the newcomers then run their first chunk, and so on until no lane
+        comes free."""
+        n = 0
         pending: list[tuple[Request, int, int, int]] = []   # req, slot, plen, bucket
+        advanced: list[_Chunking] = []      # had their chunk of this pass
+        while True:
+            n += self._admit_waiting(pending)
+            due = [ch for ch in self._chunkings
+                   if not any(ch is done for done in advanced)]
+            if not due:
+                break
+            advanced += due
+            lanes = len(self._chunkings)
+            n += self._advance_chunked(due)
+            if len(self._chunkings) == lanes:
+                break
+        n += self._flush_prefills(pending)
+        # Chunked-prefill completions parked by _start_first_token: one
+        # batched sampler dispatch + one fetch for the whole admit round.
+        self._flush_first_tokens()
+        # Prefill-role exports queued this round: one batched KV fetch.
+        self._flush_handoffs()
+        if n:
+            # The device just ran prefill work — the next decode round's
+            # host-gap sample would measure admission, not the hot loop.
+            self._last_ready_t = None
+        return n
+
+    def _admit_waiting(self, pending: list) -> int:
+        """Give free slots and prefill lanes to waiting requests: chunked
+        prefills (every paged admission) join ``_chunkings``, to be
+        advanced by the caller; one-shot prompts join ``pending``; handed-
+        off requests are adopted here. Returns the adoptions."""
+        n = 0
         while True:
             if len(self._chunkings) >= self.max_concurrent_prefills \
                     and self.paged:
@@ -2139,9 +2303,7 @@ class LLMEngine:
                 self._table[slot_idx, :] = -1
                 self._table[slot_idx, :len(pages)] = pages
                 self._dstate.mark_row(slot_idx)
-                ch = _Chunking(req, slot_idx, covered)
-                self._chunkings.append(ch)
-                n += self._advance_one(ch)
+                self._chunkings.append(_Chunking(req, slot_idx, covered))
                 continue
             if req.trace_parent is not None:
                 # queued → prefill (covers both fresh admissions and
@@ -2162,22 +2324,10 @@ class LLMEngine:
                 # final chunk's dynamic_update_slice would clamp and
                 # overwrite earlier KV (fall through to one-shot
                 # prefill instead).
-                ch = _Chunking(req, slot_idx, 0)
-                self._chunkings.append(ch)
-                n += self._advance_one(ch)
+                self._chunkings.append(_Chunking(req, slot_idx, 0))
                 continue
             pending.append((req, slot_idx,
                             plen, self._bucket_for(plen)))
-        n += self._flush_prefills(pending)
-        # Chunked-prefill completions parked by _start_first_token: one
-        # batched sampler dispatch + one fetch for the whole admit round.
-        self._flush_first_tokens()
-        # Prefill-role exports queued this round: one batched KV fetch.
-        self._flush_handoffs()
-        if n:
-            # The device just ran prefill work — the next decode round's
-            # host-gap sample would measure admission, not the hot loop.
-            self._last_ready_t = None
         return n
 
     def _flush_prefills(self, pending) -> int:

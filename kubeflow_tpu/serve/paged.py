@@ -382,10 +382,11 @@ def _head_logits(params: Params, x: jax.Array, cfg: DecoderConfig):  # traced
 
 
 def _feed_forward(bp, h, cfg: DecoderConfig, expert_stack=None,  # traced
-                  valid_len=None):
+                  valid_len=None, capacity_per_row: bool = False):
     if cfg.is_moe:
         return L.moe_block(bp["mlp"], h, cfg, valid_len=valid_len,
-                           expert_stack=expert_stack)[0]
+                           expert_stack=expert_stack,
+                           capacity_per_row=capacity_per_row)[0]
     return L.mlp_block(bp["mlp"], h, cfg)
 
 
@@ -656,34 +657,45 @@ def context_bucket(pos: int, chunk: int, page_size: int, mpp: int) -> int:
 
 
 def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # traced
-                        table_row: jax.Array, start: jax.Array,
+                        table_rows: jax.Array, start: jax.Array,
                         valid_len: jax.Array, cfg: DecoderConfig,
                         attn_impl: str = "xla",
                         context_pages: Optional[int] = None,
                         lora=None, adapter_idx=None,
                         paged_attn_impl: str = "gather"):
-    """Prefill ONE chunk (``tokens`` [1,C], positions [start, start+C)) of a
-    slot whose pages are ``table_row`` [mpp]; the chunk's K/V scatters back
-    per token as (page, offset) writes off the table row — exactly the
-    decode write's addressing — so ``start`` needs NO page alignment.
-    Sub-page prefix reuse (the radix index's copy-on-write tail,
-    serve/kvtier.py) resumes prefill mid-page through this path; only the
-    first ``valid_len`` positions write (the padded tail and any unmapped
-    page aim out of bounds and DROP).
+    """Prefill one chunk of EACH of ``B`` prompts in one program: row ``b``
+    is ``tokens[b]`` ([B,C]) at positions [start[b], start[b]+C) of the slot
+    whose pages are ``table_rows[b]`` ([B,mpp]), ``valid_len[b]`` of them
+    real. Everything that multiplies by a weight (projections, experts, the
+    head) sees the ``B x C`` rows together, so each weight matrix is read
+    once for all of them; attention stays per prompt (a row attends to its
+    own pages from its own start). A row's result does not depend on the
+    other rows: the capacity of a dispatch expert layer is taken per row
+    (``layers._moe_dispatch``), nothing else crosses rows. A DEAD row
+    (``valid_len`` 0, table -1) computes and writes nothing that is kept.
 
-    The chunk attends to the slot's earlier KV by gathering the page table
-    into the contiguous layout decoder_forward's cache path expects, then
-    scatters only the chunk's tokens back; every plane of a per-head pool
-    (``pool_planes``) goes the same way. A latent pool takes the chunk as
-    the decode step takes a token (``_paged_latent_chunk_prefill``: rows
-    written in place, attention absorbed over the pages where they lie,
-    ``paged_attn_impl`` the engine's "gather" | "pallas"). ``context_pages``
-    (STATIC)
-    bounds the gather to the pages actually covering [0, start+C): chunk
-    cost then tracks the resident context, not max_len — without it a long
-    prompt pays O(max_len²/C) in gathers (round-2 weak #4). The caller
-    buckets the count (powers of two) so the trace set stays logarithmic.
-    Returns ([C,V] logits, cache)."""
+    A row's K/V scatters back per token as (page, offset) writes off its
+    table row — exactly the decode write's addressing — so a ``start`` needs
+    NO page alignment. Sub-page prefix reuse (the radix index's
+    copy-on-write tail, serve/kvtier.py) resumes prefill mid-page through
+    this path; only a row's first ``valid_len`` positions write (the padded
+    tail and any unmapped page aim out of bounds and DROP).
+
+    A chunk attends to its slot's earlier KV by gathering the page table
+    into the contiguous layout decoder_forward's cache path expects (one
+    start a row; each row then attends over the shortest span of the bucket
+    ladder that holds its own context, ``layers._cached_attention_by_row``),
+    then scatters only the chunk's tokens back; every plane of a per-head
+    pool (``pool_planes``) goes the same way. A latent pool takes
+    the chunk as the decode step takes a token
+    (``_paged_latent_chunk_prefill``: rows written in place, attention
+    absorbed over the pages where they lie, ``paged_attn_impl`` the engine's
+    "gather" | "pallas"). ``context_pages`` (STATIC, one for all rows: the
+    largest row's) bounds the gather to the pages actually covering
+    [0, start+C): chunk cost then tracks the resident context, not max_len —
+    without it a long prompt pays O(max_len²/C) in gathers (round-2 weak
+    #4). The caller buckets the count (powers of two) so the trace set stays
+    logarithmic. Returns ([B,C,V] logits, cache)."""
     from kubeflow_tpu.models.decoder import decoder_forward
 
     if cfg.is_latent:
@@ -691,24 +703,24 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
             raise NotImplementedError(
                 "LoRA over latent attention projections")
         return _paged_latent_chunk_prefill(
-            params, cache, tokens, table_row, start, valid_len, cfg,
+            params, cache, tokens, table_rows, start, valid_len, cfg,
             paged_attn_impl, context_pages)
     planes = _planes_of(cache)
     pg = cache[planes[0]].shape[2]
-    c = tokens.shape[1]
+    b, c = tokens.shape
     kv_quant = "ks" in cache
     if context_pages is not None:
         # Static slice: the bucket must cover the chunk's own pages too
         # (the [start, start+C) update-slice window below).
-        table_row = table_row[:min(context_pages, table_row.shape[0])]
-    # Gather the slot's visible cache row, every plane: [L,1,ctx*pg,...].
-    # Pad the row by one chunk of scratch positions so the final chunk's
+        table_rows = table_rows[:, :min(context_pages, table_rows.shape[1])]
+    # Gather each slot's visible cache row, every plane: [L,B,ctx*pg,...].
+    # Pad the rows by one chunk of scratch positions so the final chunk's
     # C-wide dynamic_update_slice window can never clamp and overwrite
     # earlier KV (prefix-cache hits start chunks at page — not chunk —
     # alignment, so start + C may exceed the bucket edge). The scratch tail
     # is causal-masked (kv position > any query position) and never
     # scattered back to pages.
-    rows = {n: jax.vmap(lambda pool: paged_gather(pool, table_row[None]))(
+    rows = {n: jax.vmap(lambda pool: paged_gather(pool, table_rows))(
         cache[n]) for n in planes}
     if kv_quant:
         from kubeflow_tpu.ops.quantization import dequantize_kv, quantize_kv
@@ -723,90 +735,102 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
     lr = None if lora is None else {**lora, "aidx": adapter_idx}
     logits, filled, _ = decoder_forward(params, tokens, cfg, kv_caches=caches,
                                         attn_impl=attn_impl,
-                                        valid_len=valid_len, lora=lr)
-    # Scatter the chunk's tokens back into the pool per (page, offset):
-    # position start+i lands on table_row[(start+i)//pg] at offset
-    # (start+i)%pg. Invalid rows (past valid_len, or an unmapped/-1 page)
-    # aim out of bounds and drop.
-    written = {n: jax.lax.dynamic_slice_in_dim(filled[n], start, c,
-                                               axis=2)[:, 0]   # [L,C,...]
-               for n in rows}
-    pos = start + jnp.arange(c, dtype=jnp.int32)
-    pslot = pos // pg
-    page_id = table_row[jnp.clip(pslot, 0, table_row.shape[0] - 1)]
-    ok = (jnp.arange(c, dtype=jnp.int32) < valid_len) & (page_id >= 0) \
-        & (pslot < table_row.shape[0])
-    npages_pool = cache[planes[0]].shape[1]
-    pidx = jnp.where(ok & (page_id < npages_pool), page_id, npages_pool)
-    off = pos % pg
+                                        valid_len=valid_len, lora=lr,
+                                        moe_capacity_per_row=True)
+    # Scatter the chunks' tokens back into the pool per (page, offset):
+    # row b's position start[b]+i lands on table_rows[b, (start[b]+i)//pg]
+    # at offset (start[b]+i)%pg. Invalid rows (past valid_len, or an
+    # unmapped/-1 page) aim out of bounds and drop.
+    written = {n: jnp.stack(
+        [jax.lax.dynamic_slice_in_dim(filled[n][:, r], start[r], c, axis=1)
+         for r in range(b)], axis=1) for n in rows}           # [L,B,C,...]
+    pidx, off = _chunk_write_index(table_rows, start, valid_len, c, pg,
+                                   cache[planes[0]].shape[1])
     if kv_quant:
         written["k"], written["ks"] = quantize_kv(written["k"])
         written["v"], written["vs"] = quantize_kv(written["v"])
     out = {n: cache[n].at[:, pidx, off].set(written[n], mode="drop")
            for n in planes}
-    return logits[0], out
+    return logits, out
+
+
+def _chunk_write_index(table_rows: jax.Array, start: jax.Array,  # traced
+                       valid_len: jax.Array, c: int, pg: int, num_pages: int):
+    """Where the ``C`` positions of each row's chunk are written: (page
+    [B,C], offset [B,C]) off the rows' page tables. A position past its
+    row's ``valid_len``, past the table or on an unmapped page gets page
+    ``num_pages``, one past the pool: the write drops."""
+    i = jnp.arange(c, dtype=jnp.int32)[None, :]
+    pos = start[:, None] + i
+    pslot = pos // pg
+    page_id = jnp.take_along_axis(
+        table_rows, jnp.clip(pslot, 0, table_rows.shape[1] - 1), axis=1)
+    ok = (i < valid_len[:, None]) & (page_id >= 0) \
+        & (pslot < table_rows.shape[1]) & (page_id < num_pages)
+    return jnp.where(ok, page_id, num_pages), pos % pg
 
 
 def _paged_latent_chunk_prefill(params: Params, cache: dict,  # traced
-                                tokens: jax.Array, table_row: jax.Array,
+                                tokens: jax.Array, table_rows: jax.Array,
                                 start: jax.Array, valid_len: jax.Array,
                                 cfg: DecoderConfig, attn_impl: str,
                                 context_pages: Optional[int]):
     """``paged_chunk_prefill`` over a latent pool, built like the decode
     step and not like ``decoder_forward``'s cache path: the pool is carried
     whole and flat ``[L*P, pg, W]`` through the layer scans, a layer writes
-    the chunk's ``C`` rows in place at ``(layer*P + page, offset)`` and
-    then attends, ABSORBED and causally, over the slot's pages where they
-    lie (the chunk's own among them): "pallas" through
-    ``paged_latent_chunk_attention``, which skips the pages behind the
-    chunk, "gather" through the same sums in XLA over the gathered rows.
-    Nothing gathers all layers' context up front, pads it, or puts a
-    layer's slab back. Same contract: only the first ``valid_len`` positions
-    write; ``context_pages`` bounds the pages looked at."""
+    every row's ``C`` cache rows in place at ``(layer*P + page, offset)``
+    and then each prompt attends, ABSORBED and causally, over its own pages
+    where they lie (the chunk's own among them): "pallas" through
+    ``paged_latent_chunk_attention``, one call a prompt, which skips the
+    pages behind the chunk, "gather" through the same sums in XLA over the
+    gathered rows. Nothing gathers all layers' context up front, pads it, or
+    puts a layer's slab back. Same contract: only a row's first
+    ``valid_len`` positions write; ``context_pages`` bounds the pages looked
+    at."""
     dt = cfg.activation_dtype
     pool = cache["ckv"]
     num_pages, pg = pool.shape[1:3]
-    c = tokens.shape[1]
+    b, c = tokens.shape
     if context_pages is not None:
-        table_row = table_row[:min(context_pages, table_row.shape[0])]
-    pos = start + jnp.arange(c, dtype=jnp.int32)
-    pslot = pos // pg
-    page_id = table_row[jnp.clip(pslot, 0, table_row.shape[0] - 1)]
-    ok = (jnp.arange(c, dtype=jnp.int32) < valid_len) & (page_id >= 0) \
-        & (pslot < table_row.shape[0]) & (page_id < num_pages)
-    off = pos % pg
+        table_rows = table_rows[:, :min(context_pages, table_rows.shape[1])]
+    page, off = _chunk_write_index(table_rows, start, valid_len, c, pg,
+                                   num_pages)
+    pos = start[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]   # [B,C]
 
     def block(bp, carry, layer, gcfg, _, expert_stack):
         x, flat = carry
         a = bp["attn"]
         base = layer * num_pages
         h = L.rmsnorm(x, bp["ln1"], gcfg)
-        q_nope, q_rope, row = L.latent_qkv(a, h, pos[None], gcfg)
-        pidx = jnp.where(ok, base + page_id, flat.shape[0])
-        flat = flat.at[pidx, off].set(row[0], mode="drop")
-        ltable = jnp.where(table_row >= 0, table_row + base, -1)
+        q_nope, q_rope, row = L.latent_qkv(a, h, pos, gcfg)
+        # One past this layer's pages is the next layer's page 0: a dropped
+        # write aims past the END of the flat pool.
+        pidx = jnp.where(page < num_pages, base + page, flat.shape[0])
+        flat = flat.at[pidx, off].set(row, mode="drop")
+        ltable = jnp.where(table_rows >= 0, table_rows + base, -1)
         if attn_impl == "pallas":
             from kubeflow_tpu.ops.paged_attention import (
                 paged_latent_chunk_attention,
             )
 
-            q = L.latent_query(a, q_nope[0], q_rope[0], gcfg)  # [C,H,W]
-            o_row = paged_latent_chunk_attention(
-                jnp.swapaxes(q, 0, 1), flat, ltable, start,
-                sm_scale=L.latent_scale(gcfg))
-            attn = L.latent_output(a, jnp.swapaxes(o_row, 0, 1), gcfg)[None]
+            q = L.latent_query(a, q_nope, q_rope, gcfg)        # [B,C,H,W]
+            o_row = jnp.stack([
+                paged_latent_chunk_attention(
+                    jnp.swapaxes(q[r], 0, 1), flat, ltable[r], start[r],
+                    sm_scale=L.latent_scale(gcfg)) for r in range(b)])
+            attn = L.latent_output(a, jnp.swapaxes(o_row, 1, 2), gcfg)
         else:
-            rows = paged_gather(flat, ltable[None])        # [1, T, W]
-            causal = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :] \
-                <= pos[:, None]                            # [C, T]
+            rows = paged_gather(flat, ltable)                  # [B, T, W]
+            causal = jnp.arange(rows.shape[1], dtype=jnp.int32)[
+                None, None, :] <= pos[:, :, None]              # [B, C, T]
             attn = L.latent_absorbed_attention(
-                a, q_nope, q_rope, rows, causal[None, None], gcfg)
+                a, q_nope, q_rope, rows, causal[:, None], gcfg)
         proj = jnp.einsum("bshk,hkd->bsd", attn, a["wo"].astype(dt))
         x, h = L.add_rmsnorm(x, proj, bp["ln2"], gcfg)
-        return x + _feed_forward(bp, h, gcfg, expert_stack, valid_len), flat
+        return x + _feed_forward(bp, h, gcfg, expert_stack, valid_len,
+                                 capacity_per_row=True), flat
 
     x, flat = _scan_layer_groups(
         params, cfg, (_embed(params, tokens, cfg),
                       pool.reshape(-1, *pool.shape[2:])), block)
-    logits = _head_logits(params, x, cfg)
-    return logits[0], {"ckv": flat.reshape(pool.shape)}
+    return _head_logits(params, x, cfg), {"ckv": flat.reshape(pool.shape)}

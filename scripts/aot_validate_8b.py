@@ -170,7 +170,8 @@ def paged_serve_analysis(topology: str, tp: int, *, model: str,
             paged_attn_impl="pallas" if attn_impl == "pallas" else "gather"),
         donate_argnums=(1,)).lower(
             params_sds, cache, sds((1, chunk), jnp.int32),
-            sds((mpp,), jnp.int32), sds((), jnp.int32), sds((), jnp.int32))
+            sds((1, mpp), jnp.int32), sds((1,), jnp.int32),
+            sds((1,), jnp.int32))
     return {name: {**_mem_gb(low.compile()),
                    "kernels": kernel_calls(low.as_text())}
             for name, low in (("decode", decode),

@@ -50,7 +50,8 @@ def main():
 
     fn = jax.jit(
         lambda c, st, vl, ncp: paged_chunk_prefill(
-            params, c, tokens, table, st, vl, cfg, context_pages=ncp),
+            params, c, tokens, table[None], st[None], vl[None], cfg,
+            context_pages=ncp),
         static_argnums=(3,), donate_argnums=(0,))
 
     def run(pos, ctx, reps=10):

@@ -151,8 +151,9 @@ ENGINE_KEYS = {
     "host_gap_n", "preemptions", "requests_shed", "requests_completed",
     "tokens_generated", "decode_rounds", "first_token_fetches",
     "prefill_phase_sum_s", "prefill_phase_n", "decode_steps_dispatched",
-    "decode_tokens_emitted", "decode_context_tokens", "kv_bytes_per_token",
-    "kv_pool_bytes"}
+    "decode_tokens_emitted", "decode_context_tokens",
+    "prefill_programs_dispatched", "prefill_chunks_dispatched",
+    "prefill_tokens_dispatched", "kv_bytes_per_token", "kv_pool_bytes"}
 ENGINE_CONSTANTS = {"slots", "kv_bytes_per_token", "kv_pool_bytes"}
 
 
@@ -175,6 +176,11 @@ def test_engine_counters_exist_at_construction_and_only_grow(engine):
         assert all(b[k] >= a[k] for k in ENGINE_KEYS)
     after = snaps[-1]
     assert after["prefill_phase_n"] == 3 and after["prefill_phase_sum_s"] > 0
+    # a prompt token is dispatched once, or found in the prefix index (the
+    # third prompt can be); chunks share programs
+    assert 39 + 40 < after["prefill_tokens_dispatched"] <= 39 + 40 + 41
+    assert 0 < after["prefill_programs_dispatched"] \
+        <= after["prefill_chunks_dispatched"]
     # a first token comes from the prefill; the decode rounds emit the rest
     assert after["decode_tokens_emitted"] == 3 * 8
     # a round dispatches 1, 2 (a prefill in flight) or 4 steps
