@@ -3,7 +3,7 @@
 BASELINE config 2 evidence ("KServe req/s + p50 TTFT, v5e"); run by hand
 (the driver's headline bench stays bench.py):
 
-    python bench_serve.py [--workload uniform|mixed|prefix|all] [--paged]
+    python bench_serve.py [--workload uniform|mixed|prefix|all]
 
 Methodology (round-3 fix of round-2 weak #2 — numbers were
 compile-confounded): every run WARMS the exact dispatch set first (the
@@ -15,13 +15,10 @@ the measured window.
 Workloads (closed-loop A/Bs; ``--workload scenarios`` is the open-loop
 trace-driven path — see ``run_scenarios`` and kubeflow_tpu/loadgen/):
   uniform — fixed 512-token prompts, 64 new tokens (the round-1/2 shape).
-  mixed   — lognormal prompt lengths 64..1024 at high concurrency under the
-            SAME KV-pool HBM budget for both engines: the paged engine
-            turns pool density into extra decode slots (48 vs 16), which is
-            where paging should win throughput.
+  mixed   — lognormal prompt lengths 64..1024 at high concurrency: the
+            page pool of 16 whole contexts serves 48 decode slots.
   prefix  — a shared 512-token system prompt + short unique tails: the
-            paged prefix cache skips the shared prefill, which is where
-            paging should win TTFT.
+            prefix cache skips the shared prefill.
 """
 
 from __future__ import annotations
@@ -32,8 +29,8 @@ import threading
 import time
 
 
-def _mk_engine(cfg, *, paged: bool, slots: int, buckets, max_pages=None,
-               on_tpu: bool, adapters=()):
+def _mk_engine(cfg, *, slots: int, max_pages=None, on_tpu: bool,
+               adapters=()):
     from kubeflow_tpu.core.serving import BatchingSpec, LoRASpec
     from kubeflow_tpu.serve.engine import LLMEngine
 
@@ -41,8 +38,7 @@ def _mk_engine(cfg, *, paged: bool, slots: int, buckets, max_pages=None,
             if adapters else LoRASpec())
     engine = LLMEngine(cfg, BatchingSpec(
         max_batch_size=slots, max_seq_len=cfg.max_seq_len,
-        prefill_buckets=list(buckets),
-        paged=paged, page_size=128, max_pages=max_pages,
+        paged=True, page_size=128, max_pages=max_pages,
         weights_dtype="bfloat16" if on_tpu else None, lora=lora))
     if adapters:
         import jax
@@ -167,7 +163,7 @@ import numpy as np  # noqa: E402  (used by _prompts_for)
 
 
 def run_bench(workload: str, requests: int, concurrency: int,
-              prompt_len: int, max_new: int, paged: bool = False) -> dict:
+              prompt_len: int, max_new: int) -> dict:
     import jax
 
     from kubeflow_tpu.models.config import preset
@@ -185,39 +181,27 @@ def run_bench(workload: str, requests: int, concurrency: int,
         model_tag = "tiny"
         prompt_len = min(prompt_len, 64)
 
-    # KV HBM budget: 16 contiguous slots × max_seq_len. The paged engine
-    # gets the SAME pool (16×2048/128 = 256 pages) but may run more slots —
-    # pool density is the whole point of paging on mixed traffic.
+    # KV HBM budget: 16 whole contexts (16×2048/128 = 256 pages); the
+    # engine may run more slots than that over it — pool density is the
+    # whole point of paging on mixed traffic.
     cap = cfg.max_seq_len - max_new - 1
     prompt_len = min(prompt_len, cap)
     base_slots = min(16, concurrency)
     pool_pages = base_slots * cfg.max_seq_len // 128
+    slots = base_slots
     if workload == "mixed":
-        buckets = sorted({min(b, cfg.max_seq_len) for b in
-                          (128, 256, 512, 1024)})
-        # Density comparison needs offered load above the contiguous slot
-        # count: the paged engine runs 3× the slots over the SAME pool, and
-        # both engines face the same concurrency.
+        # Offered load above the whole contexts the pool holds: 3× the
+        # slots over the same pool.
         concurrency = max(concurrency, 2 * base_slots)
-        slots = 3 * base_slots if paged else base_slots
-    elif workload == "prefix":
-        buckets = [min(prompt_len + 128, cfg.max_seq_len)]
-        slots = base_slots
-    else:
-        buckets = [prompt_len]
-        slots = base_slots
-    engine = _mk_engine(cfg, paged=paged, slots=slots, buckets=buckets,
-                        max_pages=pool_pages if paged else None,
+        slots = 3 * base_slots
+    engine = _mk_engine(cfg, slots=slots, max_pages=pool_pages,
                         on_tpu=on_tpu)
     params = SamplingParams(max_new_tokens=max_new, temperature=0.0)
     rng = np.random.default_rng(0)
 
-    # Warm the EXACT dispatch set: one prompt per configured prefill bucket
-    # (deterministic — a rare bucket must not compile mid-measurement) plus
-    # 2× slots of the workload's own mix.
-    warm = [rng.integers(1, cfg.vocab_size,
-                         size=max(1, min(b - 1, cap))).tolist()
-            for b in buckets]
+    # Warm the dispatch set: the longest prompt (every context bucket of
+    # the chunk program) plus 2× slots of the workload's own mix.
+    warm = [rng.integers(1, cfg.vocab_size, size=max(1, cap)).tolist()]
     warm += _prompts_for(workload, 2 * slots, cfg, prompt_len, rng, max_new)
     m = _measure(engine,
                  lambda n: _prompts_for(workload, n, cfg, prompt_len, rng,
@@ -225,8 +209,7 @@ def run_bench(workload: str, requests: int, concurrency: int,
                  params, concurrency, requests, warm)
     return {
         "metric": f"serve_req_per_sec[{model_tag},{workload},"
-                  f"gen{max_new},c{concurrency}"
-                  f"{',paged' if paged else ''}]",
+                  f"gen{max_new},c{concurrency}]",
         "value": m["value"],
         "unit": "req/s",
         "vs_baseline": 1.0,
@@ -235,7 +218,7 @@ def run_bench(workload: str, requests: int, concurrency: int,
             "spread_pct": m["spread_pct"],
             "slots": slots,
             "concurrency": concurrency,
-            "pool_pages": pool_pages if paged else None,
+            "pool_pages": pool_pages,
             "requests_per_segment": requests,
         },
     }
@@ -286,7 +269,6 @@ def run_moe_ab(requests: int, concurrency: int, prompt_len: int,
     for tag, knobs in variants:
         engine = LLMEngine(cfg, BatchingSpec(
             max_batch_size=slots, max_seq_len=cfg.max_seq_len,
-            prefill_buckets=[prompt_len],
             weights_dtype="bfloat16" if on_tpu else None, **knobs))
         gen = lambda n: [rng.integers(1, cfg.vocab_size,          # noqa: E731
                                       size=prompt_len).tolist()
@@ -310,10 +292,10 @@ def run_moe_ab(requests: int, concurrency: int, prompt_len: int,
 def run_quant_ab(requests: int, concurrency: int, prompt_len: int,
                  max_new: int, only: str = "all") -> list[dict]:
     """int8 weight-only + int8-KV served A/B (VERDICT r4 #3): bf16 vs
-    quantized weights (contiguous engine — isolates the decode param-read
-    halving) and paged bf16 vs paged int8 KV at the SAME pool page count
-    (isolates the read-traffic change; the density win — 2x resident
-    tokens/byte — is architectural, AOT-proven in BASELINE.md).
+    quantized weights (isolates the decode param-read halving) and then
+    int8 KV on top, all at the SAME pool page count (isolates the
+    read-traffic change; the density win — 2x resident tokens/byte — is
+    architectural, AOT-proven in BASELINE.md).
     Decode-heavy workload (short prompts, long generations) so the per-step
     param/KV read is what the req/s measures."""
     import jax
@@ -351,11 +333,7 @@ def run_quant_ab(requests: int, concurrency: int, prompt_len: int,
     variants = [
         ("bf16", {}),
         ("int8w", {"quantize": "int8"}),
-        ("paged_bf16", {"paged": True, "max_pages": pool_pages,
-                        "paged_attn_impl": "gather"}),
-        ("paged_int8kv", {"paged": True, "max_pages": pool_pages,
-                          "quantize": "int8", "kv_cache_dtype": "int8",
-                          "paged_attn_impl": "gather"}),
+        ("int8w_int8kv", {"quantize": "int8", "kv_cache_dtype": "int8"}),
     ]
     if only != "all":
         variants = [vk for vk in variants if vk[0] == only]
@@ -363,7 +341,8 @@ def run_quant_ab(requests: int, concurrency: int, prompt_len: int,
     for tag, knobs in variants:
         engine = LLMEngine(cfg, BatchingSpec(
             max_batch_size=slots, max_seq_len=cfg.max_seq_len,
-            prefill_buckets=[prompt_len], chunked_prefill_tokens=512,
+            paged=True, page_size=128, max_pages=pool_pages,
+            paged_attn_impl="gather",
             weights_dtype="bfloat16" if on_tpu else None, **knobs))
         gen = lambda n: [rng.integers(1, cfg.vocab_size,          # noqa: E731
                                       size=prompt_len).tolist()
@@ -455,7 +434,7 @@ def run_longctx_ab(requests: int, concurrency: int, prompt_len: int,
 
 
 def run_spec_ab(requests: int, concurrency: int, prompt_len: int,
-                max_new: int, only: str = "all", paged: bool = False,
+                max_new: int, only: str = "all",
                 spec_k: int = 6) -> list[dict]:
     """Speculative decoding served A/B: spec-off vs n-gram-draft spec-on at
     a DECODE-HEAVY shape (short templated prompts, long generations — the
@@ -516,8 +495,7 @@ def run_spec_ab(requests: int, concurrency: int, prompt_len: int,
     for tag, spec in variants:
         engine = LLMEngine(cfg, BatchingSpec(
             max_batch_size=slots, max_seq_len=cfg.max_seq_len,
-            prefill_buckets=[max(prompt_len, 16)],
-            paged=paged, page_size=128,
+            paged=True, page_size=128,
             weights_dtype="bfloat16" if on_tpu else None,
             speculative=spec))
         m = _measure(engine, gen, params, concurrency, requests,
@@ -528,7 +506,7 @@ def run_spec_ab(requests: int, concurrency: int, prompt_len: int,
         rows.append({
             "metric": f"serve_spec_decode_tok_s[{model_tag},{tag},"
                       f"p{prompt_len},gen{max_new},c{concurrency},"
-                      f"k{spec_k}{',paged' if paged else ''}]",
+                      f"k{spec_k}]",
             "value": round(toks[tag], 1),
             "unit": "tok/s",
             "vs_baseline": 1.0,
@@ -551,7 +529,7 @@ def run_spec_ab(requests: int, concurrency: int, prompt_len: int,
         rows.append({
             "metric": f"serve_spec_speedup[{model_tag},ngram_vs_off,"
                       f"p{prompt_len},gen{max_new},c{concurrency},"
-                      f"k{spec_k}{',paged' if paged else ''}]",
+                      f"k{spec_k}]",
             "value": round(toks["spec_ngram"] / max(toks["spec_off"], 1e-9),
                            3),
             "unit": "x decode tok/s",
@@ -563,8 +541,7 @@ def run_spec_ab(requests: int, concurrency: int, prompt_len: int,
 
 
 def run_hotloop_ab(requests: int, concurrency: int, prompt_len: int,
-                   max_new: int, only: str = "all",
-                   paged: bool = False) -> list[dict]:
+                   max_new: int, only: str = "all") -> list[dict]:
     """Decode hot-loop host-overhead A/B (ISSUE 4 tentpole): pipelined
     dispatch + device-resident scheduler state ON vs the synchronous
     dispatch-then-consume loop, same engine shape, same process, warmed
@@ -613,8 +590,7 @@ def run_hotloop_ab(requests: int, concurrency: int, prompt_len: int,
     for tag, pipelined in variants:
         engine = LLMEngine(cfg, BatchingSpec(
             max_batch_size=slots, max_seq_len=cfg.max_seq_len,
-            prefill_buckets=[max(prompt_len, 16)],
-            paged=paged, page_size=128,
+            paged=True, page_size=128,
             weights_dtype="bfloat16" if on_tpu else None,
             pipelined_decode=pipelined))
         m = _measure(engine, gen, params, concurrency, requests,
@@ -624,8 +600,7 @@ def run_hotloop_ab(requests: int, concurrency: int, prompt_len: int,
         em = m["engine_metrics"]
         rows.append({
             "metric": f"serve_hotloop_decode_tok_s[{model_tag},{tag},"
-                      f"p{prompt_len},gen{max_new},c{concurrency}"
-                      f"{',paged' if paged else ''}]",
+                      f"p{prompt_len},gen{max_new},c{concurrency}]",
             "value": round(toks[tag], 1),
             "unit": "tok/s",
             "vs_baseline": 1.0,
@@ -647,8 +622,7 @@ def run_hotloop_ab(requests: int, concurrency: int, prompt_len: int,
     if len(toks) == 2:
         rows.append({
             "metric": f"serve_hotloop_speedup[{model_tag},pipelined_vs_off,"
-                      f"p{prompt_len},gen{max_new},c{concurrency}"
-                      f"{',paged' if paged else ''}]",
+                      f"p{prompt_len},gen{max_new},c{concurrency}]",
             "value": round(
                 toks["pipelined_on"] / max(toks["pipelined_off"], 1e-9), 3),
             "unit": "x decode tok/s",
@@ -660,8 +634,7 @@ def run_hotloop_ab(requests: int, concurrency: int, prompt_len: int,
 
 
 def run_scenarios(requests: int, rate_rps: float, prompt_len: int,
-                  max_new: int, paged: bool = False,
-                  only: str = "all") -> list[dict]:
+                  max_new: int, only: str = "all") -> list[dict]:
     """Open-loop trace-driven scenario matrix (ISSUE 11): replay the
     canonical loadgen scenarios (uniform Poisson / bursty multi-QoS /
     shared-prefix long-tail) against one engine and report the full
@@ -703,11 +676,9 @@ def run_scenarios(requests: int, rate_rps: float, prompt_len: int,
     rows = []
     for sc in scenarios:
         slots = 16
-        buckets = sorted({min(_p2(prompt_len), cap), min(2 * prompt_len, cap)})
-        engine = _mk_engine(cfg, paged=paged, slots=slots, buckets=buckets,
-                            max_pages=(slots * cfg.max_seq_len // 128
-                                       if paged else None), on_tpu=on_tpu,
-                            adapters=sc.adapter_ids)
+        engine = _mk_engine(cfg, slots=slots,
+                            max_pages=slots * cfg.max_seq_len // 128,
+                            on_tpu=on_tpu, adapters=sc.adapter_ids)
         engine.start()
         try:
             tracer.reset()
@@ -729,21 +700,13 @@ def run_scenarios(requests: int, rate_rps: float, prompt_len: int,
             engine.stop()
         rows.append({
             "metric": f"serve_scenario_req_per_sec[{model_tag},{sc.name},"
-                      f"r{rate_rps:g},n{requests}"
-                      f"{',paged' if paged else ''}]",
+                      f"r{rate_rps:g},n{requests}]",
             "value": rep["req_s"],
             "unit": "req/s",
             "vs_baseline": 1.0,
             "detail": rep,
         })
     return rows
-
-
-def _p2(n: int) -> int:
-    p = 1
-    while p < n:
-        p *= 2
-    return p
 
 
 if __name__ == "__main__":
@@ -757,8 +720,6 @@ if __name__ == "__main__":
     ap.add_argument("--concurrency", type=int, default=16)
     ap.add_argument("--prompt-len", type=int, default=512)
     ap.add_argument("--max-new", type=int, default=64)
-    ap.add_argument("--paged", action="store_true",
-                    help="paged KV cache + prefix caching engine")
     ap.add_argument("--moe-variant", default="all",
                     choices=["all", "dense", "dispatch_prefill",
                              "dispatch_prefill+zd_decode"],
@@ -769,7 +730,7 @@ if __name__ == "__main__":
     ap.add_argument("--variant", default="all",
                     choices=["all", "dense", "dispatch_prefill",
                              "dispatch_prefill+zd_decode", "bf16", "int8w",
-                             "paged_bf16", "paged_int8kv", "paged_gather",
+                             "int8w_int8kv", "paged_gather",
                              "paged_pallas", "spec_off", "spec_ngram",
                              "pipelined_off", "pipelined_on"],
                     help="moe/quant/longctx/spec/hotloop workloads: run "
@@ -785,22 +746,21 @@ if __name__ == "__main__":
     args = ap.parse_args()
     if args.workload == "scenarios":
         rows = run_scenarios(args.requests, args.rate, args.prompt_len,
-                             args.max_new, paged=args.paged,
-                             only=args.scenario)
+                             args.max_new, only=args.scenario)
         for row in rows:
             print(json.dumps(row), flush=True)
         raise SystemExit(0)
     if args.workload == "hotloop":
         rows = run_hotloop_ab(args.requests, args.concurrency,
                               args.prompt_len, args.max_new,
-                              only=args.variant, paged=args.paged)
+                              only=args.variant)
         for row in rows:
             print(json.dumps(row), flush=True)
         raise SystemExit(0)
     if args.workload == "spec":
         rows = run_spec_ab(args.requests, args.concurrency, args.prompt_len,
                            args.max_new, only=args.variant,
-                           paged=args.paged, spec_k=args.spec_k)
+                           spec_k=args.spec_k)
         for row in rows:
             print(json.dumps(row), flush=True)
         raise SystemExit(0)
@@ -825,5 +785,5 @@ if __name__ == "__main__":
            else [args.workload])
     for wl in wls:
         print(json.dumps(run_bench(wl, args.requests, args.concurrency,
-                                   args.prompt_len, args.max_new,
-                                   paged=args.paged)), flush=True)
+                                   args.prompt_len, args.max_new)),
+              flush=True)

@@ -59,7 +59,8 @@ def main() -> None:
                                 "overrides": {"vocab_size": 512,
                                               "max_seq_len": 64}}),
                     batching=BatchingSpec(max_batch_size=4, max_seq_len=64,
-                                          prefill_buckets=[32])),
+                                          page_size=16,
+                                          chunked_prefill_tokens=32)),
                 explainer=ExplainerSpec(handler="grad_x_input"))))
         ready = client.wait_for(isvc, "Ready", timeout=300)
 

@@ -64,7 +64,7 @@ Whole-program core (ISSUE 8): one ``Program`` per lint run parses every
 parses across rule families, seeded-regression re-lints, and ``--changed``
 subsets that still need package-wide resolution context), resolves
 imports across modules (``from kubeflow_tpu.serve.spec_decode import
-verify_step`` makes the callee's def visible to a rule scanning the
+paged_verify_step`` makes the callee's def visible to a rule scanning the
 importer), and propagates jit/donation/static-argnum facts transitively
 through the cross-module call graph with a depth bound
 (``Program.transitive_callees``). The compilation-stability family
@@ -415,7 +415,7 @@ class JitFact:
     signature rule (F6xx) and donation rule (D104/S401) reads, so the
     fact set can't drift between families."""
 
-    name: str                       # call-site spelling ('self._decode_n')
+    name: str               # call-site spelling ('self._paged_decode_n')
     ctor: ast.AST                   # the jax.jit(...) call or decorated def
     static_argnums: tuple[int, ...] = ()
     static_argnames: tuple[str, ...] = ()
@@ -463,7 +463,8 @@ def _fact_from_ctor(mod: Module, name: str, call: ast.Call) -> JitFact:
 
 def _expr_spelling(node: ast.AST) -> Optional[str]:
     """Dotted source spelling of a Name/Attribute chain (``self._fn``,
-    ``engine._decode_n``) — the call-site key jit facts are stored under."""
+    ``engine._paged_decode_n``) — the call-site key jit facts are stored
+    under."""
     parts: list[str] = []
     while isinstance(node, ast.Attribute):
         parts.append(node.attr)
@@ -613,8 +614,8 @@ class Program:
                      ) -> Optional[tuple[Module, ast.AST]]:
         """Cross-module call resolution: same-module first (the ISSUE-7
         callgraph), then the alias-expanded qualname against the program
-        (``verify_step(...)`` under ``from ..spec_decode import
-        verify_step`` lands on the def in spec_decode.py)."""
+        (``paged_verify_step(...)`` under ``from ..spec_decode import
+        paged_verify_step`` lands on the def in spec_decode.py)."""
         local = mod.callgraph.resolve_call(call, fn)
         if local is not None:
             return mod, local

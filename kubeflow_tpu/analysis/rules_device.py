@@ -286,7 +286,7 @@ class FullBufferReupload(Rule):
 
 
 def _donating_callables(mod: Module) -> dict[str, tuple[int, ...]]:
-    """Map of callee spellings ('self._decode_n' / 'decode_n') to donated
+    """Map of callee spellings ('self._paged_decode_n' / 'decode_n') to donated
     positional indices — read from the shared jit-fact table
     (``core.jit_table``), the same source the F6xx dispatch-signature
     rules use, so donation facts can't drift between families."""

@@ -16,7 +16,9 @@ from __future__ import annotations
 import enum
 from typing import Any, Optional
 
-from pydantic import BaseModel, ConfigDict, Field, model_validator
+from pydantic import (
+    BaseModel, ConfigDict, Field, field_validator, model_validator,
+)
 
 from kubeflow_tpu.core.object import ApiObject, ConditionMixin
 from kubeflow_tpu.core.registry import register_kind
@@ -211,9 +213,12 @@ class BatchingSpec(BaseModel):
     role: str = "unified"
     max_batch_size: int = 8          # decode batch slots
     max_seq_len: int = 2048
-    # Paged KV cache (vLLM analog): HBM budget decoupled from
-    # slots × max_seq_len; shared-prefix requests reuse pages.
-    paged: bool = False
+    # The KV cache is a page pool (vLLM analog): HBM budget decoupled from
+    # slots × max_seq_len; shared-prefix requests reuse pages. It is the
+    # engine's only cache, so this is not an option: the key still parses
+    # (manifests and the benchmark's traffic files carry ``paged: true``)
+    # and its one legal value is True.
+    paged: bool = True
     page_size: int = 128             # KV cache page (tokens)
     max_pages: Optional[int] = None  # default: slots × max_seq_len / page
     enable_prefix_caching: bool = True
@@ -252,30 +257,16 @@ class BatchingSpec(BaseModel):
     # 2× KV read), "pallas" (direct page reads via the paged-attention
     # kernel), or "auto" (pallas on TPU, gather elsewhere).
     paged_attn_impl: str = "auto"
-    # Long prompts split into chunks with decode interleaving; this many may
+    # Prompts prefill in chunks with decode interleaving; this many may
     # chunk concurrently (no head-of-line blocking between long prompts).
-    # Paged: where one chunk leaves the model's weights under-used (an
+    # Where one chunk leaves the model's weights under-used (an
     # expert layer, a small chunk: engine.chunk_rows_per_weight), the
     # chunks of all of them go to the device as ONE program a scheduler
     # pass, so each weight is read once for the pass.
     max_concurrent_prefills: int = 2
-    # Batched prefill: up to this many same-bucket waiting prompts share ONE
-    # prefill dispatch (power-of-two group sizes bound the trace set),
-    # amortizing the per-admission dispatch floor — measured p50 TTFT
-    # −16–29% on uniform traffic (order-reversed A/Bs, BASELINE.md round 5).
-    # Outputs are exactly the sequential path's (rows are
-    # attention-independent). Auto-disabled for dispatch-MoE prefill
-    # (capacity buffers would couple co-batched prompts) and unused in
-    # paged mode (admission is chunk-based). 1 = off.
-    prefill_batch_max: int = 4
-    # Transient-HBM bound for a batched prefill group: group_size × bucket
-    # never exceeds this many tokens (the group multiplies scratch KV and
-    # the [N, bucket, V] logits — a config provisioned for [1, max_bucket]
-    # must not OOM when 4 max-bucket prompts arrive together). Big buckets
-    # batch less; buckets above the budget never batch.
-    prefill_batch_token_budget: int = 4096
+    # Every admission prefills in chunks of this many tokens (a multiple
+    # of page_size: chunk boundaries are page boundaries).
     chunked_prefill_tokens: int = 512
-    prefill_buckets: list[int] = Field(default_factory=lambda: [128, 512, 2048])
     # Decode steps per device dispatch: sampling runs on-device and up to
     # this many tokens emit per host round-trip (amortizes dispatch latency;
     # early-exits when all slots finish). 1 = one step per dispatch.
@@ -309,20 +300,16 @@ class BatchingSpec(BaseModel):
     # — halves the decode-step HBM param read again vs bf16 and halves
     # param residency (the v5e density lever). None = off.
     quantize: Optional[str] = None
-    # KV cache storage dtype for the PAGED pool: "int8" stores K/V int8
+    # KV cache storage dtype of the page pool: "int8" stores K/V int8
     # with per-token-per-head dynamic scales — doubles the pool's resident
-    # tokens at the same HBM. Requires paged=True; composes with both
-    # paged-attention impls (the direct-page-read kernel dequantizes
-    # in VMEM), with disaggregated roles (scale blobs ride the v2 wire
-    # format), and with the host tier (demote/promote batches carry
-    # scale rows). None = the model activation dtype.
+    # tokens at the same HBM. Composes with both paged-attention impls
+    # (the direct-page-read kernel dequantizes in VMEM), with
+    # disaggregated roles (scale blobs ride the v2 wire format), and with
+    # the host tier (demote/promote batches carry scale rows). None = the
+    # model activation dtype.
     kv_cache_dtype: Optional[str] = None
-    # "auto": Pallas flash kernel on TPU (forward-only prefill is where it
-    # wins), XLA elsewhere; or force "pallas"/"xla".
-    prefill_attn_impl: str = "auto"
     # MoE expert path per phase. A request's capacity drops can never
-    # depend on co-batched neighbors: one-shot prefill runs per request
-    # ([1, bucket]), and a paged chunk program that carries several
+    # depend on co-batched neighbors: a chunk program that carries several
     # prompts' chunks takes the capacity and the claiming order PER ROW
     # (layers._moe_dispatch, capacity_per_row), so the training dispatch
     # path is batch-independent there too. "auto" uses it for MoE models
@@ -366,6 +353,16 @@ class BatchingSpec(BaseModel):
     # adapter's packed low-rank slices in the SAME batched dispatch as
     # base traffic. max_adapters=0 (default) = off.
     lora: LoRASpec = Field(default_factory=LoRASpec)
+
+    @field_validator("paged")
+    @classmethod
+    def _only_paged(cls, value: bool) -> bool:
+        if not value:
+            raise ValueError(
+                "paged=False: the contiguous slot cache is gone; the page "
+                "pool is the engine's only KV cache (drop the key or set "
+                "paged=true)")
+        return value
 
     @model_validator(mode="after")
     def _check_role(self) -> "BatchingSpec":

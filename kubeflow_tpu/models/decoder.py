@@ -127,14 +127,14 @@ def decoder_param_specs(cfg: DecoderConfig) -> Params:
 
 def _block_forward(block_params, x, positions, cfg: DecoderConfig,
                    kv_cache=None, attn_impl="xla", mesh=None,
-                   rules=DEFAULT_RULES, prefill=False,
+                   rules=DEFAULT_RULES,
                    expert_axis=None, seq_axis=None, tp_axis=None,
                    valid_len=None, lora=None, expert_stack=None,
                    moe_capacity_per_row=False):
     h = L.rmsnorm(x, block_params["ln1"], cfg, mesh=mesh)
     attn_out, new_cache = L.attention_block(
         block_params["attn"], h, positions, cfg,
-        kv_cache=kv_cache, attn_impl=attn_impl, mesh=mesh, prefill=prefill,
+        kv_cache=kv_cache, attn_impl=attn_impl, mesh=mesh,
         tp_axis=tp_axis, lora=lora)
     # Residual add + second norm as ONE op: fused kernels run it in a
     # single pass over the stream (layers.add_rmsnorm).
@@ -197,7 +197,7 @@ def _remat(fn, policy: str):
 
 def _run_layers(layers, x, positions, cfg: DecoderConfig, planes: tuple,
                 plane_names: tuple, cache_len, *, attn_impl, mesh, rules,
-                prefill, valid_len, lora, moe_capacity_per_row=False):
+                valid_len, lora, moe_capacity_per_row=False):
     """One group of alike layers (``layer_groups``) over ``x``: scanned when
     stacked, looped when a list. ``planes``: this group's slices of the
     cache's stacked planes, in ``plane_names``' order (empty without a
@@ -205,7 +205,7 @@ def _run_layers(layers, x, positions, cfg: DecoderConfig, planes: tuple,
     def block(bp, x, cache, lr, expert_stack=None):
         return _block_forward(
             bp, x, positions, cfg, kv_cache=cache, attn_impl=attn_impl,
-            mesh=mesh, rules=rules, prefill=prefill, valid_len=valid_len,
+            mesh=mesh, rules=rules, valid_len=valid_len,
             lora=lr, expert_stack=expert_stack,
             moe_capacity_per_row=moe_capacity_per_row)
 
@@ -278,7 +278,7 @@ def decoder_forward(
     With ``skip_head``, returns the final-norm hidden states [B,S,D] instead
     of logits (the chunked-CE loss applies the head blockwise).
     ``valid_len`` (traced scalar or [B]): marks trailing positions as
-    padding for the MoE dispatch path (serving prefill buckets) — see
+    padding for the MoE dispatch path (a serving chunk's tail) — see
     layers.moe_block. ``inputs_embeds`` [B,S,D] replaces the embedding
     lookup (pre-scale) — the differentiable-input path attribution
     explainers need (serve/explain.py); ``tokens`` still supplies shapes
@@ -318,15 +318,9 @@ def decoder_forward(
 
     aux_total = jnp.float32(0)
     new_caches = None
-    # Static prefill marker (engine prefill path): cache start is known to be
-    # 0 at trace time, enabling the flash kernel. Must never enter a traced
-    # pytree (remat would trace it into an array).
-    prefill = bool(kv_caches.get("prefill", False)) if kv_caches else False
-
     # The cache's planes (k and v per head): every stacked [L, ...] leaf
-    # beside the scalar length and the static prefill marker.
-    plane_names = tuple(n for n in (kv_caches or {})
-                        if n not in ("len", "prefill"))
+    # beside the length.
+    plane_names = tuple(n for n in (kv_caches or {}) if n != "len")
     groups = layer_groups(cfg)
 
     pp = dict(mesh.shape).get("pipeline", 1) if mesh is not None else 1
@@ -360,7 +354,7 @@ def decoder_forward(
         x, planes, aux = _run_layers(
             params[name], x, positions, gcfg, planes, plane_names,
             kv_caches["len"] if kv_caches is not None else None,
-            attn_impl=attn_impl, mesh=mesh, rules=rules, prefill=prefill,
+            attn_impl=attn_impl, mesh=mesh, rules=rules,
             valid_len=valid_len, lora=lora_g,
             moe_capacity_per_row=moe_capacity_per_row)
         aux_total = aux_total + aux
@@ -524,16 +518,6 @@ def _chunked_ce(hidden: jax.Array, head: jax.Array, targets: jax.Array,
     _, (nll, correct) = jax.lax.scan(body, None, (h, t))
     return (nll.swapaxes(0, 1).reshape(b, s),
             correct.swapaxes(0, 1).reshape(b, s))
-
-
-def init_kv_caches(cfg: DecoderConfig, batch: int, max_len: int) -> dict:
-    """Contiguous decode cache, stacked over layers."""
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
-    return {
-        "k": jnp.zeros(shape, cfg.activation_dtype),
-        "v": jnp.zeros(shape, cfg.activation_dtype),
-        "len": jnp.int32(0),
-    }
 
 
 def decoder_loss(

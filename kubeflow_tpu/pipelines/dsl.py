@@ -178,11 +178,13 @@ class ParallelFor(_Group):
     fan-in as a list (KFP dsl.Collected semantics)."""
 
     kind = "loop"
-    _counter = 0
 
     def __init__(self, items: Any):
-        ParallelFor._counter += 1
-        self.loop_id = f"loop-{ParallelFor._counter}"
+        # Numbered within the pipeline being traced, so that compiling a
+        # pipeline gives the same IR whatever the process compiled before.
+        trace = _require_trace("ParallelFor")
+        trace.loops += 1
+        self.loop_id = f"loop-{trace.loops}"
         self.items = items
 
     def __enter__(self) -> LoopItem:
@@ -295,6 +297,7 @@ class _PipelineTrace:
         self.tasks: dict[str, dict[str, Any]] = {}
         self._group_stack: list[_Group] = []
         self._names: dict[str, int] = {}
+        self.loops = 0
 
     def push_group(self, g: _Group) -> None:
         self._group_stack.append(g)

@@ -64,6 +64,7 @@ import _thread
 import contextlib
 import logging
 import os
+import re
 import sys
 import threading
 import time
@@ -301,6 +302,7 @@ class RecompileError(AssertionError):
 #: start with "Compiling ".
 _COMPILE_LOGGERS = ("jax._src.interpreters.pxla",)
 _COMPILE_PREFIX = "Compiling "
+_PROGRAM_NAME = re.compile(r"\w+\((.+)\)")
 
 
 def _app_call_site() -> str:
@@ -357,6 +359,11 @@ class _RecompileWatchdog(logging.Handler):
             return
         fn = str(record.args[0]) if record.args else \
             msg[len(_COMPILE_PREFIX):].split(" ", 1)[0]
+        # This JAX (0.9) announces the PROGRAM's name, "jit(<fn>)" or
+        # "pmap(<fn>)"; the report names the function.
+        wrapped = _PROGRAM_NAME.fullmatch(fn)
+        if wrapped:
+            fn = wrapped.group(1)
         site = _app_call_site()
         with self._meta:
             phase = "steady" if self._warm else "warmup"

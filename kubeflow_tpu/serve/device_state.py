@@ -4,7 +4,7 @@ hot-loop elimination (ISSUE 4 tentpole (a)).
 Before this module, every decode round re-materialised the scheduler's
 tensor-shaped state from host Python: eight ``[B]`` arrays
 (tokens/lengths/live/temps/top_k/top_p/stops/budgets) rebuilt with numpy and
-``jnp.asarray``-uploaded per dispatch, plus — in paged mode — the FULL
+``jnp.asarray``-uploaded per dispatch, plus the FULL
 ``[B, max_pages_per_slot]`` page table. Each of those uploads pays the
 per-dispatch host overhead the multi-step dispatch exists to amortize, and
 the re-materialisation itself is host work serialized against device
@@ -36,7 +36,7 @@ upload accounting.
 
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -78,13 +78,12 @@ class DecodeState:
     """Persistent on-device scheduler state + dirty-index delta sync.
 
     ``arrays`` is the dict of eight ``[B]`` device arrays the decode
-    dispatch donates and returns; ``table`` (paged engines only) is the
-    ``[B, mpp]`` device page table threaded through paged dispatches the
-    same way. ``adopt()`` swaps in a dispatch's returned handles; the
-    ``mark_*``/``sync_*`` pair applies host-side scheduler deltas as
-    per-index donated scatters."""
+    dispatch donates and returns; ``table`` is the ``[B, mpp]`` device page
+    table threaded through the dispatches the same way. ``adopt()`` swaps
+    in a dispatch's returned handles; the ``mark_*``/``sync_*`` pair applies
+    host-side scheduler deltas as per-index donated scatters."""
 
-    def __init__(self, num_slots: int, mpp: Optional[int] = None):
+    def __init__(self, num_slots: int, mpp: int):
         self.num_slots = num_slots
         self.arrays: dict[str, jax.Array] = {
             "tokens": jnp.zeros((num_slots,), jnp.int32),
@@ -99,15 +98,13 @@ class DecodeState:
             # whose low-rank delta applies to this row; -1 = base model.
             "adapter": jnp.full((num_slots,), -1, jnp.int32),
         }
-        self.table: Optional[jax.Array] = None
-        if mpp is not None:
-            self.table = jnp.full((num_slots, mpp), -1, jnp.int32)
+        self.table = jnp.full((num_slots, mpp), -1, jnp.int32)
         # Upload accounting — the tentpole's proof obligation. "full"
         # counters may only ever reflect construction; sync counters grow
         # with scheduler events, never with steady-state decode rounds.
         self.stats = {
             "full_state_uploads": 1,
-            "full_table_uploads": 1 if mpp is not None else 0,
+            "full_table_uploads": 1,
             "slot_syncs": 0,
             "table_row_syncs": 0,
         }
@@ -122,12 +119,8 @@ class DecodeState:
     def mark_slot(self, idx: int) -> None:
         self.dirty_slots.add(idx)
 
-    def mark_slots(self, idxs) -> None:
-        self.dirty_slots.update(idxs)
-
     def mark_row(self, idx: int) -> None:
-        if self.table is not None:
-            self.dirty_rows.add(idx)
+        self.dirty_rows.add(idx)
 
     # -- delta sync (immediately before a dispatch that reads the state) ---
 
@@ -158,9 +151,6 @@ class DecodeState:
     def sync_rows(self, row_for: Callable[[int], np.ndarray]) -> None:  # hot-loop
         """Scatter every dirty page-table row (one ``[mpp]`` upload each —
         page-table GROWTH costs one row, never the full table)."""
-        if self.table is None:
-            self.dirty_rows.clear()
-            return
         for idx in sorted(self.dirty_rows):
             self.table = self._row_set(
                 self.table, jax.device_put(np.int32(idx)),
@@ -171,11 +161,10 @@ class DecodeState:
 
     # -- post-dispatch adoption --------------------------------------------
 
-    def adopt(self, arrays: dict, table: Optional[jax.Array] = None) -> None:
+    def adopt(self, arrays: dict, table: jax.Array) -> None:
         """Swap in the advanced state a decode dispatch returned (the
         donated buffers' successors). Deltas applied after this chain onto
         the dispatch's outputs — JAX's program-order queueing keeps the
         one-round-deep pipeline coherent without host synchronization."""
         self.arrays = arrays
-        if table is not None:
-            self.table = table
+        self.table = table

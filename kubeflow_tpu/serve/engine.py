@@ -5,17 +5,19 @@ python/huggingfaceserver; SURVEY.md §3.2 "engine step loop").
 Design, driven by XLA's compilation model rather than CUDA streams:
 
 - **Recompile-free shapes.** Two compiled programs serve all traffic: one
-  decode step at a fixed slot count [B, 1], and one prefill per length
-  bucket. Admission changes data (slot contents), never shapes — XLA traces
-  once, the MXU sees the same tiles forever.
-- **Slot KV cache.** [L, B, Smax, KV, Dh] with per-slot lengths. A slot is
-  the unit of admission (continuous batching: new sequences join between
-  decode steps, finished ones free their slot immediately). Per-slot cache
-  writes are one scatter; decode attention masks by each slot's length.
-  Buffers are donated so the cache updates in place in HBM.
-- **Prefill reuses the training forward** (models/decoder.py
-  decoder_forward) on a [1, bucket] block, then scatters the resulting
-  K/V into the slot — one model definition, two execution shapes.
+  decode step at a fixed slot count [B, 1], and one chunk prefill per
+  context bucket (powers of two of pages). Admission changes data (slot
+  contents, page-table rows), never shapes — XLA traces once, the MXU
+  sees the same tiles forever.
+- **One KV cache: the page pool** (serve/paged.py). [L, P, page, ...] per
+  plane with a [B, mpp] page table and per-slot lengths. A slot is the
+  unit of admission (continuous batching: new sequences join between
+  decode steps, finished ones free their slot and pages immediately);
+  pages are the unit of memory, shared between requests by the prefix
+  index. Buffers are donated so the pool updates in place in HBM.
+- **Every prompt prefills in chunks** (``chunked_prefill_tokens``), decode
+  rounds interleaving between them; a chunk's K/V scatters per token into
+  the pages its table row names.
 - **Scheduler in plain Python** between device steps: reap → admit →
   prefill → decode → emit. The hot loop holds no Python per-token state
   beyond the slot table; everything tensor-shaped lives on device.
@@ -71,9 +73,12 @@ from kubeflow_tpu.core.serving import (
     BatchingSpec, QOS_DEFAULT, QOS_PRIORITY,
 )
 from kubeflow_tpu.serve.device_state import DEAD_SLOT, DecodeState
-from kubeflow_tpu.models import layers as L
+from kubeflow_tpu.serve.paged import (
+    PageAllocator, PagePoolExhausted, context_bucket, paged_chunk_prefill,
+    paged_decode_multi, pool_bytes_per_token, pool_planes,
+)
 from kubeflow_tpu.models.config import DecoderConfig
-from kubeflow_tpu.models.decoder import Params, decoder_forward, init_decoder_params
+from kubeflow_tpu.models.decoder import Params, init_decoder_params
 from kubeflow_tpu.obs import profiler as prof
 from kubeflow_tpu.obs.profiler import hot_span
 from kubeflow_tpu.obs.stats import quantile as _quantile
@@ -155,223 +160,6 @@ def _sample_batch(logits: jax.Array, key: jax.Array, temps: jax.Array,  # traced
     return jnp.where(temps > 0, sampled, greedy)
 
 
-# -- device-side steps ---------------------------------------------------------
-
-def _decode_attention(q, ck, cv, lengths, cfg: DecoderConfig):  # traced
-    """One-token attention over slot caches.
-
-    q [B,1,H,Dh]; ck/cv [B,Smax,KV,Dh]; lengths [B] = position of the token
-    being decoded (its K/V were just written at that index, so attend to
-    kpos <= lengths[b])."""
-    b, smax = ck.shape[0], ck.shape[1]
-    groups = cfg.n_heads // cfg.n_kv_heads
-    qg = q.reshape(b, cfg.n_kv_heads, groups, cfg.head_dim)
-    scores = jnp.einsum("bkgd,bskd->bkgs", qg, ck,
-                        preferred_element_type=jnp.float32)
-    scores *= cfg.head_dim ** -0.5
-    kpos = jnp.arange(smax, dtype=jnp.int32)
-    mask = kpos[None, :] <= lengths[:, None]            # [B, Smax]
-    scores = jnp.where(mask[:, None, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(ck.dtype)
-    out = jnp.einsum("bkgs,bskd->bkgd", probs, cv)
-    return out.reshape(b, 1, cfg.n_heads, cfg.head_dim)
-
-
-def _decode_block(bp, x, positions, lengths, live, cache_k, cache_v, cfg,  # traced
-                  lora=None):
-    """One transformer block for a [B,1] decode step against slot caches.
-    Returns (x, new_k_cache, new_v_cache)."""
-    dt = cfg.activation_dtype
-    h = L.rmsnorm(x, bp["ln1"], cfg)
-    q = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wq"].astype(dt))
-    k = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wk"].astype(dt))
-    v = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wv"].astype(dt))
-    if lora is not None:
-        # Multi-adapter decode (serve/lora.py): each row's low-rank
-        # delta adds onto the shared base projection — one gather + two
-        # einsums per target; adapter_idx = -1 rows add an exact zero.
-        q = L.apply_lora_layer(lora, "wq", h, q)
-        k = L.apply_lora_layer(lora, "wk", h, k)
-        v = L.apply_lora_layer(lora, "wv", h, v)
-    q = L.rope(q, positions, cfg.rope_theta)
-    k = L.rope(k, positions, cfg.rope_theta)
-    bidx = jnp.arange(x.shape[0])
-    # Dead rows (free slots, finished slots, and the slot a chunked prefill
-    # is filling) must not touch the cache: aim their write out of bounds
-    # and drop it — a slot mid-chunking has real KV at position 0 that a
-    # lengths=0 placeholder write would silently corrupt.
-    widx = jnp.where(live, lengths, jnp.int32(cache_k.shape[1]))
-    ck = cache_k.at[bidx, widx].set(k[:, 0], mode="drop")
-    cv = cache_v.at[bidx, widx].set(v[:, 0], mode="drop")
-    attn = _decode_attention(q, ck, cv, lengths, cfg)
-    proj = jnp.einsum("bshk,hkd->bsd", attn, bp["attn"]["wo"].astype(dt))
-    if lora is not None and "wo" in lora["targets"]:
-        proj = L.apply_lora_layer(
-            lora, "wo", attn.reshape(attn.shape[0], 1, -1), proj)
-    x = x + proj
-    h = L.rmsnorm(x, bp["ln2"], cfg)
-    if cfg.is_moe:
-        mlp_out, _ = L.moe_block(bp["mlp"], h, cfg)
-    else:
-        mlp_out = L.mlp_block(bp["mlp"], h, cfg)
-    return x + mlp_out, ck, cv
-
-
-def _decode_step(params: Params, cache: dict, tokens: jax.Array,  # traced
-                 lengths: jax.Array, live: jax.Array, cfg: DecoderConfig,
-                 lora=None):
-    """tokens [B] (last sampled), lengths [B] (their positions), live [B]
-    (rows whose KV write is real). Returns (logits [B,V] fp32, new cache)."""
-    dt = cfg.activation_dtype
-    x = params["embed"].astype(dt)[tokens[:, None]]      # [B,1,D]
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.hidden ** 0.5, dt)
-    positions = lengths[:, None]
-    lora_xs = L.slice_layers(lora)
-
-    def body(x, scan_in):
-        bp, ck, cv, lsl = scan_in
-        x, nk, nv = _decode_block(bp, x, positions, lengths, live, ck, cv,
-                                  cfg, lora=L.layer_view(lora, lsl))
-        return x, (nk, nv)
-
-    x, (nk, nv) = jax.lax.scan(body, x, (params["layers"],
-                                         cache["k"], cache["v"], lora_xs))
-    x = L.rmsnorm(x, params["final_norm"], cfg)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(dt),
-                        preferred_element_type=jnp.float32)[:, 0]
-    if cfg.logits_softcap is not None:
-        logits = jnp.tanh(logits / cfg.logits_softcap) * cfg.logits_softcap
-    return logits, {"k": nk, "v": nv}
-
-
-def _decode_multi(params: Params, cache: dict, tokens: jax.Array,  # traced
-                  lengths: jax.Array, live: jax.Array, temps: jax.Array,
-                  top_k: jax.Array, top_p: jax.Array, stop_tokens: jax.Array,
-                  budgets: jax.Array, key: jax.Array, cfg: DecoderConfig,
-                  num_steps: int, sample_mode: str = "full",
-                  lora=None, adapter_idx=None):
-    """Up to ``num_steps`` decode+sample steps in ONE device dispatch.
-
-    The single-step loop pays one host round-trip per token, which at small
-    batch can dwarf the model forward. Sampling runs
-    on-device inside a ``while_loop`` that exits as soon as every slot is
-    finished (stop token, token budget, or cache-length cap).
-
-    Dead rows (free slots, finished slots, a slot mid-chunked-prefill) still
-    flow through the batch so shapes never change, but their KV writes are
-    aimed out of bounds and DROPPED in _decode_block — a replayed write is
-    NOT safe (it would corrupt KV a chunked prefill already wrote). Their
-    sampled tokens are discarded via the ``live`` mask. Emitted tokens
-    surface as ``out`` [B, num_steps] with -1 in never-emitted cells.
-
-    Returns (out, cache, tokens, lengths, live, budgets) — the advanced
-    carry IS the next round's input, which is what lets the engine keep
-    the whole scheduler state device-resident (serve/device_state.py) and
-    dispatch round N+1 before round N's tokens ever reach the host."""
-    b = tokens.shape[0]
-    max_len = cache["k"].shape[2]
-    out0 = jnp.full((b, num_steps), -1, jnp.int32)
-    lr = None if lora is None else {**lora, "aidx": adapter_idx}
-
-    def cond(carry):
-        i, _, _, _, live, _, _, _ = carry
-        return (i < num_steps) & jnp.any(live)
-
-    def body(carry):
-        i, cache, tokens, lengths, live, budgets, key, out = carry
-        logits, cache = _decode_step(params, cache, tokens, lengths, live,
-                                     cfg, lora=lr)
-        key, sub = jax.random.split(key)
-        sampled = _sample_batch(logits, sub, temps, top_k, top_p,
-                                mode=sample_mode)
-        tokens = jnp.where(live, sampled, tokens)
-        out = out.at[:, i].set(jnp.where(live, sampled, -1))
-        lengths = jnp.where(live, lengths + 1, lengths)
-        budgets = jnp.where(live, budgets - 1, budgets)
-        # Same finish rules the host scheduler applies (they must agree, or a
-        # slot would stall or over-generate between dispatches).
-        live = live & (sampled != stop_tokens) & (budgets > 0) \
-            & (lengths + 1 < max_len)
-        return i + 1, cache, tokens, lengths, live, budgets, key, out
-
-    _, cache, tokens, lengths, live, budgets, _, out = jax.lax.while_loop(
-        cond, body,
-        (jnp.int32(0), cache, tokens, lengths, live, budgets, key, out0))
-    return out, cache, tokens, lengths, live, budgets
-
-
-def _chunk_prefill_step(params: Params, cache: dict, tokens: jax.Array,  # traced
-                        slot: jax.Array, start: jax.Array,
-                        cfg: DecoderConfig,
-                        valid_len: Optional[jax.Array] = None,
-                        lora=None, adapter_idx=None):
-    """Prefill ONE chunk of a prompt into slot ``slot`` at position ``start``.
-
-    Chunked prefill (SURVEY.md §5 long-context serving): long prompts are
-    split into fixed-size chunks so decode steps for running streams
-    interleave between chunks — bounding their TPOT spike. The slot's cache
-    row accumulates KV across chunks (the cache path already supports an
-    arbitrary traced start); positions beyond the written region are causal-
-    masked until decode overwrites them. Returns ([C, V] logits, cache)."""
-    ck = jax.lax.dynamic_slice_in_dim(cache["k"], slot, 1, axis=1)
-    cv = jax.lax.dynamic_slice_in_dim(cache["v"], slot, 1, axis=1)
-    caches = {"k": ck, "v": cv, "len": start}
-    lr = None if lora is None else {**lora, "aidx": adapter_idx}
-    logits, filled, _ = decoder_forward(params, tokens, cfg, kv_caches=caches,
-                                        valid_len=valid_len, lora=lr)
-    nk = jax.lax.dynamic_update_slice_in_dim(cache["k"], filled["k"], slot,
-                                             axis=1)
-    nv = jax.lax.dynamic_update_slice_in_dim(cache["v"], filled["v"], slot,
-                                             axis=1)
-    return logits[0], {"k": nk, "v": nv}
-
-
-def _prefill_step(params: Params, cache: dict, tokens: jax.Array,  # traced
-                  slots: jax.Array, lengths: jax.Array,
-                  cfg: DecoderConfig, attn_impl: str = "xla",
-                  mesh: Optional[Mesh] = None,
-                  lora=None, adapter_idx=None):
-    """Prefill N same-bucket prompts in ONE dispatch (tokens [N, bucket],
-    slots/lengths [N]); returns ([N, V] last-real-token logits, cache).
-    N=1 is the classic per-request path — one function serves both, so the
-    scratch-cache layout and impl selection can never diverge.
-
-    Runs the training forward with a scratch contiguous cache, scatters the
-    resulting K/V into the slot rows, and returns the last-real-token
-    logits (the basis of the first sampled tokens — TTFT ends when they
-    land). The per-admission dispatch floor (one host round-trip, plus a
-    [1, bucket] forward that under-fills the MXU at small buckets)
-    amortizes across the group; rows are
-    attention-independent (batched causal attention never crosses rows),
-    so outputs are exactly the sequential path's. NOT used for
-    dispatch-MoE prefill — shared [E, C] capacity buffers would couple
-    co-batched prompts, the batch dependence the per-request path exists
-    to avoid (engine.__init__). ``mesh`` (TP serving): the flash path runs
-    per-shard via shard_map."""
-    n, bucket = tokens.shape
-    scratch = {
-        "k": jnp.zeros((cfg.n_layers, n, bucket,
-                        cfg.n_kv_heads, cfg.head_dim), cfg.activation_dtype),
-        "v": jnp.zeros((cfg.n_layers, n, bucket,
-                        cfg.n_kv_heads, cfg.head_dim), cfg.activation_dtype),
-        "len": jnp.int32(0),
-        # Static marker: lets attention_block use the flash kernel (start is
-        # statically 0 on this path).
-        "prefill": True,
-    }
-    lr = None if lora is None else {**lora, "aidx": adapter_idx}
-    logits, filled, _ = decoder_forward(params, tokens, cfg,
-                                        kv_caches=scratch,
-                                        attn_impl=attn_impl, mesh=mesh,
-                                        valid_len=lengths, lora=lr)
-    ck = cache["k"].at[:, slots, :bucket].set(filled["k"])
-    cv = cache["v"].at[:, slots, :bucket].set(filled["v"])
-    last = logits[jnp.arange(n), lengths - 1]
-    return last, {"k": ck, "v": cv}
-
-
 # -- requests ------------------------------------------------------------------
 
 @dataclasses.dataclass
@@ -397,7 +185,7 @@ class Request:
     # admission acquires a packed-buffer slot (hot-loading on miss) and
     # every release path returns the reference.
     adapter: Optional[str] = None
-    # Recompute-preemption bookkeeping (paged engine): output tokens already
+    # Recompute-preemption bookkeeping: output tokens already
     # folded back into prompt_tokens when the slot was preempted.
     resumed_from: int = 0
     # Disaggregated serving (serve/handoff.py). ``handoff_requested``:
@@ -494,7 +282,7 @@ class _Chunking:
     request: Request
     slot: int
     pos: int              # next prompt position to prefill
-    stalls: int = 0       # consecutive page-starved attempts (paged mode)
+    stalls: int = 0       # consecutive page-starved attempts
 
 
 @dataclasses.dataclass
@@ -844,8 +632,7 @@ class LLMEngine:
         # change because co-batched traffic filled an expert's capacity
         # buffer. Two phases, two resolutions (VERDICT r3 #3):
         # - PREFILL: capacity drops are a function of the request alone.
-        #   One-shot prefill runs per request on a [1, bucket] block; the
-        #   paged chunk program may carry several prompts' chunks, and
+        #   The chunk program may carry several prompts' chunks, and
         #   takes the dispatch path's capacity and claiming order per row
         #   (layers._moe_dispatch, capacity_per_row), so a prompt keeps and
         #   drops what it would alone. The training dispatch path applies
@@ -889,8 +676,6 @@ class LLMEngine:
             raise ValueError("batching.max_seq_len exceeds model max_seq_len")
         self.num_slots = b.max_batch_size
         self.max_len = b.max_seq_len
-        self.buckets = sorted(set(
-            min(x, self.max_len) for x in b.prefill_buckets)) or [self.max_len]
 
         key = jax.random.PRNGKey(seed)
         self.params = params if params is not None else init_decoder_params(key, cfg)
@@ -919,11 +704,6 @@ class LLMEngine:
                 f"unknown kv_cache_dtype {b.kv_cache_dtype!r}; "
                 "supported: int8")
         self.kv_quant = b.kv_cache_dtype == "int8"
-        if self.kv_quant and not b.paged:
-            raise ValueError(
-                "kv_cache_dtype=int8 requires paged=True (the density win "
-                "is the page pool's; the contiguous slot cache pre-reserves "
-                "slots x max_seq_len either way)")
         self._cache_sh: Optional[NamedSharding] = None
         self._cache_scale_sh: Optional[NamedSharding] = None
         if self.mesh is not None:
@@ -948,55 +728,39 @@ class LLMEngine:
             self._cache_scale_sh = NamedSharding(self.mesh, scale_ps)
         self._rng = jax.random.PRNGKey(seed + 1)  # lockfree: scheduler-confined
 
-        self.paged = bool(b.paged)
-        self.page_size = int(b.page_size)
-        self._allocator = None
+        self.page_size = pg = int(b.page_size)
         self._kvtier = None          # lockfree: scheduler-confined
-        if self.paged:
-            from kubeflow_tpu.serve.paged import PageAllocator
-
-            pg = self.page_size
-            if pg <= 0 or self.max_len % pg:
-                raise ValueError("page_size must divide max_seq_len")
-            chunk = max(0, int(b.chunked_prefill_tokens)) or pg
-            if chunk % pg:
-                raise ValueError(
-                    "chunked_prefill_tokens must be a multiple of page_size "
-                    "in paged mode (chunk boundaries are page boundaries)")
-            self._mpp = self.max_len // pg
-            self._num_pages = int(b.max_pages or self.num_slots * self._mpp)
-            if self._num_pages * pg < self.max_len:
-                raise ValueError(
-                    "page pool smaller than one max-length sequence")
-            self._allocator = PageAllocator(
-                self._num_pages, pg,
-                enable_prefix_caching=b.enable_prefix_caching)
-            # lockfree: scheduler-confined (host page-table mirror)
-            self._table = np.full((self.num_slots, self._mpp), -1, np.int32)
-            self._slot_pages: list[list[int]] = [  # lockfree: scheduler-confined
-                [] for _ in range(self.num_slots)]
-            from kubeflow_tpu.serve.paged import pool_planes
-
-            # The pool, plane by plane as the model describes it (k and v
-            # per head, int8 pools with their per-token-per-head scales:
-            # +4 bytes per token per kv head against the 2x density win on
-            # the Dh-wide vectors; a latent model's one padded row).
-            self.cache = {  # lockfree: scheduler-confined (donated KV)
-                name: self._zeros(
-                    (cfg.n_layers, self._num_pages, pg, *trail), dt,
-                    scale=name in ("ks", "vs"))
-                for name, trail, dt in pool_planes(cfg, self.kv_quant)}
-        else:
-            self.cache = {  # lockfree: scheduler-confined (donated KV)
-                "k": self._zeros((cfg.n_layers, self.num_slots, self.max_len,
-                                  cfg.n_kv_heads, cfg.head_dim),
-                                 cfg.activation_dtype),
-                "v": self._zeros((cfg.n_layers, self.num_slots, self.max_len,
-                                  cfg.n_kv_heads, cfg.head_dim),
-                                 cfg.activation_dtype),
-            }
-
-        from kubeflow_tpu.serve.paged import pool_bytes_per_token
+        if pg <= 0 or self.max_len % pg:
+            raise ValueError("page_size must divide max_seq_len")
+        # Every admission prefills in chunks, and a chunk writes exactly
+        # the pages it fills (no bucket slack), so chunking cannot be off:
+        # 0 falls back to one page a chunk.
+        self.chunk_size = max(0, int(b.chunked_prefill_tokens)) or pg
+        if self.chunk_size % pg:
+            raise ValueError(
+                "chunked_prefill_tokens must be a multiple of page_size "
+                "(chunk boundaries are page boundaries)")
+        self._mpp = self.max_len // pg
+        self._num_pages = int(b.max_pages or self.num_slots * self._mpp)
+        if self._num_pages * pg < self.max_len:
+            raise ValueError(
+                "page pool smaller than one max-length sequence")
+        self._allocator = PageAllocator(
+            self._num_pages, pg,
+            enable_prefix_caching=b.enable_prefix_caching)
+        # lockfree: scheduler-confined (host page-table mirror)
+        self._table = np.full((self.num_slots, self._mpp), -1, np.int32)
+        self._slot_pages: list[list[int]] = [  # lockfree: scheduler-confined
+            [] for _ in range(self.num_slots)]
+        # The pool, plane by plane as the model describes it (k and v
+        # per head, int8 pools with their per-token-per-head scales:
+        # +4 bytes per token per kv head against the 2x density win on
+        # the Dh-wide vectors; a latent model's one padded row).
+        self.cache = {  # lockfree: scheduler-confined (donated KV)
+            name: self._zeros(
+                (cfg.n_layers, self._num_pages, pg, *trail), dt,
+                scale=name in ("ks", "vs"))
+            for name, trail, dt in pool_planes(cfg, self.kv_quant)}
 
         self._kv_bytes_per_token = pool_bytes_per_token(cfg, self.kv_quant)
         self._kv_pool_bytes = int(sum(v.nbytes for v in self.cache.values()))
@@ -1009,131 +773,81 @@ class LLMEngine:
         # /debug/device reports it. Stays empty off the TPU.
         self.program_kernels: dict[str, dict[str, int]] = {}  # lockfree: scheduler-confined writes; readers snapshot
 
-        def _prefill_fn(p, c, t, s, ln, lr=None, ai=None):
-            # Per-bucket impl choice (shape is static per trace): measured on
-            # v5e, the flash kernel overtakes fused XLA attention in the full
-            # model around S≈2k (XLA wins below — matmul-dominated regime).
-            # Mesh mode runs the kernel per-shard via shard_map (Mosaic
-            # can't be GSPMD-partitioned); non-dividing head counts fall
-            # back to XLA inside attention_block.
-            impl = b.prefill_attn_impl
-            if impl == "auto":
-                # Flash kernel needs the bucket to divide its 128 block.
-                impl = ("pallas" if on_tpu and t.shape[1] >= 2048
-                        and t.shape[1] % 128 == 0 else "xla")
-            out, cache = _prefill_step(p, c, t, s, ln, cfg_prefill, impl,
-                                       mesh=self.mesh, lora=lr,
-                                       adapter_idx=ai)
-            return out, self._pin(cache)
-
-        # One jitted program serves every group size (N is a trace dim:
-        # sizes are powers of two up to the cap, so the trace set stays
-        # log-bounded per bucket; N=1 is the classic per-request path).
-        self._prefill = jax.jit(_prefill_fn, donate_argnums=(1,))
-        # Group cap for batched prefill; forced off where co-batching would
-        # change outputs (dispatch-MoE prefill couples rows through the
-        # shared expert-capacity buffers). The token budget bounds the
-        # transient HBM a group multiplies (scratch KV + [N, bucket, V]
-        # logits): big buckets batch less, the biggest not at all.
-        self.prefill_batch_max = max(1, int(b.prefill_batch_max))
-        self.prefill_batch_token_budget = max(
-            0, int(b.prefill_batch_token_budget))
-        if cfg.is_moe and cfg_prefill.moe_impl == "dispatch":
-            self.prefill_batch_max = 1
-        # Chunked prefill for prompts longer than the chunk size: one chunk
-        # per scheduler step per in-flight prompt, decode interleaving
-        # between chunks. In paged mode EVERY admission takes this path
-        # (chunks write exactly the pages they fill — no bucket slack), so
-        # chunking can't be off: 0 falls back to one page per chunk.
-        self.chunk_size = max(0, int(b.chunked_prefill_tokens))
-        if self.paged and (self.chunk_size <= 0
-                           or self.chunk_size % self.page_size):
-            self.chunk_size = self.page_size
-        self._prefill_chunk = jax.jit(
-            lambda p, c, t, s, st, vl, lr=None, ai=None: _pin2(
-                _chunk_prefill_step(p, c, t, s, st, cfg_prefill, vl,
-                                    lora=lr, adapter_idx=ai),
-                self._pin),
-            donate_argnums=(1,))
         self._chunkings: list[_Chunking] = []   # lockfree: scheduler-confined
         self.max_concurrent_prefills = max(1, int(b.max_concurrent_prefills))
-        # Chunks one prefill program takes: 1 unless the paged engine below
-        # builds the program over several prompts' chunks.
+        # Chunks one prefill program takes: 1 unless the program over
+        # several prompts' chunks is built below.
         self._chunk_rows = 1
-        if self.paged:
-            from kubeflow_tpu.serve.paged import (
-                paged_chunk_prefill, paged_decode_multi,
-            )
+        pattn = b.paged_attn_impl
+        if pattn == "auto":
+            # Mesh mode: gather (pure XLA ops — GSPMD-partitionable);
+            # the direct-page-read kernel would need a shard_map.
+            # int8 pools ride the kernel too: it reads int8 pages +
+            # scale rows and dequantizes in VMEM.
+            pattn = ("pallas" if on_tpu and self.mesh is None
+                     else "gather")
+        if pattn not in ("gather", "pallas"):
+            raise ValueError(
+                f"unknown paged_attn_impl {b.paged_attn_impl!r}; "
+                "one of auto|gather|pallas")
+        self.paged_attn_impl = pattn    # resolved (post-auto) impl
 
-            pattn = b.paged_attn_impl
-            if pattn == "auto":
-                # Mesh mode: gather (pure XLA ops — GSPMD-partitionable);
-                # the direct-page-read kernel would need a shard_map.
-                # int8 pools ride the kernel too: it reads int8 pages +
-                # scale rows and dequantizes in VMEM.
-                pattn = ("pallas" if on_tpu and self.mesh is None
-                         else "gather")
-            if pattn not in ("gather", "pallas"):
-                raise ValueError(
-                    f"unknown paged_attn_impl {b.paged_attn_impl!r}; "
-                    "one of auto|gather|pallas")
-            self.paged_attn_impl = pattn    # resolved (post-auto) impl
-            def _chunk_rows_fn(p, c, t, tr, st, vl, ncp, lr, ai):
-                return _pin2(
-                    paged_chunk_prefill(
-                        p, c, t, tr, st, vl, cfg_prefill, context_pages=ncp,
-                        lora=lr, adapter_idx=ai, paged_attn_impl=pattn),
-                    self._pin)
+        def _chunk_rows_fn(p, c, t, tr, st, vl, ncp, lr, ai):
+            return _pin2(
+                paged_chunk_prefill(
+                    p, c, t, tr, st, vl, cfg_prefill, context_pages=ncp,
+                    lora=lr, adapter_idx=ai, paged_attn_impl=pattn),
+                self._pin)
 
-            # ONE prompt's chunk: tokens [1,C], its table row, scalar start
-            # and valid length; [C,V] logits.
-            self._paged_chunk = jax.jit(
-                lambda p, c, t, tr, st, vl, ncp, lr=None, ai=None: _row0(
-                    _chunk_rows_fn(p, c, t, tr[None], st[None], vl[None],
-                                   ncp, lr, ai)),
+        # ONE prompt's chunk: tokens [1,C], its table row, scalar start
+        # and valid length; [C,V] logits.
+        self._paged_chunk = jax.jit(
+            lambda p, c, t, tr, st, vl, ncp, lr=None, ai=None: _row0(
+                _chunk_rows_fn(p, c, t, tr[None], st[None], vl[None],
+                               ncp, lr, ai)),
+            static_argnums=(6,), donate_argnums=(1,))
+        # The chunks of ALL in-flight prefills in one program (tokens
+        # [B,C], a table row, a start and a valid length a row; [B,C,V]
+        # logits), so a scheduler pass reads every weight once. Built
+        # only where one chunk leaves the weights under-used
+        # (``chunk_rows_per_weight``): a dense model at 512 tokens
+        # dispatches exactly as it always did. It is dispatched at ONE
+        # static context, the whole table: a row's attention follows
+        # its own context whatever the table's length (the span ladder
+        # of layers._cached_attention_by_row; the latent kernel skips
+        # the pages behind its chunk), so a ladder of context buckets
+        # would spare only the gather of a per-head pool's rows, and
+        # each further program is loaded and run at every start
+        # (0.75 s warm, 5 s cold on a v5e: PERF.md, PR 29).
+        if self.max_concurrent_prefills > 1 and chunk_rows_per_weight(
+                cfg_prefill, self.chunk_size) < RIDGE_ROWS:
+            self._chunk_rows = self.max_concurrent_prefills
+            self._paged_chunks = jax.jit(
+                lambda p, c, t, tr, st, vl, ncp, lr=None, ai=None:
+                _chunk_rows_fn(p, c, t, tr, st, vl, ncp, lr, ai),
                 static_argnums=(6,), donate_argnums=(1,))
-            # The chunks of ALL in-flight prefills in one program (tokens
-            # [B,C], a table row, a start and a valid length a row; [B,C,V]
-            # logits), so a scheduler pass reads every weight once. Built
-            # only where one chunk leaves the weights under-used
-            # (``chunk_rows_per_weight``): a dense model at 512 tokens
-            # dispatches exactly as it always did. It is dispatched at ONE
-            # static context, the whole table: a row's attention follows
-            # its own context whatever the table's length (the span ladder
-            # of layers._cached_attention_by_row; the latent kernel skips
-            # the pages behind its chunk), so a ladder of context buckets
-            # would spare only the gather of a per-head pool's rows, and
-            # each further program is loaded and run at every start
-            # (0.75 s warm, 5 s cold on a v5e: PERF.md, PR 29).
-            if self.max_concurrent_prefills > 1 and chunk_rows_per_weight(
-                    cfg_prefill, self.chunk_size) < RIDGE_ROWS:
-                self._chunk_rows = self.max_concurrent_prefills
-                self._paged_chunks = jax.jit(
-                    lambda p, c, t, tr, st, vl, ncp, lr=None, ai=None:
-                    _chunk_rows_fn(p, c, t, tr, st, vl, ncp, lr, ai),
-                    static_argnums=(6,), donate_argnums=(1,))
 
-            def _paged_decode_fn(p, c, st, tbl, key, n, m, lr=None,
-                                 _impl=pattn):
-                # The device-resident state dict + page table ride in as
-                # donated buffers and return advanced — the scheduler never
-                # re-uploads them (serve/device_state.py).
-                cache_in = {**c, "table": tbl}
-                out, cache, tokens, lengths, live, budgets = \
-                    paged_decode_multi(
-                        p, cache_in, st["tokens"], st["lengths"],
-                        st["live"], st["temps"], st["top_k"], st["top_p"],
-                        st["stops"], st["budgets"], key, cfg_decode, n,
-                        sample_mode=m, attn_impl=_impl,
-                        lora=lr, adapter_idx=st["adapter"])
-                table = cache.pop("table")
-                st = {**st, "tokens": tokens, "lengths": lengths,
-                      "live": live, "budgets": budgets}
-                return out, self._pin(cache), st, table
+        def _paged_decode_fn(p, c, st, tbl, key, n, m, lr=None,
+                             _impl=pattn):
+            # The device-resident state dict + page table ride in as
+            # donated buffers and return advanced — the scheduler never
+            # re-uploads them (serve/device_state.py).
+            cache_in = {**c, "table": tbl}
+            out, cache, tokens, lengths, live, budgets = \
+                paged_decode_multi(
+                    p, cache_in, st["tokens"], st["lengths"],
+                    st["live"], st["temps"], st["top_k"], st["top_p"],
+                    st["stops"], st["budgets"], key, cfg_decode, n,
+                    sample_mode=m, attn_impl=_impl,
+                    lora=lr, adapter_idx=st["adapter"])
+            table = cache.pop("table")
+            st = {**st, "tokens": tokens, "lengths": lengths,
+                  "live": live, "budgets": budgets}
+            return out, self._pin(cache), st, table
 
-            self._paged_decode_n = jax.jit(
-                _paged_decode_fn, static_argnums=(5, 6),
-                donate_argnums=(1, 2, 3))
+        self._paged_decode_n = jax.jit(
+            _paged_decode_fn, static_argnums=(5, 6),
+            donate_argnums=(1, 2, 3))
         # Scheduler-confined state (the whole block below): mutated ONLY
         # on the scheduler thread (or by step() when no loop runs — the
         # unthreaded mode never coexists with start()). Cross-thread
@@ -1158,7 +872,7 @@ class LLMEngine:
         # ``_handoff_release`` and free on the next step.
         self._handoff_holds: dict[str, tuple] = {}  # lockfree: scheduler-confined
         self._handoff_release: "queue.Queue[tuple[str, bool]]" = queue.Queue()
-        if self.paged and self.kv_quant:
+        if self.kv_quant:
             def _adopt_paged_fn(c, k, v, ks, vs, pidx):
                 # int8 pool: the scale planes scatter alongside their
                 # pages — a page without its scales is garbage content.
@@ -1169,7 +883,7 @@ class LLMEngine:
                        "ks": c["ks"].at[:, pi].set(ks, mode="drop"),
                        "vs": c["vs"].at[:, pi].set(vs, mode="drop")}
                 return self._pin(out)
-        elif self.paged:
+        else:
             def _adopt_paged_fn(c, k, v, pidx):
                 # OOB page ids (the power-of-two pad) drop their writes —
                 # one trace per padded page-count, log-bounded.
@@ -1178,16 +892,8 @@ class LLMEngine:
                 out = {**c, "k": c["k"].at[:, pi].set(k, mode="drop"),
                        "v": c["v"].at[:, pi].set(v, mode="drop")}
                 return self._pin(out)
-        else:
-            def _adopt_paged_fn(c, k, v, slot):
-                # Dense adoption: the padded tail past plen is junk the
-                # length-masked attention never reads.
-                out = {**c, "k": c["k"].at[:, slot, :k.shape[1]].set(k),
-                       "v": c["v"].at[:, slot, :k.shape[1]].set(v)}
-                return self._pin(out)
         self._adopt_upload = jax.jit(_adopt_paged_fn, donate_argnums=(0,))
-        if self.paged and b.enable_prefix_caching \
-                and b.prefix_index == "radix":
+        if b.enable_prefix_caching and b.prefix_index == "radix":
             # Tiered KV cache (serve/kvtier.py): token-block radix index
             # with live copy-on-write page sharing + optional host-RAM
             # overflow tier. The index is scheduler-confined like the
@@ -1238,29 +944,14 @@ class LLMEngine:
         self.decode_steps = max(1, int(b.decode_steps))
         self.prefill_interleave_steps = max(1, int(b.prefill_interleave_steps))
 
-        def _decode_fn(p, c, st, key, n, m, lr=None):
-            out, cache, tokens, lengths, live, budgets = _decode_multi(
-                p, c, st["tokens"], st["lengths"], st["live"], st["temps"],
-                st["top_k"], st["top_p"], st["stops"], st["budgets"], key,
-                cfg_decode, n, sample_mode=m,
-                lora=lr, adapter_idx=st["adapter"])
-            st = {**st, "tokens": tokens, "lengths": lengths, "live": live,
-                  "budgets": budgets}
-            return out, self._pin(cache), st
-
-        self._decode_n = jax.jit(_decode_fn, static_argnums=(4, 5),
-                                 donate_argnums=(1, 2))
         if on_tpu:
             # What the chip is given, per program variant, for
             # /debug/device (_introspected). Wrapped HERE, by name, so the
             # jit constructors above keep the exact shape `kftpu lint`'s
             # donation / dispatch-signature rules read.
-            for attr, name in (("_prefill", "prefill"),
-                               ("_prefill_chunk", "chunk_prefill"),
-                               ("_paged_chunk", "paged_chunk_prefill"),
+            for attr, name in (("_paged_chunk", "paged_chunk_prefill"),
                                ("_paged_chunks", "paged_chunk_prefill"),
-                               ("_paged_decode_n", "paged_decode"),
-                               ("_decode_n", "decode")):
+                               ("_paged_decode_n", "paged_decode")):
                 if hasattr(self, attr):
                     setattr(self, attr,
                             self._introspected(name, getattr(self, attr)))
@@ -1282,21 +973,13 @@ class LLMEngine:
                 raise ValueError(
                     "speculative decoding is not supported in mesh "
                     "(tensor-parallel) mode yet")
-            from kubeflow_tpu.serve.spec_decode import (
-                paged_verify_step, verify_step,
-            )
+            from kubeflow_tpu.serve.spec_decode import paged_verify_step
 
-            if self.paged:
-                self._verify = jax.jit(
-                    lambda p, c, t, l, lv: _pin2(
-                        paged_verify_step(p, c, t, l, lv, cfg_decode),
-                        self._pin),
-                    donate_argnums=(1,))
-            else:
-                self._verify = jax.jit(
-                    lambda p, c, t, l, lv: _pin2(
-                        verify_step(p, c, t, l, lv, cfg_decode), self._pin),
-                    donate_argnums=(1,))
+            self._verify = jax.jit(
+                lambda p, c, t, l, lv: _pin2(
+                    paged_verify_step(p, c, t, l, lv, cfg_decode),
+                    self._pin),
+                donate_argnums=(1,))
         if self.spec_mode == "draft_model":
             from kubeflow_tpu.models.config import preset as _preset
             from kubeflow_tpu.serve.spec_decode import draft_propose
@@ -1321,17 +1004,19 @@ class LLMEngine:
                     lambda x: (x.astype(wdt)
                                if jnp.issubdtype(x.dtype, jnp.floating)
                                else x), self._draft_params)
-            # The draft's own KV residency: a dense slot cache (the draft is
-            # small — that's the point — so slots × max_len of its few
-            # kv-heads is cheap even when the target pool is paged).
+            # The draft's own KV residency: a page pool of its own planes
+            # under the IDENTITY table (slot s owns pages s*mpp ..
+            # (s+1)*mpp-1 for good: no allocator, nothing to free). The
+            # draft is small, so slots x max_len of its few kv-heads is
+            # cheap, and it runs the pool's own decode step and chunk
+            # prefill.
             self._draft_cache = {  # lockfree: scheduler-confined
-                "k": jnp.zeros((dcfg.n_layers, self.num_slots, self.max_len,
-                                dcfg.n_kv_heads, dcfg.head_dim),
-                               dcfg.activation_dtype),
-                "v": jnp.zeros((dcfg.n_layers, self.num_slots, self.max_len,
-                                dcfg.n_kv_heads, dcfg.head_dim),
-                               dcfg.activation_dtype),
-            }
+                name: jnp.zeros((dcfg.n_layers, self.num_slots * self._mpp,
+                                 self.page_size, *trail), dt)
+                for name, trail, dt in pool_planes(dcfg)}
+            self._draft_cache["table"] = jnp.arange(
+                self.num_slots * self._mpp, dtype=jnp.int32).reshape(
+                    self.num_slots, self._mpp)
             # consumed-context pointer per slot: positions [0, pos) of the
             # TRUE sequence have valid draft KV; reset at (re-)admission
             self._draft_pos = [0] * self.num_slots  # lockfree: scheduler-confined
@@ -1339,17 +1024,16 @@ class LLMEngine:
                 lambda p, c, d, dl, dp, lv, n:
                 draft_propose(p, c, d, dl, dp, lv, dcfg, n),
                 static_argnums=(6,), donate_argnums=(1,))
-            self._draft_chunkfn = jax.jit(
-                lambda p, c, t, s, st, vl:
-                _chunk_prefill_step(p, c, t, s, st, dcfg, vl),
-                donate_argnums=(1,))
-            # Catch-up chunk size: the largest power-of-two <= 128 that
-            # divides max_len, so C-aligned chunk windows never cross the
-            # cache edge (the dynamic_update_slice clamp hazard).
-            c = min(128, self.max_len)
-            while c > 1 and self.max_len % c:
-                c //= 2
-            self._draft_chunk = max(c, 1)
+
+            def _draft_chunk_fn(p, c, t, s, st, vl):
+                # Catch-up: one chunk of slot ``s``'s context into its row
+                # of the identity table.
+                _, pool = paged_chunk_prefill(
+                    p, c, t, c["table"][s][None], st[None], vl[None], dcfg)
+                return {**pool, "table": c["table"]}
+
+            self._draft_chunkfn = jax.jit(_draft_chunk_fn,
+                                          donate_argnums=(1,))
 
         # Multi-tenant LoRA adapters (serve/lora.py): the registry owns
         # the packed per-target A/B device buffers and the LRU hot-load/
@@ -1375,8 +1059,7 @@ class LLMEngine:
         # device for the engine's lifetime; host scheduler events sync as
         # per-slot donated scatters, so steady-state rounds upload nothing
         # (the stats counters prove it).
-        self._dstate = DecodeState(
-            self.num_slots, mpp=self._mpp if self.paged else None)
+        self._dstate = DecodeState(self.num_slots, mpp=self._mpp)
         # Pipelined dispatch (double buffering): dispatch round N+1 before
         # consuming round N, keeping at most ONE unconsumed round in flight
         # while the host detokenizes/streams/reaps/admits. Staleness is one
@@ -1386,7 +1069,7 @@ class LLMEngine:
         self._rounds: list[_InflightRound] = []  # lockfree: scheduler-confined
         # First-token sampling batched per admit round: chunked-prefill
         # completions park here and one sampler dispatch + ONE host fetch
-        # serves them all (_sample_first_batch).
+        # serves them all (_flush_first_tokens).
         # lockfree: scheduler-confined
         self._pending_first: list[tuple[Request, int, int, jax.Array]] = []
         self._last_ready_t: Optional[float] = None  # lockfree: scheduler-confined
@@ -1472,8 +1155,7 @@ class LLMEngine:
         payload, the host tier's wire format and the speculative verify
         step are written over; a stack of more than one kind of layer is
         not one ``params["layers"]``, which those and the weight quantizer,
-        the adapter buffers, the contiguous cache and the mesh's sharding
-        walk."""
+        the adapter buffers and the mesh's sharding walk."""
         if not (cfg.is_latent or cfg.leading_dense_layers):
             return
         what = ("a latent (ckv) KV pool" if cfg.is_latent
@@ -1488,7 +1170,6 @@ class LLMEngine:
             f"lora.targets={list(b.lora.targets)} (LoRA targets that do "
             "not exist in this block)": bool(b.lora.max_adapters),
             "quantize=int8 (weight quantization)": b.quantize is not None,
-            "paged=False (the contiguous slot cache)": not b.paged,
             "a mesh (tensor-parallel serving)": self.mesh is not None,
         }
         hit = [name for name, on in refused.items() if on]
@@ -1570,21 +1251,21 @@ class LLMEngine:
                    for r in list(self.waiting.queue) + list(self._backlog))
 
     def kv_pages_in_use(self) -> int:
-        """RESIDENT-REFERENCED paged-KV pages — pages live requests hold
-        references to right now (0 for the contiguous cache). Cached
+        """RESIDENT-REFERENCED KV pages — pages live requests hold
+        references to right now. Cached
         ref-0 prefix content is deliberately excluded: it is freely
         evictable, so it is capacity, not load (the decode router's
         placement signal must not count it). The chaos-suite invariant:
         quiescent engine -> 0 — every reap/finish path freed exactly
         what admission allocated."""
-        return 0 if self._allocator is None else self._allocator.in_use()
+        return self._allocator.in_use()
 
     def kv_pages_cached(self) -> int:
         """Ref-0 pages still holding reusable prefix content (the
         reclaimable LRU) — the freely-evictable half of the old
         ``resident`` notion, split out so dashboards and the router can
         tell load from cache."""
-        return 0 if self._allocator is None else self._allocator.cached()
+        return self._allocator.cached()
 
     def kv_pages_host(self) -> int:
         """Pages resident in the host-RAM overflow tier (0 when the
@@ -1616,19 +1297,16 @@ class LLMEngine:
         return self._kvtier.spill_all_to_remote(timeout_s)
 
     def kv_tier_stats(self) -> dict:
-        """Radix/tier counters (empty dict on flat/contiguous engines):
+        """Radix/tier counters (empty dict under the flat prefix index):
         hits, matched/COW token counts, demotions/promotions, host
         occupancy — the /metrics tier series' source."""
         return {} if self._kvtier is None else self._kvtier.snapshot()
 
     def kv_pool_density(self) -> dict:
-        """Paged-pool capacity accounting (empty dict on contiguous
-        engines): token capacity, pool HBM bytes (int8 payload + scale
-        rows when quantized), and tokens-per-MiB — the density series
-        the int8-KV HBM claim (~1.9x resident tokens at equal HBM) is
-        measured from."""
-        if not self.paged:
-            return {}
+        """Page-pool capacity accounting: token capacity, pool HBM bytes
+        (int8 payload + scale rows when quantized), and tokens-per-MiB —
+        the density series the int8-KV HBM claim (~1.9x resident tokens at
+        equal HBM) is measured from."""
         pool_bytes = self._kv_pool_bytes
         tokens = self._num_pages * self.page_size
         return {
@@ -1801,15 +1479,8 @@ class LLMEngine:
 
     # -- scheduler -------------------------------------------------------------
 
-    def _bucket_for(self, n: int) -> int:
-        for bkt in self.buckets:
-            if n <= bkt:
-                return bkt
-        return self.max_len
-
-    def _free_slot(self, extra_reserved: frozenset = frozenset()
-                   ) -> Optional[int]:
-        reserved = {ch.slot for ch in self._chunkings} | extra_reserved \
+    def _free_slot(self) -> Optional[int]:
+        reserved = {ch.slot for ch in self._chunkings} \
             | {slot for _, slot, _, _ in self._pending_first}
         for i, s in enumerate(self.slots):
             if s is None and i not in reserved:
@@ -1820,40 +1491,23 @@ class LLMEngine:
         self._rng, k = jax.random.split(self._rng)
         return k
 
-    def _start_first_token(self, req: Request, slot_idx: int, plen: int,
-                           last_logits: jax.Array) -> None:
-        """Park a finished prefill's first-token sampling until the end of
-        the admit pass: one stalled per-request ``device_get`` here used to
-        serialize every admission behind it — now every admission in the
-        round shares ONE sampler dispatch + ONE fetch
-        (``_flush_first_tokens``). The slot stays reserved via
-        ``_pending_first`` until the flush admits into it."""
-        self._pending_first.append((req, slot_idx, plen, last_logits))
-
-    def _flush_first_tokens(self) -> int:
-        """Sample + fetch every pending first token in one batch."""
+    def _flush_first_tokens(self) -> None:
+        """ONE sampler dispatch + ONE host fetch for the first tokens of
+        every prefill the admit pass finished (``_pending_first``, which
+        keeps their slots reserved until here), then admit each request
+        into its slot: a ``device_get`` a request would serialize every
+        admission behind it. The rows stack here, padded to the next power
+        of two so the sampler trace set stays log-bounded."""
         if not self._pending_first:
-            return 0
+            return
         items, self._pending_first = self._pending_first, []
-        self._sample_first_batch(items)
-        return len(items)
-
-    def _sample_first_batch(self, items,
-                            stacked: Optional[jax.Array] = None) -> None:
-        """ONE sampler dispatch + ONE host fetch for a batch of first
-        tokens, then admit each request into its slot. ``stacked`` is a
-        pre-batched [N, V] logits block (the grouped-prefill path);
-        otherwise individual rows stack here, padded to the next power of
-        two so the sampler trace set stays log-bounded."""
         n = len(items)
         with hot_span(prof.ENGINE_SAMPLE_FIRST, n=n):
-            if stacked is None:
-                width = 1
-                while width < n:
-                    width *= 2
-                stacked = jnp.stack(
-                    [it[3] for it in items] + [items[-1][3]] * (width - n))
-            width = stacked.shape[0]
+            width = 1
+            while width < n:
+                width *= 2
+            stacked = jnp.stack(
+                [it[3] for it in items] + [items[-1][3]] * (width - n))
             params_list = [it[0].params for it in items]
             padded = params_list + [SamplingParams()] * (width - n)
             firsts = self._sampler(
@@ -1895,8 +1549,8 @@ class LLMEngine:
                                      last_token=tok,
                                      generated=len(req.output_tokens),
                                      admit_seq=next(self._admit_seq))
-        # New occupant: its device-resident decode state (and, in paged
-        # mode, its page-table row) sync as deltas at the next dispatch.
+        # New occupant: its device-resident decode state and its
+        # page-table row sync as deltas at the next dispatch.
         self._dstate.mark_slot(slot_idx)
         self._dstate.mark_row(slot_idx)
         if self._draft_cfg is not None:
@@ -1910,7 +1564,7 @@ class LLMEngine:
             self._export_handoff(slot_idx)
 
     def _reserve_chunk_pages(self, ch: "_Chunking") -> bool:
-        """Pages for ``ch``'s next chunk (paged mode). False when page-pool
+        """Pages for ``ch``'s next chunk. False when page-pool
         pressure defers the chunk to a later step; the other in-flight
         prefills go on without it."""
         req, slot_idx = ch.request, ch.slot
@@ -1970,8 +1624,18 @@ class LLMEngine:
                     jnp.asarray(table), jnp.asarray(start),
                     jnp.asarray(valid), self._mpp, *lora)
             else:
-                logits = self._dispatch_one_chunk(group[0], chunk, reals[0],
-                                                  lora)
+                # Static context bucket (next power of two covering the
+                # pages this chunk can see): chunk cost tracks its position,
+                # not max_len, with a log-bounded trace set. The chunk's
+                # writes address per token off the table row, so the
+                # position may sit mid-page (the radix COW tail resume).
+                ch = group[0]
+                logits, self.cache = self._paged_chunk(
+                    self.params, self.cache, jnp.asarray(chunk),
+                    jnp.asarray(self._table[ch.slot]), jnp.int32(ch.pos),
+                    jnp.int32(reals[0]),
+                    context_bucket(ch.pos, C, self.page_size, self._mpp),
+                    *lora)
         self._prefill_programs_dispatched += 1
         self._prefill_chunks_dispatched += len(group)
         self._prefill_tokens_dispatched += sum(reals)
@@ -1981,41 +1645,15 @@ class LLMEngine:
             if ch.pos < plen:
                 continue
             self._chunkings.remove(ch)
-            if self.paged:
-                # Index the prompt's KV for cross-request reuse — LIVE:
-                # the owner keeps decoding while sharers match through
-                # these pages (decode writes start at plen, past every
-                # claimed position — COW by construction).
-                self._kv_register(req.prompt_tokens, ch.slot, plen)
+            # Index the prompt's KV for cross-request reuse — LIVE: the
+            # owner keeps decoding while sharers match through these pages
+            # (decode writes start at plen, past every claimed position —
+            # COW by construction).
+            self._kv_register(req.prompt_tokens, ch.slot, plen)
             # Logits index of the prompt's true last token in this chunk.
-            self._start_first_token(
-                req, ch.slot, plen,
-                logits[r, real - 1] if rows > 1 else logits[real - 1])
-
-    def _dispatch_one_chunk(self, ch: "_Chunking", chunk: np.ndarray,
-                            real: int, lora: tuple) -> jax.Array:
-        """The one-row chunk program, paged or contiguous: [C,V] logits."""
-        slot_idx = ch.slot
-        if self.paged:
-            # Static context bucket (next power of two covering the pages
-            # this chunk can see): chunk cost tracks ch.pos, not max_len,
-            # with a log-bounded trace set. The chunk's writes address
-            # per token off the table row, so ch.pos may sit mid-page
-            # (the radix COW tail resume).
-            from kubeflow_tpu.serve.paged import context_bucket
-
-            ctx = context_bucket(ch.pos, self.chunk_size, self.page_size,
-                                 self._mpp)
-            logits, self.cache = self._paged_chunk(
-                self.params, self.cache, jnp.asarray(chunk),
-                jnp.asarray(self._table[slot_idx]), jnp.int32(ch.pos),
-                jnp.int32(real), ctx, *lora)
-        else:
-            logits, self.cache = self._prefill_chunk(
-                self.params, self.cache, jnp.asarray(chunk),
-                jnp.int32(slot_idx), jnp.int32(ch.pos), jnp.int32(real),
-                *lora)
-        return logits
+            self._pending_first.append(
+                (req, ch.slot, plen,
+                 logits[r, real - 1] if rows > 1 else logits[real - 1]))
 
     def _advance_chunked(self, due: "Optional[list[_Chunking]]" = None) -> int:
         """One chunk of every in-flight chunked prefill in ``due`` (all of
@@ -2026,7 +1664,7 @@ class LLMEngine:
         prefill whose pages cannot be had waits for a later pass and holds
         nobody back. Returns the chunks dispatched."""
         ready = [ch for ch in (list(self._chunkings) if due is None else due)
-                 if not self.paged or self._reserve_chunk_pages(ch)]
+                 if self._reserve_chunk_pages(ch)]
         for i in range(0, len(ready), self._chunk_rows):
             self._dispatch_chunks(ready[i:i + self._chunk_rows])
         return len(ready)
@@ -2108,8 +1746,7 @@ class LLMEngine:
         for rid, (hreq, pages) in list(self._handoff_holds.items()):
             if hreq.abandon_reason(now):
                 del self._handoff_holds[rid]
-                if self._allocator is not None:
-                    self._allocator.free(pages)
+                self._allocator.free(pages)
                 self.metrics.note_handoff("failed")
                 n += 1
         for lane in (self._preempted, self._backlog):
@@ -2162,20 +1799,17 @@ class LLMEngine:
         """Next request the scheduler may start: STRICT PRIORITY across QoS
         classes (QOS_PRIORITY order), FIFO within a class.
 
-        Within each class the preempted lane resumes first, and — paged —
-        only once the pool can hold its entire remaining run; while one
-        waits, nothing at its class or below is admitted (the livelock
+        Within each class the preempted lane resumes first, and only once
+        the pool can hold its entire remaining run; while one waits,
+        nothing at its class or below is admitted (the livelock
         backpressure, scoped per class so a higher-class arrival can still
-        jump a starved batch resume). Fresh paged requests need room for
-        their prompt plus one growth page. Single-class traffic reduces to
+        jump a starved batch resume). Fresh requests need room for their
+        prompt plus one growth page. Single-class traffic reduces to
         the pre-QoS behavior exactly."""
         self._drain_waiting()
         for cls in sorted(QOS_PRIORITY, key=QOS_PRIORITY.get):
             pre = next((r for r in self._preempted if r.qos == cls), None)
             if pre is not None:
-                if not self.paged:
-                    self._preempted.remove(pre)
-                    return pre
                 remaining = max(pre.params.max_new_tokens
                                 - len(pre.output_tokens), 0)
                 if self._allocator.available() >= self._pages_for(
@@ -2186,7 +1820,7 @@ class LLMEngine:
             req = next((r for r in self._backlog if r.qos == cls), None)
             if req is None:
                 continue
-            if self.paged and self._allocator.available() < self._pages_for(
+            if self._allocator.available() < self._pages_for(
                     len(req.prompt_tokens)) + 1:
                 return None          # head-of-line within the priority order
             self._backlog.remove(req)
@@ -2196,11 +1830,10 @@ class LLMEngine:
     def _admit(self) -> int:
         """Prefill waiting requests into free slots. Returns admissions.
 
-        One-shot admissions accumulate into same-bucket groups and flush as
-        batched prefill dispatches (``prefill_batch_max``). Chunked
-        prefills (every paged admission) are admitted FIRST and then every
-        in-flight one advances by one chunk, together where the engine has
-        the program for it (``_advance_chunked``): a pass above the knee
+        Waiting requests are admitted FIRST (each joins ``_chunkings``) and
+        then every in-flight prefill advances by one chunk, together where
+        the engine has the program for it (``_advance_chunked``): a pass
+        above the knee
         carries its prefills' chunks in one program, and not the older
         ones' and then the newcomer's. A prefill that finished leaves its
         lane to the next waiting request within the pass, as it always did
@@ -2208,10 +1841,9 @@ class LLMEngine:
         the newcomers then run their first chunk, and so on until no lane
         comes free."""
         n = 0
-        pending: list[tuple[Request, int, int, int]] = []   # req, slot, plen, bucket
         advanced: list[_Chunking] = []      # had their chunk of this pass
         while True:
-            n += self._admit_waiting(pending)
+            n += self._admit_waiting()
             due = [ch for ch in self._chunkings
                    if not any(ch is done for done in advanced)]
             if not due:
@@ -2221,9 +1853,8 @@ class LLMEngine:
             n += self._advance_chunked(due)
             if len(self._chunkings) == lanes:
                 break
-        n += self._flush_prefills(pending)
-        # Chunked-prefill completions parked by _start_first_token: one
-        # batched sampler dispatch + one fetch for the whole admit round.
+        # The pass's finished prefills: one batched sampler dispatch + one
+        # fetch for the whole admit round.
         self._flush_first_tokens()
         # Prefill-role exports queued this round: one batched KV fetch.
         self._flush_handoffs()
@@ -2233,22 +1864,19 @@ class LLMEngine:
             self._last_ready_t = None
         return n
 
-    def _admit_waiting(self, pending: list) -> int:
-        """Give free slots and prefill lanes to waiting requests: chunked
-        prefills (every paged admission) join ``_chunkings``, to be
-        advanced by the caller; one-shot prompts join ``pending``; handed-
-        off requests are adopted here. Returns the adoptions."""
+    def _admit_waiting(self) -> int:
+        """Give free slots and prefill lanes to waiting requests: a prompt
+        joins ``_chunkings``, to be advanced by the caller; a handed-off
+        request is adopted here. Returns the adoptions."""
         n = 0
         while True:
-            if len(self._chunkings) >= self.max_concurrent_prefills \
-                    and self.paged:
+            if len(self._chunkings) >= self.max_concurrent_prefills:
                 # Chunking slots exhausted: a strictly higher-class
                 # arrival may evict the lowest-class in-flight chunking
                 # (cross-class chunking preemption) and take its slot.
                 if not self._maybe_preempt_chunking_for_priority():
                     break
-            slot_idx = self._free_slot(
-                frozenset(p[1] for p in pending))
+            slot_idx = self._free_slot()
             if slot_idx is None:
                 # Slots exhausted: a strictly higher-class arrival may
                 # recompute-preempt the lowest running class's youngest
@@ -2272,142 +1900,38 @@ class LLMEngine:
                 # and stop admitting until one drains (the page-
                 # exhaustion discipline, for the adapter buffer).
                 break
-            if self.paged:
-                # Paged admission is always chunked; the prefix index
-                # trims the work to the uncached tail (radix: live COW
-                # sharing, host-tier promotion, sub-page resume).
-                pages, covered = self._kv_match(req)
-                if req.trace_parent is not None:
-                    _span_close(req)       # queued →
-                    if adapter_hot:
-                        # The admission hot-loaded its adapter: surface
-                        # the registry pull + packed-buffer scatter as a
-                        # first-class phase on the trace.
-                        _span_open(req, "engine.adapter_load",
-                                   adapter=req.adapter)
-                        _span_close(req)
-                    tier = self._kvtier
-                    if tier is not None and (tier.last_promoted
-                                             or tier.last_cow_tokens):
-                        # Promotion/COW rode this admission: surface it
-                        # as a first-class (near-instant — the transfers
-                        # are async-enqueued) phase on the trace.
-                        _span_open(req, "engine.kv_migrate",
-                                   promoted_pages=tier.last_promoted,
-                                   cow_tokens=tier.last_cow_tokens)
-                        _span_close(req)
-                    _span_open(req, "engine.prefill",
-                               cached_tokens=covered)
-                self._release_slot_pages(slot_idx)
-                self._slot_pages[slot_idx] = list(pages)
-                self._table[slot_idx, :] = -1
-                self._table[slot_idx, :len(pages)] = pages
-                self._dstate.mark_row(slot_idx)
-                self._chunkings.append(_Chunking(req, slot_idx, covered))
-                continue
+            # The prefix index trims the work to the uncached tail (radix:
+            # live COW sharing, host-tier promotion, sub-page resume).
+            pages, covered = self._kv_match(req)
             if req.trace_parent is not None:
                 # queued → prefill (covers both fresh admissions and
                 # preempted-lane resumes, which skip _note_admitted).
                 _span_close(req)
                 if adapter_hot:
+                    # The admission hot-loaded its adapter: surface
+                    # the registry pull + packed-buffer scatter as a
+                    # first-class phase on the trace.
                     _span_open(req, "engine.adapter_load",
                                adapter=req.adapter)
                     _span_close(req)
-                _span_open(req, "engine.prefill")
-            plen = len(req.prompt_tokens)
-            C = self.chunk_size
-            if C and plen > C and -(-plen // C) * C <= self.max_len \
-                    and len(self._chunkings) < self.max_concurrent_prefills:
-                # Long prompt: chunked path — _free_slot holds this slot
-                # while chunks stream across scheduler steps. Guard:
-                # every C-wide window must fit inside max_len, else the
-                # final chunk's dynamic_update_slice would clamp and
-                # overwrite earlier KV (fall through to one-shot
-                # prefill instead).
-                self._chunkings.append(_Chunking(req, slot_idx, 0))
-                continue
-            pending.append((req, slot_idx,
-                            plen, self._bucket_for(plen)))
+                tier = self._kvtier
+                if tier is not None and (tier.last_promoted
+                                         or tier.last_cow_tokens):
+                    # Promotion/COW rode this admission: surface it
+                    # as a first-class (near-instant — the transfers
+                    # are async-enqueued) phase on the trace.
+                    _span_open(req, "engine.kv_migrate",
+                               promoted_pages=tier.last_promoted,
+                               cow_tokens=tier.last_cow_tokens)
+                    _span_close(req)
+                _span_open(req, "engine.prefill", cached_tokens=covered)
+            self._release_slot_pages(slot_idx)
+            self._slot_pages[slot_idx] = list(pages)
+            self._table[slot_idx, :] = -1
+            self._table[slot_idx, :len(pages)] = pages
+            self._dstate.mark_row(slot_idx)
+            self._chunkings.append(_Chunking(req, slot_idx, covered))
         return n
-
-    def _flush_prefills(self, pending) -> int:
-        """Dispatch accumulated one-shot admissions, same-bucket groups in
-        power-of-two sizes (p2 keeps the trace set at log(batch_max) per
-        bucket) capped by ``prefill_batch_max`` AND the transient-HBM token
-        budget (group_size × bucket ≤ budget). First tokens sample in ONE
-        batched sampler dispatch + ONE fetch per group — serializing N
-        sampler round-trips here would hand back the amortization the
-        grouped prefill just bought.
-
-        Exception safety (ADVICE r5): the requests here were already popped
-        off the backlog — a mid-flush failure (e.g. OOM on a large group)
-        must not silently drop the rest. The failing group's requests fail
-        loudly (their callers see finish_reason="error"); every not-yet-
-        dispatched request goes back to the FRONT of the backlog in
-        arrival order."""
-        n = 0
-        by_bucket: dict[int, list] = {}
-        for item in pending:
-            by_bucket.setdefault(item[3], []).append(item)
-        remaining = {id(item): item for item in pending}
-        for bucket, items in by_bucket.items():
-            cap = self.prefill_batch_max
-            if self.prefill_batch_token_budget:
-                cap = min(cap, max(1,
-                                   self.prefill_batch_token_budget // bucket))
-            i = 0
-            while i < len(items):
-                take = 1
-                while take * 2 <= cap and i + take * 2 <= len(items):
-                    take *= 2
-                group = items[i:i + take]
-                i += take
-                toks = np.zeros((take, bucket), np.int32)
-                slots = np.zeros((take,), np.int32)
-                plens = np.zeros((take,), np.int32)
-                aidxs = np.full((take,), -1, np.int32)
-                for j, (req, slot_idx, plen, _) in enumerate(group):
-                    toks[j, :plen] = req.prompt_tokens
-                    slots[j] = slot_idx
-                    plens[j] = plen
-                    aidxs[j] = self._slot_aidx[slot_idx]
-                try:
-                    if self._lora is not None:
-                        last_logits, self.cache = self._prefill(
-                            self.params, self.cache, jnp.asarray(toks),
-                            jnp.asarray(slots), jnp.asarray(plens),
-                            self._lora.buffers, jnp.asarray(aidxs))
-                    else:
-                        last_logits, self.cache = self._prefill(
-                            self.params, self.cache, jnp.asarray(toks),
-                            jnp.asarray(slots), jnp.asarray(plens))
-                    self._sample_first_batch(
-                        [(req, slot_idx, plen, None)
-                         for req, slot_idx, plen, _ in group],
-                        stacked=last_logits)
-                except Exception:
-                    for item in group:
-                        remaining.pop(id(item), None)
-                    self._fail_flush(group, list(remaining.values()))
-                    raise
-                for item in group:
-                    remaining.pop(id(item), None)
-                n += len(group)
-        return n
-
-    def _fail_flush(self, failed_group, requeue_items) -> None:
-        """Mid-flush failure cleanup: fail the dispatched-but-broken group's
-        requests (their engine-side state is unknown — retrying could
-        double-write KV) and requeue everything never dispatched."""
-        for req, slot_idx, _, _ in failed_group:
-            self._release_slot_adapter(slot_idx)
-            self._fail_request(req, "error")
-        # FRONT of the backlog, original arrival order: they were admitted
-        # once already — nothing may overtake them now (re-admission
-        # re-acquires their adapter references, released here).
-        for item in requeue_items:
-            self._release_slot_adapter(item[1])
-        self._backlog[:0] = [item[0] for item in requeue_items]
 
     # -- disaggregated handoff (serve/handoff.py) ------------------------------
 
@@ -2415,40 +1939,36 @@ class LLMEngine:
         """Queue one just-prefilled slot's KV for export: enqueue the
         device-side gather now (program order guarantees it reads the
         pre-overwrite values even if a later admission reuses the slot),
-        fetch batched in ``_flush_handoffs``. Paged ownership moves to
+        fetch batched in ``_flush_handoffs``. The pages' ownership moves to
         the ack hold; the slot frees either way."""
         s = self.slots[slot_idx]
         req = s.request
         plen = s.length
         sk_dev = sv_dev = None
-        if self.paged:
-            pages = self._slot_pages[slot_idx]
-            need = -(-plen // self.page_size)
-            ids = jnp.asarray(np.asarray(pages[:need], np.int32))
-            k_dev = self.cache["k"][:, ids].reshape(
+        pages = self._slot_pages[slot_idx]
+        need = -(-plen // self.page_size)
+        ids = jnp.asarray(np.asarray(pages[:need], np.int32))
+        k_dev = self.cache["k"][:, ids].reshape(
+            self.cfg.n_layers, need * self.page_size,
+            self.cfg.n_kv_heads, self.cfg.head_dim)
+        v_dev = self.cache["v"][:, ids].reshape(
+            self.cfg.n_layers, need * self.page_size,
+            self.cfg.n_kv_heads, self.cfg.head_dim)
+        if self.kv_quant:
+            # int8 pool: the per-token-per-head scale rows ride the
+            # same enqueued gather (wire v2 ships them alongside).
+            sk_dev = self.cache["ks"][:, ids].reshape(
                 self.cfg.n_layers, need * self.page_size,
-                self.cfg.n_kv_heads, self.cfg.head_dim)
-            v_dev = self.cache["v"][:, ids].reshape(
+                self.cfg.n_kv_heads)
+            sv_dev = self.cache["vs"][:, ids].reshape(
                 self.cfg.n_layers, need * self.page_size,
-                self.cfg.n_kv_heads, self.cfg.head_dim)
-            if self.kv_quant:
-                # int8 pool: the per-token-per-head scale rows ride the
-                # same enqueued gather (wire v2 ships them alongside).
-                sk_dev = self.cache["ks"][:, ids].reshape(
-                    self.cfg.n_layers, need * self.page_size,
-                    self.cfg.n_kv_heads)
-                sv_dev = self.cache["vs"][:, ids].reshape(
-                    self.cfg.n_layers, need * self.page_size,
-                    self.cfg.n_kv_heads)
-            # Ownership transfer: the slot's page refs back the payload
-            # until the decode side acks — NOT freed, NOT on the table.
-            self._handoff_holds[req.id] = (req, pages)
-            self._slot_pages[slot_idx] = []
-            self._table[slot_idx, :] = -1
-            self._dstate.mark_row(slot_idx)
-        else:
-            k_dev = self.cache["k"][:, slot_idx]
-            v_dev = self.cache["v"][:, slot_idx]
+                self.cfg.n_kv_heads)
+        # Ownership transfer: the slot's page refs back the payload
+        # until the decode side acks — NOT freed, NOT on the table.
+        self._handoff_holds[req.id] = (req, pages)
+        self._slot_pages[slot_idx] = []
+        self._table[slot_idx, :] = -1
+        self._dstate.mark_row(slot_idx)
         self.slots[slot_idx] = None
         self._dstate.mark_slot(slot_idx)
         self._pending_exports.append((req, k_dev, v_dev, sk_dev, sv_dev,
@@ -2503,78 +2023,64 @@ class LLMEngine:
             p.kv_scale_k, np.float32)
         kv_sv = None if p.kv_scale_v is None else np.asarray(
             p.kv_scale_v, np.float32)
-        if self.paged:
-            pg = self.page_size
-            need = -(-plen // pg)
-            self._release_slot_pages(slot_idx)
-            # Cross-request reuse ACROSS the handoff boundary: pages this
-            # decode pool already holds for the prompt's prefix are
-            # adopted by reference — only the uncovered tail uploads.
-            # Page-aligned match (no COW tail): the upload below is
-            # page-granular.
-            hit, start = self._kv_match(req, allow_cow=False)
-            fresh = self._allocator.alloc(need - len(hit), owner=req.id)
-            try:
-                pages = list(hit) + fresh
-                n2 = 1
-                while n2 < len(fresh):
-                    n2 *= 2
-                buf_k = np.zeros((cfg.n_layers, n2 * pg, cfg.n_kv_heads,
-                                  cfg.head_dim), dt)
-                buf_v = np.zeros_like(buf_k)
-                buf_k[:, :plen - start] = kv_k[:, start:plen]
-                buf_v[:, :plen - start] = kv_v[:, start:plen]
-                shape5 = (cfg.n_layers, n2, pg, cfg.n_kv_heads,
-                          cfg.head_dim)
-                pidx = np.full((n2,), self._num_pages, np.int32)
-                pidx[:len(fresh)] = fresh
-                if self.kv_quant:
-                    # Adoption rebuilds pages AND scales: the payload's
-                    # scale rows scatter into the same fresh pages.
-                    buf_sk = np.zeros(
-                        (cfg.n_layers, n2 * pg, cfg.n_kv_heads), np.float32)
-                    buf_sv = np.zeros_like(buf_sk)
-                    buf_sk[:, :plen - start] = kv_sk[:, start:plen]
-                    buf_sv[:, :plen - start] = kv_sv[:, start:plen]
-                    shape4 = (cfg.n_layers, n2, pg, cfg.n_kv_heads)
-                    self.cache = self._adopt_upload(
-                        self.cache, jnp.asarray(buf_k.reshape(shape5)),
-                        jnp.asarray(buf_v.reshape(shape5)),
-                        jnp.asarray(buf_sk.reshape(shape4)),
-                        jnp.asarray(buf_sv.reshape(shape4)),
-                        jnp.asarray(pidx))
-                else:
-                    self.cache = self._adopt_upload(
-                        self.cache, jnp.asarray(buf_k.reshape(shape5)),
-                        jnp.asarray(buf_v.reshape(shape5)),
-                        jnp.asarray(pidx))
-            except Exception:
-                # A failed upload must not strand the refs just taken —
-                # the request fails loudly, the pool stays balanced.
-                self._allocator.free(fresh)
-                self._allocator.free(hit)
-                raise
-            self._slot_pages[slot_idx] = list(pages)
-            self._table[slot_idx, :] = -1
-            self._table[slot_idx, :need] = pages
-            self._dstate.mark_row(slot_idx)
-            # The adopted pages hold full-prefix KV — index them so
-            # same-prefix traffic landing on this decode engine reuses
-            # them (decode writes start at plen, never touching these).
-            self._kv_register(p.prompt_tokens, slot_idx, plen)
-        else:
-            width = 1
-            while width < plen:
-                width *= 2
-            width = min(width, self.max_len)
-            buf_k = np.zeros((cfg.n_layers, width, cfg.n_kv_heads,
+        pg = self.page_size
+        need = -(-plen // pg)
+        self._release_slot_pages(slot_idx)
+        # Cross-request reuse ACROSS the handoff boundary: pages this
+        # decode pool already holds for the prompt's prefix are
+        # adopted by reference — only the uncovered tail uploads.
+        # Page-aligned match (no COW tail): the upload below is
+        # page-granular.
+        hit, start = self._kv_match(req, allow_cow=False)
+        fresh = self._allocator.alloc(need - len(hit), owner=req.id)
+        try:
+            pages = list(hit) + fresh
+            n2 = 1
+            while n2 < len(fresh):
+                n2 *= 2
+            buf_k = np.zeros((cfg.n_layers, n2 * pg, cfg.n_kv_heads,
                               cfg.head_dim), dt)
             buf_v = np.zeros_like(buf_k)
-            buf_k[:, :plen] = kv_k
-            buf_v[:, :plen] = kv_v
-            self.cache = self._adopt_upload(
-                self.cache, jnp.asarray(buf_k), jnp.asarray(buf_v),
-                jnp.int32(slot_idx))
+            buf_k[:, :plen - start] = kv_k[:, start:plen]
+            buf_v[:, :plen - start] = kv_v[:, start:plen]
+            shape5 = (cfg.n_layers, n2, pg, cfg.n_kv_heads,
+                      cfg.head_dim)
+            pidx = np.full((n2,), self._num_pages, np.int32)
+            pidx[:len(fresh)] = fresh
+            if self.kv_quant:
+                # Adoption rebuilds pages AND scales: the payload's
+                # scale rows scatter into the same fresh pages.
+                buf_sk = np.zeros(
+                    (cfg.n_layers, n2 * pg, cfg.n_kv_heads), np.float32)
+                buf_sv = np.zeros_like(buf_sk)
+                buf_sk[:, :plen - start] = kv_sk[:, start:plen]
+                buf_sv[:, :plen - start] = kv_sv[:, start:plen]
+                shape4 = (cfg.n_layers, n2, pg, cfg.n_kv_heads)
+                self.cache = self._adopt_upload(
+                    self.cache, jnp.asarray(buf_k.reshape(shape5)),
+                    jnp.asarray(buf_v.reshape(shape5)),
+                    jnp.asarray(buf_sk.reshape(shape4)),
+                    jnp.asarray(buf_sv.reshape(shape4)),
+                    jnp.asarray(pidx))
+            else:
+                self.cache = self._adopt_upload(
+                    self.cache, jnp.asarray(buf_k.reshape(shape5)),
+                    jnp.asarray(buf_v.reshape(shape5)),
+                    jnp.asarray(pidx))
+        except Exception:
+            # A failed upload must not strand the refs just taken —
+            # the request fails loudly, the pool stays balanced.
+            self._allocator.free(fresh)
+            self._allocator.free(hit)
+            raise
+        self._slot_pages[slot_idx] = list(pages)
+        self._table[slot_idx, :] = -1
+        self._table[slot_idx, :need] = pages
+        self._dstate.mark_row(slot_idx)
+        # The adopted pages hold full-prefix KV — index them so
+        # same-prefix traffic landing on this decode engine reuses
+        # them (decode writes start at plen, never touching these).
+        self._kv_register(p.prompt_tokens, slot_idx, plen)
         self.slots[slot_idx] = _Slot(request=req, length=plen,
                                      last_token=p.first_token,
                                      generated=0,
@@ -2596,7 +2102,7 @@ class LLMEngine:
             except queue.Empty:
                 break
             hold = self._handoff_holds.pop(rid, None)
-            if hold is not None and self._allocator is not None:
+            if hold is not None:
                 self._allocator.free(hold[1])
             if not ok:
                 self.metrics.note_handoff("failed")
@@ -2700,7 +2206,7 @@ class LLMEngine:
         reuse (radix) or hash the full-page prompt prefix (flat) — in
         the slot occupant's adapter NAMESPACE: KV content is a function
         of (tokens, model variant), so tenants never share pages."""
-        if self._allocator is None or n_tokens <= 0:
+        if n_tokens <= 0:
             return
         ns = self._slot_namespace(slot_idx)
         if self._kvtier is not None:
@@ -2817,8 +2323,6 @@ class LLMEngine:
 
     def _ensure_pages(self, slot_idx: int, upto: int) -> bool:
         """Grow ``slot_idx``'s page list to cover positions [0, upto)."""
-        from kubeflow_tpu.serve.paged import PagePoolExhausted
-
         need = min(-(-upto // self.page_size), self._mpp)
         have = len(self._slot_pages[slot_idx])
         if need <= have:
@@ -2834,7 +2338,7 @@ class LLMEngine:
         return True
 
     def _release_slot_pages(self, idx: int) -> None:
-        if self._allocator is not None and self._slot_pages[idx]:
+        if self._slot_pages[idx]:
             # Leaf-first (reversed) release: indexed pages enter the
             # reclaimable LRU children-before-parents, so pool-pressure
             # eviction trims cached subtrees from the leaves instead of
@@ -2918,7 +2422,7 @@ class LLMEngine:
         if req.trace_parent is not None:
             _span_close(req, preempted=True, chunked=True)
             _span_open(req, "engine.queued", requeued=True)
-        if self.paged and self._allocator is not None and ch.pos:
+        if ch.pos:
             # The written chunks hold real prefix KV — index them so
             # the resume's match skips the rework (freed pages linger
             # reclaimable until the pool needs them; the radix index
@@ -2975,15 +2479,14 @@ class LLMEngine:
         req.stream.put(None)
         req.done.set()
         self.metrics.observe(req)
-        if self.paged:
-            if self._kvtier is not None:
-                # Conversation reuse: index prompt + generated tokens
-                # (the last emitted token's KV is not written — valid
-                # content is ctx[:s.length]) before the pages release,
-                # so the next turn of this conversation matches straight
-                # through prompt AND history, partial tail included.
-                self._kv_register(self._context_tokens(s), idx, s.length)
-            self._release_slot_pages(idx)
+        if self._kvtier is not None:
+            # Conversation reuse: index prompt + generated tokens
+            # (the last emitted token's KV is not written — valid
+            # content is ctx[:s.length]) before the pages release,
+            # so the next turn of this conversation matches straight
+            # through prompt AND history, partial tail included.
+            self._kv_register(self._context_tokens(s), idx, s.length)
+        self._release_slot_pages(idx)
         self._release_slot_adapter(idx)
         self.slots[idx] = None
         return True
@@ -3037,13 +2540,13 @@ class LLMEngine:
         and sync nothing — the zero-upload invariant."""
         if self._dstate.dirty_slots:
             self._dstate.sync_slots(self._slot_state_values)
-        if self.paged and self._dstate.dirty_rows:
+        if self._dstate.dirty_rows:
             self._dstate.sync_rows(lambda i: self._table[i])
 
     def _dispatch_round(self, active) -> bool:  # hot-loop
         """Enqueue one multi-step decode dispatch over the device-resident
         state (no host blocking — JAX async dispatch). Returns False when
-        paged pool pressure preempted every candidate slot."""
+        page-pool pressure preempted every candidate slot."""
         # While a chunked prefill is in flight, decode still multi-steps —
         # just with a smaller K: hard-capping at 1 let concurrent paged
         # traffic (where EVERY admission chunks) pay a full dispatch
@@ -3055,30 +2558,29 @@ class LLMEngine:
         # past the host's slot lengths — page pre-allocation must cover
         # the stale window too or a mid-dispatch write lands unmapped.
         slack = sum(r.k_steps for r in self._rounds)
-        if self.paged:
-            # Pre-allocate pages covering every live slot's next k_steps
-            # write positions (mid-dispatch page crossings must land on
-            # mapped pages); under pool pressure, preempt youngest-first.
-            with hot_span(prof.ENGINE_ENSURE_PAGES):
-                for i, s in list(active):
-                    if self.slots[i] is not s:
-                        continue    # preempted by an earlier slot's allocation
-                    upto = min(s.length + slack + k_steps, self.max_len)
-                    while not self._ensure_pages(i, upto):
-                        if self._preempt_youngest(keep=i):
-                            continue
-                        # Sole survivor: shrink the dispatch to one step;
-                        # init guarantees one max-length sequence always
-                        # fits, but guard the next write position anyway.
-                        k_steps = 1
-                        if not self._ensure_pages(
-                                i, min(s.length + slack + 1, self.max_len)):
-                            self._preempt_slot(i)
-                        break
-                active = [(i, s) for i, s in enumerate(self.slots)
-                          if s is not None]
-            if not active:
-                return False
+        # Pre-allocate pages covering every live slot's next k_steps
+        # write positions (mid-dispatch page crossings must land on
+        # mapped pages); under pool pressure, preempt youngest-first.
+        with hot_span(prof.ENGINE_ENSURE_PAGES):
+            for i, s in list(active):
+                if self.slots[i] is not s:
+                    continue    # preempted by an earlier slot's allocation
+                upto = min(s.length + slack + k_steps, self.max_len)
+                while not self._ensure_pages(i, upto):
+                    if self._preempt_youngest(keep=i):
+                        continue
+                    # Sole survivor: shrink the dispatch to one step;
+                    # init guarantees one max-length sequence always
+                    # fits, but guard the next write position anyway.
+                    k_steps = 1
+                    if not self._ensure_pages(
+                            i, min(s.length + slack + 1, self.max_len)):
+                        self._preempt_slot(i)
+                    break
+            active = [(i, s) for i, s in enumerate(self.slots)
+                      if s is not None]
+        if not active:
+            return False
         mode = _mode_for([s.request.params for _, s in active])
         with hot_span(prof.ENGINE_SYNC_STATE):
             self._sync_decode_state()
@@ -3112,27 +2614,16 @@ class LLMEngine:
         """Enqueue the decode program over the device-resident state and
         adopt the state it returns; returns the token buffer's handle."""
         key = self._next_key()
-        if self.paged:
-            if self._lora is not None:
-                out, self.cache, st, tbl = self._paged_decode_n(
-                    self.params, self.cache, self._dstate.arrays,
-                    self._dstate.table, key, k_steps, mode,
-                    self._lora.buffers)
-            else:
-                out, self.cache, st, tbl = self._paged_decode_n(
-                    self.params, self.cache, self._dstate.arrays,
-                    self._dstate.table, key, k_steps, mode)
-            self._dstate.adopt(st, tbl)
+        if self._lora is not None:
+            out, self.cache, st, tbl = self._paged_decode_n(
+                self.params, self.cache, self._dstate.arrays,
+                self._dstate.table, key, k_steps, mode,
+                self._lora.buffers)
         else:
-            if self._lora is not None:
-                out, self.cache, st = self._decode_n(
-                    self.params, self.cache, self._dstate.arrays, key,
-                    k_steps, mode, self._lora.buffers)
-            else:
-                out, self.cache, st = self._decode_n(
-                    self.params, self.cache, self._dstate.arrays, key, k_steps,
-                    mode)
-            self._dstate.adopt(st)
+            out, self.cache, st, tbl = self._paged_decode_n(
+                self.params, self.cache, self._dstate.arrays,
+                self._dstate.table, key, k_steps, mode)
+        self._dstate.adopt(st, tbl)
         return out
 
     def _consume_round(self) -> int:  # hot-loop
@@ -3240,29 +2731,28 @@ class LLMEngine:
         draft_s = time.monotonic() - t0
         t1 = time.monotonic()
         T = k + 1
-        if self.paged:
-            # Pages must cover ALL T verify write positions — a dropped
-            # write would corrupt an accepted token's KV. Under pool
-            # pressure preempt youngest-first; if even that cannot cover a
-            # slot, fall back to plain decode (whose shrink-to-one-step
-            # path handles the sole-survivor case).
-            for i, s in list(active):
-                if self.slots[i] is not s:
-                    continue    # preempted by an earlier slot's allocation
-                upto = min(s.length + T, self.max_len)
-                covered = True
-                while not self._ensure_pages(i, upto):
-                    if not self._preempt_youngest(keep=i):
-                        covered = False
-                        break
-                if not covered:
-                    active = [(j, sl) for j, sl in enumerate(self.slots)
-                              if sl is not None]
-                    return self._plain_decode_once(active) if active else 0
-            active = [(i, s) for i, s in enumerate(self.slots)
-                      if s is not None]
-            if not active:
-                return 0
+        # Pages must cover ALL T verify write positions — a dropped
+        # write would corrupt an accepted token's KV. Under pool
+        # pressure preempt youngest-first; if even that cannot cover a
+        # slot, fall back to plain decode (whose shrink-to-one-step
+        # path handles the sole-survivor case).
+        for i, s in list(active):
+            if self.slots[i] is not s:
+                continue    # preempted by an earlier slot's allocation
+            upto = min(s.length + T, self.max_len)
+            covered = True
+            while not self._ensure_pages(i, upto):
+                if not self._preempt_youngest(keep=i):
+                    covered = False
+                    break
+            if not covered:
+                active = [(j, sl) for j, sl in enumerate(self.slots)
+                          if sl is not None]
+                return self._plain_decode_once(active) if active else 0
+        active = [(i, s) for i, s in enumerate(self.slots)
+                  if s is not None]
+        if not active:
+            return 0
         nb = self.num_slots
         tokens = np.zeros((nb, T), np.int32)
         lengths = np.zeros((nb,), np.int32)
@@ -3273,23 +2763,18 @@ class LLMEngine:
             tokens[i, 1:1 + len(d)] = d
             lengths[i] = s.length
             live[i] = True
-        if self.paged:
-            # The verify dispatch shares the device-resident page table
-            # with the plain path: dirty rows sync as deltas, the table
-            # itself is donated through and adopted back — never a full
-            # host upload. (The [B, T] token matrix is inherently host
-            # data — the drafts were proposed there.)
-            self._sync_decode_state()
-            cache_in = {**self.cache, "table": self._dstate.table}
-            greedy, cache_out = self._verify(
-                self.params, cache_in, jnp.asarray(tokens),
-                jnp.asarray(lengths), jnp.asarray(live))
-            self.cache = {n: cache_out[n] for n in cache_out if n != "table"}
-            self._dstate.adopt(self._dstate.arrays, cache_out["table"])
-        else:
-            greedy, self.cache = self._verify(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(lengths), jnp.asarray(live))
+        # The verify dispatch shares the device-resident page table
+        # with the plain path: dirty rows sync as deltas, the table
+        # itself is donated through and adopted back — never a full
+        # host upload. (The [B, T] token matrix is inherently host
+        # data — the drafts were proposed there.)
+        self._sync_decode_state()
+        cache_in = {**self.cache, "table": self._dstate.table}
+        greedy, cache_out = self._verify(
+            self.params, cache_in, jnp.asarray(tokens),
+            jnp.asarray(lengths), jnp.asarray(live))
+        self.cache = {n: cache_out[n] for n in cache_out if n != "table"}
+        self._dstate.adopt(self._dstate.arrays, cache_out["table"])
         greedy = np.asarray(jax.device_get(greedy))  # sync-point: greedy verification happens host-side
         verify_s = time.monotonic() - t1
         emitted = 0
@@ -3328,11 +2813,10 @@ class LLMEngine:
                 drafted=len(d), accepted=min(a, len(emit)),
                 emitted=len(emit),
                 draft_s=draft_s / len(active), verify_s=verify_s / len(active))
-            if self.paged:
-                # Roll back rejected positions: live KV covers exactly
-                # [0, s.length) now — truncate the page table to it so pool
-                # refcounts always account for tokens the slot kept.
-                self._truncate_slot_pages(i, s.length)
+            # Roll back rejected positions: live KV covers exactly
+            # [0, s.length) now — truncate the page table to it so pool
+            # refcounts always account for tokens the slot kept.
+            self._truncate_slot_pages(i, s.length)
             if self._draft_cfg is not None:
                 # Draft KV is valid for everything but the final (bonus)
                 # token, which the draft never consumed.
@@ -3351,16 +2835,16 @@ class LLMEngine:
             ctx = self._context_tokens(s)
             ctxs[i] = ctx
             # Catch-up: consume all but the last context token through the
-            # chunked prefill (C-aligned windows; C divides max_len).
+            # chunk prefill (a chunk may start anywhere in a page).
             if len(ctx) - self._draft_pos[i] > dmax:
-                C = self._draft_chunk
+                C = self.chunk_size
                 target = len(ctx) - 1
                 pos = self._draft_pos[i]
                 while pos < target:
-                    real = min(C - pos % C, target - pos)
+                    real = min(C, target - pos)
                     chunk = np.zeros((1, C), np.int32)
                     chunk[0, :real] = ctx[pos:pos + real]
-                    _, self._draft_cache = self._draft_chunkfn(
+                    self._draft_cache = self._draft_chunkfn(
                         self._draft_params, self._draft_cache,
                         jnp.asarray(chunk), jnp.int32(i), jnp.int32(pos),
                         jnp.int32(real))
@@ -3394,11 +2878,9 @@ class LLMEngine:
 
     def _truncate_slot_pages(self, idx: int, keep_tokens: int) -> None:
         """Free the pages past the ones covering [0, keep_tokens) — the
-        paged-KV rollback after a speculative rejection. Decode-grown pages
+        KV rollback after a speculative rejection. Decode-grown pages
         are never prefix-registered and keep_tokens never rewinds into the
         prompt, so registered prefix pages are never dropped here."""
-        if self._allocator is None:
-            return
         keep = -(-keep_tokens // self.page_size)
         pages = self._slot_pages[idx]
         if len(pages) <= keep:

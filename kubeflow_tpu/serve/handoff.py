@@ -42,7 +42,7 @@ first token's is not), ``last_token = first_token``, and the request's
 ``prompt_tokens`` carry ``prompt + [first_token]`` so recompute
 preemption and speculative context reconstruction keep their
 invariants. Greedy outputs are therefore token-identical to the unified
-path on dense and paged backends (pinned in tests).
+path (pinned in tests).
 
 Wire format: one JSON metadata line + raw little-endian KV bytes
 (dtype/shape in the metadata — bf16 rides as raw ml_dtypes bytes, no
@@ -283,10 +283,9 @@ def payload_from_export(req, kv_k: np.ndarray, kv_v: np.ndarray,
                         kv_sk: Optional[np.ndarray] = None,
                         kv_sv: Optional[np.ndarray] = None) -> HandoffPayload:
     """Build the payload at flush time: ``kv_*`` are the fetched host
-    arrays (dense exports fetch the full cache row — trim to ``plen``),
-    and the decode budget is the original budget minus the first token
-    the prefill side already emitted. int8 pools pass the fetched scale
-    rows too."""
+    arrays (whole pages — trim to ``plen``), and the decode budget is the
+    original budget minus the first token the prefill side already
+    emitted. int8 pools pass the fetched scale rows too."""
     p = req.params
     payload = HandoffPayload(
         request_id=req.id,

@@ -29,7 +29,7 @@ weights (the S-LoRA/Punica motif, TPU-native). The pieces:
 
 Correctness contract: greedy decode under every loaded adapter is
 token-identical to a single-model engine running the MERGED weights
-(``merged_params``), dense and paged (tests/test_serve_lora.py), and
+(``merged_params``; tests/test_serve_lora.py), and
 prefix-cache KV is namespaced per adapter (engine._kv_match) so two
 tenants sharing a prompt never share each other's KV.
 """

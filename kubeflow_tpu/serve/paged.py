@@ -3,7 +3,7 @@ analog of vLLM's PagedAttention memory manager ((U) kserve
 python/huggingfaceserver vLLM backend; SURVEY.md §2.3#27 'continuous
 batching, paged KV').
 
-Why paging matters on v5e: the contiguous slot cache reserves
+Why paging matters on v5e: a contiguous cache of one row a slot reserves
 ``slots × max_seq_len`` HBM whether or not requests use it; high-density
 serving wants HBM proportional to *actual* tokens resident. Here KV lives in
 a fixed pool of pages ``[L, P, page, KV, Dh]`` (``pool_planes``: K and V per
@@ -21,11 +21,10 @@ slot owns an ordered page list (its page table), and:
   pages and its request requeues with prompt+generated so far (vLLM's
   recompute preemption).
 
-Device side, the paged variants mirror the contiguous ones (engine.py): the
-page table rides into the dispatch as a ``[B, max_pages_per_slot]`` int32
-array; reads gather pages back into the ``[B, S, KV, Dh]`` layout XLA
-already tiles well, writes scatter ``(page, offset)`` with out-of-bounds
-drops for dead rows. The decode dispatch never takes a layer's slab out of
+Device side, the page table rides into the dispatch as a
+``[B, max_pages_per_slot]`` int32 array; reads gather pages back into the
+``[B, S, KV, Dh]`` layout XLA already tiles well, writes scatter
+``(page, offset)`` with out-of-bounds drops for dead rows. The decode dispatch never takes a layer's slab out of
 the pool: it carries every plane whole, viewed flat ``[L*P, page, KV, Dh]``,
 through its step loop and its layer scan, writes layer ``l``'s rows in place
 at flat page ``l*P + page`` and reads through the table offset by ``l*P``
@@ -35,9 +34,10 @@ it attends to, not the pool. The speculative verify dispatch
 with a verify-length axis — k+1 (page, offset) writes per slot per round —
 and rejection rolls the page table back to the accepted length
 (engine._truncate_slot_pages): truncated pages return to the free list,
-so pool refcounts account for exactly the tokens each slot kept. Exactness: with the "gather" attention impl the same
-einsums run over the same values, so the paged engine is bit-compatible
-with the contiguous one (tests pin this); the "pallas" impl
+so pool refcounts account for exactly the tokens each slot kept.
+Exactness: the "gather" attention impl runs the plain einsums over the
+gathered pages (the engine's greedy tokens are pinned against a
+full-recompute ``decoder_forward`` loop); the "pallas" impl
 (ops/paged_attention.py) is mathematically exact blockwise softmax with
 fp32 accumulation — numerically equal, not bitwise (its probabilities are
 never rounded to bf16 before the PV product).
@@ -413,12 +413,31 @@ def _scan_layer_groups(params: Params, cfg: DecoderConfig, carry, block,  # trac
     return carry
 
 
+def _decode_attention(q, ck, cv, lengths, cfg: DecoderConfig):  # traced
+    """One-token attention over the slots' gathered pages.
+
+    q [B,1,H,Dh]; ck/cv [B,Smax,KV,Dh]; lengths [B] = position of the token
+    being decoded (its K/V were just written at that index, so attend to
+    kpos <= lengths[b])."""
+    b, smax = ck.shape[0], ck.shape[1]
+    groups = cfg.n_heads // cfg.n_kv_heads
+    qg = q.reshape(b, cfg.n_kv_heads, groups, cfg.head_dim)
+    scores = jnp.einsum("bkgd,bskd->bkgs", qg, ck,
+                        preferred_element_type=jnp.float32)
+    scores *= cfg.head_dim ** -0.5
+    kpos = jnp.arange(smax, dtype=jnp.int32)
+    mask = kpos[None, :] <= lengths[:, None]            # [B, Smax]
+    scores = jnp.where(mask[:, None, None, :], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(ck.dtype)
+    out = jnp.einsum("bkgs,bskd->bkgd", probs, cv)
+    return out.reshape(b, 1, cfg.n_heads, cfg.head_dim)
+
+
 def _paged_decode_block(bp, x, positions, lengths, live, pools, table,  # traced
                         layer, num_pages: int, cfg: DecoderConfig,
                         attn_impl: str = "gather", lora=None,
                         expert_stack=None):
     """One transformer block for a [B,1] decode step against the page pool.
-    Mirrors engine._decode_block; only the KV residency differs.
 
     ``pools`` holds every plane of the WHOLE pool viewed flat —
     ``k``/``v`` ``[L*P,pg,KV,Dh]`` and, iff the pool stores int8, the
@@ -468,8 +487,6 @@ def _kv_decode_attention(a, h, positions, lengths, pools, pidx, off,  # traced
     """Per-head K/V: project, write this token's rows at (pidx, off), attend
     to the slot's pages. Returns (the block's attention output [B,1,D], the
     planes as written)."""
-    from kubeflow_tpu.serve.engine import _decode_attention
-
     dt = cfg.activation_dtype
     kv_quant = "ks" in pools
     q = jnp.einsum("bsd,dhk->bshk", h, a["wq"].astype(dt))
@@ -546,7 +563,9 @@ def _paged_decode_step(params: Params, cache: dict, tokens: jax.Array,  # traced
                        lengths: jax.Array, live: jax.Array,
                        cfg: DecoderConfig, attn_impl: str = "gather",
                        lora=None):
-    """One [B,1] decode step over the page pool (≈ engine._decode_step).
+    """One [B,1] decode step over the page pool: tokens [B] (last sampled),
+    lengths [B] (their positions), live [B] (rows whose KV write is real).
+    Returns (logits [B,V] fp32, new cache).
 
     The pool is a CARRY of the layer scan, never a scanned input/output: a
     scan's stacked outputs are a new buffer, so scanning over ``[L,P,...]``
@@ -584,12 +603,20 @@ def paged_decode_multi(params: Params, cache: dict, tokens: jax.Array,  # traced
                        sample_mode: str = "full", attn_impl: str = "gather",
                        lora=None, adapter_idx=None):
     """Up to ``num_steps`` decode+sample steps in ONE dispatch over the page
-    pool (≈ engine._decode_multi; the host pre-allocates pages covering
-    ``lengths + num_steps`` so mid-dispatch page-boundary crossings always
-    land on mapped pages — with pipelined dispatch the engine adds one
-    in-flight round of slack on top). Returns (out, cache, tokens, lengths,
-    live, budgets): the advanced carry is the next round's input, kept
-    device-resident by the engine (serve/device_state.py)."""
+    pool (the host pre-allocates pages covering ``lengths + num_steps`` so
+    mid-dispatch page-boundary crossings always land on mapped pages — with
+    pipelined dispatch the engine adds one in-flight round of slack on
+    top). Sampling runs on-device inside a ``while_loop`` that exits as
+    soon as every slot is finished (stop token, token budget, or
+    cache-length cap). Dead rows (free or finished slots, a slot
+    mid-chunked-prefill) still flow through the batch so shapes never
+    change; their KV writes are DROPPED, never replayed (a replayed write
+    would corrupt KV a chunked prefill already wrote), and their tokens
+    are discarded via ``live``. Emitted tokens surface as
+    ``out`` [B, num_steps] with -1 in never-emitted cells. Returns (out,
+    cache, tokens, lengths, live, budgets): the advanced carry is the next
+    round's input, kept device-resident by the engine
+    (serve/device_state.py)."""
     from kubeflow_tpu.serve.engine import _sample_batch
 
     b = tokens.shape[0]

@@ -809,12 +809,9 @@ def serving_metrics_registry(engines: list, *,
                             model=name)
         remote_corrupt.inc(tier.get("remote_blobs_corrupt", 0), model=name)
         tier_pressure.set(round(engine.kv_tier_pressure(), 3), model=name)
-        # Contiguous-cache engines render 0/0: the series must exist on
-        # every replica (the loadgen attribution scrape pins the set).
         density = engine.kv_pool_density()
-        kvq_enabled.set(density.get("quant", 0), model=name)
-        kvq_density.set(round(density.get("tokens_per_mib", 0.0), 1),
-                        model=name)
+        kvq_enabled.set(density["quant"], model=name)
+        kvq_density.set(round(density["tokens_per_mib"], 1), model=name)
         ho_bytes_out.inc(snap.get("handoff_bytes_exported", 0), model=name)
         ho_bytes_in.inc(snap.get("handoff_bytes_adopted", 0), model=name)
         wire_demote.inc(tier.get("demote_wire_bytes", 0), model=name)

@@ -1,4 +1,4 @@
-"""Speculative decoding — draft + batched verify over the slot/page caches.
+"""Speculative decoding — draft + batched verify over the page pool.
 
 Why it wins on v5e: a decode step is dispatch- and HBM-bound (the whole
 param read for ONE token per slot), so scoring k+1 positions per slot in a
@@ -18,22 +18,23 @@ Two draft sources (core/serving.py ``SpeculativeSpec``):
   exactly where serving traffic is decode-heavy: templated suffixes,
   extraction, code, and greedy generations that fall into repeating cycles.
 - **draft_model**: a small decoder (same vocab) runs ``k`` autoregressive
-  steps per round against its OWN dense slot cache; the target verifies.
-  The draft cache tracks the true sequence via a per-slot consumed-length
-  pointer — on rejection the pointer rewinds (draft KV past it is garbage
-  but every position is rewritten before it is ever attended, the same
-  overwrite-before-read invariant the decode caches already rely on).
+  steps per round against its OWN page pool, whose table is the identity
+  (slot s owns pages s*mpp .. (s+1)*mpp-1: no allocator); the target
+  verifies. The draft cache tracks the true sequence via a per-slot
+  consumed-length pointer — on rejection the pointer rewinds (draft KV
+  past it is garbage but every position is rewritten before it is ever
+  attended, the same overwrite-before-read invariant the decode step
+  already relies on).
 
 Verification is exact for GREEDY requests only (argmax chains compose);
 the engine falls back to the normal decode path whenever a sampling
 request shares the batch.
 
 KV rollback: the verify dispatch writes K/V for all k+1 positions before
-acceptance is known. Rejected positions hold garbage — harmless in the
-dense cache (overwritten before read), while the paged engine additionally
-truncates each slot's page table back to the accepted length
-(engine._truncate_slot_pages) so the pool's refcounts always account for
-exactly the tokens a slot actually kept.
+acceptance is known. Rejected positions hold garbage (overwritten before
+read), and the engine truncates each slot's page table back to the
+accepted length (engine._truncate_slot_pages) so the pool's refcounts
+always account for exactly the tokens a slot actually kept.
 
 Scheduler-state residency: ``paged_verify_step`` consumes the SAME
 device-resident page table the plain decode path owns
@@ -57,6 +58,9 @@ import jax.numpy as jnp
 from kubeflow_tpu.models import layers as L
 from kubeflow_tpu.models.config import DecoderConfig
 from kubeflow_tpu.models.decoder import Params
+from kubeflow_tpu.serve.paged import (
+    _paged_decode_step, _planes_of, paged_gather,
+)
 
 
 # -- drafting ------------------------------------------------------------------
@@ -82,12 +86,13 @@ def ngram_propose(ctx: Sequence[int], k: int, ngram_max: int,
     return []
 
 
-# -- batched verify (dense slot cache) -----------------------------------------
+# -- batched verify -------------------------------------------------------------
 
 def _spec_attention(q, ck, cv, lengths, cfg: DecoderConfig):  # traced
-    """T-query attention over slot caches (the verify-length generalization
-    of engine._decode_attention). q [B,T,H,Dh]; ck/cv [B,Smax,KV,Dh];
-    query t sits at position lengths[b]+t and attends kpos <= that."""
+    """T-query attention over the slots' gathered pages (the verify-length
+    generalization of paged._decode_attention). q [B,T,H,Dh]; ck/cv
+    [B,Smax,KV,Dh]; query t sits at position lengths[b]+t and attends
+    kpos <= that."""
     b, t = q.shape[0], q.shape[1]
     smax = ck.shape[1]
     groups = cfg.n_heads // cfg.n_kv_heads
@@ -104,70 +109,6 @@ def _spec_attention(q, ck, cv, lengths, cfg: DecoderConfig):  # traced
     return out.reshape(b, t, cfg.n_heads, cfg.head_dim)
 
 
-def _spec_block(bp, x, positions, lengths, live, cache_k, cache_v,  # traced
-                cfg: DecoderConfig):
-    """One transformer block for a [B,T] verify step against slot caches
-    (engine._decode_block with a verify-length axis). Writes the K/V of all
-    T tokens at positions[b, t]; dead rows and out-of-range positions aim
-    out of bounds and DROP."""
-    dt = cfg.activation_dtype
-    h = L.rmsnorm(x, bp["ln1"], cfg)
-    q = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wq"].astype(dt))
-    k = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wk"].astype(dt))
-    v = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wv"].astype(dt))
-    q = L.rope(q, positions, cfg.rope_theta)
-    k = L.rope(k, positions, cfg.rope_theta)
-    smax = cache_k.shape[1]
-    bidx = jnp.arange(x.shape[0])[:, None]
-    widx = jnp.where(live[:, None] & (positions < smax), positions, smax)
-    ck = cache_k.at[bidx, widx].set(k, mode="drop")
-    cv = cache_v.at[bidx, widx].set(v, mode="drop")
-    attn = _spec_attention(q, ck, cv, lengths, cfg)
-    x = x + jnp.einsum("bshk,hkd->bsd", attn, bp["attn"]["wo"].astype(dt))
-    h = L.rmsnorm(x, bp["ln2"], cfg)
-    if cfg.is_moe:
-        mlp_out, _ = L.moe_block(bp["mlp"], h, cfg)
-    else:
-        mlp_out = L.mlp_block(bp["mlp"], h, cfg)
-    return x + mlp_out, ck, cv
-
-
-def verify_step(params: Params, cache: dict, tokens: jax.Array,  # traced
-                lengths: jax.Array, live: jax.Array, cfg: DecoderConfig):
-    """ONE dispatch scoring T = k+1 positions per slot over the dense slot
-    cache. tokens [B,T] = [last_token, draft_1..draft_k] (pad columns are
-    scored too — the host just ignores them); lengths [B] = the write
-    position of tokens[:,0], exactly as in engine._decode_step.
-
-    Returns ([B,T] int32 greedy next-token ids, new cache): row b column t
-    is the target's argmax continuation after consuming tokens[b, :t+1] —
-    the verification oracle for draft t+1 and the correction/bonus token
-    when the match breaks there."""
-    dt = cfg.activation_dtype
-    t = tokens.shape[1]
-    x = params["embed"].astype(dt)[tokens]                    # [B,T,D]
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.hidden ** 0.5, dt)
-    positions = lengths[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
-
-    def body(x, scan_in):
-        bp, ck, cv = scan_in
-        x, nk, nv = _spec_block(bp, x, positions, lengths, live, ck, cv, cfg)
-        return x, (nk, nv)
-
-    x, (nk, nv) = jax.lax.scan(body, x, (params["layers"],
-                                         cache["k"], cache["v"]))
-    x = L.rmsnorm(x, params["final_norm"], cfg)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("btd,dv->btv", x, head.astype(dt),
-                        preferred_element_type=jnp.float32)
-    if cfg.logits_softcap is not None:
-        logits = jnp.tanh(logits / cfg.logits_softcap) * cfg.logits_softcap
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32), {"k": nk, "v": nv}
-
-
-# -- batched verify (paged pool) -----------------------------------------------
-
 def _paged_spec_block(bp, x, positions, lengths, live, pool_k, pool_v,  # traced
                       table, cfg: DecoderConfig, pool_ks=None, pool_vs=None):
     """Verify block against the page pool (paged._paged_decode_block with a
@@ -175,8 +116,6 @@ def _paged_spec_block(bp, x, positions, lengths, live, pool_k, pool_v,  # traced
     paged-attention kernel is single-query). Position -> (page, offset)
     per token; unmapped pages, dead rows and positions past the table's
     reach aim out of bounds and DROP."""
-    from kubeflow_tpu.serve.paged import paged_gather
-
     dt = cfg.activation_dtype
     kv_quant = pool_ks is not None
     pg = pool_k.shape[1]
@@ -225,9 +164,17 @@ def _paged_spec_block(bp, x, positions, lengths, live, pool_k, pool_v,  # traced
 def paged_verify_step(params: Params, cache: dict, tokens: jax.Array,  # traced
                       lengths: jax.Array, live: jax.Array,
                       cfg: DecoderConfig):
-    """verify_step over the page pool (cache carries "table"; the host
-    pre-allocates pages covering all T write positions, exactly like
-    paged_decode_multi's contract). Returns ([B,T] greedy ids, cache)."""
+    """ONE dispatch scoring T = k+1 positions per slot over the page pool
+    (cache carries "table"; the host pre-allocates pages covering all T
+    write positions, exactly like paged_decode_multi's contract). tokens
+    [B,T] = [last_token, draft_1..draft_k] (pad columns are scored too —
+    the host just ignores them); lengths [B] = the write position of
+    tokens[:,0], exactly as in paged._paged_decode_step.
+
+    Returns ([B,T] int32 greedy next-token ids, new cache): row b column t
+    is the target's argmax continuation after consuming tokens[b, :t+1] —
+    the verification oracle for draft t+1 and the correction/bonus token
+    when the match breaks there."""
     dt = cfg.activation_dtype
     kv_quant = "ks" in cache
     t = tokens.shape[1]
@@ -275,9 +222,9 @@ def draft_propose(params: Params, cache: dict, deltas: jax.Array,  # traced
                   delta_lens: jax.Array, draft_pos: jax.Array,
                   live: jax.Array, cfg: DecoderConfig, num_steps: int):
     """Catch-up + autoregressive drafting for the small model in ONE
-    dispatch of ``num_steps`` single-token decode steps over its dense slot
-    cache (engine._decode_step reused verbatim — the draft is just another
-    decoder).
+    dispatch of ``num_steps`` single-token decode steps over its own page
+    pool (``cache`` carries the identity "table"; paged._paged_decode_step
+    reused verbatim — the draft is just another decoder).
 
     Per slot b: steps t < delta_lens[b] feed deltas[b, t] (the true tokens
     the draft hasn't consumed yet — the previous round's accepted suffix);
@@ -286,11 +233,9 @@ def draft_propose(params: Params, cache: dict, deltas: jax.Array,  # traced
     b's k drafts at columns delta_lens[b]-1 .. delta_lens[b]-1+k-1.
 
     Returns (out [B, num_steps] int32, new cache)."""
-    from kubeflow_tpu.serve.engine import _decode_step
-
     b = deltas.shape[0]
     dmax = deltas.shape[1]
-    max_len = cache["k"].shape[2]
+    max_len = cache["table"].shape[1] * cache[_planes_of(cache)[0]].shape[2]
 
     def body(carry, t):
         cache, prev = carry
@@ -298,8 +243,8 @@ def draft_propose(params: Params, cache: dict, deltas: jax.Array,  # traced
                         deltas[:, jnp.clip(t, 0, dmax - 1)], prev)
         lengths = draft_pos + t
         step_live = live & (lengths < max_len)
-        logits, cache = _decode_step(params, cache, fed, lengths,
-                                     step_live, cfg)
+        logits, cache = _paged_decode_step(params, cache, fed, lengths,
+                                           step_live, cfg)
         g = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return (cache, g), g
 

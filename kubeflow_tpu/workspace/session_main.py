@@ -24,6 +24,24 @@ def touch(path: str) -> None:
         os.utime(path, None)
 
 
+@contextlib.contextmanager
+def unix_address(path: str):
+    """An address for the unix socket at ``path`` that ``bind`` and
+    ``connect`` take whatever the path's length: an AF_UNIX address holds
+    107 bytes, and a notebook's directory under a deep ``base_dir`` is
+    longer (the session then died at ``bind`` and the controller restarted
+    it for ever). A longer path is reached through its directory's open
+    descriptor."""
+    if len(os.fsencode(path)) <= 107:
+        yield path
+        return
+    fd = os.open(os.path.dirname(path), os.O_RDONLY | os.O_DIRECTORY)
+    try:
+        yield f"/proc/self/fd/{fd}/{os.path.basename(path)}"
+    finally:
+        os.close(fd)
+
+
 class _Handler(socketserver.StreamRequestHandler):
     def handle(self):
         for line in self.rfile:
@@ -79,7 +97,8 @@ def main() -> int:
         os.unlink(sock_path)
     os.makedirs(os.path.dirname(sock_path), exist_ok=True)
     touch(activity)
-    srv = _Server(sock_path, _Handler)
+    with unix_address(sock_path) as address:
+        srv = _Server(address, _Handler)
     srv.activity_file = activity
     srv.user_globals = {"__name__": "__kftpu_notebook__"}
     # Kernel-profile preimports (the image family's preinstalled stack —
@@ -131,7 +150,8 @@ def exec_code(sock_path: str, code: str, timeout: float = 60.0) -> dict:
     """Client helper: run one cell in a session (used by the CLI and tests)."""
     with socket.socket(socket.AF_UNIX, socket.SOCK_STREAM) as s:
         s.settimeout(timeout)
-        s.connect(sock_path)
+        with unix_address(sock_path) as address:
+            s.connect(address)
         s.sendall((json.dumps({"code": code}) + "\n").encode())
         buf = b""
         while not buf.endswith(b"\n"):

@@ -74,7 +74,7 @@ def main() -> int:
         eng = LLMEngine(
             cfg,
             BatchingSpec(max_batch_size=2, max_seq_len=96,
-                         prefill_buckets=[32], paged=True, page_size=16,
+                         paged=True, page_size=16,
                          chunked_prefill_tokens=16, decode_steps=4),
             params=params)
         srv = ModelServer(name, eng, port=0)

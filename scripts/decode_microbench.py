@@ -1,5 +1,6 @@
 """Microbench the decode dispatch path on-chip: time K-step dispatches and
-the prefill program, separating model time from per-dispatch overhead."""
+the chunk-prefill program, separating model time from per-dispatch
+overhead."""
 
 from __future__ import annotations
 
@@ -24,8 +25,10 @@ def main():
         n_layers=8, hidden=2048, n_heads=32, n_kv_heads=8, head_dim=64,
         mlp_dim=8192, vocab_size=32000, max_seq_len=2048)
     eng = LLMEngine(cfg, BatchingSpec(max_batch_size=16, max_seq_len=2048,
-                                      prefill_buckets=[512]))
+                                      chunked_prefill_tokens=512))
     nb = eng.num_slots
+    # Every slot's pages mapped: slot s owns pages s*mpp .. (s+1)*mpp-1.
+    table = jnp.arange(nb * eng._mpp, dtype=jnp.int32).reshape(nb, eng._mpp)
 
     key = jax.random.PRNGKey(0)
 
@@ -42,20 +45,21 @@ def main():
             "top_p": jnp.ones((nb,), jnp.float32),
             "stops": jnp.full((nb,), -1, jnp.int32),
             "budgets": jnp.full((nb,), 10**6, jnp.int32),
+            "adapter": jnp.full((nb,), -1, jnp.int32),
         }
 
     for k_steps in (1, 8, 16, 32):
         state = fresh_state()
         # compile
-        out, eng.cache, state = eng._decode_n(
-            eng.params, eng.cache, state, key, k_steps, "greedy")
+        out, eng.cache, state, table = eng._paged_decode_n(
+            eng.params, eng.cache, state, table, key, k_steps, "greedy")
         _ = out.block_until_ready()
         _ = int(jax.device_get(out)[0, 0])  # fence
         reps = 6
         t0 = time.perf_counter()
         for _ in range(reps):
-            out, eng.cache, state = eng._decode_n(
-                eng.params, eng.cache, state, key, k_steps, "greedy")
+            out, eng.cache, state, table = eng._paged_decode_n(
+                eng.params, eng.cache, state, table, key, k_steps, "greedy")
             _ = int(jax.device_get(out)[0, 0])  # fence via host fetch
         dt = (time.perf_counter() - t0) / reps
         print(json.dumps({
@@ -65,17 +69,21 @@ def main():
             "agg_tok_s": round(nb * k_steps / dt, 1),
         }), flush=True)
 
-    # prefill program timing (512 bucket)
+    # chunk-prefill program timing: one 512-token chunk into slot 0
     toks = jnp.zeros((1, 512), jnp.int32)
-    last, eng.cache = eng._prefill(eng.params, eng.cache, toks,
-                                   jnp.int32(0), jnp.int32(500))
-    _ = float(jax.device_get(last)[0])
+
+    def chunk():
+        logits, eng.cache = eng._paged_chunk(
+            eng.params, eng.cache, toks, table[0], jnp.int32(0),
+            jnp.int32(500), 4)
+        return float(jax.device_get(logits[499, 0]))
+
+    chunk()
     t0 = time.perf_counter()
     for _ in range(4):
-        last, eng.cache = eng._prefill(eng.params, eng.cache, toks,
-                                       jnp.int32(0), jnp.int32(500))
-        _ = float(jax.device_get(last)[0])
-    print(json.dumps({"prefill512_ms": round((time.perf_counter() - t0) / 4 * 1e3, 2)}))
+        chunk()
+    print(json.dumps({"chunk512_ms":
+                      round((time.perf_counter() - t0) / 4 * 1e3, 2)}))
 
 
 if __name__ == "__main__":

@@ -154,7 +154,6 @@ def main() -> int:
         def srv_spec(role):
             return BatchingSpec(max_batch_size=2, max_seq_len=96,
                                 paged=True, page_size=16,
-                                prefill_buckets=[32],
                                 chunked_prefill_tokens=16, decode_steps=4,
                                 role=role)
 

@@ -1,8 +1,8 @@
 """Hot-loop smoke stage (scripts/smoke.sh): a short pipelined-dispatch
 on/off A/B on CPU asserting CORRECTNESS + PLUMBING, never perf —
 
-- greedy outputs token-identical with pipelining on and off, dense and
-  paged (the tentpole's output contract);
+- greedy outputs token-identical with pipelining on and off
+  (the tentpole's output contract);
 - steady-state decode rounds perform zero full-array host→device uploads
   of scheduler state (the device_state counters stay at their
   construction values while rounds accumulate);
@@ -25,13 +25,13 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def mk_engine(cfg, params, *, pipelined, paged=False):
+def mk_engine(cfg, params, *, pipelined):
     from kubeflow_tpu.core.serving import BatchingSpec
     from kubeflow_tpu.serve.engine import LLMEngine
 
     return LLMEngine(cfg, BatchingSpec(
-        max_batch_size=4, max_seq_len=128, prefill_buckets=[16, 64],
-        chunked_prefill_tokens=32, paged=paged, page_size=16,
+        max_batch_size=4, max_seq_len=128, chunked_prefill_tokens=32,
+        paged=True, page_size=16,
         decode_steps=4, pipelined_decode=pipelined), params=params)
 
 
@@ -67,21 +67,17 @@ def main() -> int:
 
     result: dict = {}
 
-    # 1) Token identity: pipelining on/off, dense and paged.
+    # 1) Token identity: pipelining on/off.
     outputs = {}
     engines = {}
-    for tag, kw in (("dense_off", {"pipelined": False}),
-                    ("dense_on", {"pipelined": True}),
-                    ("paged_off", {"pipelined": False, "paged": True}),
-                    ("paged_on", {"pipelined": True, "paged": True})):
-        eng = mk_engine(cfg, params, **kw)
+    for tag, pipelined in (("paged_off", False), ("paged_on", True)):
+        eng = mk_engine(cfg, params, pipelined=pipelined)
         outputs[tag] = gen_all(eng, prompts, args.max_new)
         engines[tag] = eng
-    for tag in ("dense_on", "paged_off", "paged_on"):
-        if outputs[tag] != outputs["dense_off"]:
-            result["hotloop_smoke"] = f"token mismatch: {tag}"
-            print(json.dumps(result))
-            return 1
+    if outputs["paged_on"] != outputs["paged_off"]:
+        result["hotloop_smoke"] = "token mismatch: paged_on"
+        print(json.dumps(result))
+        return 1
     result["token_identity"] = "ok"
 
     # 2) Zero full uploads of scheduler state past construction.
@@ -92,11 +88,11 @@ def main() -> int:
             print(json.dumps(result))
             return 1
         if stats["full_state_uploads"] != 1 or \
-                stats["full_table_uploads"] != (1 if eng.paged else 0):
+                stats["full_table_uploads"] != 1:
             result["hotloop_smoke"] = f"{tag}: full upload leak {stats}"
             print(json.dumps(result))
             return 1
-        if eng.paged and eng.kv_pages_in_use() != 0:
+        if eng.kv_pages_in_use() != 0:
             result["hotloop_smoke"] = f"{tag}: page leak"
             print(json.dumps(result))
             return 1
@@ -133,7 +129,7 @@ def main() -> int:
     from kubeflow_tpu.obs.registry import parse_exposition
     from kubeflow_tpu.serve.server import ModelServer
 
-    srv = ModelServer("smoke", engines["dense_on"], port=0)
+    srv = ModelServer("smoke", engines["paged_on"], port=0)
     try:
         text = srv.metrics_text()
         names = {n for n, _, _ in parse_exposition(text)}

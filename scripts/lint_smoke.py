@@ -9,7 +9,7 @@ seeded regression — the PR-4 per-round ``jnp.asarray(self._table)``
 upload (D103), a dropped router lock acquisition (C301), a de-donated
 decode carry (S401), an exception-path page leak (R501), an inverted
 router lock pair (R503), a fire-and-forget trainer checkpoint save
-(R504), a weak-type scalar riding into the dense decode dispatch (F602),
+(R504), a weak-type scalar riding into the decode dispatch (F602),
 a fresh tuple in its static num_steps position (F604), a renamed
 autoscaler-scraped series (X701, linted under the full package Program
 so the cross-component table sees the real producers), a typoed
@@ -106,13 +106,13 @@ def _seeded_regressions() -> list[str]:
         ("    def note_activity(self) -> None:\n        with self._lock:\n",
          "    def note_activity(self) -> None:\n        if True:\n"),
         "C301", "_last_activity")
-    # Family S: drop the dense decode dispatch's carry donation (2x HBM).
+    # Family S: drop the decode dispatch's carry donation (2x HBM).
     new_findings(
         "kubeflow_tpu/serve/engine.py",
-        ("self._decode_n = jax.jit(_decode_fn, static_argnums=(4, 5),\n"
-         "                                 donate_argnums=(1, 2))",
-         "self._decode_n = jax.jit(_decode_fn, static_argnums=(4, 5))"),
-        "S401", "self._decode_n")
+        ("            _paged_decode_fn, static_argnums=(5, 6),\n"
+         "            donate_argnums=(1, 2, 3))",
+         "            _paged_decode_fn, static_argnums=(5, 6))"),
+        "S401", "self._paged_decode_n")
     # Family R: a raise-capable call between page alloc and the ownership
     # recording — the exception path leaks the pages.
     new_findings(
@@ -149,41 +149,29 @@ def _seeded_regressions() -> list[str]:
          "        start = self.try_resume()\n"
          "        self.ckpt.save(0, self.task.state)\n"),
         "R504", "self.ckpt.save")
-    # Family F: a weak-typed Python scalar in the dense decode dispatch
-    # (a fresh compile-cache entry per scalar source) — the cycle
-    # KFTPU_SANITIZE=recompile would catch at runtime.
+    # Family F: a weak-typed Python scalar in the decode dispatch (a fresh
+    # compile-cache entry per scalar source) — the cycle
+    # KFTPU_SANITIZE=recompile would catch at runtime. The dispatch runs
+    # the fused RMSNorm Pallas kernel inside it (layers.rmsnorm): exactly
+    # the steady-state recompile the warmed-fused-step sanitizer test pins
+    # to zero.
     _DECODE_CALL = (
-        "                out, self.cache, st = self._decode_n(\n"
-        "                    self.params, self.cache, self._dstate.arrays,"
-        " key, k_steps,\n"
-        "                    mode)")
+        "            out, self.cache, st, tbl = self._paged_decode_n(\n"
+        "                self.params, self.cache, self._dstate.arrays,\n"
+        "                self._dstate.table, key, k_steps, mode)")
     new_findings(
         "kubeflow_tpu/serve/engine.py",
         (_DECODE_CALL,
-         _DECODE_CALL.replace(" key, k_steps,", " 0.5, k_steps,")),
-        "F602", "self._decode_n")
+         _DECODE_CALL.replace(" key, k_steps, mode)", " 0.5, k_steps, mode)")),
+        "F602", "self._paged_decode_n")
     # Family F: a per-call tuple in the dispatch's STATIC num_steps
     # position — hashed by value each call, a retrace per dispatch.
     new_findings(
         "kubeflow_tpu/serve/engine.py",
         (_DECODE_CALL,
-         _DECODE_CALL.replace(" key, k_steps,", " key, (k_steps,),")),
-        "F604", "self._decode_n")
-    # Family F on the FUSED-KERNEL dispatch surface (ISSUE 15): the paged
-    # decode dispatch now runs the fused RMSNorm Pallas kernel inside it
-    # (layers.rmsnorm) — a weak Python scalar replacing its key would be
-    # one fresh compile-cache entry per scalar source, exactly the
-    # steady-state recompile the warmed-fused-step sanitizer test pins to
-    # zero. Prove the analyzer guards the new path too.
-    _PAGED_CALL = (
-        "                out, self.cache, st, tbl = self._paged_decode_n(\n"
-        "                    self.params, self.cache, self._dstate.arrays,\n"
-        "                    self._dstate.table, key, k_steps, mode)")
-    new_findings(
-        "kubeflow_tpu/serve/engine.py",
-        (_PAGED_CALL,
-         _PAGED_CALL.replace(" key, k_steps, mode)", " 0.5, k_steps, mode)")),
-        "F602", "self._paged_decode_n")
+         _DECODE_CALL.replace(" key, k_steps, mode)",
+                              " key, (k_steps,), mode)")),
+        "F604", "self._paged_decode_n")
 
     # Family T: strip the scrape probe's timeout — the exact unbounded
     # urlopen class that wedged a router behind a SIGKILLed replica.

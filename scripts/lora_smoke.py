@@ -5,8 +5,8 @@ recompile-free, leak-free (ISSUE 14).
 
 What must hold, on small f32 CPU engines:
 
-- **token identity**: greedy decode under every registered adapter —
-  dense AND paged — is token-identical to a single-model engine running
+- **token identity**: greedy decode under every registered adapter
+  is token-identical to a single-model engine running
   the MERGED weights, while base traffic through the same batched
   dispatch matches a LoRA-free engine exactly;
 - **the degradation band**: the ``multi_adapter`` loadgen scenario at
@@ -92,8 +92,8 @@ def mk_engine(cfg, params, *, n_register: int = 0, slots: int = LORA_SLOTS,
     lora = (LoRASpec(max_adapters=slots, rank=RANK) if n_register
             else LoRASpec())
     eng = LLMEngine(cfg, BatchingSpec(
-        max_batch_size=8, max_seq_len=128, prefill_buckets=[64],
-        paged=True, page_size=16, chunked_prefill_tokens=32,
+        max_batch_size=8, max_seq_len=128, paged=True, page_size=16,
+        chunked_prefill_tokens=32,
         decode_steps=8, lora=lora), params=params)
     for i in range(n_register):
         eng._lora.register(AdapterSpec(
@@ -179,7 +179,7 @@ def main() -> int:
     params = init_decoder_params(jax.random.PRNGKey(0), cfg)
     prompt = [(13 * i) % 250 + 1 for i in range(PROMPT_LEN)]
 
-    # ---- 1) token identity: adapters vs merged references, dense+paged
+    # ---- 1) token identity: adapters vs merged references
     from kubeflow_tpu.core.serving import BatchingSpec, LoRASpec
     from kubeflow_tpu.serve.engine import LLMEngine
 
@@ -187,35 +187,33 @@ def main() -> int:
         f"adpt-{i}", rank=RANK,
         weights=init_adapter_weights(jax.random.PRNGKey(100 + i), cfg,
                                      RANK)) for i in range(2)]
-    for paged in (False, True):
-        def mk(b_lora, p):
-            return LLMEngine(cfg, BatchingSpec(
-                max_batch_size=4, max_seq_len=128, prefill_buckets=[64],
-                paged=paged, page_size=16, lora=b_lora), params=p)
 
-        eng = mk(LoRASpec(max_adapters=2, rank=RANK), params)
-        for s in ident_specs:
-            eng._lora.register(s)
-        base = eng.generate(prompt, SamplingParams(max_new_tokens=MAX_NEW))
-        want_base = mk(LoRASpec(), params).generate(
+    def mk(b_lora, p):
+        return LLMEngine(cfg, BatchingSpec(
+            max_batch_size=4, max_seq_len=128, paged=True, page_size=16,
+            lora=b_lora), params=p)
+
+    eng = mk(LoRASpec(max_adapters=2, rank=RANK), params)
+    for s in ident_specs:
+        eng._lora.register(s)
+    base = eng.generate(prompt, SamplingParams(max_new_tokens=MAX_NEW))
+    want_base = mk(LoRASpec(), params).generate(
+        prompt, SamplingParams(max_new_tokens=MAX_NEW))
+    if base != want_base:
+        return fail("identity: base traffic diverged")
+    for s in ident_specs:
+        req = eng.submit(prompt, SamplingParams(max_new_tokens=MAX_NEW),
+                         adapter=s.name)
+        while not req.done.is_set():
+            eng.step()
+        got = req.result(5)
+        want = mk(LoRASpec(), merged_params(params, cfg, s)).generate(
             prompt, SamplingParams(max_new_tokens=MAX_NEW))
-        if base != want_base:
-            return fail(f"identity: base traffic diverged (paged={paged})")
-        for s in ident_specs:
-            req = eng.submit(prompt, SamplingParams(max_new_tokens=MAX_NEW),
-                             adapter=s.name)
-            while not req.done.is_set():
-                eng.step()
-            got = req.result(5)
-            want = mk(LoRASpec(), merged_params(params, cfg, s)).generate(
-                prompt, SamplingParams(max_new_tokens=MAX_NEW))
-            if got != want or got == base:
-                return fail(
-                    f"identity: adapter {s.name} (paged={paged}) "
-                    f"got={got} want={want}")
-        eng._lora.assert_quiescent()
-        if paged:
-            eng._allocator.assert_quiescent()
+        if got != want or got == base:
+            return fail(
+                f"identity: adapter {s.name} got={got} want={want}")
+    eng._lora.assert_quiescent()
+    eng._allocator.assert_quiescent()
     result["token_identity"] = "ok"
 
     # ---- 2) degradation band + recompile-free churn
@@ -391,8 +389,8 @@ def chaos_kill_mid_hot_load(cfg, params, result, fail):
         from kubeflow_tpu.core.serving import BatchingSpec, LoRASpec
         from kubeflow_tpu.serve.engine import LLMEngine
         eng = LLMEngine(cfg, BatchingSpec(
-            max_batch_size=4, max_seq_len=128, prefill_buckets=[64],
-            paged=True, page_size=16, decode_steps=4,
+            max_batch_size=4, max_seq_len=128, paged=True, page_size=16,
+            decode_steps=4,
             lora=LoRASpec(max_adapters=4, rank=RANK)), params=params)
         for i in range(4):
             w = init_adapter_weights(jax.random.PRNGKey(100 + i), cfg, RANK)

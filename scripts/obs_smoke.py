@@ -51,8 +51,8 @@ def main() -> int:
     params = init_decoder_params(jax.random.PRNGKey(0), cfg)
     engine = LLMEngine(
         cfg,
-        BatchingSpec(max_batch_size=2, max_seq_len=96, prefill_buckets=[32],
-                     paged=True, page_size=16, chunked_prefill_tokens=16,
+        BatchingSpec(max_batch_size=2, max_seq_len=96, paged=True,
+                     page_size=16, chunked_prefill_tokens=16,
                      decode_steps=4),
         params=params)
     server = ModelServer("obs-smoke", engine, port=0)

@@ -117,7 +117,7 @@ def main() -> int:
     def spec(kv=None, role="unified", impl="auto", host=0):
         return BatchingSpec(
             max_batch_size=4, max_seq_len=128, paged=True, page_size=16,
-            prefill_buckets=[32], chunked_prefill_tokens=16,
+            chunked_prefill_tokens=16,
             decode_steps=4, kv_cache_dtype=kv, role=role,
             paged_attn_impl=impl, host_kv_pages=host,
             prefix_index="radix",

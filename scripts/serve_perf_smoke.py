@@ -4,7 +4,7 @@ thresholded regression check — the serving analogue of the train bench
 gate (ISSUE 11).
 
 Replays the canonical 3-scenario loadgen matrix (uniform Poisson /
-bursty multi-QoS / shared-prefix on the paged prefix-cache engine)
+bursty multi-QoS / shared-prefix)
 open-loop over the FULL protocol path — HTTP SSE against a real
 ``ModelServer``, QoS on the ``X-Kftpu-Qos`` header, trace context on
 ``X-Kftpu-Trace`` — and gates on:
@@ -25,7 +25,7 @@ open-loop over the FULL protocol path — HTTP SSE against a real
   classes in the bursty scenario, and the measured shared-prefix
   overlap within tolerance of the declared fraction;
 - **hygiene**: ``open_spans() == 0`` after every segment (the
-  quiescence invariant), zero leaked KV pages on the paged engine, the
+  quiescence invariant), zero leaked KV pages, the
   ``kftpu_loadgen_*`` report registry passing the metric-name lint and
   the exposition grammar, and ``/debug/traces?slowest=N`` surfacing the
   per-phase rollup.
@@ -66,7 +66,7 @@ PROMPT_LEN = 32
 MAX_NEW = 8
 
 
-def make_server(*, paged: bool):
+def make_server():
     import jax
 
     from kubeflow_tpu.core.serving import BatchingSpec
@@ -79,8 +79,8 @@ def make_server(*, paged: bool):
     params = init_decoder_params(jax.random.PRNGKey(0), cfg)
     engine = LLMEngine(cfg, BatchingSpec(
         max_batch_size=8, max_seq_len=cfg.max_seq_len,
-        prefill_buckets=[32, 64], chunked_prefill_tokens=32,
-        paged=paged, page_size=16, decode_steps=4), params=params)
+        chunked_prefill_tokens=32,
+        paged=True, page_size=16, decode_steps=4), params=params)
     srv = ModelServer("perf-smoke", engine, port=0)
     srv.start()
     return srv, cfg
@@ -177,17 +177,13 @@ def main() -> int:
         prompt_len=PROMPT_LEN, max_new=MAX_NEW, slo_ttft_ms=5000.0)
         if s.name not in ("multi_turn", "multi_adapter")]
 
-    # 1) Measure: per scenario, warm + two measured segments. The
-    #    shared-prefix scenario runs on the paged prefix-cache engine
-    #    (its traffic property is the cache's whole case); the others on
-    #    the dense engine.
+    # 1) Measure: per scenario, warm + two measured segments.
     rows = []
     baseline_rows = []
     candidate_rows = []
     bands: dict = {}
     for sc in matrix:
-        paged = sc.name == "shared_prefix"
-        srv, cfg = make_server(paged=paged)
+        srv, cfg = make_server()
         try:
             warm_server(srv, cfg)
             run_segment(srv, cfg, sc)        # settle: the scenario's own mix
@@ -210,7 +206,7 @@ def main() -> int:
                 # exactly once, so the LAST two segments converge — keep
                 # them and let the spread-derived band tell the truth.
             segs = segs[-2:]
-            if paged and srv.engine.kv_pages_in_use() != 0:
+            if srv.engine.kv_pages_in_use() != 0:
                 return fail(f"{sc.name}: leaked KV pages")
             # /debug/traces?slowest=N must carry the per-phase rollup
             # (the surface the loadgen's breakdown rides in production).
@@ -260,8 +256,7 @@ def main() -> int:
         candidate_rows.append(rep_b)
         rows.append({
             "metric": f"serve_scenario_req_per_sec[tiny,{sc.name},"
-                      f"r{args.rate:g},n{args.requests}"
-                      f"{',paged' if paged else ''}]",
+                      f"r{args.rate:g},n{args.requests}]",
             "value": round((rep_a["req_s"] + rep_b["req_s"]) / 2, 3),
             "unit": "req/s",
             "vs_baseline": 1.0,
@@ -278,7 +273,7 @@ def main() -> int:
 
     # 2) Seeded regression: throttle the dispatch and the gate MUST see
     #    it — req/s down and/or TTFT p95 up beyond every band above.
-    srv, cfg = make_server(paged=False)
+    srv, cfg = make_server()
     try:
         orig_step = srv.engine.step
 

@@ -2113,16 +2113,16 @@ class TestSeededRegressions:
         assert [f.rule for f in fresh] == ["M201"]
 
     def test_dropped_decode_donation_is_caught(self):
-        """Removing the dense decode dispatch's donate_argnums — the 2x-HBM
+        """Removing the decode dispatch's donate_argnums — the 2x-HBM
         carry — produces exactly one S401."""
         fresh = _new_findings(
             "kubeflow_tpu/serve/engine.py",
-            "self._decode_n = jax.jit(_decode_fn, static_argnums=(4, 5),\n"
-            "                                 donate_argnums=(1, 2))",
-            "self._decode_n = jax.jit(_decode_fn, static_argnums=(4, 5))")
+            "            _paged_decode_fn, static_argnums=(5, 6),\n"
+            "            donate_argnums=(1, 2, 3))",
+            "            _paged_decode_fn, static_argnums=(5, 6))")
         assert len(fresh) == 1
         f = fresh[0]
-        assert f.rule == "S401" and "self._decode_n" in f.message
+        assert f.rule == "S401" and "self._paged_decode_n" in f.message
 
     def test_exception_path_page_leak_is_caught(self):
         """A raise-capable call between the page alloc and its ownership
@@ -2179,39 +2179,35 @@ class TestSeededRegressions:
         assert "Router._aux_lock" in f.message and "Router._lock" in f.message
 
     def test_weak_type_scalar_into_decode_dispatch_is_caught(self):
-        """Replacing the dense decode dispatch's PRNG key with a bare
+        """Replacing the decode dispatch's PRNG key with a bare
         Python float — a weak-typed cache entry per dispatch — produces
         exactly one F602."""
         fresh = _new_findings(
             "kubeflow_tpu/serve/engine.py",
-            "                out, self.cache, st = self._decode_n(\n"
-            "                    self.params, self.cache, self._dstate.arrays,"
-            " key, k_steps,\n"
-            "                    mode)",
-            "                out, self.cache, st = self._decode_n(\n"
-            "                    self.params, self.cache, self._dstate.arrays,"
-            " 0.5, k_steps,\n"
-            "                    mode)")
+            "            out, self.cache, st, tbl = self._paged_decode_n(\n"
+            "                self.params, self.cache, self._dstate.arrays,\n"
+            "                self._dstate.table, key, k_steps, mode)",
+            "            out, self.cache, st, tbl = self._paged_decode_n(\n"
+            "                self.params, self.cache, self._dstate.arrays,\n"
+            "                self._dstate.table, 0.5, k_steps, mode)")
         assert len(fresh) == 1
         f = fresh[0]
-        assert f.rule == "F602" and "self._decode_n" in f.message
+        assert f.rule == "F602" and "self._paged_decode_n" in f.message
 
     def test_fresh_tuple_static_arg_is_caught(self):
         """Feeding the decode dispatch's static num_steps position a
         per-call tuple produces exactly one F604."""
         fresh = _new_findings(
             "kubeflow_tpu/serve/engine.py",
-            "                out, self.cache, st = self._decode_n(\n"
-            "                    self.params, self.cache, self._dstate.arrays,"
-            " key, k_steps,\n"
-            "                    mode)",
-            "                out, self.cache, st = self._decode_n(\n"
-            "                    self.params, self.cache, self._dstate.arrays,"
-            " key, (k_steps,),\n"
-            "                    mode)")
+            "            out, self.cache, st, tbl = self._paged_decode_n(\n"
+            "                self.params, self.cache, self._dstate.arrays,\n"
+            "                self._dstate.table, key, k_steps, mode)",
+            "            out, self.cache, st, tbl = self._paged_decode_n(\n"
+            "                self.params, self.cache, self._dstate.arrays,\n"
+            "                self._dstate.table, key, (k_steps,), mode)")
         assert len(fresh) == 1
         f = fresh[0]
-        assert f.rule == "F604" and "self._decode_n" in f.message
+        assert f.rule == "F604" and "self._paged_decode_n" in f.message
 
 
 def _new_findings_prog(relpath: str, old: str, new: str):

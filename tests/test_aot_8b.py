@@ -65,14 +65,18 @@ def test_train_step_8b_compiles_on_v5p16_within_hbm():
 def test_serving_decode_8b_compiles_on_v5e8_within_hbm():
     import sys
     sys.path.insert(0, ".")
-    from scripts.aot_validate_8b import serve_decode_analysis
+    from scripts.aot_validate_8b import (
+        SERVE_BF16, SERVE_POOL, paged_serve_analysis)
 
     _topo("v5e:2x4x1")
-    out = serve_decode_analysis("v5e:2x4x1", 8)
-    # bf16 8B weights sharded 8 ways ≈ 2 GB/chip + KV cache: far under the
+    out = paged_serve_analysis("v5e:2x4x1", 8, model="llama3-8b",
+                               overrides=SERVE_BF16["llama3-8b"],
+                               **SERVE_POOL)
+    # bf16 8B weights sharded 8 ways ≈ 2 GB/chip + KV pool: far under the
     # 16 GB a single v5e chip has — which full replication could never fit.
-    assert out["total_gb"] < 16.0, out
-    assert out["argument_gb"] > 1.5, out
+    for prog in out.values():
+        assert prog["total_gb"] < 16.0, out
+        assert prog["argument_gb"] > 1.5, out
 
 
 # -- Mixtral-8x7B north star (BASELINE.json configs[2]; VERDICT r4 #2) ---------
@@ -125,12 +129,16 @@ def test_serving_decode_mixtral_compiles_on_v5e8_within_hbm():
     cache; single-chip serving could never hold it."""
     import sys
     sys.path.insert(0, ".")
-    from scripts.aot_validate_8b import serve_decode_analysis
+    from scripts.aot_validate_8b import (
+        SERVE_BF16, SERVE_POOL, paged_serve_analysis)
 
     _topo("v5e:2x4x1")
-    out = serve_decode_analysis("v5e:2x4x1", 8, model="mixtral-8x7b")
-    assert out["total_gb"] < 16.0, out
-    assert out["argument_gb"] > 10.0, out    # the real 46.7B resident
+    out = paged_serve_analysis("v5e:2x4x1", 8, model="mixtral-8x7b",
+                               overrides=SERVE_BF16["mixtral-8x7b"],
+                               **SERVE_POOL)
+    for prog in out.values():
+        assert prog["total_gb"] < 16.0, out
+        assert prog["argument_gb"] > 10.0, out    # the real 46.7B resident
 
 
 # -- int8 density (VERDICT r4 #3: AOT-prove the quantization HBM win) ----------
@@ -144,14 +152,18 @@ def test_serving_decode_8b_int8_fits_one_v5e_chip():
     pytrees + per-field shardings)."""
     import sys
     sys.path.insert(0, ".")
-    from scripts.aot_validate_8b import serve_decode_analysis
+    from scripts.aot_validate_8b import (
+        SERVE_BF16, SERVE_POOL, paged_serve_analysis)
 
     _topo("v5e:2x4x1")      # libtpu-presence gate (shared skip semantics)
-    out = serve_decode_analysis(
-        "v5e:1x1x1", 1, model="llama3-8b", quantize="int8", slots=8,
-        max_len=2048, topo_kwargs={"chips_per_host_bounds": [1, 1, 1]})
-    assert out["total_gb"] < 16.0, out
-    assert out["argument_gb"] < 11.0, out    # int8 params ≈ 8 GB + KV
+    out = paged_serve_analysis(
+        "v5e:1x1x1", 1, model="llama3-8b",
+        overrides=SERVE_BF16["llama3-8b"], quantize="int8",
+        topo_kwargs={"chips_per_host_bounds": [1, 1, 1]},
+        **{**SERVE_POOL, "slots": 8, "num_pages": 128})
+    for prog in out.values():
+        assert prog["total_gb"] < 16.0, out
+        assert prog["argument_gb"] < 11.0, out    # int8 params ≈ 8 GB + KV
 
 
 # -- chip_smoke.py's own programs (ISSUE 21 (f)) -------------------------------

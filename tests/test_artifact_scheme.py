@@ -378,7 +378,7 @@ def test_pipeline_trains_publishes_and_serves(live_cp, tmp_path):
                 storage_uri=uri,
                 config={"preset": "tiny", "overrides": {"vocab_size": 512}}),
             batching=BatchingSpec(max_batch_size=2, max_seq_len=64,
-                                  prefill_buckets=[32])))))
+                                  page_size=16, chunked_prefill_tokens=32)))))
     ready = live_cp.wait_for(isvc, "Ready", timeout=240)
     out = _post(ready.status.url + "/v1/completions",
                 {"prompt": "hello", "max_tokens": 4})

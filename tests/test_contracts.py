@@ -56,7 +56,7 @@ def server():
     params = init_decoder_params(jax.random.PRNGKey(0), cfg)
     engine = LLMEngine(
         cfg, BatchingSpec(max_batch_size=2, max_seq_len=96,
-                          prefill_buckets=[32]),
+                          page_size=16, chunked_prefill_tokens=32),
         params=params)
     srv = ModelServer("contract-pin", engine, port=0)
     srv.start()

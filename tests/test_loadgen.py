@@ -472,7 +472,8 @@ def scenario_engine():
     params = init_decoder_params(jax.random.PRNGKey(0), cfg)
     engine = LLMEngine(
         cfg, BatchingSpec(max_batch_size=4, max_seq_len=128,
-                          prefill_buckets=[16, 32], decode_steps=4),
+                          page_size=16, chunked_prefill_tokens=32,
+                          decode_steps=4),
         params=params)
     engine.start()
     yield engine, cfg

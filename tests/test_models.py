@@ -10,7 +10,7 @@ import pytest
 from kubeflow_tpu.models import (
     preset, init_decoder_params, decoder_forward, decoder_loss,
 )
-from kubeflow_tpu.models.decoder import decoder_param_specs, init_kv_caches
+from kubeflow_tpu.models.decoder import decoder_param_specs
 from kubeflow_tpu.parallel.sharding import (
     DEFAULT_RULES, logical_to_mesh_axes, shard_params,
 )
@@ -55,7 +55,10 @@ def test_decode_cache_matches_full_forward():
     params = init_decoder_params(jax.random.PRNGKey(0), cfg)
     toks = jax.random.randint(jax.random.PRNGKey(2), (1, 9), 0, cfg.vocab_size)
     full, _, _ = decoder_forward(params, toks, cfg)
-    cache = init_kv_caches(cfg, 1, 16)
+    shape = (cfg.n_layers, 1, 16, cfg.n_kv_heads, cfg.head_dim)
+    cache = {"k": jnp.zeros(shape, cfg.activation_dtype),
+             "v": jnp.zeros(shape, cfg.activation_dtype),
+             "len": jnp.int32(0)}
     out, cache, _ = decoder_forward(params, toks[:, :6], cfg, kv_caches=cache)
     chunks = [out]
     for i in range(6, 9):

@@ -142,7 +142,7 @@ def test_serving_engine_moe_phase_resolution():
 
     cfg = preset("tiny-moe", moe_impl="dispatch")
     eng = LLMEngine(cfg, BatchingSpec(max_batch_size=2, max_seq_len=32,
-                                      prefill_buckets=[16]))
+                                      page_size=16, chunked_prefill_tokens=16))
     assert eng._cfg_decode.moe_impl == "dense"
     assert eng._cfg_prefill.moe_impl == "dispatch"
     assert eng.cfg.moe_impl == "dispatch"    # model cfg left untouched

@@ -253,7 +253,7 @@ def tiny_engine_server():
     params = init_decoder_params(jax.random.PRNGKey(0), cfg)
     engine = LLMEngine(
         cfg, BatchingSpec(max_batch_size=2, max_seq_len=64,
-                          prefill_buckets=[32]),
+                          page_size=16, chunked_prefill_tokens=32),
         params=params)
     # One completed request so rate/percentile gauges have data.
     req = engine.submit([1, 2, 3], SamplingParams(max_new_tokens=2))

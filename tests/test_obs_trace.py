@@ -155,7 +155,7 @@ def tiny_engine():
     params = init_decoder_params(jax.random.PRNGKey(0), cfg)
     return LLMEngine(
         cfg, BatchingSpec(max_batch_size=2, max_seq_len=64,
-                          prefill_buckets=[32]),
+                          page_size=16, chunked_prefill_tokens=32),
         params=params)
 
 
@@ -245,8 +245,9 @@ def routed_stack(tiny_engine):
     router.start()
     yield router, server
     router.stop()
-    server.httpd.shutdown()
-    server.httpd.server_close()
+    # the whole server, engine included: a scheduler thread left looping
+    # writes engine.* spans into every later profiler capture of the process
+    server.stop()
 
 
 def _post(url: str, body: dict) -> dict:

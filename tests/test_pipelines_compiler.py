@@ -171,6 +171,13 @@ class TestNestedLoopIR:
             "compiled IR drifted from the golden snapshot; if intentional, "
             f"delete {NESTED_GOLDEN} and rerun")
 
+    def test_compiling_twice_gives_the_same_ir(self):
+        """Loop ids are numbered within the pipeline, not by a counter of
+        the process (which made the golden file above depend on what the
+        worker had compiled before it)."""
+        assert to_yaml(compile_pipeline(nested_loops)) == \
+            to_yaml(compile_pipeline(nested_loops))
+
     def test_nested_ir_structure(self):
         ir = compile_pipeline(nested_loops)
         t = ir.tasks["shard_work"]

@@ -90,11 +90,12 @@ def test_engine_int8_generates():
     params = init_decoder_params(jax.random.PRNGKey(2), cfg)
     b = BatchingSpec(max_batch_size=2, max_seq_len=128,
                      weights_dtype="bfloat16", quantize="int8",
-                     decode_steps=4, prefill_buckets=[16])
+                     decode_steps=4, page_size=16, chunked_prefill_tokens=16)
     eng = LLMEngine(cfg, b, params=params)
     ref = LLMEngine(cfg, BatchingSpec(max_batch_size=2, max_seq_len=128,
                                       weights_dtype="bfloat16",
-                                      decode_steps=4, prefill_buckets=[16]),
+                                      decode_steps=4, page_size=16,
+                                      chunked_prefill_tokens=16),
                     params=params)
     sp = SamplingParams(max_new_tokens=12, temperature=0.0)
     out_q = eng.generate([4, 8, 15, 16], sp)
@@ -114,9 +115,6 @@ def test_engine_rejects_bad_knobs():
     cfg = preset("tiny")
     with pytest.raises(ValueError, match="quantize"):
         LLMEngine(cfg, BatchingSpec(quantize="fp4", max_seq_len=128))
-    with pytest.raises(ValueError, match="paged"):
-        LLMEngine(cfg, BatchingSpec(kv_cache_dtype="int8", paged=False,
-                                    max_seq_len=128))
     # pallas + int8 is a SUPPORTED pair now (in-kernel dequant): the old
     # "requires paged_attn_impl=gather" ban is gone.
     eng = LLMEngine(cfg, BatchingSpec(kv_cache_dtype="int8", paged=True,
@@ -262,7 +260,7 @@ def test_tp_sharded_quantized_engine():
     mesh = build_mesh({"model": 2}, jax.devices()[:2])
     b = BatchingSpec(max_batch_size=2, max_seq_len=64,
                      weights_dtype="bfloat16", quantize="int8",
-                     decode_steps=4, prefill_buckets=[16])
+                     decode_steps=4, page_size=16, chunked_prefill_tokens=16)
     eng_tp = LLMEngine(cfg, b, params=params, mesh=mesh)
     eng_1 = LLMEngine(cfg, b, params=params)
     # Per-field shardings really applied: wq's int8 payload is sharded on

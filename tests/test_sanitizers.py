@@ -340,12 +340,15 @@ class TestSteadyStateZeroRecompiles:
         from kubeflow_tpu.serve.engine import LLMEngine
 
         cfg = preset("tiny")
+        # One chunk covers any prompt here, so every chunk program is at
+        # the whole table's context: a prefix hit on the second pass moves
+        # a chunk's start, not its context bucket.
         self._drive(LLMEngine(cfg, BatchingSpec(
-            max_batch_size=2, max_seq_len=64, prefill_buckets=[16])),
+            max_batch_size=2, max_seq_len=64, page_size=16)),
             recompile_wd)
         recompile_wd.reset()
         self._drive(LLMEngine(cfg, BatchingSpec(
-            max_batch_size=2, max_seq_len=64, prefill_buckets=[16],
+            max_batch_size=2, max_seq_len=64, page_size=16,
             speculative=SpeculativeSpec(mode="ngram", k=3))),
             recompile_wd)
 
@@ -398,7 +401,8 @@ class TestEngineWiring:
         def mk():
             return LLMEngine(
                 cfg, BatchingSpec(max_batch_size=1, max_seq_len=32,
-                                  prefill_buckets=[16]), seed=0)
+                                  page_size=16, chunked_prefill_tokens=16),
+                seed=0)
 
         monkeypatch.setenv("KFTPU_SANITIZE", "1")
         assert mk().sanitize is True

@@ -39,7 +39,7 @@ def stack():
         eng = LLMEngine(
             cfg,
             BatchingSpec(max_batch_size=2, max_seq_len=96,
-                         prefill_buckets=[32], paged=True, page_size=16,
+                         paged=True, page_size=16,
                          chunked_prefill_tokens=16, decode_steps=4,
                          # Explicit: every scenario here runs with a decode
                          # round potentially in flight (ISSUE 4) — the
@@ -236,8 +236,8 @@ def test_chaos_halt_with_round_in_flight_reaps_clean():
     params = init_decoder_params(jax.random.PRNGKey(0), cfg)
     eng = LLMEngine(
         cfg,
-        BatchingSpec(max_batch_size=2, max_seq_len=96, prefill_buckets=[32],
-                     paged=True, page_size=16, chunked_prefill_tokens=16,
+        BatchingSpec(max_batch_size=2, max_seq_len=96, paged=True,
+                     page_size=16, chunked_prefill_tokens=16,
                      decode_steps=4, pipelined_decode=True),
         params=params)
     reqs = [eng.submit([i + 1] * 20, SamplingParams(max_new_tokens=60))
@@ -339,7 +339,7 @@ def test_chaos_refcount_sanitizer_kill_mid_traffic(monkeypatch):
         eng = LLMEngine(
             cfg,
             BatchingSpec(max_batch_size=2, max_seq_len=96,
-                         prefill_buckets=[32], paged=True, page_size=16,
+                         paged=True, page_size=16,
                          chunked_prefill_tokens=16, decode_steps=4,
                          pipelined_decode=True),
             params=params)
@@ -400,7 +400,7 @@ def test_chaos_prefill_kill_mid_handoff_unified_fallback(monkeypatch):
         eng = LLMEngine(
             cfg,
             BatchingSpec(max_batch_size=2, max_seq_len=96,
-                         prefill_buckets=[32], paged=True, page_size=16,
+                         paged=True, page_size=16,
                          chunked_prefill_tokens=16, decode_steps=4,
                          role=role),
             params=params)
@@ -538,7 +538,7 @@ def test_chaos_kill_mid_migration(monkeypatch):
         eng = LLMEngine(
             cfg,
             BatchingSpec(max_batch_size=2, max_seq_len=96,
-                         prefill_buckets=[32], paged=True, page_size=16,
+                         paged=True, page_size=16,
                          chunked_prefill_tokens=16, decode_steps=4,
                          host_kv_pages=48, kv_demote_after_s=0.05),
             params=params)
@@ -610,7 +610,7 @@ def test_chaos_int8_prefill_kill_mid_handoff(monkeypatch):
 
     def spec(role):
         return BatchingSpec(max_batch_size=2, max_seq_len=96,
-                            prefill_buckets=[32], paged=True, page_size=16,
+                            paged=True, page_size=16,
                             chunked_prefill_tokens=16, decode_steps=4,
                             kv_cache_dtype="int8", role=role)
 
@@ -692,7 +692,7 @@ def test_chaos_int8_kill_mid_migration(monkeypatch):
 
     def spec():
         return BatchingSpec(max_batch_size=2, max_seq_len=96,
-                            prefill_buckets=[32], paged=True, page_size=16,
+                            paged=True, page_size=16,
                             chunked_prefill_tokens=16, decode_steps=4,
                             kv_cache_dtype="int8",
                             host_kv_pages=48, kv_demote_after_s=0.05)

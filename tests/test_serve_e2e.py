@@ -48,7 +48,7 @@ def test_isvc_serves_through_router_and_recovers(cp):
                             config={"preset": "tiny",
                                     "overrides": {"vocab_size": 512}}),
             batching=BatchingSpec(max_batch_size=2, max_seq_len=64,
-                                  prefill_buckets=[32])))))
+                                  page_size=16, chunked_prefill_tokens=32)))))
     ready = cp.wait_for(isvc, "Ready", timeout=180)
     url = ready.status.url
 
@@ -95,7 +95,7 @@ def test_scale_to_zero_cold_start_e2e(cp):
                                     "overrides": {"vocab_size": 512}}),
             min_replicas=0, max_replicas=1,
             batching=BatchingSpec(max_batch_size=2, max_seq_len=64,
-                                  prefill_buckets=[32])))))
+                                  page_size=16, chunked_prefill_tokens=32)))))
     ready = cp.wait_for(isvc, "Ready", timeout=180)
     url = ready.status.url
     out = _post(url + "/v1/completions", {"prompt": "hi", "max_tokens": 2})
@@ -137,7 +137,7 @@ def test_tensor_parallel_predictor_e2e(cp):
                                     "overrides": {"vocab_size": 512}}),
             parallelism=ParallelismSpec(model=2),
             batching=BatchingSpec(max_batch_size=2, max_seq_len=64,
-                                  prefill_buckets=[32])))))
+                                  page_size=16, chunked_prefill_tokens=32)))))
     ready = cp.wait_for(isvc, "Ready", timeout=240)
     # The replica worker is a 2-chip gang member, not two replicas.
     ws = cp.store.list(Worker, label_selector={

@@ -28,7 +28,7 @@ def engine(cfg, params):
     return LLMEngine(
         cfg,
         BatchingSpec(max_batch_size=4, max_seq_len=96,
-                     prefill_buckets=[16, 32, 64]),
+                     page_size=16, chunked_prefill_tokens=64),
         params=params)
 
 
@@ -98,7 +98,9 @@ def test_stop_token_and_metrics(engine):
 
 def test_background_loop_and_streaming(cfg, params):
     eng = LLMEngine(cfg, BatchingSpec(max_batch_size=2, max_seq_len=64,
-                                      prefill_buckets=[16]), params=params)
+                                      page_size=16,
+                                      chunked_prefill_tokens=16),
+                    params=params)
     eng.start()
     try:
         req = eng.submit([8, 6, 4], SamplingParams(max_new_tokens=5))
@@ -132,7 +134,8 @@ class TestMultiStepDecode:
 
     def make_engine(self, cfg, params, decode_steps):
         return LLMEngine(cfg, BatchingSpec(
-            max_batch_size=4, max_seq_len=96, prefill_buckets=[16, 32, 64],
+            max_batch_size=4, max_seq_len=96, page_size=16,
+            chunked_prefill_tokens=64,
             decode_steps=decode_steps), params=params)
 
     def test_matches_single_step_greedy(self, cfg, params):
@@ -227,7 +230,7 @@ class TestChunkedPrefill:
         params = init_decoder_params(jax.random.PRNGKey(0), cfg)
         return LLMEngine(cfg, BatchingSpec(
             max_batch_size=2, max_seq_len=128,
-            prefill_buckets=[16, 64], chunked_prefill_tokens=chunk),
+            page_size=16, chunked_prefill_tokens=chunk),
             params=params)
 
     def test_matches_one_shot(self):
@@ -309,78 +312,3 @@ class TestChunkedPrefill:
                 break
         assert long_req.done.is_set() and s1.done.is_set() and s2.done.is_set()
 
-
-class TestBatchedPrefill:
-    """Batched prefill (round-5 serving lever): same-bucket one-shot
-    admissions share a dispatch; outputs are exactly the sequential
-    path's (rows are attention-independent)."""
-
-    def _gen_all(self, engine, prompts, max_new=8):
-        from kubeflow_tpu.serve.engine import SamplingParams
-
-        sp = SamplingParams(max_new_tokens=max_new, temperature=0.0)
-        reqs = [engine.submit(list(p), sp) for p in prompts]
-        while not all(r.done.is_set() for r in reqs):
-            engine.step()
-        return [r.output_tokens for r in reqs]
-
-    def test_batched_matches_sequential(self):
-        import jax
-
-        from kubeflow_tpu.core.serving import BatchingSpec
-        from kubeflow_tpu.models.config import preset
-        from kubeflow_tpu.models.decoder import init_decoder_params
-        from kubeflow_tpu.serve.engine import LLMEngine
-
-        cfg = preset("tiny", param_dtype="float32")
-        params = init_decoder_params(jax.random.PRNGKey(0), cfg)
-        prompts = [[3, 1, 4], [1, 5, 9, 2], [6, 5], [3, 5, 8, 9, 7],
-                   [2, 7, 1]]
-
-        def make(batch_max):
-            return LLMEngine(cfg, BatchingSpec(
-                max_batch_size=8, max_seq_len=64, prefill_buckets=[8],
-                prefill_batch_max=batch_max, decode_steps=4), params=params)
-
-        out_b = self._gen_all(make(4), prompts)
-        out_s = self._gen_all(make(1), prompts)
-        assert out_b == out_s
-
-    def test_mixed_buckets_group_separately(self):
-        import jax
-
-        from kubeflow_tpu.core.serving import BatchingSpec
-        from kubeflow_tpu.models.config import preset
-        from kubeflow_tpu.models.decoder import init_decoder_params
-        from kubeflow_tpu.serve.engine import LLMEngine
-
-        cfg = preset("tiny", param_dtype="float32")
-        params = init_decoder_params(jax.random.PRNGKey(1), cfg)
-        eng = LLMEngine(cfg, BatchingSpec(
-            max_batch_size=8, max_seq_len=64, prefill_buckets=[4, 16],
-            prefill_batch_max=4, decode_steps=4), params=params)
-        prompts = [[1, 2], [9, 9, 9, 9, 9, 9], [3], [8, 8, 8, 8, 8]]
-        outs = self._gen_all(eng, prompts)
-        assert all(len(o) == 8 for o in outs)
-
-    def test_dispatch_moe_prefill_stays_unbatched(self):
-        """Co-batched dispatch-MoE prompts would couple through capacity
-        buffers — the engine forces the group size to 1 there."""
-        import jax
-
-        from kubeflow_tpu.core.serving import BatchingSpec
-        from kubeflow_tpu.models.config import preset
-        from kubeflow_tpu.models.decoder import init_decoder_params
-        from kubeflow_tpu.serve.engine import LLMEngine
-
-        cfg = preset("tiny-moe", param_dtype="float32")
-        params = init_decoder_params(jax.random.PRNGKey(2), cfg)
-        eng = LLMEngine(cfg, BatchingSpec(
-            max_batch_size=4, max_seq_len=64, prefill_buckets=[8],
-            prefill_batch_max=4, moe_prefill_impl="dispatch"),
-            params=params)
-        assert eng.prefill_batch_max == 1
-        dense = LLMEngine(cfg, BatchingSpec(
-            max_batch_size=4, max_seq_len=64, prefill_buckets=[8],
-            prefill_batch_max=4, moe_prefill_impl="dense"), params=params)
-        assert dense.prefill_batch_max == 4

@@ -89,7 +89,8 @@ class TestExplainRoute:
         from kubeflow_tpu.serve.server import ModelServer
 
         engine = LLMEngine(cfg, BatchingSpec(max_batch_size=2, max_seq_len=64,
-                                             prefill_buckets=[16]),
+                                             page_size=16,
+                                             chunked_prefill_tokens=16),
                            params=params)
         server = ModelServer(
             "exp", engine,
@@ -116,7 +117,8 @@ class TestExplainRoute:
         from kubeflow_tpu.serve.server import ModelServer
 
         engine = LLMEngine(cfg, BatchingSpec(max_batch_size=2, max_seq_len=32,
-                                             prefill_buckets=[16]),
+                                             page_size=16,
+                                             chunked_prefill_tokens=16),
                            params=params)
         server = ModelServer(
             "exp", engine,
@@ -137,7 +139,8 @@ class TestExplainRoute:
         from kubeflow_tpu.serve.server import ModelServer
 
         engine = LLMEngine(cfg, BatchingSpec(max_batch_size=2, max_seq_len=64,
-                                             prefill_buckets=[16]),
+                                             page_size=16,
+                                             chunked_prefill_tokens=16),
                            params=params)
         server = ModelServer("exp", engine)
         server.start()
@@ -179,7 +182,8 @@ def test_isvc_explainer_e2e(tmp_path):
                                     config={"preset": "tiny",
                                             "overrides": {"vocab_size": 512}}),
                     batching=BatchingSpec(max_batch_size=2, max_seq_len=64,
-                                          prefill_buckets=[32])),
+                                          page_size=16,
+                                          chunked_prefill_tokens=32)),
                 explainer=ExplainerSpec(handler="leave_one_out"))))
         ready = plane.wait_for(isvc, "Ready", timeout=240)
         out = _post(ready.status.url + "/v1/models/exp:explain",
@@ -205,7 +209,8 @@ class TestShardedExplain:
 
         engine = LLMEngine(cfg, BatchingSpec(max_batch_size=2,
                                              max_seq_len=64,
-                                             prefill_buckets=[16]),
+                                             page_size=16,
+                                             chunked_prefill_tokens=16),
                            params=params, mesh=mesh)
         server = ModelServer(
             "exp", engine,
@@ -267,7 +272,8 @@ class TestShardedExplain:
 
         engine = LLMEngine(
             cfg, BatchingSpec(max_batch_size=2, max_seq_len=64,
-                              prefill_buckets=[16], quantize="int8"),
+                              page_size=16, chunked_prefill_tokens=16,
+                              quantize="int8"),
             params=params)
         server = ModelServer(
             "exp", engine,

@@ -47,7 +47,7 @@ def spec(role="unified", *, remote_root=None, prefix=True):
                   kv_remote_after_s=0.05, remote_kv_root=str(remote_root),
                   prefix_index="radix")
     return BatchingSpec(max_batch_size=2, max_seq_len=96,
-                        prefill_buckets=[32], paged=True, page_size=16,
+                        paged=True, page_size=16,
                         chunked_prefill_tokens=16, decode_steps=4,
                         enable_prefix_caching=prefix, role=role, **kw)
 

@@ -23,7 +23,8 @@ def server():
     cfg = preset("tiny", vocab_size=512)
     params = init_decoder_params(jax.random.PRNGKey(0), cfg)
     engine = LLMEngine(cfg, BatchingSpec(
-        max_batch_size=2, max_seq_len=96, prefill_buckets=[16, 32]),
+        max_batch_size=2, max_seq_len=96, page_size=16,
+        chunked_prefill_tokens=32),
         params=params)
     srv = ModelServer("llm", engine, grpc_port=0)
     srv.start()

@@ -1,6 +1,6 @@
 """Disaggregated prefill/decode handoff (ISSUE 12 tentpole): greedy
-token identity across the prefill→handoff→decode boundary on dense AND
-paged backends, the page-ownership protocol (holds released only on
+token identity across the prefill→handoff→decode boundary, the
+page-ownership protocol (holds released only on
 ack, failure/reap paths refcount-balanced), and the wire format."""
 
 import time
@@ -19,16 +19,14 @@ CFG = preset("tiny", vocab_size=512)
 PARAMS = init_decoder_params(jax.random.PRNGKey(0), CFG)
 
 
-def spec(role="unified", paged=False, **kw):
-    base = dict(max_batch_size=2, max_seq_len=96, prefill_buckets=[32],
+def spec(role="unified", paged=True, **kw):
+    base = dict(max_batch_size=2, max_seq_len=96, paged=paged, page_size=16,
                 chunked_prefill_tokens=16, decode_steps=4, role=role)
-    if paged:
-        base.update(paged=True, page_size=16)
     base.update(kw)
     return BatchingSpec(**base)
 
 
-def engine(role="unified", paged=False, **kw):
+def engine(role="unified", paged=True, **kw):
     return LLMEngine(CFG, spec(role=role, paged=paged, **kw), params=PARAMS)
 
 
@@ -51,10 +49,10 @@ def drain(eng, timeout=30.0):
 PROMPTS = [list(range(3, 23)), [7, 9, 11] * 9, list(range(40, 45))]
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+@pytest.mark.parametrize("paged", [True], ids=["paged"])
 def test_greedy_token_identity_across_handoff(paged):
     """The acceptance pin: unified output == prefill→handoff→decode
-    output, token for token, on both KV backends."""
+    output, token for token."""
     uni = engine(paged=paged)
     pre = engine(role="prefill", paged=paged)
     dec = engine(role="decode", paged=paged)
@@ -76,9 +74,8 @@ def test_greedy_token_identity_across_handoff(paged):
         pre.complete_handoff(p_req.id)
     drain(pre)
     drain(dec)
-    if paged:
-        pre._allocator.assert_quiescent()
-        dec._allocator.assert_quiescent()
+    pre._allocator.assert_quiescent()
+    dec._allocator.assert_quiescent()
 
 
 def test_handoff_hold_released_only_on_ack():

@@ -3,7 +3,7 @@ scheduler state + pipelined double-buffered dispatch.
 
 Contracts pinned here:
 - greedy outputs are TOKEN-IDENTICAL with pipelining on and off, across
-  dense, paged, and speculative engines (the pipeline must be invisible to
+  plain and speculative engines (the pipeline must be invisible to
   outputs — only latency moves);
 - steady-state decode rounds perform ZERO full-array host→device uploads
   of scheduler state (counter-asserted: the device_state stats stay at
@@ -13,7 +13,7 @@ Contracts pinned here:
   while a round is in flight masks that round's results — output streams
   never contain post-cancel tokens — and paged-KV refcounts balance;
 - first-token sampling batches per admit round (one fetch for N
-  admissions, chunked and grouped alike);
+  admissions);
 - EngineMetrics surfaces host_gap/dispatch_depth and the model server
   exposes them on /metrics.
 """
@@ -43,11 +43,11 @@ PROMPTS = [[5, 17, 3, 99, 42], list(range(1, 50)), [7] * 20,
            [9, 8, 7, 6, 5, 4]]
 
 
-def make_engine(cfg, params, *, pipelined, paged=False, spec=None,
+def make_engine(cfg, params, *, pipelined, paged=True, spec=None,
                 chunk=32, decode_steps=4, slots=4):
     return LLMEngine(cfg, BatchingSpec(
-        max_batch_size=slots, max_seq_len=128, prefill_buckets=[16, 64],
-        chunked_prefill_tokens=chunk, paged=paged, page_size=16,
+        max_batch_size=slots, max_seq_len=128, chunked_prefill_tokens=chunk,
+        paged=paged, page_size=16,
         decode_steps=decode_steps, pipelined_decode=pipelined,
         speculative=spec or SpeculativeSpec()), params=params)
 
@@ -74,11 +74,6 @@ class TestTokenIdentity:
     @pytest.fixture(scope="class")
     def want(self, cfg, params):
         return gen_all(make_engine(cfg, params, pipelined=False), PROMPTS)
-
-    def test_dense(self, cfg, params, want):
-        eng = make_engine(cfg, params, pipelined=True)
-        assert gen_all(eng, PROMPTS) == want
-        assert eng.decode_rounds > 0
 
     @pytest.mark.slow  # tier-1 budget (ISSUE 20): ~11s; test_spec_paged
     # keeps a fast pipelined-vs-off paged identity check in this class
@@ -131,19 +126,18 @@ class TestDeviceResidentState:
 
     @pytest.mark.slow  # tier-1 budget (ISSUE 12): >10s on the gate host
     def test_full_uploads_stay_at_construction(self, cfg, params):
-        for paged in (False, True):
-            eng = make_engine(cfg, params, pipelined=True, paged=paged)
-            gen_all(eng, PROMPTS)
-            rounds1 = eng.decode_rounds
-            stats1 = dict(eng._dstate.stats)
-            assert rounds1 > 0
-            assert stats1["full_state_uploads"] == 1
-            assert stats1["full_table_uploads"] == (1 if paged else 0)
-            gen_all(eng, PROMPTS)
-            stats2 = eng._dstate.stats
-            assert eng.decode_rounds > rounds1
-            assert stats2["full_state_uploads"] == 1
-            assert stats2["full_table_uploads"] == (1 if paged else 0)
+        eng = make_engine(cfg, params, pipelined=True, paged=True)
+        gen_all(eng, PROMPTS)
+        rounds1 = eng.decode_rounds
+        stats1 = dict(eng._dstate.stats)
+        assert rounds1 > 0
+        assert stats1["full_state_uploads"] == 1
+        assert stats1["full_table_uploads"] == 1
+        gen_all(eng, PROMPTS)
+        stats2 = eng._dstate.stats
+        assert eng.decode_rounds > rounds1
+        assert stats2["full_state_uploads"] == 1
+        assert stats2["full_table_uploads"] == 1
 
     def test_steady_state_rounds_sync_nothing(self, cfg, params):
         """Mid-generation decode rounds (no admissions, no reaps) must not
@@ -185,7 +179,7 @@ class TestPipelinedCancellation:
                 return toks
             toks.append(t)
 
-    @pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+    @pytest.mark.parametrize("paged", [True], ids=["paged"])
     def test_cancel_mid_flight_emits_nothing_after(self, cfg, params,
                                                    paged):
         eng = make_engine(cfg, params, pipelined=True, paged=paged,
@@ -205,9 +199,8 @@ class TestPipelinedCancellation:
             "post-cancel tokens leaked into the output"
         streamed = self._drain_stream(req)
         assert streamed == req.output_tokens
-        if paged:
-            assert eng.kv_pages_in_use() == 0
-            eng._allocator.assert_quiescent()
+        assert eng.kv_pages_in_use() == 0
+        eng._allocator.assert_quiescent()
 
     def test_deadline_mid_flight_frees_pages(self, cfg, params):
         eng = make_engine(cfg, params, pipelined=True, paged=True,
@@ -257,18 +250,6 @@ class TestFirstTokenBatching:
         assert eng.first_token_fetches == before + 1
         run_all(eng, reqs)
 
-    def test_grouped_prefill_shares_one_fetch(self, cfg, params):
-        eng = LLMEngine(cfg, BatchingSpec(
-            max_batch_size=8, max_seq_len=64, prefill_buckets=[8],
-            prefill_batch_max=4, decode_steps=4), params=params)
-        sp = SamplingParams(max_new_tokens=4, temperature=0.0)
-        reqs = [eng.submit([i + 1, i + 2, i + 3], sp) for i in range(4)]
-        before = eng.first_token_fetches
-        eng.step()
-        assert all(r.first_token_time is not None for r in reqs)
-        assert eng.first_token_fetches == before + 1
-        run_all(eng, reqs)
-
     @pytest.mark.slow  # tier-1 budget (ISSUE 20): ~8s; the one-fetch
     # accounting stays fast via test_chunked_completions_share_one_fetch
     def test_batched_first_tokens_match_reference(self, cfg, params):
@@ -285,7 +266,7 @@ class TestTransferGuard:
     move is EXPLICIT (device_put at the sync sites, device_get at the
     designed fetch points). Proven by running mid-generation decode
     rounds under ``jax.transfer_guard("disallow")`` — an implicit
-    transfer anywhere raises — on all three engine flavors, and by the
+    transfer anywhere raises — on plain and speculative engines, and by the
     ``KFTPU_SANITIZE=1`` mode that wires the same guard inside step()."""
 
     def _steady_state_under_guard(self, eng, warmup=6, guarded=5):
@@ -301,10 +282,6 @@ class TestTransferGuard:
         assert eng.decode_rounds > rounds_before
         run_all(eng, [req])
         return req
-
-    def test_dense_steady_state(self, cfg, params):
-        self._steady_state_under_guard(
-            make_engine(cfg, params, pipelined=True))
 
     def test_paged_steady_state(self, cfg, params):
         eng = make_engine(cfg, params, pipelined=True, paged=True)
@@ -322,7 +299,7 @@ class TestTransferGuard:
         still produce reference greedy outputs on every flavor."""
         want = gen_all(make_engine(cfg, params, pipelined=False), PROMPTS)
         monkeypatch.setenv("KFTPU_SANITIZE", "1")
-        for kw in ({}, {"paged": True},
+        for kw in ({"paged": True},
                    {"spec": SpeculativeSpec(mode="ngram", k=4)}):
             eng = make_engine(cfg, params, pipelined=True, **kw)
             assert eng.sanitize

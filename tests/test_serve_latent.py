@@ -473,7 +473,6 @@ class TestRefusals:
         (dict(speculative={"mode": "ngram", "k": 2}), "speculative verify"),
         (dict(lora={"max_adapters": 2, "rank": 4}), "LoRA targets"),
         (dict(quantize="int8"), "weight quantization"),
-        (dict(paged=False), "contiguous slot cache"),
     ])
     def test_each_mechanism_refuses_the_model_by_name(self, cfg, params, kw,
                                                       names):

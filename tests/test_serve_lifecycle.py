@@ -36,8 +36,8 @@ def engine(cfg, params):
     # decode_steps so deadline reaping gets frequent scheduler control.
     return LLMEngine(
         cfg,
-        BatchingSpec(max_batch_size=2, max_seq_len=64, prefill_buckets=[16],
-                     paged=True, page_size=8, chunked_prefill_tokens=8,
+        BatchingSpec(max_batch_size=2, max_seq_len=64, paged=True,
+                     page_size=8, chunked_prefill_tokens=8,
                      decode_steps=4),
         params=params)
 
@@ -179,7 +179,9 @@ def test_queue_delay_histogram_populated(engine):
 
 def test_stop_clean_sets_flag(cfg, params):
     eng = LLMEngine(cfg, BatchingSpec(max_batch_size=1, max_seq_len=32,
-                                      prefill_buckets=[16]), params=params)
+                                      page_size=16,
+                                      chunked_prefill_tokens=16),
+                    params=params)
     assert eng.stopped_clean is None
     eng.start()
     assert eng.stop() is True
@@ -190,7 +192,9 @@ def test_stop_surfaces_wedged_thread(cfg, params):
     """Satellite: a join timeout must not be silent success — the leaked
     thread still holds device buffers."""
     eng = LLMEngine(cfg, BatchingSpec(max_batch_size=1, max_seq_len=32,
-                                      prefill_buckets=[16]), params=params)
+                                      page_size=16,
+                                      chunked_prefill_tokens=16),
+                    params=params)
     release = threading.Event()
     eng._thread = threading.Thread(target=release.wait, daemon=True)
     eng._thread.start()

@@ -2,7 +2,7 @@
 
 The acceptance contract (ISSUE 14): greedy decode under every loaded
 adapter is token-identical to a single-model engine running the MERGED
-weights — dense and paged — while base traffic through the same batched
+weights, while base traffic through the same batched
 dispatch stays identical to a LoRA-free engine. Identity is pinned at
 f32 compute (the factored delta and the merged matmul are mathematically
 equal; bf16 rounds them differently, flipping argmax on near-ties —
@@ -61,11 +61,11 @@ def specs(cfg):
     ]
 
 
-def mk_engine(cfg, params, *, paged: bool, lora_slots: int = 2,
+def mk_engine(cfg, params, *, paged: bool = True, lora_slots: int = 2,
               max_new_room: int = 128, kv_dtype=None):
     b = BatchingSpec(
         max_batch_size=4, max_seq_len=max_new_room,
-        prefill_buckets=[16, 64], paged=paged, page_size=16,
+        paged=paged, page_size=16,
         kv_cache_dtype=kv_dtype,
         lora=(LoRASpec(max_adapters=lora_slots, rank=4,
                        targets=ALL_TARGETS) if lora_slots else LoRASpec()))
@@ -83,31 +83,24 @@ PROMPT = [5, 17, 3, 99, 42, 8, 8, 1]
 
 @pytest.fixture(scope="module")
 def merged_refs(cfg, params, specs):
-    """name -> (dense tokens, paged tokens) from merged-weights engines
-    — the single-model oracle the multi-adapter dispatch must match."""
+    """name -> tokens from a merged-weights engine — the single-model
+    oracle the multi-adapter dispatch must match."""
     out = {}
     for spec in specs:
-        mp = merged_params(params, cfg, spec)
-        outs = []
-        for paged in (False, True):
-            eng = mk_engine(cfg, mp, paged=paged, lora_slots=0)
-            outs.append(eng.generate(PROMPT,
-                                     SamplingParams(max_new_tokens=10)))
-        out[spec.name] = tuple(outs)
+        eng = mk_engine(cfg, merged_params(params, cfg, spec), lora_slots=0)
+        out[spec.name] = eng.generate(PROMPT,
+                                      SamplingParams(max_new_tokens=10))
     return out
 
 
 @pytest.fixture(scope="module")
 def base_refs(cfg, params):
-    out = []
-    for paged in (False, True):
-        eng = mk_engine(cfg, params, paged=paged, lora_slots=0)
-        out.append(eng.generate(PROMPT, SamplingParams(max_new_tokens=10)))
-    return tuple(out)
+    eng = mk_engine(cfg, params, lora_slots=0)
+    return eng.generate(PROMPT, SamplingParams(max_new_tokens=10))
 
 
 class TestTokenIdentity:
-    @pytest.mark.parametrize("paged", [False, True])
+    @pytest.mark.parametrize("paged", [True])
     def test_every_adapter_matches_merged_reference(
             self, cfg, params, specs, merged_refs, base_refs, paged):
         """3 adapters through 2 packed slots (forces a hot-load + LRU
@@ -118,19 +111,18 @@ class TestTokenIdentity:
         for s in specs:
             eng._lora.register(s)
         base = eng.generate(PROMPT, SamplingParams(max_new_tokens=10))
-        assert base == base_refs[int(paged)], \
+        assert base == base_refs, \
             "base traffic must be bit-identical to a LoRA-free engine"
         for s in specs:
             got = run_to_done(eng, eng.submit(
                 PROMPT, SamplingParams(max_new_tokens=10), adapter=s.name))
-            want = merged_refs[s.name][int(paged)]
+            want = merged_refs[s.name]
             assert got == want, (s.name, got, want)
             assert got != base, "adapter must actually change the output"
         assert eng._lora.stats["evictions"] >= 1, \
             "3 adapters over 2 slots must have evicted"
         eng._lora.assert_quiescent()
-        if paged:
-            eng._allocator.assert_quiescent()
+        eng._allocator.assert_quiescent()
 
     @pytest.mark.slow  # tier-1 budget: 3 merged-reference engines on an int8 pool
     def test_int8_kv_every_adapter_matches_merged_reference(
@@ -179,9 +171,9 @@ class TestTokenIdentity:
         ]
         while not all(r.done.is_set() for r in reqs):
             eng.step()
-        assert reqs[0].output_tokens == list(base_refs[1])
-        assert reqs[1].output_tokens == list(merged_refs["tenant-a"][1])
-        assert reqs[2].output_tokens == list(merged_refs["tenant-b"][1])
+        assert reqs[0].output_tokens == list(base_refs)
+        assert reqs[1].output_tokens == list(merged_refs["tenant-a"])
+        assert reqs[2].output_tokens == list(merged_refs["tenant-b"])
         eng._lora.assert_quiescent()
         eng._allocator.assert_quiescent()
 
@@ -353,11 +345,11 @@ class TestArtifactRoundTrip:
 
 class TestEngineLifecycle:
     def test_submit_unknown_adapter_404s(self, cfg, params):
-        eng = mk_engine(cfg, params, paged=False, lora_slots=2)
+        eng = mk_engine(cfg, params, lora_slots=2)
         with pytest.raises(KeyError):
             eng.submit(PROMPT, adapter="nobody")
         # LoRA-free engines reject every adapter id the same way.
-        bare = mk_engine(cfg, params, paged=False, lora_slots=0)
+        bare = mk_engine(cfg, params, lora_slots=0)
         with pytest.raises(KeyError):
             bare.submit(PROMPT, adapter="tenant-a")
 
@@ -365,7 +357,7 @@ class TestEngineLifecycle:
         """Every adapter slot referenced by a live request: the next
         adapter's request WAITS (requeued, not failed) and completes
         once a slot drains."""
-        eng = mk_engine(cfg, params, paged=False, lora_slots=1)
+        eng = mk_engine(cfg, params, lora_slots=1)
         for s in specs[:2]:
             eng._lora.register(s)
         r1 = eng.submit(PROMPT, SamplingParams(max_new_tokens=6),
@@ -425,7 +417,7 @@ class TestRoutingSignals:
         from kubeflow_tpu.obs.registry import parse_exposition
         from kubeflow_tpu.serve.server import serving_metrics_registry
 
-        eng = mk_engine(cfg, params, paged=False, lora_slots=2)
+        eng = mk_engine(cfg, params, lora_slots=2)
         eng._lora.register(specs[0])
         run_to_done(eng, eng.submit(PROMPT,
                                     SamplingParams(max_new_tokens=4),
@@ -438,7 +430,7 @@ class TestRoutingSignals:
         assert samples[("kftpu_engine_adapter_evictions_total", None)] == 0
         # LoRA-free engines still render the series (0 / no labels) so
         # the loadgen's ATTRIBUTION_SERIES pin holds fleet-wide.
-        bare = mk_engine(cfg, params, paged=False, lora_slots=0)
+        bare = mk_engine(cfg, params, lora_slots=0)
         names = {n for n, _, _ in parse_exposition(
             serving_metrics_registry([("m", bare)]).render())}
         assert "kftpu_engine_adapters_resident" in names
@@ -528,7 +520,7 @@ class TestServerRouting:
     def server(self, cfg, params, specs):
         from kubeflow_tpu.serve.server import ModelServer
 
-        eng = mk_engine(cfg, params, paged=False, lora_slots=2)
+        eng = mk_engine(cfg, params, lora_slots=2)
         for s in specs[:2]:
             eng._lora.register(s)
         srv = ModelServer("base", eng, port=0)

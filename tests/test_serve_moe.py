@@ -31,7 +31,7 @@ def _engine(cfg, params, **knobs):
     return LLMEngine(
         cfg,
         BatchingSpec(max_batch_size=4, max_seq_len=96,
-                     prefill_buckets=[16, 32], **knobs),
+                     page_size=16, chunked_prefill_tokens=32, **knobs),
         params=params)
 
 

@@ -41,8 +41,8 @@ def engine(cfg, params):
     # Paged so every scenario also audits page-refcount balance.
     return LLMEngine(
         cfg,
-        BatchingSpec(max_batch_size=2, max_seq_len=64, prefill_buckets=[16],
-                     paged=True, page_size=8, chunked_prefill_tokens=8,
+        BatchingSpec(max_batch_size=2, max_seq_len=64, paged=True,
+                     page_size=8, chunked_prefill_tokens=8,
                      decode_steps=4),
         params=params)
 
@@ -267,8 +267,8 @@ def served(cfg, params):
 
     eng = LLMEngine(
         cfg,
-        BatchingSpec(max_batch_size=2, max_seq_len=64, prefill_buckets=[16],
-                     paged=True, page_size=8, chunked_prefill_tokens=8,
+        BatchingSpec(max_batch_size=2, max_seq_len=64, paged=True,
+                     page_size=8, chunked_prefill_tokens=8,
                      decode_steps=4),
         params=params)
     srv = ModelServer("qos-svc", eng, port=0)

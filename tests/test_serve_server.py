@@ -22,7 +22,7 @@ def server():
     params = init_decoder_params(jax.random.PRNGKey(0), cfg)
     engine = LLMEngine(
         cfg, BatchingSpec(max_batch_size=4, max_seq_len=96,
-                          prefill_buckets=[32, 64]),
+                          page_size=16, chunked_prefill_tokens=64),
         params=params)
     srv = ModelServer("demo", engine, port=0)
     srv.start()
@@ -192,7 +192,8 @@ def test_overload_returns_429_with_retry_after():
     params = init_decoder_params(jax.random.PRNGKey(1), cfg)
     engine = LLMEngine(
         cfg, BatchingSpec(max_batch_size=1, max_seq_len=64,
-                          prefill_buckets=[32], max_queue=1),
+                          page_size=16, chunked_prefill_tokens=32,
+                          max_queue=1),
         params=params)
     srv = ModelServer("jam", engine, port=0)
     srv.start()
@@ -268,9 +269,11 @@ class TestMultiModel:
 
         repo = ModelRepository(max_loaded=1)   # force evictions
         repo.register("alpha", preset("tiny"), batching=BatchingSpec(
-            max_batch_size=2, max_seq_len=64, prefill_buckets=[16]))
+            max_batch_size=2, max_seq_len=64, page_size=16,
+            chunked_prefill_tokens=16))
         repo.register("beta", preset("tiny-gemma"), batching=BatchingSpec(
-            max_batch_size=2, max_seq_len=64, prefill_buckets=[16]))
+            max_batch_size=2, max_seq_len=64, page_size=16,
+            chunked_prefill_tokens=16))
         srv = ModelServer("alpha", repository=repo, port=0)
         srv.start()
         yield srv
@@ -346,7 +349,8 @@ class TestTransformer:
         cfg = preset("tiny", vocab_size=512)
         params = init_decoder_params(jax.random.PRNGKey(0), cfg)
         engine = LLMEngine(cfg, BatchingSpec(max_batch_size=2, max_seq_len=64,
-                                             prefill_buckets=[16]),
+                                             page_size=16,
+                                             chunked_prefill_tokens=16),
                            params=params)
         srv = ModelServer("t", engine, transformer=upcase_transformer, port=0)
         srv.start()

@@ -110,6 +110,33 @@ class TestNotebookSession:
         assert exec_code(sock, "print('back')")["ok"]
 
 
+def test_socket_path_longer_than_an_af_unix_address(tmp_path):
+    """A notebook directory deeper than the 107 bytes an AF_UNIX address
+    holds (pytest-xdist's ``popen-gwN`` level was enough): the session
+    binds and the client connects through the directory's descriptor."""
+    import threading
+
+    from kubeflow_tpu.workspace import session_main as sm
+
+    deep = tmp_path / ("d" * 120)
+    deep.mkdir()
+    sock = str(deep / "kernel.sock")
+    assert len(sock) > 107
+    with sm.unix_address(sock) as address:
+        srv = sm._Server(address, sm._Handler)
+    srv.activity_file = str(deep / "activity")
+    srv.user_globals = {}
+    threading.Thread(target=srv.serve_forever,
+                     kwargs={"poll_interval": 0.05}, daemon=True).start()
+    try:
+        assert os.path.exists(sock)
+        res = exec_code(sock, "20 + 22")
+        assert res["ok"] and res["output"].strip() == "42"
+    finally:
+        srv.shutdown()
+        srv.server_close()
+
+
 class TestPodDefaults:
     def test_merge_semantics(self):
         pds = [
