@@ -439,9 +439,13 @@ def serve_branches(programs: dict, *, mesh: bool) -> dict:
               if k.startswith("paged_chunk_prefill[")}
     check(decode and chunks,
           f"no paged decode / chunk-prefill program ran: {sorted(programs)}")
+    # A round's length is the scheduler's choice under its cap; the
+    # engine compiles and runs every length it can choose when it is built.
     steps = BATCHING["decode_steps"]
-    check(any(k.startswith(f"paged_decode[{steps},") for k in decode),
-          f"no {steps}-step decode dispatch ran: {sorted(decode)}")
+    check(all(any(k.startswith(f"paged_decode[{n},") for k in decode)
+              for n in (1, steps)),
+          f"the decode program is not there at one step and at its cap, "
+          f"{steps}: {sorted(decode)}")
 
     def has(kernels, names):
         return any(n in kernels for n in names)

@@ -267,16 +267,21 @@ class BatchingSpec(BaseModel):
     # Every admission prefills in chunks of this many tokens (a multiple
     # of page_size: chunk boundaries are page boundaries).
     chunked_prefill_tokens: int = 512
-    # Decode steps per device dispatch: sampling runs on-device and up to
-    # this many tokens emit per host round-trip (amortizes dispatch latency;
-    # early-exits when all slots finish). 1 = one step per dispatch.
-    # 32 beat 16 by +14-17% req/s in order-reversed on-chip A/Bs (the
-    # dispatch floor dominates at this model size).
+    # The most decode steps one device dispatch (a round) may run while
+    # no prefill is in flight. A round is one program: sampling runs
+    # on-device, the round's tokens reach the host together when it is
+    # fetched, and a request that arrives waits behind the rounds in
+    # flight. A CAP, not a length: with pipelined_decode the scheduler
+    # dispatches the SHORTEST round whose device time hides the host's
+    # own time an iteration, from what it measures of both
+    # (serve/pacing.py): one step where the host is a few milliseconds
+    # from the chip, up to this many where a dispatch costs more than a
+    # step (a tunnel). 1 = always one step a dispatch.
     decode_steps: int = 32
-    # Decode steps per dispatch WHILE a chunked prefill is in flight: the
-    # prefill's next chunk waits at most this many decode steps (TPOT-spike
-    # bound for running streams vs dispatch amortization; 1 = the old
-    # strict interleave, which costs concurrent paged traffic ~40% req/s).
+    # The same cap WHILE a chunked prefill is in flight (the smaller of
+    # the two binds): the prefill's next chunk and the running streams'
+    # next tokens wait for the round, so this bounds both at this many
+    # steps however slow the host is.
     prefill_interleave_steps: int = 8
     # Pipelined decode dispatch (hot-loop host-overhead elimination):
     # dispatch round N+1 before consuming round N's tokens, so

@@ -73,6 +73,7 @@ from kubeflow_tpu.core.serving import (
     BatchingSpec, QOS_DEFAULT, QOS_PRIORITY,
 )
 from kubeflow_tpu.serve.device_state import DEAD_SLOT, DecodeState
+from kubeflow_tpu.serve.pacing import RoundPacer, decode_ladder
 from kubeflow_tpu.serve.paged import (
     PageAllocator, PagePoolExhausted, context_bucket, paged_chunk_prefill,
     paged_decode_multi, pool_bytes_per_token, pool_planes,
@@ -297,6 +298,10 @@ class _InflightRound:
     k_steps: int
     gap_ms: Optional[float]             # host gap preceding this dispatch
     round_id: int = 0                   # ties the dispatch span to its fetch
+    # the iteration that dispatched it sent no prefill program before it:
+    # the device runs nothing but this round between the last one's end and
+    # its own
+    alone: bool = False
 
 
 def _pin2(out, pin):
@@ -937,12 +942,16 @@ class LLMEngine:
             # drops the write — a no-op dispatch.
             self._kv_copy_pages([0], [-1])
         self._sampler = jax.jit(_sample_batch, static_argnums=(5,))
-        # K decode steps per dispatch amortizes host round-trip latency
-        # (sampling happens on-device; the while_loop exits early when every
-        # slot finishes). num_steps and sample_mode are static — a handful
-        # of traces (K/1 × greedy/plain/full) cover all traffic.
+        # Steps a decode dispatch: sampling happens on-device, and the
+        # while_loop exits early when every slot finishes. The two options
+        # are CAPS; the length of a round is the scheduler's choice from
+        # its own measurements (serve/pacing.py), among the ladder's
+        # lengths. num_steps and sample_mode are static: one program a
+        # ladder length and sampling mode.
         self.decode_steps = max(1, int(b.decode_steps))
         self.prefill_interleave_steps = max(1, int(b.prefill_interleave_steps))
+        self._pacer = RoundPacer(decode_ladder(  # lockfree: scheduler-confined
+            self.decode_steps, self.prefill_interleave_steps))
 
         if on_tpu:
             # What the chip is given, per program variant, for
@@ -1084,6 +1093,16 @@ class LLMEngine:
         self._prefill_programs_dispatched = 0   # lockfree: scheduler-confined counter
         self._prefill_chunks_dispatched = 0     # lockfree: scheduler-confined counter
         self._prefill_tokens_dispatched = 0     # lockfree: scheduler-confined counter
+        # The scheduler's own time: seconds of its iterations it was not
+        # blocked on the device (the pacer's input, summed), the blocked
+        # seconds themselves, and the rounds left at their cap.
+        self._sched_host_busy_sum_s = 0.0       # lockfree: scheduler-confined counter
+        self._blocked_s = 0.0                   # lockfree: scheduler-confined counter
+        self._decode_rounds_at_cap = 0          # lockfree: scheduler-confined counter
+        # Of the scheduler iteration under way: the prefill programs
+        # dispatched when it began, the length of the round it consumed.
+        self._programs_at_step = 0              # lockfree: scheduler-confined
+        self._consumed_k: Optional[int] = None  # lockfree: scheduler-confined
         self.waiting: "queue.Queue[Request]" = queue.Queue()
         self.metrics = EngineMetrics()
         # Bounded admission + queue-delay budget (load shedding): see
@@ -1119,6 +1138,20 @@ class LLMEngine:
         self.stopped_clean: Optional[bool] = None
         if self._chunk_rows > 1:
             self._warm_chunk_rows()
+        self._warm_decode_ladder()
+
+    def _warm_decode_ladder(self) -> None:
+        """Compile and run once, now, the greedy decode program at every
+        length of the ladder, over DEAD rows (no live slot: the while_loop
+        runs zero steps and writes nothing). Which lengths traffic reaches
+        depends on what the scheduler measures, so no warm-up of a caller's
+        can be relied on to reach them all; the program set is the engine's
+        own, and fixed from here on. The other sampling modes compile at
+        their first use. The key is not drawn from: a sampled stream is
+        what it was."""
+        for k in self._pacer.ladder:
+            jax.block_until_ready(
+                self._dispatch_decode(k, "greedy", self._rng))
 
     def _warm_chunk_rows(self) -> None:
         """Compile and run once, now, the program over several prompts'
@@ -1214,6 +1247,13 @@ class LLMEngine:
             # sum of k_steps; tokens the consumed rounds handed to requests
             "decode_steps_dispatched": self._decode_steps_dispatched,
             "decode_tokens_emitted": self._decode_tokens_emitted,
+            # rounds dispatched at the cap in force (the length the two
+            # options set), not shorter by the scheduler's choice
+            "decode_rounds_at_cap": self._decode_rounds_at_cap,
+            # the scheduler iterations' wall time less the time blocked
+            # fetching from the device: the host's own share, and what a
+            # round's length is chosen to hide
+            "sched_host_busy_sum_s": self._sched_host_busy_sum_s,
             # cache rows the dispatched steps attend to, summed over the
             # live slots and the steps of every round
             "decode_context_tokens": self._decode_context_tokens,
@@ -1519,7 +1559,7 @@ class LLMEngine:
             # Blocks until the prefill is done, which queues behind the
             # decode round in flight: a wait for the device like the
             # round's own fetch, and named like it.
-            with hot_span(prof.ENGINE_FETCH, first=n):
+            with hot_span(prof.ENGINE_FETCH, first=n), self._blocked():
                 vals = jax.device_get(firsts)
             self.first_token_fetches += 1
             for j, (req, slot_idx, plen, _) in enumerate(items):
@@ -2512,7 +2552,7 @@ class LLMEngine:
             return emitted + self._spec_decode_once(active)
         dispatched = False
         if active:
-            dispatched = self._dispatch_round(active)
+            dispatched = self._dispatch_round(active, paced=self.pipelined)
         # Pipelined: leave the just-dispatched round in flight and consume
         # only the previous one; unpipelined (and trailing) rounds drain.
         keep = 1 if (self.pipelined and dispatched) else 0
@@ -2543,17 +2583,20 @@ class LLMEngine:
         if self._dstate.dirty_rows:
             self._dstate.sync_rows(lambda i: self._table[i])
 
-    def _dispatch_round(self, active) -> bool:  # hot-loop
+    def _dispatch_round(self, active, paced: bool = False) -> bool:  # hot-loop
         """Enqueue one multi-step decode dispatch over the device-resident
         state (no host blocking — JAX async dispatch). Returns False when
-        page-pool pressure preempted every candidate slot."""
-        # While a chunked prefill is in flight, decode still multi-steps —
-        # just with a smaller K: hard-capping at 1 let concurrent paged
-        # traffic (where EVERY admission chunks) pay a full dispatch
-        # round-trip per token, measured −40% req/s. The cap bounds the
-        # waiting chunk's TPOT spike to K steps instead of the full K=16.
-        k_steps = (min(self.decode_steps, self.prefill_interleave_steps)
-                   if self._chunkings else self.decode_steps)
+        page-pool pressure preempted every candidate slot.
+
+        The two options cap the round: ``decode_steps``, and the smaller of
+        it and ``prefill_interleave_steps`` while a chunked prefill is in
+        flight (its next chunk waits for the round). A ``paced`` round, one
+        the pipeline overlaps with the host's work, is the shortest length
+        of the ladder that hides that work (``RoundPacer``); a round that is
+        consumed at once hides nothing and runs at its cap."""
+        cap = (min(self.decode_steps, self.prefill_interleave_steps)
+               if self._chunkings else self.decode_steps)
+        k_steps = self._pacer.choose(cap) if paced else cap
         # With rounds in flight the device may already be this many steps
         # past the host's slot lengths — page pre-allocation must cover
         # the stale window too or a mid-dispatch write lands unmapped.
@@ -2601,19 +2644,20 @@ class LLMEngine:
             for _, s in active)
         with hot_span(prof.ENGINE_DECODE_DISPATCH, round=round_id,
                       k_steps=k_steps, live=len(active), context=context):
-            out = self._dispatch_decode(k_steps, mode)
+            out = self._dispatch_decode(k_steps, mode, self._next_key())
         self.decode_rounds += 1
         self._decode_steps_dispatched += k_steps
+        self._decode_rounds_at_cap += k_steps == cap
         self._decode_context_tokens += context
         self._rounds.append(_InflightRound(
             out=out, active=list(active), k_steps=k_steps,
-            gap_ms=None if gap is None else gap * 1e3, round_id=round_id))
+            gap_ms=None if gap is None else gap * 1e3, round_id=round_id,
+            alone=self._sent_no_prefill()))
         return True
 
-    def _dispatch_decode(self, k_steps: int, mode: str):  # hot-loop
+    def _dispatch_decode(self, k_steps: int, mode: str, key):  # hot-loop
         """Enqueue the decode program over the device-resident state and
         adopt the state it returns; returns the token buffer's handle."""
-        key = self._next_key()
         if self._lora is not None:
             out, self.cache, st, tbl = self._paged_decode_n(
                 self.params, self.cache, self._dstate.arrays,
@@ -2632,9 +2676,18 @@ class LLMEngine:
         re-admitted) are MASKED — a cancelled request's output stream never
         contains post-cancel tokens. Returns tokens emitted."""
         rnd = self._rounds.pop(0)
-        with hot_span(prof.ENGINE_FETCH, round=rnd.round_id):
+        with hot_span(prof.ENGINE_FETCH, round=rnd.round_id), self._blocked():
             out = np.asarray(jax.device_get(rnd.out))  # sync-point: the pipeline's one designed fetch per round
-        self._last_ready_t = time.monotonic()
+        now = time.monotonic()
+        self._consumed_k = rnd.k_steps
+        if rnd.alone and self._rounds and self._last_ready_t is not None:
+            # The next round was queued before this one landed and nothing
+            # else ran between the last round's end and this one's: the
+            # spacing of the two ready times is this round's steps.
+            steps = int((out >= 0).any(axis=0).sum())
+            if steps:
+                self._pacer.note_step((now - self._last_ready_t) / steps)
+        self._last_ready_t = now
         with hot_span(prof.ENGINE_EMIT, round=rnd.round_id):
             emitted = self._emit_round(rnd, out)
         self._decode_tokens_emitted += emitted
@@ -2775,7 +2828,8 @@ class LLMEngine:
             jnp.asarray(lengths), jnp.asarray(live))
         self.cache = {n: cache_out[n] for n in cache_out if n != "table"}
         self._dstate.adopt(self._dstate.arrays, cache_out["table"])
-        greedy = np.asarray(jax.device_get(greedy))  # sync-point: greedy verification happens host-side
+        with self._blocked():
+            greedy = np.asarray(jax.device_get(greedy))  # sync-point: greedy verification happens host-side
         verify_s = time.monotonic() - t1
         emitted = 0
         for i, s in active:
@@ -2865,7 +2919,8 @@ class LLMEngine:
         out, self._draft_cache = self._draft_propose_n(
             self._draft_params, self._draft_cache, jnp.asarray(deltas),
             jnp.asarray(dlens), jnp.asarray(dpos), jnp.asarray(live), steps)
-        out = np.asarray(jax.device_get(out))  # sync-point: drafts are proposed host-side
+        with self._blocked():
+            out = np.asarray(jax.device_get(out))  # sync-point: drafts are proposed host-side
         drafts: dict[int, list[int]] = {}
         for i, s in active:
             first = int(dlens[i]) - 1    # step that predicts past the ctx
@@ -2891,6 +2946,16 @@ class LLMEngine:
         self._dstate.mark_row(idx)
         self._allocator.free(drop)
 
+    @contextlib.contextmanager
+    def _blocked(self):
+        """Around a fetch the scheduler waits for the device in: the wait is
+        not the host's own time (``sched_host_busy_sum_s``)."""
+        t0 = time.monotonic()
+        try:
+            yield
+        finally:
+            self._blocked_s += time.monotonic() - t0
+
     def _transfer_guard(self):
         """``jax.transfer_guard("disallow")`` in sanitize mode: implicit
         transfers (a stray numpy array riding into a dispatch — the PR-4
@@ -2910,6 +2975,9 @@ class LLMEngine:
         round in flight). Under ``KFTPU_SANITIZE=1`` the decode pass runs
         with implicit transfers disallowed — the runtime half of the
         static device-hygiene rules."""
+        t0, blocked0 = time.monotonic(), self._blocked_s
+        self._programs_at_step = self._prefill_programs_dispatched
+        self._consumed_k = None
         with hot_span(prof.ENGINE_REAP):
             n = self._reap_abandoned() + self._enforce_queue_bound() \
                 + self._drain_handoff_releases()
@@ -2931,7 +2999,21 @@ class LLMEngine:
             # Idle: the next round's host-gap sample would span the idle
             # wait, not the hot loop.
             self._last_ready_t = None
+        # The host's own time this iteration: what a round must hide. Its
+        # emit loop, and the handler threads it wakes, grow with the round
+        # it consumed: filed under that length. An iteration that sent a
+        # prefill program is no sample: its dispatch and first tokens cost
+        # the host more, and the device has the chunk's time to spend on it.
+        host_s = time.monotonic() - t0 - (self._blocked_s - blocked0)
+        self._sched_host_busy_sum_s += host_s
+        if self._consumed_k is not None and self._sent_no_prefill():
+            self._pacer.note_host(self._consumed_k, host_s)
         return n
+
+    def _sent_no_prefill(self) -> bool:
+        """No prefill program has gone to the device in the scheduler
+        iteration under way."""
+        return self._prefill_programs_dispatched == self._programs_at_step
 
     # -- background loop -------------------------------------------------------
 

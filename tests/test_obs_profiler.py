@@ -151,8 +151,8 @@ ENGINE_KEYS = {
     "host_gap_n", "preemptions", "requests_shed", "requests_completed",
     "tokens_generated", "decode_rounds", "first_token_fetches",
     "prefill_phase_sum_s", "prefill_phase_n", "decode_steps_dispatched",
-    "decode_tokens_emitted", "decode_context_tokens",
-    "prefill_programs_dispatched", "prefill_chunks_dispatched",
+    "decode_tokens_emitted", "decode_context_tokens", "decode_rounds_at_cap",
+    "sched_host_busy_sum_s", "prefill_programs_dispatched", "prefill_chunks_dispatched",
     "prefill_tokens_dispatched", "kv_bytes_per_token", "kv_pool_bytes"}
 ENGINE_CONSTANTS = {"slots", "kv_bytes_per_token", "kv_pool_bytes"}
 
