@@ -284,6 +284,7 @@ class _Chunking:
     slot: int
     pos: int              # next prompt position to prefill
     stalls: int = 0       # consecutive page-starved attempts
+    turn: int = -1        # the admit pass of its last turn (_advance_chunked)
 
 
 @dataclasses.dataclass
@@ -1093,6 +1094,11 @@ class LLMEngine:
         self._prefill_programs_dispatched = 0   # lockfree: scheduler-confined counter
         self._prefill_chunks_dispatched = 0     # lockfree: scheduler-confined counter
         self._prefill_tokens_dispatched = 0     # lockfree: scheduler-confined counter
+        # Admit passes that sent a prefill program; chunks that were due in
+        # a pass and waited for a later one (its budget of programs spent).
+        self._prefill_passes = 0                # lockfree: scheduler-confined counter
+        self._prefill_chunks_deferred = 0       # lockfree: scheduler-confined counter
+        self._admit_pass = 0                    # lockfree: scheduler-confined
         # The scheduler's own time: seconds of its iterations it was not
         # blocked on the device (the pacer's input, summed), the blocked
         # seconds themselves, and the rounds left at their cap.
@@ -1263,6 +1269,12 @@ class LLMEngine:
             "prefill_programs_dispatched": self._prefill_programs_dispatched,
             "prefill_chunks_dispatched": self._prefill_chunks_dispatched,
             "prefill_tokens_dispatched": self._prefill_tokens_dispatched,
+            # scheduler iterations that sent a prefill program (programs
+            # over passes: the programs every live stream waited for at
+            # once), and the chunks that were due in a pass and waited for
+            # a later one because its budget of programs was spent
+            "prefill_passes": self._prefill_passes,
+            "prefill_chunks_deferred": self._prefill_chunks_deferred,
             # constants: content bytes a token holds over all layers of
             # the cache, and the cache's size on the device
             "kv_bytes_per_token": self._kv_bytes_per_token,
@@ -1556,9 +1568,14 @@ class LLMEngine:
                 jnp.asarray([p.top_k for p in padded], jnp.int32),
                 jnp.asarray([p.top_p for p in padded], jnp.float32),
                 _mode_for(params_list))
-            # Blocks until the prefill is done, which queues behind the
-            # decode round in flight: a wait for the device like the
-            # round's own fetch, and named like it.
+            # The fetch below blocks until the prefill is done, and that
+            # queues behind the decode round in flight: the round's tokens
+            # are ready first. They go out before the wait, not after it,
+            # or every live stream waits a second chunk's time for a token
+            # the device has had all along.
+            self._consume_rounds()
+            # A wait for the device like the round's own fetch, and named
+            # like it.
             with hot_span(prof.ENGINE_FETCH, first=n), self._blocked():
                 vals = jax.device_get(firsts)
             self.first_token_fetches += 1
@@ -1695,16 +1712,26 @@ class LLMEngine:
                 (req, ch.slot, plen,
                  logits[r, real - 1] if rows > 1 else logits[real - 1]))
 
-    def _advance_chunked(self, due: "Optional[list[_Chunking]]" = None) -> int:
-        """One chunk of every in-flight chunked prefill in ``due`` (all of
+    def _advance_chunked(self, due: "Optional[list[_Chunking]]" = None,
+                         programs: Optional[int] = None) -> int:
+        """One chunk of the in-flight chunked prefills in ``due`` (all of
         them unless given; decode steps run between calls — that's the whole
-        point), as few programs as the engine has rows for: where it built
-        the program over several prompts' chunks, all of them go to the
-        device together and every weight is read once for the pass. A
-        prefill whose pages cannot be had waits for a later pass and holds
-        nobody back. Returns the chunks dispatched."""
-        ready = [ch for ch in (list(self._chunkings) if due is None else due)
-                 if self._reserve_chunk_pages(ch)]
+        point), in that order, as few programs as the engine has rows for:
+        where it built the program over several prompts' chunks, they go to
+        the device together and every weight is read once for the pass. At
+        most ``programs`` programs where given: the prefills past that have
+        no turn in this pass. A prefill whose pages cannot be had waits for
+        a later pass, fills no row and holds nobody back. Returns the chunks
+        dispatched."""
+        due = list(self._chunkings) if due is None else due
+        room = len(due) if programs is None else programs * self._chunk_rows
+        ready: list[_Chunking] = []
+        for ch in due:
+            if len(ready) == room:
+                break
+            ch.turn = self._admit_pass
+            if self._reserve_chunk_pages(ch):
+                ready.append(ch)
         for i in range(0, len(ready), self._chunk_rows):
             self._dispatch_chunks(ready[i:i + self._chunk_rows])
         return len(ready)
@@ -1867,11 +1894,34 @@ class LLMEngine:
             return self._note_admitted(req)
         return None
 
+    def _prefill_budget(self) -> Optional[int]:
+        """The prefill programs one admit pass may send: one for each decode
+        step of the round it stands in front of, while a decode stream is
+        live. Every live stream waits for the pass's programs and then for
+        the round, so its tokens are never further apart than the round and
+        as many programs as the round has steps; the work is the same, in
+        another order. None where no slot is live (a cold engine, the
+        prefill role of a disaggregated pair, the first pass after
+        idleness): nobody waits for the pass."""
+        if all(s is None for s in self.slots):
+            return None
+        if self._spec_round():
+            return 1        # host-verified: one dispatch, consumed at once
+        cap = min(self.decode_steps, self.prefill_interleave_steps)
+        return min(self._pacer.k, cap) if self.pipelined else cap
+
+    def _due_chunkings(self) -> "list[_Chunking]":
+        """The in-flight prefills that have had no turn in the admit pass
+        under way: by QoS class, the oldest first within a class."""
+        return sorted(
+            (ch for ch in self._chunkings if ch.turn != self._admit_pass),
+            key=lambda ch: QOS_PRIORITY.get(ch.request.qos, 1))
+
     def _admit(self) -> int:
         """Prefill waiting requests into free slots. Returns admissions.
 
         Waiting requests are admitted FIRST (each joins ``_chunkings``) and
-        then every in-flight prefill advances by one chunk, together where
+        then the in-flight prefills advance by one chunk, together where
         the engine has the program for it (``_advance_chunked``): a pass
         above the knee
         carries its prefills' chunks in one program, and not the older
@@ -1879,20 +1929,32 @@ class LLMEngine:
         lane to the next waiting request within the pass, as it always did
         (short prompts do not queue behind the decode round for a lane):
         the newcomers then run their first chunk, and so on until no lane
-        comes free."""
+        comes free.
+
+        While a decode stream is live the pass sends no more programs than
+        ``_prefill_budget`` allows: the prefills take their turn by QoS
+        class, the oldest first within a class (it finishes soonest that
+        way, and a younger one keeps its lane, slot and pages), and what is
+        left waits for the next pass. The hand-on itself does not wait: the
+        newcomer is admitted, and only its chunk is deferred."""
         n = 0
-        advanced: list[_Chunking] = []      # had their chunk of this pass
+        self._admit_pass += 1
+        budget = self._prefill_budget()
+        programs = self._prefill_programs_dispatched
         while True:
             n += self._admit_waiting()
-            due = [ch for ch in self._chunkings
-                   if not any(ch is done for done in advanced)]
-            if not due:
+            due = self._due_chunkings()
+            left = None if budget is None else \
+                budget - (self._prefill_programs_dispatched - programs)
+            if not due or left == 0:
                 break
-            advanced += due
             lanes = len(self._chunkings)
-            n += self._advance_chunked(due)
+            n += self._advance_chunked(due, left)
             if len(self._chunkings) == lanes:
                 break
+        self._prefill_chunks_deferred += len(self._due_chunkings())
+        if self._prefill_programs_dispatched != programs:
+            self._prefill_passes += 1
         # The pass's finished prefills: one batched sampler dispatch + one
         # fetch for the whole admit round.
         self._flush_first_tokens()
@@ -2531,6 +2593,15 @@ class LLMEngine:
         self.slots[idx] = None
         return True
 
+    def _spec_round(self) -> bool:
+        """The live slots' next round is a speculative one: configured, a
+        slot is live and every stream greedy."""
+        if self.spec_mode == "off":
+            return False
+        live = [s for s in self.slots if s is not None]
+        return bool(live) and all(
+            s.request.params.temperature <= 0.0 for s in live)
+
     def _decode_once(self) -> int:  # hot-loop
         """One decode scheduler pass. Routes greedy-only rounds to the
         speculative path when configured; sampling traffic (and spec-off
@@ -2539,9 +2610,7 @@ class LLMEngine:
         reap/admit of the next ``step()``) overlaps device compute.
         Returns work done (tokens emitted + dispatches)."""
         active = [(i, s) for i, s in enumerate(self.slots) if s is not None]
-        if (self.spec_mode != "off" and active
-                and all(s.request.params.temperature <= 0.0
-                        for _, s in active)):
+        if self._spec_round():
             # Spec rounds verify on host between dispatches — drain the
             # plain pipeline first so host mirrors are current.
             emitted = self._consume_rounds()

@@ -8,7 +8,9 @@ chosen from printed beside its result (to standard error; the result line is
 the benchmark's own and stays the last line of standard output): over the
 window, from the snapshots of ``LLMEngine.counters()`` the harness takes,
 the rounds, the steps a round, the rounds left at their cap and the
-scheduler's own seconds a round and as a share of the window; at the
+scheduler's own seconds a round and as a share of the window, the prefill
+programs a scheduler pass sent and the chunks a pass's budget deferred
+(ISSUE 34: ``prefill_programs_a_pass``, ``prefill_chunks_deferred``); at the
 window's end the scheduler's two running averages (the host's time an
 iteration, the device's time a step) and the length in force; the client's
 gaps between tokens and times to the first token at several percentiles;
@@ -29,6 +31,7 @@ in place of the traffic file's, for the first readings of a sweep.
 from __future__ import annotations
 
 import argparse
+import collections
 import json
 import os
 import re
@@ -91,6 +94,21 @@ def _tail(record: dict) -> None:
                 k = attrs.get("k_steps")
                 lengths[k] = lengths.get(k, 0) + 1
     _log(f"tail rounds by k_steps: {json.dumps(lengths, sort_keys=True)}")
+    # Prefill programs by the admit pass that sent them (from the spans, so
+    # that a checkout without the counter ``prefill_passes`` reads too).
+    sent: dict = {}
+    for thread in record.get("host_spans") or []:
+        admits = [(t0, t0 + dur) for name, t0, dur, _ in thread
+                  if name == "engine.admit"]
+        for name, t0, _, _ in thread:
+            if name == "engine.prefill_dispatch":
+                owner = next((a for a in admits if a[0] <= t0 < a[1]), None)
+                sent[owner] = sent.get(owner, 0) + 1
+    if sent:
+        by_count = collections.Counter(sent.values())
+        _log(f"tail prefill programs a pass: "
+             f"{sum(sent.values()) / len(sent):.3f} (passes by programs "
+             f"sent: {json.dumps(by_count, sort_keys=True)})")
     for name, (n, total) in sorted(names.items(), key=lambda kv: -kv[1][1]):
         _log(f"tail span {name}: {n} x {1e3 * total / n:.3f} ms = "
              f"{total:.4f} s")
@@ -190,7 +208,8 @@ def main() -> int:
     if len(snapshots) >= 2 and snapshots[0] and "engine" in snapshots[0]:
         a, b = snapshots[0]["engine"], snapshots[1]["engine"]
         d = {k: b[k] - a[k] for k in b if k in a and k.startswith(
-            ("decode_", "sched_", "prefill_", "first_token", "host_gap"))}
+            ("decode_", "sched_", "prefill_", "first_token", "host_gap",
+             "queue_delay"))}
         rounds = d.get("decode_rounds") or 0
         if rounds:
             d["steps_a_round"] = d["decode_steps_dispatched"] / rounds
@@ -199,6 +218,9 @@ def main() -> int:
                     1e3 * d["sched_host_busy_sum_s"] / rounds
                 d["sched_host_busy_share"] = \
                     d["sched_host_busy_sum_s"] / args.seconds
+        if d.get("prefill_passes"):         # ISSUE 34; the parent has none
+            d["prefill_programs_a_pass"] = \
+                d["prefill_programs_dispatched"] / d["prefill_passes"]
         _log(f"window counters: {json.dumps(d, sort_keys=True)}")
     _client_side(args.workload, args.seconds)
     return rc

@@ -570,8 +570,14 @@ class TestEndToEnd:
             FleetTraceCollector, MetricsHistory, fleet_obs_registry,
         )
         from kubeflow_tpu.obs.registry import parse_exposition
+        from kubeflow_tpu.serve.engine import SamplingParams
         from kubeflow_tpu.serve.server import serving_metrics_registry
 
+        # The host-gap quantiles are rendered once a round has been
+        # dispatched behind another with no admission between them: a
+        # stream of a few rounds, whatever the scenarios above left.
+        engine.submit([5, 6, 7], SamplingParams(max_new_tokens=24,
+                                                temperature=0.0)).result(60)
         text = serving_metrics_registry([("pin", engine)]).render()
         names = {n for n, _, _ in parse_exposition(text)}
         fleet = fleet_obs_registry(collector=FleetTraceCollector(),

@@ -260,6 +260,55 @@ class TestFirstTokenBatching:
         assert gen_all(eng, PROMPTS, max_new=6) == want
 
 
+class TestPrefillBudget:
+    """ISSUE 34: one prefill program a decode step while a stream is live.
+    What the pacer samples is decided by what an iteration SENT, as before:
+    one that deferred a chunk sent a program and is no sample of the host's
+    time; one whose prefills could not get pages sent none and is one."""
+
+    def test_a_sample_unless_the_iteration_sent_a_prefill_program(
+            self, cfg, params, monkeypatch):
+        eng = make_engine(cfg, params, pipelined=True, decode_steps=1)
+        eng.max_concurrent_prefills = 3     # two rows a program: one waits
+        assert eng._chunk_rows == 2
+        samples = []
+        eng._pacer.note_host = lambda k, seconds: samples.append(
+            eng.counters()["prefill_programs_dispatched"])
+        live = eng.submit([3, 1, 4], SamplingParams(max_new_tokens=60,
+                                                    temperature=0.0))
+        while live.first_token_time is None:
+            eng.step()
+        for _ in range(3):
+            eng.step()
+        assert samples and eng._prefill_budget() == 1
+        sp = SamplingParams(max_new_tokens=3, temperature=0.0)
+        reqs = [eng.submit([i + 1] * 70, sp) for i in range(3)]
+        before, n = eng.counters(), len(samples)
+        eng.step()
+        eng.step()
+        c = eng.counters()
+        assert c["prefill_chunks_deferred"] - before[
+            "prefill_chunks_deferred"] == 2
+        assert c["prefill_passes"] - before["prefill_passes"] == 2
+        assert len(samples) == n            # both sent a program: no sample
+        # No page for any prefill: they keep their lanes, nothing is sent.
+        ensure = eng._ensure_pages
+        held = {ch.slot for ch in eng._chunkings}
+        assert len(held) == 3
+        monkeypatch.setattr(
+            eng, "_ensure_pages",
+            lambda slot, upto: slot not in held and ensure(slot, upto))
+        eng.step()
+        monkeypatch.setattr(eng, "_ensure_pages", ensure)
+        after = eng.counters()
+        assert after["prefill_passes"] == c["prefill_passes"]
+        # ... and no budget is spent: all three had their turn
+        assert after["prefill_chunks_deferred"] == c["prefill_chunks_deferred"]
+        assert len(samples) == n + 1 and samples[-1] == c[
+            "prefill_programs_dispatched"]
+        run_all(eng, [live, *reqs])
+
+
 class TestTransferGuard:
     """Runtime half of the static device-hygiene rules (ISSUE 5): the
     engine's transfer contract is that every steady-state host<->device

@@ -410,6 +410,45 @@ def test_an_iteration_that_sent_a_chunk_is_no_sample_of_the_host(cfg, params):
     assert len(seen) > plain
 
 
+def test_the_prefill_budget_is_the_length_of_the_round_in_force(cfg, params):
+    """A pass may send one prefill program for each decode step of the round
+    behind it (ISSUE 34): eight while the scheduler holds eight-step rounds,
+    one once it has come down to one step. One lane of 32-token chunks, so
+    a program is one chunk, and short prompts that hand the lane on within
+    the pass."""
+    eng = make_engine(cfg, params, max_batch_size=24,
+                      prefill_interleave_steps=8, max_concurrent_prefills=1)
+    assert eng._chunk_rows == 1 and eng._prefill_budget() is None
+    pin(eng, *SLOW_HOST)
+    live = eng.submit([3, 1, 4], SamplingParams(max_new_tokens=100,
+                                                temperature=0.0))
+    while live.first_token_time is None:
+        eng.step()
+    eng.step()
+    assert eng._pacer.k == 8 == eng._prefill_budget()
+
+    def one_iteration():
+        before = eng.counters()
+        eng.step()
+        after = eng.counters()
+        return tuple(after[k] - before[k] for k in (
+            "prefill_programs_dispatched", "prefill_passes",
+            "prefill_chunks_deferred"))
+
+    sp = SamplingParams(max_new_tokens=2, temperature=0.0)
+    short = [eng.submit([i + 1] * 5, sp) for i in range(10)]
+    # eight prompts through the one lane; the ninth has it and waits
+    assert one_iteration() == (8, 1, 1)
+    assert sum(r.first_token_time is not None for r in short) == 8
+    pin(eng, *FAST_HOST)
+    assert one_iteration() == (2, 1, 0)     # the length in force is still 8
+    assert eng._pacer.k == 1 == eng._prefill_budget()
+    more = [eng.submit([i + 1] * 5, sp) for i in range(3)]
+    for waiting in (1, 1, 0):
+        assert one_iteration() == (1, 1, waiting)
+    run_all(eng, [live, *short, *more])
+
+
 def test_the_two_counters_from_construction_and_they_only_grow(cfg, params):
     eng = make_engine(cfg, params)
     before = eng.counters()
