@@ -56,6 +56,7 @@ class DecoderConfig:
     router_score: str = "softmax"
     router_norm_topk: bool = True
     router_scale: float = 1.0
+    router_norm_eps: float = 1e-20   # beside the sum the weights divide by
     # Latent attention (MLA; kv_lora_rank > 0): queries through a
     # ``q_lora_rank`` bottleneck, keys and values expanded per head from one
     # ``kv_lora_rank`` latent row a token, beside ``qk_rope_dim`` rotary
@@ -66,6 +67,21 @@ class DecoderConfig:
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
     v_head_dim: int = 0
+    # The stack's pattern: the kind of layer ``i`` is ``layer_kinds[i %
+    # len(layer_kinds)]``, "attention" or "conv" (() = every layer
+    # attention). A "conv" layer's operator is the gated short convolution
+    # (layers.conv_block): a causal depthwise convolution of ``conv_taps``
+    # taps over time between two elementwise gates, whose state is the last
+    # ``conv_taps - 1`` gated rows of a sequence. ``qk_norm``: an RMSNorm
+    # over each head's values of q and k, before RoPE.
+    layer_kinds: tuple = ()
+    conv_taps: int = 3
+    qk_norm: bool = False
+    # One ``n_kv_heads * head_dim`` row a token for K and one for V in the
+    # page pool, not ``[n_kv_heads, head_dim]``: for heads narrower than the
+    # chip's 128-value lanes, whose per-head planes the compiler pads to
+    # twice their size and copies whole around every decode write.
+    kv_heads_packed: bool = False
     # Per-expert buffer size = capacity_factor * k * T / E (rounded up to a
     # multiple of 8 for TPU tiling). 1.0 = perfectly balanced load fits.
     capacity_factor: float = 1.25
@@ -88,6 +104,27 @@ class DecoderConfig:
     fused_kernels: str = "auto"
     dtype: str = "bfloat16"        # activation/compute dtype
     param_dtype: str = "float32"
+
+    def __post_init__(self):
+        # A configuration file's list (JSON has no tuple) stays hashable.
+        object.__setattr__(self, "layer_kinds", tuple(self.layer_kinds))
+        unknown = set(self.layer_kinds) - {"attention", "conv"}
+        if unknown:
+            raise ValueError(f"unknown layer kinds {sorted(unknown)}")
+
+    @property
+    def period(self) -> tuple:
+        """One period of the stack's pattern of layer kinds."""
+        return self.layer_kinds or ("attention",)
+
+    @property
+    def kinds(self) -> tuple:
+        """The kind of every layer of the stack, in order."""
+        period = self.period
+        return tuple(period[i % len(period)] for i in range(self.n_layers))
+
+    def layers_of(self, kind: str) -> int:
+        return self.kinds.count(kind)
 
     @property
     def q_dim(self) -> int:
@@ -117,8 +154,14 @@ class DecoderConfig:
     def expert_mlp_dim(self) -> int:
         return self.moe_mlp_dim or self.mlp_dim
 
+    def _conv_params(self) -> int:
+        """One conv block's operator: in and out projections and the taps."""
+        d = self.hidden
+        return 3 * d * d + self.conv_taps * d + d * d
+
     def _attn_params(self) -> int:
-        """One block's attention matrices (a latent block's two norms too)."""
+        """One block's attention matrices (a latent block's two norms too;
+        the two per-head norms of ``qk_norm``)."""
         d, h = self.hidden, self.n_heads
         if self.is_latent:
             r, q = self.kv_lora_rank, self.q_lora_rank
@@ -126,7 +169,14 @@ class DecoderConfig:
                     + d * (r + self.qk_rope_dim) + r
                     + r * h * (self.qk_nope_dim + self.v_head_dim)
                     + h * self.v_head_dim * d)
-        return d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+        return d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d \
+            + (2 * self.head_dim if self.qk_norm else 0)
+
+    def _operator_params(self, first: int, last: int) -> int:
+        """The operators (attention or convolution) of layers [first, last)."""
+        kinds = self.kinds[first:last]
+        return kinds.count("attention") * self._attn_params() \
+            + kinds.count("conv") * self._conv_params()
 
     def _mlp_params(self, active: bool) -> int:
         """One expert layer's (or, dense, one MLP's) matrices; ``active``
@@ -145,9 +195,9 @@ class DecoderConfig:
         """Parameter count (embedding included once if tied)."""
         d, v = self.hidden, self.vocab_size
         k = self.leading_dense_layers
-        layers = (self.n_layers - k) * (self._attn_params()
-                                        + self._mlp_params(False) + 2 * d) \
-            + k * (self._attn_params() + 3 * d * self.mlp_dim + 2 * d)
+        layers = self._operator_params(0, self.n_layers) \
+            + (self.n_layers - k) * (self._mlp_params(False) + 2 * d) \
+            + k * (3 * d * self.mlp_dim + 2 * d)
         embed = v * d if self.tie_embeddings else 2 * v * d
         return layers + embed + d
 
@@ -156,10 +206,9 @@ class DecoderConfig:
         counts only active experts)."""
         d = self.hidden
         k = self.leading_dense_layers
-        dense_n = (self.n_layers - k) * (self._attn_params()
-                                         + self._mlp_params(True)) \
-            + k * (self._attn_params() + 3 * d * self.mlp_dim) \
-            + self.vocab_size * d
+        dense_n = self._operator_params(0, self.n_layers) \
+            + (self.n_layers - k) * self._mlp_params(True) \
+            + k * 3 * d * self.mlp_dim + self.vocab_size * d
         return 6.0 * dense_n
 
 
@@ -202,6 +251,21 @@ PRESETS: dict[str, DecoderConfig] = {
         router_norm_topk=True, router_scale=1.8, q_lora_rank=768,
         kv_lora_rank=512, qk_nope_dim=192, qk_rope_dim=64, v_head_dim=256,
     ),
+    # LFM2-24B-A2B (LiquidAI config.json, model_type lfm2_moe: 40L, 2048h;
+    # layers 2, 6, ... 38 attention of 32/8 heads of 64 with per-head q/k
+    # norms, every other layer a gated short convolution of 3 taps; two
+    # dense layers of 11776 then 64 sigmoid-routed experts of 1536, top-4,
+    # no shared expert; tied head)
+    "lfm2-24b-a2b": DecoderConfig(
+        vocab_size=65536, hidden=2048, n_layers=40, n_heads=32, n_kv_heads=8,
+        head_dim=64, mlp_dim=11776, max_seq_len=128000,
+        rope_theta=1000000.0, tie_embeddings=True, num_experts=64,
+        experts_per_token=4, moe_impl="sorted", moe_mlp_dim=1536,
+        leading_dense_layers=2, router_score="sigmoid",
+        router_norm_topk=True, router_scale=1.0, router_norm_eps=1e-6,
+        layer_kinds=("conv", "conv", "attention", "conv"), conv_taps=3,
+        qk_norm=True, kv_heads_packed=True,
+    ),
     # tiny variants for tests/sim (structure-faithful, sized for 1 CPU core)
     "tiny": DecoderConfig(
         vocab_size=256, hidden=64, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -227,6 +291,18 @@ PRESETS: dict[str, DecoderConfig] = {
         shared_experts=1, leading_dense_layers=1, router_score="sigmoid",
         router_norm_topk=True, router_scale=1.8, q_lora_rank=24,
         kv_lora_rank=40, qk_nope_dim=12, qk_rope_dim=8, v_head_dim=20,
+    ),
+    # LFM2's structure: a leading dense conv layer, then two periods of
+    # (attention, conv, conv, conv) with 8 sigmoid-routed experts top-2
+    "tiny-lfm2": DecoderConfig(
+        vocab_size=256, hidden=64, n_layers=9, n_heads=4, n_kv_heads=2,
+        head_dim=16, mlp_dim=160, max_seq_len=256, tie_embeddings=True,
+        num_experts=8, experts_per_token=2, moe_impl="sorted",
+        moe_mlp_dim=48, leading_dense_layers=1, router_score="sigmoid",
+        router_norm_topk=True, router_norm_eps=1e-6,
+        layer_kinds=("conv", "attention", "conv", "conv", "conv",
+                     "attention", "conv", "conv", "conv"),
+        conv_taps=3, qk_norm=True, kv_heads_packed=True,
     ),
 }
 

@@ -25,39 +25,131 @@ from kubeflow_tpu.parallel.sharding import (
 Params = dict[str, Any]
 
 
-def _init_block(key, cfg: DecoderConfig):
-    k_attn, k_mlp = jax.random.split(key)
-    attn_p, attn_s = L.init_attention(k_attn, cfg)
-    if cfg.is_moe:
-        mlp_p, mlp_s = L.init_moe(k_mlp, cfg)
-    else:
-        mlp_p, mlp_s = L.init_mlp(k_mlp, cfg)
+# A layer's operator, by its kind: the block's key in the parameter tree and
+# the cache planes it holds (every plane not named here is attention's).
+OPERATOR = {"attention": "attn", "conv": "conv"}
+CONV_PLANES = ("conv",)
+
+
+def plane_kind(name: str) -> str:
+    return "conv" if name in CONV_PLANES else "attention"
+
+
+def _init_operator(key, cfg: DecoderConfig, kind: str):
+    init = L.init_conv if kind == "conv" else L.init_attention
+    return init(jax.random.split(key)[0], cfg)
+
+
+def _init_ffn(key, cfg: DecoderConfig):
+    """What every kind of block has beside its operator: the feed-forward
+    (or expert) layer and the two norms."""
+    k_mlp = jax.random.split(key)[1]
+    mlp_p, mlp_s = (L.init_moe if cfg.is_moe else L.init_mlp)(k_mlp, cfg)
     ln1, ln1_s = L.init_rmsnorm(cfg)
     ln2, ln2_s = L.init_rmsnorm(cfg)
-    params = {"attn": attn_p, "mlp": mlp_p, "ln1": ln1, "ln2": ln2}
-    specs = {"attn": attn_s, "mlp": mlp_s, "ln1": ln1_s, "ln2": ln2_s}
-    return params, specs
+    return ({"mlp": mlp_p, "ln1": ln1, "ln2": ln2},
+            {"mlp": mlp_s, "ln1": ln1_s, "ln2": ln2_s})
+
+
+def _init_block(key, cfg: DecoderConfig, kind: str = "attention"):
+    op_p, op_s = _init_operator(key, cfg, kind)
+    ffn_p, ffn_s = _init_ffn(key, cfg)
+    return ({OPERATOR[kind]: op_p, **ffn_p}, {OPERATOR[kind]: op_s, **ffn_s})
+
+
+def _period(kinds: tuple) -> int:
+    """The shortest period of ``kinds`` (its last period may be cut)."""
+    return next(p for p in range(1, len(kinds) + 1)
+                if all(kinds[i] == kinds[i - p]
+                       for i in range(p, len(kinds))))
+
+
+def _periodic(name: str, cfg: DecoderConfig, first: int, n: int) -> list:
+    """Layers [first, first + n) as whole periods of their pattern, and what
+    is left behind them (a cut period) as a group of its own."""
+    kinds = cfg.kinds[first:first + n]
+    p = _period(kinds)
+    whole = n // p * p
+    out = [(name, dataclasses.replace(cfg, n_layers=whole,
+                                      layer_kinds=kinds[:p]), first)]
+    if whole < n:
+        out.append((name + "_rest", dataclasses.replace(
+            cfg, n_layers=n - whole, layer_kinds=kinds[whole:]),
+            first + whole))
+    return out
 
 
 def layer_groups(cfg: DecoderConfig) -> list[tuple[str, DecoderConfig, int]]:
-    """The stack as groups of alike layers, in order: (the group's key in
-    the parameter tree, the config its blocks run under, its first layer's
-    index). A model whose layers are all alike is the one group "layers"
-    under its own config, as it always was; ``leading_dense_layers`` puts a
-    group "dense_layers" of plain-MLP blocks before the expert layers. Each
-    group is stacked on a leading axis and scanned on its own."""
+    """The stack as groups that are each ONE scan, in order: (the group's
+    key in the parameter tree, the config its blocks run under, its first
+    layer's index). A model whose layers are all alike is the one group
+    "layers" under its own config, as it always was; ``leading_dense_layers``
+    puts a group "dense_layers" of plain-MLP blocks before the expert layers.
+
+    Where the layers' kinds differ (``cfg.layer_kinds``) a group is whole
+    PERIODS of its pattern: its config's ``layer_kinds`` is one period, the
+    unit its scan walks (``period_units`` / ``unit_blocks``), and
+    ``n_layers`` the layers it holds. In the tree a group's norms and
+    feed-forward leaves are stacked over its layers in order, an operator's
+    over the layers of its kind (``OPERATOR``)."""
     k = cfg.leading_dense_layers
     if not k:
-        return [("layers", cfg, 0)]
+        if not cfg.layer_kinds:
+            return [("layers", cfg, 0)]
+        return _periodic("layers", cfg, 0, cfg.n_layers)
     if not cfg.is_moe or not 0 < k < cfg.n_layers:
         raise ValueError(
             f"leading_dense_layers={k} needs an expert model of more than "
             f"{k} layers (n_layers={cfg.n_layers})")
-    dense = dataclasses.replace(cfg, num_experts=0, leading_dense_layers=0,
-                                n_layers=k)
-    experts = dataclasses.replace(cfg, leading_dense_layers=0,
-                                  n_layers=cfg.n_layers - k)
-    return [("dense_layers", dense, 0), ("layers", experts, k)]
+    dense = dataclasses.replace(cfg, num_experts=0, leading_dense_layers=0)
+    experts = dataclasses.replace(cfg, leading_dense_layers=0)
+    return _periodic("dense_layers", dense, 0, k) \
+        + _periodic("layers", experts, k, cfg.n_layers - k)
+
+
+def period_units(stack, gcfg: DecoderConfig):
+    """A group's stacked leaves as its scan takes them: one period of the
+    pattern an iteration, so a leaf [n, ...] becomes [periods, n / periods,
+    ...] (merging and splitting a leading axis moves nothing). A group of
+    alike layers is scanned as it is."""
+    p = len(gcfg.period)
+    if p == 1:
+        return stack
+    m = gcfg.n_layers // p
+    return jax.tree.map(
+        lambda a: a.reshape(m, a.shape[0] // m, *a.shape[1:]), stack)
+
+
+def unit_blocks(unit, gcfg: DecoderConfig) -> list:
+    """The layers of one scan unit, in order: (kind, the layer's place among
+    the unit's layers of its kind, its block's parameters). ``unit``: one
+    iteration's slice of ``period_units``."""
+    period = gcfg.period
+    if len(period) == 1:
+        return [(period[0], 0, unit)]
+    out, seen = [], {}
+    for j, kind in enumerate(period):
+        i = seen[kind] = seen.get(kind, -1) + 1
+        out.append((kind, i, {
+            **{n: jax.tree.map(lambda a, j=j: a[j], unit[n])
+               for n in ("mlp", "ln1", "ln2")},
+            OPERATOR[kind]: jax.tree.map(lambda a, i=i: a[i],
+                                         unit[OPERATOR[kind]])}))
+    return out
+
+
+def _init_group(keys, gcfg: DecoderConfig):
+    """One group's stacked parameters (``layer_groups``) from a key a
+    layer."""
+    kinds = gcfg.kinds
+    if len(set(kinds)) == 1:
+        return jax.vmap(lambda k: _init_block(k, gcfg, kinds[0])[0])(keys)
+    stack = jax.vmap(lambda k: _init_ffn(k, gcfg)[0])(keys)
+    for kind in sorted(set(kinds)):
+        own = jnp.asarray([i for i, x in enumerate(kinds) if x == kind])
+        stack[OPERATOR[kind]] = jax.vmap(
+            lambda k, kind=kind: _init_operator(k, gcfg, kind)[0])(keys[own])
+    return stack
 
 
 def init_decoder_params(key: jax.Array, cfg: DecoderConfig) -> Params:
@@ -70,10 +162,10 @@ def init_decoder_params(key: jax.Array, cfg: DecoderConfig) -> Params:
         keys = layer_keys[first:first + gcfg.n_layers]
         if cfg.scan_layers:
             # Stack per-layer params on a leading axis via vmapped init.
-            stacks[name] = jax.vmap(
-                lambda k, gcfg=gcfg: _init_block(k, gcfg)[0])(keys)
+            stacks[name] = _init_group(keys, gcfg)
         else:
-            stacks[name] = [_init_block(k, gcfg)[0] for k in keys]
+            stacks[name] = [_init_block(k, gcfg, kind)[0]
+                            for k, kind in zip(keys, gcfg.kinds)]
 
     final_norm, _ = L.init_rmsnorm(cfg)
     params: Params = {"embed": tok, **stacks, "final_norm": final_norm}
@@ -83,14 +175,14 @@ def init_decoder_params(key: jax.Array, cfg: DecoderConfig) -> Params:
     return params
 
 
-def _block_specs(cfg: DecoderConfig):
+def _block_specs(cfg: DecoderConfig, kind: str = "attention"):
     """Logical-axis spec tree for one decoder block (no params materialize:
     llama3-70b's block is ~GBs — trace under eval_shape, capture the static
     spec tree on the side)."""
     captured = {}
 
     def _shape_only():
-        params, specs = _init_block(jax.random.PRNGKey(0), cfg)
+        params, specs = _init_block(jax.random.PRNGKey(0), cfg, kind)
         captured["specs"] = specs
         return params
 
@@ -108,12 +200,16 @@ def decoder_param_specs(cfg: DecoderConfig) -> Params:
 
     stacks = {}
     for name, gcfg, _ in layer_groups(cfg):
-        block_specs = _block_specs(gcfg)
+        by_kind = {kind: _block_specs(gcfg, kind) for kind in set(gcfg.kinds)}
         if cfg.scan_layers:
-            stacks[name] = jax.tree.map(stack_spec, block_specs,
+            # Every kind's block has the same norms and feed-forward; each
+            # brings its own operator.
+            merged = {k: v for specs in by_kind.values()
+                      for k, v in specs.items()}
+            stacks[name] = jax.tree.map(stack_spec, merged,
                                         is_leaf=_is_spec_leaf)
         else:
-            stacks[name] = [block_specs] * gcfg.n_layers
+            stacks[name] = [by_kind[kind] for kind in gcfg.kinds]
 
     specs: Params = {
         "embed": ("vocab", "embed_table"),
@@ -132,10 +228,23 @@ def _block_forward(block_params, x, positions, cfg: DecoderConfig,
                    valid_len=None, lora=None, expert_stack=None,
                    moe_capacity_per_row=False):
     h = L.rmsnorm(x, block_params["ln1"], cfg, mesh=mesh)
-    attn_out, new_cache = L.attention_block(
-        block_params["attn"], h, positions, cfg,
-        kv_cache=kv_cache, attn_impl=attn_impl, mesh=mesh,
-        tp_axis=tp_axis, lora=lora)
+    if "conv" in block_params:
+        # A conv layer's cache is the tail of gated rows before ``x``; what
+        # it hands back is that tail followed by its own rows, of which the
+        # caller keeps the windows it needs (serve/paged.py).
+        if tp_axis is not None or lora is not None:
+            raise NotImplementedError(
+                "a conv layer under in-stage tensor parallelism or with "
+                "LoRA adapters")
+        attn_out, zs = L.conv_block(
+            block_params["conv"], h, cfg,
+            None if kv_cache is None else kv_cache["conv"])
+        new_cache = None if kv_cache is None else {"conv": zs}
+    else:
+        attn_out, new_cache = L.attention_block(
+            block_params["attn"], h, positions, cfg,
+            kv_cache=kv_cache, attn_impl=attn_impl, mesh=mesh,
+            tp_axis=tp_axis, lora=lora)
     # Residual add + second norm as ONE op: fused kernels run it in a
     # single pass over the stream (layers.add_rmsnorm).
     x, h = L.add_rmsnorm(x, attn_out, block_params["ln2"], cfg, mesh=mesh)
@@ -198,10 +307,12 @@ def _remat(fn, policy: str):
 def _run_layers(layers, x, positions, cfg: DecoderConfig, planes: tuple,
                 plane_names: tuple, cache_len, *, attn_impl, mesh, rules,
                 valid_len, lora, moe_capacity_per_row=False):
-    """One group of alike layers (``layer_groups``) over ``x``: scanned when
-    stacked, looped when a list. ``planes``: this group's slices of the
-    cache's stacked planes, in ``plane_names``' order (empty without a
-    cache). Returns (x, the planes as written, the group's summed aux)."""
+    """One group of layers (``layer_groups``) over ``x``: scanned a period
+    of its pattern at a time when stacked, looped when a list. ``planes``:
+    this group's slices of the cache's stacked planes, in ``plane_names``'
+    order (empty without a cache), each over the layers of its plane's kind
+    (``plane_kind``). Returns (x, the planes as written, the group's summed
+    aux)."""
     def block(bp, x, cache, lr, expert_stack=None):
         return _block_forward(
             bp, x, positions, cfg, kv_cache=cache, attn_impl=attn_impl,
@@ -209,10 +320,15 @@ def _run_layers(layers, x, positions, cfg: DecoderConfig, planes: tuple,
             lora=lr, expert_stack=expert_stack,
             moe_capacity_per_row=moe_capacity_per_row)
 
-    def cache_of(layer_planes):
-        if not plane_names:
-            return None
-        return {**dict(zip(plane_names, layer_planes)), "len": cache_len}
+    def cache_of(kind, layer_planes):
+        own = {n: pl for n, pl in zip(plane_names, layer_planes)
+               if plane_kind(n) == kind}
+        return {**own, "len": cache_len} if own else None
+
+    def written_by(new_cache, kind, out):
+        for n in plane_names:
+            if plane_kind(n) == kind:
+                out[n].append(new_cache[n])
 
     if cfg.scan_layers:
         # Per-layer adapter slices ride the scan xs alongside the layer
@@ -222,40 +338,45 @@ def _run_layers(layers, x, positions, cfg: DecoderConfig, planes: tuple,
         # body takes them whole with the layer's index (no slice of the
         # stack is copied out for the grouped matmul).
         layers, experts = L.split_expert_stack(layers, cfg)
+        p = len(cfg.period)
 
         def scan_body(carry, scan_in):
-            block_params, cache, lora_sl, layer = scan_in
-            out, new_cache, aux = block(
-                block_params, carry, cache, L.layer_view(lora, lora_sl),
-                None if experts is None else (experts, layer))
-            return out, (new_cache, aux)
-
-        body = _remat(scan_body, cfg.remat_policy)
+            unit, unit_planes, lora_sl, u = scan_in
+            out = {n: [] for n in plane_names}
+            aux_sum = jnp.float32(0)
+            for j, (kind, i, bp) in enumerate(unit_blocks(unit, cfg)):
+                carry, new_cache, aux = block(
+                    bp, carry,
+                    cache_of(kind, unit_planes if p == 1
+                             else tuple(pl[i] for pl in unit_planes)),
+                    L.layer_view(lora, lora_sl),
+                    None if experts is None else (experts, u * p + j))
+                written_by(new_cache, kind, out)
+                aux_sum = aux_sum + aux
+            return carry, (tuple(out[n][0] if p == 1 else jnp.stack(out[n])
+                                 for n in plane_names), aux_sum)
 
         # scan consumes the stacked [L, ...] cache leaves alongside params
-        def scan_layer(carry, scan_in):
-            block_params, layer_planes, lora_sl, layer = scan_in
-            out, (new_cache, aux) = body(
-                carry, (block_params, cache_of(layer_planes), lora_sl,
-                        layer))
-            written = tuple(new_cache[n] for n in plane_names)
-            return out, (written, aux)
-
         x, (planes, auxs) = jax.lax.scan(
-            scan_layer, x, (layers, planes, L.slice_layers(lora),
-                            jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+            _remat(scan_body, cfg.remat_policy), x,
+            (period_units(layers, cfg), period_units(planes, cfg),
+             L.slice_layers(lora),
+             jnp.arange(cfg.n_layers // p, dtype=jnp.int32)))
+        if p > 1:       # [periods, layers of the kind a period, ...] again
+            planes = tuple(pl.reshape(-1, *pl.shape[2:]) for pl in planes)
         return x, planes, jnp.sum(auxs)
 
     block_fn = _remat(block, cfg.remat_policy)
-    auxs, written = [], [[] for _ in plane_names]
-    for i, block_params in enumerate(layers):
+    auxs, out, seen = [], {n: [] for n in plane_names}, {}
+    for i, (block_params, kind) in enumerate(zip(layers, cfg.kinds)):
+        at = seen[kind] = seen.get(kind, -1) + 1
         x, new_cache, aux = block_fn(
-            block_params, x, cache_of(tuple(p[i] for p in planes)),
+            block_params, x, cache_of(kind, tuple(pl[at] for pl in planes)),
             L.index_layer(lora, i))
         auxs.append(aux)
-        for w, n in zip(written, plane_names):
-            w.append(new_cache[n])
-    return x, tuple(jnp.stack(w) for w in written), jnp.sum(jnp.stack(auxs))
+        written_by(new_cache, kind, out)
+    return x, tuple(jnp.stack(out[n]) for n in plane_names), \
+        jnp.sum(jnp.stack(auxs))
 
 
 def decoder_forward(
@@ -264,7 +385,7 @@ def decoder_forward(
     cfg: DecoderConfig,
     *,
     positions: Optional[jax.Array] = None,
-    kv_caches: Optional[dict] = None,  # {"k","v": [L,B,Smax,K,Dh], "len": scalar | [B]}
+    kv_caches: Optional[dict] = None,  # {"k","v": [La,B,Smax,K,Dh], "conv": [Lc,B,taps-1,D], "len": scalar | [B]}
     attn_impl: str = "xla",
     mesh=None,
     rules: LogicalRules = DEFAULT_RULES,
@@ -287,7 +408,11 @@ def decoder_forward(
     "scale": [S]}`` — each row's adapter delta applies inside every
     attention block (rows with aidx = -1 add exact zero).
     ``kv_caches["len"]`` may be one start a row ([B]: the serving chunk of
-    several prompts, each at its own position; layers.attention_block).
+    several prompts, each at its own position; layers.attention_block). A
+    plane of the cache is stacked over the layers of ITS kind
+    (``plane_kind``): K and V over the attention layers, "conv" (the gated
+    rows just before ``tokens``) over the conv layers, which hand back that
+    tail followed by their own rows ([Lc,B,taps-1+S,D]).
     ``moe_capacity_per_row``: the dispatch MoE path's capacity is taken
     within each row (layers.moe_block), which that chunk sets."""
     custom_positions = positions is not None
@@ -346,19 +471,25 @@ def decoder_forward(
     for name, gcfg, first in groups:
         last = first + gcfg.n_layers
         whole = len(groups) == 1        # one group: nothing is sliced
-        planes = tuple(kv_caches[n] if whole else kv_caches[n][first:last]
-                       for n in plane_names)
+        # The group's planes: those of the kinds it has, each sliced over
+        # the layers of its kind that lie in the group.
+        names = tuple(n for n in plane_names if plane_kind(n) in gcfg.kinds)
+        at = {n: cfg.kinds[:first].count(plane_kind(n)) for n in names}
+        planes = tuple(
+            kv_caches[n] if whole else kv_caches[n][
+                at[n]:at[n] + gcfg.kinds.count(plane_kind(n))]
+            for n in names)
         lora_g = lora if whole or lora is None else {
             **lora, "targets": {t: (a[first:last], b[first:last])
                                 for t, (a, b) in lora["targets"].items()}}
         x, planes, aux = _run_layers(
-            params[name], x, positions, gcfg, planes, plane_names,
+            params[name], x, positions, gcfg, planes, names,
             kv_caches["len"] if kv_caches is not None else None,
             attn_impl=attn_impl, mesh=mesh, rules=rules,
             valid_len=valid_len, lora=lora_g,
             moe_capacity_per_row=moe_capacity_per_row)
         aux_total = aux_total + aux
-        for n, plane in zip(plane_names, planes):
+        for n, plane in zip(names, planes):
             new_planes[n].append(plane)
     if kv_caches is not None:
         new_caches = {n: ps[0] if len(ps) == 1 else jnp.concatenate(ps)

@@ -192,7 +192,24 @@ def init_attention(key, cfg: DecoderConfig):
         "wv": ("embed", "kv_heads", "head_dim"),
         "wo": ("heads", "head_dim", "embed"),
     }
+    if cfg.qk_norm:
+        # One weight vector for all heads, over each head's values.
+        params["q_norm"] = jnp.ones((cfg.head_dim,), cfg.weight_dtype)
+        params["k_norm"] = jnp.ones((cfg.head_dim,), cfg.weight_dtype)
+        specs["q_norm"] = specs["k_norm"] = ("norm",)
     return params, specs
+
+
+def qk_rope(p: dict, q: jax.Array, k: jax.Array, positions: jax.Array,
+            cfg: DecoderConfig):
+    """Queries and keys [B,S,H,Dh] as attention takes them: each head's
+    values through its RMSNorm where the model has one (``qk_norm``), then
+    RoPE. One function for the forward pass and the paged programs."""
+    if cfg.qk_norm:
+        q = rmsnorm(q, p["q_norm"], cfg)
+        k = rmsnorm(k, p["k_norm"], cfg)
+    return rope(q, positions, cfg.rope_theta), \
+        rope(k, positions, cfg.rope_theta)
 
 
 def _cached_attention_by_row(q, k, v, kv_cache: dict):  # traced
@@ -280,8 +297,9 @@ def attention_block(
     # Names feed the "block_outs" remat policy: saving post-rope Q/K/V plus
     # the block outputs skips reprojecting + re-rotating in the backward
     # while staying far under dots_no_batch's save footprint.
-    q = checkpoint_name(rope(q, positions, cfg.rope_theta), "q_rope")
-    k = checkpoint_name(rope(k, positions, cfg.rope_theta), "k_rope")
+    q, k = qk_rope(p, q, k, positions, cfg)
+    q = checkpoint_name(q, "q_rope")
+    k = checkpoint_name(k, "k_rope")
     v = checkpoint_name(v, "v_proj")
 
     new_cache = None
@@ -507,6 +525,50 @@ def latent_attention_block(p: dict, x: jax.Array, positions: jax.Array,
     return checkpoint_name(proj, "attn_out"), None
 
 
+# -- Gated short convolution ----------------------------------------------------
+
+def init_conv(key, cfg: DecoderConfig):
+    """A conv layer's operator: ``win`` projects to the three ``hidden``-wide
+    parts B, C, u (in that order on its middle axis), ``taps`` [taps, D] is
+    the depthwise filter (``taps[-1]`` multiplies the current position),
+    ``wout`` projects back. No biases."""
+    ki, kt, ko = jax.random.split(key, 3)
+    d, wdt = cfg.hidden, cfg.weight_dtype
+    params = {"win": _init(ki, (d, 3, d), wdt),
+              "taps": _init(kt, (cfg.conv_taps, d), wdt,
+                            scale=cfg.conv_taps ** -0.5),
+              "wout": _init(ko, (d, d), wdt)}
+    specs = {"win": ("embed", None, "mlp"), "taps": (None, "norm"),
+             "wout": ("mlp", "embed")}
+    return params, specs
+
+
+def conv_block(p: dict, x: jax.Array, cfg: DecoderConfig,
+               tail: Optional[jax.Array] = None):
+    """The gated short convolution over ``x`` [B,S,D]: ``[B|C|u] = x Win``,
+    ``z = B * u``, ``c_t = sum_j taps[j] * z_{t-(taps-1)+j}`` (causal,
+    depthwise), ``out = (C * c) Wout``. ``tail`` [B,taps-1,D]: the ``z`` rows
+    just before ``x`` (zeros at a sequence's start, and when None): the
+    whole state a sequence carries. Returns (out [B,S,D], ``zs``
+    [B,taps-1+S,D]: the tail followed by this call's ``z`` rows, so the
+    state as it stands after position ``i`` of ``x`` is ``zs[:, i+1 :
+    i+taps]``). Rows never mix: the convolution runs along a row's own
+    time axis."""
+    dt = cfg.activation_dtype
+    b, s, d = x.shape
+    k = cfg.conv_taps
+    bcu = jnp.einsum("bsd,dgk->bsgk", x, p["win"].astype(dt))
+    z = bcu[:, :, 0] * bcu[:, :, 2]
+    if tail is None:
+        tail = jnp.zeros((b, k - 1, d), dt)
+    zs = jnp.concatenate([tail.astype(dt), z], axis=1)
+    taps = p["taps"].astype(jnp.float32)
+    c = sum(taps[j] * zs[:, j:j + s].astype(jnp.float32) for j in range(k))
+    out = jnp.einsum("bsd,de->bse", bcu[:, :, 1] * c.astype(dt),
+                     p["wout"].astype(dt))
+    return checkpoint_name(out, "attn_out"), zs
+
+
 # -- MLP -----------------------------------------------------------------------
 
 def init_mlp(key, cfg: DecoderConfig):
@@ -615,7 +677,7 @@ def route(p: dict, xf: jax.Array, cfg: DecoderConfig):
     _, idx = jax.lax.top_k(s + p["router_bias"].astype(jnp.float32), k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if cfg.router_norm_topk:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + cfg.router_norm_eps)
     return logits, idx, w * cfg.router_scale
 
 

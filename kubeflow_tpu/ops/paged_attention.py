@@ -304,6 +304,94 @@ def paged_latent_decode_attention(
     )(table, lengths, q, pool)
 
 
+# -- packed K/V rows (heads narrower than the lanes) -----------------------------
+#
+# Where a head is narrower than the 128-value lanes (64), the pool holds all
+# of a token's KV heads side by side in ONE row a plane, ``[P, page, KV*D]``
+# (serve/paged.py::pool_planes). Attention over it is the latent kernel's
+# schedule with the values in a plane of their own: every query head is laid
+# out like a row, its D values in its KV head's place and zeros elsewhere, so
+# a head's score is one product with the K row (the zeros add nothing), and
+# the attended V row holds the head's output in that same place.
+
+def _packed_decode_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+                          m_ref, l_ref, acc_ref, *, page_size: int,
+                          sm_scale: float, num_pages_per_slot: int):
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    pl.when(j == 0)(lambda: _softmax_init(m_ref, l_ref, acc_ref))
+    length = len_ref[b]                 # position being decoded (inclusive)
+    needed = jnp.logical_and(j * page_size <= length, table_ref[b, j] >= 0)
+
+    @pl.when(needed)
+    def _compute():
+        s = jax.lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)      # [H, pg]
+        kv_pos = j * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (1, page_size), 1)
+        s = jnp.where(kv_pos <= length, s * sm_scale, NEG_INF)
+        _online_softmax_step(s, v_ref[0], m_ref, l_ref, acc_ref)
+
+    @pl.when(j == num_pages_per_slot - 1)
+    def _finalize():
+        o_ref[0] = _softmax_result(l_ref, acc_ref, o_ref.dtype)
+
+
+def paged_packed_decode_attention(
+    q: jax.Array,                 # [B, 1, H, D] — one decode token per slot
+    pool_k: jax.Array,            # [P, page, KV*D]
+    pool_v: jax.Array,            # [P, page, KV*D]
+    table: jax.Array,             # [B, mpp] int32 page ids (-1 = unmapped)
+    lengths: jax.Array,           # [B] position being decoded (attend <=)
+    num_kv_heads: int,
+    *,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Exact decode attention over packed K/V rows; returns [B, 1, H, D].
+    Every page of each plane is read once, for all heads."""
+    b, _, h, d = q.shape
+    page, w = pool_k.shape[1:]
+    kv, g = num_kv_heads, h // num_kv_heads
+    mpp = table.shape[1]
+    place = jnp.eye(kv, dtype=q.dtype)[None, :, None, :, None]
+    rows = (q.reshape(b, kv, g, 1, d) * place).reshape(b, h, w)
+    kernel = functools.partial(
+        _packed_decode_kernel, page_size=page, sm_scale=d ** -0.5,
+        num_pages_per_slot=mpp)
+
+    def q_map(bi, ji, table_ref, len_ref):
+        return (bi, 0, 0)
+
+    def page_map(bi, ji, table_ref, len_ref):
+        # Unmapped pages clamp to page 0: the DMA happens but the compute
+        # predicate never reads it.
+        return (jnp.maximum(table_ref[bi, ji], 0), 0, 0)
+
+    out = pl.pallas_call(
+        kernel,
+        name="paged_packed_decode_attention",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, mpp),
+            in_specs=[pl.BlockSpec((1, h, w), q_map),
+                      pl.BlockSpec((1, page, w), page_map),
+                      pl.BlockSpec((1, page, w), page_map)],
+            out_specs=pl.BlockSpec((1, h, w), q_map),
+            scratch_shapes=[
+                pltpu.VMEM((h, 1), jnp.float32),   # running max m
+                pltpu.VMEM((h, 1), jnp.float32),   # running denom l
+                pltpu.VMEM((h, w), jnp.float32),   # row accumulator
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, w), q.dtype),
+        interpret=interpret if interpret is not None else auto_interpret(),
+    )(table, lengths, rows, pool_k, pool_v)
+    # A head's output lies in its KV head's place of the attended row.
+    own = out.reshape(b, kv, g, kv, d) * place
+    return own.sum(axis=3).reshape(b, 1, h, d)
+
+
 # Pages a grid step of the chunk kernel attends to at once: 4 pages of 128
 # are a 512-row block, which keeps the MXU's operands large; each is its own
 # operand (the same pool, another index map), DMA'd from where it lies.

@@ -76,10 +76,12 @@ from kubeflow_tpu.serve.device_state import DEAD_SLOT, DecodeState
 from kubeflow_tpu.serve.pacing import RoundPacer, decode_ladder
 from kubeflow_tpu.serve.paged import (
     PageAllocator, PagePoolExhausted, context_bucket, paged_chunk_prefill,
-    paged_decode_multi, pool_bytes_per_token, pool_planes,
+    paged_decode_multi, pool_bytes_per_token, pool_shapes,
 )
 from kubeflow_tpu.models.config import DecoderConfig
-from kubeflow_tpu.models.decoder import Params, init_decoder_params
+from kubeflow_tpu.models.decoder import (
+    Params, init_decoder_params, plane_kind,
+)
 from kubeflow_tpu.obs import profiler as prof
 from kubeflow_tpu.obs.profiler import hot_span
 from kubeflow_tpu.obs.stats import quantile as _quantile
@@ -761,15 +763,20 @@ class LLMEngine:
         # The pool, plane by plane as the model describes it (k and v
         # per head, int8 pools with their per-token-per-head scales:
         # +4 bytes per token per kv head against the 2x density win on
-        # the Dh-wide vectors; a latent model's one padded row).
+        # the Dh-wide vectors; a latent model's one padded row), each
+        # over the layers of its kind; the conv layers of a patterned
+        # stack hold their state a page, beside the attention layers'
+        # rows a token.
         self.cache = {  # lockfree: scheduler-confined (donated KV)
-            name: self._zeros(
-                (cfg.n_layers, self._num_pages, pg, *trail), dt,
-                scale=name in ("ks", "vs"))
-            for name, trail, dt in pool_planes(cfg, self.kv_quant)}
+            name: self._zeros(shape, dt, scale=name in ("ks", "vs"))
+            for name, (shape, dt) in pool_shapes(
+                cfg, self._num_pages, pg, self.kv_quant).items()}
 
         self._kv_bytes_per_token = pool_bytes_per_token(cfg, self.kv_quant)
         self._kv_pool_bytes = int(sum(v.nbytes for v in self.cache.values()))
+        self._state_pool_bytes = int(sum(
+            v.nbytes for n, v in self.cache.items()
+            if plane_kind(n) == "conv"))
 
         # Compiled programs: donate the cache so it mutates in place in HBM.
         on_tpu = jax.default_backend() == "tpu"
@@ -1021,9 +1028,10 @@ class LLMEngine:
             # cheap, and it runs the pool's own decode step and chunk
             # prefill.
             self._draft_cache = {  # lockfree: scheduler-confined
-                name: jnp.zeros((dcfg.n_layers, self.num_slots * self._mpp,
-                                 self.page_size, *trail), dt)
-                for name, trail, dt in pool_planes(dcfg)}
+                name: jnp.zeros(shape, dt)
+                for name, (shape, dt) in pool_shapes(
+                    dcfg, self.num_slots * self._mpp,
+                    self.page_size).items()}
             self._draft_cache["table"] = jnp.arange(
                 self.num_slots * self._mpp, dtype=jnp.int32).reshape(
                     self.num_slots, self._mpp)
@@ -1094,6 +1102,7 @@ class LLMEngine:
         self._prefill_programs_dispatched = 0   # lockfree: scheduler-confined counter
         self._prefill_chunks_dispatched = 0     # lockfree: scheduler-confined counter
         self._prefill_tokens_dispatched = 0     # lockfree: scheduler-confined counter
+        self._state_tail_writes = 0             # lockfree: scheduler-confined counter
         # Admit passes that sent a prefill program; chunks that were due in
         # a pass and waited for a later one (its budget of programs spent).
         self._prefill_passes = 0                # lockfree: scheduler-confined counter
@@ -1190,15 +1199,23 @@ class LLMEngine:
     def _refuse_unsupported(self, cfg: DecoderConfig, b) -> None:
         """Name, when the engine is built, each mechanism that cannot take
         this model yet: a latent page pool (one row a token for all heads)
-        has no per-head K and V, which the int8 pool's scales, the handoff
-        payload, the host tier's wire format and the speculative verify
-        step are written over; a stack of more than one kind of layer is
+        has no per-head K and V, nor have K/V heads packed into one row,
+        which the int8 pool's scales, the handoff payload, the host tier's
+        wire format and the speculative verify step are written over; conv
+        layers keep their state a page in planes of their own, which those
+        do not carry; a stack of more than one kind or group of layers is
         not one ``params["layers"]``, which those and the weight quantizer,
-        the adapter buffers and the mesh's sharding walk."""
-        if not (cfg.is_latent or cfg.leading_dense_layers):
+        the adapter buffers and the mesh's sharding walk. (Prefix reuse is
+        taken: over conv layers it resumes at page boundaries only,
+        ``_kv_match``.)"""
+        what = [name for name, has in (
+            ("a latent (ckv) KV pool", cfg.is_latent),
+            ("convolution layers whose state lives in the page pool",
+             bool(cfg.layers_of("conv"))),
+            ("K/V heads packed into one pool row", cfg.kv_heads_packed),
+            ("leading dense layers", bool(cfg.leading_dense_layers))) if has]
+        if not what:
             return
-        what = ("a latent (ckv) KV pool" if cfg.is_latent
-                else "leading dense layers")
         refused = {
             "kv_cache_dtype=int8 (int8 KV)": b.kv_cache_dtype is not None,
             f"role={b.role!r} (handoff export/adopt)": b.role != "unified",
@@ -1214,8 +1231,8 @@ class LLMEngine:
         hit = [name for name, on in refused.items() if on]
         if hit:
             raise ValueError(
-                f"this model has {what}; not supported with it yet: "
-                + "; ".join(hit))
+                f"this model has {', '.join(what)}; not supported with it "
+                "yet: " + "; ".join(hit))
 
     def _pin(self, cache: dict) -> dict:
         if self._cache_sh is None:
@@ -1275,10 +1292,15 @@ class LLMEngine:
             # a later one because its budget of programs was spent
             "prefill_passes": self._prefill_passes,
             "prefill_chunks_deferred": self._prefill_chunks_deferred,
-            # constants: content bytes a token holds over all layers of
-            # the cache, and the cache's size on the device
+            # constants: content bytes a token holds over all layers that
+            # keep rows a token, the cache's size on the device (every
+            # plane), and of it the planes that hold conv layers' state
             "kv_bytes_per_token": self._kv_bytes_per_token,
             "kv_pool_bytes": self._kv_pool_bytes,
+            "state_pool_bytes": self._state_pool_bytes,
+            # page-end tails of the conv layers' state that chunk-prefill
+            # programs wrote: one for each page a chunk's tokens touched
+            "state_tail_writes": self._state_tail_writes,
         }
 
     def queue_depth(self) -> int:
@@ -1696,6 +1718,11 @@ class LLMEngine:
         self._prefill_programs_dispatched += 1
         self._prefill_chunks_dispatched += len(group)
         self._prefill_tokens_dispatched += sum(reals)
+        if self._state_pool_bytes:
+            pg = self.page_size
+            self._state_tail_writes += sum(
+                (ch.pos + real - 1) // pg - ch.pos // pg + 1
+                for ch, real in zip(group, reals))
         for r, (ch, real) in enumerate(zip(group, reals)):
             req, plen = ch.request, len(ch.request.prompt_tokens)
             ch.pos += real
@@ -2325,8 +2352,11 @@ class LLMEngine:
         """Longest reusable prefix of ``req``'s prompt: (pages now owned
         by the request, tokens covered). Radix: live COW sharing +
         host-tier promotion, possibly sub-page. Flat: the legacy
-        full-page chained-hash hit."""
+        full-page chained-hash hit. Over conv layers a match ends at a page
+        boundary: a page holds the state it ENDS in, so there is none to
+        resume from inside one."""
         ns = req.adapter or ""
+        allow_cow = allow_cow and not self._state_pool_bytes
         if self._kvtier is not None:
             pages, covered = self._kvtier.match_and_acquire(
                 req.prompt_tokens, owner=req.id, allow_cow=allow_cow,

@@ -55,7 +55,9 @@ import numpy as np
 
 from kubeflow_tpu.models import layers as L
 from kubeflow_tpu.models.config import DecoderConfig
-from kubeflow_tpu.models.decoder import Params, layer_groups
+from kubeflow_tpu.models.decoder import (
+    Params, layer_groups, period_units, plane_kind, unit_blocks,
+)
 
 
 # -- host-side page allocator --------------------------------------------------
@@ -334,6 +336,14 @@ def pool_planes(cfg: DecoderConfig, kv_quant: bool = False) -> tuple:
             raise ValueError("int8 KV over a latent (ckv) pool")
         return (("ckv", (L.latent_row_width(cfg),), dt),)
     kv = (cfg.n_kv_heads, cfg.head_dim)
+    if cfg.kv_heads_packed:
+        # Heads narrower than the 128-value lanes: all of a token's heads
+        # side by side in ONE row, for the same reason as the latent row
+        # (an [8, 64] plane compiles to twice its bytes and is copied whole
+        # around each decode write: PERF.md, PR 35).
+        if kv_quant:
+            raise ValueError("int8 KV over packed K/V rows")
+        kv = (cfg.n_kv_heads * cfg.head_dim,)
     if kv_quant:
         f32 = jnp.dtype(jnp.float32)
         return (("k", kv, jnp.dtype(jnp.int8)), ("v", kv, jnp.dtype(jnp.int8)),
@@ -341,13 +351,59 @@ def pool_planes(cfg: DecoderConfig, kv_quant: bool = False) -> tuple:
     return (("k", kv, dt), ("v", kv, dt))
 
 
+def state_planes(cfg: DecoderConfig) -> tuple:
+    """What one PAGE holds in one conv layer of the pool, beside the token
+    planes of the attention layers: (name, trailing shape, type). "conv":
+    the ``conv_taps - 1`` gated rows (``layers.conv_block``) as they stood
+    after the last token written in that page, so a sequence's state is
+    reached through its page table like its K and V: a decode step at
+    position ``t`` reads the page of ``t - 1`` and writes the page of ``t``,
+    a chunk reads at ``start - 1`` and writes the end of every page it
+    fills and its last token's, and whole pages are shared, copied and
+    preempted with the state they end in. () for a stack without conv
+    layers."""
+    if not cfg.layers_of("conv"):
+        return ()
+    return (("conv", (cfg.conv_taps - 1, cfg.hidden), cfg.activation_dtype),)
+
+
+def pool_shapes(cfg: DecoderConfig, num_pages: int, page_size: int,
+                kv_quant: bool = False) -> dict:
+    """The pool an engine builds: {plane: (shape, type)}. A token plane is
+    ``[layers of attention, P, page, ...]``, a state plane ``[layers of
+    conv, P, ...]``: each over the layers of ITS kind (``decoder.
+    plane_kind``), so a stack whose layers are all attention keeps ``[L, P,
+    page, ...]``."""
+    out = {n: ((cfg.layers_of("attention"), num_pages, page_size, *trail),
+               dt) for n, trail, dt in pool_planes(cfg, kv_quant)}
+    out.update({n: ((cfg.layers_of("conv"), num_pages, *trail), dt)
+                for n, trail, dt in state_planes(cfg)})
+    return out
+
+
+def _plane_bytes(planes: tuple) -> int:
+    return sum(int(np.prod(trail)) * jnp.dtype(dt).itemsize
+               for _, trail, dt in planes)
+
+
 def pool_bytes_per_token(cfg: DecoderConfig, kv_quant: bool = False) -> int:
-    """Bytes one token holds over all layers of the pool (a latent row's
-    padding included: 1280 a layer at the published ranks for 1152 of
-    content)."""
-    return cfg.n_layers * sum(
-        int(np.prod(trail)) * jnp.dtype(dt).itemsize
-        for _, trail, dt in pool_planes(cfg, kv_quant))
+    """Bytes one token holds over all layers of the pool that keep rows a
+    token: the attention layers (a latent row's padding included: 1280 a
+    layer at the published ranks for 1152 of content). A conv layer holds
+    none a token; what it holds a page is ``state_bytes_per_page``."""
+    return cfg.layers_of("attention") * _plane_bytes(
+        pool_planes(cfg, kv_quant))
+
+
+def state_bytes_per_page(cfg: DecoderConfig) -> int:
+    """Bytes one page holds over all conv layers: their state's tail."""
+    return cfg.layers_of("conv") * _plane_bytes(state_planes(cfg))
+
+
+def _pool_geometry(cache: dict) -> tuple:
+    """(pages, page size) of a cache pytree: its first token plane's."""
+    return next(cache[n].shape[1:3] for n in _planes_of(cache)
+                if plane_kind(n) == "attention")
 
 
 def _planes_of(cache: dict) -> tuple:
@@ -392,24 +448,35 @@ def _feed_forward(bp, h, cfg: DecoderConfig, expert_stack=None,  # traced
 
 def _scan_layer_groups(params: Params, cfg: DecoderConfig, carry, block,  # traced
                        lora=None):
-    """``carry`` through every layer, one scan per group of alike layers
-    (decoder.layer_groups): ``block(bp, carry, layer, gcfg, lora_view,
-    expert_stack) -> carry``, ``layer`` the index into the pool, which runs
-    through the groups. A sorted expert group's expert leaves are taken
-    whole, with the layer's index in the group (layers.split_expert_stack)."""
+    """``carry`` through every layer, one scan per group (decoder.
+    layer_groups), a period of the group's pattern an iteration:
+    ``block(bp, carry, layer, gcfg, lora_view, expert_stack) -> carry``.
+    ``layer`` is the layer's index among the stack's layers of ITS kind,
+    which is its index into the pool's planes of that kind (for a stack of
+    alike layers: its index in the stack); ``bp`` holds its operator under
+    its kind's key (``"conv" in bp``). A sorted expert group's expert leaves
+    are taken whole, with the layer's index in the group
+    (layers.split_expert_stack)."""
     for name, gcfg, first in layer_groups(cfg):
         stack, experts = L.split_expert_stack(params[name], gcfg)
+        period = gcfg.period
+        at = {kind: cfg.kinds[:first].count(kind) for kind in period}
 
-        def body(carry, scan_in, gcfg=gcfg, experts=experts, first=first):
-            bp, lsl, layer = scan_in
-            return block(bp, carry, layer, gcfg, L.layer_view(lora, lsl),
-                         None if experts is None
-                         else (experts, layer - first)), None
+        def body(carry, scan_in, gcfg=gcfg, experts=experts, period=period,
+                 at=at):
+            unit, lsl, u = scan_in
+            for j, (kind, i, bp) in enumerate(unit_blocks(unit, gcfg)):
+                carry = block(
+                    bp, carry, at[kind] + u * period.count(kind) + i, gcfg,
+                    L.layer_view(lora, lsl),
+                    None if experts is None
+                    else (experts, u * len(period) + j))
+            return carry, None
 
         carry, _ = jax.lax.scan(
             body, carry,
-            (stack, L.slice_layers(lora),
-             first + jnp.arange(gcfg.n_layers, dtype=jnp.int32)))
+            (period_units(stack, gcfg), L.slice_layers(lora),
+             jnp.arange(gcfg.n_layers // len(period), dtype=jnp.int32)))
     return carry
 
 
@@ -434,17 +501,19 @@ def _decode_attention(q, ck, cv, lengths, cfg: DecoderConfig):  # traced
 
 
 def _paged_decode_block(bp, x, positions, lengths, live, pools, table,  # traced
-                        layer, num_pages: int, cfg: DecoderConfig,
-                        attn_impl: str = "gather", lora=None,
-                        expert_stack=None):
+                        layer, num_pages: int, page_size: int,
+                        cfg: DecoderConfig, attn_impl: str = "gather",
+                        lora=None, expert_stack=None):
     """One transformer block for a [B,1] decode step against the page pool.
 
     ``pools`` holds every plane of the WHOLE pool viewed flat —
-    ``k``/``v`` ``[L*P,pg,KV,Dh]`` and, iff the pool stores int8, the
-    per-token-per-head scales ``ks``/``vs`` ``[L*P,pg,KV]`` f32; a latent
-    pool's one plane ``ckv`` ``[L*P,pg,W]`` — and ``layer`` (a traced scalar)
-    picks this block's ``num_pages`` (P) pages out of it: page ``p`` of
-    layer ``l`` is flat page ``l*P + p``. The block writes its token's rows
+    ``k``/``v`` ``[L*P,pg,KV,Dh]`` (``[L*P,pg,KV*Dh]`` where the heads are
+    packed) and, iff the pool stores int8, the per-token-per-head scales
+    ``ks``/``vs`` ``[L*P,pg,KV]`` f32; a latent pool's one plane ``ckv``
+    ``[L*P,pg,W]``; the conv layers' state ``conv`` ``[Lc*P,taps-1,D]`` —
+    and ``layer`` (a traced scalar: the block's index among the layers of
+    its kind) picks this block's ``num_pages`` (P) pages out of its kind's
+    planes: page ``p`` of layer ``l`` is flat page ``l*P + p``. The block writes its token's rows
     into the planes it was handed and returns them, so the caller can carry
     them through its loops and the write lands in place; nothing here
     slices a layer's slab out or puts one back.
@@ -460,7 +529,9 @@ def _paged_decode_block(bp, x, positions, lengths, live, pools, table,  # traced
     read. A latent pool is attended in the ABSORBED form (the key expansion
     folded into the query, the value expansion applied to the attended
     latent), so the step never holds per-head K or V of the context."""
-    total, pg = next(iter(pools.values())).shape[:2]
+    kind = "conv" if "conv" in bp else "attention"
+    own = next(pools[n] for n in pools if plane_kind(n) == kind)
+    total, pg = own.shape[0], page_size
     base = layer * num_pages
     h = L.rmsnorm(x, bp["ln1"], cfg)
     # Write position -> (flat page, offset). Dead rows and unmapped pages
@@ -470,16 +541,38 @@ def _paged_decode_block(bp, x, positions, lengths, live, pools, table,  # traced
     page_slot = lengths // pg
     page_id = table[bidx, jnp.clip(page_slot, 0, table.shape[1] - 1)]
     pidx = jnp.where(live & (page_id >= 0), base + page_id, total)
-    off = lengths % pg
-    # This layer's page table into the flat pool; -1 stays unmapped.
-    ltable = jnp.where(table >= 0, table + base, -1)
-    attend = _latent_decode_attention if cfg.is_latent \
-        else _kv_decode_attention
-    proj, pools = attend(bp["attn"], h, positions, lengths, pools, pidx, off,
-                         ltable, cfg, attn_impl, lora)
+    if kind == "conv":
+        proj, pools = _conv_decode(bp["conv"], h, lengths, pools, pidx,
+                                   base, table, pg, cfg)
+    else:
+        off = lengths % pg
+        # This layer's page table into the flat pool; -1 stays unmapped.
+        ltable = jnp.where(table >= 0, table + base, -1)
+        attend = _latent_decode_attention if cfg.is_latent \
+            else _kv_decode_attention
+        proj, pools = attend(bp["attn"], h, positions, lengths, pools, pidx,
+                             off, ltable, cfg, attn_impl, lora)
     x = x + proj
     h = L.rmsnorm(x, bp["ln2"], cfg)
     return x + _feed_forward(bp, h, cfg, expert_stack), pools
+
+
+def _conv_decode(c, h, lengths, pools, pidx, base, table, pg: int,  # traced
+                 cfg: DecoderConfig):
+    """A conv layer's decode step: the state as it stood after position
+    ``t - 1`` is in the page of ``t - 1`` (nothing before a sequence's first
+    token), the state after ``t`` goes to the page of ``t`` (``pidx``: past
+    the pool for a dead row). Returns (the operator's output [B,1,D], the
+    planes as written)."""
+    state = pools["conv"]
+    before = jnp.maximum(lengths - 1, 0) // pg
+    prev = table[jnp.arange(h.shape[0]),
+                 jnp.clip(before, 0, table.shape[1] - 1)]
+    tail = state[jnp.clip(base + prev, 0, state.shape[0] - 1)]
+    tail = jnp.where(((lengths > 0) & (prev >= 0))[:, None, None], tail, 0)
+    proj, zs = L.conv_block(c, h, cfg, tail)
+    return proj, {**pools, "conv": state.at[pidx].set(zs[:, 1:],
+                                                      mode="drop")}
 
 
 def _kv_decode_attention(a, h, positions, lengths, pools, pidx, off,  # traced
@@ -498,17 +591,27 @@ def _kv_decode_attention(a, h, positions, lengths, pools, pidx, off,  # traced
         q = L.apply_lora_layer(lora, "wq", h, q)
         k = L.apply_lora_layer(lora, "wk", h, k)
         v = L.apply_lora_layer(lora, "wv", h, v)
-    q = L.rope(q, positions, cfg.rope_theta)
-    k = L.rope(k, positions, cfg.rope_theta)
+    q, k = L.qk_rope(a, q, k, positions, cfg)
     rows = {"k": k[:, 0], "v": v[:, 0]}
+    packed = pools["k"].ndim == 3       # [L*P, pg, KV*Dh]: heads in one row
+    if packed:
+        rows = {n: r.reshape(r.shape[0], -1) for n, r in rows.items()}
     if kv_quant:
         from kubeflow_tpu.ops.quantization import dequantize_kv, quantize_kv
 
         rows["k"], rows["ks"] = quantize_kv(k[:, 0])
         rows["v"], rows["vs"] = quantize_kv(v[:, 0])
-    pools = {name: pools[name].at[pidx, off].set(row, mode="drop")
-             for name, row in rows.items()}
-    if attn_impl == "pallas":
+    pools = {**pools, **{
+        name: pools[name].at[pidx, off].set(row, mode="drop")
+        for name, row in rows.items()}}
+    if attn_impl == "pallas" and packed:
+        from kubeflow_tpu.ops.paged_attention import (
+            paged_packed_decode_attention,
+        )
+
+        attn = paged_packed_decode_attention(
+            q, pools["k"], pools["v"], ltable, lengths, cfg.n_kv_heads)
+    elif attn_impl == "pallas":
         from kubeflow_tpu.ops.paged_attention import paged_decode_attention
 
         attn = paged_decode_attention(q, pools["k"], pools["v"], ltable,
@@ -517,6 +620,9 @@ def _kv_decode_attention(a, h, positions, lengths, pools, pidx, off,  # traced
     else:
         ck = paged_gather(pools["k"], ltable)
         cv = paged_gather(pools["v"], ltable)
+        if packed:
+            ck = ck.reshape(*ck.shape[:2], cfg.n_kv_heads, cfg.head_dim)
+            cv = cv.reshape(*cv.shape[:2], cfg.n_kv_heads, cfg.head_dim)
         if kv_quant:
             ck = dequantize_kv(ck, paged_gather(pools["ks"], ltable), dt)
             cv = dequantize_kv(cv, paged_gather(pools["vs"], ltable), dt)
@@ -579,13 +685,13 @@ def _paged_decode_step(params: Params, cache: dict, tokens: jax.Array,  # traced
     positions = lengths[:, None]
     table = cache["table"]
     planes = _planes_of(cache)
-    num_pages = cache[planes[0]].shape[1]
+    num_pages, pg = _pool_geometry(cache)
     flat = {n: cache[n].reshape(-1, *cache[n].shape[2:]) for n in planes}
 
     def block(bp, carry, layer, gcfg, lora_view, expert_stack):
         return _paged_decode_block(
             bp, carry[0], positions, lengths, live, carry[1], table, layer,
-            num_pages, gcfg, attn_impl=attn_impl, lora=lora_view,
+            num_pages, pg, gcfg, attn_impl=attn_impl, lora=lora_view,
             expert_stack=expert_stack)
 
     x, flat = _scan_layer_groups(params, cfg, (x, flat), block, lora)
@@ -621,8 +727,7 @@ def paged_decode_multi(params: Params, cache: dict, tokens: jax.Array,  # traced
 
     b = tokens.shape[0]
     mpp = cache["table"].shape[1]
-    pg = cache[_planes_of(cache)[0]].shape[2]
-    max_len = mpp * pg
+    max_len = mpp * _pool_geometry(cache)[1]
     out0 = jnp.full((b, num_steps), -1, jnp.int32)
     lr = (None if lora is None
           else {**lora, "aidx": adapter_idx})
@@ -655,7 +760,8 @@ def paged_decode_multi(params: Params, cache: dict, tokens: jax.Array,  # traced
 
 def copy_pages(cache: dict, src: jax.Array, dst: jax.Array) -> dict:  # traced
     """Page-to-page pool copy: ``dst[i] <- src[i]`` for every pool plane
-    (k/v and, when quantized, their scales; a latent pool's one padded row)
+    (k/v and, when quantized, their scales; a latent pool's one padded row;
+    the conv layers' state, which a page ends in)
     — the radix index's copy-on-write primitive (serve/kvtier.py): a
     request diverging inside a shared block gets a private copy of the
     partial tail in ONE dispatch instead of recomputing it. Out-of-range
@@ -713,7 +819,9 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
     start a row; each row then attends over the shortest span of the bucket
     ladder that holds its own context, ``layers._cached_attention_by_row``),
     then scatters only the chunk's tokens back; every plane of a per-head
-    pool (``pool_planes``) goes the same way. A latent pool takes
+    pool (``pool_planes``) goes the same way. The conv layers of a
+    patterned stack take the state their row's chunk starts from and leave
+    the tails its pages end in (``state_planes``). A latent pool takes
     the chunk as the decode step takes a token
     (``_paged_latent_chunk_prefill``: rows written in place, attention
     absorbed over the pages where they lie, ``paged_attn_impl`` the engine's
@@ -732,10 +840,12 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
         return _paged_latent_chunk_prefill(
             params, cache, tokens, table_rows, start, valid_len, cfg,
             paged_attn_impl, context_pages)
-    planes = _planes_of(cache)
-    pg = cache[planes[0]].shape[2]
+    planes = tuple(n for n in _planes_of(cache)
+                   if plane_kind(n) == "attention")
+    num_pages, pg = _pool_geometry(cache)
     b, c = tokens.shape
     kv_quant = "ks" in cache
+    packed = cache["k"].ndim == 4       # [L, P, pg, KV*Dh]: heads in one row
     if context_pages is not None:
         # Static slice: the bucket must cover the chunk's own pages too
         # (the [start, start+C) update-slice window below).
@@ -749,6 +859,9 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
     # scattered back to pages.
     rows = {n: jax.vmap(lambda pool: paged_gather(pool, table_rows))(
         cache[n]) for n in planes}
+    if packed:
+        rows = {n: r.reshape(*r.shape[:3], cfg.n_kv_heads, cfg.head_dim)
+                for n, r in rows.items()}
     if kv_quant:
         from kubeflow_tpu.ops.quantization import dequantize_kv, quantize_kv
 
@@ -758,6 +871,9 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
     caches = {n: jnp.pad(row, [(0, 0), (0, 0), (0, c)]
                          + [(0, 0)] * (row.ndim - 3))
               for n, row in rows.items()}
+    if "conv" in cache:
+        caches["conv"] = _chunk_state_before(cache["conv"], table_rows,
+                                             start, pg)
     caches["len"] = start
     lr = None if lora is None else {**lora, "aidx": adapter_idx}
     logits, filled, _ = decoder_forward(params, tokens, cfg, kv_caches=caches,
@@ -772,13 +888,78 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
         [jax.lax.dynamic_slice_in_dim(filled[n][:, r], start[r], c, axis=1)
          for r in range(b)], axis=1) for n in rows}           # [L,B,C,...]
     pidx, off = _chunk_write_index(table_rows, start, valid_len, c, pg,
-                                   cache[planes[0]].shape[1])
+                                   num_pages)
     if kv_quant:
         written["k"], written["ks"] = quantize_kv(written["k"])
         written["v"], written["vs"] = quantize_kv(written["v"])
-    out = {n: cache[n].at[:, pidx, off].set(written[n], mode="drop")
-           for n in planes}
+    if packed:
+        out = {n: _scatter_flat(cache[n], pidx, off,
+                                w.reshape(*w.shape[:3], -1))
+               for n, w in written.items()}
+    else:
+        out = {n: cache[n].at[:, pidx, off].set(written[n], mode="drop")
+               for n in planes}
+    if "conv" in cache:
+        out["conv"] = _chunk_state_after(cache["conv"], filled["conv"],
+                                         table_rows, start, valid_len, c, pg)
     return logits, out
+
+
+def _scatter_flat(pool: jax.Array, pidx: jax.Array, off: jax.Array,  # traced
+                  written: jax.Array) -> jax.Array:
+    """``pool[l, pidx, off] = written[l]`` for every layer, as ONE scatter
+    into the pool viewed flat ``[L*P, page, W]`` (the decode write's
+    addressing): scattered under a leading layer axis, a packed-row pool of
+    two layers is re-laid out whole, four pool-sized copies a chunk program
+    (the chip's compiler, PR 35). A dropped write (``pidx`` past the
+    layer's pages) aims past the END of the flat pool."""
+    layers, pages = pool.shape[:2]
+    flat = pool.reshape(layers * pages, *pool.shape[2:])
+    at = jnp.where(
+        pidx < pages,
+        pidx + pages * jnp.arange(layers, dtype=jnp.int32)[:, None, None],
+        layers * pages)
+    return flat.at[at, off].set(written, mode="drop").reshape(pool.shape)
+
+
+def _chunk_state_before(state: jax.Array, table_rows: jax.Array,  # traced
+                        start: jax.Array, pg: int) -> jax.Array:
+    """The conv layers' state each row's chunk starts from, [Lc,B,taps-1,D]:
+    what the page of position ``start - 1`` ends in, zeros at a sequence's
+    start and for a row without that page."""
+    before = jnp.maximum(start - 1, 0) // pg
+    prev = jnp.take_along_axis(
+        table_rows, jnp.clip(before, 0, table_rows.shape[1] - 1)[:, None],
+        axis=1)[:, 0]
+    tail = state[:, jnp.clip(prev, 0, state.shape[1] - 1)]
+    return jnp.where(((start > 0) & (prev >= 0))[None, :, None, None],
+                     tail, 0)
+
+
+def _chunk_state_after(state: jax.Array, zs: jax.Array,  # traced
+                       table_rows: jax.Array, start: jax.Array,
+                       valid_len: jax.Array, c: int, pg: int) -> jax.Array:
+    """The state plane with the chunks' tails written: for every page a
+    row's VALID tokens touch, the state as it stood after the last of them
+    in that page (the page's end, or the chunk's last valid token), so that
+    the next chunk, the first decode step and a later request that reuses
+    whole pages each find theirs. ``zs`` [Lc,B,taps-1+C,D]
+    (``layers.conv_block``): the state after chunk position ``i`` is its
+    rows ``i+1 .. i+taps-1``. A dead row and an unmapped page aim past the
+    pool and drop."""
+    num_pages, keep = state.shape[1], state.shape[2]
+    ends = (pg - 1 - start % pg)[:, None] + pg * jnp.arange(
+        -(-c // pg) + 1, dtype=jnp.int32)[None, :]              # [B,M]
+    i = jnp.minimum(ends, valid_len[:, None] - 1)
+    pslot = (start[:, None] + i) // pg
+    page = jnp.take_along_axis(
+        table_rows, jnp.clip(pslot, 0, table_rows.shape[1] - 1), axis=1)
+    ok = (i >= 0) & (page >= 0) & (pslot < table_rows.shape[1]) \
+        & (page < num_pages)
+    rows = jnp.maximum(i, 0)[..., None] + 1 + jnp.arange(keep)   # [B,M,keep]
+    tails = zs[:, jnp.arange(zs.shape[1])[:, None, None], rows]
+    return state.at[:, jnp.where(ok, page, num_pages)].set(tails,
+                                                           mode="drop")
 
 
 def _chunk_write_index(table_rows: jax.Array, start: jax.Array,  # traced

@@ -124,8 +124,7 @@ def _paged_spec_block(bp, x, positions, lengths, live, pool_k, pool_v,  # traced
     q = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wq"].astype(dt))
     k = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wk"].astype(dt))
     v = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wv"].astype(dt))
-    q = L.rope(q, positions, cfg.rope_theta)
-    k = L.rope(k, positions, cfg.rope_theta)
+    q, k = L.qk_rope(bp["attn"], q, k, positions, cfg)
     bidx = jnp.arange(x.shape[0])[:, None]                    # [B,1]
     page_slot = positions // pg                               # [B,T]
     page_id = table[bidx, jnp.clip(page_slot, 0, mpp - 1)]

@@ -126,7 +126,7 @@ def paged_serve_analysis(topology: str, tp: int, *, model: str,
     from kubeflow_tpu.parallel.sharding import shard_params
     from kubeflow_tpu.runtime.device_report import kernel_calls
     from kubeflow_tpu.serve.paged import (
-        paged_chunk_prefill, paged_decode_multi, pool_planes)
+        paged_chunk_prefill, paged_decode_multi, pool_shapes)
 
     mesh = _mesh_on(topology, {"model": tp}, topo_kwargs=topo_kwargs)
     cfg = preset(model, **overrides)
@@ -160,9 +160,9 @@ def paged_serve_analysis(topology: str, tp: int, *, model: str,
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
 
     mpp = max_len // page_size
-    cache = {n: sds((cfg.n_layers, num_pages, page_size, *trail), dt,
-                    kv_sh if len(trail) == 2 else rep)
-             for n, trail, dt in pool_planes(cfg)}
+    cache = {n: sds(shape, dt, kv_sh if n in ("k", "v") else rep)
+             for n, (shape, dt) in pool_shapes(cfg, num_pages,
+                                               page_size).items()}
     i32, f32 = (lambda: sds((slots,), jnp.int32)), (
         lambda: sds((slots,), jnp.float32))
 

@@ -237,3 +237,41 @@ def test_sorted_experts_are_a_grouped_matmul_on_v5e(chip, tokens):
     if tokens % 128:        # a kernel's tiles are not in XLA's count
         need = 2.0 * tokens * (cfg.experts_per_token + 1) * 3 * d * m
         assert compiled.cost_analysis()["flops"] < 2 * need
+
+
+def test_packed_row_decode_step_compiles_for_v5e_without_pool_copies(chip):
+    """LFM2's heads of 64: K and V lie one 512-value row a token in the
+    pool (``kv_heads_packed``). At the cell's sizes (64 slots, 1600 pages of
+    128, two attention layers) the packed-row kernel compiles, and the
+    decode write over the flat pool leaves no pool-sized copy in the
+    program (an [8, 64] plane is padded to twice its bytes and copied whole,
+    twice a plane: PERF.md, PR 35)."""
+    import re
+
+    from kubeflow_tpu.ops.paged_attention import (
+        paged_packed_decode_attention,
+    )
+
+    slots, h, kv, d, layers, pages, mpp = 64, 32, 8, 64, 2, 1600, 25
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    def step(q, pool_k, pool_v, rows, pidx, off, table, lengths):
+        pool_k = pool_k.at[pidx, off].set(rows, mode="drop")
+        pool_v = pool_v.at[pidx, off].set(rows, mode="drop")
+        return paged_packed_decode_attention(
+            q, pool_k, pool_v, table, lengths, kv, interpret=False), \
+            pool_k, pool_v
+
+    pool = sds((layers * pages, PAGE, kv * d))
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        sds((slots, 1, h, d)), pool, pool, sds((slots, kv * d)),
+        sds((slots,), jnp.int32), sds((slots,), jnp.int32),
+        sds((slots, mpp), jnp.int32), sds((slots,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "paged_packed_decode_attention" in text
+    whole = re.findall(
+        rf"= bf16\[{layers * pages},{PAGE},{kv * d}\]\S* copy\(", text)
+    assert not whole, whole

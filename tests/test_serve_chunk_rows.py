@@ -24,7 +24,7 @@ from kubeflow_tpu.serve.engine import (
     RIDGE_ROWS, LLMEngine, SamplingParams, chunk_rows_per_weight,
 )
 from kubeflow_tpu.serve.paged import (
-    context_bucket, paged_chunk_prefill, pool_planes,
+    context_bucket, paged_chunk_prefill, pool_shapes,
 )
 
 PAGE, CHUNK, MPP, POOL = 16, 32, 8, 14
@@ -39,11 +39,15 @@ def _config(kind: str):
         # Mixtral-like: capacity buffers at the published factor.
         return preset("tiny-moe", dtype="float32", param_dtype="float32",
                       capacity_factor=1.25, max_seq_len=1024)
+    if kind == "patterned":
+        # LFM2-like: conv layers beside attention, state in the pool
+        return preset("tiny-lfm2", dtype="float32", param_dtype="float32",
+                      max_seq_len=1024)
     return preset("tiny-glm", dtype="float32", param_dtype="float32",
                   max_seq_len=1024)
 
 
-KINDS = ("dense", "dispatch", "latent")
+KINDS = ("dense", "dispatch", "latent", "patterned")
 EXPERT_KINDS = KINDS[1:]
 
 
@@ -67,8 +71,8 @@ def _tokens(seed: int, n: int) -> np.ndarray:
 
 
 def _empty_pool(cfg):
-    return {name: jnp.zeros((cfg.n_layers, POOL, PAGE, *trail), dt)
-            for name, trail, dt in pool_planes(cfg)}
+    return {name: jnp.zeros(shape, dt)
+            for name, (shape, dt) in pool_shapes(cfg, POOL, PAGE).items()}
 
 
 def _rows_program(cfg):
@@ -145,8 +149,9 @@ class TestRowsProgram:
                                        atol=2e-5, err_msg=name)
         # ... and they wrote: a's 32 rows from position 24 on (pages 1-3),
         # b's 19 from 64 on (pages 8-9), nothing behind b's valid length.
-        plane = np.asarray(got[next(iter(got))])
-        before = np.asarray(cache[next(iter(cache))])
+        rows = next(n for n in got if n != "conv")      # a plane a token
+        plane = np.asarray(got[rows])
+        before = np.asarray(cache[rows])
         assert np.any(plane[:, 1, 8:] != before[:, 1, 8:])
         assert np.any(plane[:, 8] != before[:, 8])
         np.testing.assert_array_equal(plane[:, 9, 3:], before[:, 9, 3:])
