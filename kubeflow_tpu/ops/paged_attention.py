@@ -1,5 +1,6 @@
-"""Pallas TPU paged-attention decode kernels (per-head K/V pools; latent
-pools at the end of the file).
+"""Pallas TPU paged-attention kernels: decode over per-head K/V pools
+(first), latent pools and packed rows, and a chunk of queries over a latent
+pool and over per-head pools (the end of the file).
 
 The paged engine's XLA path reads KV twice per step: a gather materializes
 each slot's pages into the [B, S, K, D] layout, then attention reads the
@@ -205,19 +206,20 @@ def paged_decode_attention(
 # softmax, float32 accumulation): one query a slot (decode), and a chunk of
 # queries of one slot (chunk prefill).
 
-def _online_softmax_step(s, rows, m_ref, l_ref, acc_ref):
+def _online_softmax_step(s, rows, m_ref, l_ref, acc_ref, at=slice(None)):
     """One block of the running softmax: ``s`` [Q, T] masked scores (f32),
-    ``rows`` [T, W] the block's cache rows, which are also its values."""
-    m_prev = m_ref[:]                                # [Q, 1]
+    ``rows`` [T, W] the block's values (a latent block's cache rows are
+    both); ``at``: the state's rows these queries own."""
+    m_prev = m_ref[at]                               # [Q, 1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_new)                           # [Q, T]
     alpha = jnp.exp(m_prev - m_new)
-    l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=1, keepdims=True)
+    l_ref[at] = alpha * l_ref[at] + jnp.sum(p, axis=1, keepdims=True)
     # Probabilities go to the MXU in the pool's type, as in the XLA form.
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+    acc_ref[at] = acc_ref[at] * alpha + jax.lax.dot_general(
         p.astype(rows.dtype), rows, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)          # [Q, W]
-    m_ref[:] = m_new
+    m_ref[at] = m_new
 
 
 def _softmax_init(m_ref, l_ref, acc_ref):
@@ -481,3 +483,212 @@ def paged_latent_chunk_attention(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret if interpret is not None else auto_interpret(),
     )(table, jnp.reshape(start, (1,)).astype(jnp.int32), q, *([pool] * n))
+
+
+# -- a chunk of queries over per-head pools --------------------------------------
+#
+# The chunk kernel's schedule again (pages DMA'd from where they lie through
+# the table row, ``CHUNK_PAGES_PER_STEP`` of each plane a grid step, pages
+# behind the queries skipped, blockwise softmax in float32), over K and V
+# planes ``[P, page, KV, D]``. A page is one block with all its KV heads, and
+# a grid step attends EVERY head of a tile of queries to it, so a page is
+# read once a tile: a group's query heads lie head-major ``[g x tile, D]``
+# against their KV head's ``[block, D]`` keys (no repeat of K or V), and
+# nothing ``[H, C, context]`` ever leaves fast memory.
+
+# Queries a grid step takes, all heads of them, halved while heads x tile is
+# over CHUNK_STATE_ROWS: 128 x 32 heads keep the float32 accumulator, the
+# running max and the denominator at 2 MiB each (a tile of 256 measured a
+# third slower on a v5e: PERF.md, PR 36).
+CHUNK_QUERY_TILE = 128
+CHUNK_STATE_ROWS = 4096
+# What a grid step then keeps in fast memory: those 6 MiB, the queries' and
+# the output's blocks twice (4), eight page blocks twice (4), a head's scores
+# and probabilities (3): over the compiler's default of 16 MiB, a quarter of
+# a v5e's 128.
+CHUNK_VMEM_BYTES = 32 * 2 ** 20
+
+
+def chunk_attention_supported(num_kv_heads: int, head_dim: int,
+                              dtype) -> bool:
+    """Whether ``paged_chunk_attention`` takes K/V planes ``[P, page, KV,
+    D]`` of this type: a head's rows are parted from a page's by a strided
+    load, which the chip has for 128-value rows of 32 bits; bfloat16 rides
+    it two heads a word (``_word_heads``), so its heads must pair."""
+    dtype = jnp.dtype(dtype)
+    return head_dim == 128 and (
+        dtype == jnp.float32
+        or (dtype == jnp.bfloat16 and num_kv_heads % 2 == 0))
+
+
+def _word_heads(words, dtype) -> list:
+    """The heads a page's strided rows hold, [page, D] each in ``dtype``: a
+    float32 row is its head; a 32-bit word of two-byte rows holds heads 2i
+    (its low half) and 2i+1 (its high half), and a half moved to the upper
+    bits of a float32 IS that bfloat16 value."""
+    if words.dtype != jnp.uint32:
+        return [words]
+    return [pltpu.bitcast(half, jnp.float32).astype(dtype)
+            for half in (words << 16, words & jnp.uint32(0xFFFF0000))]
+
+
+def _chunk_kernel(table_ref, start_ref, q_ref, *rest, page_size: int,
+                  sm_scale: float):
+    n = CHUNK_PAGES_PER_STEP
+    k_refs, v_refs = rest[:n], rest[n:2 * n]
+    o_ref, k_rows, v_rows, m_ref, l_ref, acc_ref = rest[2 * n:]
+    j = pl.program_id(1)
+    h, tile, d = q_ref.shape
+    kv = k_refs[0].shape[2]
+    per = k_rows.shape[0]               # heads a 32-bit word of a row holds
+    g = h // kv
+    block = n * page_size
+    pl.when(j == 0)(lambda: _softmax_init(m_ref, l_ref, acc_ref))
+    first = start_ref[0] + pl.program_id(0) * tile   # the tile's first query
+
+    def words_of(page_ref):
+        """A page block [1, page, KV, D] as rows of 32-bit words
+        ``[page * KV / per, D]``: every (KV / per)-th row from ``w`` on is
+        word ``w`` of the page's tokens in order."""
+        rows = page_ref.at[0].reshape(page_size * kv, d)
+        return rows if per == 1 else rows.bitcast(jnp.uint32)
+
+    planes = [(k_rows, [words_of(r) for r in k_refs]),
+              (v_rows, [words_of(r) for r in v_refs])]
+
+    def attend(masked: bool):
+        if masked:
+            q_pos = first + jax.lax.broadcasted_iota(
+                jnp.int32, (tile, block), 0)
+            kv_pos = j * block + jax.lax.broadcasted_iota(
+                jnp.int32, (tile, block), 1)
+            allowed = (kv_pos <= q_pos)[None]
+
+        def one_word(w, _):
+            for rows_ref, pages in planes:
+                for i, page in enumerate(pages):
+                    at = slice(i * page_size, (i + 1) * page_size)
+                    words = page[pl.ds(w, page_size, stride=kv // per), :]
+                    for half, rows in enumerate(_word_heads(
+                            words, rows_ref.dtype)):
+                        rows_ref[half, at, :] = rows
+            for half in range(per):
+                head = w * per + half
+                q = q_ref[pl.ds(head * g, g)].reshape(g * tile, d)
+                s = jax.lax.dot_general(
+                    q, k_rows[half], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * sm_scale
+                if masked:                           # [g * tile, block]
+                    s = jnp.where(allowed, s.reshape(g, tile, block),
+                                  NEG_INF).reshape(g * tile, block)
+                own = pl.ds(pl.multiple_of(head * g * tile, g * tile),
+                            g * tile)
+                _online_softmax_step(s, v_rows[half], m_ref, l_ref, acc_ref,
+                                     own)
+
+        # One trip a word of heads, not KV unrolled copies of it: a kernel's
+        # body is traced and lowered again at every call site of every
+        # program (unrolled, 2.5 s a site on the chip's host: PERF.md, PR 36).
+        jax.lax.fori_loop(0, kv // per, one_word, None)
+
+    # A block is attended if a query of the tile sees it and the row has it
+    # (a dead row's table is unmapped: it attends to nothing and emits
+    # zeros); only a block that reaches past the tile's FIRST query pays for
+    # the causal mask.
+    seen = jnp.logical_and(j * block <= first + tile - 1,
+                           table_ref[j * n] >= 0)
+    whole = (j + 1) * block - 1 <= first
+    pl.when(jnp.logical_and(seen, whole))(lambda: attend(False))
+    pl.when(jnp.logical_and(seen, jnp.logical_not(whole)))(
+        lambda: attend(True))
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        # The state's rows lie KV head, then the group's heads, then the
+        # tile's queries: query heads in order.
+        o_ref[:] = _softmax_result(l_ref, acc_ref, o_ref.dtype).reshape(
+            h, tile, d)
+
+
+def paged_chunk_attention(
+    q: jax.Array,                 # [H, C, D]: one slot's chunk, head-major
+    pool_k: jax.Array,            # [P, page, KV, D]
+    pool_v: jax.Array,            # [P, page, KV, D]
+    table_row: jax.Array,         # [n] int32: the slot's pages in order
+    start: jax.Array,             # scalar int32: position of query 0
+    *,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Causal attention of a chunk of ``C`` queries (positions ``start ..``)
+    over ONE slot's K/V pages, the chunk's own rows among them (the caller
+    writes them first); returns [H, C, D]. The contract of
+    ``paged_latent_chunk_attention``: cost follows the context, a query
+    attends to positions <= its own. Planes this takes:
+    ``chunk_attention_supported``."""
+    h, d = q.shape[0], q.shape[2]
+    kv = pool_k.shape[2]
+    if not chunk_attention_supported(kv, d, pool_k.dtype) or h % kv:
+        raise ValueError(
+            f"paged_chunk_attention over {pool_k.dtype} planes of {kv} heads "
+            f"x {pool_k.shape[3]}, {h} query heads x {d}")
+    return _chunk_attention_call(
+        q, pool_k, pool_v, table_row, jnp.asarray(start, jnp.int32),
+        interpret=interpret if interpret is not None else auto_interpret())
+
+
+# Traced ONCE for each set of shapes and inlined wherever it is called: every
+# row of every chunk program of an engine attends through the same call.
+@functools.partial(jax.jit, static_argnames=("interpret",), inline=True)
+def _chunk_attention_call(q, pool_k, pool_v, table_row, start, *,
+                          interpret: bool):
+    h, c, d = q.shape
+    page, kv = pool_k.shape[1:3]
+    per = 4 // pool_k.dtype.itemsize
+    n = CHUNK_PAGES_PER_STEP
+    tile = CHUNK_QUERY_TILE
+    while h * tile > CHUNK_STATE_ROWS and tile > 16:
+        tile //= 2
+    if c % tile:
+        tile = c
+    block = n * page
+    num_blocks = -(-table_row.shape[0] // n)
+    table = jnp.pad(table_row, (0, num_blocks * n - table_row.shape[0]),
+                    constant_values=-1)
+    kernel = functools.partial(
+        _chunk_kernel, page_size=page, sm_scale=d ** -0.5)
+
+    def q_map(ti, ji, table_ref, start_ref):
+        return (0, ti, 0)
+
+    def page_map(i):
+        def index(ti, ji, table_ref, start_ref):
+            # A block behind the tile's last query is not attended: it stays
+            # on the last block that is, which is not fetched again.
+            ji = jnp.minimum(ji, (start_ref[0] + (ti + 1) * tile - 1) // block)
+            return (jnp.maximum(table_ref[ji * n + i], 0), 0, 0, 0)
+        return index
+
+    return pl.pallas_call(
+        kernel,
+        name="paged_chunk_attention",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(c // tile, num_blocks),
+            in_specs=[pl.BlockSpec((h, tile, d), q_map)]
+            + [pl.BlockSpec((1, page, kv, d), page_map(i))
+               for _ in range(2) for i in range(n)],
+            out_specs=pl.BlockSpec((h, tile, d), q_map),
+            scratch_shapes=[
+                pltpu.VMEM((per, block, d), pool_k.dtype),  # a word's keys
+                pltpu.VMEM((per, block, d), pool_v.dtype),  # and its values
+                pltpu.VMEM((h * tile, 1), jnp.float32),     # running max m
+                pltpu.VMEM((h * tile, 1), jnp.float32),     # running denom l
+                pltpu.VMEM((h * tile, d), jnp.float32),     # accumulator
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((h, c, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=CHUNK_VMEM_BYTES),
+        interpret=interpret,
+    )(table, jnp.reshape(start, (1,)), q, *([pool_k] * n), *([pool_v] * n))

@@ -75,8 +75,9 @@ from kubeflow_tpu.core.serving import (
 from kubeflow_tpu.serve.device_state import DEAD_SLOT, DecodeState
 from kubeflow_tpu.serve.pacing import RoundPacer, decode_ladder
 from kubeflow_tpu.serve.paged import (
-    PageAllocator, PagePoolExhausted, context_bucket, paged_chunk_prefill,
-    paged_decode_multi, pool_bytes_per_token, pool_shapes,
+    PageAllocator, PagePoolExhausted, chunk_reads_context, context_bucket,
+    paged_chunk_prefill, paged_decode_multi, pool_bytes_per_token,
+    pool_shapes,
 )
 from kubeflow_tpu.models.config import DecoderConfig
 from kubeflow_tpu.models.decoder import (
@@ -317,6 +318,28 @@ def _pin2(out, pin):
 def _row0(out):
     """A one-row chunk program's ([1,C,V] logits, cache) as ([C,V], cache)."""
     return (out[0][0],) + tuple(out[1:])
+
+
+class _OneContext:
+    """The one-row chunk program of a pool whose chunk does not read its
+    context bucket (``paged.chunk_reads_context``): whatever bucket a call
+    names, it runs and lowers the program of ``context``, so a start traces,
+    loads and warms ONE program where it had one a bucket. A call with LoRA
+    takes the gathered form, which reads its bucket, and keeps it."""
+
+    def __init__(self, jitted, context: int):
+        self.jitted, self.context = jitted, context
+
+    def _at_one(self, args):   # (p, c, t, tr, st, vl, ncp[, lora[, aidx]])
+        if len(args) > 7 and args[7] is not None:
+            return args
+        return args[:6] + (self.context,) + args[7:]
+
+    def __call__(self, *args):
+        return self.jitted(*self._at_one(args))
+
+    def lower(self, *args):
+        return self.jitted.lower(*self._at_one(args))
 
 
 #: Rows a bf16 weight matrix must multiply before the matrix work takes as
@@ -819,6 +842,8 @@ class LLMEngine:
                 _chunk_rows_fn(p, c, t, tr[None], st[None], vl[None],
                                ncp, lr, ai)),
             static_argnums=(6,), donate_argnums=(1,))
+        if not chunk_reads_context(self.cache, cfg_prefill, None, pattn):
+            self._paged_chunk = _OneContext(self._paged_chunk, self._mpp)
         # The chunks of ALL in-flight prefills in one program (tokens
         # [B,C], a table row, a start and a valid length a row; [B,C,V]
         # logits), so a scheduler pass reads every weight once. Built
@@ -826,12 +851,13 @@ class LLMEngine:
         # (``chunk_rows_per_weight``): a dense model at 512 tokens
         # dispatches exactly as it always did. It is dispatched at ONE
         # static context, the whole table: a row's attention follows
-        # its own context whatever the table's length (the span ladder
-        # of layers._cached_attention_by_row; the latent kernel skips
-        # the pages behind its chunk), so a ladder of context buckets
-        # would spare only the gather of a per-head pool's rows, and
-        # each further program is loaded and run at every start
-        # (0.75 s warm, 5 s cold on a v5e: PERF.md, PR 29).
+        # its own context whatever the table's length (the chunk
+        # kernels skip the pages behind their chunk; the gathered
+        # form's span ladder, layers._cached_attention_by_row), so a
+        # ladder of context buckets would spare only the gather of a
+        # pool that still takes the gathered form, and each further
+        # program is loaded and run at every start (0.75 s warm, 5 s
+        # cold on a v5e: PERF.md, PR 29).
         if self.max_concurrent_prefills > 1 and chunk_rows_per_weight(
                 cfg_prefill, self.chunk_size) < RIDGE_ROWS:
             self._chunk_rows = self.max_concurrent_prefills

@@ -29,7 +29,9 @@ the pool: it carries every plane whole, viewed flat ``[L*P, page, KV, Dh]``,
 through its step loop and its layer scan, writes layer ``l``'s rows in place
 at flat page ``l*P + page`` and reads through the table offset by ``l*P``
 (``_paged_decode_step``), so a step moves the rows it writes and the pages
-it attends to, not the pool. The speculative verify dispatch
+it attends to, not the pool; a chunk prefill is built the same way
+wherever a kernel can attend a chunk of queries over the pool
+(``_paged_chunk_in_place``). The speculative verify dispatch
 (serve/spec_decode.py ``paged_verify_step``) extends the same contract
 with a verify-length axis — k+1 (page, offset) writes per slot per round —
 and rejection rolls the page table back to the accepted length
@@ -575,13 +577,10 @@ def _conv_decode(c, h, lengths, pools, pidx, base, table, pg: int,  # traced
                                                       mode="drop")}
 
 
-def _kv_decode_attention(a, h, positions, lengths, pools, pidx, off,  # traced
-                         ltable, cfg: DecoderConfig, attn_impl: str, lora):
-    """Per-head K/V: project, write this token's rows at (pidx, off), attend
-    to the slot's pages. Returns (the block's attention output [B,1,D], the
-    planes as written)."""
+def _qkv_rope(a, h, positions, cfg: DecoderConfig, lora=None):  # traced
+    """Per-head projections of ``h`` [B,S,D] at ``positions`` [B,S]: (q
+    [B,S,H,Dh], k and v [B,S,KV,Dh]), q and k normed and rotated."""
     dt = cfg.activation_dtype
-    kv_quant = "ks" in pools
     q = jnp.einsum("bsd,dhk->bshk", h, a["wq"].astype(dt))
     k = jnp.einsum("bsd,dhk->bshk", h, a["wk"].astype(dt))
     v = jnp.einsum("bsd,dhk->bshk", h, a["wv"].astype(dt))
@@ -592,6 +591,17 @@ def _kv_decode_attention(a, h, positions, lengths, pools, pidx, off,  # traced
         k = L.apply_lora_layer(lora, "wk", h, k)
         v = L.apply_lora_layer(lora, "wv", h, v)
     q, k = L.qk_rope(a, q, k, positions, cfg)
+    return q, k, v
+
+
+def _kv_decode_attention(a, h, positions, lengths, pools, pidx, off,  # traced
+                         ltable, cfg: DecoderConfig, attn_impl: str, lora):
+    """Per-head K/V: project, write this token's rows at (pidx, off), attend
+    to the slot's pages. Returns (the block's attention output [B,1,D], the
+    planes as written)."""
+    dt = cfg.activation_dtype
+    kv_quant = "ks" in pools
+    q, k, v = _qkv_rope(a, h, positions, cfg, lora)
     rows = {"k": k[:, 0], "v": v[:, 0]}
     packed = pools["k"].ndim == 3       # [L*P, pg, KV*Dh]: heads in one row
     if packed:
@@ -814,43 +824,47 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
     this path; only a row's first ``valid_len`` positions write (the padded
     tail and any unmapped page aim out of bounds and DROP).
 
-    A chunk attends to its slot's earlier KV by gathering the page table
-    into the contiguous layout decoder_forward's cache path expects (one
-    start a row; each row then attends over the shortest span of the bucket
-    ladder that holds its own context, ``layers._cached_attention_by_row``),
-    then scatters only the chunk's tokens back; every plane of a per-head
-    pool (``pool_planes``) goes the same way. The conv layers of a
-    patterned stack take the state their row's chunk starts from and leave
-    the tails its pages end in (``state_planes``). A latent pool takes
-    the chunk as the decode step takes a token
-    (``_paged_latent_chunk_prefill``: rows written in place, attention
-    absorbed over the pages where they lie, ``paged_attn_impl`` the engine's
-    "gather" | "pallas"). ``context_pages`` (STATIC, one for all rows: the
-    largest row's) bounds the gather to the pages actually covering
+    Two forms, chosen from the cache and the call (``_chunk_in_place``).
+    IN PLACE (``_paged_chunk_in_place``; a latent pool always, a per-head
+    pool where ``paged_attn_impl``, the engine's "gather" | "pallas", is
+    "pallas" and the kernel takes its planes): the pool rides flat through
+    the layer scans, a layer writes its rows where they belong and each
+    prompt attends over its own pages where they lie. GATHERED (every other
+    per-head pool: int8, packed rows beside conv layers, a call with LoRA,
+    "gather"): the page table is gathered into the contiguous layout
+    decoder_forward's cache path expects (one start a row; each row then
+    attends over the shortest span of the bucket ladder that holds its own
+    context, ``layers._cached_attention_by_row``) and only the chunk's
+    tokens are scattered back, every plane of the pool (``pool_planes``)
+    the same way; the conv layers of a patterned stack take the state their
+    row's chunk starts from and leave the tails its pages end in
+    (``state_planes``). ``context_pages`` (STATIC, one for all rows: the
+    largest row's) bounds the pages looked at to those covering
     [0, start+C): chunk cost then tracks the resident context, not max_len —
     without it a long prompt pays O(max_len²/C) in gathers (round-2 weak
     #4). The caller buckets the count (powers of two) so the trace set stays
-    logarithmic. Returns ([B,C,V] logits, cache)."""
+    logarithmic. The in-place form of a per-head pool does not read it: its
+    kernel's cost follows each row's own context whatever the table's
+    length. Returns ([B,C,V] logits, cache)."""
     from kubeflow_tpu.models.decoder import decoder_forward
 
-    if cfg.is_latent:
-        if lora is not None:
-            raise NotImplementedError(
-                "LoRA over latent attention projections")
-        return _paged_latent_chunk_prefill(
-            params, cache, tokens, table_rows, start, valid_len, cfg,
-            paged_attn_impl, context_pages)
+    if cfg.is_latent and lora is not None:
+        raise NotImplementedError("LoRA over latent attention projections")
+    if context_pages is not None and chunk_reads_context(
+            cache, cfg, lora, paged_attn_impl):
+        table_rows = table_rows[:, :min(context_pages, table_rows.shape[1])]
+    if _chunk_in_place(cache, cfg, lora, paged_attn_impl):
+        return _paged_chunk_in_place(params, cache, tokens, table_rows,
+                                     start, valid_len, cfg, paged_attn_impl)
     planes = tuple(n for n in _planes_of(cache)
                    if plane_kind(n) == "attention")
     num_pages, pg = _pool_geometry(cache)
     b, c = tokens.shape
     kv_quant = "ks" in cache
     packed = cache["k"].ndim == 4       # [L, P, pg, KV*Dh]: heads in one row
-    if context_pages is not None:
-        # Static slice: the bucket must cover the chunk's own pages too
-        # (the [start, start+C) update-slice window below).
-        table_rows = table_rows[:, :min(context_pages, table_rows.shape[1])]
-    # Gather each slot's visible cache row, every plane: [L,B,ctx*pg,...].
+    # Gather each slot's visible cache row, every plane: [L,B,ctx*pg,...]
+    # (the bucket covers the chunk's own pages too: the [start, start+C)
+    # update-slice window below).
     # Pad the rows by one chunk of scratch positions so the final chunk's
     # C-wide dynamic_update_slice window can never clamp and overwrite
     # earlier KV (prefix-cache hits start chunks at page — not chunk —
@@ -978,67 +992,124 @@ def _chunk_write_index(table_rows: jax.Array, start: jax.Array,  # traced
     return jnp.where(ok, page_id, num_pages), pos % pg
 
 
-def _paged_latent_chunk_prefill(params: Params, cache: dict,  # traced
-                                tokens: jax.Array, table_rows: jax.Array,
-                                start: jax.Array, valid_len: jax.Array,
-                                cfg: DecoderConfig, attn_impl: str,
-                                context_pages: Optional[int]):
-    """``paged_chunk_prefill`` over a latent pool, built like the decode
-    step and not like ``decoder_forward``'s cache path: the pool is carried
-    whole and flat ``[L*P, pg, W]`` through the layer scans, a layer writes
-    every row's ``C`` cache rows in place at ``(layer*P + page, offset)``
-    and then each prompt attends, ABSORBED and causally, over its own pages
-    where they lie (the chunk's own among them): "pallas" through
-    ``paged_latent_chunk_attention``, one call a prompt, which skips the
-    pages behind the chunk, "gather" through the same sums in XLA over the
-    gathered rows. Nothing gathers all layers' context up front, pads it, or
-    puts a layer's slab back. Same contract: only a row's first
-    ``valid_len`` positions write; ``context_pages`` bounds the pages looked
-    at."""
-    dt = cfg.activation_dtype
-    pool = cache["ckv"]
-    num_pages, pg = pool.shape[1:3]
-    b, c = tokens.shape
-    if context_pages is not None:
-        table_rows = table_rows[:, :min(context_pages, table_rows.shape[1])]
+def _chunk_in_place(cache: dict, cfg: DecoderConfig, lora,
+                    attn_impl: str) -> bool:
+    """Whether a chunk program over this cache is built in place
+    (``_paged_chunk_in_place``): a latent pool always; a per-head pool
+    where the engine runs the kernels ("pallas") and
+    ``paged_chunk_attention`` takes its planes. What stays on the gathered
+    form: int8 pools (scale planes), packed rows and the conv state beside
+    them, a call with LoRA, planes the kernel cannot part by head."""
+    if cfg.is_latent:
+        return True
+    from kubeflow_tpu.ops.paged_attention import chunk_attention_supported
+
+    k = cache["k"]
+    return (attn_impl == "pallas" and lora is None
+            and set(_planes_of(cache)) == {"k", "v"} and k.ndim == 5
+            and chunk_attention_supported(*k.shape[3:], k.dtype))
+
+
+def chunk_reads_context(cache: dict, cfg: DecoderConfig, lora,
+                        attn_impl: str) -> bool:
+    """Whether ``paged_chunk_prefill`` over this cache reads its
+    ``context_pages``. The in-place form of a per-head pool does not: its
+    kernel takes the table whole and skips what lies behind its chunk, so
+    every context bucket is ONE program, and every row of every program
+    one call that is traced once (``paged_chunk_attention``)."""
+    return cfg.is_latent or not _chunk_in_place(cache, cfg, lora, attn_impl)
+
+
+def _kv_chunk_attention(a, h, pos, start, pools, pidx, off, ltable,  # traced
+                        cfg: DecoderConfig, attn_impl: str):
+    """Per-head K/V, a chunk a row: project, write every row's ``C`` K and V
+    rows at (pidx, off), then each prompt attends causally over its own
+    pages through ``paged_chunk_attention``, one call a prompt. Returns (the
+    block's attention output [B,C,D], the planes as written)."""
+    from kubeflow_tpu.ops.paged_attention import paged_chunk_attention
+
+    q, k, v = _qkv_rope(a, h, pos, cfg)
+    pools = {"k": pools["k"].at[pidx, off].set(k, mode="drop"),
+             "v": pools["v"].at[pidx, off].set(v, mode="drop")}
+    attn = jnp.stack([
+        paged_chunk_attention(jnp.swapaxes(q[r], 0, 1), pools["k"],
+                              pools["v"], ltable[r], start[r])
+        for r in range(h.shape[0])])                           # [B,H,C,Dh]
+    return jnp.einsum("bhsk,hkd->bsd", attn,
+                      a["wo"].astype(cfg.activation_dtype)), pools
+
+
+def _latent_chunk_attention(a, h, pos, start, pools, pidx, off,  # traced
+                            ltable, cfg: DecoderConfig, attn_impl: str):
+    """Latent attention, a chunk a row, absorbed: write every row's ``C``
+    cache rows, then each prompt attends causally over its own pages
+    ("pallas": ``paged_latent_chunk_attention``, one call a prompt;
+    "gather": the same sums in XLA over the gathered rows). Same return as
+    the per-head form."""
+    q_nope, q_rope, row = L.latent_qkv(a, h, pos, cfg)
+    flat = pools["ckv"].at[pidx, off].set(row, mode="drop")
+    if attn_impl == "pallas":
+        from kubeflow_tpu.ops.paged_attention import (
+            paged_latent_chunk_attention,
+        )
+
+        q = L.latent_query(a, q_nope, q_rope, cfg)             # [B,C,H,W]
+        o_row = jnp.stack([
+            paged_latent_chunk_attention(
+                jnp.swapaxes(q[r], 0, 1), flat, ltable[r], start[r],
+                sm_scale=L.latent_scale(cfg)) for r in range(h.shape[0])])
+        attn = L.latent_output(a, jnp.swapaxes(o_row, 1, 2), cfg)
+    else:
+        rows = paged_gather(flat, ltable)                      # [B, T, W]
+        causal = jnp.arange(rows.shape[1], dtype=jnp.int32)[
+            None, None, :] <= pos[:, :, None]                  # [B, C, T]
+        attn = L.latent_absorbed_attention(
+            a, q_nope, q_rope, rows, causal[:, None], cfg)
+    return jnp.einsum("bshk,hkd->bsd", attn,
+                      a["wo"].astype(cfg.activation_dtype)), {"ckv": flat}
+
+
+def _paged_chunk_in_place(params: Params, cache: dict,  # traced
+                          tokens: jax.Array, table_rows: jax.Array,
+                          start: jax.Array, valid_len: jax.Array,
+                          cfg: DecoderConfig, attn_impl: str):
+    """``paged_chunk_prefill`` built like the decode step and not like
+    ``decoder_forward``'s cache path: every plane of the pool is carried
+    whole and flat ``[L*P, pg, ...]`` through the layer scans, a layer
+    writes every row's ``C`` cache rows in place at ``(layer*P + page,
+    offset)`` and then each prompt attends, causally, over its own pages
+    where they lie (the chunk's own among them; the kernels skip the pages
+    behind the chunk). ONE builder whose layers differ in how they project
+    and attend (``_latent_chunk_attention``, ``_kv_chunk_attention``).
+    Nothing gathers all layers' context up front, pads it, or puts a
+    layer's slab back. Same contract: only a row's first ``valid_len``
+    positions write; ``table_rows`` holds the pages looked at."""
+    num_pages, pg = _pool_geometry(cache)
+    c = tokens.shape[1]
     page, off = _chunk_write_index(table_rows, start, valid_len, c, pg,
                                    num_pages)
     pos = start[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]   # [B,C]
+    flat = {n: cache[n].reshape(-1, *cache[n].shape[2:])
+            for n in _planes_of(cache)}
+    total = next(iter(flat.values())).shape[0]
+    attend = _latent_chunk_attention if cfg.is_latent \
+        else _kv_chunk_attention
 
     def block(bp, carry, layer, gcfg, _, expert_stack):
-        x, flat = carry
-        a = bp["attn"]
+        x, pools = carry
         base = layer * num_pages
         h = L.rmsnorm(x, bp["ln1"], gcfg)
-        q_nope, q_rope, row = L.latent_qkv(a, h, pos, gcfg)
         # One past this layer's pages is the next layer's page 0: a dropped
         # write aims past the END of the flat pool.
-        pidx = jnp.where(page < num_pages, base + page, flat.shape[0])
-        flat = flat.at[pidx, off].set(row, mode="drop")
+        pidx = jnp.where(page < num_pages, base + page, total)
         ltable = jnp.where(table_rows >= 0, table_rows + base, -1)
-        if attn_impl == "pallas":
-            from kubeflow_tpu.ops.paged_attention import (
-                paged_latent_chunk_attention,
-            )
-
-            q = L.latent_query(a, q_nope, q_rope, gcfg)        # [B,C,H,W]
-            o_row = jnp.stack([
-                paged_latent_chunk_attention(
-                    jnp.swapaxes(q[r], 0, 1), flat, ltable[r], start[r],
-                    sm_scale=L.latent_scale(gcfg)) for r in range(b)])
-            attn = L.latent_output(a, jnp.swapaxes(o_row, 1, 2), gcfg)
-        else:
-            rows = paged_gather(flat, ltable)                  # [B, T, W]
-            causal = jnp.arange(rows.shape[1], dtype=jnp.int32)[
-                None, None, :] <= pos[:, :, None]              # [B, C, T]
-            attn = L.latent_absorbed_attention(
-                a, q_nope, q_rope, rows, causal[:, None], gcfg)
-        proj = jnp.einsum("bshk,hkd->bsd", attn, a["wo"].astype(dt))
+        proj, pools = attend(bp["attn"], h, pos, start, pools, pidx, off,
+                             ltable, gcfg, attn_impl)
         x, h = L.add_rmsnorm(x, proj, bp["ln2"], gcfg)
         return x + _feed_forward(bp, h, gcfg, expert_stack, valid_len,
-                                 capacity_per_row=True), flat
+                                 capacity_per_row=True), pools
 
     x, flat = _scan_layer_groups(
-        params, cfg, (_embed(params, tokens, cfg),
-                      pool.reshape(-1, *pool.shape[2:])), block)
-    return _head_logits(params, x, cfg), {"ckv": flat.reshape(pool.shape)}
+        params, cfg, (_embed(params, tokens, cfg), flat), block)
+    return _head_logits(params, x, cfg), {
+        n: p.reshape(cache[n].shape) for n, p in flat.items()}

@@ -275,3 +275,62 @@ def test_packed_row_decode_step_compiles_for_v5e_without_pool_copies(chip):
     whole = re.findall(
         rf"= bf16\[{layers * pages},{PAGE},{kv * d}\]\S* copy\(", text)
     assert not whole, whole
+
+
+# -- the chunk program of a per-head pool, in place ------------------------------
+
+CHUNK_PROGRAMS = {
+    # cell: (preset, overrides, pool pages, pages a slot, rows a program)
+    "mixtral-8x7b.batch-longprompt": (
+        "mixtral-8x7b", {"n_layers": 3, "max_seq_len": 8320}, 1040, 65, 2),
+    "mistral-7b.chat-open": (
+        "llama3-8b", {"vocab_size": 32768, "rope_theta": 1e6, "n_layers": 16,
+                      "max_seq_len": 4096}, 448, 32, 1),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CHUNK_PROGRAMS))
+def test_chunk_program_compiles_for_v5e_in_place(chip, cell, monkeypatch):
+    """The chunk programs of the two per-head cells at their sizes, as the
+    engine builds them on one chip ("pallas"): they compile, every row of
+    every scanned layer group attends through ``paged_chunk_attention`` (the
+    engine's ``program_kernels`` reads the same lowered text), and the
+    program holds no temporary the size of a layer's pool or of a row's
+    gathered context: the pool is written and read where it lies. Code that
+    asks for the backend is told "tpu" here, as benchmark/aot_sizes.py
+    tells it: a kernel interprets anywhere else."""
+    from kubeflow_tpu.models.config import preset
+    from kubeflow_tpu.models.decoder import init_decoder_params
+    from kubeflow_tpu.runtime.device_report import lowered_kernel_calls
+    from kubeflow_tpu.serve.paged import paged_chunk_prefill, pool_shapes
+
+    name, over, pages, mpp, rows = CHUNK_PROGRAMS[cell]
+    cfg = preset(name, dtype="bfloat16", param_dtype="bfloat16", **over)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    params = jax.tree.map(
+        lambda s: sds(s.shape, s.dtype),
+        jax.eval_shape(lambda: init_decoder_params(jax.random.PRNGKey(0),
+                                                   cfg)))
+    cache = {n: sds(shape, dt)
+             for n, (shape, dt) in pool_shapes(cfg, pages, PAGE).items()}
+    program = jax.jit(
+        lambda p, c, t, tr, st, vl: paged_chunk_prefill(
+            p, c, t, tr, st, vl, cfg, context_pages=mpp,
+            paged_attn_impl="pallas"), donate_argnums=(1,))
+    args = (params, cache, sds((rows, 512)), sds((rows, mpp)), sds((rows,)),
+            sds((rows,)))
+    kernels = lowered_kernel_calls(program, *args)
+    assert kernels["paged_chunk_attention"] == rows, kernels
+    compiled = program.lower(*args).compile()
+    layer_pool = pages * PAGE * cfg.n_kv_heads * cfg.head_dim * 2
+    context = mpp * PAGE * cfg.n_kv_heads * cfg.head_dim * 2
+    logits = rows * 512 * cfg.vocab_size * 4
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    # What the gathered form held: K and V of every layer's context, padded
+    # by a chunk, twice over (the copy and the copy as written).
+    assert temps < min(layer_pool, 2 * cfg.n_layers * context), temps
+    assert temps < logits + 256 * 2 ** 20, (temps, logits)

@@ -3,7 +3,10 @@ CPU: the program over rows (a, b) against the one-row program on a and on b
 in turn (pool rows and logits), a row's independence of its neighbour, the
 per-row capacity of the dispatch expert layer, and the engine's side: when
 the program is built, that it is warm from construction on, what a pass
-dispatches and counts, and that a stalled prefill holds nobody back."""
+dispatches and counts, and that a stalled prefill holds nobody back. And the
+chunk program of a per-head pool as the chip runs it (ISSUE 36): rows written
+in place and attended through ``paged_chunk_attention`` (interpreted here),
+against the gathered form, and the kernel alone against plain attention."""
 
 import contextlib
 import dataclasses
@@ -23,8 +26,13 @@ from kubeflow_tpu.serve import engine as engine_mod
 from kubeflow_tpu.serve.engine import (
     RIDGE_ROWS, LLMEngine, SamplingParams, chunk_rows_per_weight,
 )
+from kubeflow_tpu.ops.attention import multi_head_attention
+from kubeflow_tpu.ops.paged_attention import (
+    CHUNK_PAGES_PER_STEP, CHUNK_QUERY_TILE, chunk_attention_supported,
+    paged_chunk_attention,
+)
 from kubeflow_tpu.serve.paged import (
-    context_bucket, paged_chunk_prefill, pool_shapes,
+    _chunk_in_place, context_bucket, paged_chunk_prefill, pool_shapes,
 )
 
 PAGE, CHUNK, MPP, POOL = 16, 32, 8, 14
@@ -75,10 +83,11 @@ def _empty_pool(cfg):
             for name, (shape, dt) in pool_shapes(cfg, POOL, PAGE).items()}
 
 
-def _rows_program(cfg):
+def _rows_program(cfg, impl="gather"):
     return jax.jit(
         lambda p, c, t, tr, st, vl, ncp: paged_chunk_prefill(
-            p, c, t, tr, st, vl, cfg, context_pages=ncp),
+            p, c, t, tr, st, vl, cfg, context_pages=ncp,
+            paged_attn_impl=impl),
         static_argnums=(6,))
 
 
@@ -189,6 +198,240 @@ class TestRowsProgram:
         tight, _ = _one(program, params, cache, *ra)
         loose, _ = _one(ample, params, cache, *ra)
         assert float(jnp.max(jnp.abs(tight - loose))) > 1e-3
+
+
+# -- the chunk of a per-head pool, in place ---------------------------------------
+
+# (heads, KV heads, chunk, start, tokens the slot holds, table length, type)
+KERNEL_CASES = {
+    "start-0-inside-one-page": (8, 2, 16, 0, 16, 4, jnp.float32),
+    "page-aligned-one-whole-step": (8, 2, 32, 32, 64, 4, jnp.float32),
+    "mid-page-across-two-steps": (8, 2, 32, 40, 72, 12, jnp.float32),
+    "long-context-skipped-pages-unmapped": (8, 2, 32, 200, 232, 20,
+                                            jnp.float32),
+    "group-of-one": (2, 2, 32, 40, 72, 7, jnp.float32),
+    "bfloat16-two-heads-a-word": (8, 4, 32, 40, 72, 12, jnp.bfloat16),
+    "short-valid-length": (8, 2, 32, 24, 24 + 19, 8, jnp.float32),
+    "two-query-tiles": (8, 2, 2 * CHUNK_QUERY_TILE, 40,
+                        40 + 2 * CHUNK_QUERY_TILE, 24, jnp.float32),
+    "dead-row": (8, 2, 32, 0, 0, 8, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_chunk_kernel_matches_plain_attention(case):
+    """``paged_chunk_attention`` (interpreted) against
+    ``multi_head_attention(impl="xla")`` over the slot's gathered rows in
+    float32: the rows the slot holds are mapped in a shuffled order, the
+    table's tail is unmapped, and only queries at positions the slot holds
+    are compared (a padded query's output is discarded). A dead row (an
+    unmapped table) attends to nothing and emits zeros."""
+    h, kv, c, start, held, mpp, dt = KERNEL_CASES[case]
+    assert chunk_attention_supported(kv, 128, dt)
+    keys = jax.random.split(jax.random.PRNGKey(len(case)), 3)
+    pool_k = jax.random.normal(keys[0], (40, PAGE, kv, 128), dt)
+    pool_v = jax.random.normal(keys[1], (40, PAGE, kv, 128), dt)
+    q = jax.random.normal(keys[2], (h, c, 128), dt)
+    pages = -(-held // PAGE)
+    assert held <= start + c and pages <= mpp
+    if case != "dead-row":
+        assert mpp > CHUNK_PAGES_PER_STEP or pages <= CHUNK_PAGES_PER_STEP
+    table = np.full((mpp,), -1, np.int32)
+    table[:pages] = np.random.default_rng(len(case)).permutation(40)[:pages]
+    out = paged_chunk_attention(q, pool_k, pool_v, jnp.asarray(table),
+                                jnp.int32(start), interpret=True)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    valid = held - start
+    if valid <= 0:
+        np.testing.assert_array_equal(np.asarray(out, np.float32), 0.0)
+        return
+    rows = [pool[jnp.clip(jnp.asarray(table), 0)].reshape(
+        1, -1, kv, 128).astype(jnp.float32) for pool in (pool_k, pool_v)]
+    want = multi_head_attention(
+        jnp.swapaxes(q, 0, 1)[None].astype(jnp.float32), *rows, causal=True,
+        q_offset=start, impl="xla")[0]
+    tol = 2e-5 if dt == jnp.float32 else 2e-2
+    np.testing.assert_allclose(
+        np.asarray(jnp.swapaxes(out, 0, 1), np.float32)[:valid],
+        np.asarray(want)[:valid], rtol=tol, atol=tol)
+
+
+def test_planes_the_chunk_kernel_does_not_take():
+    # int8 pools, heads that are not one 128-value row, a lone bf16 head
+    assert not chunk_attention_supported(8, 128, jnp.int8)
+    assert not chunk_attention_supported(8, 64, jnp.bfloat16)
+    assert not chunk_attention_supported(1, 128, jnp.bfloat16)
+    assert chunk_attention_supported(1, 128, jnp.float32)
+    with pytest.raises(ValueError, match="paged_chunk_attention"):
+        paged_chunk_attention(
+            jnp.zeros((4, 16, 64)), jnp.zeros((4, PAGE, 2, 64)),
+            jnp.zeros((4, PAGE, 2, 64)), jnp.zeros((4,), jnp.int32),
+            jnp.int32(0), interpret=True)
+
+
+IN_PLACE_KINDS = ("dense", "dispatch", "dense-bfloat16")
+
+
+@functools.lru_cache(maxsize=None)
+def _wide_model(kind: str):
+    """The tiny presets with heads of 128, which the kernel takes."""
+    dt = "bfloat16" if kind.endswith("bfloat16") else "float32"
+    over = dict(head_dim=128, dtype=dt, param_dtype=dt, max_seq_len=1024)
+    cfg = (preset("tiny-moe", capacity_factor=1.25, **over)
+           if kind == "dispatch" else preset("tiny", **over))
+    return cfg, init_decoder_params(jax.random.PRNGKey(3), cfg)
+
+
+def _assert_same(kind, got, want, what):
+    tol = 3e-2 if kind.endswith("bfloat16") else 2e-5
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), rtol=tol,
+                               atol=tol, err_msg=what)
+
+
+class TestChunkInPlace:
+    """The "pallas" arm of the chunk program over a per-head pool (rows
+    written in place, ``paged_chunk_attention`` over the pages where they
+    lie) against the "gather" arm: the logits of every valid position and
+    every plane of the pool, chunk after chunk, each arm on its own pool."""
+
+    @pytest.mark.parametrize("kind", IN_PLACE_KINDS)
+    def test_which_form_a_program_takes(self, kind):
+        cfg, params = _wide_model(kind)
+        cache = _empty_pool(cfg)
+        assert _chunk_in_place(cache, cfg, None, "pallas")
+        assert not _chunk_in_place(cache, cfg, None, "gather")
+        assert not _chunk_in_place(cache, cfg, {"targets": {}}, "pallas")
+        args = (params, cache, jnp.zeros((1, CHUNK), jnp.int32),
+                jnp.zeros((1, MPP), jnp.int32), jnp.zeros((1,), jnp.int32),
+                jnp.zeros((1,), jnp.int32))
+        for impl, sites in (("pallas", 1), ("gather", 0)):
+            text = str(jax.make_jaxpr(
+                lambda *a, impl=impl: paged_chunk_prefill(
+                    *a, cfg, context_pages=4, paged_attn_impl=impl))(*args))
+            # one call site a scanned layer group a row
+            assert text.count("name=paged_chunk_attention") == sites, impl
+
+    def test_every_row_of_every_bucket_traces_the_kernel_once(
+            self, monkeypatch):
+        """A kernel's body is traced anew wherever ``pallas_call`` is
+        reached, and a trace of this one costs a chip's host seconds; the
+        table goes to it whole, so a row of any program is the same call,
+        traced once (``setup_s``: PERF.md, PR 36)."""
+        from kubeflow_tpu.ops import paged_attention as pa
+
+        cfg, params = _wide_model("dense")
+        traced = []
+        real = pa._chunk_kernel
+        monkeypatch.setattr(
+            pa, "_chunk_kernel",
+            lambda *a, **k: (traced.append(1), real(*a, **k))[1])
+        pa._chunk_attention_call.clear_cache()
+        for rows, ctx in ((1, 2), (1, 4), (2, MPP)):
+            args = (params, _empty_pool(cfg),
+                    jnp.zeros((rows, CHUNK), jnp.int32),
+                    jnp.zeros((rows, MPP), jnp.int32),
+                    jnp.zeros((rows,), jnp.int32),
+                    jnp.zeros((rows,), jnp.int32))
+            text = str(jax.make_jaxpr(
+                lambda *a, ctx=ctx: paged_chunk_prefill(
+                    *a, cfg, context_pages=ctx,
+                    paged_attn_impl="pallas"))(*args))
+            assert text.count("name=paged_chunk_attention") == rows
+        pa._chunk_attention_call.clear_cache()
+        assert len(traced) == 1
+
+    def test_other_pools_stay_on_the_gathered_form(self):
+        for kind in ("dense", "dispatch", "patterned"):    # heads of 16
+            _, cfg, _ = _model(kind)
+            assert not _chunk_in_place(_empty_pool(cfg), cfg, None, "pallas")
+        cfg, _ = _wide_model("dense")
+        int8 = {name: jnp.zeros(shape, dt) for name, (shape, dt) in
+                pool_shapes(cfg, POOL, PAGE, kv_quant=True).items()}
+        assert not _chunk_in_place(int8, cfg, None, "pallas")
+        _, glm, _ = _model("latent")
+        assert _chunk_in_place(_empty_pool(glm), glm, None, "gather")
+
+    @pytest.mark.parametrize("kind", IN_PLACE_KINDS)
+    def test_one_row_two_chunks_in_sequence(self, kind):
+        cfg, params = _wide_model(kind)
+        a = _tokens(1, CHUNK + 19)
+        row = np.asarray([3, 0, 2, 1, -1, -1, -1, -1], np.int32)
+        pools = {impl: _empty_pool(cfg) for impl in ("gather", "pallas")}
+        for start, valid in ((0, CHUNK), (CHUNK, 19)):
+            logits = {}
+            for impl in pools:
+                logits[impl], pools[impl] = _one(
+                    _rows_program(cfg, impl), params, pools[impl], a, row,
+                    start, valid)
+            _assert_same(kind, logits["pallas"][:valid],
+                         logits["gather"][:valid], f"logits at {start}")
+            for name in pools["gather"]:
+                _assert_same(kind, pools["pallas"][name],
+                             pools["gather"][name], f"{name} at {start}")
+        assert np.any(np.asarray(pools["pallas"]["k"], np.float32)[:, 1])
+
+    @pytest.mark.parametrize("kind", IN_PLACE_KINDS)
+    def test_two_rows_at_unlike_starts_then_one_dead(self, kind):
+        """Row a resumes MID-PAGE (24 tokens held), row b at 64 with a short
+        chunk; the next pass carries a's last 9 tokens beside a dead row."""
+        cfg, params = _wide_model(kind)
+        a, b = _tokens(1, 24 + CHUNK + 9), _tokens(2, 64 + 19)
+        row_a = np.asarray([0, 1, 2, 3, 10, -1, -1, -1], np.int32)
+        row_b = np.asarray([4, 5, 6, 7, 8, 9, -1, -1], np.int32)
+        pools = {}
+        for impl in ("gather", "pallas"):
+            one = _rows_program(cfg, impl)
+            _, pool = _one(one, params, _empty_pool(cfg), a, row_a, 0, 24)
+            _, pool = _one(one, params, pool, b, row_b, 0, CHUNK)
+            _, pools[impl] = _one(one, params, pool, b, row_b, CHUNK, CHUNK)
+        passes = (((a, row_a, 24, CHUNK), (b, row_b, 64, 19)),
+                  ((a, row_a, 24 + CHUNK, 9), None))
+        for n, rows in enumerate(passes):
+            logits = {}
+            for impl in pools:
+                logits[impl], pools[impl] = _two(
+                    _rows_program(cfg, impl), params, pools[impl], rows,
+                    ctx=MPP)
+            for r, row in enumerate(rows):
+                if row is not None:
+                    _assert_same(kind, logits["pallas"][r, :row[3]],
+                                 logits["gather"][r, :row[3]],
+                                 f"pass {n} row {r}")
+            for name in pools["gather"]:
+                _assert_same(kind, pools["pallas"][name],
+                             pools["gather"][name], f"{name} after pass {n}")
+
+    def test_the_engine_runs_every_bucket_as_one_program(self):
+        """The in-place form of a per-head pool does not read its context
+        bucket, so the engine's one-row program is ONE compiled program
+        whatever bucket a call names (the names ``program_kernels`` records
+        stay a bucket's); the gathered form keeps a program a bucket."""
+        cfg, params = _wide_model("dense")
+        row = jnp.asarray([3, 0, 2, 1, -1, -1, -1, -1], jnp.int32)
+        block = jnp.asarray(_tokens(6, CHUNK)[None])
+        programs = {}
+        for impl in ("pallas", "gather"):
+            eng = _engine(cfg, params, max_len=MPP * PAGE,
+                          paged_attn_impl=impl)
+            one = getattr(eng._paged_chunk, "jitted", eng._paged_chunk)
+            before = one._cache_size()
+            for start, bucket in ((0, 2), (CHUNK, 4)):
+                logits, eng.cache = eng._paged_chunk(
+                    eng.params, eng.cache, block, row, jnp.int32(start),
+                    jnp.int32(CHUNK), bucket)
+                assert logits.shape == (CHUNK, cfg.vocab_size)
+            programs[impl] = one._cache_size() - before
+        assert programs == {"pallas": 1, "gather": 2}
+
+    def test_the_engine_serves_the_same_tokens_on_either_arm(self):
+        cfg, params = _wide_model("dispatch")
+        prompts = [_tokens(4, 3 * CHUNK - 5), _tokens(5, 2 * CHUNK - 9)]
+        eng = _engine(cfg, params, paged_attn_impl="pallas")
+        assert eng._chunk_rows == 2
+        assert _chunk_in_place(eng.cache, cfg, None, eng.paged_attn_impl)
+        assert _greedy(eng, prompts) == _greedy(
+            _engine(cfg, params, paged_attn_impl="gather"), prompts)
 
 
 class TestCapacityPerRow:
