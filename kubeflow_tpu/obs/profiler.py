@@ -17,11 +17,26 @@ Who pays what:
 - on: a span is a ``jax.profiler.TraceAnnotation`` (one TraceMe, a
   microsecond or two); the profiler itself costs what it costs
   (PERF.md, Findings, PR 25).
+- always (``PhaseClock``, the engine scheduler's): a phase boundary is one
+  clock read and one add into the phase's running sum, capture or none, and
+  the ``hot_span`` of the same name between the same two boundaries: a
+  microsecond a phase, no object allocated while no capture is active
+  (PERF.md, Findings, PR 37).
 
 The spans of one thread nest, so the innermost span that covers an instant
 says what that thread was doing; keyword arguments become the event's
 stats and tie spans together (``round=`` on a decode dispatch and on the
-fetch that consumed it, ``step=`` in the trainer).
+fetch that consumed it, ``step=`` in the trainer; ``slots=`` and ``rows=``
+on ``engine.sync_state``: what the sync sent; ``tokens=`` and ``streams=``
+on ``engine.emit``: what the round handed on).
+
+A span exists only inside a capture. What a loop's time went to over ANY
+stretch is ``PhaseClock``'s: the scheduler's eleven phases as running sums
+of EXCLUSIVE seconds (a phase's own time, its children's taken out: the
+rule ``benchmark/hostspans.py::innermost_segments`` cuts a capture's spans
+by, so a window's sums and a tail's segments mean the same thing), and the
+loop's time under no phase beside them. ``LLMEngine.counters()`` carries
+them as ``sched_<phase>_sum_s`` and ``sched_other_sum_s``.
 
 On ``start`` one anchor annotation (``ANCHOR``) carries ``time.time_ns()``
 and ``time.monotonic_ns()`` of the instant it was written: a reader lays
@@ -55,6 +70,10 @@ ENGINE_DECODE_DISPATCH = "engine.decode_dispatch"
 ENGINE_FETCH = "engine.fetch"            # every blocking device_get
 ENGINE_EMIT = "engine.emit"
 ENGINE_IDLE = "engine.idle"
+ENGINE_PHASES = (
+    ENGINE_REAP, ENGINE_ADMIT, ENGINE_PREFILL_DISPATCH, ENGINE_SAMPLE_FIRST,
+    ENGINE_KVTIER_TICK, ENGINE_ENSURE_PAGES, ENGINE_SYNC_STATE,
+    ENGINE_DECODE_DISPATCH, ENGINE_FETCH, ENGINE_EMIT, ENGINE_IDLE)
 TRAIN_STEP = "train"
 TRAIN_STAGE_WAIT = "train.stage_wait"
 TRAIN_DISPATCH = "train.dispatch"
@@ -73,6 +92,9 @@ class _NoSpan:
 
     def __exit__(self, *exc: Any) -> bool:
         return False
+
+    def set_metadata(self, **attrs: Any) -> None:
+        """What a ``TraceAnnotation`` takes once its phase knows it."""
 
 
 NO_SPAN = _NoSpan()
@@ -143,3 +165,101 @@ def hot_step(name: str, step: int):
     if not _active:
         return NO_SPAN
     return _step_annotation(name, step_num=step)
+
+
+class _Phase:
+    """One phase of a ``PhaseClock``, entered and left with ``with``. One
+    object a phase for the clock's lifetime: what an entry has to remember
+    lies on the clock's stacks, so a phase may nest in itself."""
+
+    __slots__ = ("_clock", "_index", "_name")
+
+    def __init__(self, clock: "PhaseClock", index: int, name: str):
+        self._clock, self._index, self._name = clock, index, name
+
+    def __enter__(self):
+        c = self._clock
+        c.tick()
+        c._enclosing.append(c._current)
+        c._current = self._index
+        attrs, c._attrs = c._attrs, None
+        span = hot_span(self._name, **attrs) if attrs \
+            else hot_span(self._name)
+        c._spans.append(span)
+        return span.__enter__()
+
+    def __exit__(self, *exc: Any) -> bool:
+        c = self._clock
+        c._spans.pop().__exit__(*exc)
+        c.tick()
+        c._current = c._enclosing.pop()
+        return False
+
+
+class PhaseClock:
+    """One loop thread's time by phase, always on: one boundary, two sinks.
+
+    ``with clock.phase(name, attrs):`` adds the seconds between its two
+    boundaries to ``sums[name]`` LESS what phases entered inside it took
+    (each boundary is one clock read: the time since the last boundary goes
+    to the innermost phase open, or to ``OTHER`` under none), and opens
+    ``hot_span(name, **attrs)`` between the same boundaries, which is a span
+    while a capture is active and nothing otherwise. ``attrs`` is a dict or
+    anything false: a caller that has attributes builds them only under
+    ``active()``. ``with`` hands back the span (``NO_SPAN`` off capture),
+    for ``set_metadata`` of what the phase learns by its end. ``phase`` is
+    for a ``with`` at once: it hands out ONE object a phase, and the
+    attributes wait on the clock for the entry that follows.
+
+    ``begin`` / ``end`` bracket the loop: between them every second lands
+    in exactly one sum, so the sums of a stretch add up to its wall time.
+    Outside them a phase still counts, and the time between phases does
+    not. Confined to the loop's thread; another thread reads ``sums``
+    (plain floats: a snapshot lacks at most the phase under way)."""
+
+    OTHER = "other"
+
+    def __init__(self, names, clock=time.monotonic):
+        self.names = tuple(names) + (self.OTHER,)
+        self.sums = [0.0] * len(self.names)
+        self._phases = {name: _Phase(self, i, name)
+                        for i, name in enumerate(names)}
+        self._read = clock
+        self._running = False
+        self._mark = 0.0
+        self._current = len(names)          # OTHER
+        self._enclosing: list[int] = []     # the phases open around it
+        self._spans: list[Any] = []         # their spans, innermost last
+        self._attrs: Any = None
+
+    def phase(self, name: str, attrs: Any = None) -> _Phase:
+        self._attrs = attrs
+        return self._phases[name]
+
+    def begin(self) -> bool:
+        """Start attributing every second; False where the loop already
+        runs (``end`` is then its starter's to call)."""
+        if self._running:
+            return False
+        self._mark = self._read()
+        self._running = True
+        return True
+
+    def end(self) -> None:
+        self.tick()
+        self._running = False
+
+    def tick(self) -> float:
+        """A boundary that changes no phase: the clock, read once, with the
+        time up to it attributed."""
+        now = self._read()
+        if self._running or self._enclosing:
+            self.sums[self._current] += now - self._mark
+        self._mark = now
+        return now
+
+    def total(self, name: str) -> float:
+        return self.sums[self._phases[name]._index]
+
+    def snapshot(self) -> dict[str, float]:
+        return dict(zip(self.names, self.sums))

@@ -84,7 +84,6 @@ from kubeflow_tpu.models.decoder import (
     Params, init_decoder_params, plane_kind,
 )
 from kubeflow_tpu.obs import profiler as prof
-from kubeflow_tpu.obs.profiler import hot_span
 from kubeflow_tpu.obs.stats import quantile as _quantile
 from kubeflow_tpu.obs.trace import get_tracer
 
@@ -207,6 +206,11 @@ class Request:
     # (``LLMEngine.counters``).
     admitted_time: Optional[float] = None
     first_token_time: Optional[float] = None
+    # The scheduler's clock when the tokens last put on ``stream`` lay
+    # ready on the host: the fetch of their round, or of their prompt's
+    # first token, had returned. Set once a round a stream, before its
+    # puts; the server's wake time runs from it (``ModelServer.counters``).
+    tokens_ready_time: Optional[float] = None
     finish_time: Optional[float] = None
     finish_reason: Optional[str] = None
     stream: "queue.Queue[Optional[int]]" = dataclasses.field(
@@ -1134,11 +1138,16 @@ class LLMEngine:
         self._prefill_passes = 0                # lockfree: scheduler-confined counter
         self._prefill_chunks_deferred = 0       # lockfree: scheduler-confined counter
         self._admit_pass = 0                    # lockfree: scheduler-confined
-        # The scheduler's own time: seconds of its iterations it was not
-        # blocked on the device (the pacer's input, summed), the blocked
-        # seconds themselves, and the rounds left at their cap.
-        self._sched_host_busy_sum_s = 0.0       # lockfree: scheduler-confined counter
-        self._blocked_s = 0.0                   # lockfree: scheduler-confined counter
+        # The scheduler's time by phase, always on (obs/profiler.py): every
+        # phase of the loop goes through ``_phase``, which adds its own
+        # seconds to a running sum and opens the ``hot_span`` of the same
+        # name while a capture is active. Its iterations, the decode rounds
+        # whose state sync found anything dirty, the rounds left at their
+        # cap.
+        self._phases = prof.PhaseClock(prof.ENGINE_PHASES)  # lockfree: scheduler-confined
+        self._phase = self._phases.phase
+        self._sched_iterations = 0              # lockfree: scheduler-confined counter
+        self._state_sync_rounds = 0             # lockfree: scheduler-confined counter
         self._decode_rounds_at_cap = 0          # lockfree: scheduler-confined counter
         # Of the scheduler iteration under way: the prefill programs
         # dispatched when it began, the length of the round it consumed.
@@ -1271,6 +1280,14 @@ class LLMEngine:
 
     # -- submission ------------------------------------------------------------
 
+    def sched_phase_seconds(self) -> dict[str, float]:
+        """The scheduler thread's running seconds by phase, exclusive (a
+        phase's own, its children's taken out), keyed by the phase's short
+        name (``engine.sync_state`` is ``sync_state``), and ``other``: the
+        loop's time under no phase. Together the loop's wall time."""
+        return {name.rpartition(".")[2]: seconds
+                for name, seconds in self._phases.snapshot().items()}
+
     def counters(self) -> dict[str, float]:
         """One total snapshot of the engine's running sums and counts: a
         flat dict whose keys all exist from construction on, whatever the
@@ -1281,6 +1298,7 @@ class LLMEngine:
         m = self.metrics
         _, _, qd_sum, qd_n = m.queue_delay_histogram()
         _, _, hg_sum, hg_n = m.host_gap_histogram()
+        phases = self.sched_phase_seconds()
         return {
             "slots": self.num_slots,
             "queue_delay_sum_s": qd_sum, "queue_delay_n": qd_n,
@@ -1299,10 +1317,24 @@ class LLMEngine:
             # rounds dispatched at the cap in force (the length the two
             # options set), not shorter by the scheduler's choice
             "decode_rounds_at_cap": self._decode_rounds_at_cap,
-            # the scheduler iterations' wall time less the time blocked
-            # fetching from the device: the host's own share, and what a
+            # the scheduler's time by phase (``sched_phase_seconds``:
+            # ``sched_sync_state_sum_s``, ... ``sched_other_sum_s``), and
+            # its iterations
+            **{f"sched_{phase}_sum_s": seconds
+               for phase, seconds in phases.items()},
+            "sched_iterations": self._sched_iterations,
+            # the loop's wall time less the time blocked fetching from the
+            # device and waiting for work: the host's own share, and what a
             # round's length is chosen to hide
-            "sched_host_busy_sum_s": self._sched_host_busy_sum_s,
+            "sched_host_busy_sum_s": sum(phases.values())
+            - phases["fetch"] - phases["idle"],
+            # what the state syncs sent (``DecodeState.stats``: one
+            # scatter dispatch a dirty slot, one row upload a dirty
+            # page-table row) and the decode rounds whose sync found
+            # anything dirty
+            "state_slot_syncs": self._dstate.stats["slot_syncs"],
+            "state_row_syncs": self._dstate.stats["table_row_syncs"],
+            "state_sync_rounds": self._state_sync_rounds,
             # cache rows the dispatched steps attend to, summed over the
             # live slots and the steps of every round
             "decode_context_tokens": self._decode_context_tokens,
@@ -1602,7 +1634,8 @@ class LLMEngine:
             return
         items, self._pending_first = self._pending_first, []
         n = len(items)
-        with hot_span(prof.ENGINE_SAMPLE_FIRST, n=n):
+        with self._phase(prof.ENGINE_SAMPLE_FIRST,
+                         prof.active() and {"n": n}):
             width = 1
             while width < n:
                 width *= 2
@@ -1624,7 +1657,8 @@ class LLMEngine:
             self._consume_rounds()
             # A wait for the device like the round's own fetch, and named
             # like it.
-            with hot_span(prof.ENGINE_FETCH, first=n), self._blocked():
+            with self._phase(prof.ENGINE_FETCH,
+                             prof.active() and {"first": n}):
                 vals = jax.device_get(firsts)
             self.first_token_fetches += 1
             for j, (req, slot_idx, plen, _) in enumerate(items):
@@ -1640,8 +1674,9 @@ class LLMEngine:
             _span_close(req, prompt_tokens=plen)
             if not req.handoff_requested:
                 _span_open(req, "engine.decode", slot=slot_idx)
+        req.tokens_ready_time = time.monotonic()
         if req.first_token_time is None:
-            req.first_token_time = time.monotonic()
+            req.first_token_time = req.tokens_ready_time
             if req.admitted_time is not None:
                 self._prefill_phase_sum_s += \
                     req.first_token_time - req.admitted_time
@@ -1714,8 +1749,9 @@ class LLMEngine:
             jnp.asarray(np.asarray(
                 [self._slot_aidx[ch.slot] for ch in group]
                 + [-1] * (rows - len(group)), np.int32)))
-        with hot_span(prof.ENGINE_PREFILL_DISPATCH, slot=group[0].slot,
-                      pos=group[0].pos, chunks=len(group)):
+        with self._phase(prof.ENGINE_PREFILL_DISPATCH, prof.active() and {
+                "slot": group[0].slot, "pos": group[0].pos,
+                "chunks": len(group)}):
             if rows > 1:
                 # Rows past the group are DEAD: no valid position, no page.
                 table = np.full((rows, self._mpp), -1, np.int32)
@@ -2703,10 +2739,15 @@ class LLMEngine:
         spec advances, page-table growth) to the device-resident state as
         per-index donated scatters. Steady-state rounds have nothing dirty
         and sync nothing — the zero-upload invariant."""
-        if self._dstate.dirty_slots:
-            self._dstate.sync_slots(self._slot_state_values)
-        if self._dstate.dirty_rows:
-            self._dstate.sync_rows(lambda i: self._table[i])
+        slots, rows = len(self._dstate.dirty_slots), \
+            len(self._dstate.dirty_rows)
+        with self._phase(prof.ENGINE_SYNC_STATE, prof.active() and {
+                "slots": slots, "rows": rows}):
+            if slots:
+                self._dstate.sync_slots(self._slot_state_values)
+            if rows:
+                self._dstate.sync_rows(lambda i: self._table[i])
+        self._state_sync_rounds += bool(slots or rows)
 
     def _dispatch_round(self, active, paced: bool = False) -> bool:  # hot-loop
         """Enqueue one multi-step decode dispatch over the device-resident
@@ -2729,7 +2770,7 @@ class LLMEngine:
         # Pre-allocate pages covering every live slot's next k_steps
         # write positions (mid-dispatch page crossings must land on
         # mapped pages); under pool pressure, preempt youngest-first.
-        with hot_span(prof.ENGINE_ENSURE_PAGES):
+        with self._phase(prof.ENGINE_ENSURE_PAGES):
             for i, s in list(active):
                 if self.slots[i] is not s:
                     continue    # preempted by an earlier slot's allocation
@@ -2750,8 +2791,7 @@ class LLMEngine:
         if not active:
             return False
         mode = _mode_for([s.request.params for _, s in active])
-        with hot_span(prof.ENGINE_SYNC_STATE):
-            self._sync_decode_state()
+        self._sync_decode_state()
         now = time.monotonic()
         gap = None
         if self._last_ready_t is not None:
@@ -2767,8 +2807,9 @@ class LLMEngine:
         context = sum(
             k_steps * (s.length + slack) + k_steps * (k_steps + 1) // 2
             for _, s in active)
-        with hot_span(prof.ENGINE_DECODE_DISPATCH, round=round_id,
-                      k_steps=k_steps, live=len(active), context=context):
+        with self._phase(prof.ENGINE_DECODE_DISPATCH, prof.active() and {
+                "round": round_id, "k_steps": k_steps, "live": len(active),
+                "context": context}):
             out = self._dispatch_decode(k_steps, mode, self._next_key())
         self.decode_rounds += 1
         self._decode_steps_dispatched += k_steps
@@ -2801,7 +2842,8 @@ class LLMEngine:
         re-admitted) are MASKED — a cancelled request's output stream never
         contains post-cancel tokens. Returns tokens emitted."""
         rnd = self._rounds.pop(0)
-        with hot_span(prof.ENGINE_FETCH, round=rnd.round_id), self._blocked():
+        with self._phase(prof.ENGINE_FETCH,
+                         prof.active() and {"round": rnd.round_id}):
             out = np.asarray(jax.device_get(rnd.out))  # sync-point: the pipeline's one designed fetch per round
         now = time.monotonic()
         self._consumed_k = rnd.k_steps
@@ -2813,17 +2855,25 @@ class LLMEngine:
             if steps:
                 self._pacer.note_step((now - self._last_ready_t) / steps)
         self._last_ready_t = now
-        with hot_span(prof.ENGINE_EMIT, round=rnd.round_id):
-            emitted = self._emit_round(rnd, out)
+        with self._phase(prof.ENGINE_EMIT,
+                         prof.active() and {"round": rnd.round_id}) as span:
+            emitted, streams = self._emit_round(rnd, out, now)
+            if prof.active():
+                span.set_metadata(tokens=emitted, streams=streams)
         self._decode_tokens_emitted += emitted
         return emitted
 
-    def _emit_round(self, rnd: "_InflightRound", out) -> int:  # hot-loop
-        """Hand one fetched round's tokens to their requests."""
-        emitted = 0
+    def _emit_round(self, rnd: "_InflightRound", out,  # hot-loop
+                    ready_t: float) -> tuple[int, int]:
+        """Hand one fetched round's tokens to their requests, each stamped
+        once with the instant the round lay ready on the host. Returns the
+        tokens handed on and the streams that got any."""
+        emitted = streams = 0
         for i, s in rnd.active:
             if self.slots[i] is not s or s.request.done.is_set():
                 continue
+            if out[i][0] >= 0:          # it gets a token: stamped before
+                s.request.tokens_ready_time = ready_t
             n_emit = 0
             for t in out[i]:
                 if t < 0:
@@ -2836,6 +2886,7 @@ class LLMEngine:
                 s.generated += 1
                 n_emit += 1
             emitted += n_emit
+            streams += n_emit > 0
             if n_emit and s.request.first_token_time is None:
                 # Adopted (handed-off) requests see their first LOCAL
                 # token here — this engine's TTFT is its decode-side
@@ -2854,7 +2905,7 @@ class LLMEngine:
                                              host_gap_ms=round(rnd.gap_ms,
                                                                3))
             self._finish_if_done(i)
-        return emitted
+        return emitted, streams
 
     def _consume_rounds(self) -> int:
         """Drain every in-flight round (the pipeline barrier the spec path
@@ -2953,9 +3004,10 @@ class LLMEngine:
             jnp.asarray(lengths), jnp.asarray(live))
         self.cache = {n: cache_out[n] for n in cache_out if n != "table"}
         self._dstate.adopt(self._dstate.arrays, cache_out["table"])
-        with self._blocked():
+        with self._phase(prof.ENGINE_FETCH):
             greedy = np.asarray(jax.device_get(greedy))  # sync-point: greedy verification happens host-side
-        verify_s = time.monotonic() - t1
+        ready_t = time.monotonic()
+        verify_s = ready_t - t1
         emitted = 0
         for i, s in active:
             d = drafts.get(i, [])
@@ -2973,6 +3025,7 @@ class LLMEngine:
             emit = emit[:self.max_len - 1 - s.length]
             if p.stop_token is not None and p.stop_token in emit:
                 emit = emit[:emit.index(p.stop_token) + 1]
+            s.request.tokens_ready_time = ready_t
             for tok in emit:
                 s.request.output_tokens.append(tok)
                 s.request.stream.put(tok)
@@ -3044,7 +3097,7 @@ class LLMEngine:
         out, self._draft_cache = self._draft_propose_n(
             self._draft_params, self._draft_cache, jnp.asarray(deltas),
             jnp.asarray(dlens), jnp.asarray(dpos), jnp.asarray(live), steps)
-        with self._blocked():
+        with self._phase(prof.ENGINE_FETCH):
             out = np.asarray(jax.device_get(out))  # sync-point: drafts are proposed host-side
         drafts: dict[int, list[int]] = {}
         for i, s in active:
@@ -3071,16 +3124,6 @@ class LLMEngine:
         self._dstate.mark_row(idx)
         self._allocator.free(drop)
 
-    @contextlib.contextmanager
-    def _blocked(self):
-        """Around a fetch the scheduler waits for the device in: the wait is
-        not the host's own time (``sched_host_busy_sum_s``)."""
-        t0 = time.monotonic()
-        try:
-            yield
-        finally:
-            self._blocked_s += time.monotonic() - t0
-
     def _transfer_guard(self):
         """``jax.transfer_guard("disallow")`` in sanitize mode: implicit
         transfers (a stray numpy array riding into a dispatch — the PR-4
@@ -3099,14 +3142,29 @@ class LLMEngine:
         a dispatched round counts too, so the loop never idles with a
         round in flight). Under ``KFTPU_SANITIZE=1`` the decode pass runs
         with implicit transfers disallowed — the runtime half of the
-        static device-hygiene rules."""
-        t0, blocked0 = time.monotonic(), self._blocked_s
+        static device-hygiene rules.
+
+        Every second of an iteration lands in one phase's sum or in the
+        loop's own (``_phases``): ``_loop`` holds that timeline across
+        iterations and their idle waits, a caller that drives ``step``
+        itself gets one an iteration."""
+        own_timeline = self._phases.begin()
+        try:
+            return self._iterate()
+        finally:
+            if own_timeline:
+                self._phases.end()
+
+    def _iterate(self) -> int:
+        t0 = self._phases.tick()
+        fetch0 = self._phases.total(prof.ENGINE_FETCH)
+        self._sched_iterations += 1
         self._programs_at_step = self._prefill_programs_dispatched
         self._consumed_k = None
-        with hot_span(prof.ENGINE_REAP):
+        with self._phase(prof.ENGINE_REAP):
             n = self._reap_abandoned() + self._enforce_queue_bound() \
                 + self._drain_handoff_releases()
-        with hot_span(prof.ENGINE_ADMIT):
+        with self._phase(prof.ENGINE_ADMIT):
             n += self._admit()
         if self._kvtier is not None:
             # Demotion scan (host tier): cold sharer-free prefix pages
@@ -3114,7 +3172,7 @@ class LLMEngine:
             # Interval-gated inside tick — idle 50 ms polls drive it —
             # and it yields to foreground traffic unless pool pressure
             # says demoting NOW is what saves the cached content.
-            with hot_span(prof.ENGINE_KVTIER_TICK):
+            with self._phase(prof.ENGINE_KVTIER_TICK):
                 busy = bool(self._backlog) or bool(self._chunkings) \
                     or any(s is not None for s in self.slots)
                 self._kvtier.tick(busy=busy)
@@ -3124,14 +3182,15 @@ class LLMEngine:
             # Idle: the next round's host-gap sample would span the idle
             # wait, not the hot loop.
             self._last_ready_t = None
-        # The host's own time this iteration: what a round must hide. Its
-        # emit loop, and the handler threads it wakes, grow with the round
-        # it consumed: filed under that length. An iteration that sent a
-        # prefill program is no sample: its dispatch and first tokens cost
-        # the host more, and the device has the chunk's time to spend on it.
-        host_s = time.monotonic() - t0 - (self._blocked_s - blocked0)
-        self._sched_host_busy_sum_s += host_s
+        # The host's own time this iteration (its wall time less its
+        # fetches): what a round must hide. Its emit loop, and the handler
+        # threads it wakes, grow with the round it consumed: filed under
+        # that length. An iteration that sent a prefill program is no
+        # sample: its dispatch and first tokens cost the host more, and the
+        # device has the chunk's time to spend on it.
         if self._consumed_k is not None and self._sent_no_prefill():
+            host_s = self._phases.tick() - t0 \
+                - (self._phases.total(prof.ENGINE_FETCH) - fetch0)
             self._pacer.note_host(self._consumed_k, host_s)
         return n
 
@@ -3149,12 +3208,16 @@ class LLMEngine:
         self._thread.start()
 
     def _loop(self) -> None:
-        while not self._stop.is_set():
-            if self.step() == 0:
-                # idle: block until a request arrives
-                with hot_span(prof.ENGINE_IDLE):
-                    self._wake.wait(timeout=0.05)
-                self._wake.clear()
+        self._phases.begin()
+        try:
+            while not self._stop.is_set():
+                if self.step() == 0:
+                    # idle: block until a request arrives
+                    with self._phase(prof.ENGINE_IDLE):
+                        self._wake.wait(timeout=0.05)
+                    self._wake.clear()
+        finally:
+            self._phases.end()
 
     def stop(self, timeout: float = 10.0) -> bool:
         """Stop the background scheduler. Returns (and records in

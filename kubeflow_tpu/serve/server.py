@@ -72,6 +72,31 @@ from kubeflow_tpu.serve.tokenizer import Tokenizer, get_tokenizer
 #: ``engine.submit`` returned plus first token to first chunk written.
 FIRST_BYTE_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.1, 0.5)
 
+#: Streamed chunks a handler thread sums by itself before it folds them into
+#: the server's counters: a snapshot misses at most this many a live stream,
+#: and no token takes the lock.
+STREAM_FOLD_CHUNKS = 32
+
+
+class StreamSums:
+    """A token's way from its round to the socket, summed over streamed
+    chunks: ``chunks`` written after the headers; ``write_s`` from
+    ``req.stream.get`` returning to the chunk flushed; ``wake_s`` over
+    ``wake_n`` tokens from the instant their round lay ready on the
+    scheduler's side (``Request.tokens_ready_time``) to ``get`` returning
+    them, taken while no later token of their request waited; ``behind``
+    the tokens taken while one did. A handler thread keeps its own and
+    folds them into the server's (``ModelServer.fold_stream``)."""
+
+    __slots__ = ("chunks", "write_s", "wake_s", "wake_n", "behind")
+
+    def __init__(self) -> None:
+        self.clear()
+
+    def clear(self) -> None:
+        self.chunks = self.wake_n = self.behind = 0
+        self.write_s = self.wake_s = 0.0
+
 #: The longest capture ``POST /debug/profile/start`` may ask for, and the
 #: length of one that names none: the port is the one that serves inference,
 #: so a client that never sends /stop must not leave the profiler on.
@@ -235,6 +260,10 @@ class ModelServer:
         self._fbo_counts = [0] * (len(FIRST_BYTE_BUCKETS) + 1)  # guarded_by: _fbo_lock
         self._fbo_sum = 0.0             # guarded_by: _fbo_lock
         self._fbo_n = 0                 # guarded_by: _fbo_lock
+        # Over every chunk ``_stream_tokens`` writes after the headers; a
+        # handler thread folds its own in every STREAM_FOLD_CHUNKS chunks
+        # and at its stream's end.
+        self._streamed = StreamSums()   # guarded_by: _fbo_lock
         # Where /debug/profile captures go, and the timer that ends the one
         # this server started.
         self.profile_dir = profile_dir or os.path.join(
@@ -583,6 +612,15 @@ class ModelServer:
         with self._fbo_lock:
             return list(self._fbo_counts), self._fbo_sum, self._fbo_n
 
+    def fold_stream(self, mine: StreamSums) -> None:
+        """Add one handler thread's sums to the server's and zero them."""
+        with self._fbo_lock:
+            total = self._streamed
+            for field in StreamSums.__slots__:
+                setattr(total, field,
+                        getattr(total, field) + getattr(mine, field))
+        mine.clear()
+
     def start_profile(self, seconds: Optional[float] = None) -> dict:
         """Start a profiler capture of this replica into ``profile_dir``
         (obs/profiler.py: raises ``RuntimeError`` while one is active). It
@@ -614,9 +652,18 @@ class ModelServer:
         """One total snapshot of the server's own running sums and counts
         (the engine has its own, ``LLMEngine.counters``): every key exists
         from construction on and only ever grows."""
-        _, total, n = self.first_byte_overhead_histogram()
-        return {"first_byte_overhead_sum_s": total,
-                "first_byte_overhead_n": n}
+        with self._fbo_lock:
+            t = self._streamed
+            return {"first_byte_overhead_sum_s": self._fbo_sum,
+                    "first_byte_overhead_n": self._fbo_n,
+                    # ``StreamSums``; ``write`` holds the piece decoded,
+                    # ``json.dumps``, write, flush and the waits for the
+                    # interpreter lock in between, handler threads summed
+                    "stream_chunks_n": t.chunks,
+                    "stream_write_sum_s": t.write_s,
+                    "stream_wake_sum_s": t.wake_s,
+                    "stream_wake_n": t.wake_n,
+                    "stream_behind_n": t.behind}
 
     def metrics_registry(self) -> MetricsRegistry:
         """Scrape-time registry over the live engine counters — the model
@@ -693,6 +740,12 @@ def serving_metrics_registry(engines: list, *,
     host_gap = reg.histogram("kftpu_engine_host_gap_seconds",
                              HOST_GAP_BUCKETS)
     depth = reg.gauge("kftpu_engine_dispatch_depth")
+    # The scheduler thread's seconds by phase (``sched_phase_seconds``,
+    # which ``LLMEngine.counters`` carries as ``sched_<phase>_sum_s``;
+    # ``phase="other"``: under none): what the benchmark's readers
+    # difference over a window, for the operator's ``rate()``. ``fetch``
+    # and ``idle`` are waits; the rest is the host's own.
+    sched_phase = reg.counter("kftpu_engine_sched_phase_seconds_total")
     # Disaggregated serving: the token-aware router's placement signals
     # (pending prefill tokens → prefill pool, resident KV pages → decode
     # pool) plus the handoff lifecycle counters.
@@ -786,6 +839,8 @@ def serving_metrics_registry(engines: list, *,
         _, hcounts, hsum, hn = engine.metrics.host_gap_histogram()
         host_gap.set_cumulative(hcounts, hsum, hn, model=name)
         depth.set(snap.get("dispatch_depth", 0), model=name)
+        for phase, seconds in engine.sched_phase_seconds().items():
+            sched_phase.inc(seconds, model=name, phase=phase)
         pending_prefill.set(engine.pending_prefill_tokens(), model=name)
         pages_resident.set(engine.kv_pages_in_use(), model=name)
         pages_cached.set(engine.kv_pages_cached(), model=name)
@@ -1141,6 +1196,7 @@ def _make_handler(server: ModelServer):
             server's first-byte overhead."""
             self._send_sse_headers()
             first_byte_due = entry_overhead_s is not None
+            mine = StreamSums()
             try:
                 while True:
                     try:
@@ -1156,6 +1212,13 @@ def _make_handler(server: ModelServer):
                         break
                     if tok == tokenizer.eos_id:
                         continue
+                    t_got = time.monotonic()
+                    # The stamp first, then whether a later token waits: a
+                    # round that lands between the two reads makes this
+                    # token one that fell behind, never a sample against a
+                    # stamp that is not its own.
+                    ready_t = req.tokens_ready_time
+                    caught_up = req.stream.empty()
                     piece = tokenizer.decode([tok])
                     if chat:
                         delta = {"choices": [
@@ -1168,11 +1231,22 @@ def _make_handler(server: ModelServer):
                     self._chunk(json.dumps({"id": req.id, "object": "chunk",
                                             "model": model or server.name,
                                             **delta}))
+                    t_sent = time.monotonic()
+                    mine.chunks += 1
+                    mine.write_s += t_sent - t_got
+                    if caught_up and ready_t is not None \
+                            and ready_t <= t_got:
+                        mine.wake_s += t_got - ready_t
+                        mine.wake_n += 1
+                    else:
+                        mine.behind += 1
+                    if mine.chunks == STREAM_FOLD_CHUNKS:
+                        server.fold_stream(mine)
                     if first_byte_due:
                         first_byte_due = False
                         if req.first_token_time is not None:
                             server.observe_first_byte_overhead(
-                                entry_overhead_s + time.monotonic()
+                                entry_overhead_s + t_sent
                                 - req.first_token_time)
             except OSError:
                 # Client hung up mid-stream: free the slot and its KV
@@ -1181,6 +1255,8 @@ def _make_handler(server: ModelServer):
                 req.cancel()
                 self.close_connection = True
                 return
+            finally:
+                server.fold_stream(mine)
             self._chunk("[DONE]")
             self.wfile.write(b"0\r\n\r\n")
 

@@ -8,14 +8,22 @@ chosen from printed beside its result (to standard error; the result line is
 the benchmark's own and stays the last line of standard output): over the
 window, from the snapshots of ``LLMEngine.counters()`` the harness takes,
 the rounds, the steps a round, the rounds left at their cap and the
-scheduler's own seconds a round and as a share of the window, the prefill
+scheduler's own milliseconds a round, the prefill
 programs a scheduler pass sent and the chunks a pass's budget deferred
-(ISSUE 34: ``prefill_programs_a_pass``, ``prefill_chunks_deferred``); at the
+(ISSUE 34: ``prefill_programs_a_pass``, ``prefill_chunks_deferred``); the
+host's time a token over the window (ISSUE 37, by
+``benchmark/phase_readers.py``'s definitions: the scheduler's share of the
+window, which the line's ``engine.sched_busy_share_window.*`` prints where it
+is declared, each of its phases in milliseconds a round and as a share, what
+the state syncs sent a round, the server's write share and wake time); at the
 window's end the scheduler's two running averages (the host's time an
 iteration, the device's time a step) and the length in force; the client's
 gaps between tokens and times to the first token at several percentiles;
 of a traced run the tail's rounds by length, its decode and chunk programs
-(executions, mean milliseconds) and the scheduler thread's spans by name;
+(executions, mean milliseconds), the scheduler thread's spans by name and,
+phase by phase, the always-on sum over the traced stretch beside what
+``hostspans.innermost_segments`` cuts out of the spans for it, and the
+thread's time under no span by the spans on either side of it;
 and of the set-up, when the engine was built, how long each length of the
 decode ladder took to compile or load, and when each program variant was
 first dispatched. It runs on a checkout without the mechanism too (the parent commit):
@@ -26,6 +34,14 @@ engine's choice is replaced, from outside, by ``min(K, cap)``, to read the
 host's time an iteration at a length the scheduler would not choose here.
 ``--rate R`` is another: an open loop's arrivals at ``R`` requests a second
 in place of the traffic file's, for the first readings of a sweep.
+``--switch-interval S`` a third: ``sys.setswitchinterval(S)`` for the run
+(the interpreter hands its lock on after 5 ms by default), to see how much
+of the scheduler's time under no phase is the wait for that lock behind the
+handler threads a round's tokens woke. ``--probe-other`` a fourth: the
+seconds since the scheduler's last phase boundary, read as each of
+``_consume_round``, ``_decode_once`` and ``_iterate`` returns and as
+``_iterate`` begins, summed a call: where between ``engine.emit`` and the
+next ``engine.reap`` the loop's time under no phase goes.
 """
 
 from __future__ import annotations
@@ -40,6 +56,9 @@ import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
+
+
+EDGE = 0.1      # seconds of a capture's ends left out of ``recorded``'s readings
 
 
 def _log(msg: str) -> None:
@@ -65,6 +84,96 @@ def _client_side(workload: str, seconds: float) -> None:
         red = serving.reduce_requests(json.load(f)["results"], seconds)
     _log(f"client itl ms: {_percentiles(red['itl_ms'])}")
     _log(f"client ttft ms: {_percentiles(red['ttft_ms'])}")
+
+
+def _window(before: dict, after: dict, seconds: float) -> None:
+    """The host's time a token over the window, by the definitions the
+    per-layer metrics of ISSUE 37 have (``benchmark/phase_readers.py``): a
+    checkout without a key prints ``null`` there."""
+    from benchmark import phase_readers as pr
+
+    run = {"counters_before": before, "counters_after": after,
+           "window_s": seconds}
+    phases = sorted(k[len("sched_"):-len("_sum_s")]
+                    for k in after.get("engine", {})
+                    if k.startswith("sched_") and k.endswith("_sum_s")
+                    and k != "sched_host_busy_sum_s")
+    table = {
+        "sched_busy_share_window_pct": pr.sched_busy_share_window(run),
+        "prefill_dispatch_ms_per_program":
+            pr.prefill_dispatch_ms_per_program(run),
+        "state_syncs_per_round": pr.state_syncs_per_round(run),
+        "state_slot_syncs_per_round": pr.per(
+            run, "engine", ("state_slot_syncs",), "decode_rounds"),
+        "state_sync_rounds_share": pr.per(
+            run, "engine", ("state_sync_rounds",), "decode_rounds"),
+        "stream_write_share_pct": pr.stream_write_share(run),
+        "stream_wake_mean_ms": pr.stream_wake_mean_ms(run),
+        "stream_behind_share": pr.per(
+            run, "server", ("stream_behind_n",), "stream_chunks_n"),
+        "phase_ms_per_round": {p: pr.phase_ms_per_round(run, p)
+                               for p in phases},
+        "phase_share_of_window_pct": {
+            p: pr.share_of_window(run, "engine", f"sched_{p}_sum_s")
+            for p in phases},
+    }
+    _log(f"window host time: {json.dumps(table, sort_keys=True)}")
+
+
+def _sched_pieces(record: dict) -> list:
+    """The scheduler thread's traced time as (start, end, innermost span)."""
+    from benchmark import hostspans
+
+    sched = hostspans.thread_with(record.get("host_spans"),
+                                  hostspans.ENGINE_THREAD)
+    return hostspans.innermost_segments(
+        [s for s in sched or [] if s[0] != hostspans.ANCHOR])
+
+
+def _tail_sums_against_spans(record: dict, tail: dict) -> None:
+    """One boundary, two sinks: each phase's always-on seconds between the
+    two readings ``recorded`` took inside the capture, beside the seconds
+    of its spans' innermost segments between the same two instants (laid on
+    the trace's timeline through the capture's anchor). What is left is a
+    phase under way at either reading: the sum has the whole of the first
+    and none of the second."""
+    from benchmark import hostspans
+
+    pieces = _sched_pieces(record)
+    anchor = hostspans.anchor(record.get("host_spans"))
+    if not tail or not pieces or not anchor:
+        return
+    sums = {k: tail["b"][k] - tail["a"][k] for k in tail["b"]}
+    lo, hi = (anchor["trace_s"] + t - anchor["mono_ns"] / 1e9
+              for t in (tail["t_a"], tail["t_b"]))
+    spanned: dict = {}
+    for t0, t1, name in pieces:
+        part = min(t1, hi) - max(t0, lo)
+        if part > 0:
+            spanned[name] = spanned.get(name, 0.0) + part
+    for name in sorted(set(spanned) | {"engine." + p for p in sums}):
+        a, b = sums.get(name.rpartition(".")[2]), spanned.get(name)
+        if a is None or not b:
+            _log(f"tail phase {name}: sum {a} spans {b}")
+            continue
+        _log(f"tail phase {name}: sum {1e3 * a:.3f} ms spans "
+             f"{1e3 * b:.3f} ms ({100 * (a - b) / b:+.2f}%)")
+
+
+def _tail_untraced(record: dict) -> None:
+    """The scheduler thread's time under NO span (``sched_other_sum_s``
+    over a window), by the two spans each stretch lies between."""
+    pieces = _sched_pieces(record)
+    between: dict = {}
+    for (_, end, before), (start, _, after) in zip(pieces, pieces[1:]):
+        if start - end > 1e-7:
+            n = between.setdefault(f"{before} -> {after}", [0, 0.0, 0.0])
+            n[0], n[1] = n[0] + 1, n[1] + start - end
+            n[2] = max(n[2], start - end)
+    for key, (n, total, worst) in sorted(between.items(),
+                                         key=lambda kv: -kv[1][1])[:8]:
+        _log(f"tail untraced {key}: {n} x {1e3 * total / n:.3f} ms = "
+             f"{total:.4f} s (max {1e3 * worst:.3f} ms)")
 
 
 def _tail(record: dict) -> None:
@@ -120,10 +229,13 @@ def main() -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--pin-steps", type=int, default=0)
     ap.add_argument("--rate", type=float, default=0.0)
+    ap.add_argument("--switch-interval", type=float, default=0.0)
+    ap.add_argument("--probe-other", action="store_true")
     args, rest = ap.parse_known_args()
 
     from benchmark import manifest as mf
-    from benchmark import run, serving
+    from benchmark import run, serving, tracing
+    from benchmark.device import sleep_until
     from kubeflow_tpu.serve.engine import LLMEngine
 
     t_origin = time.monotonic()
@@ -146,9 +258,30 @@ def main() -> int:
                  f"samples { {k: len(d) for k, d in pacer._host.items()} }")
         return snap
 
+    engines: list = []
+    tail_sums: dict = {}
+
+    def recorded(trace_dir, seconds):
+        """``tracing.record`` with the engine's phase sums read twice
+        inside the capture, ``EDGE`` seconds from either end (the
+        profiler's start stalls whatever phase it falls into)."""
+        phases = getattr(engines[-1], "sched_phase_seconds", None) \
+            if engines else None
+        tracing.start(trace_dir)
+        t_on = time.monotonic()
+        if phases:
+            time.sleep(EDGE)
+            tail_sums.update(a=phases(), t_a=time.monotonic())
+            time.sleep(seconds - 2 * EDGE)
+            tail_sums.update(b=phases(), t_b=time.monotonic())
+        sleep_until(t_on + seconds)
+        return tracing.stop(trace_dir, time.monotonic() - t_on)
+
     def reading(manifest, cell_name, record):
         try:
             _tail(record)
+            _tail_sums_against_spans(record, tail_sums)
+            _tail_untraced(record)
         except Exception as exc:    # boundary: the run's result comes first
             _log(f"tail not printed: {type(exc).__name__}: {exc}")
         return read(manifest, cell_name, record)
@@ -173,6 +306,7 @@ def main() -> int:
     def built(self, *a, **kw):
         t0 = time.monotonic()
         build(self, *a, **kw)
+        engines.append(self)
         self.program_kernels = Timeline(self.program_kernels)
         at(f"LLMEngine() took {time.monotonic() - t0:.3f} s")
         if args.pin_steps:
@@ -196,7 +330,30 @@ def main() -> int:
         _log(f"decode ladder {self._pacer.ladder} compiled and run in "
              f"{time.monotonic() - t0:.3f} s")
 
+    if args.switch_interval:
+        sys.setswitchinterval(args.switch_interval)
+    probed: dict = {}
+    if args.probe_other:
+        def since_boundary(self, where):
+            n = probed.setdefault(where, [0, 0.0])
+            n[0], n[1] = n[0] + 1, \
+                n[1] + time.monotonic() - self._phases._mark
+
+        def probing(name):
+            inner = getattr(LLMEngine, name)
+
+            def outer(self, *a, **kw):
+                if name == "_iterate":
+                    since_boundary(self, "_iterate begins")
+                out = inner(self, *a, **kw)
+                since_boundary(self, name + " returned")
+                return out
+            setattr(LLMEngine, name, outer)
+
+        for name in ("_consume_round", "_decode_once", "_iterate"):
+            probing(name)
     serving.program_counters = recording
+    tracing.record = recorded
     mf.read_layer_metrics = reading
     if args.rate:
         mf.load_traffic = at_rate
@@ -208,20 +365,26 @@ def main() -> int:
     if len(snapshots) >= 2 and snapshots[0] and "engine" in snapshots[0]:
         a, b = snapshots[0]["engine"], snapshots[1]["engine"]
         d = {k: b[k] - a[k] for k in b if k in a and k.startswith(
-            ("decode_", "sched_", "prefill_", "first_token", "host_gap",
-             "queue_delay"))}
+            ("decode_", "sched_", "state_", "prefill_", "first_token",
+             "host_gap", "queue_delay"))}
         rounds = d.get("decode_rounds") or 0
         if rounds:
             d["steps_a_round"] = d["decode_steps_dispatched"] / rounds
             if "sched_host_busy_sum_s" in d:
                 d["sched_host_busy_ms_a_round"] = \
                     1e3 * d["sched_host_busy_sum_s"] / rounds
-                d["sched_host_busy_share"] = \
-                    d["sched_host_busy_sum_s"] / args.seconds
         if d.get("prefill_passes"):         # ISSUE 34; the parent has none
             d["prefill_programs_a_pass"] = \
                 d["prefill_programs_dispatched"] / d["prefill_passes"]
         _log(f"window counters: {json.dumps(d, sort_keys=True)}")
+        if "server" in snapshots[0]:
+            a, b = snapshots[0]["server"], snapshots[1]["server"]
+            _log("window server counters: " + json.dumps(
+                {k: b[k] - a[k] for k in b if k in a}, sort_keys=True))
+        _window(snapshots[0], snapshots[1], args.seconds)
+    for where, (n, total) in probed.items():
+        _log(f"probe {where}: {n} x {1e3 * total / n:.3f} ms since the "
+             f"last phase boundary = {total:.3f} s")
     _client_side(args.workload, args.seconds)
     return rc
 

@@ -83,6 +83,14 @@ LONGANSWER_STATED = {
     "engine.sched_busy_share.longanswer": 0.0,
     "kernel.paged_packed_decode_attention_bw_share.longanswer": 0.0,
 }
+# PR 37's two readers of the scheduler's share of the WINDOW
+# (benchmark/phase_readers.py), the same way: the counter they take, at
+# rest, and their number for a window in which it stood still.
+WINDOW_ENGINE_COUNTERS = {"sched_host_busy_sum_s": 0.0}
+WINDOW_STATED = {
+    "engine.sched_busy_share_window.chat": 0.0,
+    "engine.sched_busy_share_window.longanswer": 0.0,
+}
 
 
 @pytest.fixture(autouse=True, scope="session")
@@ -102,8 +110,9 @@ def benchmark_suite_tables_know_the_longanswer_cell(request):
     for tables, added in (
             ((suite.ADDED_CELLS, rehearsal.CELLS), LONGANSWER_CELLS),
             ((suite.ADDED_ENGINE_COUNTERS, readers.ENGINE0),
-             LONGANSWER_ENGINE_COUNTERS),
-            ((suite.ADDED_STATED, total.STATED), LONGANSWER_STATED)):
+             {**LONGANSWER_ENGINE_COUNTERS, **WINDOW_ENGINE_COUNTERS}),
+            ((suite.ADDED_STATED, total.STATED),
+             {**LONGANSWER_STATED, **WINDOW_STATED})):
         for table in tables:
             for key, value in added.items():
                 table.setdefault(key, value)
@@ -112,20 +121,38 @@ def benchmark_suite_tables_know_the_longanswer_cell(request):
 # test_benchmark_glm.py pins where PR 28's entries stand (the LAST cell, the
 # last configuration, the last five per-layer metrics), which held when they
 # were appended; PR 35 appends its own behind them, as the benchmark's
-# contract has it. That one test is handed the manifest without this PR's
-# entries (what the suite's conftest.py does for the test that pins PR 25's);
-# every other test reads it whole.
+# contract has it, and PR 37 its two behind those. That one test is handed
+# the manifest without the later entries (what the suite's conftest.py does
+# for the test that pins PR 25's); test_benchmark_lfm2.py's two, which pin the
+# per-layer metrics of PR 35's cell as that PR left them (in the manifest, and
+# in the line its CPU rehearsal prints), without PR 37's. Every other test
+# reads the manifest whole.
 PINS_PR28_AT_THE_END = "test_what_this_pr_added_is_listed_with_the_benchmark"
+PINS_PR35S_CELL = \
+    "test_what_this_pr_added_is_listed_with_the_benchmark_at_the_end"
+PINS_PR35S_LINE = ("test_benchmark_lfm2",
+                   "test_the_cells_path_runs_end_to_end_on_the_cpu")
 
 
 @pytest.fixture(autouse=True)
-def the_manifest_as_it_stood_for_the_test_that_pins_pr28(request,
+def the_manifest_as_it_stood_for_the_tests_that_pin_a_pr(request,
                                                          monkeypatch):
-    if request.node.name != PINS_PR28_AT_THE_END:
-        return
+    name = request.node.name
     module = request.node.module
-    cell = next(iter(LONGANSWER_CELLS.values()))[0]
-    config = cell.split(".")[0]
+    later = set(WINDOW_STATED)
+    if (module.__name__, request.node.originalname) == PINS_PR35S_LINE:
+        whole = module.rehearsal_manifest
+        monkeypatch.setattr(module, "rehearsal_manifest", lambda: {
+            **whole(), "per_layer": [m for m in whole()["per_layer"]
+                                     if m["name"] not in later]})
+        return
+    if name not in (PINS_PR28_AT_THE_END, PINS_PR35S_CELL):
+        return
+    cell = config = None
+    if name == PINS_PR28_AT_THE_END:
+        later |= set(LONGANSWER_STATED)
+        cell = next(iter(LONGANSWER_CELLS.values()))[0]
+        config = cell.split(".")[0]
     monkeypatch.setattr(module, "MANIFEST", {
         **module.MANIFEST,
         "configs": [c for c in module.MANIFEST["configs"]
@@ -133,7 +160,7 @@ def the_manifest_as_it_stood_for_the_test_that_pins_pr28(request,
         "workloads": [w for w in module.MANIFEST["workloads"]
                       if w["name"] != cell],
         "per_layer": [m for m in module.MANIFEST["per_layer"]
-                      if m["name"] not in LONGANSWER_STATED]})
+                      if m["name"] not in later]})
 
 
 @pytest.fixture()

@@ -146,6 +146,9 @@ def engine():
     eng.stop()
 
 
+# What must be IN ``counters()``: a later key is added without a word here
+# (the scheduler's phase sums and the state syncs' counts have their own
+# tests, tests/test_serve_phase_sums.py).
 ENGINE_KEYS = {
     "slots", "queue_delay_sum_s", "queue_delay_n", "host_gap_sum_s",
     "host_gap_n", "preemptions", "requests_shed", "requests_completed",
@@ -162,7 +165,7 @@ ENGINE_CONSTANTS = {"slots", "kv_bytes_per_token", "kv_pool_bytes",
 
 def test_engine_counters_exist_at_construction_and_only_grow(engine):
     before = engine.counters()
-    assert set(before) == ENGINE_KEYS      # before any request
+    assert set(before) >= ENGINE_KEYS      # before any request
     assert all(v == 0 for k, v in before.items()
                if k not in ENGINE_CONSTANTS)
     assert before["slots"] == 4
@@ -175,8 +178,8 @@ def test_engine_counters_exist_at_construction_and_only_grow(engine):
         engine.step()
         snaps.append(engine.counters())
     for a, b in zip(snaps, snaps[1:]):
-        assert set(b) == ENGINE_KEYS
-        assert all(b[k] >= a[k] for k in ENGINE_KEYS)
+        assert set(b) == set(before)       # no key comes or goes with traffic
+        assert all(b[k] >= a[k] for k in b)
     after = snaps[-1]
     assert after["prefill_phase_n"] == 3 and after["prefill_phase_sum_s"] > 0
     # a prompt token is dispatched once, or found in the prefix index (the
@@ -284,8 +287,8 @@ def test_server_counts_first_byte_overhead_and_captures_a_live_replica(
                     params=init_decoder_params(jax.random.PRNGKey(0), cfg))
     d = str(tmp_path / "cap")
     server = ModelServer("m", eng, profile_dir=d)
-    assert server.counters() == {"first_byte_overhead_sum_s": 0.0,
-                                 "first_byte_overhead_n": 0}
+    assert server.counters().items() >= {"first_byte_overhead_sum_s": 0.0,
+                                         "first_byte_overhead_n": 0}.items()
     server.start()
     try:
         # the capture goes where the SERVER was told, whatever a client

@@ -10,14 +10,12 @@ The tiny models and prompts are ``test_serve_chunk_rows``'s. ``dense`` runs at
 program: the budget defers the younger prompt's); the expert kinds at 32,
 where both lanes' chunks ride in the one program a pass may send."""
 
-import contextlib
 
 import pytest
 
-from kubeflow_tpu.serve import engine as engine_mod
 from kubeflow_tpu.serve.engine import SamplingParams
 from test_serve_chunk_rows import (
-    CHUNK, KINDS, _engine, _greedy, _model, _run, _tokens,
+    CHUNK, KINDS, _engine, _greedy, _model, _run, _tokens, record_spans,
 )
 
 GREEDY = SamplingParams(max_new_tokens=4, temperature=0.0)
@@ -49,19 +47,6 @@ def _live_stream(eng, new_tokens: int = 200):
         eng.step()
     assert any(s is not None for s in eng.slots) and not eng._chunkings
     return req
-
-
-def _record_spans(patch) -> list:
-    """The engine's host spans from here on, as (name, attrs)."""
-    seen = []
-
-    @contextlib.contextmanager
-    def span(name, **attrs):
-        seen.append((name, attrs))
-        yield
-
-    patch.setattr(engine_mod, "hot_span", span)
-    return seen
 
 
 def _delta(eng, before: dict) -> dict:
@@ -139,7 +124,7 @@ def test_a_newcomer_is_admitted_in_the_pass_and_its_chunk_rides_the_next(
     eng = _engine(cfg, params, decode_steps=1, prefill_interleave_steps=1)
     assert eng._chunk_rows == 2
     _live_stream(eng)
-    seen = _record_spans(monkeypatch)
+    seen = record_spans(monkeypatch)
     before = eng.counters()
     short = eng.submit(list(map(int, _tokens(4, 20))), GREEDY)
     long = eng.submit(list(map(int, _tokens(5, 3 * CHUNK - 5))), GREEDY)
@@ -198,7 +183,7 @@ def test_a_higher_class_goes_first(kind, monkeypatch):
     urgent = eng.submit(_prompt(kind, 5, 2), GREEDY, qos="interactive")
     before = eng.counters()
     with monkeypatch.context() as patch:
-        seen = _record_spans(patch)
+        seen = record_spans(patch)
         eng._admit()
     a, b = eng._chunkings           # admission order: the batch one is older
     assert (a.request, b.request) == (batch, urgent)

@@ -22,7 +22,7 @@ from kubeflow_tpu.core.serving import BatchingSpec
 from kubeflow_tpu.models import layers as L
 from kubeflow_tpu.models.config import preset
 from kubeflow_tpu.models.decoder import init_decoder_params
-from kubeflow_tpu.serve import engine as engine_mod
+from kubeflow_tpu.obs import profiler
 from kubeflow_tpu.serve.engine import (
     RIDGE_ROWS, LLMEngine, SamplingParams, chunk_rows_per_weight,
 )
@@ -527,6 +527,22 @@ class TestTheRule:
                                        and not overrides)
 
 
+def record_spans(patch) -> list:
+    """The engine's host spans from here on, as (name, attrs): what its
+    phases would write into a capture (obs/profiler.py), with none taken."""
+    seen = []
+
+    class Span(contextlib.nullcontext):
+        def __init__(self, name, **attrs):
+            super().__init__(self)
+            seen.append((name, attrs))
+            self.set_metadata = attrs.update
+
+    patch.setattr(profiler, "hot_span", Span)
+    patch.setattr(profiler, "active", lambda: True)
+    return seen
+
+
 class TestEngineBatchesChunks:
     def test_two_prompts_together_as_each_alone(self, model):
         kind, cfg, params = model
@@ -641,15 +657,8 @@ class TestEngineBatchesChunks:
 
     def test_the_dispatch_span_carries_its_chunks(self, monkeypatch):
         _, cfg, params = _model("dispatch")
-        seen = []
-
-        @contextlib.contextmanager
-        def span(name, **attrs):
-            seen.append((name, attrs))
-            yield
-
         eng = _engine(cfg, params)
-        monkeypatch.setattr(engine_mod, "hot_span", span)
+        seen = record_spans(monkeypatch)
         _greedy(eng, [_tokens(4, 70), _tokens(5, 40)])
         chunks = [attrs["chunks"] for name, attrs in seen
                   if name == "engine.prefill_dispatch"]
