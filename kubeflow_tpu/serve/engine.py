@@ -79,6 +79,9 @@ from kubeflow_tpu.serve.paged import (
     paged_chunk_prefill, paged_decode_multi, pool_bytes_per_token,
     pool_shapes,
 )
+from kubeflow_tpu.serve.weight_layout import (
+    relaid_bytes, relay, weight_formats,
+)
 from kubeflow_tpu.models.config import DecoderConfig
 from kubeflow_tpu.models.decoder import (
     Params, init_decoder_params, plane_kind,
@@ -317,6 +320,12 @@ def _pin2(out, pin):
     the second tuple element) — keeps donated in/out layouts identical so
     GSPMD never re-lays the KV cache between steps in mesh mode."""
     return (out[0], pin(out[1])) + tuple(out[2:])
+
+
+def _committed(tree):
+    """``tree`` with every array committed where it lies (no copy, no
+    program)."""
+    return jax.device_put(tree, jax.tree.map(lambda x: x.sharding, tree))
 
 
 def _row0(out):
@@ -645,6 +654,54 @@ class EngineMetrics:
             return out
 
 
+def serving_configs(cfg: DecoderConfig, b: BatchingSpec):
+    """``(cfg_prefill, cfg_decode)``: the config the chunk programs and the
+    decode programs of an engine over ``cfg`` are built with. They differ
+    only in how a sparse model's experts are reached."""
+    # Serving MoE must be batch-independent: a request's tokens must not
+    # change because co-batched traffic filled an expert's capacity
+    # buffer. Two phases, two resolutions (VERDICT r3 #3):
+    # - PREFILL: capacity drops are a function of the request alone.
+    #   The chunk program may carry several prompts' chunks, and
+    #   takes the dispatch path's capacity and claiming order per row
+    #   (layers._moe_dispatch, capacity_per_row), so a prompt keeps and
+    #   drops what it would alone. The training dispatch path applies
+    #   and WINS the on-chip serving A/B (7.0 vs 6.5 req/s, p50 TTFT
+    #   -15% at mixtral-0.8b p1024).
+    # - DECODE co-batches slots; dispatch is only batch-independent at
+    #   zero-drop capacity (C = k*T). The same A/B measured it a tie
+    #   within session noise, so dense (simpler, drop-free by
+    #   construction) stays the default (bench_serve.py --workload moe).
+    cfg_prefill, cfg_decode = cfg, cfg
+    if cfg.is_moe:
+        pre = b.moe_prefill_impl
+        if pre == "auto":
+            pre = cfg.moe_impl          # the model's training-time path
+        if pre not in ("dispatch", "dense", "sorted"):
+            raise ValueError(
+                f"unknown moe_prefill_impl {b.moe_prefill_impl!r}")
+        cfg_prefill = dataclasses.replace(cfg, moe_impl=pre)
+        dec = b.moe_decode_impl
+        if dec == "auto":
+            # A model whose own path is the drop-free sorted one keeps
+            # it (no capacity, so co-batched slots cannot change each
+            # other); dense for the capacity-dispatch models.
+            dec = "sorted" if cfg.moe_impl == "sorted" else "dense"
+        if dec == "zero_drop":
+            # cf = E caps capacity at k*T: nothing can ever drop, so
+            # outputs are exactly the dense oracle's (tested) while the
+            # buffers stay dispatch-shaped for the A/B.
+            cfg_decode = dataclasses.replace(
+                cfg, moe_impl="dispatch",
+                capacity_factor=float(cfg.num_experts))
+        elif dec in ("dense", "sorted"):
+            cfg_decode = dataclasses.replace(cfg, moe_impl=dec)
+        else:
+            raise ValueError(
+                f"unknown moe_decode_impl {b.moe_decode_impl!r}")
+    return cfg_prefill, cfg_decode
+
+
 class LLMEngine:
     """Slot-based continuous-batching engine over a decoder LLM."""
 
@@ -663,47 +720,7 @@ class LLMEngine:
         self.cfg = cfg
         self.batching = batching or BatchingSpec()
         b = self.batching
-        # Serving MoE must be batch-independent: a request's tokens must not
-        # change because co-batched traffic filled an expert's capacity
-        # buffer. Two phases, two resolutions (VERDICT r3 #3):
-        # - PREFILL: capacity drops are a function of the request alone.
-        #   The chunk program may carry several prompts' chunks, and
-        #   takes the dispatch path's capacity and claiming order per row
-        #   (layers._moe_dispatch, capacity_per_row), so a prompt keeps and
-        #   drops what it would alone. The training dispatch path applies
-        #   and WINS the on-chip serving A/B (7.0 vs 6.5 req/s, p50 TTFT
-        #   -15% at mixtral-0.8b p1024).
-        # - DECODE co-batches slots; dispatch is only batch-independent at
-        #   zero-drop capacity (C = k*T). The same A/B measured it a tie
-        #   within session noise, so dense (simpler, drop-free by
-        #   construction) stays the default (bench_serve.py --workload moe).
-        cfg_prefill, cfg_decode = cfg, cfg
-        if cfg.is_moe:
-            pre = b.moe_prefill_impl
-            if pre == "auto":
-                pre = cfg.moe_impl          # the model's training-time path
-            if pre not in ("dispatch", "dense", "sorted"):
-                raise ValueError(
-                    f"unknown moe_prefill_impl {b.moe_prefill_impl!r}")
-            cfg_prefill = dataclasses.replace(cfg, moe_impl=pre)
-            dec = b.moe_decode_impl
-            if dec == "auto":
-                # A model whose own path is the drop-free sorted one keeps
-                # it (no capacity, so co-batched slots cannot change each
-                # other); dense for the capacity-dispatch models.
-                dec = "sorted" if cfg.moe_impl == "sorted" else "dense"
-            if dec == "zero_drop":
-                # cf = E caps capacity at k*T: nothing can ever drop, so
-                # outputs are exactly the dense oracle's (tested) while the
-                # buffers stay dispatch-shaped for the A/B.
-                cfg_decode = dataclasses.replace(
-                    cfg, moe_impl="dispatch",
-                    capacity_factor=float(cfg.num_experts))
-            elif dec in ("dense", "sorted"):
-                cfg_decode = dataclasses.replace(cfg, moe_impl=dec)
-            else:
-                raise ValueError(
-                    f"unknown moe_decode_impl {b.moe_decode_impl!r}")
+        cfg_prefill, cfg_decode = serving_configs(cfg, b)
         self._cfg_prefill, self._cfg_decode = cfg_prefill, cfg_decode
         self.mesh = mesh if (mesh is not None and mesh.size > 1) else None
         self._refuse_unsupported(cfg, b)
@@ -831,6 +848,29 @@ class LLMEngine:
                 f"unknown paged_attn_impl {b.paged_attn_impl!r}; "
                 "one of auto|gather|pallas")
         self.paged_attn_impl = pattn    # resolved (post-auto) impl
+        # The last step of the load path (behind the cast, the quantizer and
+        # the mesh's placement: an ``astype`` or a ``tree.map`` after it
+        # would drop the layout): the per-head projections lie as both
+        # serving programs read them, so neither copies a weight again
+        # (serve/weight_layout.py). Logical shapes stay.
+        formats = weight_formats(
+            self.params, cfg,
+            one_chip_pallas=(on_tpu and self.mesh is None
+                             and pattn == "pallas"))
+        self.params = relay(self.params, formats)
+        self._weights_relaid_bytes = relaid_bytes(self.params, formats)
+        if self._weights_relaid_bytes:
+            # A relaid leaf is a COMMITTED array (JAX reads a layout off a
+            # committed argument only), and what a program returns is
+            # committed where any of its arguments is: the pool, the decode
+            # state, a chunk's logits. A program called once with a fresh,
+            # uncommitted pool and then with a returned one is lowered
+            # twice, the second time in the middle of traffic (seen on the
+            # chip: the COW copy warmed below, compiled again inside a
+            # measured window). So what the engine allocates starts
+            # committed too, here and at the decode state, and the programs
+            # warmed before traffic are the ones traffic reaches.
+            self.cache = _committed(self.cache)
 
         def _chunk_rows_fn(p, c, t, tr, st, vl, ncp, lr, ai):
             return _pin2(
@@ -1108,6 +1148,9 @@ class LLMEngine:
         # per-slot donated scatters, so steady-state rounds upload nothing
         # (the stats counters prove it).
         self._dstate = DecodeState(self.num_slots, mpp=self._mpp)
+        if self._weights_relaid_bytes:      # as the pool: see the load path
+            self._dstate.arrays, self._dstate.table = _committed(
+                (self._dstate.arrays, self._dstate.table))
         # Pipelined dispatch (double buffering): dispatch round N+1 before
         # consuming round N, keeping at most ONE unconsumed round in flight
         # while the host detokenizes/streams/reaps/admits. Staleness is one
@@ -1189,6 +1232,8 @@ class LLMEngine:
         if self._chunk_rows > 1:
             self._warm_chunk_rows()
         self._warm_decode_ladder()
+        if self._weights_relaid_bytes:
+            self._warm_first_tokens()
 
     def _warm_decode_ladder(self) -> None:
         """Compile and run once, now, the greedy decode program at every
@@ -1202,6 +1247,22 @@ class LLMEngine:
         for k in self._pacer.ladder:
             jax.block_until_ready(
                 self._dispatch_decode(k, "greedy", self._rng))
+
+    def _warm_first_tokens(self) -> None:
+        """Compile and run once, now, the greedy first-token sampler at
+        every width an admit pass can finish prefills in (the powers of two
+        up to the slots), over a logits row as a chunk program of COMMITTED
+        parameters returns it. Which widths traffic reaches depends on how
+        arrivals fall, and a caller that warms them with rows of its own
+        making (uncommitted ones) warms other programs than these. The key
+        is not drawn from."""
+        row = _committed(jnp.zeros((self.cfg.vocab_size,), jnp.float32))
+        greedy = SamplingParams(temperature=0.0)
+        width = 1
+        while width <= self.num_slots:
+            jax.block_until_ready(self._sample_first(
+                [row] * width, [greedy] * width, self._rng))
+            width *= 2
 
     def _warm_chunk_rows(self) -> None:
         """Compile and run once, now, the program over several prompts'
@@ -1359,6 +1420,10 @@ class LLMEngine:
             # page-end tails of the conv layers' state that chunk-prefill
             # programs wrote: one for each page a chunk's tokens touched
             "state_tail_writes": self._state_tail_writes,
+            # a constant: bytes of the parameters held in another layout
+            # than the default, laid out once at load as the programs read
+            # them (serve/weight_layout.py)
+            "weights_relaid_bytes": self._weights_relaid_bytes,
         }
 
     def queue_depth(self) -> int:
@@ -1623,32 +1688,38 @@ class LLMEngine:
         self._rng, k = jax.random.split(self._rng)
         return k
 
+    def _sample_first(self, rows: list, sampling: list, key) -> jax.Array:
+        """ONE sampler dispatch over the last logits rows of ``len(rows)``
+        prefills, stacked and padded to the next power of two so the
+        sampler trace set stays log-bounded: their first tokens (and the
+        padding's), on the device."""
+        n = len(rows)
+        width = 1
+        while width < n:
+            width *= 2
+        padded = sampling + [SamplingParams()] * (width - n)
+        return self._sampler(
+            jnp.stack(rows + [rows[-1]] * (width - n)), key,
+            jnp.asarray([p.temperature for p in padded], jnp.float32),
+            jnp.asarray([p.top_k for p in padded], jnp.int32),
+            jnp.asarray([p.top_p for p in padded], jnp.float32),
+            _mode_for(sampling))
+
     def _flush_first_tokens(self) -> None:
         """ONE sampler dispatch + ONE host fetch for the first tokens of
         every prefill the admit pass finished (``_pending_first``, which
         keeps their slots reserved until here), then admit each request
         into its slot: a ``device_get`` a request would serialize every
-        admission behind it. The rows stack here, padded to the next power
-        of two so the sampler trace set stays log-bounded."""
+        admission behind it."""
         if not self._pending_first:
             return
         items, self._pending_first = self._pending_first, []
         n = len(items)
         with self._phase(prof.ENGINE_SAMPLE_FIRST,
                          prof.active() and {"n": n}):
-            width = 1
-            while width < n:
-                width *= 2
-            stacked = jnp.stack(
-                [it[3] for it in items] + [items[-1][3]] * (width - n))
-            params_list = [it[0].params for it in items]
-            padded = params_list + [SamplingParams()] * (width - n)
-            firsts = self._sampler(
-                stacked, self._next_key(),
-                jnp.asarray([p.temperature for p in padded], jnp.float32),
-                jnp.asarray([p.top_k for p in padded], jnp.int32),
-                jnp.asarray([p.top_p for p in padded], jnp.float32),
-                _mode_for(params_list))
+            firsts = self._sample_first(
+                [it[3] for it in items], [it[0].params for it in items],
+                self._next_key())
             # The fetch below blocks until the prefill is done, and that
             # queues behind the decode round in flight: the round's tokens
             # are ready first. They go out before the wait, not after it,

@@ -127,6 +127,7 @@ def paged_serve_analysis(topology: str, tp: int, *, model: str,
     from kubeflow_tpu.runtime.device_report import kernel_calls
     from kubeflow_tpu.serve.paged import (
         paged_chunk_prefill, paged_decode_multi, pool_shapes)
+    from kubeflow_tpu.serve.weight_layout import relay, weight_formats
 
     mesh = _mesh_on(topology, {"model": tp}, topo_kwargs=topo_kwargs)
     cfg = preset(model, **overrides)
@@ -155,6 +156,10 @@ def paged_serve_analysis(topology: str, tp: int, *, model: str,
     params_sds = jax.tree.map(
         lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
         params_sds, psh)
+    # The last step of the engine's load path: on one chip with the Pallas
+    # kernels the per-head projections lie as the programs read them.
+    params_sds = relay(params_sds, weight_formats(
+        params_sds, cfg, one_chip_pallas=tp == 1 and attn_impl == "pallas"))
 
     def sds(shape, dtype, sh=rep):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)

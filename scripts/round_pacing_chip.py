@@ -191,6 +191,13 @@ def _tail(record: dict) -> None:
              f"{1e3 * sum(durs) / len(durs):.3f} ms (median "
              f"{1e3 * durs[len(durs) // 2]:.3f}, max {1e3 * durs[-1]:.3f}) "
              f"= {sum(durs):.4f} s")
+        # one name, several programs (the chunk program beside the table's
+        # row uploads, all ``jit__lambda``): the long ones apart
+        long = [d for d in durs if d >= 2e-3]
+        if long and len(long) < len(durs):
+            _log(f"tail program {name} of 2 ms or more: {len(long)} x median "
+                 f"{1e3 * long[len(long) // 2]:.3f} ms (min "
+                 f"{1e3 * long[0]:.3f}, max {1e3 * long[-1]:.3f})")
     names: dict = {}
     lengths: dict = {}
     for thread in record.get("host_spans") or []:
@@ -377,6 +384,8 @@ def main() -> int:
             d["prefill_programs_a_pass"] = \
                 d["prefill_programs_dispatched"] / d["prefill_passes"]
         _log(f"window counters: {json.dumps(d, sort_keys=True)}")
+        # ISSUE 39: a constant of the engine's load path (the parent has none)
+        _log(f"weights_relaid_bytes: {b.get('weights_relaid_bytes')}")
         if "server" in snapshots[0]:
             a, b = snapshots[0]["server"], snapshots[1]["server"]
             _log("window server counters: " + json.dumps(

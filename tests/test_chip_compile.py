@@ -334,3 +334,107 @@ def test_chunk_program_compiles_for_v5e_in_place(chip, cell, monkeypatch):
     # by a chunk, twice over (the copy and the copy as written).
     assert temps < min(layer_pool, 2 * cfg.n_layers * context), temps
     assert temps < logits + 256 * 2 ** 20, (temps, logits)
+
+
+# -- the serving cells' programs copy no weight before they use it ---------------
+
+# cell -> the parameters whose copy stands on the parent too and that no
+# layout of this repo's removes: named so that nothing NEW can join them.
+# GLM's decode program lays its 14 MB `wkva` stack out again a dispatch;
+# LFM2's programs their 2 MB router stack. `Layout.AUTO` prefers another
+# layout for both in every program (scripts/aot_weight_copies.py --auto);
+# whether that is worth anything on the chip is open (PERF.md section 7).
+SERVING_CELLS = {
+    "mistral-7b.chat-open": set(),
+    "mixtral-8x7b.batch-longprompt": set(),
+    "glm-4.7-flash.batch-longcontext": {"['layers']['attn']['wkva']"},
+    "lfm2-24b-a2b.batch-longanswer": {"['layers']['mlp']['router']"},
+}
+# program -> the Mosaic kernel its attention goes through, a cell's family
+ATTENTION_KERNELS = {
+    "mistral-7b.chat-open": ("paged_decode_attention",
+                             "paged_chunk_attention"),
+    "mixtral-8x7b.batch-longprompt": ("paged_decode_attention",
+                                      "paged_chunk_attention"),
+    "glm-4.7-flash.batch-longcontext": ("paged_latent_decode_attention",
+                                        "paged_latent_chunk_attention"),
+    "lfm2-24b-a2b.batch-longanswer": ("paged_packed_decode_attention",
+                                      None),      # packed rows stay gathered
+}
+
+
+@pytest.fixture(scope="module")
+def cell_programs(chip):
+    """``(cell, relaid) -> {program: Lowered}``, each cell lowered once a
+    module: the benchmark's sizes (configuration and traffic files), the
+    engine's own construction (scripts/aot_weight_copies.py), the parameters
+    in the formats the engine's function returns or all default."""
+    import functools
+
+    from scripts.aot_weight_copies import lowered_programs, serving_cell
+
+    @functools.lru_cache(maxsize=None)
+    def lowered(cell: str, relaid: bool = True) -> dict:
+        cfg, batching = serving_cell(cell)
+        with pytest.MonkeyPatch.context() as mp:    # as benchmark/aot_sizes.py
+            mp.setattr(jax, "default_backend", lambda: "tpu")
+            return lowered_programs(cfg, batching, chip, relaid=relaid)
+
+    return lowered
+
+
+# A dense model at 512 tokens a chunk: the engine builds no program over
+# several prompts' rows (``chunk_rows_per_weight``), so the chat cell has two.
+SERVING_PROGRAMS = [
+    (cell, program) for cell in sorted(SERVING_CELLS)
+    for program in ("decode", "chunk[1]", "chunk[2]")
+    if (cell, program) != ("mistral-7b.chat-open", "chunk[2]")]
+
+
+@pytest.mark.parametrize("cell,program", SERVING_PROGRAMS)
+def test_serving_program_copies_no_weight_on_v5e(cell_programs, cell,
+                                                 program):
+    """At the cell's real sizes, with the parameters as the engine holds
+    them: the compiled program has no ``copy`` the size of a parameter (or
+    of a layer's slice of one) of a million elements or more, beyond the
+    cell's standing ones, and its attention is still the Mosaic kernel. The
+    chat and batch cases fail on a tree whose per-head projections lie in
+    the default layout (``copy.19`` / ``.18`` / ``.20`` of the chat cell's
+    decode program: 0.8 GB a dispatch); the GLM and LFM2 cases pin that the
+    rule changes nothing there."""
+    from scripts.aot_weight_copies import weight_copies
+
+    programs = cell_programs(cell)
+    assert sorted(programs) == sorted(
+        p for c, p in SERVING_PROGRAMS if c == cell)
+    lowered = programs[program]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    copied = {leaf for c in weight_copies(text, lowered.args_info[0][0])
+              for leaf in c["leaf"]}
+    assert copied <= SERVING_CELLS[cell], copied
+    kernel = ATTENTION_KERNELS[cell][program != "decode"]
+    assert "tpu_custom_call" in text
+    assert kernel is None or kernel in text, kernel
+    if cell == "mistral-7b.chat-open" and program == "decode":
+        # the hoisted copies were the program's temporaries: 0.806 GB
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_the_detector_finds_the_copies_of_default_layouts(cell_programs):
+    """The guard's own guard: with every parameter in the compiler's default
+    layout the chat cell's decode program copies all three per-head
+    projections, whole, and the reader of the compiled text says so. (A
+    change of the text's format that blinded the reader would pass every
+    case above.)"""
+    from scripts.aot_weight_copies import weight_copies
+
+    lowered = cell_programs("mistral-7b.chat-open", False)["decode"]
+    compiled = lowered.compile()
+    copies = [c for c in weight_copies(compiled.as_text(),
+                                       lowered.args_info[0][0]) if c["leaf"]]
+    assert sorted(c["shape"] for c in copies) == [
+        [16, 4096, 8, 128], [16, 4096, 8, 128], [16, 4096, 32, 128]]
+    assert {leaf for c in copies for leaf in c["leaf"]} == {
+        f"['layers']['attn']['{n}']" for n in ("wq", "wk", "wv")}
+    assert compiled.memory_analysis().temp_size_in_bytes > 800e6

@@ -158,9 +158,9 @@ ENGINE_KEYS = {
     "sched_host_busy_sum_s", "prefill_programs_dispatched", "prefill_chunks_dispatched",
     "prefill_tokens_dispatched", "prefill_passes", "prefill_chunks_deferred",
     "kv_bytes_per_token", "kv_pool_bytes", "state_pool_bytes",
-    "state_tail_writes"}
+    "state_tail_writes", "weights_relaid_bytes"}
 ENGINE_CONSTANTS = {"slots", "kv_bytes_per_token", "kv_pool_bytes",
-                    "state_pool_bytes"}
+                    "state_pool_bytes", "weights_relaid_bytes"}
 
 
 def test_engine_counters_exist_at_construction_and_only_grow(engine):
