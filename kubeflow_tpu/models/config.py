@@ -1,8 +1,10 @@
 """Decoder model configuration + the preset zoo.
 
-Presets cover the BASELINE.json configs: Llama-3-8B (training + serving
-flagship), Gemma-2B (HPO sweeps), Mixtral-8x7B (expert parallel), plus tiny
-variants for tests. Architecture facts are from the public model papers/cards.
+Presets cover the BASELINE.json configs (Llama-3-8B, Gemma-2B, Mixtral-8x7B)
+and the models the benchmark serves at their published widths (GLM-4.7-Flash,
+LFM2-24B-A2B, K-EXAONE-236B-A23B), plus tiny variants of each structure for
+tests. Architecture facts are from the public model cards and ``config.json``
+files.
 """
 
 from __future__ import annotations
@@ -57,6 +59,15 @@ class DecoderConfig:
     router_norm_topk: bool = True
     router_scale: float = 1.0
     router_norm_eps: float = 1e-20   # beside the sum the weights divide by
+    # One chip's share of an expert-parallel group: the layer HOLDS
+    # ``experts_held`` of the ``num_experts`` the router scores (0 = all of
+    # them), the experts ``expert_offset ..``. The router's matrix and bias
+    # keep ``num_experts`` outputs, the top-k and its normalisation run over
+    # all of them, and only the rows routed to a held expert are computed
+    # (``layers._moe_sorted``); what the other chips' experts would add is
+    # left out, the shared expert runs whole.
+    experts_held: int = 0
+    expert_offset: int = 0
     # Latent attention (MLA; kv_lora_rank > 0): queries through a
     # ``q_lora_rank`` bottleneck, keys and values expanded per head from one
     # ``kv_lora_rank`` latent row a token, beside ``qk_rope_dim`` rotary
@@ -68,13 +79,29 @@ class DecoderConfig:
     qk_rope_dim: int = 0
     v_head_dim: int = 0
     # The stack's pattern: the kind of layer ``i`` is ``layer_kinds[i %
-    # len(layer_kinds)]``, "attention" or "conv" (() = every layer
-    # attention). A "conv" layer's operator is the gated short convolution
+    # len(layer_kinds)]``, "attention", "window" or "conv" (() = every layer
+    # attention). A "window" layer is attention whose query ``i`` sees the
+    # keys ``i - attn_window < j <= i`` (itself among ``attn_window``), with
+    # parameters and cache planes of its own kind; ``rope_window_only``: only
+    # the window layers rotate q and k (the global layers carry no position).
+    # A "conv" layer's operator is the gated short convolution
     # (layers.conv_block): a causal depthwise convolution of ``conv_taps``
     # taps over time between two elementwise gates, whose state is the last
     # ``conv_taps - 1`` gated rows of a sequence. ``qk_norm``: an RMSNorm
     # over each head's values of q and k, before RoPE.
     layer_kinds: tuple = ()
+    attn_window: int = 0
+    rope_window_only: bool = False
+    # Serving: the pages a sequence keeps in a window layer of the page pool,
+    # a ring over its first pages (serve/paged.py::ring_table). The engine
+    # sets it from its chunk and page sizes (``engine.serving_configs``,
+    # ``paged.ring_pages``) and nobody else does. It rides here because a
+    # program's config is all a caller of the paged programs hands them of
+    # the engine (the benchmark's correctness seam passes
+    # ``engine._cfg_decode`` and one table row: no slot count to derive it
+    # from). 0: a window layer keeps every page, like a global one
+    # (``ring_table`` over the whole row); an engine's pool refuses it.
+    window_ring_pages: int = 0
     conv_taps: int = 3
     qk_norm: bool = False
     # One ``n_kv_heads * head_dim`` row a token for K and one for V in the
@@ -108,9 +135,18 @@ class DecoderConfig:
     def __post_init__(self):
         # A configuration file's list (JSON has no tuple) stays hashable.
         object.__setattr__(self, "layer_kinds", tuple(self.layer_kinds))
-        unknown = set(self.layer_kinds) - {"attention", "conv"}
+        unknown = set(self.layer_kinds) - {"attention", "window", "conv"}
         if unknown:
             raise ValueError(f"unknown layer kinds {sorted(unknown)}")
+        if "window" in self.layer_kinds and self.attn_window <= 0:
+            raise ValueError("window layers need attn_window > 0")
+        if self.experts_held and self.num_experts and not (
+                0 <= self.expert_offset
+                and self.expert_offset + self.experts_held
+                <= self.num_experts):
+            raise ValueError(
+                f"experts {self.expert_offset}..+{self.experts_held} held "
+                f"of {self.num_experts}")
 
     @property
     def period(self) -> tuple:
@@ -154,6 +190,11 @@ class DecoderConfig:
     def expert_mlp_dim(self) -> int:
         return self.moe_mlp_dim or self.mlp_dim
 
+    @property
+    def experts_here(self) -> int:
+        """The experts whose weights this layer holds."""
+        return self.experts_held or self.num_experts
+
     def _conv_params(self) -> int:
         """One conv block's operator: in and out projections and the taps."""
         d = self.hidden
@@ -175,24 +216,31 @@ class DecoderConfig:
     def _operator_params(self, first: int, last: int) -> int:
         """The operators (attention or convolution) of layers [first, last)."""
         kinds = self.kinds[first:last]
-        return kinds.count("attention") * self._attn_params() \
-            + kinds.count("conv") * self._conv_params()
+        return (kinds.count("attention") + kinds.count("window")) \
+            * self._attn_params() + kinds.count("conv") * self._conv_params()
 
     def _mlp_params(self, active: bool) -> int:
         """One expert layer's (or, dense, one MLP's) matrices; ``active``
-        counts the experts one token multiplies against, not those held."""
+        counts the experts one token multiplies against HERE (of its
+        ``experts_per_token`` choices the expected share that falls on a held
+        expert, ``experts_here / num_experts`` of them), not those held."""
         d = self.hidden
         if not self.is_moe:
             return 3 * d * self.mlp_dim
         per_expert = 3 * d * self.expert_mlp_dim
         if active:      # the router's small product is left out, as before
-            return (self.experts_per_token + self.shared_experts) * per_expert
+            met = self.experts_per_token * self.experts_here \
+                / self.num_experts
+            return int((met + self.shared_experts) * per_expert)
         routing = d * self.num_experts + (
             self.num_experts if self.router_score == "sigmoid" else 0)
-        return (self.num_experts + self.shared_experts) * per_expert + routing
+        return (self.experts_here + self.shared_experts) * per_expert \
+            + routing
 
     def num_params(self) -> int:
-        """Parameter count (embedding included once if tied)."""
+        """Parameters HELD (embedding included once if tied): of an expert
+        layer the ``experts_here`` experts this chip keeps, beside the whole
+        router and the shared expert."""
         d, v = self.hidden, self.vocab_size
         k = self.leading_dense_layers
         layers = self._operator_params(0, self.n_layers) \
@@ -203,7 +251,9 @@ class DecoderConfig:
 
     def flops_per_token(self) -> float:
         """Approximate training FLOPs/token (fwd+bwd ≈ 6N for dense; MoE
-        counts only active experts)."""
+        counts only the experts a token meets HERE: all its choices where
+        every expert is held, the expected share of them on a chip that holds
+        ``experts_held``)."""
         d = self.hidden
         k = self.leading_dense_layers
         dense_n = self._operator_params(0, self.n_layers) \
@@ -266,6 +316,22 @@ PRESETS: dict[str, DecoderConfig] = {
         layer_kinds=("conv", "conv", "attention", "conv"), conv_taps=3,
         qk_norm=True, kv_heads_packed=True,
     ),
+    # K-EXAONE-236B-A23B (LGAI-EXAONE config.json, model_type exaone_moe:
+    # 48L, 6144h, 64/8 heads of 128 with per-head q/k norms; layers follow
+    # "LLLG": three window layers (128 keys, rotated) then one global layer
+    # (every key, no rotation); one dense layer of 18432 then 128
+    # sigmoid-routed experts of 2048, top-8, beside one shared expert,
+    # weights scaled 2.5; untied head)
+    "k-exaone-236b-a23b": DecoderConfig(
+        vocab_size=153600, hidden=6144, n_layers=48, n_heads=64,
+        n_kv_heads=8, head_dim=128, mlp_dim=18432, max_seq_len=262144,
+        rope_theta=1000000.0, num_experts=128, experts_per_token=8,
+        moe_impl="sorted", moe_mlp_dim=2048, shared_experts=1,
+        leading_dense_layers=1, router_score="sigmoid",
+        router_norm_topk=True, router_scale=2.5,
+        layer_kinds=("window", "window", "window", "attention"),
+        attn_window=128, rope_window_only=True, qk_norm=True,
+    ),
     # tiny variants for tests/sim (structure-faithful, sized for 1 CPU core)
     "tiny": DecoderConfig(
         vocab_size=256, hidden=64, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -303,6 +369,19 @@ PRESETS: dict[str, DecoderConfig] = {
         layer_kinds=("conv", "attention", "conv", "conv", "conv",
                      "attention", "conv", "conv", "conv"),
         conv_taps=3, qk_norm=True, kv_heads_packed=True,
+    ),
+    # K-EXAONE's structure as one chip of four holds it: a leading dense
+    # window layer, then window, window, global, window over 16
+    # sigmoid-routed experts top-4 of which 4 are held, beside a shared one;
+    # a window of 24 (longer than the tests' page of 16; they also run 8)
+    "tiny-exaone": DecoderConfig(
+        vocab_size=256, hidden=64, n_layers=5, n_heads=4, n_kv_heads=2,
+        head_dim=16, mlp_dim=160, max_seq_len=256, num_experts=16,
+        experts_per_token=4, moe_impl="sorted", moe_mlp_dim=48,
+        shared_experts=1, leading_dense_layers=1, router_score="sigmoid",
+        router_norm_topk=True, router_scale=2.5, experts_held=4,
+        layer_kinds=("window", "window", "window", "attention", "window"),
+        attn_window=24, rope_window_only=True, qk_norm=True,
     ),
 }
 

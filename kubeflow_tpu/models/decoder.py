@@ -26,13 +26,23 @@ Params = dict[str, Any]
 
 
 # A layer's operator, by its kind: the block's key in the parameter tree and
-# the cache planes it holds (every plane not named here is attention's).
-OPERATOR = {"attention": "attn", "conv": "conv"}
-CONV_PLANES = ("conv",)
+# the cache planes it holds (every plane not named here is attention's). A
+# window layer's operator has an attention layer's leaves under a key of its
+# own, and K/V planes of its own (a pool keeps a bounded ring of them a
+# sequence where a global layer keeps every page, serve/paged.py).
+OPERATOR = {"attention": "attn", "window": "window", "conv": "conv"}
+# a window layer's plane -> the name attention knows it by
+WINDOW_PLANES = {"window_k": "k", "window_v": "v"}
+PLANE_KINDS = {"conv": "conv", **dict.fromkeys(WINDOW_PLANES, "window")}
 
 
 def plane_kind(name: str) -> str:
-    return "conv" if name in CONV_PLANES else "attention"
+    return PLANE_KINDS.get(name, "attention")
+
+
+def block_kind(bp: dict) -> str:
+    """A block's kind, read off its parameters: the operator it holds."""
+    return next(kind for kind, key in OPERATOR.items() if key in bp)
 
 
 def _init_operator(key, cfg: DecoderConfig, kind: str):
@@ -240,6 +250,18 @@ def _block_forward(block_params, x, positions, cfg: DecoderConfig,
             block_params["conv"], h, cfg,
             None if kv_cache is None else kv_cache["conv"])
         new_cache = None if kv_cache is None else {"conv": zs}
+    elif "window" in block_params:
+        # Attention over the last ``attn_window`` keys; its cache planes are
+        # its own kind's, which attention takes under its names.
+        if kv_cache is not None:
+            kv_cache = {WINDOW_PLANES.get(n, n): a
+                        for n, a in kv_cache.items()}
+        attn_out, new_cache = L.attention_block(
+            block_params["window"], h, positions, cfg,
+            kv_cache=kv_cache, attn_impl=attn_impl, mesh=mesh,
+            tp_axis=tp_axis, lora=lora, window=cfg.attn_window)
+        if new_cache is not None:
+            new_cache = {n: new_cache[a] for n, a in WINDOW_PLANES.items()}
     else:
         attn_out, new_cache = L.attention_block(
             block_params["attn"], h, positions, cfg,

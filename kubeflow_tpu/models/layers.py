@@ -201,18 +201,22 @@ def init_attention(key, cfg: DecoderConfig):
 
 
 def qk_rope(p: dict, q: jax.Array, k: jax.Array, positions: jax.Array,
-            cfg: DecoderConfig):
+            cfg: DecoderConfig, window: int = 0):
     """Queries and keys [B,S,H,Dh] as attention takes them: each head's
     values through its RMSNorm where the model has one (``qk_norm``), then
-    RoPE. One function for the forward pass and the paged programs."""
+    RoPE. One function for the forward pass and the paged programs.
+    ``window``: the layer's window (0: a global layer), which decides
+    whether it rotates at all where ``rope_window_only``."""
     if cfg.qk_norm:
         q = rmsnorm(q, p["q_norm"], cfg)
         k = rmsnorm(k, p["k_norm"], cfg)
+    if cfg.rope_window_only and not window:
+        return q, k
     return rope(q, positions, cfg.rope_theta), \
         rope(k, positions, cfg.rope_theta)
 
 
-def _cached_attention_by_row(q, k, v, kv_cache: dict):  # traced
+def _cached_attention_by_row(q, k, v, kv_cache: dict, window: int = 0):  # traced
     """The cache path for ONE START A ROW (``kv_cache["len"]`` [B]: the
     serving chunk of several prompts, each at its own position). Every row
     writes its K/V at its own start and attends as it would alone: a row's
@@ -243,7 +247,7 @@ def _cached_attention_by_row(q, k, v, kv_cache: dict):  # traced
     def attend(span):
         return lambda qr, kr, vr, at: multi_head_attention(
             qr, kr[:, :span], vr[:, :span], causal=True, q_offset=at,
-            impl="xla")
+            impl="xla", window=window)
 
     branches = [attend(s) for s in spans]
     out = []
@@ -268,8 +272,13 @@ def attention_block(
     prefill: bool = False,              # static: cache start is known to be 0
     tp_axis: Optional[str] = None,      # inside shard_map: heads sharded here
     lora: Optional[dict] = None,        # per-layer adapter view (apply_lora_layer)
+    window: int = 0,                    # static: a window layer's length
 ):
     """Returns (out [B,S,D], new_kv_cache|None).
+
+    ``window`` > 0: a window layer, whose query ``i`` sees keys ``i - window
+    < j <= i``; it takes the XLA attention (the flash kernel and the
+    sequence-parallel forms have no lower bound on the keys).
 
     ``tp_axis`` (Megatron-style TP inside shard_map — the pipeline×TP
     composition): ``wq/wk/wv/wo`` hold this device's head shard, attention
@@ -297,14 +306,19 @@ def attention_block(
     # Names feed the "block_outs" remat policy: saving post-rope Q/K/V plus
     # the block outputs skips reprojecting + re-rotating in the backward
     # while staying far under dots_no_batch's save footprint.
-    q, k = qk_rope(p, q, k, positions, cfg)
+    q, k = qk_rope(p, q, k, positions, cfg, window)
     q = checkpoint_name(q, "q_rope")
     k = checkpoint_name(k, "k_rope")
     v = checkpoint_name(v, "v_proj")
+    if window:
+        if attn_impl not in ("xla", "pallas"):
+            raise NotImplementedError(
+                f"a window layer under attn_impl={attn_impl!r}")
+        attn_impl = "xla"
 
     new_cache = None
     if kv_cache is not None and jnp.ndim(kv_cache["len"]):
-        out, new_cache = _cached_attention_by_row(q, k, v, kv_cache)
+        out, new_cache = _cached_attention_by_row(q, k, v, kv_cache, window)
     elif kv_cache is not None:
         # Contiguous cache decode path: write new K/V at position `len`.
         start = kv_cache["len"]
@@ -334,7 +348,7 @@ def attention_block(
                                           "ulysses") else attn_impl
             out = multi_head_attention(
                 q, ck, cv, causal=True, q_offset=start, impl=impl,
-            )
+                window=window)
     elif attn_impl in ("ring", "ring_flash", "ulysses"):
         # Sequence-parallel attention over the mesh 'seq' axis (SURVEY.md
         # §2.6 SP/CP rows). Degenerates to XLA attention when the mesh has
@@ -376,7 +390,8 @@ def attention_block(
 
         out = flash_sharded_or_xla(q, k, v, mesh, causal=True)
     else:
-        out = multi_head_attention(q, k, v, causal=True, impl=attn_impl)
+        out = multi_head_attention(q, k, v, causal=True, impl=attn_impl,
+                                   window=window)
     proj = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
     if lora is not None and "wo" in lora["targets"]:
         b, s = out.shape[0], out.shape[1]
@@ -621,11 +636,12 @@ def mlp_block(p: dict, x: jax.Array, cfg: DecoderConfig,
 def init_moe(key, cfg: DecoderConfig):
     kr, kg, ku, kd = jax.random.split(key, 4)
     d, m, e = cfg.hidden, cfg.expert_mlp_dim, cfg.num_experts
+    eh = cfg.experts_here       # the router scores all, the stack holds these
     params = {
         "router": _init(kr, (d, e), cfg.weight_dtype),
-        "gate": _init(kg, (e, d, m), cfg.weight_dtype, scale=d ** -0.5),
-        "up": _init(ku, (e, d, m), cfg.weight_dtype, scale=d ** -0.5),
-        "down": _init(kd, (e, m, d), cfg.weight_dtype, scale=m ** -0.5),
+        "gate": _init(kg, (eh, d, m), cfg.weight_dtype, scale=d ** -0.5),
+        "up": _init(ku, (eh, d, m), cfg.weight_dtype, scale=d ** -0.5),
+        "down": _init(kd, (eh, m, d), cfg.weight_dtype, scale=m ** -0.5),
     }
     specs = {
         "router": ("embed", None),
@@ -707,7 +723,8 @@ def moe_block(p: dict, x: jax.Array, cfg: DecoderConfig,
               valid_len: Optional[jax.Array] = None,
               tp_axis: Optional[str] = None,
               expert_stack: Optional[tuple] = None,
-              capacity_per_row: bool = False):
+              capacity_per_row: bool = False,
+              rows_out: bool = False):
     """Top-k MoE (Mixtral semantics: softmax over the selected k logits).
 
     Dispatches on ``cfg.moe_impl``: "dispatch" (default) routes tokens into
@@ -736,7 +753,18 @@ def moe_block(p: dict, x: jax.Array, cfg: DecoderConfig,
     it): the dispatch path's capacity and claiming order are taken within
     each row of ``x`` and not over the whole block, so a row keeps and drops
     what it would alone (``_moe_dispatch``). The other paths have no
-    capacity and ignore it."""
+    capacity and ignore it.
+
+    A layer that holds a SHARE of its experts (``cfg.experts_held``) is the
+    sorted path's alone. ``rows_out`` (static): a third result, int32 [2]:
+    the (token, choice) rows this call routed and those of them whose expert
+    is held here (the serving programs sum them: serve/paged.py)."""
+    if cfg.experts_held and cfg.moe_impl != "sorted":
+        raise NotImplementedError(
+            f"experts_held={cfg.experts_held} of {cfg.num_experts} under "
+            f"moe_impl={cfg.moe_impl!r}: only the sorted path computes a "
+            "share")
+    rows = None
     if cfg.moe_impl == "dispatch":
         out, aux = _moe_dispatch(p, x, cfg, expert_axis=expert_axis,
                                  seq_axis=seq_axis, valid_len=valid_len,
@@ -747,8 +775,8 @@ def moe_block(p: dict, x: jax.Array, cfg: DecoderConfig,
             raise NotImplementedError(
                 "moe_impl 'sorted' inside a pipeline stage's shard_map "
                 "(expert or tensor parallel)")
-        out, aux = _moe_sorted(p, x, cfg, seq_axis=seq_axis,
-                               expert_stack=expert_stack)
+        out, aux, rows = _moe_sorted(p, x, cfg, seq_axis=seq_axis,
+                                     expert_stack=expert_stack)
     elif cfg.moe_impl == "dense":
         out, aux = _moe_dense(p, x, cfg, expert_axis=expert_axis,
                               seq_axis=seq_axis, tp_axis=tp_axis)
@@ -757,6 +785,10 @@ def moe_block(p: dict, x: jax.Array, cfg: DecoderConfig,
     if cfg.shared_experts:
         # Every token, beside whatever it was routed to.
         out = out + mlp_block(p["shared"], x, cfg, tp_axis=tp_axis)
+    if rows_out:
+        if rows is None:        # every expert held, every routed row computed
+            rows = (x.shape[0] * x.shape[1] * cfg.experts_per_token,) * 2
+        return out, aux, jnp.stack([jnp.asarray(n, jnp.int32) for n in rows])
     return out, aux
 
 
@@ -960,22 +992,41 @@ def _moe_sorted(p: dict, x: jax.Array, cfg: DecoderConfig,
     serving chunk are routed and computed like any other; they displace
     nothing. With ``expert_stack`` the weights are the whole group's,
     viewed [L*E, ...] (a bitcast), and this layer's experts are the groups
-    from ``layer*E`` on; every other group is empty."""
+    from ``layer*E`` on; every other group is empty.
+
+    A layer that holds a share (``cfg.experts_held`` experts from
+    ``cfg.expert_offset`` on: one chip of an expert-parallel group) routes
+    over ALL ``num_experts`` and computes its own experts' part: a row whose
+    expert lies elsewhere sorts behind every held group and belongs to none,
+    so it costs no matrix work (the grouped matmul walks the groups' rows
+    only) and adds nothing; however uneven the routing, every row of a held
+    expert is computed. Returns (out, aux, int32 [2]: rows routed, rows
+    held)."""
     dt = cfg.activation_dtype
     b, s, d = x.shape
     e, k = cfg.num_experts, cfg.experts_per_token
+    eh = cfg.experts_here
     t = b * s
     xf = x.reshape(t, d)
     router_logits, topk_idx, topk_w = route(p, xf, cfg)              # [T,k]
+    held, n_held = None, t * k
     flat_e = topk_idx.reshape(-1)                                    # [Tk]
+    if eh != e:
+        local = topk_idx - cfg.expert_offset
+        held = (local >= 0) & (local < eh)                           # [T,k]
+        # a row held elsewhere: group ``eh``, behind every held group
+        flat_e = jnp.where(held, local, eh).reshape(-1)
+        n_held = jnp.sum(held, dtype=jnp.int32)
     order = jnp.argsort(flat_e, stable=True)
-    sizes = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
+    sizes = jnp.zeros((e,), jnp.int32).at[flat_e].add(1) if held is None \
+        else jnp.zeros((eh + 1,), jnp.int32).at[flat_e].add(1)[:eh]
     w = p
     if expert_stack is not None:
         stack, layer = expert_stack
         w = {n: a.reshape(-1, *a.shape[2:]) for n, a in stack.items()}
         sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros((w["gate"].shape[0],), jnp.int32), sizes, (layer * e,))
+            jnp.zeros((w["gate"].shape[0],), jnp.int32), sizes,
+            (layer * eh,))
     rows = jnp.take(xf, order // k, axis=0)                          # [Tk,D]
     gate = _act(grouped_matmul(rows, w["gate"].astype(dt), sizes, cfg),
                 cfg.hidden_act)
@@ -986,12 +1037,16 @@ def _moe_sorted(p: dict, x: jax.Array, cfg: DecoderConfig,
     inv = jnp.zeros((t * k,), jnp.int32).at[order].set(
         jnp.arange(t * k, dtype=jnp.int32))
     back = jnp.take(y, inv, axis=0).reshape(t, k, d)
+    if held is not None:
+        # Rows behind the groups were never computed: whatever lies there
+        # (a kernel leaves it unwritten) must not reach the sum.
+        back = jnp.where(held[..., None], back, 0)
     out = jnp.einsum("tkd,tk->td", back, topk_w.astype(dt)).reshape(b, s, d)
     aux = _moe_aux_loss(
         router_logits.reshape(b, s, e),
         jax.nn.one_hot(topk_idx, e, dtype=jnp.float32).sum(-2).reshape(
             b, s, e), cfg, seq_axis)
-    return checkpoint_name(out, "mlp_out"), aux
+    return checkpoint_name(out, "mlp_out"), aux, (t * k, n_held)
 
 
 def _moe_dense(p: dict, x: jax.Array, cfg: DecoderConfig,

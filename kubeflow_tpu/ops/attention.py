@@ -25,11 +25,15 @@ def _repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
     return jnp.broadcast_to(k[:, :, :, None, :], (b, s, h, n_rep, d)).reshape(b, s, h * n_rep, d)
 
 
-def causal_mask(q_len: int, kv_len: int, *, q_offset: jax.Array | int = 0) -> jax.Array:
+def causal_mask(q_len: int, kv_len: int, *, q_offset: jax.Array | int = 0,
+                window: int = 0) -> jax.Array:
     """[q_len, kv_len] boolean mask; True = attend. ``q_offset`` is the
-    absolute position of query 0 (for decode with a KV cache)."""
+    absolute position of query 0 (for decode with a KV cache). ``window``
+    > 0: a query sees its last ``window`` keys only, itself among them."""
     q_pos = jnp.arange(q_len)[:, None] + q_offset
     kv_pos = jnp.arange(kv_len)[None, :]
+    if window:
+        return (kv_pos <= q_pos) & (kv_pos > q_pos - window)
     return kv_pos <= q_pos
 
 
@@ -43,9 +47,15 @@ def multi_head_attention(
     q_offset: jax.Array | int = 0,
     logits_softcap: Optional[float] = None,
     impl: str = "xla",
+    window: int = 0,
 ) -> jax.Array:
-    """Scaled dot-product attention with GQA. Returns [B, Sq, H, D]."""
-    if impl == "pallas":
+    """Scaled dot-product attention with GQA. Returns [B, Sq, H, D].
+    ``window`` > 0 (with ``causal``): query ``i`` sees keys ``i - window <
+    j <= i``. The flash kernel has no lower bound on the keys, so a window
+    takes the XLA path whatever ``impl`` says."""
+    if window and not causal:
+        raise ValueError("an attention window needs causal attention")
+    if impl == "pallas" and not window:
         try:
             from kubeflow_tpu.ops.flash_attention import flash_attention
         except ImportError as exc:
@@ -55,7 +65,7 @@ def multi_head_attention(
 
         return flash_attention(q, k, v, causal=causal, q_offset=q_offset,
                                logits_softcap=logits_softcap)
-    if impl != "xla":
+    if impl not in ("xla", "pallas"):
         raise ValueError(f"unknown attention impl {impl!r}")
 
     b, sq, h, d = q.shape
@@ -70,7 +80,7 @@ def multi_head_attention(
     if logits_softcap is not None:
         logits = jnp.tanh(logits / logits_softcap) * logits_softcap
     if causal:
-        cmask = causal_mask(sq, skv, q_offset=q_offset)
+        cmask = causal_mask(sq, skv, q_offset=q_offset, window=window)
         logits = jnp.where(cmask[None, None, :, :], logits, NEG_INF)
     if mask is not None:
         logits = jnp.where(mask, logits, NEG_INF)

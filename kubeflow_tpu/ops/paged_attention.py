@@ -48,9 +48,14 @@ from kubeflow_tpu.ops import auto_interpret
 from kubeflow_tpu.ops.attention import NEG_INF
 
 
-def _kernel(table_ref, len_ref, q_ref, k_ref, v_ref, *rest,
+def _kernel(table_ref, len_ref, *rest,
             page_size: int, sm_scale: float, num_pages_per_slot: int,
-            num_kv_heads: int, group: int, quantized: bool):
+            num_kv_heads: int, group: int, quantized: bool,
+            bounded: bool = False):
+    lo_ref = None
+    if bounded:                         # a third scalar operand: the lowest
+        lo_ref, rest = rest[0], rest[1:]    # position each row attends to
+    q_ref, k_ref, v_ref, *rest = rest
     if quantized:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -69,6 +74,8 @@ def _kernel(table_ref, len_ref, q_ref, k_ref, v_ref, *rest,
 
     length = len_ref[b]                 # position being decoded (inclusive)
     needed = jnp.logical_and(j * page_size <= length, table_ref[b, j] >= 0)
+    if bounded:                         # a page wholly behind the bound
+        needed = jnp.logical_and(needed, (j + 1) * page_size > lo_ref[b])
 
     @pl.when(needed)
     def _compute():
@@ -86,7 +93,10 @@ def _kernel(table_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         s = s.reshape(h, page_size)
         kv_pos = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1)
-        s = jnp.where(kv_pos <= length, s, NEG_INF)
+        seen = kv_pos <= length
+        if bounded:
+            seen = jnp.logical_and(seen, kv_pos >= lo_ref[b])
+        s = jnp.where(seen, s, NEG_INF)
 
         m_prev = m_ref[:]                            # [h, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -123,12 +133,21 @@ def paged_decode_attention(
     pool_ks: Optional[jax.Array] = None,   # [P, page, K] f32 (int8 pools)
     pool_vs: Optional[jax.Array] = None,
     sm_scale: Optional[float] = None,
+    lower: Optional[jax.Array] = None,     # [B] lowest position attended to
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Exact decode attention over the page pool; returns [B, 1, H, D].
 
     If ``pool_ks``/``pool_vs`` are given, ``pool_k``/``pool_v`` hold int8
-    pages and the kernel dequantizes in VMEM (per-token-per-head scales)."""
+    pages and the kernel dequantizes in VMEM (per-token-per-head scales).
+
+    ``lower`` (a window layer's call): row ``b`` attends to positions
+    ``lower[b] <= j <= lengths[b]`` of ITS table row, keys behind the bound
+    are masked and a page wholly behind it is not computed on. Positions
+    count from the table row's first page, so a caller that hands in only
+    the pages a window touches (serve/paged.py: two of a ring) reads no
+    other; the call is named ``paged_window_decode_attention`` in a trace.
+    Without it the kernel is what it was."""
     b, one, h, d = q.shape
     if one != 1:
         raise ValueError("paged decode attention takes one token per slot")
@@ -141,20 +160,21 @@ def paged_decode_attention(
     g = h // kh
     mpp = table.shape[1]
     scale = sm_scale if sm_scale is not None else d ** -0.5
-
+    bounded = lower is not None
     kernel = functools.partial(
         _kernel, page_size=page, sm_scale=scale, num_pages_per_slot=mpp,
-        num_kv_heads=kh, group=g, quantized=quantized)
+        num_kv_heads=kh, group=g, quantized=quantized, bounded=bounded)
+    scalars = (table, lengths, lower) if bounded else (table, lengths)
 
-    def q_map(bi, ji, table_ref, len_ref):
+    def q_map(bi, ji, table_ref, *_):
         return (bi, 0, 0, 0)
 
-    def kv_map(bi, ji, table_ref, len_ref):
+    def kv_map(bi, ji, table_ref, *_):
         # Unmapped pages clamp to page 0: the DMA happens but the compute
         # predicate never reads it.
         return (jnp.maximum(table_ref[bi, ji], 0), 0, 0, 0)
 
-    def scale_map(bi, ji, table_ref, len_ref):
+    def scale_map(bi, ji, table_ref, *_):
         return (jnp.maximum(table_ref[bi, ji], 0), 0, 0)
 
     in_specs = [
@@ -173,9 +193,10 @@ def paged_decode_attention(
 
     out = pl.pallas_call(
         kernel,
-        name="paged_decode_attention",
+        name=("paged_window_decode_attention" if bounded
+              else "paged_decode_attention"),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(scalars),
             grid=(b, mpp),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, 1, h, d), q_map),
@@ -187,7 +208,7 @@ def paged_decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((b, 1, h, d), q.dtype),
         interpret=interpret if interpret is not None else auto_interpret(),
-    )(table, lengths, *operands)
+    )(*scalars, *operands)
     return out
 
 
@@ -533,7 +554,7 @@ def _word_heads(words, dtype) -> list:
 
 
 def _chunk_kernel(table_ref, start_ref, q_ref, *rest, page_size: int,
-                  sm_scale: float):
+                  sm_scale: float, window: int = 0):
     n = CHUNK_PAGES_PER_STEP
     k_refs, v_refs = rest[:n], rest[n:2 * n]
     o_ref, k_rows, v_rows, m_ref, l_ref, acc_ref = rest[2 * n:]
@@ -562,7 +583,10 @@ def _chunk_kernel(table_ref, start_ref, q_ref, *rest, page_size: int,
                 jnp.int32, (tile, block), 0)
             kv_pos = j * block + jax.lax.broadcasted_iota(
                 jnp.int32, (tile, block), 1)
-            allowed = (kv_pos <= q_pos)[None]
+            allowed = kv_pos <= q_pos
+            if window:
+                allowed = jnp.logical_and(allowed, kv_pos > q_pos - window)
+            allowed = allowed[None]
 
         def one_word(w, _):
             for rows_ref, pages in planes:
@@ -597,10 +621,19 @@ def _chunk_kernel(table_ref, start_ref, q_ref, *rest, page_size: int,
     # the causal mask.
     seen = jnp.logical_and(j * block <= first + tile - 1,
                            table_ref[j * n] >= 0)
-    whole = (j + 1) * block - 1 <= first
-    pl.when(jnp.logical_and(seen, whole))(lambda: attend(False))
-    pl.when(jnp.logical_and(seen, jnp.logical_not(whole)))(
-        lambda: attend(True))
+    if window:
+        # A window layer's call: a block wholly behind the window of the
+        # tile's first query is not attended, and every block that is pays
+        # for the mask (a block is never wholly inside every query's
+        # window at a window of a page).
+        pl.when(jnp.logical_and(
+            seen, (j + 1) * block - 1 > first - window))(
+                lambda: attend(True))
+    else:
+        whole = (j + 1) * block - 1 <= first
+        pl.when(jnp.logical_and(seen, whole))(lambda: attend(False))
+        pl.when(jnp.logical_and(seen, jnp.logical_not(whole)))(
+            lambda: attend(True))
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
@@ -617,6 +650,7 @@ def paged_chunk_attention(
     table_row: jax.Array,         # [n] int32: the slot's pages in order
     start: jax.Array,             # scalar int32: position of query 0
     *,
+    window: int = 0,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Causal attention of a chunk of ``C`` queries (positions ``start ..``)
@@ -624,7 +658,15 @@ def paged_chunk_attention(
     writes them first); returns [H, C, D]. The contract of
     ``paged_latent_chunk_attention``: cost follows the context, a query
     attends to positions <= its own. Planes this takes:
-    ``chunk_attention_supported``."""
+    ``chunk_attention_supported``.
+
+    ``window`` > 0 (static; a window layer's call, named
+    ``paged_window_chunk_attention`` in a trace): query ``i`` sees keys ``i
+    - window < j <= i``; blocks wholly behind a tile's window are neither
+    fetched nor attended. Positions count from ``table_row``'s first page,
+    so a caller hands in the pages the chunk and the window before it touch
+    (serve/paged.py: a ring's) and no other is read. With no window the
+    kernel is what it was."""
     h, d = q.shape[0], q.shape[2]
     kv = pool_k.shape[2]
     if not chunk_attention_supported(kv, d, pool_k.dtype) or h % kv:
@@ -633,14 +675,18 @@ def paged_chunk_attention(
             f"x {pool_k.shape[3]}, {h} query heads x {d}")
     return _chunk_attention_call(
         q, pool_k, pool_v, table_row, jnp.asarray(start, jnp.int32),
-        interpret=interpret if interpret is not None else auto_interpret())
+        interpret=interpret if interpret is not None else auto_interpret(),
+        # no keyword where no window is set: the call lowers under the name
+        # it had (tests/test_chip_compile.py pins the older cells' programs)
+        **({"window": window} if window else {}))
 
 
 # Traced ONCE for each set of shapes and inlined wherever it is called: every
 # row of every chunk program of an engine attends through the same call.
-@functools.partial(jax.jit, static_argnames=("interpret",), inline=True)
+@functools.partial(jax.jit, static_argnames=("interpret", "window"),
+                   inline=True)
 def _chunk_attention_call(q, pool_k, pool_v, table_row, start, *,
-                          interpret: bool):
+                          interpret: bool, window: int = 0):
     h, c, d = q.shape
     page, kv = pool_k.shape[1:3]
     per = 4 // pool_k.dtype.itemsize
@@ -655,7 +701,7 @@ def _chunk_attention_call(q, pool_k, pool_v, table_row, start, *,
     table = jnp.pad(table_row, (0, num_blocks * n - table_row.shape[0]),
                     constant_values=-1)
     kernel = functools.partial(
-        _chunk_kernel, page_size=page, sm_scale=d ** -0.5)
+        _chunk_kernel, page_size=page, sm_scale=d ** -0.5, window=window)
 
     def q_map(ti, ji, table_ref, start_ref):
         return (0, ti, 0)
@@ -665,12 +711,16 @@ def _chunk_attention_call(q, pool_k, pool_v, table_row, start, *,
             # A block behind the tile's last query is not attended: it stays
             # on the last block that is, which is not fetched again.
             ji = jnp.minimum(ji, (start_ref[0] + (ti + 1) * tile - 1) // block)
+            if window:      # nor is one wholly behind the tile's window
+                ji = jnp.maximum(ji, jnp.maximum(
+                    start_ref[0] + ti * tile - window + 1, 0) // block)
             return (jnp.maximum(table_ref[ji * n + i], 0), 0, 0, 0)
         return index
 
     return pl.pallas_call(
         kernel,
-        name="paged_chunk_attention",
+        name=("paged_window_chunk_attention" if window
+              else "paged_chunk_attention"),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(c // tile, num_blocks),

@@ -75,9 +75,9 @@ from kubeflow_tpu.core.serving import (
 from kubeflow_tpu.serve.device_state import DEAD_SLOT, DecodeState
 from kubeflow_tpu.serve.pacing import RoundPacer, decode_ladder
 from kubeflow_tpu.serve.paged import (
-    PageAllocator, PagePoolExhausted, chunk_reads_context, context_bucket,
-    paged_chunk_prefill, paged_decode_multi, pool_bytes_per_token,
-    pool_shapes,
+    MOE_ROWS, PageAllocator, PagePoolExhausted, chunk_reads_context,
+    context_bucket, engine_pool_shapes, paged_chunk_prefill,
+    paged_decode_multi, pool_bytes_per_token, pool_shapes, ring_pages,
 )
 from kubeflow_tpu.serve.weight_layout import (
     relaid_bytes, relay, weight_formats,
@@ -313,6 +313,9 @@ class _InflightRound:
     # the device runs nothing but this round between the last one's end and
     # its own
     alone: bool = False
+    # the expert rows' running sums as they stood when the round ended
+    # (int32 [2], a buffer of its own: the pool's is donated onward), or None
+    rows: Optional[jax.Array] = None
 
 
 def _pin2(out, pin):
@@ -657,7 +660,8 @@ class EngineMetrics:
 def serving_configs(cfg: DecoderConfig, b: BatchingSpec):
     """``(cfg_prefill, cfg_decode)``: the config the chunk programs and the
     decode programs of an engine over ``cfg`` are built with. They differ
-    only in how a sparse model's experts are reached."""
+    only in how a sparse model's experts are reached; both carry the ring a
+    sequence keeps in the window layers' planes (``window_ring_pages``)."""
     # Serving MoE must be batch-independent: a request's tokens must not
     # change because co-batched traffic filled an expert's capacity
     # buffer. Two phases, two resolutions (VERDICT r3 #3):
@@ -672,6 +676,12 @@ def serving_configs(cfg: DecoderConfig, b: BatchingSpec):
     #   zero-drop capacity (C = k*T). The same A/B measured it a tie
     #   within session noise, so dense (simpler, drop-free by
     #   construction) stays the default (bench_serve.py --workload moe).
+    if cfg.layers_of("window"):
+        # A sequence's ring in the window layers' planes: every program of
+        # the engine reads its length off its config (paged.ring_table).
+        cfg = dataclasses.replace(cfg, window_ring_pages=ring_pages(
+            cfg, max(0, int(b.chunked_prefill_tokens)) or b.page_size,
+            b.page_size, b.max_seq_len // b.page_size))
     cfg_prefill, cfg_decode = cfg, cfg
     if cfg.is_moe:
         pre = b.moe_prefill_impl
@@ -797,9 +807,17 @@ class LLMEngine:
         if self._num_pages * pg < self.max_len:
             raise ValueError(
                 "page pool smaller than one max-length sequence")
+        # Window layers keep a ring of ``_ring`` pages a sequence, over its
+        # first pages, in planes of ``_window_pages`` pages: a ring for
+        # every slot. Those ids are a sequence's first pages and nothing
+        # else (``_ensure_pages``).
+        self._ring = cfg_decode.window_ring_pages
+        self._window_pages = min(self._num_pages,
+                                 self.num_slots * self._ring)
         self._allocator = PageAllocator(
             self._num_pages, pg,
-            enable_prefix_caching=b.enable_prefix_caching)
+            enable_prefix_caching=b.enable_prefix_caching,
+            ring_pages=self._window_pages)
         # lockfree: scheduler-confined (host page-table mirror)
         self._table = np.full((self.num_slots, self._mpp), -1, np.int32)
         self._slot_pages: list[list[int]] = [  # lockfree: scheduler-confined
@@ -813,14 +831,26 @@ class LLMEngine:
         # rows a token.
         self.cache = {  # lockfree: scheduler-confined (donated KV)
             name: self._zeros(shape, dt, scale=name in ("ks", "vs"))
-            for name, (shape, dt) in pool_shapes(
-                cfg, self._num_pages, pg, self.kv_quant).items()}
+            for name, (shape, dt) in engine_pool_shapes(
+                cfg_decode, self.num_slots, self._num_pages, pg,
+                self.kv_quant).items() if name != MOE_ROWS}
 
         self._kv_bytes_per_token = pool_bytes_per_token(cfg, self.kv_quant)
         self._kv_pool_bytes = int(sum(v.nbytes for v in self.cache.values()))
-        self._state_pool_bytes = int(sum(
-            v.nbytes for n, v in self.cache.items()
-            if plane_kind(n) == "conv"))
+        by_kind = {kind: int(sum(v.nbytes for n, v in self.cache.items()
+                                 if plane_kind(n) == kind))
+                   for kind in ("attention", "window", "conv")}
+        self._state_pool_bytes = by_kind["conv"]
+        self._kv_window_pool_bytes = by_kind["window"]
+        self._kv_global_pool_bytes = by_kind["attention"]
+        # Where a layer holds a share of its experts, the rows its expert
+        # layers routed and held ride in the cache pytree as running sums
+        # (no pool plane: ``paged._planes_of``); the scheduler reads them in
+        # a round's fetch (``_consume_round``).
+        self._expert_rows = [0, 0]      # lockfree: scheduler-confined
+        self._expert_rows_seen = np.zeros((2,), np.uint32)
+        if cfg.experts_held:
+            self.cache[MOE_ROWS] = jnp.zeros((2,), jnp.int32)
 
         # Compiled programs: donate the cache so it mutates in place in HBM.
         on_tpu = jax.default_backend() == "tpu"
@@ -926,7 +956,8 @@ class LLMEngine:
             table = cache.pop("table")
             st = {**st, "tokens": tokens, "lengths": lengths,
                   "live": live, "budgets": budgets}
-            return out, self._pin(cache), st, table
+            rows = cache[MOE_ROWS] + 0 if MOE_ROWS in cache else None
+            return out, self._pin(cache), st, table, rows
 
         self._paged_decode_n = jax.jit(
             _paged_decode_fn, static_argnums=(5, 6),
@@ -1298,18 +1329,27 @@ class LLMEngine:
         has no per-head K and V, nor have K/V heads packed into one row,
         which the int8 pool's scales, the handoff payload, the host tier's
         wire format and the speculative verify step are written over; conv
-        layers keep their state a page in planes of their own, which those
-        do not carry; a stack of more than one kind or group of layers is
-        not one ``params["layers"]``, which those and the weight quantizer,
-        the adapter buffers and the mesh's sharding walk. (Prefix reuse is
-        taken: over conv layers it resumes at page boundaries only,
-        ``_kv_match``.)"""
+        layers keep their state a page, and window layers a ring of pages a
+        sequence, in planes of their own, which those do not carry; a stack
+        of more than one kind or group of layers is not one
+        ``params["layers"]``, which those and the weight quantizer, the
+        adapter buffers and the mesh's sharding walk; an expert layer that
+        holds a share of its experts is one chip's part of a group and has
+        no form over a mesh. Prefix reuse is taken over conv layers (it
+        resumes at page boundaries only, ``_kv_match``) and REFUSED over
+        window layers: a ring that its sequence overwrites cannot be shared
+        read-only, and a match would need the ring's pages as they stood at
+        the match."""
         what = [name for name, has in (
             ("a latent (ckv) KV pool", cfg.is_latent),
             ("convolution layers whose state lives in the page pool",
              bool(cfg.layers_of("conv"))),
+            ("window layers that keep a ring of pages a sequence",
+             bool(cfg.layers_of("window"))),
             ("K/V heads packed into one pool row", cfg.kv_heads_packed),
-            ("leading dense layers", bool(cfg.leading_dense_layers))) if has]
+            ("leading dense layers", bool(cfg.leading_dense_layers)),
+            (f"expert layers that hold {cfg.experts_held} of "
+             f"{cfg.num_experts} experts", bool(cfg.experts_held))) if has]
         if not what:
             return
         refused = {
@@ -1323,6 +1363,9 @@ class LLMEngine:
             "not exist in this block)": bool(b.lora.max_adapters),
             "quantize=int8 (weight quantization)": b.quantize is not None,
             "a mesh (tensor-parallel serving)": self.mesh is not None,
+            "enable_prefix_caching (prefix reuse over window layers: a "
+            "ring its sequence overwrites cannot be shared)":
+                bool(cfg.layers_of("window")) and b.enable_prefix_caching,
         }
         hit = [name for name, on in refused.items() if on]
         if hit:
@@ -1417,6 +1460,19 @@ class LLMEngine:
             "kv_bytes_per_token": self._kv_bytes_per_token,
             "kv_pool_bytes": self._kv_pool_bytes,
             "state_pool_bytes": self._state_pool_bytes,
+            # of the cache's size the window layers' planes (a ring of
+            # ``kv_window_pages_a_sequence`` pages a sequence; 0 and 0 for a
+            # stack without window layers) and the global layers' (every
+            # page of a sequence)
+            "kv_window_pool_bytes": self._kv_window_pool_bytes,
+            "kv_global_pool_bytes": self._kv_global_pool_bytes,
+            "kv_window_pages_a_sequence": self._ring,
+            # (token, choice) rows the expert layers of every program
+            # routed, and those of them whose expert is held here and was
+            # computed (0 and 0 where every expert is held), as of the last
+            # decode round fetched
+            "expert_rows_routed": self._expert_rows[0],
+            "expert_rows_held": self._expert_rows[1],
             # page-end tails of the conv layers' state that chunk-prefill
             # programs wrote: one for each page a chunk's tokens touched
             "state_tail_writes": self._state_tail_writes,
@@ -1899,6 +1955,13 @@ class LLMEngine:
     def _pages_for(self, tokens: int) -> int:
         return -(-min(tokens, self.max_len) // self.page_size)
 
+    def _room_for(self, pages: int) -> bool:
+        """Whether the pool can hand a NEW sequence ``pages`` pages, its
+        first ``_ring`` of them ring pages."""
+        in_ring = min(pages, self._ring)
+        return self._allocator.available(ring=True) >= in_ring \
+            and self._allocator.available() >= pages - in_ring
+
     def _drain_waiting(self) -> None:
         while True:
             try:
@@ -2039,16 +2102,16 @@ class LLMEngine:
             if pre is not None:
                 remaining = max(pre.params.max_new_tokens
                                 - len(pre.output_tokens), 0)
-                if self._allocator.available() >= self._pages_for(
-                        len(pre.prompt_tokens) + remaining):
+                if self._room_for(self._pages_for(
+                        len(pre.prompt_tokens) + remaining)):
                     self._preempted.remove(pre)
                     return pre
                 return None          # backpressure: this class and below wait
             req = next((r for r in self._backlog if r.qos == cls), None)
             if req is None:
                 continue
-            if self._allocator.available() < self._pages_for(
-                    len(req.prompt_tokens)) + 1:
+            if not self._room_for(self._pages_for(
+                    len(req.prompt_tokens)) + 1):
                 return None          # head-of-line within the priority order
             self._backlog.remove(req)
             return self._note_admitted(req)
@@ -2593,8 +2656,11 @@ class LLMEngine:
         if need <= have:
             return True
         try:
-            new = self._allocator.alloc(need - have,
-                                        owner=self._slot_owner(slot_idx))
+            # A sequence's first pages are its ring in the window layers'
+            # planes: they come from the ids those planes hold.
+            new = self._allocator.alloc(
+                need - have, ring=max(0, min(need, self._ring) - have),
+                owner=self._slot_owner(slot_idx))
         except PagePoolExhausted:
             return False
         self._table[slot_idx, have:need] = new
@@ -2878,10 +2944,19 @@ class LLMEngine:
         context = sum(
             k_steps * (s.length + slack) + k_steps * (k_steps + 1) // 2
             for _, s in active)
-        with self._phase(prof.ENGINE_DECODE_DISPATCH, prof.active() and {
-                "round": round_id, "k_steps": k_steps, "live": len(active),
-                "context": context}):
-            out = self._dispatch_decode(k_steps, mode, self._next_key())
+        attrs = prof.active() and {
+            "round": round_id, "k_steps": k_steps, "live": len(active),
+            "context": context}
+        if attrs and self._ring:
+            # rows a window layer's steps attend to: a step at position t
+            # sees min(t + 1, window) of them
+            w = self.cfg.attn_window
+            attrs["window_context"] = sum(
+                min(s.length + slack + j + 1, w)
+                for _, s in active for j in range(k_steps))
+        with self._phase(prof.ENGINE_DECODE_DISPATCH, attrs):
+            out, rows = self._dispatch_decode(k_steps, mode,
+                                              self._next_key())
         self.decode_rounds += 1
         self._decode_steps_dispatched += k_steps
         self._decode_rounds_at_cap += k_steps == cap
@@ -2889,23 +2964,25 @@ class LLMEngine:
         self._rounds.append(_InflightRound(
             out=out, active=list(active), k_steps=k_steps,
             gap_ms=None if gap is None else gap * 1e3, round_id=round_id,
-            alone=self._sent_no_prefill()))
+            alone=self._sent_no_prefill(), rows=rows))
         return True
 
     def _dispatch_decode(self, k_steps: int, mode: str, key):  # hot-loop
         """Enqueue the decode program over the device-resident state and
-        adopt the state it returns; returns the token buffer's handle."""
+        adopt the state it returns; returns the token buffer's handle and
+        the expert rows' sums as the round leaves them (None where every
+        expert is held)."""
         if self._lora is not None:
-            out, self.cache, st, tbl = self._paged_decode_n(
+            out, self.cache, st, tbl, rows = self._paged_decode_n(
                 self.params, self.cache, self._dstate.arrays,
                 self._dstate.table, key, k_steps, mode,
                 self._lora.buffers)
         else:
-            out, self.cache, st, tbl = self._paged_decode_n(
+            out, self.cache, st, tbl, rows = self._paged_decode_n(
                 self.params, self.cache, self._dstate.arrays,
                 self._dstate.table, key, k_steps, mode)
         self._dstate.adopt(st, tbl)
-        return out
+        return out, rows
 
     def _consume_round(self) -> int:  # hot-loop
         """Fetch and emit the oldest in-flight round's tokens. Slots whose
@@ -2915,7 +2992,14 @@ class LLMEngine:
         rnd = self._rounds.pop(0)
         with self._phase(prof.ENGINE_FETCH,
                          prof.active() and {"round": rnd.round_id}):
-            out = np.asarray(jax.device_get(rnd.out))  # sync-point: the pipeline's one designed fetch per round
+            out, rows = jax.device_get((rnd.out, rnd.rows))  # sync-point: the pipeline's one designed fetch per round
+            out = np.asarray(out)
+        if rows is not None:
+            # int32 sums that wrap: what was added since the last fetch
+            seen = np.asarray(rows).astype(np.uint32)
+            for i, d in enumerate(seen - self._expert_rows_seen):
+                self._expert_rows[i] += int(d)
+            self._expert_rows_seen = seen
         now = time.monotonic()
         self._consumed_k = rnd.k_steps
         if rnd.alone and self._rounds and self._last_ready_t is not None:

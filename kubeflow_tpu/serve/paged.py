@@ -58,7 +58,8 @@ import numpy as np
 from kubeflow_tpu.models import layers as L
 from kubeflow_tpu.models.config import DecoderConfig
 from kubeflow_tpu.models.decoder import (
-    Params, layer_groups, period_units, plane_kind, unit_blocks,
+    WINDOW_PLANES, Params, block_kind, layer_groups, period_units,
+    plane_kind, unit_blocks,
 )
 
 
@@ -85,11 +86,18 @@ class PageAllocator:
     """
 
     def __init__(self, num_pages: int, page_size: int,
-                 enable_prefix_caching: bool = True):
+                 enable_prefix_caching: bool = True, ring_pages: int = 0):
+        """``ring_pages`` (a stack with window layers): the page ids below
+        it exist in the window layers' planes too and are handed out ONLY on
+        request (``alloc(n, ring=r)``: a sequence's first pages, over
+        which its window layers keep their ring, ``ring_table``); every other
+        page comes from the ids above."""
         self.num_pages = num_pages
         self.page_size = page_size
         self.prefix_caching = enable_prefix_caching
-        self._free: list[int] = list(range(num_pages - 1, -1, -1))
+        self.ring_pages = ring_pages
+        self._free: list[int] = list(range(num_pages - 1, ring_pages - 1, -1))
+        self._free_ring: list[int] = list(range(ring_pages - 1, -1, -1))
         self._ref = np.zeros((num_pages,), np.int32)
         # content key -> page id (for reuse); page id -> key (for eviction)
         self._by_key: dict[tuple, int] = {}
@@ -142,7 +150,9 @@ class PageAllocator:
 
     # -- raw pages ---------------------------------------------------------
 
-    def available(self) -> int:
+    def available(self, ring: bool = False) -> int:
+        if ring:
+            return len(self._free_ring)
         return len(self._free) + len(self._reclaimable)
 
     def cached(self) -> int:
@@ -203,13 +213,22 @@ class PageAllocator:
                                     sorted(by_owner.items())))
             raise AssertionError(msg)
 
-    def alloc(self, n: int, owner: Optional[str] = None) -> list[int]:
-        """n fresh pages (ref=1 each). Evicts cached pages LRU if needed."""
-        if self.available() < n:
-            raise PagePoolExhausted(f"need {n}, have {self.available()}")
+    def alloc(self, n: int, owner: Optional[str] = None,
+              ring: int = 0) -> list[int]:
+        """n fresh pages (ref=1 each). Evicts cached pages LRU if needed.
+        ``ring``: the first ``ring`` of them from the ids the window layers'
+        planes hold too. All of them or none: nothing is taken where either
+        kind runs short."""
+        if self.available(ring=True) < ring \
+                or self.available() < n - ring:
+            raise PagePoolExhausted(
+                f"need {ring} ring + {n - ring}, have "
+                f"{self.available(ring=True)} + {self.available()}")
         out = []
-        for _ in range(n):
-            if self._free:
+        for i in range(n):
+            if i < ring:
+                p = self._free_ring.pop()
+            elif self._free:
                 p = self._free.pop()
             else:
                 p, _ = self._reclaimable.popitem(last=False)   # LRU evict
@@ -249,7 +268,9 @@ class PageAllocator:
             if self.refcount_debug:
                 self._unstamp(p)
             if self._ref[p] == 0:
-                if p in self._key_of or p in self.retained:
+                if p < self.ring_pages:
+                    self._free_ring.append(p)
+                elif p in self._key_of or p in self.retained:
                     self._reclaimable[p] = None    # keep content, LRU
                 else:
                     self._free.append(p)
@@ -317,6 +338,13 @@ class PageAllocator:
 # (reads are length-masked, writes aimed out of bounds and dropped).
 
 
+# What rides in a cache pytree beside the pool's planes: the page table, and
+# where a layer holds a share of its experts the running sums of the rows
+# its expert layers routed and held (int32 [2], wrapping: a reader works on
+# differences).
+MOE_ROWS = "moe_rows"
+
+
 def pool_planes(cfg: DecoderConfig, kv_quant: bool = False) -> tuple:
     """What one token holds in one layer of the page pool: (name, trailing
     shape, type) per plane. The ONE description the pool is built from and
@@ -369,17 +397,89 @@ def state_planes(cfg: DecoderConfig) -> tuple:
     return (("conv", (cfg.conv_taps - 1, cfg.hidden), cfg.activation_dtype),)
 
 
+def window_planes(cfg: DecoderConfig, kv_quant: bool = False) -> tuple:
+    """What one token holds in one WINDOW layer of the pool, in planes of
+    that kind's own: ``decoder.WINDOW_PLANES`` [KV, Dh], a global layer's K
+    and V under other names. Their pool has fewer pages than the global
+    layers' (a sequence keeps a ring of ``ring_pages`` there:
+    ``ring_table``). () for a stack without window layers."""
+    if not cfg.layers_of("window"):
+        return ()
+    if kv_quant or cfg.is_latent or cfg.kv_heads_packed:
+        raise ValueError("window layers over an int8, latent or packed pool")
+    kv, dt = (cfg.n_kv_heads, cfg.head_dim), cfg.activation_dtype
+    return tuple((n, kv, dt) for n in WINDOW_PLANES)
+
+
+def ring_pages(cfg: DecoderConfig, chunk: int, page_size: int,
+               mpp: int) -> int:
+    """Pages a sequence keeps in a window layer: what a chunk of ``chunk``
+    tokens and the window before it span, plus one for a chunk that starts
+    inside a page (6 at a chunk of 512, a window and a page of 128), at most
+    the table's length. 0 for a stack without window layers."""
+    if not cfg.layers_of("window"):
+        return 0
+    behind = -(-(cfg.attn_window - 1) // page_size)
+    return min(mpp, -(-chunk // page_size) + behind + 1)
+
+
+def _ring_len(cfg: DecoderConfig, mpp: int) -> int:
+    """A ring's pages under a table of ``mpp``: the whole row where the
+    config sets none."""
+    return min(cfg.window_ring_pages or mpp, mpp)
+
+
+def ring_table(table: jax.Array, first: jax.Array, n: int,  # traced
+               cfg: DecoderConfig) -> jax.Array:
+    """Where a window layer keeps a sequence's logical pages ``first ..
+    first + n``: ``table`` [B, mpp] rows, ``first`` [B] -> [B, n] page ids.
+    A global layer keeps logical page ``i`` at ``row[i]``; a window layer
+    keeps it at ``row[i mod R]``, a ring over the sequence's own first ``R``
+    pages (``cfg.window_ring_pages``; the whole row where none is set), in
+    which page ``i`` overwrites page ``i - R``, which no query still sees.
+    Found from the row and a position alone: no slot, no second table."""
+    ring = _ring_len(cfg, table.shape[1])
+    at = (first[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :]) % ring
+    return jnp.take_along_axis(table, at, axis=1)
+
+
 def pool_shapes(cfg: DecoderConfig, num_pages: int, page_size: int,
-                kv_quant: bool = False) -> dict:
+                kv_quant: bool = False,
+                window_pages: Optional[int] = None) -> dict:
     """The pool an engine builds: {plane: (shape, type)}. A token plane is
     ``[layers of attention, P, page, ...]``, a state plane ``[layers of
-    conv, P, ...]``: each over the layers of ITS kind (``decoder.
-    plane_kind``), so a stack whose layers are all attention keeps ``[L, P,
-    page, ...]``."""
+    conv, P, ...]``, a window layer's ``[layers of window, H, page, ...]``
+    with ``H`` = ``window_pages`` (the whole pool's where not given): each
+    over the layers of ITS kind (``decoder.plane_kind``), so a stack whose
+    layers are all attention keeps ``[L, P, page, ...]``."""
     out = {n: ((cfg.layers_of("attention"), num_pages, page_size, *trail),
-               dt) for n, trail, dt in pool_planes(cfg, kv_quant)}
+               dt) for n, trail, dt in pool_planes(cfg, kv_quant)
+           if cfg.layers_of("attention")}
     out.update({n: ((cfg.layers_of("conv"), num_pages, *trail), dt)
                 for n, trail, dt in state_planes(cfg)})
+    out.update({n: ((cfg.layers_of("window"),
+                     num_pages if window_pages is None else window_pages,
+                     page_size, *trail), dt)
+                for n, trail, dt in window_planes(cfg, kv_quant)})
+    return out
+
+
+def engine_pool_shapes(cfg: DecoderConfig, slots: int, num_pages: int,
+                       page_size: int, kv_quant: bool = False) -> dict:
+    """The cache pytree of an engine of ``slots`` slots over ``cfg`` as its
+    programs take it (``engine.serving_configs`` has set the ring):
+    ``pool_shapes`` with a ring for every slot in the window layers' planes
+    (``slots * window_ring_pages`` pages, the ids a sequence's first pages
+    come from) and, where a layer holds a share of its experts, the rows'
+    running sums."""
+    if cfg.layers_of("window") and not cfg.window_ring_pages:
+        raise ValueError("an engine's pool over window layers needs "
+                         "cfg.window_ring_pages (paged.ring_pages)")
+    out = pool_shapes(cfg, num_pages, page_size, kv_quant,
+                      window_pages=min(num_pages,
+                                       slots * cfg.window_ring_pages))
+    if cfg.experts_held:
+        out[MOE_ROWS] = ((2,), jnp.dtype(jnp.int32))
     return out
 
 
@@ -402,16 +502,32 @@ def state_bytes_per_page(cfg: DecoderConfig) -> int:
     return cfg.layers_of("conv") * _plane_bytes(state_planes(cfg))
 
 
+def window_bytes_per_page(cfg: DecoderConfig, page_size: int) -> int:
+    """Bytes one ring page holds over all window layers."""
+    return cfg.layers_of("window") * page_size * _plane_bytes(
+        window_planes(cfg))
+
+
 def _pool_geometry(cache: dict) -> tuple:
-    """(pages, page size) of a cache pytree: its first token plane's."""
-    return next(cache[n].shape[1:3] for n in _planes_of(cache)
-                if plane_kind(n) == "attention")
+    """(pages, page size) of a cache pytree: its first token plane's (a
+    global layer's where the stack has one)."""
+    names = sorted((n for n in _planes_of(cache) if plane_kind(n) != "conv"),
+                   key=lambda n: plane_kind(n) != "attention")
+    return cache[names[0]].shape[1:3]
+
+
+_NOT_PLANES = ("table", MOE_ROWS)
 
 
 def _planes_of(cache: dict) -> tuple:
     """The names of a cache pytree's pool planes (everything but the page
-    table)."""
-    return tuple(n for n in cache if n != "table")
+    table and the expert rows' sums)."""
+    return tuple(n for n in cache if n not in _NOT_PLANES)
+
+
+def _pages_by_kind(cache: dict) -> dict:
+    """Pages a layer holds in the planes of each kind."""
+    return {plane_kind(n): cache[n].shape[1] for n in _planes_of(cache)}
 
 
 def paged_gather(pool: jax.Array, table: jax.Array) -> jax.Array:  # traced
@@ -440,12 +556,20 @@ def _head_logits(params: Params, x: jax.Array, cfg: DecoderConfig):  # traced
 
 
 def _feed_forward(bp, h, cfg: DecoderConfig, expert_stack=None,  # traced
-                  valid_len=None, capacity_per_row: bool = False):
-    if cfg.is_moe:
-        return L.moe_block(bp["mlp"], h, cfg, valid_len=valid_len,
-                           expert_stack=expert_stack,
-                           capacity_per_row=capacity_per_row)[0]
-    return L.mlp_block(bp["mlp"], h, cfg)
+                  valid_len=None, capacity_per_row: bool = False, *,
+                  pools: dict):
+    """The block's feed-forward (or expert) layer over ``h``: (out, pools),
+    the carried planes, where a share of the experts is held with the
+    layer's routed and held rows added to ``pools[MOE_ROWS]``."""
+    if not cfg.is_moe:
+        return L.mlp_block(bp["mlp"], h, cfg), pools
+    counted = MOE_ROWS in pools
+    out = L.moe_block(bp["mlp"], h, cfg, valid_len=valid_len,
+                      expert_stack=expert_stack,
+                      capacity_per_row=capacity_per_row, rows_out=counted)
+    if counted:
+        pools = {**pools, MOE_ROWS: pools[MOE_ROWS] + out[2]}
+    return out[0], pools
 
 
 def _scan_layer_groups(params: Params, cfg: DecoderConfig, carry, block,  # traced
@@ -482,12 +606,14 @@ def _scan_layer_groups(params: Params, cfg: DecoderConfig, carry, block,  # trac
     return carry
 
 
-def _decode_attention(q, ck, cv, lengths, cfg: DecoderConfig):  # traced
+def _decode_attention(q, ck, cv, lengths, cfg: DecoderConfig,  # traced
+                      lower=None):
     """One-token attention over the slots' gathered pages.
 
     q [B,1,H,Dh]; ck/cv [B,Smax,KV,Dh]; lengths [B] = position of the token
     being decoded (its K/V were just written at that index, so attend to
-    kpos <= lengths[b])."""
+    kpos <= lengths[b]); ``lower`` [B] (a window layer): and to kpos >=
+    lower[b]."""
     b, smax = ck.shape[0], ck.shape[1]
     groups = cfg.n_heads // cfg.n_kv_heads
     qg = q.reshape(b, cfg.n_kv_heads, groups, cfg.head_dim)
@@ -496,6 +622,8 @@ def _decode_attention(q, ck, cv, lengths, cfg: DecoderConfig):  # traced
     scores *= cfg.head_dim ** -0.5
     kpos = jnp.arange(smax, dtype=jnp.int32)
     mask = kpos[None, :] <= lengths[:, None]            # [B, Smax]
+    if lower is not None:
+        mask = mask & (kpos[None, :] >= lower[:, None])
     scores = jnp.where(mask[:, None, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(ck.dtype)
     out = jnp.einsum("bkgs,bskd->bkgd", probs, cv)
@@ -531,21 +659,42 @@ def _paged_decode_block(bp, x, positions, lengths, live, pools, table,  # traced
     read. A latent pool is attended in the ABSORBED form (the key expansion
     folded into the query, the value expansion applied to the attended
     latent), so the step never holds per-head K or V of the context."""
-    kind = "conv" if "conv" in bp else "attention"
-    own = next(pools[n] for n in pools if plane_kind(n) == kind)
+    kind = block_kind(bp)
+    own = next(pools[n] for n in pools
+               if n != MOE_ROWS and plane_kind(n) == kind)
     total, pg = own.shape[0], page_size
-    base = layer * num_pages
+    base = layer * num_pages[kind]
     h = L.rmsnorm(x, bp["ln1"], cfg)
     # Write position -> (flat page, offset). Dead rows and unmapped pages
     # aim past the END of the flat pool and DROP: one past this layer's
     # pages (base + P) is the next layer's page 0.
     bidx = jnp.arange(x.shape[0])
     page_slot = lengths // pg
-    page_id = table[bidx, jnp.clip(page_slot, 0, table.shape[1] - 1)]
+    if kind == "window":
+        # its ring's page; an id the window planes do not hold (a caller
+        # that put a sequence's first pages elsewhere) drops like no page
+        page_id = ring_table(table, page_slot, 1, cfg)[:, 0]
+        page_id = jnp.where(page_id < num_pages[kind], page_id, -1)
+    else:
+        page_id = table[bidx, jnp.clip(page_slot, 0, table.shape[1] - 1)]
     pidx = jnp.where(live & (page_id >= 0), base + page_id, total)
     if kind == "conv":
         proj, pools = _conv_decode(bp["conv"], h, lengths, pools, pidx,
                                    base, table, pg, cfg)
+    elif kind == "window":
+        # The pages its window touches and no other (the page of position
+        # ``t - window + 1`` up to the page of ``t``: two at a window of a
+        # page), found through the ring. Positions are counted from the
+        # first of those pages, which is all the kernel sees of the context:
+        # its time does not grow with it.
+        lower = jnp.maximum(lengths - cfg.attn_window + 1, 0)
+        first = lower // pg
+        touched = ring_table(table, first,
+                             -(-(cfg.attn_window - 1) // pg) + 1, cfg)
+        proj, pools = _kv_decode_attention(
+            bp["window"], h, positions, lengths - first * pg, pools, pidx,
+            lengths % pg, jnp.where(touched >= 0, touched + base, -1), cfg,
+            attn_impl, lora, tuple(WINDOW_PLANES), lower - first * pg)
     else:
         off = lengths % pg
         # This layer's page table into the flat pool; -1 stays unmapped.
@@ -556,7 +705,8 @@ def _paged_decode_block(bp, x, positions, lengths, live, pools, table,  # traced
                              off, ltable, cfg, attn_impl, lora)
     x = x + proj
     h = L.rmsnorm(x, bp["ln2"], cfg)
-    return x + _feed_forward(bp, h, cfg, expert_stack), pools
+    out, pools = _feed_forward(bp, h, cfg, expert_stack, pools=pools)
+    return x + out, pools
 
 
 def _conv_decode(c, h, lengths, pools, pidx, base, table, pg: int,  # traced
@@ -577,9 +727,11 @@ def _conv_decode(c, h, lengths, pools, pidx, base, table, pg: int,  # traced
                                                       mode="drop")}
 
 
-def _qkv_rope(a, h, positions, cfg: DecoderConfig, lora=None):  # traced
+def _qkv_rope(a, h, positions, cfg: DecoderConfig, lora=None,  # traced
+              window: int = 0):
     """Per-head projections of ``h`` [B,S,D] at ``positions`` [B,S]: (q
-    [B,S,H,Dh], k and v [B,S,KV,Dh]), q and k normed and rotated."""
+    [B,S,H,Dh], k and v [B,S,KV,Dh]), q and k normed and rotated (a global
+    layer, ``window`` 0, not rotated where only window layers are)."""
     dt = cfg.activation_dtype
     q = jnp.einsum("bsd,dhk->bshk", h, a["wq"].astype(dt))
     k = jnp.einsum("bsd,dhk->bshk", h, a["wk"].astype(dt))
@@ -590,27 +742,33 @@ def _qkv_rope(a, h, positions, cfg: DecoderConfig, lora=None):  # traced
         q = L.apply_lora_layer(lora, "wq", h, q)
         k = L.apply_lora_layer(lora, "wk", h, k)
         v = L.apply_lora_layer(lora, "wv", h, v)
-    q, k = L.qk_rope(a, q, k, positions, cfg)
+    q, k = L.qk_rope(a, q, k, positions, cfg, window)
     return q, k, v
 
 
 def _kv_decode_attention(a, h, positions, lengths, pools, pidx, off,  # traced
-                         ltable, cfg: DecoderConfig, attn_impl: str, lora):
-    """Per-head K/V: project, write this token's rows at (pidx, off), attend
-    to the slot's pages. Returns (the block's attention output [B,1,D], the
-    planes as written)."""
+                         ltable, cfg: DecoderConfig, attn_impl: str, lora,
+                         planes: tuple = ("k", "v"), lower=None):
+    """Per-head K/V: project, write this token's rows at (pidx, off) of the
+    K and V ``planes``, attend to the pages of ``ltable`` up to key
+    ``lengths``. A window layer hands its own planes, the pages its window
+    touches with ``lengths`` counted from the first of them, and ``lower``
+    [B], the first key a query still sees. Returns (the block's attention
+    output [B,1,D], the planes as written)."""
     dt = cfg.activation_dtype
+    nk, nv = planes
     kv_quant = "ks" in pools
-    q, k, v = _qkv_rope(a, h, positions, cfg, lora)
-    rows = {"k": k[:, 0], "v": v[:, 0]}
-    packed = pools["k"].ndim == 3       # [L*P, pg, KV*Dh]: heads in one row
+    q, k, v = _qkv_rope(a, h, positions, cfg, lora,
+                        window=0 if lower is None else cfg.attn_window)
+    rows = {nk: k[:, 0], nv: v[:, 0]}
+    packed = pools[nk].ndim == 3        # [L*P, pg, KV*Dh]: heads in one row
     if packed:
         rows = {n: r.reshape(r.shape[0], -1) for n, r in rows.items()}
     if kv_quant:
         from kubeflow_tpu.ops.quantization import dequantize_kv, quantize_kv
 
-        rows["k"], rows["ks"] = quantize_kv(k[:, 0])
-        rows["v"], rows["vs"] = quantize_kv(v[:, 0])
+        rows[nk], rows["ks"] = quantize_kv(k[:, 0])
+        rows[nv], rows["vs"] = quantize_kv(v[:, 0])
     pools = {**pools, **{
         name: pools[name].at[pidx, off].set(row, mode="drop")
         for name, row in rows.items()}}
@@ -620,23 +778,23 @@ def _kv_decode_attention(a, h, positions, lengths, pools, pidx, off,  # traced
         )
 
         attn = paged_packed_decode_attention(
-            q, pools["k"], pools["v"], ltable, lengths, cfg.n_kv_heads)
+            q, pools[nk], pools[nv], ltable, lengths, cfg.n_kv_heads)
     elif attn_impl == "pallas":
         from kubeflow_tpu.ops.paged_attention import paged_decode_attention
 
-        attn = paged_decode_attention(q, pools["k"], pools["v"], ltable,
+        attn = paged_decode_attention(q, pools[nk], pools[nv], ltable,
                                       lengths, pool_ks=pools.get("ks"),
-                                      pool_vs=pools.get("vs"))
+                                      pool_vs=pools.get("vs"), lower=lower)
     else:
-        ck = paged_gather(pools["k"], ltable)
-        cv = paged_gather(pools["v"], ltable)
+        ck = paged_gather(pools[nk], ltable)
+        cv = paged_gather(pools[nv], ltable)
         if packed:
             ck = ck.reshape(*ck.shape[:2], cfg.n_kv_heads, cfg.head_dim)
             cv = cv.reshape(*cv.shape[:2], cfg.n_kv_heads, cfg.head_dim)
         if kv_quant:
             ck = dequantize_kv(ck, paged_gather(pools["ks"], ltable), dt)
             cv = dequantize_kv(cv, paged_gather(pools["vs"], ltable), dt)
-        attn = _decode_attention(q, ck, cv, lengths, cfg)
+        attn = _decode_attention(q, ck, cv, lengths, cfg, lower=lower)
     proj = jnp.einsum("bshk,hkd->bsd", attn, a["wo"].astype(dt))
     if lora is not None and "wo" in lora["targets"]:
         proj = L.apply_lora_layer(
@@ -655,7 +813,8 @@ def _latent_decode_attention(a, h, positions, lengths, pools, pidx, off,  # trac
     if lora is not None:
         raise NotImplementedError("LoRA over latent attention projections")
     q_nope, q_rope, row = L.latent_qkv(a, h, positions, cfg)
-    pools = {"ckv": pools["ckv"].at[pidx, off].set(row[:, 0], mode="drop")}
+    pools = {**pools,
+             "ckv": pools["ckv"].at[pidx, off].set(row[:, 0], mode="drop")}
     if attn_impl == "pallas":
         from kubeflow_tpu.ops.paged_attention import (
             paged_latent_decode_attention,
@@ -694,9 +853,9 @@ def _paged_decode_step(params: Params, cache: dict, tokens: jax.Array,  # traced
     x = _embed(params, tokens[:, None], cfg)
     positions = lengths[:, None]
     table = cache["table"]
-    planes = _planes_of(cache)
-    num_pages, pg = _pool_geometry(cache)
-    flat = {n: cache[n].reshape(-1, *cache[n].shape[2:]) for n in planes}
+    pg = _pool_geometry(cache)[1]
+    num_pages = _pages_by_kind(cache)
+    flat = _flat_pools(cache)
 
     def block(bp, carry, layer, gcfg, lora_view, expert_stack):
         return _paged_decode_block(
@@ -709,6 +868,17 @@ def _paged_decode_step(params: Params, cache: dict, tokens: jax.Array,  # traced
     out = {n: p.reshape(cache[n].shape) for n, p in flat.items()}
     out["table"] = table
     return logits, out
+
+
+def _flat_pools(cache: dict) -> dict:  # traced
+    """What a program carries through its layer scans: every plane of the
+    pool viewed flat ``[L*P, ...]`` (a bitcast) and, where the cache has
+    them, the expert rows' running sums."""
+    flat = {n: cache[n].reshape(-1, *cache[n].shape[2:])
+            for n in _planes_of(cache)}
+    if MOE_ROWS in cache:
+        flat[MOE_ROWS] = cache[MOE_ROWS]
+    return flat
 
 
 def paged_decode_multi(params: Params, cache: dict, tokens: jax.Array,  # traced
@@ -850,29 +1020,37 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
 
     if cfg.is_latent and lora is not None:
         raise NotImplementedError("LoRA over latent attention projections")
+    whole_rows = table_rows     # a window layer's ring lies in its first pages
     if context_pages is not None and chunk_reads_context(
             cache, cfg, lora, paged_attn_impl):
         table_rows = table_rows[:, :min(context_pages, table_rows.shape[1])]
     if _chunk_in_place(cache, cfg, lora, paged_attn_impl):
         return _paged_chunk_in_place(params, cache, tokens, table_rows,
                                      start, valid_len, cfg, paged_attn_impl)
-    planes = tuple(n for n in _planes_of(cache)
-                   if plane_kind(n) == "attention")
+    planes = tuple(n for n in _planes_of(cache) if plane_kind(n) != "conv")
     num_pages, pg = _pool_geometry(cache)
+    pages_of = _pages_by_kind(cache)
     b, c = tokens.shape
     kv_quant = "ks" in cache
-    packed = cache["k"].ndim == 4       # [L, P, pg, KV*Dh]: heads in one row
+    packed = "k" in cache and cache["k"].ndim == 4   # [L, P, pg, KV*Dh]
     # Gather each slot's visible cache row, every plane: [L,B,ctx*pg,...]
     # (the bucket covers the chunk's own pages too: the [start, start+C)
-    # update-slice window below).
+    # update-slice window below). A window layer's logical page ``i`` lies
+    # in its ring (``ring_table``): the pages its chunk and the window
+    # before it touch come back in their places, every older place holds a
+    # newer page's rows, which the window's mask never lets a query see.
     # Pad the rows by one chunk of scratch positions so the final chunk's
     # C-wide dynamic_update_slice window can never clamp and overwrite
     # earlier KV (prefix-cache hits start chunks at page — not chunk —
     # alignment, so start + C may exceed the bucket edge). The scratch tail
     # is causal-masked (kv position > any query position) and never
     # scattered back to pages.
-    rows = {n: jax.vmap(lambda pool: paged_gather(pool, table_rows))(
-        cache[n]) for n in planes}
+    tables = {"attention": table_rows}
+    if "window" in pages_of:
+        tables["window"] = ring_table(
+            whole_rows, jnp.zeros((b,), jnp.int32), table_rows.shape[1], cfg)
+    rows = {n: jax.vmap(lambda pool, n=n: paged_gather(
+        pool, tables[plane_kind(n)]))(cache[n]) for n in planes}
     if packed:
         rows = {n: r.reshape(*r.shape[:3], cfg.n_kv_heads, cfg.head_dim)
                 for n, r in rows.items()}
@@ -912,10 +1090,21 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
                for n, w in written.items()}
     else:
         out = {n: cache[n].at[:, pidx, off].set(written[n], mode="drop")
-               for n in planes}
+               for n in planes if plane_kind(n) == "attention"}
+    if "window" in pages_of:
+        widx, _ = _chunk_write_index(whole_rows, start, valid_len, c, pg,
+                                     pages_of["window"], cfg)
+        out.update({n: cache[n].at[:, widx, off].set(written[n], mode="drop")
+                    for n in planes if plane_kind(n) == "window"})
     if "conv" in cache:
         out["conv"] = _chunk_state_after(cache["conv"], filled["conv"],
                                          table_rows, start, valid_len, c, pg)
+    if MOE_ROWS in cache:
+        # The gathered form runs the model's own forward pass, which keeps
+        # no sums; its expert layers' rows are counted by what it was given
+        # (every row of every chunk is routed) and the router's choices are
+        # not seen here: the in-place form (a chip's) counts them.
+        out[MOE_ROWS] = cache[MOE_ROWS]
     return logits, out
 
 
@@ -977,16 +1166,22 @@ def _chunk_state_after(state: jax.Array, zs: jax.Array,  # traced
 
 
 def _chunk_write_index(table_rows: jax.Array, start: jax.Array,  # traced
-                       valid_len: jax.Array, c: int, pg: int, num_pages: int):
+                       valid_len: jax.Array, c: int, pg: int, num_pages: int,
+                       ring_cfg: Optional[DecoderConfig] = None):
     """Where the ``C`` positions of each row's chunk are written: (page
     [B,C], offset [B,C]) off the rows' page tables. A position past its
     row's ``valid_len``, past the table or on an unmapped page gets page
-    ``num_pages``, one past the pool: the write drops."""
+    ``num_pages``, one past the pool: the write drops. ``ring_cfg``: a
+    window layer's planes, where a position's page is its ring's
+    (``ring_table``)."""
     i = jnp.arange(c, dtype=jnp.int32)[None, :]
     pos = start[:, None] + i
     pslot = pos // pg
-    page_id = jnp.take_along_axis(
-        table_rows, jnp.clip(pslot, 0, table_rows.shape[1] - 1), axis=1)
+    if ring_cfg is not None:
+        pslot_at = pslot % _ring_len(ring_cfg, table_rows.shape[1])
+    else:
+        pslot_at = jnp.clip(pslot, 0, table_rows.shape[1] - 1)
+    page_id = jnp.take_along_axis(table_rows, pslot_at, axis=1)
     ok = (i < valid_len[:, None]) & (page_id >= 0) \
         & (pslot < table_rows.shape[1]) & (page_id < num_pages)
     return jnp.where(ok, page_id, num_pages), pos % pg
@@ -999,14 +1194,18 @@ def _chunk_in_place(cache: dict, cfg: DecoderConfig, lora,
     where the engine runs the kernels ("pallas") and
     ``paged_chunk_attention`` takes its planes. What stays on the gathered
     form: int8 pools (scale planes), packed rows and the conv state beside
-    them, a call with LoRA, planes the kernel cannot part by head."""
+    them, a call with LoRA, planes the kernel cannot part by head. A window
+    layer's planes are K and V per head like a global layer's and go the
+    same way."""
     if cfg.is_latent:
         return True
     from kubeflow_tpu.ops.paged_attention import chunk_attention_supported
 
-    k = cache["k"]
+    planes = set(_planes_of(cache))
+    k = cache[next(n for n in ("k", *WINDOW_PLANES) if n in cache)]
     return (attn_impl == "pallas" and lora is None
-            and set(_planes_of(cache)) == {"k", "v"} and k.ndim == 5
+            and planes in ({"k", "v"}, {"k", "v", *WINDOW_PLANES},
+                           set(WINDOW_PLANES)) and k.ndim == 5
             and chunk_attention_supported(*k.shape[3:], k.dtype))
 
 
@@ -1021,19 +1220,24 @@ def chunk_reads_context(cache: dict, cfg: DecoderConfig, lora,
 
 
 def _kv_chunk_attention(a, h, pos, start, pools, pidx, off, ltable,  # traced
-                        cfg: DecoderConfig, attn_impl: str):
+                        cfg: DecoderConfig, attn_impl: str,
+                        planes: tuple = ("k", "v"), window: int = 0):
     """Per-head K/V, a chunk a row: project, write every row's ``C`` K and V
-    rows at (pidx, off), then each prompt attends causally over its own
-    pages through ``paged_chunk_attention``, one call a prompt. Returns (the
+    rows at (pidx, off) of the K and V ``planes``, then each prompt attends
+    causally over its own pages through ``paged_chunk_attention``, one call
+    a prompt. A window layer hands its own planes and ``window``, and as
+    ``ltable`` the pages its chunk and the window before it touch, in
+    order, with ``start`` counted from the first of them. Returns (the
     block's attention output [B,C,D], the planes as written)."""
     from kubeflow_tpu.ops.paged_attention import paged_chunk_attention
 
-    q, k, v = _qkv_rope(a, h, pos, cfg)
-    pools = {"k": pools["k"].at[pidx, off].set(k, mode="drop"),
-             "v": pools["v"].at[pidx, off].set(v, mode="drop")}
+    nk, nv = planes
+    q, k, v = _qkv_rope(a, h, pos, cfg, window=window)
+    pools = {**pools, nk: pools[nk].at[pidx, off].set(k, mode="drop"),
+             nv: pools[nv].at[pidx, off].set(v, mode="drop")}
     attn = jnp.stack([
-        paged_chunk_attention(jnp.swapaxes(q[r], 0, 1), pools["k"],
-                              pools["v"], ltable[r], start[r])
+        paged_chunk_attention(jnp.swapaxes(q[r], 0, 1), pools[nk],
+                              pools[nv], ltable[r], start[r], window=window)
         for r in range(h.shape[0])])                           # [B,H,C,Dh]
     return jnp.einsum("bhsk,hkd->bsd", attn,
                       a["wo"].astype(cfg.activation_dtype)), pools
@@ -1066,7 +1270,8 @@ def _latent_chunk_attention(a, h, pos, start, pools, pidx, off,  # traced
         attn = L.latent_absorbed_attention(
             a, q_nope, q_rope, rows, causal[:, None], cfg)
     return jnp.einsum("bshk,hkd->bsd", attn,
-                      a["wo"].astype(cfg.activation_dtype)), {"ckv": flat}
+                      a["wo"].astype(cfg.activation_dtype)), {
+                          **pools, "ckv": flat}
 
 
 def _paged_chunk_in_place(params: Params, cache: dict,  # traced
@@ -1085,30 +1290,55 @@ def _paged_chunk_in_place(params: Params, cache: dict,  # traced
     layer's slab back. Same contract: only a row's first ``valid_len``
     positions write; ``table_rows`` holds the pages looked at."""
     num_pages, pg = _pool_geometry(cache)
+    pages_of = _pages_by_kind(cache)
     c = tokens.shape[1]
     page, off = _chunk_write_index(table_rows, start, valid_len, c, pg,
                                    num_pages)
     pos = start[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]   # [B,C]
-    flat = {n: cache[n].reshape(-1, *cache[n].shape[2:])
-            for n in _planes_of(cache)}
-    total = next(iter(flat.values())).shape[0]
-    attend = _latent_chunk_attention if cfg.is_latent \
-        else _kv_chunk_attention
+    flat = _flat_pools(cache)
+    if "window" in pages_of:
+        # A window layer's pages: its writes go to the ring's, and it
+        # attends over the pages from the window before the chunk's first
+        # query on, as many as a ring holds, counted from the first of them.
+        wpages = pages_of["window"]
+        wpage, _ = _chunk_write_index(table_rows, start, valid_len, c, pg,
+                                      wpages, cfg)
+        first = jnp.maximum(start - cfg.attn_window + 1, 0) // pg
+        touched = ring_table(table_rows, first,
+                             _ring_len(cfg, table_rows.shape[1]), cfg)
+        rel_start = start - first * pg
+        wtotal = flat[next(iter(WINDOW_PLANES))].shape[0]
 
     def block(bp, carry, layer, gcfg, _, expert_stack):
         x, pools = carry
-        base = layer * num_pages
-        h = L.rmsnorm(x, bp["ln1"], gcfg)
-        # One past this layer's pages is the next layer's page 0: a dropped
-        # write aims past the END of the flat pool.
-        pidx = jnp.where(page < num_pages, base + page, total)
-        ltable = jnp.where(table_rows >= 0, table_rows + base, -1)
-        proj, pools = attend(bp["attn"], h, pos, start, pools, pidx, off,
-                             ltable, gcfg, attn_impl)
+        if "window" in bp:
+            base = layer * wpages
+            h = L.rmsnorm(x, bp["ln1"], gcfg)
+            pidx = jnp.where(wpage < wpages, base + wpage, wtotal)
+            wtable = jnp.where((touched >= 0) & (touched < wpages),
+                               touched + base, -1)
+            proj, pools = _kv_chunk_attention(
+                bp["window"], h, pos, rel_start, pools, pidx, off, wtable,
+                gcfg, attn_impl, tuple(WINDOW_PLANES), gcfg.attn_window)
+        else:
+            base = layer * num_pages
+            h = L.rmsnorm(x, bp["ln1"], gcfg)
+            # One past this layer's pages is the next layer's page 0: a
+            # dropped write aims past the END of the flat pool.
+            pidx = jnp.where(page < num_pages, base + page, total)
+            ltable = jnp.where(table_rows >= 0, table_rows + base, -1)
+            proj, pools = attend(bp["attn"], h, pos, start, pools, pidx, off,
+                                 ltable, gcfg, attn_impl)
         x, h = L.add_rmsnorm(x, proj, bp["ln2"], gcfg)
-        return x + _feed_forward(bp, h, gcfg, expert_stack, valid_len,
-                                 capacity_per_row=True), pools
+        out, pools = _feed_forward(bp, h, gcfg, expert_stack, valid_len,
+                                   capacity_per_row=True, pools=pools)
+        return x + out, pools
 
+    total = next(flat[n] for n in flat if n != MOE_ROWS
+                 and plane_kind(n) == "attention").shape[0] \
+        if "attention" in pages_of else 0
+    attend = _latent_chunk_attention if cfg.is_latent \
+        else _kv_chunk_attention
     x, flat = _scan_layer_groups(
         params, cfg, (_embed(params, tokens, cfg), flat), block)
     return _head_logits(params, x, cfg), {

@@ -126,11 +126,15 @@ def paged_serve_analysis(topology: str, tp: int, *, model: str,
     from kubeflow_tpu.parallel.sharding import shard_params
     from kubeflow_tpu.runtime.device_report import kernel_calls
     from kubeflow_tpu.serve.paged import (
-        paged_chunk_prefill, paged_decode_multi, pool_shapes)
+        engine_pool_shapes, paged_chunk_prefill, paged_decode_multi,
+        ring_pages)
     from kubeflow_tpu.serve.weight_layout import relay, weight_formats
 
     mesh = _mesh_on(topology, {"model": tp}, topo_kwargs=topo_kwargs)
     cfg = preset(model, **overrides)
+    # the ring a sequence keeps in window layers (engine.serving_configs)
+    cfg = dataclasses.replace(cfg, window_ring_pages=ring_pages(
+        cfg, chunk, page_size, max_len // page_size))
     if tp > 1:
         # LLMEngine's rule: no Mosaic norm/GLU kernels over sharded operands.
         cfg = dataclasses.replace(cfg, fused_kernels="off")
@@ -166,8 +170,8 @@ def paged_serve_analysis(topology: str, tp: int, *, model: str,
 
     mpp = max_len // page_size
     cache = {n: sds(shape, dt, kv_sh if n in ("k", "v") else rep)
-             for n, (shape, dt) in pool_shapes(cfg, num_pages,
-                                               page_size).items()}
+             for n, (shape, dt) in engine_pool_shapes(
+                 cfg, slots, num_pages, page_size).items()}
     i32, f32 = (lambda: sds((slots,), jnp.int32)), (
         lambda: sds((slots,), jnp.float32))
 

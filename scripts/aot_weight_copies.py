@@ -89,7 +89,7 @@ def lowered_programs(cfg, batching, dev, *, relaid: bool = True,
         RIDGE_ROWS, chunk_rows_per_weight, serving_configs,
     )
     from kubeflow_tpu.serve.paged import (
-        paged_chunk_prefill, paged_decode_multi, pool_shapes,
+        engine_pool_shapes, paged_chunk_prefill, paged_decode_multi,
     )
     from kubeflow_tpu.serve.weight_layout import relay, weight_formats
 
@@ -109,7 +109,8 @@ def lowered_programs(cfg, batching, dev, *, relaid: bool = True,
     params = relay(params, weight_formats(
         params, cfg, one_chip_pallas=relaid and not auto))
     cache = {n: sds(shape, dt)
-             for n, (shape, dt) in pool_shapes(cfg, pages, pg).items()}
+             for n, (shape, dt) in engine_pool_shapes(
+                 cfg_decode, slots, pages, pg).items()}
     p_in = jax.tree.map(lambda _: Format(Layout.AUTO, dev), params) \
         if auto else None
 
@@ -185,6 +186,38 @@ def weight_copies(text: str, params,
             "leaf": sized.get((hlo_names.get(m["dtype"], m["dtype"]),
                                shape), [])})
     return out
+
+
+_KERNEL_BODY = re.compile(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22')
+
+
+def lowered_fingerprint(lowered) -> str:
+    """A lowered program's text as a short digest that does not move with
+    the checkout's path or a kernel file's line numbers: every Mosaic
+    kernel's serialized body (MLIR bytecode, which carries each operation's
+    source location) is replaced by the digest of that module printed
+    WITHOUT debug information. Two programs with the same fingerprint are
+    the same operations in the same order, kernels' bodies included:
+    ``tests/test_chip_compile.py`` pins the serving cells' against the
+    commit before a change that must leave them alone."""
+    import base64
+    import hashlib
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    ctx = jax_mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+
+    def body(match):
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(match.group(1)))
+            asm = module.operation.get_asm(enable_debug_info=False)
+        return '\\22body\\22: \\22<' + hashlib.sha256(
+            asm.encode()).hexdigest()[:16] + '>\\22'
+
+    text = _KERNEL_BODY.sub(body, lowered.as_text())
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
 def describe(name: str, lowered) -> dict:

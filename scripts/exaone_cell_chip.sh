@@ -1,0 +1,24 @@
+#!/bin/bash
+# The mixed-length cell on the chip, run after run in one call (they share
+# the compile cache): scripts/exaone_cell_chip.sh <tag> <trace> <seed> [...]
+# Each run's result line goes to chiprun_out/<tag>/<seed>.t<trace>.json and
+# its log's tail to .err. DIR=<checkout> runs another checkout's files;
+# TRAFFIC=<file> lays that traffic file over the cell's own first (in the
+# machine's copy of the checkout: a sizing experiment, nothing is committed).
+tag=$1; shift
+here=$(pwd); mkdir -p chiprun_out/$tag
+[ -n "$TRAFFIC" ] && cp $TRAFFIC ${DIR:-.}/benchmark/traffic/batch-mixedlength.json
+while [ $# -ge 2 ]; do
+  trace=$1; seed=$2; shift 2
+  out=$here/chiprun_out/$tag/$seed.t$trace
+  (cd ${DIR:-.} && python3 -m benchmark.run \
+    --workload ${WORKLOAD:-k-exaone-236b-a23b.batch-mixedlength} \
+    --seed $seed --seconds ${SECONDS_:-51} --trace $trace > $out.json 2> $out.log)
+  echo "rc=$? seed=$seed trace=$trace $(tail -c 2600 $out.json)"
+  grep -E "compared|requests:|serve_tokens|setup_s|engine built|NO RESULT|Error|metric |tail:" $out.log | tail -n 30
+  tail -n 400 $out.log > $out.err; rm -f $out.log
+  # the traced tail's modules and its forty heaviest ops
+  cp ${DIR:-.}/benchmark_out/${WORKLOAD:-k-exaone-236b-a23b.batch-mixedlength}/trace_summary.json \
+    $out.summary.json 2>/dev/null
+done
+true

@@ -326,7 +326,7 @@ def main() -> int:
         def one(k, mode, key):
             t1 = time.monotonic()
             out = dispatch(k, mode, key)
-            out.block_until_ready()
+            out[0].block_until_ready()      # (the token buffer, expert rows)
             _log(f"decode program of {k} steps: first dispatch "
                  f"{time.monotonic() - t1:.3f} s")
             return out

@@ -2184,10 +2184,10 @@ class TestSeededRegressions:
         exactly one F602."""
         fresh = _new_findings(
             "kubeflow_tpu/serve/engine.py",
-            "            out, self.cache, st, tbl = self._paged_decode_n(\n"
+            "            out, self.cache, st, tbl, rows = self._paged_decode_n(\n"
             "                self.params, self.cache, self._dstate.arrays,\n"
             "                self._dstate.table, key, k_steps, mode)",
-            "            out, self.cache, st, tbl = self._paged_decode_n(\n"
+            "            out, self.cache, st, tbl, rows = self._paged_decode_n(\n"
             "                self.params, self.cache, self._dstate.arrays,\n"
             "                self._dstate.table, 0.5, k_steps, mode)")
         assert len(fresh) == 1
@@ -2199,10 +2199,10 @@ class TestSeededRegressions:
         per-call tuple produces exactly one F604."""
         fresh = _new_findings(
             "kubeflow_tpu/serve/engine.py",
-            "            out, self.cache, st, tbl = self._paged_decode_n(\n"
+            "            out, self.cache, st, tbl, rows = self._paged_decode_n(\n"
             "                self.params, self.cache, self._dstate.arrays,\n"
             "                self._dstate.table, key, k_steps, mode)",
-            "            out, self.cache, st, tbl = self._paged_decode_n(\n"
+            "            out, self.cache, st, tbl, rows = self._paged_decode_n(\n"
             "                self.params, self.cache, self._dstate.arrays,\n"
             "                self._dstate.table, key, (k_steps,), mode)")
         assert len(fresh) == 1

@@ -350,6 +350,26 @@ SERVING_CELLS = {
     "glm-4.7-flash.batch-longcontext": {"['layers']['attn']['wkva']"},
     "lfm2-24b-a2b.batch-longanswer": {"['layers']['mlp']['router']"},
 }
+# (cell, program) -> scripts/aot_weight_copies.py::lowered_fingerprint of the
+# program as it lowered on the commit BEFORE PR 40 (cc77c2e), which gave the
+# two paged kernels a window, the pool planes of a third kind and the sorted
+# expert layer a held share: none of the four cells has a window or a share,
+# and each of their programs is, operation for operation and kernel body for
+# kernel body, what it was. A change that means to move one of them records
+# the new digest here and says why.
+LOWERED_BEFORE_PR40 = {
+    ("mistral-7b.chat-open", "chunk[1]"): "9a16ab9307d01ba7",
+    ("mistral-7b.chat-open", "decode"): "db316db56b64acbb",
+    ("mixtral-8x7b.batch-longprompt", "chunk[1]"): "03a77e220d400617",
+    ("mixtral-8x7b.batch-longprompt", "chunk[2]"): "24684074f747f6c2",
+    ("mixtral-8x7b.batch-longprompt", "decode"): "ddb784f45f5677a3",
+    ("glm-4.7-flash.batch-longcontext", "chunk[1]"): "57ce1b084168e27a",
+    ("glm-4.7-flash.batch-longcontext", "chunk[2]"): "4ca45b4b80949972",
+    ("glm-4.7-flash.batch-longcontext", "decode"): "51228f8cbfaf3acc",
+    ("lfm2-24b-a2b.batch-longanswer", "chunk[1]"): "6a4770d8248e2dcb",
+    ("lfm2-24b-a2b.batch-longanswer", "chunk[2]"): "3aa5bb964b3b2815",
+    ("lfm2-24b-a2b.batch-longanswer", "decode"): "ac6c858f412bc9bb",
+}
 # program -> the Mosaic kernel its attention goes through, a cell's family
 ATTENTION_KERNELS = {
     "mistral-7b.chat-open": ("paged_decode_attention",
@@ -389,6 +409,20 @@ SERVING_PROGRAMS = [
     (cell, program) for cell in sorted(SERVING_CELLS)
     for program in ("decode", "chunk[1]", "chunk[2]")
     if (cell, program) != ("mistral-7b.chat-open", "chunk[2]")]
+
+
+@pytest.mark.parametrize("cell,program", SERVING_PROGRAMS)
+def test_serving_program_lowers_to_what_it_was_before_pr40(cell_programs,
+                                                           cell, program):
+    """With no window and no share set, the decode and chunk programs of the
+    four accepted serving cells lower, at the cells' shapes for a described
+    v5e, to the programs of the commit before the window went into
+    ``paged_decode_attention`` / ``paged_chunk_attention`` and the share
+    into ``_moe_sorted``."""
+    from scripts.aot_weight_copies import lowered_fingerprint
+
+    assert lowered_fingerprint(cell_programs(cell)[program]) \
+        == LOWERED_BEFORE_PR40[cell, program]
 
 
 @pytest.mark.parametrize("cell,program", SERVING_PROGRAMS)

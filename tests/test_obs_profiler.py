@@ -160,7 +160,9 @@ ENGINE_KEYS = {
     "kv_bytes_per_token", "kv_pool_bytes", "state_pool_bytes",
     "state_tail_writes", "weights_relaid_bytes"}
 ENGINE_CONSTANTS = {"slots", "kv_bytes_per_token", "kv_pool_bytes",
-                    "state_pool_bytes", "weights_relaid_bytes"}
+                    "state_pool_bytes", "weights_relaid_bytes",
+                    "kv_window_pool_bytes", "kv_global_pool_bytes",
+                    "kv_window_pages_a_sequence"}
 
 
 def test_engine_counters_exist_at_construction_and_only_grow(engine):
