@@ -902,15 +902,18 @@ class LLMEngine:
             # warmed before traffic are the ones traffic reaches.
             self.cache = _committed(self.cache)
 
-        def _chunk_rows_fn(p, c, t, tr, st, vl, ncp, lr, ai):
+        def _chunk_rows_fn(p, c, t, tr, st, vl, ncp, lr, ai,
+                           logits_at="all", wanted=None):
             return _pin2(
                 paged_chunk_prefill(
                     p, c, t, tr, st, vl, cfg_prefill, context_pages=ncp,
-                    lora=lr, adapter_idx=ai, paged_attn_impl=pattn),
+                    lora=lr, adapter_idx=ai, paged_attn_impl=pattn,
+                    logits_at=logits_at, wanted=wanted),
                 self._pin)
 
         # ONE prompt's chunk: tokens [1,C], its table row, scalar start
-        # and valid length; [C,V] logits.
+        # and valid length; [C,V] logits, every position's (callers
+        # outside the engine compare them all).
         self._paged_chunk = jax.jit(
             lambda p, c, t, tr, st, vl, ncp, lr=None, ai=None: _row0(
                 _chunk_rows_fn(p, c, t, tr[None], st[None], vl[None],
@@ -919,8 +922,14 @@ class LLMEngine:
         if not chunk_reads_context(self.cache, cfg_prefill, None, pattn):
             self._paged_chunk = _OneContext(self._paged_chunk, self._mpp)
         # The chunks of ALL in-flight prefills in one program (tokens
-        # [B,C], a table row, a start and a valid length a row; [B,C,V]
-        # logits), so a scheduler pass reads every weight once. Built
+        # [B,C], a table row, a start, a valid length and "this row ends
+        # its prompt" a row), so a scheduler pass reads every weight once.
+        # It returns [B,V] logits, the head at each row's LAST valid
+        # position: the one row the engine samples from, and that only of
+        # a prompt's last chunk, so a program in which no row ends one
+        # runs no head at all (the head over every position was 7% of a
+        # long-context cell's device time and 634 MB a result at a
+        # vocabulary of 155k). Built
         # only where one chunk leaves the weights under-used
         # (``chunk_rows_per_weight``): a dense model at 512 tokens
         # dispatches exactly as it always did. It is dispatched at ONE
@@ -936,9 +945,10 @@ class LLMEngine:
                 cfg_prefill, self.chunk_size) < RIDGE_ROWS:
             self._chunk_rows = self.max_concurrent_prefills
             self._paged_chunks = jax.jit(
-                lambda p, c, t, tr, st, vl, ncp, lr=None, ai=None:
-                _chunk_rows_fn(p, c, t, tr, st, vl, ncp, lr, ai),
-                static_argnums=(6,), donate_argnums=(1,))
+                lambda p, c, t, tr, st, vl, ends, ncp, lr=None, ai=None:
+                _chunk_rows_fn(p, c, t, tr, st, vl, ncp, lr, ai, "last",
+                               ends),
+                static_argnums=(7,), donate_argnums=(1,))
 
         def _paged_decode_fn(p, c, st, tbl, key, n, m, lr=None,
                              _impl=pattn):
@@ -1205,6 +1215,8 @@ class LLMEngine:
         self._decode_context_tokens = 0     # lockfree: scheduler-confined counter
         self._prefill_programs_dispatched = 0   # lockfree: scheduler-confined counter
         self._prefill_chunks_dispatched = 0     # lockfree: scheduler-confined counter
+        self._prefill_row_programs_dispatched = 0   # lockfree: scheduler-confined counter
+        self._prefill_programs_with_end = 0     # lockfree: scheduler-confined counter
         self._prefill_tokens_dispatched = 0     # lockfree: scheduler-confined counter
         self._state_tail_writes = 0             # lockfree: scheduler-confined counter
         # Admit passes that sent a prefill program; chunks that were due in
@@ -1301,7 +1313,7 @@ class LLMEngine:
         written). Traffic reaches several concurrent prefills only where
         arrivals fall together, so no warm-up of a caller's can be relied on
         to reach it; the program set is the engine's own, and fixed from
-        here on. Also warms the read of one row's last logits."""
+        here on. Also warms the read of one row's logits."""
         rows, C = self._chunk_rows, self.chunk_size
         lora = () if self._lora is None else (
             self._lora.buffers, jnp.full((rows,), -1, jnp.int32))
@@ -1309,8 +1321,8 @@ class LLMEngine:
             self.params, self.cache, jnp.zeros((rows, C), jnp.int32),
             jnp.full((rows, self._mpp), -1, jnp.int32),
             jnp.zeros((rows,), jnp.int32), jnp.zeros((rows,), jnp.int32),
-            self._mpp, *lora)
-        jax.block_until_ready(logits[rows - 1, C - 1])
+            jnp.zeros((rows,), jnp.bool_), self._mpp, *lora)
+        jax.block_until_ready(logits[rows - 1])
 
     # -- mesh-mode helpers -----------------------------------------------------
 
@@ -1448,6 +1460,14 @@ class LLMEngine:
             "prefill_programs_dispatched": self._prefill_programs_dispatched,
             "prefill_chunks_dispatched": self._prefill_chunks_dispatched,
             "prefill_tokens_dispatched": self._prefill_tokens_dispatched,
+            # of those programs, the ones sent through the program over
+            # several prompts' rows (whose head runs at one position a
+            # row), and the ones in which some row ended its prompt (the
+            # only programs whose logits anybody reads: the program over
+            # rows runs its head in no other)
+            "prefill_row_programs_dispatched":
+                self._prefill_row_programs_dispatched,
+            "prefill_programs_with_end": self._prefill_programs_with_end,
             # scheduler iterations that sent a prefill program (programs
             # over passes: the programs every live stream waited for at
             # once), and the chunks that were due in a pass and waited for
@@ -1861,13 +1881,16 @@ class LLMEngine:
     def _dispatch_chunks(self, group: "list[_Chunking]") -> None:
         """ONE program for the next chunk of every prefill in ``group``
         (their pages are reserved): row ``r`` carries ``group[r]``'s tokens,
-        table row, start and valid length. One prefill alone, or an engine
-        that built no program over several rows, takes the one-row program
-        it always took."""
+        table row, start, valid length and whether the chunk ends its
+        prompt (only then are the row's logits read). One prefill alone, or
+        an engine that built no program over several rows, takes the
+        one-row program it always took."""
         C = self.chunk_size
         rows = 1 if len(group) == 1 else self._chunk_rows
         reals = [min(C, len(ch.request.prompt_tokens) - ch.pos)
                  for ch in group]
+        ends = [ch.pos + real == len(ch.request.prompt_tokens)
+                for ch, real in zip(group, reals)]
         chunk = np.zeros((rows, C), np.int32)
         for r, (ch, real) in enumerate(zip(group, reals)):
             chunk[r, :real] = ch.request.prompt_tokens[ch.pos:ch.pos + real]
@@ -1884,13 +1907,15 @@ class LLMEngine:
                 table = np.full((rows, self._mpp), -1, np.int32)
                 start = np.zeros((rows,), np.int32)
                 valid = np.zeros((rows,), np.int32)
+                wanted = np.zeros((rows,), np.bool_)
                 for r, (ch, real) in enumerate(zip(group, reals)):
                     table[r] = self._table[ch.slot]
-                    start[r], valid[r] = ch.pos, real
+                    start[r], valid[r], wanted[r] = ch.pos, real, ends[r]
                 logits, self.cache = self._paged_chunks(
                     self.params, self.cache, jnp.asarray(chunk),
                     jnp.asarray(table), jnp.asarray(start),
-                    jnp.asarray(valid), self._mpp, *lora)
+                    jnp.asarray(valid), jnp.asarray(wanted), self._mpp,
+                    *lora)
             else:
                 # Static context bucket (next power of two covering the
                 # pages this chunk can see): chunk cost tracks its position,
@@ -1905,6 +1930,8 @@ class LLMEngine:
                     context_bucket(ch.pos, C, self.page_size, self._mpp),
                     *lora)
         self._prefill_programs_dispatched += 1
+        self._prefill_row_programs_dispatched += rows > 1
+        self._prefill_programs_with_end += any(ends)
         self._prefill_chunks_dispatched += len(group)
         self._prefill_tokens_dispatched += sum(reals)
         if self._state_pool_bytes:
@@ -1915,7 +1942,7 @@ class LLMEngine:
         for r, (ch, real) in enumerate(zip(group, reals)):
             req, plen = ch.request, len(ch.request.prompt_tokens)
             ch.pos += real
-            if ch.pos < plen:
+            if not ends[r]:
                 continue
             self._chunkings.remove(ch)
             # Index the prompt's KV for cross-request reuse — LIVE: the
@@ -1923,10 +1950,11 @@ class LLMEngine:
             # (decode writes start at plen, past every claimed position —
             # COW by construction).
             self._kv_register(req.prompt_tokens, ch.slot, plen)
-            # Logits index of the prompt's true last token in this chunk.
+            # The logits of the prompt's true last token: the program over
+            # rows returns that position's alone, a row a prompt.
             self._pending_first.append(
                 (req, ch.slot, plen,
-                 logits[r, real - 1] if rows > 1 else logits[real - 1]))
+                 logits[r] if rows > 1 else logits[real - 1]))
 
     def _advance_chunked(self, due: "Optional[list[_Chunking]]" = None,
                          programs: Optional[int] = None) -> int:

@@ -544,9 +544,12 @@ def _embed(params: Params, tokens: jax.Array, cfg: DecoderConfig):  # traced
     return x
 
 
-def _head_logits(params: Params, x: jax.Array, cfg: DecoderConfig):  # traced
-    """Final norm and output head: [B,S,D] -> [B,S,V] float32."""
-    x = L.rmsnorm(x, params["final_norm"], cfg)
+def _head_logits(params: Params, x: jax.Array, cfg: DecoderConfig,  # traced
+                 normed: bool = False):
+    """Final norm (unless ``x`` is ``normed`` already) and output head:
+    [B,S,D] -> [B,S,V] float32."""
+    if not normed:
+        x = L.rmsnorm(x, params["final_norm"], cfg)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.activation_dtype),
                         preferred_element_type=jnp.float32)
@@ -975,7 +978,8 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
                         attn_impl: str = "xla",
                         context_pages: Optional[int] = None,
                         lora=None, adapter_idx=None,
-                        paged_attn_impl: str = "gather"):
+                        paged_attn_impl: str = "gather",
+                        logits_at: str = "all", wanted=None):
     """Prefill one chunk of EACH of ``B`` prompts in one program: row ``b``
     is ``tokens[b]`` ([B,C]) at positions [start[b], start[b]+C) of the slot
     whose pages are ``table_rows[b]`` ([B,mpp]), ``valid_len[b]`` of them
@@ -1015,9 +1019,28 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
     #4). The caller buckets the count (powers of two) so the trace set stays
     logarithmic. The in-place form of a per-head pool does not read it: its
     kernel's cost follows each row's own context whatever the table's
-    length. Returns ([B,C,V] logits, cache)."""
+    length.
+
+    ``logits_at`` (STATIC) says which positions' logits come back. "all":
+    ([B,C,V] logits, cache), the head over every position of every row.
+    "last": ([B,V] logits, cache), the final norm and the head at ONE
+    position a row, its last valid one (``valid_len[b] - 1``): what a
+    caller that samples a prompt's first token reads, at a ``C``-th of the
+    head's work and of the result's bytes. The same norm and matrix on the
+    same row of activations in both forms of the program; a dead row's is a
+    row nobody reads. The pool is written alike either way. ``wanted``
+    ([B] bool, with "last" only) names the rows whose logits the caller
+    will read: where NO row is wanted (six in seven programs of long
+    prompts: only a prompt's last chunk is sampled from) the norm and the
+    head are not run at all and zeros come back, one ``lax.cond`` around
+    them and nothing else; where any is, every row's come back as without
+    it."""
     from kubeflow_tpu.models.decoder import decoder_forward
 
+    if logits_at not in ("all", "last"):
+        raise ValueError(f"unknown logits_at {logits_at!r}; one of all|last")
+    if wanted is not None and logits_at != "last":
+        raise ValueError('wanted rows are named with logits_at="last" only')
     if cfg.is_latent and lora is not None:
         raise NotImplementedError("LoRA over latent attention projections")
     whole_rows = table_rows     # a window layer's ring lies in its first pages
@@ -1026,7 +1049,8 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
         table_rows = table_rows[:, :min(context_pages, table_rows.shape[1])]
     if _chunk_in_place(cache, cfg, lora, paged_attn_impl):
         return _paged_chunk_in_place(params, cache, tokens, table_rows,
-                                     start, valid_len, cfg, paged_attn_impl)
+                                     start, valid_len, cfg, paged_attn_impl,
+                                     logits_at, wanted)
     planes = tuple(n for n in _planes_of(cache) if plane_kind(n) != "conv")
     num_pages, pg = _pool_geometry(cache)
     pages_of = _pages_by_kind(cache)
@@ -1071,7 +1095,11 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
     logits, filled, _ = decoder_forward(params, tokens, cfg, kv_caches=caches,
                                         attn_impl=attn_impl,
                                         valid_len=valid_len, lora=lr,
-                                        moe_capacity_per_row=True)
+                                        moe_capacity_per_row=True,
+                                        skip_head=logits_at == "last")
+    if logits_at == "last":     # ``logits`` is the final norm's output here
+        logits = _last_logits(params, logits, valid_len, cfg, wanted,
+                              normed=True)
     # Scatter the chunks' tokens back into the pool per (page, offset):
     # row b's position start[b]+i lands on table_rows[b, (start[b]+i)//pg]
     # at offset (start[b]+i)%pg. Invalid rows (past valid_len, or an
@@ -1274,10 +1302,29 @@ def _latent_chunk_attention(a, h, pos, start, pools, pidx, off,  # traced
                           **pools, "ckv": flat}
 
 
+def _last_logits(params: Params, x: jax.Array, valid_len: jax.Array,  # traced
+                 cfg: DecoderConfig, wanted=None,
+                 normed: bool = False) -> jax.Array:
+    """[B,C,D] -> [B,V] float32: ``_head_logits`` of each row at its last
+    valid position (a dead row's position 0); zeros, and no head, where
+    ``wanted`` ([B] bool) is given and names no row."""
+    def head():
+        at = jnp.maximum(valid_len - 1, 0)
+        last = jnp.take_along_axis(x, at[:, None, None], axis=1)  # [B,1,D]
+        return _head_logits(params, last, cfg, normed)[:, 0]
+
+    if wanted is None:
+        return head()
+    return jax.lax.cond(
+        jnp.any(wanted), head,
+        lambda: jnp.zeros((x.shape[0], cfg.vocab_size), jnp.float32))
+
+
 def _paged_chunk_in_place(params: Params, cache: dict,  # traced
                           tokens: jax.Array, table_rows: jax.Array,
                           start: jax.Array, valid_len: jax.Array,
-                          cfg: DecoderConfig, attn_impl: str):
+                          cfg: DecoderConfig, attn_impl: str,
+                          logits_at: str = "all", wanted=None):
     """``paged_chunk_prefill`` built like the decode step and not like
     ``decoder_forward``'s cache path: every plane of the pool is carried
     whole and flat ``[L*P, pg, ...]`` through the layer scans, a layer
@@ -1341,5 +1388,6 @@ def _paged_chunk_in_place(params: Params, cache: dict,  # traced
         else _kv_chunk_attention
     x, flat = _scan_layer_groups(
         params, cfg, (_embed(params, tokens, cfg), flat), block)
-    return _head_logits(params, x, cfg), {
-        n: p.reshape(cache[n].shape) for n, p in flat.items()}
+    logits = _last_logits(params, x, valid_len, cfg, wanted) \
+        if logits_at == "last" else _head_logits(params, x, cfg)
+    return logits, {n: p.reshape(cache[n].shape) for n, p in flat.items()}

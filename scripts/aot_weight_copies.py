@@ -6,7 +6,9 @@ Lowers the cell's serving programs for a DESCRIBED ``v5e:2x2`` (nothing
 runs, no chip is needed) as the engine builds them on one chip: the
 one-step ``paged_decode_multi`` over the cell's slots and pool, and
 ``paged_chunk_prefill`` over one row and, where the engine builds it,
-over ``max_concurrent_prefills`` rows; ``attn_impl="pallas"``, the
+over ``max_concurrent_prefills`` rows (the head at each row's last valid
+position, under a conditional on "some row ends its prompt", as the engine
+asks for it); ``attn_impl="pallas"``, the
 parameters in the formats ``serve/weight_layout.py`` gives them
 (``--default-layouts``: every leaf as the compiler lays it out, the tree
 as it stood before PR 39). It prints, a program:
@@ -71,7 +73,7 @@ def serving_cell(name: str):
 
 
 def lowered_programs(cfg, batching, dev, *, relaid: bool = True,
-                     auto: bool = False) -> dict:
+                     auto: bool = False, rows_logits_at: str = "last") -> dict:
     """``{program: jax.stages.Lowered}`` of an engine over ``cfg`` and
     ``batching`` on the one described chip ``dev`` (a sharding): "decode"
     (one step a dispatch), "chunk[1]" and, where the engine builds the
@@ -79,6 +81,8 @@ def lowered_programs(cfg, batching, dev, *, relaid: bool = True,
     parameters in the engine's formats, else all in the default layout.
     ``auto``: the parameters' layouts left to the compiler, whatever
     ``relaid`` says (``compiled.input_formats`` then says what it chose).
+    ``rows_logits_at``: the positions whose logits "chunk[N]" returns, the
+    engine's "last" unless a comparison wants the other form.
     The caller has made ``jax.default_backend()`` answer "tpu"."""
     import jax
     import jax.numpy as jnp
@@ -95,7 +99,8 @@ def lowered_programs(cfg, batching, dev, *, relaid: bool = True,
 
     b = batching
     cfg_prefill, cfg_decode = serving_configs(cfg, b)
-    slots, pg, chunk = b.max_batch_size, b.page_size, b.chunked_prefill_tokens
+    slots, pg = b.max_batch_size, b.page_size
+    chunk_tokens = b.chunked_prefill_tokens
     mpp = b.max_seq_len // pg
     pages = int(b.max_pages or slots * mpp)
 
@@ -136,15 +141,23 @@ def lowered_programs(cfg, batching, dev, *, relaid: bool = True,
         sds((2,), jnp.uint32))}
     rows = [1]
     if b.max_concurrent_prefills > 1 and chunk_rows_per_weight(
-            cfg_prefill, chunk) < RIDGE_ROWS:
+            cfg_prefill, chunk_tokens) < RIDGE_ROWS:
         rows.append(b.max_concurrent_prefills)
     for n in rows:
+        # the engine's program over rows returns the last position's logits
+        # and takes "this row ends its prompt" a row
+        last = n > 1 and rows_logits_at == "last"
+
+        # (a lambda, as the engine's: the lowered module's name is part of
+        # the digests tests/test_chip_compile.py pins)
         out[f"chunk[{n}]"] = jit(
-            lambda p, c, t, tr, st, vl: paged_chunk_prefill(
+            lambda p, c, t, tr, st, vl, ends=None, at="last" if last
+            else "all": paged_chunk_prefill(
                 p, c, t, tr, st, vl, cfg_prefill, context_pages=mpp,
-                paged_attn_impl="pallas"), 5).lower(
-            params, cache, sds((n, chunk)), sds((n, mpp)), sds((n,)),
-            sds((n,)))
+                paged_attn_impl="pallas", logits_at=at, wanted=ends),
+            5 + last).lower(
+            params, cache, sds((n, chunk_tokens)), sds((n, mpp)), sds((n,)),
+            sds((n,)), *([sds((n,), jnp.bool_)] if last else []))
     return out
 
 
@@ -221,12 +234,14 @@ def lowered_fingerprint(lowered) -> str:
 
 
 def describe(name: str, lowered) -> dict:
-    """A lowered program compiled: its temporaries, its arguments, its
-    copies of a million elements or more."""
+    """A lowered program compiled: its temporaries, its arguments, what it
+    returns beyond the buffers it was donated, its copies of a million
+    elements or more."""
     compiled = lowered.compile()
     ma = compiled.memory_analysis()
     return {"program": name, "temp_bytes": ma.temp_size_in_bytes,
             "argument_bytes": ma.argument_size_in_bytes,
+            "result_bytes": ma.output_size_in_bytes - ma.alias_size_in_bytes,
             "copies": weight_copies(compiled.as_text(),
                                     lowered.args_info[0][0])}
 

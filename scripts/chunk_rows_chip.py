@@ -12,21 +12,30 @@ and b (a longer context, a short last chunk) are prefilled twice into pages of
 their own; then the next chunk of each goes through the one-row program in
 turn on the first copy and through ONE two-row program on the second, and a's
 once more beside a DEAD row on a third (the same program: the engine
-dispatches it at one static context, the whole table). Printed: the per-position relative
-error of the logits (``benchmark.correctness.position_errors``, medians): each
-program against the cell's plain float32 reference on the same weights and
-tokens, which is what the benchmark's ``correct`` compares and is held to the
-cell's own limit here too; rows against one row; beside a dead row against
-beside b, which must be 0; and the largest difference of the pool rows
-written. Exit 1 where a number is over its limit. Rows against one row is
-printed and not judged: two compiled programs need not round alike (the
-gathered form read 0.0 here, PR 29; the in-place form of a per-head pool
-reads what either reads against the reference, from ONE unit in the last
-place of the K rows the first layer writes, the rotation fused into the
-projection in one program and not in the other: PERF.md section 6, PR 36).
+dispatches it at one static context, the whole table). The program over rows
+returns ``[B, V]``, the head at each row's LAST valid position (PR 41), so a
+row is held against the one-row program's ``logits[valid - 1]``. Printed: the
+relative error of the logits (``benchmark.correctness.position_errors``): the
+one-row program against the cell's plain float32 reference on the same weights
+and tokens, median over the chunk's positions, which is what the benchmark's
+``correct`` compares and is held to the cell's own limit here too; a row's one
+position against the reference's (held to the limit, or to twice what the
+one-row program reads at that position where that is more: one position may
+sit on an expert choice that flips in bfloat16); rows against one row there
+(relative, the largest absolute difference, and whether the greedy token is
+the same); beside a dead row against beside b, which must be 0; and the
+largest difference of the pool rows written. Exit 1 where a number is over its
+limit. Rows against one row is printed and not judged: two compiled programs
+need not round alike (the gathered form read 0.0 here, PR 29; the in-place
+form of a per-head pool reads what either reads against the reference, from
+ONE unit in the last place of the K rows the first layer writes, the rotation
+fused into the projection in one program and not in the other: PERF.md
+section 6, PR 36).
 
 ``cell`` is ``python3 -m benchmark.run`` with the engine's counters printed:
-the window's ``prefill_chunks_dispatched / prefill_programs_dispatched`` and
+the window's ``prefill_chunks_dispatched / prefill_programs_dispatched``, the
+programs sent through the program over rows and those in which a prompt ended
+(``prefill_row_programs_dispatched``, ``prefill_programs_with_end``: PR 41) and
 tokens, from the snapshots the harness takes (they ride in the run's record,
 which the result line does not print), the Pallas kernels in each program
 variant the warm-up reached (``LLMEngine.program_kernels``), the device's
@@ -67,6 +76,7 @@ def check(workload: str, seed: int) -> int:
 
     from kubeflow_tpu.core.serving import BatchingSpec
     from kubeflow_tpu.serve.engine import LLMEngine
+    from kubeflow_tpu.models.decoder import plane_kind
     from kubeflow_tpu.serve.paged import context_bucket
 
     cfg = architecture.part(conf, "program").program_config(conf)
@@ -113,18 +123,25 @@ def check(workload: str, seed: int) -> int:
             start[r], valid[r] = plan[k]
             table[r] = tables[copy, k]
             block[r, :valid[r]] = toks[k][start[r]:start[r] + valid[r]]
+        # every live row's logits are wanted (a program in which no row ends
+        # its prompt runs no head: PR 41)
         logits, eng.cache = eng._paged_chunks(
             eng.params, eng.cache, jnp.asarray(block), jnp.asarray(table),
-            jnp.asarray(start), jnp.asarray(valid), mpp)
+            jnp.asarray(start), jnp.asarray(valid), jnp.asarray(valid > 0),
+            mpp)
         return logits
 
     def written(copy, k):
-        """The pool rows of ``k``'s next chunk in ``copy``'s pages."""
+        """The pool rows of ``k``'s next chunk in ``copy``'s pages: the
+        pages the chunk touched, of a window layer's planes the sequence's
+        whole ring (its first pages); the expert rows' sums are no plane."""
         start, valid = plan[k]
-        pages = tables[copy, k][start // pg:-(-(start + valid) // pg)]
-        return {n: np.asarray(jax.device_get(
-            eng.cache[n][:, jnp.asarray(pages)])).astype(np.float32)
-            for n in eng.cache}
+        row = tables[copy, k]
+        touched = row[start // pg:-(-(start + valid) // pg)]
+        return {n: np.asarray(jax.device_get(plane[:, jnp.asarray(
+            row[:eng._ring] if plane_kind(n) == "window" else touched)])
+        ).astype(np.float32) for n, plane in eng.cache.items()
+            if plane.ndim > 2}
 
     for copy in ("one", "rows", "dead"):
         for k, (start, _) in plan.items():
@@ -139,27 +156,33 @@ def check(workload: str, seed: int) -> int:
         start, valid = plan[k]
         want = correctness.reference_logits(params, toks[k][:start + valid],
                                             conf, last=valid)
-        for name, got in (("rows", both[r]), ("one", alone[k])):
-            out[f"logits_{k}_{name}_vs_reference_median"] = float(np.median(
-                correctness.position_errors(got[:valid], want)))
-        err = correctness.position_errors(both[r, :valid], alone[k][:valid])
-        out[f"logits_{k}_rows_vs_one_median"] = float(np.median(err))
-        out[f"logits_{k}_rows_vs_one_max"] = float(np.max(err))
-        out[f"argmax_{k}_agree"] = float(np.mean(np.asarray(
-            jnp.argmax(both[r, :valid], -1) == jnp.argmax(alone[k][:valid],
-                                                          -1))))
+        last = alone[k][valid - 1:valid]
+        out[f"logits_{k}_one_vs_reference_median"] = float(np.median(
+            correctness.position_errors(alone[k][:valid], want)))
+        for name, got in (("rows", both[r:r + 1]), ("one", last)):
+            out[f"logits_{k}_{name}_vs_reference_last"] = float(
+                correctness.position_errors(got, want[-1:])[0])
+        out[f"logits_{k}_rows_vs_one_last"] = float(
+            correctness.position_errors(both[r:r + 1], last)[0])
+        out[f"logits_{k}_rows_vs_one_max_abs"] = float(
+            jnp.max(jnp.abs(both[r] - last[0])))
+        out[f"logits_{k}_scale"] = float(jnp.max(jnp.abs(last)))
+        out[f"argmax_{k}_agree"] = bool(
+            jnp.argmax(both[r]) == jnp.argmax(last[0]))
         got, want = written("rows", k), written("one", k)
         out[f"pool_{k}_rows_vs_one_max_abs"] = max(
             float(np.max(np.abs(got[n] - want[n]))) for n in got)
         out[f"pool_{k}_scale"] = max(float(np.max(np.abs(want[n])))
                                      for n in want)
     out["logits_a_dead_vs_b_max"] = float(np.max(correctness.position_errors(
-        beside_dead[0], both[0])))
+        beside_dead[:1], both[:1])))
     got, want = written("dead", "a"), written("rows", "a")
     out["pool_a_dead_vs_b_max_abs"] = max(
         float(np.max(np.abs(got[n] - want[n]))) for n in got)
-    ok = (all(out[f"logits_{k}_{name}_vs_reference_median"] < limit
-              for k in plan for name in ("rows", "one"))
+    ok = (all(out[f"logits_{k}_one_vs_reference_median"] < limit
+              and out[f"logits_{k}_rows_vs_reference_last"] < max(
+                  limit, 2 * out[f"logits_{k}_one_vs_reference_last"])
+              for k in plan)
           and out["logits_a_dead_vs_b_max"] == 0.0
           and out["pool_a_dead_vs_b_max_abs"] == 0.0)
     out["ok"] = ok
@@ -247,7 +270,8 @@ def run_cell(argv: list) -> int:
     if len(snapshots) >= 2 and snapshots[0] and "engine" in snapshots[0]:
         before, after = snapshots[0]["engine"], snapshots[1]["engine"]
         d = {k: after[k] - before[k] for k in after
-             if k.startswith("prefill_") and k.endswith("_dispatched")}
+             if k.startswith("prefill_") and (
+                 k.endswith("_dispatched") or k.endswith("_with_end"))}
         if d.get("prefill_programs_dispatched"):
             d["chunks_per_program"] = (d["prefill_chunks_dispatched"]
                                        / d["prefill_programs_dispatched"])

@@ -14,8 +14,10 @@ Builds the cell's engine as the benchmark does (weights from the seed, the
    global layer's ``paged_decode_attention``, the window layers'
    ``paged_window_decode_attention`` (its grid is two pages a stream whatever
    the context) and the expert layers' grouped matmuls;
-2. the engine's two-row chunk program at a short and at a long start: per
-   call ``paged_chunk_attention``, ``paged_window_chunk_attention`` and the
+2. the engine's two-row chunk program (``[2, V]`` logits: the head at each
+   row's last valid position, here with both rows' wanted, PR 41) at a short
+   and at a long start: per call ``paged_chunk_attention``,
+   ``paged_window_chunk_attention`` and the
    grouped matmuls, beside the rows the program's expert layers routed and
    held (the cache's running sums).
 
@@ -172,7 +174,8 @@ def main(argv=None) -> int:
 
         def run():
             logits, eng.cache = eng._paged_chunks(
-                eng.params, eng.cache, block, dtable[:2], starts, valid, mpp)
+                eng.params, eng.cache, block, dtable[:2], starts, valid,
+                valid > 0, mpp)
             return logits
         before = rows_now()
         numbers = traced(run, args.calls)

@@ -228,6 +228,34 @@ def _tail(record: dict) -> None:
     for name, (n, total) in sorted(names.items(), key=lambda kv: -kv[1][1]):
         _log(f"tail span {name}: {n} x {1e3 * total / n:.3f} ms = "
              f"{total:.4f} s")
+    # The chunk programs by the chunks they carried (ISSUE 41: the one-row
+    # program apart from the program over rows): the k-th
+    # ``engine.prefill_dispatch`` span sent the k-th long ``jit__lambda``;
+    # the capture's edges may cut one program or one span.
+    carried = sorted((t0, attrs.get("chunks", 1))
+                     for thread in record.get("host_spans") or []
+                     for name, t0, _, attrs in thread
+                     if name == "engine.prefill_dispatch")
+    programs = sorted((t0, dur) for name, t0, dur
+                      in trace["devices"][0]["modules"]
+                      if name.startswith("jit__lambda") and dur >= 2e-3)
+    if len(programs) == len(carried) + 1:
+        programs = programs[1:]
+    elif len(carried) == len(programs) + 1:
+        carried = carried[:-1]
+    if carried and len(carried) == len(programs):
+        by_chunks: dict = {}
+        for (_, chunks), (_, dur) in zip(carried, programs):
+            by_chunks.setdefault(chunks, []).append(dur)
+        for chunks, durs in sorted(by_chunks.items()):
+            durs.sort()
+            _log(f"tail chunk program carrying {chunks}: {len(durs)} x "
+                 f"median {1e3 * durs[len(durs) // 2]:.3f} ms (min "
+                 f"{1e3 * durs[0]:.3f}, max {1e3 * durs[-1]:.3f}) = "
+                 f"{sum(durs):.4f} s")
+    elif carried:
+        _log(f"tail chunk programs {len(programs)} against "
+             f"{len(carried)} dispatch spans: not paired")
 
 
 def main() -> int:

@@ -370,6 +370,16 @@ LOWERED_BEFORE_PR40 = {
     ("lfm2-24b-a2b.batch-longanswer", "chunk[2]"): "3aa5bb964b3b2815",
     ("lfm2-24b-a2b.batch-longanswer", "decode"): "ac6c858f412bc9bb",
 }
+# The program over rows as the engine builds it since PR 41 (the head at each
+# row's last valid position, ``[2, V]`` logits, under a conditional on "some
+# row ends its prompt"): another program than "chunk[2]" above, which is its
+# all-position form and still lowers to what it was. Recorded on PR 41's tree
+# for the next change that must leave it alone.
+ROWS_PROGRAM_SINCE_PR41 = {
+    "glm-4.7-flash.batch-longcontext": "8d33877ceb87639a",
+    "lfm2-24b-a2b.batch-longanswer": "661f820c73930e41",
+    "mixtral-8x7b.batch-longprompt": "1f4f9199db3e0328",
+}
 # program -> the Mosaic kernel its attention goes through, a cell's family
 ATTENTION_KERNELS = {
     "mistral-7b.chat-open": ("paged_decode_attention",
@@ -388,17 +398,21 @@ def cell_programs(chip):
     """``(cell, relaid) -> {program: Lowered}``, each cell lowered once a
     module: the benchmark's sizes (configuration and traffic files), the
     engine's own construction (scripts/aot_weight_copies.py), the parameters
-    in the formats the engine's function returns or all default."""
+    in the formats the engine's function returns or all default, the
+    program over rows with the head where the engine asks for it (each
+    row's last valid position) or over every position."""
     import functools
 
     from scripts.aot_weight_copies import lowered_programs, serving_cell
 
     @functools.lru_cache(maxsize=None)
-    def lowered(cell: str, relaid: bool = True) -> dict:
+    def lowered(cell: str, relaid: bool = True,
+                rows_logits_at: str = "last") -> dict:
         cfg, batching = serving_cell(cell)
         with pytest.MonkeyPatch.context() as mp:    # as benchmark/aot_sizes.py
             mp.setattr(jax, "default_backend", lambda: "tpu")
-            return lowered_programs(cfg, batching, chip, relaid=relaid)
+            return lowered_programs(cfg, batching, chip, relaid=relaid,
+                                    rows_logits_at=rows_logits_at)
 
     return lowered
 
@@ -418,11 +432,19 @@ def test_serving_program_lowers_to_what_it_was_before_pr40(cell_programs,
     four accepted serving cells lower, at the cells' shapes for a described
     v5e, to the programs of the commit before the window went into
     ``paged_decode_attention`` / ``paged_chunk_attention`` and the share
-    into ``_moe_sorted``."""
+    into ``_moe_sorted``. The program over rows in its all-position form
+    too (``logits_at``'s default: what every caller but the engine's
+    program over rows takes); the form the engine builds since PR 41 is
+    pinned beside it."""
     from scripts.aot_weight_copies import lowered_fingerprint
 
-    assert lowered_fingerprint(cell_programs(cell)[program]) \
+    rows = program == "chunk[2]"
+    assert lowered_fingerprint(
+        cell_programs(cell, True, "all" if rows else "last")[program]) \
         == LOWERED_BEFORE_PR40[cell, program]
+    if rows:
+        assert lowered_fingerprint(cell_programs(cell)[program]) \
+            == ROWS_PROGRAM_SINCE_PR41[cell]
 
 
 @pytest.mark.parametrize("cell,program", SERVING_PROGRAMS)
@@ -453,6 +475,36 @@ def test_serving_program_copies_no_weight_on_v5e(cell_programs, cell,
     if cell == "mistral-7b.chat-open" and program == "decode":
         # the hoisted copies were the program's temporaries: 0.806 GB
         assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+@pytest.mark.parametrize("cell", sorted(
+    c for c, p in SERVING_PROGRAMS if p == "chunk[2]"))
+def test_rows_program_returns_one_position_a_row_on_v5e(cell_programs, cell):
+    """The program over two prompts' rows as the engine builds it (PR 41:
+    the head at each row's last valid position, under a conditional on
+    "some row ends its prompt") beside its all-position form, at the cell's sizes: what it returns beyond the pool it was
+    donated is ``[2, V]`` float32 and some scalars where the other form
+    returns ``[2, 512, V]`` (634 MB at GLM's vocabulary, with the program
+    before it still in flight). Its temporaries ALONE read larger than the
+    other form's (Mixtral 83 MB where 10, LFM2 441 where 229): the compiler
+    lays the other form's out inside the logits' buffer before the head
+    fills it, so what is compared is what a program holds beyond its
+    arguments, temporaries and result together (GLM 24 MB where 646,
+    Mixtral 83 where 141, LFM2 441 where 497)."""
+    from scripts.aot_weight_copies import serving_cell
+
+    vocab = serving_cell(cell)[0].vocab_size
+    last, every = (
+        cell_programs(cell, True, form)["chunk[2]"].compile()
+        .memory_analysis() for form in ("last", "all"))
+    assert every.output_size_in_bytes - every.alias_size_in_bytes \
+        >= 2 * 512 * vocab * 4
+    result = last.output_size_in_bytes - last.alias_size_in_bytes
+    assert 2 * vocab * 4 <= result < 16 * 2 ** 20, result
+    held = {form: m.temp_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes for form, m in (("last", last),
+                                                    ("all", every))}
+    assert held["last"] < held["all"], held
 
 
 def test_the_detector_finds_the_copies_of_default_layouts(cell_programs):

@@ -6,7 +6,10 @@ the program is built, that it is warm from construction on, what a pass
 dispatches and counts, and that a stalled prefill holds nobody back. And the
 chunk program of a per-head pool as the chip runs it (ISSUE 36): rows written
 in place and attended through ``paged_chunk_attention`` (interpreted here),
-against the gathered form, and the kernel alone against plain attention."""
+against the gathered form, and the kernel alone against plain attention. And
+the program's last-position form (ISSUE 41): ``[B,V]`` logits, the head at each
+row's last valid position, against the all-position form's row there, over
+every kind of pool; the engine's program over rows takes it."""
 
 import contextlib
 import dataclasses
@@ -32,7 +35,8 @@ from kubeflow_tpu.ops.paged_attention import (
     paged_chunk_attention,
 )
 from kubeflow_tpu.serve.paged import (
-    _chunk_in_place, context_bucket, paged_chunk_prefill, pool_shapes,
+    _chunk_in_place, context_bucket, engine_pool_shapes, paged_chunk_prefill,
+    pool_shapes, ring_pages,
 )
 
 PAGE, CHUNK, MPP, POOL = 16, 32, 8, 14
@@ -79,15 +83,17 @@ def _tokens(seed: int, n: int) -> np.ndarray:
 
 
 def _empty_pool(cfg):
-    return {name: jnp.zeros(shape, dt)
-            for name, (shape, dt) in pool_shapes(cfg, POOL, PAGE).items()}
+    shapes = (engine_pool_shapes(cfg, 3, POOL, PAGE)
+              if cfg.layers_of("window") else pool_shapes(cfg, POOL, PAGE))
+    return {name: jnp.zeros(shape, dt) for name, (shape, dt) in shapes.items()}
 
 
-def _rows_program(cfg, impl="gather"):
+def _rows_program(cfg, impl="gather", logits_at="all"):
+    """``program(..., ncp[, wanted])``: ``wanted`` with "last" only."""
     return jax.jit(
-        lambda p, c, t, tr, st, vl, ncp: paged_chunk_prefill(
+        lambda p, c, t, tr, st, vl, ncp, wanted=None: paged_chunk_prefill(
             p, c, t, tr, st, vl, cfg, context_pages=ncp,
-            paged_attn_impl=impl),
+            paged_attn_impl=impl, logits_at=logits_at, wanted=wanted),
         static_argnums=(6,))
 
 
@@ -102,10 +108,10 @@ def _one(program, params, cache, tokens, table_row, start, valid):
     return logits[0], cache
 
 
-def _two(program, params, cache, rows, ctx=None):
+def _two(program, params, cache, rows, ctx=None, wanted=None):
     """The two-row program; a row is (tokens, table_row, start, valid) or
     None for a dead one. ``ctx``: the static context bucket (the largest
-    live row's unless given)."""
+    live row's unless given). ``wanted``: a bool a row, where given."""
     block = np.zeros((2, CHUNK), np.int32)
     table = np.full((2, MPP), -1, np.int32)
     start, valid = np.zeros((2,), np.int32), np.zeros((2,), np.int32)
@@ -116,8 +122,9 @@ def _two(program, params, cache, rows, ctx=None):
         block[r, :valid[r]] = toks[start[r]:start[r] + valid[r]]
     ctx = ctx or max(context_bucket(int(start[r]), CHUNK, PAGE, MPP)
                      for r, row in enumerate(rows) if row is not None)
+    more = () if wanted is None else (jnp.asarray(wanted),)
     return program(params, cache, jnp.asarray(block), jnp.asarray(table),
-                   jnp.asarray(start), jnp.asarray(valid), ctx)
+                   jnp.asarray(start), jnp.asarray(valid), ctx, *more)
 
 
 @functools.lru_cache(maxsize=None)
@@ -477,6 +484,176 @@ class TestCapacityPerRow:
         assert float(aux_row) == float(aux)
 
 
+# -- the head at each row's last valid position -----------------------------------
+
+# kind -> (config, the engine's "gather" | "pallas"): this file's kinds, a
+# per-head pool in place, packed rows beside conv layers with the tied head
+# in bfloat16 (the longanswer cell's way), window layers over a ring in
+# either form.
+LAST_KINDS = {
+    **{kind: (lambda kind=kind: _config(kind), "gather") for kind in KINDS},
+    "dense-in-place": (lambda: _wide_model("dense")[0], "pallas"),
+    "packed-beside-conv-bfloat16": (
+        lambda: preset("tiny-lfm2", max_seq_len=1024), "gather"),
+    "window": (lambda: _window_config(), "gather"),
+    "window-in-place": (lambda: _window_config(head_dim=128), "pallas"),
+}
+
+
+def _window_config(**over):
+    """test_serve_exaone.py's small model (a leading dense window layer,
+    then window, window, global, window; a quarter of the experts held)
+    with the ring an engine of this file's sizes would set."""
+    cfg = preset("tiny-exaone", dtype="float32", param_dtype="float32",
+                 max_seq_len=1024, **over)
+    return dataclasses.replace(
+        cfg, window_ring_pages=ring_pages(cfg, CHUNK, PAGE, MPP))
+
+
+@functools.lru_cache(maxsize=None)
+def _last_and_all(kind: str):
+    """Both forms of the two-row program, each on its own pool, over two
+    passes: row a resumes MID-PAGE (24 tokens held) with a FULL chunk beside
+    row b at 64 with a SHORT last chunk of 19; then a's last 9 tokens beside
+    a DEAD row. Returns the config and, a pass, (rows, {form: logits},
+    {form: pool})."""
+    make, impl = LAST_KINDS[kind]
+    cfg = make()
+    params = init_decoder_params(jax.random.PRNGKey(3), cfg)
+    a, b = _tokens(1, 24 + CHUNK + 9), _tokens(2, 64 + 19)
+    row_a = np.asarray([0, 1, 2, 3, 10, -1, -1, -1], np.int32)
+    row_b = np.asarray([4, 5, 6, 7, 8, 9, -1, -1], np.int32)
+    one = _rows_program(cfg, impl)
+    _, pool = _one(one, params, _empty_pool(cfg), a, row_a, 0, 24)
+    _, pool = _one(one, params, pool, b, row_b, 0, CHUNK)
+    _, pool = _one(one, params, pool, b, row_b, CHUNK, CHUNK)
+    pools = {"all": pool, "last": pool}
+    out = []
+    for rows in (((a, row_a, 24, CHUNK), (b, row_b, 64, 19)),
+                 ((a, row_a, 24 + CHUNK, 9), None)):
+        logits = {}
+        for form in pools:
+            logits[form], pools[form] = _two(
+                _rows_program(cfg, impl, form), params, pools[form], rows,
+                ctx=MPP)
+        out.append((rows, logits, dict(pools)))
+    return cfg, out
+
+
+@pytest.mark.parametrize("kind", sorted(LAST_KINDS))
+class TestLastPosition:
+    def test_a_row_is_the_all_position_forms_row_there(self, kind):
+        """``[B,V]``: row ``r`` is the all-position form's
+        ``logits[r, valid_len[r] - 1]``: the same greedy token, and values
+        within one unit in the last place of a bfloat16 (the matmul's
+        inputs on the chip) of the largest logit; the number is printed."""
+        cfg, passes = _last_and_all(kind)
+        worst = 0.0
+        for rows, logits, _ in passes:
+            assert logits["all"].shape == (2, CHUNK, cfg.vocab_size)
+            assert logits["last"].shape == (2, cfg.vocab_size)
+            assert logits["last"].dtype == jnp.float32
+            for r, row in enumerate(rows):
+                if row is None:
+                    continue
+                want = np.asarray(logits["all"][r, row[3] - 1])
+                got = np.asarray(logits["last"][r])
+                assert int(got.argmax()) == int(want.argmax())
+                worst = max(worst, float(np.abs(got - want).max())
+                            / float(np.abs(want).max()))
+        print(f"{kind}: largest |last - all| over the largest logit "
+              f"{worst:.3g}")
+        assert worst <= 2.0 ** -8
+
+    def test_the_pool_is_written_alike(self, kind):
+        _, passes = _last_and_all(kind)
+        for n, (_, _, pools) in enumerate(passes):
+            for name in pools["all"]:
+                np.testing.assert_array_equal(
+                    np.asarray(pools["last"][name], np.float32),
+                    np.asarray(pools["all"][name], np.float32),
+                    err_msg=f"{name} after pass {n}")
+
+    def test_a_dead_row_writes_nothing(self, kind):
+        """The second pass's dead row beside a's last tokens: b's pages and
+        every unmapped page stand as the first pass left them; two dead
+        rows leave the whole pool as it was and return finite rows."""
+        cfg, passes = _last_and_all(kind)
+        before, after = passes[0][2]["last"], passes[1][2]["last"]
+        for name in (n for n in after if after[n].ndim > 1):
+            np.testing.assert_array_equal(
+                np.asarray(after[name], np.float32)[:, 5:10],
+                np.asarray(before[name], np.float32)[:, 5:10], err_msg=name)
+        impl = LAST_KINDS[kind][1]
+        params = init_decoder_params(jax.random.PRNGKey(3), cfg)
+        logits, pool = _two(_rows_program(cfg, impl, "last"), params, after,
+                            (None, None), ctx=MPP)
+        assert logits.shape == (2, cfg.vocab_size)
+        assert bool(jnp.isfinite(logits).all())
+        for name in (n for n in pool if pool[n].ndim > 1):
+            np.testing.assert_array_equal(
+                np.asarray(pool[name], np.float32),
+                np.asarray(after[name], np.float32), err_msg=name)
+
+
+    def test_with_no_row_wanted_the_head_is_not_run(self, kind):
+        """``wanted``: where it names any row every row's logits come back
+        as without it, to the bit; where it names none, zeros: the head's
+        ONE matrix product lies under a conditional; the pool is written
+        alike whatever it names."""
+        cfg, passes = _last_and_all(kind)
+        impl = LAST_KINDS[kind][1]
+        params = init_decoder_params(jax.random.PRNGKey(3), cfg)
+        program = _rows_program(cfg, impl, "last")
+        rows, logits, pools = passes[1]         # a's last tokens, a dead row
+        before = passes[0][2]["last"]
+        for wanted in ((True, False), (False, True), (False, False)):
+            got, pool = _two(program, params, before, rows, ctx=MPP,
+                             wanted=wanted)
+            if any(wanted):
+                np.testing.assert_array_equal(got, logits["last"])
+            else:
+                np.testing.assert_array_equal(got, 0.0)
+            for name in pool:
+                np.testing.assert_array_equal(
+                    np.asarray(pool[name], np.float32),
+                    np.asarray(pools["last"][name], np.float32), err_msg=name)
+        args = (params, before, jnp.zeros((2, CHUNK), jnp.int32),
+                jnp.zeros((2, MPP), jnp.int32), jnp.zeros((2,), jnp.int32),
+                jnp.zeros((2,), jnp.int32))
+        traced = jax.make_jaxpr(lambda *a: paged_chunk_prefill(
+            *a[:6], cfg, context_pages=MPP, paged_attn_impl=impl,
+            logits_at="last", wanted=a[6]))(*args, jnp.zeros((2,), bool))
+        assert _head_products(traced.jaxpr, cfg.vocab_size) == [True]
+
+
+def _head_products(jaxpr, vocab: int, under_cond: bool = False) -> list:
+    """For every matrix product in ``jaxpr`` whose result is ``[2, 1, V]``
+    (the head at one position a row): whether it lies under a ``cond``."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general" \
+                and eqn.outvars[0].aval.shape == (2, 1, vocab):
+            found.append(under_cond)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _head_products(
+                        sub, vocab, under_cond or eqn.primitive.name == "cond")
+    return found
+
+
+def test_an_unknown_form_is_refused():
+    _, cfg, params = _model("dense")
+    with pytest.raises(ValueError, match="logits_at"):
+        _two(_rows_program(cfg, logits_at="first"), params, _empty_pool(cfg),
+             (None, None), ctx=MPP)
+    with pytest.raises(ValueError, match="wanted"):
+        _two(_rows_program(cfg), params, _empty_pool(cfg), (None, None),
+             ctx=MPP, wanted=(True, False))
+
+
 # -- the engine ------------------------------------------------------------------
 
 def _engine(cfg, params, *, chunk=CHUNK, max_len=256, **kw):
@@ -585,14 +762,38 @@ class TestEngineBatchesChunks:
         logits, eng.cache = eng._paged_chunks(
             eng.params, eng.cache, jnp.zeros((2, CHUNK), jnp.int32),
             jnp.full((2, eng._mpp), -1, jnp.int32),
-            jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32), eng._mpp)
-        jax.block_until_ready(logits[1, CHUNK - 1])
+            jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
+            jnp.zeros((2,), jnp.bool_), eng._mpp)
+        assert logits.shape == (2, cfg.vocab_size)
+        jax.block_until_ready(logits[1])
         sp = SamplingParams(max_new_tokens=2, temperature=0.0)
         for seed in (4, 5):
             eng.submit(list(map(int, _tokens(seed, 60))), sp)
         eng._admit()
         assert eng.counters()["prefill_chunks_dispatched"] == 2
         assert compiles.stop() == 0, compiles.names
+
+    @pytest.mark.parametrize("kind", KINDS)
+    def test_the_programs_over_rows_and_those_with_an_end_are_counted(
+            self, kind):
+        """Prompts of three chunks and of two: two passes carry a chunk of
+        each (the program over rows; b ends in the second), the third a's
+        last chunk alone (the one-row program). A dense model at its ridge
+        builds no program over rows: five one-row programs, two with an
+        end."""
+        _, cfg, params = _model(kind)
+        chunk = 256 if kind == "dense" else CHUNK
+        eng = _engine(cfg, params, chunk=chunk,
+                      max_len=1024 if kind == "dense" else 256)
+        c = eng.counters()
+        assert (c["prefill_row_programs_dispatched"],
+                c["prefill_programs_with_end"]) == (0, 0)
+        _greedy(eng, [_tokens(4, 3 * chunk - 5), _tokens(5, 2 * chunk - 9)])
+        c = eng.counters()
+        assert (c["prefill_programs_dispatched"],
+                c["prefill_row_programs_dispatched"],
+                c["prefill_programs_with_end"]) == (
+                    (5, 0, 2) if kind == "dense" else (3, 2, 2))
 
     def test_a_small_dense_chunk_batches_too(self):
         """The rule reads rows, not a model's kind: a dense model at 32
