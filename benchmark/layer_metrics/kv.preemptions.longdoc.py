@@ -1,0 +1,13 @@
+"""Recompute preemptions inside the window of the long-document cell: the
+difference of the engine's ``preemptions`` counter (``kv.preemptions.
+mixedlength``'s reader); 0.0 when none happened. The pool holds 32 whole
+contexts of 17408 tokens in the GQA layer and a state entry for every slot in
+the KDA layers, so none is expected."""
+
+from benchmark.manifest import load_layer_metric
+
+DECLARATION = {"unit": "count", "better": "lower",
+               "source": "program_counter", "layer": "KV manager",
+               "moves": "serve_tokens_per_s"}
+
+read = load_layer_metric("kv.preemptions.mixedlength").read

@@ -2,7 +2,7 @@
 
 Presets cover the BASELINE.json configs (Llama-3-8B, Gemma-2B, Mixtral-8x7B)
 and the models the benchmark serves at their published widths (GLM-4.7-Flash,
-LFM2-24B-A2B, K-EXAONE-236B-A23B), plus tiny variants of each structure for
+LFM2-24B-A2B, K-EXAONE-236B-A23B, Solar-Open2-250B), plus tiny variants of each structure for
 tests. Architecture facts are from the public model cards and ``config.json``
 files.
 """
@@ -89,7 +89,20 @@ class DecoderConfig:
     # taps over time between two elementwise gates, whose state is the last
     # ``conv_taps - 1`` gated rows of a sequence. ``qk_norm``: an RMSNorm
     # over each head's values of q and k, before RoPE.
+    # A "linear" layer's operator is gated delta-rule linear attention
+    # (layers.kda_block): ``linear_heads`` heads of ``linear_head_dim`` keys
+    # and as many values, q / k / v each through a causal depthwise
+    # convolution of ``conv_taps`` taps, a decay a CHANNEL and an output gate
+    # through two projections of rank ``linear_gate_rank``; its state is a
+    # ``[linear_head_dim, linear_head_dim]`` float32 matrix a head and the
+    # convolutions' tails, one entry a SEQUENCE (serve/paged.py).
+    # ``attn_output_gate``: an attention layer's output is multiplied by
+    # ``sigmoid(x Wgate)``, a value a head channel, before ``wo``.
     layer_kinds: tuple = ()
+    linear_heads: int = 0
+    linear_head_dim: int = 0
+    linear_gate_rank: int = 0
+    attn_output_gate: bool = False
     attn_window: int = 0
     rope_window_only: bool = False
     # Serving: the pages a sequence keeps in a window layer of the page pool,
@@ -135,11 +148,17 @@ class DecoderConfig:
     def __post_init__(self):
         # A configuration file's list (JSON has no tuple) stays hashable.
         object.__setattr__(self, "layer_kinds", tuple(self.layer_kinds))
-        unknown = set(self.layer_kinds) - {"attention", "window", "conv"}
+        unknown = set(self.layer_kinds) - {"attention", "window", "conv",
+                                           "linear"}
         if unknown:
             raise ValueError(f"unknown layer kinds {sorted(unknown)}")
         if "window" in self.layer_kinds and self.attn_window <= 0:
             raise ValueError("window layers need attn_window > 0")
+        if "linear" in self.layer_kinds and not (
+                self.linear_heads > 0 and self.linear_head_dim > 0
+                and self.linear_gate_rank > 0):
+            raise ValueError("linear layers need linear_heads, "
+                             "linear_head_dim and linear_gate_rank > 0")
         if self.experts_held and self.num_experts and not (
                 0 <= self.expert_offset
                 and self.expert_offset + self.experts_held
@@ -200,9 +219,24 @@ class DecoderConfig:
         d = self.hidden
         return 3 * d * d + self.conv_taps * d + d * d
 
+    @property
+    def linear_dim(self) -> int:
+        """Channels of a linear layer's q, k or v: all its heads' values."""
+        return self.linear_heads * self.linear_head_dim
+
+    def _linear_params(self) -> int:
+        """One linear-attention block's operator: q / k / v and output
+        projections, their taps, the two low-rank pairs (decay, gate), the
+        decay's ``A_log`` a head and ``dt_bias`` a channel, beta's
+        projection, the output norm's weight."""
+        d, n, r = self.hidden, self.linear_dim, self.linear_gate_rank
+        return (4 * d * n + 3 * self.conv_taps * n + 2 * (d * r + r * n)
+                + self.linear_heads + n + d * self.linear_heads
+                + self.linear_head_dim)
+
     def _attn_params(self) -> int:
         """One block's attention matrices (a latent block's two norms too;
-        the two per-head norms of ``qk_norm``)."""
+        the two per-head norms of ``qk_norm``; the output gate's matrix)."""
         d, h = self.hidden, self.n_heads
         if self.is_latent:
             r, q = self.kv_lora_rank, self.q_lora_rank
@@ -211,13 +245,16 @@ class DecoderConfig:
                     + r * h * (self.qk_nope_dim + self.v_head_dim)
                     + h * self.v_head_dim * d)
         return d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d \
-            + (2 * self.head_dim if self.qk_norm else 0)
+            + (2 * self.head_dim if self.qk_norm else 0) \
+            + (d * self.q_dim if self.attn_output_gate else 0)
 
     def _operator_params(self, first: int, last: int) -> int:
-        """The operators (attention or convolution) of layers [first, last)."""
+        """The operators (attention, convolution or linear attention) of
+        layers [first, last)."""
         kinds = self.kinds[first:last]
         return (kinds.count("attention") + kinds.count("window")) \
-            * self._attn_params() + kinds.count("conv") * self._conv_params()
+            * self._attn_params() + kinds.count("conv") * self._conv_params() \
+            + kinds.count("linear") * self._linear_params()
 
     def _mlp_params(self, active: bool) -> int:
         """One expert layer's (or, dense, one MLP's) matrices; ``active``
@@ -332,6 +369,22 @@ PRESETS: dict[str, DecoderConfig] = {
         layer_kinds=("window", "window", "window", "attention"),
         attn_window=128, rope_window_only=True, qk_norm=True,
     ),
+    # Solar-Open2-250B (upstage config.json, model_type solar_open2: 48L,
+    # 4096h; layers 0, 4, ... 44 softmax GQA of 64/8 heads of 128 with no
+    # position and an output gate, every other layer gated delta-rule linear
+    # attention (KDA) of 64 heads of 128 behind convolutions of 4 taps; every
+    # layer 320 sigmoid-routed experts of 1280, top-8, beside one shared
+    # expert; untied head)
+    "solar-open2-250b": DecoderConfig(
+        vocab_size=196608, hidden=4096, n_layers=48, n_heads=64,
+        n_kv_heads=8, head_dim=128, mlp_dim=10240, max_seq_len=1048576,
+        rope_theta=10000.0, num_experts=320, experts_per_token=8,
+        moe_impl="sorted", moe_mlp_dim=1280, shared_experts=1,
+        router_score="sigmoid", router_norm_topk=True, router_scale=1.0,
+        layer_kinds=("attention", "linear", "linear", "linear"),
+        rope_window_only=True, attn_output_gate=True, conv_taps=4,
+        linear_heads=64, linear_head_dim=128, linear_gate_rank=128,
+    ),
     # tiny variants for tests/sim (structure-faithful, sized for 1 CPU core)
     "tiny": DecoderConfig(
         vocab_size=256, hidden=64, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -382,6 +435,20 @@ PRESETS: dict[str, DecoderConfig] = {
         router_norm_topk=True, router_scale=2.5, experts_held=4,
         layer_kinds=("window", "window", "window", "attention", "window"),
         attn_window=24, rope_window_only=True, qk_norm=True,
+    ),
+    # Solar-Open2's structure as one chip of four holds it: two periods of
+    # (gated global attention without position, linear, linear, linear)
+    # over 16 sigmoid-routed experts top-4 of which 4 are held, beside a
+    # shared one; linear heads of 16 keys, convolutions of 4 taps
+    "tiny-solar": DecoderConfig(
+        vocab_size=256, hidden=64, n_layers=8, n_heads=4, n_kv_heads=2,
+        head_dim=16, mlp_dim=160, max_seq_len=256, num_experts=16,
+        experts_per_token=4, moe_impl="sorted", moe_mlp_dim=48,
+        shared_experts=1, router_score="sigmoid", router_norm_topk=True,
+        router_scale=1.0, experts_held=4,
+        layer_kinds=("attention", "linear", "linear", "linear"),
+        rope_window_only=True, attn_output_gate=True, conv_taps=4,
+        linear_heads=4, linear_head_dim=16, linear_gate_rank=8,
     ),
 }
 

@@ -30,10 +30,15 @@ Params = dict[str, Any]
 # window layer's operator has an attention layer's leaves under a key of its
 # own, and K/V planes of its own (a pool keeps a bounded ring of them a
 # sequence where a global layer keeps every page, serve/paged.py).
-OPERATOR = {"attention": "attn", "window": "window", "conv": "conv"}
+OPERATOR = {"attention": "attn", "window": "window", "conv": "conv",
+            "linear": "linear"}
 # a window layer's plane -> the name attention knows it by
 WINDOW_PLANES = {"window_k": "k", "window_v": "v"}
-PLANE_KINDS = {"conv": "conv", **dict.fromkeys(WINDOW_PLANES, "window")}
+# a linear layer's planes: a sequence's recurrent matrices and the tails of
+# its three convolutions (one entry a SEQUENCE, serve/paged.py)
+LINEAR_PLANES = ("kda_state", "kda_conv")
+PLANE_KINDS = {"conv": "conv", **dict.fromkeys(WINDOW_PLANES, "window"),
+               **dict.fromkeys(LINEAR_PLANES, "linear")}
 
 
 def plane_kind(name: str) -> str:
@@ -46,7 +51,8 @@ def block_kind(bp: dict) -> str:
 
 
 def _init_operator(key, cfg: DecoderConfig, kind: str):
-    init = L.init_conv if kind == "conv" else L.init_attention
+    init = {"conv": L.init_conv, "linear": L.init_linear}.get(
+        kind, L.init_attention)
     return init(jax.random.split(key)[0], cfg)
 
 
@@ -250,6 +256,21 @@ def _block_forward(block_params, x, positions, cfg: DecoderConfig,
             block_params["conv"], h, cfg,
             None if kv_cache is None else kv_cache["conv"])
         new_cache = None if kv_cache is None else {"conv": zs}
+    elif "linear" in block_params:
+        # A linear layer's cache is its state before ``x`` (the matrices and
+        # the convolutions' tails); it hands back the state after the last
+        # valid position.
+        if tp_axis is not None or lora is not None:
+            raise NotImplementedError(
+                "a linear-attention layer under in-stage tensor parallelism "
+                "or with LoRA adapters")
+        attn_out, state = L.kda_block(
+            block_params["linear"], h, cfg,
+            None if kv_cache is None else tuple(
+                kv_cache[n] for n in LINEAR_PLANES),
+            valid_len)
+        new_cache = None if kv_cache is None else dict(
+            zip(LINEAR_PLANES, state))
     elif "window" in block_params:
         # Attention over the last ``attn_window`` keys; its cache planes are
         # its own kind's, which attention takes under its names.

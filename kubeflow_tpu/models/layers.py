@@ -197,7 +197,28 @@ def init_attention(key, cfg: DecoderConfig):
         params["q_norm"] = jnp.ones((cfg.head_dim,), cfg.weight_dtype)
         params["k_norm"] = jnp.ones((cfg.head_dim,), cfg.weight_dtype)
         specs["q_norm"] = specs["k_norm"] = ("norm",)
+    if cfg.attn_output_gate:
+        params["wgate"] = _init(jax.random.fold_in(key, 2),
+                                (d, cfg.n_heads, cfg.head_dim),
+                                cfg.weight_dtype)
+        specs["wgate"] = ("embed", "heads", "head_dim")
     return params, specs
+
+
+def gate_attention(p: dict, x: jax.Array, attn: jax.Array,
+                   cfg: DecoderConfig, heads_axis: int = 2) -> jax.Array:
+    """The attention output as ``wo`` takes it: times ``sigmoid(x Wgate)``,
+    a value a head channel, where the layer has an output gate
+    (``attn_output_gate``); as it came otherwise. ``x`` [B,S,D] is the
+    block's normed input, ``attn`` [B,S,H,Dh] (``heads_axis`` 2) or
+    [B,H,S,Dh] (1). One function for the forward pass and the paged
+    programs."""
+    if "wgate" not in p:
+        return attn
+    spec = "bsd,dhk->bshk" if heads_axis == 2 else "bsd,dhk->bhsk"
+    gate = jax.nn.sigmoid(jnp.einsum(
+        spec, x, p["wgate"].astype(cfg.activation_dtype)).astype(jnp.float32))
+    return (attn.astype(jnp.float32) * gate).astype(attn.dtype)
 
 
 def qk_rope(p: dict, q: jax.Array, k: jax.Array, positions: jax.Array,
@@ -392,6 +413,7 @@ def attention_block(
     else:
         out = multi_head_attention(q, k, v, causal=True, impl=attn_impl,
                                    window=window)
+    out = gate_attention(p, x, out, cfg)
     proj = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
     if lora is not None and "wo" in lora["targets"]:
         b, s = out.shape[0], out.shape[1]
@@ -582,6 +604,163 @@ def conv_block(p: dict, x: jax.Array, cfg: DecoderConfig,
     out = jnp.einsum("bsd,de->bse", bcu[:, :, 1] * c.astype(dt),
                      p["wout"].astype(dt))
     return checkpoint_name(out, "attn_out"), zs
+
+
+# -- Gated delta-rule linear attention (KDA) -------------------------------------
+
+# beside the squared norm a head's q and k are divided by
+KDA_L2_EPS = 1e-6
+
+
+def init_linear(key, cfg: DecoderConfig):
+    """A linear layer's operator (``ops/kda.py`` has the equations): ``wq``
+    / ``wk`` / ``wv`` [D, H, dk] and their depthwise filters ``conv_q`` /
+    ``conv_k`` / ``conv_v`` [taps, H, dk] (``[-1]`` multiplies the current
+    position); the decay a channel through ``wf1`` [D, r], ``wf2`` [r, H,
+    dk], ``a_log`` [H] and ``dt_bias`` [H, dk]; beta through ``wb`` [D, H];
+    the output gate through ``wg1`` / ``wg2``; the output norm's weight
+    ``o_norm`` [dk] (one vector for all heads) and ``wo`` [H, dk, D]. No
+    biases but ``dt_bias``. ``a_log`` and ``dt_bias`` start as the FLA
+    initialisation has them: ``A`` uniform in [1, 16], the step's bias the
+    inverse softplus of a step log-uniform in [1e-3, 1e-1]."""
+    ks = iter(jax.random.split(key, 14))
+    d, h, dk = cfg.hidden, cfg.linear_heads, cfg.linear_head_dim
+    r, taps, wdt = cfg.linear_gate_rank, cfg.conv_taps, cfg.weight_dtype
+    params, specs = {}, {}
+    for n in ("q", "k", "v"):
+        params["w" + n] = _init(next(ks), (d, h, dk), wdt)
+        params["conv_" + n] = _init(next(ks), (taps, h, dk), wdt,
+                                    scale=taps ** -0.5)
+        specs["w" + n] = ("embed", "heads", "head_dim")
+        specs["conv_" + n] = (None, "heads", "head_dim")
+    step = jnp.exp(jax.random.uniform(
+        next(ks), (h, dk), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+    params.update({
+        "wf1": _init(next(ks), (d, r), wdt),
+        "wf2": _init(next(ks), (r, h, dk), wdt),
+        "a_log": jnp.log(jax.random.uniform(
+            next(ks), (h,), jnp.float32, 1.0, 16.0)).astype(wdt),
+        "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(wdt),
+        "wb": _init(next(ks), (d, h), wdt),
+        "wg1": _init(next(ks), (d, r), wdt),
+        "wg2": _init(next(ks), (r, h, dk), wdt),
+        "o_norm": jnp.ones((dk,), wdt),
+        "wo": _init(next(ks), (h, dk, d), wdt, scale=(h * dk) ** -0.5),
+    })
+    specs.update({
+        "wf1": ("embed", None), "wf2": (None, "heads", "head_dim"),
+        "a_log": ("heads",), "dt_bias": ("heads", "head_dim"),
+        "wb": ("embed", "heads"), "wg1": ("embed", None),
+        "wg2": (None, "heads", "head_dim"), "o_norm": ("norm",),
+        "wo": ("heads", "head_dim", "embed"),
+    })
+    return params, specs
+
+
+def kda_conv_rows(cfg: DecoderConfig) -> int:
+    """Rows of a sequence's convolution tails: ``conv_taps - 1`` inputs each
+    of q, k and v, side by side ([rows, H * dk])."""
+    return 3 * (cfg.conv_taps - 1)
+
+
+def kda_inputs(p: dict, x: jax.Array, cfg: DecoderConfig,
+               tails: Optional[jax.Array] = None, valid_len=None):
+    """What the recurrence takes of ``x`` [B,S,D]: (q, k, v, g [B,S,H,dk]
+    float32, beta [B,S,H] float32, the convolutions' tails after the last
+    valid position [B, 3 (taps - 1), H dk]). q, k, v: the projection, a
+    causal depthwise convolution of ``conv_taps`` taps over time (``tails``:
+    the ``taps - 1`` projected rows before ``x``, zeros at a sequence's
+    start and when None), SiLU; q and k L2-normalised a head, q scaled by
+    ``dk ** -0.5``. ``g = -exp(a_log) softplus(x Wf1 Wf2 + dt_bias)`` a
+    channel, ``beta = 2 sigmoid(x wb)``. A position ``>= valid_len`` ([B])
+    is padding: its convolution input is zero, its ``beta`` and ``g`` are 0,
+    so the state passes through it unchanged."""
+    dt = cfg.activation_dtype
+    b, s, _ = x.shape
+    h, dk, taps = cfg.linear_heads, cfg.linear_head_dim, cfg.conv_taps
+    valid = None
+    if valid_len is not None:
+        valid = jnp.arange(s)[None, :] < jnp.reshape(valid_len, (-1, 1))
+    if tails is None:
+        tails = jnp.zeros((b, kda_conv_rows(cfg), h * dk), dt)
+    tails = tails.astype(dt).reshape(b, 3, taps - 1, h, dk)
+    out, new_tails = [], []
+    for i, n in enumerate(("q", "k", "v")):
+        proj = jnp.einsum("bsd,dhk->bshk", x, p["w" + n].astype(dt))
+        if valid is not None:
+            proj = jnp.where(valid[..., None, None], proj, 0)
+        xs = jnp.concatenate([tails[:, i], proj], axis=1)   # [B,taps-1+S,..]
+        w = p["conv_" + n].astype(jnp.float32)
+        c = sum(w[j] * xs[:, j:j + s].astype(jnp.float32)
+                for j in range(taps))
+        out.append(jax.nn.silu(c))
+        if valid is None:
+            new_tails.append(xs[:, s:])
+        else:       # the rows before position ``valid_len``
+            at = jnp.reshape(valid_len, (-1, 1)) + jnp.arange(taps - 1)
+            new_tails.append(jnp.take_along_axis(
+                xs, jnp.broadcast_to(at, (b, taps - 1))[..., None, None],
+                axis=1))
+    q, k, v = out
+
+    def unit(a):
+        return a * jax.lax.rsqrt(
+            jnp.sum(a * a, axis=-1, keepdims=True) + KDA_L2_EPS)
+
+    f = jnp.einsum("bsr,rhk->bshk",
+                   jnp.einsum("bsd,dr->bsr", x, p["wf1"].astype(dt)),
+                   p["wf2"].astype(dt)).astype(jnp.float32)
+    g = -jnp.exp(p["a_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(
+        f + p["dt_bias"].astype(jnp.float32))
+    beta = 2.0 * jax.nn.sigmoid(jnp.einsum(
+        "bsd,dh->bsh", x, p["wb"].astype(dt)).astype(jnp.float32))
+    if valid is not None:
+        g = jnp.where(valid[..., None, None], g, 0.0)
+        beta = jnp.where(valid[..., None], beta, 0.0)
+    tails = jnp.stack(new_tails, axis=1).reshape(b, -1, h * dk)
+    return unit(q) * dk ** -0.5, unit(k), v, g, beta, tails
+
+
+def kda_output(p: dict, x: jax.Array, o: jax.Array,
+               cfg: DecoderConfig) -> jax.Array:
+    """``(RMSNorm_head(o) * sigmoid(x Wg1 Wg2)) Wo``: o [B,S,H,dv] float32,
+    ``x`` [B,S,D] the block's normed input. Returns [B,S,D]."""
+    dt = cfg.activation_dtype
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                          + cfg.norm_eps) * p["o_norm"].astype(jnp.float32)
+    gate = jnp.einsum("bsr,rhk->bshk",
+                      jnp.einsum("bsd,dr->bsr", x, p["wg1"].astype(dt)),
+                      p["wg2"].astype(dt)).astype(jnp.float32)
+    y = (o * jax.nn.sigmoid(gate)).astype(dt)
+    return jnp.einsum("bshk,hkd->bsd", y, p["wo"].astype(dt))
+
+
+def kda_block(p: dict, x: jax.Array, cfg: DecoderConfig,
+              state: Optional[tuple] = None, valid_len=None,
+              impl: str = "xla"):
+    """Gated delta-rule linear attention over ``x`` [B,S,D] from ``state``
+    to a state: (the recurrent matrices [B,H,dk,dv] float32, the
+    convolutions' tails [B, 3 (taps - 1), H dk]); zeros when None (a
+    sequence's start). One token (``S == 1``) takes the recurrence itself,
+    anything longer the chunked form (``ops/kda.py``; ``impl`` "xla" |
+    "pallas"). Returns (out [B,S,D], the state after the last valid
+    position). Rows never mix."""
+    from kubeflow_tpu.ops import kda
+
+    b, s, _ = x.shape
+    h, dk = cfg.linear_heads, cfg.linear_head_dim
+    mat, tails = state if state is not None else (None, None)
+    if mat is None:
+        mat = jnp.zeros((b, h, dk, dk), jnp.float32)
+    q, k, v, g, beta, tails = kda_inputs(p, x, cfg, tails, valid_len)
+    if s == 1:
+        o, mat = kda.kda_step_xla(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                  beta[:, 0], mat)
+        o = o[:, None]
+    else:
+        o, mat = kda.kda_chunk(q, k, v, g, beta, mat, impl=impl)
+    return checkpoint_name(kda_output(p, x, o, cfg), "attn_out"), \
+        (mat, tails)
 
 
 # -- MLP -----------------------------------------------------------------------
@@ -958,6 +1137,15 @@ def _moe_dispatch(p: dict, x: jax.Array, cfg: DecoderConfig,
 GROUPED_TILE_ROWS = 128
 
 
+def _grouped_tile_columns(n: int) -> int:
+    """Columns a tile of the grouped matmul holds of an ``n``-wide output:
+    512 where that divides ``n``; else the widest whole number of 128-value
+    lanes up to 512 that does (256 at experts of 1280: a [4096, 1280] tile
+    of weights, twice for the pipeline, is 20 MB of the kernel's 16); ``n``
+    whole where no such width divides it (a tiny preset's)."""
+    return next((t for t in (512, 384, 256, 128) if n % t == 0), n)
+
+
 def grouped_matmul(rows: jax.Array, w: jax.Array, sizes: jax.Array,
                    cfg: DecoderConfig) -> jax.Array:
     """``rows`` [M, K] sorted by group times ``w`` [G, K, N]: the rows of
@@ -975,8 +1163,8 @@ def grouped_matmul(rows: jax.Array, w: jax.Array, sizes: jax.Array,
 
         return megablox.gmm(
             rows, w, sizes, rows.dtype,
-            (GROUPED_TILE_ROWS, k, n if n % 512 else 512), None, None, False,
-            auto_interpret())
+            (GROUPED_TILE_ROWS, k, _grouped_tile_columns(n)), None, None,
+            False, auto_interpret())
     return jax.lax.ragged_dot(rows, w, sizes)
 
 

@@ -77,7 +77,8 @@ from kubeflow_tpu.serve.pacing import RoundPacer, decode_ladder
 from kubeflow_tpu.serve.paged import (
     MOE_ROWS, PageAllocator, PagePoolExhausted, chunk_reads_context,
     context_bucket, engine_pool_shapes, paged_chunk_prefill,
-    paged_decode_multi, pool_bytes_per_token, pool_shapes, ring_pages,
+    own_first_pages, paged_decode_multi, pool_bytes_per_token, pool_shapes,
+    ring_pages,
 )
 from kubeflow_tpu.serve.weight_layout import (
     relaid_bytes, relay, weight_formats,
@@ -809,9 +810,11 @@ class LLMEngine:
                 "page pool smaller than one max-length sequence")
         # Window layers keep a ring of ``_ring`` pages a sequence, over its
         # first pages, in planes of ``_window_pages`` pages: a ring for
-        # every slot. Those ids are a sequence's first pages and nothing
-        # else (``_ensure_pages``).
-        self._ring = cfg_decode.window_ring_pages
+        # every slot; linear layers one entry a sequence at its first
+        # page's id (a ring of 1 where the stack has no window layer). Those
+        # ids are a sequence's first pages and nothing else
+        # (``_ensure_pages``).
+        self._ring = own_first_pages(cfg_decode)
         self._window_pages = min(self._num_pages,
                                  self.num_slots * self._ring)
         self._allocator = PageAllocator(
@@ -839,10 +842,15 @@ class LLMEngine:
         self._kv_pool_bytes = int(sum(v.nbytes for v in self.cache.values()))
         by_kind = {kind: int(sum(v.nbytes for n, v in self.cache.items()
                                  if plane_kind(n) == kind))
-                   for kind in ("attention", "window", "conv")}
+                   for kind in ("attention", "window", "conv", "linear")}
         self._state_pool_bytes = by_kind["conv"]
         self._kv_window_pool_bytes = by_kind["window"]
         self._kv_global_pool_bytes = by_kind["attention"]
+        # The linear layers' planes hold an entry a SEQUENCE (``slots`` of
+        # them, beside the token pages ``max_pages`` buys); every other
+        # plane holds rows a token or a tail a page.
+        self._kv_sequence_pool_bytes = by_kind["linear"]
+        self._state_sequences_started = 0       # lockfree: scheduler-confined counter
         # Where a layer holds a share of its experts, the rows its expert
         # layers routed and held ride in the cache pytree as running sums
         # (no pool plane: ``paged._planes_of``); the scheduler reads them in
@@ -1351,13 +1359,18 @@ class LLMEngine:
         resumes at page boundaries only, ``_kv_match``) and REFUSED over
         window layers: a ring that its sequence overwrites cannot be shared
         read-only, and a match would need the ring's pages as they stood at
-        the match."""
+        the match; and over linear-attention layers, whose state a sequence
+        is one matrix a head that every token rewrites: a match would need
+        it AS IT STOOD at the match (a snapshot a page: ROADMAP Reach 11),
+        and the speculative verify step cannot roll it back."""
         what = [name for name, has in (
             ("a latent (ckv) KV pool", cfg.is_latent),
             ("convolution layers whose state lives in the page pool",
              bool(cfg.layers_of("conv"))),
             ("window layers that keep a ring of pages a sequence",
              bool(cfg.layers_of("window"))),
+            ("linear-attention layers whose state a sequence lives in the "
+             "page pool", bool(cfg.layers_of("linear"))),
             ("K/V heads packed into one pool row", cfg.kv_heads_packed),
             ("leading dense layers", bool(cfg.leading_dense_layers)),
             (f"expert layers that hold {cfg.experts_held} of "
@@ -1378,6 +1391,9 @@ class LLMEngine:
             "enable_prefix_caching (prefix reuse over window layers: a "
             "ring its sequence overwrites cannot be shared)":
                 bool(cfg.layers_of("window")) and b.enable_prefix_caching,
+            "enable_prefix_caching (prefix reuse over linear-attention "
+            "layers: a match needs the state as it stood at the match)":
+                bool(cfg.layers_of("linear")) and b.enable_prefix_caching,
         }
         hit = [name for name, on in refused.items() if on]
         if hit:
@@ -1486,7 +1502,22 @@ class LLMEngine:
             # page of a sequence)
             "kv_window_pool_bytes": self._kv_window_pool_bytes,
             "kv_global_pool_bytes": self._kv_global_pool_bytes,
-            "kv_window_pages_a_sequence": self._ring,
+            "kv_window_pages_a_sequence":
+                self._cfg_decode.window_ring_pages,
+            # of the cache's size the planes that hold an entry a SEQUENCE
+            # (the linear layers' recurrent matrices and convolution tails,
+            # ``slots`` entries; 0 for a stack without linear layers) and
+            # every other plane (rows a token, tails a page)
+            "kv_sequence_pool_bytes": self._kv_sequence_pool_bytes,
+            "kv_token_pool_bytes":
+                self._kv_pool_bytes - self._kv_sequence_pool_bytes,
+            # sequences whose state was started from zeros (a chunk at
+            # position 0: admissions and a preempted request's second
+            # prefill); 0 without linear layers. The entries programs move
+            # follow from the counters above: a chunk writes one a linear
+            # layer and reads one unless it starts a sequence, a decode step
+            # reads and writes one a layer a live stream
+            "state_sequences_started": self._state_sequences_started,
             # (token, choice) rows the expert layers of every program
             # routed, and those of them whose expert is held here and was
             # computed (0 and 0 where every expert is held), as of the last
@@ -1934,6 +1965,9 @@ class LLMEngine:
         self._prefill_programs_with_end += any(ends)
         self._prefill_chunks_dispatched += len(group)
         self._prefill_tokens_dispatched += sum(reals)
+        if self._kv_sequence_pool_bytes:
+            self._state_sequences_started += sum(
+                ch.pos == 0 for ch in group)
         if self._state_pool_bytes:
             pg = self.page_size
             self._state_tail_writes += sum(
@@ -2975,7 +3009,7 @@ class LLMEngine:
         attrs = prof.active() and {
             "round": round_id, "k_steps": k_steps, "live": len(active),
             "context": context}
-        if attrs and self._ring:
+        if attrs and self.cfg.layers_of("window"):
             # rows a window layer's steps attend to: a step at position t
             # sees min(t + 1, window) of them
             w = self.cfg.attn_window

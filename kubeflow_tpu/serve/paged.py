@@ -58,8 +58,8 @@ import numpy as np
 from kubeflow_tpu.models import layers as L
 from kubeflow_tpu.models.config import DecoderConfig
 from kubeflow_tpu.models.decoder import (
-    WINDOW_PLANES, Params, block_kind, layer_groups, period_units,
-    plane_kind, unit_blocks,
+    LINEAR_PLANES, WINDOW_PLANES, Params, block_kind, layer_groups,
+    period_units, plane_kind, unit_blocks,
 )
 
 
@@ -87,11 +87,12 @@ class PageAllocator:
 
     def __init__(self, num_pages: int, page_size: int,
                  enable_prefix_caching: bool = True, ring_pages: int = 0):
-        """``ring_pages`` (a stack with window layers): the page ids below
-        it exist in the window layers' planes too and are handed out ONLY on
-        request (``alloc(n, ring=r)``: a sequence's first pages, over
-        which its window layers keep their ring, ``ring_table``); every other
-        page comes from the ids above."""
+        """``ring_pages`` (a stack with window or linear layers): the page
+        ids below it exist in those layers' planes too and are handed out
+        ONLY on request (``alloc(n, ring=r)``: a sequence's first pages,
+        over which its window layers keep their ring, ``ring_table``, and at
+        the first of which its linear layers keep its state,
+        ``sequence_planes``); every other page comes from the ids above."""
         self.num_pages = num_pages
         self.page_size = page_size
         self.prefix_caching = enable_prefix_caching
@@ -411,6 +412,37 @@ def window_planes(cfg: DecoderConfig, kv_quant: bool = False) -> tuple:
     return tuple((n, kv, dt) for n in WINDOW_PLANES)
 
 
+def sequence_planes(cfg: DecoderConfig) -> tuple:
+    """What one SEQUENCE holds in one linear layer of the pool: (name,
+    trailing shape, type). "kda_state": the recurrent matrix a head,
+    float32; "kda_conv": the ``conv_taps - 1`` projected rows before the
+    next token of each of q, k and v, side by side (``layers.kda_inputs``).
+    A state of 4 MB a layer can be neither copied into every page (as the
+    conv layers' tails are) nor found by slot (a program is handed a page
+    table row and no slot), so an entry lies at the id of the sequence's
+    FIRST page, ``table_row[0]``: the allocator hands first pages out from a
+    range of ids of their own (``PageAllocator(ring_pages=...)``, ``own_first_pages``), as
+    many as the planes have entries, and a row alone finds its state. A
+    chunk that starts at 0 and a decode step at length 0 start from zeros
+    whatever the entry holds, so an entry needs no clearing when its page
+    changes hands. () for a stack without linear layers."""
+    if not cfg.layers_of("linear"):
+        return ()
+    h, dk = cfg.linear_heads, cfg.linear_head_dim
+    return ((LINEAR_PLANES[0], (h, dk, dk), jnp.dtype(jnp.float32)),
+            (LINEAR_PLANES[1], (L.kda_conv_rows(cfg), h * dk),
+             cfg.activation_dtype))
+
+
+def own_first_pages(cfg: DecoderConfig) -> int:
+    """How many of a sequence's first pages come from the range of ids kept
+    for first pages: its ring where the stack has window layers
+    (``window_ring_pages``), one where it has linear layers (the id is the
+    sequence's entry in their planes), the larger where it has both; 0 for
+    any other stack."""
+    return max(cfg.window_ring_pages, 1 if cfg.layers_of("linear") else 0)
+
+
 def ring_pages(cfg: DecoderConfig, chunk: int, page_size: int,
                mpp: int) -> int:
     """Pages a sequence keeps in a window layer: what a chunk of ``chunk``
@@ -449,9 +481,10 @@ def pool_shapes(cfg: DecoderConfig, num_pages: int, page_size: int,
     """The pool an engine builds: {plane: (shape, type)}. A token plane is
     ``[layers of attention, P, page, ...]``, a state plane ``[layers of
     conv, P, ...]``, a window layer's ``[layers of window, H, page, ...]``
-    with ``H`` = ``window_pages`` (the whole pool's where not given): each
-    over the layers of ITS kind (``decoder.plane_kind``), so a stack whose
-    layers are all attention keeps ``[L, P, page, ...]``."""
+    and a sequence plane ``[layers of linear, H, ...]`` with ``H`` =
+    ``window_pages``, the ids first pages come from (the whole pool's where
+    not given): each over the layers of ITS kind (``decoder.plane_kind``),
+    so a stack whose layers are all attention keeps ``[L, P, page, ...]``."""
     out = {n: ((cfg.layers_of("attention"), num_pages, page_size, *trail),
                dt) for n, trail, dt in pool_planes(cfg, kv_quant)
            if cfg.layers_of("attention")}
@@ -461,6 +494,9 @@ def pool_shapes(cfg: DecoderConfig, num_pages: int, page_size: int,
                      num_pages if window_pages is None else window_pages,
                      page_size, *trail), dt)
                 for n, trail, dt in window_planes(cfg, kv_quant)})
+    out.update({n: ((cfg.layers_of("linear"),
+                     num_pages if window_pages is None else window_pages,
+                     *trail), dt) for n, trail, dt in sequence_planes(cfg)})
     return out
 
 
@@ -469,15 +505,15 @@ def engine_pool_shapes(cfg: DecoderConfig, slots: int, num_pages: int,
     """The cache pytree of an engine of ``slots`` slots over ``cfg`` as its
     programs take it (``engine.serving_configs`` has set the ring):
     ``pool_shapes`` with a ring for every slot in the window layers' planes
-    (``slots * window_ring_pages`` pages, the ids a sequence's first pages
-    come from) and, where a layer holds a share of its experts, the rows'
-    running sums."""
+    and an entry for every slot in the linear layers' (``slots *
+    own_first_pages`` ids, those a sequence's first pages come from) and,
+    where a layer holds a share of its experts, the rows' running sums."""
     if cfg.layers_of("window") and not cfg.window_ring_pages:
         raise ValueError("an engine's pool over window layers needs "
                          "cfg.window_ring_pages (paged.ring_pages)")
     out = pool_shapes(cfg, num_pages, page_size, kv_quant,
                       window_pages=min(num_pages,
-                                       slots * cfg.window_ring_pages))
+                                       slots * own_first_pages(cfg)))
     if cfg.experts_held:
         out[MOE_ROWS] = ((2,), jnp.dtype(jnp.int32))
     return out
@@ -502,6 +538,12 @@ def state_bytes_per_page(cfg: DecoderConfig) -> int:
     return cfg.layers_of("conv") * _plane_bytes(state_planes(cfg))
 
 
+def state_bytes_per_sequence(cfg: DecoderConfig) -> int:
+    """Bytes one sequence holds over all linear layers, whatever its
+    length: the recurrent matrices and the convolutions' tails."""
+    return cfg.layers_of("linear") * _plane_bytes(sequence_planes(cfg))
+
+
 def window_bytes_per_page(cfg: DecoderConfig, page_size: int) -> int:
     """Bytes one ring page holds over all window layers."""
     return cfg.layers_of("window") * page_size * _plane_bytes(
@@ -511,7 +553,8 @@ def window_bytes_per_page(cfg: DecoderConfig, page_size: int) -> int:
 def _pool_geometry(cache: dict) -> tuple:
     """(pages, page size) of a cache pytree: its first token plane's (a
     global layer's where the stack has one)."""
-    names = sorted((n for n in _planes_of(cache) if plane_kind(n) != "conv"),
+    names = sorted((n for n in _planes_of(cache)
+                    if plane_kind(n) not in ("conv", "linear")),
                    key=lambda n: plane_kind(n) != "attention")
     return cache[names[0]].shape[1:3]
 
@@ -684,6 +727,11 @@ def _paged_decode_block(bp, x, positions, lengths, live, pools, table,  # traced
     if kind == "conv":
         proj, pools = _conv_decode(bp["conv"], h, lengths, pools, pidx,
                                    base, table, pg, cfg)
+    elif kind == "linear":
+        proj, pools = _kda_decode(
+            bp["linear"], h, lengths, pools,
+            _sequence_entry(table, live, base, num_pages[kind], total),
+            cfg, attn_impl)
     elif kind == "window":
         # The pages its window touches and no other (the page of position
         # ``t - window + 1`` up to the page of ``t``: two at a window of a
@@ -728,6 +776,69 @@ def _conv_decode(c, h, lengths, pools, pidx, base, table, pg: int,  # traced
     proj, zs = L.conv_block(c, h, cfg, tail)
     return proj, {**pools, "conv": state.at[pidx].set(zs[:, 1:],
                                                       mode="drop")}
+
+
+def _sequence_entry(table_rows, live, base, entries: int,  # traced
+                    total: int):
+    """Where each row's sequence keeps its state in a linear layer's flat
+    planes: ``base`` (the layer's first entry) plus the id of the row's
+    first page; ``total`` (past the end: reads are masked, writes drop) for
+    a dead row, a row without a first page, and a first page whose id the
+    planes do not hold."""
+    first = table_rows[:, 0]
+    ok = live & (first >= 0) & (first < entries)
+    return jnp.where(ok, base + first, total)
+
+
+def _state_at(plane, entry, fresh):  # traced
+    """What ``plane`` ([N, ...]: one kind of a linear layer's state, flat)
+    holds at ``entry`` [B], zeros for a row that starts ``fresh`` and for an
+    entry past the plane (a dead row)."""
+    n = plane.shape[0]
+    blank = fresh | (entry >= n)
+    held = plane[jnp.clip(entry, 0, n - 1)]
+    return jnp.where(blank.reshape(-1, *[1] * (held.ndim - 1)), 0, held)
+
+
+def _kda_decode(lin, h, lengths, pools, entry, cfg: DecoderConfig,  # traced
+                attn_impl: str):
+    """A linear layer's decode step: the state at ``entry`` [B] of the
+    layer's planes (zeros at length 0, a sequence's first token) through one
+    token and back to where it lay ("pallas": ``ops/kda.py::kda_step``, in
+    place; "gather": the same in XLA). An ``entry`` past the planes is a
+    dead row: nothing read, nothing written. Returns (the operator's output
+    [B,1,D], the planes as written)."""
+    from kubeflow_tpu.ops import kda
+
+    mats, tails = (pools[n] for n in LINEAR_PLANES)
+    fresh = lengths == 0
+    q, k, v, g, beta, tail = L.kda_inputs(lin, h, cfg,
+                                          _state_at(tails, entry, fresh))
+    o, mats = kda.kda_step(
+        q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], mats, entry, fresh,
+        entry < mats.shape[0],
+        impl="pallas" if attn_impl == "pallas" else "xla")
+    pools = {**pools, LINEAR_PLANES[0]: mats,
+             LINEAR_PLANES[1]: tails.at[entry].set(
+                 tail.astype(tails.dtype), mode="drop")}
+    return L.kda_output(lin, h, o[:, None], cfg), pools
+
+
+def _kda_chunk(lin, h, start, valid_len, pools, entry,  # traced
+               cfg: DecoderConfig, attn_impl: str):
+    """A linear layer over a chunk a row: from the state at ``entry`` [B]
+    (zeros for a chunk that starts its sequence) to the state after the
+    row's last valid position, written back to the entry. Returns (the
+    operator's output [B,C,D], the planes as written)."""
+    mats, tails = (pools[n] for n in LINEAR_PLANES)
+    proj, (mat, tail) = L.kda_block(
+        lin, h, cfg, tuple(_state_at(pl, entry, start == 0)
+                           for pl in (mats, tails)), valid_len,
+        impl="pallas" if attn_impl == "pallas" else "xla")
+    return proj, {**pools,
+                  LINEAR_PLANES[0]: mats.at[entry].set(mat, mode="drop"),
+                  LINEAR_PLANES[1]: tails.at[entry].set(
+                      tail.astype(tails.dtype), mode="drop")}
 
 
 def _qkv_rope(a, h, positions, cfg: DecoderConfig, lora=None,  # traced
@@ -798,6 +909,7 @@ def _kv_decode_attention(a, h, positions, lengths, pools, pidx, off,  # traced
             ck = dequantize_kv(ck, paged_gather(pools["ks"], ltable), dt)
             cv = dequantize_kv(cv, paged_gather(pools["vs"], ltable), dt)
         attn = _decode_attention(q, ck, cv, lengths, cfg, lower=lower)
+    attn = L.gate_attention(a, h, attn, cfg)
     proj = jnp.einsum("bshk,hkd->bsd", attn, a["wo"].astype(dt))
     if lora is not None and "wo" in lora["targets"]:
         proj = L.apply_lora_layer(
@@ -944,7 +1056,8 @@ def paged_decode_multi(params: Params, cache: dict, tokens: jax.Array,  # traced
 def copy_pages(cache: dict, src: jax.Array, dst: jax.Array) -> dict:  # traced
     """Page-to-page pool copy: ``dst[i] <- src[i]`` for every pool plane
     (k/v and, when quantized, their scales; a latent pool's one padded row;
-    the conv layers' state, which a page ends in)
+    the conv layers' state, which a page ends in; a linear layer's entry
+    with a sequence's first page)
     — the radix index's copy-on-write primitive (serve/kvtier.py): a
     request diverging inside a shared block gets a private copy of the
     partial tail in ONE dispatch instead of recomputing it. Out-of-range
@@ -954,6 +1067,10 @@ def copy_pages(cache: dict, src: jax.Array, dst: jax.Array) -> dict:  # traced
         pool = cache[name]
         npages = pool.shape[1]
         d = jnp.where((dst >= 0) & (dst < npages), dst, npages)
+        if plane_kind(name) == "linear":
+            # A sequence's entry goes with its first page: to another first
+            # page, from one; any other pair copies nothing here.
+            d = jnp.where((src >= 0) & (src < npages), d, npages)
         out[name] = pool.at[:, d].set(
             pool[:, jnp.clip(src, 0, npages - 1)], mode="drop")
     return out
@@ -1051,7 +1168,8 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
         return _paged_chunk_in_place(params, cache, tokens, table_rows,
                                      start, valid_len, cfg, paged_attn_impl,
                                      logits_at, wanted)
-    planes = tuple(n for n in _planes_of(cache) if plane_kind(n) != "conv")
+    planes = tuple(n for n in _planes_of(cache)
+                   if plane_kind(n) not in ("conv", "linear"))
     num_pages, pg = _pool_geometry(cache)
     pages_of = _pages_by_kind(cache)
     b, c = tokens.shape
@@ -1090,6 +1208,13 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
     if "conv" in cache:
         caches["conv"] = _chunk_state_before(cache["conv"], table_rows,
                                              start, pg)
+    if "linear" in pages_of:
+        # a row's state where its first page's id says, zeros at a start
+        entry = _sequence_entry(whole_rows, valid_len > 0, 0,
+                                pages_of["linear"], pages_of["linear"])
+        caches.update({n: jax.vmap(
+            lambda plane: _state_at(plane, entry, start == 0))(cache[n])
+            for n in LINEAR_PLANES})
     caches["len"] = start
     lr = None if lora is None else {**lora, "aidx": adapter_idx}
     logits, filled, _ = decoder_forward(params, tokens, cfg, kv_caches=caches,
@@ -1127,6 +1252,10 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
     if "conv" in cache:
         out["conv"] = _chunk_state_after(cache["conv"], filled["conv"],
                                          table_rows, start, valid_len, c, pg)
+    if "linear" in pages_of:
+        out.update({n: cache[n].at[:, entry].set(
+            filled[n].astype(cache[n].dtype), mode="drop")
+            for n in LINEAR_PLANES})
     if MOE_ROWS in cache:
         # The gathered form runs the model's own forward pass, which keeps
         # no sums; its expert layers' rows are counted by what it was given
@@ -1224,12 +1353,13 @@ def _chunk_in_place(cache: dict, cfg: DecoderConfig, lora,
     form: int8 pools (scale planes), packed rows and the conv state beside
     them, a call with LoRA, planes the kernel cannot part by head. A window
     layer's planes are K and V per head like a global layer's and go the
-    same way."""
+    same way; a linear layer's planes ride beside them (its operator reads a
+    state a row, not pages)."""
     if cfg.is_latent:
         return True
     from kubeflow_tpu.ops.paged_attention import chunk_attention_supported
 
-    planes = set(_planes_of(cache))
+    planes = set(_planes_of(cache)) - set(LINEAR_PLANES)
     k = cache[next(n for n in ("k", *WINDOW_PLANES) if n in cache)]
     return (attn_impl == "pallas" and lora is None
             and planes in ({"k", "v"}, {"k", "v", *WINDOW_PLANES},
@@ -1267,6 +1397,7 @@ def _kv_chunk_attention(a, h, pos, start, pools, pidx, off, ltable,  # traced
         paged_chunk_attention(jnp.swapaxes(q[r], 0, 1), pools[nk],
                               pools[nv], ltable[r], start[r], window=window)
         for r in range(h.shape[0])])                           # [B,H,C,Dh]
+    attn = L.gate_attention(a, h, attn, cfg, heads_axis=1)
     return jnp.einsum("bhsk,hkd->bsd", attn,
                       a["wo"].astype(cfg.activation_dtype)), pools
 
@@ -1358,7 +1489,15 @@ def _paged_chunk_in_place(params: Params, cache: dict,  # traced
 
     def block(bp, carry, layer, gcfg, _, expert_stack):
         x, pools = carry
-        if "window" in bp:
+        if "linear" in bp:
+            h = L.rmsnorm(x, bp["ln1"], gcfg)
+            entries = pages_of["linear"]
+            proj, pools = _kda_chunk(
+                bp["linear"], h, start, valid_len, pools,
+                _sequence_entry(table_rows, valid_len > 0, layer * entries,
+                                entries, pools[LINEAR_PLANES[0]].shape[0]),
+                gcfg, attn_impl)
+        elif "window" in bp:
             base = layer * wpages
             h = L.rmsnorm(x, bp["ln1"], gcfg)
             pidx = jnp.where(wpage < wpages, base + wpage, wtotal)

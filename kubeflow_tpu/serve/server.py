@@ -791,6 +791,8 @@ def serving_metrics_registry(engines: list, *,
     # full-dtype bytes, and THESE counters are where that shows up.
     kvq_enabled = reg.gauge("kftpu_engine_kv_quant_enabled")
     kvq_density = reg.gauge("kftpu_engine_kv_quant_tokens_per_mib")
+    pool_bytes = reg.gauge("kftpu_engine_kv_pool_bytes")
+    states_started = reg.counter("kftpu_engine_sequence_states_started_total")
     ho_bytes_out = reg.counter("kftpu_engine_kv_handoff_bytes_exported_total")
     ho_bytes_in = reg.counter("kftpu_engine_kv_handoff_bytes_adopted_total")
     wire_demote = reg.counter("kftpu_engine_kv_wire_bytes_demoted_total")
@@ -867,6 +869,14 @@ def serving_metrics_registry(engines: list, *,
         density = engine.kv_pool_density()
         kvq_enabled.set(density["quant"], model=name)
         kvq_density.set(round(density["tokens_per_mib"], 1), model=name)
+        # the pool by what a plane holds: rows a token (and tails a page),
+        # or an entry a SEQUENCE (linear-attention state), and the
+        # sequences whose state began from zeros
+        counters = engine.counters()
+        for planes in ("token", "sequence"):
+            pool_bytes.set(counters[f"kv_{planes}_pool_bytes"], model=name,
+                           planes=planes)
+        states_started.inc(counters["state_sequences_started"], model=name)
         ho_bytes_out.inc(snap.get("handoff_bytes_exported", 0), model=name)
         ho_bytes_in.inc(snap.get("handoff_bytes_adopted", 0), model=name)
         wire_demote.inc(tier.get("demote_wire_bytes", 0), model=name)

@@ -13,7 +13,9 @@ path, so that no program lays a weight out again.
 
 The rule reads the leaf, not a model's name and not an option. A stacked
 per-head projection ``wq`` / ``wk`` / ``wv`` of shape ``[L, D, heads,
-head_dim]`` is contracted over ``D`` a head at a time (``"bsd,dhk->bshk"``):
+head_dim]`` (an attention layer's output gate ``wgate`` and a linear
+layer's three projections are such leaves too) is contracted over ``D`` a
+head at a time (``"bsd,dhk->bshk"``):
 the matrix unit wants each head's ``[D, head_dim]`` plane contiguous, which
 is ``major_to_minor=(0, 2, 1, 3)``. That holds where a head's row fills
 whole 128-lane tiles (``head_dim % 128 == 0``); at heads of 64 the
@@ -59,7 +61,7 @@ LANES = 128
 # [layers, hidden, heads, head_dim] with each head's [hidden, head_dim]
 # plane contiguous.
 HEADS_MAJOR = (0, 2, 1, 3)
-_PER_HEAD = ("wq", "wk", "wv")
+_PER_HEAD = ("wq", "wk", "wv", "wgate")
 
 
 def _leaf_layout(path, leaf, cfg: DecoderConfig) -> Optional[Layout]:
@@ -68,9 +70,13 @@ def _leaf_layout(path, leaf, cfg: DecoderConfig) -> Optional[Layout]:
     if name not in _PER_HEAD or len(leaf.shape) != 4:
         return None
     _, hidden, heads, head_dim = leaf.shape
-    if hidden != cfg.hidden or head_dim != cfg.head_dim:
-        return None
-    if heads != (cfg.n_heads if name == "wq" else cfg.n_kv_heads):
+    # a linear layer's three projections have its own heads, all alike; an
+    # attention layer's output gate has the queries'
+    linear = len(path) > 1 and getattr(path[-2], "key", None) == "linear"
+    want_heads, want_dim = (cfg.linear_heads, cfg.linear_head_dim) \
+        if linear else (cfg.n_heads if name in ("wq", "wgate")
+                        else cfg.n_kv_heads, cfg.head_dim)
+    if hidden != cfg.hidden or head_dim != want_dim or heads != want_heads:
         return None
     if head_dim % LANES or not jnp.issubdtype(leaf.dtype, jnp.floating):
         return None

@@ -4,21 +4,30 @@
 # Each run's result line goes to chiprun_out/<tag>/<seed>.t<trace>.json and
 # its log's tail to .err. DIR=<checkout> runs another checkout's files;
 # TRAFFIC=<file> lays that traffic file over the cell's own first (in the
-# machine's copy of the checkout: a sizing experiment, nothing is committed).
+# machine's copy of the checkout, so the file lies outside chiprun_out/: a
+# sizing experiment, nothing is committed). WORKLOAD=<cell> runs another
+# cell (scripts/solar_cell_chip.sh).
+cell=${WORKLOAD:-k-exaone-236b-a23b.batch-mixedlength}
 tag=$1; shift
 here=$(pwd); mkdir -p chiprun_out/$tag
-[ -n "$TRAFFIC" ] && cp $TRAFFIC ${DIR:-.}/benchmark/traffic/batch-mixedlength.json
+if [ -n "$TRAFFIC" ]; then
+  # chiprun leaves chiprun_out/ out of the machine's copy: a file there is
+  # not laid and the cell would run its own sizes under the experiment's tag
+  cp "$TRAFFIC" ${DIR:-.}/benchmark/traffic/${cell##*.}.json \
+    || { echo "TRAFFIC=$TRAFFIC not laid" >&2; exit 2; }
+  grep -E '"(pool|min|max)"' ${DIR:-.}/benchmark/traffic/${cell##*.}.json | tr -d '\n'; echo
+fi
 while [ $# -ge 2 ]; do
   trace=$1; seed=$2; shift 2
   out=$here/chiprun_out/$tag/$seed.t$trace
-  (cd ${DIR:-.} && python3 -m benchmark.run \
-    --workload ${WORKLOAD:-k-exaone-236b-a23b.batch-mixedlength} \
+  t0=$(date +%s)
+  (cd ${DIR:-.} && python3 -m benchmark.run --workload $cell \
     --seed $seed --seconds ${SECONDS_:-51} --trace $trace > $out.json 2> $out.log)
-  echo "rc=$? seed=$seed trace=$trace $(tail -c 2600 $out.json)"
+  echo "rc=$? seed=$seed trace=$trace took=$(( $(date +%s) - t0 ))s $(tail -c 2600 $out.json)"
   grep -E "compared|requests:|serve_tokens|setup_s|engine built|NO RESULT|Error|metric |tail:" $out.log | tail -n 30
   tail -n 400 $out.log > $out.err; rm -f $out.log
   # the traced tail's modules and its forty heaviest ops
-  cp ${DIR:-.}/benchmark_out/${WORKLOAD:-k-exaone-236b-a23b.batch-mixedlength}/trace_summary.json \
+  cp ${DIR:-.}/benchmark_out/$cell/trace_summary.json \
     $out.summary.json 2>/dev/null
 done
 true

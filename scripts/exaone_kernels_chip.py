@@ -51,9 +51,12 @@ OPS = {"global_decode": r"^%?paged_decode_attention[.\d]* =",
        "gmm": r"^%?gmm[.\d]* ="}
 
 
-def traced(run, calls: int) -> dict:
+def traced(run, calls: int, ops: dict | None = None, top: int = 0) -> dict:
     """``run()`` ``calls`` times under a trace (once before it, untraced):
-    {op: [calls of it, ms a call]} and the module's ms an execution."""
+    {op: [calls of it, ms a call]} for the kernels of ``ops`` (this
+    script's ``OPS`` unless given) and the module's ms an execution; with
+    ``top``, the program's heaviest instructions beside them (``top_ops``:
+    [name, calls of it an execution, ms a call])."""
     import jax
 
     from benchmark import tracing
@@ -71,12 +74,20 @@ def traced(run, calls: int) -> dict:
         return {}
     out = {"program_ms": 1e3 * sum(
         d for _, _, d in trace["devices"][0]["modules"]) / calls}
-    for name, pattern in OPS.items():
+    for name, pattern in (OPS if ops is None else ops).items():
         found = [d for _, _, d in tracing.ops_within(
             trace, float("-inf"), float("inf"), pattern)]
         if found:
             out[name] = [len(found) // calls,
                          round(1e3 * sum(found) / len(found), 4)]
+    if top:
+        by_name: dict = {}
+        for text, _, d in tracing.ops_within(trace, float("-inf"),
+                                             float("inf"), r""):
+            by_name.setdefault(text.split(" = ")[0].lstrip("%"), []).append(d)
+        heaviest = sorted(by_name.items(), key=lambda kv: -sum(kv[1]))[:top]
+        out["top_ops"] = [[n, len(d) // calls, round(1e3 * sum(d) / len(d), 4)]
+                          for n, d in heaviest]
     return out
 
 

@@ -3,7 +3,11 @@ from? Per seed, against the float32 reference at the cell's own size: the
 PROGRAM (the engine's chunk prefill and decode step), the reference computed
 with every matrix operand rounded to bfloat16 (the program's precision in
 another implementation: what rounding alone does to this model, expert
-choices that flip included), and the float8 control. One JSON line a side,
+choices that flip included), and the float8 control; for a stack with
+linear-attention layers also ``program_state_bf16``: the program with its
+float32 recurrent state rounded to bfloat16 after every program call (what a
+bfloat16 state plane would hold between programs: the precision one step
+below what such a configuration states for its state). One JSON line a side,
 with the quartiles of the per-position errors beside the two medians that
 ``correct`` compares.
 
@@ -13,6 +17,7 @@ with the quartiles of the per-position errors beside the two medians that
 from __future__ import annotations
 
 import argparse
+import contextlib
 import gc
 import json
 import os
@@ -50,16 +55,42 @@ def main(argv=None) -> int:
     def bf16_round_trip(x):
         return x.astype(jnp.bfloat16).astype(jnp.float32)
 
+    @contextlib.contextmanager
+    def state_rounded(engine, on: bool):
+        """Both programs ``engine_logits`` calls hand back a pool whose
+        float32 planes (a linear layer's recurrent matrices) went through
+        bfloat16."""
+        from kubeflow_tpu.serve import paged
+
+        if not on:
+            yield
+            return
+
+        def rounded(result):
+            logits, cache = result
+            return logits, {n: bf16_round_trip(a) if a.dtype == jnp.float32
+                            and n in engine.cache else a
+                            for n, a in cache.items()}
+
+        chunk, step = engine._paged_chunk, paged._paged_decode_step
+        engine._paged_chunk = lambda *a, **k: rounded(chunk(*a, **k))
+        paged._paged_decode_step = lambda *a, **k: rounded(step(*a, **k))
+        try:
+            yield
+        finally:
+            engine._paged_chunk, paged._paged_decode_step = chunk, step
+
     for seed in (int(s) for s in args.seeds.split(",")):
         params = make_params(conf, seed, cfg.param_dtype)
         want = correctness.reference_side(params, conf, spec, seed, chunk)
         for side in args.sides.split(","):
-            if side == "program":
+            if side in ("program", "program_state_bf16"):
                 kw = {**traffic["engine"], "max_pages": 2 * traffic["engine"][
                     "max_seq_len"] // traffic["engine"]["page_size"]}
                 engine = LLMEngine(cfg, BatchingSpec(**kw), params=params,
                                    seed=seed & 0x7FFFFFFF)
-                got = correctness.engine_side(engine, conf, spec, seed)
+                with state_rounded(engine, side == "program_state_bf16"):
+                    got = correctness.engine_side(engine, conf, spec, seed)
                 shared = {id(x) for x in jax.tree.leaves(params)}
                 for leaf in jax.tree.leaves((engine.cache, engine.params)):
                     if id(leaf) not in shared:

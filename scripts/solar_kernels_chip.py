@@ -1,0 +1,149 @@
+"""ISSUE 43 on the chip, beside the benchmark and editing none of it: what the
+two gated delta-rule kernels and the XLA part of the chunked form cost, read
+off a trace of the long-document cell's OWN programs at the cell's sizes.
+
+    python3 scripts/solar_kernels_chip.py --seed <n>
+
+Builds the cell's engine as the benchmark does (weights from the seed, the
+``BatchingSpec`` of the traffic file; no reference, no server) and traces
+
+1. the decode step (``paged._paged_decode_step``, the program ``correct``
+   drives) over all 32 slots, a token of its own each, at a context of 4096
+   and of 16384, every slot on pages of its own with its first page (its
+   state's entry) from the entries' ids: per call the three ``kda_step`` (a
+   stream's [64, 128, 128] float32 state in and out where it lies), the GQA
+   layer's ``paged_decode_attention`` and the grouped matmuls, each beside
+   its bytes at the bus's peak;
+2. the engine's two-row chunk program at starts 0 (the state from zeros) and
+   15872 (the state read from its entry): per call the three ``kda_chunk``,
+   ``paged_chunk_attention`` and the grouped matmuls, and the program's
+   heaviest instructions, so that PERF.md section 5 can say which of
+   convolution, norms, gates, the blocks' solves and the scan the time is in.
+
+One JSON line a part, times in milliseconds a call (mean over the traced
+calls; ``scripts/exaone_kernels_chip.py::traced``). ``--tiny`` rehearses it on
+the CPU at the tiny-solar preset (no device plane: the parts print their
+shapes alone).
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+CELL = "solar-open2-250b.batch-longdoc"
+OPS = {"kda_step": r"^%?kda_step[.\d]* =",
+       "kda_chunk": r"^%?kda_chunk[.\d]* =",
+       "gqa_decode": r"^%?paged_decode_attention[.\d]* =",
+       "gqa_chunk": r"^%?paged_chunk_attention[.\d]* =",
+       "gmm": r"^%?gmm[.\d]* ="}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--calls", type=int, default=20)
+    ap.add_argument("--tiny", action="store_true",
+                    help="rehearse on the CPU at the tiny-solar preset")
+    args = ap.parse_args(argv)
+
+    from benchmark import architecture, device
+    from benchmark import manifest as mf
+    from benchmark.weights import make_params
+    from scripts.exaone_kernels_chip import traced
+
+    manifest = mf.load_manifest()
+    cell = mf.cell(manifest, CELL)
+    conf = mf.load_config(manifest, cell["config"])
+    traffic = mf.load_traffic(cell["traffic"])
+    if args.tiny:
+        conf = mf.load_json("benchmark/configs/rehearsal-tiny-solar.json")
+        traffic = mf.load_traffic("rehearsal-closed-state")
+    else:
+        device.prepare_process(platform_is_tpu=True)
+        device.require_devices(cell["chips"])
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from kubeflow_tpu.core.serving import BatchingSpec
+    from kubeflow_tpu.serve.engine import LLMEngine
+    from kubeflow_tpu.serve.paged import _paged_decode_step
+
+    cfg = architecture.part(conf, "program").program_config(conf)
+    counts = architecture.part(conf, "counts")
+    params = make_params(conf, args.seed, cfg.param_dtype)
+    eng = LLMEngine(cfg, BatchingSpec(**traffic["engine"]), params=params,
+                    seed=args.seed & 0x7FFFFFFF)
+    slots, mpp = eng.num_slots, eng._mpp
+    C, pg = eng.chunk_size, eng.page_size
+    # every slot on pages of its own: its first from the entries' ids (its
+    # own slot's number), the rest from above them
+    table = np.zeros((slots, mpp), np.int32)
+    for b in range(slots):
+        table[b, 0] = b
+        table[b, 1:] = slots + b * (mpp - 1) + np.arange(mpp - 1)
+    assert table.max() < eng._num_pages
+    dcfg, impl = eng._cfg_decode, eng.paged_attn_impl
+    step = jax.jit(lambda p, c, tbl, t, ln, lv: _paged_decode_step(
+        p, {**c, "table": tbl}, t, ln, lv, dcfg, attn_impl=impl),
+        donate_argnums=(1,))
+    live = jnp.ones((slots,), bool)
+    rng = np.random.default_rng(args.seed)
+    tok = jnp.asarray(rng.integers(3, conf["vocab_size"], slots).astype(
+        np.int32))
+
+    def decode_at(context: int):
+        lens = jnp.full((slots,), context - 1, jnp.int32)
+        tbl = jnp.asarray(np.where(
+            np.arange(mpp)[None, :] < -(-context // pg), table, -1))
+
+        def run():
+            lg, cache = step(eng.params, eng.cache, tbl, tok, lens, live)
+            cache.pop("table", None)
+            eng.cache = eng._pin(cache)
+            return lg
+        return run
+
+    short, long_ = (C, mpp * pg) if args.tiny else (4096, 16384)
+    bus = 819e9
+    for context in (short, long_):
+        print(json.dumps({
+            "part": "decode_step", "context": context, "slots": slots,
+            "kda_step_ms_at_the_bus": round(
+                1e3 * counts.kda_step_bytes(conf, slots) / bus, 4),
+            "gqa_decode_ms_at_the_bus": round(
+                1e3 * counts.decode_attention_bytes(
+                    conf, slots * context, 2) / bus, 4),
+            **traced(decode_at(context), args.calls, OPS, top=12)}),
+            flush=True)
+
+    block = jnp.asarray(rng.integers(
+        3, conf["vocab_size"], (2, C)).astype(np.int32))
+    rows = jnp.asarray(table[:2])
+    for start in (0, long_ - C):
+        starts = jnp.full((2,), start, jnp.int32)
+        valid = jnp.full((2,), C, jnp.int32)
+
+        def run():
+            logits, eng.cache = eng._paged_chunks(
+                eng.params, eng.cache, block, rows, starts, valid, valid > 0,
+                mpp)
+            return logits
+        print(json.dumps({
+            "part": "chunk_program", "rows": 2, "start": start,
+            "kda_chunk_ms_at_the_bus": round(
+                1e3 * counts.kda_chunk_bytes(conf, 2 * C, 2) / bus, 4),
+            "kda_chunk_ms_at_the_peak": round(
+                1e3 * counts.kda_chunk_flops(conf, 2 * C) / 197e12, 4),
+            **traced(run, args.calls, OPS, top=30)}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -122,6 +122,34 @@ MIXEDLENGTH_STATED = {
 }
 
 
+# PR 43's cell (solar-open2-250b.batch-longdoc), the same way: its rehearsal
+# cell (the engine refuses prefix reuse over linear-attention layers), the
+# counters its readers take, at rest, and each metric's number for a window
+# without samples (the pool's share is a constant of the engine as built).
+LONGDOC_CELLS = {
+    "tiny-solar.rehearsal-closed-state": (
+        "solar-open2-250b.batch-longdoc", "rehearsal-tiny-solar",
+        "rehearsal-closed-state", 1),
+}
+LONGDOC_ENGINE_COUNTERS = {
+    "kv_sequence_pool_bytes": 32768, "kv_token_pool_bytes": 229376,
+    "prefill_tokens_dispatched": 0}
+LONGDOC_STATED = {
+    "step.prefill_mfu.longdoc": 0.0,
+    "step.decode_weight_bw_share.longdoc": 0.0,
+    "kernel.kda_chunk_roofline_share.longdoc": 0.0,
+    "kernel.kda_step_bw_share.longdoc": 0.0,
+    "kernel.paged_decode_attention_bw_share.longdoc": 0.0,
+    "kernel.paged_chunk_attention_mfu.longdoc": 0.0,
+    "kv.state_share_of_pool.longdoc": 12.5,    # 32768 of 262144 bytes
+    "moe.held_row_share.longdoc": 0.0,
+    "engine.decode_occupancy.longdoc": 0.0,
+    "kv.preemptions.longdoc": 0.0,
+    "engine.sched_busy_share_window.longdoc": 0.0,
+    "step.kda_mixer_mfu.longdoc": 0.0,
+}
+
+
 @pytest.fixture(autouse=True, scope="session")
 def benchmark_suite_tables_know_the_longanswer_cell(request):
     suite = next(
@@ -138,12 +166,13 @@ def benchmark_suite_tables_know_the_longanswer_cell(request):
     # stood reads ADDED_STATED) and the tables they are copied into.
     for tables, added in (
             ((suite.ADDED_CELLS, rehearsal.CELLS),
-             {**LONGANSWER_CELLS, **MIXEDLENGTH_CELLS}),
+             {**LONGANSWER_CELLS, **MIXEDLENGTH_CELLS, **LONGDOC_CELLS}),
             ((suite.ADDED_ENGINE_COUNTERS, readers.ENGINE0),
              {**LONGANSWER_ENGINE_COUNTERS, **WINDOW_ENGINE_COUNTERS,
-              **MIXEDLENGTH_ENGINE_COUNTERS}),
+              **MIXEDLENGTH_ENGINE_COUNTERS, **LONGDOC_ENGINE_COUNTERS}),
             ((suite.ADDED_STATED, total.STATED),
-             {**LONGANSWER_STATED, **WINDOW_STATED, **MIXEDLENGTH_STATED})):
+             {**LONGANSWER_STATED, **WINDOW_STATED, **MIXEDLENGTH_STATED,
+              **LONGDOC_STATED})):
         for table in tables:
             for key, value in added.items():
                 table.setdefault(key, value)
@@ -158,8 +187,9 @@ def benchmark_suite_tables_know_the_longanswer_cell(request):
 # per-layer metrics of PR 35's cell as that PR left them (in the manifest, and
 # in the line its CPU rehearsal prints), without PR 37's. Every other test
 # reads the manifest whole. PR 40 appends a configuration, a cell and ten
-# per-layer metrics behind all of those: the pinning tests are handed the
-# manifest without them too.
+# per-layer metrics behind all of those, and PR 43 a configuration, a cell
+# and twelve behind PR 40's: the pinning tests are handed the manifest
+# without them too.
 PINS_PR28_AT_THE_END = "test_what_this_pr_added_is_listed_with_the_benchmark"
 PINS_PR35S_CELL = \
     "test_what_this_pr_added_is_listed_with_the_benchmark_at_the_end"
@@ -193,7 +223,7 @@ def the_manifest_as_it_stood_for_the_tests_that_pin_a_pr(request,
                                                          monkeypatch):
     name = request.node.name
     module = request.node.module
-    later = set(WINDOW_STATED) | set(MIXEDLENGTH_STATED)
+    later = set(WINDOW_STATED) | set(MIXEDLENGTH_STATED) | set(LONGDOC_STATED)
     mixed = next(iter(MIXEDLENGTH_CELLS.values()))[0]
     if (module.__name__, request.node.originalname) == PINS_PR35S_LINE:
         whole = module.rehearsal_manifest
@@ -206,7 +236,7 @@ def the_manifest_as_it_stood_for_the_tests_that_pin_a_pr(request,
         return
     if name not in (PINS_PR28_AT_THE_END, PINS_PR35S_CELL):
         return
-    cells = {mixed}
+    cells = {mixed, next(iter(LONGDOC_CELLS.values()))[0]}
     if name == PINS_PR28_AT_THE_END:
         later |= set(LONGANSWER_STATED)
         cells.add(next(iter(LONGANSWER_CELLS.values()))[0])

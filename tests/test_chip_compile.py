@@ -524,3 +524,73 @@ def test_the_detector_finds_the_copies_of_default_layouts(cell_programs):
     assert {leaf for c in copies for leaf in c["leaf"]} == {
         f"['layers']['attn']['{n}']" for n in ("wq", "wk", "wv")}
     assert compiled.memory_analysis().temp_size_in_bytes > 800e6
+
+
+# -- PR 43: the two gated delta-rule kernels, and the cell that runs them ------
+
+def test_kda_kernels_compile_for_v5e(chip):
+    """``ops/kda.py`` at Solar-Open2's widths (64 heads of 128 keys and
+    values): the chunk kernel over two rows of 512 positions with what XLA
+    computes of the chunked form around it, and the step kernel over 32
+    streams whose state lies in a plane of 96 entries, ALIASED to the
+    result (no copy of the plane: what the call holds beyond its arguments
+    stays under the operands' few megabytes)."""
+    from kubeflow_tpu.ops import kda
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    b, c, h, dk = 2, 512, 64, 128
+    chunk = jax.jit(lambda q, k, v, g, beta, s: kda.kda_chunk(
+        q, k, v, g, beta, s, impl="pallas", interpret=False)).lower(
+        *(sds(b, c, h, dk) for _ in range(4)), sds(b, c, h),
+        sds(b, h, dk, dk)).compile()
+    assert "kda_chunk" in chunk.as_text()
+    assert "tpu_custom_call" in chunk.as_text()
+    b = 32
+    plane = sds(96, h, dk, dk)
+    step = jax.jit(
+        lambda q, k, v, g, beta, p, i, f, lv: kda.kda_step(
+            q, k, v, g, beta, p, i, f, lv, impl="pallas", interpret=False),
+        donate_argnums=(5,)).lower(
+        *(sds(b, h, dk) for _ in range(4)), sds(b, h), plane,
+        sds(b, dtype=jnp.int32), sds(b, dtype=jnp.bool_),
+        sds(b, dtype=jnp.bool_)).compile()
+    assert "kda_step" in step.as_text()
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= 96 * h * dk * dk * 4
+    assert mem.temp_size_in_bytes < 8 * 2 ** 20, mem.temp_size_in_bytes
+
+
+LONGDOC = "solar-open2-250b.batch-longdoc"
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk[1]", "chunk[2]"])
+def test_longdoc_program_compiles_for_v5e_with_its_kernels(cell_programs,
+                                                           program):
+    """The long-document cell's three programs at the cell's real sizes,
+    parameters as the engine holds them: each fits the chip beside its
+    arguments, runs the GQA layer's paged kernel and the KDA layers' own
+    (``kda_step`` in the decode step, ``kda_chunk`` in the chunk programs,
+    which are built in place), walks its experts through the grouped
+    matmul (tiles of 256 columns at experts of 1280: a whole [4096, 1280]
+    tile twice over is 20 MB of the kernel's 16), and copies no weight but
+    the two small low-rank second halves (``wf2`` / ``wg2``, 2 MB a
+    layer)."""
+    from scripts.aot_weight_copies import weight_copies
+
+    lowered = cell_programs(LONGDOC)[program]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    decode = program == "decode"
+    for kernel in ("kda_step" if decode else "kda_chunk",
+                   "paged_decode_attention" if decode
+                   else "paged_chunk_attention", "gmm"):
+        assert kernel in text, kernel
+    copied = {leaf for c in weight_copies(text, lowered.args_info[0][0])
+              for leaf in c["leaf"]}
+    assert copied <= {"['layers']['linear']['wf2']",
+                      "['layers']['linear']['wg2']"}, copied
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 11e9
+    assert mem.temp_size_in_bytes < 1e9

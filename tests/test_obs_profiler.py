@@ -162,7 +162,8 @@ ENGINE_KEYS = {
 ENGINE_CONSTANTS = {"slots", "kv_bytes_per_token", "kv_pool_bytes",
                     "state_pool_bytes", "weights_relaid_bytes",
                     "kv_window_pool_bytes", "kv_global_pool_bytes",
-                    "kv_window_pages_a_sequence"}
+                    "kv_window_pages_a_sequence", "kv_sequence_pool_bytes",
+                    "kv_token_pool_bytes"}
 
 
 def test_engine_counters_exist_at_construction_and_only_grow(engine):
