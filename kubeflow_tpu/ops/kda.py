@@ -1,6 +1,6 @@
 """Gated delta-rule linear attention (Kimi Delta Attention) with a decay a
 CHANNEL: the operator of a "linear" layer (models/layers.py::kda_block), in
-a plain XLA form and as two Pallas TPU kernels.
+a plain XLA form and as three Pallas TPU kernels.
 
 A head keeps a matrix ``S`` [dk, dv] in float32. A token with query ``q``,
 key ``k`` (both L2-normalised, ``q`` scaled), value ``v``, log-decay ``g``
@@ -18,9 +18,13 @@ carried block to block. With ``G`` the cumulative log-decay inside a block
 
 so ``U = U~ - W S_0`` with ``U~ = (I + A)^-1 beta V`` and ``W = (I +
 A)^-1 beta K e^G``, neither of which reads the state: they are computed for
-every block at once (``block_operands``), and what runs block after block is
-four matrix products (``_scan_blocks_xla``; on a TPU the kernel
-``kda_chunk``, which keeps ``S`` in VMEM across the blocks of a chunk).
+every block at once (``block_operands``; on a TPU the kernel
+``kda_operands``, which keeps a block's differences, products and solve in
+VMEM and writes the six arrays the scan takes, as it takes them), and what
+runs block after block is four matrix products (``_scan_blocks_xla``; on a
+TPU the kernel ``kda_chunk``, which keeps ``S`` in VMEM across the blocks of
+a chunk). The two kernels stay two: the benchmark counts the scan's
+operands as bytes it reads from HBM.
 
 **Decays a channel make the usual ``k / e^G`` overflow** (a channel may lose
 a factor e^30 a token). Every factor formed here is ``e^(G_i - G_j)`` with
@@ -30,7 +34,8 @@ block through a reference between the two, ``e^(G_t - R) e^(R - G_j)`` with
 ``R`` the cumulative decay at the end of the sub-block before ``t``'s, both
 factors at most 1 (a product that underflows is a term that is zero in
 float32 anyway). ``(I + A)^-1`` is forward substitution: row by row inside a
-sub-block's diagonal block, sub-block by sub-block across them.
+sub-block's diagonal block (the kernel: diagonal by diagonal, every
+sub-block at once), sub-block by sub-block across them.
 
 A position with ``beta = 0`` and ``g = 0`` (a last chunk's padding) leaves
 the state as it was: no program needs a second form for a tail.
@@ -160,6 +165,171 @@ def block_operands(q, k, v, g, beta, block: int = BLOCK, sub: int = SUB):
             "gt": jnp.exp(g_end[..., 0, :])}
 
 
+# -- a chunk: the same operands from ONE kernel ------------------------------------
+
+def _operands_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, qg_ref, w_ref,
+                     ut_ref, bm_ref, kdt_ref, gt_ref, rows, *, nb: int,
+                     t: int, sub: int, lw: int):
+    """``block_operands`` for one (row, head), nothing of it leaving VMEM,
+    ``lw`` positions (whole blocks) a loop turn.
+
+    Inside sub-blocks the positions are taken TRANSPOSED, a channel a
+    sublane and a position a lane, and a [sub, sub] block of a matrix by its
+    DIAGONALS, one row of positions an offset ``d``: the factor of a pair
+    ``(t, t - d)`` is ``e^(G_t - G_(t-d))``, a lane roll by ``d`` and a
+    difference, and the sum over channels is a sum over sublanes, so that no
+    [sub, sub, dk] array and no lane reduction is formed. The diagonal
+    blocks' ``(I + A)^-1 = N`` is forward substitution on those rows,
+    ``N_d = -sum_e A_e roll(N_(d-e), e)``, all sub-blocks at once. The
+    diagonals (``rows``: an offset a row, reversed, N from row 0 and qk from
+    row T) are then turned back, a position a row, and rolled by the row's
+    own number, which puts each where it belongs in [T, T]. The rest is a
+    block as it lies: the products across sub-blocks through the reference
+    ``R``, ``B``, and the forward substitution sub-block by sub-block."""
+    dk, dv = q_ref.shape[-1], v_ref.shape[-1]
+    ns, wide = t // sub, dv + dk
+
+    def dot(x, y):
+        return jnp.dot(x, y, preferred_element_type=F32, precision=HIGHEST)
+
+    def dot_t(x, y):                                # x @ y^T
+        return jax.lax.dot_general(
+            x, y, (((1,), (1,)), ((), ())), preferred_element_type=F32,
+            precision=HIGHEST)
+
+    def iota(shape, dim):
+        return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+    def f32(ref, at):
+        return ref[0, 0, at, :].astype(F32)
+
+    pr, pc = iota((lw, lw), 0), iota((lw, lw), 1)
+    cum = ((pc <= pr) & (pc // t == pr // t)).astype(F32)
+    in_sub = iota((1, lw), 1) % sub
+    which = iota((dk, nb), 1)
+    row = iota((t, 1), 0)
+    rows[...] = jnp.zeros(rows.shape, F32)
+
+    def block(at, g, beta, dg):
+        q, k, v = f32(q_ref, at), f32(k_ref, at), f32(v_ref, at)
+        eg = jnp.exp(g)
+        qg_ref[0, 0, at, :] = q * eg
+        # across sub-blocks: through R, the decay at the end of the one before
+        kk, qk = [jnp.zeros((sub, t), F32)], [jnp.zeros((sub, t), F32)]
+        for s in range(1, ns):
+            own = slice(s * sub, (s + 1) * sub)
+            r = g[s * sub - 1:s * sub, :]
+            row_f = jnp.exp(g[own] - r)
+            col = k * jnp.exp(jnp.where(row < s * sub, r - g, -jnp.inf))
+            o = dot_t(jnp.concatenate([k[own] * row_f, q[own] * row_f]), col)
+            kk.append(o[:sub])
+            qk.append(o[sub:])
+        # (I + A)^-1 [beta V | beta K e^G]: the diagonal blocks' inverses
+        # first (on A's other blocks too), then a sub-block's rows from
+        # those before it
+        x = jnp.concatenate([beta * v, beta * (k * eg),
+                             beta * jnp.concatenate(kk)], axis=1)
+        x = dot(dg[:, :t], x)
+        a_off = x[:, wide:]
+        xs = [x[:sub, :wide]]
+        for s in range(1, ns):
+            own = slice(s * sub, (s + 1) * sub)
+            xs.append(x[own, :wide] - dot(a_off[own, :s * sub],
+                                          jnp.concatenate(xs)))
+        x = jnp.concatenate(xs)
+        return jnp.concatenate(qk) + dg[:, t:], x[:, :dv], x[:, dv:]
+
+    def tile(i, gt):
+        at = pl.ds(pl.multiple_of(i * lw, lw), lw)
+        g_blk = dot(cum, f32(g_ref, at))        # inclusive, a block at a time
+        g_t, k_t, q_t = g_blk.T, f32(k_ref, at).T, f32(q_ref, at).T
+        beta = beta_ref[0, 0, :, at].astype(F32)                    # [1, lw]
+        rows[t + sub - 1:t + sub, :] = jnp.sum(q_t * k_t, axis=0,
+                                               keepdims=True)
+        a, n = [None], [jnp.ones((1, lw), F32)]     # A's diagonals inside
+        for d in range(1, sub):
+            e = jnp.exp(jnp.where(in_sub >= d,
+                                  g_t - pltpu.roll(g_t, d, 1), -jnp.inf))
+            ke = pltpu.roll(k_t, d, 1) * e
+            a.append(beta * jnp.sum(k_t * ke, axis=0, keepdims=True))
+            rows[t + sub - 1 - d:t + sub - d, :] = jnp.sum(
+                q_t * ke, axis=0, keepdims=True)
+            # ... and (I + A)^-1's: the newest diagonal enters last
+            acc = a[d]
+            for m in range(1, d):
+                acc = acc + a[d - m] * pltpu.roll(n[m], d - m, 1)
+            n.append(-acc)
+        for d, n_d in enumerate(n):
+            rows[sub - 1 - d:sub - d, :] = n_d
+        diag = rows[...].T                                          # [lw, 2 T]
+        beta_col = jnp.broadcast_to(beta, (8, lw)).T[:, :1]         # [lw, 1]
+        for j in range(lw // t):
+            own = slice(j * t, (j + 1) * t)
+            m, at_j = i * (lw // t) + j, pl.ds(
+                pl.multiple_of(i * lw + j * t, t), t)
+            end = g_t[:, (j + 1) * t - 1:(j + 1) * t]               # [dk, 1]
+            kdt_ref[0, 0, m] = k_t[:, own] * jnp.exp(end - g_t[:, own])
+            gt = jnp.where(which == m, jnp.exp(end), gt)
+            # row t's diagonals: lane (sub - 1 - d) -> lane (t - d)
+            dg = pltpu.roll(diag[own], 2 * t - (sub - 1), 1, stride=1,
+                            stride_axis=0)
+            bm_ref[0, 0, m], ut_ref[0, 0, at_j, :], w_ref[0, 0, at_j, :] = \
+                block(at_j, g_blk[own], beta_col[own], dg)
+        return gt
+
+    gt_ref[0, 0] = jax.lax.fori_loop(0, nb * t // lw, tile,
+                                     jnp.zeros((dk, nb), F32))
+
+
+@functools.partial(jax.jit, static_argnames=("block", "sub", "interpret"))
+def kda_operands(q, k, v, g, beta, *, block: int = BLOCK, sub: int = SUB,
+                 interpret: bool):
+    """``block_operands`` as ONE kernel call, ``kda_operands``: q, k, v, g
+    [B, S, H, dk] and beta [B, S, H], ``S`` whole blocks; a grid step a
+    (row, head). The operands are read heads-major, which is how the
+    compiler lays the projections' results out in a chunk program (so the
+    ``swapaxes`` here is no copy there; [B, S, H dk] is NOT the same tiles:
+    a tile of [.., H, dk] holds 8 heads, one of [.., S, H dk] 8 positions),
+    and the six arrays are written as ``_scan_blocks_call`` hands them to
+    its kernel (its own ``swapaxes`` of ``gt`` undoes the one below).
+    Reached through this one cached call, as that one is. Returns
+    ``block_operands``' dict."""
+    b, c, h, dk = q.shape
+    dv = v.shape[-1]
+    t, nb = block, c // block
+    # positions a transposed walk: whole blocks, 128 lanes where they divide
+    lw = max(n * t for n in range(1, nb + 1)
+             if nb % n == 0 and n * t <= max(128, t))
+
+    def heads_major(x):     # where the projections' fusions leave them
+        return jnp.swapaxes(x, 1, 2)
+
+    def spec(*shape):
+        return pl.BlockSpec((1, 1) + shape,
+                            lambda bi, hi: (bi, hi) + (0,) * len(shape))
+
+    qg, w, ut, bm, kdt, gt = pl.pallas_call(
+        functools.partial(_operands_kernel, nb=nb, t=t, sub=sub, lw=lw),
+        name="kda_operands",
+        grid=(b, h),
+        in_specs=[spec(c, dk), spec(c, dk), spec(c, dv), spec(c, dk),
+                  spec(1, c)],
+        out_specs=[spec(c, dk), spec(c, dk), spec(c, dv), spec(nb, t, t),
+                   spec(nb, dk, t), spec(dk, nb)],
+        out_shape=[jax.ShapeDtypeStruct((b, h) + s, F32) for s in (
+            (c, dk), (c, dk), (c, dv), (nb, t, t), (nb, dk, t), (dk, nb))],
+        scratch_shapes=[pltpu.VMEM((2 * t, lw), F32)],
+        interpret=interpret,
+    )(*(heads_major(x) for x in (q, k, v, g)),
+      heads_major(beta)[:, :, None, :])
+
+    def blocks(x):          # [B,H,C,n] -> [B,H,nb,T,n]
+        return x.reshape(b, h, nb, t, x.shape[-1])
+
+    return {"qg": blocks(qg), "w": blocks(w), "ut": blocks(ut), "bm": bm,
+            "kdt": kdt, "gt": jnp.swapaxes(gt, -1, -2)}
+
+
 def _scan_blocks_xla(ops: dict, state):
     """The state through the blocks of a chunk. Returns (o [B, H, S, dv],
     the state after)."""
@@ -224,33 +394,44 @@ def _scan_blocks_call(ops: dict, state, *, interpret: bool):
     return o, s
 
 
+def whole_blocks(q, k, v, g, beta, block: int = BLOCK, sub: int = SUB):
+    """A chunk of any length as whole blocks: a short one is one block (of
+    whole sub-blocks), a ragged one is padded with positions that leave the
+    state alone (``beta = 0``, ``g = 0``). Returns ((q, k, v, g, beta),
+    block, sub)."""
+    s = q.shape[1]
+    block = min(block, -(-s // sub) * sub)
+    pad = -s % block
+    if pad:
+        q, k, v, g = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                      for x in (q, k, v, g))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    return (q, k, v, g, beta), block, min(sub, block)
+
+
 def kda_chunk(q, k, v, g, beta, state, *, impl: str = "xla",
               block: int = BLOCK, sub: int = SUB,
               interpret: Optional[bool] = None):
     """A chunk a row, from a state to a state. q, k, v, g [B, S, H, dk]
     (``g`` the log-decay, <= 0); beta [B, S, H]; state [B, H, dk, dv]
     float32; ``S`` any length (padded here to whole blocks with positions
-    that leave the state alone). ``impl`` "xla" | "pallas" (the kernel
-    ``kda_chunk`` runs the blocks; what does not read the state is XLA's in
-    both). Returns (o [B, S, H, dv] float32, the state after)."""
-    s = q.shape[1]
-    block = min(block, -(-s // sub) * sub)      # a short chunk: one block
-    sub = min(sub, block)
-    pad = -s % block
-    hm = [jnp.swapaxes(x.astype(F32), 1, 2) for x in (q, k, v, g)]
-    bh = jnp.swapaxes(beta.astype(F32), 1, 2)
-    if pad:
-        hm = [jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0))) for x in hm]
-        bh = jnp.pad(bh, ((0, 0), (0, 0), (0, pad)))
-    ops = block_operands(*hm, bh, block, sub)
-    if impl == "pallas":
-        o, end = _scan_blocks_call(
-            ops, state,
-            interpret=auto_interpret() if interpret is None else interpret)
-    elif impl == "xla":
-        o, end = _scan_blocks_xla(ops, state)
-    else:
+    that leave the state alone). ``impl`` "pallas": the kernel
+    ``kda_operands`` computes what does not read the state and the kernel
+    ``kda_chunk`` runs the blocks; "xla": ``block_operands`` and a
+    ``lax.scan``. Returns (o [B, S, H, dv] float32, the state after)."""
+    if impl not in ("xla", "pallas"):
         raise ValueError(f"unknown kda impl {impl!r}; one of xla|pallas")
+    s = q.shape[1]
+    (q, k, v, g, beta), block, sub = whole_blocks(q, k, v, g, beta, block,
+                                                  sub)
+    if impl == "pallas":
+        interpret = auto_interpret() if interpret is None else interpret
+        o, end = _scan_blocks_call(
+            kda_operands(q, k, v, g, beta, block=block, sub=sub,
+                         interpret=interpret), state, interpret=interpret)
+    else:
+        hm = [jnp.swapaxes(x.astype(F32), 1, 2) for x in (q, k, v, g, beta)]
+        o, end = _scan_blocks_xla(block_operands(*hm, block, sub), state)
     return jnp.swapaxes(o[:, :, :s], 1, 2), end
 
 

@@ -15,10 +15,13 @@ Builds the cell's engine as the benchmark does (weights from the seed, the
    layer's ``paged_decode_attention`` and the grouped matmuls, each beside
    its bytes at the bus's peak;
 2. the engine's two-row chunk program at starts 0 (the state from zeros) and
-   15872 (the state read from its entry): per call the three ``kda_chunk``,
-   ``paged_chunk_attention`` and the grouped matmuls, and the program's
-   heaviest instructions, so that PERF.md section 5 can say which of
-   convolution, norms, gates, the blocks' solves and the scan the time is in.
+   15872 (the state read from its entry): per call the three ``kda_chunk``
+   and, in front of each, the ``kda_operands`` call that computes what the
+   scan takes (PR 44; beside the bytes it reads and writes at the bus's
+   peak), ``paged_chunk_attention`` and the grouped matmuls, and the
+   program's heaviest instructions, so that PERF.md section 5 can say which
+   of convolution, norms, gates, the blocks' solves and the scan the time is
+   in.
 
 One JSON line a part, times in milliseconds a call (mean over the traced
 calls; ``scripts/exaone_kernels_chip.py::traced``). ``--tiny`` rehearses it on
@@ -38,9 +41,21 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 CELL = "solar-open2-250b.batch-longdoc"
 OPS = {"kda_step": r"^%?kda_step[.\d]* =",
        "kda_chunk": r"^%?kda_chunk[.\d]* =",
+       "kda_operands": r"^%?kda_operands[.\d]* =",
        "gqa_decode": r"^%?paged_decode_attention[.\d]* =",
        "gqa_chunk": r"^%?paged_chunk_attention[.\d]* =",
        "gmm": r"^%?gmm[.\d]* ="}
+
+
+def operands_bytes(cfg, tokens: int) -> int:
+    """What one ``kda_operands`` call moves for ``tokens`` positions of one
+    layer, float32: q, k, v, g and beta in; qg, w, ut, a block's [T, T]
+    ``bm``, ``kdt`` and a block's ``gt`` out."""
+    from kubeflow_tpu.ops.kda import BLOCK
+
+    h, dk = cfg.linear_heads, cfg.linear_head_dim
+    return 4 * tokens * h * (4 * dk + 1 + 4 * dk + BLOCK) \
+        + 4 * (tokens // BLOCK) * h * dk
 
 
 def main(argv=None) -> int:
@@ -137,6 +152,8 @@ def main(argv=None) -> int:
             return logits
         print(json.dumps({
             "part": "chunk_program", "rows": 2, "start": start,
+            "kda_operands_ms_at_the_bus": round(
+                1e3 * operands_bytes(cfg, 2 * C) / bus, 4),
             "kda_chunk_ms_at_the_bus": round(
                 1e3 * counts.kda_chunk_bytes(conf, 2 * C, 2) / bus, 4),
             "kda_chunk_ms_at_the_peak": round(

@@ -19,7 +19,9 @@ Nothing here runs on a device: a compile that passes is not a chip run.
 The whole-step compiles live in tests/test_aot_8b.py under ``slow``.
 """
 
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # or libtpu logs to /tmp
 
@@ -527,14 +529,33 @@ def test_the_detector_finds_the_copies_of_default_layouts(cell_programs):
 
 
 # -- PR 43: the two gated delta-rule kernels, and the cell that runs them ------
+# -- PR 44: a third in front of the scan, for what does not read the state ----
+
+def _calls(text: str, kernel: str) -> int:
+    """Instructions of a compiled text named ``kernel`` (or ``kernel.N``)."""
+    return len(re.findall(rf"^\s*%?{kernel}[.\d]* = ", text, re.M))
+
+
+def _f32_relayouts(text: str, elements: int) -> list:
+    """The float32 ``copy`` and ``transpose`` instructions of a compiled
+    text over ``elements`` elements or more, by result shape."""
+    found = re.findall(
+        r"^\s*(?:ROOT )?%?[\w.\-]+ = (f32\[[\d,]+\])\S* (?:copy|transpose)\(",
+        text, re.M)
+    return [s for s in found
+            if math.prod(int(n) for n in s[4:-1].split(",")) >= elements]
+
 
 def test_kda_kernels_compile_for_v5e(chip):
     """``ops/kda.py`` at Solar-Open2's widths (64 heads of 128 keys and
-    values): the chunk kernel over two rows of 512 positions with what XLA
-    computes of the chunked form around it, and the step kernel over 32
-    streams whose state lies in a plane of 96 entries, ALIASED to the
-    result (no copy of the plane: what the call holds beyond its arguments
-    stays under the operands' few megabytes)."""
+    values): the two chunk kernels over two rows of 512 positions
+    (``kda_operands``, what does not read the state, inside the kernel's
+    VMEM limit, in front of the scan ``kda_chunk``: here over operands that
+    lie positions-major, as a parameter does; the cell's program, below,
+    hands them over heads-major and copies none), and the step kernel
+    over 32 streams whose state lies in a plane of 96 entries, ALIASED to
+    the result (no copy of the plane: what the call holds beyond its
+    arguments stays under the operands' few megabytes)."""
     from kubeflow_tpu.ops import kda
 
     def sds(*shape, dtype=jnp.float32):
@@ -545,8 +566,9 @@ def test_kda_kernels_compile_for_v5e(chip):
         q, k, v, g, beta, s, impl="pallas", interpret=False)).lower(
         *(sds(b, c, h, dk) for _ in range(4)), sds(b, c, h),
         sds(b, h, dk, dk)).compile()
-    assert "kda_chunk" in chunk.as_text()
-    assert "tpu_custom_call" in chunk.as_text()
+    text = chunk.as_text()
+    assert "tpu_custom_call" in text
+    assert (_calls(text, "kda_operands"), _calls(text, "kda_chunk")) == (1, 1)
     b = 32
     plane = sds(96, h, dk, dk)
     step = jax.jit(
@@ -571,8 +593,10 @@ def test_longdoc_program_compiles_for_v5e_with_its_kernels(cell_programs,
     """The long-document cell's three programs at the cell's real sizes,
     parameters as the engine holds them: each fits the chip beside its
     arguments, runs the GQA layer's paged kernel and the KDA layers' own
-    (``kda_step`` in the decode step, ``kda_chunk`` in the chunk programs,
-    which are built in place), walks its experts through the grouped
+    (``kda_step`` in the decode step; in the chunk programs, which are
+    built in place, one ``kda_operands`` in front of every ``kda_chunk``
+    and no float32 array of the chunk's size copied or transposed to feed
+    them: PR 44), walks its experts through the grouped
     matmul (tiles of 256 columns at experts of 1280: a whole [4096, 1280]
     tile twice over is 20 MB of the kernel's 16), and copies no weight but
     the two small low-rank second halves (``wf2`` / ``wg2``, 2 MB a
@@ -587,10 +611,17 @@ def test_longdoc_program_compiles_for_v5e_with_its_kernels(cell_programs,
                    "paged_decode_attention" if decode
                    else "paged_chunk_attention", "gmm"):
         assert kernel in text, kernel
+    if not decode:
+        assert (_calls(text, "kda_operands"), _calls(text, "kda_chunk")) \
+            == (3, 3)
+        assert "f32[2,64,8,64,128]" not in text
+        # q, k, v, g reach the kernel as the projections' fusions wrote them
+        rows = int(program[len("chunk["):-1])
+        assert _f32_relayouts(text, rows * 512 * 64 * 128) == []
     copied = {leaf for c in weight_copies(text, lowered.args_info[0][0])
               for leaf in c["leaf"]}
     assert copied <= {"['layers']['linear']['wf2']",
                       "['layers']['linear']['wg2']"}, copied
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 11e9
-    assert mem.temp_size_in_bytes < 1e9
+    assert mem.temp_size_in_bytes < 0.4e9      # 0.363 GB, two rows (0.65 before PR 44)

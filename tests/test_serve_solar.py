@@ -122,6 +122,54 @@ def test_padding_leaves_the_state_as_it_was():
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-5)
 
 
+def _chunk_case(s, block, sub, strong):
+    return _operands(s, 2, s, 3, 16, strong)[:5], block, sub
+
+
+def _underflow_case():
+    q, k, v, g, beta, _ = _operands(3, 1, 64, 2, 16)
+    return (q, k, v, jnp.full_like(g, -50.0), beta), 64, 16
+
+
+def _padded_rows_case():
+    q, k, v, g, beta, _ = _operands(4, 2, 128, 2, 16, True)
+    valid = jnp.arange(128)[None, :] < jnp.asarray([[20], [97]])
+    return (q, k, v, jnp.where(valid[..., None, None], g, 0),
+            jnp.where(valid[..., None], beta, 0)), 64, 16
+
+
+@pytest.mark.parametrize("case", [
+    pytest.param(lambda: _chunk_case(64, 64, 16, False), id="one-block"),
+    pytest.param(lambda: _chunk_case(128, 64, 16, True), id="underflowing"),
+    pytest.param(lambda: _chunk_case(48, 16, 16, False), id="three-blocks"),
+    pytest.param(lambda: _chunk_case(100, 64, 16, True), id="ragged"),
+    pytest.param(lambda: _chunk_case(32, 32, 8, False), id="block32-sub8"),
+    pytest.param(lambda: _chunk_case(7, 64, 16, True), id="short"),
+    pytest.param(lambda: _chunk_case(512, 64, 16, True), id="a-chunk-of-512"),
+    pytest.param(_underflow_case, id="g-minus-50"),
+    pytest.param(_padded_rows_case, id="padding-rows"),
+])
+def test_the_operands_kernel_is_block_operands(case):
+    """``kda_operands`` (interpreted) against ``block_operands``: each of the
+    six arrays the scan takes, on the chunked form's cases, where every
+    channel loses e^-50 a token and behind a row's valid length."""
+    args, block, sub = case()
+    # the chunked form's own tolerances; its underflow test's where every
+    # term off the diagonal is exactly zero
+    tol = dict(rtol=1e-5, atol=1e-6) if case is _underflow_case \
+        else dict(rtol=1e-4, atol=5e-5)
+    args, block, sub = kda.whole_blocks(*args, block, sub)
+    want = kda.block_operands(*(jnp.swapaxes(x, 1, 2) for x in args),
+                              block, sub)
+    got = kda.kda_operands(*args, block=block, sub=sub, interpret=True)
+    assert sorted(got) == sorted(want)
+    for name in want:
+        assert got[name].shape == want[name].shape, name
+        assert bool(jnp.isfinite(got[name]).all()), name
+        np.testing.assert_allclose(got[name], want[name], err_msg=name,
+                                   **tol)
+
+
 def test_the_step_kernel_moves_live_rows_state_in_place_and_no_other():
     b, h, dk = 5, 4, 16
     q, k, v, g, beta, _ = _operands(1, b, 1, h, dk)
