@@ -5,34 +5,42 @@ pool and over per-head pools (the end of the file).
 The paged engine's XLA path reads KV twice per step: a gather materializes
 each slot's pages into the [B, S, K, D] layout, then attention reads the
 gathered buffer — 2× the HBM traffic of the contiguous cache (serve/paged.py
-module notes). This kernel reads pages DIRECTLY: the page table rides in as
-a scalar-prefetch operand and the kv BlockSpec index map looks the page id
-up per grid step, so each page is DMA'd from the pool exactly once and the
-online softmax accumulates across pages in VMEM — the TPU form of vLLM's
-PagedAttention (same role as the public jax pallas paged kernels; written
-against this repo's pool/table layout and GQA grouping).
+module notes). The kernels read pages DIRECTLY, each from the pool exactly
+once, and the online softmax accumulates across pages in VMEM — the TPU form
+of vLLM's PagedAttention (same role as the public jax pallas paged kernels;
+written against this repo's pool/table layout and GQA grouping).
 
-Grid (batch, page), page innermost so the m/l/acc scratch carries across a
-slot's pages. Each step loads one FULL page ``[page, K, D]`` (Mosaic needs
-the block's trailing dims tile-aligned, so the kv-head dim stays whole) and
-computes every query head against it: GQA grouping happens in-register via
-a K-batched dot ([K, g, D] x [K, page, D] -> [K, g, page]). Unmapped (-1)
-and beyond-length pages are predicated off with ``pl.when`` (their index map
-clamps to page 0 — the DMA is wasted but never read).
+The decode kernel over per-head pools WALKS A ROW'S LIVE PAGES ITSELF (PR
+45). Grid ``(rows,)``, in order; the pools stay in HBM and the page table,
+the lengths and a window layer's lower bounds ride in as scalar prefetch.
+Inside a row a loop runs over the pages its context holds and no other, from
+the page of ``lower[b]`` (0 without it) to the page of ``lengths[b]``,
+``DECODE_PAGES_PER_TURN`` a turn: a turn waits for its K and V pages in one
+half of a double buffer (``_walk_live_pages``: one copy a page a plane, a
+DMA semaphore a half) while the next turn's copies, or the next row's first,
+are in flight in the other. A table id below 0 is never copied and never
+read; a row with nothing to attend to costs its scalars and writes zeros. A
+page ``[page, K, D]`` is parted into its KV heads' ``[page, D]`` rows by the
+strided load the chunk kernel uses (``_word_heads``), each head's queries
+``[g, D]`` against them in the pool's type with float32 accumulation, the
+probabilities in the pool's type too, as the XLA form has them; planes that
+load does not take (int8, one KV head, narrow heads) go head-major through
+float32 a page at a time.
 
 The pool operand is whatever ``[P, page, K, D]`` array the table's ids index.
 The decode step (serve/paged.py) hands in the WHOLE pool viewed flat
 ``[L*P, page, K, D]`` with the layer's table offset by ``l*P``, so no
-per-layer slab is ever sliced out for the kernel: blocks are DMA'd from
-where the pages lie.
+per-layer slab is ever sliced out for the kernel: pages are copied from
+where they lie.
 
-int8 pools (``kv_cache_dtype="int8"``) ride the same grid with two extra
-per-page operands: the per-token-per-head scale planes ``[P, page, K]``
-(f32, ops/quantization.quantize_kv layout). The kernel dequantizes in
-VMEM — ``k_f32 = k_int8 * ks[..., None]`` — right before the QK/PV dots,
-so the HBM read per decode step is the int8 page plus a 4/Dh-sized scale
-row instead of a full-dtype page: the capacity win and the bandwidth win
-come from the same bytes."""
+int8 pools (``kv_cache_dtype="int8"``) ride the same walk with two more
+planes: the per-token-per-head scales ``[P, page, K]`` (f32,
+ops/quantization.quantize_kv layout), a page's copied beside it head-major
+``[K, page]``. The kernel dequantizes in VMEM, on the small side of each
+product: ``(q . k_int8) * ks`` and ``(p * vs) . v_int8``, so the HBM read
+per decode step is the int8 page plus a 4/Dh-sized scale row instead of a
+full-dtype page: the capacity win and the bandwidth win come from the same
+bytes."""
 
 from __future__ import annotations
 
@@ -44,83 +52,228 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from kubeflow_tpu.ops import auto_interpret
+from kubeflow_tpu.ops import VMEM_BUDGET_BYTES, auto_interpret
 from kubeflow_tpu.ops.attention import NEG_INF
 
 
-def _kernel(table_ref, len_ref, *rest,
-            page_size: int, sm_scale: float, num_pages_per_slot: int,
-            num_kv_heads: int, group: int, quantized: bool,
-            bounded: bool = False):
+# Most pages of a plane a turn of the decode walk copies and attends to at
+# once; fewer where both planes' double buffers would take over half of
+# ``VMEM_BUDGET_BYTES`` (``_pages_a_turn``), or the table row is shorter.
+DECODE_PAGES_PER_TURN = 4
+
+
+def _pages_a_turn(page_bytes: int, mpp: int) -> int:
+    """Pages of EACH plane a turn holds: two halves of two planes of them
+    lie in fast memory beside the scores."""
+    fit = VMEM_BUDGET_BYTES // 2 // (4 * page_bytes)
+    return max(1, min(DECODE_PAGES_PER_TURN, fit, mpp))
+
+
+def _walk_live_pages(table_ref, span, planes, sem, side_ref, attend):
+    """The walk over this grid step's table row (grid ``(rows,)``, in
+    order): its LIVE pages, ``first <= j < last`` of ``span(row)``, ``n`` a
+    turn, copied from where they lie in HBM into one half of a double
+    buffer while the turn before is attended to in the other. A row's last
+    turn starts the first copies of the next row that has a turn, so a copy's
+    latency is paid once a call and not once a row. ``planes``: ``(pool_ref
+    [P, ...] in HBM, buf_ref [2, n, ...])`` a plane; ``side_ref`` (SMEM,
+    [1]): the half the row's first turn lies in, carried from row to row;
+    ``attend(half, j0, mapped)`` is called once a turn, its pages landed in
+    ``buf_ref[half]``: ``j0`` the turn's first page and ``mapped`` a flag a
+    page. A page with no id (a hole: -1) or past ``last`` (the last turn is
+    short) is neither copied nor waited for: its flag is False and its
+    buffer holds ANYTHING (NaN too), so ``attend`` takes nothing from it. A
+    turn with no mapped page is not attended to at all, so a dead row (every
+    id -1) costs its scalars."""
+    n = planes[0][1].shape[1]
+    mpp = table_ref.shape[1]
+    b, rows = pl.program_id(0), pl.num_programs(0)
+
+    def turns_of(r):
+        first, last = span(r)
+        return jax.lax.div(jnp.maximum(last - first, 0) + n - 1, n)
+
+    def row_behind(r):
+        """The first row behind ``r`` that has a turn (``rows``: none)."""
+        return jax.lax.while_loop(
+            lambda r: jnp.logical_and(r < rows, turns_of(
+                jnp.minimum(r, rows - 1)) == 0), lambda r: r + 1, r + 1)
+
+    def pages_of(r, t):
+        """(the page's id, whether it is copied) for each of turn t's."""
+        first, last = span(r)
+        out = []
+        for i in range(n):
+            j = first + t * n + i
+            pid = table_ref[r, jnp.minimum(j, mpp - 1)]
+            out.append((pid, jnp.logical_and(j < last, pid >= 0)))
+        return out
+
+    def copies(half, i, pid):
+        return [pltpu.make_async_copy(pool.at[pid], buf.at[half, i],
+                                      sem.at[half])
+                for pool, buf in planes]
+
+    def start(r, t, half):
+        for i, (pid, mapped) in enumerate(pages_of(r, t)):
+            @pl.when(mapped)
+            def _():
+                for copy in copies(half, i, pid):
+                    copy.start()
+
+    @pl.when(b == 0)
+    def _():
+        side_ref[0] = 0
+        r = row_behind(-1)
+        pl.when(r < rows)(lambda: start(jnp.minimum(r, rows - 1), 0, 0))
+
+    turns = turns_of(b)
+    side = side_ref[0]
+
+    def turn(t, _):
+        half = jax.lax.rem(side + t, 2)
+        more = t + 1 < turns            # else: the next row's first turn
+        r = jnp.where(more, b, row_behind(b))
+        pl.when(r < rows)(lambda: start(
+            jnp.minimum(r, rows - 1), jnp.where(more, t + 1, 0), 1 - half))
+        pages = pages_of(b, t)
+        for i, (pid, mapped) in enumerate(pages):
+            @pl.when(mapped)
+            def _():
+                for copy in copies(half, i, pid):
+                    copy.wait()     # blocking-ok: a DMA semaphore, in-kernel
+        mapped = [m for _, m in pages]
+        pl.when(functools.reduce(jnp.logical_or, mapped))(
+            lambda: attend(half, span(b)[0] + t * n, mapped))
+
+    jax.lax.fori_loop(0, turns, turn, None)
+    side_ref[0] = jax.lax.rem(side + turns, 2)
+
+
+def _decode_kernel(table_ref, len_ref, *rest, page_size: int,
+                   sm_scale: float, quantized: bool, bounded: bool,
+                   strided: bool):
     lo_ref = None
     if bounded:                         # a third scalar operand: the lowest
         lo_ref, rest = rest[0], rest[1:]    # position each row attends to
-    q_ref, k_ref, v_ref, *rest = rest
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_ref, l_ref, acc_ref = rest
+    q_ref, *rest = rest
+    num = 4 if quantized else 2         # planes: K, V and, int8, their scales
+    pools, o_ref, bufs = rest[:num], rest[num], rest[num + 1:2 * num + 1]
+    sem, side_ref, q_rows, m_ref, l_ref, acc_ref, s_ref = rest[2 * num + 1:]
+    k_buf, v_buf = bufs[:2]
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    h = num_kv_heads * group
-    d = q_ref.shape[-1]
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
+    pg = page_size
+    n = k_buf.shape[1]
+    kv, g, d = acc_ref.shape
+    mpp = table_ref.shape[1]
     length = len_ref[b]                 # position being decoded (inclusive)
-    needed = jnp.logical_and(j * page_size <= length, table_ref[b, j] >= 0)
-    if bounded:                         # a page wholly behind the bound
-        needed = jnp.logical_and(needed, (j + 1) * page_size > lo_ref[b])
+    lowest = jnp.maximum(lo_ref[b], 0) if bounded else 0
 
-    @pl.when(needed)
-    def _compute():
-        qg = q_ref[0, 0].astype(jnp.float32).reshape(
-            num_kv_heads, group, d)                  # [K, g, d]
-        k = k_ref[0].astype(jnp.float32)             # [pg, K, d]
-        if quantized:
-            # int8 page → f32 operand in VMEM: per-token-per-head scale
-            # broadcast over head_dim (quantize_kv's axis=-1 layout).
-            k = k * ks_ref[0][:, :, None]            # [pg, K, 1]
-        kt = jnp.swapaxes(k, 0, 1)                   # [K, pg, d]
-        s = jax.lax.dot_general(
-            qg, kt, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * sm_scale   # [K, g, pg]
-        s = s.reshape(h, page_size)
-        kv_pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
+    def span(r):
+        """Row ``r``'s live pages: from the page of the lowest position it
+        attends to up to the page of its length (a length below 0: none)."""
+        first = jax.lax.div(jnp.maximum(lo_ref[r], 0), pg) if bounded else 0
+        return first, jnp.clip(jax.lax.div(len_ref[r] + pg, pg), 0, mpp)
+
+    _softmax_init(m_ref, l_ref, acc_ref)
+    # The queries a KV head: q and the result keep the shapes the projections
+    # around the call have, [H, D] (another shape moves the layouts XLA gives
+    # those projections' weights: tests/test_chip_compile.py, the copies).
+    q_rows[:] = q_ref[0, 0].astype(jnp.float32).reshape(kv, g, d)
+    # the heads of a 32-bit word of a row (the strided form): _word_heads
+    per = 4 // k_buf.dtype.itemsize if strided else 1
+
+    def page_heads(buf, half, i):
+        """Page ``i`` of the half head-major ``[KV, page, D]`` in float32:
+        the form for planes the strided load does not take (int8 pages,
+        still to be scaled; one KV head, whose page is ``[page, D]``; an
+        odd count of two-byte heads; rows that are not 128 values)."""
+        x = buf[half, i].astype(jnp.float32)
+        return x[None] if x.ndim == 2 else jnp.swapaxes(x, 0, 1)
+
+    def attend(half, j0, mapped):
+        at = [slice(i * pg, (i + 1) * pg) for i in range(n)]
+        if strided:
+            def scores(w, _):
+                for i in range(n):
+                    words = _page_words(k_buf.at[half, i], per)[
+                        pl.ds(w, pg, stride=kv // per), :]
+                    for part, rows in enumerate(_word_heads(
+                            words, k_buf.dtype)):
+                        head = w * per + part
+                        s_ref[head, :, at[i]] = jax.lax.dot_general(
+                            q_rows[head].astype(rows.dtype), rows,
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)  # [g, pg]
+
+            # One trip a word of heads, not KV unrolled copies of it: the
+            # body is lowered again at every call site (PERF.md, PR 36).
+            jax.lax.fori_loop(0, kv // per, scores, None)
+        else:
+            for i in range(n):
+                s = jax.lax.dot_general(
+                    q_rows[:], page_heads(k_buf, half, i),
+                    (((2,), (2,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32)  # [KV, g, pg]
+                if quantized:   # an int8 page's scales, [KV, pg], act on
+                    s = s * bufs[2][half, i][:, None, :]     # its scores
+                s_ref[:, :, at[i]] = s
+
+        kv_page = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n * pg), 2) // pg
+        kv_pos = j0 * pg + jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, n * pg), 2)
         seen = kv_pos <= length
         if bounded:
-            seen = jnp.logical_and(seen, kv_pos >= lo_ref[b])
-        s = jnp.where(seen, s, NEG_INF)
-
-        m_prev = m_ref[:]                            # [h, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                       # [h, pg]
+            seen = jnp.logical_and(seen, kv_pos >= lowest)
+        for i, m in enumerate(mapped):  # what a page never copied holds
+            seen = jnp.logical_and(seen, jnp.logical_or(m, kv_page != i))
+        s = jnp.where(seen, s_ref[:] * sm_scale, NEG_INF)     # [KV, g, n*pg]
+        m_prev = m_ref[:]                                # [KV, g, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0].astype(jnp.float32)             # [pg, K, d]
-        if quantized:
-            v = v * vs_ref[0][:, :, None]
-        vt = jnp.swapaxes(v, 0, 1)                   # [K, pg, d]
-        pv = jax.lax.dot_general(
-            p.reshape(num_kv_heads, group, page_size), vt,
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)      # [K, g, d]
-        acc_ref[:] = acc_ref[:] * alpha + pv.reshape(h, d)
+        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=2, keepdims=True)
         m_ref[:] = m_new
+        s_ref[:] = p
 
-    @pl.when(j == num_pages_per_slot - 1)
-    def _finalize():
-        # Dead rows (live=False upstream: length masks everything) keep
-        # l == 0: emit zeros, the host discards them anyway.
-        l = l_ref[:]
-        safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[:] / safe).astype(o_ref.dtype)
+        acc_ref[:] = acc_ref[:] * alpha
+
+        if strided:
+            # Probabilities go to the MXU in the pool's type, as in the XLA
+            # form (serve/paged.py::_decode_attention); a page never copied
+            # adds nothing, whatever its buffer holds (0 x NaN is NaN).
+            def weighted(w, _):
+                out = [jnp.zeros((g, d), jnp.float32) for _ in range(per)]
+                for i in range(n):
+                    words = _page_words(v_buf.at[half, i], per)[
+                        pl.ds(w, pg, stride=kv // per), :]
+                    for part, rows in enumerate(_word_heads(
+                            words, v_buf.dtype)):
+                        out[part] += jnp.where(mapped[i], jax.lax.dot_general(
+                            s_ref[w * per + part, :, at[i]].astype(
+                                rows.dtype),
+                            rows, (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32), 0)  # [g, D]
+                for part in range(per):
+                    acc_ref[w * per + part] += out[part]
+
+            jax.lax.fori_loop(0, kv // per, weighted, None)
+        else:
+            for i in range(n):
+                p = s_ref[:, :, at[i]]
+                if quantized:                   # and on its probabilities
+                    p = p * bufs[3][half, i][:, None, :]
+                acc_ref[:] += jnp.where(mapped[i], jax.lax.dot_general(
+                    p, page_heads(v_buf, half, i),
+                    (((2,), (1,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32), 0)  # [KV, g, D]
+
+    _walk_live_pages(table_ref, span, list(zip(pools, bufs)), sem, side_ref,
+                     attend)
+    # A row that attended to nothing (a dead slot: every id -1) keeps l ==
+    # 0 and emits zeros; the host discards them anyway.
+    o_ref[0, 0] = _softmax_result(l_ref, acc_ref, o_ref.dtype).reshape(
+        kv * g, d)
 
 
 def paged_decode_attention(
@@ -143,11 +296,10 @@ def paged_decode_attention(
 
     ``lower`` (a window layer's call): row ``b`` attends to positions
     ``lower[b] <= j <= lengths[b]`` of ITS table row, keys behind the bound
-    are masked and a page wholly behind it is not computed on. Positions
-    count from the table row's first page, so a caller that hands in only
-    the pages a window touches (serve/paged.py: two of a ring) reads no
-    other; the call is named ``paged_window_decode_attention`` in a trace.
-    Without it the kernel is what it was."""
+    are masked and a page wholly behind it is not read. Positions count
+    from the table row's first page, so a caller that hands in only the
+    pages a window touches (serve/paged.py: two of a ring) reads no other;
+    the call is named ``paged_window_decode_attention`` in a trace."""
     b, one, h, d = q.shape
     if one != 1:
         raise ValueError("paged decode attention takes one token per slot")
@@ -156,60 +308,72 @@ def paged_decode_attention(
         raise ValueError(f"q heads {h} must be a multiple of kv heads {kh}")
     if (pool_ks is None) != (pool_vs is None):
         raise ValueError("pool_ks and pool_vs must be given together")
+    return _decode_attention_call(
+        q, pool_k, pool_v, table, lengths, pool_ks, pool_vs, lower,
+        sm_scale=sm_scale if sm_scale is not None else d ** -0.5,
+        interpret=interpret if interpret is not None else auto_interpret())
+
+
+# Traced ONCE for each set of shapes and inlined wherever it is called: the
+# programs of a decode ladder (serve/pacing.py) attend through the same call.
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"),
+                   inline=True)
+def _decode_attention_call(q, pool_k, pool_v, table, lengths, pool_ks,
+                           pool_vs, lower, *, sm_scale: float,
+                           interpret: bool):
+    b, _, h, d = q.shape
+    p_total, page, kh, _ = pool_k.shape
     quantized = pool_ks is not None
     g = h // kh
     mpp = table.shape[1]
-    scale = sm_scale if sm_scale is not None else d ** -0.5
+    n = _pages_a_turn(page * kh * d * pool_k.dtype.itemsize, mpp)
     bounded = lower is not None
     kernel = functools.partial(
-        _kernel, page_size=page, sm_scale=scale, num_pages_per_slot=mpp,
-        num_kv_heads=kh, group=g, quantized=quantized, bounded=bounded)
+        _decode_kernel, page_size=page, sm_scale=sm_scale,
+        quantized=quantized, bounded=bounded,
+        strided=not quantized and kh > 1
+        and chunk_attention_supported(kh, d, pool_k.dtype))
     scalars = (table, lengths, lower) if bounded else (table, lengths)
+    planes = [pool_k, pool_v]
+    if kh == 1:     # one KV head: a page is its rows (a bitcast; the chip's
+        # copy engine takes no slice of a dimension of 1 padded to a tile)
+        planes = [pool.reshape(p_total, page, d) for pool in planes]
+    if quantized:   # a page's scales head-major [K, page]: how the chip
+        # lays the plane out (a bitcast there), whole tiles for the copy
+        planes += [jnp.swapaxes(scales.astype(jnp.float32), 1, 2)
+                   for scales in (pool_ks, pool_vs)]
 
-    def q_map(bi, ji, table_ref, *_):
+    def row(bi, *_):
         return (bi, 0, 0, 0)
 
-    def kv_map(bi, ji, table_ref, *_):
-        # Unmapped pages clamp to page 0: the DMA happens but the compute
-        # predicate never reads it.
-        return (jnp.maximum(table_ref[bi, ji], 0), 0, 0, 0)
-
-    def scale_map(bi, ji, table_ref, *_):
-        return (jnp.maximum(table_ref[bi, ji], 0), 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, h, d), q_map),
-        pl.BlockSpec((1, page, kh, d), kv_map),
-        pl.BlockSpec((1, page, kh, d), kv_map),
-    ]
-    operands = [q, pool_k, pool_v]
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((1, page, kh), scale_map),
-            pl.BlockSpec((1, page, kh), scale_map),
-        ]
-        operands += [pool_ks.astype(jnp.float32),
-                     pool_vs.astype(jnp.float32)]
-
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         name=("paged_window_decode_attention" if bounded
               else "paged_decode_attention"),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
-            grid=(b, mpp),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, h, d), q_map),
+            grid=(b,),
+            in_specs=[pl.BlockSpec((1, 1, h, d), row)]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(planes),
+            out_specs=pl.BlockSpec((1, 1, h, d), row),
             scratch_shapes=[
-                pltpu.VMEM((h, 1), jnp.float32),   # running max m
-                pltpu.VMEM((h, 1), jnp.float32),   # running denom l
-                pltpu.VMEM((h, d), jnp.float32),   # output accumulator
+                pltpu.VMEM((2, n, *plane.shape[1:]), plane.dtype)
+                for plane in planes] + [
+                pltpu.SemaphoreType.DMA((2,)),          # one a half
+                pltpu.SMEM((1,), jnp.int32),    # the half a row starts in
+                pltpu.VMEM((kh, g, d), jnp.float32),    # queries a KV head
+                pltpu.VMEM((kh, g, 1), jnp.float32),    # running max m
+                pltpu.VMEM((kh, g, 1), jnp.float32),    # running denom l
+                pltpu.VMEM((kh, g, d), jnp.float32),    # output accumulator
+                pltpu.VMEM((kh, g, n * page), jnp.float32),  # scores, probs
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, 1, h, d), q.dtype),
-        interpret=interpret if interpret is not None else auto_interpret(),
-    )(*scalars, *operands)
-    return out
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        # rows in order: a row's last turn starts the next row's copies
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(*scalars, q, *planes)
 
 
 # -- latent (MLA) pools ----------------------------------------------------------
@@ -222,9 +386,11 @@ def paged_decode_attention(
 # row, so a head's score is ONE product with the row) and expands the
 # attended row afterwards (``layers.latent_output``), so a kernel is
 # multi-query attention of H heads against one shared row that is also the
-# value, and no per-head K or V of the context ever exists. Two kernels, the
-# schedule of the one above (pages DMA'd from where they lie, blockwise
-# softmax, float32 accumulation): one query a slot (decode), and a chunk of
+# value, and no per-head K or V of the context ever exists. Two kernels on a
+# grid ``(rows, pages)``, a page a step through its index map (the schedule
+# the per-head decode kernel had before PR 45; ``_walk_live_pages`` is
+# written to take them: ROADMAP Speed 2), blockwise softmax, float32
+# accumulation: one query a slot (decode), and a chunk of
 # queries of one slot (chunk prefill).
 
 def _online_softmax_step(s, rows, m_ref, l_ref, acc_ref, at=slice(None)):
@@ -542,6 +708,16 @@ def chunk_attention_supported(num_kv_heads: int, head_dim: int,
         or (dtype == jnp.bfloat16 and num_kv_heads % 2 == 0))
 
 
+def _page_words(page_ref, per: int):
+    """A page ``[page, KV, D]`` in fast memory as rows of 32-bit words
+    ``[page * KV / per, D]`` (``per`` heads a word: 1 for float32, 2 for
+    two-byte rows): every (KV / per)-th row from ``w`` on is word ``w`` of
+    the page's tokens in order."""
+    page, kv, d = page_ref.shape
+    rows = page_ref.reshape(page * kv, d)
+    return rows if per == 1 else rows.bitcast(jnp.uint32)
+
+
 def _word_heads(words, dtype) -> list:
     """The heads a page's strided rows hold, [page, D] each in ``dtype``: a
     float32 row is its head; a 32-bit word of two-byte rows holds heads 2i
@@ -567,15 +743,8 @@ def _chunk_kernel(table_ref, start_ref, q_ref, *rest, page_size: int,
     pl.when(j == 0)(lambda: _softmax_init(m_ref, l_ref, acc_ref))
     first = start_ref[0] + pl.program_id(0) * tile   # the tile's first query
 
-    def words_of(page_ref):
-        """A page block [1, page, KV, D] as rows of 32-bit words
-        ``[page * KV / per, D]``: every (KV / per)-th row from ``w`` on is
-        word ``w`` of the page's tokens in order."""
-        rows = page_ref.at[0].reshape(page_size * kv, d)
-        return rows if per == 1 else rows.bitcast(jnp.uint32)
-
-    planes = [(k_rows, [words_of(r) for r in k_refs]),
-              (v_rows, [words_of(r) for r in v_refs])]
+    planes = [(k_rows, [_page_words(r.at[0], per) for r in k_refs]),
+              (v_rows, [_page_words(r.at[0], per) for r in v_refs])]
 
     def attend(masked: bool):
         if masked:

@@ -2,14 +2,20 @@
 window layer's calls and a held share's grouped matmuls cost, read off a
 trace of the mixed-length cell's OWN programs at the cell's sizes.
 
-    python3 scripts/exaone_kernels_chip.py --seed <n>
+    python3 scripts/exaone_kernels_chip.py --seed <n> [--root .parent]
+        [--parts decode]
+
+``--root DIR`` takes ``kubeflow_tpu`` and ``benchmark`` from another checkout
+(``git archive <parent> | tar -x -C .parent``), so that one call to the chip
+reads the parent and the change one after the other.
 
 Builds the cell's engine as the benchmark does (weights from the seed, the
 ``BatchingSpec`` of the traffic file; no reference, no server) and traces
 
 1. the decode step (``paged._paged_decode_step``, the program ``correct``
-   drives) over all 32 slots, a token of its own each, at a SHORT and at a
-   LONG context, every slot on pages of its own with its first pages from the
+   drives) over all 32 slots, a token of its own each, at contexts of 512,
+   4096 and 8192 (what a call costs whatever it reads, and a live page's
+   rate: PR 45), every slot on pages of its own with its first pages from the
    ring's ids and the pages its context has not reached unmapped: per call the
    global layer's ``paged_decode_attention``, the window layers'
    ``paged_window_decode_attention`` (its grid is two pages a stream whatever
@@ -97,7 +103,13 @@ def main(argv=None) -> int:
     ap.add_argument("--calls", type=int, default=20)
     ap.add_argument("--tiny", action="store_true",
                     help="rehearse on the CPU at the tiny-exaone preset")
+    ap.add_argument("--root", default=None,
+                    help="another checkout to take the program from")
+    ap.add_argument("--parts", default="decode,chunk")
     args = ap.parse_args(argv)
+    if args.root:
+        sys.path.insert(0, os.path.abspath(args.root))
+    side = {"root": args.root or "."}
 
     from benchmark import architecture, device
     from benchmark import manifest as mf
@@ -165,9 +177,10 @@ def main(argv=None) -> int:
             return lg
         return run
 
-    short, long_ = (C, mpp * pg) if args.tiny else (512, 8192)
-    for context in (short, long_):
-        print(json.dumps({"part": "decode_step", "context": context,
+    contexts = (C, mpp * pg) if args.tiny else (512, 4096, 8192)
+    long_ = contexts[-1]
+    for context in contexts if "decode" in args.parts else ():
+        print(json.dumps({**side, "part": "decode_step", "context": context,
                           "slots": slots, "window_pages_a_stream": -(-(
                               cfg.attn_window - 1) // pg) + 1,
                           **traced(decode_at(context), args.calls)}),
@@ -179,7 +192,7 @@ def main(argv=None) -> int:
 
     block = jnp.asarray(rng.integers(
         3, conf["vocab_size"], (2, C)).astype(np.int32))
-    for start in (0, long_ - C):
+    for start in (0, long_ - C) if "chunk" in args.parts else ():
         starts = jnp.full((2,), start, jnp.int32)
         valid = jnp.full((2,), C, jnp.int32)
 
@@ -191,7 +204,8 @@ def main(argv=None) -> int:
         before = rows_now()
         numbers = traced(run, args.calls)
         routed, held = (rows_now() - before) // (args.calls + 1)
-        print(json.dumps({"part": "chunk_program", "rows": 2, "start": start,
+        print(json.dumps({**side, "part": "chunk_program", "rows": 2,
+                          "start": start,
                           "expert_rows_routed_a_program": int(routed),
                           "expert_rows_held_a_program": int(held),
                           **numbers}), flush=True)

@@ -2,14 +2,22 @@
 two gated delta-rule kernels and the XLA part of the chunked form cost, read
 off a trace of the long-document cell's OWN programs at the cell's sizes.
 
-    python3 scripts/solar_kernels_chip.py --seed <n>
+    python3 scripts/solar_kernels_chip.py --seed <n> [--root .parent]
+        [--parts decode]
+
+``--root DIR`` takes ``kubeflow_tpu`` and ``benchmark`` from another checkout
+(``git archive <parent> | tar -x -C .parent``), so that one call to the chip
+reads the parent and the change one after the other:
+``... --root .parent --parts decode; ... --parts decode``.
 
 Builds the cell's engine as the benchmark does (weights from the seed, the
 ``BatchingSpec`` of the traffic file; no reference, no server) and traces
 
 1. the decode step (``paged._paged_decode_step``, the program ``correct``
-   drives) over all 32 slots, a token of its own each, at a context of 4096
-   and of 16384, every slot on pages of its own with its first page (its
+   drives) over all 32 slots, a token of its own each, at contexts of 512,
+   4096 and 16384 (the GQA call's time at the first is what a call costs
+   whatever it reads, the slope between the other two a live page's rate:
+   PR 45), every slot on pages of its own with its first page (its
    state's entry) from the entries' ids: per call the three ``kda_step`` (a
    stream's [64, 128, 128] float32 state in and out where it lies), the GQA
    layer's ``paged_decode_attention`` and the grouped matmuls, each beside
@@ -64,7 +72,13 @@ def main(argv=None) -> int:
     ap.add_argument("--calls", type=int, default=20)
     ap.add_argument("--tiny", action="store_true",
                     help="rehearse on the CPU at the tiny-solar preset")
+    ap.add_argument("--root", default=None,
+                    help="another checkout to take the program from")
+    ap.add_argument("--parts", default="decode,chunk")
     args = ap.parse_args(argv)
+    if args.root:
+        sys.path.insert(0, os.path.abspath(args.root))
+    side = {"root": args.root or "."}
 
     from benchmark import architecture, device
     from benchmark import manifest as mf
@@ -125,11 +139,13 @@ def main(argv=None) -> int:
             return lg
         return run
 
-    short, long_ = (C, mpp * pg) if args.tiny else (4096, 16384)
+    contexts = (C, mpp * pg) if args.tiny else (512, 4096, 16384)
+    long_ = contexts[-1]
     bus = 819e9
-    for context in (short, long_):
+    for context in contexts if "decode" in args.parts else ():
         print(json.dumps({
-            "part": "decode_step", "context": context, "slots": slots,
+            **side, "part": "decode_step", "context": context,
+            "slots": slots,
             "kda_step_ms_at_the_bus": round(
                 1e3 * counts.kda_step_bytes(conf, slots) / bus, 4),
             "gqa_decode_ms_at_the_bus": round(
@@ -141,7 +157,7 @@ def main(argv=None) -> int:
     block = jnp.asarray(rng.integers(
         3, conf["vocab_size"], (2, C)).astype(np.int32))
     rows = jnp.asarray(table[:2])
-    for start in (0, long_ - C):
+    for start in (0, long_ - C) if "chunk" in args.parts else ():
         starts = jnp.full((2,), start, jnp.int32)
         valid = jnp.full((2,), C, jnp.int32)
 
@@ -151,7 +167,7 @@ def main(argv=None) -> int:
                 mpp)
             return logits
         print(json.dumps({
-            "part": "chunk_program", "rows": 2, "start": start,
+            **side, "part": "chunk_program", "rows": 2, "start": start,
             "kda_operands_ms_at_the_bus": round(
                 1e3 * operands_bytes(cfg, 2 * C) / bus, 4),
             "kda_chunk_ms_at_the_bus": round(

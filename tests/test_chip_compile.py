@@ -178,6 +178,46 @@ def test_kernel_compiles_for_v5e(chip, kernel, widths):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# -- PR 45: the decode kernel walks a row's live pages by its own copies ---------
+
+# shape -> (rows, query heads, KV heads, pages of the flat plane, pages a
+# table row, a window layer's call): the chat cell's decode step (16 layers x
+# 448 pages), the long-document cell's GQA layer, K-EXAONE's window layers
+# (four rings of 192 pages, two pages a row).
+WALK_SHAPES = {
+    "chat": (32, 32, 8, 16 * 448, 32, False),
+    "longdoc": (32, 64, 8, 4352, 136, False),
+    "window": (32, 64, 8, 4 * 192, 2, True),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(WALK_SHAPES))
+def test_decode_walk_compiles_for_v5e(chip, shape):
+    """``paged_decode_attention`` at the cells' shapes: a loop of a traced
+    length around copies and waits, four pages of each plane a turn in a
+    double buffer (4 MiB of the 16 MiB scoped limit: a kernel over it is
+    refused here), the pools left in HBM where they lie (the call holds
+    nothing beyond its arguments and its result: no copy of a plane)."""
+    from kubeflow_tpu.ops import paged_attention as pa
+
+    rows, h, kv, pages, mpp, window = WALK_SHAPES[shape]
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    assert pa._pages_a_turn(PAGE * kv * 128 * 2, mpp) == min(4, mpp)
+    pool = sds((pages, PAGE, kv, 128))
+    args = [sds((rows, 1, h, 128)), pool, pool, sds((rows, mpp), jnp.int32),
+            sds((rows,), jnp.int32)] + [sds((rows,), jnp.int32)] * window
+    compiled = jax.jit(lambda q, k, v, t, ln, lo=None: (
+        pa.paged_decode_attention(q, k, v, t, ln, lower=lo,
+                                  interpret=False))).lower(*args).compile()
+    text = compiled.as_text()
+    assert _calls(text, "paged_window_decode_attention" if window
+                  else "paged_decode_attention") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
 # -- GLM-4.7-Flash's widths: the latent decode kernel, the sorted experts -------
 
 def test_latent_kernels_compile_for_v5e(chip):
@@ -361,16 +401,25 @@ SERVING_CELLS = {
 # the new digest here and says why.
 LOWERED_BEFORE_PR40 = {
     ("mistral-7b.chat-open", "chunk[1]"): "9a16ab9307d01ba7",
-    ("mistral-7b.chat-open", "decode"): "db316db56b64acbb",
     ("mixtral-8x7b.batch-longprompt", "chunk[1]"): "03a77e220d400617",
     ("mixtral-8x7b.batch-longprompt", "chunk[2]"): "24684074f747f6c2",
-    ("mixtral-8x7b.batch-longprompt", "decode"): "ddb784f45f5677a3",
     ("glm-4.7-flash.batch-longcontext", "chunk[1]"): "57ce1b084168e27a",
     ("glm-4.7-flash.batch-longcontext", "chunk[2]"): "4ca45b4b80949972",
     ("glm-4.7-flash.batch-longcontext", "decode"): "51228f8cbfaf3acc",
     ("lfm2-24b-a2b.batch-longanswer", "chunk[1]"): "6a4770d8248e2dcb",
     ("lfm2-24b-a2b.batch-longanswer", "chunk[2]"): "3aa5bb964b3b2815",
     ("lfm2-24b-a2b.batch-longanswer", "decode"): "ac6c858f412bc9bb",
+}
+# PR 45 gave ``paged_decode_attention`` another schedule (grid ``(rows,)``,
+# the kernel walks a row's live pages by its own copies): the decode programs
+# of the cells that attend through it are new programs, pinned here as PR 45
+# left them (db316db56b64acbb / ddb784f45f5677a3 before). Every ``chunk[...]``
+# digest above and the decode digests of GLM (latent rows) and LFM2 (packed
+# rows), whose kernels PR 45 does not touch, stand as they were: the proof
+# that those two cells run what they ran.
+DECODE_SINCE_PR45 = {
+    ("mistral-7b.chat-open", "decode"): "21df67c531cb4588",
+    ("mixtral-8x7b.batch-longprompt", "decode"): "2e6eb883f208d847",
 }
 # The program over rows as the engine builds it since PR 41 (the head at each
 # row's last valid position, ``[2, V]`` logits, under a conditional on "some
@@ -434,16 +483,18 @@ def test_serving_program_lowers_to_what_it_was_before_pr40(cell_programs,
     four accepted serving cells lower, at the cells' shapes for a described
     v5e, to the programs of the commit before the window went into
     ``paged_decode_attention`` / ``paged_chunk_attention`` and the share
-    into ``_moe_sorted``. The program over rows in its all-position form
-    too (``logits_at``'s default: what every caller but the engine's
-    program over rows takes); the form the engine builds since PR 41 is
-    pinned beside it."""
+    into ``_moe_sorted`` (the decode programs of the two cells that attend
+    through ``paged_decode_attention``: to PR 45's, ``DECODE_SINCE_PR45``).
+    The program over rows in its all-position form too (``logits_at``'s
+    default: what every caller but the engine's program over rows takes);
+    the form the engine builds since PR 41 is pinned beside it."""
     from scripts.aot_weight_copies import lowered_fingerprint
 
     rows = program == "chunk[2]"
+    pinned = {**LOWERED_BEFORE_PR40, **DECODE_SINCE_PR45}
     assert lowered_fingerprint(
         cell_programs(cell, True, "all" if rows else "last")[program]) \
-        == LOWERED_BEFORE_PR40[cell, program]
+        == pinned[cell, program]
     if rows:
         assert lowered_fingerprint(cell_programs(cell)[program]) \
             == ROWS_PROGRAM_SINCE_PR41[cell]
@@ -533,7 +584,8 @@ def test_the_detector_finds_the_copies_of_default_layouts(cell_programs):
 
 def _calls(text: str, kernel: str) -> int:
     """Instructions of a compiled text named ``kernel`` (or ``kernel.N``)."""
-    return len(re.findall(rf"^\s*%?{kernel}[.\d]* = ", text, re.M))
+    return len(re.findall(rf"^\s*(?:ROOT )?%?{kernel}[.\d]* = ", text,
+                          re.M))
 
 
 def _f32_relayouts(text: str, elements: int) -> list:

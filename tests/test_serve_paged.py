@@ -499,6 +499,140 @@ class TestPagedAttentionKernel:
                 paged_attn_impl="flash"), params=params)
 
 
+# -- the decode kernel walks a row's live pages itself ----------------------------
+#
+# One call a case: rows [B] over a pool of pages of 16 tokens (heads of 128,
+# two KV heads: the strided form of the kernel, as on the chip), ``N`` = 4
+# pages a turn. A row is (table row, length, lower or None).
+
+_WALK_MPP = 10
+_WALK = {
+    # contexts that end on a page's FIRST and LAST token; 1, 4, 5, 8 and 9
+    # live pages (a whole and a short last turn); a row of length 0
+    "page_edges": [([3], 0, None), ([4, 5, 6, 7], 48, None),
+                   ([8, 9, 10, 11], 63, None),
+                   ([12, 13, 14, 15, 16], 64, None),
+                   (list(range(17, 25)), 127, None),
+                   (list(range(25, 34)), 128, None)],
+    # dead rows (every id -1, a stale length) between live ones
+    "dead_rows": [([], 37, None), ([1, 2, 3], 40, None), ([], 0, None),
+                  ([], 159, None), ([4, 5, 6, 7, 8, 9], 90, None),
+                  ([], 5, None)],
+    # a hole inside a live row's range: in a turn of its own pages, alone
+    # in the last turn, the row's first page
+    "hole": [([1, -1, 2, 3, 4, 5], 93, None), ([6, 7, 8, 9, -1], 79, None),
+             ([-1, 10, 11], 40, None), ([12, 13, -1, -1, 14, -1, 15], 110,
+                                        None)],
+    # ``lower`` inside the first page, on a page's edge, past whole turns
+    "lower": [([1, 2, 3], 40, 5), ([4, 5, 6, 7, 8, 9], 95, 32),
+              ([10, 11, 12, 13, 14, 15, 16, 17, 18], 143, 100),
+              ([19, 20], 31, 31), ([21, 22, 23], 47, 0)],
+    # the window layers' call: a two-page table, its walk one turn
+    "window": [([1, 2], 20, 5), ([3, 4], 31, 16), ([5, -1], 9, 0),
+               ([6, 7], 16, 1), ([], 0, 0)],
+}
+
+
+def _walk_rows(rows, mpp):
+    import numpy as np
+
+    table = np.full((len(rows), mpp), -1, np.int32)
+    for r, (ids, _, _) in enumerate(rows):
+        table[r, :len(ids)] = ids
+    lengths = np.asarray([ln for _, ln, _ in rows], np.int32)
+    bounded = rows[0][2] is not None
+    lower = np.asarray([lo for _, _, lo in rows], np.int32) if bounded \
+        else None
+    return table, lengths, lower
+
+
+def _without_holes(table, lengths, lower, pg):
+    """The same attention with no hole: a row's unmapped pages inside its
+    range are cut out and the positions behind them move up (attention
+    knows no position beyond the masks)."""
+    import numpy as np
+
+    table, lengths = table.copy(), lengths.copy()
+    lower = None if lower is None else lower.copy()
+    for r in range(table.shape[0]):
+        last = lengths[r] // pg
+        keep = [j for j in range(table.shape[1])
+                if not (table[r, j] < 0 and j <= last)]
+        cut = [j for j in range(last + 1) if table[r, j] < 0]
+        if len(cut) == last + 1:        # nothing to attend to
+            table[r], lengths[r] = -1, 0
+            continue
+        if lower is not None:
+            assert not any(j * pg < lower[r] for j in cut)
+        lengths[r] -= pg * len(cut)
+        row = table[r, keep]
+        table[r] = -1
+        table[r, :len(row)] = row
+    return table, lengths, lower
+
+
+@pytest.mark.parametrize("group", [4, 8])
+@pytest.mark.parametrize("pool", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("case", sorted(_WALK))
+def test_decode_kernel_walks_live_pages(cfg, case, pool, group):
+    """``paged_decode_attention`` against ``_decode_attention`` over the
+    gathered pages. Every unmapped page and every page no context holds is
+    POISONED (NaN): a walk that copied one, or attended to a buffer it did
+    not fill, shows it."""
+    import dataclasses
+
+    import numpy as np
+
+    from kubeflow_tpu.ops import paged_attention as pa
+    from kubeflow_tpu.ops.quantization import dequantize_kv, quantize_kv
+    from kubeflow_tpu.serve.paged import _decode_attention, paged_gather
+
+    pg, kv, d, pages = 16, 2, 128, 36
+    rows = _WALK[case]
+    mpp = 2 if case == "window" else _WALK_MPP
+    assert pa._pages_a_turn(pg * kv * d * 4, _WALK_MPP) == 4
+    table, lengths, lower = _walk_rows(rows, mpp)
+    rng = np.random.default_rng(len(case))
+    dt = jnp.float32 if pool == "int8" else jnp.dtype(pool)
+    pk, pv = (jnp.asarray(rng.normal(size=(pages, pg, kv, d)), dt)
+              for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(len(rows), 1, kv * group, d)), dt)
+    # what the walk may read: pages a row maps at or before its length's
+    used = {int(i) for r, (ids, ln, _) in enumerate(rows)
+            for j, i in enumerate(ids) if i >= 0 and j <= ln // pg}
+    idle = jnp.asarray([i not in used for i in range(pages)])[
+        :, None, None, None]
+    extra, scales = {}, None
+    if pool == "int8":
+        (pk, sk), (pv, sv) = quantize_kv(pk), quantize_kv(pv)
+        scales = (sk, sv)
+        extra = {"pool_ks": jnp.where(idle[..., 0], jnp.nan, sk),
+                 "pool_vs": jnp.where(idle[..., 0], jnp.nan, sv)}
+        poisoned = (pk, pv)
+    else:
+        poisoned = tuple(jnp.where(idle, jnp.nan, x) for x in (pk, pv))
+    got = pa.paged_decode_attention(
+        q, *poisoned, jnp.asarray(table), jnp.asarray(lengths),
+        lower=None if lower is None else jnp.asarray(lower), **extra)
+
+    if scales is not None:
+        pk, pv = (dequantize_kv(x, sc, jnp.float32)
+                  for x, sc in zip((pk, pv), scales))
+    t2, l2, lo2 = _without_holes(table, lengths, lower, pg)
+    c = dataclasses.replace(cfg, n_heads=kv * group, n_kv_heads=kv,
+                            head_dim=d)
+    want = _decode_attention(
+        q, paged_gather(pk, jnp.asarray(t2)), paged_gather(pv, jnp.asarray(t2)),
+        jnp.asarray(l2), c, lower=None if lo2 is None else jnp.asarray(lo2))
+    nothing = np.asarray((t2 < 0).all(axis=1))      # rows that attend to
+    want = jnp.where(nothing[:, None, None, None], 0, want)     # no page
+    err = jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32)).max()
+    assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
+    # bfloat16: the probabilities are rounded to the pool's type on both
+    # sides, before (the kernel) and after (the oracle) they are normed.
+    assert float(err) < (3e-2 if pool == "bfloat16" else 2e-5), float(err)
+
+
 # -- the decode step writes the pool in place (flat carry) ---------------------
 
 def _oracle_decode_step(params, cache, tokens, lengths, live, cfg, attn_impl):
