@@ -290,7 +290,6 @@ def attention_block(
     kv_cache: Optional[dict] = None,    # {"k","v": [B,Smax,K,Dh]}, + "len": scalar | [B]
     attn_impl: str = "xla",
     mesh=None,
-    prefill: bool = False,              # static: cache start is known to be 0
     tp_axis: Optional[str] = None,      # inside shard_map: heads sharded here
     lora: Optional[dict] = None,        # per-layer adapter view (apply_lora_layer)
     window: int = 0,                    # static: a window layer's length
@@ -346,30 +345,13 @@ def attention_block(
         ck = jax.lax.dynamic_update_slice_in_dim(kv_cache["k"], k, start, axis=1)
         cv = jax.lax.dynamic_update_slice_in_dim(kv_cache["v"], v, start, axis=1)
         new_cache = {"k": ck, "v": cv, "len": start + x.shape[1]}
-        if attn_impl == "pallas" and prefill:
-            # Prefill from an empty scratch cache: start is statically 0 and
-            # the cache length equals the block, so the flash kernel applies
-            # directly (its big win is exactly this forward-only pass).
-            # Under a multi-device mesh the kernel must run per-shard
-            # (Mosaic can't be GSPMD-partitioned) — the TP serving engine's
-            # sharded prefill path; non-dividing shapes fall back to XLA.
-            if mesh is not None and mesh.size > 1:
-                from kubeflow_tpu.ops.flash_attention import (
-                    flash_sharded_or_xla,
-                )
-
-                out = flash_sharded_or_xla(q, ck, cv, mesh, causal=True)
-            else:
-                out = multi_head_attention(q, ck, cv, causal=True, q_offset=0,
-                                           impl="pallas")
-        else:
-            # Decode with a traced cache offset: the masked XLA path (the
-            # pallas kernel needs a static q_offset).
-            impl = "xla" if attn_impl in ("pallas", "ring", "ring_flash",
-                                          "ulysses") else attn_impl
-            out = multi_head_attention(
-                q, ck, cv, causal=True, q_offset=start, impl=impl,
-                window=window)
+        # A traced cache offset: the masked XLA path (the pallas kernel
+        # needs a static q_offset).
+        impl = "xla" if attn_impl in ("pallas", "ring", "ring_flash",
+                                      "ulysses") else attn_impl
+        out = multi_head_attention(
+            q, ck, cv, causal=True, q_offset=start, impl=impl,
+            window=window)
     elif attn_impl in ("ring", "ring_flash", "ulysses"):
         # Sequence-parallel attention over the mesh 'seq' axis (SURVEY.md
         # §2.6 SP/CP rows). Degenerates to XLA attention when the mesh has

@@ -24,19 +24,24 @@ slot owns an ordered page list (its page table), and:
 Device side, the page table rides into the dispatch as a
 ``[B, max_pages_per_slot]`` int32 array; reads gather pages back into the
 ``[B, S, KV, Dh]`` layout XLA already tiles well, writes scatter
-``(page, offset)`` with out-of-bounds drops for dead rows. The decode dispatch never takes a layer's slab out of
-the pool: it carries every plane whole, viewed flat ``[L*P, page, KV, Dh]``,
-through its step loop and its layer scan, writes layer ``l``'s rows in place
-at flat page ``l*P + page`` and reads through the table offset by ``l*P``
-(``_paged_decode_step``), so a step moves the rows it writes and the pages
-it attends to, not the pool; a chunk prefill is built the same way
-wherever a kernel can attend a chunk of queries over the pool
-(``_paged_chunk_in_place``). The speculative verify dispatch
-(serve/spec_decode.py ``paged_verify_step``) extends the same contract
-with a verify-length axis — k+1 (page, offset) writes per slot per round —
-and rejection rolls the page table back to the accepted length
-(engine._truncate_slot_pages): truncated pages return to the free list,
-so pool refcounts account for exactly the tokens each slot kept.
+``(page, offset)`` with out-of-bounds drops for dead rows. No program that
+meets the pool where it lies takes a layer's slab out of it: it carries
+every plane whole, viewed flat ``[L*P, page, KV, Dh]``, through its loops
+and its layer scan, writes layer ``l``'s rows in place at flat page ``l*P +
+page`` and reads through the table offset by ``l*P``, so a program moves the
+rows it writes and the pages it attends to, not the pool. Those programs
+are ONE builder (``_pool_forward``) over ONE block (``_pool_block``) for
+``B`` rows of ``T`` tokens: the decode step is its ``T = 1``
+(``_paged_decode_step``), the chunk prefill wherever a kernel can attend a
+chunk of queries over the pool its ``T = chunk``
+(``_paged_chunk_in_place``), the speculative verify its ``T = k+1``
+(serve/spec_decode.py ``paged_verify_step``: k+1 (page, offset) writes per
+slot per round, in place like every other program's). Rejection rolls the
+page table back to the accepted length (engine._truncate_slot_pages):
+truncated pages return to the free list, so pool refcounts account for
+exactly the tokens each slot kept. The second way a chunk reaches the pool
+(``paged_chunk_prefill``'s gathered form, through ``decoder_forward``'s
+cache path) stays for int8 pools, packed rows, LoRA and "gather".
 Exactness: the "gather" attention impl runs the plain einsums over the
 gathered pages (the engine's greedy tokens are pinned against a
 full-recompute ``decoder_forward`` loop); the "pallas" impl
@@ -461,6 +466,14 @@ def _ring_len(cfg: DecoderConfig, mpp: int) -> int:
     return min(cfg.window_ring_pages or mpp, mpp)
 
 
+def _ring_slot(slot, cfg: DecoderConfig, mpp: int):  # traced
+    """Where in its row's table a window layer keeps logical page ``slot``:
+    ``slot mod R``, a ring over the sequence's own first ``R`` pages
+    (``_ring_len``). THE ring arithmetic: ``ring_table`` (reads), and the
+    write indices of both ways to the pool go through it."""
+    return slot % _ring_len(cfg, mpp)
+
+
 def ring_table(table: jax.Array, first: jax.Array, n: int,  # traced
                cfg: DecoderConfig) -> jax.Array:
     """Where a window layer keeps a sequence's logical pages ``first ..
@@ -470,8 +483,8 @@ def ring_table(table: jax.Array, first: jax.Array, n: int,  # traced
     pages (``cfg.window_ring_pages``; the whole row where none is set), in
     which page ``i`` overwrites page ``i - R``, which no query still sees.
     Found from the row and a position alone: no slot, no second table."""
-    ring = _ring_len(cfg, table.shape[1])
-    at = (first[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :]) % ring
+    at = _ring_slot(first[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :],
+                    cfg, table.shape[1])
     return jnp.take_along_axis(table, at, axis=1)
 
 
@@ -654,125 +667,178 @@ def _scan_layer_groups(params: Params, cfg: DecoderConfig, carry, block,  # trac
 
 def _decode_attention(q, ck, cv, lengths, cfg: DecoderConfig,  # traced
                       lower=None):
-    """One-token attention over the slots' gathered pages.
+    """Attention of ``T`` queries a row over the row's gathered pages, the
+    decode step's one and the verify step's ``k+1`` alike.
 
-    q [B,1,H,Dh]; ck/cv [B,Smax,KV,Dh]; lengths [B] = position of the token
-    being decoded (its K/V were just written at that index, so attend to
-    kpos <= lengths[b]); ``lower`` [B] (a window layer): and to kpos >=
-    lower[b]."""
-    b, smax = ck.shape[0], ck.shape[1]
+    q [B,T,H,Dh]; ck/cv [B,Smax,KV,Dh]; lengths [B] = position of a row's
+    first query (its K/V were just written at that index on, so query ``t``
+    attends to kpos <= lengths[b] + t); ``lower`` [B] or [B,T] (a window
+    layer): and to kpos >= lower."""
+    b, t, smax = q.shape[0], q.shape[1], ck.shape[1]
     groups = cfg.n_heads // cfg.n_kv_heads
-    qg = q.reshape(b, cfg.n_kv_heads, groups, cfg.head_dim)
-    scores = jnp.einsum("bkgd,bskd->bkgs", qg, ck,
+    qg = q.reshape(b, t, cfg.n_kv_heads, groups, cfg.head_dim)
+    scores = jnp.einsum("btkgd,bskd->btkgs", qg, ck,
                         preferred_element_type=jnp.float32)
     scores *= cfg.head_dim ** -0.5
     kpos = jnp.arange(smax, dtype=jnp.int32)
-    mask = kpos[None, :] <= lengths[:, None]            # [B, Smax]
+    qpos = lengths[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+    mask = kpos[None, None, :] <= qpos[:, :, None]            # [B,T,Smax]
     if lower is not None:
-        mask = mask & (kpos[None, :] >= lower[:, None])
-    scores = jnp.where(mask[:, None, None, :], scores, -1e30)
+        mask = mask & (kpos[None, None, :] >= lower.reshape(b, -1, 1))
+    scores = jnp.where(mask[:, :, None, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(ck.dtype)
-    out = jnp.einsum("bkgs,bskd->bkgd", probs, cv)
-    return out.reshape(b, 1, cfg.n_heads, cfg.head_dim)
+    out = jnp.einsum("btkgs,bskd->btkgd", probs, cv)
+    return out.reshape(b, t, cfg.n_heads, cfg.head_dim)
 
 
-def _paged_decode_block(bp, x, positions, lengths, live, pools, table,  # traced
-                        layer, num_pages: int, page_size: int,
-                        cfg: DecoderConfig, attn_impl: str = "gather",
-                        lora=None, expert_stack=None):
-    """One transformer block for a [B,1] decode step against the page pool.
+def _pool_block(bp, x, positions, start, valid, pools, table, layer,  # traced
+                num_pages: dict, page_size: int, cfg: DecoderConfig,
+                attn_impl: str = "gather", lora=None, expert_stack=None):
+    """One transformer block for ``B`` rows of ``T`` tokens against the page
+    pool: ``x`` [B,T,D] at ``positions`` [B,T] = ``start[b] .. start[b]+T-1``
+    of the sequences whose pages are ``table`` [B,mpp]. THE block of every
+    program that meets the pool where it lies: the decode step (``T`` 1),
+    the speculative verify (``T`` k+1), the chunk prefill in place (``T``
+    the chunk). First norm, the operator of the block's kind
+    (``decoder.block_kind``), residual, second norm, ``_feed_forward``,
+    residual: ``decoder._block_forward``'s skeleton, the only other copy.
 
     ``pools`` holds every plane of the WHOLE pool viewed flat —
     ``k``/``v`` ``[L*P,pg,KV,Dh]`` (``[L*P,pg,KV*Dh]`` where the heads are
     packed) and, iff the pool stores int8, the per-token-per-head scales
     ``ks``/``vs`` ``[L*P,pg,KV]`` f32; a latent pool's one plane ``ckv``
-    ``[L*P,pg,W]``; the conv layers' state ``conv`` ``[Lc*P,taps-1,D]`` —
-    and ``layer`` (a traced scalar: the block's index among the layers of
-    its kind) picks this block's ``num_pages`` (P) pages out of its kind's
-    planes: page ``p`` of layer ``l`` is flat page ``l*P + p``. The block writes its token's rows
-    into the planes it was handed and returns them, so the caller can carry
-    them through its loops and the write lands in place; nothing here
-    slices a layer's slab out or puts one back.
+    ``[L*P,pg,W]``; the conv layers' state ``conv`` ``[Lc*P,taps-1,D]``; a
+    window layer's K and V ``[Lw*H,pg,KV,Dh]``; a linear layer's state a
+    sequence ``[Ll*H,...]`` — and ``layer`` (a traced scalar: the block's
+    index among the layers of its kind) picks this block's ``num_pages``
+    (P) pages out of its kind's planes: page ``p`` of layer ``l`` is flat
+    page ``l*P + p``. The block writes its tokens' rows into the planes it
+    was handed and returns them, so the caller can carry them through its
+    loops and the write lands in place; nothing here slices a layer's slab
+    out or puts one back.
 
-    ``attn_impl``: "gather" materializes the slot's pages into the
-    contiguous layout and runs the XLA decode attention (2× KV read);
-    "pallas" reads pages directly via the paged-attention kernel
-    (ops/paged_attention.py — one DMA per page). int8 pools quantize on
-    write; reads either gather+dequantize into the attention einsum's
-    operand ("gather") or ride the kernel, which dequantizes in VMEM
-    ("pallas") — the pool (the resident thing) holds 2× the tokens per
-    byte either way, and the kernel path also halves the per-step KV HBM
-    read. A latent pool is attended in the ABSORBED form (the key expansion
-    folded into the query, the value expansion applied to the attended
-    latent), so the step never holds per-head K or V of the context."""
+    ``valid`` [B]: how many of a row's ``T`` tokens are real, the first
+    ones (a bool counts as 0 or 1: the decode step's ``live``). A token
+    past them, past the table or on an unmapped page writes nothing: its
+    write aims past the END of the flat pool and DROPS (one past this
+    layer's pages, ``base + P``, is the next layer's page 0). A dead row
+    reads nothing that is kept and writes nothing.
+
+    What follows ``T``, read from the shapes and from nothing else. Where
+    ``T`` is statically 1 a row IS its token and its addressing has no token
+    axis ([B] indices, as the decode programs always lowered). The add and
+    the second norm are two operations at one token a row and
+    ``L.add_rmsnorm`` (one fused pass where the kernels are on) at several;
+    the expert layer is told which tokens are real and takes its capacity a
+    row at several tokens a row, and neither at one (a row is a token). What
+    attends is the operator's choice (``_kv_attention``).
+
+    ``attn_impl``: "gather" materializes the rows' pages into the
+    contiguous layout and runs the XLA attention (2x KV read); "pallas"
+    reads pages directly via the paged kernels (ops/paged_attention.py).
+    int8 pools quantize on write and dequantize on read (in the einsum's
+    operand, or in the decode kernel's VMEM): the pool, the resident thing,
+    holds 2x the tokens per byte either way. A latent pool is attended in
+    the ABSORBED form, so no program holds per-head K or V of the
+    context."""
     kind = block_kind(bp)
+    t, pg = x.shape[1], page_size
     own = next(pools[n] for n in pools
                if n != MOE_ROWS and plane_kind(n) == kind)
-    total, pg = own.shape[0], page_size
-    base = layer * num_pages[kind]
+    total, pages = own.shape[0], num_pages[kind]
+    base = layer * pages
     h = L.rmsnorm(x, bp["ln1"], cfg)
-    # Write position -> (flat page, offset). Dead rows and unmapped pages
-    # aim past the END of the flat pool and DROP: one past this layer's
-    # pages (base + P) is the next layer's page 0.
-    bidx = jnp.arange(x.shape[0])
-    page_slot = lengths // pg
-    if kind == "window":
-        # its ring's page; an id the window planes do not hold (a caller
-        # that put a sequence's first pages elsewhere) drops like no page
-        page_id = ring_table(table, page_slot, 1, cfg)[:, 0]
-        page_id = jnp.where(page_id < num_pages[kind], page_id, -1)
-    else:
-        page_id = table[bidx, jnp.clip(page_slot, 0, table.shape[1] - 1)]
-    pidx = jnp.where(live & (page_id >= 0), base + page_id, total)
-    if kind == "conv":
-        proj, pools = _conv_decode(bp["conv"], h, lengths, pools, pidx,
-                                   base, table, pg, cfg)
-    elif kind == "linear":
-        proj, pools = _kda_decode(
-            bp["linear"], h, lengths, pools,
-            _sequence_entry(table, live, base, num_pages[kind], total),
+    if kind == "linear":
+        proj, pools = _kda(
+            bp["linear"], h, start, valid, pools,
+            _sequence_entry(table, valid.astype(bool), base, pages, total),
             cfg, attn_impl)
-    elif kind == "window":
-        # The pages its window touches and no other (the page of position
-        # ``t - window + 1`` up to the page of ``t``: two at a window of a
-        # page), found through the ring. Positions are counted from the
-        # first of those pages, which is all the kernel sees of the context:
-        # its time does not grow with it.
-        lower = jnp.maximum(lengths - cfg.attn_window + 1, 0)
-        first = lower // pg
-        touched = ring_table(table, first,
-                             -(-(cfg.attn_window - 1) // pg) + 1, cfg)
-        proj, pools = _kv_decode_attention(
-            bp["window"], h, positions, lengths - first * pg, pools, pidx,
-            lengths % pg, jnp.where(touched >= 0, touched + base, -1), cfg,
-            attn_impl, lora, tuple(WINDOW_PLANES), lower - first * pg)
     else:
-        off = lengths % pg
-        # This layer's page table into the flat pool; -1 stays unmapped.
-        ltable = jnp.where(table >= 0, table + base, -1)
-        attend = _latent_decode_attention if cfg.is_latent \
-            else _kv_decode_attention
-        proj, pools = attend(bp["attn"], h, positions, lengths, pools, pidx,
-                             off, ltable, cfg, attn_impl, lora)
-    x = x + proj
-    h = L.rmsnorm(x, bp["ln2"], cfg)
-    out, pools = _feed_forward(bp, h, cfg, expert_stack, pools=pools)
+        if t == 1:
+            pos, real = start, valid.astype(bool)
+        else:
+            pos = positions
+            real = (jnp.arange(t, dtype=jnp.int32)[None, :]
+                    < valid[:, None]) & (pos < table.shape[1] * pg)
+        page_id = _token_pages(table, pos, pg, pages,
+                               cfg if kind == "window" else None)
+        pidx = jnp.where(real & (page_id >= 0), base + page_id, total)
+        if kind == "conv":
+            proj, pools = _conv(bp["conv"], h, start, pools, pidx, base,
+                                table, pg, cfg)
+        elif kind == "window":
+            touched, seen = _window_pages(table, start, t, pages, pg, cfg)
+            proj, pools = _kv_attention(
+                bp["window"], h, positions, seen, pools, pidx, pos % pg,
+                jnp.where(touched >= 0, touched + base, -1), cfg, attn_impl,
+                lora, tuple(WINDOW_PLANES), cfg.attn_window)
+        else:
+            # This layer's page table into the flat pool; -1 stays unmapped.
+            attend = _latent_attention if cfg.is_latent else _kv_attention
+            proj, pools = attend(
+                bp["attn"], h, positions, start, pools, pidx, pos % pg,
+                jnp.where(table >= 0, table + base, -1), cfg, attn_impl,
+                lora)
+    if t == 1:
+        x = x + proj
+        h = L.rmsnorm(x, bp["ln2"], cfg)
+    else:
+        x, h = L.add_rmsnorm(x, proj, bp["ln2"], cfg)
+    out, pools = _feed_forward(bp, h, cfg, expert_stack,
+                               None if t == 1 else valid,
+                               capacity_per_row=t > 1, pools=pools)
     return x + out, pools
 
 
-def _conv_decode(c, h, lengths, pools, pidx, base, table, pg: int,  # traced
-                 cfg: DecoderConfig):
-    """A conv layer's decode step: the state as it stood after position
+def _token_pages(table, pos, pg: int, pages: int, ring_cfg=None):  # traced
+    """The page each token's rows are written to, off its row's table:
+    ``pos`` [B] or [B,T] positions -> page ids alike, -1 unmapped.
+    ``ring_cfg``: a window layer's planes, where a position's page is its
+    ring's (``_ring_slot``); an id those planes do not hold (a caller that
+    put a sequence's first pages elsewhere) is unmapped like no page."""
+    mpp = table.shape[1]
+    rows = jnp.arange(table.shape[0]).reshape(-1, *[1] * (pos.ndim - 1))
+    slot = pos // pg
+    if ring_cfg is None:
+        return table[rows, jnp.clip(slot, 0, mpp - 1)]
+    page_id = table[rows, _ring_slot(slot, ring_cfg, mpp)]
+    return jnp.where(page_id < pages, page_id, -1)
+
+
+def _window_pages(table, start, t: int, pages: int, pg: int,  # traced
+                  cfg: DecoderConfig):
+    """What ``T`` queries a row from ``start`` [B] read of a window layer:
+    (the pages their windows touch and no other [B,n], -1 where unmapped or
+    not held by the window planes; ``start`` [B] counted from the first of
+    them). From the page of position ``start - window + 1`` to the page of
+    ``start + T - 1`` (two at one token and a window of a page; a ring at a
+    chunk), found through the ring. Those pages are all a kernel sees of
+    the context: its time does not grow with it."""
+    first = jnp.maximum(start - cfg.attn_window + 1, 0) // pg
+    n = min(_ring_len(cfg, table.shape[1]),
+            -(-(t + cfg.attn_window - 2) // pg) + 1)
+    touched = ring_table(table, first, n, cfg)
+    return jnp.where(touched < pages, touched, -1), start - first * pg
+
+
+def _conv(c, h, start, pools, pidx, base, table, pg: int,  # traced
+          cfg: DecoderConfig):
+    """A conv layer, one token a row: the state as it stood after position
     ``t - 1`` is in the page of ``t - 1`` (nothing before a sequence's first
     token), the state after ``t`` goes to the page of ``t`` (``pidx``: past
-    the pool for a dead row). Returns (the operator's output [B,1,D], the
-    planes as written)."""
+    the pool for a dead row). Several tokens a row never come here: a conv
+    stack's chunks go the gathered way (``_chunk_in_place``), which leaves
+    the tail every page ends in. Returns (the operator's output [B,1,D],
+    the planes as written)."""
+    if h.shape[1] != 1:
+        raise NotImplementedError(
+            "a conv layer over several tokens a row of the pool in place")
     state = pools["conv"]
-    before = jnp.maximum(lengths - 1, 0) // pg
+    before = jnp.maximum(start - 1, 0) // pg
     prev = table[jnp.arange(h.shape[0]),
                  jnp.clip(before, 0, table.shape[1] - 1)]
     tail = state[jnp.clip(base + prev, 0, state.shape[0] - 1)]
-    tail = jnp.where(((lengths > 0) & (prev >= 0))[:, None, None], tail, 0)
+    tail = jnp.where(((start > 0) & (prev >= 0))[:, None, None], tail, 0)
     proj, zs = L.conv_block(c, h, cfg, tail)
     return proj, {**pools, "conv": state.at[pidx].set(zs[:, 1:],
                                                       mode="drop")}
@@ -800,45 +866,39 @@ def _state_at(plane, entry, fresh):  # traced
     return jnp.where(blank.reshape(-1, *[1] * (held.ndim - 1)), 0, held)
 
 
-def _kda_decode(lin, h, lengths, pools, entry, cfg: DecoderConfig,  # traced
-                attn_impl: str):
-    """A linear layer's decode step: the state at ``entry`` [B] of the
-    layer's planes (zeros at length 0, a sequence's first token) through one
-    token and back to where it lay ("pallas": ``ops/kda.py::kda_step``, in
-    place; "gather": the same in XLA). An ``entry`` past the planes is a
-    dead row: nothing read, nothing written. Returns (the operator's output
-    [B,1,D], the planes as written)."""
+def _kda(lin, h, start, valid, pools, entry, cfg: DecoderConfig,  # traced
+         attn_impl: str):
+    """A linear layer over ``T`` tokens a row: from the state at ``entry``
+    [B] of the layer's planes (zeros for a row that starts its sequence) to
+    the state after the row's last valid token, written back to the entry.
+    One token goes through the recurrence with the state read and written
+    where it lies ("pallas": ``ops/kda.py::kda_step``; "gather": the same in
+    XLA), several through the chunked form (``kda_chunk``) from the state
+    gathered. An ``entry`` past the planes is a dead row: nothing read,
+    nothing written. Returns (the operator's output [B,T,D], the planes as
+    written)."""
     from kubeflow_tpu.ops import kda
 
+    t = h.shape[1]
     mats, tails = (pools[n] for n in LINEAR_PLANES)
-    fresh = lengths == 0
-    q, k, v, g, beta, tail = L.kda_inputs(lin, h, cfg,
-                                          _state_at(tails, entry, fresh))
-    o, mats = kda.kda_step(
-        q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], mats, entry, fresh,
-        entry < mats.shape[0],
-        impl="pallas" if attn_impl == "pallas" else "xla")
+    fresh = start == 0
+    impl = "pallas" if attn_impl == "pallas" else "xla"
+    q, k, v, g, beta, tail = L.kda_inputs(
+        lin, h, cfg, _state_at(tails, entry, fresh),
+        None if t == 1 else valid)
+    if t == 1:
+        o, mats = kda.kda_step(
+            q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], mats, entry,
+            fresh, entry < mats.shape[0], impl=impl)
+        o = o[:, None]
+    else:
+        o, mat = kda.kda_chunk(q, k, v, g, beta,
+                               _state_at(mats, entry, fresh), impl=impl)
+        mats = mats.at[entry].set(mat, mode="drop")
     pools = {**pools, LINEAR_PLANES[0]: mats,
              LINEAR_PLANES[1]: tails.at[entry].set(
                  tail.astype(tails.dtype), mode="drop")}
-    return L.kda_output(lin, h, o[:, None], cfg), pools
-
-
-def _kda_chunk(lin, h, start, valid_len, pools, entry,  # traced
-               cfg: DecoderConfig, attn_impl: str):
-    """A linear layer over a chunk a row: from the state at ``entry`` [B]
-    (zeros for a chunk that starts its sequence) to the state after the
-    row's last valid position, written back to the entry. Returns (the
-    operator's output [B,C,D], the planes as written)."""
-    mats, tails = (pools[n] for n in LINEAR_PLANES)
-    proj, (mat, tail) = L.kda_block(
-        lin, h, cfg, tuple(_state_at(pl, entry, start == 0)
-                           for pl in (mats, tails)), valid_len,
-        impl="pallas" if attn_impl == "pallas" else "xla")
-    return proj, {**pools,
-                  LINEAR_PLANES[0]: mats.at[entry].set(mat, mode="drop"),
-                  LINEAR_PLANES[1]: tails.at[entry].set(
-                      tail.astype(tails.dtype), mode="drop")}
+    return L.kda_output(lin, h, o, cfg), pools
 
 
 def _qkv_rope(a, h, positions, cfg: DecoderConfig, lora=None,  # traced
@@ -860,129 +920,192 @@ def _qkv_rope(a, h, positions, cfg: DecoderConfig, lora=None,  # traced
     return q, k, v
 
 
-def _kv_decode_attention(a, h, positions, lengths, pools, pidx, off,  # traced
-                         ltable, cfg: DecoderConfig, attn_impl: str, lora,
-                         planes: tuple = ("k", "v"), lower=None):
-    """Per-head K/V: project, write this token's rows at (pidx, off) of the
-    K and V ``planes``, attend to the pages of ``ltable`` up to key
-    ``lengths``. A window layer hands its own planes, the pages its window
-    touches with ``lengths`` counted from the first of them, and ``lower``
-    [B], the first key a query still sees. Returns (the block's attention
-    output [B,1,D], the planes as written)."""
+def _kv_attention(a, h, positions, start, pools, pidx, off, ltable,  # traced
+                  cfg: DecoderConfig, attn_impl: str, lora,
+                  planes: tuple = ("k", "v"), window: int = 0):
+    """Per-head K/V over ``T`` tokens a row: project, write the tokens' rows
+    at (pidx, off) of the K and V ``planes`` ([B] where ``T`` is 1, else
+    [B,T]), attend causally to the pages of ``ltable`` from key 0 of its
+    first page, ``start`` [B] being a row's first query, project out. A
+    window layer hands its own planes, ``window``, and the pages its
+    queries' windows touch with ``start`` counted from the first of them.
+
+    What attends follows ``attn_impl`` and ``T``: "pallas" at one token the
+    decode kernels (all rows one call), at several ``paged_chunk_attention``
+    (one call a row; it takes neither int8 planes nor packed rows nor a
+    call with LoRA, which ``_chunk_in_place`` keeps from it); "gather" ONE
+    masked attention over the gathered pages whatever ``T``. Returns (the
+    block's attention output [B,T,D], the planes as written)."""
     dt = cfg.activation_dtype
     nk, nv = planes
+    t = h.shape[1]
     kv_quant = "ks" in pools
-    q, k, v = _qkv_rope(a, h, positions, cfg, lora,
-                        window=0 if lower is None else cfg.attn_window)
-    rows = {nk: k[:, 0], nv: v[:, 0]}
+    q, k, v = _qkv_rope(a, h, positions, cfg, lora, window)
+    if t == 1:
+        k, v = k[:, 0], v[:, 0]
+    rows = {nk: k, nv: v}
     packed = pools[nk].ndim == 3        # [L*P, pg, KV*Dh]: heads in one row
     if packed:
-        rows = {n: r.reshape(r.shape[0], -1) for n, r in rows.items()}
+        rows = {n: r.reshape(*r.shape[:-2], -1) for n, r in rows.items()}
     if kv_quant:
         from kubeflow_tpu.ops.quantization import dequantize_kv, quantize_kv
 
-        rows[nk], rows["ks"] = quantize_kv(k[:, 0])
-        rows[nv], rows["vs"] = quantize_kv(v[:, 0])
+        rows[nk], rows["ks"] = quantize_kv(k)
+        rows[nv], rows["vs"] = quantize_kv(v)
     pools = {**pools, **{
         name: pools[name].at[pidx, off].set(row, mode="drop")
         for name, row in rows.items()}}
-    if attn_impl == "pallas" and packed:
-        from kubeflow_tpu.ops.paged_attention import (
-            paged_packed_decode_attention,
-        )
+    by_row = attn_impl == "pallas" and t > 1     # heads-major [B,H,T,Dh]
+    if by_row:
+        from kubeflow_tpu.ops.paged_attention import paged_chunk_attention
 
-        attn = paged_packed_decode_attention(
-            q, pools[nk], pools[nv], ltable, lengths, cfg.n_kv_heads)
-    elif attn_impl == "pallas":
-        from kubeflow_tpu.ops.paged_attention import paged_decode_attention
-
-        attn = paged_decode_attention(q, pools[nk], pools[nv], ltable,
-                                      lengths, pool_ks=pools.get("ks"),
-                                      pool_vs=pools.get("vs"), lower=lower)
+        attn = jnp.stack([
+            paged_chunk_attention(jnp.swapaxes(q[r], 0, 1), pools[nk],
+                                  pools[nv], ltable[r], start[r],
+                                  window=window)
+            for r in range(h.shape[0])])
     else:
-        ck = paged_gather(pools[nk], ltable)
-        cv = paged_gather(pools[nv], ltable)
-        if packed:
-            ck = ck.reshape(*ck.shape[:2], cfg.n_kv_heads, cfg.head_dim)
-            cv = cv.reshape(*cv.shape[:2], cfg.n_kv_heads, cfg.head_dim)
-        if kv_quant:
-            ck = dequantize_kv(ck, paged_gather(pools["ks"], ltable), dt)
-            cv = dequantize_kv(cv, paged_gather(pools["vs"], ltable), dt)
-        attn = _decode_attention(q, ck, cv, lengths, cfg, lower=lower)
-    attn = L.gate_attention(a, h, attn, cfg)
-    proj = jnp.einsum("bshk,hkd->bsd", attn, a["wo"].astype(dt))
+        lower = None
+        if window:      # the first key each query still sees
+            qpos = start if t == 1 else start[:, None] + jnp.arange(
+                t, dtype=jnp.int32)[None, :]
+            lower = jnp.maximum(qpos - window + 1, 0)
+        if attn_impl == "pallas" and packed:
+            from kubeflow_tpu.ops.paged_attention import (
+                paged_packed_decode_attention,
+            )
+
+            attn = paged_packed_decode_attention(
+                q, pools[nk], pools[nv], ltable, start, cfg.n_kv_heads)
+        elif attn_impl == "pallas":
+            from kubeflow_tpu.ops.paged_attention import (
+                paged_decode_attention,
+            )
+
+            attn = paged_decode_attention(
+                q, pools[nk], pools[nv], ltable, start,
+                pool_ks=pools.get("ks"), pool_vs=pools.get("vs"),
+                lower=lower)
+        else:
+            ck = paged_gather(pools[nk], ltable)
+            cv = paged_gather(pools[nv], ltable)
+            if packed:
+                ck = ck.reshape(*ck.shape[:2], cfg.n_kv_heads, cfg.head_dim)
+                cv = cv.reshape(*cv.shape[:2], cfg.n_kv_heads, cfg.head_dim)
+            if kv_quant:
+                ck = dequantize_kv(ck, paged_gather(pools["ks"], ltable), dt)
+                cv = dequantize_kv(cv, paged_gather(pools["vs"], ltable), dt)
+            attn = _decode_attention(q, ck, cv, start, cfg, lower=lower)
+    attn = L.gate_attention(a, h, attn, cfg, heads_axis=1 if by_row else 2)
+    proj = jnp.einsum("bhsk,hkd->bsd" if by_row else "bshk,hkd->bsd", attn,
+                      a["wo"].astype(dt))
     if lora is not None and "wo" in lora["targets"]:
         proj = L.apply_lora_layer(
-            lora, "wo", attn.reshape(attn.shape[0], 1, -1), proj)
+            lora, "wo", attn.reshape(*attn.shape[:2], -1), proj)
     return proj, pools
 
 
-def _latent_decode_attention(a, h, positions, lengths, pools, pidx, off,  # traced
-                             ltable, cfg: DecoderConfig, attn_impl: str,
-                             lora):
-    """Latent attention's decode step, absorbed: write this token's cache
-    row, fold the key expansion into the query, attend over the slot's
-    pages ("pallas": the kernel reads each page once for all heads;
-    "gather": the same sums in XLA over the gathered rows), expand the
-    attended row into values. Same return as the per-head form."""
+def _latent_attention(a, h, positions, start, pools, pidx, off,  # traced
+                      ltable, cfg: DecoderConfig, attn_impl: str, lora):
+    """Latent attention over ``T`` tokens a row, absorbed: write the tokens'
+    cache rows, fold the key expansion into the query, attend causally over
+    the row's pages ("pallas": at one token ``paged_latent_decode_attention``
+    reads each page once for all rows' heads, at several
+    ``paged_latent_chunk_attention``, one call a row; "gather": the same
+    sums in XLA over the gathered rows, whatever ``T``), expand the attended
+    row into values. Same return as the per-head form."""
     if lora is not None:
         raise NotImplementedError("LoRA over latent attention projections")
+    t = h.shape[1]
     q_nope, q_rope, row = L.latent_qkv(a, h, positions, cfg)
-    pools = {**pools,
-             "ckv": pools["ckv"].at[pidx, off].set(row[:, 0], mode="drop")}
-    if attn_impl == "pallas":
+    flat = pools["ckv"].at[pidx, off].set(row[:, 0] if t == 1 else row,
+                                          mode="drop")
+    if attn_impl == "pallas" and t == 1:
         from kubeflow_tpu.ops.paged_attention import (
             paged_latent_decode_attention,
         )
 
         q = L.latent_query(a, q_nope[:, 0], q_rope[:, 0], cfg)   # [B,H,W]
         o_row = paged_latent_decode_attention(
-            q, pools["ckv"], ltable, lengths, sm_scale=L.latent_scale(cfg))
+            q, flat, ltable, start, sm_scale=L.latent_scale(cfg))
         attn = L.latent_output(a, o_row, cfg)[:, None]
+    elif attn_impl == "pallas":
+        from kubeflow_tpu.ops.paged_attention import (
+            paged_latent_chunk_attention,
+        )
+
+        q = L.latent_query(a, q_nope, q_rope, cfg)             # [B,T,H,W]
+        o_row = jnp.stack([
+            paged_latent_chunk_attention(
+                jnp.swapaxes(q[r], 0, 1), flat, ltable[r], start[r],
+                sm_scale=L.latent_scale(cfg)) for r in range(h.shape[0])])
+        attn = L.latent_output(a, jnp.swapaxes(o_row, 1, 2), cfg)
     else:
-        rows = paged_gather(pools["ckv"], ltable)          # [B, S, W]
-        mask = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :] \
-            <= lengths[:, None]                            # [B, S]
+        rows = paged_gather(flat, ltable)                      # [B, S, W]
+        causal = jnp.arange(rows.shape[1], dtype=jnp.int32)[
+            None, None, :] <= positions[:, :, None]            # [B, T, S]
         attn = L.latent_absorbed_attention(
-            a, q_nope, q_rope, rows, mask[:, None, None, :], cfg)
+            a, q_nope, q_rope, rows, causal[:, None], cfg)
     return jnp.einsum("bshk,hkd->bsd", attn,
-                      a["wo"].astype(cfg.activation_dtype)), pools
+                      a["wo"].astype(cfg.activation_dtype)), {
+                          **pools, "ckv": flat}
+
+
+def _pool_forward(params: Params, cache: dict, tokens: jax.Array,  # traced
+                  table: jax.Array, start: jax.Array, valid: jax.Array,
+                  cfg: DecoderConfig, attn_impl: str, lora=None):
+    """``tokens`` [B,T] at positions ``start[b] ..`` of the sequences whose
+    pages are ``table`` [B,mpp], through every layer against the pool where
+    it lies (``_pool_block``): the ONE builder under the decode step, the
+    chunk prefill in place and the speculative verify. Returns (the last
+    layer's output [B,T,D], the planes as written, still flat:
+    ``_pool_planes`` hands them back as the cache holds them, once the
+    caller's head has read ``x``).
+
+    The pool is a CARRY of the layer scan, never a scanned input/output: a
+    scan's stacked outputs are a new buffer, so scanning over ``[L,P,...]``
+    copies every layer's slab out and back to write B rows of it. Each
+    plane is viewed flat ``[L*P,...]`` (a bitcast), carried beside ``x`` and
+    written in place by the block at ``layer*P + page``; the scanned inputs
+    are the layer's weights, its LoRA slice and its index."""
+    t = tokens.shape[1]
+    x = _embed(params, tokens, cfg)
+    positions = start[:, None]
+    if t > 1:
+        positions = positions + jnp.arange(t, dtype=jnp.int32)[None, :]
+    pg = _pool_geometry(cache)[1]
+    num_pages = _pages_by_kind(cache)
+    flat = _flat_pools(cache)
+
+    def block(bp, carry, layer, gcfg, lora_view, expert_stack):
+        return _pool_block(
+            bp, carry[0], positions, start, valid, carry[1], table, layer,
+            num_pages, pg, gcfg, attn_impl=attn_impl, lora=lora_view,
+            expert_stack=expert_stack)
+
+    return _scan_layer_groups(params, cfg, (x, flat), block, lora)
 
 
 def _paged_decode_step(params: Params, cache: dict, tokens: jax.Array,  # traced
                        lengths: jax.Array, live: jax.Array,
                        cfg: DecoderConfig, attn_impl: str = "gather",
                        lora=None):
-    """One [B,1] decode step over the page pool: tokens [B] (last sampled),
-    lengths [B] (their positions), live [B] (rows whose KV write is real).
-    Returns (logits [B,V] fp32, new cache).
-
-    The pool is a CARRY of the layer scan, never a scanned input/output: a
-    scan's stacked outputs are a new buffer, so scanning over ``[L,P,...]``
-    copies every layer's slab out and back to write B rows of it. Each
-    plane is viewed flat ``[L*P,...]`` (merging the two leading dimensions
-    is a bitcast), carried beside ``x`` and written in place by the block
-    at ``layer*P + page``; the scanned inputs are the layer's weights, its
-    LoRA slice and its index. The pytree handed back is ``[L,P,...]``
-    again, so every other program sees the cache it always saw."""
-    x = _embed(params, tokens[:, None], cfg)
-    positions = lengths[:, None]
+    """One [B,1] decode step over the page pool (``_pool_forward`` at one
+    token a row): tokens [B] (last sampled), lengths [B] (their positions),
+    live [B] (rows whose KV write is real). Returns (logits [B,V] fp32, new
+    cache)."""
     table = cache["table"]
-    pg = _pool_geometry(cache)[1]
-    num_pages = _pages_by_kind(cache)
-    flat = _flat_pools(cache)
-
-    def block(bp, carry, layer, gcfg, lora_view, expert_stack):
-        return _paged_decode_block(
-            bp, carry[0], positions, lengths, live, carry[1], table, layer,
-            num_pages, pg, gcfg, attn_impl=attn_impl, lora=lora_view,
-            expert_stack=expert_stack)
-
-    x, flat = _scan_layer_groups(params, cfg, (x, flat), block, lora)
+    x, flat = _pool_forward(params, cache, tokens[:, None], table, lengths,
+                            live, cfg, attn_impl, lora)
     logits = _head_logits(params, x, cfg)[:, 0]
-    out = {n: p.reshape(cache[n].shape) for n, p in flat.items()}
-    out["table"] = table
-    return logits, out
+    return logits, {**_pool_planes(flat, cache), "table": table}
+
+
+def _pool_planes(flat: dict, cache: dict) -> dict:  # traced
+    """What ``_flat_pools`` made of ``cache``, as the cache holds it:
+    ``[L,P,...]`` again, so every other program sees the cache it always
+    saw."""
+    return {n: p.reshape(cache[n].shape) for n, p in flat.items()}
 
 
 def _flat_pools(cache: dict) -> dict:  # traced
@@ -1335,7 +1458,7 @@ def _chunk_write_index(table_rows: jax.Array, start: jax.Array,  # traced
     pos = start[:, None] + i
     pslot = pos // pg
     if ring_cfg is not None:
-        pslot_at = pslot % _ring_len(ring_cfg, table_rows.shape[1])
+        pslot_at = _ring_slot(pslot, ring_cfg, table_rows.shape[1])
     else:
         pslot_at = jnp.clip(pslot, 0, table_rows.shape[1] - 1)
     page_id = jnp.take_along_axis(table_rows, pslot_at, axis=1)
@@ -1377,62 +1500,6 @@ def chunk_reads_context(cache: dict, cfg: DecoderConfig, lora,
     return cfg.is_latent or not _chunk_in_place(cache, cfg, lora, attn_impl)
 
 
-def _kv_chunk_attention(a, h, pos, start, pools, pidx, off, ltable,  # traced
-                        cfg: DecoderConfig, attn_impl: str,
-                        planes: tuple = ("k", "v"), window: int = 0):
-    """Per-head K/V, a chunk a row: project, write every row's ``C`` K and V
-    rows at (pidx, off) of the K and V ``planes``, then each prompt attends
-    causally over its own pages through ``paged_chunk_attention``, one call
-    a prompt. A window layer hands its own planes and ``window``, and as
-    ``ltable`` the pages its chunk and the window before it touch, in
-    order, with ``start`` counted from the first of them. Returns (the
-    block's attention output [B,C,D], the planes as written)."""
-    from kubeflow_tpu.ops.paged_attention import paged_chunk_attention
-
-    nk, nv = planes
-    q, k, v = _qkv_rope(a, h, pos, cfg, window=window)
-    pools = {**pools, nk: pools[nk].at[pidx, off].set(k, mode="drop"),
-             nv: pools[nv].at[pidx, off].set(v, mode="drop")}
-    attn = jnp.stack([
-        paged_chunk_attention(jnp.swapaxes(q[r], 0, 1), pools[nk],
-                              pools[nv], ltable[r], start[r], window=window)
-        for r in range(h.shape[0])])                           # [B,H,C,Dh]
-    attn = L.gate_attention(a, h, attn, cfg, heads_axis=1)
-    return jnp.einsum("bhsk,hkd->bsd", attn,
-                      a["wo"].astype(cfg.activation_dtype)), pools
-
-
-def _latent_chunk_attention(a, h, pos, start, pools, pidx, off,  # traced
-                            ltable, cfg: DecoderConfig, attn_impl: str):
-    """Latent attention, a chunk a row, absorbed: write every row's ``C``
-    cache rows, then each prompt attends causally over its own pages
-    ("pallas": ``paged_latent_chunk_attention``, one call a prompt;
-    "gather": the same sums in XLA over the gathered rows). Same return as
-    the per-head form."""
-    q_nope, q_rope, row = L.latent_qkv(a, h, pos, cfg)
-    flat = pools["ckv"].at[pidx, off].set(row, mode="drop")
-    if attn_impl == "pallas":
-        from kubeflow_tpu.ops.paged_attention import (
-            paged_latent_chunk_attention,
-        )
-
-        q = L.latent_query(a, q_nope, q_rope, cfg)             # [B,C,H,W]
-        o_row = jnp.stack([
-            paged_latent_chunk_attention(
-                jnp.swapaxes(q[r], 0, 1), flat, ltable[r], start[r],
-                sm_scale=L.latent_scale(cfg)) for r in range(h.shape[0])])
-        attn = L.latent_output(a, jnp.swapaxes(o_row, 1, 2), cfg)
-    else:
-        rows = paged_gather(flat, ltable)                      # [B, T, W]
-        causal = jnp.arange(rows.shape[1], dtype=jnp.int32)[
-            None, None, :] <= pos[:, :, None]                  # [B, C, T]
-        attn = L.latent_absorbed_attention(
-            a, q_nope, q_rope, rows, causal[:, None], cfg)
-    return jnp.einsum("bshk,hkd->bsd", attn,
-                      a["wo"].astype(cfg.activation_dtype)), {
-                          **pools, "ckv": flat}
-
-
 def _last_logits(params: Params, x: jax.Array, valid_len: jax.Array,  # traced
                  cfg: DecoderConfig, wanted=None,
                  normed: bool = False) -> jax.Array:
@@ -1457,76 +1524,15 @@ def _paged_chunk_in_place(params: Params, cache: dict,  # traced
                           cfg: DecoderConfig, attn_impl: str,
                           logits_at: str = "all", wanted=None):
     """``paged_chunk_prefill`` built like the decode step and not like
-    ``decoder_forward``'s cache path: every plane of the pool is carried
-    whole and flat ``[L*P, pg, ...]`` through the layer scans, a layer
-    writes every row's ``C`` cache rows in place at ``(layer*P + page,
-    offset)`` and then each prompt attends, causally, over its own pages
-    where they lie (the chunk's own among them; the kernels skip the pages
-    behind the chunk). ONE builder whose layers differ in how they project
-    and attend (``_latent_chunk_attention``, ``_kv_chunk_attention``).
-    Nothing gathers all layers' context up front, pads it, or puts a
-    layer's slab back. Same contract: only a row's first ``valid_len``
-    positions write; ``table_rows`` holds the pages looked at."""
-    num_pages, pg = _pool_geometry(cache)
-    pages_of = _pages_by_kind(cache)
-    c = tokens.shape[1]
-    page, off = _chunk_write_index(table_rows, start, valid_len, c, pg,
-                                   num_pages)
-    pos = start[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]   # [B,C]
-    flat = _flat_pools(cache)
-    if "window" in pages_of:
-        # A window layer's pages: its writes go to the ring's, and it
-        # attends over the pages from the window before the chunk's first
-        # query on, as many as a ring holds, counted from the first of them.
-        wpages = pages_of["window"]
-        wpage, _ = _chunk_write_index(table_rows, start, valid_len, c, pg,
-                                      wpages, cfg)
-        first = jnp.maximum(start - cfg.attn_window + 1, 0) // pg
-        touched = ring_table(table_rows, first,
-                             _ring_len(cfg, table_rows.shape[1]), cfg)
-        rel_start = start - first * pg
-        wtotal = flat[next(iter(WINDOW_PLANES))].shape[0]
-
-    def block(bp, carry, layer, gcfg, _, expert_stack):
-        x, pools = carry
-        if "linear" in bp:
-            h = L.rmsnorm(x, bp["ln1"], gcfg)
-            entries = pages_of["linear"]
-            proj, pools = _kda_chunk(
-                bp["linear"], h, start, valid_len, pools,
-                _sequence_entry(table_rows, valid_len > 0, layer * entries,
-                                entries, pools[LINEAR_PLANES[0]].shape[0]),
-                gcfg, attn_impl)
-        elif "window" in bp:
-            base = layer * wpages
-            h = L.rmsnorm(x, bp["ln1"], gcfg)
-            pidx = jnp.where(wpage < wpages, base + wpage, wtotal)
-            wtable = jnp.where((touched >= 0) & (touched < wpages),
-                               touched + base, -1)
-            proj, pools = _kv_chunk_attention(
-                bp["window"], h, pos, rel_start, pools, pidx, off, wtable,
-                gcfg, attn_impl, tuple(WINDOW_PLANES), gcfg.attn_window)
-        else:
-            base = layer * num_pages
-            h = L.rmsnorm(x, bp["ln1"], gcfg)
-            # One past this layer's pages is the next layer's page 0: a
-            # dropped write aims past the END of the flat pool.
-            pidx = jnp.where(page < num_pages, base + page, total)
-            ltable = jnp.where(table_rows >= 0, table_rows + base, -1)
-            proj, pools = attend(bp["attn"], h, pos, start, pools, pidx, off,
-                                 ltable, gcfg, attn_impl)
-        x, h = L.add_rmsnorm(x, proj, bp["ln2"], gcfg)
-        out, pools = _feed_forward(bp, h, gcfg, expert_stack, valid_len,
-                                   capacity_per_row=True, pools=pools)
-        return x + out, pools
-
-    total = next(flat[n] for n in flat if n != MOE_ROWS
-                 and plane_kind(n) == "attention").shape[0] \
-        if "attention" in pages_of else 0
-    attend = _latent_chunk_attention if cfg.is_latent \
-        else _kv_chunk_attention
-    x, flat = _scan_layer_groups(
-        params, cfg, (_embed(params, tokens, cfg), flat), block)
+    ``decoder_forward``'s cache path (``_pool_forward`` at a chunk a row):
+    a layer writes every row's ``C`` cache rows in place at ``(layer*P +
+    page, offset)`` and then each prompt attends, causally, over its own
+    pages where they lie (the chunk's own among them; the kernels skip the
+    pages behind the chunk). Same contract: only a row's first
+    ``valid_len`` positions write; ``table_rows`` holds the pages looked
+    at."""
+    x, flat = _pool_forward(params, cache, tokens, table_rows, start,
+                            valid_len, cfg, attn_impl)
     logits = _last_logits(params, x, valid_len, cfg, wanted) \
         if logits_at == "last" else _head_logits(params, x, cfg)
-    return logits, {n: p.reshape(cache[n].shape) for n, p in flat.items()}
+    return logits, _pool_planes(flat, cache)

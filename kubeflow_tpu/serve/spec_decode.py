@@ -30,6 +30,11 @@ Verification is exact for GREEDY requests only (argmax chains compose);
 the engine falls back to the normal decode path whenever a sampling
 request shares the batch.
 
+The verify dispatch is the page pool's one builder at ``T = k+1`` tokens a
+row (``paged._pool_forward`` over ``paged._pool_block``, whose ``T = 1`` is
+the decode step): the pool rides it flat and is written in place, and what
+attends is the gathered form whatever kernels the decode step runs.
+
 KV rollback: the verify dispatch writes K/V for all k+1 positions before
 acceptance is known. Rejected positions hold garbage (overwritten before
 read), and the engine truncates each slot's page table back to the
@@ -55,11 +60,11 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-from kubeflow_tpu.models import layers as L
 from kubeflow_tpu.models.config import DecoderConfig
 from kubeflow_tpu.models.decoder import Params
 from kubeflow_tpu.serve.paged import (
-    _paged_decode_step, _planes_of, paged_gather,
+    _head_logits, _paged_decode_step, _planes_of, _pool_forward,
+    _pool_planes,
 )
 
 
@@ -88,131 +93,33 @@ def ngram_propose(ctx: Sequence[int], k: int, ngram_max: int,
 
 # -- batched verify -------------------------------------------------------------
 
-def _spec_attention(q, ck, cv, lengths, cfg: DecoderConfig):  # traced
-    """T-query attention over the slots' gathered pages (the verify-length
-    generalization of paged._decode_attention). q [B,T,H,Dh]; ck/cv
-    [B,Smax,KV,Dh]; query t sits at position lengths[b]+t and attends
-    kpos <= that."""
-    b, t = q.shape[0], q.shape[1]
-    smax = ck.shape[1]
-    groups = cfg.n_heads // cfg.n_kv_heads
-    qg = q.reshape(b, t, cfg.n_kv_heads, groups, cfg.head_dim)
-    scores = jnp.einsum("btkgd,bskd->btkgs", qg, ck,
-                        preferred_element_type=jnp.float32)
-    scores *= cfg.head_dim ** -0.5
-    kpos = jnp.arange(smax, dtype=jnp.int32)
-    qpos = lengths[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
-    mask = kpos[None, None, :] <= qpos[:, :, None]            # [B,T,Smax]
-    scores = jnp.where(mask[:, :, None, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(ck.dtype)
-    out = jnp.einsum("btkgs,bskd->btkgd", probs, cv)
-    return out.reshape(b, t, cfg.n_heads, cfg.head_dim)
-
-
-def _paged_spec_block(bp, x, positions, lengths, live, pool_k, pool_v,  # traced
-                      table, cfg: DecoderConfig, pool_ks=None, pool_vs=None):
-    """Verify block against the page pool (paged._paged_decode_block with a
-    verify-length axis; always the gather attention impl — the Pallas
-    paged-attention kernel is single-query). Position -> (page, offset)
-    per token; unmapped pages, dead rows and positions past the table's
-    reach aim out of bounds and DROP."""
-    dt = cfg.activation_dtype
-    kv_quant = pool_ks is not None
-    pg = pool_k.shape[1]
-    mpp = table.shape[1]
-    h = L.rmsnorm(x, bp["ln1"], cfg)
-    q = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wq"].astype(dt))
-    k = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wk"].astype(dt))
-    v = jnp.einsum("bsd,dhk->bshk", h, bp["attn"]["wv"].astype(dt))
-    q, k = L.qk_rope(bp["attn"], q, k, positions, cfg)
-    bidx = jnp.arange(x.shape[0])[:, None]                    # [B,1]
-    page_slot = positions // pg                               # [B,T]
-    page_id = table[bidx, jnp.clip(page_slot, 0, mpp - 1)]
-    ok = live[:, None] & (page_id >= 0) & (positions < mpp * pg)
-    pidx = jnp.where(ok, page_id, pool_k.shape[0])
-    off = positions % pg
-    nks = nvs = None
-    if kv_quant:
-        from kubeflow_tpu.ops.quantization import dequantize_kv, quantize_kv
-
-        kq, ks = quantize_kv(k)
-        vq, vs = quantize_kv(v)
-        nk = pool_k.at[pidx, off].set(kq, mode="drop")
-        nv = pool_v.at[pidx, off].set(vq, mode="drop")
-        nks = pool_ks.at[pidx, off].set(ks, mode="drop")
-        nvs = pool_vs.at[pidx, off].set(vs, mode="drop")
-        ck = dequantize_kv(paged_gather(nk, table),
-                           paged_gather(nks, table), dt)
-        cv = dequantize_kv(paged_gather(nv, table),
-                           paged_gather(nvs, table), dt)
-    else:
-        nk = pool_k.at[pidx, off].set(k, mode="drop")
-        nv = pool_v.at[pidx, off].set(v, mode="drop")
-        ck = paged_gather(nk, table)
-        cv = paged_gather(nv, table)
-    attn = _spec_attention(q, ck, cv, lengths, cfg)
-    x = x + jnp.einsum("bshk,hkd->bsd", attn, bp["attn"]["wo"].astype(dt))
-    h = L.rmsnorm(x, bp["ln2"], cfg)
-    if cfg.is_moe:
-        mlp_out, _ = L.moe_block(bp["mlp"], h, cfg)
-    else:
-        mlp_out = L.mlp_block(bp["mlp"], h, cfg)
-    return x + mlp_out, nk, nv, nks, nvs
-
-
 def paged_verify_step(params: Params, cache: dict, tokens: jax.Array,  # traced
                       lengths: jax.Array, live: jax.Array,
                       cfg: DecoderConfig):
-    """ONE dispatch scoring T = k+1 positions per slot over the page pool
-    (cache carries "table"; the host pre-allocates pages covering all T
-    write positions, exactly like paged_decode_multi's contract). tokens
-    [B,T] = [last_token, draft_1..draft_k] (pad columns are scored too —
-    the host just ignores them); lengths [B] = the write position of
-    tokens[:,0], exactly as in paged._paged_decode_step.
+    """ONE dispatch scoring T = k+1 positions per slot over the page pool:
+    the pool's one builder (``paged._pool_forward``) at ``T`` tokens a row
+    and the argmax (cache carries "table"; the host pre-allocates pages
+    covering all T write positions, exactly like paged_decode_multi's
+    contract). tokens [B,T] = [last_token, draft_1..draft_k] (pad columns
+    are scored too — the host just ignores them); lengths [B] = the write
+    position of tokens[:,0], exactly as in paged._paged_decode_step. Every
+    column of a live row writes its K/V; a dead row, an unmapped page and a
+    position past the table's reach aim out of bounds and DROP. Attention
+    is the gathered form whatever the engine's decode step runs: verify in
+    place through the chunk kernel waits for a cell that times speculation
+    (ROADMAP Design 1).
 
     Returns ([B,T] int32 greedy next-token ids, new cache): row b column t
     is the target's argmax continuation after consuming tokens[b, :t+1] —
     the verification oracle for draft t+1 and the correction/bonus token
     when the match breaks there."""
-    dt = cfg.activation_dtype
-    kv_quant = "ks" in cache
-    t = tokens.shape[1]
-    x = params["embed"].astype(dt)[tokens]
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.hidden ** 0.5, dt)
-    positions = lengths[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
     table = cache["table"]
-
-    if kv_quant:
-        def body(x, scan_in):
-            bp, pk, pv, pks, pvs = scan_in
-            x, nk, nv, nks, nvs = _paged_spec_block(
-                bp, x, positions, lengths, live, pk, pv, table, cfg,
-                pool_ks=pks, pool_vs=pvs)
-            return x, (nk, nv, nks, nvs)
-
-        x, scanned = jax.lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"],
-                      cache["ks"], cache["vs"]))
-    else:
-        def body(x, scan_in):
-            bp, pk, pv = scan_in
-            x, nk, nv, _, _ = _paged_spec_block(
-                bp, x, positions, lengths, live, pk, pv, table, cfg)
-            return x, (nk, nv)
-
-        x, scanned = jax.lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"]))
-    x = L.rmsnorm(x, params["final_norm"], cfg)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("btd,dv->btv", x, head.astype(dt),
-                        preferred_element_type=jnp.float32)
-    if cfg.logits_softcap is not None:
-        logits = jnp.tanh(logits / cfg.logits_softcap) * cfg.logits_softcap
-    out = {"k": scanned[0], "v": scanned[1], "table": table}
-    if kv_quant:
-        out["ks"], out["vs"] = scanned[2], scanned[3]
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32), out
+    x, flat = _pool_forward(
+        params, cache, tokens, table, lengths,
+        jnp.where(live, tokens.shape[1], 0), cfg, "gather")
+    greedy = jnp.argmax(_head_logits(params, x, cfg), axis=-1)
+    return greedy.astype(jnp.int32), {**_pool_planes(flat, cache),
+                                      "table": table}
 
 
 # -- draft-model proposal ------------------------------------------------------

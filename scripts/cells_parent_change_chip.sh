@@ -2,7 +2,9 @@
 # One accepted cell on the parent commit (unpacked under .parent/) and on the
 # working tree, in one call on one machine, the same seed on both sides:
 # scripts/cells_parent_change_chip.sh <tag> <cell> <seed> <trace> [order]
-# order: "pc" (parent first, the default) or "cp".
+# order: "pc" (parent first, the default) or "cp". DIR=<checkout> runs the
+# change from another copy than the working tree (the committed files:
+# git archive $(git write-tree) | tar -x -C .proof).
 tag=$1; cell=$2; seed=$3; trace=$4; order=${5:-pc}
 mkdir -p chiprun_out/$tag
 here=$(pwd)
@@ -14,4 +16,5 @@ run() {   # side dir
   grep -E "compared|requests:|window opens|NO RESULT|Error" $out.log | tail -n 8
   tail -n 300 $out.log > $out.err; rm -f $out.log
 }
-if [ "$order" = pc ]; then run parent .parent; run change .; else run change .; run parent .parent; fi
+change=${DIR:-.}
+if [ "$order" = pc ]; then run parent .parent; run change $change; else run change $change; run parent .parent; fi

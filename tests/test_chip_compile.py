@@ -395,16 +395,12 @@ SERVING_CELLS = {
 # (cell, program) -> scripts/aot_weight_copies.py::lowered_fingerprint of the
 # program as it lowered on the commit BEFORE PR 40 (cc77c2e), which gave the
 # two paged kernels a window, the pool planes of a third kind and the sorted
-# expert layer a held share: none of the four cells has a window or a share,
-# and each of their programs is, operation for operation and kernel body for
+# expert layer a held share: none of these cells has a window or a share,
+# and each of these programs is, operation for operation and kernel body for
 # kernel body, what it was. A change that means to move one of them records
-# the new digest here and says why.
+# the new digest here and says why. (PR 46 moved the five in-place chunk
+# programs that stood here: ``CHUNK_SINCE_PR46``.)
 LOWERED_BEFORE_PR40 = {
-    ("mistral-7b.chat-open", "chunk[1]"): "9a16ab9307d01ba7",
-    ("mixtral-8x7b.batch-longprompt", "chunk[1]"): "03a77e220d400617",
-    ("mixtral-8x7b.batch-longprompt", "chunk[2]"): "24684074f747f6c2",
-    ("glm-4.7-flash.batch-longcontext", "chunk[1]"): "57ce1b084168e27a",
-    ("glm-4.7-flash.batch-longcontext", "chunk[2]"): "4ca45b4b80949972",
     ("glm-4.7-flash.batch-longcontext", "decode"): "51228f8cbfaf3acc",
     ("lfm2-24b-a2b.batch-longanswer", "chunk[1]"): "6a4770d8248e2dcb",
     ("lfm2-24b-a2b.batch-longanswer", "chunk[2]"): "3aa5bb964b3b2815",
@@ -413,23 +409,46 @@ LOWERED_BEFORE_PR40 = {
 # PR 45 gave ``paged_decode_attention`` another schedule (grid ``(rows,)``,
 # the kernel walks a row's live pages by its own copies): the decode programs
 # of the cells that attend through it are new programs, pinned here as PR 45
-# left them (db316db56b64acbb / ddb784f45f5677a3 before). Every ``chunk[...]``
-# digest above and the decode digests of GLM (latent rows) and LFM2 (packed
-# rows), whose kernels PR 45 does not touch, stand as they were: the proof
-# that those two cells run what they ran.
+# left them (db316db56b64acbb / ddb784f45f5677a3 before). The decode digests
+# above, of GLM (latent rows) and LFM2 (packed rows), whose kernels PR 45
+# does not touch, stand as they were.
 DECODE_SINCE_PR45 = {
     ("mistral-7b.chat-open", "decode"): "21df67c531cb4588",
     ("mixtral-8x7b.batch-longprompt", "decode"): "2e6eb883f208d847",
 }
+# PR 46 put the decode step (T = 1), the chunk prefill in place (T = the
+# chunk) and the speculative verify (T = k+1) behind ONE block over the pool
+# (``paged._pool_block``), whose addressing is the decode block's, with a
+# token axis where T > 1. Every decode digest above STANDS (the four older
+# cells run the block at T = 1 operation for operation), and so do LFM2's
+# three (its chunks go the gathered way, which PR 46 does not touch). What
+# MOVED is every chunk program built in place: a row's write index, its
+# positions and a window layer's touched pages were computed once in front of
+# the layer scans by the chunk builder's own closure and are now computed by
+# the block, inside the scan's body, as the decode step always did (the
+# compiled programs hold the same instructions: PERF.md section 6, PR 46,
+# with each cell's parent and change medians from the chip). Before PR 46:
+# chat chunk[1] 9a16ab9307d01ba7; batch chunk[1] 03a77e220d400617, chunk[2]
+# 24684074f747f6c2; GLM chunk[1] 57ce1b084168e27a, chunk[2] 4ca45b4b80949972.
+CHUNK_SINCE_PR46 = {
+    ("mistral-7b.chat-open", "chunk[1]"): "d3e188b71af063b1",
+    ("mixtral-8x7b.batch-longprompt", "chunk[1]"): "837e7469f651050f",
+    ("mixtral-8x7b.batch-longprompt", "chunk[2]"): "093224dc77fc0a9a",
+    ("glm-4.7-flash.batch-longcontext", "chunk[1]"): "cf8d888984c356b5",
+    ("glm-4.7-flash.batch-longcontext", "chunk[2]"): "a822e0159c25f6c9",
+}
 # The program over rows as the engine builds it since PR 41 (the head at each
 # row's last valid position, ``[2, V]`` logits, under a conditional on "some
 # row ends its prompt"): another program than "chunk[2]" above, which is its
-# all-position form and still lowers to what it was. Recorded on PR 41's tree
-# for the next change that must leave it alone.
+# all-position form. LFM2's (gathered) stands as PR 41 left it; the two built
+# in place moved with PR 46 as their all-position forms did (before:
+# Mixtral 1f4f9199db3e0328, GLM 8d33877ceb87639a).
 ROWS_PROGRAM_SINCE_PR41 = {
-    "glm-4.7-flash.batch-longcontext": "8d33877ceb87639a",
     "lfm2-24b-a2b.batch-longanswer": "661f820c73930e41",
-    "mixtral-8x7b.batch-longprompt": "1f4f9199db3e0328",
+}
+ROWS_PROGRAM_SINCE_PR46 = {
+    "glm-4.7-flash.batch-longcontext": "6832c82200bca8c7",
+    "mixtral-8x7b.batch-longprompt": "773a9fd9ca219067",
 }
 # program -> the Mosaic kernel its attention goes through, a cell's family
 ATTENTION_KERNELS = {
@@ -484,20 +503,67 @@ def test_serving_program_lowers_to_what_it_was_before_pr40(cell_programs,
     v5e, to the programs of the commit before the window went into
     ``paged_decode_attention`` / ``paged_chunk_attention`` and the share
     into ``_moe_sorted`` (the decode programs of the two cells that attend
-    through ``paged_decode_attention``: to PR 45's, ``DECODE_SINCE_PR45``).
+    through ``paged_decode_attention``: to PR 45's, ``DECODE_SINCE_PR45``;
+    the chunk programs built in place: to PR 46's, ``CHUNK_SINCE_PR46``).
     The program over rows in its all-position form too (``logits_at``'s
     default: what every caller but the engine's program over rows takes);
     the form the engine builds since PR 41 is pinned beside it."""
     from scripts.aot_weight_copies import lowered_fingerprint
 
     rows = program == "chunk[2]"
-    pinned = {**LOWERED_BEFORE_PR40, **DECODE_SINCE_PR45}
+    pinned = {**LOWERED_BEFORE_PR40, **DECODE_SINCE_PR45, **CHUNK_SINCE_PR46}
     assert lowered_fingerprint(
         cell_programs(cell, True, "all" if rows else "last")[program]) \
         == pinned[cell, program]
     if rows:
-        assert lowered_fingerprint(cell_programs(cell)[program]) \
-            == ROWS_PROGRAM_SINCE_PR41[cell]
+        assert lowered_fingerprint(cell_programs(cell)[program]) == {
+            **ROWS_PROGRAM_SINCE_PR41, **ROWS_PROGRAM_SINCE_PR46}[cell]
+
+
+# (cell, program) -> the digest of the two cells whose arms the four older
+# cells do not run (a window layer's ring and planes, a held share of
+# experts; linear layers whose state a sequence lies in the pool).
+# ``serving_cell`` builds both. "rows[2]" is the program over rows as the
+# engine builds it (PR 41's form), "chunk[2]" its all-position form. Recorded
+# on the commit BEFORE PR 46 (e29c218), ahead of any edit, as: mixedlength
+# decode 8a3d1940de856e91, chunk[1] 8fede4ad7e9b60dd, chunk[2]
+# 8dd7a78eb5524fb2, rows[2] 7c0cd4c5a51642b2; longdoc decode
+# 93d131f0326d7640, chunk[1] 89bd64f404d1b287, chunk[2] 54a9679f5b6e1c57,
+# rows[2] 1685c4df765cd50a. ALL EIGHT MOVED with PR 46 and stand here as it
+# left them. The chunk programs for ``CHUNK_SINCE_PR46``'s reason. The decode
+# programs because the one block addresses a window layer through ONE helper
+# for every T (the ring's page for the write by ``_token_pages``, the touched
+# pages and the first key a query sees by ``_window_pages`` and
+# ``_kv_attention``: the same values from other operations) and no longer
+# computes a page index for a linear layer, which writes none (the decode
+# block computed one and dropped it). Both cells' parent and change medians
+# from the chip: PERF.md section 6, PR 46.
+LOWERED_SINCE_PR46 = {
+    ("k-exaone-236b-a23b.batch-mixedlength", "decode"): "0fdfbf21e1e5d814",
+    ("k-exaone-236b-a23b.batch-mixedlength", "chunk[1]"): "27ef78fcc42c5462",
+    ("k-exaone-236b-a23b.batch-mixedlength", "chunk[2]"): "027d5cbd6aaba6e3",
+    ("k-exaone-236b-a23b.batch-mixedlength", "rows[2]"): "6f1045fbb9f0dfd9",
+    ("solar-open2-250b.batch-longdoc", "decode"): "bda4aec20a02d93c",
+    ("solar-open2-250b.batch-longdoc", "chunk[1]"): "ff1cd95d0e635193",
+    ("solar-open2-250b.batch-longdoc", "chunk[2]"): "a64d9e7ee9199456",
+    ("solar-open2-250b.batch-longdoc", "rows[2]"): "43d827beeebc967b",
+}
+
+
+@pytest.mark.parametrize("cell,program", sorted(LOWERED_SINCE_PR46))
+def test_newer_serving_program_lowers_to_what_pr46_left(cell_programs,
+                                                           cell, program):
+    """The window, ring, held-share and linear arms of the pool's block held
+    to the standard of the four older cells: the mixed-length and the
+    long-document cell's programs lower, at the cells' shapes for a
+    described v5e, to the digests PR 46 left (the comment above names what
+    moved them off the parent's)."""
+    from scripts.aot_weight_copies import lowered_fingerprint
+
+    form = "all" if program == "chunk[2]" else "last"
+    name = "chunk[2]" if program == "rows[2]" else program
+    assert lowered_fingerprint(cell_programs(cell, True, form)[name]) \
+        == LOWERED_SINCE_PR46[cell, program]
 
 
 @pytest.mark.parametrize("cell,program", SERVING_PROGRAMS)

@@ -827,3 +827,94 @@ class TestDecodeWritesPoolInPlace:
         assert mem.temp_size_in_bytes < plane_bytes, (
             mem.temp_size_in_bytes, plane_bytes)
         assert mem.alias_size_in_bytes >= 2 * plane_bytes
+
+
+# -- the pool's one block over T tokens a row ------------------------------------
+
+POOL_STEP_CASES = {
+    # case: (preset, overrides, int8 pool)
+    "dense-f32": ("tiny", {}, False),
+    "int8-pool": ("tiny", {}, True),
+    "sorted-experts": ("tiny-moe", {"moe_impl": "sorted"}, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(POOL_STEP_CASES))
+def test_pool_step_over_t_tokens_is_t_single_steps(case):
+    """``paged._pool_forward`` (the builder under the decode step, the
+    in-place chunk and the speculative verify) at ``T = 4`` tokens a row
+    returns, at column ``t``, the logits that ``t + 1`` single decode steps
+    (``T = 1``) return, and leaves the pool rows those steps leave. A dead
+    row writes nothing; a row whose tokens run onto an unmapped page writes
+    the tokens before it and nothing after. ``paged_verify_step`` is that
+    call and the argmax."""
+    import numpy as np
+
+    from kubeflow_tpu.serve.paged import (
+        _head_logits, _paged_decode_step, _pool_forward, _pool_planes,
+    )
+    from kubeflow_tpu.serve.spec_decode import paged_verify_step
+
+    name, over, int8 = POOL_STEP_CASES[case]
+    cfg = preset(name, vocab_size=64, dtype="float32", param_dtype="float32",
+                 **over)
+    params = init_decoder_params(jax.random.PRNGKey(2), cfg)
+    pg, pages, t = 4, 12, 4
+    rng = np.random.default_rng(5)
+    shape = (cfg.n_layers, pages, pg, cfg.n_kv_heads, cfg.head_dim)
+    if int8:
+        cache = {n: jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+                 for n in ("k", "v")}
+        for n in ("ks", "vs"):
+            cache[n] = jnp.asarray(rng.uniform(0.001, 0.02, shape[:-1]),
+                                   jnp.float32)
+    else:
+        cache = {n: jnp.asarray(rng.normal(size=shape), jnp.float32)
+                 for n in ("k", "v")}
+    # row 0 crosses from its first page into its second; row 1 is DEAD on
+    # mapped pages; row 2's third and fourth tokens fall on an unmapped
+    # page; row 3 stays inside one page. Page 0 belongs to no row.
+    table = jnp.asarray([[3, 5, -1], [7, 2, -1], [9, -1, -1], [4, 11, 8]],
+                        jnp.int32)
+    start = np.array([pg - 2, 5, pg - 2, pg], np.int32)
+    live = np.array([True, False, True, True])
+    tokens = rng.integers(1, cfg.vocab_size, (4, t)).astype(np.int32)
+    initial = {n: np.asarray(p) for n, p in cache.items()}
+
+    def at_once(c):
+        x, flat = _pool_forward(
+            params, c, jnp.asarray(tokens), table, jnp.asarray(start),
+            jnp.where(jnp.asarray(live), t, 0), cfg, "gather")
+        return _head_logits(params, x, cfg), _pool_planes(flat, c)
+
+    got, pool = jax.jit(at_once)(cache)
+    step = jax.jit(lambda c, tok, ln: _paged_decode_step(
+        params, c, tok, ln, jnp.asarray(live), cfg))
+    stepped = {**cache, "table": table}
+    whole = [0, 3]                      # live rows, every page mapped
+    for i in range(t):
+        want, stepped = step(stepped, jnp.asarray(tokens[:, i]),
+                             jnp.asarray(start + i))
+        rows = whole + ([2] if i < 2 else [])
+        np.testing.assert_allclose(np.asarray(got)[rows, i],
+                                   np.asarray(want)[rows], atol=2e-4)
+    written = {(3, 2), (3, 3), (5, 0), (5, 1), (9, 2), (9, 3),
+               (11, 0), (11, 1), (11, 2), (11, 3)}
+    for n in cache:
+        after = np.asarray(pool[n])
+        np.testing.assert_allclose(after, np.asarray(stepped[n]),
+                                   atol=1e-5)
+        touched = np.zeros(after.shape[:3], bool)
+        for page, off in written:
+            touched[:, page, off] = True
+        changed = (after != initial[n]).reshape(*touched.shape, -1).any(-1)
+        assert not (changed & ~touched).any()           # dead, unmapped
+        assert changed[:, [3, 5, 9, 11]].any(axis=(1, 2)).all()
+    greedy, verified = paged_verify_step(
+        params, {**cache, "table": table}, jnp.asarray(tokens),
+        jnp.asarray(start), jnp.asarray(live), cfg)
+    np.testing.assert_array_equal(
+        np.asarray(greedy)[whole], np.asarray(got).argmax(-1)[whole])
+    for n in cache:
+        np.testing.assert_array_equal(np.asarray(verified[n]),
+                                      np.asarray(pool[n]))
