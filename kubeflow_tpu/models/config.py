@@ -2,8 +2,9 @@
 
 Presets cover the BASELINE.json configs (Llama-3-8B, Gemma-2B, Mixtral-8x7B)
 and the models the benchmark serves at their published widths (GLM-4.7-Flash,
-LFM2-24B-A2B, K-EXAONE-236B-A23B, Solar-Open2-250B), plus tiny variants of each structure for
-tests. Architecture facts are from the public model cards and ``config.json``
+LFM2-24B-A2B, K-EXAONE-236B-A23B, Solar-Open2-250B,
+Phi-4-mini-flash-reasoning), plus tiny variants of each structure for tests.
+Architecture facts are from the public model cards and ``config.json``
 files.
 """
 
@@ -13,6 +14,13 @@ import dataclasses
 from typing import Optional
 
 import jax.numpy as jnp
+
+
+# The kinds a state-space stack brings: "ssm" keeps a state a sequence; "gmu"
+# and "cross" keep none and read what another layer computed (value: the
+# kind whose last layer in front of the tail they read).
+STATELESS_KINDS = {"gmu": "ssm", "cross": "attention"}
+SSM_KINDS = ("ssm", *STATELESS_KINDS)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -98,7 +106,31 @@ class DecoderConfig:
     # convolutions' tails, one entry a SEQUENCE (serve/paged.py).
     # ``attn_output_gate``: an attention layer's output is multiplied by
     # ``sigmoid(x Wgate)``, a value a head channel, before ``wo``.
+    # An "ssm" layer's operator is the Mamba-1 selective scan
+    # (layers.ssm_block, ops/ssm.py): ``ssm_inner`` channels behind a causal
+    # depthwise convolution of ``conv_taps`` taps, each with ``ssm_state``
+    # states, the step through a projection of rank ``ssm_dt_rank``; its
+    # state is a ``[ssm_state, ssm_inner]`` float32 matrix and the
+    # convolution's tail, one entry a SEQUENCE. A "gmu" layer (gated memory
+    # unit) multiplies ``SiLU(x W1)`` with the scan output of the LAST ssm
+    # layer in front of it at the same position and keeps nothing; a "cross"
+    # layer is attention with queries only, over the K and V of the last
+    # "attention" layer in front of it, and keeps nothing either. Both kinds
+    # stand behind every layer that keeps state (``stateless_tail``).
+    # ``diff_attention``: every attention layer (window, global, cross) is
+    # differential attention (layers.diff_qkv): adjacent heads pair up and
+    # the second's softmax is subtracted from the first's, scaled by a
+    # learned lambda. ``attn_bias``: biases on its projections.
+    # ``use_rope`` False: no layer rotates q and k. ``norm_kind``: "rms", or
+    # "layer" (LayerNorm with weight and bias) for every norm of the stack.
     layer_kinds: tuple = ()
+    ssm_state: int = 0
+    ssm_inner: int = 0
+    ssm_dt_rank: int = 0
+    diff_attention: bool = False
+    attn_bias: bool = False
+    use_rope: bool = True
+    norm_kind: str = "rms"
     linear_heads: int = 0
     linear_head_dim: int = 0
     linear_gate_rank: int = 0
@@ -149,7 +181,7 @@ class DecoderConfig:
         # A configuration file's list (JSON has no tuple) stays hashable.
         object.__setattr__(self, "layer_kinds", tuple(self.layer_kinds))
         unknown = set(self.layer_kinds) - {"attention", "window", "conv",
-                                           "linear"}
+                                           "linear", *SSM_KINDS}
         if unknown:
             raise ValueError(f"unknown layer kinds {sorted(unknown)}")
         if "window" in self.layer_kinds and self.attn_window <= 0:
@@ -159,6 +191,32 @@ class DecoderConfig:
                 and self.linear_gate_rank > 0):
             raise ValueError("linear layers need linear_heads, "
                              "linear_head_dim and linear_gate_rank > 0")
+        if self.norm_kind not in ("rms", "layer"):
+            raise ValueError(f"unknown norm_kind {self.norm_kind!r}")
+        if "ssm" in self.layer_kinds and not (
+                self.ssm_state > 0 and self.ssm_inner > 0
+                and self.ssm_dt_rank > 0):
+            raise ValueError("ssm layers need ssm_state, ssm_inner and "
+                             "ssm_dt_rank > 0")
+        if self.diff_attention and (self.n_heads % 2 or self.n_kv_heads % 2
+                                    or self.is_latent):
+            raise ValueError("differential attention pairs per-head queries "
+                             "and K/V heads: both counts even, not latent")
+        kinds = self.kinds
+        head = self.n_layers - self.stateless_tail
+        if "cross" in kinds and not self.diff_attention:
+            raise NotImplementedError(
+                "cross layers are differential attention's (diff_attention)")
+        # (a config that is ALL tail is one of a stack's groups,
+        # ``decoder.layer_groups``, not a stack)
+        for kind, source in STATELESS_KINDS.items():
+            if kind not in kinds or not head:
+                continue
+            if source not in kinds[:head] or kind in kinds[:head]:
+                raise ValueError(
+                    f"{kind!r} layers stand behind every layer that keeps "
+                    f"state and read the last {source!r} layer in front of "
+                    f"them; the stack is {kinds}")
         if self.experts_held and self.num_experts and not (
                 0 <= self.expert_offset
                 and self.expert_offset + self.experts_held
@@ -180,6 +238,17 @@ class DecoderConfig:
 
     def layers_of(self, kind: str) -> int:
         return self.kinds.count(kind)
+
+    @property
+    def stateless_tail(self) -> int:
+        """The layers at the END of the stack that keep no state and write
+        no cache ("gmu" and "cross"): a position whose logits nobody reads
+        need not pass through them (serve/paged.py::_pool_forward)."""
+        kinds = self.kinds
+        n = 0
+        while n < len(kinds) and kinds[-1 - n] in STATELESS_KINDS:
+            n += 1
+        return n
 
     @property
     def q_dim(self) -> int:
@@ -234,10 +303,30 @@ class DecoderConfig:
                 + self.linear_heads + n + d * self.linear_heads
                 + self.linear_head_dim)
 
+    def _ssm_params(self) -> int:
+        """One ssm block's operator: the in projection (u and z), the taps
+        and their bias, ``wx`` (step, B, C), the step's projection and bias,
+        ``A`` a channel and state, ``D``, the out projection."""
+        d, e, n, r = self.hidden, self.ssm_inner, self.ssm_state, \
+            self.ssm_dt_rank
+        return (2 * d * e + (self.conv_taps + 1) * e + e * (r + 2 * n)
+                + r * e + e + n * e + e + e * d)
+
+    def _diff_params(self, cross: bool) -> int:
+        """One differential attention operator: q (k and v unless ``cross``)
+        and output projections with their biases, the four lambda vectors of
+        ``head_dim`` and the pair norm's weight of twice that."""
+        d = self.hidden
+        kv = 0 if cross else 2 * self.kv_dim
+        return ((d + 1) * (self.q_dim + kv) + (self.q_dim + 1) * d
+                + 6 * self.head_dim)
+
     def _attn_params(self) -> int:
         """One block's attention matrices (a latent block's two norms too;
         the two per-head norms of ``qk_norm``; the output gate's matrix)."""
         d, h = self.hidden, self.n_heads
+        if self.diff_attention:
+            return self._diff_params(cross=False)
         if self.is_latent:
             r, q = self.kv_lora_rank, self.q_lora_rank
             return (d * q + q + q * h * (self.qk_nope_dim + self.qk_rope_dim)
@@ -254,7 +343,11 @@ class DecoderConfig:
         kinds = self.kinds[first:last]
         return (kinds.count("attention") + kinds.count("window")) \
             * self._attn_params() + kinds.count("conv") * self._conv_params() \
-            + kinds.count("linear") * self._linear_params()
+            + kinds.count("linear") * self._linear_params() \
+            + kinds.count("ssm") * self._ssm_params() \
+            + kinds.count("gmu") * 2 * self.hidden * self.ssm_inner \
+            + (kinds.count("cross") * self._diff_params(cross=True)
+               if "cross" in kinds else 0)
 
     def _mlp_params(self, active: bool) -> int:
         """One expert layer's (or, dense, one MLP's) matrices; ``active``
@@ -280,11 +373,12 @@ class DecoderConfig:
         router and the shared expert."""
         d, v = self.hidden, self.vocab_size
         k = self.leading_dense_layers
+        norm = d * (2 if self.norm_kind == "layer" else 1)  # weight (, bias)
         layers = self._operator_params(0, self.n_layers) \
-            + (self.n_layers - k) * (self._mlp_params(False) + 2 * d) \
-            + k * (3 * d * self.mlp_dim + 2 * d)
+            + (self.n_layers - k) * (self._mlp_params(False) + 2 * norm) \
+            + k * (3 * d * self.mlp_dim + 2 * norm)
         embed = v * d if self.tie_embeddings else 2 * v * d
-        return layers + embed + d
+        return layers + embed + norm
 
     def flops_per_token(self) -> float:
         """Approximate training FLOPs/token (fwd+bwd ≈ 6N for dense; MoE
@@ -385,6 +479,22 @@ PRESETS: dict[str, DecoderConfig] = {
         rope_window_only=True, attn_output_gate=True, conv_taps=4,
         linear_heads=64, linear_head_dim=128, linear_gate_rank=128,
     ),
+    # Phi-4-mini-flash-reasoning (microsoft config.json, model_type
+    # phi4flash; SambaY, arXiv:2507.06607: 32L, 2560h, 40/20 heads of 64,
+    # differential attention without position, LayerNorm; layers 0-15
+    # (Mamba-1, window 512) x 8, 16 Mamba-1 whose scan output is the memory,
+    # 17 full attention whose K/V every cross layer reads, 18-31 (gated
+    # memory unit, cross attention) x 7; dense MLP of 10240; tied head)
+    "phi-4-mini-flash": DecoderConfig(
+        vocab_size=200064, hidden=2560, n_layers=32, n_heads=40,
+        n_kv_heads=20, head_dim=64, mlp_dim=10240, max_seq_len=262144,
+        norm_eps=1e-5, tie_embeddings=True,
+        layer_kinds=("ssm", "window") * 8 + ("ssm", "attention")
+        + ("gmu", "cross") * 7,
+        attn_window=512, conv_taps=4, ssm_state=16, ssm_inner=5120,
+        ssm_dt_rank=160, diff_attention=True, attn_bias=True,
+        use_rope=False, norm_kind="layer",
+    ),
     # tiny variants for tests/sim (structure-faithful, sized for 1 CPU core)
     "tiny": DecoderConfig(
         vocab_size=256, hidden=64, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -449,6 +559,18 @@ PRESETS: dict[str, DecoderConfig] = {
         layer_kinds=("attention", "linear", "linear", "linear"),
         rope_window_only=True, attn_output_gate=True, conv_taps=4,
         linear_heads=4, linear_head_dim=16, linear_gate_rank=8,
+    ),
+    # Phi-4-mini-flash's structure: two (ssm, window 8), one (ssm, full
+    # attention), two (gmu, cross); differential attention over 4/2 heads of
+    # 16 without position, LayerNorm, 128 channels of 4 states, tied head
+    "tiny-phi4flash": DecoderConfig(
+        vocab_size=256, hidden=64, n_layers=10, n_heads=4, n_kv_heads=2,
+        head_dim=16, mlp_dim=160, max_seq_len=256, tie_embeddings=True,
+        layer_kinds=("ssm", "window") * 2 + ("ssm", "attention")
+        + ("gmu", "cross") * 2,
+        attn_window=8, conv_taps=4, ssm_state=4, ssm_inner=128,
+        ssm_dt_rank=4, diff_attention=True, attn_bias=True, use_rope=False,
+        norm_kind="layer",
     ),
 }
 

@@ -31,14 +31,18 @@ Params = dict[str, Any]
 # own, and K/V planes of its own (a pool keeps a bounded ring of them a
 # sequence where a global layer keeps every page, serve/paged.py).
 OPERATOR = {"attention": "attn", "window": "window", "conv": "conv",
-            "linear": "linear"}
+            "linear": "linear", "ssm": "ssm", "gmu": "gmu", "cross": "cross"}
 # a window layer's plane -> the name attention knows it by
 WINDOW_PLANES = {"window_k": "k", "window_v": "v"}
 # a linear layer's planes: a sequence's recurrent matrices and the tails of
 # its three convolutions (one entry a SEQUENCE, serve/paged.py)
 LINEAR_PLANES = ("kda_state", "kda_conv")
+# an ssm layer's planes: a sequence's recurrent state [N, E] and the tail of
+# its convolution (one entry a SEQUENCE, as a linear layer's)
+SSM_PLANES = ("ssm_state", "ssm_conv")
 PLANE_KINDS = {"conv": "conv", **dict.fromkeys(WINDOW_PLANES, "window"),
-               **dict.fromkeys(LINEAR_PLANES, "linear")}
+               **dict.fromkeys(LINEAR_PLANES, "linear"),
+               **dict.fromkeys(SSM_PLANES, "ssm")}
 
 
 def plane_kind(name: str) -> str:
@@ -51,8 +55,10 @@ def block_kind(bp: dict) -> str:
 
 
 def _init_operator(key, cfg: DecoderConfig, kind: str):
-    init = {"conv": L.init_conv, "linear": L.init_linear}.get(
-        kind, L.init_attention)
+    init = {"conv": L.init_conv, "linear": L.init_linear,
+            "ssm": L.init_ssm, "gmu": L.init_gmu,
+            "cross": lambda k, c: L.init_diff_attention(k, c, cross=True),
+            }.get(kind, L.init_attention)
     return init(jax.random.split(key)[0], cfg)
 
 
@@ -61,10 +67,9 @@ def _init_ffn(key, cfg: DecoderConfig):
     (or expert) layer and the two norms."""
     k_mlp = jax.random.split(key)[1]
     mlp_p, mlp_s = (L.init_moe if cfg.is_moe else L.init_mlp)(k_mlp, cfg)
-    ln1, ln1_s = L.init_rmsnorm(cfg)
-    ln2, ln2_s = L.init_rmsnorm(cfg)
-    return ({"mlp": mlp_p, "ln1": ln1, "ln2": ln2},
-            {"mlp": mlp_s, "ln1": ln1_s, "ln2": ln2_s})
+    ln1, ln1_s = L.init_norm(cfg, "ln1")
+    ln2, ln2_s = L.init_norm(cfg, "ln2")
+    return ({"mlp": mlp_p, **ln1, **ln2}, {"mlp": mlp_s, **ln1_s, **ln2_s})
 
 
 def _init_block(key, cfg: DecoderConfig, kind: str = "attention"):
@@ -80,19 +85,66 @@ def _period(kinds: tuple) -> int:
                        for i in range(p, len(kinds))))
 
 
-def _periodic(name: str, cfg: DecoderConfig, first: int, n: int) -> list:
-    """Layers [first, first + n) as whole periods of their pattern, and what
-    is left behind them (a cut period) as a group of its own."""
+def _periodic(cfg: DecoderConfig, first: int, n: int) -> list:
+    """Layers [first, first + n) as whole periods of their shortest pattern,
+    and what is left behind them (a cut period) as a group of its own: (the
+    group's config, its first layer)."""
     kinds = cfg.kinds[first:first + n]
     p = _period(kinds)
     whole = n // p * p
-    out = [(name, dataclasses.replace(cfg, n_layers=whole,
-                                      layer_kinds=kinds[:p]), first)]
+    out = [(dataclasses.replace(cfg, n_layers=whole, layer_kinds=kinds[:p]),
+            first)]
     if whole < n:
-        out.append((name + "_rest", dataclasses.replace(
+        out.append((dataclasses.replace(
             cfg, n_layers=n - whole, layer_kinds=kinds[whole:]),
             first + whole))
     return out
+
+
+def _stretches(kinds: tuple) -> list:
+    """``kinds`` cut where a RUN starts and ends: a pattern of two or more
+    kinds that stands at least twice in a row (``(ssm, window) x 8``), taken
+    from the left, the shortest pattern first. [(start, length)]: the runs
+    and what lies between them; one stretch, the whole, where there is no
+    run or the stack is one (``(a, c, c, c) x 9`` and its cut period)."""
+    n, s, loose, out = len(kinds), 0, 0, []
+    while s < n:
+        run = 0
+        for p in range(2, (n - s) // 2 + 1):
+            pattern = kinds[s:s + p]
+            r = 1
+            while kinds[s + r * p:s + (r + 1) * p] == pattern:
+                r += 1
+            if r >= 2 and len(set(pattern)) >= 2:
+                run = r * p
+                break
+        if not run:
+            s += 1
+            continue
+        # the cut period behind a run that reaches the end stays with it
+        if n - s - run < p and kinds[s + run:] == pattern[:n - s - run]:
+            run = n - s
+        if s > loose:
+            out.append((loose, s - loose))
+        out.append((s, run))
+        s = loose = s + run
+    if n > loose:
+        out.append((loose, n - loose))
+    return out
+
+
+def _grouped(name: str, cfg: DecoderConfig, first: int, n: int) -> list:
+    """Layers [first, first + n) as groups that are each one scan, named
+    ``name``, ``name_rest``, ``name_rest2`` ... in order. The layers of the
+    stack's stateless tail (``cfg.stateless_tail``) never share a group with
+    a layer in front of them: a program may run them at other positions."""
+    head = max(first, min(first + n, cfg.n_layers - cfg.stateless_tail))
+    found = []
+    for lo, hi in ((first, head), (head, first + n)):
+        for at, length in _stretches(cfg.kinds[lo:hi]):
+            found += _periodic(cfg, lo + at, length)
+    return [(name + ("" if i == 0 else "_rest" + (str(i) if i > 1 else "")),
+             gcfg, at) for i, (gcfg, at) in enumerate(found)]
 
 
 def layer_groups(cfg: DecoderConfig) -> list[tuple[str, DecoderConfig, int]]:
@@ -105,22 +157,25 @@ def layer_groups(cfg: DecoderConfig) -> list[tuple[str, DecoderConfig, int]]:
     Where the layers' kinds differ (``cfg.layer_kinds``) a group is whole
     PERIODS of its pattern: its config's ``layer_kinds`` is one period, the
     unit its scan walks (``period_units`` / ``unit_blocks``), and
-    ``n_layers`` the layers it holds. In the tree a group's norms and
+    ``n_layers`` the layers it holds. A stack that is several patterns in a
+    row (``(ssm, window) x 8, (ssm, attention), (gmu, cross) x 7``) is a
+    group a pattern (``_stretches``), and its stateless tail always groups
+    of its own (``_grouped``). In the tree a group's norms and
     feed-forward leaves are stacked over its layers in order, an operator's
     over the layers of its kind (``OPERATOR``)."""
     k = cfg.leading_dense_layers
     if not k:
         if not cfg.layer_kinds:
             return [("layers", cfg, 0)]
-        return _periodic("layers", cfg, 0, cfg.n_layers)
+        return _grouped("layers", cfg, 0, cfg.n_layers)
     if not cfg.is_moe or not 0 < k < cfg.n_layers:
         raise ValueError(
             f"leading_dense_layers={k} needs an expert model of more than "
             f"{k} layers (n_layers={cfg.n_layers})")
     dense = dataclasses.replace(cfg, num_experts=0, leading_dense_layers=0)
     experts = dataclasses.replace(cfg, leading_dense_layers=0)
-    return _periodic("dense_layers", dense, 0, k) \
-        + _periodic("layers", experts, k, cfg.n_layers - k)
+    return _grouped("dense_layers", dense, 0, k) \
+        + _grouped("layers", experts, k, cfg.n_layers - k)
 
 
 def period_units(stack, gcfg: DecoderConfig):
@@ -136,10 +191,29 @@ def period_units(stack, gcfg: DecoderConfig):
         lambda a: a.reshape(m, a.shape[0] // m, *a.shape[1:]), stack)
 
 
-def unit_blocks(unit, gcfg: DecoderConfig) -> list:
+def split_dense_stack(stack, gcfg: DecoderConfig):
+    """A stacked group as (what its scan slices a period at a time, the
+    dense MLP's leaves taken WHOLE). In a group of more than one kind of
+    layer a scan unit holds ``p`` layers' feed-forward leaves, ``[p, D, M]``,
+    and a layer's is a slice of that slice: the chip's compiler copies the
+    unit's ``p`` matrices out of the stack before every layer uses one (0.32
+    ms a matrix a period at 2560 x 10240: 12 ms of a decode step of 32
+    layers, my chip run, PR 47). Left out of the scan and indexed by the
+    LAYER inside the body (``unit_blocks(whole=, u=)``), each use is one
+    slice of the stack that fuses into its product, as a scan over alike
+    layers gives it. (An expert layer's stack has its own way:
+    ``layers.split_expert_stack``; those groups stay as they were.)"""
+    if len(gcfg.period) == 1 or gcfg.is_moe:
+        return stack, None
+    return {k: v for k, v in stack.items() if k != "mlp"}, stack["mlp"]
+
+
+def unit_blocks(unit, gcfg: DecoderConfig, whole=None, u=None) -> list:
     """The layers of one scan unit, in order: (kind, the layer's place among
     the unit's layers of its kind, its block's parameters). ``unit``: one
-    iteration's slice of ``period_units``."""
+    iteration's slice of ``period_units``; ``whole`` / ``u``
+    (``split_dense_stack``): the feed-forward leaves not scanned and the
+    iteration's index."""
     period = gcfg.period
     if len(period) == 1:
         return [(period[0], 0, unit)]
@@ -148,7 +222,11 @@ def unit_blocks(unit, gcfg: DecoderConfig) -> list:
         i = seen[kind] = seen.get(kind, -1) + 1
         out.append((kind, i, {
             **{n: jax.tree.map(lambda a, j=j: a[j], unit[n])
-               for n in ("mlp", "ln1", "ln2")},
+               for n in ("mlp", "ln1", "ln2",
+                         *(m for m in ("ln1_b", "ln2_b") if m in unit))
+               if n in unit},
+            **({} if whole is None else {"mlp": jax.tree.map(
+                lambda a, j=j: a[u * len(period) + j], whole)}),
             OPERATOR[kind]: jax.tree.map(lambda a, i=i: a[i],
                                          unit[OPERATOR[kind]])}))
     return out
@@ -182,13 +260,30 @@ def init_decoder_params(key: jax.Array, cfg: DecoderConfig) -> Params:
         else:
             stacks[name] = [_init_block(k, gcfg, kind)[0]
                             for k, kind in zip(keys, gcfg.kinds)]
+        if cfg.diff_attention:
+            _set_lambda_init(stacks[name], gcfg, first)
 
-    final_norm, _ = L.init_rmsnorm(cfg)
-    params: Params = {"embed": tok, **stacks, "final_norm": final_norm}
+    final_norm, _ = L.init_norm(cfg, "final_norm")
+    params: Params = {"embed": tok, **stacks, **final_norm}
     if not cfg.tie_embeddings:
         params["lm_head"] = L._init(k_head, (cfg.hidden, cfg.vocab_size),
                                     cfg.weight_dtype)
     return params
+
+
+def _set_lambda_init(group, gcfg: DecoderConfig, first: int) -> None:
+    """Every differential attention operator of ``group`` (a stacked tree or
+    a list of blocks, whose first layer is the stack's ``first``) given the
+    ``lambda_init`` of its layer's depth."""
+    for kind in set(gcfg.kinds) & {"attention", "window", "cross"}:
+        depths = [first + i for i, k in enumerate(gcfg.kinds) if k == kind]
+        if isinstance(group, list):
+            for d in depths:
+                group[d - first][OPERATOR[kind]]["lambda_init"] = \
+                    L.diff_lambda_init(d)
+        else:
+            group[OPERATOR[kind]]["lambda_init"] = L.diff_lambda_init(
+                jnp.asarray(depths))
 
 
 def _block_specs(cfg: DecoderConfig, kind: str = "attention"):
@@ -230,7 +325,7 @@ def decoder_param_specs(cfg: DecoderConfig) -> Params:
     specs: Params = {
         "embed": ("vocab", "embed_table"),
         **stacks,
-        "final_norm": ("norm",),
+        **L.init_norm(cfg, "final_norm")[1],
     }
     if not cfg.tie_embeddings:
         specs["lm_head"] = ("embed", "vocab")
@@ -242,9 +337,39 @@ def _block_forward(block_params, x, positions, cfg: DecoderConfig,
                    rules=DEFAULT_RULES,
                    expert_axis=None, seq_axis=None, tp_axis=None,
                    valid_len=None, lora=None, expert_stack=None,
-                   moe_capacity_per_row=False):
-    h = L.rmsnorm(x, block_params["ln1"], cfg, mesh=mesh)
-    if "conv" in block_params:
+                   moe_capacity_per_row=False, cache_len=None):
+    # A stack with a stateless tail carries, beside ``x``, what the tail's
+    # layers read of the layers in front: ``shared`` = {"m": the last ssm
+    # layer's scan output, "k" / "v": what the last attention layer attended
+    # over} (``decoder_forward``).
+    x, shared = x if isinstance(x, tuple) else (x, None)
+    h = L.rmsnorm(x, block_params["ln1"], cfg, mesh=mesh,
+                  bias=block_params.get("ln1_b"))
+    if set(block_params) & {"ssm", "gmu", "cross"} and (
+            tp_axis is not None or lora is not None):
+        raise NotImplementedError(
+            "an ssm, gmu or cross layer under in-stage tensor parallelism "
+            "or with LoRA adapters")
+    if "ssm" in block_params:
+        # An ssm layer's cache is its state before ``x`` (the recurrent
+        # state and the convolution's tail); it hands back the state after
+        # the last valid position, and its scan output to the carry.
+        attn_out, state, y = L.ssm_block(
+            block_params["ssm"], h, cfg,
+            None if kv_cache is None else tuple(
+                kv_cache[n] for n in SSM_PLANES), valid_len)
+        new_cache = None if kv_cache is None else dict(zip(SSM_PLANES, state))
+        if shared is not None:
+            shared = {**shared, "m": y}
+    elif "gmu" in block_params:
+        attn_out, new_cache = L.gmu_block(block_params["gmu"], h,
+                                          shared["m"], cfg), None
+    elif "cross" in block_params:
+        attn_out, new_cache = L.attention_block(
+            block_params["cross"], h, positions, cfg,
+            kv_cache=None if cache_len is None else {"len": cache_len},
+            cross_kv=(shared["k"], shared["v"]))
+    elif "conv" in block_params:
         # A conv layer's cache is the tail of gated rows before ``x``; what
         # it hands back is that tail followed by its own rows, of which the
         # caller keeps the windows it needs (serve/paged.py).
@@ -288,9 +413,15 @@ def _block_forward(block_params, x, positions, cfg: DecoderConfig,
             block_params["attn"], h, positions, cfg,
             kv_cache=kv_cache, attn_impl=attn_impl, mesh=mesh,
             tp_axis=tp_axis, lora=lora)
+        if shared is not None:      # what the cross layers behind it read
+            k, v = (new_cache["k"], new_cache["v"]) \
+                if new_cache is not None \
+                else L.diff_kv(block_params["attn"], h, cfg)
+            shared = {**shared, "k": k, "v": v}
     # Residual add + second norm as ONE op: fused kernels run it in a
     # single pass over the stream (layers.add_rmsnorm).
-    x, h = L.add_rmsnorm(x, attn_out, block_params["ln2"], cfg, mesh=mesh)
+    x, h = L.add_rmsnorm(x, attn_out, block_params["ln2"], cfg, mesh=mesh,
+                         bias=block_params.get("ln2_b"))
     if cfg.is_moe:
         mlp_out, aux = L.moe_block(block_params["mlp"], h, cfg,
                                    expert_axis=expert_axis, seq_axis=seq_axis,
@@ -304,7 +435,7 @@ def _block_forward(block_params, x, positions, cfg: DecoderConfig,
     x = x + mlp_out
     if mesh is not None:
         x = with_logical_constraint(x, ("batch", "act_seq", "act_embed"), mesh, rules)
-    return x, new_cache, aux
+    return (x if shared is None else (x, shared)), new_cache, aux
 
 
 def _remat(fn, policy: str):
@@ -361,7 +492,7 @@ def _run_layers(layers, x, positions, cfg: DecoderConfig, planes: tuple,
             bp, x, positions, cfg, kv_cache=cache, attn_impl=attn_impl,
             mesh=mesh, rules=rules, valid_len=valid_len,
             lora=lr, expert_stack=expert_stack,
-            moe_capacity_per_row=moe_capacity_per_row)
+            moe_capacity_per_row=moe_capacity_per_row, cache_len=cache_len)
 
     def cache_of(kind, layer_planes):
         own = {n: pl for n, pl in zip(plane_names, layer_planes)
@@ -381,13 +512,15 @@ def _run_layers(layers, x, positions, cfg: DecoderConfig, planes: tuple,
         # body takes them whole with the layer's index (no slice of the
         # stack is copied out for the grouped matmul).
         layers, experts = L.split_expert_stack(layers, cfg)
+        layers, dense = split_dense_stack(layers, cfg)
         p = len(cfg.period)
 
         def scan_body(carry, scan_in):
             unit, unit_planes, lora_sl, u = scan_in
             out = {n: [] for n in plane_names}
             aux_sum = jnp.float32(0)
-            for j, (kind, i, bp) in enumerate(unit_blocks(unit, cfg)):
+            for j, (kind, i, bp) in enumerate(
+                    unit_blocks(unit, cfg, dense, u)):
                 carry, new_cache, aux = block(
                     bp, carry,
                     cache_of(kind, unit_planes if p == 1
@@ -510,6 +643,13 @@ def decoder_forward(
                                         mesh, attn_impl)
         groups = []
 
+    if cfg.stateless_tail:
+        # what the tail's layers read of the layers in front rides beside x
+        kv = (tokens.shape[0],
+              kv_caches["k"].shape[2] if kv_caches is not None
+              else tokens.shape[1], cfg.n_kv_heads // 2, 2 * cfg.head_dim)
+        x = (x, {"m": jnp.zeros((*tokens.shape, cfg.ssm_inner), jnp.float32),
+                 "k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt)})
     new_planes: dict[str, list] = {n: [] for n in plane_names}
     for name, gcfg, first in groups:
         last = first + gcfg.n_layers
@@ -539,7 +679,10 @@ def decoder_forward(
                       for n, ps in new_planes.items()}
         new_caches["len"] = kv_caches["len"] + tokens.shape[1]
 
-    x = L.rmsnorm(x, params["final_norm"], cfg, mesh=mesh)
+    if cfg.stateless_tail:
+        x = x[0]
+    x = L.rmsnorm(x, params["final_norm"], cfg, mesh=mesh,
+                  bias=params.get("final_norm_b"))
     if skip_head:
         return x, new_caches, aux_total
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]

@@ -57,8 +57,32 @@ def init_rmsnorm(cfg: DecoderConfig):
     return w, ("norm",)
 
 
+def init_norm(cfg: DecoderConfig, name: str):
+    """A norm of the stack under ``name``: its weight and, where the stack's
+    norms are LayerNorms (``norm_kind`` "layer"), its bias under ``name +
+    "_b"``. Returns (leaves, specs)."""
+    w, spec = init_rmsnorm(cfg)
+    if cfg.norm_kind != "layer":
+        return {name: w}, {name: spec}
+    return ({name: w, name + "_b": jnp.zeros_like(w)},
+            {name: spec, name + "_b": spec})
+
+
+def layernorm(x: jax.Array, w: jax.Array, b: jax.Array,
+              eps: float) -> jax.Array:
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (xf * w.astype(jnp.float32) + b.astype(jnp.float32)).astype(x.dtype)
+
+
 def rmsnorm(x: jax.Array, w: jax.Array, cfg: DecoderConfig,
-            mesh=None) -> jax.Array:
+            mesh=None, bias: Optional[jax.Array] = None) -> jax.Array:
+    """The stack's norm: RMSNorm, or LayerNorm with ``bias`` where
+    ``cfg.norm_kind`` says so (a norm over a head's values has no bias and
+    stays an RMSNorm)."""
+    if cfg.norm_kind == "layer" and bias is not None:
+        return layernorm(x, w, bias, cfg.norm_eps)
     if fused_kernels_on(cfg, mesh):
         from kubeflow_tpu.ops import fused_norm
 
@@ -73,11 +97,15 @@ def rmsnorm(x: jax.Array, w: jax.Array, cfg: DecoderConfig,
 
 
 def add_rmsnorm(x: jax.Array, res: jax.Array, w: jax.Array,
-                cfg: DecoderConfig, mesh=None):
+                cfg: DecoderConfig, mesh=None,
+                bias: Optional[jax.Array] = None):
     """The decoder-block residual idiom ``y = x + res; h = rmsnorm(y)``
     as one op — fused into a single Pallas pass when the kernels are on
     (the stream is read/written once), the two XLA ops otherwise.
     Returns ``(y, h)``."""
+    if cfg.norm_kind == "layer" and bias is not None:
+        y = x + res
+        return y, layernorm(y, w, bias, cfg.norm_eps)
     if fused_kernels_on(cfg, mesh):
         from kubeflow_tpu.ops import fused_norm
 
@@ -177,6 +205,8 @@ def index_layer(lora: Optional[dict], i: int) -> Optional[dict]:
 def init_attention(key, cfg: DecoderConfig):
     if cfg.is_latent:
         return init_latent_attention(key, cfg)
+    if cfg.diff_attention:
+        return init_diff_attention(key, cfg)
     kq, kk, kv, ko = jax.random.split(key, 4)
     d = cfg.hidden
     params = {
@@ -231,7 +261,7 @@ def qk_rope(p: dict, q: jax.Array, k: jax.Array, positions: jax.Array,
     if cfg.qk_norm:
         q = rmsnorm(q, p["q_norm"], cfg)
         k = rmsnorm(k, p["k_norm"], cfg)
-    if cfg.rope_window_only and not window:
+    if not cfg.use_rope or (cfg.rope_window_only and not window):
         return q, k
     return rope(q, positions, cfg.rope_theta), \
         rope(k, positions, cfg.rope_theta)
@@ -293,8 +323,17 @@ def attention_block(
     tp_axis: Optional[str] = None,      # inside shard_map: heads sharded here
     lora: Optional[dict] = None,        # per-layer adapter view (apply_lora_layer)
     window: int = 0,                    # static: a window layer's length
+    cross_kv: Optional[tuple] = None,   # a cross layer: another layer's (K, V)
 ):
     """Returns (out [B,S,D], new_kv_cache|None).
+
+    ``cfg.diff_attention``: the projections and the output are differential
+    attention's (``diff_q`` / ``diff_kv`` / ``diff_output``); what lies
+    between them, the cache and the attention itself, is GQA over paired
+    heads and takes every path below. ``cross_kv`` (a "cross" layer): queries
+    only, over the K and V another layer attended over ([B,Skv,KV/2,2 Dh],
+    that layer's cache where there is one), each query seeing the positions
+    up to its own; nothing is written.
 
     ``window`` > 0: a window layer, whose query ``i`` sees keys ``i - window
     < j <= i``; it takes the XLA attention (the flash kernel and the
@@ -313,6 +352,24 @@ def attention_block(
         return latent_attention_block(p, x, positions, cfg,
                                       kv_cache=kv_cache, attn_impl=attn_impl)
     dt = cfg.activation_dtype
+    if cfg.diff_attention:
+        if tp_axis is not None or lora is not None:
+            raise NotImplementedError(
+                "differential attention under in-stage tensor parallelism "
+                "or with LoRA adapters")
+        q = diff_q(p, x, cfg)
+        if cross_kv is not None:
+            start = 0 if kv_cache is None else kv_cache["len"]
+            k, v = cross_kv
+            seen = jnp.arange(k.shape[1])[None, None, :] <= (
+                jnp.reshape(start, (-1, 1, 1)) + jnp.arange(x.shape[1])[
+                    None, :, None])                          # [B|1,S,Skv]
+            out = multi_head_attention(q, k, v, causal=False,
+                                       mask=seen[:, None])
+            return checkpoint_name(diff_output(p, out, cfg), "attn_out"), None
+        k, v = diff_kv(p, x, cfg)
+        return _attend(p, x, q, k, v, cfg, kv_cache, attn_impl, mesh,
+                       tp_axis, lora, window)
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(dt))
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(dt))
@@ -327,6 +384,15 @@ def attention_block(
     # the block outputs skips reprojecting + re-rotating in the backward
     # while staying far under dots_no_batch's save footprint.
     q, k = qk_rope(p, q, k, positions, cfg, window)
+    return _attend(p, x, q, k, v, cfg, kv_cache, attn_impl, mesh, tp_axis,
+                   lora, window)
+
+
+def _attend(p, x, q, k, v, cfg: DecoderConfig, kv_cache, attn_impl, mesh,
+            tp_axis, lora, window):
+    """``attention_block`` behind its projections: the cache, the attention
+    of whichever path, the output projection."""
+    dt = cfg.activation_dtype
     q = checkpoint_name(q, "q_rope")
     k = checkpoint_name(k, "k_rope")
     v = checkpoint_name(v, "v_proj")
@@ -395,6 +461,9 @@ def attention_block(
     else:
         out = multi_head_attention(q, k, v, causal=True, impl=attn_impl,
                                    window=window)
+    if cfg.diff_attention:
+        return checkpoint_name(diff_output(p, out, cfg), "attn_out"), \
+            new_cache
     out = gate_attention(p, x, out, cfg)
     proj = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
     if lora is not None and "wo" in lora["targets"]:
@@ -403,6 +472,247 @@ def attention_block(
     if tp_axis is not None:
         proj = jax.lax.psum(proj, tp_axis)
     return checkpoint_name(proj, "attn_out"), new_cache
+
+
+# -- Differential attention -----------------------------------------------------
+
+def init_diff_attention(key, cfg: DecoderConfig, cross: bool = False):
+    """A differential attention operator (arXiv:2410.05258, the
+    ``multihead_flashdiff_2`` form): ``wq`` [H Dh, D], ``wk`` / ``wv`` [KV
+    Dh, D] (OUT by IN: how the chip's compiler lays a projection whose result
+    is parted into heads, so no program copies one; none where ``cross``: the
+    layer reads another layer's K and V), ``wo`` [H Dh, D], their biases
+    where ``attn_bias``; the four lambda
+    vectors of ``head_dim``; ``subln`` [2 Dh], the weight of the RMSNorm over
+    a pair's output; ``lambda_init``, a constant of the layer's depth
+    (``diff_lambda_init``: set by whoever knows the depth). The matrices are
+    flat: a head of 64 values in a per-head leaf is half a lane tile."""
+    ks = iter(jax.random.split(key, 8))
+    d, wdt, dh = cfg.hidden, cfg.weight_dtype, cfg.head_dim
+    widths = {"q": cfg.q_dim} if cross else {
+        "q": cfg.q_dim, "k": cfg.kv_dim, "v": cfg.kv_dim}
+    params = {"w" + n: _init(next(ks), (w, d), wdt, scale=d ** -0.5)
+              for n, w in widths.items()}
+    specs = {"w" + n: ("heads" if n == "q" else "kv_heads", "embed")
+             for n in widths}
+    params["wo"] = _init(next(ks), (cfg.q_dim, d), wdt)
+    specs["wo"] = ("heads", "embed")
+    if cfg.attn_bias:
+        widths["o"] = d
+        for n, w in widths.items():
+            params["b" + n] = jnp.zeros((w,), wdt)
+            specs["b" + n] = ("norm",)
+    for n in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"):
+        params[n] = (0.1 * jax.random.normal(next(ks), (dh,),
+                                             jnp.float32)).astype(wdt)
+        specs[n] = ("norm",)
+    params["subln"] = jnp.ones((2 * dh,), wdt)
+    params["lambda_init"] = jnp.float32(diff_lambda_init(0))
+    specs["subln"], specs["lambda_init"] = ("norm",), ()
+    return params, specs
+
+
+def diff_lambda_init(depth):
+    """``lambda_init`` of the layer at ``depth`` in the stack."""
+    return 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(depth, jnp.float32))
+
+
+def _project(p: dict, name: str, x: jax.Array, cfg: DecoderConfig):
+    """``x W + b`` in float32: [B,S,D] -> [B,S,W] (q, k and v lie OUT by
+    IN, the output projection IN by OUT)."""
+    y = jnp.einsum("bsd,de->bse" if name == "o" else "bsd,ed->bse", x,
+                   p["w" + name].astype(x.dtype),
+                   preferred_element_type=jnp.float32)
+    if "b" + name in p:
+        y = y + p["b" + name].astype(jnp.float32)
+    return y
+
+
+def diff_q(p: dict, x: jax.Array, cfg: DecoderConfig) -> jax.Array:
+    """Differential attention's queries as the attention paths and the
+    paged kernels take them: [B,S,H,2 Dh]. Query pair ``i`` is heads ``2i``
+    (``q1``) and ``2i + 1`` (``q2``) of ``head_dim``; over K rows ``[k[2j] |
+    k[2j+1]]`` (``diff_kv``) the padded queries ``[q1 | 0]`` and ``[0 | q2]``
+    score as ``q1 . k[2j]`` and ``q2 . k[2j+1]``, which is GQA of ``H`` heads
+    over ``KV / 2`` of twice the width: head ``h`` reads KV pair ``h // (2 H
+    / KV)``, its own pair's. The paths scale by ``(2 Dh) ** -0.5`` where the
+    model scales by ``Dh ** -0.5``: the queries carry the ``sqrt 2``."""
+    b, s, _ = x.shape
+    dh = cfg.head_dim
+    q = (_project(p, "q", x, cfg) * 2.0 ** 0.5).astype(
+        cfg.activation_dtype).reshape(b, s, cfg.n_heads // 2, 2, dh)
+    zero = jnp.zeros_like(q[..., 0, :])
+    return jnp.stack(
+        [jnp.concatenate([q[..., 0, :], zero], axis=-1),
+         jnp.concatenate([zero, q[..., 1, :]], axis=-1)],
+        axis=3).reshape(b, s, cfg.n_heads, 2 * dh)
+
+
+def diff_kv(p: dict, x: jax.Array, cfg: DecoderConfig):
+    """A token's K and V as the cache keeps them: [B,S,KV/2,2 Dh] each, the
+    adjacent heads ``2j`` and ``2j + 1`` side by side."""
+    b, s, _ = x.shape
+    shape = (b, s, cfg.n_kv_heads // 2, 2 * cfg.head_dim)
+    return tuple(_project(p, n, x, cfg).astype(cfg.activation_dtype)
+                 .reshape(shape) for n in ("k", "v"))
+
+
+def diff_output(p: dict, attn: jax.Array, cfg: DecoderConfig) -> jax.Array:
+    """From the padded queries' attention [B,S,H,2 Dh] (``A1 V`` at the even
+    heads, ``A2 V`` at the odd) to the block's output [B,S,D]: ``(A1 - lambda
+    A2) V`` a pair, its RMSNorm over the pair's ``2 Dh`` values times ``1 -
+    lambda_init``, the output projection."""
+    b, s = attn.shape[:2]
+    f32 = jnp.float32
+    lam0 = jax.lax.stop_gradient(p["lambda_init"].astype(f32))
+    lam = jnp.exp(jnp.sum(p["lambda_q1"].astype(f32)
+                          * p["lambda_k1"].astype(f32))) \
+        - jnp.exp(jnp.sum(p["lambda_q2"].astype(f32)
+                          * p["lambda_k2"].astype(f32))) + lam0
+    a = attn.astype(f32).reshape(b, s, cfg.n_heads // 2, 2, -1)
+    o = a[..., 0, :] - lam * a[..., 1, :]
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                          + cfg.norm_eps) * p["subln"].astype(f32)
+    o = (o * (1.0 - lam0)).astype(cfg.activation_dtype).reshape(b, s, -1)
+    return _project(p, "o", o, cfg).astype(cfg.activation_dtype)
+
+
+# -- Mamba-1 selective scan and the gated memory unit ---------------------------
+
+SSM_STEP_RANGE = (1e-3, 1e-1)
+
+
+def init_ssm(key, cfg: DecoderConfig):
+    """An ssm layer's operator (``ops/ssm.py`` has the recurrence): ``wu`` /
+    ``wz`` [D, E], the in projection's two halves; ``conv`` [taps, E]
+    (``[-1]`` multiplies the current position) and ``conv_b`` [E]; ``wx`` [E,
+    R + 2 N]: the step's low-rank input, B and C; ``wdt`` [R, E] and
+    ``dt_bias`` [E]; ``a_log`` [N, E] (``A = -exp(a_log)``, states on the
+    leading axis as the scan lays them); ``d_skip`` [E]; ``wout`` [E, D].
+    ``a_log`` and ``dt_bias`` start as Mamba's: ``A = 1 .. N`` a channel, the
+    bias the inverse softplus of a step log-uniform in [1e-3, 1e-1]."""
+    ks = iter(jax.random.split(key, 7))
+    d, e, n, r = cfg.hidden, cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank
+    wdt, taps = cfg.weight_dtype, cfg.conv_taps
+    step = jnp.exp(jax.random.uniform(
+        next(ks), (e,), jnp.float32, *jnp.log(jnp.asarray(SSM_STEP_RANGE))))
+    params = {
+        "wu": _init(next(ks), (d, e), wdt), "wz": _init(next(ks), (d, e), wdt),
+        "conv": _init(next(ks), (taps, e), wdt, scale=taps ** -0.5),
+        "conv_b": jnp.zeros((e,), wdt),
+        "wx": _init(next(ks), (e, r + 2 * n), wdt),
+        "wdt": _init(next(ks), (r, e), wdt),
+        "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(wdt),
+        "a_log": jnp.broadcast_to(jnp.log(jnp.arange(
+            1, n + 1, dtype=jnp.float32))[:, None], (n, e)).astype(wdt),
+        "d_skip": jnp.ones((e,), wdt),
+        "wout": _init(next(ks), (e, d), wdt),
+    }
+    specs = {"wu": ("embed", "mlp"), "wz": ("embed", "mlp"),
+             "conv": (None, "mlp"), "conv_b": ("mlp",), "wx": ("mlp", None),
+             "wdt": (None, "mlp"), "dt_bias": ("mlp",),
+             "a_log": (None, "mlp"), "d_skip": ("mlp",),
+             "wout": ("mlp", "embed")}
+    return params, specs
+
+
+def ssm_inputs(p: dict, x: jax.Array, cfg: DecoderConfig,
+               tail: Optional[jax.Array] = None, valid_len=None):
+    """What the scan takes of ``x`` [B,S,D]: (c [B,S,E] float32: the
+    convolved, SiLU'd channels; z [B,S,E]: the gate's input; delta [B,S,E]
+    float32; bm, cm [B,S,N] float32; the convolution's tail after the last
+    valid position [B,taps-1,E]). ``tail``: the ``taps - 1`` projected rows
+    before ``x`` (zeros at a sequence's start and when None). A position ``>=
+    valid_len`` ([B]) is padding: its convolution input and its ``delta`` are
+    0, so the state passes through it unchanged."""
+    dt = cfg.activation_dtype
+    b, s, _ = x.shape
+    taps, n, r = cfg.conv_taps, cfg.ssm_state, cfg.ssm_dt_rank
+    u = jnp.einsum("bsd,de->bse", x, p["wu"].astype(dt))
+    z = jnp.einsum("bsd,de->bse", x, p["wz"].astype(dt))
+    valid = None
+    if valid_len is not None:
+        valid = jnp.arange(s)[None, :] < jnp.reshape(valid_len, (-1, 1))
+        u = jnp.where(valid[..., None], u, 0)
+    if tail is None:
+        tail = jnp.zeros((b, taps - 1, cfg.ssm_inner), dt)
+    us = jnp.concatenate([tail.astype(dt), u], axis=1)   # [B,taps-1+S,E]
+    w = p["conv"].astype(jnp.float32)
+    c = jax.nn.silu(sum(w[j] * us[:, j:j + s].astype(jnp.float32)
+                        for j in range(taps))
+                    + p["conv_b"].astype(jnp.float32))
+    if valid is None:
+        tail = us[:, s:]
+    else:           # the rows before position ``valid_len``
+        at = jnp.reshape(valid_len, (-1, 1)) + jnp.arange(taps - 1)
+        tail = jnp.take_along_axis(
+            us, jnp.broadcast_to(at, (b, taps - 1))[..., None], axis=1)
+    dbc = jnp.einsum("bse,er->bsr", c.astype(dt), p["wx"].astype(dt),
+                     preferred_element_type=jnp.float32)
+    delta = jax.nn.softplus(
+        jnp.einsum("bsr,re->bse", dbc[..., :r].astype(dt),
+                   p["wdt"].astype(dt), preferred_element_type=jnp.float32)
+        + p["dt_bias"].astype(jnp.float32))
+    if valid is not None:
+        delta = jnp.where(valid[..., None], delta, 0.0)
+    return c, z, delta, dbc[..., r:r + n], dbc[..., r + n:], tail
+
+
+def ssm_decay(p: dict) -> jax.Array:
+    """``A`` [N, E] float32, negative."""
+    return -jnp.exp(p["a_log"].astype(jnp.float32))
+
+
+def ssm_output(p: dict, y: jax.Array, z: jax.Array,
+               cfg: DecoderConfig) -> jax.Array:
+    """``(y * SiLU(z)) Wout``: y [B,S,E] float32, the scan's output."""
+    dt = cfg.activation_dtype
+    gated = (y * jax.nn.silu(z.astype(jnp.float32))).astype(dt)
+    return jnp.einsum("bse,ed->bsd", gated, p["wout"].astype(dt))
+
+
+def ssm_block(p: dict, x: jax.Array, cfg: DecoderConfig,
+              state: Optional[tuple] = None, valid_len=None,
+              impl: str = "xla"):
+    """The Mamba-1 mixer over ``x`` [B,S,D] from ``state`` to a state: (the
+    recurrent state [B,N,E] float32, the convolution's tail [B,taps-1,E]);
+    zeros when None (a sequence's start). Returns (out [B,S,D], the state
+    after the last valid position, the scan's output ``y`` [B,S,E] float32
+    BEFORE its gate: the memory a gated memory unit reads). Rows never
+    mix."""
+    from kubeflow_tpu.ops import ssm
+
+    h, tail = state if state is not None else (None, None)
+    if h is None:
+        h = jnp.zeros((x.shape[0], cfg.ssm_state, cfg.ssm_inner),
+                      jnp.float32)
+    c, z, delta, bm, cm, tail = ssm_inputs(p, x, cfg, tail, valid_len)
+    y, h = ssm.ssm_scan(c, delta, bm, cm, ssm_decay(p),
+                        p["d_skip"].astype(jnp.float32), h, impl=impl)
+    return checkpoint_name(ssm_output(p, y, z, cfg), "attn_out"), \
+        (h, tail), y
+
+
+def init_gmu(key, cfg: DecoderConfig):
+    """A gated memory unit's two matrices: ``w1`` [D, E], ``w2`` [E, D]."""
+    k1, k2 = jax.random.split(key)
+    d, e = cfg.hidden, cfg.ssm_inner
+    return ({"w1": _init(k1, (d, e), cfg.weight_dtype),
+             "w2": _init(k2, (e, d), cfg.weight_dtype)},
+            {"w1": ("embed", "mlp"), "w2": ("mlp", "embed")})
+
+
+def gmu_block(p: dict, x: jax.Array, memory: jax.Array,
+              cfg: DecoderConfig) -> jax.Array:
+    """``(SiLU(x W1) * m) W2``: ``memory`` [B,S,E] float32 is an ssm layer's
+    scan output at the same positions."""
+    dt = cfg.activation_dtype
+    gate = jax.nn.silu(jnp.einsum(
+        "bsd,de->bse", x, p["w1"].astype(dt),
+        preferred_element_type=jnp.float32))
+    out = jnp.einsum("bse,ed->bsd", (gate * memory).astype(dt),
+                     p["w2"].astype(dt))
+    return checkpoint_name(out, "attn_out")
 
 
 # -- Latent attention (MLA) ----------------------------------------------------

@@ -287,9 +287,14 @@ def paged_decode_attention(
     pool_vs: Optional[jax.Array] = None,
     sm_scale: Optional[float] = None,
     lower: Optional[jax.Array] = None,     # [B] lowest position attended to
+    kv_heads: int = 0,            # planes kept as rows [P, page * K, D]
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Exact decode attention over the page pool; returns [B, 1, H, D].
+
+    ``kv_heads`` > 0: the planes are kept as rows, ``[P, page * K, D]``
+    (``_page_words``): the same bytes in the same order with no padded
+    dimension, for a head count that is no whole tile.
 
     If ``pool_ks``/``pool_vs`` are given, ``pool_k``/``pool_v`` hold int8
     pages and the kernel dequantizes in VMEM (per-token-per-head scales).
@@ -303,26 +308,41 @@ def paged_decode_attention(
     b, one, h, d = q.shape
     if one != 1:
         raise ValueError("paged decode attention takes one token per slot")
-    p_total, page, kh, _ = pool_k.shape
+    kh = kv_heads or pool_k.shape[2]
     if h % kh:
         raise ValueError(f"q heads {h} must be a multiple of kv heads {kh}")
     if (pool_ks is None) != (pool_vs is None):
         raise ValueError("pool_ks and pool_vs must be given together")
+    if kv_heads and not (kh > 1 and pool_ks is None
+                         and chunk_attention_supported(kh, d, pool_k.dtype)):
+        # rows the strided form does not read (one head, rows that are not
+        # 128 values: no plane of a chip's): as pages of heads again
+        pool_k, pool_v = (pool.reshape(pool.shape[0], -1, kh, d)
+                          for pool in (pool_k, pool_v))
+        kv_heads = 0
     return _decode_attention_call(
         q, pool_k, pool_v, table, lengths, pool_ks, pool_vs, lower,
         sm_scale=sm_scale if sm_scale is not None else d ** -0.5,
-        interpret=interpret if interpret is not None else auto_interpret())
+        interpret=interpret if interpret is not None else auto_interpret(),
+        # no keyword where the planes are pages of heads: the call lowers
+        # under the name it had
+        **({"kv_heads": kv_heads} if kv_heads else {}))
 
 
 # Traced ONCE for each set of shapes and inlined wherever it is called: the
 # programs of a decode ladder (serve/pacing.py) attend through the same call.
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"),
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "interpret", "kv_heads"),
                    inline=True)
 def _decode_attention_call(q, pool_k, pool_v, table, lengths, pool_ks,
                            pool_vs, lower, *, sm_scale: float,
-                           interpret: bool):
+                           interpret: bool, kv_heads: int = 0):
     b, _, h, d = q.shape
-    p_total, page, kh, _ = pool_k.shape
+    if kv_heads:
+        p_total, kh, page = pool_k.shape[0], kv_heads, \
+            pool_k.shape[1] // kv_heads
+    else:
+        p_total, page, kh, _ = pool_k.shape
     quantized = pool_ks is not None
     g = h // kh
     mpp = table.shape[1]
@@ -712,9 +732,15 @@ def _page_words(page_ref, per: int):
     """A page ``[page, KV, D]`` in fast memory as rows of 32-bit words
     ``[page * KV / per, D]`` (``per`` heads a word: 1 for float32, 2 for
     two-byte rows): every (KV / per)-th row from ``w`` on is word ``w`` of
-    the page's tokens in order."""
-    page, kv, d = page_ref.shape
-    rows = page_ref.reshape(page * kv, d)
+    the page's tokens in order. A plane KEPT AS ROWS, ``[P, page * KV, D]``
+    (``kv_heads`` of the calls below: a head count such as 10, which a
+    ``[page, KV, D]`` page pads to a whole tile of 16 in device memory, whose
+    copy engine then takes no page of it), comes as those rows already."""
+    if len(page_ref.shape) == 2:        # a plane kept as rows (below)
+        rows = page_ref
+    else:
+        page, kv, d = page_ref.shape
+        rows = page_ref.reshape(page * kv, d)
     return rows if per == 1 else rows.bitcast(jnp.uint32)
 
 
@@ -730,13 +756,13 @@ def _word_heads(words, dtype) -> list:
 
 
 def _chunk_kernel(table_ref, start_ref, q_ref, *rest, page_size: int,
-                  sm_scale: float, window: int = 0):
+                  sm_scale: float, window: int = 0, kv_heads: int = 0):
     n = CHUNK_PAGES_PER_STEP
     k_refs, v_refs = rest[:n], rest[n:2 * n]
     o_ref, k_rows, v_rows, m_ref, l_ref, acc_ref = rest[2 * n:]
     j = pl.program_id(1)
     h, tile, d = q_ref.shape
-    kv = k_refs[0].shape[2]
+    kv = kv_heads or k_refs[0].shape[2]
     per = k_rows.shape[0]               # heads a 32-bit word of a row holds
     g = h // kv
     block = n * page_size
@@ -820,6 +846,7 @@ def paged_chunk_attention(
     start: jax.Array,             # scalar int32: position of query 0
     *,
     window: int = 0,
+    kv_heads: int = 0,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Causal attention of a chunk of ``C`` queries (positions ``start ..``)
@@ -835,9 +862,10 @@ def paged_chunk_attention(
     fetched nor attended. Positions count from ``table_row``'s first page,
     so a caller hands in the pages the chunk and the window before it touch
     (serve/paged.py: a ring's) and no other is read. With no window the
-    kernel is what it was."""
+    kernel is what it was. ``kv_heads`` > 0: the planes are kept as rows,
+    ``[P, page * KV, D]`` (``paged_decode_attention``)."""
     h, d = q.shape[0], q.shape[2]
-    kv = pool_k.shape[2]
+    kv = kv_heads or pool_k.shape[2]
     if not chunk_attention_supported(kv, d, pool_k.dtype) or h % kv:
         raise ValueError(
             f"paged_chunk_attention over {pool_k.dtype} planes of {kv} heads "
@@ -847,17 +875,23 @@ def paged_chunk_attention(
         interpret=interpret if interpret is not None else auto_interpret(),
         # no keyword where no window is set: the call lowers under the name
         # it had (tests/test_chip_compile.py pins the older cells' programs)
-        **({"window": window} if window else {}))
+        **({"window": window} if window else {}),
+        **({"kv_heads": kv_heads} if kv_heads else {}))
 
 
 # Traced ONCE for each set of shapes and inlined wherever it is called: every
 # row of every chunk program of an engine attends through the same call.
-@functools.partial(jax.jit, static_argnames=("interpret", "window"),
+@functools.partial(jax.jit,
+                   static_argnames=("interpret", "window", "kv_heads"),
                    inline=True)
 def _chunk_attention_call(q, pool_k, pool_v, table_row, start, *,
-                          interpret: bool, window: int = 0):
+                          interpret: bool, window: int = 0,
+                          kv_heads: int = 0):
     h, c, d = q.shape
-    page, kv = pool_k.shape[1:3]
+    if kv_heads:
+        page, kv = pool_k.shape[1] // kv_heads, kv_heads
+    else:
+        page, kv = pool_k.shape[1:3]
     per = 4 // pool_k.dtype.itemsize
     n = CHUNK_PAGES_PER_STEP
     tile = CHUNK_QUERY_TILE
@@ -870,7 +904,10 @@ def _chunk_attention_call(q, pool_k, pool_v, table_row, start, *,
     table = jnp.pad(table_row, (0, num_blocks * n - table_row.shape[0]),
                     constant_values=-1)
     kernel = functools.partial(
-        _chunk_kernel, page_size=page, sm_scale=d ** -0.5, window=window)
+        _chunk_kernel, page_size=page, sm_scale=d ** -0.5, window=window,
+        **({"kv_heads": kv_heads} if kv_heads else {}))
+    # a page's block: its heads' rows, or all its rows where kept as rows
+    a_page = (1, page * kv, d) if kv_heads else (1, page, kv, d)
 
     def q_map(ti, ji, table_ref, start_ref):
         return (0, ti, 0)
@@ -883,7 +920,8 @@ def _chunk_attention_call(q, pool_k, pool_v, table_row, start, *,
             if window:      # nor is one wholly behind the tile's window
                 ji = jnp.maximum(ji, jnp.maximum(
                     start_ref[0] + ti * tile - window + 1, 0) // block)
-            return (jnp.maximum(table_ref[ji * n + i], 0), 0, 0, 0)
+            return (jnp.maximum(table_ref[ji * n + i], 0),) \
+                + (0,) * (len(a_page) - 1)
         return index
 
     return pl.pallas_call(
@@ -894,7 +932,7 @@ def _chunk_attention_call(q, pool_k, pool_v, table_row, start, *,
             num_scalar_prefetch=2,
             grid=(c // tile, num_blocks),
             in_specs=[pl.BlockSpec((h, tile, d), q_map)]
-            + [pl.BlockSpec((1, page, kv, d), page_map(i))
+            + [pl.BlockSpec(a_page, page_map(i))
                for _ in range(2) for i in range(n)],
             out_specs=pl.BlockSpec((h, tile, d), q_map),
             scratch_shapes=[

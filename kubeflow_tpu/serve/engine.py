@@ -77,8 +77,8 @@ from kubeflow_tpu.serve.pacing import RoundPacer, decode_ladder
 from kubeflow_tpu.serve.paged import (
     MOE_ROWS, PageAllocator, PagePoolExhausted, chunk_reads_context,
     context_bucket, engine_pool_shapes, paged_chunk_prefill,
-    own_first_pages, paged_decode_multi, pool_bytes_per_token, pool_shapes,
-    ring_pages,
+    first_page_ids, own_first_pages, paged_decode_multi,
+    pool_bytes_per_token, pool_shapes, ring_pages,
 )
 from kubeflow_tpu.serve.weight_layout import (
     relaid_bytes, relay, weight_formats,
@@ -344,13 +344,16 @@ class _OneContext:
     loads and warms ONE program where it had one a bucket. A call with LoRA
     takes the gathered form, which reads its bucket, and keeps it."""
 
-    def __init__(self, jitted, context: int):
-        self.jitted, self.context = jitted, context
+    def __init__(self, jitted, context: int, at: int = 6):
+        """``at``: where the bucket stands among the arguments (the program
+        over rows takes "this row ends its prompt" in front of it)."""
+        self.jitted, self.context, self.at = jitted, context, at
 
     def _at_one(self, args):   # (p, c, t, tr, st, vl, ncp[, lora[, aidx]])
-        if len(args) > 7 and args[7] is not None:
+        at = self.at
+        if len(args) > at + 1 and args[at + 1] is not None:
             return args
-        return args[:6] + (self.context,) + args[7:]
+        return args[:at] + (self.context,) + args[at + 1:]
 
     def __call__(self, *args):
         return self.jitted(*self._at_one(args))
@@ -813,14 +816,18 @@ class LLMEngine:
         # every slot; linear layers one entry a sequence at its first
         # page's id (a ring of 1 where the stack has no window layer). Those
         # ids are a sequence's first pages and nothing else
-        # (``_ensure_pages``).
+        # (``_ensure_pages``). Where a sequence keeps a ring of several
+        # pages AND a state (ssm beside window layers), the lowest ids, one
+        # a slot, are handed out as a sequence's very first page only: the
+        # state's planes hold an entry a slot (``paged.first_page_ids``).
         self._ring = own_first_pages(cfg_decode)
         self._window_pages = min(self._num_pages,
                                  self.num_slots * self._ring)
         self._allocator = PageAllocator(
             self._num_pages, pg,
             enable_prefix_caching=b.enable_prefix_caching,
-            ring_pages=self._window_pages)
+            ring_pages=self._window_pages,
+            first_pages=first_page_ids(cfg_decode, self.num_slots))
         # lockfree: scheduler-confined (host page-table mirror)
         self._table = np.full((self.num_slots, self._mpp), -1, np.int32)
         self._slot_pages: list[list[int]] = [  # lockfree: scheduler-confined
@@ -842,14 +849,15 @@ class LLMEngine:
         self._kv_pool_bytes = int(sum(v.nbytes for v in self.cache.values()))
         by_kind = {kind: int(sum(v.nbytes for n, v in self.cache.items()
                                  if plane_kind(n) == kind))
-                   for kind in ("attention", "window", "conv", "linear")}
+                   for kind in ("attention", "window", "conv", "linear",
+                                "ssm")}
         self._state_pool_bytes = by_kind["conv"]
         self._kv_window_pool_bytes = by_kind["window"]
         self._kv_global_pool_bytes = by_kind["attention"]
-        # The linear layers' planes hold an entry a SEQUENCE (``slots`` of
-        # them, beside the token pages ``max_pages`` buys); every other
-        # plane holds rows a token or a tail a page.
-        self._kv_sequence_pool_bytes = by_kind["linear"]
+        # The linear and ssm layers' planes hold an entry a SEQUENCE
+        # (``slots`` of them, beside the token pages ``max_pages`` buys);
+        # every other plane holds rows a token or a tail a page.
+        self._kv_sequence_pool_bytes = by_kind["linear"] + by_kind["ssm"]
         self._state_sequences_started = 0       # lockfree: scheduler-confined counter
         # Where a layer holds a share of its experts, the rows its expert
         # layers routed and held ride in the cache pytree as running sums
@@ -949,14 +957,29 @@ class LLMEngine:
         # pool that still takes the gathered form, and each further
         # program is loaded and run at every start (0.75 s warm, 5 s
         # cold on a v5e: PERF.md, PR 29).
-        if self.max_concurrent_prefills > 1 and chunk_rows_per_weight(
-                cfg_prefill, self.chunk_size) < RIDGE_ROWS:
-            self._chunk_rows = self.max_concurrent_prefills
+        # A stack that ENDS in layers that keep no state
+        # (``cfg.stateless_tail``) builds it whatever the ridge says and
+        # sends EVERY chunk through it, one prefill alone as a group of one
+        # row: the program over rows runs that tail at the one position a
+        # row whose logits are read, and not at all where no row ends its
+        # prompt (``paged._pool_forward``), where the one-row ``[C, V]``
+        # program runs it, and the head, at all ``C``.
+        self._tail_at_last = cfg_prefill.stateless_tail > 0
+        by_ridge = self.max_concurrent_prefills > 1 and chunk_rows_per_weight(
+            cfg_prefill, self.chunk_size) < RIDGE_ROWS
+        if by_ridge or self._tail_at_last:
+            if self.max_concurrent_prefills > 1:
+                self._chunk_rows = self.max_concurrent_prefills
             self._paged_chunks = jax.jit(
                 lambda p, c, t, tr, st, vl, ends, ncp, lr=None, ai=None:
                 _chunk_rows_fn(p, c, t, tr, st, vl, ncp, lr, ai, "last",
                                ends),
                 static_argnums=(7,), donate_argnums=(1,))
+            if self._tail_at_last and not chunk_reads_context(
+                    self.cache, cfg_prefill, None, pattn):
+                # (a group of one names its own bucket: ``_dispatch_chunks``)
+                self._paged_chunks = _OneContext(self._paged_chunks,
+                                                 self._mpp, at=7)
 
         def _paged_decode_fn(p, c, st, tbl, key, n, m, lr=None,
                              _impl=pattn):
@@ -1362,7 +1385,12 @@ class LLMEngine:
         the match; and over linear-attention layers, whose state a sequence
         is one matrix a head that every token rewrites: a match would need
         it AS IT STOOD at the match (a snapshot a page: ROADMAP Reach 11),
-        and the speculative verify step cannot roll it back."""
+        and the speculative verify step cannot roll it back; and over
+        state-space (ssm) layers for the same two reasons. Gated memory
+        units and cross layers keep nothing, but read what a layer in front
+        of them computed, which none of the mechanisms above carries, and
+        differential attention's paired K/V rows are not the ``[KV, Dh]``
+        those are written over."""
         what = [name for name, has in (
             ("a latent (ckv) KV pool", cfg.is_latent),
             ("convolution layers whose state lives in the page pool",
@@ -1371,6 +1399,12 @@ class LLMEngine:
              bool(cfg.layers_of("window"))),
             ("linear-attention layers whose state a sequence lives in the "
              "page pool", bool(cfg.layers_of("linear"))),
+            ("state-space (ssm) layers whose state a sequence lives in the "
+             "page pool", bool(cfg.layers_of("ssm"))),
+            ("gated memory units and cross-attention layers that read "
+             "another layer's output and cache", bool(cfg.stateless_tail)),
+            ("differential attention over paired K/V heads",
+             cfg.diff_attention),
             ("K/V heads packed into one pool row", cfg.kv_heads_packed),
             ("leading dense layers", bool(cfg.leading_dense_layers)),
             (f"expert layers that hold {cfg.experts_held} of "
@@ -1394,6 +1428,10 @@ class LLMEngine:
             "enable_prefix_caching (prefix reuse over linear-attention "
             "layers: a match needs the state as it stood at the match)":
                 bool(cfg.layers_of("linear")) and b.enable_prefix_caching,
+            "enable_prefix_caching (prefix reuse and the radix copy-on-write "
+            "tail over ssm layers: a match needs the state as it stood at "
+            "the match)":
+                bool(cfg.layers_of("ssm")) and b.enable_prefix_caching,
         }
         hit = [name for name, on in refused.items() if on]
         if hit:
@@ -1509,6 +1547,9 @@ class LLMEngine:
             # ``slots`` entries; 0 for a stack without linear layers) and
             # every other plane (rows a token, tails a page)
             "kv_sequence_pool_bytes": self._kv_sequence_pool_bytes,
+            # layers that keep no K/V of their own and attend over ONE
+            # layer's planes (the cross layers; 0 for every other stack)
+            "kv_layers_sharing": self.cfg.layers_of("cross"),
             "kv_token_pool_bytes":
                 self._kv_pool_bytes - self._kv_sequence_pool_bytes,
             # sequences whose state was started from zeros (a chunk at
@@ -1915,9 +1956,12 @@ class LLMEngine:
         table row, start, valid length and whether the chunk ends its
         prompt (only then are the row's logits read). One prefill alone, or
         an engine that built no program over several rows, takes the
-        one-row program it always took."""
+        one-row program it always took; an engine whose stack ends in a
+        stateless tail sends it through the program over rows as a group of
+        one row (``__init__``)."""
         C = self.chunk_size
         rows = 1 if len(group) == 1 else self._chunk_rows
+        by_rows = rows > 1 or self._tail_at_last
         reals = [min(C, len(ch.request.prompt_tokens) - ch.pos)
                  for ch in group]
         ends = [ch.pos + real == len(ch.request.prompt_tokens)
@@ -1933,7 +1977,7 @@ class LLMEngine:
         with self._phase(prof.ENGINE_PREFILL_DISPATCH, prof.active() and {
                 "slot": group[0].slot, "pos": group[0].pos,
                 "chunks": len(group)}):
-            if rows > 1:
+            if by_rows:
                 # Rows past the group are DEAD: no valid position, no page.
                 table = np.full((rows, self._mpp), -1, np.int32)
                 start = np.zeros((rows,), np.int32)
@@ -1945,7 +1989,9 @@ class LLMEngine:
                 logits, self.cache = self._paged_chunks(
                     self.params, self.cache, jnp.asarray(chunk),
                     jnp.asarray(table), jnp.asarray(start),
-                    jnp.asarray(valid), jnp.asarray(wanted), self._mpp,
+                    jnp.asarray(valid), jnp.asarray(wanted),
+                    self._mpp if rows > 1 else context_bucket(
+                        group[0].pos, C, self.page_size, self._mpp),
                     *lora)
             else:
                 # Static context bucket (next power of two covering the
@@ -1988,7 +2034,7 @@ class LLMEngine:
             # rows returns that position's alone, a row a prompt.
             self._pending_first.append(
                 (req, ch.slot, plen,
-                 logits[r] if rows > 1 else logits[real - 1]))
+                 logits[r] if by_rows else logits[real - 1]))
 
     def _advance_chunked(self, due: "Optional[list[_Chunking]]" = None,
                          programs: Optional[int] = None) -> int:
@@ -2722,7 +2768,7 @@ class LLMEngine:
             # planes: they come from the ids those planes hold.
             new = self._allocator.alloc(
                 need - have, ring=max(0, min(need, self._ring) - have),
-                owner=self._slot_owner(slot_idx))
+                first=have == 0, owner=self._slot_owner(slot_idx))
         except PagePoolExhausted:
             return False
         self._table[slot_idx, have:need] = new

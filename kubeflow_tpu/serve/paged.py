@@ -63,8 +63,8 @@ import numpy as np
 from kubeflow_tpu.models import layers as L
 from kubeflow_tpu.models.config import DecoderConfig
 from kubeflow_tpu.models.decoder import (
-    LINEAR_PLANES, WINDOW_PLANES, Params, block_kind, layer_groups,
-    period_units, plane_kind, unit_blocks,
+    LINEAR_PLANES, SSM_PLANES, WINDOW_PLANES, Params, block_kind,
+    layer_groups, period_units, plane_kind, split_dense_stack, unit_blocks,
 )
 
 
@@ -91,19 +91,27 @@ class PageAllocator:
     """
 
     def __init__(self, num_pages: int, page_size: int,
-                 enable_prefix_caching: bool = True, ring_pages: int = 0):
-        """``ring_pages`` (a stack with window or linear layers): the page
-        ids below it exist in those layers' planes too and are handed out
-        ONLY on request (``alloc(n, ring=r)``: a sequence's first pages,
+                 enable_prefix_caching: bool = True, ring_pages: int = 0,
+                 first_pages: int = 0):
+        """``ring_pages`` (a stack with window, linear or ssm layers): the
+        page ids below it exist in those layers' planes too and are handed
+        out ONLY on request (``alloc(n, ring=r)``: a sequence's first pages,
         over which its window layers keep their ring, ``ring_table``, and at
-        the first of which its linear layers keep its state,
-        ``sequence_planes``); every other page comes from the ids above."""
+        the first of which its linear and ssm layers keep its state,
+        ``sequence_planes``); every other page comes from the ids above.
+        ``first_pages`` (a stack with a ring of several pages AND a state a
+        sequence): the ids below IT are handed out only as a sequence's very
+        FIRST page (``alloc(.., first=True)``), so the state's planes hold
+        an entry a slot and not one a ring page."""
         self.num_pages = num_pages
         self.page_size = page_size
         self.prefix_caching = enable_prefix_caching
         self.ring_pages = ring_pages
+        self.first_pages = first_pages
         self._free: list[int] = list(range(num_pages - 1, ring_pages - 1, -1))
-        self._free_ring: list[int] = list(range(ring_pages - 1, -1, -1))
+        self._free_ring: list[int] = list(
+            range(ring_pages - 1, first_pages - 1, -1))
+        self._free_first: list[int] = list(range(first_pages - 1, -1, -1))
         self._ref = np.zeros((num_pages,), np.int32)
         # content key -> page id (for reuse); page id -> key (for eviction)
         self._by_key: dict[tuple, int] = {}
@@ -157,8 +165,8 @@ class PageAllocator:
     # -- raw pages ---------------------------------------------------------
 
     def available(self, ring: bool = False) -> int:
-        if ring:
-            return len(self._free_ring)
+        if ring:    # a new sequence takes one first page for its others
+            return len(self._free_ring) + len(self._free_first)
         return len(self._free) + len(self._reclaimable)
 
     def cached(self) -> int:
@@ -220,19 +228,24 @@ class PageAllocator:
             raise AssertionError(msg)
 
     def alloc(self, n: int, owner: Optional[str] = None,
-              ring: int = 0) -> list[int]:
+              ring: int = 0, first: bool = False) -> list[int]:
         """n fresh pages (ref=1 each). Evicts cached pages LRU if needed.
         ``ring``: the first ``ring`` of them from the ids the window layers'
-        planes hold too. All of them or none: nothing is taken where either
-        kind runs short."""
-        if self.available(ring=True) < ring \
+        planes hold too; ``first``: the first of those is a sequence's first
+        page (from the ids kept for first pages, where there are any). All
+        of them or none: nothing is taken where any kind runs short."""
+        first = bool(first and ring and self.first_pages)
+        if len(self._free_ring) < ring - first \
+                or len(self._free_first) < first \
                 or self.available() < n - ring:
             raise PagePoolExhausted(
                 f"need {ring} ring + {n - ring}, have "
                 f"{self.available(ring=True)} + {self.available()}")
         out = []
         for i in range(n):
-            if i < ring:
+            if i == 0 and first:
+                p = self._free_first.pop()
+            elif i < ring:
                 p = self._free_ring.pop()
             elif self._free:
                 p = self._free.pop()
@@ -274,7 +287,9 @@ class PageAllocator:
             if self.refcount_debug:
                 self._unstamp(p)
             if self._ref[p] == 0:
-                if p < self.ring_pages:
+                if p < self.first_pages:
+                    self._free_first.append(p)
+                elif p < self.ring_pages:
                     self._free_ring.append(p)
                 elif p in self._key_of or p in self.retained:
                     self._reclaimable[p] = None    # keep content, LRU
@@ -349,6 +364,12 @@ class PageAllocator:
 # its expert layers routed and held (int32 [2], wrapping: a reader works on
 # differences).
 MOE_ROWS = "moe_rows"
+# What rides through a program's layer scans beside the planes where the
+# stack has gated memory units: the scan output [B,T,E] float32 of the last
+# ssm layer in front of them. A program's own: no cache pytree holds it.
+SSM_MEMORY = "ssm_memory"
+# a kind of layer whose state is one entry a SEQUENCE -> its planes
+SEQUENCE_PLANES = {"linear": LINEAR_PLANES, "ssm": SSM_PLANES}
 
 
 def pool_planes(cfg: DecoderConfig, kv_quant: bool = False) -> tuple:
@@ -371,7 +392,7 @@ def pool_planes(cfg: DecoderConfig, kv_quant: bool = False) -> tuple:
         if kv_quant:
             raise ValueError("int8 KV over a latent (ckv) pool")
         return (("ckv", (L.latent_row_width(cfg),), dt),)
-    kv = (cfg.n_kv_heads, cfg.head_dim)
+    kv = _kv_row(cfg)
     if cfg.kv_heads_packed:
         # Heads narrower than the 128-value lanes: all of a token's heads
         # side by side in ONE row, for the same reason as the latent row
@@ -385,6 +406,36 @@ def pool_planes(cfg: DecoderConfig, kv_quant: bool = False) -> tuple:
         return (("k", kv, jnp.dtype(jnp.int8)), ("v", kv, jnp.dtype(jnp.int8)),
                 ("ks", kv[:1], f32), ("vs", kv[:1], f32))
     return (("k", kv, dt), ("v", kv, dt))
+
+
+def _kv_row(cfg: DecoderConfig) -> tuple:
+    """The trailing shape of a token's K (or V) in a per-head plane: [KV,
+    Dh]; under differential attention the adjacent heads of a pair side by
+    side, [KV / 2, 2 Dh] (``layers.diff_kv``: heads of 64 then fill the 128
+    lanes, and the paged kernels see plain GQA)."""
+    if cfg.diff_attention:
+        return (cfg.n_kv_heads // 2, 2 * cfg.head_dim)
+    return (cfg.n_kv_heads, cfg.head_dim)
+
+
+def kept_as_rows(cfg: DecoderConfig) -> int:
+    """Rows a token holds in a per-head plane that is KEPT AS ROWS, ``[L, P,
+    page * rows, D]`` where every other plane is ``[L, P, page, KV, D]`` (0:
+    the planes are pages of heads). Differential attention's are: its 10
+    pairs of 128 values a token are no whole tile of the chip's memory
+    (``[page, 10, 128]`` is padded to 16 rows a token, 60% more bytes to
+    hold and to read, and the kernels' copy engine takes no page of a padded
+    plane), while the same bytes in the same order as ``page * 10`` rows of
+    128 are. The kernels read a page as rows anyway
+    (``ops/paged_attention.py::_page_words``); what writes and gathers here
+    goes through ``_token_rows``."""
+    return _kv_row(cfg)[0] if cfg.diff_attention else 0
+
+
+def _token_rows(off, rows: int):  # traced
+    """Where in a page kept as rows the token at offset ``off`` ([..]) lies:
+    [.., rows] row indices, its heads in order."""
+    return off[..., None] * rows + jnp.arange(rows, dtype=jnp.int32)
 
 
 def state_planes(cfg: DecoderConfig) -> tuple:
@@ -413,7 +464,7 @@ def window_planes(cfg: DecoderConfig, kv_quant: bool = False) -> tuple:
         return ()
     if kv_quant or cfg.is_latent or cfg.kv_heads_packed:
         raise ValueError("window layers over an int8, latent or packed pool")
-    kv, dt = (cfg.n_kv_heads, cfg.head_dim), cfg.activation_dtype
+    kv, dt = _kv_row(cfg), cfg.activation_dtype
     return tuple((n, kv, dt) for n in WINDOW_PLANES)
 
 
@@ -430,22 +481,32 @@ def sequence_planes(cfg: DecoderConfig) -> tuple:
     many as the planes have entries, and a row alone finds its state. A
     chunk that starts at 0 and a decode step at length 0 start from zeros
     whatever the entry holds, so an entry needs no clearing when its page
-    changes hands. () for a stack without linear layers."""
-    if not cfg.layers_of("linear"):
-        return ()
-    h, dk = cfg.linear_heads, cfg.linear_head_dim
-    return ((LINEAR_PLANES[0], (h, dk, dk), jnp.dtype(jnp.float32)),
-            (LINEAR_PLANES[1], (L.kda_conv_rows(cfg), h * dk),
-             cfg.activation_dtype))
+    changes hands. An ssm layer's entry is found the same way:
+    "ssm_state", the recurrent state ``[ssm_state, ssm_inner]`` float32
+    (``ops/ssm.py``: channels on the lanes), and "ssm_conv", the
+    ``conv_taps - 1`` projected rows before the next token. () for a stack
+    with neither kind."""
+    out = ()
+    if cfg.layers_of("linear"):
+        h, dk = cfg.linear_heads, cfg.linear_head_dim
+        out += ((LINEAR_PLANES[0], (h, dk, dk), jnp.dtype(jnp.float32)),
+                (LINEAR_PLANES[1], (L.kda_conv_rows(cfg), h * dk),
+                 cfg.activation_dtype))
+    if cfg.layers_of("ssm"):
+        out += ((SSM_PLANES[0], (cfg.ssm_state, cfg.ssm_inner),
+                 jnp.dtype(jnp.float32)),
+                (SSM_PLANES[1], (cfg.conv_taps - 1, cfg.ssm_inner),
+                 cfg.activation_dtype))
+    return out
 
 
 def own_first_pages(cfg: DecoderConfig) -> int:
     """How many of a sequence's first pages come from the range of ids kept
     for first pages: its ring where the stack has window layers
-    (``window_ring_pages``), one where it has linear layers (the id is the
-    sequence's entry in their planes), the larger where it has both; 0 for
-    any other stack."""
-    return max(cfg.window_ring_pages, 1 if cfg.layers_of("linear") else 0)
+    (``window_ring_pages``), one where it has linear or ssm layers (the id
+    is the sequence's entry in their planes), the larger where it has both;
+    0 for any other stack."""
+    return max(cfg.window_ring_pages, int(bool(sequence_planes(cfg))))
 
 
 def ring_pages(cfg: DecoderConfig, chunk: int, page_size: int,
@@ -490,25 +551,34 @@ def ring_table(table: jax.Array, first: jax.Array, n: int,  # traced
 
 def pool_shapes(cfg: DecoderConfig, num_pages: int, page_size: int,
                 kv_quant: bool = False,
-                window_pages: Optional[int] = None) -> dict:
+                window_pages: Optional[int] = None,
+                sequence_entries: Optional[int] = None) -> dict:
     """The pool an engine builds: {plane: (shape, type)}. A token plane is
     ``[layers of attention, P, page, ...]``, a state plane ``[layers of
     conv, P, ...]``, a window layer's ``[layers of window, H, page, ...]``
-    and a sequence plane ``[layers of linear, H, ...]`` with ``H`` =
-    ``window_pages``, the ids first pages come from (the whole pool's where
-    not given): each over the layers of ITS kind (``decoder.plane_kind``),
-    so a stack whose layers are all attention keeps ``[L, P, page, ...]``."""
-    out = {n: ((cfg.layers_of("attention"), num_pages, page_size, *trail),
+    with ``H`` = ``window_pages``, the ids a sequence's first pages come
+    from (the whole pool's where not given), and a sequence plane ``[layers
+    of linear or ssm, S, ...]`` with ``S`` = ``sequence_entries``, the ids a
+    sequence's very first page comes from (``window_pages`` where not
+    given): each over the layers of ITS kind (``decoder.plane_kind``), so a
+    stack whose layers are all attention keeps ``[L, P, page, ...]``."""
+    def page_of(trail):
+        rows = kept_as_rows(cfg)
+        return (page_size * rows, *trail[1:]) if rows \
+            else (page_size, *trail)
+
+    out = {n: ((cfg.layers_of("attention"), num_pages, *page_of(trail)),
                dt) for n, trail, dt in pool_planes(cfg, kv_quant)
            if cfg.layers_of("attention")}
     out.update({n: ((cfg.layers_of("conv"), num_pages, *trail), dt)
                 for n, trail, dt in state_planes(cfg)})
     out.update({n: ((cfg.layers_of("window"),
                      num_pages if window_pages is None else window_pages,
-                     page_size, *trail), dt)
+                     *page_of(trail)), dt)
                 for n, trail, dt in window_planes(cfg, kv_quant)})
-    out.update({n: ((cfg.layers_of("linear"),
-                     num_pages if window_pages is None else window_pages,
+    held = num_pages if window_pages is None else window_pages
+    out.update({n: ((cfg.layers_of(plane_kind(n)),
+                     held if sequence_entries is None else sequence_entries,
                      *trail), dt) for n, trail, dt in sequence_planes(cfg)})
     return out
 
@@ -518,18 +588,28 @@ def engine_pool_shapes(cfg: DecoderConfig, slots: int, num_pages: int,
     """The cache pytree of an engine of ``slots`` slots over ``cfg`` as its
     programs take it (``engine.serving_configs`` has set the ring):
     ``pool_shapes`` with a ring for every slot in the window layers' planes
-    and an entry for every slot in the linear layers' (``slots *
-    own_first_pages`` ids, those a sequence's first pages come from) and,
-    where a layer holds a share of its experts, the rows' running sums."""
+    (``slots * own_first_pages`` ids, those a sequence's first pages come
+    from) and an entry for every slot in the linear and ssm layers' (the ids
+    a sequence's very first page comes from: ``first_page_ids``) and, where
+    a layer holds a share of its experts, the rows' running sums."""
     if cfg.layers_of("window") and not cfg.window_ring_pages:
         raise ValueError("an engine's pool over window layers needs "
                          "cfg.window_ring_pages (paged.ring_pages)")
     out = pool_shapes(cfg, num_pages, page_size, kv_quant,
                       window_pages=min(num_pages,
-                                       slots * own_first_pages(cfg)))
+                                       slots * own_first_pages(cfg)),
+                      sequence_entries=min(num_pages, slots))
     if cfg.experts_held:
         out[MOE_ROWS] = ((2,), jnp.dtype(jnp.int32))
     return out
+
+
+def first_page_ids(cfg: DecoderConfig, slots: int) -> int:
+    """The ids an engine of ``slots`` slots keeps for a sequence's very
+    first page (``PageAllocator(first_pages=...)``): one a slot where a
+    sequence keeps a ring of several pages AND a state; 0 where the ring's
+    ids are first pages anyway (a ring of one) or nothing is found there."""
+    return slots if sequence_planes(cfg) and own_first_pages(cfg) > 1 else 0
 
 
 def _plane_bytes(planes: tuple) -> int:
@@ -552,9 +632,10 @@ def state_bytes_per_page(cfg: DecoderConfig) -> int:
 
 
 def state_bytes_per_sequence(cfg: DecoderConfig) -> int:
-    """Bytes one sequence holds over all linear layers, whatever its
-    length: the recurrent matrices and the convolutions' tails."""
-    return cfg.layers_of("linear") * _plane_bytes(sequence_planes(cfg))
+    """Bytes one sequence holds over all linear and ssm layers, whatever
+    its length: the recurrent states and the convolutions' tails."""
+    return sum(cfg.layers_of(plane_kind(plane[0])) * _plane_bytes((plane,))
+               for plane in sequence_planes(cfg))
 
 
 def window_bytes_per_page(cfg: DecoderConfig, page_size: int) -> int:
@@ -563,13 +644,16 @@ def window_bytes_per_page(cfg: DecoderConfig, page_size: int) -> int:
         window_planes(cfg))
 
 
-def _pool_geometry(cache: dict) -> tuple:
+def _pool_geometry(cache: dict, cfg: Optional[DecoderConfig] = None) -> tuple:
     """(pages, page size) of a cache pytree: its first token plane's (a
-    global layer's where the stack has one)."""
+    global layer's where the stack has one); ``cfg``: the stack's, where its
+    planes may be kept as rows (``kept_as_rows``)."""
     names = sorted((n for n in _planes_of(cache)
-                    if plane_kind(n) not in ("conv", "linear")),
+                    if plane_kind(n) not in ("conv", *SEQUENCE_PLANES)),
                    key=lambda n: plane_kind(n) != "attention")
-    return cache[names[0]].shape[1:3]
+    pages, page = cache[names[0]].shape[1:3]
+    rows = kept_as_rows(cfg) if cfg is not None else 0
+    return pages, page // rows if rows else page
 
 
 _NOT_PLANES = ("table", MOE_ROWS)
@@ -605,7 +689,8 @@ def _head_logits(params: Params, x: jax.Array, cfg: DecoderConfig,  # traced
     """Final norm (unless ``x`` is ``normed`` already) and output head:
     [B,S,D] -> [B,S,V] float32."""
     if not normed:
-        x = L.rmsnorm(x, params["final_norm"], cfg)
+        x = L.rmsnorm(x, params["final_norm"], cfg,
+                      bias=params.get("final_norm_b"))
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.activation_dtype),
                         preferred_element_type=jnp.float32)
@@ -632,7 +717,7 @@ def _feed_forward(bp, h, cfg: DecoderConfig, expert_stack=None,  # traced
 
 
 def _scan_layer_groups(params: Params, cfg: DecoderConfig, carry, block,  # traced
-                       lora=None):
+                       lora=None, groups=None):
     """``carry`` through every layer, one scan per group (decoder.
     layer_groups), a period of the group's pattern an iteration:
     ``block(bp, carry, layer, gcfg, lora_view, expert_stack) -> carry``.
@@ -641,16 +726,19 @@ def _scan_layer_groups(params: Params, cfg: DecoderConfig, carry, block,  # trac
     alike layers: its index in the stack); ``bp`` holds its operator under
     its kind's key (``"conv" in bp``). A sorted expert group's expert leaves
     are taken whole, with the layer's index in the group
-    (layers.split_expert_stack)."""
-    for name, gcfg, first in layer_groups(cfg):
+    (layers.split_expert_stack). ``groups``: those of ``layer_groups(cfg)``
+    to run (all of them where None)."""
+    for name, gcfg, first in layer_groups(cfg) if groups is None else groups:
         stack, experts = L.split_expert_stack(params[name], gcfg)
+        stack, dense = split_dense_stack(stack, gcfg)
         period = gcfg.period
         at = {kind: cfg.kinds[:first].count(kind) for kind in period}
 
         def body(carry, scan_in, gcfg=gcfg, experts=experts, period=period,
-                 at=at):
+                 at=at, dense=dense):
             unit, lsl, u = scan_in
-            for j, (kind, i, bp) in enumerate(unit_blocks(unit, gcfg)):
+            for j, (kind, i, bp) in enumerate(
+                    unit_blocks(unit, gcfg, dense, u)):
                 carry = block(
                     bp, carry, at[kind] + u * period.count(kind) + i, gcfg,
                     L.layer_view(lora, lsl),
@@ -675,11 +763,13 @@ def _decode_attention(q, ck, cv, lengths, cfg: DecoderConfig,  # traced
     attends to kpos <= lengths[b] + t); ``lower`` [B] or [B,T] (a window
     layer): and to kpos >= lower."""
     b, t, smax = q.shape[0], q.shape[1], ck.shape[1]
-    groups = cfg.n_heads // cfg.n_kv_heads
-    qg = q.reshape(b, t, cfg.n_kv_heads, groups, cfg.head_dim)
+    # heads and widths as the arrays have them (differential attention's
+    # are pairs: ``layers.diff_q``)
+    heads, kv_heads, width = q.shape[2], ck.shape[2], q.shape[3]
+    qg = q.reshape(b, t, kv_heads, heads // kv_heads, width)
     scores = jnp.einsum("btkgd,bskd->btkgs", qg, ck,
                         preferred_element_type=jnp.float32)
-    scores *= cfg.head_dim ** -0.5
+    scores *= width ** -0.5
     kpos = jnp.arange(smax, dtype=jnp.int32)
     qpos = lengths[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
     mask = kpos[None, None, :] <= qpos[:, :, None]            # [B,T,Smax]
@@ -688,7 +778,7 @@ def _decode_attention(q, ck, cv, lengths, cfg: DecoderConfig,  # traced
     scores = jnp.where(mask[:, :, None, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(ck.dtype)
     out = jnp.einsum("btkgs,bskd->btkgd", probs, cv)
-    return out.reshape(b, t, cfg.n_heads, cfg.head_dim)
+    return out.reshape(b, t, heads, width)
 
 
 def _pool_block(bp, x, positions, start, valid, pools, table, layer,  # traced
@@ -708,8 +798,9 @@ def _pool_block(bp, x, positions, start, valid, pools, table, layer,  # traced
     packed) and, iff the pool stores int8, the per-token-per-head scales
     ``ks``/``vs`` ``[L*P,pg,KV]`` f32; a latent pool's one plane ``ckv``
     ``[L*P,pg,W]``; the conv layers' state ``conv`` ``[Lc*P,taps-1,D]``; a
-    window layer's K and V ``[Lw*H,pg,KV,Dh]``; a linear layer's state a
-    sequence ``[Ll*H,...]`` — and ``layer`` (a traced scalar: the block's
+    window layer's K and V ``[Lw*H,pg,KV,Dh]``; a linear or ssm layer's
+    state a sequence ``[Ll*S,...]``; beside them, where the stack has gated
+    memory units, ``SSM_MEMORY`` — and ``layer`` (a traced scalar: the block's
     index among the layers of its kind) picks this block's ``num_pages``
     (P) pages out of its kind's planes: page ``p`` of layer ``l`` is flat
     page ``l*P + p``. The block writes its tokens' rows into the planes it
@@ -743,12 +834,29 @@ def _pool_block(bp, x, positions, start, valid, pools, table, layer,  # traced
     context."""
     kind = block_kind(bp)
     t, pg = x.shape[1], page_size
-    own = next(pools[n] for n in pools
-               if n != MOE_ROWS and plane_kind(n) == kind)
-    total, pages = own.shape[0], num_pages[kind]
-    base = layer * pages
-    h = L.rmsnorm(x, bp["ln1"], cfg)
-    if kind == "linear":
+    # The planes the block meets: its own kind's; a cross layer's are the
+    # LAST attention layer's (it writes none); a gated memory unit has none.
+    met = {"cross": "attention", "gmu": None}.get(kind, kind)
+    if met is not None:
+        own = next(pools[n] for n in pools
+                   if n not in (MOE_ROWS, SSM_MEMORY)
+                   and plane_kind(n) == met)
+        total, pages = own.shape[0], num_pages[met]
+        base = layer * pages if kind == met else total - pages
+    h = L.rmsnorm(x, bp["ln1"], cfg, bias=bp.get("ln1_b"))
+    if kind == "gmu":
+        proj = L.gmu_block(bp["gmu"], h, pools[SSM_MEMORY], cfg)
+    elif kind == "cross":
+        proj, pools = _kv_attention(
+            bp["cross"], h, positions, start, pools, None, None,
+            jnp.where(table >= 0, table + base, -1), cfg, attn_impl, lora,
+            cross=True)
+    elif kind == "ssm":
+        proj, pools = _ssm(
+            bp["ssm"], h, start, valid, pools,
+            _sequence_entry(table, valid.astype(bool), base, pages, total),
+            cfg, attn_impl)
+    elif kind == "linear":
         proj, pools = _kda(
             bp["linear"], h, start, valid, pools,
             _sequence_entry(table, valid.astype(bool), base, pages, total),
@@ -781,9 +889,9 @@ def _pool_block(bp, x, positions, start, valid, pools, table, layer,  # traced
                 lora)
     if t == 1:
         x = x + proj
-        h = L.rmsnorm(x, bp["ln2"], cfg)
+        h = L.rmsnorm(x, bp["ln2"], cfg, bias=bp.get("ln2_b"))
     else:
-        x, h = L.add_rmsnorm(x, proj, bp["ln2"], cfg)
+        x, h = L.add_rmsnorm(x, proj, bp["ln2"], cfg, bias=bp.get("ln2_b"))
     out, pools = _feed_forward(bp, h, cfg, expert_stack,
                                None if t == 1 else valid,
                                capacity_per_row=t > 1, pools=pools)
@@ -901,6 +1009,43 @@ def _kda(lin, h, start, valid, pools, entry, cfg: DecoderConfig,  # traced
     return L.kda_output(lin, h, o, cfg), pools
 
 
+def _ssm(sp, h, start, valid, pools, entry, cfg: DecoderConfig,  # traced
+         attn_impl: str):
+    """An ssm layer over ``T`` tokens a row: from the state at ``entry`` [B]
+    of the layer's planes (zeros for a row that starts its sequence) to the
+    state after the row's last valid token, written back to the entry. One
+    token is the recurrence's one step in XLA (gather, step, scatter),
+    several the scan (``ops/ssm.py``: "pallas" the kernel ``ssm_scan``,
+    "gather" the scan over positions). An ``entry`` past the planes is a
+    dead row: nothing read, nothing written. The scan's output before its
+    gate goes to ``pools[SSM_MEMORY]`` where the program carries one.
+    Returns (the operator's output [B,T,D], the planes as written)."""
+    from kubeflow_tpu.ops import ssm
+
+    t = h.shape[1]
+    states, tails = (pools[n] for n in SSM_PLANES)
+    fresh = start == 0
+    c, z, delta, bm, cm, tail = L.ssm_inputs(
+        sp, h, cfg, _state_at(tails, entry, fresh), None if t == 1 else valid)
+    args = (L.ssm_decay(sp), sp["d_skip"].astype(jnp.float32),
+            _state_at(states, entry, fresh))
+    if t == 1:
+        y, state = ssm.ssm_step_xla(c[:, 0], delta[:, 0], bm[:, 0], cm[:, 0],
+                                    *args)
+        y = y[:, None]
+    else:
+        y, state = ssm.ssm_scan(
+            c, delta, bm, cm, *args,
+            impl="pallas" if attn_impl == "pallas" else "xla")
+    pools = {**pools,
+             SSM_PLANES[0]: states.at[entry].set(state, mode="drop"),
+             SSM_PLANES[1]: tails.at[entry].set(tail.astype(tails.dtype),
+                                                mode="drop")}
+    if SSM_MEMORY in pools:
+        pools[SSM_MEMORY] = y
+    return L.ssm_output(sp, y, z, cfg), pools
+
+
 def _qkv_rope(a, h, positions, cfg: DecoderConfig, lora=None,  # traced
               window: int = 0):
     """Per-head projections of ``h`` [B,S,D] at ``positions`` [B,S]: (q
@@ -922,7 +1067,8 @@ def _qkv_rope(a, h, positions, cfg: DecoderConfig, lora=None,  # traced
 
 def _kv_attention(a, h, positions, start, pools, pidx, off, ltable,  # traced
                   cfg: DecoderConfig, attn_impl: str, lora,
-                  planes: tuple = ("k", "v"), window: int = 0):
+                  planes: tuple = ("k", "v"), window: int = 0,
+                  cross: bool = False):
     """Per-head K/V over ``T`` tokens a row: project, write the tokens' rows
     at (pidx, off) of the K and V ``planes`` ([B] where ``T`` is 1, else
     [B,T]), attend causally to the pages of ``ltable`` from key 0 of its
@@ -934,17 +1080,32 @@ def _kv_attention(a, h, positions, start, pools, pidx, off, ltable,  # traced
     decode kernels (all rows one call), at several ``paged_chunk_attention``
     (one call a row; it takes neither int8 planes nor packed rows nor a
     call with LoRA, which ``_chunk_in_place`` keeps from it); "gather" ONE
-    masked attention over the gathered pages whatever ``T``. Returns (the
-    block's attention output [B,T,D], the planes as written)."""
+    masked attention over the gathered pages whatever ``T``. Differential
+    attention (``cfg.diff_attention``) is its projections in front and its
+    subtraction, norm and output behind the same calls (``layers.diff_q``:
+    to them it is GQA over paired heads). ``cross``: queries only, over
+    ``planes`` as another layer wrote them; nothing is written here.
+    Returns (the block's attention output [B,T,D], the planes as
+    written)."""
     dt = cfg.activation_dtype
     nk, nv = planes
     t = h.shape[1]
     kv_quant = "ks" in pools
-    q, k, v = _qkv_rope(a, h, positions, cfg, lora, window)
-    if t == 1:
+    if cfg.diff_attention:
+        if lora is not None:
+            raise NotImplementedError("LoRA over differential attention")
+        q = L.diff_q(a, h, cfg)
+        k, v = (None, None) if cross else L.diff_kv(a, h, cfg)
+    else:
+        q, k, v = _qkv_rope(a, h, positions, cfg, lora, window)
+    if t == 1 and not cross:
         k, v = k[:, 0], v[:, 0]
-    rows = {nk: k, nv: v}
-    packed = pools[nk].ndim == 3        # [L*P, pg, KV*Dh]: heads in one row
+    rows = {} if cross else {nk: k, nv: v}
+    as_rows = kept_as_rows(cfg)         # [L*P, pg*KV, Dh]: a head a row
+    # [L*P, pg, KV*Dh]: heads in one row
+    packed = pools[nk].ndim == 3 and not as_rows
+    if as_rows and not cross:           # a token's rows, its heads in order
+        pidx, off = pidx[..., None], _token_rows(off, as_rows)
     if packed:
         rows = {n: r.reshape(*r.shape[:-2], -1) for n, r in rows.items()}
     if kv_quant:
@@ -962,7 +1123,7 @@ def _kv_attention(a, h, positions, start, pools, pidx, off, ltable,  # traced
         attn = jnp.stack([
             paged_chunk_attention(jnp.swapaxes(q[r], 0, 1), pools[nk],
                                   pools[nv], ltable[r], start[r],
-                                  window=window)
+                                  window=window, kv_heads=as_rows)
             for r in range(h.shape[0])])
     else:
         lower = None
@@ -985,10 +1146,13 @@ def _kv_attention(a, h, positions, start, pools, pidx, off, ltable,  # traced
             attn = paged_decode_attention(
                 q, pools[nk], pools[nv], ltable, start,
                 pool_ks=pools.get("ks"), pool_vs=pools.get("vs"),
-                lower=lower)
+                lower=lower, kv_heads=as_rows)
         else:
             ck = paged_gather(pools[nk], ltable)
             cv = paged_gather(pools[nv], ltable)
+            if as_rows:
+                ck = ck.reshape(ck.shape[0], -1, as_rows, ck.shape[-1])
+                cv = cv.reshape(cv.shape[0], -1, as_rows, cv.shape[-1])
             if packed:
                 ck = ck.reshape(*ck.shape[:2], cfg.n_kv_heads, cfg.head_dim)
                 cv = cv.reshape(*cv.shape[:2], cfg.n_kv_heads, cfg.head_dim)
@@ -996,6 +1160,9 @@ def _kv_attention(a, h, positions, start, pools, pidx, off, ltable,  # traced
                 ck = dequantize_kv(ck, paged_gather(pools["ks"], ltable), dt)
                 cv = dequantize_kv(cv, paged_gather(pools["vs"], ltable), dt)
             attn = _decode_attention(q, ck, cv, start, cfg, lower=lower)
+    if cfg.diff_attention:
+        return L.diff_output(a, jnp.swapaxes(attn, 1, 2) if by_row else attn,
+                             cfg), pools
     attn = L.gate_attention(a, h, attn, cfg, heads_axis=1 if by_row else 2)
     proj = jnp.einsum("bhsk,hkd->bsd" if by_row else "bshk,hkd->bsd", attn,
                       a["wo"].astype(dt))
@@ -1053,7 +1220,8 @@ def _latent_attention(a, h, positions, start, pools, pidx, off,  # traced
 
 def _pool_forward(params: Params, cache: dict, tokens: jax.Array,  # traced
                   table: jax.Array, start: jax.Array, valid: jax.Array,
-                  cfg: DecoderConfig, attn_impl: str, lora=None):
+                  cfg: DecoderConfig, attn_impl: str, lora=None,
+                  tail_at: str = "all", wanted=None):
     """``tokens`` [B,T] at positions ``start[b] ..`` of the sequences whose
     pages are ``table`` [B,mpp], through every layer against the pool where
     it lies (``_pool_block``): the ONE builder under the decode step, the
@@ -1067,15 +1235,29 @@ def _pool_forward(params: Params, cache: dict, tokens: jax.Array,  # traced
     copies every layer's slab out and back to write B rows of it. Each
     plane is viewed flat ``[L*P,...]`` (a bitcast), carried beside ``x`` and
     written in place by the block at ``layer*P + page``; the scanned inputs
-    are the layer's weights, its LoRA slice and its index."""
-    t = tokens.shape[1]
+    are the layer's weights, its LoRA slice and its index.
+
+    A stack's STATELESS TAIL (``cfg.stateless_tail``: gated memory units
+    and cross layers, which write nothing) runs behind the layers that keep
+    state, over ``x`` alone: the planes are read where they lie and are no
+    carry of its scans. ``tail_at`` (STATIC) says at which positions: "all",
+    every one, like the layers in front; "last", ONE a row, its last valid
+    one (``x`` and the memory gathered there, a cross layer's one query
+    over the row's pages up to it), and ``x`` comes back ``[B,1,D]``: what
+    a chunk program whose caller reads one position's logits a row needs.
+    ``wanted`` ([B] bool, with "last"): where it names no row the tail is
+    not run at all (one ``lax.cond``; what comes back is then read by
+    nobody)."""
+    b, t = tokens.shape
     x = _embed(params, tokens, cfg)
     positions = start[:, None]
     if t > 1:
         positions = positions + jnp.arange(t, dtype=jnp.int32)[None, :]
-    pg = _pool_geometry(cache)[1]
+    pg = _pool_geometry(cache, cfg)[1]
     num_pages = _pages_by_kind(cache)
     flat = _flat_pools(cache)
+    groups = layer_groups(cfg)
+    tail = [g for g in groups if g[2] >= cfg.n_layers - cfg.stateless_tail]
 
     def block(bp, carry, layer, gcfg, lora_view, expert_stack):
         return _pool_block(
@@ -1083,7 +1265,36 @@ def _pool_forward(params: Params, cache: dict, tokens: jax.Array,  # traced
             num_pages, pg, gcfg, attn_impl=attn_impl, lora=lora_view,
             expert_stack=expert_stack)
 
-    return _scan_layer_groups(params, cfg, (x, flat), block, lora)
+    if not tail:
+        return _scan_layer_groups(params, cfg, (x, flat), block, lora)
+    if MOE_ROWS in flat:
+        raise NotImplementedError(
+            "a stateless tail whose expert layers hold a share")
+    if cfg.layers_of("gmu"):
+        flat[SSM_MEMORY] = jnp.zeros((b, t, cfg.ssm_inner), jnp.float32)
+    x, flat = _scan_layer_groups(params, cfg, (x, flat), block, lora,
+                                 groups[:len(groups) - len(tail)])
+    seen = {n: flat.pop(n) for n in (SSM_MEMORY,) if n in flat}
+    if tail_at == "last" and t > 1:
+        at = jnp.maximum(valid - 1, 0)
+        x, seen = jax.tree.map(
+            lambda a: jnp.take_along_axis(a, at[:, None, None], axis=1),
+            (x, seen))
+        start, valid = start + at, valid > 0
+        positions = start[:, None]
+
+    def tail_block(bp, x, layer, gcfg, lora_view, expert_stack):
+        return _pool_block(
+            bp, x, positions, start, valid, {**flat, **seen}, table, layer,
+            num_pages, pg, gcfg, attn_impl=attn_impl, lora=lora_view,
+            expert_stack=expert_stack)[0]
+
+    def run(x):
+        return _scan_layer_groups(params, cfg, x, tail_block, lora, tail)
+
+    if wanted is None:
+        return run(x), flat
+    return jax.lax.cond(jnp.any(wanted), run, lambda x: x, x), flat
 
 
 def _paged_decode_step(params: Params, cache: dict, tokens: jax.Array,  # traced
@@ -1145,7 +1356,7 @@ def paged_decode_multi(params: Params, cache: dict, tokens: jax.Array,  # traced
 
     b = tokens.shape[0]
     mpp = cache["table"].shape[1]
-    max_len = mpp * _pool_geometry(cache)[1]
+    max_len = mpp * _pool_geometry(cache, cfg)[1]
     out0 = jnp.full((b, num_steps), -1, jnp.int32)
     lr = (None if lora is None
           else {**lora, "aidx": adapter_idx})
@@ -1190,7 +1401,7 @@ def copy_pages(cache: dict, src: jax.Array, dst: jax.Array) -> dict:  # traced
         pool = cache[name]
         npages = pool.shape[1]
         d = jnp.where((dst >= 0) & (dst < npages), dst, npages)
-        if plane_kind(name) == "linear":
+        if plane_kind(name) in SEQUENCE_PLANES:
             # A sequence's entry goes with its first page: to another first
             # page, from one; any other pair copies nothing here.
             d = jnp.where((src >= 0) & (src < npages), d, npages)
@@ -1292,12 +1503,14 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
                                      start, valid_len, cfg, paged_attn_impl,
                                      logits_at, wanted)
     planes = tuple(n for n in _planes_of(cache)
-                   if plane_kind(n) not in ("conv", "linear"))
-    num_pages, pg = _pool_geometry(cache)
+                   if plane_kind(n) not in ("conv", *SEQUENCE_PLANES))
+    num_pages, pg = _pool_geometry(cache, cfg)
     pages_of = _pages_by_kind(cache)
     b, c = tokens.shape
     kv_quant = "ks" in cache
-    packed = "k" in cache and cache["k"].ndim == 4   # [L, P, pg, KV*Dh]
+    # [L, P, pg, KV*Dh]
+    packed = "k" in cache and cache["k"].ndim == 4 \
+        and not kept_as_rows(cfg)
     # Gather each slot's visible cache row, every plane: [L,B,ctx*pg,...]
     # (the bucket covers the chunk's own pages too: the [start, start+C)
     # update-slice window below). A window layer's logical page ``i`` lies
@@ -1319,6 +1532,10 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
     if packed:
         rows = {n: r.reshape(*r.shape[:3], cfg.n_kv_heads, cfg.head_dim)
                 for n, r in rows.items()}
+    as_rows = kept_as_rows(cfg)
+    if as_rows:     # [L,B,ctx*pg*rows,D]: a token's heads, rows in order
+        rows = {n: r.reshape(*r.shape[:2], -1, as_rows, r.shape[-1])
+                for n, r in rows.items()}
     if kv_quant:
         from kubeflow_tpu.ops.quantization import dequantize_kv, quantize_kv
 
@@ -1331,13 +1548,15 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
     if "conv" in cache:
         caches["conv"] = _chunk_state_before(cache["conv"], table_rows,
                                              start, pg)
-    if "linear" in pages_of:
+    entries = {}
+    for kind in set(SEQUENCE_PLANES) & set(pages_of):
         # a row's state where its first page's id says, zeros at a start
-        entry = _sequence_entry(whole_rows, valid_len > 0, 0,
-                                pages_of["linear"], pages_of["linear"])
+        entry = entries[kind] = _sequence_entry(
+            whole_rows, valid_len > 0, 0, pages_of[kind], pages_of[kind])
         caches.update({n: jax.vmap(
-            lambda plane: _state_at(plane, entry, start == 0))(cache[n])
-            for n in LINEAR_PLANES})
+            lambda plane, entry=entry: _state_at(
+                plane, entry, start == 0))(cache[n])
+            for n in SEQUENCE_PLANES[kind]})
     caches["len"] = start
     lr = None if lora is None else {**lora, "aidx": adapter_idx}
     logits, filled, _ = decoder_forward(params, tokens, cfg, kv_caches=caches,
@@ -1365,20 +1584,24 @@ def paged_chunk_prefill(params: Params, cache: dict, tokens: jax.Array,  # trace
                                 w.reshape(*w.shape[:3], -1))
                for n, w in written.items()}
     else:
+        if as_rows:
+            pidx, off = pidx[..., None], _token_rows(off, as_rows)
         out = {n: cache[n].at[:, pidx, off].set(written[n], mode="drop")
                for n in planes if plane_kind(n) == "attention"}
     if "window" in pages_of:
         widx, _ = _chunk_write_index(whole_rows, start, valid_len, c, pg,
                                      pages_of["window"], cfg)
+        if as_rows:
+            widx = widx[..., None]
         out.update({n: cache[n].at[:, widx, off].set(written[n], mode="drop")
                     for n in planes if plane_kind(n) == "window"})
     if "conv" in cache:
         out["conv"] = _chunk_state_after(cache["conv"], filled["conv"],
                                          table_rows, start, valid_len, c, pg)
-    if "linear" in pages_of:
+    for kind, entry in entries.items():
         out.update({n: cache[n].at[:, entry].set(
             filled[n].astype(cache[n].dtype), mode="drop")
-            for n in LINEAR_PLANES})
+            for n in SEQUENCE_PLANES[kind]})
     if MOE_ROWS in cache:
         # The gathered form runs the model's own forward pass, which keeps
         # no sums; its expert layers' rows are counted by what it was given
@@ -1476,18 +1699,21 @@ def _chunk_in_place(cache: dict, cfg: DecoderConfig, lora,
     form: int8 pools (scale planes), packed rows and the conv state beside
     them, a call with LoRA, planes the kernel cannot part by head. A window
     layer's planes are K and V per head like a global layer's and go the
-    same way; a linear layer's planes ride beside them (its operator reads a
-    state a row, not pages)."""
+    same way; a linear or ssm layer's planes ride beside them (its operator
+    reads a state a row, not pages)."""
     if cfg.is_latent:
         return True
     from kubeflow_tpu.ops.paged_attention import chunk_attention_supported
 
-    planes = set(_planes_of(cache)) - set(LINEAR_PLANES)
+    planes = set(_planes_of(cache)) - set(LINEAR_PLANES) - set(SSM_PLANES)
     k = cache[next(n for n in ("k", *WINDOW_PLANES) if n in cache)]
+    rows = kept_as_rows(cfg)    # [L, P, page * rows, D], else [L, P, page, KV, D]
+    heads = (rows, k.shape[-1]) if rows else k.shape[3:]
     return (attn_impl == "pallas" and lora is None
             and planes in ({"k", "v"}, {"k", "v", *WINDOW_PLANES},
-                           set(WINDOW_PLANES)) and k.ndim == 5
-            and chunk_attention_supported(*k.shape[3:], k.dtype))
+                           set(WINDOW_PLANES))
+            and k.ndim == (4 if rows else 5)
+            and chunk_attention_supported(*heads, k.dtype))
 
 
 def chunk_reads_context(cache: dict, cfg: DecoderConfig, lora,
@@ -1532,7 +1758,11 @@ def _paged_chunk_in_place(params: Params, cache: dict,  # traced
     ``valid_len`` positions write; ``table_rows`` holds the pages looked
     at."""
     x, flat = _pool_forward(params, cache, tokens, table_rows, start,
-                            valid_len, cfg, attn_impl)
+                            valid_len, cfg, attn_impl, tail_at=logits_at,
+                            wanted=wanted)
+    if logits_at == "last" and cfg.stateless_tail:
+        # the tail ran at each row's last valid position only: x is [B,1,D]
+        valid_len = jnp.minimum(valid_len, 1)
     logits = _last_logits(params, x, valid_len, cfg, wanted) \
         if logits_at == "last" else _head_logits(params, x, cfg)
     return logits, _pool_planes(flat, cache)

@@ -77,7 +77,8 @@ def lowered_programs(cfg, batching, dev, *, relaid: bool = True,
     """``{program: jax.stages.Lowered}`` of an engine over ``cfg`` and
     ``batching`` on the one described chip ``dev`` (a sharding): "decode"
     (one step a dispatch), "chunk[1]" and, where the engine builds the
-    program over several prompts' rows, "chunk[N]". ``relaid``: the
+    program over several prompts' rows, "chunk[N]" (a stack that ends in a
+    stateless tail: also "rows[1]", the program over rows at one row). ``relaid``: the
     parameters in the engine's formats, else all in the default layout.
     ``auto``: the parameters' layouts left to the compiler, whatever
     ``relaid`` says (``compiled.input_formats`` then says what it chose).
@@ -139,18 +140,23 @@ def lowered_programs(cfg, batching, dev, *, relaid: bool = True,
         params, cache, sds((slots, mpp)), i32(), i32(),
         sds((slots,), jnp.bool_), f32(), i32(), f32(), i32(), i32(),
         sds((2,), jnp.uint32))}
-    rows = [1]
-    if b.max_concurrent_prefills > 1 and chunk_rows_per_weight(
-            cfg_prefill, chunk_tokens) < RIDGE_ROWS:
-        rows.append(b.max_concurrent_prefills)
-    for n in rows:
-        # the engine's program over rows returns the last position's logits
-        # and takes "this row ends its prompt" a row
-        last = n > 1 and rows_logits_at == "last"
-
+    # (key, rows, whether the program is the engine's form over rows: the
+    # last position's logits, "this row ends its prompt" a row)
+    by_rows = rows_logits_at == "last"
+    forms = [("chunk[1]", 1, False)]
+    tail = cfg_prefill.stateless_tail > 0
+    if tail and by_rows:
+        # a stack that ends in a stateless tail sends a prefill alone
+        # through the program over rows as a group of one (serve/engine.py)
+        forms.append(("rows[1]", 1, True))
+    if b.max_concurrent_prefills > 1 and (tail or chunk_rows_per_weight(
+            cfg_prefill, chunk_tokens) < RIDGE_ROWS):
+        n = b.max_concurrent_prefills
+        forms.append((f"chunk[{n}]", n, by_rows))
+    for key, n, last in forms:
         # (a lambda, as the engine's: the lowered module's name is part of
         # the digests tests/test_chip_compile.py pins)
-        out[f"chunk[{n}]"] = jit(
+        out[key] = jit(
             lambda p, c, t, tr, st, vl, ends=None, at="last" if last
             else "all": paged_chunk_prefill(
                 p, c, t, tr, st, vl, cfg_prefill, context_pages=mpp,

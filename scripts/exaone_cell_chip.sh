@@ -24,7 +24,7 @@ while [ $# -ge 2 ]; do
   (cd ${DIR:-.} && python3 -m benchmark.run --workload $cell \
     --seed $seed --seconds ${SECONDS_:-51} --trace $trace > $out.json 2> $out.log)
   echo "rc=$? seed=$seed trace=$trace took=$(( $(date +%s) - t0 ))s $(tail -c 2600 $out.json)"
-  grep -E "compared|requests:|serve_tokens|setup_s|engine built|NO RESULT|Error|metric |tail:" $out.log | tail -n 30
+  grep -E "compared|requests:|serve_tokens|setup_s|engine built|correctness done|NO RESULT|Error|metric |tail:" $out.log | tail -n 30
   tail -n 400 $out.log > $out.err; rm -f $out.log
   # the traced tail's modules and its forty heaviest ops
   cp ${DIR:-.}/benchmark_out/$cell/trace_summary.json \

@@ -38,6 +38,29 @@ try:
     jax.config.update("jax_platforms", "cpu")
 except ImportError:
     pass
+else:
+    # JAX writes a cache entry IN PLACE (``Path.write_bytes``) and takes no
+    # lock where eviction is off, and a reader takes a file that exists for a
+    # whole one: with several workers compiling the same program at once, one
+    # reads another's half-written executable and its deserialisation takes
+    # the worker down (a segmentation fault under
+    # ``compilation_cache.get_executable_and_time``: three whole runs of PR
+    # 47's tree lost a worker and a test each to it, in an engine's
+    # ``_warm_decode_ladder`` twice). For the suite's runs an entry is written
+    # beside its place and moved into it, which a reader sees whole or not at
+    # all. (The program's own processes are one a chip and do not race.)
+    from jax._src import lru_cache as _lru
+
+    def _put_whole(self, key, val, _put=_lru.LRUCache.put):
+        if self.eviction_enabled or not key:    # locked, or refused there
+            return _put(self, key, val)
+        path = self.path / f"{key}{_lru._CACHE_SUFFIX}"
+        if not path.exists():
+            beside = self.path / f".{os.getpid()}.{key}.part"
+            beside.write_bytes(val)
+            os.replace(beside, path)
+
+    _lru.LRUCache.put = _put_whole
 
 import pytest  # noqa: E402
 
@@ -150,6 +173,33 @@ LONGDOC_STATED = {
 }
 
 
+# PR 47's cell (phi-4-mini-flash.batch-reasoning), the same way: its
+# rehearsal cell (the engine refuses prefix reuse over ssm layers), the
+# counters its readers take, at rest, and each metric's number for a window
+# without samples (the pool's shares are constants of the engine as built).
+REASONING_CELLS = {
+    "tiny-phi4flash.rehearsal-closed-ssm": (
+        "phi-4-mini-flash.batch-reasoning", "rehearsal-tiny-phi4flash",
+        "rehearsal-closed-ssm", 1),
+}
+REASONING_ENGINE_COUNTERS = {
+    "prefill_programs_with_end": 0, "kv_layers_sharing": 0}
+REASONING_STATED = {
+    "step.decode_weight_bw_share.reasoning": 0.0,
+    "step.prefill_mfu.reasoning": 0.0,
+    "kernel.ssm_scan_roofline_share.reasoning": 0.0,
+    "kernel.paged_decode_attention_bw_share.reasoning": 0.0,
+    "kernel.paged_window_decode_attention_bw_share.reasoning": 0.0,
+    "kernel.paged_chunk_attention_mfu.reasoning": 0.0,
+    "kv.state_share_of_pool.reasoning": 12.5,     # 32768 of 262144 bytes
+    "kv.window_share_of_pool.reasoning": 25.0,    # 65536 of 262144 bytes
+    "step.tail_program_share.reasoning": 0.0,
+    "engine.decode_occupancy.reasoning": 0.0,
+    "kv.preemptions.reasoning": 0.0,
+    "engine.sched_busy_share_window.reasoning": 0.0,
+}
+
+
 @pytest.fixture(autouse=True, scope="session")
 def benchmark_suite_tables_know_the_longanswer_cell(request):
     suite = next(
@@ -166,13 +216,15 @@ def benchmark_suite_tables_know_the_longanswer_cell(request):
     # stood reads ADDED_STATED) and the tables they are copied into.
     for tables, added in (
             ((suite.ADDED_CELLS, rehearsal.CELLS),
-             {**LONGANSWER_CELLS, **MIXEDLENGTH_CELLS, **LONGDOC_CELLS}),
+             {**LONGANSWER_CELLS, **MIXEDLENGTH_CELLS, **LONGDOC_CELLS,
+              **REASONING_CELLS}),
             ((suite.ADDED_ENGINE_COUNTERS, readers.ENGINE0),
              {**LONGANSWER_ENGINE_COUNTERS, **WINDOW_ENGINE_COUNTERS,
-              **MIXEDLENGTH_ENGINE_COUNTERS, **LONGDOC_ENGINE_COUNTERS}),
+              **MIXEDLENGTH_ENGINE_COUNTERS, **LONGDOC_ENGINE_COUNTERS,
+              **REASONING_ENGINE_COUNTERS}),
             ((suite.ADDED_STATED, total.STATED),
              {**LONGANSWER_STATED, **WINDOW_STATED, **MIXEDLENGTH_STATED,
-              **LONGDOC_STATED})):
+              **LONGDOC_STATED, **REASONING_STATED})):
         for table in tables:
             for key, value in added.items():
                 table.setdefault(key, value)
@@ -189,7 +241,9 @@ def benchmark_suite_tables_know_the_longanswer_cell(request):
 # reads the manifest whole. PR 40 appends a configuration, a cell and ten
 # per-layer metrics behind all of those, and PR 43 a configuration, a cell
 # and twelve behind PR 40's: the pinning tests are handed the manifest
-# without them too.
+# without them too. PR 47 appends a configuration, a cell and twelve behind
+# PR 43's, whose own test pins ITS entries at the end the same way.
+PINS_PR43_AT_THE_END = "test_what_pr_43_added_is_listed_with_the_benchmark"
 PINS_PR28_AT_THE_END = "test_what_this_pr_added_is_listed_with_the_benchmark"
 PINS_PR35S_CELL = \
     "test_what_this_pr_added_is_listed_with_the_benchmark_at_the_end"
@@ -223,8 +277,10 @@ def the_manifest_as_it_stood_for_the_tests_that_pin_a_pr(request,
                                                          monkeypatch):
     name = request.node.name
     module = request.node.module
-    later = set(WINDOW_STATED) | set(MIXEDLENGTH_STATED) | set(LONGDOC_STATED)
+    later = set(WINDOW_STATED) | set(MIXEDLENGTH_STATED) \
+        | set(LONGDOC_STATED) | set(REASONING_STATED)
     mixed = next(iter(MIXEDLENGTH_CELLS.values()))[0]
+    reasoning = next(iter(REASONING_CELLS.values()))[0]
     if (module.__name__, request.node.originalname) == PINS_PR35S_LINE:
         whole = module.rehearsal_manifest
         monkeypatch.setattr(module, "rehearsal_manifest", lambda: {
@@ -234,9 +290,12 @@ def the_manifest_as_it_stood_for_the_tests_that_pin_a_pr(request,
     if name == TAKES_SIZE_FOR_A_WIDTH:
         monkeypatch.setattr(module, "re", _VocabRowsAreNoWidth())
         return
-    if name not in (PINS_PR28_AT_THE_END, PINS_PR35S_CELL):
+    if name not in (PINS_PR28_AT_THE_END, PINS_PR35S_CELL,
+                    PINS_PR43_AT_THE_END):
         return
-    cells = {mixed, next(iter(LONGDOC_CELLS.values()))[0]}
+    cells = {mixed, next(iter(LONGDOC_CELLS.values()))[0], reasoning}
+    if name == PINS_PR43_AT_THE_END:
+        later, cells = set(REASONING_STATED), {reasoning}
     if name == PINS_PR28_AT_THE_END:
         later |= set(LONGANSWER_STATED)
         cells.add(next(iter(LONGANSWER_CELLS.values()))[0])

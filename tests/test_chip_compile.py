@@ -743,3 +743,62 @@ def test_longdoc_program_compiles_for_v5e_with_its_kernels(cell_programs,
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 11e9
     assert mem.temp_size_in_bytes < 0.4e9      # 0.363 GB, two rows (0.65 before PR 44)
+
+
+def test_ssm_scan_compiles_for_v5e(chip):
+    """``ops/ssm.py`` at Phi-4-mini-flash's widths (5120 channels of 16
+    states, two rows of 512 positions): ONE Mosaic call named ``ssm_scan``
+    (the two rows' ``B`` and ``C``, 128 KB of float32 scalars, fit its scalar
+    memory), the state's sixteen registers a block of 1024 channels."""
+    from kubeflow_tpu.ops import ssm
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=chip)
+
+    b, t, e, n = 2, 512, 5120, 16
+    scan = jax.jit(lambda x, dt, bm, cm, a, d, h: ssm.ssm_scan(
+        x, dt, bm, cm, a, d, h, impl="pallas", interpret=False)).lower(
+        sds(b, t, e), sds(b, t, e), sds(b, t, n), sds(b, t, n), sds(n, e),
+        sds(e), sds(b, n, e)).compile()
+    text = scan.as_text()
+    assert "tpu_custom_call" in text and _calls(text, "ssm_scan") == 1
+    assert scan.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+REASONING = "phi-4-mini-flash.batch-reasoning"
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk[2]"])
+def test_reasoning_program_compiles_for_v5e_with_its_kernels(cell_programs,
+                                                             program):
+    """The reasoning cell's decode step and its two-row program over rows at
+    the cell's real sizes (all 32 layers, the whole vocabulary): each fits
+    the chip beside its arguments; the decode step attends through the
+    global kernel at two call sites (the full layer, the cross layers' scan)
+    and the window kernel over planes KEPT AS ROWS (a ``[page, 10, 128]``
+    page is padded to 16 heads in device memory and the kernels' copy engine
+    refuses a page of it: this compile is what said so); the chunk program
+    runs one ``ssm_scan`` a Mamba layer's scan site, the chunk kernels and,
+    for the tail at one position a row, the decode kernel; neither copies a
+    weight but the Mamba layers' narrow ``wx`` (192 columns, 2 MB a layer):
+    differential attention's q, k and v matrices lie OUT by IN, as the
+    compiler wants a projection whose result is parted into heads."""
+    from scripts.aot_weight_copies import weight_copies
+
+    lowered = cell_programs(REASONING)[program]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    decode = program == "decode"
+    for kernel in (("paged_decode_attention", "paged_window_decode_attention")
+                   if decode else
+                   ("ssm_scan", "paged_chunk_attention",
+                    "paged_window_chunk_attention",
+                    "paged_decode_attention")):
+        assert kernel in text, kernel
+    copied = {leaf for c in weight_copies(text, lowered.args_info[0][0])
+              for leaf in c["leaf"]}
+    assert copied <= {"['layers']['ssm']['wx']",
+                      "['layers_rest']['ssm']['wx']"}, copied
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 11.5e9
+    assert mem.temp_size_in_bytes < 0.5e9

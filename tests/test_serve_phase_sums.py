@@ -280,31 +280,48 @@ def test_a_childs_seconds_are_taken_out_of_its_parent(engine, monkeypatch):
 def test_under_a_capture_the_sums_are_the_spans_innermost_segments(
         engine, tmp_path):
     """One boundary, two sinks: over a captured stretch each phase's sum is
-    what ``innermost_segments`` cuts out of the recorded spans for it."""
-    d = str(tmp_path / "t")
-    profiler.start(d)
-    before = engine.counters()
-    reqs = [engine.submit(list(range(1, 60 + 5 * i)),
-                          SamplingParams(max_new_tokens=25))
-            for i in range(6)]
-    while not all(r.done.is_set() for r in reqs):
-        engine.step()
-    after = engine.counters()
-    profiler.stop()
-    sched = hostspans.thread_with(load_spans(d), hostspans.ENGINE_THREAD)
-    by_name: dict = {}
-    for t0, t1, name in hostspans.innermost_segments(
-            [s for s in sched if s[0] != profiler.ANCHOR]):
-        by_name[name] = by_name.get(name, 0.0) + (t1 - t0)
-    moved = delta(before, after)
-    for name in profiler.ENGINE_PHASES:
-        if name in (profiler.ENGINE_IDLE, profiler.ENGINE_KVTIER_TICK):
-            continue                # ``_loop``'s; no host tier on this engine
-        summed = moved[f"sched_{name.rpartition('.')[2]}_sum_s"]
-        spanned = by_name[name]
-        assert summed > 0.0
-        assert abs(summed - spanned) <= max(0.05 * spanned, 1e-3), \
-            (name, summed, spanned)
+    what ``innermost_segments`` cuts out of the recorded spans for it.
+
+    The two sinks read the clock one after the other at a boundary (the
+    phase clock's ``tick``, then the span's own stamp), so a worker the
+    machine takes off its core between the two reads puts the whole stall
+    into one sink: under six busy workers that is a millisecond now and then
+    (the driver's run of PR 46: one phase off by more than 5% / 1 ms; alone
+    and beside its file the test passed every time). A stall is one
+    capture's; a sum that does not follow its spans is every capture's: the
+    stretch is captured a second time where the first is off, and has to
+    agree then."""
+    def captured(at: str):
+        profiler.start(at)
+        before = engine.counters()
+        reqs = [engine.submit(list(range(1, 60 + 5 * i)),
+                              SamplingParams(max_new_tokens=25))
+                for i in range(6)]
+        while not all(r.done.is_set() for r in reqs):
+            engine.step()
+        after = engine.counters()
+        profiler.stop()
+        sched = hostspans.thread_with(load_spans(at), hostspans.ENGINE_THREAD)
+        by_name: dict = {}
+        for t0, t1, name in hostspans.innermost_segments(
+                [s for s in sched if s[0] != profiler.ANCHOR]):
+            by_name[name] = by_name.get(name, 0.0) + (t1 - t0)
+        return delta(before, after), sched, by_name
+
+    for attempt in ("t", "again"):
+        moved, sched, by_name = captured(str(tmp_path / attempt))
+        off = {}
+        for name in profiler.ENGINE_PHASES:
+            if name in (profiler.ENGINE_IDLE, profiler.ENGINE_KVTIER_TICK):
+                continue            # ``_loop``'s; no host tier on this engine
+            summed = moved[f"sched_{name.rpartition('.')[2]}_sum_s"]
+            spanned = by_name[name]
+            assert summed > 0.0
+            if abs(summed - spanned) > max(0.05 * spanned, 1e-3):
+                off[name] = (summed, spanned)
+        if not off:
+            break
+    assert not off, off
     # what the sync sent and what the round handed on, on their spans
     syncs = [s[3] for s in sched if s[0] == profiler.ENGINE_SYNC_STATE]
     assert sum(a["slots"] for a in syncs) == moved["state_slot_syncs"]
