@@ -10,9 +10,11 @@ once, and the online softmax accumulates across pages in VMEM — the TPU form
 of vLLM's PagedAttention (same role as the public jax pallas paged kernels;
 written against this repo's pool/table layout and GQA grouping).
 
-The decode kernel over per-head pools WALKS A ROW'S LIVE PAGES ITSELF (PR
-45). Grid ``(rows,)``, in order; the pools stay in HBM and the page table,
-the lengths and a window layer's lower bounds ride in as scalar prefetch.
+Every decode kernel WALKS A ROW'S LIVE PAGES ITSELF (PR 45: per-head pools,
+described here; PR 48: latent pools and packed rows, ``_rows_decode_kernel``,
+the same walk over planes of whole-token rows). Grid ``(rows,)``, in order;
+the pools stay in HBM and the page table, the lengths and a window layer's
+lower bounds ride in as scalar prefetch.
 Inside a row a loop runs over the pages its context holds and no other, from
 the page of ``lower[b]`` (0 without it) to the page of ``lengths[b]``,
 ``DECODE_PAGES_PER_TURN`` a turn: a turn waits for its K and V pages in one
@@ -57,16 +59,19 @@ from kubeflow_tpu.ops.attention import NEG_INF
 
 
 # Most pages of a plane a turn of the decode walk copies and attends to at
-# once; fewer where both planes' double buffers would take over half of
-# ``VMEM_BUDGET_BYTES`` (``_pages_a_turn``), or the table row is shorter.
+# once over TWO planes (K and V); fewer where the planes' double buffers
+# would take over half of ``VMEM_BUDGET_BYTES`` (``_pages_a_turn``), or the
+# table row is shorter. A walk over ONE plane (a latent pool) takes twice as
+# many, the same eight copies in flight a turn: four of its 164 KB pages a
+# turn read 76% of the bus on a v5e, eight 90%, sixteen 90% (PERF.md, PR 48).
 DECODE_PAGES_PER_TURN = 4
 
 
-def _pages_a_turn(page_bytes: int, mpp: int) -> int:
-    """Pages of EACH plane a turn holds: two halves of two planes of them
+def _pages_a_turn(page_bytes: int, mpp: int, planes: int = 2) -> int:
+    """Pages of EACH of ``planes`` planes a turn holds: two halves of them
     lie in fast memory beside the scores."""
-    fit = VMEM_BUDGET_BYTES // 2 // (4 * page_bytes)
-    return max(1, min(DECODE_PAGES_PER_TURN, fit, mpp))
+    fit = VMEM_BUDGET_BYTES // 2 // (2 * planes * page_bytes)
+    return max(1, min(DECODE_PAGES_PER_TURN * 2 // planes, fit, mpp))
 
 
 def _walk_live_pages(table_ref, span, planes, sem, side_ref, attend):
@@ -150,6 +155,22 @@ def _walk_live_pages(table_ref, span, planes, sem, side_ref, attend):
     side_ref[0] = jax.lax.rem(side + turns, 2)
 
 
+def _turn_seen(shape, pg: int, j0, mapped, length, lowest=None):
+    """Which keys of a turn a row attends to, ``shape`` ``[.., n * pg]``:
+    positions from the turn's first page ``j0`` on, up to ``length`` and,
+    with a bound, from ``lowest``; none of a page never copied, whatever
+    its buffer holds."""
+    at = len(shape) - 1
+    kv_page = jax.lax.broadcasted_iota(jnp.int32, shape, at) // pg
+    kv_pos = j0 * pg + jax.lax.broadcasted_iota(jnp.int32, shape, at)
+    seen = kv_pos <= length
+    if lowest is not None:
+        seen = jnp.logical_and(seen, kv_pos >= lowest)
+    for i, m in enumerate(mapped):
+        seen = jnp.logical_and(seen, jnp.logical_or(m, kv_page != i))
+    return seen
+
+
 def _decode_kernel(table_ref, len_ref, *rest, page_size: int,
                    sm_scale: float, quantized: bool, bounded: bool,
                    strided: bool):
@@ -219,14 +240,8 @@ def _decode_kernel(table_ref, len_ref, *rest, page_size: int,
                     s = s * bufs[2][half, i][:, None, :]     # its scores
                 s_ref[:, :, at[i]] = s
 
-        kv_page = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n * pg), 2) // pg
-        kv_pos = j0 * pg + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, n * pg), 2)
-        seen = kv_pos <= length
-        if bounded:
-            seen = jnp.logical_and(seen, kv_pos >= lowest)
-        for i, m in enumerate(mapped):  # what a page never copied holds
-            seen = jnp.logical_and(seen, jnp.logical_or(m, kv_page != i))
+        seen = _turn_seen((1, 1, n * pg), pg, j0, mapped, length,
+                          lowest if bounded else None)
         s = jnp.where(seen, s_ref[:] * sm_scale, NEG_INF)     # [KV, g, n*pg]
         m_prev = m_ref[:]                                # [KV, g, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
@@ -406,26 +421,42 @@ def _decode_attention_call(q, pool_k, pool_v, table, lengths, pool_ks,
 # row, so a head's score is ONE product with the row) and expands the
 # attended row afterwards (``layers.latent_output``), so a kernel is
 # multi-query attention of H heads against one shared row that is also the
-# value, and no per-head K or V of the context ever exists. Two kernels on a
-# grid ``(rows, pages)``, a page a step through its index map (the schedule
-# the per-head decode kernel had before PR 45; ``_walk_live_pages`` is
-# written to take them: ROADMAP Speed 2), blockwise softmax, float32
-# accumulation: one query a slot (decode), and a chunk of
-# queries of one slot (chunk prefill).
+# value, and no per-head K or V of the context ever exists. Two kernels,
+# blockwise softmax, float32 accumulation. One query a slot (decode) takes
+# the decode walk (``_rows_decode_kernel``: grid ``(rows,)``, a row's live
+# pages by ``_walk_live_pages``' copies, eight a turn). A chunk of queries of
+# one slot (chunk prefill) is a grid ``(heads, blocks)``, four pages a step
+# through their index maps.
 
-def _online_softmax_step(s, rows, m_ref, l_ref, acc_ref, at=slice(None)):
+def _online_softmax_step(s, rows, m_ref, l_ref, acc_ref, at=slice(None),
+                         kept=None):
     """One block of the running softmax: ``s`` [Q, T] masked scores (f32),
     ``rows`` [T, W] the block's values (a latent block's cache rows are
-    both); ``at``: the state's rows these queries own."""
+    both); ``at``: the state's rows these queries own. ``kept`` (the decode
+    walk's): a flag a page of ``rows``, then a buffer [n, T / n, W] read a
+    page at a time; a page whose flag is False holds ANYTHING (NaN too, and
+    ``0 x NaN`` is NaN), so its product is dropped whole."""
     m_prev = m_ref[at]                               # [Q, 1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_new)                           # [Q, T]
     alpha = jnp.exp(m_prev - m_new)
     l_ref[at] = alpha * l_ref[at] + jnp.sum(p, axis=1, keepdims=True)
-    # Probabilities go to the MXU in the pool's type, as in the XLA form.
-    acc_ref[at] = acc_ref[at] * alpha + jax.lax.dot_general(
-        p.astype(rows.dtype), rows, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)          # [Q, W]
+    acc = acc_ref[at] * alpha
+
+    def weighted(p, rows):
+        # Probabilities go to the MXU in the pool's type, as in the XLA form.
+        return jax.lax.dot_general(
+            p.astype(rows.dtype), rows, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)      # [Q, W]
+
+    if kept is None:
+        attended = weighted(p, rows)
+    else:
+        t = rows.shape[1]
+        attended = functools.reduce(jnp.add, [
+            jnp.where(k, weighted(p[:, i * t:(i + 1) * t], rows[i]), 0)
+            for i, k in enumerate(kept)])
+    acc_ref[at] = acc + attended
     m_ref[at] = m_new
 
 
@@ -441,29 +472,92 @@ def _softmax_result(l_ref, acc_ref, dtype):
     return (acc_ref[:] / safe).astype(dtype)
 
 
-def _latent_decode_kernel(table_ref, len_ref, q_ref, page_ref, o_ref,
-                          m_ref, l_ref, acc_ref, *, page_size: int,
-                          sm_scale: float, num_pages_per_slot: int):
+def _rows_decode_kernel(table_ref, len_ref, q_ref, *rest, page_size: int,
+                        sm_scale: float, num_planes: int):
+    """One table row of decode attention over planes of whole-token rows
+    ``[P, page, W]``: ONE plane whose rows are keys and values both (a
+    latent pool), or a plane of K rows and a plane of V rows (packed
+    heads). The row's ``[H, W]`` queries against a turn's ``n * page`` keys
+    in one product; a page's values in a product of their own, whose result
+    a page never copied does not reach (its buffer holds ANYTHING, and
+    ``0 x NaN`` is NaN)."""
+    pools, o_ref = rest[:num_planes], rest[num_planes]
+    bufs = rest[num_planes + 1:2 * num_planes + 1]
+    sem, side_ref, m_ref, l_ref, acc_ref = rest[2 * num_planes + 1:]
+    k_buf, v_buf = bufs[0], bufs[-1]
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    pl.when(j == 0)(lambda: _softmax_init(m_ref, l_ref, acc_ref))
+    pg = page_size
+    n, w = k_buf.shape[1], k_buf.shape[3]
+    mpp = table_ref.shape[1]
     length = len_ref[b]                 # position being decoded (inclusive)
-    needed = jnp.logical_and(j * page_size <= length, table_ref[b, j] >= 0)
 
-    @pl.when(needed)
-    def _compute():
-        rows = page_ref[0]                           # [pg, W]
+    def span(r):
+        """Row ``r``'s live pages: up to the page of its length (a length
+        below 0: none)."""
+        return 0, jnp.clip(jax.lax.div(len_ref[r] + pg, pg), 0, mpp)
+
+    _softmax_init(m_ref, l_ref, acc_ref)
+
+    def attend(half, j0, mapped):
         s = jax.lax.dot_general(
-            q_ref[0], rows, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)      # [H, pg]
-        kv_pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        s = jnp.where(kv_pos <= length, s * sm_scale, NEG_INF)
-        _online_softmax_step(s, rows, m_ref, l_ref, acc_ref)
+            q_ref[0], k_buf[half].reshape(n * pg, w),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)      # [H, n*pg]
+        seen = _turn_seen((1, n * pg), pg, j0, mapped, length)
+        s = jnp.where(seen, s * sm_scale, NEG_INF)
+        _online_softmax_step(s, v_buf.at[half], m_ref, l_ref, acc_ref,
+                             kept=mapped)
 
-    @pl.when(j == num_pages_per_slot - 1)
-    def _finalize():
-        o_ref[0] = _softmax_result(l_ref, acc_ref, o_ref.dtype)
+    _walk_live_pages(table_ref, span, list(zip(pools, bufs)), sem, side_ref,
+                     attend)
+    # A row that attended to nothing (a dead slot: every id -1) keeps l ==
+    # 0 and emits zeros; the host discards them anyway.
+    o_ref[0] = _softmax_result(l_ref, acc_ref, o_ref.dtype)
+
+
+# Traced ONCE for each set of shapes and inlined wherever it is called: the
+# programs of a decode ladder (serve/pacing.py) and a stack's layers attend
+# through the same call.
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret", "name"),
+                   inline=True)
+def _rows_decode_call(q, planes, table, lengths, *, sm_scale: float,
+                      interpret: bool, name: str):
+    b, h, w = q.shape
+    page = planes[0].shape[1]
+    n = _pages_a_turn(page * w * planes[0].dtype.itemsize, table.shape[1],
+                      len(planes))
+    kernel = functools.partial(
+        _rows_decode_kernel, page_size=page, sm_scale=sm_scale,
+        num_planes=len(planes))
+
+    def row(bi, *_):
+        return (bi, 0, 0)
+
+    return pl.pallas_call(
+        kernel,
+        name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[pl.BlockSpec((1, h, w), row)]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(planes),
+            out_specs=pl.BlockSpec((1, h, w), row),
+            scratch_shapes=[
+                pltpu.VMEM((2, n, page, w), plane.dtype)
+                for plane in planes] + [
+                pltpu.SemaphoreType.DMA((2,)),      # one a half
+                pltpu.SMEM((1,), jnp.int32),        # the half a row starts in
+                pltpu.VMEM((h, 1), jnp.float32),    # running max m
+                pltpu.VMEM((h, 1), jnp.float32),    # running denom l
+                pltpu.VMEM((h, w), jnp.float32),    # row accumulator
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, w), q.dtype),
+        # rows in order: a row's last turn starts the next row's copies
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(table, lengths, q, *planes)
 
 
 def paged_latent_decode_attention(
@@ -477,75 +571,23 @@ def paged_latent_decode_attention(
 ) -> jax.Array:
     """Absorbed decode attention over a latent page pool; returns the
     attended row [B, H, W] (the caller expands it: layers.latent_output).
-    Every page is read once, for all heads."""
-    b, h, w = q.shape
-    page = pool.shape[1]
-    mpp = table.shape[1]
-    kernel = functools.partial(
-        _latent_decode_kernel, page_size=page, sm_scale=sm_scale,
-        num_pages_per_slot=mpp)
-
-    def q_map(bi, ji, table_ref, len_ref):
-        return (bi, 0, 0)
-
-    def page_map(bi, ji, table_ref, len_ref):
-        # Unmapped pages clamp to page 0: the DMA happens but the compute
-        # predicate never reads it.
-        return (jnp.maximum(table_ref[bi, ji], 0), 0, 0)
-
-    return pl.pallas_call(
-        kernel,
-        name="paged_latent_decode_attention",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, mpp),
-            in_specs=[pl.BlockSpec((1, h, w), q_map),
-                      pl.BlockSpec((1, page, w), page_map)],
-            out_specs=pl.BlockSpec((1, h, w), q_map),
-            scratch_shapes=[
-                pltpu.VMEM((h, 1), jnp.float32),   # running max m
-                pltpu.VMEM((h, 1), jnp.float32),   # running denom l
-                pltpu.VMEM((h, w), jnp.float32),   # row accumulator
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, h, w), q.dtype),
+    Every page a context holds is read once, for all heads, and no other."""
+    return _rows_decode_call(
+        q, (pool,), table, lengths, sm_scale=sm_scale,
         interpret=interpret if interpret is not None else auto_interpret(),
-    )(table, lengths, q, pool)
+        name="paged_latent_decode_attention")
 
 
 # -- packed K/V rows (heads narrower than the lanes) -----------------------------
 #
 # Where a head is narrower than the 128-value lanes (64), the pool holds all
 # of a token's KV heads side by side in ONE row a plane, ``[P, page, KV*D]``
-# (serve/paged.py::pool_planes). Attention over it is the latent kernel's
-# schedule with the values in a plane of their own: every query head is laid
-# out like a row, its D values in its KV head's place and zeros elsewhere, so
-# a head's score is one product with the K row (the zeros add nothing), and
-# the attended V row holds the head's output in that same place.
-
-def _packed_decode_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                          m_ref, l_ref, acc_ref, *, page_size: int,
-                          sm_scale: float, num_pages_per_slot: int):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    pl.when(j == 0)(lambda: _softmax_init(m_ref, l_ref, acc_ref))
-    length = len_ref[b]                 # position being decoded (inclusive)
-    needed = jnp.logical_and(j * page_size <= length, table_ref[b, j] >= 0)
-
-    @pl.when(needed)
-    def _compute():
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)      # [H, pg]
-        kv_pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        s = jnp.where(kv_pos <= length, s * sm_scale, NEG_INF)
-        _online_softmax_step(s, v_ref[0], m_ref, l_ref, acc_ref)
-
-    @pl.when(j == num_pages_per_slot - 1)
-    def _finalize():
-        o_ref[0] = _softmax_result(l_ref, acc_ref, o_ref.dtype)
-
+# (serve/paged.py::pool_planes). Attention over it is the latent decode
+# kernel (``_rows_decode_kernel``) with the values in a plane of their own:
+# every query head is laid out like a row, its D values in its KV head's
+# place and zeros elsewhere, so a head's score is one product with the K row
+# (the zeros add nothing), and the attended V row holds the head's output in
+# that same place.
 
 def paged_packed_decode_attention(
     q: jax.Array,                 # [B, 1, H, D] — one decode token per slot
@@ -558,44 +600,16 @@ def paged_packed_decode_attention(
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Exact decode attention over packed K/V rows; returns [B, 1, H, D].
-    Every page of each plane is read once, for all heads."""
+    Every page of each plane a context holds is read once, for all heads."""
     b, _, h, d = q.shape
-    page, w = pool_k.shape[1:]
+    w = pool_k.shape[2]
     kv, g = num_kv_heads, h // num_kv_heads
-    mpp = table.shape[1]
     place = jnp.eye(kv, dtype=q.dtype)[None, :, None, :, None]
     rows = (q.reshape(b, kv, g, 1, d) * place).reshape(b, h, w)
-    kernel = functools.partial(
-        _packed_decode_kernel, page_size=page, sm_scale=d ** -0.5,
-        num_pages_per_slot=mpp)
-
-    def q_map(bi, ji, table_ref, len_ref):
-        return (bi, 0, 0)
-
-    def page_map(bi, ji, table_ref, len_ref):
-        # Unmapped pages clamp to page 0: the DMA happens but the compute
-        # predicate never reads it.
-        return (jnp.maximum(table_ref[bi, ji], 0), 0, 0)
-
-    out = pl.pallas_call(
-        kernel,
-        name="paged_packed_decode_attention",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, mpp),
-            in_specs=[pl.BlockSpec((1, h, w), q_map),
-                      pl.BlockSpec((1, page, w), page_map),
-                      pl.BlockSpec((1, page, w), page_map)],
-            out_specs=pl.BlockSpec((1, h, w), q_map),
-            scratch_shapes=[
-                pltpu.VMEM((h, 1), jnp.float32),   # running max m
-                pltpu.VMEM((h, 1), jnp.float32),   # running denom l
-                pltpu.VMEM((h, w), jnp.float32),   # row accumulator
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, h, w), q.dtype),
+    out = _rows_decode_call(
+        rows, (pool_k, pool_v), table, lengths, sm_scale=d ** -0.5,
         interpret=interpret if interpret is not None else auto_interpret(),
-    )(table, lengths, rows, pool_k, pool_v)
+        name="paged_packed_decode_attention")
     # A head's output lies in its KV head's place of the attended row.
     own = out.reshape(b, kv, g, kv, d) * place
     return own.sum(axis=3).reshape(b, 1, h, d)

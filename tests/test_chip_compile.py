@@ -223,30 +223,32 @@ def test_decode_walk_compiles_for_v5e(chip, shape):
 def test_latent_kernels_compile_for_v5e(chip):
     """20 heads against one shared 640-wide row (512 latent + 64 rotary +
     padding), over the benchmark cell's pool viewed flat (7 layers x 2080
-    pages of 128): the decode kernel at 16 slots of 130 pages, the chunk
-    kernel at 512 queries over a slot's 130 pages."""
-    from kubeflow_tpu.ops.paged_attention import (
-        paged_latent_chunk_attention, paged_latent_decode_attention,
-    )
+    pages of 128): the decode kernel at 16 slots of 130 pages (PR 48: the
+    walk over a row's live pages, eight of the one plane's 164 KB pages a
+    turn in a double buffer, the pool left in HBM where it lies: the call
+    holds nothing beyond its arguments and its result), the chunk kernel at
+    512 queries over a slot's 130 pages."""
+    from kubeflow_tpu.ops import paged_attention as pa
 
     slots, h, w, pages, mpp, chunk = 16, 20, 640, 7 * 2080, 130, 512
 
     def sds(shape, dt=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
 
+    assert pa._pages_a_turn(PAGE * w * 2, mpp, 1) == 8
     pool = sds((pages, PAGE, w))
-    decode = jax.jit(lambda *a: paged_latent_decode_attention(
+    decode = jax.jit(lambda *a: pa.paged_latent_decode_attention(
         *a, sm_scale=256 ** -0.5, interpret=False)).lower(
             sds((slots, h, w)), pool, sds((slots, mpp), jnp.int32),
             sds((slots,), jnp.int32)).compile()
-    prefill = jax.jit(lambda *a: paged_latent_chunk_attention(
+    prefill = jax.jit(lambda *a: pa.paged_latent_chunk_attention(
         *a, sm_scale=256 ** -0.5, interpret=False)).lower(
             sds((h, chunk, w)), pool, sds((mpp,), jnp.int32),
             sds((), jnp.int32)).compile()
     for compiled, name in ((decode, "paged_latent_decode_attention"),
                            (prefill, "paged_latent_chunk_attention")):
-        text = compiled.as_text()
-        assert "tpu_custom_call" in text and name in text
+        assert _calls(compiled.as_text(), name) == 1
+    assert decode.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
 @pytest.mark.parametrize("tokens", [512, 16])
@@ -283,18 +285,20 @@ def test_sorted_experts_are_a_grouped_matmul_on_v5e(chip, tokens):
 
 def test_packed_row_decode_step_compiles_for_v5e_without_pool_copies(chip):
     """LFM2's heads of 64: K and V lie one 512-value row a token in the
-    pool (``kv_heads_packed``). At the cell's sizes (64 slots, 1600 pages of
-    128, two attention layers) the packed-row kernel compiles, and the
-    decode write over the flat pool leaves no pool-sized copy in the
-    program (an [8, 64] plane is padded to twice its bytes and copied whole,
-    twice a plane: PERF.md, PR 35)."""
+    pool (``kv_heads_packed``). At the cell's sizes (64 slots of 25 pages,
+    1600 pages of 128, two attention layers) the packed-row kernel compiles
+    (PR 48: the walk over a row's live pages, four pages of each plane a
+    turn, both planes left in HBM), and the decode write over the flat pool
+    leaves no pool-sized copy in the program (an [8, 64] plane is padded to
+    twice its bytes and copied whole, twice a plane: PERF.md, PR 35)."""
     import re
 
     from kubeflow_tpu.ops.paged_attention import (
-        paged_packed_decode_attention,
+        _pages_a_turn, paged_packed_decode_attention,
     )
 
     slots, h, kv, d, layers, pages, mpp = 64, 32, 8, 64, 2, 1600, 25
+    assert _pages_a_turn(PAGE * kv * d * 2, mpp, 2) == 4
 
     def sds(shape, dt=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
@@ -312,8 +316,7 @@ def test_packed_row_decode_step_compiles_for_v5e_without_pool_copies(chip):
         sds((slots,), jnp.int32), sds((slots,), jnp.int32),
         sds((slots, mpp), jnp.int32), sds((slots,), jnp.int32)).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
-    assert "paged_packed_decode_attention" in text
+    assert _calls(text, "paged_packed_decode_attention") == 1
     whole = re.findall(
         rf"= bf16\[{layers * pages},{PAGE},{kv * d}\]\S* copy\(", text)
     assert not whole, whole
@@ -399,22 +402,34 @@ SERVING_CELLS = {
 # and each of these programs is, operation for operation and kernel body for
 # kernel body, what it was. A change that means to move one of them records
 # the new digest here and says why. (PR 46 moved the five in-place chunk
-# programs that stood here: ``CHUNK_SINCE_PR46``.)
+# programs that stood here: ``CHUNK_SINCE_PR46``; PR 48 the two decode
+# programs: ``DECODE_SINCE_PR48``.)
 LOWERED_BEFORE_PR40 = {
-    ("glm-4.7-flash.batch-longcontext", "decode"): "51228f8cbfaf3acc",
     ("lfm2-24b-a2b.batch-longanswer", "chunk[1]"): "6a4770d8248e2dcb",
     ("lfm2-24b-a2b.batch-longanswer", "chunk[2]"): "3aa5bb964b3b2815",
-    ("lfm2-24b-a2b.batch-longanswer", "decode"): "ac6c858f412bc9bb",
 }
 # PR 45 gave ``paged_decode_attention`` another schedule (grid ``(rows,)``,
 # the kernel walks a row's live pages by its own copies): the decode programs
 # of the cells that attend through it are new programs, pinned here as PR 45
-# left them (db316db56b64acbb / ddb784f45f5677a3 before). The decode digests
-# above, of GLM (latent rows) and LFM2 (packed rows), whose kernels PR 45
-# does not touch, stand as they were.
+# left them (db316db56b64acbb / ddb784f45f5677a3 before). PR 48, which put
+# the other two decode kernels on the same walk, leaves these two as they
+# are: two planes of 524 KB page pairs still get four pages a turn.
 DECODE_SINCE_PR45 = {
     ("mistral-7b.chat-open", "decode"): "21df67c531cb4588",
     ("mixtral-8x7b.batch-longprompt", "decode"): "2e6eb883f208d847",
+}
+# PR 48 gave ``paged_latent_decode_attention`` (GLM: latent rows) and
+# ``paged_packed_decode_attention`` (LFM2: packed rows) that schedule too:
+# grid ``(rows,)`` in place of ``(rows, pages)``, a row's live pages copied
+# by the kernel itself (``_rows_decode_kernel`` under ``_walk_live_pages``),
+# ONE cached inlined call for both. The two cells' decode programs are new
+# programs in nothing but that kernel's body and operands, pinned here as
+# PR 48 left them (before, and since before PR 40: GLM 51228f8cbfaf3acc, LFM2
+# ac6c858f412bc9bb). Their chunk programs do not reach either kernel and
+# stand where they were.
+DECODE_SINCE_PR48 = {
+    ("glm-4.7-flash.batch-longcontext", "decode"): "ed7f52be335dfbc1",
+    ("lfm2-24b-a2b.batch-longanswer", "decode"): "521adff686368ef4",
 }
 # PR 46 put the decode step (T = 1), the chunk prefill in place (T = the
 # chunk) and the speculative verify (T = k+1) behind ONE block over the pool
@@ -504,14 +519,17 @@ def test_serving_program_lowers_to_what_it_was_before_pr40(cell_programs,
     ``paged_decode_attention`` / ``paged_chunk_attention`` and the share
     into ``_moe_sorted`` (the decode programs of the two cells that attend
     through ``paged_decode_attention``: to PR 45's, ``DECODE_SINCE_PR45``;
-    the chunk programs built in place: to PR 46's, ``CHUNK_SINCE_PR46``).
+    of the two whose decode kernels took the same walk: to PR 48's,
+    ``DECODE_SINCE_PR48``; the chunk programs built in place: to PR 46's,
+    ``CHUNK_SINCE_PR46``).
     The program over rows in its all-position form too (``logits_at``'s
     default: what every caller but the engine's program over rows takes);
     the form the engine builds since PR 41 is pinned beside it."""
     from scripts.aot_weight_copies import lowered_fingerprint
 
     rows = program == "chunk[2]"
-    pinned = {**LOWERED_BEFORE_PR40, **DECODE_SINCE_PR45, **CHUNK_SINCE_PR46}
+    pinned = {**LOWERED_BEFORE_PR40, **DECODE_SINCE_PR45,
+              **DECODE_SINCE_PR48, **CHUNK_SINCE_PR46}
     assert lowered_fingerprint(
         cell_programs(cell, True, "all" if rows else "last")[program]) \
         == pinned[cell, program]

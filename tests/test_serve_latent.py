@@ -27,6 +27,7 @@ from kubeflow_tpu.serve.paged import (
     _paged_decode_step, copy_pages, paged_gather, pool_bytes_per_token,
     pool_planes,
 )
+from test_serve_paged import _ROW_WALK, _WALK_MPP, _idle_pages, _walk_rows
 
 CONF = mf.load_json("benchmark/configs/rehearsal-tiny-glm.json")
 REF = architecture.part(CONF, "reference")
@@ -356,9 +357,9 @@ class TestLatentKernels:
 
     H, R, ROPE, W, PG, PAGES = 4, 40, 8, 128, 16, 12
 
-    def _pool(self, dtype):
+    def _pool(self, dtype, pages=PAGES):
         rng = np.random.default_rng(0)
-        rows = rng.normal(size=(self.PAGES, self.PG, self.W))
+        rows = rng.normal(size=(pages, self.PG, self.W))
         rows[..., self.R + self.ROPE:] = 0.0
         return jnp.asarray(rows, dtype)
 
@@ -374,27 +375,44 @@ class TestLatentKernels:
         p = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
         return jnp.einsum("...ht,tw->...hw", p, rows.astype(jnp.float32))
 
+    # row 0 three pages; row 1 one partial page; row 2 has an unmapped page
+    # inside its range, which the kernel skips; beside them the walk's own
+    # cases (tests/test_serve_paged.py: a context that ends on a page's first
+    # and last token, a whole and a short last turn, length 0, dead rows
+    # between live ones, holes)
+    ROWS = {"three_rows": [([3, 7, 1], 40, None), ([9], 5, None),
+                           ([2, -1, 5], 37, None)], **_ROW_WALK}
+
     @pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-5),
                                             (jnp.bfloat16, 0.06)])
-    def test_decode_kernel_matches_the_gather_form(self, dtype, atol):
-        from kubeflow_tpu.ops.paged_attention import (
-            paged_latent_decode_attention,
-        )
+    @pytest.mark.parametrize("case", sorted(ROWS))
+    def test_decode_kernel_matches_the_gather_form(self, case, dtype, atol):
+        """Every page no context holds is POISONED (NaN): a walk that copied
+        one, or attended to a buffer it did not fill, shows it (a latent
+        page's rows are keys AND values: ``0 x NaN`` is NaN)."""
+        from kubeflow_tpu.ops import paged_attention as pa
 
-        pool, q = self._pool(dtype), self._queries((3, self.H), dtype)
-        # row 0 three pages; row 1 one partial page; row 2 has an unmapped
-        # page inside its range, which the kernel skips
-        table = jnp.asarray([[3, 7, 1, -1], [9, -1, -1, -1], [2, -1, 5, -1]],
-                            jnp.int32)
-        lengths = jnp.asarray([40, 5, 37], jnp.int32)
-        out = paged_latent_decode_attention(q, pool, table, lengths,
-                                            sm_scale=0.2, interpret=True)
+        rows = self.ROWS[case]
+        mpp = 4 if case == "three_rows" else _WALK_MPP
+        assert pa._pages_a_turn(
+            self.PG * self.W * jnp.dtype(dtype).itemsize, mpp, 1) \
+            == min(8, mpp)
+        table, lengths, _ = _walk_rows(rows, mpp)
+        pool = self._pool(dtype, 36)
+        q = self._queries((len(rows), self.H), dtype)
+        idle = _idle_pages(rows, self.PG, 36)[:, None, None]
+        table, lengths = jnp.asarray(table), jnp.asarray(lengths)
+        out = pa.paged_latent_decode_attention(
+            q, jnp.where(idle, jnp.nan, pool), table, lengths, sm_scale=0.2,
+            interpret=True)
         assert out.dtype == dtype and out.shape == q.shape
-        rows = paged_gather(pool, table)                   # [B, S, W]
-        pos = jnp.arange(rows.shape[1])[None, :]
+        gathered = paged_gather(pool, table)               # [B, S, W]
+        pos = jnp.arange(gathered.shape[1])[None, :]
         mask = (pos <= lengths[:, None]) & jnp.repeat(table >= 0, self.PG, 1)
-        for b in range(3):
-            want = self._attend(q[b], rows[b], mask[b][None], 0.2)
+        for b in range(len(rows)):
+            want = self._attend(q[b], gathered[b], mask[b][None], 0.2)
+            if not bool(mask[b].any()):     # a row that attends to no page
+                want = jnp.zeros_like(want)
             np.testing.assert_allclose(np.asarray(out[b], np.float32),
                                        np.asarray(want), atol=atol)
 

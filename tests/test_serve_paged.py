@@ -533,6 +533,13 @@ _WALK = {
 }
 
 
+# What the kernels over whole-token rows take of it (a latent pool, packed
+# K/V rows: no lower bound): 8 pages a turn over one plane, 4 over two, so 8
+# and 9 live pages are a whole turn and a short last one of the first too.
+_ROW_WALK = {case: _WALK[case] for case in ("page_edges", "dead_rows",
+                                            "hole")}
+
+
 def _walk_rows(rows, mpp):
     import numpy as np
 
@@ -544,6 +551,16 @@ def _walk_rows(rows, mpp):
     lower = np.asarray([lo for _, _, lo in rows], np.int32) if bounded \
         else None
     return table, lengths, lower
+
+
+def _idle_pages(rows, pg, pages):
+    """[pages] bool: the pages the walk may NOT read, every one but those a
+    row maps at or before its length's page."""
+    import numpy as np
+
+    used = {i for ids, ln, _ in rows
+            for j, i in enumerate(ids) if i >= 0 and j <= ln // pg}
+    return np.asarray([i not in used for i in range(pages)])
 
 
 def _without_holes(table, lengths, lower, pg):
@@ -597,11 +614,7 @@ def test_decode_kernel_walks_live_pages(cfg, case, pool, group):
     pk, pv = (jnp.asarray(rng.normal(size=(pages, pg, kv, d)), dt)
               for _ in range(2))
     q = jnp.asarray(rng.normal(size=(len(rows), 1, kv * group, d)), dt)
-    # what the walk may read: pages a row maps at or before its length's
-    used = {int(i) for r, (ids, ln, _) in enumerate(rows)
-            for j, i in enumerate(ids) if i >= 0 and j <= ln // pg}
-    idle = jnp.asarray([i not in used for i in range(pages)])[
-        :, None, None, None]
+    idle = jnp.asarray(_idle_pages(rows, pg, pages))[:, None, None, None]
     extra, scales = {}, None
     if pool == "int8":
         (pk, sk), (pv, sv) = quantize_kv(pk), quantize_kv(pv)
@@ -630,6 +643,47 @@ def test_decode_kernel_walks_live_pages(cfg, case, pool, group):
     assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
     # bfloat16: the probabilities are rounded to the pool's type on both
     # sides, before (the kernel) and after (the oracle) they are normed.
+    assert float(err) < (3e-2 if pool == "bfloat16" else 2e-5), float(err)
+
+
+@pytest.mark.parametrize("pool", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_ROW_WALK))
+def test_packed_decode_kernel_walks_live_pages(cfg, case, pool):
+    """``paged_packed_decode_attention`` (heads of 64, a token's KV heads
+    side by side in one row a plane) against ``_decode_attention`` over the
+    gathered pages, every page no context holds POISONED as above."""
+    import dataclasses
+
+    import numpy as np
+
+    from kubeflow_tpu.ops import paged_attention as pa
+    from kubeflow_tpu.serve.paged import _decode_attention, paged_gather
+
+    pg, kv, d, group, pages = 16, 2, 64, 4, 36
+    rows = _ROW_WALK[case]
+    dt = jnp.dtype(pool)
+    assert pa._pages_a_turn(pg * kv * d * dt.itemsize, _WALK_MPP, 2) == 4
+    table, lengths, _ = _walk_rows(rows, _WALK_MPP)
+    rng = np.random.default_rng(len(case))
+    pk, pv = (jnp.asarray(rng.normal(size=(pages, pg, kv * d)), dt)
+              for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(len(rows), 1, kv * group, d)), dt)
+    idle = jnp.asarray(_idle_pages(rows, pg, pages))[:, None, None]
+    got = pa.paged_packed_decode_attention(
+        q, jnp.where(idle, jnp.nan, pk), jnp.where(idle, jnp.nan, pv),
+        jnp.asarray(table), jnp.asarray(lengths), kv)
+
+    t2, l2, _ = _without_holes(table, lengths, None, pg)
+    c = dataclasses.replace(cfg, n_heads=kv * group, n_kv_heads=kv,
+                            head_dim=d)
+    want = _decode_attention(
+        q, *(paged_gather(x.reshape(pages, pg, kv, d), jnp.asarray(t2))
+             for x in (pk, pv)), jnp.asarray(l2), c)
+    nothing = np.asarray((t2 < 0).all(axis=1))
+    want = jnp.where(nothing[:, None, None, None], 0, want)
+    assert got.dtype == dt and got.shape == q.shape
+    err = jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32)).max()
+    assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
     assert float(err) < (3e-2 if pool == "bfloat16" else 2e-5), float(err)
 
 
