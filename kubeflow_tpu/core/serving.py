@@ -327,7 +327,10 @@ class BatchingSpec(BaseModel):
     # measured it a tie with dense across three sessions including a
     # decode-heavy p128/gen128 run (3.98 vs 3.96 req/s), so "auto" keeps
     # the simpler dense path; "zero_drop" selects the variant for
-    # remeasurement at other batch sizes.
+    # remeasurement at other batch sizes. It governs the decode-ONLY
+    # programs: where a chunk program carries the decode step (PR 49) the
+    # step's rows go the prefill path's drop-free way (a capacity group of
+    # their own that holds every token; sorted experts have no capacity).
     moe_prefill_impl: str = "auto"   # auto|dispatch|dense
     moe_decode_impl: str = "auto"    # auto|zero_drop|dense
     # Speculative decoding (draft + batched verify): greedy requests emit

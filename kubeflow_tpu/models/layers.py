@@ -1195,7 +1195,8 @@ def moe_block(p: dict, x: jax.Array, cfg: DecoderConfig,
               tp_axis: Optional[str] = None,
               expert_stack: Optional[tuple] = None,
               capacity_per_row: bool = False,
-              rows_out: bool = False):
+              rows_out: bool = False,
+              tail: Optional[jax.Array] = None):
     """Top-k MoE (Mixtral semantics: softmax over the selected k logits).
 
     Dispatches on ``cfg.moe_impl``: "dispatch" (default) routes tokens into
@@ -1229,7 +1230,17 @@ def moe_block(p: dict, x: jax.Array, cfg: DecoderConfig,
     A layer that holds a SHARE of its experts (``cfg.experts_held``) is the
     sorted path's alone. ``rows_out`` (static): a third result, int32 [2]:
     the (token, choice) rows this call routed and those of them whose expert
-    is held here (the serving programs sum them: serve/paged.py)."""
+    is held here (the serving programs sum them: serve/paged.py).
+
+    ``tail`` [N, D] (the dispatch path's alone; the decode rows that ride
+    in a serving chunk program): tokens beside ``x``'s that are a dispatch
+    group of their own and cannot drop (``_moe_dispatch``); ``out`` is then
+    the pair (``x``'s [B,S,D], the tail's [N,D]). The paths without a
+    capacity take such tokens as rows of ``x``."""
+    if tail is not None and (cfg.moe_impl != "dispatch" or rows_out):
+        raise NotImplementedError(
+            f"a tail of tokens under moe_impl={cfg.moe_impl!r}: only a "
+            "capacity sets tokens apart, hand them in as rows")
     if cfg.experts_held and cfg.moe_impl != "sorted":
         raise NotImplementedError(
             f"experts_held={cfg.experts_held} of {cfg.num_experts} under "
@@ -1240,7 +1251,7 @@ def moe_block(p: dict, x: jax.Array, cfg: DecoderConfig,
         out, aux = _moe_dispatch(p, x, cfg, expert_axis=expert_axis,
                                  seq_axis=seq_axis, valid_len=valid_len,
                                  tp_axis=tp_axis,
-                                 capacity_per_row=capacity_per_row)
+                                 capacity_per_row=capacity_per_row, tail=tail)
     elif cfg.moe_impl == "sorted":
         if expert_axis is not None or tp_axis is not None:
             raise NotImplementedError(
@@ -1253,7 +1264,14 @@ def moe_block(p: dict, x: jax.Array, cfg: DecoderConfig,
                               seq_axis=seq_axis, tp_axis=tp_axis)
     else:
         raise ValueError(f"unknown moe_impl {cfg.moe_impl!r}")
-    if cfg.shared_experts:
+    if cfg.shared_experts and tail is not None:
+        # Every token, the tail's too: one pass over the shared weights.
+        n = x.shape[0] * x.shape[1]
+        shared = mlp_block(p["shared"], jnp.concatenate(
+            [x.reshape(1, n, -1), tail[None]], axis=1), cfg,
+            tp_axis=tp_axis)[0]
+        out = (out[0] + shared[:n].reshape(x.shape), out[1] + shared[n:])
+    elif cfg.shared_experts:
         # Every token, beside whatever it was routed to.
         out = out + mlp_block(p["shared"], x, cfg, tp_axis=tp_axis)
     if rows_out:
@@ -1313,7 +1331,8 @@ def _moe_dispatch(p: dict, x: jax.Array, cfg: DecoderConfig,
                   seq_axis: Optional[str] = None,
                   valid_len: Optional[jax.Array] = None,
                   tp_axis: Optional[str] = None,
-                  capacity_per_row: bool = False):
+                  capacity_per_row: bool = False,
+                  tail: Optional[jax.Array] = None):
     """Capacity-factor top-k dispatch (SURVEY.md §2.6 EP row: the TPU-native
     MoE data path; (U) training-operator-era Mixtral recipes route via NCCL
     all-to-all — here the routing is scatter/gather into static [E, C]
@@ -1340,6 +1359,14 @@ def _moe_dispatch(p: dict, x: jax.Array, cfg: DecoderConfig,
       ``[E, B*c, D]``, so a row keeps and drops exactly the (token, choice)
       pairs it keeps and drops alone while each expert's weights are still
       read once for all rows. At one row the two are the same computation.
+    - ``tail`` [N, D] (serving: the decode rows that ride in a chunk
+      program) are ``N`` more tokens, a dispatch group of their own whose
+      capacity is ``N``: an expert is chosen by a token at most once, so
+      none of them can drop, and no token of ``x`` displaces one. Their
+      ``N`` slots stand behind ``x``'s in each expert's buffer
+      (``[E, g*c + N, D]``), so an expert's weights are still read once.
+      ``out`` is then the pair (``x``'s [B,S,D], the tail's [N,D]); the
+      balance loss stays ``x``'s.
 
     With ``expert_axis`` (inside shard_map): weights hold the local expert
     slice; positions are computed on the replicated router output (identical
@@ -1354,7 +1381,14 @@ def _moe_dispatch(p: dict, x: jax.Array, cfg: DecoderConfig,
     g = b if capacity_per_row else 1
     tg = t // g
     xf = x.reshape(t, d)
-    router_logits, topk_idx, topk_w = route(p, xf, cfg)              # [T,k]
+    n = 0 if tail is None else tail.shape[0]
+    if n:
+        xf = jnp.concatenate([xf, tail.astype(xf.dtype)])            # [T+N,D]
+    router_logits, topk_idx, topk_w = route(p, xf, cfg)              # [T+N,k]
+    if n:
+        tail_idx, tail_w = topk_idx[t:], topk_w[t:]
+        router_logits, topk_idx, topk_w = (
+            router_logits[:t], topk_idx[:t], topk_w[:t])
 
     c = moe_capacity(cfg, tg)
     # Choice-major flattening within a group: row r of group i is
@@ -1385,34 +1419,55 @@ def _moe_dispatch(p: dict, x: jax.Array, cfg: DecoderConfig,
         e_local = p["gate"].shape[0]
         offset = jax.lax.axis_index(expert_axis) * e_local
         keep = keep & (flat_e >= offset) & (flat_e < offset + e_local)
-    # Expert ``x``'s buffer: group 0's c slots, then group 1's, ...
+    # An expert's buffer: group 0's c slots, then group 1's, ..., then the
+    # tail's N.
+    per_e = g * c + n
     slot = jnp.arange(g, dtype=jnp.int32)[:, None] * c + pos_in_e
-    rows = jnp.where(keep, (flat_e - offset) * (g * c) + slot,
-                     e_local * g * c).reshape(-1)                    # [kT]
+    rows = jnp.where(keep, (flat_e - offset) * per_e + slot,
+                     e_local * per_e).reshape(-1)                    # [kT]
     tok_of = (jnp.arange(g, dtype=jnp.int32)[:, None] * tg + jnp.tile(
         jnp.arange(tg, dtype=jnp.int32), k)[None, :]).reshape(-1)    # [kT]
+    if n:
+        # Choice-major like a group; the place among the expert's tail slots
+        # is the count of earlier pairs that chose it, always below N.
+        tail_e = jnp.swapaxes(tail_idx, 0, 1).reshape(-1)            # [kN]
+        tail_oh = jax.nn.one_hot(tail_e, e, dtype=jnp.int32)
+        place = jnp.take_along_axis(jnp.cumsum(tail_oh, axis=0) - 1,
+                                    tail_e[:, None], 1)[:, 0]
+        here = (tail_e >= offset) & (tail_e < offset + e_local)
+        rows = jnp.concatenate([rows, jnp.where(
+            here, (tail_e - offset) * per_e + g * c + place,
+            e_local * per_e)])                                       # [kT+kN]
+        tok_of = jnp.concatenate([tok_of, t + jnp.tile(
+            jnp.arange(n, dtype=jnp.int32), k)])
     # TPU lowers row-granular scatters poorly (measured 2.9× slower than
     # dense!): invert the slot permutation with a SCALAR scatter (cheap),
     # then fill the buffers with a row GATHER — empty slots read OOB and
     # fill with zeros.
-    row_of_slot = jnp.full((e_local * g * c,), t, jnp.int32).at[rows].set(
-        tok_of, mode="drop")
+    row_of_slot = jnp.full((e_local * per_e,), t + n, jnp.int32).at[
+        rows].set(tok_of, mode="drop")
     buf = jnp.take(xf, row_of_slot, axis=0, mode="fill",
-                   fill_value=0).reshape(e_local, g * c, d)
+                   fill_value=0).reshape(e_local, per_e, d)
 
     gate = _act(jnp.einsum("ecd,edm->ecm", buf, p["gate"].astype(dt)),
                 cfg.hidden_act)
     up = jnp.einsum("ecd,edm->ecm", buf, p["up"].astype(dt))
     y = jnp.einsum("ecm,emd->ecd", gate * up,
-                   p["down"].astype(dt)).reshape(e_local * g * c, d)
+                   p["down"].astype(dt)).reshape(e_local * per_e, d)
 
     back = jnp.take(y, rows, axis=0, mode="fill", fill_value=0)      # [kT,D]
     w_flat = choice_major(topk_w).reshape(-1, 1).astype(dt)
+    if n:
+        w_tail = jnp.swapaxes(tail_w, 0, 1).reshape(-1, 1).astype(dt)
+        out_tail = (back[k * t:] * w_tail).reshape(k, n, d).sum(0)
+        back = back[:k * t]
     out = (back * w_flat).reshape(g, k, tg, d).sum(1).reshape(b, s, d)
     # One combined reduction: expert partials (each shard computed its
     # local experts) and Megatron partials (down contracted a local
     # m-slice) sum over both axes at once.
     axes = tuple(a for a in (expert_axis, tp_axis) if a is not None)
+    if n:
+        out = (out, out_tail)
     if axes:
         out = jax.lax.psum(out, axes)
 

@@ -75,10 +75,11 @@ from kubeflow_tpu.core.serving import (
 from kubeflow_tpu.serve.device_state import DEAD_SLOT, DecodeState
 from kubeflow_tpu.serve.pacing import RoundPacer, decode_ladder
 from kubeflow_tpu.serve.paged import (
-    MOE_ROWS, PageAllocator, PagePoolExhausted, chunk_reads_context,
-    context_bucket, engine_pool_shapes, paged_chunk_prefill,
-    first_page_ids, own_first_pages, paged_decode_multi,
-    pool_bytes_per_token, pool_shapes, ring_pages,
+    MOE_ROWS, PageAllocator, PagePoolExhausted, chunk_carries_step,
+    chunk_reads_context, context_bucket, engine_pool_shapes,
+    paged_chunk_prefill, first_page_ids, own_first_pages,
+    paged_decode_multi, paged_mixed_step, pool_bytes_per_token,
+    pool_shapes, ring_pages,
 )
 from kubeflow_tpu.serve.weight_layout import (
     relaid_bytes, relay, weight_formats,
@@ -680,6 +681,12 @@ def serving_configs(cfg: DecoderConfig, b: BatchingSpec):
     #   zero-drop capacity (C = k*T). The same A/B measured it a tie
     #   within session noise, so dense (simpler, drop-free by
     #   construction) stays the default (bench_serve.py --workload moe).
+    # - The chunk program that CARRIES the decode step is built with
+    #   ``cfg_prefill`` alone: its decode rows take the prefill path's
+    #   drop-free form (dispatch: a capacity group of their own that holds
+    #   every token, ``layers._moe_dispatch``'s tail; sorted: no capacity
+    #   at all), the same tokens up to rounding. ``moe_decode_impl``
+    #   therefore governs the decode-ONLY programs.
     if cfg.layers_of("window"):
         # A sequence's ring in the window layers' planes: every program of
         # the engine reads its length off its config (paged.ring_table).
@@ -1003,6 +1010,41 @@ class LLMEngine:
         self._paged_decode_n = jax.jit(
             _paged_decode_fn, static_argnums=(5, 6),
             donate_argnums=(1, 2, 3))
+        # The chunk program that CARRIES the decode step: an admit pass
+        # that has a chunk program to send while slots are live sends the
+        # chunks' rows and the slots' rows in one program, one step of the
+        # round, and every weight is read once an iteration where a chunk
+        # program and a decode step each read it (a step of a sparse model
+        # is its weights' bytes: 40% of the batch cell's iteration). Built
+        # where the stack and the pool allow it (``chunk_carries_step``)
+        # and nothing rides the programs that it does not carry: adapter
+        # buffers, a speculative round. There it is also the program over
+        # several prompts' rows (``ride`` false: every decode row dead) and
+        # has that one width, so such an engine loads no program more than
+        # it did. It is jitted as the chunk programs are, a lambda: what
+        # finds a decode step by its module's name finds decode-only steps.
+        self._mixed = (
+            chunk_carries_step(self.cache, cfg_prefill, None, pattn)
+            and b.speculative.mode == "off" and not b.lora.max_adapters)
+        self._mixed_pass = -1    # lockfree: scheduler-confined (the admit pass that sent a round)
+
+        def _mixed_fn(p, c, t, tr, s0, vl, ends, ride, st, tbl, key, m):
+            logits, out, cache, tokens, lengths, live, budgets = \
+                paged_mixed_step(
+                    p, {**c, "table": tbl}, t, tr, s0, vl, ends, ride,
+                    st["tokens"], st["lengths"], st["live"], st["temps"],
+                    st["top_k"], st["top_p"], st["stops"], st["budgets"],
+                    key, cfg_prefill, sample_mode=m, attn_impl=pattn)
+            table = cache.pop("table")
+            st = {**st, "tokens": tokens, "lengths": lengths,
+                  "live": live, "budgets": budgets}
+            rows = cache[MOE_ROWS] + 0 if MOE_ROWS in cache else None
+            return logits, out, self._pin(cache), st, table, rows
+
+        self._paged_mixed = jax.jit(
+            lambda p, c, t, tr, s0, vl, ends, ride, st, tbl, key, m:
+            _mixed_fn(p, c, t, tr, s0, vl, ends, ride, st, tbl, key, m),
+            static_argnums=(11,), donate_argnums=(1, 8, 9))
         # Scheduler-confined state (the whole block below): mutated ONLY
         # on the scheduler thread (or by step() when no loop runs — the
         # unthreaded mode never coexists with start()). Cross-thread
@@ -1110,6 +1152,7 @@ class LLMEngine:
             # donation / dispatch-signature rules read.
             for attr, name in (("_paged_chunk", "paged_chunk_prefill"),
                                ("_paged_chunks", "paged_chunk_prefill"),
+                               ("_paged_mixed", "paged_mixed"),
                                ("_paged_decode_n", "paged_decode")):
                 if hasattr(self, attr):
                     setattr(self, attr,
@@ -1249,6 +1292,10 @@ class LLMEngine:
         self._prefill_row_programs_dispatched = 0   # lockfree: scheduler-confined counter
         self._prefill_programs_with_end = 0     # lockfree: scheduler-confined counter
         self._prefill_tokens_dispatched = 0     # lockfree: scheduler-confined counter
+        # Prefill programs that carried a decode step, and the live rows
+        # those steps had.
+        self._mixed_programs_dispatched = 0     # lockfree: scheduler-confined counter
+        self._mixed_decode_rows_sum = 0         # lockfree: scheduler-confined counter
         self._state_tail_writes = 0             # lockfree: scheduler-confined counter
         # Admit passes that sent a prefill program; chunks that were due in
         # a pass and waited for a later one (its budget of programs spent).
@@ -1303,8 +1350,8 @@ class LLMEngine:
         # None until stop() runs; False = the scheduler thread outlived its
         # join timeout and is leaked (it may hold live device buffers).
         self.stopped_clean: Optional[bool] = None
-        if self._chunk_rows > 1:
-            self._warm_chunk_rows()
+        if self._mixed or self._chunk_rows > 1:
+            self._warm_rows_program()
         self._warm_decode_ladder()
         if self._weights_relaid_bytes:
             self._warm_first_tokens()
@@ -1338,22 +1385,49 @@ class LLMEngine:
                 [row] * width, [greedy] * width, self._rng))
             width *= 2
 
-    def _warm_chunk_rows(self) -> None:
+    def _warm_rows_program(self) -> None:
         """Compile and run once, now, the program over several prompts'
-        chunks, on DEAD rows (no valid position, no page: nothing is
-        written). Traffic reaches several concurrent prefills only where
-        arrivals fall together, so no warm-up of a caller's can be relied on
-        to reach it; the program set is the engine's own, and fixed from
-        here on. Also warms the read of one row's logits."""
+        chunks (where the engine built it, the chunk program that carries
+        the decode step: greedy, no slot riding, the slots' state comes
+        back as it went in), on DEAD rows: no valid position, no page,
+        nothing is written. Traffic reaches several concurrent prefills, or
+        a chunk beside a live slot, only where arrivals fall so, and no
+        warm-up of a caller's can be relied on to; the program set is the
+        engine's own, and fixed from here on. The state and the pool go in
+        as traffic's dispatches hand them in (committed where the weights
+        are); the key is not drawn from. Also warms the read of each row's
+        logits."""
         rows, C = self._chunk_rows, self.chunk_size
-        lora = () if self._lora is None else (
-            self._lora.buffers, jnp.full((rows,), -1, jnp.int32))
-        logits, self.cache = self._paged_chunks(
-            self.params, self.cache, jnp.zeros((rows, C), jnp.int32),
-            jnp.full((rows, self._mpp), -1, jnp.int32),
-            jnp.zeros((rows,), jnp.int32), jnp.zeros((rows,), jnp.int32),
-            jnp.zeros((rows,), jnp.bool_), self._mpp, *lora)
-        jax.block_until_ready(logits[rows - 1])
+        dead = (jnp.zeros((rows, C), jnp.int32),
+                jnp.full((rows, self._mpp), -1, jnp.int32),
+                jnp.zeros((rows,), jnp.int32), jnp.zeros((rows,), jnp.int32),
+                jnp.zeros((rows,), jnp.bool_))
+        if self._mixed:
+            logits, _ = self._send_mixed(*dead)
+        else:
+            lora = () if self._lora is None else (
+                self._lora.buffers, jnp.full((rows,), -1, jnp.int32))
+            logits, self.cache = self._paged_chunks(
+                self.params, self.cache, *dead, self._mpp, *lora)
+        jax.block_until_ready([logits[r] for r in range(rows)])
+
+    def _send_mixed(self, chunk, table, start, valid, ends,
+                    mode: Optional[str] = None):
+        """Enqueue the chunk program that carries the decode step over the
+        device-resident state and adopt the pool and the state it returns.
+        ``mode``: the sampling mode of the live slots' step where they ride
+        (a key is drawn); None where none does (every decode row dead:
+        greedy, the key not drawn from). Returns (the chunk rows' logits,
+        (the round's token buffer, the expert rows' sums as the program
+        leaves them))."""
+        ride = mode is not None
+        logits, out, self.cache, st, tbl, rows = self._paged_mixed(
+            self.params, self.cache, jnp.asarray(chunk), jnp.asarray(table),
+            jnp.asarray(start), jnp.asarray(valid), jnp.asarray(ends),
+            jnp.asarray(ride), self._dstate.arrays, self._dstate.table,
+            self._next_key() if ride else self._rng, mode or "greedy")
+        self._dstate.adopt(st, tbl)
+        return logits, (out, rows)
 
     # -- mesh-mode helpers -----------------------------------------------------
 
@@ -1514,6 +1588,12 @@ class LLMEngine:
             "prefill_programs_dispatched": self._prefill_programs_dispatched,
             "prefill_chunks_dispatched": self._prefill_chunks_dispatched,
             "prefill_tokens_dispatched": self._prefill_tokens_dispatched,
+            # of those programs, the ones that carried a decode step of the
+            # live slots (one program an iteration where it would have been
+            # two; over ``prefill_programs_dispatched``: how often), and
+            # the live rows those steps had
+            "mixed_programs_dispatched": self._mixed_programs_dispatched,
+            "mixed_decode_rows_sum": self._mixed_decode_rows_sum,
             # of those programs, the ones sent through the program over
             # several prompts' rows (whose head runs at one position a
             # row), and the ones in which some row ended its prompt (the
@@ -1958,10 +2038,29 @@ class LLMEngine:
         an engine that built no program over several rows, takes the
         one-row program it always took; an engine whose stack ends in a
         stateless tail sends it through the program over rows as a group of
-        one row (``__init__``)."""
+        one row (``__init__``).
+
+        Where the engine built the chunk program that carries the decode
+        step (``_mixed``) and a slot is live, the pass's first program
+        carries the live slots' step, the round of this iteration
+        (``_ready_round``: their pages, their state's sync, as before any
+        round), and says so in both dispatch spans; its rounds' tokens go
+        the way every round's go (``_rounds``). That program has ONE width,
+        as many rows as the engine sends chunks together (a prefill alone
+        rides beside dead rows: one program to load, not two); such an
+        engine sends several prompts' chunks through it too, no slot
+        riding."""
         C = self.chunk_size
-        rows = 1 if len(group) == 1 else self._chunk_rows
-        by_rows = rows > 1 or self._tail_at_last
+        ride = None
+        if self._mixed and self._mixed_pass != self._admit_pass:
+            ride = self._ready_round(
+                [(i, s) for i, s in enumerate(self.slots) if s is not None],
+                1)
+        # Several chunks, or one that a step rides with, go together in the
+        # program of the engine's one width (rows past the group dead).
+        together = len(group) > 1 or ride is not None
+        rows = self._chunk_rows if together else 1
+        by_rows = together or self._tail_at_last
         reals = [min(C, len(ch.request.prompt_tokens) - ch.pos)
                  for ch in group]
         ends = [ch.pos + real == len(ch.request.prompt_tokens)
@@ -1986,6 +2085,20 @@ class LLMEngine:
                 for r, (ch, real) in enumerate(zip(group, reals)):
                     table[r] = self._table[ch.slot]
                     start[r], valid[r], wanted[r] = ch.pos, real, ends[r]
+            if self._mixed and together:
+                active, mode, gap, context, attrs = ride or (None,) * 5
+                with self._phase(prof.ENGINE_DECODE_DISPATCH,
+                                 prof.active() and attrs) \
+                        if ride else contextlib.nullcontext():
+                    logits, sent = self._send_mixed(
+                        chunk, table, start, valid, wanted, mode)
+                if ride:
+                    self._note_round(*sent, active, 1, self._round_cap(),
+                                     gap, context, alone=False)
+                    self._mixed_pass = self._admit_pass
+                    self._mixed_programs_dispatched += 1
+                    self._mixed_decode_rows_sum += len(active)
+            elif by_rows:
                 logits, self.cache = self._paged_chunks(
                     self.params, self.cache, jnp.asarray(chunk),
                     jnp.asarray(table), jnp.asarray(start),
@@ -2238,7 +2351,12 @@ class LLMEngine:
             return None
         if self._spec_round():
             return 1        # host-verified: one dispatch, consumed at once
-        cap = min(self.decode_steps, self.prefill_interleave_steps)
+        return self._steps_in_force(
+            min(self.decode_steps, self.prefill_interleave_steps))
+
+    def _steps_in_force(self, cap: int) -> int:
+        """The length the next round under ``cap`` has as things stand: the
+        pacer's last choice where rounds are paced, else the cap."""
         return min(self._pacer.k, cap) if self.pipelined else cap
 
     def _due_chunkings(self) -> "list[_Chunking]":
@@ -2957,7 +3075,13 @@ class LLMEngine:
                 return emitted
             return emitted + self._spec_decode_once(active)
         dispatched = False
-        if active:
+        if active and self._mixed_pass == self._admit_pass \
+                and self._steps_in_force(self._round_cap()) == 1:
+            # The admit pass's chunk program carried this iteration's round
+            # (a round the pacer makes longer follows it as the decode
+            # program it always was).
+            dispatched = True
+        elif active:
             dispatched = self._dispatch_round(active, paced=self.pipelined)
         # Pipelined: leave the just-dispatched round in flight and consume
         # only the previous one; unpipelined (and trailing) rounds drain.
@@ -3005,9 +3129,38 @@ class LLMEngine:
         the pipeline overlaps with the host's work, is the shortest length
         of the ladder that hides that work (``RoundPacer``); a round that is
         consumed at once hides nothing and runs at its cap."""
-        cap = (min(self.decode_steps, self.prefill_interleave_steps)
-               if self._chunkings else self.decode_steps)
-        k_steps = self._pacer.choose(cap) if paced else cap
+        cap = self._round_cap()
+        ready = self._ready_round(
+            active, self._pacer.choose(cap) if paced else cap)
+        if ready is None:
+            return False
+        active, mode, gap, context, attrs = ready
+        k_steps = attrs["k_steps"]
+        with self._phase(prof.ENGINE_DECODE_DISPATCH,
+                         prof.active() and attrs):
+            out, rows = self._dispatch_decode(k_steps, mode,
+                                              self._next_key())
+        self._note_round(out, rows, active, k_steps, cap, gap, context,
+                         alone=self._sent_no_prefill())
+        return True
+
+    def _round_cap(self) -> int:
+        """The most steps the next round may have."""
+        return (min(self.decode_steps, self.prefill_interleave_steps)
+                if self._chunkings else self.decode_steps)
+
+    def _ready_round(self, active, k_steps: int):  # hot-loop
+        """What stands before any program that runs decode steps of the
+        live slots, the decode program and the chunk program that carries a
+        step alike: pages for the steps' writes, the slots' state on the
+        device brought up to date. Returns None where nothing is live (or
+        page-pool pressure preempted every candidate slot), else (the live
+        slots, the sampling mode, the host gap before the dispatch, the
+        cache rows the steps attend to, the dispatch span's attributes,
+        ``k_steps`` among them: 1 where a sole survivor had room for no
+        more)."""
+        if not active:
+            return None
         # With rounds in flight the device may already be this many steps
         # past the host's slot lengths — page pre-allocation must cover
         # the stale window too or a mid-dispatch write lands unmapped.
@@ -3034,37 +3187,38 @@ class LLMEngine:
             active = [(i, s) for i, s in enumerate(self.slots)
                       if s is not None]
         if not active:
-            return False
+            return None
         mode = _mode_for([s.request.params for _, s in active])
         self._sync_decode_state()
-        now = time.monotonic()
         gap = None
         if self._last_ready_t is not None:
             # Host gap: wall time the device spent waiting on the host
             # between rounds. 0 by construction when the next round was
             # already queued before the previous one's results landed.
-            gap = 0.0 if self._rounds else max(0.0, now - self._last_ready_t)
+            gap = 0.0 if self._rounds else max(
+                0.0, time.monotonic() - self._last_ready_t)
             self.metrics.observe_host_gap(gap)
         self.metrics.note_dispatch_depth(len(self._rounds))
-        round_id = self.decode_rounds
         # Cache rows the round attends to, over its live slots and steps:
         # step j attends to a slot's rows 0..length+slack+j.
         context = sum(
             k_steps * (s.length + slack) + k_steps * (k_steps + 1) // 2
             for _, s in active)
-        attrs = prof.active() and {
-            "round": round_id, "k_steps": k_steps, "live": len(active),
-            "context": context}
-        if attrs and self.cfg.layers_of("window"):
+        attrs = {"round": self.decode_rounds, "k_steps": k_steps,
+                 "live": len(active), "context": context}
+        if prof.active() and self.cfg.layers_of("window"):
             # rows a window layer's steps attend to: a step at position t
             # sees min(t + 1, window) of them
             w = self.cfg.attn_window
             attrs["window_context"] = sum(
                 min(s.length + slack + j + 1, w)
                 for _, s in active for j in range(k_steps))
-        with self._phase(prof.ENGINE_DECODE_DISPATCH, attrs):
-            out, rows = self._dispatch_decode(k_steps, mode,
-                                              self._next_key())
+        return active, mode, gap, context, attrs
+
+    def _note_round(self, out, rows, active, k_steps: int, cap: int,  # hot-loop
+                    gap, context: int, alone: bool) -> None:
+        """Count a dispatched round and queue it for its fetch."""
+        round_id = self.decode_rounds
         self.decode_rounds += 1
         self._decode_steps_dispatched += k_steps
         self._decode_rounds_at_cap += k_steps == cap
@@ -3072,8 +3226,7 @@ class LLMEngine:
         self._rounds.append(_InflightRound(
             out=out, active=list(active), k_steps=k_steps,
             gap_ms=None if gap is None else gap * 1e3, round_id=round_id,
-            alone=self._sent_no_prefill(), rows=rows))
-        return True
+            alone=alone, rows=rows))
 
     def _dispatch_decode(self, k_steps: int, mode: str, key):  # hot-loop
         """Enqueue the decode program over the device-resident state and

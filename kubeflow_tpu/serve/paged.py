@@ -54,7 +54,7 @@ from __future__ import annotations
 
 import dataclasses
 from collections import OrderedDict
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -699,21 +699,74 @@ def _head_logits(params: Params, x: jax.Array, cfg: DecoderConfig,  # traced
     return logits
 
 
-def _feed_forward(bp, h, cfg: DecoderConfig, expert_stack=None,  # traced
-                  valid_len=None, capacity_per_row: bool = False, *,
-                  pools: dict):
-    """The block's feed-forward (or expert) layer over ``h``: (out, pools),
-    the carried planes, where a share of the experts is held with the
-    layer's routed and held rows added to ``pools[MOE_ROWS]``."""
+def _feed_forward(bp, hs, valids, cfg: DecoderConfig,  # traced
+                  expert_stack=None, *, pools: dict):
+    """The block's feed-forward (or expert) layer over the tokens of every
+    group of ``hs`` ([B,T,D] each; ``valids`` [B] each: a row's real tokens)
+    TOGETHER, so a weight is read once for all of them: (a group's output
+    each, pools), the carried planes, where a share of the experts is held
+    with the layer's routed and held rows added to ``pools[MOE_ROWS]``.
+
+    One group is the layer as it always ran: an expert layer is told which
+    tokens are real and takes its capacity a row at several tokens a row,
+    and neither at one (a row is a token). Two groups are a chunk's rows and
+    the decode rows that ride with them (``paged_mixed_step``): a plain MLP
+    and the experts without a capacity take the tokens in a row, the decode
+    rows behind the chunk's (``mixed_step_rows`` of them: sorted rows in
+    whole tiles); a dispatch layer keeps the chunk rows' capacity a row and
+    takes the decode tokens as a group that cannot drop
+    (``layers._moe_dispatch``'s ``tail``), whatever the chunk rows claim."""
+    if len(hs) == 1:
+        (h,), t = hs, hs[0].shape[1]
+        valid_len, per_row = (None if t == 1 else valids[0]), t > 1
+
+        def parted(out):
+            return (out,)
+    else:
+        chunk, step = hs
+        if len(hs) != 2 or chunk.shape[1] == 1 or step.shape[1] != 1:
+            raise NotImplementedError(
+                "groups other than a chunk's rows and one token a row "
+                "beside them")
+        (r, c, d), b = chunk.shape, step.shape[0]
+        if cfg.is_moe and cfg.moe_impl == "dispatch":
+            (out, beside), _ = L.moe_block(
+                bp["mlp"], chunk, cfg, valid_len=valids[0],
+                capacity_per_row=True, tail=step[:, 0])
+            return (out, beside[:, None]), pools
+        rows = [chunk.reshape(1, r * c, d), step.reshape(1, b, d)]
+        pad = mixed_step_rows(cfg, r * c, b) - b
+        if pad:
+            rows.append(jnp.zeros((1, pad, d), chunk.dtype))
+        h, valid_len, per_row = jnp.concatenate(rows, axis=1), None, False
+
+        def parted(out):
+            return (out[:, :r * c].reshape(r, c, d),
+                    out[0, r * c:r * c + b][:, None])
     if not cfg.is_moe:
-        return L.mlp_block(bp["mlp"], h, cfg), pools
+        return parted(L.mlp_block(bp["mlp"], h, cfg)), pools
     counted = MOE_ROWS in pools
     out = L.moe_block(bp["mlp"], h, cfg, valid_len=valid_len,
-                      expert_stack=expert_stack,
-                      capacity_per_row=capacity_per_row, rows_out=counted)
+                      expert_stack=expert_stack, capacity_per_row=per_row,
+                      rows_out=counted)
     if counted:
         pools = {**pools, MOE_ROWS: pools[MOE_ROWS] + out[2]}
-    return out[0], pools
+    return parted(out[0]), pools
+
+
+def mixed_step_rows(cfg: DecoderConfig, chunk_tokens: int, rows: int) -> int:
+    """Rows the decode group of a mixed program (``paged_mixed_step``) takes
+    in a feed-forward beside ``chunk_tokens`` tokens of chunks: ``rows``;
+    where the experts are sorted, the next count at which the whole
+    program's sorted rows, ``(chunk_tokens + rows) x k``, are whole tiles of
+    the grouped matmul (``layers.grouped_matmul`` takes its kernel only
+    then, and ``ragged_dot`` is 2.5x from it: 16 rows beside 1024 at k = 4
+    become 32). The rows added are dead: zeros in, nothing read out."""
+    if not (cfg.is_moe and cfg.moe_impl == "sorted"):
+        return rows
+    tile, k = L.GROUPED_TILE_ROWS, cfg.experts_per_token
+    return next(n for n in range(rows, rows + tile)
+                if (chunk_tokens + n) * k % tile == 0)
 
 
 def _scan_layer_groups(params: Params, cfg: DecoderConfig, carry, block,  # traced
@@ -781,17 +834,33 @@ def _decode_attention(q, ck, cv, lengths, cfg: DecoderConfig,  # traced
     return out.reshape(b, t, heads, width)
 
 
-def _pool_block(bp, x, positions, start, valid, pools, table, layer,  # traced
-                num_pages: dict, page_size: int, cfg: DecoderConfig,
+class _Rows(NamedTuple):
+    """A group of a program's rows against the pool: ``B`` rows of ``T``
+    tokens at ``positions`` [B,T] = ``start[b] .. start[b]+T-1`` of the
+    sequences whose pages are ``table`` [B,mpp], ``valid`` [B] of a row's
+    tokens real."""
+    positions: jax.Array
+    start: jax.Array
+    valid: jax.Array
+    table: jax.Array
+
+
+def _pool_block(bp, xs, groups, pools, layer, num_pages: dict,  # traced
+                page_size: int, cfg: DecoderConfig,
                 attn_impl: str = "gather", lora=None, expert_stack=None):
-    """One transformer block for ``B`` rows of ``T`` tokens against the page
-    pool: ``x`` [B,T,D] at ``positions`` [B,T] = ``start[b] .. start[b]+T-1``
-    of the sequences whose pages are ``table`` [B,mpp]. THE block of every
-    program that meets the pool where it lies: the decode step (``T`` 1),
-    the speculative verify (``T`` k+1), the chunk prefill in place (``T``
-    the chunk). First norm, the operator of the block's kind
-    (``decoder.block_kind``), residual, second norm, ``_feed_forward``,
-    residual: ``decoder._block_forward``'s skeleton, the only other copy.
+    """One transformer block for GROUPS of rows against the page pool, a
+    group ``B`` rows of ``T`` tokens: ``xs[g]`` [B,T,D] at ``groups[g]``
+    (``_Rows``). THE block of every program that meets the pool where it
+    lies: the decode step (one group, ``T`` 1), the speculative verify
+    (``T`` k+1), the chunk prefill in place (``T`` the chunk), and the
+    chunk's rows with the decode rows beside them (two groups:
+    ``paged_mixed_step``). First norm, the operator of the block's kind
+    (``decoder.block_kind``), residual and second norm, a group at a time
+    (``_operator``: groups are different sequences, so their pages are
+    disjoint and each writes its rows before it attends); then
+    ``_feed_forward`` ONCE over every group's tokens; residual:
+    ``decoder._block_forward``'s skeleton, the only other copy. Returns (a
+    group's output each, the planes as written).
 
     ``pools`` holds every plane of the WHOLE pool viewed flat —
     ``k``/``v`` ``[L*P,pg,KV,Dh]`` (``[L*P,pg,KV*Dh]`` where the heads are
@@ -832,6 +901,29 @@ def _pool_block(bp, x, positions, start, valid, pools, table, layer,  # traced
     holds 2x the tokens per byte either way. A latent pool is attended in
     the ABSORBED form, so no program holds per-head K or V of the
     context."""
+    out = []
+    for x, rows in zip(xs, groups):
+        proj, pools = _operator(bp, x, rows, pools, layer, num_pages,
+                                page_size, cfg, attn_impl, lora)
+        if x.shape[1] == 1:
+            x = x + proj
+            h = L.rmsnorm(x, bp["ln2"], cfg, bias=bp.get("ln2_b"))
+        else:
+            x, h = L.add_rmsnorm(x, proj, bp["ln2"], cfg,
+                                 bias=bp.get("ln2_b"))
+        out.append((x, h))
+    fed, pools = _feed_forward(bp, [h for _, h in out],
+                               [rows.valid for rows in groups], cfg,
+                               expert_stack, pools=pools)
+    return tuple(x + y for (x, _), y in zip(out, fed)), pools
+
+
+def _operator(bp, x, rows: _Rows, pools, layer, num_pages: dict,  # traced
+              page_size: int, cfg: DecoderConfig, attn_impl: str, lora):
+    """The first norm and the operator of the block's kind over ONE group of
+    rows (``_pool_block``): (the operator's output [B,T,D], the planes with
+    the group's rows written)."""
+    positions, start, valid, table = rows
     kind = block_kind(bp)
     t, pg = x.shape[1], page_size
     # The planes the block meets: its own kind's; a cross layer's are the
@@ -887,15 +979,7 @@ def _pool_block(bp, x, positions, start, valid, pools, table, layer,  # traced
                 bp["attn"], h, positions, start, pools, pidx, pos % pg,
                 jnp.where(table >= 0, table + base, -1), cfg, attn_impl,
                 lora)
-    if t == 1:
-        x = x + proj
-        h = L.rmsnorm(x, bp["ln2"], cfg, bias=bp.get("ln2_b"))
-    else:
-        x, h = L.add_rmsnorm(x, proj, bp["ln2"], cfg, bias=bp.get("ln2_b"))
-    out, pools = _feed_forward(bp, h, cfg, expert_stack,
-                               None if t == 1 else valid,
-                               capacity_per_row=t > 1, pools=pools)
-    return x + out, pools
+    return proj, pools
 
 
 def _token_pages(table, pos, pg: int, pages: int, ring_cfg=None):  # traced
@@ -1218,17 +1302,23 @@ def _latent_attention(a, h, positions, start, pools, pidx, off,  # traced
                           **pools, "ckv": flat}
 
 
-def _pool_forward(params: Params, cache: dict, tokens: jax.Array,  # traced
-                  table: jax.Array, start: jax.Array, valid: jax.Array,
-                  cfg: DecoderConfig, attn_impl: str, lora=None,
+def _pool_forward(params: Params, cache: dict, tokens, table, start,  # traced
+                  valid, cfg: DecoderConfig, attn_impl: str, lora=None,
                   tail_at: str = "all", wanted=None):
     """``tokens`` [B,T] at positions ``start[b] ..`` of the sequences whose
     pages are ``table`` [B,mpp], through every layer against the pool where
     it lies (``_pool_block``): the ONE builder under the decode step, the
-    chunk prefill in place and the speculative verify. Returns (the last
+    chunk prefill in place, the speculative verify and the program that
+    carries a chunk's rows and the decode rows together. Returns (the last
     layer's output [B,T,D], the planes as written, still flat:
     ``_pool_planes`` hands them back as the cache holds them, once the
     caller's head has read ``x``).
+
+    Several GROUPS of rows go through the one layer scan where ``tokens``,
+    ``table``, ``start`` and ``valid`` are tuples, a group's each
+    (``paged_mixed_step``: rows of a chunk and rows of one token); what comes
+    back first is then a tuple too, a group's output each. A group runs a
+    layer's operator by itself and all of them its feed-forward together.
 
     The pool is a CARRY of the layer scan, never a scanned input/output: a
     scan's stacked outputs are a new buffer, so scanning over ``[L,P,...]``
@@ -1248,32 +1338,45 @@ def _pool_forward(params: Params, cache: dict, tokens: jax.Array,  # traced
     ``wanted`` ([B] bool, with "last"): where it names no row the tail is
     not run at all (one ``lax.cond``; what comes back is then read by
     nobody)."""
-    b, t = tokens.shape
-    x = _embed(params, tokens, cfg)
-    positions = start[:, None]
-    if t > 1:
-        positions = positions + jnp.arange(t, dtype=jnp.int32)[None, :]
+    several = isinstance(tokens, tuple)
+    if not several:
+        tokens, table, start, valid = (tokens,), (table,), (start,), (valid,)
+    xs, groups = [], []
+    for tok, tbl, st, vl in zip(tokens, table, start, valid):
+        xs.append(_embed(params, tok, cfg))
+        positions = st[:, None]
+        if tok.shape[1] > 1:
+            positions = positions + jnp.arange(
+                tok.shape[1], dtype=jnp.int32)[None, :]
+        groups.append(_Rows(positions, st, vl, tbl))
+    xs, groups = tuple(xs), tuple(groups)
     pg = _pool_geometry(cache, cfg)[1]
     num_pages = _pages_by_kind(cache)
     flat = _flat_pools(cache)
-    groups = layer_groups(cfg)
-    tail = [g for g in groups if g[2] >= cfg.n_layers - cfg.stateless_tail]
+    stretches = layer_groups(cfg)
+    tail = [g for g in stretches
+            if g[2] >= cfg.n_layers - cfg.stateless_tail]
 
     def block(bp, carry, layer, gcfg, lora_view, expert_stack):
         return _pool_block(
-            bp, carry[0], positions, start, valid, carry[1], table, layer,
-            num_pages, pg, gcfg, attn_impl=attn_impl, lora=lora_view,
-            expert_stack=expert_stack)
+            bp, carry[0], groups, carry[1], layer, num_pages, pg, gcfg,
+            attn_impl=attn_impl, lora=lora_view, expert_stack=expert_stack)
 
     if not tail:
-        return _scan_layer_groups(params, cfg, (x, flat), block, lora)
+        xs, flat = _scan_layer_groups(params, cfg, (xs, flat), block, lora)
+        return (xs if several else xs[0]), flat
+    if several:
+        raise NotImplementedError(
+            "several groups of rows through a stateless tail")
     if MOE_ROWS in flat:
         raise NotImplementedError(
             "a stateless tail whose expert layers hold a share")
+    (b, t), (positions, start, valid, table) = tokens[0].shape, groups[0]
     if cfg.layers_of("gmu"):
         flat[SSM_MEMORY] = jnp.zeros((b, t, cfg.ssm_inner), jnp.float32)
-    x, flat = _scan_layer_groups(params, cfg, (x, flat), block, lora,
-                                 groups[:len(groups) - len(tail)])
+    (x,), flat = _scan_layer_groups(
+        params, cfg, (xs, flat), block, lora,
+        stretches[:len(stretches) - len(tail)])
     seen = {n: flat.pop(n) for n in (SSM_MEMORY,) if n in flat}
     if tail_at == "last" and t > 1:
         at = jnp.maximum(valid - 1, 0)
@@ -1282,12 +1385,13 @@ def _pool_forward(params: Params, cache: dict, tokens: jax.Array,  # traced
             (x, seen))
         start, valid = start + at, valid > 0
         positions = start[:, None]
+    behind = _Rows(positions, start, valid, table)
 
     def tail_block(bp, x, layer, gcfg, lora_view, expert_stack):
         return _pool_block(
-            bp, x, positions, start, valid, {**flat, **seen}, table, layer,
-            num_pages, pg, gcfg, attn_impl=attn_impl, lora=lora_view,
-            expert_stack=expert_stack)[0]
+            bp, (x,), (behind,), {**flat, **seen}, layer, num_pages, pg,
+            gcfg, attn_impl=attn_impl, lora=lora_view,
+            expert_stack=expert_stack)[0][0]
 
     def run(x):
         return _scan_layer_groups(params, cfg, x, tail_block, lora, tail)
@@ -1385,6 +1489,68 @@ def paged_decode_multi(params: Params, cache: dict, tokens: jax.Array,  # traced
         cond, body,
         (jnp.int32(0), cache, tokens, lengths, live, budgets, key, out0))
     return out, cache, tokens, lengths, live, budgets
+
+
+def paged_mixed_step(params: Params, cache: dict, chunk: jax.Array,  # traced
+                     table_rows: jax.Array, start: jax.Array,
+                     valid_len: jax.Array, wanted: jax.Array, ride,
+                     tokens: jax.Array, lengths: jax.Array, live: jax.Array,
+                     temps: jax.Array, top_k: jax.Array, top_p: jax.Array,
+                     stop_tokens: jax.Array, budgets: jax.Array,
+                     key: jax.Array, cfg: DecoderConfig,
+                     sample_mode: str = "full", attn_impl: str = "pallas"):
+    """One chunk of each of ``R`` prompts AND one decode step of the ``B``
+    slots in ONE program, so an iteration that has both reads every weight
+    once: ``paged_chunk_prefill``'s in-place form at ``logits_at="last"``
+    (``chunk`` [R,C], ``table_rows``, ``start``, ``valid_len``, ``wanted``:
+    its arguments) beside one step of ``paged_decode_multi`` (``tokens`` ..
+    ``key``: its arguments; ``cache`` carries "table"), two groups of rows
+    through one layer scan (``_pool_forward``). In a layer each group runs
+    the operator as it does in its own program (the chunk's rows first: they
+    are other sequences than the slots', so the pages written are disjoint),
+    the feed-forward runs once over all their tokens, and behind the last
+    layer the norm and the head run once, over the chunk rows' last valid
+    positions and the slots' tokens (not at all where no row is ``wanted``
+    and no slot live). A decode row's expert layer cannot drop it, whatever
+    the chunk rows claim (``_feed_forward``).
+
+    ``ride`` (a traced bool): the slots take their step. Where false every
+    decode row is dead for this program, whatever ``live`` says: nothing of
+    the slots' state is read into a result, written or advanced (the chunk
+    program alone, under the same name). Returns (the chunk rows' logits
+    [R,V] float32, out [B,1] as a round's token buffer: -1 where nothing was
+    emitted, cache, tokens, lengths, live, budgets)."""
+    from kubeflow_tpu.serve.engine import _sample_batch
+
+    if not chunk_carries_step(cache, cfg, None, attn_impl):
+        raise NotImplementedError(
+            "a chunk and a decode step in one program: attention layers "
+            "over a pool the chunk meets in place")
+    table = cache["table"]
+    on = live & ride
+    (xc, xd), flat = _pool_forward(
+        params, cache, (chunk, tokens[:, None]), (table_rows, table),
+        (start, lengths), (valid_len, on), cfg, attn_impl)
+    rows = chunk.shape[0]
+    last = jnp.take_along_axis(
+        xc, jnp.maximum(valid_len - 1, 0)[:, None, None], axis=1)
+    logits = _last_logits(
+        params, jnp.concatenate([last, xd]),
+        jnp.ones((rows + tokens.shape[0],), jnp.int32), cfg,
+        jnp.concatenate([wanted, on]))
+    sampled = _sample_batch(logits[rows:], jax.random.split(key)[1], temps,
+                            top_k, top_p, mode=sample_mode)
+    # the slots' state after the step: ``paged_decode_multi``'s own rules
+    max_len = table.shape[1] * _pool_geometry(cache, cfg)[1]
+    tokens = jnp.where(on, sampled, tokens)
+    out = jnp.where(on, sampled, -1)[:, None]
+    lengths = jnp.where(on, lengths + 1, lengths)
+    budgets = jnp.where(on, budgets - 1, budgets)
+    live = jnp.where(on, (sampled != stop_tokens) & (budgets > 0)
+                     & (lengths + 1 < max_len), live)
+    return (logits[:rows], out,
+            {**_pool_planes(flat, cache), "table": table}, tokens, lengths,
+            live, budgets)
 
 
 def copy_pages(cache: dict, src: jax.Array, dst: jax.Array) -> dict:  # traced
@@ -1714,6 +1880,19 @@ def _chunk_in_place(cache: dict, cfg: DecoderConfig, lora,
                            set(WINDOW_PLANES))
             and k.ndim == (4 if rows else 5)
             and chunk_attention_supported(*heads, k.dtype))
+
+
+def chunk_carries_step(cache: dict, cfg: DecoderConfig, lora,
+                       attn_impl: str) -> bool:
+    """Whether a chunk program over this cache can carry the slots' decode
+    step (``paged_mixed_step``): the kernels are on, the chunk meets the
+    pool in place (``_chunk_in_place``: no int8 pool, no packed rows, no
+    call with LoRA) and every layer is of kind "attention" (per-head planes
+    or a latent pool, whatever its feed-forward): the kinds that keep a
+    state, a ring or a tail wait for their operators to be held side by side
+    in one program (ROADMAP Speed)."""
+    return (attn_impl == "pallas" and set(cfg.kinds) == {"attention"}
+            and _chunk_in_place(cache, cfg, lora, attn_impl))
 
 
 def chunk_reads_context(cache: dict, cfg: DecoderConfig, lora,

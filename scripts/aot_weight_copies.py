@@ -73,7 +73,8 @@ def serving_cell(name: str):
 
 
 def lowered_programs(cfg, batching, dev, *, relaid: bool = True,
-                     auto: bool = False, rows_logits_at: str = "last") -> dict:
+                     auto: bool = False, rows_logits_at: str = "last",
+                     mixed: bool = False) -> dict:
     """``{program: jax.stages.Lowered}`` of an engine over ``cfg`` and
     ``batching`` on the one described chip ``dev`` (a sharding): "decode"
     (one step a dispatch), "chunk[1]" and, where the engine builds the
@@ -84,6 +85,10 @@ def lowered_programs(cfg, batching, dev, *, relaid: bool = True,
     ``relaid`` says (``compiled.input_formats`` then says what it chose).
     ``rows_logits_at``: the positions whose logits "chunk[N]" returns, the
     engine's "last" unless a comparison wants the other form.
+    ``mixed``: also "mixed[N]", the chunk program that carries the slots'
+    decode step (``paged_mixed_step``) at its one width (as many rows as the
+    engine sends chunks together), where the engine builds it
+    (``paged.chunk_carries_step``).
     The caller has made ``jax.default_backend()`` answer "tpu"."""
     import jax
     import jax.numpy as jnp
@@ -94,7 +99,8 @@ def lowered_programs(cfg, batching, dev, *, relaid: bool = True,
         RIDGE_ROWS, chunk_rows_per_weight, serving_configs,
     )
     from kubeflow_tpu.serve.paged import (
-        engine_pool_shapes, paged_chunk_prefill, paged_decode_multi,
+        chunk_carries_step, engine_pool_shapes, paged_chunk_prefill,
+        paged_decode_multi, paged_mixed_step,
     )
     from kubeflow_tpu.serve.weight_layout import relay, weight_formats
 
@@ -164,6 +170,18 @@ def lowered_programs(cfg, batching, dev, *, relaid: bool = True,
             5 + last).lower(
             params, cache, sds((n, chunk_tokens)), sds((n, mpp)), sds((n,)),
             sds((n,)), *([sds((n,), jnp.bool_)] if last else []))
+    if mixed and chunk_carries_step(cache, cfg_prefill, None, "pallas"):
+        for n in (forms[-1][1],):       # its one width: the rows sent together
+            out[f"mixed[{n}]"] = jit(
+                lambda p, c, tbl, t, tr, s0, vl, ends, ride, tok, ln, lv,
+                tmp, tk, tpp, st, bd, key: paged_mixed_step(
+                    p, {**c, "table": tbl}, t, tr, s0, vl, ends, ride, tok,
+                    ln, lv, tmp, tk, tpp, st, bd, key, cfg_prefill,
+                    sample_mode="greedy", attn_impl="pallas"), 17).lower(
+                params, cache, sds((slots, mpp)), sds((n, chunk_tokens)),
+                sds((n, mpp)), sds((n,)), sds((n,)), sds((n,), jnp.bool_),
+                sds((), jnp.bool_), i32(), i32(), sds((slots,), jnp.bool_),
+                f32(), i32(), f32(), i32(), i32(), sds((2,), jnp.uint32))
     return out
 
 
@@ -287,7 +305,8 @@ def one_chip():
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("cell")
-    ap.add_argument("program", nargs="?", choices=["decode", "chunk"])
+    ap.add_argument("program", nargs="?",
+                    choices=["decode", "chunk", "mixed"])
     ap.add_argument("--default-layouts", action="store_true",
                     help="every parameter in the compiler's default layout")
     ap.add_argument("--auto", action="store_true",
@@ -304,7 +323,8 @@ def main(argv=None) -> int:
         return args.program is None or name.startswith(args.program)
 
     for name, low in lowered_programs(
-            cfg, batching, dev, relaid=not args.default_layouts).items():
+            cfg, batching, dev, relaid=not args.default_layouts,
+            mixed=True).items():
         if wanted(name):
             print(json.dumps({"cell": args.cell,
                               **describe(name, low)}), flush=True)

@@ -400,8 +400,8 @@ def main() -> int:
     if len(snapshots) >= 2 and snapshots[0] and "engine" in snapshots[0]:
         a, b = snapshots[0]["engine"], snapshots[1]["engine"]
         d = {k: b[k] - a[k] for k in b if k in a and k.startswith(
-            ("decode_", "sched_", "state_", "prefill_", "first_token",
-             "host_gap", "queue_delay"))}
+            ("decode_", "sched_", "state_", "prefill_", "mixed_",
+             "first_token", "host_gap", "queue_delay"))}
         rounds = d.get("decode_rounds") or 0
         if rounds:
             d["steps_a_round"] = d["decode_steps_dispatched"] / rounds

@@ -2077,7 +2077,9 @@ def _new_findings(relpath: str, old: str, new: str):
 class TestSeededRegressions:
     def test_pr4_full_table_reupload_is_caught(self):
         """Re-introducing the PR-4 bug — a per-round full page-table
-        upload in the dispatch hot loop — produces exactly one D103."""
+        upload in the dispatch hot loop (since PR 49 ``_ready_round``: what
+        stands before the decode program and before the chunk program that
+        carries a step alike) — produces exactly one D103."""
         fresh = _new_findings(
             "kubeflow_tpu/serve/engine.py",
             "        self._sync_decode_state()\n",
@@ -2086,7 +2088,7 @@ class TestSeededRegressions:
         assert len(fresh) == 1
         f = fresh[0]
         assert f.rule == "D103" and "self._table" in f.message
-        assert "_dispatch_round" in f.message
+        assert "_ready_round" in f.message
 
     def test_removed_router_lock_is_caught(self):
         """Dropping one router lock acquisition produces exactly one C301

@@ -492,12 +492,13 @@ def cell_programs(chip):
 
     @functools.lru_cache(maxsize=None)
     def lowered(cell: str, relaid: bool = True,
-                rows_logits_at: str = "last") -> dict:
+                rows_logits_at: str = "last", mixed: bool = False) -> dict:
         cfg, batching = serving_cell(cell)
         with pytest.MonkeyPatch.context() as mp:    # as benchmark/aot_sizes.py
             mp.setattr(jax, "default_backend", lambda: "tpu")
             return lowered_programs(cfg, batching, chip, relaid=relaid,
-                                    rows_logits_at=rows_logits_at)
+                                    rows_logits_at=rows_logits_at,
+                                    mixed=mixed)
 
     return lowered
 
@@ -612,6 +613,92 @@ def test_serving_program_copies_no_weight_on_v5e(cell_programs, cell,
     if cell == "mistral-7b.chat-open" and program == "decode":
         # the hoisted copies were the program's temporaries: 0.806 GB
         assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+# PR 49: the chunk program that carries the slots' decode step
+# (``paged.paged_mixed_step``: the chunk's rows and the slots' rows through
+# ONE layer scan, the feed-forward once over all their tokens), at its one
+# width, as many rows as the engine sends chunks together: the cells whose
+# every layer is of kind "attention". NEW programs, pinned as PR 49 left
+# them. Every digest above stands: a program with one group of rows lowers
+# to what it lowered to. The engine of these three cells no longer
+# DISPATCHES the program over rows ("chunk[2]" in the engine's form,
+# ``ROWS_PROGRAM_SINCE_PR46``): the two-row program below, no slot riding,
+# is it. It is still built and pinned, for every other pool and for
+# scripts/chunk_rows_chip.py.
+MIXED_SINCE_PR49 = {
+    ("mistral-7b.chat-open", "mixed[1]"): "66b2485d321d364b",
+    ("mixtral-8x7b.batch-longprompt", "mixed[2]"): "005fd78fc582fd87",
+    ("glm-4.7-flash.batch-longcontext", "mixed[2]"): "a58b95787ee20d98",
+}
+
+
+@pytest.mark.parametrize("cell,program", sorted(MIXED_SINCE_PR49))
+def test_mixed_program_compiles_for_v5e_with_both_kernels(cell_programs,
+                                                          cell, program):
+    """At the cell's real sizes, with the parameters as the engine holds
+    them: the program compiles for the described chip, holds the chunk
+    kernel a row and the decode kernel by name, copies no parameter (beyond
+    the cell's standing ones) and no plane of the pool, returns ``[R, V]``
+    logits and a token a slot beyond the buffers it was donated, and lowers
+    to the pinned digest."""
+    from scripts.aot_weight_copies import (
+        lowered_fingerprint, serving_cell, weight_copies,
+    )
+
+    programs = cell_programs(cell, True, "last", True)
+    assert sorted(p for p in programs if p.startswith("mixed")) == sorted(
+        p for c, p in MIXED_SINCE_PR49 if c == cell)
+    lowered = programs[program]
+    assert lowered_fingerprint(lowered) == MIXED_SINCE_PR49[cell, program]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    copies = weight_copies(text, lowered.args_info[0][0])
+    assert {leaf for c in copies for leaf in c["leaf"]} \
+        <= SERVING_CELLS[cell]
+    cfg, batching = serving_cell(cell)
+    # nothing the size of a layer's pages of one plane is copied
+    page = batching.page_size * (
+        cfg.kv_lora_rank + cfg.qk_rope_dim if cfg.is_latent
+        else cfg.n_kv_heads * cfg.head_dim)
+    assert max([math.prod(c["shape"]) for c in copies] + [0]) \
+        < batching.max_pages * page
+    step_kernel, chunk_kernel = ATTENTION_KERNELS[cell]
+    rows = int(program[len("mixed["):-1])
+    assert "tpu_custom_call" in text
+    assert chunk_kernel in text and step_kernel in text
+    ma = compiled.memory_analysis()
+    result = ma.output_size_in_bytes - ma.alias_size_in_bytes
+    assert rows * cfg.vocab_size * 4 <= result < 16 * 2 ** 20, result
+    assert ma.temp_size_in_bytes < 256 * 2 ** 20
+
+
+@pytest.mark.parametrize("cell,carries", [
+    ("mistral-7b.chat-open", True),
+    ("mixtral-8x7b.batch-longprompt", True),
+    ("glm-4.7-flash.batch-longcontext", True),
+    ("lfm2-24b-a2b.batch-longanswer", False),           # conv layers
+    ("k-exaone-236b-a23b.batch-mixedlength", False),    # window layers
+    ("solar-open2-250b.batch-longdoc", False),          # linear layers
+    ("phi-4-mini-flash.batch-reasoning", False),        # ssm, gmu, cross
+])
+def test_which_cells_chunk_program_carries_the_step(cell, carries):
+    """The rule reads the stack and the pool (``paged.chunk_carries_step``),
+    at the cells' own shapes: every layer of kind "attention"."""
+    from scripts.aot_weight_copies import serving_cell
+
+    from kubeflow_tpu.serve.engine import serving_configs
+    from kubeflow_tpu.serve.paged import (
+        chunk_carries_step, engine_pool_shapes,
+    )
+
+    cfg, b = serving_cell(cell)
+    pre, dec = serving_configs(cfg, b)
+    cache = {n: jax.ShapeDtypeStruct(shape, dt) for n, (shape, dt) in
+             engine_pool_shapes(dec, b.max_batch_size, int(b.max_pages),
+                                b.page_size).items()}
+    assert chunk_carries_step(cache, pre, None, "pallas") == carries
+    assert not chunk_carries_step(cache, pre, None, "gather")
 
 
 @pytest.mark.parametrize("cell", sorted(
@@ -784,6 +871,37 @@ def test_ssm_scan_compiles_for_v5e(chip):
 
 
 REASONING = "phi-4-mini-flash.batch-reasoning"
+# program -> the digest of the reasoning cell's programs, the one cell whose
+# stack ends in a stateless tail (``paged._pool_forward``'s branch for it:
+# the tail's layers run for the rows that end, at one position a row).
+# "rows[2]" and "rows[1]" are the program over rows as the engine builds it
+# (the one-row form is what it sends for one prompt alone), "chunk[2]" the
+# all-position form. Recorded on PR 48's commit (3ad1a64) AND on PR 49's
+# tree, which rewrote that branch for groups of rows (the carry a tuple of
+# one group, the rows behind the tail a ``_Rows``): the same five digests,
+# so the cell runs the parent's programs. A change that means to move one
+# records the new digest here and says why.
+REASONING_SINCE_PR48 = {
+    "decode": "2dbb416d3dfd499d",
+    "chunk[1]": "60c103aa02f0bca6",
+    "chunk[2]": "2bdf2fdb8ffe3367",
+    "rows[2]": "ea139d1679aaca64",
+    "rows[1]": "d452283b3fbaf306",
+}
+
+
+@pytest.mark.parametrize("program", sorted(REASONING_SINCE_PR48))
+def test_reasoning_program_lowers_to_what_pr48_left(cell_programs, program):
+    """The ssm, gmu and cross arms of the pool's block and the stateless
+    tail held to the standard of the other six serving cells: the reasoning
+    cell's programs lower, at the cell's shapes for a described v5e, to the
+    digests of the commit before the block took groups of rows."""
+    from scripts.aot_weight_copies import lowered_fingerprint
+
+    form = "all" if program == "chunk[2]" else "last"
+    name = "chunk[2]" if program == "rows[2]" else program
+    assert lowered_fingerprint(cell_programs(REASONING, True, form)[name]) \
+        == REASONING_SINCE_PR48[program]
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk[2]"])

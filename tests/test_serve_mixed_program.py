@@ -1,0 +1,555 @@
+"""A chunk program that carries the decode step (ISSUE 49), on the CPU with
+the kernels interpreted, in float32. The program: ``paged.paged_mixed_step``
+(a chunk's rows and the slots' rows, two groups through ``_pool_block``,
+ONE feed-forward over their tokens) against the chunk program and then the
+decode step on the same inputs, over three tiny stacks: per-head planes with
+a plain MLP, per-head planes with capacity-dispatch experts at a factor that
+DROPS chunk rows, a latent pool with a leading dense layer, sorted experts
+and a shared expert. The engine: which stacks send it, that a run's greedy
+tokens are the full recompute's, the counters, the spans, the programs'
+names, and that nothing compiles after the engine is built."""
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.device import CompileCounter
+from kubeflow_tpu.core.serving import BatchingSpec
+from kubeflow_tpu.models import layers as L
+from kubeflow_tpu.models.config import preset
+from kubeflow_tpu.models.decoder import decoder_forward, init_decoder_params
+from kubeflow_tpu.serve.engine import (
+    LLMEngine, SamplingParams, serving_configs,
+)
+from kubeflow_tpu.serve.paged import (
+    mixed_step_rows, paged_chunk_prefill, paged_decode_multi,
+    paged_mixed_step, pool_shapes,
+)
+
+PAGE, CHUNK, MPP = 16, 32, 8
+SLOTS = 4
+KINDS = ("dense", "dispatch", "latent")
+
+
+def _config(kind: str):
+    over = dict(dtype="float32", param_dtype="float32", max_seq_len=1024)
+    if kind == "dense":
+        return preset("tiny", head_dim=128, **over)
+    if kind == "dispatch":
+        # Mixtral-like; the published factor drops rows of crowded chunks
+        return preset("tiny-moe", head_dim=128, capacity_factor=1.25, **over)
+    return preset("tiny-glm", **over)
+
+
+@functools.lru_cache(maxsize=None)
+def _model(kind: str):
+    cfg = _config(kind)
+    return cfg, init_decoder_params(jax.random.PRNGKey(3), cfg)
+
+
+def _tokens(seed: int, n: int) -> np.ndarray:
+    """Tokens from FEW ids, so a chunk crowds the same experts and a
+    capacity of 1.25 x the even share overflows."""
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(3, 256, 3)
+    return ids[rng.choice(3, n, p=[0.8, 0.1, 0.1])].astype(np.int32)
+
+
+def _batching(slots=SLOTS):
+    return BatchingSpec(max_batch_size=slots, max_seq_len=MPP * PAGE,
+                        page_size=PAGE, chunked_prefill_tokens=CHUNK,
+                        paged_attn_impl="pallas")
+
+
+@functools.lru_cache(maxsize=None)
+def _programs(kind: str, slots: int = SLOTS):
+    """(the chunk program over rows, one decode step with its sampler, the
+    two in one), each as the engine builds it for ``kind``'s stack."""
+    cfg, _ = _model(kind)
+    pre, dec = serving_configs(cfg, _batching(slots))
+    chunk = jax.jit(lambda p, c, t, tr, st, vl, ends: paged_chunk_prefill(
+        p, c, t, tr, st, vl, pre, context_pages=MPP,
+        paged_attn_impl="pallas", logits_at="last", wanted=ends))
+    step = jax.jit(lambda p, c, s, key: paged_decode_multi(
+        p, c, s["tokens"], s["lengths"], s["live"], s["temps"], s["top_k"],
+        s["top_p"], s["stops"], s["budgets"], key, dec, 1,
+        sample_mode="greedy", attn_impl="pallas"))
+    mixed = jax.jit(lambda p, c, t, tr, st, vl, ends, ride, s, key:
+                    paged_mixed_step(
+                        p, c, t, tr, st, vl, ends, ride, s["tokens"],
+                        s["lengths"], s["live"], s["temps"], s["top_k"],
+                        s["top_p"], s["stops"], s["budgets"], key, pre,
+                        sample_mode="greedy", attn_impl="pallas"))
+    return chunk, step, mixed
+
+
+def _rows(rows):
+    """The chunk program's arrays for ``rows``: (tokens, table row, start,
+    valid, ends its prompt) each, None a dead row."""
+    block = np.zeros((len(rows), CHUNK), np.int32)
+    table = np.full((len(rows), MPP), -1, np.int32)
+    start, valid = (np.zeros((len(rows),), np.int32) for _ in range(2))
+    ends = np.zeros((len(rows),), np.bool_)
+    for r, row in enumerate(rows):
+        if row is None:
+            continue
+        toks, table[r], start[r], valid[r], ends[r] = row
+        block[r, :valid[r]] = toks[start[r]:start[r] + valid[r]]
+    return tuple(map(jnp.asarray, (block, table, start, valid, ends)))
+
+
+@functools.lru_cache(maxsize=None)
+def _scene(kind: str, slots: int = SLOTS):
+    """A pool in which prompt a holds 24 tokens (its next chunk starts
+    MID-PAGE, whole, and does not end it) and prompt b 64 (its next chunk 19
+    tokens long, its last), and every slot a context of its own length on
+    pages of its own; the slots' table; each slot's next token."""
+    cfg, params = _model(kind)
+    chunk, _, _ = _programs(kind, slots)
+    pool = {n: jnp.zeros(shape, dt) for n, (shape, dt) in
+            pool_shapes(cfg, 10 + 2 * slots, PAGE).items()}
+    a, b = _tokens(1, 24 + CHUNK), _tokens(2, 64 + 19)
+    row_a = np.asarray([0, 1, 2, 3, -1, -1, -1, -1], np.int32)
+    row_b = np.asarray([4, 5, 6, 7, 8, 9, -1, -1], np.int32)
+    table = np.full((slots, MPP), -1, np.int32)
+    held = []
+    for s in range(slots):
+        table[s, :2] = 10 + 2 * s, 11 + 2 * s
+        held.append((_tokens(10 + s, 32), table[s], 0, 5 + 3 * (s % 7),
+                     True))
+    for rows in ([(a, row_a, 0, 24, False), (b, row_b, 0, CHUNK, False)],
+                 [None, (b, row_b, CHUNK, CHUNK, False)],
+                 *[held[i:i + 2] for i in range(0, slots, 2)]):
+        _, pool = chunk(params, pool, *_rows(rows))
+    state = {
+        "tokens": np.asarray([int(h[0][h[3]]) for h in held], np.int32),
+        "lengths": np.asarray([h[3] for h in held], np.int32),
+        "live": np.ones((slots,), np.bool_),
+        "temps": np.zeros((slots,), np.float32),
+        "top_k": np.zeros((slots,), np.int32),
+        "top_p": np.ones((slots,), np.float32),
+        "stops": np.full((slots,), -1, np.int32),
+        "budgets": np.full((slots,), 9, np.int32)}
+    rows = [(a, row_a, 24, CHUNK, False), (b, row_b, 64, 19, True)]
+    return pool, table, state, rows
+
+
+def _close(got, want, what):
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), rtol=2e-5,
+                               atol=2e-5, err_msg=what)
+
+
+def _both_ways(kind, state, rows, ride=True, params=None, slots=SLOTS):
+    """(what the chunk program and then the decode step leave, what the one
+    program leaves): each (chunk logits, the round's tokens, cache, tokens,
+    lengths, live, budgets)."""
+    chunk, step, mixed = _programs(kind, slots)
+    pool, table, _, _ = _scene(kind, slots)
+    params = _model(kind)[1] if params is None else params
+    state = {n: jnp.asarray(v) for n, v in state.items()}
+    key = jax.random.PRNGKey(11)
+    logits, after = chunk(params, pool, *_rows(rows))
+    want = (logits,) + tuple(step(
+        params, {**after, "table": jnp.asarray(table)}, state, key))
+    got = mixed(params, {**pool, "table": jnp.asarray(table)}, *_rows(rows),
+                jnp.asarray(ride), state, key)
+    return want, got
+
+
+SLOT_CASES = {
+    "all-live": [True, True, True, True],
+    "some-dead": [True, False, False, True],
+    "all-dead": [False, False, False, False],
+    "one-at-its-budget": [True, True, True, True],
+}
+
+
+@pytest.mark.parametrize("case", sorted(SLOT_CASES))
+@pytest.mark.parametrize("kind", KINDS)
+def test_the_one_program_is_the_chunk_program_then_the_decode_step(kind,
+                                                                   case):
+    """Row a's chunk does not end its prompt, row b's does: b's logits, the
+    slots' tokens, every plane of the pool and the slots' carried state."""
+    _, _, state, rows = _scene(kind)
+    state = {**state, "live": np.asarray(SLOT_CASES[case])}
+    if case == "one-at-its-budget":
+        state["budgets"] = np.asarray([9, 1, 9, 9], np.int32)
+    want, got = _both_ways(kind, state, rows)
+    _close(got[0][1], want[0][1], "the logits of the row that ends")
+    np.testing.assert_array_equal(got[1], want[1])      # the round's tokens
+    live = np.asarray(SLOT_CASES[case])
+    assert (np.asarray(got[1])[:, 0] >= 0).tolist() == live.tolist()
+    for name in want[2]:
+        _close(got[2][name], want[2][name], f"plane {name}")
+    for i, name in enumerate(("tokens", "lengths", "live", "budgets"), 3):
+        np.testing.assert_array_equal(got[i], want[i], err_msg=name)
+    if case == "one-at-its-budget":
+        assert np.asarray(got[5]).tolist() == [True, False, True, True]
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_where_the_slots_do_not_ride_it_is_the_chunk_program(kind):
+    """``ride`` false: live slots or not, nothing of theirs is written,
+    emitted or advanced; a dead chunk row beside a live one."""
+    pool, table, state, rows = _scene(kind)
+    chunk, _, mixed = _programs(kind)
+    _, params = _model(kind)
+    rows = [None, rows[1]]
+    logits, after = chunk(params, pool, *_rows(rows))
+    dev = {n: jnp.asarray(v) for n, v in state.items()}
+    got = mixed(params, {**pool, "table": jnp.asarray(table)}, *_rows(rows),
+                jnp.asarray(False), dev, jax.random.PRNGKey(11))
+    _close(got[0][1], logits[1], "the logits of the row that ends")
+    assert np.all(np.asarray(got[1]) == -1)
+    for name in after:
+        _close(got[2][name], after[name], f"plane {name}")
+    for i, name in enumerate(("tokens", "lengths", "live", "budgets"), 3):
+        np.testing.assert_array_equal(got[i], state[name], err_msg=name)
+
+
+def test_sixteen_decode_tokens_on_one_expert_lose_none():
+    """A router that sends EVERY token to experts 0 and 1: a chunk row
+    overflows its capacity there (the two programs drop the same pairs),
+    and the sixteen slots' tokens, a group whose capacity is its size, are
+    the drop-free decode step's."""
+    cfg, params = _model("dispatch")
+    mlp = params["layers"]["mlp"]
+    flat = {**params, "layers": {**params["layers"], "mlp": {
+        **mlp, "router": jnp.zeros_like(mlp["router"])}}}
+    _, _, state, rows = _scene("dispatch", 16)
+    assert L.moe_capacity(cfg, CHUNK) < CHUNK       # a row's 32 cannot fit
+    want, got = _both_ways("dispatch", state, rows, params=flat, slots=16)
+    _close(got[0][1], want[0][1], "the logits of the row that ends")
+    np.testing.assert_array_equal(got[1], want[1])
+    for name in want[2]:
+        _close(got[2][name], want[2][name], f"plane {name}")
+    # and dropping DID change the chunk rows: ample capacity reads otherwise
+    ample = dataclasses.replace(cfg, capacity_factor=float(cfg.num_experts))
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, CHUNK, cfg.hidden))
+    layer = jax.tree.map(lambda a: a[0], flat["layers"]["mlp"])
+    tight, _ = L.moe_block(layer, x, cfg, capacity_per_row=True)
+    roomy, _ = L.moe_block(layer, x, ample, capacity_per_row=True)
+    assert float(jnp.abs(tight - roomy).max()) > 1e-3
+
+
+def test_the_tail_of_a_dispatch_is_a_group_that_cannot_drop():
+    """``layers._moe_dispatch(tail=)``: the rows of ``x`` are what they are
+    without a tail; the tail's tokens are the dense oracle's, also where
+    every one of them and every token of ``x`` wants the same expert."""
+    cfg, params = _model("dispatch")
+    layer = jax.tree.map(lambda a: a[0], params["layers"]["mlp"])
+    layer = {**layer, "router": jnp.zeros_like(layer["router"])}
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, CHUNK, cfg.hidden))
+    tail = jax.random.normal(jax.random.PRNGKey(6), (16, cfg.hidden))
+    alone, _ = L.moe_block(layer, x, cfg, capacity_per_row=True)
+    (out, beside), _ = L.moe_block(layer, x, cfg, capacity_per_row=True,
+                                   tail=tail)
+    _close(out, alone, "the rows beside a tail")
+    oracle, _ = L.moe_block(layer, tail[:, None],
+                            dataclasses.replace(cfg, moe_impl="dense"))
+    _close(beside, oracle[:, 0], "the tail")
+    with pytest.raises(NotImplementedError, match="tail of tokens"):
+        L.moe_block(layer, x, dataclasses.replace(cfg, moe_impl="dense"),
+                    tail=tail)
+
+
+@pytest.mark.parametrize("chunk_tokens,slots,k,rows", [
+    (1024, 16, 4, 32),      # GLM-4.7-Flash's cell: 4224 = 33 tiles
+    (512, 16, 4, 32),
+    (64, 4, 2, 64),         # the tiny stack here
+    (1024, 16, 6, 64),
+    (1000, 16, 4, 24),
+])
+def test_sorted_rows_are_whole_tiles(chunk_tokens, slots, k, rows):
+    cfg = preset("tiny-glm", experts_per_token=k)
+    got = mixed_step_rows(cfg, chunk_tokens, slots)
+    assert got == rows >= slots
+    assert (chunk_tokens + got) * k % L.GROUPED_TILE_ROWS == 0
+    assert mixed_step_rows(preset("tiny-moe"), chunk_tokens, slots) == slots
+    assert mixed_step_rows(preset("tiny"), chunk_tokens, slots) == slots
+
+
+def test_other_stacks_and_pools_are_refused():
+    cfg, params = _model("dense")
+    pool = {n: jnp.zeros(shape, dt) for n, (shape, dt) in
+            pool_shapes(cfg, 8, PAGE).items()}
+    state = _scene("dense")[2]
+    args = (*_rows([None]), jnp.asarray(True),
+            *(jnp.asarray(state[n]) for n in (
+                "tokens", "lengths", "live", "temps", "top_k", "top_p",
+                "stops", "budgets")), jax.random.PRNGKey(0))
+    table = jnp.full((SLOTS, MPP), -1, jnp.int32)
+    with pytest.raises(NotImplementedError, match="in one program"):
+        paged_mixed_step(params, {**pool, "table": table}, *args, cfg,
+                         attn_impl="gather")
+    lfm2 = preset("tiny-lfm2", dtype="float32", param_dtype="float32")
+    with pytest.raises(NotImplementedError, match="in one program"):
+        paged_mixed_step(
+            init_decoder_params(jax.random.PRNGKey(1), lfm2),
+            {**{n: jnp.zeros(shape, dt) for n, (shape, dt) in
+                pool_shapes(lfm2, 8, PAGE).items()}, "table": table},
+            *args, lfm2, attn_impl="pallas")
+
+
+# -- the engine ------------------------------------------------------------------
+
+def _engine(kind, impl="pallas", **kw):
+    cfg, params = _model(kind)
+    spec = dict(max_batch_size=SLOTS, max_seq_len=256, page_size=PAGE,
+                chunked_prefill_tokens=CHUNK, enable_prefix_caching=False,
+                max_concurrent_prefills=2, paged_attn_impl=impl,
+                decode_steps=1, prefill_interleave_steps=1)
+    return LLMEngine(cfg, BatchingSpec(**{**spec, **kw}), params=params)
+
+
+PROMPTS = [_tokens(4, 70), _tokens(5, 40), _tokens(6, 100), _tokens(7, 33),
+           _tokens(8, 90)]
+ARRIVALS = {0: (0, 1), 3: (2, 3), 8: (4,)}      # scheduler iteration: prompts
+
+
+def _serve(eng, n=12, sampling=None):
+    """PROMPTS arriving while earlier ones prefill and decode: a run that
+    meets iterations with a chunk alone, a round alone and both."""
+    sp = sampling or SamplingParams(max_new_tokens=n, temperature=0.0)
+    reqs = {}
+    for i in range(600):
+        for j in ARRIVALS.get(i, ()):
+            reqs[j] = eng.submit(list(map(int, PROMPTS[j])), sp)
+        eng.step()
+        if len(reqs) == len(PROMPTS) and all(
+                r.done.is_set() for r in reqs.values()):
+            return [list(reqs[j].output_tokens) for j in sorted(reqs)]
+    raise AssertionError("requests did not finish")
+
+
+@functools.lru_cache(maxsize=None)
+def _recompute(kind: str, j: int, n: int = 12) -> list:
+    """``n`` greedy tokens behind prompt ``j`` by full recompute: a whole
+    forward pass over what stands so far, padded to one length (causal: what
+    lies behind a position cannot move it). A capacity-dispatch stack drops
+    by the CHUNK a token stands in, which no whole forward pass does: its
+    prompt is served alone by the two programs (the gathered arm, one
+    prefill at a time)."""
+    if kind == "dispatch":
+        eng = _engine(kind, "gather", max_concurrent_prefills=1)
+        req = eng.submit(list(map(int, PROMPTS[j])), SamplingParams(
+            max_new_tokens=n, temperature=0.0))
+        while not req.done.is_set():
+            eng.step()
+        return list(req.output_tokens)
+    cfg, params = _model(kind)
+    forward = _padded_forward(kind)
+    toks = list(map(int, PROMPTS[j]))
+    for _ in range(n):
+        block = np.zeros((256,), np.int32)
+        block[:len(toks)] = toks
+        toks.append(int(jnp.argmax(forward(jnp.asarray(block))[
+            len(toks) - 1])))
+    return toks[len(PROMPTS[j]):]
+
+
+@functools.lru_cache(maxsize=None)
+def _padded_forward(kind: str):
+    cfg, params = _model(kind)
+    return jax.jit(lambda t: decoder_forward(params, t[None], cfg)[0][0])
+
+
+@pytest.mark.parametrize("kind,kw", [
+    *((kind, {}) for kind in KINDS),
+    # the option governs decode-only rounds; a step that rides goes the
+    # prefill path's drop-free way (``serving_configs``): the same tokens
+    ("dispatch", {"moe_decode_impl": "zero_drop"}),
+], ids=[*KINDS, "dispatch-zero_drop"])
+def test_engine_tokens_are_the_full_recomputes(kind, kw):
+    eng = _engine(kind, **kw)
+    assert eng._mixed
+    c = eng.counters()
+    assert (c["mixed_programs_dispatched"], c["mixed_decode_rows_sum"]) \
+        == (0, 0)
+    assert _serve(eng) == [_recompute(kind, j) for j in range(len(PROMPTS))]
+    c = eng.counters()
+    # ten chunk programs; the two of the first pass find no slot live
+    assert (c["prefill_programs_dispatched"],
+            c["mixed_programs_dispatched"]) == (10, 8)
+    assert c["mixed_decode_rows_sum"] >= c["mixed_programs_dispatched"]
+    # a program that carried a round is one round, one step, one program
+    assert c["decode_rounds"] == c["decode_steps_dispatched"]
+    assert c["decode_tokens_emitted"] == 11 * len(PROMPTS)
+    eng._allocator.assert_quiescent()
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_longer_round_follows_as_the_decode_program(kind):
+    """At the defaults' caps (32 steps, 8 beside a prefill) the chunk
+    program carries ONE step and the round of the cap follows it."""
+    eng = _engine(kind, decode_steps=32, prefill_interleave_steps=8)
+    assert _serve(eng) == [_recompute(kind, j) for j in range(len(PROMPTS))]
+    c = eng.counters()
+    assert c["mixed_programs_dispatched"] > 0
+    assert c["decode_steps_dispatched"] > c["decode_rounds"]
+
+
+def test_sampled_streams_ride_too():
+    """Sampling traffic compiles the program's other modes at first use,
+    like the decode program's; every request gets its tokens."""
+    eng = _engine("dense")
+    out = _serve(eng, sampling=SamplingParams(
+        max_new_tokens=6, temperature=0.8, top_k=5))
+    assert [len(o) for o in out] == [6] * len(PROMPTS)
+    assert eng.counters()["mixed_programs_dispatched"] > 0
+
+
+OTHER_STACKS = {
+    # kind of layer -> (preset, engine options that its tests use)
+    "conv": ("tiny-lfm2", dict(max_seq_len=128, page_size=16,
+                               chunked_prefill_tokens=32)),
+    "window": ("tiny-exaone", dict(max_seq_len=128, page_size=8,
+                                   chunked_prefill_tokens=16,
+                                   enable_prefix_caching=False)),
+    "linear": ("tiny-solar", dict(max_seq_len=128, page_size=8,
+                                  chunked_prefill_tokens=16,
+                                  enable_prefix_caching=False)),
+    "ssm": ("tiny-phi4flash", dict(max_seq_len=128, page_size=8,
+                                   chunked_prefill_tokens=16,
+                                   enable_prefix_caching=False)),
+}
+
+
+@pytest.mark.parametrize("layer", sorted(OTHER_STACKS))
+def test_a_stack_with_another_kind_of_layer_sends_two_programs(layer):
+    name, opts = OTHER_STACKS[layer]
+    cfg = preset(name, dtype="float32", param_dtype="float32")
+    assert layer in cfg.kinds
+    eng = LLMEngine(cfg, BatchingSpec(
+        max_batch_size=3, max_concurrent_prefills=2, decode_steps=1,
+        prefill_interleave_steps=1, paged_attn_impl="pallas", **opts))
+    assert not eng._mixed
+    sp = SamplingParams(max_new_tokens=5, temperature=0.0)
+    rng = np.random.default_rng(3)
+    reqs = [eng.submit([int(t) for t in rng.integers(3, 200, 40)], sp)]
+    for i in range(300):
+        eng.step()
+        if i == 2:
+            reqs.append(eng.submit(
+                [int(t) for t in rng.integers(3, 200, 50)], sp))
+        if len(reqs) == 2 and all(r.done.is_set() for r in reqs):
+            break
+    c = eng.counters()
+    assert c["prefill_programs_dispatched"] > 0 and c["decode_rounds"] > 0
+    assert (c["mixed_programs_dispatched"], c["mixed_decode_rows_sum"]) \
+        == (0, 0)
+
+
+@pytest.mark.parametrize("why,kw", [
+    ("the gathered arm", dict(impl="gather")),
+    ("an int8 pool", dict(kv_cache_dtype="int8")),
+    ("adapter buffers", dict(lora={"max_adapters": 2})),
+    ("a speculative round", dict(speculative={"mode": "ngram"})),
+])
+def test_what_else_keeps_two_programs(why, kw):
+    from kubeflow_tpu.core.serving import LoRASpec, SpeculativeSpec
+
+    if "lora" in kw:
+        kw = {"lora": LoRASpec(**kw["lora"])}
+    if "speculative" in kw:
+        kw = {"speculative": SpeculativeSpec(**kw["speculative"])}
+    eng = _engine("dense", **kw)
+    assert not eng._mixed, why
+    got = _serve(eng, n=4)
+    assert [len(o) for o in got] == [4] * len(PROMPTS)
+    assert eng.counters()["mixed_programs_dispatched"] == 0
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_no_compile_after_construction(kind):
+    """The program is compiled and run when the engine is built, at its
+    one width, as are the decode programs: a run that meets chunk-only,
+    decode-only and mixed iterations (one prompt's chunk beside a dead row
+    among them) compiles nothing once every OTHER program it needs has run
+    (a first run of the same traffic)."""
+    eng = _engine(kind)
+    assert eng._chunk_rows == 2
+    # the engine's own warm-up reached it, no slot riding
+    sizes = eng._paged_mixed._cache_size()
+    assert sizes == 1
+    _serve(eng)
+    assert eng._paged_mixed._cache_size() == sizes
+    compiles = CompileCounter()
+    compiles.start()
+    _serve(eng)
+    assert compiles.stop() == 0, compiles.names
+    c = eng.counters()
+    assert 0 < c["mixed_programs_dispatched"] \
+        < c["prefill_programs_dispatched"]
+    assert c["decode_rounds"] > c["mixed_programs_dispatched"]
+
+
+def test_a_dense_model_at_its_ridge_builds_it_one_row_wide():
+    cfg, params = _model("dense")
+    eng = LLMEngine(cfg, BatchingSpec(
+        max_batch_size=2, max_seq_len=1024, page_size=PAGE,
+        chunked_prefill_tokens=256, paged_attn_impl="pallas",
+        decode_steps=1, prefill_interleave_steps=1,
+        max_concurrent_prefills=2), params=params)
+    assert eng._mixed and eng._chunk_rows == 1
+    assert eng._paged_mixed._cache_size() == 1
+    assert not hasattr(eng, "_paged_chunks")
+
+
+def test_both_dispatch_spans_carry_their_attributes(monkeypatch):
+    from test_serve_chunk_rows import record_spans
+
+    eng = _engine("dispatch")
+    seen = record_spans(monkeypatch)
+    _serve(eng)
+    names = [name for name, _ in seen]
+    mixed = [i for i, name in enumerate(names[:-1])
+             if name == "engine.prefill_dispatch"
+             and names[i + 1:i + 2] == ["engine.decode_dispatch"]]
+    c = eng.counters()
+    assert len(mixed) == c["mixed_programs_dispatched"] > 0
+    rows = 0
+    for i in mixed:
+        assert set(seen[i][1]) == {"slot", "pos", "chunks"}
+        step = seen[i + 1][1]
+        assert set(step) == {"round", "k_steps", "live", "context"}
+        assert step["k_steps"] == 1 and step["live"] >= 1
+        assert step["context"] >= step["live"]
+        rows += step["live"]
+    assert rows == c["mixed_decode_rows_sum"]
+    # every round has its span, carried or not, and its fetch
+    rounds = [a["round"] for n, a in seen if n == "engine.decode_dispatch"]
+    assert rounds == list(range(c["decode_rounds"]))
+
+
+def test_the_programs_keep_their_names(monkeypatch):
+    """On the chip ``program_kernels`` names what was dispatched (wrapped
+    here as ``__init__`` wraps them there): the one-row chunk program and
+    the decode program under the names the benchmark asks for, the new
+    program under a third, and no program over rows beside it."""
+    monkeypatch.setattr(
+        "kubeflow_tpu.runtime.device_report.lowered_kernel_calls",
+        lambda jitted, *args: {})
+    eng = _engine("dense")
+    for attr, name in (("_paged_chunk", "paged_chunk_prefill"),
+                       ("_paged_chunks", "paged_chunk_prefill"),
+                       ("_paged_mixed", "paged_mixed"),
+                       ("_paged_decode_n", "paged_decode")):
+        setattr(eng, attr, eng._introspected(name, getattr(eng, attr)))
+    eng._warm_rows_program()
+    assert set(eng.program_kernels) == {f"paged_mixed[2x{CHUNK},greedy]"}
+    _serve(eng, n=3)
+    names = set(eng.program_kernels)
+    assert "paged_decode[1,greedy]" in names
+    # one prompt's chunk with no slot to carry: the one-row program, named
+    # by its bucket
+    assert any(n.startswith(f"paged_chunk_prefill[1x{CHUNK},")
+               for n in names)
+    assert not any(n.startswith("paged_chunk_prefill[2x") for n in names)
+    assert [n for n in names if n.startswith("paged_mixed[")] == [
+        f"paged_mixed[2x{CHUNK},greedy]"]
