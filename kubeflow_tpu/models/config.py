@@ -3,7 +3,8 @@
 Presets cover the BASELINE.json configs (Llama-3-8B, Gemma-2B, Mixtral-8x7B)
 and the models the benchmark serves at their published widths (GLM-4.7-Flash,
 LFM2-24B-A2B, K-EXAONE-236B-A23B, Solar-Open2-250B,
-Phi-4-mini-flash-reasoning), plus tiny variants of each structure for tests.
+Phi-4-mini-flash-reasoning, Falcon-H1-34B-Instruct), plus tiny variants of
+each structure for tests.
 Architecture facts are from the public model cards and ``config.json``
 files.
 """
@@ -21,6 +22,10 @@ import jax.numpy as jnp
 # kind whose last layer in front of the tail they read).
 STATELESS_KINDS = {"gmu": "ssm", "cross": "attention"}
 SSM_KINDS = ("ssm", *STATELESS_KINDS)
+# A layer of the key's kind ALSO keeps the cache planes of these kinds: a
+# "parallel" layer its K and V rows a token, as an attention layer does,
+# beside the state a sequence that is its own.
+ALSO_HOLDS = {"parallel": ("attention",)}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -123,7 +128,35 @@ class DecoderConfig:
     # learned lambda. ``attn_bias``: biases on its projections.
     # ``use_rope`` False: no layer rotates q and k. ``norm_kind``: "rms", or
     # "layer" (LayerNorm with weight and bias) for every norm of the stack.
+    # A "parallel" layer runs TWO operators on its one normed input and adds
+    # both to the residual (layers.ssd_block, ops/ssd.py): attention as an
+    # "attention" layer has it, and a Mamba-2 (SSD) mixer of ``ssd_heads``
+    # heads of ``ssd_head_dim`` values, each with a ``[ssd_state,
+    # ssd_head_dim]`` float32 state and ONE scalar decay a token, ``B`` and
+    # ``C`` shared by the heads of each of ``ssd_groups`` groups, behind a
+    # causal depthwise convolution of ``conv_taps`` taps over ``[x | B |
+    # C]``; the chunked form walks blocks of ``ssd_chunk`` positions. Its
+    # layer keeps K and V rows a token AND the state and the convolution's
+    # tail, one entry a SEQUENCE. A stack of them holds no other kind.
     layer_kinds: tuple = ()
+    ssd_heads: int = 0
+    ssd_head_dim: int = 0
+    ssd_state: int = 0
+    ssd_groups: int = 1
+    ssd_chunk: int = 128
+    # Fixed multipliers of a model parameterised for width transfer, each
+    # applied where the forward pass has it (() and 1.0: none).
+    # ``embed_multiplier`` a token's embedding, ``head_multiplier`` the
+    # logits; ``attn_multipliers`` (in, out, key): the attention branch's
+    # input, its output, its keys; ``ssd_multipliers`` (in, out, z, x, B, C,
+    # dt): the SSD branch's input, its output and the five column blocks of
+    # its in-projection; ``mlp_multipliers`` (gate, down): the gate inside
+    # its activation, the feed-forward's output.
+    embed_multiplier: float = 1.0
+    head_multiplier: float = 1.0
+    attn_multipliers: tuple = ()
+    ssd_multipliers: tuple = ()
+    mlp_multipliers: tuple = ()
     ssm_state: int = 0
     ssm_inner: int = 0
     ssm_dt_rank: int = 0
@@ -180,10 +213,32 @@ class DecoderConfig:
     def __post_init__(self):
         # A configuration file's list (JSON has no tuple) stays hashable.
         object.__setattr__(self, "layer_kinds", tuple(self.layer_kinds))
+        for name, n in (("attn_multipliers", 3), ("ssd_multipliers", 7),
+                        ("mlp_multipliers", 2)):
+            value = tuple(float(m) for m in getattr(self, name))
+            if len(value) not in (0, n):
+                raise ValueError(f"{name} holds {n} multipliers or none")
+            object.__setattr__(self, name, value)
         unknown = set(self.layer_kinds) - {"attention", "window", "conv",
-                                           "linear", *SSM_KINDS}
+                                           "linear", "parallel", *SSM_KINDS}
         if unknown:
             raise ValueError(f"unknown layer kinds {sorted(unknown)}")
+        if "parallel" in self.layer_kinds:
+            if not (self.ssd_heads > 0 and self.ssd_head_dim > 0
+                    and self.ssd_state > 0 and self.ssd_groups > 0
+                    and self.ssd_chunk > 0
+                    and self.ssd_heads % self.ssd_groups == 0):
+                raise ValueError(
+                    "parallel layers need ssd_heads, ssd_head_dim, "
+                    "ssd_state and ssd_chunk > 0 and ssd_groups dividing "
+                    "ssd_heads")
+            if set(self.layer_kinds) != {"parallel"} or self.is_latent \
+                    or self.diff_attention or self.kv_heads_packed:
+                raise NotImplementedError(
+                    "parallel layers beside another kind of layer, or over "
+                    "latent, differential or packed attention: a parallel "
+                    "layer's K and V are the global planes' rows of its "
+                    "own index")
         if "window" in self.layer_kinds and self.attn_window <= 0:
             raise ValueError("window layers need attn_window > 0")
         if "linear" in self.layer_kinds and not (
@@ -238,6 +293,13 @@ class DecoderConfig:
 
     def layers_of(self, kind: str) -> int:
         return self.kinds.count(kind)
+
+    def layers_holding(self, kind: str) -> int:
+        """Layers that keep cache planes of ``kind``: the layers of that
+        kind and those that hold its planes beside their own
+        (``ALSO_HOLDS``)."""
+        return sum(k == kind or kind in ALSO_HOLDS.get(k, ())
+                   for k in self.kinds)
 
     @property
     def stateless_tail(self) -> int:
@@ -312,6 +374,26 @@ class DecoderConfig:
         return (2 * d * e + (self.conv_taps + 1) * e + e * (r + 2 * n)
                 + r * e + e + n * e + e + e * d)
 
+    @property
+    def ssd_inner(self) -> int:
+        """Channels of an SSD mixer's ``x``, gate and output: all its heads'
+        values."""
+        return self.ssd_heads * self.ssd_head_dim
+
+    @property
+    def ssd_conv_dim(self) -> int:
+        """Channels the SSD mixer's convolution runs over: ``[x | B | C]``."""
+        return self.ssd_inner + 2 * self.ssd_groups * self.ssd_state
+
+    def _ssd_params(self) -> int:
+        """One SSD mixer: the in-projection (gate, ``[x | B | C]``, a step a
+        head), the taps and their bias, ``A_log``, ``D`` and ``dt_bias`` a
+        head, the gated norm's weight, the out-projection."""
+        d, e, c, h = self.hidden, self.ssd_inner, self.ssd_conv_dim, \
+            self.ssd_heads
+        return (d * (e + c + h) + (self.conv_taps + 1) * c + 3 * h + e
+                + e * d)
+
     def _diff_params(self, cross: bool) -> int:
         """One differential attention operator: q (k and v unless ``cross``)
         and output projections with their biases, the four lambda vectors of
@@ -345,6 +427,8 @@ class DecoderConfig:
             * self._attn_params() + kinds.count("conv") * self._conv_params() \
             + kinds.count("linear") * self._linear_params() \
             + kinds.count("ssm") * self._ssm_params() \
+            + kinds.count("parallel") * (self._attn_params()
+                                         + self._ssd_params()) \
             + kinds.count("gmu") * 2 * self.hidden * self.ssm_inner \
             + (kinds.count("cross") * self._diff_params(cross=True)
                if "cross" in kinds else 0)
@@ -495,6 +579,24 @@ PRESETS: dict[str, DecoderConfig] = {
         ssm_dt_rank=160, diff_attention=True, attn_bias=True,
         use_rope=False, norm_kind="layer",
     ),
+    # Falcon-H1-34B-Instruct (tiiuae config.json, model_type falcon_h1;
+    # arXiv:2507.22448: 72L, 5120h; EVERY block runs GQA of 20/4 heads of
+    # 128 with RoPE (theta 1e11) and a Mamba-2 (SSD, arXiv:2405.21060) mixer
+    # of 32 heads of 128 with a state of 256, 2 groups, a convolution of 4
+    # taps, side by side on one normed input; dense MLP of 21504; untied
+    # head; the model's fixed muP multipliers)
+    "falcon-h1-34b": DecoderConfig(
+        vocab_size=261120, hidden=5120, n_layers=72, n_heads=20,
+        n_kv_heads=4, head_dim=128, mlp_dim=21504, max_seq_len=262144,
+        rope_theta=1e11, norm_eps=1e-5, layer_kinds=("parallel",),
+        conv_taps=4, ssd_heads=32, ssd_head_dim=128, ssd_state=256,
+        ssd_groups=2, ssd_chunk=128,
+        embed_multiplier=5.656854249492381, head_multiplier=0.0078125,
+        attn_multipliers=(1.0, 0.0375, 0.011048543456039804),
+        ssd_multipliers=(0.25, 0.08838834764831845, 0.3535533905932738,
+                         0.25, 0.1767766952966369, 0.5, 0.3535533905932738),
+        mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+    ),
     # tiny variants for tests/sim (structure-faithful, sized for 1 CPU core)
     "tiny": DecoderConfig(
         vocab_size=256, hidden=64, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -571,6 +673,20 @@ PRESETS: dict[str, DecoderConfig] = {
         attn_window=8, conv_taps=4, ssm_state=4, ssm_inner=128,
         ssm_dt_rank=4, diff_attention=True, attn_bias=True, use_rope=False,
         norm_kind="layer",
+    ),
+    # Falcon-H1's structure: three parallel blocks of GQA (4/2 heads of 16,
+    # rotated) beside an SSD mixer of 4 heads of 16 with a state of 32 in 2
+    # groups, blocks of 8 positions, convolutions of 4 taps; every
+    # multiplier another number than 1
+    "tiny-falconh1": DecoderConfig(
+        vocab_size=256, hidden=64, n_layers=3, n_heads=4, n_kv_heads=2,
+        head_dim=16, mlp_dim=160, max_seq_len=256, rope_theta=1e6,
+        layer_kinds=("parallel",), conv_taps=4, ssd_heads=4,
+        ssd_head_dim=16, ssd_state=32, ssd_groups=2, ssd_chunk=8,
+        embed_multiplier=2.0, head_multiplier=0.5,
+        attn_multipliers=(0.75, 0.5, 0.25),
+        ssd_multipliers=(0.5, 0.4, 0.7, 0.6, 0.35, 0.8, 0.45),
+        mlp_multipliers=(0.6, 0.3),
     ),
 }
 

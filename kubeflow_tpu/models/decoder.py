@@ -16,7 +16,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from kubeflow_tpu.models.config import DecoderConfig
+from kubeflow_tpu.models.config import ALSO_HOLDS, DecoderConfig
 from kubeflow_tpu.models import layers as L
 from kubeflow_tpu.parallel.sharding import (
     LogicalRules, DEFAULT_RULES, _is_spec_leaf, with_logical_constraint,
@@ -31,7 +31,8 @@ Params = dict[str, Any]
 # own, and K/V planes of its own (a pool keeps a bounded ring of them a
 # sequence where a global layer keeps every page, serve/paged.py).
 OPERATOR = {"attention": "attn", "window": "window", "conv": "conv",
-            "linear": "linear", "ssm": "ssm", "gmu": "gmu", "cross": "cross"}
+            "linear": "linear", "ssm": "ssm", "gmu": "gmu", "cross": "cross",
+            "parallel": "parallel"}
 # a window layer's plane -> the name attention knows it by
 WINDOW_PLANES = {"window_k": "k", "window_v": "v"}
 # a linear layer's planes: a sequence's recurrent matrices and the tails of
@@ -40,13 +41,26 @@ LINEAR_PLANES = ("kda_state", "kda_conv")
 # an ssm layer's planes: a sequence's recurrent state [N, E] and the tail of
 # its convolution (one entry a SEQUENCE, as a linear layer's)
 SSM_PLANES = ("ssm_state", "ssm_conv")
+# a parallel layer's own planes: a sequence's SSD state [H, N, P] and the
+# tail of its convolution (one entry a SEQUENCE); its K and V are the
+# attention planes' rows (``config.ALSO_HOLDS``)
+SSD_PLANES = ("ssd_state", "ssd_conv")
 PLANE_KINDS = {"conv": "conv", **dict.fromkeys(WINDOW_PLANES, "window"),
                **dict.fromkeys(LINEAR_PLANES, "linear"),
-               **dict.fromkeys(SSM_PLANES, "ssm")}
+               **dict.fromkeys(SSM_PLANES, "ssm"),
+               **dict.fromkeys(SSD_PLANES, "parallel")}
 
 
 def plane_kind(name: str) -> str:
     return PLANE_KINDS.get(name, "attention")
+
+
+def holds(kind: str, plane: str) -> bool:
+    """Whether a layer of ``kind`` keeps the cache plane ``plane``: its own
+    kind's, and those it holds beside them (a parallel layer its K and
+    V)."""
+    of = plane_kind(plane)
+    return of == kind or of in ALSO_HOLDS.get(kind, ())
 
 
 def block_kind(bp: dict) -> str:
@@ -57,6 +71,7 @@ def block_kind(bp: dict) -> str:
 def _init_operator(key, cfg: DecoderConfig, kind: str):
     init = {"conv": L.init_conv, "linear": L.init_linear,
             "ssm": L.init_ssm, "gmu": L.init_gmu,
+            "parallel": L.init_parallel,
             "cross": lambda k, c: L.init_diff_attention(k, c, cross=True),
             }.get(kind, L.init_attention)
     return init(jax.random.split(key)[0], cfg)
@@ -345,12 +360,25 @@ def _block_forward(block_params, x, positions, cfg: DecoderConfig,
     x, shared = x if isinstance(x, tuple) else (x, None)
     h = L.rmsnorm(x, block_params["ln1"], cfg, mesh=mesh,
                   bias=block_params.get("ln1_b"))
-    if set(block_params) & {"ssm", "gmu", "cross"} and (
+    if set(block_params) & {"ssm", "gmu", "cross", "parallel"} and (
             tp_axis is not None or lora is not None):
         raise NotImplementedError(
-            "an ssm, gmu or cross layer under in-stage tensor parallelism "
-            "or with LoRA adapters")
-    if "ssm" in block_params:
+            "an ssm, gmu, cross or parallel layer under in-stage tensor "
+            "parallelism or with LoRA adapters")
+    if "parallel" in block_params:
+        # Two operators on the one normed input: attention over the K and V
+        # planes, the SSD mixer from the state before ``x``; both caches
+        # come back, the state as it stands after the last valid position.
+        attn_out, kv, state = L.parallel_block(
+            block_params["parallel"], h, positions, cfg,
+            None if kv_cache is None else {
+                n: kv_cache[n] for n in ("k", "v", "len")},
+            None if kv_cache is None else tuple(
+                kv_cache[n] for n in SSD_PLANES),
+            valid_len, attn_impl, mesh)
+        new_cache = None if kv_cache is None else {
+            "k": kv["k"], "v": kv["v"], **dict(zip(SSD_PLANES, state))}
+    elif "ssm" in block_params:
         # An ssm layer's cache is its state before ``x`` (the recurrent
         # state and the convolution's tail); it hands back the state after
         # the last valid position, and its scan output to the carry.
@@ -496,12 +524,12 @@ def _run_layers(layers, x, positions, cfg: DecoderConfig, planes: tuple,
 
     def cache_of(kind, layer_planes):
         own = {n: pl for n, pl in zip(plane_names, layer_planes)
-               if plane_kind(n) == kind}
+               if holds(kind, n)}
         return {**own, "len": cache_len} if own else None
 
     def written_by(new_cache, kind, out):
         for n in plane_names:
-            if plane_kind(n) == kind:
+            if holds(kind, n):
                 out[n].append(new_cache[n])
 
     if cfg.scan_layers:
@@ -616,6 +644,7 @@ def decoder_forward(
         x = with_logical_constraint(x, ("batch", "act_seq", "act_embed"), mesh, rules)
     if cfg.embed_scale:
         x = x * jnp.asarray(cfg.hidden ** 0.5, dt)
+    x = L.scaled(x, cfg.embed_multiplier)
 
     aux_total = jnp.float32(0)
     new_caches = None
@@ -656,11 +685,14 @@ def decoder_forward(
         whole = len(groups) == 1        # one group: nothing is sliced
         # The group's planes: those of the kinds it has, each sliced over
         # the layers of its kind that lie in the group.
-        names = tuple(n for n in plane_names if plane_kind(n) in gcfg.kinds)
-        at = {n: cfg.kinds[:first].count(plane_kind(n)) for n in names}
+        def held(kinds, n):     # layers of ``kinds`` that keep plane ``n``
+            return sum(holds(kind, n) for kind in kinds)
+
+        names = tuple(n for n in plane_names if held(gcfg.kinds, n))
+        at = {n: held(cfg.kinds[:first], n) for n in names}
         planes = tuple(
             kv_caches[n] if whole else kv_caches[n][
-                at[n]:at[n] + gcfg.kinds.count(plane_kind(n))]
+                at[n]:at[n] + held(gcfg.kinds, n)]
             for n in names)
         lora_g = lora if whole or lora is None else {
             **lora, "targets": {t: (a[first:last], b[first:last])
@@ -686,8 +718,8 @@ def decoder_forward(
     if skip_head:
         return x, new_caches, aux_total
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(dt),
-                        preferred_element_type=jnp.float32)
+    logits = jnp.einsum("bsd,dv->bsv", L.scaled(x, cfg.head_multiplier),
+                        head.astype(dt), preferred_element_type=jnp.float32)
     if cfg.logits_softcap is not None:
         logits = jnp.tanh(logits / cfg.logits_softcap) * cfg.logits_softcap
     return logits, new_caches, aux_total
@@ -871,6 +903,7 @@ def decoder_loss(
             params, inputs, cfg, attn_impl=attn_impl, mesh=mesh, rules=rules,
             skip_head=True)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        hidden = L.scaled(hidden, cfg.head_multiplier)
         nll, correct = fused_xent.fused_cross_entropy(
             hidden, head.astype(hidden.dtype), targets,
             logits_softcap=cfg.logits_softcap)
@@ -879,8 +912,8 @@ def decoder_loss(
             params, inputs, cfg, attn_impl=attn_impl, mesh=mesh, rules=rules,
             skip_head=True)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        nll, correct = _chunked_ce(hidden, head.astype(hidden.dtype), targets,
-                                   cfg)
+        nll, correct = _chunked_ce(L.scaled(hidden, cfg.head_multiplier),
+                                   head.astype(hidden.dtype), targets, cfg)
     else:
         logits, _, aux = decoder_forward(
             params, inputs, cfg, attn_impl=attn_impl, mesh=mesh, rules=rules)

@@ -28,6 +28,12 @@ def _init(key, shape, dtype, scale: Optional[float] = None):
             * scale).astype(dtype)
 
 
+def scaled(x: jax.Array, m: float) -> jax.Array:
+    """``x`` times a model's fixed multiplier ``m`` in ``x``'s own type (1:
+    ``x`` as it came)."""
+    return x if m == 1.0 else x * jnp.asarray(m, x.dtype)
+
+
 # -- Fused-kernel resolution ---------------------------------------------------
 
 def fused_kernels_on(cfg: DecoderConfig, mesh=None) -> bool:
@@ -257,7 +263,9 @@ def qk_rope(p: dict, q: jax.Array, k: jax.Array, positions: jax.Array,
     values through its RMSNorm where the model has one (``qk_norm``), then
     RoPE. One function for the forward pass and the paged programs.
     ``window``: the layer's window (0: a global layer), which decides
-    whether it rotates at all where ``rope_window_only``."""
+    whether it rotates at all where ``rope_window_only``. A model with
+    ``attn_multipliers`` scales its keys first."""
+    k = scaled(k, (cfg.attn_multipliers or (1.0,) * 3)[2])
     if cfg.qk_norm:
         q = rmsnorm(q, p["q_norm"], cfg)
         k = rmsnorm(k, p["k_norm"], cfg)
@@ -715,6 +723,172 @@ def gmu_block(p: dict, x: jax.Array, memory: jax.Array,
     return checkpoint_name(out, "attn_out")
 
 
+# -- Mamba-2 (SSD) mixer, the second branch of a parallel block -----------------
+
+def init_ssd(key, cfg: DecoderConfig):
+    """An SSD mixer's leaves (``ops/ssd.py`` has the recurrence): the
+    in-projection's three column blocks as three leaves, ``w_z`` [D, E] (the
+    gate; E = ``ssd_inner``), ``w_xbc`` [D, C] (``[x | B | C]``; C =
+    ``ssd_conv_dim``) and ``w_dt`` [D, H] (a step a head): ONE matrix [D, E +
+    C + H] is no whole number of 128-lane tiles at the published widths
+    (9248 columns), and the chip's compiler then copies all of it in front
+    of every decode step (0.47 GB at five layers: a compile for a described
+    v5e, PR 50); ``conv`` [taps, C] (``[-1]`` multiplies the current
+    position) and ``conv_b`` [C]; ``a_log``, ``d_skip``, ``dt_bias`` [H];
+    ``ssd_norm`` [E], the gated group norm's weight; ``w_out`` [E, D].
+    ``a_log`` and ``dt_bias`` start as Mamba-2's: ``A`` uniform in [1, 16],
+    the bias the inverse softplus of a step log-uniform in [1e-3, 1e-1]."""
+    ks = iter(jax.random.split(key, 7))
+    d, e, c, h = cfg.hidden, cfg.ssd_inner, cfg.ssd_conv_dim, cfg.ssd_heads
+    wdt, taps = cfg.weight_dtype, cfg.conv_taps
+    step = jnp.exp(jax.random.uniform(
+        next(ks), (h,), jnp.float32, *jnp.log(jnp.asarray(SSM_STEP_RANGE))))
+    params = {
+        "w_z": _init(next(ks), (d, e), wdt),
+        "w_xbc": _init(next(ks), (d, c), wdt),
+        "w_dt": _init(next(ks), (d, h), wdt),
+        "conv": _init(next(ks), (taps, c), wdt, scale=taps ** -0.5),
+        "conv_b": jnp.zeros((c,), wdt),
+        "a_log": jnp.log(jax.random.uniform(
+            next(ks), (h,), jnp.float32, 1.0, 16.0)).astype(wdt),
+        "d_skip": jnp.ones((h,), wdt),
+        "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(wdt),
+        "ssd_norm": jnp.ones((e,), wdt),
+        "w_out": _init(next(ks), (e, d), wdt),
+    }
+    specs = {"w_z": ("embed", "mlp"), "w_xbc": ("embed", "mlp"),
+             "w_dt": ("embed", None), "conv": (None, "mlp"),
+             "conv_b": ("mlp",), "a_log": (None,), "d_skip": (None,),
+             "dt_bias": (None,), "ssd_norm": ("mlp",),
+             "w_out": ("mlp", "embed")}
+    return params, specs
+
+
+def init_parallel(key, cfg: DecoderConfig):
+    """A parallel block's operator: an attention operator's leaves and an
+    SSD mixer's, side by side in one dict (no name is in both)."""
+    ka, ks = jax.random.split(key)
+    attn_p, attn_s = init_attention(ka, cfg)
+    ssd_p, ssd_s = init_ssd(ks, cfg)
+    return {**attn_p, **ssd_p}, {**attn_s, **ssd_s}
+
+
+def ssd_inputs(p: dict, x: jax.Array, cfg: DecoderConfig,
+               tail: Optional[jax.Array] = None, valid_len=None):
+    """What the recurrence takes of ``x`` [B,S,D], the block's normed input:
+    (xs [B,S,H,P]: the convolved, SiLU'd values a head, in the activation
+    type; z [B,S,E]: the gate's input; dt [B,S,H] float32, after its
+    softplus; bm, cm [B,S,G,N]; the convolution's tail after the last valid
+    position [B,taps-1,C]). ``[z | xBC | dt] = ((in x) [W_z | W_xbc | W_dt])
+    * m`` with ``m`` the multipliers of the column blocks ``z, x, B, C, dt``
+    (``cfg.ssd_multipliers``); ``xBC`` through a causal depthwise
+    convolution of ``conv_taps`` taps and its bias (``tail``: the ``taps -
+    1`` rows of ``xBC`` before ``x``, zeros at a sequence's start and when
+    None), SiLU. A position ``>= valid_len`` ([B])
+    is padding: its convolution input and its ``dt`` are 0, so the state
+    passes through it unchanged."""
+    dt_ = cfg.activation_dtype
+    b, s, _ = x.shape
+    taps, h, g, n = cfg.conv_taps, cfg.ssd_heads, cfg.ssd_groups, \
+        cfg.ssd_state
+    e, c = cfg.ssd_inner, cfg.ssd_conv_dim
+    m_in, _, m_z, m_x, m_b, m_c, m_dt = cfg.ssd_multipliers or (1.0,) * 7
+    x = scaled(x, m_in)
+    z = scaled(jnp.einsum("bsd,de->bse", x, p["w_z"].astype(dt_)), m_z)
+    xbc = jnp.einsum("bsd,de->bse", x, p["w_xbc"].astype(dt_))
+    if not m_x == m_b == m_c == 1.0:
+        xbc = xbc * jnp.concatenate([
+            jnp.full((w,), m, dt_)
+            for w, m in ((e, m_x), (g * n, m_b), (g * n, m_c))])
+    dt = scaled(jnp.einsum("bsd,dh->bsh", x, p["w_dt"].astype(dt_),
+                           preferred_element_type=jnp.float32), m_dt)
+    valid = None
+    if valid_len is not None:
+        valid = jnp.arange(s)[None, :] < jnp.reshape(valid_len, (-1, 1))
+        xbc = jnp.where(valid[..., None], xbc, 0)
+    if tail is None:
+        tail = jnp.zeros((b, taps - 1, c), dt_)
+    us = jnp.concatenate([tail.astype(dt_), xbc], axis=1)  # [B,taps-1+S,C]
+    w = p["conv"].astype(jnp.float32)
+    conv = jax.nn.silu(sum(w[j] * us[:, j:j + s].astype(jnp.float32)
+                           for j in range(taps))
+                       + p["conv_b"].astype(jnp.float32)).astype(dt_)
+    if valid is None:
+        tail = us[:, s:]
+    else:           # the rows before position ``valid_len``
+        at = jnp.reshape(valid_len, (-1, 1)) + jnp.arange(taps - 1)
+        tail = jnp.take_along_axis(
+            us, jnp.broadcast_to(at, (b, taps - 1))[..., None], axis=1)
+    dt = jax.nn.softplus(dt + p["dt_bias"].astype(jnp.float32))
+    if valid is not None:
+        dt = jnp.where(valid[..., None], dt, 0.0)
+    return (conv[..., :e].reshape(b, s, h, -1), z, dt,
+            conv[..., e:e + g * n].reshape(b, s, g, n),
+            conv[..., e + g * n:].reshape(b, s, g, n), tail)
+
+
+def ssd_decay(p: dict) -> jax.Array:
+    """``A`` [H] float32, negative."""
+    return -jnp.exp(p["a_log"].astype(jnp.float32))
+
+
+def ssd_output(p: dict, y: jax.Array, z: jax.Array,
+               cfg: DecoderConfig) -> jax.Array:
+    """``out GroupRMSNorm(y * SiLU(z)) W_out``: y [B,S,H,P] float32, the
+    recurrence's output; the norm over each of ``ssd_groups`` groups of
+    channels, one weight a channel (the gate BEFORE the norm)."""
+    dt = cfg.activation_dtype
+    b, s = y.shape[:2]
+    gated = y.reshape(b, s, -1) * jax.nn.silu(z.astype(jnp.float32))
+    grouped = gated.reshape(b, s, cfg.ssd_groups, -1)
+    grouped = grouped * jax.lax.rsqrt(
+        jnp.mean(grouped * grouped, axis=-1, keepdims=True) + cfg.norm_eps)
+    normed = (grouped.reshape(b, s, -1)
+              * p["ssd_norm"].astype(jnp.float32)).astype(dt)
+    out = jnp.einsum("bse,ed->bsd", normed, p["w_out"].astype(dt))
+    return scaled(out, (cfg.ssd_multipliers or (1.0, 1.0))[1])
+
+
+def ssd_block(p: dict, x: jax.Array, cfg: DecoderConfig,
+              state: Optional[tuple] = None, valid_len=None,
+              impl: str = "xla"):
+    """The SSD mixer over ``x`` [B,S,D] from ``state`` to a state: (the
+    recurrent state [B,H,N,P] float32, the convolution's tail [B,taps-1,C]);
+    zeros when None (a sequence's start). Returns (out [B,S,D], the state
+    after the last valid position). Rows never mix."""
+    from kubeflow_tpu.ops import ssd
+
+    mat, tail = state if state is not None else (None, None)
+    if mat is None:
+        mat = jnp.zeros((x.shape[0], cfg.ssd_heads, cfg.ssd_state,
+                         cfg.ssd_head_dim), jnp.float32)
+    xs, z, dt, bm, cm, tail = ssd_inputs(p, x, cfg, tail, valid_len)
+    y, mat = ssd.ssd_chunk(xs, dt, ssd_decay(p), bm, cm,
+                           p["d_skip"].astype(jnp.float32), mat, impl=impl,
+                           block=cfg.ssd_chunk)
+    return ssd_output(p, y, z, cfg), (mat, tail)
+
+
+def parallel_block(p: dict, x: jax.Array, positions: jax.Array,
+                   cfg: DecoderConfig, kv_cache: Optional[dict] = None,
+                   state: Optional[tuple] = None, valid_len=None,
+                   attn_impl: str = "xla", mesh=None):
+    """A parallel block's two branches on ONE normed input ``x`` [B,S,D]:
+    attention (``attention_block`` over ``kv_cache``) and the SSD mixer
+    (``ssd_block`` from ``state``), each between its multipliers. Returns
+    (their sum [B,S,D], the K/V cache as written | None, the SSD state
+    after)."""
+    a_in, a_out, _ = cfg.attn_multipliers or (1.0, 1.0, 1.0)
+    with jax.named_scope("parallel_attention"):
+        attn, new_cache = attention_block(
+            p, scaled(x, a_in), positions, cfg, kv_cache=kv_cache,
+            attn_impl=attn_impl, mesh=mesh)
+    with jax.named_scope("parallel_ssd"):
+        mixed, state = ssd_block(p, x, cfg, state, valid_len)
+    return checkpoint_name(scaled(attn, a_out) + mixed, "attn_out"), \
+        new_cache, state
+
+
 # -- Latent attention (MLA) ----------------------------------------------------
 
 def init_latent_attention(key, cfg: DecoderConfig):
@@ -1083,7 +1257,9 @@ def mlp_block(p: dict, x: jax.Array, cfg: DecoderConfig,
     down's partial products psum over the axis (Megatron MLP split, manual
     form for inside shard_map)."""
     dt = cfg.activation_dtype
-    gate_pre = jnp.einsum("bsd,dm->bsm", x, p["gate"].astype(dt))
+    gate_m, down_m = cfg.mlp_multipliers or (1.0, 1.0)
+    gate_pre = scaled(jnp.einsum("bsd,dm->bsm", x, p["gate"].astype(dt)),
+                      gate_m)
     up = jnp.einsum("bsd,dm->bsm", x, p["up"].astype(dt))
     h = None
     if fused_kernels_on(cfg, mesh) and cfg.hidden_act in ("silu", "gelu"):
@@ -1099,7 +1275,7 @@ def mlp_block(p: dict, x: jax.Array, cfg: DecoderConfig,
     out = jnp.einsum("bsm,md->bsd", h, p["down"].astype(dt))
     if tp_axis is not None:
         out = jax.lax.psum(out, tp_axis)
-    return checkpoint_name(out, "mlp_out")
+    return checkpoint_name(scaled(out, down_m), "mlp_out")
 
 
 # -- MoE -----------------------------------------------------------------------

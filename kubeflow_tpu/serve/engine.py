@@ -75,7 +75,8 @@ from kubeflow_tpu.core.serving import (
 from kubeflow_tpu.serve.device_state import DEAD_SLOT, DecodeState
 from kubeflow_tpu.serve.pacing import RoundPacer, decode_ladder
 from kubeflow_tpu.serve.paged import (
-    MOE_ROWS, PageAllocator, PagePoolExhausted, chunk_carries_step,
+    MOE_ROWS, SEQUENCE_PLANES, PageAllocator, PagePoolExhausted,
+    chunk_carries_step,
     chunk_reads_context, context_bucket, engine_pool_shapes,
     paged_chunk_prefill, first_page_ids, own_first_pages,
     paged_decode_multi, paged_mixed_step, pool_bytes_per_token,
@@ -856,15 +857,17 @@ class LLMEngine:
         self._kv_pool_bytes = int(sum(v.nbytes for v in self.cache.values()))
         by_kind = {kind: int(sum(v.nbytes for n, v in self.cache.items()
                                  if plane_kind(n) == kind))
-                   for kind in ("attention", "window", "conv", "linear",
-                                "ssm")}
+                   for kind in ("attention", "window", "conv",
+                                *SEQUENCE_PLANES)}
         self._state_pool_bytes = by_kind["conv"]
         self._kv_window_pool_bytes = by_kind["window"]
         self._kv_global_pool_bytes = by_kind["attention"]
-        # The linear and ssm layers' planes hold an entry a SEQUENCE
-        # (``slots`` of them, beside the token pages ``max_pages`` buys);
-        # every other plane holds rows a token or a tail a page.
-        self._kv_sequence_pool_bytes = by_kind["linear"] + by_kind["ssm"]
+        # The linear and ssm layers' planes, and a parallel layer's own
+        # (its K and V are rows of the global planes), hold an entry a
+        # SEQUENCE (``slots`` of them, beside the token pages ``max_pages``
+        # buys); every other plane holds rows a token or a tail a page.
+        self._kv_sequence_pool_bytes = sum(
+            by_kind[kind] for kind in SEQUENCE_PLANES)
         self._state_sequences_started = 0       # lockfree: scheduler-confined counter
         # Where a layer holds a share of its experts, the rows its expert
         # layers routed and held ride in the cache pytree as running sums
@@ -1460,7 +1463,10 @@ class LLMEngine:
         is one matrix a head that every token rewrites: a match would need
         it AS IT STOOD at the match (a snapshot a page: ROADMAP Reach 11),
         and the speculative verify step cannot roll it back; and over
-        state-space (ssm) layers for the same two reasons. Gated memory
+        state-space (ssm) layers for the same two reasons, and over parallel
+        layers, whose SSD state a sequence is such a matrix beside the
+        layer's own K and V: a matched page's K and V could be shared, the
+        state as it stood at the match cannot. Gated memory
         units and cross layers keep nothing, but read what a layer in front
         of them computed, which none of the mechanisms above carries, and
         differential attention's paired K/V rows are not the ``[KV, Dh]``
@@ -1475,6 +1481,9 @@ class LLMEngine:
              "page pool", bool(cfg.layers_of("linear"))),
             ("state-space (ssm) layers whose state a sequence lives in the "
              "page pool", bool(cfg.layers_of("ssm"))),
+            ("parallel layers (attention beside a Mamba-2 mixer) whose SSD "
+             "state a sequence lives in the page pool beside the layer's K "
+             "and V", bool(cfg.layers_of("parallel"))),
             ("gated memory units and cross-attention layers that read "
              "another layer's output and cache", bool(cfg.stateless_tail)),
             ("differential attention over paired K/V heads",
@@ -1506,6 +1515,10 @@ class LLMEngine:
             "tail over ssm layers: a match needs the state as it stood at "
             "the match)":
                 bool(cfg.layers_of("ssm")) and b.enable_prefix_caching,
+            "enable_prefix_caching (prefix reuse and the radix copy-on-write "
+            "tail over parallel layers: a match needs the SSD state as it "
+            "stood at the match)":
+                bool(cfg.layers_of("parallel")) and b.enable_prefix_caching,
         }
         hit = [name for name, on in refused.items() if on]
         if hit:
@@ -1623,8 +1636,9 @@ class LLMEngine:
             "kv_window_pages_a_sequence":
                 self._cfg_decode.window_ring_pages,
             # of the cache's size the planes that hold an entry a SEQUENCE
-            # (the linear layers' recurrent matrices and convolution tails,
-            # ``slots`` entries; 0 for a stack without linear layers) and
+            # (the linear, ssm and parallel layers' recurrent states and
+            # convolution tails, ``slots`` entries; 0 for a stack without
+            # such layers) and
             # every other plane (rows a token, tails a page)
             "kv_sequence_pool_bytes": self._kv_sequence_pool_bytes,
             # layers that keep no K/V of their own and attend over ONE

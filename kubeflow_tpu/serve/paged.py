@@ -63,7 +63,7 @@ import numpy as np
 from kubeflow_tpu.models import layers as L
 from kubeflow_tpu.models.config import DecoderConfig
 from kubeflow_tpu.models.decoder import (
-    LINEAR_PLANES, SSM_PLANES, WINDOW_PLANES, Params, block_kind,
+    LINEAR_PLANES, SSD_PLANES, SSM_PLANES, WINDOW_PLANES, Params, block_kind,
     layer_groups, period_units, plane_kind, split_dense_stack, unit_blocks,
 )
 
@@ -369,7 +369,8 @@ MOE_ROWS = "moe_rows"
 # ssm layer in front of them. A program's own: no cache pytree holds it.
 SSM_MEMORY = "ssm_memory"
 # a kind of layer whose state is one entry a SEQUENCE -> its planes
-SEQUENCE_PLANES = {"linear": LINEAR_PLANES, "ssm": SSM_PLANES}
+SEQUENCE_PLANES = {"linear": LINEAR_PLANES, "ssm": SSM_PLANES,
+                   "parallel": SSD_PLANES}
 
 
 def pool_planes(cfg: DecoderConfig, kv_quant: bool = False) -> tuple:
@@ -484,8 +485,12 @@ def sequence_planes(cfg: DecoderConfig) -> tuple:
     changes hands. An ssm layer's entry is found the same way:
     "ssm_state", the recurrent state ``[ssm_state, ssm_inner]`` float32
     (``ops/ssm.py``: channels on the lanes), and "ssm_conv", the
-    ``conv_taps - 1`` projected rows before the next token. () for a stack
-    with neither kind."""
+    ``conv_taps - 1`` projected rows before the next token. A parallel
+    layer's too, beside the K and V rows a token it keeps in the global
+    planes: "ssd_state" ``[ssd_heads, ssd_state, ssd_head_dim]`` float32
+    (``ops/ssd.py``: a head's values on the lanes) and "ssd_conv", the
+    ``conv_taps - 1`` rows of ``[x | B | C]`` before the next token. () for
+    a stack with none of these kinds."""
     out = ()
     if cfg.layers_of("linear"):
         h, dk = cfg.linear_heads, cfg.linear_head_dim
@@ -496,6 +501,11 @@ def sequence_planes(cfg: DecoderConfig) -> tuple:
         out += ((SSM_PLANES[0], (cfg.ssm_state, cfg.ssm_inner),
                  jnp.dtype(jnp.float32)),
                 (SSM_PLANES[1], (cfg.conv_taps - 1, cfg.ssm_inner),
+                 cfg.activation_dtype))
+    if cfg.layers_of("parallel"):
+        out += ((SSD_PLANES[0], (cfg.ssd_heads, cfg.ssd_state,
+                                 cfg.ssd_head_dim), jnp.dtype(jnp.float32)),
+                (SSD_PLANES[1], (cfg.conv_taps - 1, cfg.ssd_conv_dim),
                  cfg.activation_dtype))
     return out
 
@@ -567,9 +577,10 @@ def pool_shapes(cfg: DecoderConfig, num_pages: int, page_size: int,
         return (page_size * rows, *trail[1:]) if rows \
             else (page_size, *trail)
 
-    out = {n: ((cfg.layers_of("attention"), num_pages, *page_of(trail)),
-               dt) for n, trail, dt in pool_planes(cfg, kv_quant)
-           if cfg.layers_of("attention")}
+    out = {n: ((cfg.layers_holding("attention"), num_pages,
+                *page_of(trail)), dt)
+           for n, trail, dt in pool_planes(cfg, kv_quant)
+           if cfg.layers_holding("attention")}
     out.update({n: ((cfg.layers_of("conv"), num_pages, *trail), dt)
                 for n, trail, dt in state_planes(cfg)})
     out.update({n: ((cfg.layers_of("window"),
@@ -577,7 +588,7 @@ def pool_shapes(cfg: DecoderConfig, num_pages: int, page_size: int,
                      *page_of(trail)), dt)
                 for n, trail, dt in window_planes(cfg, kv_quant)})
     held = num_pages if window_pages is None else window_pages
-    out.update({n: ((cfg.layers_of(plane_kind(n)),
+    out.update({n: ((cfg.layers_holding(plane_kind(n)),
                      held if sequence_entries is None else sequence_entries,
                      *trail), dt) for n, trail, dt in sequence_planes(cfg)})
     return out
@@ -622,7 +633,7 @@ def pool_bytes_per_token(cfg: DecoderConfig, kv_quant: bool = False) -> int:
     token: the attention layers (a latent row's padding included: 1280 a
     layer at the published ranks for 1152 of content). A conv layer holds
     none a token; what it holds a page is ``state_bytes_per_page``."""
-    return cfg.layers_of("attention") * _plane_bytes(
+    return cfg.layers_holding("attention") * _plane_bytes(
         pool_planes(cfg, kv_quant))
 
 
@@ -634,8 +645,8 @@ def state_bytes_per_page(cfg: DecoderConfig) -> int:
 def state_bytes_per_sequence(cfg: DecoderConfig) -> int:
     """Bytes one sequence holds over all linear and ssm layers, whatever
     its length: the recurrent states and the convolutions' tails."""
-    return sum(cfg.layers_of(plane_kind(plane[0])) * _plane_bytes((plane,))
-               for plane in sequence_planes(cfg))
+    return sum(cfg.layers_holding(plane_kind(plane[0]))
+               * _plane_bytes((plane,)) for plane in sequence_planes(cfg))
 
 
 def window_bytes_per_page(cfg: DecoderConfig, page_size: int) -> int:
@@ -681,7 +692,7 @@ def _embed(params: Params, tokens: jax.Array, cfg: DecoderConfig):  # traced
     x = params["embed"].astype(cfg.activation_dtype)[tokens]
     if cfg.embed_scale:
         x = x * jnp.asarray(cfg.hidden ** 0.5, cfg.activation_dtype)
-    return x
+    return L.scaled(x, cfg.embed_multiplier)
 
 
 def _head_logits(params: Params, x: jax.Array, cfg: DecoderConfig,  # traced
@@ -692,7 +703,8 @@ def _head_logits(params: Params, x: jax.Array, cfg: DecoderConfig,  # traced
         x = L.rmsnorm(x, params["final_norm"], cfg,
                       bias=params.get("final_norm_b"))
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.activation_dtype),
+    logits = jnp.einsum("bsd,dv->bsv", L.scaled(x, cfg.head_multiplier),
+                        head.astype(cfg.activation_dtype),
                         preferred_element_type=jnp.float32)
     if cfg.logits_softcap is not None:
         logits = jnp.tanh(logits / cfg.logits_softcap) * cfg.logits_softcap
@@ -927,58 +939,88 @@ def _operator(bp, x, rows: _Rows, pools, layer, num_pages: dict,  # traced
     kind = block_kind(bp)
     t, pg = x.shape[1], page_size
     # The planes the block meets: its own kind's; a cross layer's are the
-    # LAST attention layer's (it writes none); a gated memory unit has none.
+    # LAST attention layer's (it writes none); a gated memory unit has none;
+    # a parallel layer meets the attention planes with its own index too.
     met = {"cross": "attention", "gmu": None}.get(kind, kind)
+
+    def lies(of: str):
+        """(``layer``'s first flat page in the planes of kind ``of``, its
+        pages there, the planes' flat length)."""
+        plane = next(pools[n] for n in pools
+                     if n not in (MOE_ROWS, SSM_MEMORY)
+                     and plane_kind(n) == of)
+        total, pages = plane.shape[0], num_pages[of]
+        return (total - pages if kind == "cross" else layer * pages), \
+            pages, total
+
     if met is not None:
-        own = next(pools[n] for n in pools
-                   if n not in (MOE_ROWS, SSM_MEMORY)
-                   and plane_kind(n) == met)
-        total, pages = own.shape[0], num_pages[met]
-        base = layer * pages if kind == met else total - pages
-    h = L.rmsnorm(x, bp["ln1"], cfg, bias=bp.get("ln1_b"))
-    if kind == "gmu":
-        proj = L.gmu_block(bp["gmu"], h, pools[SSM_MEMORY], cfg)
-    elif kind == "cross":
-        proj, pools = _kv_attention(
-            bp["cross"], h, positions, start, pools, None, None,
-            jnp.where(table >= 0, table + base, -1), cfg, attn_impl, lora,
-            cross=True)
-    elif kind == "ssm":
-        proj, pools = _ssm(
-            bp["ssm"], h, start, valid, pools,
-            _sequence_entry(table, valid.astype(bool), base, pages, total),
-            cfg, attn_impl)
-    elif kind == "linear":
-        proj, pools = _kda(
-            bp["linear"], h, start, valid, pools,
-            _sequence_entry(table, valid.astype(bool), base, pages, total),
-            cfg, attn_impl)
-    else:
+        own = lies(met)
+
+    def entry():
+        """Where each row's sequence keeps its state in this layer."""
+        return _sequence_entry(table, valid.astype(bool), *own)
+
+    def token_rows(at: tuple, ring_cfg=None):
+        """Where the rows' tokens are written in the planes ``at``
+        (``lies``): (flat page [B] or [B,T], past the planes where nothing
+        is written; the positions alike)."""
+        base, pages, total = at
         if t == 1:
             pos, real = start, valid.astype(bool)
         else:
             pos = positions
             real = (jnp.arange(t, dtype=jnp.int32)[None, :]
                     < valid[:, None]) & (pos < table.shape[1] * pg)
-        page_id = _token_pages(table, pos, pg, pages,
-                               cfg if kind == "window" else None)
-        pidx = jnp.where(real & (page_id >= 0), base + page_id, total)
-        if kind == "conv":
-            proj, pools = _conv(bp["conv"], h, start, pools, pidx, base,
-                                table, pg, cfg)
-        elif kind == "window":
-            touched, seen = _window_pages(table, start, t, pages, pg, cfg)
-            proj, pools = _kv_attention(
-                bp["window"], h, positions, seen, pools, pidx, pos % pg,
-                jnp.where(touched >= 0, touched + base, -1), cfg, attn_impl,
-                lora, tuple(WINDOW_PLANES), cfg.attn_window)
-        else:
-            # This layer's page table into the flat pool; -1 stays unmapped.
-            attend = _latent_attention if cfg.is_latent else _kv_attention
-            proj, pools = attend(
-                bp["attn"], h, positions, start, pools, pidx, pos % pg,
-                jnp.where(table >= 0, table + base, -1), cfg, attn_impl,
-                lora)
+        page_id = _token_pages(table, pos, pg, pages, ring_cfg)
+        return jnp.where(real & (page_id >= 0), base + page_id, total), pos
+
+    def attention(a, h, at: tuple):
+        # This layer's page table into the flat pool; -1 stays unmapped.
+        pidx, pos = token_rows(at)
+        attend = _latent_attention if cfg.is_latent else _kv_attention
+        return attend(a, h, positions, start, pools, pidx, pos % pg,
+                      jnp.where(table >= 0, table + at[0], -1), cfg,
+                      attn_impl, lora)
+
+    h = L.rmsnorm(x, bp["ln1"], cfg, bias=bp.get("ln1_b"))
+    if kind == "gmu":
+        proj = L.gmu_block(bp["gmu"], h, pools[SSM_MEMORY], cfg)
+    elif kind == "cross":
+        proj, pools = _kv_attention(
+            bp["cross"], h, positions, start, pools, None, None,
+            jnp.where(table >= 0, table + own[0], -1), cfg, attn_impl,
+            lora, cross=True)
+    elif kind == "ssm":
+        proj, pools = _ssm(bp["ssm"], h, start, valid, pools, entry(), cfg,
+                           attn_impl)
+    elif kind == "linear":
+        proj, pools = _kda(bp["linear"], h, start, valid, pools, entry(),
+                           cfg, attn_impl)
+    elif kind == "parallel":
+        # Both branches on the one normed input, each between its
+        # multipliers: they read and write different planes, so neither
+        # waits for the other.
+        a_in, a_out, _ = cfg.attn_multipliers or (1.0, 1.0, 1.0)
+        with jax.named_scope("parallel_attention"):
+            attn, pools = attention(bp["parallel"], L.scaled(h, a_in),
+                                    lies("attention"))
+        with jax.named_scope("parallel_ssd"):
+            mixed, pools = _ssd(bp["parallel"], h, start, valid, pools,
+                                entry(), cfg, attn_impl)
+        proj = L.scaled(attn, a_out) + mixed
+    elif kind == "conv":
+        pidx, _ = token_rows(own)
+        proj, pools = _conv(bp["conv"], h, start, pools, pidx, own[0], table,
+                            pg, cfg)
+    elif kind == "window":
+        pidx, pos = token_rows(own, cfg)
+        touched, seen = _window_pages(table, start, t, own[1], pg, cfg)
+        proj, pools = _kv_attention(
+            bp["window"], h, positions, seen, pools, pidx, pos % pg,
+            jnp.where(touched >= 0, touched + own[0], -1), cfg, attn_impl,
+            lora, tuple(WINDOW_PLANES), cfg.attn_window)
+    else:
+        proj, pools = attention(bp["attn"], h, own)
     return proj, pools
 
 
@@ -1128,6 +1170,42 @@ def _ssm(sp, h, start, valid, pools, entry, cfg: DecoderConfig,  # traced
     if SSM_MEMORY in pools:
         pools[SSM_MEMORY] = y
     return L.ssm_output(sp, y, z, cfg), pools
+
+
+def _ssd(sp, h, start, valid, pools, entry, cfg: DecoderConfig,  # traced
+         attn_impl: str):
+    """A parallel layer's SSD mixer over ``T`` tokens a row: from the state
+    at ``entry`` [B] of the layer's planes (zeros for a row that starts its
+    sequence) to the state after the row's last valid token, written back to
+    the entry. One token goes through the recurrence with the state read and
+    written where it lies ("pallas": ``ops/ssd.py::ssd_step``; "gather": the
+    same in XLA), several through the chunked form (``ssd_chunk``: "pallas"
+    the kernel, "gather" token by token) from the state gathered. An
+    ``entry`` past the planes is a dead row: nothing read, nothing written.
+    Returns (the mixer's output [B,T,D], the planes as written)."""
+    from kubeflow_tpu.ops import ssd
+
+    t = h.shape[1]
+    mats, tails = (pools[n] for n in SSD_PLANES)
+    fresh = start == 0
+    impl = "pallas" if attn_impl == "pallas" else "xla"
+    xs, z, dt, bm, cm, tail = L.ssd_inputs(
+        sp, h, cfg, _state_at(tails, entry, fresh), None if t == 1 else valid)
+    a, d = L.ssd_decay(sp), sp["d_skip"].astype(jnp.float32)
+    if t == 1:
+        y, mats = ssd.ssd_step(
+            xs[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0], d, mats, entry, fresh,
+            entry < mats.shape[0], impl=impl)
+        y = y[:, None]
+    else:
+        y, mat = ssd.ssd_chunk(xs, dt, a, bm, cm, d,
+                               _state_at(mats, entry, fresh), impl=impl,
+                               block=cfg.ssd_chunk)
+        mats = mats.at[entry].set(mat, mode="drop")
+    pools = {**pools, SSD_PLANES[0]: mats,
+             SSD_PLANES[1]: tails.at[entry].set(tail.astype(tails.dtype),
+                                                mode="drop")}
+    return L.ssd_output(sp, y, z, cfg), pools
 
 
 def _qkv_rope(a, h, positions, cfg: DecoderConfig, lora=None,  # traced
@@ -1865,13 +1943,14 @@ def _chunk_in_place(cache: dict, cfg: DecoderConfig, lora,
     form: int8 pools (scale planes), packed rows and the conv state beside
     them, a call with LoRA, planes the kernel cannot part by head. A window
     layer's planes are K and V per head like a global layer's and go the
-    same way; a linear or ssm layer's planes ride beside them (its operator
-    reads a state a row, not pages)."""
+    same way; a linear, ssm or parallel layer's state planes ride beside
+    them (their operator reads a state a row, not pages)."""
     if cfg.is_latent:
         return True
     from kubeflow_tpu.ops.paged_attention import chunk_attention_supported
 
-    planes = set(_planes_of(cache)) - set(LINEAR_PLANES) - set(SSM_PLANES)
+    planes = set(_planes_of(cache)).difference(
+        *SEQUENCE_PLANES.values())
     k = cache[next(n for n in ("k", *WINDOW_PLANES) if n in cache)]
     rows = kept_as_rows(cfg)    # [L, P, page * rows, D], else [L, P, page, KV, D]
     heads = (rows, k.shape[-1]) if rows else k.shape[3:]
@@ -1889,8 +1968,9 @@ def chunk_carries_step(cache: dict, cfg: DecoderConfig, lora,
     pool in place (``_chunk_in_place``: no int8 pool, no packed rows, no
     call with LoRA) and every layer is of kind "attention" (per-head planes
     or a latent pool, whatever its feed-forward): the kinds that keep a
-    state, a ring or a tail wait for their operators to be held side by side
-    in one program (ROADMAP Speed)."""
+    state, a ring or a tail (a parallel layer's SSD state among them) wait
+    for their operators to be held side by side in one program (ROADMAP
+    Speed)."""
     return (attn_impl == "pallas" and set(cfg.kinds) == {"attention"}
             and _chunk_in_place(cache, cfg, lora, attn_impl))
 

@@ -236,6 +236,30 @@ REASONING_STATED = {
 }
 
 
+# PR 50's cell (falcon-h1-34b.batch-assistant), the same way: its rehearsal
+# cell (the engine refuses prefix reuse over parallel layers), and each
+# metric's number for a window without samples (the pool's share is a
+# constant of the engine as built); its readers take counters the older cells'
+# tables hold already.
+ASSISTANT_CELLS = {
+    "tiny-falconh1.rehearsal-closed-ssd": (
+        "falcon-h1-34b.batch-assistant", "rehearsal-tiny-falconh1",
+        "rehearsal-closed-ssd", 1),
+}
+ASSISTANT_STATED = {
+    "kernel.ssd_chunk_roofline_share.assistant": 0.0,
+    "kernel.ssd_step_bw_share.assistant": 0.0,
+    "kernel.paged_decode_attention_bw_share.assistant": 0.0,
+    "kernel.paged_chunk_attention_mfu.assistant": 0.0,
+    "step.decode_weight_bw_share.assistant": 0.0,
+    "step.prefill_mfu.assistant": 0.0,
+    "kv.state_share_of_pool.assistant": 12.5,     # 32768 of 262144 bytes
+    "engine.decode_occupancy.assistant": 0.0,
+    "kv.preemptions.assistant": 0.0,
+    "engine.sched_busy_share_window.assistant": 0.0,
+}
+
+
 @pytest.fixture(autouse=True, scope="session")
 def benchmark_suite_tables_know_the_longanswer_cell(request):
     suite = next(
@@ -253,14 +277,14 @@ def benchmark_suite_tables_know_the_longanswer_cell(request):
     for tables, added in (
             ((suite.ADDED_CELLS, rehearsal.CELLS),
              {**LONGANSWER_CELLS, **MIXEDLENGTH_CELLS, **LONGDOC_CELLS,
-              **REASONING_CELLS}),
+              **REASONING_CELLS, **ASSISTANT_CELLS}),
             ((suite.ADDED_ENGINE_COUNTERS, readers.ENGINE0),
              {**LONGANSWER_ENGINE_COUNTERS, **WINDOW_ENGINE_COUNTERS,
               **MIXEDLENGTH_ENGINE_COUNTERS, **LONGDOC_ENGINE_COUNTERS,
               **REASONING_ENGINE_COUNTERS}),
             ((suite.ADDED_STATED, total.STATED),
              {**LONGANSWER_STATED, **WINDOW_STATED, **MIXEDLENGTH_STATED,
-              **LONGDOC_STATED, **REASONING_STATED})):
+              **LONGDOC_STATED, **REASONING_STATED, **ASSISTANT_STATED})):
         for table in tables:
             for key, value in added.items():
                 table.setdefault(key, value)
@@ -278,7 +302,8 @@ def benchmark_suite_tables_know_the_longanswer_cell(request):
 # per-layer metrics behind all of those, and PR 43 a configuration, a cell
 # and twelve behind PR 40's: the pinning tests are handed the manifest
 # without them too. PR 47 appends a configuration, a cell and twelve behind
-# PR 43's, whose own test pins ITS entries at the end the same way.
+# PR 43's, whose own test pins ITS entries at the end the same way; PR 50 a
+# configuration, a cell and ten behind PR 47's (whose test pins no end).
 PINS_PR43_AT_THE_END = "test_what_pr_43_added_is_listed_with_the_benchmark"
 PINS_PR28_AT_THE_END = "test_what_this_pr_added_is_listed_with_the_benchmark"
 PINS_PR35S_CELL = \
@@ -314,9 +339,10 @@ def the_manifest_as_it_stood_for_the_tests_that_pin_a_pr(request,
     name = request.node.name
     module = request.node.module
     later = set(WINDOW_STATED) | set(MIXEDLENGTH_STATED) \
-        | set(LONGDOC_STATED) | set(REASONING_STATED)
+        | set(LONGDOC_STATED) | set(REASONING_STATED) | set(ASSISTANT_STATED)
     mixed = next(iter(MIXEDLENGTH_CELLS.values()))[0]
     reasoning = next(iter(REASONING_CELLS.values()))[0]
+    assistant = next(iter(ASSISTANT_CELLS.values()))[0]
     if (module.__name__, request.node.originalname) == PINS_PR35S_LINE:
         whole = module.rehearsal_manifest
         monkeypatch.setattr(module, "rehearsal_manifest", lambda: {
@@ -329,9 +355,11 @@ def the_manifest_as_it_stood_for_the_tests_that_pin_a_pr(request,
     if name not in (PINS_PR28_AT_THE_END, PINS_PR35S_CELL,
                     PINS_PR43_AT_THE_END):
         return
-    cells = {mixed, next(iter(LONGDOC_CELLS.values()))[0], reasoning}
+    cells = {mixed, next(iter(LONGDOC_CELLS.values()))[0], reasoning,
+             assistant}
     if name == PINS_PR43_AT_THE_END:
-        later, cells = set(REASONING_STATED), {reasoning}
+        later = set(REASONING_STATED) | set(ASSISTANT_STATED)
+        cells = {reasoning, assistant}
     if name == PINS_PR28_AT_THE_END:
         later |= set(LONGANSWER_STATED)
         cells.add(next(iter(LONGANSWER_CELLS.values()))[0])

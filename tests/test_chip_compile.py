@@ -681,6 +681,7 @@ def test_mixed_program_compiles_for_v5e_with_both_kernels(cell_programs,
     ("k-exaone-236b-a23b.batch-mixedlength", False),    # window layers
     ("solar-open2-250b.batch-longdoc", False),          # linear layers
     ("phi-4-mini-flash.batch-reasoning", False),        # ssm, gmu, cross
+    ("falcon-h1-34b.batch-assistant", False),           # parallel layers
 ])
 def test_which_cells_chunk_program_carries_the_step(cell, carries):
     """The rule reads the stack and the pool (``paged.chunk_carries_step``),
@@ -938,3 +939,77 @@ def test_reasoning_program_compiles_for_v5e_with_its_kernels(cell_programs,
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 11.5e9
     assert mem.temp_size_in_bytes < 0.5e9
+
+
+def test_ssd_kernels_compile_for_v5e(chip):
+    """``ops/ssd.py`` at Falcon-H1's widths (32 heads of 128 values, 2
+    groups, a state of 256, bfloat16 operands): the chunk kernel over one
+    and two rows of 512 positions, ONE Mosaic call named ``ssd_chunk`` whose
+    grid step holds a group's sixteen states beside its blocks (over the
+    compiler's default of 16 MiB: ``ssd.CHUNK_VMEM_BYTES``), and the step
+    kernel over 48 streams whose state lies in a plane of 240 entries,
+    ALIASED to the result (no copy of the plane: what the call holds beyond
+    its arguments stays under the operands' few megabytes)."""
+    from kubeflow_tpu.ops import ssd
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    bf = jnp.bfloat16
+    h, p, g, n = 32, 128, 2, 256
+    for b in (1, 2):
+        c = 512
+        chunk = jax.jit(lambda x, dt, a, bm, cm, d, s: ssd.ssd_chunk(
+            x, dt, a, bm, cm, d, s, impl="pallas", interpret=False)).lower(
+            sds(b, c, h, p, dtype=bf), sds(b, c, h), sds(h),
+            sds(b, c, g, n, dtype=bf), sds(b, c, g, n, dtype=bf), sds(h),
+            sds(b, h, n, p)).compile()
+        text = chunk.as_text()
+        assert "tpu_custom_call" in text and _calls(text, "ssd_chunk") == 1
+        assert chunk.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+    b = 48
+    plane = sds(5 * 48, h, n, p)
+    step = jax.jit(
+        lambda x, dt, a, bm, cm, d, pl, i, f, lv: ssd.ssd_step(
+            x, dt, a, bm, cm, d, pl, i, f, lv, impl="pallas",
+            interpret=False), donate_argnums=(6,)).lower(
+        sds(b, h, p, dtype=bf), sds(b, h), sds(h), sds(b, g, n, dtype=bf),
+        sds(b, g, n, dtype=bf), sds(h), plane, sds(b, dtype=jnp.int32),
+        sds(b, dtype=jnp.bool_), sds(b, dtype=jnp.bool_)).compile()
+    assert _calls(step.as_text(), "ssd_step") == 1
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= 5 * 48 * h * n * p * 4
+    assert mem.temp_size_in_bytes < 8 * 2 ** 20, mem.temp_size_in_bytes
+
+
+ASSISTANT = "falcon-h1-34b.batch-assistant"
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk[1]"])
+def test_assistant_program_compiles_for_v5e_with_its_kernels(cell_programs,
+                                                             program):
+    """The assistant cell's decode step and its one-row chunk program (a
+    dense model at 512 tokens: the engine builds no program over several
+    prompts' rows) at the cell's real sizes, parameters as the engine holds
+    them: each fits the chip beside its arguments, runs BOTH branches'
+    kernels in every layer's scan (``ssd_step`` and the decode kernel;
+    ``ssd_chunk`` and the chunk kernel) and copies no weight: the
+    in-projection is held as three lane-aligned leaves (as ONE ``[5120,
+    9248]`` matrix the decode program copied all five layers' 0.47 GB of it
+    in front of every step: this compile is what said so)."""
+    from scripts.aot_weight_copies import weight_copies
+
+    lowered = cell_programs(ASSISTANT)[program]
+    assert set(cell_programs(ASSISTANT)) == {"decode", "chunk[1]"}
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    for kernel in (("ssd_step", "paged_decode_attention")
+                   if program == "decode"
+                   else ("ssd_chunk", "paged_chunk_attention")):
+        assert _calls(text, kernel) >= 1, kernel
+    copied = {leaf for c in weight_copies(text, lowered.args_info[0][0])
+              for leaf in c["leaf"]}
+    assert copied == set(), copied
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.2e9
+    assert mem.temp_size_in_bytes < 0.1e9
