@@ -938,8 +938,12 @@ class LLMEngine:
                 self._pin)
 
         # ONE prompt's chunk: tokens [1,C], its table row, scalar start
-        # and valid length; [C,V] logits, every position's (callers
-        # outside the engine compare them all).
+        # and valid length; [C,V] logits, every position's. For callers
+        # OUTSIDE the engine, which compare them all (the benchmark's
+        # ``correct``, the scripts); of the engine's traffic only a lone
+        # chunk of an engine that sends several chunks a program by the
+        # ridge, or a mixed engine's with no live slot, takes it
+        # (``_dispatch_chunks``).
         self._paged_chunk = jax.jit(
             lambda p, c, t, tr, st, vl, ncp, lr=None, ai=None: _row0(
                 _chunk_rows_fn(p, c, t, tr[None], st[None], vl[None],
@@ -947,6 +951,14 @@ class LLMEngine:
             static_argnums=(6,), donate_argnums=(1,))
         if not chunk_reads_context(self.cache, cfg_prefill, None, pattn):
             self._paged_chunk = _OneContext(self._paged_chunk, self._mpp)
+        # Whether a chunk program CARRIES the decode step (the program is
+        # built further down, where its comment is): where the stack and
+        # the pool allow it (``chunk_carries_step``) and nothing rides the
+        # programs that it does not carry: adapter buffers, a speculative
+        # round.
+        self._mixed = (
+            chunk_carries_step(self.cache, cfg_prefill, None, pattn)
+            and b.speculative.mode == "off" and not b.lora.max_adapters)
         # The chunks of ALL in-flight prefills in one program (tokens
         # [B,C], a table row, a start, a valid length and "this row ends
         # its prompt" a row), so a scheduler pass reads every weight once.
@@ -955,37 +967,43 @@ class LLMEngine:
         # a prompt's last chunk, so a program in which no row ends one
         # runs no head at all (the head over every position was 7% of a
         # long-context cell's device time and 634 MB a result at a
-        # vocabulary of 155k). Built
-        # only where one chunk leaves the weights under-used
-        # (``chunk_rows_per_weight``): a dense model at 512 tokens
-        # dispatches exactly as it always did. It is dispatched at ONE
-        # static context, the whole table: a row's attention follows
-        # its own context whatever the table's length (the chunk
-        # kernels skip the pages behind their chunk; the gathered
-        # form's span ladder, layers._cached_attention_by_row), so a
-        # ladder of context buckets would spare only the gather of a
+        # vocabulary of 155k; a third of a one-row program's time and 535
+        # MB at 261k). It takes SEVERAL rows only where one chunk leaves
+        # the weights under-used (``chunk_rows_per_weight``), and is then
+        # dispatched at ONE static context, the whole table: a row's
+        # attention follows its own context whatever the table's length
+        # (the chunk kernels skip the pages behind their chunk; the
+        # gathered form's span ladder, layers._cached_attention_by_row),
+        # so a ladder of context buckets would spare only the gather of a
         # pool that still takes the gathered form, and each further
-        # program is loaded and run at every start (0.75 s warm, 5 s
-        # cold on a v5e: PERF.md, PR 29).
-        # A stack that ENDS in layers that keep no state
-        # (``cfg.stateless_tail``) builds it whatever the ridge says and
-        # sends EVERY chunk through it, one prefill alone as a group of one
-        # row: the program over rows runs that tail at the one position a
-        # row whose logits are read, and not at all where no row ends its
-        # prompt (``paged._pool_forward``), where the one-row ``[C, V]``
-        # program runs it, and the head, at all ``C``.
-        self._tail_at_last = cfg_prefill.stateless_tail > 0
+        # program is loaded and run at every start (0.75 s warm, 5 s cold
+        # on a v5e: PERF.md, PR 29).
+        # An engine that sends ONE chunk a program (a dense model at 512
+        # tokens, over the ridge; one prefill at a time) and carries no
+        # step in its chunk programs sends every chunk through it all the
+        # same, as a group of one row (``_lone_at_last``): the engine reads
+        # one row of a chunk's logits or none, and the ``[C, V]`` program
+        # runs the head at all ``C``. So does, whatever the ridge says, a
+        # stack that ENDS in layers that keep no state
+        # (``cfg.stateless_tail``): the program over rows runs that tail
+        # at the one position a row whose logits are read, and not at all
+        # where no row ends its prompt (``paged._pool_forward``). A group
+        # of one is dispatched at its own context bucket, as the one-row
+        # program is: as many programs as that had, named alike.
+        tail_at_last = cfg_prefill.stateless_tail > 0
         by_ridge = self.max_concurrent_prefills > 1 and chunk_rows_per_weight(
             cfg_prefill, self.chunk_size) < RIDGE_ROWS
-        if by_ridge or self._tail_at_last:
-            if self.max_concurrent_prefills > 1:
-                self._chunk_rows = self.max_concurrent_prefills
+        if (by_ridge or tail_at_last) and self.max_concurrent_prefills > 1:
+            self._chunk_rows = self.max_concurrent_prefills
+        self._lone_at_last = tail_at_last or (
+            self._chunk_rows == 1 and not self._mixed)
+        if by_ridge or self._lone_at_last:
             self._paged_chunks = jax.jit(
                 lambda p, c, t, tr, st, vl, ends, ncp, lr=None, ai=None:
                 _chunk_rows_fn(p, c, t, tr, st, vl, ncp, lr, ai, "last",
                                ends),
                 static_argnums=(7,), donate_argnums=(1,))
-            if self._tail_at_last and not chunk_reads_context(
+            if self._lone_at_last and not chunk_reads_context(
                     self.cache, cfg_prefill, None, pattn):
                 # (a group of one names its own bucket: ``_dispatch_chunks``)
                 self._paged_chunks = _OneContext(self._paged_chunks,
@@ -1026,9 +1044,7 @@ class LLMEngine:
         # has that one width, so such an engine loads no program more than
         # it did. It is jitted as the chunk programs are, a lambda: what
         # finds a decode step by its module's name finds decode-only steps.
-        self._mixed = (
-            chunk_carries_step(self.cache, cfg_prefill, None, pattn)
-            and b.speculative.mode == "off" and not b.lora.max_adapters)
+        # (``_mixed`` itself: above, in front of the program over rows.)
         self._mixed_pass = -1    # lockfree: scheduler-confined (the admit pass that sent a round)
 
         def _mixed_fn(p, c, t, tr, s0, vl, ends, ride, st, tbl, key, m):
@@ -1294,6 +1310,10 @@ class LLMEngine:
         self._prefill_chunks_dispatched = 0     # lockfree: scheduler-confined counter
         self._prefill_row_programs_dispatched = 0   # lockfree: scheduler-confined counter
         self._prefill_programs_with_end = 0     # lockfree: scheduler-confined counter
+        # Positions at which the chunk programs ran the head: a chunk's
+        # ``C`` in a ``[C, V]`` program, one a row in a program over rows
+        # where any row's logits are read, none where none's are.
+        self._prefill_head_positions = 0        # lockfree: scheduler-confined counter
         self._prefill_tokens_dispatched = 0     # lockfree: scheduler-confined counter
         # Prefill programs that carried a decode step, and the live rows
         # those steps had.
@@ -1615,6 +1635,7 @@ class LLMEngine:
             "prefill_row_programs_dispatched":
                 self._prefill_row_programs_dispatched,
             "prefill_programs_with_end": self._prefill_programs_with_end,
+            "prefill_head_positions": self._prefill_head_positions,
             # scheduler iterations that sent a prefill program (programs
             # over passes: the programs every live stream waited for at
             # once), and the chunks that were due in a pass and waited for
@@ -2048,11 +2069,15 @@ class LLMEngine:
         """ONE program for the next chunk of every prefill in ``group``
         (their pages are reserved): row ``r`` carries ``group[r]``'s tokens,
         table row, start, valid length and whether the chunk ends its
-        prompt (only then are the row's logits read). One prefill alone, or
-        an engine that built no program over several rows, takes the
-        one-row program it always took; an engine whose stack ends in a
-        stateless tail sends it through the program over rows as a group of
-        one row (``__init__``).
+        prompt (only then are the row's logits read). One prefill alone goes
+        through the program over rows as a group of one row, at its own
+        context bucket, where the engine sends one chunk a program and
+        carries no step in it, or its stack ends in a stateless tail
+        (``_lone_at_last``, ``__init__``): no chunk of such an engine's
+        traffic runs the head at a position nobody reads. It takes the
+        one-row ``[C, V]`` program only as the lone chunk of an engine that
+        sends several chunks a program by the ridge, or of a mixed engine
+        with no live slot.
 
         Where the engine built the chunk program that carries the decode
         step (``_mixed``) and a slot is live, the pass's first program
@@ -2074,7 +2099,7 @@ class LLMEngine:
         # program of the engine's one width (rows past the group dead).
         together = len(group) > 1 or ride is not None
         rows = self._chunk_rows if together else 1
-        by_rows = together or self._tail_at_last
+        by_rows = together or self._lone_at_last
         reals = [min(C, len(ch.request.prompt_tokens) - ch.pos)
                  for ch in group]
         ends = [ch.pos + real == len(ch.request.prompt_tokens)
@@ -2136,6 +2161,9 @@ class LLMEngine:
         self._prefill_programs_dispatched += 1
         self._prefill_row_programs_dispatched += rows > 1
         self._prefill_programs_with_end += any(ends)
+        # (beside a riding step the one head runs over the chunks' rows too)
+        self._prefill_head_positions += (
+            rows * (any(ends) or ride is not None) if by_rows else C)
         self._prefill_chunks_dispatched += len(group)
         self._prefill_tokens_dispatched += sum(reals)
         if self._kv_sequence_pool_bytes:

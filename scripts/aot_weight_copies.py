@@ -78,8 +78,10 @@ def lowered_programs(cfg, batching, dev, *, relaid: bool = True,
     """``{program: jax.stages.Lowered}`` of an engine over ``cfg`` and
     ``batching`` on the one described chip ``dev`` (a sharding): "decode"
     (one step a dispatch), "chunk[1]" and, where the engine builds the
-    program over several prompts' rows, "chunk[N]" (a stack that ends in a
-    stateless tail: also "rows[1]", the program over rows at one row). ``relaid``: the
+    program over several prompts' rows, "chunk[N]" (an engine that sends a
+    prefill alone through the program over rows at one row, "last": also
+    "rows[1]"; "chunk[1]" is then the ``[C, V]`` program of callers outside
+    the engine's traffic). ``relaid``: the
     parameters in the engine's formats, else all in the default layout.
     ``auto``: the parameters' layouts left to the compiler, whatever
     ``relaid`` says (``compiled.input_formats`` then says what it chose).
@@ -151,12 +153,16 @@ def lowered_programs(cfg, batching, dev, *, relaid: bool = True,
     by_rows = rows_logits_at == "last"
     forms = [("chunk[1]", 1, False)]
     tail = cfg_prefill.stateless_tail > 0
-    if tail and by_rows:
-        # a stack that ends in a stateless tail sends a prefill alone
-        # through the program over rows as a group of one (serve/engine.py)
+    wider = b.max_concurrent_prefills > 1 and (tail or chunk_rows_per_weight(
+        cfg_prefill, chunk_tokens) < RIDGE_ROWS)
+    # a stack that ends in a stateless tail, and an engine that sends one
+    # chunk a program and carries no step in it, send a prefill alone
+    # through the program over rows as a group of one
+    # (serve/engine.py: ``_lone_at_last``)
+    if by_rows and (tail or not (wider or chunk_carries_step(
+            cache, cfg_prefill, None, "pallas"))):
         forms.append(("rows[1]", 1, True))
-    if b.max_concurrent_prefills > 1 and (tail or chunk_rows_per_weight(
-            cfg_prefill, chunk_tokens) < RIDGE_ROWS):
+    if wider:
         n = b.max_concurrent_prefills
         forms.append((f"chunk[{n}]", n, by_rows))
     for key, n, last in forms:

@@ -35,7 +35,8 @@ section 6, PR 36).
 ``cell`` is ``python3 -m benchmark.run`` with the engine's counters printed:
 the window's ``prefill_chunks_dispatched / prefill_programs_dispatched``, the
 programs sent through the program over rows and those in which a prompt ended
-(``prefill_row_programs_dispatched``, ``prefill_programs_with_end``: PR 41) and
+(``prefill_row_programs_dispatched``, ``prefill_programs_with_end``: PR 41), the
+positions at which they ran the head (``prefill_head_positions``: PR 52) and
 tokens, from the snapshots the harness takes (they ride in the run's record,
 which the result line does not print), the Pallas kernels in each program
 variant the warm-up reached (``LLMEngine.program_kernels``), the device's
@@ -270,11 +271,15 @@ def run_cell(argv: list) -> int:
     if len(snapshots) >= 2 and snapshots[0] and "engine" in snapshots[0]:
         before, after = snapshots[0]["engine"], snapshots[1]["engine"]
         d = {k: after[k] - before[k] for k in after
-             if k.startswith("prefill_") and (
-                 k.endswith("_dispatched") or k.endswith("_with_end"))}
+             if k.startswith("prefill_") and k.endswith(
+                 ("_dispatched", "_with_end", "_head_positions"))}
         if d.get("prefill_programs_dispatched"):
             d["chunks_per_program"] = (d["prefill_chunks_dispatched"]
                                        / d["prefill_programs_dispatched"])
+        if "prefill_head_positions" in d and d["prefill_chunks_dispatched"]:
+            # positions a chunk at which a chunk program ran the head (PR 52)
+            d["head_positions_per_chunk"] = (d["prefill_head_positions"]
+                                             / d["prefill_chunks_dispatched"])
         _log(f"window counters: {json.dumps(d)}")
     return rc
 

@@ -6,7 +6,25 @@
 # TRAFFIC=<file> lays that traffic file over the cell's own first (in the
 # machine's copy of the checkout, so the file lies outside chiprun_out/: a
 # sizing experiment, nothing is committed). WORKLOAD=<cell> runs another
-# cell (scripts/solar_cell_chip.sh).
+# cell (scripts/solar_cell_chip.sh, scripts/falconh1_cell_chip.sh).
+# scripts/exaone_cell_chip.sh pairs <tag> <trace> <seed> [...]
+# runs every seed on BOTH sides, the parent commit (unpacked under .parent/:
+# git archive <parent> | tar -x -C .parent) and the working tree (or DIR=),
+# parent first for the odd pairs and last for the even ones; the two sides
+# of a pair share their seed, outputs under chiprun_out/<tag>/parent|change/.
+if [ "$1" = pairs ]; then
+  tag=$2; shift 2; n=0
+  while [ $# -ge 2 ]; do
+    n=$((n + 1)); order="parent change"; [ $((n % 2)) = 0 ] && order="change parent"
+    for side in $order; do
+      dir=${DIR:-.}; [ $side = parent ] && dir=.parent
+      echo "== pair $n $side"
+      DIR=$dir bash "$0" $tag/$side $1 $2
+    done
+    shift 2
+  done
+  exit 0
+fi
 cell=${WORKLOAD:-k-exaone-236b-a23b.batch-mixedlength}
 tag=$1; shift
 here=$(pwd); mkdir -p chiprun_out/$tag
@@ -29,5 +47,9 @@ while [ $# -ge 2 ]; do
   # the traced tail's modules and its forty heaviest ops
   cp ${DIR:-.}/benchmark_out/$cell/trace_summary.json \
     $out.summary.json 2>/dev/null
+  # every token's arrival (KEEP_LOADGEN=1: a run far under its set is held
+  # against it, PERF.md section 7)
+  [ -n "$KEEP_LOADGEN" ] && gzip -c ${DIR:-.}/benchmark_out/$cell/loadgen.json \
+    > $out.loadgen.json.gz 2>/dev/null
 done
 true

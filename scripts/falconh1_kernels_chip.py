@@ -21,7 +21,12 @@ contexts of 256, 800 and 1536, every slot on pages of its own: per step the
 five ``ssd_step`` and the five ``paged_decode_attention`` calls, each beside
 its bytes at the bus's peak, and the program's heaviest instructions; the
 one-row ``[C, V]`` chunk program at starts 0 and 1024: the five ``ssd_chunk``
-and ``paged_chunk_attention`` calls.
+and ``paged_chunk_attention`` calls; beside it what the engine's traffic runs
+since PR 52, the program over rows at ONE row (``engine._paged_chunks``: the
+head at the last valid position, under a ``cond``), with the prompt's end in
+the chunk and without, and the two forms on the same tokens into pages of
+their own: the row traffic reads against ``logits[C - 1]``, the planes
+written.
 
 ``blind``: one prompt of the comparison's own longest size through the
 engine's chunk programs against the float32 reference on the chip, sound;
@@ -206,17 +211,61 @@ def main(argv=None) -> int:
             return logits
         return run
 
+    def at_last(start: int, ends: bool, slot: int = 0):
+        def run():
+            logits, eng.cache = eng._paged_chunks(
+                eng.params, eng.cache, block, jnp.asarray(table[slot][None]),
+                jnp.asarray([start], jnp.int32), jnp.asarray([C], jnp.int32),
+                jnp.asarray([ends]), context_bucket(start, C, pg, mpp))
+            return logits
+        return run
+
+    # what the engine's traffic runs (PR 52): the program over rows at one
+    # row, with the prompt's end in the chunk and without
+    forms = [("chunk[1] all positions", one_row)]
+    if getattr(eng, "_lone_at_last", False):
+        forms += [("rows[1] last position, ends its prompt",
+                   lambda start: at_last(start, True)),
+                  ("rows[1] last position, ends none",
+                   lambda start: at_last(start, False))]
     for start in (0, 2 * C) if "chunk" in args.parts else ():
+        for name, form in forms:
+            print(json.dumps({
+                "part": name, "start": start,
+                "ssd_chunk_ms_at_the_bus": round(
+                    1e3 * counts.ssd_chunk_bytes(conf, C, 1) / BUS, 4),
+                "ssd_chunk_ms_at_the_peak": round(
+                    1e3 * counts.ssd_chunk_flops(conf, C) / PEAK, 4),
+                "program_ms_at_the_peak": round(
+                    1e3 * (counts.prefill_flops(conf, start + C)
+                           - counts.prefill_flops(conf, start)) / PEAK, 3),
+                **traced(form(start), args.calls, OPS, top=24)}), flush=True)
+    if "chunk" in args.parts and len(forms) > 1:
+        # the two forms on the same tokens, each into a slot's own pages and
+        # entry: the one row traffic reads, and what the pool was left
+        every = np.asarray(one_row(0)()[C - 1])
+        last = np.asarray(at_last(0, True, slot=1)()[0])
+        third = min(2, slots - 1)
+        none = np.asarray(at_last(0, False, slot=third)()[0])
+        used = -(-C // pg)
+        apart = {}
+        for plane, pool in eng.cache.items():
+            if pool.ndim < 2:
+                continue
+            # an entry a sequence at table_row[0]; rows a token in its pages
+            ids = [table[b, :1] if pool.shape[1] == slots
+                   else table[b, :used] for b in (0, 1, third)]
+            rows = [np.asarray(pool[:, jnp.asarray(i)], np.float32)
+                    for i in ids]
+            apart[plane] = [float(np.abs(r - rows[0]).max())
+                            for r in rows[1:]]
         print(json.dumps({
-            "part": "chunk[1] all positions", "start": start,
-            "ssd_chunk_ms_at_the_bus": round(
-                1e3 * counts.ssd_chunk_bytes(conf, C, 1) / BUS, 4),
-            "ssd_chunk_ms_at_the_peak": round(
-                1e3 * counts.ssd_chunk_flops(conf, C) / PEAK, 4),
-            "program_ms_at_the_peak": round(
-                1e3 * (counts.prefill_flops(conf, start + C)
-                       - counts.prefill_flops(conf, start)) / PEAK, 3),
-            **traced(one_row(start), args.calls, OPS, top=24)}), flush=True)
+            "part": "rows[1] against chunk[1]", "positions": C,
+            "logits_max_abs": float(np.abs(last - every).max()),
+            "logits_largest": float(np.abs(every).max()),
+            "same_greedy_token": bool(last.argmax() == every.argmax()),
+            "no_end_is_zeros": bool((none == 0.0).all()),
+            "pool_max_abs_ends_and_not": apart}), flush=True)
 
     if "blind" in args.parts:
         spec = conf["correctness"]

@@ -985,22 +985,28 @@ def test_ssd_kernels_compile_for_v5e(chip):
 ASSISTANT = "falcon-h1-34b.batch-assistant"
 
 
-@pytest.mark.parametrize("program", ["decode", "chunk[1]"])
+@pytest.mark.parametrize("program", ["decode", "chunk[1]", "rows[1]"])
 def test_assistant_program_compiles_for_v5e_with_its_kernels(cell_programs,
                                                              program):
-    """The assistant cell's decode step and its one-row chunk program (a
-    dense model at 512 tokens: the engine builds no program over several
-    prompts' rows) at the cell's real sizes, parameters as the engine holds
+    """The assistant cell's decode step and its two one-row chunk programs
+    (a dense model at 512 tokens sends one chunk a program: "rows[1]", the
+    program over rows at one row, is what its traffic runs since PR 52, the
+    head at ONE position under a conditional; "chunk[1]", every position's
+    logits, is what callers outside the engine drive) at the cell's real
+    sizes, parameters as the engine holds
     them: each fits the chip beside its arguments, runs BOTH branches'
     kernels in every layer's scan (``ssd_step`` and the decode kernel;
     ``ssd_chunk`` and the chunk kernel) and copies no weight: the
     in-projection is held as three lane-aligned leaves (as ONE ``[5120,
     9248]`` matrix the decode program copied all five layers' 0.47 GB of it
-    in front of every step: this compile is what said so)."""
-    from scripts.aot_weight_copies import weight_copies
+    in front of every step: this compile is what said so). What a chunk
+    program returns beyond the pool it was donated is ``[512, V]`` float32
+    (535 MB at a vocabulary of 261120) in the one form and ``[1, V]`` (1 MB)
+    in the other."""
+    from scripts.aot_weight_copies import serving_cell, weight_copies
 
     lowered = cell_programs(ASSISTANT)[program]
-    assert set(cell_programs(ASSISTANT)) == {"decode", "chunk[1]"}
+    assert set(cell_programs(ASSISTANT)) == {"decode", "chunk[1]", "rows[1]"}
     compiled = lowered.compile()
     text = compiled.as_text()
     for kernel in (("ssd_step", "paged_decode_attention")
@@ -1013,3 +1019,11 @@ def test_assistant_program_compiles_for_v5e_with_its_kernels(cell_programs,
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.2e9
     assert mem.temp_size_in_bytes < 0.1e9
+    if program != "decode":
+        cfg, batching = serving_cell(ASSISTANT)
+        positions = batching.chunked_prefill_tokens \
+            if program == "chunk[1]" else 1
+        logits = positions * cfg.vocab_size * 4
+        result = mem.output_size_in_bytes - mem.alias_size_in_bytes
+        print(f"{program}: result {result} temp {mem.temp_size_in_bytes}")
+        assert logits <= result < logits + 2 ** 20, result

@@ -9,7 +9,10 @@ in place and attended through ``paged_chunk_attention`` (interpreted here),
 against the gathered form, and the kernel alone against plain attention. And
 the program's last-position form (ISSUE 41): ``[B,V]`` logits, the head at each
 row's last valid position, against the all-position form's row there, over
-every kind of pool; the engine's program over rows takes it."""
+every kind of pool; the engine's program over rows takes it. And the engine
+that sends ONE chunk a program (ISSUE 52): a lone chunk through the program
+over rows at one row, the head at one position or under the untaken branch of
+a ``cond``, the ``[C,V]`` program left to callers outside the engine."""
 
 import contextlib
 import dataclasses
@@ -21,7 +24,7 @@ import numpy as np
 import pytest
 
 from benchmark.device import CompileCounter
-from kubeflow_tpu.core.serving import BatchingSpec
+from kubeflow_tpu.core.serving import BatchingSpec, LoRASpec, SpeculativeSpec
 from kubeflow_tpu.models import layers as L
 from kubeflow_tpu.models.config import preset
 from kubeflow_tpu.models.decoder import init_decoder_params
@@ -29,6 +32,7 @@ from kubeflow_tpu.obs import profiler
 from kubeflow_tpu.serve.engine import (
     RIDGE_ROWS, LLMEngine, SamplingParams, chunk_rows_per_weight,
 )
+from kubeflow_tpu.serve.lora import AdapterSpec, init_adapter_weights
 from kubeflow_tpu.ops.attention import multi_head_attention
 from kubeflow_tpu.ops.paged_attention import (
     CHUNK_PAGES_PER_STEP, CHUNK_QUERY_TILE, chunk_attention_supported,
@@ -55,6 +59,8 @@ def _config(kind: str):
         # LFM2-like: conv layers beside attention, state in the pool
         return preset("tiny-lfm2", dtype="float32", param_dtype="float32",
                       max_seq_len=1024)
+    if kind == "parallel":
+        return _parallel_config()
     return preset("tiny-glm", dtype="float32", param_dtype="float32",
                   max_seq_len=1024)
 
@@ -497,7 +503,18 @@ LAST_KINDS = {
         lambda: preset("tiny-lfm2", max_seq_len=1024), "gather"),
     "window": (lambda: _window_config(), "gather"),
     "window-in-place": (lambda: _window_config(head_dim=128), "pallas"),
+    # attention and an SSD mixer side by side (the assistant cell's way): K
+    # and V rows a token and a state and a convolution's rows a sequence
+    "parallel": (lambda: _parallel_config(), "gather"),
+    "parallel-in-place": (
+        lambda: _parallel_config(n_heads=2, n_kv_heads=1, head_dim=128),
+        "pallas"),
 }
+
+
+def _parallel_config(**over):
+    return preset("tiny-falconh1", dtype="float32", param_dtype="float32",
+                  max_seq_len=1024, **over)
 
 
 def _window_config(**over):
@@ -627,20 +644,23 @@ class TestLastPosition:
         assert _head_products(traced.jaxpr, cfg.vocab_size) == [True]
 
 
-def _head_products(jaxpr, vocab: int, under_cond: bool = False) -> list:
-    """For every matrix product in ``jaxpr`` whose result is ``[2, 1, V]``
-    (the head at one position a row): whether it lies under a ``cond``."""
+def _head_products(jaxpr, vocab: int, under_cond: bool = False,
+                   rows: int = 2) -> list:
+    """For every matrix product in ``jaxpr`` whose result is ``[rows, 1,
+    V]`` (the head at one position a row): whether it lies under a
+    ``cond``."""
     found = []
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "dot_general" \
-                and eqn.outvars[0].aval.shape == (2, 1, vocab):
+                and eqn.outvars[0].aval.shape == (rows, 1, vocab):
             found.append(under_cond)
         for value in eqn.params.values():
             for sub in value if isinstance(value, (tuple, list)) else (value,):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
                     found += _head_products(
-                        sub, vocab, under_cond or eqn.primitive.name == "cond")
+                        sub, vocab,
+                        under_cond or eqn.primitive.name == "cond", rows)
     return found
 
 
@@ -723,7 +743,7 @@ def record_spans(patch) -> list:
 class TestEngineBatchesChunks:
     def test_two_prompts_together_as_each_alone(self, model):
         kind, cfg, params = model
-        # A dense model's chunk at the ridge: no program over rows is built.
+        # A dense model's chunk at the ridge: one chunk a program.
         chunk = 256 if kind == "dense" else CHUNK
         max_len = 1024 if kind == "dense" else 256
         n = 3 * chunk
@@ -742,7 +762,7 @@ class TestEngineBatchesChunks:
         assert c["prefill_tokens_dispatched"] == sum(map(len, prompts))
         assert c["prefill_chunks_dispatched"] == 3 + 2
         if kind == "dense":
-            assert eng._chunk_rows == 1 and not hasattr(eng, "_paged_chunks")
+            assert eng._chunk_rows == 1 and eng._lone_at_last
             assert _chunks_per_program(eng) == 1
         else:
             assert eng._chunk_rows == 2
@@ -779,8 +799,8 @@ class TestEngineBatchesChunks:
         """Prompts of three chunks and of two: two passes carry a chunk of
         each (the program over rows; b ends in the second), the third a's
         last chunk alone (the one-row program). A dense model at its ridge
-        builds no program over rows: five one-row programs, two with an
-        end."""
+        sends one chunk a program: five programs of one row (none of them a
+        program over SEVERAL rows), two with an end."""
         _, cfg, params = _model(kind)
         chunk = 256 if kind == "dense" else CHUNK
         eng = _engine(cfg, params, chunk=chunk,
@@ -794,6 +814,23 @@ class TestEngineBatchesChunks:
                 c["prefill_row_programs_dispatched"],
                 c["prefill_programs_with_end"]) == (
                     (5, 0, 2) if kind == "dense" else (3, 2, 2))
+
+    @pytest.mark.parametrize("kind", KINDS)
+    def test_the_positions_the_head_ran_at_are_counted(self, kind):
+        """The same two prompts. Where the engine sends two chunks a
+        program: none in the first pass's program (no row ends its prompt),
+        its two rows in the second's (b ends), and a's last chunk alone
+        takes the ``[C,V]`` program, the head at all ``C``. A dense model at
+        its ridge sends every chunk alone through the program over rows:
+        one position in each of the two programs that end a prompt."""
+        _, cfg, params = _model(kind)
+        chunk = 256 if kind == "dense" else CHUNK
+        eng = _engine(cfg, params, chunk=chunk,
+                      max_len=1024 if kind == "dense" else 256)
+        assert eng.counters()["prefill_head_positions"] == 0
+        _greedy(eng, [_tokens(4, 3 * chunk - 5), _tokens(5, 2 * chunk - 9)])
+        assert eng.counters()["prefill_head_positions"] == (
+            2 if kind == "dense" else 0 + 2 + chunk)
 
     def test_a_small_dense_chunk_batches_too(self):
         """The rule reads rows, not a model's kind: a dense model at 32
@@ -864,3 +901,182 @@ class TestEngineBatchesChunks:
         chunks = [attrs["chunks"] for name, attrs in seen
                   if name == "engine.prefill_dispatch"]
         assert chunks == [2, 2, 1]
+
+
+# -- an engine that sends one chunk a program: a lone chunk at one row -------------
+
+ADAPTER = "tenant-a"
+# name -> (this file's kind of model, what the engine is built with): engines
+# that send ONE chunk a program and carry no step in their chunk programs.
+ONE_CHUNK = {
+    "dense-over-the-ridge": ("dense", dict(chunk=256, max_len=1024)),
+    "parallel": ("parallel", dict(max_concurrent_prefills=1)),
+    "lora": ("dense", dict(
+        max_concurrent_prefills=1,
+        lora=LoRASpec(max_adapters=2, rank=4, targets=("wq", "wv")))),
+    "speculative": ("dense", dict(
+        max_concurrent_prefills=1,
+        speculative=SpeculativeSpec(mode="ngram", k=4))),
+    "int8-pool": ("dense", dict(max_concurrent_prefills=1,
+                                kv_cache_dtype="int8")),
+}
+
+
+def _one_chunk_engine(name: str, at_last: bool = True):
+    """The engine of ``ONE_CHUNK[name]`` and a count of the calls of its two
+    chunk programs; ``at_last`` False: as such an engine dispatched before
+    ISSUE 52, every lone chunk through the ``[C,V]`` program."""
+    kind, options = ONE_CHUNK[name]
+    _, cfg, params = _model(kind)
+    eng = _engine(cfg, params, **options)
+    assert eng._chunk_rows == 1 and not eng._mixed and eng._lone_at_last
+    if name == "lora":
+        eng._lora.register(AdapterSpec(
+            ADAPTER, rank=4, alpha=8.0, weights=init_adapter_weights(
+                jax.random.PRNGKey(11), cfg, 4, ("wq", "wv"))))
+    eng._lone_at_last = at_last
+    calls = {}
+    for attr in ("_paged_chunk", "_paged_chunks"):
+        def counted(*args, _program=getattr(eng, attr), _attr=attr):
+            calls[_attr] = calls.get(_attr, 0) + 1
+            return _program(*args)
+        setattr(eng, attr, counted)
+    return eng, calls
+
+
+def _stream(eng, prompt, n, **submit):
+    req = eng.submit(list(map(int, prompt)), SamplingParams(
+        max_new_tokens=n, temperature=0.0), **submit)
+    _run(eng, [req])
+    return list(req.output_tokens)
+
+
+def _by_hand(eng, prompt, n: int) -> list:
+    """Greedy tokens from the engine's ``[C,V]`` program ALONE, driven as a
+    caller outside the engine drives it: for every token the whole sequence
+    is prefilled again from position 0 into the pool's first pages and the
+    token read at ``logits[real - 1]`` of its last chunk."""
+    C = eng.chunk_size
+    row = jnp.arange(eng._mpp, dtype=jnp.int32)
+    toks, out = list(map(int, prompt)), []
+    for _ in range(n):
+        for pos in range(0, len(toks), C):
+            real = min(C, len(toks) - pos)
+            block = np.zeros((1, C), np.int32)
+            block[0, :real] = toks[pos:pos + real]
+            logits, eng.cache = eng._paged_chunk(
+                eng.params, eng.cache, jnp.asarray(block), row,
+                jnp.int32(pos), jnp.int32(real),
+                context_bucket(pos, C, eng.page_size, eng._mpp))
+        out.append(int(jnp.argmax(logits[real - 1])))
+        toks.append(out[-1])
+    return out
+
+
+class TestALoneChunkAtOneRow:
+    @pytest.mark.parametrize("name", sorted(ONE_CHUNK))
+    def test_traffic_never_takes_the_all_position_program(self, name):
+        """A prompt of two chunks (and, beside it, an adapter's): every
+        chunk goes through the program over rows, none through the
+        ``[C,V]`` program, and the tokens are those the same engine gave
+        when every chunk took the ``[C,V]`` program."""
+        eng, calls = _one_chunk_engine(name)
+        prompts = [(_tokens(4, 2 * eng.chunk_size - 7), {})]
+        if name == "lora":
+            prompts.append((_tokens(5, eng.chunk_size + 3),
+                            dict(adapter=ADAPTER)))
+        got = [_stream(eng, p, 6, **kw) for p, kw in prompts]
+        assert calls == {"_paged_chunks": 2 * len(prompts)}
+        c = eng.counters()
+        assert (c["prefill_programs_dispatched"],
+                c["prefill_row_programs_dispatched"],
+                c["prefill_programs_with_end"],
+                c["prefill_head_positions"]) == (
+                    2 * len(prompts), 0, len(prompts), len(prompts))
+        before, calls = _one_chunk_engine(name, at_last=False)
+        assert got == [_stream(before, p, 6, **kw) for p, kw in prompts]
+        assert calls == {"_paged_chunk": 2 * len(prompts)}
+        assert before.counters()["prefill_head_positions"] \
+            == 2 * len(prompts) * eng.chunk_size
+        if name == "lora":
+            assert got[1] != _stream(eng, prompts[1][0], 6)
+
+    @pytest.mark.parametrize("name", ["dense-over-the-ridge", "parallel"])
+    def test_streams_are_the_all_position_programs_driven_by_hand(self,
+                                                                  name):
+        eng, calls = _one_chunk_engine(name)
+        prompts = [_tokens(4, 2 * eng.chunk_size - 7),
+                   _tokens(5, eng.chunk_size // 2)]
+        got = [_stream(eng, p, 4) for p in prompts]
+        assert "_paged_chunk" not in calls
+        by_hand, _ = _one_chunk_engine(name)
+        assert got == [_by_hand(by_hand, p, 4) for p in prompts]
+
+    @pytest.mark.parametrize("kind", ["dense", "parallel"])
+    def test_the_two_programs_of_an_engine_agree(self, kind):
+        """The engine's own two programs, each on its own pool, over a
+        prompt of three chunks: 24 tokens, a FULL chunk that starts
+        MID-PAGE, a last one of 9. The program over rows at one row returns
+        zeros while the prompt goes on and, at its end, the ``[C,V]``
+        program's row ``real - 1``; every plane of the pool (K and V rows a
+        token; a parallel layer's SSD state and its convolution's rows a
+        sequence) is the same to the bit after every chunk."""
+        _, cfg, params = _model(kind)
+        tokens = _tokens(1, 24 + CHUNK + 9)
+        row = np.asarray([0, 1, 2, 3, 10, -1, -1, -1], np.int32)
+        engines = {form: _engine(cfg, params, max_concurrent_prefills=1,
+                                 max_len=MPP * PAGE)
+                   for form in ("all", "last")}
+        for start, real in ((0, 24), (24, CHUNK), (24 + CHUNK, 9)):
+            block = np.zeros((1, CHUNK), np.int32)
+            block[0, :real] = tokens[start:start + real]
+            bucket = context_bucket(start, CHUNK, PAGE, MPP)
+            ends = start + real == len(tokens)
+            eng = engines["all"]
+            every, eng.cache = eng._paged_chunk(
+                eng.params, eng.cache, jnp.asarray(block), jnp.asarray(row),
+                jnp.int32(start), jnp.int32(real), bucket)
+            assert every.shape == (CHUNK, cfg.vocab_size)
+            eng = engines["last"]
+            last, eng.cache = eng._paged_chunks(
+                eng.params, eng.cache, jnp.asarray(block),
+                jnp.asarray(row[None]), jnp.asarray([start], jnp.int32),
+                jnp.asarray([real], jnp.int32), jnp.asarray([ends]), bucket)
+            assert last.shape == (1, cfg.vocab_size)
+            if ends:
+                want = np.asarray(every[real - 1])
+                assert int(last[0].argmax()) == int(want.argmax())
+                assert float(np.abs(np.asarray(last[0]) - want).max()) \
+                    <= 2.0 ** -8 * float(np.abs(want).max())
+            else:
+                np.testing.assert_array_equal(last, 0.0)
+            pools = {form: e.cache for form, e in engines.items()}
+            assert set(pools["last"]) == set(pools["all"]) \
+                and (kind != "parallel" or {"k", "v", "ssd_state",
+                                            "ssd_conv"} <= set(pools["all"]))
+            for plane in pools["all"]:
+                np.testing.assert_array_equal(
+                    pools["last"][plane], pools["all"][plane],
+                    err_msg=f"{plane} after the chunk at {start}")
+
+    @pytest.mark.parametrize("kind", ["dense", "parallel"])
+    def test_the_head_of_the_traffic_program_lies_under_one_cond(self, kind):
+        """What traffic runs holds ONE matrix product of the head, ``[1, 1,
+        V]``, under a conditional, and no value of the ``[C,V]`` program's
+        result's shape anywhere in its lowered text (which that program's
+        own text holds)."""
+        _, cfg, params = _model(kind)
+        eng = _engine(cfg, params, max_concurrent_prefills=1)
+        args = (eng.params, eng.cache, jnp.zeros((1, CHUNK), jnp.int32),
+                jnp.zeros((1, eng._mpp), jnp.int32),
+                jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32))
+        traced = jax.make_jaxpr(
+            lambda *a: eng._paged_chunks(*a, eng._mpp))(
+                *args, jnp.zeros((1,), bool))
+        assert _head_products(traced.jaxpr, cfg.vocab_size, rows=1) == [True]
+        all_positions = f"{CHUNK}x{cfg.vocab_size}xf32"
+        assert all_positions not in eng._paged_chunks.lower(
+            *args, jnp.zeros((1,), bool), eng._mpp).as_text()
+        assert all_positions in eng._paged_chunk.lower(
+            *args[:3], jnp.zeros((eng._mpp,), jnp.int32), jnp.int32(0),
+            jnp.int32(0), eng._mpp).as_text()

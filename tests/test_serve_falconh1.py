@@ -6,7 +6,8 @@ the mixer whatever the split, the stack's tree and counts, the pool's planes,
 the programs (gathered, and in place at heads of 128) against the full
 forward and against the benchmark's plain reference in float32 and in
 bfloat16, the state an entry ends in, a comparison that sees each branch and
-the carried state, and through the engine: tokens against the full recompute,
+the carried state, and through the engine: tokens against the full recompute
+(two chunks a program, and one chunk a program at one row: ISSUE 52),
 preemption, the counters and the refused options by name."""
 
 import dataclasses
@@ -610,13 +611,21 @@ def _serve(engine, prompts, n):
     return reqs
 
 
-def test_engine_tokens_are_the_full_recomputes():
+@pytest.mark.parametrize("prefills", [2, 1])
+def test_engine_tokens_are_the_full_recomputes(prefills):
     """Four prompts on three slots: chunks interleaved with decode rounds, a
     slot and its entry handed to a second sequence; an iteration with a
     chunk and live slots is two programs (the chunk program carries no
-    step over a state a sequence)."""
-    engine = _engine()
+    step over a state a sequence). An engine of one prefill at a time (the
+    assistant cell's way: one chunk a program, no step carried) sends every
+    chunk through the program over rows at one row: the ``[C,V]`` program
+    is never called and the head runs at one position a prompt."""
+    engine = _engine(max_concurrent_prefills=prefills)
     assert not engine._mixed and engine._ring == 1
+    assert (engine._chunk_rows, engine._lone_at_last) == (
+        prefills, prefills == 1)
+    all_positions, program = [], engine._paged_chunk
+    engine._paged_chunk = lambda *a: all_positions.append(a) or program(*a)
     prompts = [_tokens(31, 75), _tokens(32, 5), _tokens(33, 50),
                _tokens(34, 21)]
     reqs = _serve(engine, prompts, 12)
@@ -628,6 +637,10 @@ def test_engine_tokens_are_the_full_recomputes():
     assert counters["mixed_programs_dispatched"] == 0
     assert counters["state_sequences_started"] == 4
     assert engine._allocator.available(ring=True) == SLOTS
+    if prefills == 1:
+        assert not all_positions
+        assert counters["prefill_head_positions"] == 4
+        assert counters["prefill_programs_with_end"] == 4
 
 
 def test_a_preempted_sequence_starts_its_state_again_from_zeros():
