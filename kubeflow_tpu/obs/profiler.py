@@ -23,6 +23,14 @@ Who pays what:
   microsecond a phase, no object allocated while no capture is active
   (PERF.md, Findings, PR 37).
 
+- a start (``ENGINE_START_PHASES`` / ``TRAIN_START_PHASES``): the engine's
+  constructor and the trainer's build, resume and first step go through a
+  ``PhaseClock`` of their own, so what a start cost is always-on sums
+  (``start_<phase>_sum_s`` of ``counters()``, constants once the start is
+  over) and, where a capture is active while an engine or a trainer is
+  built, ``engine.start.*`` / ``train.start.*`` spans: about ten boundaries
+  a process, none inside a loop.
+
 The spans of one thread nest, so the innermost span that covers an instant
 says what that thread was doing; keyword arguments become the event's
 stats and tie spans together (``round=`` on a decode dispatch and on the
@@ -74,12 +82,29 @@ ENGINE_PHASES = (
     ENGINE_REAP, ENGINE_ADMIT, ENGINE_PREFILL_DISPATCH, ENGINE_SAMPLE_FIRST,
     ENGINE_KVTIER_TICK, ENGINE_ENSURE_PAGES, ENGINE_SYNC_STATE,
     ENGINE_DECODE_DISPATCH, ENGINE_FETCH, ENGINE_EMIT, ENGINE_IDLE)
+# The constructor's phases, on a PhaseClock of their own (``begin`` at its
+# first line, ``end`` at its last): the load path, the pool and the decode
+# state, the relaid weights, every program the constructor runs once.
+ENGINE_START_PLACE = "engine.start.place"
+ENGINE_START_POOL = "engine.start.pool"
+ENGINE_START_RELAY = "engine.start.relay"
+ENGINE_START_WARM = "engine.start.warm"    # one span a program: ``program=``
+ENGINE_START_PHASES = (ENGINE_START_PLACE, ENGINE_START_POOL,
+                       ENGINE_START_RELAY, ENGINE_START_WARM)
 TRAIN_STEP = "train"
 TRAIN_STAGE_WAIT = "train.stage_wait"
 TRAIN_DISPATCH = "train.dispatch"
 TRAIN_SYNC = "train.sync"
 TRAIN_LOG = "train.log"
 TRAIN_CHECKPOINT = "train.checkpoint"
+# A trainer's start: ``Trainer.__init__``, ``try_resume``, and the run's
+# first step alone, from its lowering to its outputs ready (compile or
+# load, first execution; a one-off ``train.sync`` whatever ``log_every``).
+TRAIN_START_BUILD = "train.start.build"
+TRAIN_START_RESUME = "train.start.resume"
+TRAIN_START_FIRST_STEP = "train.start.first_step"
+TRAIN_START_PHASES = (TRAIN_START_BUILD, TRAIN_START_RESUME,
+                      TRAIN_START_FIRST_STEP)
 
 
 class _NoSpan:

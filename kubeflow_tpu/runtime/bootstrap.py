@@ -192,10 +192,77 @@ def bootstrap_worker(wenv: Optional[WorkerEnv] = None):
 DEFAULT_COMPILE_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), ".jax_cache")
-_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hits",
-                 "/jax/compilation_cache/cache_misses": "misses"}
-# Process-wide like the JAX cache it counts: hits/misses of THIS process.
-_cache_counts: Optional[dict[str, int]] = None
+# What JAX says of its compiles (``jax.monitoring``; the names of JAX 0.9):
+# two counts and four durations, folded into six running totals. On a hit
+# of the persistent cache the backend-compile event fires all the same, with
+# the retrieval inside it: ``backend_compile_s`` holds ``retrieval_s``, and
+# ``backend_compiles`` counts hits, misses and the compiles no cache was
+# asked for alike (each is a program this process had not loaded before).
+_COMPILE_COUNTS = {"/jax/compilation_cache/cache_hits": "hits",
+                   "/jax/compilation_cache/cache_misses": "misses"}
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_COMPILE_DURATIONS = {
+    _BACKEND_COMPILE_EVENT: "backend_compile_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval_s",
+    _TRACE_EVENT: "trace_lower_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "trace_lower_s"}
+# Process-wide like the JAX caches they count: THIS process's compiles.
+_compile_totals: Optional[dict[str, float]] = None
+_compile_lock = threading.Lock()
+_cache_enabled = False
+
+
+def watch_compiles() -> dict[str, float]:
+    """Register, once a process however often it is called, the ONE set of
+    listeners that folds JAX's compile events into running totals, and
+    return the live totals: ``hits`` / ``misses`` of the persistent cache,
+    ``backend_compiles`` and ``backend_compile_s`` (XLA's compile or the
+    cache's retrieval, which is also ``retrieval_s`` by itself), and
+    ``trace_lower_s`` (tracing to a jaxpr and lowering it to a module; a
+    jitted function traced inside another's trace is inside that one's
+    seconds and is counted there alone). ``enable_compilation_cache`` and
+    the constructors of ``LLMEngine`` and ``Trainer`` call it, so the
+    totals count on the CPU and with the cache off too."""
+    global _compile_totals
+    with _compile_lock:
+        if _compile_totals is not None:
+            return _compile_totals
+        totals = _compile_totals = {
+            "hits": 0, "misses": 0, "backend_compiles": 0,
+            "backend_compile_s": 0.0, "retrieval_s": 0.0,
+            "trace_lower_s": 0.0}
+    import jax
+
+    tracing = threading.local()     # .depth: traces open on this thread
+
+    def _count(event: str, **_kw) -> None:
+        name = _COMPILE_COUNTS.get(event)
+        if name is not None:
+            with _compile_lock:
+                totals[name] += 1
+
+    def _begin(event: str, _value, **_kw) -> None:
+        if event == _TRACE_EVENT:       # JAX writes a scalar as it begins
+            tracing.depth = getattr(tracing, "depth", 0) + 1
+
+    def _duration(event: str, seconds: float, **_kw) -> None:
+        name = _COMPILE_DURATIONS.get(event)
+        if name is None:
+            return
+        if event == _TRACE_EVENT:
+            tracing.depth = max(getattr(tracing, "depth", 1) - 1, 0)
+            if tracing.depth:           # inside another trace's seconds
+                return
+        with _compile_lock:
+            totals[name] += seconds
+            if event == _BACKEND_COMPILE_EVENT:
+                totals["backend_compiles"] += 1
+
+    jax.monitoring.register_event_listener(_count)
+    jax.monitoring.register_scalar_listener(_begin)
+    jax.monitoring.register_event_duration_secs_listener(_duration)
+    return totals
 
 
 def enable_compilation_cache() -> str:
@@ -207,39 +274,45 @@ def enable_compilation_cache() -> str:
     directory is set in code; otherwise the cache goes to
     ``DEFAULT_COMPILE_CACHE_DIR``. Errors propagate: a cache that cannot
     be placed is a mis-set path, not something to run without."""
-    global _cache_counts
+    global _cache_enabled
     import jax
 
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         os.makedirs(DEFAULT_COMPILE_CACHE_DIR, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir",
                           DEFAULT_COMPILE_CACHE_DIR)
-    if _cache_counts is None:
-        counts = _cache_counts = {"hits": 0, "misses": 0}
-
-        def _count(event: str, **_kw) -> None:
-            name = _CACHE_EVENTS.get(event)
-            if name is not None:
-                counts[name] += 1
-
-        jax.monitoring.register_event_listener(_count)
+    watch_compiles()
+    _cache_enabled = True
     return jax.config.jax_compilation_cache_dir
 
 
 def compile_cache_stats() -> Optional[dict]:
-    """``{"dir", "entries", "hits", "misses"}`` of this process's
-    persistent compile cache; None when ``enable_compilation_cache`` never
-    ran here. ``entries`` counts the executables now in the directory;
-    hits/misses count this process's cacheable compiles (a miss is a
-    compile that was written)."""
-    if _cache_counts is None:
+    """``{"dir", "entries"}`` of this process's persistent compile cache
+    beside ``watch_compiles``' totals; None when
+    ``enable_compilation_cache`` never ran here. ``entries`` counts the
+    executables now in the directory; hits/misses count this process's
+    cacheable compiles (a miss is a compile that was written)."""
+    if not _cache_enabled:
         return None
     import jax
 
     path = jax.config.jax_compilation_cache_dir
     entries = (sum(1 for n in os.listdir(path) if not n.endswith("-atime"))
                if os.path.isdir(path) else 0)
-    return {"dir": path, "entries": entries, **_cache_counts}
+    return {"dir": path, "entries": entries, **watch_compiles()}
+
+
+def compile_counters() -> dict[str, float]:
+    """``watch_compiles``' totals under the names ``counters()`` of the
+    engine and the trainer carry them by: process-wide, so two engines of
+    one process read the same numbers."""
+    t = watch_compiles()
+    return {"compile_backend_sum_s": t["backend_compile_s"],
+            "compile_backend_n": t["backend_compiles"],
+            "compile_retrieval_sum_s": t["retrieval_s"],
+            "compile_trace_lower_sum_s": t["trace_lower_s"],
+            "compile_cache_hits": t["hits"],
+            "compile_cache_misses": t["misses"]}
 
 
 def _require_platform(platform: str) -> None:

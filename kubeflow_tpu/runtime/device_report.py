@@ -41,8 +41,8 @@ def lowered_kernel_calls(jitted, *args) -> dict[str, int]:
 def device_report() -> dict[str, Any]:
     """Device identity as JAX reports it in THIS process, per-device
     memory counters where the backend keeps them, the persistent compile
-    cache's directory, entry count and this process's hits, and the flag
-    variables the backend started with."""
+    cache's directory, entry count and this process's hits, misses and
+    compile seconds, and the flag variables the backend started with."""
     import jax
 
     from kubeflow_tpu.runtime.bootstrap import compile_cache_stats

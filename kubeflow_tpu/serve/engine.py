@@ -92,6 +92,7 @@ from kubeflow_tpu.models.decoder import (
 from kubeflow_tpu.obs import profiler as prof
 from kubeflow_tpu.obs.stats import quantile as _quantile
 from kubeflow_tpu.obs.trace import get_tracer
+from kubeflow_tpu.runtime.bootstrap import compile_counters, watch_compiles
 
 logger = logging.getLogger("kubeflow_tpu.serve.engine")
 
@@ -332,6 +333,13 @@ def _committed(tree):
     """``tree`` with every array committed where it lies (no copy, no
     program)."""
     return jax.device_put(tree, jax.tree.map(lambda x: x.sharding, tree))
+
+
+def _program_key(name: str, *variant) -> str:
+    """A program variant's name in ``program_kernels`` and in
+    ``start_programs()``: the program and what tells its variants apart
+    (the token block's shape, the static arguments)."""
+    return f"{name}[{','.join(map(str, variant))}]"
 
 
 def _row0(out):
@@ -731,6 +739,17 @@ class LLMEngine:
                  *, params: Optional[Params] = None, seed: int = 0,
                  mesh: Optional[Mesh] = None,
                  draft_params: Optional[Params] = None):
+        # The start-up clock (obs/profiler.py): from here to the
+        # constructor's last line every second lands in one of the four
+        # start phases or in ``other``, always on; under a capture that is
+        # active now each phase is an ``engine.start.*`` span as well.
+        # ``counters()`` carries the sums, constants once this returns.
+        watch_compiles()
+        self._start = prof.PhaseClock(prof.ENGINE_START_PHASES)
+        self._start.begin()
+        start = self._start.phase
+        # {program: seconds} of the programs run once below, in order.
+        self._start_programs: dict[str, float] = {}
         if mesh is not None and mesh.size > 1:
             # The engine's blocks call the norm/GLU layers with no mesh, so
             # layers.fused_kernels_on would resolve "auto" from the backend
@@ -751,55 +770,56 @@ class LLMEngine:
         self.num_slots = b.max_batch_size
         self.max_len = b.max_seq_len
 
-        key = jax.random.PRNGKey(seed)
-        self.params = params if params is not None else init_decoder_params(key, cfg)
-        if b.weights_dtype is not None:
-            # Inference-only weights: cast once at load instead of per-use.
-            # Decode is HBM-bound on the param read, so fp32 checkpoints
-            # served as bf16 halve the per-step floor.
-            wdt = jnp.dtype(b.weights_dtype)
-            self.params = jax.tree.map(
-                lambda x: x.astype(wdt) if jnp.issubdtype(x.dtype, jnp.floating)
-                else x, self.params)
-        if b.quantize is not None:
-            # Weight-only int8 ((U) vLLM quantization; VERDICT r4 #3): the
-            # big matmuls store int8 + per-channel scales and dequantize in
-            # the operand read — halves the decode HBM param read vs bf16
-            # and halves param residency. Applied after the dtype cast so
-            # scales quantize the served (not checkpoint) values.
-            if b.quantize != "int8":
+        with start(prof.ENGINE_START_PLACE):
+            key = jax.random.PRNGKey(seed)
+            self.params = params if params is not None else init_decoder_params(key, cfg)
+            if b.weights_dtype is not None:
+                # Inference-only weights: cast once at load instead of per-use.
+                # Decode is HBM-bound on the param read, so fp32 checkpoints
+                # served as bf16 halve the per-step floor.
+                wdt = jnp.dtype(b.weights_dtype)
+                self.params = jax.tree.map(
+                    lambda x: x.astype(wdt) if jnp.issubdtype(x.dtype, jnp.floating)
+                    else x, self.params)
+            if b.quantize is not None:
+                # Weight-only int8 ((U) vLLM quantization; VERDICT r4 #3): the
+                # big matmuls store int8 + per-channel scales and dequantize in
+                # the operand read — halves the decode HBM param read vs bf16
+                # and halves param residency. Applied after the dtype cast so
+                # scales quantize the served (not checkpoint) values.
+                if b.quantize != "int8":
+                    raise ValueError(
+                        f"unknown quantize {b.quantize!r}; supported: int8")
+                from kubeflow_tpu.ops.quantization import quantize_params_int8
+
+                self.params = quantize_params_int8(self.params, cfg)
+            if b.kv_cache_dtype not in (None, "int8"):
                 raise ValueError(
-                    f"unknown quantize {b.quantize!r}; supported: int8")
-            from kubeflow_tpu.ops.quantization import quantize_params_int8
+                    f"unknown kv_cache_dtype {b.kv_cache_dtype!r}; "
+                    "supported: int8")
+            self.kv_quant = b.kv_cache_dtype == "int8"
+            self._cache_sh: Optional[NamedSharding] = None
+            self._cache_scale_sh: Optional[NamedSharding] = None
+            if self.mesh is not None:
+                from kubeflow_tpu.models.decoder import decoder_param_specs
+                from kubeflow_tpu.parallel.sharding import shard_params
 
-            self.params = quantize_params_int8(self.params, cfg)
-        if b.kv_cache_dtype not in (None, "int8"):
-            raise ValueError(
-                f"unknown kv_cache_dtype {b.kv_cache_dtype!r}; "
-                "supported: int8")
-        self.kv_quant = b.kv_cache_dtype == "int8"
-        self._cache_sh: Optional[NamedSharding] = None
-        self._cache_scale_sh: Optional[NamedSharding] = None
-        if self.mesh is not None:
-            from kubeflow_tpu.models.decoder import decoder_param_specs
-            from kubeflow_tpu.parallel.sharding import shard_params
-
-            # Weights: the exact logical rules training uses (heads/mlp/kv/
-            # vocab → `model`); non-divisible dims auto-replicate. KV cache:
-            # sharded on the kv-head dim — the same split wk/wv produce, so
-            # cache writes and decode attention are collective-free; only
-            # wo's output psum and the vocab-parallel logits ride ICI.
-            self.params = jax.device_put(
-                self.params,
-                shard_params(self.params, decoder_param_specs(cfg),
-                             self.mesh))
-            kv_ps = PartitionSpec(None, None, None, "model", None)
-            scale_ps = PartitionSpec(None, None, None, "model")
-            if cfg.n_kv_heads % self.mesh.shape.get("model", 1):
-                kv_ps = PartitionSpec()      # GQA heads don't divide: replicate
-                scale_ps = PartitionSpec()
-            self._cache_sh = NamedSharding(self.mesh, kv_ps)
-            self._cache_scale_sh = NamedSharding(self.mesh, scale_ps)
+                # Weights: the exact logical rules training uses (heads/mlp/kv/
+                # vocab → `model`); non-divisible dims auto-replicate. KV cache:
+                # sharded on the kv-head dim — the same split wk/wv produce, so
+                # cache writes and decode attention are collective-free; only
+                # wo's output psum and the vocab-parallel logits ride ICI.
+                self.params = jax.device_put(
+                    self.params,
+                    shard_params(self.params, decoder_param_specs(cfg),
+                                 self.mesh))
+                kv_ps = PartitionSpec(None, None, None, "model", None)
+                scale_ps = PartitionSpec(None, None, None, "model")
+                if cfg.n_kv_heads % self.mesh.shape.get("model", 1):
+                    kv_ps = PartitionSpec()      # GQA heads don't divide: replicate
+                    scale_ps = PartitionSpec()
+                self._cache_sh = NamedSharding(self.mesh, kv_ps)
+                self._cache_scale_sh = NamedSharding(self.mesh, scale_ps)
         self._rng = jax.random.PRNGKey(seed + 1)  # lockfree: scheduler-confined
 
         self.page_size = pg = int(b.page_size)
@@ -847,11 +867,12 @@ class LLMEngine:
         # over the layers of its kind; the conv layers of a patterned
         # stack hold their state a page, beside the attention layers'
         # rows a token.
-        self.cache = {  # lockfree: scheduler-confined (donated KV)
-            name: self._zeros(shape, dt, scale=name in ("ks", "vs"))
-            for name, (shape, dt) in engine_pool_shapes(
-                cfg_decode, self.num_slots, self._num_pages, pg,
-                self.kv_quant).items() if name != MOE_ROWS}
+        with start(prof.ENGINE_START_POOL):
+            self.cache = {  # lockfree: scheduler-confined (donated KV)
+                name: self._zeros(shape, dt, scale=name in ("ks", "vs"))
+                for name, (shape, dt) in engine_pool_shapes(
+                    cfg_decode, self.num_slots, self._num_pages, pg,
+                    self.kv_quant).items() if name != MOE_ROWS}
 
         self._kv_bytes_per_token = pool_bytes_per_token(cfg, self.kv_quant)
         self._kv_pool_bytes = int(sum(v.nbytes for v in self.cache.values()))
@@ -909,12 +930,13 @@ class LLMEngine:
         # would drop the layout): the per-head projections lie as both
         # serving programs read them, so neither copies a weight again
         # (serve/weight_layout.py). Logical shapes stay.
-        formats = weight_formats(
-            self.params, cfg,
-            one_chip_pallas=(on_tpu and self.mesh is None
-                             and pattn == "pallas"))
-        self.params = relay(self.params, formats)
-        self._weights_relaid_bytes = relaid_bytes(self.params, formats)
+        with start(prof.ENGINE_START_RELAY):
+            formats = weight_formats(
+                self.params, cfg,
+                one_chip_pallas=(on_tpu and self.mesh is None
+                                 and pattn == "pallas"))
+            self.params = relay(self.params, formats)
+            self._weights_relaid_bytes = relaid_bytes(self.params, formats)
         if self._weights_relaid_bytes:
             # A relaid leaf is a COMMITTED array (JAX reads a layout off a
             # committed argument only), and what a program returns is
@@ -926,7 +948,8 @@ class LLMEngine:
             # measured window). So what the engine allocates starts
             # committed too, here and at the decode state, and the programs
             # warmed before traffic are the ones traffic reaches.
-            self.cache = _committed(self.cache)
+            with start(prof.ENGINE_START_POOL):
+                self.cache = _committed(self.cache)
 
         def _chunk_rows_fn(p, c, t, tr, st, vl, ncp, lr, ai,
                            logits_at="all", wanted=None):
@@ -1151,7 +1174,11 @@ class LLMEngine:
             # as a steady-state recompile (the F6xx fixed-trace
             # contract the recompile sanitizer audits). The OOB dst
             # drops the write — a no-op dispatch.
-            self._kv_copy_pages([0], [-1])
+            def cow_copy():
+                self._kv_copy_pages([0], [-1])
+                return self.cache
+
+            self._warm(_program_key("kv_copy_pages", 1), cow_copy)
         self._sampler = jax.jit(_sample_batch, static_argnums=(5,))
         # Steps a decode dispatch: sampling happens on-device, and the
         # while_loop exits early when every slot finishes. The two options
@@ -1231,14 +1258,15 @@ class LLMEngine:
             # draft is small, so slots x max_len of its few kv-heads is
             # cheap, and it runs the pool's own decode step and chunk
             # prefill.
-            self._draft_cache = {  # lockfree: scheduler-confined
-                name: jnp.zeros(shape, dt)
-                for name, (shape, dt) in pool_shapes(
-                    dcfg, self.num_slots * self._mpp,
-                    self.page_size).items()}
-            self._draft_cache["table"] = jnp.arange(
-                self.num_slots * self._mpp, dtype=jnp.int32).reshape(
-                    self.num_slots, self._mpp)
+            with start(prof.ENGINE_START_POOL):
+                self._draft_cache = {  # lockfree: scheduler-confined
+                    name: jnp.zeros(shape, dt)
+                    for name, (shape, dt) in pool_shapes(
+                        dcfg, self.num_slots * self._mpp,
+                        self.page_size).items()}
+                self._draft_cache["table"] = jnp.arange(
+                    self.num_slots * self._mpp, dtype=jnp.int32).reshape(
+                        self.num_slots, self._mpp)
             # consumed-context pointer per slot: positions [0, pos) of the
             # TRUE sequence have valid draft KV; reset at (re-)admission
             self._draft_pos = [0] * self.num_slots  # lockfree: scheduler-confined
@@ -1281,10 +1309,11 @@ class LLMEngine:
         # device for the engine's lifetime; host scheduler events sync as
         # per-slot donated scatters, so steady-state rounds upload nothing
         # (the stats counters prove it).
-        self._dstate = DecodeState(self.num_slots, mpp=self._mpp)
-        if self._weights_relaid_bytes:      # as the pool: see the load path
-            self._dstate.arrays, self._dstate.table = _committed(
-                (self._dstate.arrays, self._dstate.table))
+        with start(prof.ENGINE_START_POOL):
+            self._dstate = DecodeState(self.num_slots, mpp=self._mpp)
+            if self._weights_relaid_bytes:  # as the pool: see the load path
+                self._dstate.arrays, self._dstate.table = _committed(
+                    (self._dstate.arrays, self._dstate.table))
         # Pipelined dispatch (double buffering): dispatch round N+1 before
         # consuming round N, keeping at most ONE unconsumed round in flight
         # while the host detokenizes/streams/reaps/admits. Staleness is one
@@ -1378,6 +1407,19 @@ class LLMEngine:
         self._warm_decode_ladder()
         if self._weights_relaid_bytes:
             self._warm_first_tokens()
+        self._start.end()
+
+    def _warm(self, program: str, run) -> None:
+        """Compile or load, and run once, now, one program of the engine's
+        own set: ``run`` dispatches it and hands back what to wait for.
+        One ``engine.start.warm`` phase a program (under a capture a span
+        with ``program=``, the program's key in ``program_kernels`` where it
+        has one), its seconds kept in ``start_programs()``."""
+        clock, warm = self._start, prof.ENGINE_START_WARM
+        before = clock.total(warm)
+        with clock.phase(warm, prof.active() and {"program": program}):
+            jax.block_until_ready(run())
+        self._start_programs[program] = clock.total(warm) - before
 
     def _warm_decode_ladder(self) -> None:
         """Compile and run once, now, the greedy decode program at every
@@ -1389,8 +1431,8 @@ class LLMEngine:
         their first use. The key is not drawn from: a sampled stream is
         what it was."""
         for k in self._pacer.ladder:
-            jax.block_until_ready(
-                self._dispatch_decode(k, "greedy", self._rng))
+            self._warm(_program_key("paged_decode", k, "greedy"),
+                       lambda: self._dispatch_decode(k, "greedy", self._rng))
 
     def _warm_first_tokens(self) -> None:
         """Compile and run once, now, the greedy first-token sampler at
@@ -1404,8 +1446,9 @@ class LLMEngine:
         greedy = SamplingParams(temperature=0.0)
         width = 1
         while width <= self.num_slots:
-            jax.block_until_ready(self._sample_first(
-                [row] * width, [greedy] * width, self._rng))
+            self._warm(_program_key("sample_first", width, "greedy"),
+                       lambda: self._sample_first(
+                           [row] * width, [greedy] * width, self._rng))
             width *= 2
 
     def _warm_rows_program(self) -> None:
@@ -1425,14 +1468,21 @@ class LLMEngine:
                 jnp.full((rows, self._mpp), -1, jnp.int32),
                 jnp.zeros((rows,), jnp.int32), jnp.zeros((rows,), jnp.int32),
                 jnp.zeros((rows,), jnp.bool_))
-        if self._mixed:
-            logits, _ = self._send_mixed(*dead)
-        else:
-            lora = () if self._lora is None else (
-                self._lora.buffers, jnp.full((rows,), -1, jnp.int32))
-            logits, self.cache = self._paged_chunks(
-                self.params, self.cache, *dead, self._mpp, *lora)
-        jax.block_until_ready([logits[r] for r in range(rows)])
+
+        def run():
+            if self._mixed:
+                logits, _ = self._send_mixed(*dead)
+            else:
+                lora = () if self._lora is None else (
+                    self._lora.buffers, jnp.full((rows,), -1, jnp.int32))
+                logits, self.cache = self._paged_chunks(
+                    self.params, self.cache, *dead, self._mpp, *lora)
+            return [logits[r] for r in range(rows)]
+
+        self._warm(_program_key("paged_mixed", f"{rows}x{C}", "greedy")
+                   if self._mixed else
+                   _program_key("paged_chunk_prefill", f"{rows}x{C}",
+                                self._mpp), run)
 
     def _send_mixed(self, chunk, table, start, valid, ends,
                     mode: Optional[str] = None):
@@ -1687,7 +1737,30 @@ class LLMEngine:
             # than the default, laid out once at load as the programs read
             # them (serve/weight_layout.py)
             "weights_relaid_bytes": self._weights_relaid_bytes,
+            # the constructor's seconds by start phase (``engine.start.*``:
+            # ``start_place_sum_s``, ... ``start_other_sum_s``; together
+            # its wall time): constants once the engine is built
+            **{f"start_{phase}_sum_s": seconds
+               for phase, seconds in self.start_phase_seconds().items()},
+            # what JAX compiled, loaded from its cache, traced and lowered
+            # in this PROCESS so far (runtime/bootstrap.py::watch_compiles):
+            # in front of a window the start-up's; still over a window that
+            # compiles nothing
+            **compile_counters(),
         }
+
+    def start_phase_seconds(self) -> dict[str, float]:
+        """The constructor's seconds by start phase, exclusive, keyed by
+        the phase's short name (``engine.start.place`` is ``place``), and
+        ``other``: the constructor's time under none. Together its wall
+        time."""
+        return {name.rpartition(".")[2]: seconds
+                for name, seconds in self._start.snapshot().items()}
+
+    def start_programs(self) -> dict[str, float]:
+        """``{program: seconds}`` of the programs the constructor compiled
+        or loaded and ran once, in the order it ran them."""
+        return dict(self._start_programs)
 
     def queue_depth(self) -> int:
         """Requests waiting for a slot (admission queue + scheduler-side
@@ -1928,8 +2001,8 @@ class LLMEngine:
             # args[2]: the token block of a prefill, the state of a decode.
             variant = (["x".join(map(str, args[2].shape))]
                        if isinstance(args[2], jax.Array) else [])
-            variant += [str(a) for a in args if isinstance(a, (int, str))]
-            key = f"{name}[{','.join(variant)}]"
+            variant += [a for a in args if isinstance(a, (int, str))]
+            key = _program_key(name, *variant)
             if key not in self.program_kernels:
                 self.program_kernels[key] = lowered_kernel_calls(
                     jitted, *args)

@@ -57,6 +57,7 @@ from kubeflow_tpu.obs.fleet import spans_export_payload
 from kubeflow_tpu.obs.registry import MetricsRegistry, contract_note_header
 from kubeflow_tpu.obs.trace import debug_traces_payload, get_tracer
 from kubeflow_tpu.core.serving import QOS_DEFAULT
+from kubeflow_tpu.runtime.bootstrap import compile_counters
 from kubeflow_tpu.serve.engine import (
     EngineOverloaded, HOST_GAP_BUCKETS, LLMEngine, QUEUE_DELAY_BUCKETS,
     Request, SamplingParams,
@@ -693,13 +694,18 @@ class ModelServer:
     def device_payload(self) -> dict:
         """``GET /debug/device``: what this replica runs on, for a parent
         that must not touch the chip itself — device, memory and compile
-        cache (runtime/device_report.py) plus, per model, the Pallas
-        kernels of each program the engine has dispatched."""
+        cache (runtime/device_report.py) plus, per model, what the
+        engine's start cost (its constructor by phase, the programs it ran
+        once) and the Pallas kernels of each program it has dispatched."""
         from kubeflow_tpu.runtime.device_report import device_report
 
+        engines = self._live_engines()
         return {**device_report(),
+                "start": {name: {"phases": eng.start_phase_seconds(),
+                                 "programs": eng.start_programs()}
+                          for name, eng in engines},
                 "programs": {name: dict(eng.program_kernels)
-                             for name, eng in self._live_engines()}}
+                             for name, eng in engines}}
 
     def metrics_text(self) -> str:
         return self.metrics_registry().render()
@@ -746,6 +752,27 @@ def serving_metrics_registry(engines: list, *,
     # difference over a window, for the operator's ``rate()``. ``fetch``
     # and ``idle`` are waits; the rest is the host's own.
     sched_phase = reg.counter("kftpu_engine_sched_phase_seconds_total")
+    # What a replica's start cost: the engine's constructor by start phase
+    # (``start_phase_seconds``: place, pool, relay, warm, other; constants
+    # once it is built, so a gauge), and the PROCESS's compiles
+    # (runtime/bootstrap.py::watch_compiles, no ``model``): seconds in
+    # XLA's compile or the cache's retrieval (``backend``, which holds
+    # ``retrieval``) and tracing and lowering, the persistent cache's
+    # hits and misses, and the programs compiled or loaded
+    # (``kftpu_compiles_total``: hits, misses and the compiles no cache was
+    # asked for alike). A replica whose compiles grow under traffic is
+    # compiling in front of its clients: the alert is on this count, which
+    # moves by one a program however short the compile.
+    start_phase = reg.gauge("kftpu_engine_start_seconds")
+    compile_s = reg.counter("kftpu_compile_seconds_total")
+    compiled = reg.counter("kftpu_compiles_total")
+    cache_requests = reg.counter("kftpu_compile_cache_requests_total")
+    compiles = compile_counters()
+    for kind in ("backend", "retrieval", "trace_lower"):
+        compile_s.inc(compiles[f"compile_{kind}_sum_s"], kind=kind)
+    compiled.inc(compiles["compile_backend_n"])
+    cache_requests.inc(compiles["compile_cache_hits"], result="hit")
+    cache_requests.inc(compiles["compile_cache_misses"], result="miss")
     # Disaggregated serving: the token-aware router's placement signals
     # (pending prefill tokens → prefill pool, resident KV pages → decode
     # pool) plus the handoff lifecycle counters.
@@ -843,6 +870,8 @@ def serving_metrics_registry(engines: list, *,
         depth.set(snap.get("dispatch_depth", 0), model=name)
         for phase, seconds in engine.sched_phase_seconds().items():
             sched_phase.inc(seconds, model=name, phase=phase)
+        for phase, seconds in engine.start_phase_seconds().items():
+            start_phase.set(seconds, model=name, phase=phase)
         pending_prefill.set(engine.pending_prefill_tokens(), model=name)
         pages_resident.set(engine.kv_pages_in_use(), model=name)
         pages_cached.set(engine.kv_pages_cached(), model=name)

@@ -24,7 +24,9 @@ from kubeflow_tpu.models.config import DecoderConfig, preset
 from kubeflow_tpu.obs import profiler
 from kubeflow_tpu.obs.profiler import hot_span
 from kubeflow_tpu.obs.trace import get_tracer
-from kubeflow_tpu.runtime.bootstrap import EXIT_PREEMPTED
+from kubeflow_tpu.runtime.bootstrap import (
+    EXIT_PREEMPTED, compile_counters, watch_compiles,
+)
 from kubeflow_tpu.runtime.device_report import (
     lowered_kernel_calls, write_device_report,
 )
@@ -98,6 +100,20 @@ class Trainer:
                  process_id: int = 0, num_processes: int = 1,
                  metrics_path: Optional[str] = None,
                  workdir: Optional[str] = None):
+        # The start-up clock (obs/profiler.py): this constructor,
+        # ``try_resume`` and the first step are its three phases, always-on
+        # sums in ``counters()`` and ``train.start.*`` spans under a
+        # capture. It brackets nothing: what lies between the phases (a
+        # caller's work between the constructor and ``run``) is not its.
+        watch_compiles()
+        self._start = profiler.PhaseClock(profiler.TRAIN_START_PHASES)
+        with self._start.phase(profiler.TRAIN_START_BUILD):
+            self._build(cfg, mesh, process_id, num_processes, metrics_path,
+                        workdir)
+
+    def _build(self, cfg: TrainerConfig, mesh, process_id: int,
+               num_processes: int, metrics_path: Optional[str],
+               workdir: Optional[str]) -> None:
         self.cfg = cfg
         self.mesh = mesh
         self.process_id = process_id
@@ -214,25 +230,26 @@ class Trainer:
         checkpoint can never crash the resume or silently poison the
         numerics, and every skip is surfaced as a ``restore_fallbacks``
         metric."""
-        if self.ckpt is None:
-            return 0
-        tiers: list = []
-        if self.ckpt_emergency is not None:
-            tiers.append(("emergency", self.ckpt_emergency))
-        tiers.append(("interval", self.ckpt))
-        resumed = resume_from_tiers(
-            tiers, self._abstract_state(),
-            quarantine=(self.process_id == 0))
-        if resumed is None:
-            return 0
-        state, _, tier, fallbacks = resumed
-        self.task.state = state
-        step = int(jax.device_get(state["step"]))
-        if fallbacks and self.ledger is not None:
-            self.ledger.record_fallback(fallbacks)
-        logger.info("resumed from checkpoint at step %d (tier=%s, "
-                    "fallbacks=%d)", step, tier, fallbacks)
-        return step
+        with self._start.phase(profiler.TRAIN_START_RESUME):
+            if self.ckpt is None:
+                return 0
+            tiers: list = []
+            if self.ckpt_emergency is not None:
+                tiers.append(("emergency", self.ckpt_emergency))
+            tiers.append(("interval", self.ckpt))
+            resumed = resume_from_tiers(
+                tiers, self._abstract_state(),
+                quarantine=(self.process_id == 0))
+            if resumed is None:
+                return 0
+            state, _, tier, fallbacks = resumed
+            self.task.state = state
+            step = int(jax.device_get(state["step"]))
+            if fallbacks and self.ledger is not None:
+                self.ledger.record_fallback(fallbacks)
+            logger.info("resumed from checkpoint at step %d (tier=%s, "
+                        "fallbacks=%d)", step, tier, fallbacks)
+            return step
 
     def _abstract_state(self):
         from kubeflow_tpu.train.step import make_state_init
@@ -276,8 +293,44 @@ class Trainer:
     def counters(self) -> dict[str, float]:
         """One total snapshot of the loop's running sums: every key exists
         from construction on and only ever grows. ``stage_wait_sum_s`` is
-        the seconds the loop waited for its next batch."""
-        return {"stage_wait_sum_s": self._stage_wait_sum_s}
+        the seconds the loop waited for its next batch;
+        ``start_<phase>_sum_s`` the seconds of the three start phases
+        (``train.start.*``: constants once the first step has synced);
+        ``compile_*`` what JAX compiled, loaded from its cache, traced and
+        lowered in this PROCESS so far
+        (runtime/bootstrap.py::watch_compiles)."""
+        return {"stage_wait_sum_s": self._stage_wait_sum_s,
+                **{f"start_{phase}_sum_s": seconds
+                   for phase, seconds in self.start_phase_seconds().items()},
+                **compile_counters()}
+
+    def start_phase_seconds(self) -> dict[str, float]:
+        """The seconds of ``build``, ``resume`` and ``first_step`` (the
+        run's first step alone, from its lowering to its outputs ready:
+        compile or load, and first execution; ``_first_step``)."""
+        return {name.rpartition(".")[2]: self._start.total(name)
+                for name in profiler.TRAIN_START_PHASES}
+
+    def _first_step(self, step: int, batch):
+        """The run's first step, waited for: its lowering, its compile or
+        load from the cache, its dispatch and (once, here alone) a wait
+        for its outputs, so that ``train.start.first_step`` is exactly this
+        one step whatever ``log_every`` is. Returns the step's metrics."""
+        with self._start.phase(profiler.TRAIN_START_FIRST_STEP,
+                               profiler.active() and {"step": step}):
+            if jax.default_backend() == "tpu":
+                self.step_kernels = lowered_kernel_calls(
+                    self.task.step_fn, self.task.state, batch)
+            with hot_span(profiler.TRAIN_DISPATCH, step=step):
+                self.task.state, metrics = self.task.step_fn(self.task.state, batch)
+            with hot_span(profiler.TRAIN_SYNC, step=step):
+                jax.block_until_ready(metrics)
+        # Training shapes are fixed: everything compiles on the first
+        # executed step, so under KFTPU_SANITIZE=recompile any later compile
+        # is a dispatch-signature defect — the runtime half of the F6xx
+        # rules. No-op when the sanitizer is off.
+        mark_compile_warm()
+        return metrics
 
     def request_profile(self, num_steps: Optional[int] = None,
                         trace_dir: Optional[str] = None) -> None:
@@ -347,18 +400,11 @@ class Trainer:
                     with hot_span(profiler.TRAIN_STAGE_WAIT, step=step):
                         batch = stager.get(step)
                     self._stage_wait_sum_s += time.monotonic() - t_wait
-                    if step == start and jax.default_backend() == "tpu":
-                        self.step_kernels = lowered_kernel_calls(
-                            self.task.step_fn, self.task.state, batch)
-                    with hot_span(profiler.TRAIN_DISPATCH, step=step):
-                        self.task.state, metrics = self.task.step_fn(self.task.state, batch)
                     if step == start:
-                        # Training shapes are fixed: everything compiles on the
-                        # first executed step, so under KFTPU_SANITIZE=recompile
-                        # any later compile is a dispatch-signature defect — the
-                        # runtime half of the F6xx rules. No-op when the
-                        # sanitizer is off.
-                        mark_compile_warm()
+                        metrics = self._first_step(step, batch)
+                    else:
+                        with hot_span(profiler.TRAIN_DISPATCH, step=step):
+                            self.task.state, metrics = self.task.step_fn(self.task.state, batch)
                     if watchdog is not None:
                         watchdog.step_completed(step + 1)
                     if self._preempted.is_set():
@@ -458,6 +504,7 @@ class Trainer:
         write_device_report(
             self.workdir,
             programs={"train_step": self.step_kernels},
+            start={"phases": self.start_phase_seconds()},
             mesh={a: int(n) for a, n in self.mesh.shape.items() if n > 1},
             largest_param={
                 "shape": list(big.shape),

@@ -1,7 +1,7 @@
 """ISSUE 31 on the chip, beside the benchmark and editing none of it.
 
     python3 scripts/round_pacing_chip.py --workload <cell> --seed <n> \\
-        --seconds <s> --trace <0|2> [--pin-steps K]
+        --seconds <s> --trace <0|2> [--pin-steps K] [--trace-start]
 
 ``python3 -m benchmark.run`` with the numbers a decode round's length is
 chosen from printed beside its result (to standard error; the result line is
@@ -24,14 +24,28 @@ of a traced run the tail's rounds by length, its decode and chunk programs
 phase by phase, the always-on sum over the traced stretch beside what
 ``hostspans.innermost_segments`` cuts out of the spans for it, and the
 thread's time under no span by the spans on either side of it;
-and of the set-up, when the engine was built, how long each length of the
-decode ladder took to compile or load, and when each program variant was
-first dispatched. It runs on a checkout without the mechanism too (the parent commit):
-what that engine does not count is left out.
+and of the set-up (ISSUE 53), the program's own start-up clock as the
+snapshot taken when the window opens holds it: ``start_*`` (the engine's
+constructor by phase, or the trainer's three), ``compile_*`` (the process's
+compile seconds and cache traffic), ``LLMEngine.start_programs()`` (each
+program the constructor ran once), what ``benchmark/startup_readers.py``
+reads from them; and that the ``compile_*`` keys stood still over the
+window. It reads the training cell too (its start-up alone), and a checkout
+without the clock (the parent commit): what a snapshot does not hold is
+left out. What the harness does around the program is in its own log lines
+(``engine built at``, ``correctness done at``, ``window opens``).
 
-``--pin-steps K`` is an experiment, not an option of the program: the
-engine's choice is replaced, from outside, by ``min(K, cap)``, to read the
-host's time an iteration at a length the scheduler would not choose here.
+``--trace-start`` is an experiment: a capture through ``obs/profiler.py``
+around the engine's construction (from the harness's weights made to its
+reference check's first line), reduced with ``benchmark/hostspans.py``: per
+start phase and per warmed program the host's seconds, the device's busy
+seconds under them and the rest, idle. What the capture costs is this run's
+``start_*`` and ``setup_s`` against a run's without it.
+
+``--pin-steps K`` is an experiment, not an option of the program: from the
+window's opening on the engine's choice is replaced, from outside, by
+``min(K, cap)``, to read the host's time an iteration at a length the
+scheduler would not choose here.
 ``--rate R`` is another: an open loop's arrivals at ``R`` requests a second
 in place of the traffic file's, for the first readings of a sweep.
 ``--switch-interval S`` a third: ``sys.setswitchinterval(S)`` for the run
@@ -258,35 +272,131 @@ def _tail(record: dict) -> None:
              f"{len(carried)} dispatch spans: not paired")
 
 
+def _start_up(snapshot: dict, setup_s, programs: dict | None) -> None:
+    """The program's start-up clock, from the snapshot taken as the window
+    opens: its ``start_*`` and ``compile_*`` keys as they are, and the
+    readings ``benchmark/startup_readers.py`` takes of them. A snapshot
+    without the clock has none of the keys, and ``unattributed_s`` is
+    ``setup_s``."""
+    from benchmark import startup_readers as sr
+
+    part = snapshot.get("engine") or snapshot.get("trainer") or {}
+    run = {"counters_before": snapshot, "values": {"setup_s": setup_s}}
+    table = {
+        **{k: v for k, v in part.items()
+           if k.startswith((sr.START, "compile_"))},
+        "setup_s": setup_s,
+        "readers": {"program_start_s": sr.program_start_s(run),
+                    "unattributed_s": sr.unattributed_s(run),
+                    "compile_s": sr.compile_s(run),
+                    "cache_misses": sr.cache_misses(run),
+                    "warm_s": sr.warm_s(run)}}
+    _log(f"start-up: {json.dumps(table, sort_keys=True)}")
+    if programs:
+        _log(f"start programs ({len(programs)}): {json.dumps(programs)}")
+
+
+def _compiles_over_the_window(before: dict, after: dict) -> None:
+    """The window compiles nothing: the process's compile keys stand still
+    between the two snapshots (what ``CompileCounter`` asserts from
+    outside)."""
+    a = before.get("engine") or before.get("trainer") or {}
+    b = after.get("engine") or after.get("trainer") or {}
+    moved = {k: b[k] - a[k] for k in b
+             if k.startswith("compile_") and k in a}
+    if moved:
+        _log(f"compile keys over the window: {json.dumps(moved)} "
+             f"({'STOOD STILL' if not any(moved.values()) else 'MOVED'})")
+
+
+def _start_capture(trace: dict, engine) -> None:
+    """A capture around the engine's construction, by start phase and by
+    warmed program: the builder thread's seconds (innermost segments), the
+    device's busy seconds under them, and the rest, idle."""
+    from benchmark import hostspans
+    from benchmark.tracing import measure, union
+    from kubeflow_tpu.obs import profiler
+
+    spans = hostspans.thread_with(trace.get("host_spans"),
+                                  profiler.ENGINE_START_PHASES)
+    if not spans:
+        _log("start capture: no engine.start.* span in it (a checkout "
+             "without the clock)")
+        return
+    busy = union((t, t + d) for dev in trace["devices"][:1]
+                 for _, t, d in dev["ops"]) if trace["devices"] else []
+
+    def busy_in(t0: float, t1: float) -> float:
+        return measure([(max(a, t0), min(b, t1)) for a, b in busy
+                        if a < t1 and b > t0])
+
+    phases: dict = {}
+    for t0, t1, name in hostspans.innermost_segments(
+            [s for s in spans if s[0] != hostspans.ANCHOR]):
+        n = phases.setdefault(name, [0.0, 0.0])
+        n[0], n[1] = n[0] + t1 - t0, n[1] + busy_in(t0, t1)
+    sums = engine.start_phase_seconds()
+    for name, (host, dev) in sorted(phases.items()):
+        short = name.rpartition(".")[2]
+        _log(f"start capture {name}: spans {host:.4f} s (sum "
+             f"{sums.get(short)}), device busy {dev:.4f} s, idle "
+             f"{host - dev:.4f} s")
+    for name, t0, dur, attrs in spans:
+        if name == profiler.ENGINE_START_WARM:
+            dev = busy_in(t0, t0 + dur)
+            _log(f"start capture program {attrs.get('program')}: "
+                 f"{dur:.4f} s, device busy {dev:.4f} s")
+    _log(f"start capture: {trace['window_s']:.3f} s traced, device busy "
+         f"{measure(busy):.4f} s, constructor "
+         f"{sum(sums.values()):.4f} s by its own clock")
+
+
 def main() -> int:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    # (no abbreviations: ``--trace`` is the benchmark's own, passed on)
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0],
+                                 allow_abbrev=False)
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--pin-steps", type=int, default=0)
     ap.add_argument("--rate", type=float, default=0.0)
     ap.add_argument("--switch-interval", type=float, default=0.0)
     ap.add_argument("--probe-other", action="store_true")
+    ap.add_argument("--trace-start", action="store_true")
     args, rest = ap.parse_known_args()
+    t_origin = time.monotonic()
 
+    from benchmark import correctness, training
     from benchmark import manifest as mf
     from benchmark import run, serving, tracing
     from benchmark.device import sleep_until
     from kubeflow_tpu.serve.engine import LLMEngine
 
-    t_origin = time.monotonic()
-
     def at(msg: str) -> None:
         _log(f"t={time.monotonic() - t_origin:.3f} {msg}")
 
     snapshots = []
+    opened: list = []       # when the first snapshot was taken
     take, read, load = \
         serving.program_counters, mf.read_layer_metrics, mf.load_traffic
 
     def recording(**parts):
         snap = take(**parts)
         snapshots.append(snap)
+        if not opened:
+            # The harness takes it as its window opens: from the entry of
+            # its ``main`` to here is ``setup_s`` within a millisecond.
+            opened.append(time.monotonic())
+        engine = parts.get("engine")
+        if engine is None:      # a trainer: one snapshot a sync point
+            return snap
         at(f"counters snapshot {len(snapshots)}")
-        pacer = getattr(parts.get("engine"), "_pacer", None)
+        if engine not in engines:
+            # The first snapshot is the first the script sees of the engine:
+            # taken as the window opens, with the whole start-up in it.
+            engines.append(engine)
+            if args.pin_steps:
+                engine._pacer.choose = lambda cap: min(args.pin_steps, cap)
+        pacer = getattr(engine, "_pacer", None)
         if pacer is not None:
             _log(f"pacer at snapshot {len(snapshots)}: host_s "
                  f"{pacer.host_s()} step_s {pacer.step_s} k {pacer.k} "
@@ -326,44 +436,25 @@ def main() -> int:
         traffic["arrival"]["rate_rps"] = args.rate
         return traffic
 
-    class Timeline(dict):
-        """``LLMEngine.program_kernels`` that says when each variant was
-        first dispatched (its lowering is done, its compile or its load
-        from the cache follows)."""
+    trace_dir = os.path.join(run.OUT_ROOT, args.workload + ".start")
+    make, judge = serving.make_params, correctness.serving_numbers
+    capture_on: list = []
 
-        def __setitem__(self, key, value):
-            at(f"first dispatch of {key}")
-            super().__setitem__(key, value)
+    def made(*a, **kw):
+        """The weights are made: the engine's construction follows."""
+        params = make(*a, **kw)
+        tracing.start(trace_dir)
+        capture_on.append(time.monotonic())
+        return params
 
-    build = LLMEngine.__init__
-    ladder = getattr(LLMEngine, "_warm_decode_ladder", None)
-
-    def built(self, *a, **kw):
-        t0 = time.monotonic()
-        build(self, *a, **kw)
-        engines.append(self)
-        self.program_kernels = Timeline(self.program_kernels)
-        at(f"LLMEngine() took {time.monotonic() - t0:.3f} s")
-        if args.pin_steps:
-            self._pacer.choose = lambda cap: min(args.pin_steps, cap)
-
-    def ladder_timed(self):
-        t0 = time.monotonic()
-        dispatch = self._dispatch_decode
-
-        def one(k, mode, key):
-            t1 = time.monotonic()
-            out = dispatch(k, mode, key)
-            out[0].block_until_ready()      # (the token buffer, expert rows)
-            _log(f"decode program of {k} steps: first dispatch "
-                 f"{time.monotonic() - t1:.3f} s")
-            return out
-
-        self._dispatch_decode = one
-        ladder(self)
-        del self._dispatch_decode
-        _log(f"decode ladder {self._pacer.ladder} compiled and run in "
-             f"{time.monotonic() - t0:.3f} s")
+    def judged(engine, *a, **kw):
+        """The reference check's first line: the engine is built."""
+        trace = tracing.stop(trace_dir, time.monotonic() - capture_on.pop())
+        try:
+            _start_capture(trace, engine)
+        except Exception as exc:    # boundary: the run's result comes first
+            _log(f"start capture not printed: {type(exc).__name__}: {exc}")
+        return judge(engine, *a, **kw)
 
     if args.switch_interval:
         sys.setswitchinterval(args.switch_interval)
@@ -387,16 +478,25 @@ def main() -> int:
 
         for name in ("_consume_round", "_decode_once", "_iterate"):
             probing(name)
-    serving.program_counters = recording
+    serving.program_counters = training.program_counters = recording
     tracing.record = recorded
     mf.read_layer_metrics = reading
     if args.rate:
         mf.load_traffic = at_rate
-    LLMEngine.__init__ = built
-    if ladder is not None:          # the parent commit has none
-        LLMEngine._warm_decode_ladder = ladder_timed
+    if args.trace_start:    # the two calls of the harness's it lies between
+        serving.make_params, correctness.serving_numbers = made, judged
+    t_main = time.monotonic()
     rc = run.main(["--workload", args.workload, "--seconds",
                    str(args.seconds), *rest])
+    if snapshots and snapshots[0]:
+        programs = getattr(engines[-1], "start_programs", None) \
+            if engines else None
+        _start_up(snapshots[0], opened[0] - t_main, programs and programs())
+        if engines and engines[-1].program_kernels:
+            _log("program_kernels: "
+                 + json.dumps(sorted(engines[-1].program_kernels)))
+        if len(snapshots) >= 2 and snapshots[-1]:
+            _compiles_over_the_window(snapshots[0], snapshots[-1])
     if len(snapshots) >= 2 and snapshots[0] and "engine" in snapshots[0]:
         a, b = snapshots[0]["engine"], snapshots[1]["engine"]
         d = {k: b[k] - a[k] for k in b if k in a and k.startswith(

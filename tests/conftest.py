@@ -260,6 +260,36 @@ ASSISTANT_STATED = {
 }
 
 
+# PR 53's seventeen readers, the same way. Nine read what no start phase of
+# the program accounts for of ``setup_s`` (benchmark/startup_readers.py):
+# the start sums the snapshot holds, at rest what a built engine or a
+# trainer past its first step would hold, and ``run["values"]["setup_s"]``,
+# which the suite's empty runs do not carry: ``quiet_run`` learns it below.
+# Eight read the state sync a round (benchmark/phase_readers.py, whose keys
+# every parent since PR 38 has).
+STARTUP_SETUP_S = 30.0
+STARTUP_ENGINE_COUNTERS = {
+    "start_place_sum_s": 1.0, "start_pool_sum_s": 0.5,
+    "start_relay_sum_s": 0.25, "start_warm_sum_s": 4.0,
+    "start_other_sum_s": 0.25}
+STARTUP_TRAINER_COUNTERS = {
+    "start_build_sum_s": 2.0, "start_resume_sum_s": 0.0,
+    "start_first_step_sum_s": 8.0}
+SYNC_ENGINE_COUNTERS = {
+    "decode_rounds": 0, "sched_sync_state_sum_s": 0.0,
+    "state_slot_syncs": 0, "state_row_syncs": 0}
+STARTUP_STATED = {
+    "start.unattributed_s.train": 20.0,          # 30 less 2 + 0 + 8
+    **{f"start.unattributed_s.{cell}": 24.0      # 30 less 6
+       for cell in ("chat", "batch", "longctx", "longanswer", "mixedlength",
+                    "longdoc", "reasoning", "assistant")}}
+SYNC_STATED = {
+    **{f"engine.sync_state_ms_per_round.{cell}": 0.0
+       for cell in ("chat", "batch", "longctx", "longanswer", "assistant")},
+    **{f"engine.state_syncs_per_round.{cell}": 0.0
+       for cell in ("batch", "longanswer", "assistant")}}
+
+
 @pytest.fixture(autouse=True, scope="session")
 def benchmark_suite_tables_know_the_longanswer_cell(request):
     suite = next(
@@ -281,13 +311,27 @@ def benchmark_suite_tables_know_the_longanswer_cell(request):
             ((suite.ADDED_ENGINE_COUNTERS, readers.ENGINE0),
              {**LONGANSWER_ENGINE_COUNTERS, **WINDOW_ENGINE_COUNTERS,
               **MIXEDLENGTH_ENGINE_COUNTERS, **LONGDOC_ENGINE_COUNTERS,
-              **REASONING_ENGINE_COUNTERS}),
+              **REASONING_ENGINE_COUNTERS, **STARTUP_ENGINE_COUNTERS,
+              **SYNC_ENGINE_COUNTERS}),
+            ((readers.TRAINER0,), STARTUP_TRAINER_COUNTERS),
             ((suite.ADDED_STATED, total.STATED),
              {**LONGANSWER_STATED, **WINDOW_STATED, **MIXEDLENGTH_STATED,
-              **LONGDOC_STATED, **REASONING_STATED, **ASSISTANT_STATED})):
+              **LONGDOC_STATED, **REASONING_STATED, **ASSISTANT_STATED,
+              **STARTUP_STATED, **SYNC_STATED})):
         for table in tables:
             for key, value in added.items():
                 table.setdefault(key, value)
+
+    # The suite's empty runs are ``quiet_run`` and what is built on it: a
+    # run whose window opened has its ``setup_s`` too.
+    quiet = readers.quiet_run
+
+    def quiet_run_with_its_setup(name: str) -> dict:
+        run = quiet(name)
+        run.setdefault("values", {"setup_s": STARTUP_SETUP_S})
+        return run
+
+    readers.quiet_run = total.quiet_run = quiet_run_with_its_setup
 
 
 # test_benchmark_glm.py pins where PR 28's entries stand (the LAST cell, the
@@ -318,6 +362,15 @@ PINS_PR35S_LINE = ("test_benchmark_lfm2",
 # ``search`` lets that one key through that one pattern; the rule itself is a
 # ``benchmark`` PR's to mend (PERF.md section 7).
 TAKES_SIZE_FOR_A_WIDTH = "test_cells_configs_and_files"
+# PR 53 appends seventeen per-layer metrics for cells the benchmark had:
+# every cell's own test file pins the set its cell reports (the line its CPU
+# rehearsal prints, and for PR 40's, 47's and 50's cells the entries in the
+# manifest), as its PR left it. Those tests are handed the manifest without
+# the seventeen; every other test reads them.
+PINS_A_CELLS_LINE = "test_the_cells_path_runs_end_to_end_on_the_cpu"
+PINS_A_CELLS_ENTRIES = {
+    f"test_what_pr_{pr}_added_is_listed_with_the_benchmark"
+    for pr in (40, 47, 50)}
 
 
 class _VocabRowsAreNoWidth:
@@ -338,16 +391,26 @@ def the_manifest_as_it_stood_for_the_tests_that_pin_a_pr(request,
                                                          monkeypatch):
     name = request.node.name
     module = request.node.module
+    since_pr50 = set(STARTUP_STATED) | set(SYNC_STATED)
     later = set(WINDOW_STATED) | set(MIXEDLENGTH_STATED) \
-        | set(LONGDOC_STATED) | set(REASONING_STATED) | set(ASSISTANT_STATED)
+        | set(LONGDOC_STATED) | set(REASONING_STATED) \
+        | set(ASSISTANT_STATED) | since_pr50
     mixed = next(iter(MIXEDLENGTH_CELLS.values()))[0]
     reasoning = next(iter(REASONING_CELLS.values()))[0]
     assistant = next(iter(ASSISTANT_CELLS.values()))[0]
-    if (module.__name__, request.node.originalname) == PINS_PR35S_LINE:
+    if request.node.originalname == PINS_A_CELLS_LINE:
+        if (module.__name__, PINS_A_CELLS_LINE) != PINS_PR35S_LINE:
+            later = since_pr50
         whole = module.rehearsal_manifest
         monkeypatch.setattr(module, "rehearsal_manifest", lambda: {
             **whole(), "per_layer": [m for m in whole()["per_layer"]
                                      if m["name"] not in later]})
+        return
+    if name in PINS_A_CELLS_ENTRIES:
+        monkeypatch.setattr(module, "MANIFEST", {
+            **module.MANIFEST,
+            "per_layer": [m for m in module.MANIFEST["per_layer"]
+                          if m["name"] not in since_pr50]})
         return
     if name == TAKES_SIZE_FOR_A_WIDTH:
         monkeypatch.setattr(module, "re", _VocabRowsAreNoWidth())
@@ -358,7 +421,7 @@ def the_manifest_as_it_stood_for_the_tests_that_pin_a_pr(request,
     cells = {mixed, next(iter(LONGDOC_CELLS.values()))[0], reasoning,
              assistant}
     if name == PINS_PR43_AT_THE_END:
-        later = set(REASONING_STATED) | set(ASSISTANT_STATED)
+        later = set(REASONING_STATED) | set(ASSISTANT_STATED) | since_pr50
         cells = {reasoning, assistant}
     if name == PINS_PR28_AT_THE_END:
         later |= set(LONGANSWER_STATED)

@@ -254,7 +254,9 @@ def test_cache_dir_from_outside_is_left_alone(tmp_path):
     got = json.loads(out.stdout.strip().splitlines()[-1])
     assert got["used"] == got["config"] == outside
     assert got["stats"] == {"dir": outside, "entries": 0, "hits": 0,
-                            "misses": 0}
+                            "misses": 0, "backend_compiles": 0,
+                            "backend_compile_s": 0.0, "retrieval_s": 0.0,
+                            "trace_lower_s": 0.0}
 
 
 def test_default_cache_dir_is_one_fixed_path_in_the_checkout():
@@ -317,8 +319,12 @@ def test_trainer_writes_a_device_report(tmp_path):
     Trainer._write_device_report(types.SimpleNamespace(
         task=types.SimpleNamespace(state={"params": {
             "embed": big, "norm": jnp.zeros((4,))}}),
-        step_kernels={}, mesh=mesh, workdir=str(tmp_path)))
+        step_kernels={}, mesh=mesh, workdir=str(tmp_path),
+        start_phase_seconds=lambda: {"build": 1.5, "resume": 0.0,
+                                     "first_step": 2.5}))
     rep = read_device_report(str(tmp_path))
+    assert rep["start"] == {"phases": {"build": 1.5, "resume": 0.0,
+                                       "first_step": 2.5}}
     assert rep["platform"] == "cpu" and rep["device_kind"] == "cpu"
     assert rep["device_count"] == len(jax.devices())
     assert rep["programs"] == {"train_step": {}}

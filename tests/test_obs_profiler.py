@@ -163,14 +163,22 @@ ENGINE_CONSTANTS = {"slots", "kv_bytes_per_token", "kv_pool_bytes",
                     "state_pool_bytes", "weights_relaid_bytes",
                     "kv_window_pool_bytes", "kv_global_pool_bytes",
                     "kv_window_pages_a_sequence", "kv_sequence_pool_bytes",
-                    "kv_token_pool_bytes"}
+                    "kv_token_pool_bytes",
+                    # what the constructor took (tests/test_obs_startup.py)
+                    "start_place_sum_s", "start_pool_sum_s",
+                    "start_relay_sum_s", "start_warm_sum_s",
+                    "start_other_sum_s"}
+# the PROCESS's compiles so far: neither the engine's nor at rest
+PROCESS_KEYS = {"compile_backend_sum_s", "compile_backend_n",
+                "compile_retrieval_sum_s", "compile_trace_lower_sum_s",
+                "compile_cache_hits", "compile_cache_misses"}
 
 
 def test_engine_counters_exist_at_construction_and_only_grow(engine):
     before = engine.counters()
     assert set(before) >= ENGINE_KEYS      # before any request
     assert all(v == 0 for k, v in before.items()
-               if k not in ENGINE_CONSTANTS)
+               if k not in ENGINE_CONSTANTS | PROCESS_KEYS)
     assert before["slots"] == 4
     assert before["kv_pool_bytes"] > before["kv_bytes_per_token"] > 0
     reqs = [engine.submit(list(range(1, 40 + i)),
@@ -367,7 +375,7 @@ def check_trainer_capture(trace_dir, steps):
 
 def test_trainer_profile_start_step_goes_through_the_control(tmp_path):
     tr = make_trainer(tmp_path, profile_start_step=3, profile_num_steps=2)
-    assert tr.counters() == {"stage_wait_sum_s": 0.0}
+    assert tr.counters()["stage_wait_sum_s"] == 0.0
     seen = []
     tr.run(on_step=lambda step, m: seen.append(
         (step, profiler.active(), tr.counters())))
