@@ -282,7 +282,8 @@ class FullBufferReupload(Rule):
                     self, node,
                     f"full upload of persistent buffer '{attr}' every "
                     f"round in '{fn.name}'; keep it device-resident and "
-                    "sync per-index deltas (serve/device_state.py)")
+                    "sync a round's dirty indices together "
+                    "(serve/device_state.py: DecodeState.sync)")
 
 
 def _donating_callables(mod: Module) -> dict[str, tuple[int, ...]]:

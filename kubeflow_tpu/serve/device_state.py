@@ -2,9 +2,9 @@
 hot-loop elimination (ISSUE 4 tentpole (a)).
 
 Before this module, every decode round re-materialised the scheduler's
-tensor-shaped state from host Python: eight ``[B]`` arrays
-(tokens/lengths/live/temps/top_k/top_p/stops/budgets) rebuilt with numpy and
-``jnp.asarray``-uploaded per dispatch, plus the FULL
+tensor-shaped state from host Python: the ``[B]`` arrays of ``STATE_FIELDS``
+(tokens/lengths/live/temps/top_k/top_p/stops/budgets/adapter) rebuilt with
+numpy and ``jnp.asarray``-uploaded per dispatch, plus the FULL
 ``[B, max_pages_per_slot]`` page table. Each of those uploads pays the
 per-dispatch host overhead the multi-step dispatch exists to amortize, and
 the re-materialisation itself is host work serialized against device
@@ -20,9 +20,12 @@ lifetime:
 - **Deltas, not snapshots.** Host-side scheduler events (admission into a
   slot, reap/cancel, preemption, a speculative round advancing a slot,
   page-table growth) mark the slot/row DIRTY; immediately before the next
-  dispatch the engine flushes each dirty index through a small donated
-  ``jit`` scatter — a handful of scalars (or one ``[mpp]`` row) per changed
-  slot, instead of the whole batch every round.
+  dispatch the engine flushes everything dirty TOGETHER: one host array of
+  one fixed shape (entry ``i`` is slot ``i``'s: a flag and its nine values,
+  a flag and its ``[mpp]`` row), ONE explicit ``jax.device_put``, ONE
+  donated ``jit`` program that writes the flagged entries and leaves the
+  rest as they were — whatever the round dirtied, and nothing at all when
+  it dirtied nothing.
 - **The device is the mirror master in steady state.** The decode dispatch
   itself consumes the state and returns the advanced state (same donated
   buffers); because the device applies the exact finish rules the host
@@ -30,7 +33,7 @@ lifetime:
   without host interference never needs a sync at all.
 
 The dirty-set discipline (who marks what) lives in ``serve/engine.py``;
-this module is the mechanism: the arrays, the scatter programs, and the
+this module is the mechanism: the arrays, the one sync program, and the
 upload accounting.
 """
 
@@ -58,30 +61,47 @@ _DTYPES = {"tokens": jnp.int32, "lengths": jnp.int32, "live": jnp.bool_,
 DEAD_SLOT = (0, 0, False, 0.0, 0, 1.0, -1, 0, -1)
 
 
-def _scatter_slot(arrays: dict, idx, tok, length, live, temp, tk, tp,
-                  stop, budget, adapter) -> dict:
-    """One slot's state delta as a scatter at ``idx`` (donated in/out)."""
-    return {
-        "tokens": arrays["tokens"].at[idx].set(tok),
-        "lengths": arrays["lengths"].at[idx].set(length),
-        "live": arrays["live"].at[idx].set(live),
-        "temps": arrays["temps"].at[idx].set(temp),
-        "top_k": arrays["top_k"].at[idx].set(tk),
-        "top_p": arrays["top_p"].at[idx].set(tp),
-        "stops": arrays["stops"].at[idx].set(stop),
-        "budgets": arrays["budgets"].at[idx].set(budget),
-        "adapter": arrays["adapter"].at[idx].set(adapter),
-    }
+#: The sync's host array, ``[B, 2 + len(STATE_FIELDS) + mpp]`` int32. Entry
+#: ``i`` is slot ``i``'s: ``[_SLOT_FLAG]`` says whether its state is
+#: written, the ``STATE_FIELDS`` values follow (float32 fields as their
+#: BITS, ``live`` as 0 / 1, so every value reaches the device bit for
+#: bit); ``[_ROW_FLAG]`` says whether its page-table row is written, and
+#: the row's page ids follow.
+_SLOT_FLAG = 0
+_VALUES = 1
+_ROW_FLAG = _VALUES + len(STATE_FIELDS)
+_ROW = _ROW_FLAG + 1
+_FLOATS = tuple(i for i, name in enumerate(STATE_FIELDS)
+                if _DTYPES[name] == jnp.float32)
+
+
+def _write_packed(arrays: dict, table, pack):
+    """Every flagged entry of ``pack`` written over the state and the page
+    table (both donated in/out); an entry whose flag is 0 keeps what the
+    device holds."""
+    slot_dirty = pack[:, _SLOT_FLAG] != 0
+    out = {}
+    for col, name in enumerate(STATE_FIELDS, _VALUES):
+        old = arrays[name]
+        new = pack[:, col]
+        if old.dtype == jnp.bool_:
+            new = new != 0
+        elif old.dtype != new.dtype:
+            new = jax.lax.bitcast_convert_type(new, old.dtype)
+        out[name] = jnp.where(slot_dirty, new, old)
+    row_dirty = pack[:, _ROW_FLAG] != 0
+    return out, jnp.where(row_dirty[:, None], pack[:, _ROW:], table)
 
 
 class DecodeState:
     """Persistent on-device scheduler state + dirty-index delta sync.
 
-    ``arrays`` is the dict of eight ``[B]`` device arrays the decode
-    dispatch donates and returns; ``table`` is the ``[B, mpp]`` device page
-    table threaded through the dispatches the same way. ``adopt()`` swaps
-    in a dispatch's returned handles; the ``mark_*``/``sync_*`` pair applies
-    host-side scheduler deltas as per-index donated scatters."""
+    ``arrays`` is the dict of the ``[B]`` device arrays of ``STATE_FIELDS``
+    the decode dispatch donates and returns; ``table`` is the ``[B, mpp]``
+    device page table threaded through the dispatches the same way.
+    ``adopt()`` swaps in a dispatch's returned handles; ``mark_*`` notes a
+    host-side scheduler delta and ``sync()`` sends a round's deltas, slots
+    and rows together, as one transfer and one donated program."""
 
     def __init__(self, num_slots: int, mpp: int):
         self.num_slots = num_slots
@@ -101,18 +121,20 @@ class DecodeState:
         self.table = jnp.full((num_slots, mpp), -1, jnp.int32)
         # Upload accounting — the tentpole's proof obligation. "full"
         # counters may only ever reflect construction; sync counters grow
-        # with scheduler events, never with steady-state decode rounds.
+        # with scheduler events, never with steady-state decode rounds:
+        # slots and rows SENT, and the programs that carried them (one a
+        # sync that found anything dirty).
         self.stats = {
             "full_state_uploads": 1,
             "full_table_uploads": 1,
             "slot_syncs": 0,
             "table_row_syncs": 0,
+            "sync_dispatches": 0,
         }
         self.dirty_slots: set[int] = set()
         self.dirty_rows: set[int] = set()
-        self._scatter = jax.jit(_scatter_slot, donate_argnums=(0,))
-        self._row_set = jax.jit(lambda t, i, row: t.at[i].set(row),
-                                donate_argnums=(0,))
+        self._pack_shape = (num_slots, _ROW + mpp)
+        self._write = jax.jit(_write_packed, donate_argnums=(0, 1))
 
     # -- dirty marking (host scheduler events) -----------------------------
 
@@ -124,40 +146,53 @@ class DecodeState:
 
     # -- delta sync (immediately before a dispatch that reads the state) ---
 
-    def sync_slots(self, values_for: Callable[[int], tuple]) -> None:  # hot-loop
-        """Scatter every dirty slot's current host-side values.
-        ``values_for(idx)`` returns the STATE_FIELDS tuple (DEAD_SLOT for a
-        freed slot). Scalars upload via EXPLICIT ``jax.device_put`` so the
-        sync stays legal under ``jax.transfer_guard("disallow")`` (the
-        KFTPU_SANITIZE runtime guard, and the steady-state guard the
-        hot-loop tests apply): every intended transfer is explicit and
-        accounted; an implicit one anywhere is a regression. (In this
-        jax, ``jnp.asarray`` of a *scalar* still counts as implicit —
-        only ``device_put`` is unconditionally explicit.)"""
-        put = jax.device_put
-        for idx in sorted(self.dirty_slots):
-            (tok, length, live, temp, tk, tp, stop, budget,
-             adapter) = values_for(idx)
-            self.arrays = self._scatter(
-                self.arrays, put(np.int32(idx)),
-                put(np.int32(tok)), put(np.int32(length)),
-                put(np.bool_(live)), put(np.float32(temp)),
-                put(np.int32(tk)), put(np.float32(tp)),
-                put(np.int32(stop)), put(np.int32(budget)),
-                put(np.int32(adapter)))
-            self.stats["slot_syncs"] += 1
+    def sync(self, values_for: Callable[[int], tuple],
+             row_for: Callable[[int], np.ndarray]) -> None:  # hot-loop
+        """Send every dirty slot's current host-side values and every dirty
+        page-table row: ONE upload and ONE program whatever is dirty,
+        neither when nothing is. ``values_for(idx)`` returns the
+        STATE_FIELDS tuple (DEAD_SLOT for a freed slot), ``row_for(idx)``
+        the row's ``[mpp]`` page ids; both are read here, once an index."""
+        if not (self.dirty_slots or self.dirty_rows):
+            return
+        pack = np.zeros(self._pack_shape, np.int32)
+        for idx in self.dirty_slots:
+            values = list(values_for(idx))
+            for col in _FLOATS:
+                values[col] = np.float32(values[col]).view(np.int32)
+            pack[idx, _SLOT_FLAG] = 1
+            pack[idx, _VALUES:_ROW_FLAG] = values
+        for idx in self.dirty_rows:
+            pack[idx, _ROW_FLAG] = 1
+            pack[idx, _ROW:] = row_for(idx)
+        self._send(pack)
+        self.stats["slot_syncs"] += len(self.dirty_slots)
+        self.stats["table_row_syncs"] += len(self.dirty_rows)
+        self.stats["sync_dispatches"] += 1
         self.dirty_slots.clear()
-
-    def sync_rows(self, row_for: Callable[[int], np.ndarray]) -> None:  # hot-loop
-        """Scatter every dirty page-table row (one ``[mpp]`` upload each —
-        page-table GROWTH costs one row, never the full table)."""
-        for idx in sorted(self.dirty_rows):
-            self.table = self._row_set(
-                self.table, jax.device_put(np.int32(idx)),
-                jax.device_put(np.ascontiguousarray(row_for(idx),
-                                                    np.int32)))
-            self.stats["table_row_syncs"] += 1
         self.dirty_rows.clear()
+
+    def warm(self):
+        """Compile (or load) and run the sync's program once, on a pack
+        that flags nothing: the state comes back as it went in, and no
+        counter moves. Called once the state lies where traffic will find
+        it (the program is one a placement). Returns what to wait for."""
+        self._send(np.zeros(self._pack_shape, np.int32))
+        return self.arrays, self.table
+
+    def _send(self, pack: np.ndarray) -> None:  # hot-loop
+        """The upload is an EXPLICIT ``jax.device_put``, so the sync stays
+        legal under ``jax.transfer_guard("disallow")`` (the KFTPU_SANITIZE
+        runtime guard, and the steady-state guard the hot-loop tests
+        apply): every intended transfer is explicit and accounted; an
+        implicit one anywhere is a regression. Where the state is
+        committed (to a device, as a relaid engine's is, or over a mesh)
+        the pack goes with the state's own sharding, so the program and
+        what it returns stay the ones traffic's other programs see."""
+        table = self.table
+        self.arrays, self.table = self._write(
+            self.arrays, table, jax.device_put(
+                pack, table.sharding if table.committed else None))
 
     # -- post-dispatch adoption --------------------------------------------
 

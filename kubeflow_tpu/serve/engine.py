@@ -25,8 +25,9 @@ Design, driven by XLA's compilation model rather than CUDA streams:
   host-overhead elimination): the per-slot scheduler arrays
   (tokens/lengths/live/sampling params/budgets) and the paged page table
   are persistent device arrays (serve/device_state.py) — admissions,
-  reaps, preemptions and page-table growth apply per-slot DELTAS through
-  small donated scatters, and steady-state rounds upload nothing. With
+  reaps, preemptions and page-table growth apply as per-slot DELTAS, a
+  round's worth in one upload and one donated program, and steady-state
+  rounds upload nothing. With
   ``BatchingSpec.pipelined_decode`` (default on) the scheduler dispatches
   round N+1 before consuming round N's tokens, so detokenization, stream
   callbacks, reaping and admission overlap device compute. The staleness
@@ -1306,9 +1307,9 @@ class LLMEngine:
         self.slots: list[Optional[_Slot]] = [None] * self.num_slots  # lockfree: scheduler-confined
         # Device-resident scheduler state (serve/device_state.py): the
         # decode dispatch's [B] carries and the paged page table live on
-        # device for the engine's lifetime; host scheduler events sync as
-        # per-slot donated scatters, so steady-state rounds upload nothing
-        # (the stats counters prove it).
+        # device for the engine's lifetime; a round's host scheduler
+        # events sync together, as one upload and one donated program, so
+        # steady-state rounds upload nothing (the stats counters prove it).
         with start(prof.ENGINE_START_POOL):
             self._dstate = DecodeState(self.num_slots, mpp=self._mpp)
             if self._weights_relaid_bytes:  # as the pool: see the load path
@@ -1405,6 +1406,10 @@ class LLMEngine:
         if self._mixed or self._chunk_rows > 1:
             self._warm_rows_program()
         self._warm_decode_ladder()
+        # After the ladder: the state lies where a program left it, as
+        # every sync of traffic's will find it.
+        self._warm(_program_key("state_sync", self.num_slots, self._mpp),
+                   self._dstate.warm)
         if self._weights_relaid_bytes:
             self._warm_first_tokens()
         self._start.end()
@@ -1655,12 +1660,13 @@ class LLMEngine:
             # round's length is chosen to hide
             "sched_host_busy_sum_s": sum(phases.values())
             - phases["fetch"] - phases["idle"],
-            # what the state syncs sent (``DecodeState.stats``: one
-            # scatter dispatch a dirty slot, one row upload a dirty
-            # page-table row) and the decode rounds whose sync found
-            # anything dirty
+            # what the state syncs sent (``DecodeState.stats``: dirty
+            # slots, dirty page-table rows, and the programs that carried
+            # them: one a sync, whatever it held) and the decode rounds
+            # whose sync found anything dirty
             "state_slot_syncs": self._dstate.stats["slot_syncs"],
             "state_row_syncs": self._dstate.stats["table_row_syncs"],
+            "state_sync_dispatches": self._dstate.stats["sync_dispatches"],
             "state_sync_rounds": self._state_sync_rounds,
             # cache rows the dispatched steps attend to, summed over the
             # live slots and the steps of every round
@@ -3220,17 +3226,16 @@ class LLMEngine:
 
     def _sync_decode_state(self) -> None:  # hot-loop
         """Flush host scheduler deltas (admissions, reaps, preemptions,
-        spec advances, page-table growth) to the device-resident state as
-        per-index donated scatters. Steady-state rounds have nothing dirty
-        and sync nothing — the zero-upload invariant."""
+        spec advances, page-table growth) to the device-resident state:
+        one upload and one donated program for all of them. Steady-state
+        rounds have nothing dirty and sync nothing — the zero-upload
+        invariant."""
         slots, rows = len(self._dstate.dirty_slots), \
             len(self._dstate.dirty_rows)
         with self._phase(prof.ENGINE_SYNC_STATE, prof.active() and {
                 "slots": slots, "rows": rows}):
-            if slots:
-                self._dstate.sync_slots(self._slot_state_values)
-            if rows:
-                self._dstate.sync_rows(lambda i: self._table[i])
+            self._dstate.sync(self._slot_state_values,
+                              self._table.__getitem__)
         self._state_sync_rounds += bool(slots or rows)
 
     def _dispatch_round(self, active, paced: bool = False) -> bool:  # hot-loop

@@ -43,11 +43,12 @@ always account for exactly the tokens a slot actually kept.
 
 Scheduler-state residency: ``paged_verify_step`` consumes the SAME
 device-resident page table the plain decode path owns
-(serve/device_state.py) — the engine syncs dirty rows as deltas and
-donates the table through the dispatch, so a verify round never re-uploads
-the full table. The ``[B, T]`` token matrix and the ``[B]`` lengths/live
-masks are inherently per-round host data (the drafts were proposed on
-host), and rollback marks the affected rows dirty for the next sync.
+(serve/device_state.py) — the engine syncs dirty rows as deltas (with
+whatever else the round dirtied, in one program) and donates the table
+through the dispatch, so a verify round never re-uploads the full table.
+The ``[B, T]`` token matrix and the ``[B]`` lengths/live masks are
+inherently per-round host data (the drafts were proposed on host), and
+rollback marks the affected rows dirty for the next sync.
 Because verification is a host-side decision between dispatches, spec
 rounds do not pipeline — the engine drains any in-flight plain round
 before entering a spec round.

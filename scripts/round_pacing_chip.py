@@ -15,7 +15,8 @@ host's time a token over the window (ISSUE 37, by
 ``benchmark/phase_readers.py``'s definitions: the scheduler's share of the
 window, which the line's ``engine.sched_busy_share_window.*`` prints where it
 is declared, each of its phases in milliseconds a round and as a share, what
-the state syncs sent a round, the server's write share and wake time); at the
+the state syncs sent a round and the programs that carried them, the server's
+write share and wake time); at the
 window's end the scheduler's two running averages (the host's time an
 iteration, the device's time a step) and the length in force; the client's
 gaps between tokens and times to the first token at several percentiles;
@@ -121,6 +122,10 @@ def _window(before: dict, after: dict, seconds: float) -> None:
             run, "engine", ("state_slot_syncs",), "decode_rounds"),
         "state_sync_rounds_share": pr.per(
             run, "engine", ("state_sync_rounds",), "decode_rounds"),
+        # programs the syncs sent (PR 54: one a syncing round; a checkout
+        # that sent one an item has no such key)
+        "state_sync_dispatches_per_round": pr.per(
+            run, "engine", ("state_sync_dispatches",), "decode_rounds"),
         "stream_write_share_pct": pr.stream_write_share(run),
         "stream_wake_mean_ms": pr.stream_wake_mean_ms(run),
         "stream_behind_share": pr.per(

@@ -88,10 +88,13 @@ def test_an_engine_has_every_key_from_construction_on(engine):
     assert c["start_pool_sum_s"] > 0.0 and c["start_warm_sum_s"] > 0.0
     assert c["start_other_sum_s"] > 0.0 and c["start_place_sum_s"] > 0.0
     programs = engine.start_programs()
-    assert len(programs) >= 3
-    assert list(programs)[-3:] == [      # the ladder last, shortest first
+    assert len(programs) >= 4
+    # the ladder, shortest first, then the state sync, over the state as
+    # the ladder's programs left it
+    assert list(programs)[-4:] == [
         "paged_decode[1,greedy]", "paged_decode[2,greedy]",
-        "paged_decode[4,greedy]"]
+        "paged_decode[4,greedy]",
+        f"state_sync[{engine.num_slots},{engine._mpp}]"]
     assert sum(programs.values()) == pytest.approx(c["start_warm_sum_s"])
     programs.clear()                      # a copy: not the engine's own
     assert engine.start_programs()

@@ -156,6 +156,46 @@ class TestDeviceResidentState:
         assert eng._dstate.stats["slot_syncs"] == syncs_before
         run_all(eng, [req])
 
+    def test_steady_state_rounds_send_no_program(self, cfg, params):
+        """Rounds between two page boundaries, nobody admitted or reaped:
+        no upload and no sync program, under the guard that refuses any
+        implicit transfer."""
+        eng = make_engine(cfg, params, pipelined=True, decode_steps=1)
+        req = eng.submit([3, 1, 4], SamplingParams(max_new_tokens=40))
+        for _ in range(4):
+            eng.step()
+        before = dict(eng._dstate.stats)
+        rounds_before = eng.decode_rounds
+        with jax.transfer_guard_host_to_device("disallow_explicit"):
+            for _ in range(5):          # lengths 7..12: inside page 0
+                eng.step()
+        assert not req.done.is_set()
+        assert eng.decode_rounds > rounds_before
+        assert eng._dstate.stats == before
+        run_all(eng, [req])
+
+    @pytest.mark.parametrize("spec", [None, "ngram"])
+    def test_a_syncing_round_sends_one_program(self, cfg, params, spec):
+        """Whatever a round dirtied (four admissions at once: four slots
+        and four rows) goes in ONE program: as many programs as rounds that
+        synced, fewer than the items they carried, on the plain path and
+        through the speculative verify's sync of its rows; the tokens are
+        the unpipelined plain engine's."""
+        want = gen_all(make_engine(cfg, params, pipelined=False), PROMPTS)
+        eng = make_engine(
+            cfg, params, pipelined=True,
+            spec=spec and SpeculativeSpec(mode=spec, k=4))
+        assert eng.counters()["state_sync_dispatches"] == 0
+        assert gen_all(eng, PROMPTS) == want
+        c = eng.counters()
+        assert c["state_sync_dispatches"] == c["state_sync_rounds"] > 0
+        assert c["state_sync_dispatches"] \
+            == eng._dstate.stats["sync_dispatches"]
+        assert c["state_slot_syncs"] + c["state_row_syncs"] \
+            > c["state_sync_dispatches"]
+        assert eng._dstate.stats["full_state_uploads"] == 1
+        assert eng._dstate.stats["full_table_uploads"] == 1
+
     def test_paged_growth_is_row_deltas(self, cfg, params):
         """Page-table growth mid-decode costs row scatters, never a full
         table upload."""

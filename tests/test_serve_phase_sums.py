@@ -149,7 +149,7 @@ def test_every_key_from_construction_on():
     for key in SUM_KEYS + ["sched_host_busy_sum_s"]:
         assert c[key] == 0.0, key
     for key in ("sched_iterations", "state_slot_syncs", "state_row_syncs",
-                "state_sync_rounds"):
+                "state_sync_rounds", "state_sync_dispatches"):
         assert c[key] == 0, key
     assert not hasattr(eng, "_blocked") and not hasattr(eng, "_blocked_s")
     assert set(eng.sched_phase_seconds()) == set(PHASES) | {"other"}
@@ -327,7 +327,7 @@ def test_under_a_capture_the_sums_are_the_spans_innermost_segments(
     assert sum(a["slots"] for a in syncs) == moved["state_slot_syncs"]
     assert sum(a["rows"] for a in syncs) == moved["state_row_syncs"]
     assert sum(1 for a in syncs if a["slots"] or a["rows"]) \
-        == moved["state_sync_rounds"]
+        == moved["state_sync_rounds"] == moved["state_sync_dispatches"]
     emits = [s[3] for s in sched if s[0] == profiler.ENGINE_EMIT]
     assert sum(a["tokens"] for a in emits) == moved["decode_tokens_emitted"]
     assert all(0 <= a["streams"] <= 4 and a["streams"] <= a["tokens"]
@@ -346,6 +346,11 @@ def test_state_keys_are_decode_states_own_counts(engine):
     assert 0 < c["state_sync_rounds"] <= c["decode_rounds"]
     # a steady round syncs nothing, so fewer rounds synced than ran
     assert c["state_sync_rounds"] < c["decode_rounds"]
+    # and a round that syncs sends one program, whatever it holds
+    assert c["state_sync_dispatches"] == stats["sync_dispatches"] \
+        == c["state_sync_rounds"]
+    assert c["state_sync_dispatches"] \
+        < c["state_slot_syncs"] + c["state_row_syncs"]
 
 
 def test_the_speculative_paths_fetches_are_fetch_phases():
