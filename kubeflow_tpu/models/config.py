@@ -3,7 +3,7 @@
 Presets cover the BASELINE.json configs (Llama-3-8B, Gemma-2B, Mixtral-8x7B)
 and the models the benchmark serves at their published widths (GLM-4.7-Flash,
 LFM2-24B-A2B, K-EXAONE-236B-A23B, Solar-Open2-250B,
-Phi-4-mini-flash-reasoning, Falcon-H1-34B-Instruct), plus tiny variants of
+Phi-4-mini-flash-reasoning, Falcon-H1-34B-Instruct, GLM-5), plus tiny variants of
 each structure for tests.
 Architecture facts are from the public model cards and ``config.json``
 files.
@@ -91,6 +91,18 @@ class DecoderConfig:
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
     v_head_dim: int = 0
+    # A learned indexer that SELECTS the keys latent attention reads
+    # (DeepSeek sparse attention; ``index_topk`` 0 = none, every key is
+    # read): ``index_heads`` query heads of ``index_head_dim`` values from
+    # the latent query, ONE key of that width a token (a LayerNorm; RoPE on
+    # its first ``qk_rope_dim`` values), a weight a head from the block's
+    # input; query ``t`` attends to the ``min(index_topk, t + 1)`` positions
+    # of largest ``sum_j w_j ReLU(q_j . k_s)``, a tie to the lower position
+    # (layers.index_scores, layers.select_keys). The cache holds the
+    # indexer's key a token in a plane of its own beside the latent row.
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     # The stack's pattern: the kind of layer ``i`` is ``layer_kinds[i %
     # len(layer_kinds)]``, "attention", "window" or "conv" (() = every layer
     # attention). A "window" layer is attention whose query ``i`` sees the
@@ -257,6 +269,14 @@ class DecoderConfig:
                                     or self.is_latent):
             raise ValueError("differential attention pairs per-head queries "
                              "and K/V heads: both counts even, not latent")
+        if self.index_topk and not (
+                self.is_latent and self.index_heads > 0
+                and self.index_head_dim >= self.qk_rope_dim > 0
+                and set(self.period) == {"attention"}):
+            raise ValueError(
+                "an indexer (index_topk > 0) selects the keys of LATENT "
+                "attention layers: index_heads > 0 and index_head_dim >= "
+                "qk_rope_dim")
         kinds = self.kinds
         head = self.n_layers - self.stateless_tail
         if "cross" in kinds and not self.diff_attention:
@@ -411,10 +431,13 @@ class DecoderConfig:
             return self._diff_params(cross=False)
         if self.is_latent:
             r, q = self.kv_lora_rank, self.q_lora_rank
+            hi, di = self.index_heads, self.index_head_dim
+            indexer = (q * hi * di + d * di + 2 * di + d * hi) \
+                if self.index_topk else 0
             return (d * q + q + q * h * (self.qk_nope_dim + self.qk_rope_dim)
                     + d * (r + self.qk_rope_dim) + r
                     + r * h * (self.qk_nope_dim + self.v_head_dim)
-                    + h * self.v_head_dim * d)
+                    + h * self.v_head_dim * d + indexer)
         return d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d \
             + (2 * self.head_dim if self.qk_norm else 0) \
             + (d * self.q_dim if self.attn_output_gate else 0)
@@ -515,6 +538,20 @@ PRESETS: dict[str, DecoderConfig] = {
         leading_dense_layers=1, router_score="sigmoid",
         router_norm_topk=True, router_scale=1.8, q_lora_rank=768,
         kv_lora_rank=512, qk_nope_dim=192, qk_rope_dim=64, v_head_dim=256,
+    ),
+    # GLM-5 (zai-org config.json, model_type glm_moe_dsa: 78L, 6144h, 64
+    # latent-attention heads that read the 2048 keys an indexer of 32 heads
+    # of 128 selects, three dense layers of 12288 then 256 sigmoid-routed
+    # experts of 2048, top-8, beside one shared expert)
+    "glm-5": DecoderConfig(
+        vocab_size=154880, hidden=6144, n_layers=78, n_heads=64,
+        n_kv_heads=64, head_dim=64, mlp_dim=12288, max_seq_len=202752,
+        rope_theta=1000000.0, num_experts=256, experts_per_token=8,
+        moe_impl="sorted", moe_mlp_dim=2048, shared_experts=1,
+        leading_dense_layers=3, router_score="sigmoid",
+        router_norm_topk=True, router_scale=2.5, q_lora_rank=2048,
+        kv_lora_rank=512, qk_nope_dim=192, qk_rope_dim=64, v_head_dim=256,
+        index_heads=32, index_head_dim=128, index_topk=2048,
     ),
     # LFM2-24B-A2B (LiquidAI config.json, model_type lfm2_moe: 40L, 2048h;
     # layers 2, 6, ... 38 attention of 32/8 heads of 64 with per-head q/k
@@ -622,6 +659,20 @@ PRESETS: dict[str, DecoderConfig] = {
         shared_experts=1, leading_dense_layers=1, router_score="sigmoid",
         router_norm_topk=True, router_scale=1.8, q_lora_rank=24,
         kv_lora_rank=40, qk_nope_dim=12, qk_rope_dim=8, v_head_dim=20,
+    ),
+    # GLM-5's structure as one chip of four holds it: tiny-glm's latent
+    # attention behind an indexer of 2 heads of 16 (RoPE on 8 of them) that
+    # selects 24 keys (the tests' contexts run under, at and over it), 16
+    # experts top-4 of which 4 are held
+    "tiny-glm-5": DecoderConfig(
+        vocab_size=256, hidden=64, n_layers=4, n_heads=4, n_kv_heads=4,
+        head_dim=20, mlp_dim=160, max_seq_len=256, rope_theta=1e6,
+        num_experts=16, experts_per_token=4, moe_impl="sorted",
+        moe_mlp_dim=48, shared_experts=1, leading_dense_layers=1,
+        router_score="sigmoid", router_norm_topk=True, router_scale=2.5,
+        experts_held=4, q_lora_rank=24, kv_lora_rank=40, qk_nope_dim=12,
+        qk_rope_dim=8, v_head_dim=20, index_heads=2, index_head_dim=16,
+        index_topk=24,
     ),
     # LFM2's structure: a leading dense conv layer, then two periods of
     # (attention, conv, conv, conv) with 8 sigmoid-routed experts top-2

@@ -920,6 +920,23 @@ def init_latent_attention(key, cfg: DecoderConfig):
         "wkvb": (None, "heads", "head_dim"),
         "wo": ("heads", "head_dim", "embed"),
     }
+    if cfg.index_topk:
+        # The indexer (``index_qkw``): its queries from the latent query,
+        # ONE key a token (a LayerNorm with a bias), a weight a head.
+        kqi, kki, kwi = jax.random.split(jax.random.fold_in(key, 1), 3)
+        hi, di = cfg.index_heads, cfg.index_head_dim
+        params.update({
+            "wq_idx": _init(kqi, (hi * di, q), wdt, scale=q ** -0.5),
+            "wk_idx": _init(kki, (d, di), wdt),
+            "k_idx_norm": jnp.ones((di,), wdt),
+            "k_idx_bias": jnp.zeros((di,), wdt),
+            "w_idx": _init(kwi, (d, hi), wdt),
+        })
+        specs.update({
+            "wq_idx": (None, None), "wk_idx": ("embed", None),
+            "k_idx_norm": ("norm",), "k_idx_bias": ("norm",),
+            "w_idx": ("embed", None),
+        })
     return params, specs
 
 
@@ -941,9 +958,11 @@ def _as_latent_row(parts: list, cfg: DecoderConfig) -> jax.Array:
 def latent_qkv(p: dict, x: jax.Array, positions: jax.Array,
                cfg: DecoderConfig):
     """The block's projections of ``x`` [B,S,D]: per-head queries split
-    into (q_nope [B,S,H,nope], q_rope [B,S,H,rope], rotated) and this
-    token's CACHE row [B,S,W]: ``ckv`` (r values, after its norm), then
-    ``k_rope`` (after RoPE), then zeros up to ``latent_row_width``."""
+    into (q_nope [B,S,H,nope], q_rope [B,S,H,rope], rotated), this token's
+    CACHE row [B,S,W]: ``ckv`` (r values, after its norm), then ``k_rope``
+    (after RoPE), then zeros up to ``latent_row_width``; and the latent
+    query ``cq`` [B,S,q_lora_rank] the queries were projected from (an
+    indexer's are projected from it too)."""
     dt = cfg.activation_dtype
     r = cfg.kv_lora_rank
     cq = rmsnorm(jnp.einsum("bsd,dq->bsq", x, p["wqa"].astype(dt)),
@@ -954,7 +973,96 @@ def latent_qkv(p: dict, x: jax.Array, positions: jax.Array,
     ckv = rmsnorm(kva[..., :r], p["kv_norm"], cfg)
     k_rope = rope(kva[..., None, r:], positions, cfg.rope_theta)[:, :, 0]
     return (q_nope, rope(q_rope, positions, cfg.rope_theta),
-            _as_latent_row([ckv, k_rope], cfg))
+            _as_latent_row([ckv, k_rope], cfg), cq)
+
+
+# The indexer's key norm: a LayerNorm with a bias, its own eps whatever the
+# stack's norms are (DeepSeek-V3.2-Exp's ``Indexer.k_norm``).
+INDEX_NORM_EPS = 1e-6
+
+
+def index_qkw(p: dict, x: jax.Array, cq: jax.Array, positions: jax.Array,
+              cfg: DecoderConfig):
+    """The indexer's projections of the block's input ``x`` [B,S,D] and the
+    latent query ``cq`` [B,S,q]: queries [B,S,Hi,Di] (RoPE on a head's first
+    ``qk_rope_dim`` values), this token's KEY [B,S,Di] (one for all heads:
+    LayerNorm, then RoPE on the same values; what the cache's ``idx`` plane
+    holds) and the heads' weights [B,S,Hi] float32, both constant factors
+    ``Hi ** -0.5`` and ``Di ** -0.5`` folded in."""
+    dt = cfg.activation_dtype
+    rd, theta = cfg.qk_rope_dim, cfg.rope_theta
+
+    def rotated(v):     # [B,S,H,Di]: RoPE on the first rd values
+        return jnp.concatenate(
+            [rope(v[..., :rd], positions, theta), v[..., rd:]], axis=-1)
+
+    # (one [Hi x Di, q] matrix, the latent query's values along its rows: as
+    # [q, Hi, Di] or [q, Hi x Di] the chip's compiler copies the stacked
+    # leaf whole, transposed, in front of every decode step)
+    q = jnp.einsum("bsq,nq->bsn", cq, p["wq_idx"].astype(dt))
+    q = rotated(q.reshape(*q.shape[:2], cfg.index_heads, cfg.index_head_dim))
+    k = layernorm(jnp.einsum("bsd,dk->bsk", x, p["wk_idx"].astype(dt)),
+                  p["k_idx_norm"], p["k_idx_bias"], INDEX_NORM_EPS)
+    w = jnp.einsum("bsd,dh->bsh", x, p["w_idx"].astype(dt)).astype(
+        jnp.float32) * (cfg.index_heads ** -0.5 * cfg.index_head_dim ** -0.5)
+    return q, rotated(k[:, :, None])[:, :, 0], w
+
+
+def index_scores(q: jax.Array, w: jax.Array, keys: jax.Array,
+                 positions: jax.Array) -> jax.Array:
+    """The indexer's score of every (query, key) pair in plain XLA (the
+    kernel ``ops/paged_attention.py::paged_index_scores`` computes the same
+    sums page by page): ``I(t, s) = sum_j w[t,j] ReLU(q[t,j] . keys[s])``,
+    float32, ``-inf`` where key ``s`` lies behind query ``t``'s position.
+    q [B,T,Hi,Di], w [B,T,Hi], keys [B,S,Di] (position ``s`` at index
+    ``s``), positions [B,T] -> [B,T,S]. A head at a time, so that nothing
+    ``[B,T,Hi,S]`` is alive."""
+    def head(acc, qw):
+        q_j, w_j = qw                               # [B,T,Di], [B,T]
+        dots = jnp.einsum("btd,bsd->bts", q_j, keys,
+                          preferred_element_type=jnp.float32)
+        return acc + w_j[..., None] * jax.nn.relu(dots), None
+
+    b, t = q.shape[:2]
+    total, _ = jax.lax.scan(
+        head, jnp.zeros((b, t, keys.shape[1]), jnp.float32),
+        (jnp.moveaxis(q, 2, 0), jnp.moveaxis(w, 2, 0)))
+    seen = jnp.arange(keys.shape[1], dtype=jnp.int32)[None, None, :] \
+        <= positions[:, :, None]
+    return jnp.where(seen, total, -jnp.inf)
+
+
+def select_keys(scores: jax.Array, k: int) -> jax.Array:
+    """The selection, EXACT: of each query's visible keys (``scores``
+    [..,T,S] float32, ``-inf`` where a key is not visible) the ``k`` of
+    largest score, a tie going to the lower position; every visible key
+    where there are at most ``k``. Returns a mask [..,T,S], True = attend.
+
+    No sort: what attends under a mask needs each query's ``k``-th largest
+    score alone, and that is found bit by bit: the scores' bit patterns,
+    folded so that they order as the numbers do, against a threshold built
+    from the top bit down in 32 counting passes (the largest value that at
+    least ``k`` scores reach). Scores above it are in; of those AT it the
+    first ``k - (scores above)`` by position."""
+    if k >= scores.shape[-1]:
+        return scores > -jnp.inf
+    x = jnp.where(scores == 0, 0.0, scores)         # -0.0 ties with 0.0
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    folded = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+    order = jax.lax.bitcast_convert_type(folded, jnp.uint32) \
+        ^ jnp.uint32(0x80000000)                    # unsigned, monotone in x
+
+    def bit(i, thr):
+        cand = thr | (jnp.uint32(0x80000000) >> i.astype(jnp.uint32))
+        reach = jnp.sum(order >= cand[..., None], axis=-1, dtype=jnp.int32)
+        return jnp.where(reach >= k, cand, thr)
+
+    thr = jax.lax.fori_loop(
+        0, 32, bit, jnp.zeros(scores.shape[:-1], jnp.uint32))[..., None]
+    above, ties = order > thr, order == thr
+    room = k - jnp.sum(above, axis=-1, dtype=jnp.int32, keepdims=True)
+    first = jnp.cumsum(ties, axis=-1, dtype=jnp.int32) <= room
+    return (above | (ties & first)) & (scores > -jnp.inf)
 
 
 def latent_scale(cfg: DecoderConfig) -> float:
@@ -1011,7 +1119,20 @@ def latent_attention_block(p: dict, x: jax.Array, positions: jax.Array,
             "cache holds K and V per head")
     dt = cfg.activation_dtype
     r = cfg.kv_lora_rank
-    q_nope, q_rope, row = latent_qkv(p, x, positions, cfg)
+    q_nope, q_rope, row, cq = latent_qkv(p, x, positions, cfg)
+    if cfg.index_topk:
+        # An indexer selects each query's keys: attention under a mask, in
+        # the absorbed form (the selection composes with the causal mask).
+        with jax.named_scope("dsa.index"):
+            qi, ki, wi = index_qkw(p, x, cq, positions, cfg)
+            scores = index_scores(qi, wi, ki, positions)
+        with jax.named_scope("dsa.select"):
+            mask = select_keys(scores, cfg.index_topk)
+        with jax.named_scope("dsa.attend"):
+            out = latent_absorbed_attention(p, q_nope, q_rope, row,
+                                            mask[:, None], cfg)
+        proj = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
+        return checkpoint_name(proj, "attn_out"), None
     kv = jnp.einsum("bsr,rhk->bshk", row[..., :r], p["wkvb"].astype(dt))
     k = jnp.concatenate(
         [kv[..., :cfg.qk_nope_dim],

@@ -1,6 +1,9 @@
 """Pallas TPU paged-attention kernels: decode over per-head K/V pools
-(first), latent pools and packed rows, and a chunk of queries over a latent
-pool and over per-head pools (the end of the file).
+(first), latent pools and packed rows, a chunk of queries over a latent
+pool, an indexer's scores over a row's pages and the exact selection behind
+them (``paged_index_scores``, ``paged_select_keys``: the latent kernels take
+the selection as a second mask), and a chunk of queries over per-head pools
+(the end of the file).
 
 The paged engine's XLA path reads KV twice per step: a gather materializes
 each slot's pages into the [B, S, K, D] layout, then attention reads the
@@ -473,14 +476,18 @@ def _softmax_result(l_ref, acc_ref, dtype):
 
 
 def _rows_decode_kernel(table_ref, len_ref, q_ref, *rest, page_size: int,
-                        sm_scale: float, num_planes: int):
+                        sm_scale: float, num_planes: int,
+                        selecting: bool = False):
     """One table row of decode attention over planes of whole-token rows
     ``[P, page, W]``: ONE plane whose rows are keys and values both (a
     latent pool), or a plane of K rows and a plane of V rows (packed
     heads). The row's ``[H, W]`` queries against a turn's ``n * page`` keys
     in one product; a page's values in a product of their own, whose result
     a page never copied does not reach (its buffer holds ANYTHING, and
-    ``0 x NaN`` is NaN)."""
+    ``0 x NaN`` is NaN). ``selecting``: a second mask beside the causal
+    one, ``sel_ref`` ``[1, pages, page]`` (not 0: the key is attended to),
+    a row of it a page."""
+    sel_ref, rest = (rest[0], rest[1:]) if selecting else (None, rest)
     pools, o_ref = rest[:num_planes], rest[num_planes]
     bufs = rest[num_planes + 1:2 * num_planes + 1]
     sem, side_ref, m_ref, l_ref, acc_ref = rest[2 * num_planes + 1:]
@@ -504,6 +511,10 @@ def _rows_decode_kernel(table_ref, len_ref, q_ref, *rest, page_size: int,
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)      # [H, n*pg]
         seen = _turn_seen((1, n * pg), pg, j0, mapped, length)
+        if selecting:
+            picked = jnp.concatenate(
+                [sel_ref[0, pl.ds(j0 + i, 1), :] for i in range(n)], axis=1)
+            seen = jnp.logical_and(seen, picked != 0)
         s = jnp.where(seen, s * sm_scale, NEG_INF)
         _online_softmax_step(s, v_buf.at[half], m_ref, l_ref, acc_ref,
                              kept=mapped)
@@ -520,18 +531,26 @@ def _rows_decode_kernel(table_ref, len_ref, q_ref, *rest, page_size: int,
 # through the same call.
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret", "name"),
                    inline=True)
-def _rows_decode_call(q, planes, table, lengths, *, sm_scale: float,
-                      interpret: bool, name: str):
+def _rows_decode_call(q, planes, table, lengths, selected=None, *,
+                      sm_scale: float, interpret: bool, name: str):
     b, h, w = q.shape
     page = planes[0].shape[1]
     n = _pages_a_turn(page * w * planes[0].dtype.itemsize, table.shape[1],
                       len(planes))
     kernel = functools.partial(
         _rows_decode_kernel, page_size=page, sm_scale=sm_scale,
-        num_planes=len(planes))
+        num_planes=len(planes), **(
+            {} if selected is None else {"selecting": True}))
 
     def row(bi, *_):
         return (bi, 0, 0)
+
+    picked = ()
+    if selected is not None:
+        # a row a page, and a turn of pages past the table (the last turn's
+        # loads stay inside the block)
+        picked = (jnp.pad(selected.astype(jnp.int32),
+                          ((0, 0), (0, n), (0, 0))),)
 
     return pl.pallas_call(
         kernel,
@@ -540,6 +559,7 @@ def _rows_decode_call(q, planes, table, lengths, *, sm_scale: float,
             num_scalar_prefetch=2,
             grid=(b,),
             in_specs=[pl.BlockSpec((1, h, w), row)]
+            + [pl.BlockSpec((1, *a.shape[1:]), row) for a in picked]
             + [pl.BlockSpec(memory_space=pl.ANY)] * len(planes),
             out_specs=pl.BlockSpec((1, h, w), row),
             scratch_shapes=[
@@ -557,7 +577,7 @@ def _rows_decode_call(q, planes, table, lengths, *, sm_scale: float,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(table, lengths, q, *planes)
+    )(table, lengths, q, *picked, *planes)
 
 
 def paged_latent_decode_attention(
@@ -567,13 +587,17 @@ def paged_latent_decode_attention(
     lengths: jax.Array,           # [B] position being decoded (attend <=)
     *,
     sm_scale: float,
+    selected: Optional[jax.Array] = None,   # [B, mpp, page], not 0: attend
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Absorbed decode attention over a latent page pool; returns the
     attended row [B, H, W] (the caller expands it: layers.latent_output).
-    Every page a context holds is read once, for all heads, and no other."""
+    Every page a context holds is read once, for all heads, and no other.
+    ``selected``: the keys an indexer chose for each row's query
+    (``paged_select_keys``), a second mask beside ``<= lengths``, a row of
+    it a page."""
     return _rows_decode_call(
-        q, (pool,), table, lengths, sm_scale=sm_scale,
+        q, (pool,), table, lengths, selected, sm_scale=sm_scale,
         interpret=interpret if interpret is not None else auto_interpret(),
         name="paged_latent_decode_attention")
 
@@ -622,8 +646,10 @@ CHUNK_PAGES_PER_STEP = 4
 
 
 def _latent_chunk_kernel(table_ref, start_ref, q_ref, *rest, page_size: int,
-                         sm_scale: float, num_blocks: int):
+                         sm_scale: float, num_blocks: int,
+                         selecting: bool = False):
     n = CHUNK_PAGES_PER_STEP
+    sel_ref, rest = (rest[0], rest[1:]) if selecting else (None, rest)
     page_refs, (o_ref, rows_ref, m_ref, l_ref, acc_ref) = rest[:n], rest[n:]
     j = pl.program_id(1)
     c = q_ref.shape[1]
@@ -643,7 +669,16 @@ def _latent_chunk_kernel(table_ref, start_ref, q_ref, *rest, page_size: int,
         q_pos = start + jax.lax.broadcasted_iota(jnp.int32, (c, block), 0)
         kv_pos = j * block + jax.lax.broadcasted_iota(
             jnp.int32, (c, block), 1)
-        s = jnp.where(kv_pos <= q_pos, s * sm_scale, NEG_INF)
+        seen = kv_pos <= q_pos
+        if selecting:
+            # the second mask's [C, block] of this step, laid page-major
+            # [tiles, n, tile, page]: a tile's pages side by side, the
+            # tiles one under the other
+            picked = jnp.concatenate([jnp.concatenate(
+                [sel_ref[t, i] for i in range(n)], axis=1)
+                for t in range(sel_ref.shape[0])], axis=0)
+            seen = jnp.logical_and(seen, picked != 0)
+        s = jnp.where(seen, s * sm_scale, NEG_INF)
         _online_softmax_step(s, rows, m_ref, l_ref, acc_ref)
 
     @pl.when(j == num_blocks - 1)
@@ -658,6 +693,7 @@ def paged_latent_chunk_attention(
     start: jax.Array,             # scalar int32: position of query 0
     *,
     sm_scale: float,
+    selected: Optional[jax.Array] = None,   # [tiles, pages, C / tiles, page]
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Causal absorbed attention of a chunk of ``C`` queries (positions
@@ -665,7 +701,10 @@ def paged_latent_chunk_attention(
     them (the caller writes them first); returns the attended rows
     [H, C, W]. Pages behind the chunk's last query are skipped, so the cost
     follows the context and not the table's length; a query attends to
-    positions <= its own, which are all mapped and written."""
+    positions <= its own, which are all mapped and written. ``selected``:
+    the keys an indexer chose for each query, a second mask beside the
+    causal one (not 0: attend), page-major as ``paged_select_keys`` leaves it
+    for the chunk's tiles of queries; a grid step takes its pages' part."""
     h, c, w = q.shape
     page = pool.shape[1]
     n = CHUNK_PAGES_PER_STEP
@@ -674,7 +713,15 @@ def paged_latent_chunk_attention(
                     constant_values=-1)
     kernel = functools.partial(
         _latent_chunk_kernel, page_size=page, sm_scale=sm_scale,
-        num_blocks=num_blocks)
+        num_blocks=num_blocks, selecting=selected is not None)
+    picked, picked_specs = (), []
+    if selected is not None:
+        tiles, pages, tile, _ = selected.shape
+        picked = (jnp.pad(selected, (
+            (0, 0), (0, num_blocks * n - pages), (0, 0), (0, 0))),)
+        picked_specs = [pl.BlockSpec(
+            (tiles, n, tile, page),
+            lambda hi, ji, table_ref, start_ref: (0, ji, 0, 0))]
 
     def q_map(hi, ji, table_ref, start_ref):
         return (hi, 0, 0)
@@ -689,7 +736,7 @@ def paged_latent_chunk_attention(
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(h, num_blocks),
-            in_specs=[pl.BlockSpec((1, c, w), q_map)]
+            in_specs=[pl.BlockSpec((1, c, w), q_map)] + picked_specs
             + [pl.BlockSpec((1, page, w), page_map(i)) for i in range(n)],
             out_specs=pl.BlockSpec((1, c, w), q_map),
             scratch_shapes=[
@@ -703,7 +750,274 @@ def paged_latent_chunk_attention(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret if interpret is not None else auto_interpret(),
-    )(table, jnp.reshape(start, (1,)).astype(jnp.int32), q, *([pool] * n))
+    )(table, jnp.reshape(start, (1,)).astype(jnp.int32), q, *picked,
+      *([pool] * n))
+
+
+# -- the indexer's scores over a row's live pages ---------------------------------
+#
+# ``I(t, s) = sum_j w[t, j] ReLU(q[t, j] . key[s])`` for the queries of a row
+# against every key its context holds, the keys read from the ``idx`` plane
+# where they lie (256 bytes a token at the published width) by the decode
+# kernels' walk (``_walk_live_pages``: a grid step a row of the table, its
+# live pages and no other, a turn's copies in flight behind the turn
+# before). A chunk's queries go a tile of ``INDEX_QUERY_TILE`` a grid step,
+# each tile a row of the walk whose context ends at its last query: all
+# heads of the tile head-major ``[Hi x tile, Di]`` against a page's keys in
+# one product on the matrix unit, ReLU, the heads' weights, the sum over
+# heads. The result leaves page-major, ``[pages, tile, page]``, so that a
+# page's scores are stored at an index of an untiled axis.
+
+INDEX_QUERY_TILE = 128
+# Fast memory the call may take: a tile's result over a whole table row
+# (98 pages x 128 x 128 float32: 6.4 MB, twice), its queries and weights.
+INDEX_VMEM_BYTES = 48 * 2 ** 20
+
+
+def _index_scores_kernel(table_ref, pos_ref, q_ref, w_ref, pool_ref, o_ref,
+                         buf, sem, side_ref, *, page_size: int, heads: int,
+                         tile: int):
+    b = pl.program_id(0)
+    pg = page_size
+    n, mpp = buf.shape[1], table_ref.shape[1]
+    first = pos_ref[b]              # position of the row's first query
+
+    def span(r):
+        """Row ``r``'s live pages: up to the page of its last query."""
+        return 0, jnp.clip(jax.lax.div(pos_ref[r] + tile - 1 + pg, pg),
+                           0, mpp)
+
+    o_ref[0] = jnp.full(o_ref.shape[1:], -jnp.inf, o_ref.dtype)
+
+    def scores(keys):
+        """[Hi x tile, Di] queries against ``keys`` [K, Di] -> [tile, K]."""
+        dots = jax.lax.dot_general(
+            q_ref[0], keys, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)      # [Hi*tile, K]
+        weighed = jnp.maximum(dots, 0.0) * w_ref[0]
+        if tile == 1:
+            return jnp.sum(weighed, axis=0, keepdims=True)
+        return jnp.sum(weighed.reshape(heads, tile, keys.shape[0]), axis=0)
+
+    def store(page, total):
+        """A page's [tile, pg] scores, ``-inf`` behind each query."""
+        kv_pos = page * pg + jax.lax.broadcasted_iota(
+            jnp.int32, (tile, pg), 1)
+        q_pos = first + jax.lax.broadcasted_iota(jnp.int32, (tile, pg), 0)
+        o_ref[0, page] = jnp.where(kv_pos <= q_pos, total, -jnp.inf)
+
+    def attend(half, j0, mapped):
+        if tile == 1:
+            # One query a row: a turn's pages in ONE product (a product a
+            # page would be thirty-two rows against 128 keys each time).
+            total = scores(buf[half].reshape(n * pg, buf.shape[3]))
+            for i, m in enumerate(mapped):
+                pl.when(m)(functools.partial(
+                    store, j0 + i, total[:, i * pg:(i + 1) * pg]))
+            return
+        for i, m in enumerate(mapped):
+            pl.when(m)(lambda i=i: store(j0 + i, scores(buf[half, i])))
+
+    _walk_live_pages(table_ref, span, [(pool_ref, buf)], sem, side_ref,
+                     attend)
+
+
+def paged_index_scores(
+    q: jax.Array,                 # [B, T, Hi, Di]: layers.index_qkw
+    w: jax.Array,                 # [B, T, Hi] float32
+    pool: jax.Array,              # [P, page, Di]: the pool's ``idx`` plane
+    table: jax.Array,             # [B, mpp] int32 page ids (-1 = unmapped)
+    start: jax.Array,             # [B] position of each row's first query
+    *,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """The indexer's scores of ``T`` queries a row (positions ``start[b]
+    ..``) against the keys of the row's pages, where they lie: float32,
+    ``-inf`` where a key lies behind its query, on a page the row's context
+    does not hold or on an unmapped one (what ``layers.index_scores`` gives
+    over the gathered pages). PAGE-MAJOR, as the kernel leaves them and
+    ``paged_select_keys`` takes them: ``[B x tiles, mpp, tile, page]``, a
+    tile of ``min(T, INDEX_QUERY_TILE)`` queries a row of the walk."""
+    b, t, hi, di = q.shape
+    page, mpp = pool.shape[1], table.shape[1]
+    tile = min(t, INDEX_QUERY_TILE)
+    if t % tile:
+        raise ValueError(f"{t} queries a row are no whole tiles of {tile}")
+    tiles = t // tile
+    # a tile a row of the walk: [B x tiles, Hi x tile, Di], head-major
+    rows = jnp.swapaxes(q.reshape(b, tiles, tile, hi, di), 2, 3).reshape(
+        b * tiles, hi * tile, di)
+    weights = jnp.swapaxes(
+        w.astype(jnp.float32).reshape(b, tiles, tile, hi), 2, 3).reshape(
+            b * tiles, hi * tile, 1)
+    first = (start[:, None].astype(jnp.int32) + tile * jnp.arange(
+        tiles, dtype=jnp.int32)[None, :]).reshape(-1)
+    return _index_scores_call(
+        rows, weights, pool, jnp.repeat(table, tiles, axis=0), first,
+        heads=hi,
+        interpret=interpret if interpret is not None else auto_interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "interpret"),
+                   inline=True)
+def _index_scores_call(q, w, pool, table, first, *, heads: int,
+                         interpret: bool):
+    r, rows, di = q.shape
+    tile = rows // heads
+    page, mpp = pool.shape[1], table.shape[1]
+    n = _pages_a_turn(page * di * pool.dtype.itemsize, mpp, 1)
+    kernel = functools.partial(_index_scores_kernel, page_size=page,
+                               heads=heads, tile=tile)
+
+    def row(ri, *_):
+        return (ri, 0, 0)
+
+    return pl.pallas_call(
+        kernel,
+        name="paged_index_scores",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(r,),
+            in_specs=[pl.BlockSpec((1, rows, di), row),
+                      pl.BlockSpec((1, rows, 1), row),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, mpp, tile, page),
+                                   lambda ri, *_: (ri, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, n, page, di), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),      # one a half
+                pltpu.SMEM((1,), jnp.int32),        # the half a row starts in
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((r, mpp, tile, page), jnp.float32),
+        # rows in order: a row's last turn starts the next row's copies
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=INDEX_VMEM_BYTES),
+        interpret=interpret,
+    )(table, first, q, w, pool)
+
+
+# -- the selection: each query's ``k`` best-scored keys, exactly ------------------
+#
+# ``layers.select_keys`` in fast memory, over scores laid page-major as
+# ``paged_index_scores`` leaves them (``[R, pages, tile, page]``: a page's
+# ``[tile, page]`` block at an index of an untiled axis): a grid step a tile
+# of queries, whose scores' bit patterns are folded once into integers that
+# order as the numbers do and then COUNTED against a threshold built bit by
+# bit from the top (32 passes over the tile's live pages: the largest value
+# that at least ``k`` scores reach), and the ties AT the threshold by
+# position the same way (the first ``k - (scores above)`` of them: as many
+# passes as a position has bits). No sort,
+# and nothing ``[tile, S]`` leaves fast memory but the mask.
+
+SELECT_VMEM_BYTES = 48 * 2 ** 20
+
+
+def _select_kernel(live_ref, s_ref, o_ref, f_ref, *, k: int):
+    mpp, tile, pg = f_ref.shape
+    live = live_ref[pl.program_id(0)]       # pages some query of the tile sees
+    lowest = jnp.iinfo(jnp.int32).min
+
+    def each(body, init=None):
+        return jax.lax.fori_loop(0, live, body, init)
+
+    def fold(p, _):
+        x = s_ref[0, p]
+        bits = pltpu.bitcast(jnp.where(x == 0, 0.0, x), jnp.int32)
+        f_ref[p] = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+
+    each(fold)
+
+    def count(hit):
+        """[tile, 1]: how many of a query's live scores ``hit`` (a page's
+        folded scores and the page's index -> bool) takes."""
+        acc = each(lambda p, acc: acc + hit(f_ref[p], p).astype(jnp.int32),
+                   jnp.zeros((tile, pg), jnp.int32))
+        return jnp.sum(acc, axis=1, keepdims=True)
+
+    thr = jnp.where(count(lambda f, p: f >= 0) >= k, 0, lowest)
+
+    def bit(i, thr):
+        cand = thr + jnp.left_shift(jnp.int32(1), 30 - i)
+        return jnp.where(count(lambda f, p: f >= cand) >= k, cand, thr)
+
+    thr = jax.lax.fori_loop(0, 31, bit, thr)
+    room = k - count(lambda f, p: f > thr)              # >= 1
+
+    def position(p):
+        return p * pg + jax.lax.broadcasted_iota(jnp.int32, (tile, pg), 1)
+
+    # the ties kept lie below ``ahead + 1``: ``ahead`` the largest position
+    # with fewer than ``room`` ties in front of it, bit by bit over the
+    # bits a position of this table can have
+    bits = (mpp * pg).bit_length()
+
+    def place(i, ahead):
+        cand = ahead + jnp.left_shift(jnp.int32(1), bits - 1 - i)
+        ties = count(lambda f, p: jnp.logical_and(f == thr,
+                                                  position(p) < cand))
+        return jnp.where(ties < room, cand, ahead)
+
+    ahead = jax.lax.fori_loop(0, bits, place,
+                              jnp.zeros((tile, 1), jnp.int32))
+
+    o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+    def keep(p, _):
+        f = f_ref[p]
+        kept = jnp.logical_or(f > thr, jnp.logical_and(
+            f == thr, position(p) <= ahead))
+        o_ref[0, p] = jnp.logical_and(
+            kept, s_ref[0, p] > -jnp.inf).astype(o_ref.dtype)
+
+    each(keep)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "interpret"), inline=True)
+def _select_call(scores, live, *, k: int, interpret: bool):
+    r, mpp, tile, page = scores.shape
+
+    def row(ri, *_):
+        return (ri, 0, 0, 0)
+
+    return pl.pallas_call(
+        functools.partial(_select_kernel, k=k),
+        name="dsa_select",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(r,),
+            in_specs=[pl.BlockSpec((1, mpp, tile, page), row)],
+            out_specs=pl.BlockSpec((1, mpp, tile, page), row),
+            scratch_shapes=[pltpu.VMEM((mpp, tile, page), jnp.int32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct(scores.shape, jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=SELECT_VMEM_BYTES),
+        interpret=interpret,
+    )(live, scores)
+
+
+def paged_select_keys(
+    scores: jax.Array,            # [R, pages, tile, page] float32
+    last: jax.Array,              # [R] position of each tile's last query
+    k: int,
+    *,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """``layers.select_keys`` over page-major scores
+    (``paged_index_scores``'s): for each query the mask of
+    its ``k`` largest visible scores, a tie to the lower position, every
+    visible key where there are at most ``k``; int32 ``[R, pages, tile,
+    page]``, not 0 = attend. ``last`` bounds the pages a tile's queries can
+    see; the pages behind them are not read and come back 0."""
+    r, mpp, tile, page = scores.shape
+    live = jnp.clip(last.astype(jnp.int32) // page + 1, 0, mpp)
+    return _select_call(
+        scores, live, k=int(k),
+        interpret=interpret if interpret is not None else auto_interpret())
+
 
 
 # -- a chunk of queries over per-head pools --------------------------------------

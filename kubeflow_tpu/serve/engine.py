@@ -121,6 +121,14 @@ class SamplingParams:
     stop_token: Optional[int] = None  # eos
 
 
+def _keys_selected(pos: int, real: int, topk: int) -> int:
+    """Keys an indexer of ``topk`` selects for the ``real`` queries of a
+    chunk at ``pos``: ``min(topk, t + 1)`` for the query at position
+    ``t``."""
+    whole = min(max(topk - pos, 0), real)   # queries that still see <= topk
+    return whole * pos + whole * (whole + 1) // 2 + (real - whole) * topk
+
+
 def _mode_for(params_list) -> str:
     """Static sampling mode for a dispatch (cheapest program that is exact
     for every slot in it)."""
@@ -882,6 +890,8 @@ class LLMEngine:
                    for kind in ("attention", "window", "conv",
                                 *SEQUENCE_PLANES)}
         self._state_pool_bytes = by_kind["conv"]
+        self._index_pool_bytes = int(
+            self.cache["idx"].nbytes) if "idx" in self.cache else 0
         self._kv_window_pool_bytes = by_kind["window"]
         self._kv_global_pool_bytes = by_kind["attention"]
         # The linear and ssm layers' planes, and a parallel layer's own
@@ -1336,6 +1346,9 @@ class LLMEngine:
         self._decode_steps_dispatched = 0   # lockfree: scheduler-confined counter
         self._decode_tokens_emitted = 0     # lockfree: scheduler-confined counter
         self._decode_context_tokens = 0     # lockfree: scheduler-confined counter
+        self._dsa_keys_visible = 0          # lockfree: scheduler-confined counter
+        self._dsa_keys_selected = 0         # lockfree: scheduler-confined counter
+        self._round_selected = 0            # lockfree: scheduler-confined
         self._prefill_programs_dispatched = 0   # lockfree: scheduler-confined counter
         self._prefill_chunks_dispatched = 0     # lockfree: scheduler-confined counter
         self._prefill_row_programs_dispatched = 0   # lockfree: scheduler-confined counter
@@ -1545,9 +1558,21 @@ class LLMEngine:
         units and cross layers keep nothing, but read what a layer in front
         of them computed, which none of the mechanisms above carries, and
         differential attention's paired K/V rows are not the ``[KV, Dh]``
-        those are written over."""
+        those are written over. An indexer's key a token is a second plane
+        under the latent row's page ids, which none of them carries either;
+        prefix reuse is TAKEN over it (both planes are rows a token under
+        one page id: a matched page's are both there, and ``copy_pages``
+        walks every plane). The indexer's kernels take a chunk's queries a
+        tile of ``INDEX_QUERY_TILE`` a row of their walk, so a longer chunk
+        is whole tiles (in the gathered form too: one spec serves on
+        either)."""
+        from kubeflow_tpu.ops.paged_attention import INDEX_QUERY_TILE
+
+        chunk = max(0, int(b.chunked_prefill_tokens)) or int(b.page_size)
         what = [name for name, has in (
             ("a latent (ckv) KV pool", cfg.is_latent),
+            ("an indexer whose key a token lives in the page pool beside "
+             "the latent row (the idx plane)", bool(cfg.index_topk)),
             ("convolution layers whose state lives in the page pool",
              bool(cfg.layers_of("conv"))),
             ("window layers that keep a ring of pages a sequence",
@@ -1580,6 +1605,11 @@ class LLMEngine:
             "not exist in this block)": bool(b.lora.max_adapters),
             "quantize=int8 (weight quantization)": b.quantize is not None,
             "a mesh (tensor-parallel serving)": self.mesh is not None,
+            f"chunked_prefill_tokens={chunk} (a chunk of more than "
+            f"{INDEX_QUERY_TILE} queries that is no whole number of the "
+            f"indexer's tiles of {INDEX_QUERY_TILE})":
+                bool(cfg.index_topk) and chunk > INDEX_QUERY_TILE
+                and chunk % INDEX_QUERY_TILE != 0,
             "enable_prefix_caching (prefix reuse over window layers: a "
             "ring its sequence overwrites cannot be shared)":
                 bool(cfg.layers_of("window")) and b.enable_prefix_caching,
@@ -1671,6 +1701,14 @@ class LLMEngine:
             # cache rows the dispatched steps attend to, summed over the
             # live slots and the steps of every round
             "decode_context_tokens": self._decode_context_tokens,
+            # where an indexer selects the keys attention reads
+            # (``cfg.index_topk``; 0 and 0 elsewhere): the keys every
+            # dispatched query could see (``t + 1`` for a query at position
+            # ``t``, chunk rows and decode rows alike, a layer's worth) and
+            # those of them it attends to (``min(index_topk, t + 1)``),
+            # summed on the host from the rows' positions
+            "dsa_keys_visible": self._dsa_keys_visible,
+            "dsa_keys_selected": self._dsa_keys_selected,
             # chunk-prefill programs dispatched, the prompts' chunks they
             # carried (their ratio: how often several prefills shared one
             # program) and the real tokens of those chunks, padding excluded
@@ -1704,6 +1742,9 @@ class LLMEngine:
             "kv_bytes_per_token": self._kv_bytes_per_token,
             "kv_pool_bytes": self._kv_pool_bytes,
             "state_pool_bytes": self._state_pool_bytes,
+            # of the cache's size the planes that hold an indexer's key a
+            # token beside the latent row (0 without an indexer)
+            "index_pool_bytes": self._index_pool_bytes,
             # of the cache's size the window layers' planes (a ring of
             # ``kv_window_pages_a_sequence`` pages a sequence; 0 and 0 for a
             # stack without window layers) and the global layers' (every
@@ -2191,9 +2232,21 @@ class LLMEngine:
             jnp.asarray(np.asarray(
                 [self._slot_aidx[ch.slot] for ch in group]
                 + [-1] * (rows - len(group)), np.int32)))
+        sparse = {}
+        if self.cfg.index_topk:
+            # keys the chunks' queries can see (query ``t``: ``t + 1``) and
+            # those of them the indexer selects (``min(index_topk, t + 1)``)
+            sparse = {"context": sum(
+                real * ch.pos + real * (real + 1) // 2
+                for ch, real in zip(group, reals)),
+                "selected": sum(_keys_selected(
+                    ch.pos, real, self.cfg.index_topk)
+                    for ch, real in zip(group, reals))}
+            self._dsa_keys_visible += sparse["context"]
+            self._dsa_keys_selected += sparse["selected"]
         with self._phase(prof.ENGINE_PREFILL_DISPATCH, prof.active() and {
                 "slot": group[0].slot, "pos": group[0].pos,
-                "chunks": len(group)}):
+                "chunks": len(group), **sparse}):
             if by_rows:
                 # Rows past the group are DEAD: no valid position, no page.
                 table = np.full((rows, self._mpp), -1, np.int32)
@@ -3326,6 +3379,11 @@ class LLMEngine:
             for _, s in active)
         attrs = {"round": self.decode_rounds, "k_steps": k_steps,
                  "live": len(active), "context": context}
+        if self.cfg.index_topk:
+            # of those rows the ones an indexer selects for its step
+            self._round_selected = attrs["selected"] = sum(
+                min(self.cfg.index_topk, s.length + slack + j)
+                for _, s in active for j in range(1, k_steps + 1))
         if prof.active() and self.cfg.layers_of("window"):
             # rows a window layer's steps attend to: a step at position t
             # sees min(t + 1, window) of them
@@ -3343,6 +3401,9 @@ class LLMEngine:
         self._decode_steps_dispatched += k_steps
         self._decode_rounds_at_cap += k_steps == cap
         self._decode_context_tokens += context
+        if self.cfg.index_topk:
+            self._dsa_keys_visible += context
+            self._dsa_keys_selected += self._round_selected
         self._rounds.append(_InflightRound(
             out=out, active=list(active), k_steps=k_steps,
             gap_ms=None if gap is None else gap * 1e3, round_id=round_id,

@@ -52,6 +52,7 @@ never rounded to bf16 before the PV product).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from collections import OrderedDict
 from typing import NamedTuple, Optional, Sequence
@@ -387,12 +388,18 @@ def pool_planes(cfg: DecoderConfig, kv_quant: bool = False) -> tuple:
     ranks). One padded row and not two planes of 512 and 64: the chip's
     compiler copies a 64-wide plane WHOLE, twice, around every decode
     step's row write (its tiled layout has no 64-value rows), and a page is
-    then one aligned block and one DMA for the kernels (PERF.md, PR 28)."""
+    then one aligned block and one DMA for the kernels (PERF.md, PR 28).
+    Where an indexer selects the keys (``cfg.index_topk``), a SECOND plane
+    "idx" [index_head_dim] under the same page ids: the indexer's key a
+    token (``layers.index_qkw``). Two planes and not one wider row: the
+    indexer reads its 256 bytes at EVERY position of a context, attention
+    its 1280 at the selected ones."""
     dt = cfg.activation_dtype
     if cfg.is_latent:
         if kv_quant:
             raise ValueError("int8 KV over a latent (ckv) pool")
-        return (("ckv", (L.latent_row_width(cfg),), dt),)
+        idx = (("idx", (cfg.index_head_dim,), dt),) if cfg.index_topk else ()
+        return (("ckv", (L.latent_row_width(cfg),), dt), *idx)
     kv = _kv_row(cfg)
     if cfg.kv_heads_packed:
         # Heads narrower than the 128-value lanes: all of a token's heads
@@ -1342,42 +1349,84 @@ def _latent_attention(a, h, positions, start, pools, pidx, off,  # traced
     reads each page once for all rows' heads, at several
     ``paged_latent_chunk_attention``, one call a row; "gather": the same
     sums in XLA over the gathered rows, whatever ``T``), expand the attended
-    row into values. Same return as the per-head form."""
+    row into values. Same return as the per-head form.
+
+    Where an indexer selects the keys (``cfg.index_topk``) the tokens'
+    index keys are written beside their cache rows, at the same (page,
+    offset) of the ``idx`` plane; every query is scored against every key
+    of its row's pages (``dsa.index``: ``paged_index_scores`` over the
+    pages where they lie, or the XLA form over the gathered ones), the
+    selection is made (``dsa.select``, exact: the kernel
+    ``paged_select_keys`` over scores left page-major, or
+    ``layers.select_keys``) and attention reads under it as a second mask
+    beside the causal one (``dsa.attend``), in every form alike."""
     if lora is not None:
         raise NotImplementedError("LoRA over latent attention projections")
-    t = h.shape[1]
-    q_nope, q_rope, row = L.latent_qkv(a, h, positions, cfg)
+    from kubeflow_tpu.ops import paged_attention as PA
+
+    t, pg = h.shape[1], pools["ckv"].shape[1]
+    q_nope, q_rope, row, cq = L.latent_qkv(a, h, positions, cfg)
     flat = pools["ckv"].at[pidx, off].set(row[:, 0] if t == 1 else row,
                                           mode="drop")
-    if attn_impl == "pallas" and t == 1:
-        from kubeflow_tpu.ops.paged_attention import (
-            paged_latent_decode_attention,
-        )
+    written, selected = {"ckv": flat}, None
+    b, mpp, kernels = h.shape[0], ltable.shape[1], attn_impl == "pallas"
+    if cfg.index_topk:
+        with jax.named_scope("dsa.index"):
+            qi, ki, wi = L.index_qkw(a, h, cq, positions, cfg)
+            keys = written["idx"] = pools["idx"].at[pidx, off].set(
+                ki[:, 0] if t == 1 else ki, mode="drop")
+            if kernels:     # page-major: [B x tiles, mpp, tile, pg]
+                scores = PA.paged_index_scores(qi, wi, keys, ltable, start)
+            else:
+                scores = L.index_scores(qi, wi, paged_gather(keys, ltable),
+                                        positions)
+        with jax.named_scope("dsa.select"):
+            if not kernels:
+                selected = L.select_keys(scores, cfg.index_topk)  # [B,T,S]
+            elif t == 1:
+                # the rows' single queries as ONE tile of the selection:
+                # [B, mpp, 1, pg] -> [1, mpp, B, pg] and back, [B, mpp, pg]
+                selected = jnp.swapaxes(PA.paged_select_keys(
+                    jnp.swapaxes(scores, 0, 2), jnp.max(start)[None],
+                    cfg.index_topk), 0, 2)[:, :, 0]
+            else:
+                tile = scores.shape[2]
+                last = (start[:, None] + tile * (1 + jnp.arange(
+                    t // tile, dtype=jnp.int32))[None, :] - 1).reshape(-1)
+                selected = PA.paged_select_keys(
+                    scores, last, cfg.index_topk).reshape(
+                        b, t // tile, mpp, tile, pg)
 
-        q = L.latent_query(a, q_nope[:, 0], q_rope[:, 0], cfg)   # [B,H,W]
-        o_row = paged_latent_decode_attention(
-            q, flat, ltable, start, sm_scale=L.latent_scale(cfg))
-        attn = L.latent_output(a, o_row, cfg)[:, None]
-    elif attn_impl == "pallas":
-        from kubeflow_tpu.ops.paged_attention import (
-            paged_latent_chunk_attention,
-        )
+    def chosen(r):      # the kernels' second mask, a row's or every row's
+        return None if selected is None else selected[r]
 
-        q = L.latent_query(a, q_nope, q_rope, cfg)             # [B,T,H,W]
-        o_row = jnp.stack([
-            paged_latent_chunk_attention(
-                jnp.swapaxes(q[r], 0, 1), flat, ltable[r], start[r],
-                sm_scale=L.latent_scale(cfg)) for r in range(h.shape[0])])
-        attn = L.latent_output(a, jnp.swapaxes(o_row, 1, 2), cfg)
-    else:
-        rows = paged_gather(flat, ltable)                      # [B, S, W]
-        causal = jnp.arange(rows.shape[1], dtype=jnp.int32)[
-            None, None, :] <= positions[:, :, None]            # [B, T, S]
-        attn = L.latent_absorbed_attention(
-            a, q_nope, q_rope, rows, causal[:, None], cfg)
+    with jax.named_scope("dsa.attend") if cfg.index_topk \
+            else contextlib.nullcontext():
+        if kernels and t == 1:
+            q = L.latent_query(a, q_nope[:, 0], q_rope[:, 0], cfg)  # [B,H,W]
+            o_row = PA.paged_latent_decode_attention(
+                q, flat, ltable, start, sm_scale=L.latent_scale(cfg),
+                selected=chosen(slice(None)))
+            attn = L.latent_output(a, o_row, cfg)[:, None]
+        elif kernels:
+            q = L.latent_query(a, q_nope, q_rope, cfg)         # [B,T,H,W]
+            o_row = jnp.stack([
+                PA.paged_latent_chunk_attention(
+                    jnp.swapaxes(q[r], 0, 1), flat, ltable[r], start[r],
+                    sm_scale=L.latent_scale(cfg), selected=chosen(r))
+                for r in range(b)])
+            attn = L.latent_output(a, jnp.swapaxes(o_row, 1, 2), cfg)
+        else:
+            rows = paged_gather(flat, ltable)                  # [B, S, W]
+            seen = jnp.arange(rows.shape[1], dtype=jnp.int32)[
+                None, None, :] <= positions[:, :, None]        # [B, T, S]
+            if selected is not None:
+                seen = seen & selected
+            attn = L.latent_absorbed_attention(
+                a, q_nope, q_rope, rows, seen[:, None], cfg)
     return jnp.einsum("bshk,hkd->bsd", attn,
                       a["wo"].astype(cfg.activation_dtype)), {
-                          **pools, "ckv": flat}
+                          **pools, **written}
 
 
 def _pool_forward(params: Params, cache: dict, tokens, table, start,  # traced

@@ -1027,3 +1027,102 @@ def test_assistant_program_compiles_for_v5e_with_its_kernels(cell_programs,
         result = mem.output_size_in_bytes - mem.alias_size_in_bytes
         print(f"{program}: result {result} temp {mem.temp_size_in_bytes}")
         assert logits <= result < logits + 2 ** 20, result
+
+
+# -- GLM-5's widths: the indexer's scores, the selection, the masked kernels ----
+
+def test_dsa_kernels_compile_for_v5e(chip):
+    """At the agent-context cell's shapes (64 heads over 640-wide rows, 32
+    index heads of 128, the pool viewed flat: 5 layers x 1568 pages of 128, a
+    table row of 98 pages): ``paged_index_scores`` for one query a row of 16
+    (a turn's eight pages in ONE product) and for a chunk of 512 in four
+    tiles (a tile's result over the whole row, 6.4 MB twice, over the
+    compiler's default of 16 MiB: ``INDEX_VMEM_BYTES``); ``dsa_select`` for
+    the sixteen queries as one tile and for a chunk's four; both latent
+    kernels under the selection as a second mask, page-major. What the
+    chip's compiler takes here and interpret mode cannot see: a page's
+    scores stored at an index of an UNTILED axis, the mask's pages put side
+    by side along the lanes, integer counting passes over folded bit
+    patterns."""
+    from kubeflow_tpu.ops import paged_attention as pa
+
+    slots, h, w, pages, mpp, chunk = 16, 64, 640, 5 * 1568, 98, 512
+    hi, di, tile = 32, 128, pa.INDEX_QUERY_TILE
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    i32, f32 = jnp.int32, jnp.float32
+    pool, idx = sds((pages, PAGE, w)), sds((pages, PAGE, di))
+    programs = {
+        "paged_index_scores": [
+            jax.jit(lambda *a: pa.paged_index_scores(
+                *a, interpret=False)).lower(
+                sds((rows, t, hi, di)), sds((rows, t, hi), f32), idx,
+                sds((rows, mpp), i32), sds((rows,), i32))
+            for rows, t in ((slots, 1), (1, chunk))],
+        "dsa_select": [
+            jax.jit(lambda *a: pa.paged_select_keys(
+                *a, 2048, interpret=False)).lower(
+                sds((r, mpp, t, PAGE), f32), sds((r,), i32))
+            for r, t in ((1, slots), (chunk // tile, tile))],
+        "paged_latent_decode_attention": [
+            jax.jit(lambda q, p, tb, ln, sel: pa.paged_latent_decode_attention(
+                q, p, tb, ln, sm_scale=256 ** -0.5, selected=sel,
+                interpret=False)).lower(
+                sds((slots, h, w)), pool, sds((slots, mpp), i32),
+                sds((slots,), i32), sds((slots, mpp, PAGE), i32))],
+        "paged_latent_chunk_attention": [
+            jax.jit(lambda q, p, tb, st, sel: pa.paged_latent_chunk_attention(
+                q, p, tb, st, sm_scale=256 ** -0.5, selected=sel,
+                interpret=False)).lower(
+                sds((h, chunk, w)), pool, sds((mpp,), i32), sds((), i32),
+                sds((chunk // tile, mpp, tile, PAGE), i32))],
+    }
+    for name, lowered in programs.items():
+        for one in lowered:
+            assert _calls(one.compile().as_text(), name) == 1, name
+
+
+AGENTCONTEXT = "glm-5.batch-agentcontext"
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk[1]", "mixed[2]"])
+def test_agentcontext_program_compiles_for_v5e_with_its_kernels(
+        cell_programs, program):
+    """The agent-context cell's decode step, its one-row ``[C, V]`` chunk
+    program (what the comparison drives) and the chunk program that carries
+    the slots' step (what its traffic runs: TWO rows, the engine's default
+    of two prefills at a time) at the cell's real sizes,
+    parameters as the engine holds them: each fits the chip beside its
+    arguments and runs the indexer's kernel, the selection and the masked
+    latent kernel in every layer's scan (the mixed program for BOTH groups
+    of rows). The indexer's query projection is held ``[Hi x Di, q]`` (as
+    ``[q, Hi, Di]`` and as ``[q, Hi x Di]`` the decode program copied the
+    stacked leaf whole, transposed, in front of every step: 84 MB; this
+    compile is what said so); what is still
+    copied is latent attention's own ``wqb`` and ``wkva``, as in every
+    latent model's programs (GLM-4.7-Flash's standing ``wkva``; ``wqb`` at
+    64 heads of 256 where 20 are not: PERF.md section 7)."""
+    from scripts.aot_weight_copies import weight_copies
+
+    lowered = cell_programs(AGENTCONTEXT, mixed=program.startswith("mixed"))[
+        program]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    kernels = {"decode": ("paged_latent_decode_attention",),
+               "chunk[1]": ("paged_latent_chunk_attention",),
+               "mixed[2]": ("paged_latent_decode_attention",
+                            "paged_latent_chunk_attention")}[program]
+    for kernel in ("paged_index_scores", "dsa_select", *kernels):
+        assert _calls(text, kernel) >= 1, kernel
+    copied = {leaf for c in weight_copies(text, lowered.args_info[0][0])
+              for leaf in c["leaf"]}
+    assert not any("idx" in leaf for leaf in copied), copied
+    assert copied <= {"['layers']['attn']['wqb']",
+                      "['dense_layers']['attn']['wqb']",
+                      "['layers']['attn']['wkva']",
+                      "['dense_layers']['attn']['wkva']"}, copied
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10.0e9
+    assert mem.temp_size_in_bytes < 0.6e9
