@@ -983,7 +983,9 @@ class LLMEngine:
                 _chunk_rows_fn(p, c, t, tr[None], st[None], vl[None],
                                ncp, lr, ai)),
             static_argnums=(6,), donate_argnums=(1,))
-        if not chunk_reads_context(self.cache, cfg_prefill, None, pattn):
+        one_context = not chunk_reads_context(
+            self.cache, cfg_prefill, None, pattn)
+        if one_context:
             self._paged_chunk = _OneContext(self._paged_chunk, self._mpp)
         # Whether a chunk program CARRIES the decode step (the program is
         # built further down, where its comment is): where the stack and
@@ -1004,14 +1006,19 @@ class LLMEngine:
         # vocabulary of 155k; a third of a one-row program's time and 535
         # MB at 261k). It takes SEVERAL rows only where one chunk leaves
         # the weights under-used (``chunk_rows_per_weight``), and is then
-        # dispatched at ONE static context, the whole table: a row's
-        # attention follows its own context whatever the table's length
-        # (the chunk kernels skip the pages behind their chunk; the
-        # gathered form's span ladder, layers._cached_attention_by_row),
-        # so a ladder of context buckets would spare only the gather of a
-        # pool that still takes the gathered form, and each further
-        # program is loaded and run at every start (0.75 s warm, 5 s cold
-        # on a v5e: PERF.md, PR 29).
+        # dispatched at ONE static context, the whole table, and ONE
+        # width: a row's attention follows its own context whatever the
+        # table's length (the chunk kernels skip the pages behind their
+        # chunk; the gathered form's span ladder,
+        # layers._cached_attention_by_row), so a ladder of context buckets
+        # would spare only the gather of a pool that still takes the
+        # gathered form, and each further program, a bucket's or a
+        # width's, is loaded and run at every start (0.75 s warm, 5 s cold
+        # on a v5e: PERF.md, PR 29; 5.7 s for a width of the long-context
+        # cell's: PR 49, PR 53). Rows that no due prefill fills are not
+        # left to a narrower program: where the chunk meets the pool in
+        # place they carry the NEXT chunks of the prefills that are in the
+        # program (``_ahead`` below, ``_rows_of``).
         # An engine that sends ONE chunk a program (a dense model at 512
         # tokens, over the ridge; one prefill at a time) and carries no
         # step in its chunk programs sends every chunk through it all the
@@ -1031,14 +1038,41 @@ class LLMEngine:
             self._chunk_rows = self.max_concurrent_prefills
         self._lone_at_last = tail_at_last or (
             self._chunk_rows == 1 and not self._mixed)
+        # Whether a program's spare rows take FURTHER chunks of the
+        # prefills in it (``_rows_of``): a row may be the chunk behind
+        # another row's, of the same prompt, where a layer writes every
+        # row's keys into the pool before any row attends and nothing but
+        # those keys passes from one chunk of a prompt to the next: every
+        # layer of kind "attention", met in place
+        # (``paged._pool_block``). That is what ``chunk_carries_step``
+        # tests, and ``_mixed`` with it: a layer that keeps a state, a
+        # ring or a conv tail hands on the END state of the chunk in
+        # front, which one program cannot; the gathered form (an int8
+        # pool, a call with LoRA, the "gather" arm) attends over what the
+        # pool held BEFORE the program. Observed, not set: there is one
+        # algorithm, "fill the program the pass sends", and where this is
+        # false a group is the due chunks and no more.
+        self._ahead = self._mixed and self._chunk_rows > 1
+        # What such an engine's traffic is left with for the one-row
+        # ``[C, V]`` program is a prompt's odd LAST chunk with no slot
+        # live, at any start, and a prompt sent alone, a caller's way to
+        # warm every bucket, reaches ONE bucket with it now (its other
+        # chunks go two a program). So the engine keeps that program only
+        # where it is ONE program whatever bucket a call names, and runs it
+        # under every bucket's name when it is built
+        # (``_warm_lone_program``: one load; the names are what callers
+        # outside ask ``program_kernels`` for); where it would be a program
+        # a bucket (a latent pool: 2-3 s each to trace and load at every
+        # start, PERF.md PR 56) that chunk too goes through the program of
+        # the one width, beside a dead row (``_dispatch_chunks``).
+        self._rows_only = self._ahead and not one_context
         if by_ridge or self._lone_at_last:
             self._paged_chunks = jax.jit(
                 lambda p, c, t, tr, st, vl, ends, ncp, lr=None, ai=None:
                 _chunk_rows_fn(p, c, t, tr, st, vl, ncp, lr, ai, "last",
                                ends),
                 static_argnums=(7,), donate_argnums=(1,))
-            if self._lone_at_last and not chunk_reads_context(
-                    self.cache, cfg_prefill, None, pattn):
+            if self._lone_at_last and one_context:
                 # (a group of one names its own bucket: ``_dispatch_chunks``)
                 self._paged_chunks = _OneContext(self._paged_chunks,
                                                  self._mpp, at=7)
@@ -1351,6 +1385,11 @@ class LLMEngine:
         self._round_selected = 0            # lockfree: scheduler-confined
         self._prefill_programs_dispatched = 0   # lockfree: scheduler-confined counter
         self._prefill_chunks_dispatched = 0     # lockfree: scheduler-confined counter
+        # Of a several-row program's rows: those that carried a FURTHER
+        # chunk of a prompt already in the program, and those that carried
+        # nothing.
+        self._prefill_rows_ahead = 0            # lockfree: scheduler-confined counter
+        self._prefill_rows_dead = 0             # lockfree: scheduler-confined counter
         self._prefill_row_programs_dispatched = 0   # lockfree: scheduler-confined counter
         self._prefill_programs_with_end = 0     # lockfree: scheduler-confined counter
         # Positions at which the chunk programs ran the head: a chunk's
@@ -1418,6 +1457,8 @@ class LLMEngine:
         self.stopped_clean: Optional[bool] = None
         if self._mixed or self._chunk_rows > 1:
             self._warm_rows_program()
+        if self._ahead and not self._rows_only:
+            self._warm_lone_program()
         self._warm_decode_ladder()
         # After the ladder: the state lies where a program left it, as
         # every sync of traffic's will find it.
@@ -1501,6 +1542,28 @@ class LLMEngine:
                    if self._mixed else
                    _program_key("paged_chunk_prefill", f"{rows}x{C}",
                                 self._mpp), run)
+
+    def _warm_lone_program(self) -> None:
+        """Compile or load, and run, now, the one-row ``[C, V]`` chunk
+        program under every context bucket's name a chunk can call it by,
+        on a DEAD row (nothing is written), as traffic's dispatches hand it
+        its arguments: ONE program (``_OneContext``), run once a name. An
+        engine that sends chunks ahead needs this of itself (``_ahead``,
+        ``__init__``): no caller's warm-up reaches the names any more."""
+        C = self.chunk_size
+        block = jnp.zeros((1, C), jnp.int32)
+        row = jnp.full((self._mpp,), -1, jnp.int32)
+        for ctx in sorted({context_bucket(pos, C, self.page_size, self._mpp)
+                           for pos in range(0, self.max_len,
+                                            self.page_size)}):
+            def run(ctx=ctx):
+                logits, self.cache = self._paged_chunk(
+                    self.params, self.cache, block, row, jnp.int32(0),
+                    jnp.int32(0), ctx)
+                return logits
+
+            self._warm(_program_key("paged_chunk_prefill", f"1x{C}", ctx),
+                       run)
 
     def _send_mixed(self, chunk, table, start, valid, ends,
                     mode: Optional[str] = None):
@@ -1709,12 +1772,21 @@ class LLMEngine:
             # summed on the host from the rows' positions
             "dsa_keys_visible": self._dsa_keys_visible,
             "dsa_keys_selected": self._dsa_keys_selected,
-            # chunk-prefill programs dispatched, the prompts' chunks they
-            # carried (their ratio: how often several prefills shared one
-            # program) and the real tokens of those chunks, padding excluded
+            # chunk-prefill programs dispatched, the chunks they carried, a
+            # row each (their ratio: how full the programs went) and the
+            # real tokens of those chunks, padding excluded
             "prefill_programs_dispatched": self._prefill_programs_dispatched,
             "prefill_chunks_dispatched": self._prefill_chunks_dispatched,
             "prefill_tokens_dispatched": self._prefill_tokens_dispatched,
+            # of those chunks, the ones that went AHEAD: a further chunk of
+            # a prompt that had a row in the same program already, in a row
+            # no due prefill wanted (``_rows_of``); and the rows of the
+            # several-row programs that carried nothing (behind a prompt's
+            # last chunk, or no page to be had; every spare row where the
+            # engine sends no chunk ahead). Together with the chunks: the
+            # rows of every program sent
+            "prefill_rows_ahead": self._prefill_rows_ahead,
+            "prefill_rows_dead": self._prefill_rows_dead,
             # of those programs, the ones that carried a decode step of the
             # live slots (one program an iteration where it would have been
             # two; over ``prefill_programs_dispatched``: how often), and
@@ -2185,11 +2257,17 @@ class LLMEngine:
             self.metrics.note_preempted(req.qos)
         return False    # otherwise retry next scheduler step
 
-    def _dispatch_chunks(self, group: "list[_Chunking]") -> None:
-        """ONE program for the next chunk of every prefill in ``group``
-        (their pages are reserved): row ``r`` carries ``group[r]``'s tokens,
-        table row, start, valid length and whether the chunk ends its
-        prompt (only then are the row's logits read). One prefill alone goes
+    def _dispatch_chunks(
+            self, group: "list[tuple[_Chunking, int]]") -> None:
+        """ONE program for the chunks in ``group`` (their pages are
+        reserved): row ``r`` carries the chunk of ``group[r]``'s prefill that
+        starts at ``group[r]``'s position, its tokens, table row, start,
+        valid length and whether the chunk ends its prompt (only then are
+        the row's logits read). A row is the next chunk of a prefill or, of
+        a prefill that has a row in front of it already, the chunk after
+        that one (``_rows_of``); what is counted and said of a row (the
+        span's ``context`` and ``selected``, the states started, the tails
+        written) is taken at the row's own start. One prefill alone goes
         through the program over rows as a group of one row, at its own
         context bucket, where the engine sends one chunk a program and
         carries no step in it, or its stack ends in a stateless tail
@@ -2197,7 +2275,9 @@ class LLMEngine:
         traffic runs the head at a position nobody reads. It takes the
         one-row ``[C, V]`` program only as the lone chunk of an engine that
         sends several chunks a program by the ridge, or of a mixed engine
-        with no live slot.
+        with no live slot (where that engine sends chunks ahead: its odd
+        last chunk, and only where the one-row program is ONE program
+        whatever the bucket: ``_rows_only``, ``__init__``).
 
         Where the engine built the chunk program that carries the decode
         step (``_mixed``) and a slot is live, the pass's first program
@@ -2205,8 +2285,10 @@ class LLMEngine:
         (``_ready_round``: their pages, their state's sync, as before any
         round), and says so in both dispatch spans; its rounds' tokens go
         the way every round's go (``_rounds``). That program has ONE width,
-        as many rows as the engine sends chunks together (a prefill alone
-        rides beside dead rows: one program to load, not two); such an
+        as many rows as the engine sends chunks together (one program to
+        load, not two; a prefill alone fills the rows no other prefill wants
+        with its own next chunks, and a row stays dead only behind a
+        prompt's last chunk or where the pool has no page for it); such an
         engine sends several prompts' chunks through it too, no slot
         riding."""
         C = self.chunk_size
@@ -2216,36 +2298,39 @@ class LLMEngine:
                 [(i, s) for i, s in enumerate(self.slots) if s is not None],
                 1)
         # Several chunks, or one that a step rides with, go together in the
-        # program of the engine's one width (rows past the group dead).
-        together = len(group) > 1 or ride is not None
+        # program of the engine's one width (rows past the group dead); so
+        # does every chunk where the engine keeps no one-row program for
+        # its traffic (``_rows_only``).
+        together = len(group) > 1 or ride is not None or self._rows_only
         rows = self._chunk_rows if together else 1
         by_rows = together or self._lone_at_last
-        reals = [min(C, len(ch.request.prompt_tokens) - ch.pos)
-                 for ch in group]
-        ends = [ch.pos + real == len(ch.request.prompt_tokens)
-                for ch, real in zip(group, reals)]
+        # a row: (its prefill, its start, its real tokens)
+        group = [(ch, pos, min(C, len(ch.request.prompt_tokens) - pos))
+                 for ch, pos in group]
+        ends = [pos + real == len(ch.request.prompt_tokens)
+                for ch, pos, real in group]
         chunk = np.zeros((rows, C), np.int32)
-        for r, (ch, real) in enumerate(zip(group, reals)):
-            chunk[r, :real] = ch.request.prompt_tokens[ch.pos:ch.pos + real]
+        for r, (ch, pos, real) in enumerate(group):
+            chunk[r, :real] = ch.request.prompt_tokens[pos:pos + real]
         lora = () if self._lora is None else (
             self._lora.buffers,
             jnp.asarray(np.asarray(
-                [self._slot_aidx[ch.slot] for ch in group]
+                [self._slot_aidx[ch.slot] for ch, _, _ in group]
                 + [-1] * (rows - len(group)), np.int32)))
         sparse = {}
         if self.cfg.index_topk:
             # keys the chunks' queries can see (query ``t``: ``t + 1``) and
             # those of them the indexer selects (``min(index_topk, t + 1)``)
             sparse = {"context": sum(
-                real * ch.pos + real * (real + 1) // 2
-                for ch, real in zip(group, reals)),
+                real * pos + real * (real + 1) // 2
+                for _, pos, real in group),
                 "selected": sum(_keys_selected(
-                    ch.pos, real, self.cfg.index_topk)
-                    for ch, real in zip(group, reals))}
+                    pos, real, self.cfg.index_topk)
+                    for _, pos, real in group)}
             self._dsa_keys_visible += sparse["context"]
             self._dsa_keys_selected += sparse["selected"]
         with self._phase(prof.ENGINE_PREFILL_DISPATCH, prof.active() and {
-                "slot": group[0].slot, "pos": group[0].pos,
+                "slot": group[0][0].slot, "pos": group[0][1],
                 "chunks": len(group), **sparse}):
             if by_rows:
                 # Rows past the group are DEAD: no valid position, no page.
@@ -2253,9 +2338,9 @@ class LLMEngine:
                 start = np.zeros((rows,), np.int32)
                 valid = np.zeros((rows,), np.int32)
                 wanted = np.zeros((rows,), np.bool_)
-                for r, (ch, real) in enumerate(zip(group, reals)):
+                for r, (ch, pos, real) in enumerate(group):
                     table[r] = self._table[ch.slot]
-                    start[r], valid[r], wanted[r] = ch.pos, real, ends[r]
+                    start[r], valid[r], wanted[r] = pos, real, ends[r]
             if self._mixed and together:
                 active, mode, gap, context, attrs = ride or (None,) * 5
                 with self._phase(prof.ENGINE_DECODE_DISPATCH,
@@ -2275,7 +2360,7 @@ class LLMEngine:
                     jnp.asarray(table), jnp.asarray(start),
                     jnp.asarray(valid), jnp.asarray(wanted),
                     self._mpp if rows > 1 else context_bucket(
-                        group[0].pos, C, self.page_size, self._mpp),
+                        group[0][1], C, self.page_size, self._mpp),
                     *lora)
             else:
                 # Static context bucket (next power of two covering the
@@ -2283,12 +2368,12 @@ class LLMEngine:
                 # not max_len, with a log-bounded trace set. The chunk's
                 # writes address per token off the table row, so the
                 # position may sit mid-page (the radix COW tail resume).
-                ch = group[0]
+                ch, pos, real = group[0]
                 logits, self.cache = self._paged_chunk(
                     self.params, self.cache, jnp.asarray(chunk),
-                    jnp.asarray(self._table[ch.slot]), jnp.int32(ch.pos),
-                    jnp.int32(reals[0]),
-                    context_bucket(ch.pos, C, self.page_size, self._mpp),
+                    jnp.asarray(self._table[ch.slot]), jnp.int32(pos),
+                    jnp.int32(real),
+                    context_bucket(pos, C, self.page_size, self._mpp),
                     *lora)
         self._prefill_programs_dispatched += 1
         self._prefill_row_programs_dispatched += rows > 1
@@ -2297,18 +2382,22 @@ class LLMEngine:
         self._prefill_head_positions += (
             rows * (any(ends) or ride is not None) if by_rows else C)
         self._prefill_chunks_dispatched += len(group)
-        self._prefill_tokens_dispatched += sum(reals)
+        self._prefill_rows_ahead += sum(
+            pos != ch.pos for ch, pos, _ in group)
+        self._prefill_rows_dead += rows - len(group)
+        self._prefill_tokens_dispatched += sum(
+            real for _, _, real in group)
         if self._kv_sequence_pool_bytes:
             self._state_sequences_started += sum(
-                ch.pos == 0 for ch in group)
+                pos == 0 for _, pos, _ in group)
         if self._state_pool_bytes:
             pg = self.page_size
             self._state_tail_writes += sum(
-                (ch.pos + real - 1) // pg - ch.pos // pg + 1
-                for ch, real in zip(group, reals))
-        for r, (ch, real) in enumerate(zip(group, reals)):
+                (pos + real - 1) // pg - pos // pg + 1
+                for _, pos, real in group)
+        for r, (ch, pos, real) in enumerate(group):
             req, plen = ch.request, len(ch.request.prompt_tokens)
-            ch.pos += real
+            ch.pos = pos + real
             if not ends[r]:
                 continue
             self._chunkings.remove(ch)
@@ -2323,17 +2412,44 @@ class LLMEngine:
                 (req, ch.slot, plen,
                  logits[r] if by_rows else logits[real - 1]))
 
+    def _rows_of(self, group: "list[_Chunking]"
+                 ) -> "list[tuple[_Chunking, int]]":
+        """The rows of the program that carries the due chunks of ``group``
+        (pages reserved): a (prefill, start) each, the prefills' next chunks
+        first. Where the program has rows to spare and the engine may fill
+        them (``_ahead``), they go to the chunks AFTER those, of the same
+        prefills, the first of ``group`` first (the oldest of the highest
+        class: it finishes soonest that way): ``pos + C``, ``pos + 2C``, ...
+        while the prompt has tokens left there (so the row in front is a
+        whole chunk) and its pages can be had. A further chunk whose pages
+        cannot be had leaves its row dead; it is no stall (the chunk that
+        was due has its pages) and is due itself in the next pass."""
+        rows = [(ch, ch.pos) for ch in group]
+        if not self._ahead:
+            return rows
+        C = self.chunk_size
+        for ch in group:
+            plen = len(ch.request.prompt_tokens)
+            pos = ch.pos + C
+            while len(rows) < self._chunk_rows and pos < plen \
+                    and self._ensure_pages(ch.slot, min(pos + C, plen)):
+                rows.append((ch, pos))
+                pos += C
+        return rows
+
     def _advance_chunked(self, due: "Optional[list[_Chunking]]" = None,
                          programs: Optional[int] = None) -> int:
-        """One chunk of the in-flight chunked prefills in ``due`` (all of
-        them unless given; decode steps run between calls — that's the whole
-        point), in that order, as few programs as the engine has rows for:
-        where it built the program over several prompts' chunks, they go to
-        the device together and every weight is read once for the pass. At
-        most ``programs`` programs where given: the prefills past that have
-        no turn in this pass. A prefill whose pages cannot be had waits for
-        a later pass, fills no row and holds nobody back. Returns the chunks
-        dispatched."""
+        """The next chunk of the in-flight chunked prefills in ``due`` (all
+        of them unless given; decode steps run between calls — that's the
+        whole point), in that order, as few programs as the engine has rows
+        for: where it built the program over several prompts' chunks, they
+        go to the device together and every weight is read once for the
+        pass. At most ``programs`` programs where given: the prefills past
+        that have no turn in this pass. A prefill whose pages cannot be had
+        waits for a later pass, fills no row and holds nobody back. A
+        program with rows left over carries further chunks of the prefills
+        in it (``_rows_of``): a prefill alone advances by as many chunks a
+        program as the program has rows. Returns the chunks dispatched."""
         due = list(self._chunkings) if due is None else due
         room = len(due) if programs is None else programs * self._chunk_rows
         ready: list[_Chunking] = []
@@ -2343,9 +2459,12 @@ class LLMEngine:
             ch.turn = self._admit_pass
             if self._reserve_chunk_pages(ch):
                 ready.append(ch)
+        sent = 0
         for i in range(0, len(ready), self._chunk_rows):
-            self._dispatch_chunks(ready[i:i + self._chunk_rows])
-        return len(ready)
+            rows = self._rows_of(ready[i:i + self._chunk_rows])
+            self._dispatch_chunks(rows)
+            sent += len(rows)
+        return sent
 
     def _pages_for(self, tokens: int) -> int:
         return -(-min(tokens, self.max_len) // self.page_size)

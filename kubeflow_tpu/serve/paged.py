@@ -881,6 +881,19 @@ def _pool_block(bp, xs, groups, pools, layer, num_pages: dict,  # traced
     ``decoder._block_forward``'s skeleton, the only other copy. Returns (a
     group's output each, the planes as written).
 
+    A group's ROWS may be consecutive chunks of ONE sequence (the engine's
+    rows ahead, ``LLMEngine._rows_of``: row ``r + 1`` the same table row at
+    ``start[r] + T``) where every layer is of kind "attention": the
+    operator writes EVERY row's keys into the pool (the ``idx`` plane's
+    beside a latent row) before any row attends, a row attends to nothing
+    but the pool, from key 0 of its own table to its own position, and the
+    expert layer takes its capacity a row. So the chunk behind finds, in
+    each layer, the keys of the chunk in front written by the same layer of
+    the same program, which is all causality asks, and computes what it
+    computes a program later. Not so a layer that keeps a state a
+    sequence, a ring or a conv tail: the chunk behind needs the END state
+    of the chunk in front, which the rows of one program do not hand on.
+
     ``pools`` holds every plane of the WHOLE pool viewed flat —
     ``k``/``v`` ``[L*P,pg,KV,Dh]`` (``[L*P,pg,KV*Dh]`` where the heads are
     packed) and, iff the pool stores int8, the per-token-per-head scales

@@ -5,9 +5,11 @@
 # `prefill_programs_with_end` among them), run after run in ONE call, each run
 # on the side named:
 #   scripts/head_last_chip.sh <tag> <cell> <side> <trace> <seed> [<cell> <side> <trace> <seed> ...]
-# side: "change" (the working tree) or "parent" (.parent/, unpacked with
-# `git archive <parent> | tar -x -C .parent`; it gets this tree's copy of the
-# script, which reads a checkout without the counters too). Both sides of a
+# side: "change" (the working tree), "proof" (.proof/: the committed files,
+# `git archive $(git write-tree) | tar -x -C .proof`) or "parent" (.parent/,
+# unpacked with `git archive <parent> | tar -x -C .parent`; it gets this
+# tree's copy of the script, which reads a checkout without the counters
+# too). Both sides of a
 # pair share a seed; every other run gets its own. Result lines and log tails:
 # chiprun_out/<tag>/.
 tag=$1; shift 1
@@ -18,6 +20,7 @@ n=0
 while [ $# -ge 4 ]; do
   cell=$1; side=$2; trace=$3; seed=$4; shift 4; n=$((n + 1))
   dir=$here; [ "$side" = parent ] && dir=$here/.parent
+  [ "$side" = proof ] && dir=$here/.proof
   out=$here/chiprun_out/$tag/$n.$cell.$side.s$seed.t$trace
   (cd $dir && python3 scripts/round_pacing_chip.py --workload $cell \
      --seed $seed --seconds 51 --trace $trace > $out.json 2> $out.log)

@@ -10,7 +10,11 @@ window, from the snapshots of ``LLMEngine.counters()`` the harness takes,
 the rounds, the steps a round, the rounds left at their cap and the
 scheduler's own milliseconds a round, the prefill
 programs a scheduler pass sent and the chunks a pass's budget deferred
-(ISSUE 34: ``prefill_programs_a_pass``, ``prefill_chunks_deferred``); the
+(ISSUE 34: ``prefill_programs_a_pass``, ``prefill_chunks_deferred``), the
+chunks a program carried, the rows that carried a further chunk of a prompt
+already in the program and the rows that carried nothing (ISSUE 56:
+``prefill_chunks_a_program``, ``prefill_rows_ahead``, ``prefill_rows_dead``
+and its share of the rows sent); the
 host's time a token over the window (ISSUE 37, by
 ``benchmark/phase_readers.py``'s definitions: the scheduler's share of the
 window, which the line's ``engine.sched_busy_share_window.*`` prints where it
@@ -516,6 +520,17 @@ def main() -> int:
         if d.get("prefill_passes"):         # ISSUE 34; the parent has none
             d["prefill_programs_a_pass"] = \
                 d["prefill_programs_dispatched"] / d["prefill_passes"]
+        if d.get("prefill_programs_dispatched"):
+            # ISSUE 56: how full the chunk programs went. The rows that
+            # carried a further chunk of a prompt already in the program
+            # (``prefill_rows_ahead``) and those that carried nothing
+            # (``prefill_rows_dead``) are among the deltas above; the share
+            # is of the rows sent (a checkout without them prints neither)
+            d["prefill_chunks_a_program"] = d["prefill_chunks_dispatched"] \
+                / d["prefill_programs_dispatched"]
+            if "prefill_rows_dead" in d:
+                d["prefill_rows_dead_share"] = d["prefill_rows_dead"] / (
+                    d["prefill_chunks_dispatched"] + d["prefill_rows_dead"])
         _log(f"window counters: {json.dumps(d, sort_keys=True)}")
         # ISSUE 39: a constant of the engine's load path (the parent has none)
         _log(f"weights_relaid_bytes: {b.get('weights_relaid_bytes')}")

@@ -451,6 +451,49 @@ class TestCountersAndSpans:
         assert [(a["context"], a["selected"]) for a in rounds] == [
             (t + 1, TOPK) for t in range(50, 57)]
 
+    def test_a_prompt_alone_goes_two_chunks_a_program_and_says_each_rows(
+            self, cfg, params, monkeypatch):
+        """Over the indexed pool a program's spare row carries the prompt's
+        NEXT chunk (ISSUE 56: the row behind finds the latent rows AND the
+        index keys of the row in front written): five chunks alone in three
+        programs of the one width (a latent pool's one-row program is a
+        program a bucket, so such an engine's traffic never takes it), the
+        tokens of the full forward and of one prefill at a time, and the
+        keys visible and selected counted at each row's OWN start, in the
+        spans and in the counters."""
+        kw = dict(paged_attn_impl="pallas", max_seq_len=256, decode_steps=1,
+                  prefill_interleave_steps=1, enable_prefix_caching=False,
+                  pipelined_decode=False)
+        eng = make_engine(cfg, params, **kw)
+        assert eng._ahead and eng._rows_only and eng._chunk_rows == 2
+        prompt = np.random.default_rng(5).integers(3, 256, 137).tolist()
+        spans = record_spans(monkeypatch)
+        got = greedy(eng, prompt, 6)
+        assert got == full_forward_greedy(params, cfg, prompt, 6)
+        assert got == greedy(make_engine(
+            cfg, params, max_concurrent_prefills=1, **kw), prompt, 6)
+        c = eng.counters()
+        assert [c[f"prefill_{n}"] for n in (
+            "programs_dispatched", "chunks_dispatched", "rows_ahead",
+            "rows_dead")] == [3, 5, 2, 1]   # the odd last chunk's
+        chunks = [a for n, a in spans if n == "engine.prefill_dispatch"][:3]
+        assert [(a["pos"], a["chunks"]) for a in chunks] == [
+            (0, 2), (64, 2), (128, 1)]
+
+        def seen(pos, real):
+            return real * pos + real * (real + 1) // 2
+
+        assert [(a["context"], a["selected"]) for a in chunks] == [
+            (seen(0, 32) + seen(32, 32),
+             _keys_selected(0, 32, TOPK) + _keys_selected(32, 32, TOPK)),
+            (seen(64, 32) + seen(96, 32),
+             _keys_selected(64, 32, TOPK) + _keys_selected(96, 32, TOPK)),
+            (seen(128, 9), _keys_selected(128, 9, TOPK))]
+        positions = list(range(137)) + list(range(137, 142))
+        assert c["dsa_keys_visible"] == sum(t + 1 for t in positions)
+        assert c["dsa_keys_selected"] == sum(min(TOPK, t + 1)
+                                             for t in positions)
+
     @pytest.mark.parametrize("pos,real,k", [(0, 5, 3), (0, 5, 10), (7, 4, 9),
                                             (10, 4, 3), (2, 6, 4)])
     def test_keys_selected_by_hand(self, pos, real, k):

@@ -7,7 +7,13 @@ a plain MLP, per-head planes with capacity-dispatch experts at a factor that
 DROPS chunk rows, a latent pool with a leading dense layer, sorted experts
 and a shared expert. The engine: which stacks send it, that a run's greedy
 tokens are the full recompute's, the counters, the spans, the programs'
-names, and that nothing compiles after the engine is built."""
+names, and that nothing compiles after the engine is built.
+
+A program's spare rows carry the NEXT chunks of the prompts in it (ISSUE
+56): the program, two consecutive chunks of one prompt as two rows of one
+program against one after the other; the engine, a prompt alone two chunks
+a program, what keeps a row dead, what a pass between two programs finds,
+and which stacks never send a chunk ahead."""
 
 import dataclasses
 import functools
@@ -212,6 +218,42 @@ def test_where_the_slots_do_not_ride_it_is_the_chunk_program(kind):
         np.testing.assert_array_equal(got[i], state[name], err_msg=name)
 
 
+@pytest.mark.parametrize("ride", [False, True], ids=["alone", "riding"])
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_row_may_be_the_chunk_behind_the_row_in_front(kind, ride):
+    """Two consecutive chunks of ONE prompt as the two rows of one program
+    (same table row, the second start a chunk on): every layer writes both
+    rows' keys before either attends, so the second finds the first's there.
+    The logits of the row that ends, every plane and the slots' step are
+    those of the two chunks sent one after the other (a capacity-dispatch
+    layer drops by the row, so what a chunk keeps does not depend on the
+    row beside it)."""
+    pool, table, state, rows = _scene(kind)
+    chunk, step, mixed = _programs(kind)
+    _, params = _model(kind)
+    a, row_a = rows[0][:2]
+    a = np.concatenate([a, _tokens(9, 8)])          # 24 held, 32 + 8 to go
+    first, behind = (a, row_a, 24, CHUNK, False), (a, row_a, 56, 8, True)
+    _, after = chunk(params, pool, *_rows([first, None]))
+    logits, after = chunk(params, after, *_rows([None, behind]))
+    dev = {n: jnp.asarray(v) for n, v in state.items()}
+    key = jax.random.PRNGKey(11)
+    want = (logits,) + tuple(step(
+        params, {**after, "table": jnp.asarray(table)}, dev, key)) \
+        if ride else (logits, None, after)
+    got = mixed(params, {**pool, "table": jnp.asarray(table)},
+                *_rows([first, behind]), jnp.asarray(ride), dev, key)
+    _close(got[0][1], want[0][1], "the logits of the row behind")
+    for name in want[2]:
+        _close(got[2][name], want[2][name], f"plane {name}")
+    if ride:
+        np.testing.assert_array_equal(got[1], want[1])
+    together, planes = chunk(params, pool, *_rows([first, behind]))
+    _close(together[1], logits[1], "the chunk program's own two rows")
+    for name in after:
+        _close(planes[name], after[name], f"plane {name}")
+
+
 def test_sixteen_decode_tokens_on_one_expert_lose_none():
     """A router that sends EVERY token to experts 0 and 1: a chunk row
     overflows its capacity there (the two programs drop the same pairs),
@@ -327,9 +369,14 @@ def _serve(eng, n=12, sampling=None):
     raise AssertionError("requests did not finish")
 
 
-@functools.lru_cache(maxsize=None)
 def _recompute(kind: str, j: int, n: int = 12) -> list:
-    """``n`` greedy tokens behind prompt ``j`` by full recompute: a whole
+    """``n`` greedy tokens behind prompt ``j`` by full recompute."""
+    return _recompute_behind(kind, tuple(map(int, PROMPTS[j])), n)
+
+
+@functools.lru_cache(maxsize=None)
+def _recompute_behind(kind: str, prompt: tuple, n: int) -> list:
+    """``n`` greedy tokens behind ``prompt`` by full recompute: a whole
     forward pass over what stands so far, padded to one length (causal: what
     lies behind a position cannot move it). A capacity-dispatch stack drops
     by the CHUNK a token stands in, which no whole forward pass does: its
@@ -337,20 +384,19 @@ def _recompute(kind: str, j: int, n: int = 12) -> list:
     prefill at a time)."""
     if kind == "dispatch":
         eng = _engine(kind, "gather", max_concurrent_prefills=1)
-        req = eng.submit(list(map(int, PROMPTS[j])), SamplingParams(
+        req = eng.submit(list(prompt), SamplingParams(
             max_new_tokens=n, temperature=0.0))
         while not req.done.is_set():
             eng.step()
         return list(req.output_tokens)
-    cfg, params = _model(kind)
     forward = _padded_forward(kind)
-    toks = list(map(int, PROMPTS[j]))
+    toks = list(prompt)
     for _ in range(n):
         block = np.zeros((256,), np.int32)
         block[:len(toks)] = toks
         toks.append(int(jnp.argmax(forward(jnp.asarray(block))[
             len(toks) - 1])))
-    return toks[len(PROMPTS[j]):]
+    return toks[len(prompt):]
 
 
 @functools.lru_cache(maxsize=None)
@@ -373,9 +419,13 @@ def test_engine_tokens_are_the_full_recomputes(kind, kw):
         == (0, 0)
     assert _serve(eng) == [_recompute(kind, j) for j in range(len(PROMPTS))]
     c = eng.counters()
-    # ten chunk programs; the two of the first pass find no slot live
+    # fourteen chunks in eight programs; the first two find no slot live.
+    # Prompts 2 and 4 each go alone for a while, a chunk ahead a program;
+    # prompts 0 and 4 end in an odd chunk beside a dead row
     assert (c["prefill_programs_dispatched"],
-            c["mixed_programs_dispatched"]) == (10, 8)
+            c["mixed_programs_dispatched"]) == (8, 6)
+    assert (c["prefill_chunks_dispatched"], c["prefill_rows_ahead"],
+            c["prefill_rows_dead"]) == (14, 2, 2)
     assert c["mixed_decode_rows_sum"] >= c["mixed_programs_dispatched"]
     # a program that carried a round is one round, one step, one program
     assert c["decode_rounds"] == c["decode_steps_dispatched"]
@@ -417,6 +467,9 @@ OTHER_STACKS = {
     "ssm": ("tiny-phi4flash", dict(max_seq_len=128, page_size=8,
                                    chunked_prefill_tokens=16,
                                    enable_prefix_caching=False)),
+    "parallel": ("tiny-falconh1", dict(max_seq_len=128, page_size=8,
+                                       chunked_prefill_tokens=16,
+                                       enable_prefix_caching=False)),
 }
 
 
@@ -428,7 +481,9 @@ def test_a_stack_with_another_kind_of_layer_sends_two_programs(layer):
     eng = LLMEngine(cfg, BatchingSpec(
         max_batch_size=3, max_concurrent_prefills=2, decode_steps=1,
         prefill_interleave_steps=1, paged_attn_impl="pallas", **opts))
-    assert not eng._mixed
+    # nor does a spare row ever carry a chunk ahead: the layer hands a
+    # state, a ring or a tail from a chunk's END to the next chunk's start
+    assert not eng._mixed and not eng._ahead
     sp = SamplingParams(max_new_tokens=5, temperature=0.0)
     rng = np.random.default_rng(3)
     reqs = [eng.submit([int(t) for t in rng.integers(3, 200, 40)], sp)]
@@ -443,6 +498,8 @@ def test_a_stack_with_another_kind_of_layer_sends_two_programs(layer):
     assert c["prefill_programs_dispatched"] > 0 and c["decode_rounds"] > 0
     assert (c["mixed_programs_dispatched"], c["mixed_decode_rows_sum"]) \
         == (0, 0)
+    # the first prompt went alone for its first chunks
+    assert c["prefill_rows_ahead"] == 0
 
 
 @pytest.mark.parametrize("why,kw", [
@@ -459,10 +516,11 @@ def test_what_else_keeps_two_programs(why, kw):
     if "speculative" in kw:
         kw = {"speculative": SpeculativeSpec(**kw["speculative"])}
     eng = _engine("dense", **kw)
-    assert not eng._mixed, why
+    assert not eng._mixed and not eng._ahead, why
     got = _serve(eng, n=4)
     assert [len(o) for o in got] == [4] * len(PROMPTS)
-    assert eng.counters()["mixed_programs_dispatched"] == 0
+    c = eng.counters()
+    assert (c["mixed_programs_dispatched"], c["prefill_rows_ahead"]) == (0, 0)
 
 
 @pytest.mark.parametrize("kind", KINDS)
@@ -553,3 +611,231 @@ def test_the_programs_keep_their_names(monkeypatch):
     assert not any(n.startswith("paged_chunk_prefill[2x") for n in names)
     assert [n for n in names if n.startswith("paged_mixed[")] == [
         f"paged_mixed[2x{CHUNK},greedy]"]
+
+
+# -- a program's spare rows: the next chunks of the prompts in it (ISSUE 56) -----
+
+LONE = _tokens(21, 4 * CHUNK + 9)       # five chunks, the last of 9 tokens
+
+
+def _alone(eng, prompt=LONE, n=6, **kw):
+    """``prompt`` served by itself; its greedy tokens."""
+    req = eng.submit(list(map(int, prompt)), SamplingParams(
+        max_new_tokens=n, temperature=0.0), **kw)
+    while not req.done.is_set():
+        eng.step()
+    return list(req.output_tokens)
+
+
+def _chunk_counts(eng):
+    c = eng.counters()
+    return tuple(c[f"prefill_{n}"] for n in (
+        "programs_dispatched", "chunks_dispatched", "rows_ahead",
+        "rows_dead"))
+
+
+@functools.lru_cache(maxsize=None)
+def _lone_recompute(kind: str, n: int = 6) -> tuple:
+    """``LONE``'s greedy tokens by full recompute (``_recompute_behind``),
+    which one prefill at a time, chunk by chunk, yields too."""
+    want = _recompute_behind(kind, tuple(map(int, LONE)), n)
+    assert _alone(_engine(kind, max_concurrent_prefills=1), n=n) == want
+    return tuple(want)
+
+
+def _odd_alone(kind: str) -> int:
+    """Dead rows a prompt's odd last chunk leaves with NO slot live: none
+    where the engine keeps the one-row program for it (ONE program whatever
+    the bucket: per-head planes), one where that would be a program a
+    bucket (the latent pool) and the chunk takes the program of the one
+    width too (``engine._rows_only``)."""
+    return int(kind == "latent")
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_prompt_alone_goes_two_chunks_a_program(kind):
+    """Five chunks sent alone: three programs (two of two consecutive
+    chunks, no slot riding, and one for the odd last chunk), the tokens of
+    the full recompute and of one prefill at a time."""
+    eng = _engine(kind)
+    assert eng._ahead and eng._chunk_rows == 2
+    assert eng._rows_only == (kind == "latent")
+    assert _alone(eng) == list(_lone_recompute(kind))
+    assert _chunk_counts(eng) == (3, 5, 2, _odd_alone(kind))
+    c = eng.counters()
+    assert c["prefill_row_programs_dispatched"] == 2 + _odd_alone(kind)
+    assert c["prefill_tokens_dispatched"] == len(LONE)
+    assert c["mixed_programs_dispatched"] == 0
+    eng._allocator.assert_quiescent()
+
+
+@pytest.mark.parametrize("lens,counts", [
+    # as many chunks each: every program a row each, nothing ahead
+    ((3 * CHUNK - 4, 2 * CHUNK + 7), (3, 6, 0, 0)),
+    # the longer one is alone from its third chunk on: a chunk ahead, and
+    # its odd last chunk beside a dead row, the first one's stream riding
+    ((CHUNK + 5, 4 * CHUNK + 3), (4, 7, 1, 1)),
+], ids=["as-long", "one-outlasts"])
+def test_two_prompts_due_take_a_row_each(lens, counts):
+    eng = _engine("dense")
+    prompts = [_tokens(31 + i, n) for i, n in enumerate(lens)]
+    sp = SamplingParams(max_new_tokens=20, temperature=0.0)
+    reqs = [eng.submit(list(map(int, p)), sp) for p in prompts]
+    eng.step()
+    # the first program: a row each, whatever either has left
+    assert _chunk_counts(eng) == (1, 2, 0, 0)
+    assert [ch.pos for ch in eng._chunkings] == [CHUNK, CHUNK]
+    while not all(r.done.is_set() for r in reqs):
+        eng.step()
+    assert _chunk_counts(eng) == counts
+    one = _engine("dense", max_concurrent_prefills=1)
+    assert [list(r.output_tokens) for r in reqs] == [
+        _alone(one, p, n=20) for p in prompts]
+
+
+def _beside_a_live_stream(eng, n=60):
+    """A short prompt's stream, live for ``n`` tokens: what follows finds a
+    slot riding."""
+    first = eng.submit(list(map(int, _tokens(41, 10))), SamplingParams(
+        max_new_tokens=n, temperature=0.0))
+    while first.first_token_time is None:
+        eng.step()
+    return first
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_last_chunk_alone_leaves_its_row_dead(kind):
+    """Three chunks beside a live stream: two in one program, then the last
+    with nothing behind it, a dead row in the program of the one width."""
+    eng = _engine(kind)
+    first = _beside_a_live_stream(eng)
+    assert _chunk_counts(eng) == (1, 1, 0, _odd_alone(kind))
+    prompt = LONE[:2 * CHUNK + 9]
+    got = _alone(eng, prompt)
+    assert _chunk_counts(eng) == (3, 4, 1, 1 + _odd_alone(kind))
+    assert eng.counters()["mixed_programs_dispatched"] == 2
+    assert got == _alone(_engine(kind, max_concurrent_prefills=1), prompt)
+    assert not first.done.is_set()
+
+
+def test_a_chunk_ahead_without_pages_is_no_stall_and_goes_next_pass():
+    """The pool has pages for the chunk that is due and none for the one
+    behind it: the row stays dead, nobody stalls or is preempted, and the
+    next pass sends that chunk as its due one (with the one behind IT)."""
+    eng = _engine("dense")
+    first = _beside_a_live_stream(eng)
+    held = next(i for i, s in enumerate(eng.slots) if s is not None)
+    tight, ensure = [True], eng._ensure_pages
+    eng._ensure_pages = lambda slot, upto: (
+        not (tight[0] and slot != held and upto > CHUNK)
+        and ensure(slot, upto))
+    req = eng.submit(list(map(int, LONE)), SamplingParams(
+        max_new_tokens=6, temperature=0.0))
+    eng.step()
+    (ch,) = eng._chunkings
+    assert (ch.pos, ch.stalls) == (CHUNK, 0)
+    assert _chunk_counts(eng) == (2, 2, 0, 1)
+    tight[0] = False
+    eng.step()
+    assert (ch.pos, ch.stalls) == (3 * CHUNK, 0)
+    assert _chunk_counts(eng) == (3, 4, 1, 1)
+    while not req.done.is_set():
+        eng.step()
+    assert _chunk_counts(eng) == (4, 6, 2, 1)
+    assert list(req.output_tokens) == list(_lone_recompute("dense"))
+    assert eng.metrics.preemptions == 0 and not first.done.is_set()
+
+
+def test_an_abort_between_passes_finds_the_prefill_where_the_program_left_it():
+    eng = _engine("dense")
+    req = eng.submit(list(map(int, LONE)), SamplingParams(max_new_tokens=6))
+    eng.step()
+    (ch,) = eng._chunkings
+    # two chunks written, their pages held and no more
+    assert ch.pos == 2 * CHUNK
+    assert len(eng._slot_pages[ch.slot]) == 2 * CHUNK // PAGE
+    req.cancel()
+    eng.step()
+    assert not eng._chunkings and req.done.is_set()
+    assert eng.kv_pages_in_use() == 0
+    eng._allocator.assert_quiescent()
+    # and the next prompt is served as ever
+    assert _alone(eng) == list(_lone_recompute("dense"))
+
+
+@pytest.mark.parametrize("kind", ["dense", "latent"])
+def test_a_preemption_between_passes_registers_what_the_programs_wrote(kind):
+    """A batch prompt goes alone, two chunks a program, until a second
+    prompt joins (a row each); an interactive arrival then takes its lane:
+    the prefix registered for it is every chunk a program carried, ahead or
+    due, and its second prefill starts from there."""
+    from kubeflow_tpu.core.serving import QoSSpec
+
+    eng = _engine(kind, enable_prefix_caching=True,
+                  qos=QoSSpec(preemption=True))
+    sp = SamplingParams(max_new_tokens=6, temperature=0.0)
+    prompts = [_tokens(51, 6 * CHUNK + 5), _tokens(52, 3 * CHUNK),
+               _tokens(53, CHUNK + 3)]
+    registered, register = [], eng._kv_register
+    eng._kv_register = lambda toks, slot, n: (
+        registered.append((len(toks), n)), register(toks, slot, n))[1]
+    batch = eng.submit(list(map(int, prompts[0])), sp, qos="batch")
+    eng.step()
+    assert eng._chunkings[0].pos == 2 * CHUNK           # one chunk ahead
+    other = eng.submit(list(map(int, prompts[1])), sp)
+    eng.step()
+    assert [ch.pos for ch in eng._chunkings] == [3 * CHUNK, CHUNK]
+    urgent = eng.submit(list(map(int, prompts[2])), sp, qos="interactive")
+    eng.step()
+    assert eng.metrics.preemptions == 1
+    assert registered[0] == (len(prompts[0]), 3 * CHUNK)
+    assert batch not in [ch.request for ch in eng._chunkings]
+    hits = eng.kv_tier_stats()["tokens_matched"]
+    reqs = [batch, other, urgent]
+    while not all(r.done.is_set() for r in reqs):
+        eng.step()
+    assert eng.kv_tier_stats()["tokens_matched"] >= hits + 3 * CHUNK
+    one = _engine(kind, max_concurrent_prefills=1)
+    assert [list(r.output_tokens) for r in reqs] == [
+        _alone(one, p) for p in prompts]
+    assert eng.kv_pages_in_use() == 0
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_prompt_alone_compiles_nothing_after_construction(kind):
+    """The programs a prompt alone takes are the engine's own, run when it
+    was built: the program of the one width with no slot riding and, where
+    the engine keeps it for a prompt's odd last chunk, the one-row program
+    under every bucket's name (``_warm_lone_program``: ONE program; where
+    it would be a program a bucket the engine's traffic never takes it).
+    Once the first-token sampler has run, a second lone prompt compiles
+    nothing, and neither jitted program has grown a variant."""
+    eng = _engine(kind)
+    one = getattr(eng._paged_chunk, "jitted", eng._paged_chunk)
+    sizes = (eng._paged_mixed._cache_size(), one._cache_size())
+    assert sizes == (1, 0 if eng._rows_only else 1)
+    assert {k for k in eng.start_programs() if k.startswith(
+        "paged_chunk_prefill[1x")} == (set() if eng._rows_only else {
+            f"paged_chunk_prefill[1x{CHUNK},{b}]" for b in (2, 4, 8, 16)})
+    _alone(eng)
+    compiles = CompileCounter()
+    compiles.start()
+    _alone(eng, _tokens(22, 3 * CHUNK + 1))
+    assert compiles.stop() == 0, compiles.names
+    assert (eng._paged_mixed._cache_size(), one._cache_size()) == sizes
+    assert _chunk_counts(eng) == (3 + 2, 5 + 4, 2 + 2, _odd_alone(kind))
+
+
+def test_the_span_says_the_rows_filled_and_where_the_first_starts(
+        monkeypatch):
+    from test_serve_chunk_rows import record_spans
+
+    eng = _engine("dispatch")
+    first = _beside_a_live_stream(eng)
+    seen = record_spans(monkeypatch)
+    _alone(eng)
+    chunks = [a for n, a in seen if n == "engine.prefill_dispatch"]
+    assert [(a["pos"], a["chunks"]) for a in chunks] == [
+        (0, 2), (2 * CHUNK, 2), (4 * CHUNK, 1)]
+    assert len({a["slot"] for a in chunks}) == 1
+    assert not first.done.is_set()
