@@ -1,0 +1,19 @@
+"""Utilisation of the chunk-prefill programs in the voice-turns cell, the
+cell's share of the whole step's peak: what ``step.prefill_mfu.mixedlength``
+reads (its reader, its way of counting the chunks a traced program carries),
+with the operations of THIS architecture's ``counts.prefill_flops``: 2 per
+multiplied parameter of the four published layers for every token (both
+attentions, both dense MLPs, the router, the experts at the expected rows
+held: a quarter of a held expert a token; a zero expert nothing), both
+attentions over the visible pairs (expanded form), the head ONCE a prompt. A
+last chunk's padding, the rows the sorted path gathers and never multiplies
+and the decode rows a chunk program carries are work the program chose and
+are not counted. None where the program has no such counters; 0.0 when the
+traced seconds hold no chunk prefill."""
+
+from benchmark.manifest import load_layer_metric
+
+DECLARATION = {"unit": "%", "better": "higher", "source": "device_trace",
+               "layer": "model step", "moves": "serve_tokens_per_s"}
+
+read = load_layer_metric("step.prefill_mfu.mixedlength").read

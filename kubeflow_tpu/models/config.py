@@ -3,8 +3,9 @@
 Presets cover the BASELINE.json configs (Llama-3-8B, Gemma-2B, Mixtral-8x7B)
 and the models the benchmark serves at their published widths (GLM-4.7-Flash,
 LFM2-24B-A2B, K-EXAONE-236B-A23B, Solar-Open2-250B,
-Phi-4-mini-flash-reasoning, Falcon-H1-34B-Instruct, GLM-5), plus tiny variants of
-each structure for tests.
+Phi-4-mini-flash-reasoning, Falcon-H1-34B-Instruct, GLM-5,
+LongCat-Flash-Omni's language model), plus tiny variants of each structure
+for tests.
 Architecture facts are from the public model cards and ``config.json``
 files.
 """
@@ -65,9 +66,11 @@ class DecoderConfig:
     shared_experts: int = 0
     leading_dense_layers: int = 0
     # The router's score: "softmax" (Mixtral: top-k of the logits, softmax
-    # over the chosen) or "sigmoid" (scores sigmoid(logits) in float32,
+    # over the chosen), "sigmoid" (scores sigmoid(logits) in float32,
     # CHOSEN by score plus a learned bias, WEIGHTED by the score alone,
-    # normalised over the chosen when ``router_norm_topk``, then scaled).
+    # normalised over the chosen when ``router_norm_topk``, then scaled) or
+    # "softmax_all" (the same with scores softmax(logits) over EVERY output
+    # of the router, the zero experts' among them).
     router_score: str = "softmax"
     router_norm_topk: bool = True
     router_scale: float = 1.0
@@ -81,6 +84,20 @@ class DecoderConfig:
     # left out, the shared expert runs whole.
     experts_held: int = 0
     expert_offset: int = 0
+    # Experts that compute nothing: the router's outputs ``num_experts ..
+    # num_experts + zero_experts - 1`` are the IDENTITY, so a token that
+    # chooses one gets its own normed input back, times the choice's weight
+    # (``layers._moe_sorted``: no weights, no matrix work, and on a chip that
+    # holds a share no exchange: every chip computes them where the token
+    # is). ``num_experts`` counts the experts that have weights.
+    zero_experts: int = 0
+    # The expert layer on a shortcut: EVERY block keeps a dense MLP of
+    # ``mlp_dim``, and the blocks go in pairs: the first of a pair also
+    # starts the expert layer on its normed input ``h`` (the input of its
+    # dense MLP), and the result joins the stream at the END of the second,
+    # behind that block's attention and dense MLP. ``n_layers`` counts
+    # blocks (two a published layer), an expert layer a pair.
+    moe_shortcut: bool = False
     # Latent attention (MLA; kv_lora_rank > 0): queries through a
     # ``q_lora_rank`` bottleneck, keys and values expanded per head from one
     # ``kv_lora_rank`` latent row a token, beside ``qk_rope_dim`` rotary
@@ -91,6 +108,12 @@ class DecoderConfig:
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
     v_head_dim: int = 0
+    # ``latent_rank_scale``: two constant factors on what leaves the
+    # bottlenecks, ``sqrt(hidden / q_lora_rank)`` on the per-head queries
+    # and ``sqrt(hidden / kv_lora_rank)`` on the normed latent before its
+    # expansion into keys and values (``layers.latent_qkv``; the rotary key
+    # is not scaled).
+    latent_rank_scale: bool = False
     # A learned indexer that SELECTS the keys latent attention reads
     # (DeepSeek sparse attention; ``index_topk`` 0 = none, every key is
     # read): ``index_heads`` query heads of ``index_head_dim`` values from
@@ -292,6 +315,16 @@ class DecoderConfig:
                     f"{kind!r} layers stand behind every layer that keeps "
                     f"state and read the last {source!r} layer in front of "
                     f"them; the stack is {kinds}")
+        if self.zero_experts and not self.is_moe:
+            raise ValueError("zero experts are outputs of an expert layer's "
+                             "router: num_experts > 0")
+        if self.moe_shortcut and not (
+                self.is_moe and self.n_layers % 2 == 0
+                and not self.layer_kinds and not self.leading_dense_layers):
+            raise ValueError(
+                "an expert layer on a shortcut rides beside the dense MLPs "
+                "of a PAIR of attention blocks: num_experts > 0, an even "
+                "n_layers, no layer_kinds, no leading dense layers")
         if self.experts_held and self.num_experts and not (
                 0 <= self.expert_offset
                 and self.expert_offset + self.experts_held
@@ -302,8 +335,9 @@ class DecoderConfig:
 
     @property
     def period(self) -> tuple:
-        """One period of the stack's pattern of layer kinds."""
-        return self.layer_kinds or ("attention",)
+        """One period of the stack's pattern of layer kinds (under a
+        shortcut the pair of blocks one expert layer spans)."""
+        return self.layer_kinds or ("attention",) * (1 + self.moe_shortcut)
 
     @property
     def kinds(self) -> tuple:
@@ -364,6 +398,17 @@ class DecoderConfig:
     def experts_here(self) -> int:
         """The experts whose weights this layer holds."""
         return self.experts_held or self.num_experts
+
+    @property
+    def router_width(self) -> int:
+        """The router's outputs: every expert with weights, held or not,
+        and the zero experts behind them."""
+        return self.num_experts + self.zero_experts
+
+    def expert_layer(self, block: int) -> int:
+        """The expert layer, counted among its group's, that block ``block``
+        of the group holds (under a shortcut: starts or joins)."""
+        return block // 2 if self.moe_shortcut else block
 
     def _conv_params(self) -> int:
         """One conv block's operator: in and out projections and the taps."""
@@ -467,10 +512,10 @@ class DecoderConfig:
         per_expert = 3 * d * self.expert_mlp_dim
         if active:      # the router's small product is left out, as before
             met = self.experts_per_token * self.experts_here \
-                / self.num_experts
+                / self.router_width
             return int((met + self.shared_experts) * per_expert)
-        routing = d * self.num_experts + (
-            self.num_experts if self.router_score == "sigmoid" else 0)
+        routing = self.router_width * (
+            d + (self.router_score != "softmax"))       # the choice's bias
         return (self.experts_here + self.shared_experts) * per_expert \
             + routing
 
@@ -481,9 +526,12 @@ class DecoderConfig:
         d, v = self.hidden, self.vocab_size
         k = self.leading_dense_layers
         norm = d * (2 if self.norm_kind == "layer" else 1)  # weight (, bias)
+        if self.moe_shortcut:   # a dense MLP a block, an expert layer a pair
+            k = self.n_layers
         layers = self._operator_params(0, self.n_layers) \
-            + (self.n_layers - k) * (self._mlp_params(False) + 2 * norm) \
-            + k * (3 * d * self.mlp_dim + 2 * norm)
+            + self.expert_layer(self.n_layers - self.leading_dense_layers) \
+            * self._mlp_params(False) + self.n_layers * 2 * norm \
+            + k * 3 * d * self.mlp_dim
         embed = v * d if self.tie_embeddings else 2 * v * d
         return layers + embed + norm
 
@@ -493,9 +541,10 @@ class DecoderConfig:
         every expert is held, the expected share of them on a chip that holds
         ``experts_held``)."""
         d = self.hidden
-        k = self.leading_dense_layers
+        k = self.n_layers if self.moe_shortcut else self.leading_dense_layers
         dense_n = self._operator_params(0, self.n_layers) \
-            + (self.n_layers - k) * self._mlp_params(True) \
+            + self.expert_layer(self.n_layers - self.leading_dense_layers) \
+            * self._mlp_params(True) \
             + k * 3 * d * self.mlp_dim + self.vocab_size * d
         return 6.0 * dense_n
 
@@ -552,6 +601,24 @@ PRESETS: dict[str, DecoderConfig] = {
         router_norm_topk=True, router_scale=2.5, q_lora_rank=2048,
         kv_lora_rank=512, qk_nope_dim=192, qk_rope_dim=64, v_head_dim=256,
         index_heads=32, index_head_dim=128, index_topk=2048,
+    ),
+    # LongCat-Flash-Omni's language model (meituan-longcat config.json;
+    # LongCat-Flash technical report: 28 published layers, 6144h, each TWO
+    # latent attentions of 64 heads behind ranks 1536 / 512 (both rank
+    # factors) and TWO dense MLPs of 12288, so 56 blocks here, with ONE
+    # expert layer a published layer on a shortcut beside them: a softmax
+    # router over 512 experts of 2048 and 256 that are the identity, top-12
+    # by score plus a bias, weights 6 x the score, not normalised; untied
+    # head. The audio and vision towers and the codec decoder are not built)
+    "longcat-flash-omni": DecoderConfig(
+        vocab_size=131072, hidden=6144, n_layers=56, n_heads=64,
+        n_kv_heads=64, head_dim=128, mlp_dim=12288, max_seq_len=131072,
+        rope_theta=1e7, norm_eps=1e-5, num_experts=512, zero_experts=256,
+        experts_per_token=12, moe_impl="sorted", moe_mlp_dim=2048,
+        moe_shortcut=True, router_score="softmax_all",
+        router_norm_topk=False, router_scale=6.0, q_lora_rank=1536,
+        kv_lora_rank=512, qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128,
+        latent_rank_scale=True,
     ),
     # LFM2-24B-A2B (LiquidAI config.json, model_type lfm2_moe: 40L, 2048h;
     # layers 2, 6, ... 38 attention of 32/8 heads of 64 with per-head q/k
@@ -673,6 +740,21 @@ PRESETS: dict[str, DecoderConfig] = {
         experts_held=4, q_lora_rank=24, kv_lora_rank=40, qk_nope_dim=12,
         qk_rope_dim=8, v_head_dim=20, index_heads=2, index_head_dim=16,
         index_topk=24,
+    ),
+    # LongCat-Flash's structure as one chip of four holds it: 2 published
+    # layers (4 blocks of tiny-glm's latent attention, both rank factors,
+    # and a dense MLP each; an expert layer a pair on its shortcut), a
+    # softmax router over 16 experts and 8 that are the identity, top-4, of
+    # which 4 are held
+    "tiny-longcat-flash": DecoderConfig(
+        vocab_size=256, hidden=64, n_layers=4, n_heads=4, n_kv_heads=4,
+        head_dim=20, mlp_dim=160, max_seq_len=256, rope_theta=1e7,
+        num_experts=16, zero_experts=8, experts_per_token=4,
+        moe_impl="sorted", moe_mlp_dim=48, moe_shortcut=True,
+        router_score="softmax_all", router_norm_topk=False,
+        router_scale=6.0, experts_held=4, q_lora_rank=24, kv_lora_rank=40,
+        qk_nope_dim=12, qk_rope_dim=8, v_head_dim=20,
+        latent_rank_scale=True,
     ),
     # LFM2's structure: a leading dense conv layer, then two periods of
     # (attention, conv, conv, conv) with 8 sigmoid-routed experts top-2

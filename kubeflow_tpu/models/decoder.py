@@ -77,19 +77,33 @@ def _init_operator(key, cfg: DecoderConfig, kind: str):
     return init(jax.random.split(key)[0], cfg)
 
 
-def _init_ffn(key, cfg: DecoderConfig):
+def _init_started(key, cfg: DecoderConfig):
+    """The expert layer the first block of a pair starts under a shortcut
+    (``cfg.moe_shortcut``), the block's "moe" beside its dense "mlp"."""
+    return L.init_moe(jax.random.fold_in(key, 2), cfg)
+
+
+def _init_ffn(key, cfg: DecoderConfig, starts: bool = False):
     """What every kind of block has beside its operator: the feed-forward
-    (or expert) layer and the two norms."""
+    (or expert) layer and the two norms. Under a shortcut the feed-forward
+    is the dense MLP, and a block that ``starts`` an expert layer has that
+    too."""
     k_mlp = jax.random.split(key)[1]
-    mlp_p, mlp_s = (L.init_moe if cfg.is_moe else L.init_mlp)(k_mlp, cfg)
+    dense = not cfg.is_moe or cfg.moe_shortcut
+    mlp_p, mlp_s = (L.init_mlp if dense else L.init_moe)(k_mlp, cfg)
     ln1, ln1_s = L.init_norm(cfg, "ln1")
     ln2, ln2_s = L.init_norm(cfg, "ln2")
-    return ({"mlp": mlp_p, **ln1, **ln2}, {"mlp": mlp_s, **ln1_s, **ln2_s})
+    params, specs = {"mlp": mlp_p, **ln1, **ln2}, {"mlp": mlp_s, **ln1_s,
+                                                   **ln2_s}
+    if starts:
+        params["moe"], specs["moe"] = _init_started(key, cfg)
+    return params, specs
 
 
-def _init_block(key, cfg: DecoderConfig, kind: str = "attention"):
+def _init_block(key, cfg: DecoderConfig, kind: str = "attention",
+                starts: bool = False):
     op_p, op_s = _init_operator(key, cfg, kind)
-    ffn_p, ffn_s = _init_ffn(key, cfg)
+    ffn_p, ffn_s = _init_ffn(key, cfg, starts)
     return ({OPERATOR[kind]: op_p, **ffn_p}, {OPERATOR[kind]: op_s, **ffn_s})
 
 
@@ -217,33 +231,46 @@ def split_dense_stack(stack, gcfg: DecoderConfig):
     LAYER inside the body (``unit_blocks(whole=, u=)``), each use is one
     slice of the stack that fuses into its product, as a scan over alike
     layers gives it. (An expert layer's stack has its own way:
-    ``layers.split_expert_stack``; those groups stay as they were.)"""
+    ``layers.split_expert_stack``; those groups stay as they were.) Under a
+    shortcut a scan unit is a PAIR of alike blocks, and every leaf of a
+    block (its attention, its dense "mlp", its norms) is taken whole: the
+    scan keeps the pair's one expert layer alone (scanned as a pair the
+    decode step copied 1.9 GB of weights out of their stacks, 13 of its 27
+    ms: my chip run, PR 57)."""
+    if gcfg.moe_shortcut:
+        return ({k: v for k, v in stack.items() if k == "moe"},
+                {k: v for k, v in stack.items() if k != "moe"})
     if len(gcfg.period) == 1 or gcfg.is_moe:
         return stack, None
-    return {k: v for k, v in stack.items() if k != "mlp"}, stack["mlp"]
+    return {k: v for k, v in stack.items() if k != "mlp"}, \
+        {"mlp": stack["mlp"]}
 
 
 def unit_blocks(unit, gcfg: DecoderConfig, whole=None, u=None) -> list:
     """The layers of one scan unit, in order: (kind, the layer's place among
     the unit's layers of its kind, its block's parameters). ``unit``: one
     iteration's slice of ``period_units``; ``whole`` / ``u``
-    (``split_dense_stack``): the feed-forward leaves not scanned and the
-    iteration's index."""
+    (``split_dense_stack``): the leaves not scanned, by their key in a
+    block, and the iteration's index. Under a shortcut the unit is a pair of
+    blocks and its one expert layer ("moe") is the FIRST block's, which
+    starts it."""
     period = gcfg.period
     if len(period) == 1:
         return [(period[0], 0, unit)]
     out, seen = [], {}
     for j, kind in enumerate(period):
         i = seen[kind] = seen.get(kind, -1) + 1
+        op = OPERATOR[kind]
         out.append((kind, i, {
+            **({"moe": jax.tree.map(lambda a: a[0], unit["moe"])}
+               if j == 0 and "moe" in unit else {}),
             **{n: jax.tree.map(lambda a, j=j: a[j], unit[n])
-               for n in ("mlp", "ln1", "ln2",
-                         *(m for m in ("ln1_b", "ln2_b") if m in unit))
+               for n in ("mlp", "ln1", "ln2", "ln1_b", "ln2_b")
                if n in unit},
-            **({} if whole is None else {"mlp": jax.tree.map(
-                lambda a, j=j: a[u * len(period) + j], whole)}),
-            OPERATOR[kind]: jax.tree.map(lambda a, i=i: a[i],
-                                         unit[OPERATOR[kind]])}))
+            **{n: jax.tree.map(lambda a, j=j: a[u * len(period) + j], leaves)
+               for n, leaves in (whole or {}).items()},
+            **({op: jax.tree.map(lambda a, i=i: a[i], unit[op])}
+               if op in unit else {})}))
     return out
 
 
@@ -252,7 +279,11 @@ def _init_group(keys, gcfg: DecoderConfig):
     layer."""
     kinds = gcfg.kinds
     if len(set(kinds)) == 1:
-        return jax.vmap(lambda k: _init_block(k, gcfg, kinds[0])[0])(keys)
+        stack = jax.vmap(lambda k: _init_block(k, gcfg, kinds[0])[0])(keys)
+        if gcfg.moe_shortcut:   # an expert layer a pair, by its first block
+            stack["moe"] = jax.vmap(
+                lambda k: _init_started(k, gcfg)[0])(keys[::2])
+        return stack
     stack = jax.vmap(lambda k: _init_ffn(k, gcfg)[0])(keys)
     for kind in sorted(set(kinds)):
         own = jnp.asarray([i for i, x in enumerate(kinds) if x == kind])
@@ -273,8 +304,10 @@ def init_decoder_params(key: jax.Array, cfg: DecoderConfig) -> Params:
             # Stack per-layer params on a leading axis via vmapped init.
             stacks[name] = _init_group(keys, gcfg)
         else:
-            stacks[name] = [_init_block(k, gcfg, kind)[0]
-                            for k, kind in zip(keys, gcfg.kinds)]
+            stacks[name] = [
+                _init_block(k, gcfg, kind,
+                            gcfg.moe_shortcut and i % 2 == 0)[0]
+                for i, (k, kind) in enumerate(zip(keys, gcfg.kinds))]
         if cfg.diff_attention:
             _set_lambda_init(stacks[name], gcfg, first)
 
@@ -308,7 +341,8 @@ def _block_specs(cfg: DecoderConfig, kind: str = "attention"):
     captured = {}
 
     def _shape_only():
-        params, specs = _init_block(jax.random.PRNGKey(0), cfg, kind)
+        params, specs = _init_block(jax.random.PRNGKey(0), cfg, kind,
+                                    cfg.moe_shortcut)
         captured["specs"] = specs
         return params
 
@@ -334,8 +368,11 @@ def decoder_param_specs(cfg: DecoderConfig) -> Params:
                       for k, v in specs.items()}
             stacks[name] = jax.tree.map(stack_spec, merged,
                                         is_leaf=_is_spec_leaf)
-        else:
-            stacks[name] = [by_kind[kind] for kind in gcfg.kinds]
+        else:   # (under a shortcut a pair's first block alone has "moe")
+            stacks[name] = [
+                {k: v for k, v in by_kind[kind].items()
+                 if k != "moe" or i % 2 == 0}
+                for i, kind in enumerate(gcfg.kinds)]
 
     specs: Params = {
         "embed": ("vocab", "embed_table"),
@@ -358,6 +395,13 @@ def _block_forward(block_params, x, positions, cfg: DecoderConfig,
     # layer's scan output, "k" / "v": what the last attention layer attended
     # over} (``decoder_forward``).
     x, shared = x if isinstance(x, tuple) else (x, None)
+    # Under a shortcut (``cfg.moe_shortcut``) what rides beside ``x`` is the
+    # result of the expert layer the block in front STARTED: it rides from
+    # the first block of a pair to the second alone, and joins the stream
+    # there, behind that block's attention and dense MLP.
+    joining = None
+    if cfg.moe_shortcut:
+        joining, shared = shared, None
     h = L.rmsnorm(x, block_params["ln1"], cfg, mesh=mesh,
                   bias=block_params.get("ln1_b"))
     if set(block_params) & {"ssm", "gmu", "cross", "parallel"} and (
@@ -450,17 +494,24 @@ def _block_forward(block_params, x, positions, cfg: DecoderConfig,
     # single pass over the stream (layers.add_rmsnorm).
     x, h = L.add_rmsnorm(x, attn_out, block_params["ln2"], cfg, mesh=mesh,
                          bias=block_params.get("ln2_b"))
-    if cfg.is_moe:
-        mlp_out, aux = L.moe_block(block_params["mlp"], h, cfg,
-                                   expert_axis=expert_axis, seq_axis=seq_axis,
-                                   valid_len=valid_len, tp_axis=tp_axis,
-                                   expert_stack=expert_stack,
-                                   capacity_per_row=moe_capacity_per_row)
+    def experts(at: str):
+        return L.moe_block(block_params[at], h, cfg,
+                           expert_axis=expert_axis, seq_axis=seq_axis,
+                           valid_len=valid_len, tp_axis=tp_axis,
+                           expert_stack=expert_stack,
+                           capacity_per_row=moe_capacity_per_row)
+
+    if cfg.is_moe and not cfg.moe_shortcut:
+        mlp_out, aux = experts("mlp")
     else:
         mlp_out, aux = (L.mlp_block(block_params["mlp"], h, cfg,
                                     tp_axis=tp_axis, mesh=mesh),
                         jnp.float32(0))
+    if "moe" in block_params:   # started on ``h``, joined a block later
+        shared, aux = experts("moe")
     x = x + mlp_out
+    if joining is not None:
+        x = x + joining
     if mesh is not None:
         x = with_logical_constraint(x, ("batch", "act_seq", "act_embed"), mesh, rules)
     return (x if shared is None else (x, shared)), new_cache, aux
@@ -554,7 +605,8 @@ def _run_layers(layers, x, positions, cfg: DecoderConfig, planes: tuple,
                     cache_of(kind, unit_planes if p == 1
                              else tuple(pl[i] for pl in unit_planes)),
                     L.layer_view(lora, lora_sl),
-                    None if experts is None else (experts, u * p + j))
+                    None if experts is None
+                    else (experts, cfg.expert_layer(u * p + j)))
                 written_by(new_cache, kind, out)
                 aux_sum = aux_sum + aux
             return carry, (tuple(out[n][0] if p == 1 else jnp.stack(out[n])
@@ -660,10 +712,11 @@ def decoder_forward(
                 "pipeline parallelism computes contiguous positions inside "
                 "the stage (1F1B streams inexact leaves only); custom "
                 "positions are not supported under pp>1")
-        if len(groups) > 1:
+        if len(groups) > 1 or cfg.moe_shortcut:
             raise NotImplementedError(
                 "pipeline parallelism stages one stack of alike layers; "
-                "leading dense layers are not supported under pp>1")
+                "leading dense layers and an expert layer on a shortcut "
+                "are not supported under pp>1")
         # Pipeline parallelism: the layer stack is staged over the
         # ``pipeline`` mesh axis and microbatches stream through via
         # ppermute (parallel/pipeline.py). Decode (kv_caches) stays on the

@@ -962,15 +962,29 @@ def latent_qkv(p: dict, x: jax.Array, positions: jax.Array,
     CACHE row [B,S,W]: ``ckv`` (r values, after its norm), then ``k_rope``
     (after RoPE), then zeros up to ``latent_row_width``; and the latent
     query ``cq`` [B,S,q_lora_rank] the queries were projected from (an
-    indexer's are projected from it too)."""
+    indexer's are projected from it too).
+
+    ``cfg.latent_rank_scale``: the queries times ``sqrt(hidden /
+    q_lora_rank)`` and the normed latent times ``sqrt(hidden /
+    kv_lora_rank)``. The latent's factor stands in front of its expansion
+    into keys and values; it is applied HERE, when the row is written, so
+    the cache holds the scaled latent and every reader (``latent_query``,
+    ``latent_output``, the kernels) is what it was."""
     dt = cfg.activation_dtype
     r = cfg.kv_lora_rank
     cq = rmsnorm(jnp.einsum("bsd,dq->bsq", x, p["wqa"].astype(dt)),
                  p["q_norm"], cfg)
     q = jnp.einsum("bsq,qhk->bshk", cq, p["wqb"].astype(dt))
+    kv_norm = p["kv_norm"]
+    if cfg.latent_rank_scale:
+        # (in float32, each rounded once: the latent's factor rides in its
+        # norm's weight)
+        q = (q.astype(jnp.float32)
+             * (cfg.hidden / cfg.q_lora_rank) ** 0.5).astype(dt)
+        kv_norm = kv_norm.astype(jnp.float32) * (cfg.hidden / r) ** 0.5
     q_nope, q_rope = q[..., :cfg.qk_nope_dim], q[..., cfg.qk_nope_dim:]
     kva = jnp.einsum("bsd,dr->bsr", x, p["wkva"].astype(dt))
-    ckv = rmsnorm(kva[..., :r], p["kv_norm"], cfg)
+    ckv = rmsnorm(kva[..., :r], kv_norm, cfg)
     k_rope = rope(kva[..., None, r:], positions, cfg.rope_theta)[:, :, 0]
     return (q_nope, rope(q_rope, positions, cfg.rope_theta),
             _as_latent_row([ckv, k_rope], cfg), cq)
@@ -1403,7 +1417,7 @@ def mlp_block(p: dict, x: jax.Array, cfg: DecoderConfig,
 
 def init_moe(key, cfg: DecoderConfig):
     kr, kg, ku, kd = jax.random.split(key, 4)
-    d, m, e = cfg.hidden, cfg.expert_mlp_dim, cfg.num_experts
+    d, m, e = cfg.hidden, cfg.expert_mlp_dim, cfg.router_width
     eh = cfg.experts_here       # the router scores all, the stack holds these
     params = {
         "router": _init(kr, (d, e), cfg.weight_dtype),
@@ -1417,7 +1431,7 @@ def init_moe(key, cfg: DecoderConfig):
         "up": ("expert", "embed", "expert_mlp"),
         "down": ("expert", "expert_mlp", "embed"),
     }
-    if cfg.router_score == "sigmoid":
+    if cfg.router_score != "softmax":
         # The correction bias moves the CHOICE of experts and never their
         # weights; it is balanced outside the loss, so it starts at zero.
         params["router_bias"] = jnp.zeros((e,), jnp.float32)
@@ -1444,7 +1458,9 @@ def route(p: dict, xf: jax.Array, cfg: DecoderConfig):
     "sigmoid": ``s = sigmoid(x Wr)`` computed in float32; the top-k of
     ``s + b`` (``b`` the correction bias) are CHOSEN, the weights are ``s``
     of the chosen WITHOUT ``b``, divided by their sum when
-    ``router_norm_topk``, times ``router_scale``."""
+    ``router_norm_topk``, times ``router_scale``. "softmax_all": the same
+    with ``s = softmax(x Wr)`` over ALL ``E`` outputs (``cfg.router_width``:
+    the zero experts' too)."""
     k = cfg.experts_per_token
     if cfg.router_score == "softmax":
         logits = jnp.einsum(
@@ -1452,12 +1468,13 @@ def route(p: dict, xf: jax.Array, cfg: DecoderConfig):
             p["router"].astype(cfg.activation_dtype)).astype(jnp.float32)
         top_logits, idx = jax.lax.top_k(logits, k)
         return logits, idx, jax.nn.softmax(top_logits, axis=-1)
-    if cfg.router_score != "sigmoid":
+    if cfg.router_score not in ("sigmoid", "softmax_all"):
         raise ValueError(f"unknown router_score {cfg.router_score!r}")
     logits = jnp.einsum("...d,de->...e", xf.astype(jnp.float32),
                         p["router"].astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    s = jax.nn.sigmoid(logits)
+    s = jax.nn.sigmoid(logits) if cfg.router_score == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
     _, idx = jax.lax.top_k(s + p["router_bias"].astype(jnp.float32), k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if cfg.router_norm_topk:
@@ -1476,13 +1493,15 @@ def split_expert_stack(layers: dict, cfg: DecoderConfig):
     would be COPIED out for every layer of every step (1.2 GB a layer at 64
     experts of 2048 x 1536). So those leaves stay whole, viewed
     [L*E, ...], and the block addresses its layer's experts as groups
-    ``layer*E ..`` (``_moe_sorted``). (.., None) for every other model."""
+    ``layer*E ..`` (``_moe_sorted``). (.., None) for every other model.
+    Under a shortcut the expert layer is the group's "moe", beside every
+    block's dense "mlp"."""
     if not (cfg.is_moe and cfg.moe_impl == "sorted"):
         return layers, None
-    mlp = layers["mlp"]
-    whole = {k: mlp[k] for k in EXPERT_LEAVES}
-    return {**layers, "mlp": {k: v for k, v in mlp.items()
-                              if k not in whole}}, whole
+    at = "moe" if cfg.moe_shortcut else "mlp"
+    whole = {k: layers[at][k] for k in EXPERT_LEAVES}
+    return {**layers, at: {k: v for k, v in layers[at].items()
+                           if k not in whole}}, whole
 
 
 def moe_block(p: dict, x: jax.Array, cfg: DecoderConfig,
@@ -1527,7 +1546,9 @@ def moe_block(p: dict, x: jax.Array, cfg: DecoderConfig,
     A layer that holds a SHARE of its experts (``cfg.experts_held``) is the
     sorted path's alone. ``rows_out`` (static): a third result, int32 [2]:
     the (token, choice) rows this call routed and those of them whose expert
-    is held here (the serving programs sum them: serve/paged.py).
+    is held here (the serving programs sum them: serve/paged.py); [3] where
+    the router has zero experts (``cfg.zero_experts``; the sorted and the
+    dense path compute them): the rows that chose one, last.
 
     ``tail`` [N, D] (the dispatch path's alone; the decode rows that ride
     in a serving chunk program): tokens beside ``x``'s that are a dispatch
@@ -1543,6 +1564,12 @@ def moe_block(p: dict, x: jax.Array, cfg: DecoderConfig,
             f"experts_held={cfg.experts_held} of {cfg.num_experts} under "
             f"moe_impl={cfg.moe_impl!r}: only the sorted path computes a "
             "share")
+    if cfg.zero_experts and (cfg.moe_impl == "dispatch" or (
+            rows_out and cfg.moe_impl != "sorted")):
+        raise NotImplementedError(
+            f"{cfg.zero_experts} zero experts under moe_impl="
+            f"{cfg.moe_impl!r}: a capacity buffer has no row for an expert "
+            "without weights, and only the sorted path counts their rows")
     rows = None
     if cfg.moe_impl == "dispatch":
         out, aux = _moe_dispatch(p, x, cfg, expert_axis=expert_axis,
@@ -1781,34 +1808,65 @@ def _moe_dispatch(p: dict, x: jax.Array, cfg: DecoderConfig,
 GROUPED_TILE_ROWS = 128
 
 
-def _grouped_tile_columns(n: int) -> int:
+# What a call of the grouped-matmul kernel may hold in the chip's fast
+# memory (a v5e's 16 MiB a kernel), the rows from which on the chip's
+# compiler keeps a product's output there too, and what that costs the call:
+# at (128, 6144, 512) the tiles alone are 15.5 MiB, and from 10240 rows on
+# (9216 still pass) the compile of the gate and up products is refused 0.75
+# MiB over the limit (compiled for a described v5e, PR 57: ``T x k`` rows,
+# whatever the model).
+GROUPED_VMEM_BYTES = 16 * 2 ** 20
+GROUPED_ROWS_OUT_ELSEWHERE = 9216
+GROUPED_OUT_HELD_BYTES = 5 * 2 ** 18
+
+
+def _grouped_tile_columns(n: int, k: int, m: int) -> int:
     """Columns a tile of the grouped matmul holds of an ``n``-wide output:
     512 where that divides ``n``; else the widest whole number of 128-value
     lanes up to 512 that does (256 at experts of 1280: a [4096, 1280] tile
     of weights, twice for the pipeline, is 20 MB of the kernel's 16); ``n``
-    whole where no such width divides it (a tiny preset's)."""
-    return next((t for t in (512, 384, 256, 128) if n % t == 0), n)
+    whole where no such width divides it (a tiny preset's). A call of more
+    than ``GROUPED_ROWS_OUT_ELSEWHERE`` rows (``m``; ``k`` the products'
+    inner width) takes the widest of them whose tiles leave the output its
+    room: a row tile [128, k], a weight tile [k, t] and an output tile [128,
+    t], each twice, and the float32 accumulator."""
+    widths = [t for t in (512, 384, 256, 128) if n % t == 0]
+    if m > GROUPED_ROWS_OUT_ELSEWHERE:
+        rows = GROUPED_TILE_ROWS
+        widths = [t for t in widths
+                  if 4 * (rows * k + k * t + rows * t) + 4 * rows * t
+                  <= GROUPED_VMEM_BYTES - GROUPED_OUT_HELD_BYTES] \
+            or widths[-1:]
+    return widths[0] if widths else n
 
 
 def grouped_matmul(rows: jax.Array, w: jax.Array, sizes: jax.Array,
                    cfg: DecoderConfig) -> jax.Array:
     """``rows`` [M, K] sorted by group times ``w`` [G, K, N]: the rows of
     group ``g`` (``sizes[g]`` of them, in order) against ``w[g]``; empty
-    groups cost nothing. With the fused kernels on and whole row tiles (a
-    chunk's rows; not a decode step's few), the Pallas grouped matmul with
+    groups cost nothing. With the fused kernels on and a tile of rows or
+    more (a chunk's rows, a decode step's where they reach a tile; fewer are
+    XLA's), the Pallas grouped matmul with
     tiles of this layer's own widths (each expert's weights are read about
     once, which XLA's own ``ragged_dot`` kernel at these shapes is 2.5x
     from: PERF.md, PR 28); ``jax.lax.ragged_dot`` otherwise."""
     m, (k, n) = rows.shape[0], w.shape[1:]
-    if fused_kernels_on(cfg) and m % GROUPED_TILE_ROWS == 0:
+    if fused_kernels_on(cfg) and m >= GROUPED_TILE_ROWS:
         from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
         from kubeflow_tpu.ops import auto_interpret
 
-        return megablox.gmm(
+        # Rows beyond whole tiles (a decode step's 48 streams x 12 choices
+        # are 576) ride in a last tile of their own: rows of no group, which
+        # the kernel walks past, cut off again behind it.
+        pad = -m % GROUPED_TILE_ROWS
+        if pad:
+            rows = jnp.pad(rows, ((0, pad), (0, 0)))
+        out = megablox.gmm(
             rows, w, sizes, rows.dtype,
-            (GROUPED_TILE_ROWS, k, _grouped_tile_columns(n)), None, None,
-            False, auto_interpret())
+            (GROUPED_TILE_ROWS, k, _grouped_tile_columns(n, k, m + pad)),
+            None, None, False, auto_interpret())
+        return out[:m] if pad else out
     return jax.lax.ragged_dot(rows, w, sizes)
 
 
@@ -1832,8 +1890,16 @@ def _moe_sorted(p: dict, x: jax.Array, cfg: DecoderConfig,
     expert lies elsewhere sorts behind every held group and belongs to none,
     so it costs no matrix work (the grouped matmul walks the groups' rows
     only) and adds nothing; however uneven the routing, every row of a held
-    expert is computed. Returns (out, aux, int32 [2]: rows routed, rows
-    held)."""
+    expert is computed.
+
+    A row that chose a ZERO expert (``cfg.zero_experts``: the router's
+    outputs from ``num_experts`` on, the identity) goes behind every held
+    group the same way and costs no matrix work either; what it adds is its
+    token's own input: ``(the sum of a token's zero choices' weights) x h``,
+    one elementwise product a token. So the matrix work a token costs runs
+    from none of its ``k`` choices to all of them. Returns (out, aux, the
+    rows routed, the rows held and, with zero experts, the rows that chose
+    one)."""
     dt = cfg.activation_dtype
     b, s, d = x.shape
     e, k = cfg.num_experts, cfg.experts_per_token
@@ -1841,14 +1907,17 @@ def _moe_sorted(p: dict, x: jax.Array, cfg: DecoderConfig,
     t = b * s
     xf = x.reshape(t, d)
     router_logits, topk_idx, topk_w = route(p, xf, cfg)              # [T,k]
-    held, n_held = None, t * k
+    held, n_held, zero = None, t * k, None
     flat_e = topk_idx.reshape(-1)                                    # [Tk]
-    if eh != e:
+    if eh != e or cfg.zero_experts:
         local = topk_idx - cfg.expert_offset
         held = (local >= 0) & (local < eh)                           # [T,k]
-        # a row held elsewhere: group ``eh``, behind every held group
+        # a row held elsewhere, or by nobody: group ``eh``, behind every
+        # held group
         flat_e = jnp.where(held, local, eh).reshape(-1)
         n_held = jnp.sum(held, dtype=jnp.int32)
+    if cfg.zero_experts:
+        zero = topk_idx >= e
     order = jnp.argsort(flat_e, stable=True)
     sizes = jnp.zeros((e,), jnp.int32).at[flat_e].add(1) if held is None \
         else jnp.zeros((eh + 1,), jnp.int32).at[flat_e].add(1)[:eh]
@@ -1873,12 +1942,19 @@ def _moe_sorted(p: dict, x: jax.Array, cfg: DecoderConfig,
         # Rows behind the groups were never computed: whatever lies there
         # (a kernel leaves it unwritten) must not reach the sum.
         back = jnp.where(held[..., None], back, 0)
-    out = jnp.einsum("tkd,tk->td", back, topk_w.astype(dt)).reshape(b, s, d)
+    out = jnp.einsum("tkd,tk->td", back, topk_w.astype(dt))
+    rows = (t * k, n_held)
+    if zero is not None:
+        out = out + jnp.sum(jnp.where(zero, topk_w, 0), axis=-1,
+                            keepdims=True).astype(dt) * xf
+        rows += (jnp.sum(zero, dtype=jnp.int32),)
+    out = out.reshape(b, s, d)
+    width = cfg.router_width
     aux = _moe_aux_loss(
-        router_logits.reshape(b, s, e),
-        jax.nn.one_hot(topk_idx, e, dtype=jnp.float32).sum(-2).reshape(
-            b, s, e), cfg, seq_axis)
-    return checkpoint_name(out, "mlp_out"), aux, (t * k, n_held)
+        router_logits.reshape(b, s, width),
+        jax.nn.one_hot(topk_idx, width, dtype=jnp.float32).sum(-2).reshape(
+            b, s, width), cfg, seq_axis)
+    return checkpoint_name(out, "mlp_out"), aux, rows
 
 
 def _moe_dense(p: dict, x: jax.Array, cfg: DecoderConfig,
@@ -1902,8 +1978,13 @@ def _moe_dense(p: dict, x: jax.Array, cfg: DecoderConfig,
     dt = cfg.activation_dtype
     e, k = cfg.num_experts, cfg.experts_per_token
     router_logits, topk_idx, topk_w = route(p, x, cfg)               # [B,S,k]
-    onehot = jax.nn.one_hot(topk_idx, e, dtype=jnp.float32)          # [B,S,k,E]
+    onehot = jax.nn.one_hot(topk_idx, cfg.router_width,
+                            dtype=jnp.float32)                       # [B,S,k,E]
     combine = jnp.einsum("bske,bsk->bse", onehot, topk_w)            # [B,S,E]
+    if cfg.zero_experts:
+        # the zero experts (the outputs behind the ``e`` with weights) hand
+        # a token its own input back
+        combine, zero = combine[..., :e], jnp.sum(combine[..., e:], axis=-1)
 
     if expert_axis is not None:
         e_local = p["gate"].shape[0]
@@ -1917,6 +1998,8 @@ def _moe_dense(p: dict, x: jax.Array, cfg: DecoderConfig,
     axes = tuple(a for a in (expert_axis, tp_axis) if a is not None)
     if axes:
         out = jax.lax.psum(out, axes)
+    if cfg.zero_experts:
+        out = out + zero[..., None].astype(dt) * x
 
     aux = _moe_aux_loss(router_logits, onehot.sum(axis=2), cfg, seq_axis)
     return out, aux

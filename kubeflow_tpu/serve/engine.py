@@ -877,11 +877,11 @@ class LLMEngine:
         # stack hold their state a page, beside the attention layers'
         # rows a token.
         with start(prof.ENGINE_START_POOL):
+            shapes = engine_pool_shapes(cfg_decode, self.num_slots,
+                                        self._num_pages, pg, self.kv_quant)
             self.cache = {  # lockfree: scheduler-confined (donated KV)
                 name: self._zeros(shape, dt, scale=name in ("ks", "vs"))
-                for name, (shape, dt) in engine_pool_shapes(
-                    cfg_decode, self.num_slots, self._num_pages, pg,
-                    self.kv_quant).items() if name != MOE_ROWS}
+                for name, (shape, dt) in shapes.items() if name != MOE_ROWS}
 
         self._kv_bytes_per_token = pool_bytes_per_token(cfg, self.kv_quant)
         self._kv_pool_bytes = int(sum(v.nbytes for v in self.cache.values()))
@@ -904,11 +904,14 @@ class LLMEngine:
         # Where a layer holds a share of its experts, the rows its expert
         # layers routed and held ride in the cache pytree as running sums
         # (no pool plane: ``paged._planes_of``); the scheduler reads them in
-        # a round's fetch (``_consume_round``).
-        self._expert_rows = [0, 0]      # lockfree: scheduler-confined
-        self._expert_rows_seen = np.zeros((2,), np.uint32)
-        if cfg.experts_held:
-            self.cache[MOE_ROWS] = jnp.zeros((2,), jnp.int32)
+        # a round's fetch (``_consume_round``): routed, held and, where the
+        # router has zero experts, the rows that chose one.
+        self._expert_rows = [0, 0, 0]   # lockfree: scheduler-confined
+        self._expert_rows_last = (0, 0, 0)  # lockfree: scheduler-confined (what the last fetch added)
+        rows_shape, _ = shapes.get(MOE_ROWS, ((2,), None))
+        if MOE_ROWS in shapes:
+            self.cache[MOE_ROWS] = jnp.zeros(rows_shape, jnp.int32)
+        self._expert_rows_seen = np.zeros(rows_shape, np.uint32)  # lockfree: scheduler-confined
 
         # Compiled programs: donate the cache so it mutates in place in HBM.
         on_tpu = jax.default_backend() == "tpu"
@@ -1625,7 +1628,15 @@ class LLMEngine:
         under the latent row's page ids, which none of them carries either;
         prefix reuse is TAKEN over it (both planes are rows a token under
         one page id: a matched page's are both there, and ``copy_pages``
-        walks every plane). The indexer's kernels take a chunk's queries a
+        walks every plane). An expert layer on a shortcut makes a scan unit
+        a PAIR of blocks whose expert layer is the pair's ("moe" beside two
+        "mlp"), which the quantizer, the adapter buffers, the mesh's
+        sharding and the speculative verify's draft walk do not know; zero
+        experts are the sorted and the dense expert path's, which a
+        capacity-dispatch decode (``moe_decode_impl="zero_drop"``) is not;
+        prefix reuse is TAKEN over both (a matched page's latent rows are
+        what they would be written as: an expert layer keeps nothing). The
+        indexer's kernels take a chunk's queries a
         tile of ``INDEX_QUERY_TILE`` a row of their walk, so a longer chunk
         is whole tiles (in the gathered form too: one spec serves on
         either)."""
@@ -1654,7 +1665,11 @@ class LLMEngine:
             ("K/V heads packed into one pool row", cfg.kv_heads_packed),
             ("leading dense layers", bool(cfg.leading_dense_layers)),
             (f"expert layers that hold {cfg.experts_held} of "
-             f"{cfg.num_experts} experts", bool(cfg.experts_held))) if has]
+             f"{cfg.num_experts} experts", bool(cfg.experts_held)),
+            ("an expert layer on a shortcut beside the dense MLPs of a "
+             "pair of blocks", cfg.moe_shortcut),
+            (f"{cfg.zero_experts} zero experts (router outputs that are "
+             "the identity)", bool(cfg.zero_experts))) if has]
         if not what:
             return
         refused = {
@@ -1673,6 +1688,11 @@ class LLMEngine:
             f"indexer's tiles of {INDEX_QUERY_TILE})":
                 bool(cfg.index_topk) and chunk > INDEX_QUERY_TILE
                 and chunk % INDEX_QUERY_TILE != 0,
+            f"moe_prefill_impl={b.moe_prefill_impl!r} / moe_decode_impl="
+            f"{b.moe_decode_impl!r} (a capacity buffer has no row for an "
+            "expert without weights: zero experts are the sorted path's)":
+                bool(cfg.zero_experts) and "dispatch" in (
+                    self._cfg_prefill.moe_impl, self._cfg_decode.moe_impl),
             "enable_prefix_caching (prefix reuse over window layers: a "
             "ring its sequence overwrites cannot be shared)":
                 bool(cfg.layers_of("window")) and b.enable_prefix_caching,
@@ -1849,6 +1869,9 @@ class LLMEngine:
             # decode round fetched
             "expert_rows_routed": self._expert_rows[0],
             "expert_rows_held": self._expert_rows[1],
+            # those of the routed rows that chose a zero expert (the
+            # identity: no matrix work; 0 where the router has none)
+            "expert_rows_zero": self._expert_rows[2],
             # page-end tails of the conv layers' state that chunk-prefill
             # programs wrote: one for each page a chunk's tokens touched
             "state_tail_writes": self._state_tail_writes,
@@ -2329,9 +2352,14 @@ class LLMEngine:
                     for _, pos, real in group)}
             self._dsa_keys_visible += sparse["context"]
             self._dsa_keys_selected += sparse["selected"]
+        elif prof.active():
+            # the (query, key) pairs the chunks' queries can see
+            sparse = {"context": sum(
+                real * pos + real * (real + 1) // 2
+                for _, pos, real in group)}
         with self._phase(prof.ENGINE_PREFILL_DISPATCH, prof.active() and {
                 "slot": group[0][0].slot, "pos": group[0][1],
-                "chunks": len(group), **sparse}):
+                "chunks": len(group), **sparse, **self._rows_chosen()}):
             if by_rows:
                 # Rows past the group are DEAD: no valid position, no page.
                 table = np.full((rows, self._mpp), -1, np.int32)
@@ -3497,7 +3525,8 @@ class LLMEngine:
             k_steps * (s.length + slack) + k_steps * (k_steps + 1) // 2
             for _, s in active)
         attrs = {"round": self.decode_rounds, "k_steps": k_steps,
-                 "live": len(active), "context": context}
+                 "live": len(active), "context": context,
+                 **(self._rows_chosen() if prof.active() else {})}
         if self.cfg.index_topk:
             # of those rows the ones an indexer selects for its step
             self._round_selected = attrs["selected"] = sum(
@@ -3511,6 +3540,18 @@ class LLMEngine:
                 min(s.length + slack + j + 1, w)
                 for _, s in active for j in range(k_steps))
         return active, mode, gap, context, attrs
+
+    def _rows_chosen(self) -> dict:
+        """What a dispatch span says of the expert layers' rows, where a
+        share is held: the (token, choice) rows routed, held and sent to a
+        zero expert that the LAST FETCH read back (``_consume_round``), so
+        the programs between the two fetches in front of this dispatch: the
+        sums come back with a round's tokens, and a span is written when
+        its program is sent. {} where nothing is counted."""
+        if MOE_ROWS not in self.cache:
+            return {}
+        return dict(zip(("rows_routed", "rows_held", "rows_zero"),
+                        self._expert_rows_last))
 
     def _note_round(self, out, rows, active, k_steps: int, cap: int,  # hot-loop
                     gap, context: int, alone: bool) -> None:
@@ -3558,8 +3599,10 @@ class LLMEngine:
         if rows is not None:
             # int32 sums that wrap: what was added since the last fetch
             seen = np.asarray(rows).astype(np.uint32)
-            for i, d in enumerate(seen - self._expert_rows_seen):
-                self._expert_rows[i] += int(d)
+            added = [int(d) for d in seen - self._expert_rows_seen]
+            for i, d in enumerate(added):
+                self._expert_rows[i] += d
+            self._expert_rows_last = (*added, 0)[:3]
             self._expert_rows_seen = seen
         now = time.monotonic()
         self._consumed_k = rnd.k_steps

@@ -363,7 +363,8 @@ class PageAllocator:
 # What rides in a cache pytree beside the pool's planes: the page table, and
 # where a layer holds a share of its experts the running sums of the rows
 # its expert layers routed and held (int32 [2], wrapping: a reader works on
-# differences).
+# differences; [3] where the router has zero experts: the rows that chose
+# one, last).
 MOE_ROWS = "moe_rows"
 # What rides through a program's layer scans beside the planes where the
 # stack has gated memory units: the scan output [B,T,E] float32 of the last
@@ -618,7 +619,8 @@ def engine_pool_shapes(cfg: DecoderConfig, slots: int, num_pages: int,
                                        slots * own_first_pages(cfg)),
                       sequence_entries=min(num_pages, slots))
     if cfg.experts_held:
-        out[MOE_ROWS] = ((2,), jnp.dtype(jnp.int32))
+        out[MOE_ROWS] = ((2 + bool(cfg.zero_experts),),
+                         jnp.dtype(jnp.int32))
     return out
 
 
@@ -723,8 +725,9 @@ def _feed_forward(bp, hs, valids, cfg: DecoderConfig,  # traced
     """The block's feed-forward (or expert) layer over the tokens of every
     group of ``hs`` ([B,T,D] each; ``valids`` [B] each: a row's real tokens)
     TOGETHER, so a weight is read once for all of them: (a group's output
-    each, pools), the carried planes, where a share of the experts is held
-    with the layer's routed and held rows added to ``pools[MOE_ROWS]``.
+    each, what the block STARTED for each group or None, pools), the carried
+    planes, where a share of the experts is held with the layer's routed
+    and held rows added to ``pools[MOE_ROWS]``.
 
     One group is the layer as it always ran: an expert layer is told which
     tokens are real and takes its capacity a row at several tokens a row,
@@ -734,7 +737,12 @@ def _feed_forward(bp, hs, valids, cfg: DecoderConfig,  # traced
     rows behind the chunk's (``mixed_step_rows`` of them: sorted rows in
     whole tiles); a dispatch layer keeps the chunk rows' capacity a row and
     takes the decode tokens as a group that cannot drop
-    (``layers._moe_dispatch``'s ``tail``), whatever the chunk rows claim."""
+    (``layers._moe_dispatch``'s ``tail``), whatever the chunk rows claim.
+
+    Under a shortcut (``cfg.moe_shortcut``) every block's feed-forward is
+    its dense MLP, and the first block of a pair ALSO runs its expert layer
+    ("moe") over the same tokens: that result is what the block started,
+    which joins the stream a block later (``_pool_block``)."""
     if len(hs) == 1:
         (h,), t = hs, hs[0].shape[1]
         valid_len, per_row = (None if t == 1 else valids[0]), t > 1
@@ -752,7 +760,7 @@ def _feed_forward(bp, hs, valids, cfg: DecoderConfig,  # traced
             (out, beside), _ = L.moe_block(
                 bp["mlp"], chunk, cfg, valid_len=valids[0],
                 capacity_per_row=True, tail=step[:, 0])
-            return (out, beside[:, None]), pools
+            return (out, beside[:, None]), None, pools
         rows = [chunk.reshape(1, r * c, d), step.reshape(1, b, d)]
         pad = mixed_step_rows(cfg, r * c, b) - b
         if pad:
@@ -762,15 +770,23 @@ def _feed_forward(bp, hs, valids, cfg: DecoderConfig,  # traced
         def parted(out):
             return (out[:, :r * c].reshape(r, c, d),
                     out[0, r * c:r * c + b][:, None])
-    if not cfg.is_moe:
-        return parted(L.mlp_block(bp["mlp"], h, cfg)), pools
-    counted = MOE_ROWS in pools
-    out = L.moe_block(bp["mlp"], h, cfg, valid_len=valid_len,
-                      expert_stack=expert_stack, capacity_per_row=per_row,
-                      rows_out=counted)
-    if counted:
-        pools = {**pools, MOE_ROWS: pools[MOE_ROWS] + out[2]}
-    return parted(out[0]), pools
+
+    def experts(at: str, pools):
+        counted = MOE_ROWS in pools
+        out = L.moe_block(bp[at], h, cfg, valid_len=valid_len,
+                          expert_stack=expert_stack,
+                          capacity_per_row=per_row, rows_out=counted)
+        if counted:
+            pools = {**pools, MOE_ROWS: pools[MOE_ROWS] + out[2]}
+        return parted(out[0]), pools
+
+    if cfg.is_moe and not cfg.moe_shortcut:
+        fed, pools = experts("mlp", pools)
+        return fed, None, pools
+    fed, started = parted(L.mlp_block(bp["mlp"], h, cfg)), None
+    if "moe" in bp:
+        started, pools = experts("moe", pools)
+    return fed, started, pools
 
 
 def mixed_step_rows(cfg: DecoderConfig, chunk_tokens: int, rows: int) -> int:
@@ -815,7 +831,7 @@ def _scan_layer_groups(params: Params, cfg: DecoderConfig, carry, block,  # trac
                     bp, carry, at[kind] + u * period.count(kind) + i, gcfg,
                     L.layer_view(lora, lsl),
                     None if experts is None
-                    else (experts, u * len(period) + j))
+                    else (experts, gcfg.expert_layer(u * len(period) + j)))
             return carry, None
 
         carry, _ = jax.lax.scan(
@@ -881,6 +897,13 @@ def _pool_block(bp, xs, groups, pools, layer, num_pages: dict,  # traced
     ``decoder._block_forward``'s skeleton, the only other copy. Returns (a
     group's output each, the planes as written).
 
+    Under a shortcut (``cfg.moe_shortcut``) the first block of a pair also
+    STARTS its expert layer on the normed input of its dense MLP, and a
+    group's output is then the pair (``x``, what was started), which only
+    the second block of the pair takes: it adds what was started behind its
+    own attention and dense MLP, and hands on ``x`` alone (a layer scan's
+    iteration is a pair, so no scan carries the pair).
+
     A group's ROWS may be consecutive chunks of ONE sequence (the engine's
     rows ahead, ``LLMEngine._rows_of``: row ``r + 1`` the same table row at
     ``start[r] + T``) where every layer is of kind "attention": the
@@ -935,6 +958,7 @@ def _pool_block(bp, xs, groups, pools, layer, num_pages: dict,  # traced
     context."""
     out = []
     for x, rows in zip(xs, groups):
+        x, joining = x if isinstance(x, tuple) else (x, None)
         proj, pools = _operator(bp, x, rows, pools, layer, num_pages,
                                 page_size, cfg, attn_impl, lora)
         if x.shape[1] == 1:
@@ -943,11 +967,13 @@ def _pool_block(bp, xs, groups, pools, layer, num_pages: dict,  # traced
         else:
             x, h = L.add_rmsnorm(x, proj, bp["ln2"], cfg,
                                  bias=bp.get("ln2_b"))
-        out.append((x, h))
-    fed, pools = _feed_forward(bp, [h for _, h in out],
-                               [rows.valid for rows in groups], cfg,
-                               expert_stack, pools=pools)
-    return tuple(x + y for (x, _), y in zip(out, fed)), pools
+        out.append((x, h, joining))
+    fed, started, pools = _feed_forward(
+        bp, [h for _, h, _ in out], [rows.valid for rows in groups], cfg,
+        expert_stack, pools=pools)
+    xs = tuple(x + y if joining is None else x + y + joining
+               for (x, _, joining), y in zip(out, fed))
+    return (xs if started is None else tuple(zip(xs, started))), pools
 
 
 def _operator(bp, x, rows: _Rows, pools, layer, num_pages: dict,  # traced

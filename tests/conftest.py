@@ -319,6 +319,34 @@ AGENTCONTEXT_STATED = {
     "engine.sync_state_ms_per_round.agentcontext": 0.0,
     "start.unattributed_s.agentcontext": 24.0,    # 30 less 6
 }
+
+
+# PR 57's cell (longcat-flash-omni.batch-voiceturns), the same way: its
+# rehearsal cell (``rehearsal-closed``: prefix reuse is taken over a latent
+# pool, and an expert layer keeps nothing), the counter its new reader
+# takes, at rest, and each metric's number for a window without samples.
+VOICETURNS_CELLS = {
+    "tiny-longcat.rehearsal-closed": (
+        "longcat-flash-omni.batch-voiceturns", "rehearsal-tiny-longcat",
+        "rehearsal-closed", 1),
+}
+VOICETURNS_ENGINE_COUNTERS = {"expert_rows_zero": 0}
+VOICETURNS_STATED = {
+    "moe.zero_row_share.voiceturns": 0.0,
+    "moe.held_row_share.voiceturns": 0.0,
+    "step.expert_matmul_share.voiceturns": 0.0,
+    "step.decode_weight_bw_share.voiceturns": 0.0,
+    "step.prefill_mfu.voiceturns": 0.0,
+    "kernel.latent_decode_bw_share.voiceturns": 0.0,
+    "kernel.latent_chunk_attention_mfu.voiceturns": 0.0,
+    "engine.decode_occupancy.voiceturns": 0.0,
+    "kv.preemptions.voiceturns": 0.0,
+    "engine.sched_busy_share_window.voiceturns": 0.0,
+    "engine.sync_state_ms_per_round.voiceturns": 0.0,
+    "start.unattributed_s.voiceturns": 24.0,      # 30 less 6
+}
+
+
 @pytest.fixture(autouse=True, scope="session")
 def benchmark_suite_tables_know_the_longanswer_cell(request):
     suite = next(
@@ -336,17 +364,20 @@ def benchmark_suite_tables_know_the_longanswer_cell(request):
     for tables, added in (
             ((suite.ADDED_CELLS, rehearsal.CELLS),
              {**LONGANSWER_CELLS, **MIXEDLENGTH_CELLS, **LONGDOC_CELLS,
-              **REASONING_CELLS, **ASSISTANT_CELLS, **AGENTCONTEXT_CELLS}),
+              **REASONING_CELLS, **ASSISTANT_CELLS, **AGENTCONTEXT_CELLS,
+              **VOICETURNS_CELLS}),
             ((suite.ADDED_ENGINE_COUNTERS, readers.ENGINE0),
              {**LONGANSWER_ENGINE_COUNTERS, **WINDOW_ENGINE_COUNTERS,
               **MIXEDLENGTH_ENGINE_COUNTERS, **LONGDOC_ENGINE_COUNTERS,
               **REASONING_ENGINE_COUNTERS, **STARTUP_ENGINE_COUNTERS,
-              **SYNC_ENGINE_COUNTERS, **AGENTCONTEXT_ENGINE_COUNTERS}),
+              **SYNC_ENGINE_COUNTERS, **AGENTCONTEXT_ENGINE_COUNTERS,
+              **VOICETURNS_ENGINE_COUNTERS}),
             ((readers.TRAINER0,), STARTUP_TRAINER_COUNTERS),
             ((suite.ADDED_STATED, total.STATED),
              {**LONGANSWER_STATED, **WINDOW_STATED, **MIXEDLENGTH_STATED,
               **LONGDOC_STATED, **REASONING_STATED, **ASSISTANT_STATED,
-              **STARTUP_STATED, **SYNC_STATED, **AGENTCONTEXT_STATED})):
+              **STARTUP_STATED, **SYNC_STATED, **AGENTCONTEXT_STATED,
+              **VOICETURNS_STATED})):
         for table in tables:
             for key, value in added.items():
                 table.setdefault(key, value)
@@ -380,7 +411,9 @@ def benchmark_suite_tables_know_the_longanswer_cell(request):
 # PR 55 a configuration, a cell and fourteen behind PR 53's seventeen: the
 # tests that pin an END are handed the manifest without them, and so is PR
 # 50's, which pins a COUNT ("nine cells and nine configurations": a
-# ``benchmark`` PR should ask ``>= 9`` there, PERF.md section 7).
+# ``benchmark`` PR should ask ``>= 9`` there, PERF.md section 7). PR 57
+# appends a configuration, a cell and twelve behind PR 55's, whose own test
+# pins no end: the same tests are handed the manifest without them too.
 PINS_PR43_AT_THE_END = "test_what_pr_43_added_is_listed_with_the_benchmark"
 PINS_PR28_AT_THE_END = "test_what_this_pr_added_is_listed_with_the_benchmark"
 PINS_PR35S_CELL = \
@@ -425,7 +458,7 @@ def the_manifest_as_it_stood_for_the_tests_that_pin_a_pr(request,
     name = request.node.name
     module = request.node.module
     since_pr50 = set(STARTUP_STATED) | set(SYNC_STATED) \
-        | set(AGENTCONTEXT_STATED)
+        | set(AGENTCONTEXT_STATED) | set(VOICETURNS_STATED)
     later = set(WINDOW_STATED) | set(MIXEDLENGTH_STATED) \
         | set(LONGDOC_STATED) | set(REASONING_STATED) \
         | set(ASSISTANT_STATED) | since_pr50
@@ -433,8 +466,11 @@ def the_manifest_as_it_stood_for_the_tests_that_pin_a_pr(request,
     reasoning = next(iter(REASONING_CELLS.values()))[0]
     assistant = next(iter(ASSISTANT_CELLS.values()))[0]
     agentcontext = next(iter(AGENTCONTEXT_CELLS.values()))[0]
+    voiceturns = next(iter(VOICETURNS_CELLS.values()))[0]
+    since_pr53 = {agentcontext, voiceturns}
     if request.node.originalname == PINS_A_CELLS_LINE:
-        if module.__name__ == "test_benchmark_glm5":    # the newest cell's
+        if module.__name__ in ("test_benchmark_glm5",   # the newest cells'
+                               "test_benchmark_longcat"):
             return
         if (module.__name__, PINS_A_CELLS_LINE) != PINS_PR35S_LINE:
             later = since_pr50
@@ -447,9 +483,10 @@ def the_manifest_as_it_stood_for_the_tests_that_pin_a_pr(request,
         monkeypatch.setattr(module, "MANIFEST", {
             **module.MANIFEST,
             "configs": [c for c in module.MANIFEST["configs"]
-                        if c["name"] != agentcontext.split(".")[0]],
+                        if c["name"] not in {
+                            cell.split(".")[0] for cell in since_pr53}],
             "workloads": [w for w in module.MANIFEST["workloads"]
-                          if w["name"] != agentcontext],
+                          if w["name"] not in since_pr53],
             "per_layer": [m for m in module.MANIFEST["per_layer"]
                           if m["name"] not in since_pr50]})
         return
@@ -460,10 +497,10 @@ def the_manifest_as_it_stood_for_the_tests_that_pin_a_pr(request,
                     PINS_PR43_AT_THE_END):
         return
     cells = {mixed, next(iter(LONGDOC_CELLS.values()))[0], reasoning,
-             assistant, agentcontext}
+             assistant, *since_pr53}
     if name == PINS_PR43_AT_THE_END:
         later = set(REASONING_STATED) | set(ASSISTANT_STATED) | since_pr50
-        cells = {reasoning, assistant, agentcontext}
+        cells = {reasoning, assistant, *since_pr53}
     if name == PINS_PR28_AT_THE_END:
         later |= set(LONGANSWER_STATED)
         cells.add(next(iter(LONGANSWER_CELLS.values()))[0])

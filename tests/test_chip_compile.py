@@ -1126,3 +1126,57 @@ def test_agentcontext_program_compiles_for_v5e_with_its_kernels(
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10.0e9
     assert mem.temp_size_in_bytes < 0.6e9
+
+
+VOICETURNS = "longcat-flash-omni.batch-voiceturns"
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk[1]", "mixed[2]"])
+def test_voiceturns_program_compiles_for_v5e_with_its_kernels(
+        cell_programs, program):
+    """The voice-turns cell's decode step, its one-row ``[C, V]`` chunk
+    program (what the comparison drives) and the chunk program that carries
+    the slots' step (what its traffic runs: TWO rows) at the cell's real
+    sizes, parameters as the engine holds them: each fits the chip beside
+    its arguments and runs the latent kernels in every block of a pair.
+    The mixed program's 13056 sorted rows (1088 tokens at twelve choices)
+    take the grouped matmul's NARROWER tile (``layers._grouped_tile_columns``:
+    at ``(128, 6144, 512)`` this compile is refused, 0.75 MiB over the
+    kernel's fast memory: PR 57 found it here, before any chip call). A scan
+    unit is a pair of blocks, and neither the pair's two dense MLPs nor its
+    two attentions are copied out of their stacks: what is copied is latent
+    attention's own ``wkvb`` and ``wkva``, as in every latent model's
+    programs."""
+    from scripts.aot_weight_copies import weight_copies
+
+    lowered = cell_programs(VOICETURNS, mixed=program.startswith("mixed"))[
+        program]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    kernels = {"decode": ("paged_latent_decode_attention",),
+               "chunk[1]": ("paged_latent_chunk_attention",),
+               "mixed[2]": ("paged_latent_decode_attention",
+                            "paged_latent_chunk_attention", "gmm")}[program]
+    for kernel in kernels:
+        assert _calls(text, kernel) >= 1, kernel
+    copied = {leaf for c in weight_copies(text, lowered.args_info[0][0])
+              for leaf in c["leaf"]}
+    assert copied <= {"['layers']['attn']['wkvb']",
+                      "['layers']['attn']['wkva']"}, copied
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.2e9
+    assert mem.temp_size_in_bytes < 0.7e9
+
+
+def test_the_grouped_tile_is_narrower_only_where_the_rows_are_many():
+    """The cells the benchmark had send at most 8320 sorted rows a call and
+    keep their tile; from 9216 rows on a call whose wide tile would fill the
+    kernel's fast memory takes the next narrower one."""
+    from kubeflow_tpu.models.layers import _grouped_tile_columns as columns
+
+    assert columns(2048, 6144, 8320) == columns(2048, 6144, 9216) == 512
+    assert columns(2048, 6144, 12288) == columns(2048, 6144, 13056) == 256
+    assert columns(6144, 2048, 13056) == 512     # the down product's tile
+    assert columns(1280, 4096, 8192) == columns(1280, 4096, 16384) == 256
+    assert columns(1536, 2048, 16384) == 512
+    assert columns(48, 64, 16384) == 48          # a tiny preset's

@@ -573,7 +573,9 @@ def test_both_dispatch_spans_carry_their_attributes(monkeypatch):
     assert len(mixed) == c["mixed_programs_dispatched"] > 0
     rows = 0
     for i in mixed:
-        assert set(seen[i][1]) == {"slot", "pos", "chunks"}
+        # (``context``: the pairs its chunks' queries can see, every
+        # engine's since PR 57)
+        assert set(seen[i][1]) == {"slot", "pos", "chunks", "context"}
         step = seen[i + 1][1]
         assert set(step) == {"round", "k_steps", "live", "context"}
         assert step["k_steps"] == 1 and step["live"] >= 1
