@@ -77,8 +77,8 @@ from kubeflow_tpu.serve.device_state import DEAD_SLOT, DecodeState
 from kubeflow_tpu.serve.pacing import RoundPacer, decode_ladder
 from kubeflow_tpu.serve.paged import (
     MOE_ROWS, SEQUENCE_PLANES, PageAllocator, PagePoolExhausted,
-    chunk_carries_step,
-    chunk_reads_context, context_bucket, engine_pool_shapes,
+    chunk_carries_step, chunk_reads_context, chunk_rows_follow,
+    context_bucket, engine_pool_shapes,
     paged_chunk_prefill, first_page_ids, own_first_pages,
     paged_decode_multi, paged_mixed_step, pool_bytes_per_token,
     pool_shapes, ring_pages,
@@ -1024,10 +1024,12 @@ class LLMEngine:
         # program (``_ahead`` below, ``_rows_of``).
         # An engine that sends ONE chunk a program (a dense model at 512
         # tokens, over the ridge; one prefill at a time) and carries no
-        # step in its chunk programs sends every chunk through it all the
-        # same, as a group of one row (``_lone_at_last``): the engine reads
-        # one row of a chunk's logits or none, and the ``[C, V]`` program
-        # runs the head at all ``C``. So does, whatever the ridge says, a
+        # step in its chunk programs (where it does, that program takes
+        # this one's place: ``_dispatch_chunks``) sends every chunk through
+        # it all the same, as a group of one row (``_lone_at_last``): the
+        # engine reads one row of a chunk's logits or none, and the ``[C,
+        # V]`` program runs the head at all ``C``. So does, whatever the
+        # ridge says, a
         # stack that ENDS in layers that keep no state
         # (``cfg.stateless_tail``): the program over rows runs that tail
         # at the one position a row whose logits are read, and not at all
@@ -1046,16 +1048,19 @@ class LLMEngine:
         # another row's, of the same prompt, where a layer writes every
         # row's keys into the pool before any row attends and nothing but
         # those keys passes from one chunk of a prompt to the next: every
-        # layer of kind "attention", met in place
-        # (``paged._pool_block``). That is what ``chunk_carries_step``
-        # tests, and ``_mixed`` with it: a layer that keeps a state, a
-        # ring or a conv tail hands on the END state of the chunk in
-        # front, which one program cannot; the gathered form (an int8
-        # pool, a call with LoRA, the "gather" arm) attends over what the
-        # pool held BEFORE the program. Observed, not set: there is one
-        # algorithm, "fill the program the pass sends", and where this is
-        # false a group is the due chunks and no more.
-        self._ahead = self._mixed and self._chunk_rows > 1
+        # layer of kind "attention" (``paged.chunk_rows_follow``), met in
+        # place (``paged._pool_block``; ``chunk_carries_step`` tests that,
+        # and ``_mixed`` with it). A layer that keeps a state, a ring or a
+        # conv tail hands on the END state of the chunk in front, which one
+        # program cannot, whether or not its chunk program carries the step
+        # (a stack of parallel layers: it does, and sends no chunk ahead);
+        # the gathered form (an int8 pool, a call with LoRA, the "gather"
+        # arm) attends over what the pool held BEFORE the program.
+        # Observed, not set: there is one algorithm, "fill the program the
+        # pass sends", and where this is false a group is the due chunks
+        # and no more.
+        self._ahead = (self._mixed and self._chunk_rows > 1
+                       and chunk_rows_follow(cfg_prefill))
         # What such an engine's traffic is left with for the one-row
         # ``[C, V]`` program is a prompt's odd LAST chunk with no slot
         # live, at any start, and a prompt sent alone, a caller's way to
@@ -1117,6 +1122,7 @@ class LLMEngine:
         # finds a decode step by its module's name finds decode-only steps.
         # (``_mixed`` itself: above, in front of the program over rows.)
         self._mixed_pass = -1    # lockfree: scheduler-confined (the admit pass that sent a round)
+        self._ahead_pass = -1    # lockfree: scheduler-confined (the admit pass that sent the NEXT round too: ``_round_ahead``)
 
         def _mixed_fn(p, c, t, tr, s0, vl, ends, ride, st, tbl, key, m):
             logits, out, cache, tokens, lengths, live, budgets = \
@@ -2202,8 +2208,10 @@ class LLMEngine:
             # queues behind the decode round in flight: the round's tokens
             # are ready first. They go out before the wait, not after it,
             # or every live stream waits a second chunk's time for a token
-            # the device has had all along.
-            self._consume_rounds()
+            # the device has had all along. (Where the pass's program
+            # carried a round, the next round goes out first and stays in
+            # flight: ``_round_ahead``.)
+            self._consume_rounds(keep=int(self._round_ahead()))
             # A wait for the device like the round's own fetch, and named
             # like it.
             with self._phase(prof.ENGINE_FETCH,
@@ -2310,10 +2318,18 @@ class LLMEngine:
         the way every round's go (``_rounds``). That program has ONE width,
         as many rows as the engine sends chunks together (one program to
         load, not two; a prefill alone fills the rows no other prefill wants
-        with its own next chunks, and a row stays dead only behind a
+        with its own next chunks where the stack lets a chunk follow a chunk
+        inside one program, ``_ahead``, and a row stays dead only behind a
         prompt's last chunk or where the pool has no page for it); such an
         engine sends several prompts' chunks through it too, no slot
-        riding."""
+        riding. Where that width is ONE row (a dense model over the ridge:
+        the chat cell's, the assistant cell's) a chunk that no step rides
+        with (the second program of a pass whose round has several steps; a
+        pass that finds no slot live but several prompts) is that program
+        again with every decode row dead (``ride`` false: the head at the
+        chunk's last position if it ends its prompt, else nowhere), and the
+        ``[C, V]`` program is left to a prompt sent alone to an engine with
+        nothing else to do (``_otherwise_idle``)."""
         C = self.chunk_size
         ride = None
         if self._mixed and self._mixed_pass != self._admit_pass:
@@ -2323,8 +2339,14 @@ class LLMEngine:
         # Several chunks, or one that a step rides with, go together in the
         # program of the engine's one width (rows past the group dead); so
         # does every chunk where the engine keeps no one-row program for
-        # its traffic (``_rows_only``).
-        together = len(group) > 1 or ride is not None or self._rows_only
+        # its traffic (``_rows_only``), and, where that width is ONE row, a
+        # chunk no step rides with unless the engine has nothing else to do
+        # (``_otherwise_idle``): the same rows, no dead row beside them,
+        # the head at one position or none where the ``[C, V]`` program
+        # runs it at all ``C`` and returns them all.
+        together = len(group) > 1 or ride is not None or self._rows_only \
+            or (self._mixed and self._chunk_rows == 1
+                and not self._otherwise_idle())
         rows = self._chunk_rows if together else 1
         by_rows = together or self._lone_at_last
         # a row: (its prefill, its start, its real tokens)
@@ -2439,6 +2461,22 @@ class LLMEngine:
             self._pending_first.append(
                 (req, ch.slot, plen,
                  logits[r] if by_rows else logits[real - 1]))
+
+    def _otherwise_idle(self) -> bool:
+        """Whether the prefill being sent is all the engine has to do: no
+        slot live, no other prefill in flight, nobody waiting. Only then
+        does a mixed engine one row wide leave a chunk to the one-row ``[C,
+        V]`` program (a prompt sent alone to an idle engine: a caller's way
+        to reach every context bucket's name, and nobody waits on it). A
+        pass that finds no slot live but more prompts (a burst after
+        idleness: a closed loop's first pass) sends program after program
+        without a wait between them, and every ``[C, V]`` result, ``C x V``
+        float32, is allocated when its program is sent: 48 one-chunk
+        prompts at a vocabulary of 261120 read 15.5 GB of a chip's 16 where
+        the engine holds 11.7 (PERF.md, PR 58)."""
+        return (all(s is None for s in self.slots)
+                and len(self._chunkings) == 1 and not self._backlog
+                and self.waiting.empty())
 
     def _rows_of(self, group: "list[_Chunking]"
                  ) -> "list[tuple[_Chunking, int]]":
@@ -3396,11 +3434,11 @@ class LLMEngine:
                 return emitted
             return emitted + self._spec_decode_once(active)
         dispatched = False
-        if active and self._mixed_pass == self._admit_pass \
-                and self._steps_in_force(self._round_cap()) == 1:
+        if active and (self._ahead_pass == self._admit_pass
+                       or self._round_carried()):
             # The admit pass's chunk program carried this iteration's round
-            # (a round the pacer makes longer follows it as the decode
-            # program it always was).
+            # (and, where the pass waited for its first tokens, the round
+            # behind it went out ahead of the wait).
             dispatched = True
         elif active:
             dispatched = self._dispatch_round(active, paced=self.pipelined)
@@ -3666,13 +3704,52 @@ class LLMEngine:
             self._finish_if_done(i)
         return emitted, streams
 
-    def _consume_rounds(self) -> int:
+    def _consume_rounds(self, keep: int = 0) -> int:
         """Drain every in-flight round (the pipeline barrier the spec path
-        and quiescence paths use)."""
+        and quiescence paths use) but the ``keep`` sent last."""
         emitted = 0
-        while self._rounds:
+        while len(self._rounds) > keep:
             emitted += self._consume_round()
         return emitted
+
+    def _round_carried(self) -> bool:
+        """Whether the admit pass's chunk program carried this iteration's
+        round (a round the pacer makes longer follows it as the decode
+        program it always was)."""
+        return self._mixed_pass == self._admit_pass \
+            and self._steps_in_force(self._round_cap()) == 1
+
+    def _round_ahead(self) -> bool:  # hot-loop
+        """Before a pass waits for its first tokens: where its chunk
+        program carried this iteration's round, that program is the LAST
+        the device has, and the wait would drain the pipeline: the device
+        would stand idle from the program's end through the emit of its
+        round's tokens, the handlers' turn behind it and the next
+        iteration's dispatch (8 ms a prompt ended at 48 streams: PERF.md,
+        PR 58). So what the NEXT pass would send first goes out before the
+        wait, as every round goes out before the one in front of it is
+        consumed (``_decode_once``): a due prefill's chunk carrying the
+        next step, in a pass of its own (the prefills this pass's budget
+        deferred: a decode-only round in their place would read every
+        weight for a step that rides the next chunk program anyway), else
+        the next decode round, over the slots live now. A prompt that ends
+        in this pass joins the program after it. Returns whether a round
+        went out."""
+        if not (self.pipelined and self._round_carried()):
+            return False
+        with self._transfer_guard():
+            due = self._due_chunkings()
+            if due:
+                self._admit_pass += 1
+                self._prefill_passes += bool(self._advance_chunked(due, 1))
+                if self._mixed_pass == self._admit_pass:
+                    return True
+            active = [(i, s) for i, s in enumerate(self.slots)
+                      if s is not None]
+            if active and self._dispatch_round(active, paced=True):
+                self._ahead_pass = self._admit_pass
+                return True
+        return False
 
     def _plain_decode_once(self, active) -> int:  # hot-loop
         """Dispatch + consume one plain round synchronously — the

@@ -892,7 +892,10 @@ def _pool_block(bp, xs, groups, pools, layer, num_pages: dict,  # traced
     ``paged_mixed_step``). First norm, the operator of the block's kind
     (``decoder.block_kind``), residual and second norm, a group at a time
     (``_operator``: groups are different sequences, so their pages are
-    disjoint and each writes its rows before it attends); then
+    disjoint and each writes its rows before it attends; so are their
+    entries where a layer keeps a state a sequence: a parallel layer's
+    ``ssd_chunk`` leaves the chunk row's entry and ``ssd_step`` the slots',
+    two writers of one plane in one program, neither copying it); then
     ``_feed_forward`` ONCE over every group's tokens; residual:
     ``decoder._block_forward``'s skeleton, the only other copy. Returns (a
     group's output each, the planes as written).
@@ -914,8 +917,10 @@ def _pool_block(bp, xs, groups, pools, layer, num_pages: dict,  # traced
     each layer, the keys of the chunk in front written by the same layer of
     the same program, which is all causality asks, and computes what it
     computes a program later. Not so a layer that keeps a state a
-    sequence, a ring or a conv tail: the chunk behind needs the END state
-    of the chunk in front, which the rows of one program do not hand on.
+    sequence (a parallel layer's SSD state and conv tail among them, though
+    its chunk program carries the step: ``chunk_rows_follow``), a ring or a
+    conv tail: the chunk behind needs the END state of the chunk in front,
+    which the rows of one program do not hand on.
 
     ``pools`` holds every plane of the WHOLE pool viewed flat —
     ``k``/``v`` ``[L*P,pg,KV,Dh]`` (``[L*P,pg,KV*Dh]`` where the heads are
@@ -1673,7 +1678,8 @@ def paged_mixed_step(params: Params, cache: dict, chunk: jax.Array,  # traced
     ``key``: its arguments; ``cache`` carries "table"), two groups of rows
     through one layer scan (``_pool_forward``). In a layer each group runs
     the operator as it does in its own program (the chunk's rows first: they
-    are other sequences than the slots', so the pages written are disjoint),
+    are other sequences than the slots', so the pages written are disjoint,
+    and where a layer keeps a state a sequence, the entries),
     the feed-forward runs once over all their tokens, and behind the last
     layer the norm and the head run once, over the chunk rows' last valid
     positions and the slots' tokens (not at all where no row is ``wanted``
@@ -1690,8 +1696,9 @@ def paged_mixed_step(params: Params, cache: dict, chunk: jax.Array,  # traced
 
     if not chunk_carries_step(cache, cfg, None, attn_impl):
         raise NotImplementedError(
-            "a chunk and a decode step in one program: attention layers "
-            "over a pool the chunk meets in place")
+            "a chunk and a decode step in one program: layers of kind "
+            f"{' or '.join(sorted(STEP_CARRYING_KINDS))} over a pool the "
+            "chunk meets in place")
     table = cache["table"]
     on = live & ride
     (xc, xd), flat = _pool_forward(
@@ -2049,18 +2056,42 @@ def _chunk_in_place(cache: dict, cfg: DecoderConfig, lora,
             and chunk_attention_supported(*heads, k.dtype))
 
 
+#: The kinds of layer a chunk program can carry the decode step over
+#: (``chunk_carries_step``): each one's chunk operator and decode operator
+#: are held side by side in ONE program, against the chunk program and then
+#: the decode step, by tests of its own (tests/test_serve_mixed_program.py).
+#: "attention": per-head planes or a latent pool, whatever the feed-forward.
+#: "parallel": the same attention beside an SSD mixer whose state a sequence
+#: is ONE entry of planes of its own; the chunk's row and the slots' rows are
+#: different sequences, so ``ssd_chunk``'s one entry and ``ssd_step``'s are
+#: disjoint, as their pages are. The kinds that keep a ring or a conv tail,
+#: a linear or ssm state, or end in a stateless tail are the next names here
+#: (ROADMAP Speed 0).
+STEP_CARRYING_KINDS = frozenset({"attention", "parallel"})
+
+
 def chunk_carries_step(cache: dict, cfg: DecoderConfig, lora,
                        attn_impl: str) -> bool:
     """Whether a chunk program over this cache can carry the slots' decode
     step (``paged_mixed_step``): the kernels are on, the chunk meets the
     pool in place (``_chunk_in_place``: no int8 pool, no packed rows, no
-    call with LoRA) and every layer is of kind "attention" (per-head planes
-    or a latent pool, whatever its feed-forward): the kinds that keep a
-    state, a ring or a tail (a parallel layer's SSD state among them) wait
-    for their operators to be held side by side in one program (ROADMAP
-    Speed)."""
-    return (attn_impl == "pallas" and set(cfg.kinds) == {"attention"}
+    call with LoRA) and every layer is of a kind whose two operators are
+    held side by side in one program (``STEP_CARRYING_KINDS``)."""
+    return (attn_impl == "pallas"
+            and set(cfg.kinds) <= STEP_CARRYING_KINDS
             and _chunk_in_place(cache, cfg, lora, attn_impl))
+
+
+def chunk_rows_follow(cfg: DecoderConfig) -> bool:
+    """Whether the rows of ONE chunk program may be consecutive chunks of one
+    sequence (the engine's rows ahead, ``LLMEngine._rows_of``), given that
+    the chunk meets the pool in place: every layer of kind "attention",
+    which hands nothing but the keys in the pool from one chunk of a prompt
+    to the next (``_pool_block``). A layer that keeps a state a sequence (a
+    parallel layer's SSD state among them), a ring or a conv tail hands on
+    the END state of the chunk in front, which the rows of one program do
+    not."""
+    return set(cfg.kinds) == {"attention"}
 
 
 def chunk_reads_context(cache: dict, cfg: DecoderConfig, lora,

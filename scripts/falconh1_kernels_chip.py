@@ -26,7 +26,12 @@ since PR 52, the program over rows at ONE row (``engine._paged_chunks``: the
 head at the last valid position, under a ``cond``), with the prompt's end in
 the chunk and without, and the two forms on the same tokens into pages of
 their own: the row traffic reads against ``logits[C - 1]``, the planes
-written.
+written. Since PR 58 the cell's engine builds no program over rows: what its
+traffic runs is the chunk program that carries the slots' decode step
+(``engine._paged_mixed``), timed here with 47 slots riding at a context of
+800 and with none, a chunk that ends its prompt and one that does not
+(the parent's forms in the same call: ``cd .parent && python3
+scripts/falconh1_kernels_chip.py ...``).
 
 ``blind``: one prompt of the comparison's own longest size through the
 engine's chunk programs against the float32 reference on the chip, sound;
@@ -228,6 +233,38 @@ def main(argv=None) -> int:
                    lambda start: at_last(start, True)),
                   ("rows[1] last position, ends none",
                    lambda start: at_last(start, False))]
+
+    def carrying(start: int, ends: bool, ride: bool, context: int = 800):
+        """The chunk program that carries the step (PR 58) as the engine
+        dispatches it: the chunk on the LAST slot's pages, every other slot
+        live at ``context`` on pages of its own (47 riding)."""
+        riding = np.arange(slots) < slots - 1
+        state = {**eng._dstate.arrays,
+                 "tokens": tok, "live": jnp.asarray(riding),
+                 "lengths": jnp.where(riding, context - 1, 0).astype(
+                     jnp.int32),
+                 "budgets": jnp.full((slots,), 1 << 20, jnp.int32)}
+        tbl = jnp.asarray(np.where(
+            (np.arange(mpp)[None, :] < -(-context // pg))
+            & riding[:, None], table, -1))
+
+        def run():      # (the state and the table are donated: copies)
+            logits, _, eng.cache, _, _, _ = eng._paged_mixed(
+                eng.params, eng.cache, block,
+                jnp.asarray(table[slots - 1][None]),
+                jnp.asarray([start], jnp.int32),
+                jnp.asarray([C], jnp.int32), jnp.asarray([ends]),
+                jnp.asarray(ride), jax.tree.map(jnp.array, state),
+                jnp.array(tbl), jax.random.PRNGKey(0), "greedy")
+            return logits
+        return run
+
+    if getattr(eng, "_mixed", False):
+        forms += [(f"mixed[1] {'47 slots riding' if ride else 'none riding'}"
+                   f", ends {'its prompt' if ends else 'none'}",
+                   lambda start, ends=ends, ride=ride: carrying(
+                       start, ends, ride))
+                  for ride in (True, False) for ends in (True, False)]
     for start in (0, 2 * C) if "chunk" in args.parts else ():
         for name, form in forms:
             print(json.dumps({
@@ -240,7 +277,7 @@ def main(argv=None) -> int:
                     1e3 * (counts.prefill_flops(conf, start + C)
                            - counts.prefill_flops(conf, start)) / PEAK, 3),
                 **traced(form(start), args.calls, OPS, top=24)}), flush=True)
-    if "chunk" in args.parts and len(forms) > 1:
+    if "chunk" in args.parts and getattr(eng, "_lone_at_last", False):
         # the two forms on the same tokens, each into a slot's own pages and
         # entry: the one row traffic reads, and what the pool was left
         every = np.asarray(one_row(0)()[C - 1])

@@ -681,11 +681,15 @@ def test_mixed_program_compiles_for_v5e_with_both_kernels(cell_programs,
     ("k-exaone-236b-a23b.batch-mixedlength", False),    # window layers
     ("solar-open2-250b.batch-longdoc", False),          # linear layers
     ("phi-4-mini-flash.batch-reasoning", False),        # ssm, gmu, cross
-    ("falcon-h1-34b.batch-assistant", False),           # parallel layers
+    ("falcon-h1-34b.batch-assistant", True),    # parallel layers: PR 58
 ])
 def test_which_cells_chunk_program_carries_the_step(cell, carries):
     """The rule reads the stack and the pool (``paged.chunk_carries_step``),
-    at the cells' own shapes: every layer of kind "attention"."""
+    at the cells' own shapes: every layer of a kind whose chunk and decode
+    operators are held side by side in one program
+    (``paged.STEP_CARRYING_KINDS``: "attention" and, since PR 58,
+    "parallel"); the four that stay keep a conv tail, a ring, a linear
+    state, or an ssm state in front of a stateless tail."""
     from scripts.aot_weight_copies import serving_cell
 
     from kubeflow_tpu.serve.engine import serving_configs
@@ -985,42 +989,76 @@ def test_ssd_kernels_compile_for_v5e(chip):
 ASSISTANT = "falcon-h1-34b.batch-assistant"
 
 
-@pytest.mark.parametrize("program", ["decode", "chunk[1]", "rows[1]"])
+# PR 58: the assistant cell's chunk program carries the slots' decode step
+# ("mixed[1]": ``paged.paged_mixed_step`` over a stack of parallel layers, at
+# the cell's one width, ONE row: a dense model at 512 tokens sends one chunk
+# a program). A NEW program, pinned as PR 58 left it; it REPLACES "rows[1]",
+# the program over rows at one row that the cell's traffic ran since PR 52
+# (7f8fbb38f1f36822 on the parent): the engine no longer builds that one
+# (``engine._lone_at_last`` is false for an engine whose chunk program
+# carries the step). "decode" and "chunk[1]" (the ``[C, V]`` program of
+# callers outside the engine: the benchmark's ``correct``) lower to what they
+# lowered to on the parent (1fa4a2c), recorded there ahead of any edit.
+ASSISTANT_SINCE_PR58 = {
+    "decode": "cff3492965d294ad",
+    "chunk[1]": "7272d6eead7dff51",
+    "mixed[1]": "91ac85ae8f9e63aa",
+}
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk[1]", "mixed[1]"])
 def test_assistant_program_compiles_for_v5e_with_its_kernels(cell_programs,
                                                              program):
-    """The assistant cell's decode step and its two one-row chunk programs
-    (a dense model at 512 tokens sends one chunk a program: "rows[1]", the
-    program over rows at one row, is what its traffic runs since PR 52, the
-    head at ONE position under a conditional; "chunk[1]", every position's
-    logits, is what callers outside the engine drive) at the cell's real
-    sizes, parameters as the engine holds
-    them: each fits the chip beside its arguments, runs BOTH branches'
-    kernels in every layer's scan (``ssd_step`` and the decode kernel;
-    ``ssd_chunk`` and the chunk kernel) and copies no weight: the
-    in-projection is held as three lane-aligned leaves (as ONE ``[5120,
-    9248]`` matrix the decode program copied all five layers' 0.47 GB of it
-    in front of every step: this compile is what said so). What a chunk
+    """The assistant cell's three programs (a dense model at 512 tokens
+    sends one chunk a program: "mixed[1]", the chunk program that carries
+    the slots' decode step, is what its traffic runs since PR 58, the head
+    ONCE over the chunk row's last position and the slots' tokens under a
+    conditional; "chunk[1]", every position's logits, is what callers
+    outside the engine drive; "decode" the step alone) at the cell's real
+    sizes, parameters as the engine holds them: each lowers to its pinned
+    digest, fits the chip beside its arguments, runs BOTH branches' kernels
+    in every layer's scan (``ssd_step`` and the decode kernel; ``ssd_chunk``
+    and the chunk kernel; the mixed program all four, each ONE call site in
+    the scan's body) and copies no weight: the in-projection is held as
+    three lane-aligned leaves (as ONE ``[5120, 9248]`` matrix the decode
+    program copied all five layers' 0.47 GB of it in front of every step:
+    this compile is what said so). Nor a plane: the SSD state plane (``[5 x
+    48, 32, 256, 128]`` float32, 1.0 GB), written by two call sites in the
+    mixed program (``ssd_chunk``'s one entry by a scatter, ``ssd_step``'s
+    in place), and the K and V planes are no operand of any ``copy`` (the
+    conv tail plane, 7 MB, is laid out again on the way in and out of every
+    one of the three programs: standing, the parent's too). What a chunk
     program returns beyond the pool it was donated is ``[512, V]`` float32
-    (535 MB at a vocabulary of 261120) in the one form and ``[1, V]`` (1 MB)
-    in the other."""
-    from scripts.aot_weight_copies import serving_cell, weight_copies
+    (535 MB at a vocabulary of 261120) in the one form and ``[1, V]`` (1
+    MB) and a token a slot in the other."""
+    from scripts.aot_weight_copies import (
+        lowered_fingerprint, serving_cell, weight_copies,
+    )
 
-    lowered = cell_programs(ASSISTANT)[program]
-    assert set(cell_programs(ASSISTANT)) == {"decode", "chunk[1]", "rows[1]"}
+    programs = cell_programs(ASSISTANT, True, "last", True)
+    assert set(programs) == set(ASSISTANT_SINCE_PR58)
+    assert set(cell_programs(ASSISTANT)) == {"decode", "chunk[1]"}
+    lowered = programs[program]
+    assert lowered_fingerprint(lowered) == ASSISTANT_SINCE_PR58[program]
     compiled = lowered.compile()
     text = compiled.as_text()
-    for kernel in (("ssd_step", "paged_decode_attention")
-                   if program == "decode"
-                   else ("ssd_chunk", "paged_chunk_attention")):
-        assert _calls(text, kernel) >= 1, kernel
-    copied = {leaf for c in weight_copies(text, lowered.args_info[0][0])
-              for leaf in c["leaf"]}
-    assert copied == set(), copied
+    kernels = {"decode": ("ssd_step", "paged_decode_attention"),
+               "chunk[1]": ("ssd_chunk", "paged_chunk_attention")}
+    for kernel in kernels.get(program, sum(kernels.values(), ())):
+        assert _calls(text, kernel) == 1, kernel
+    cfg, batching = serving_cell(ASSISTANT)
+    copies = weight_copies(text, lowered.args_info[0][0])
+    assert {leaf for c in copies for leaf in c["leaf"]} == set()
+    # nothing the size of a layer's pages of K or V, or of a layer's SSD
+    # states (either is a fifth of its plane), is copied
+    page = batching.page_size * cfg.n_kv_heads * cfg.head_dim
+    state = cfg.ssd_heads * cfg.ssd_state * cfg.ssd_head_dim
+    assert max([math.prod(c["shape"]) for c in copies] + [0]) < min(
+        batching.max_pages * page, batching.max_batch_size * state)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.2e9
     assert mem.temp_size_in_bytes < 0.1e9
     if program != "decode":
-        cfg, batching = serving_cell(ASSISTANT)
         positions = batching.chunked_prefill_tokens \
             if program == "chunk[1]" else 1
         logits = positions * cfg.vocab_size * 4

@@ -8,7 +8,9 @@ forward and against the benchmark's plain reference in float32 and in
 bfloat16, the state an entry ends in, a comparison that sees each branch and
 the carried state, and through the engine: tokens against the full recompute
 (two chunks a program, and one chunk a program at one row: ISSUE 52),
-preemption, the counters and the refused options by name."""
+preemption, the counters and the refused options by name; and the one
+program that carries a chunk AND the slots' step over this stack (ISSUE 58)
+against the reference's carried states."""
 
 import dataclasses
 import functools
@@ -32,7 +34,8 @@ from kubeflow_tpu.serve.engine import LLMEngine, SamplingParams
 from kubeflow_tpu.serve.paged import (
     _chunk_in_place, _paged_decode_step, chunk_carries_step, copy_pages,
     engine_pool_shapes, first_page_ids, own_first_pages, paged_chunk_prefill,
-    pool_bytes_per_token, sequence_planes, state_bytes_per_sequence,
+    paged_mixed_step, pool_bytes_per_token, sequence_planes,
+    state_bytes_per_sequence,
 )
 
 PAGE, CHUNK, MPP, SLOTS = 8, 16, 16, 3
@@ -352,7 +355,10 @@ def test_chunked_prefill_then_decode_is_the_full_forward(impl, plen):
     for n in SSD_PLANES:      # entries 0 and 1 were nobody's: untouched
         assert float(jnp.abs(cache[n][:, :2] - 3.0).max()) == 0.0
     assert _chunk_in_place(dirty, cfg, None, impl) == (impl == "pallas")
-    assert not chunk_carries_step(dirty, cfg, None, impl)
+    # the kind rides since PR 58, where the chunk meets the pool in place
+    assert chunk_carries_step(dirty, cfg, None, impl) == (impl == "pallas")
+    assert not chunk_carries_step(_empty_pool(BASE, 80), BASE, None,
+                                  "pallas")      # heads of 16: gathered
 
 
 def test_chunks_that_end_inside_a_page_and_a_block_carry_the_state_too():
@@ -465,6 +471,84 @@ def test_the_entry_a_prompt_leaves_is_the_references_carried_state(impl):
     _, lost = _prefill(cfg, _empty_pool(cfg, 80), tokens, row, 101, impl,
                        between=dropped)
     assert apart(lost) > 1e-2
+
+
+def test_the_one_program_carries_both_sequences_states_to_the_references():
+    """ISSUE 58: a prompt's seven chunks through ``paged_mixed_step`` at one
+    row while another sequence's slot takes a decode step inside each of
+    those programs (``ssd_chunk`` writes the chunk row's entry, ``ssd_step``
+    the slot's, in one layer scan). Against what shares no code with either:
+    the prompt's entry is the state the plain reference's token-by-token
+    walk ends in after its 101 tokens, the slot's entry the one it ends in
+    after the slot's prompt and the seven tokens fed, both to 1e-5 of the
+    state's norm; the slot's seven tokens are the full forward's greedy
+    ones and the last chunk's logits its last position's; the third entry,
+    nobody's, is untouched. A program nothing rides with (``ride`` false)
+    moves the prompt's entry alone."""
+    cfg, impl = WIDE, "pallas"
+    params = _programs(cfg, impl)[0]
+    conf = {**REHEARSAL, "num_attention_heads": cfg.n_heads,
+            "num_key_value_heads": cfg.n_kv_heads, "head_dim": cfg.head_dim}
+    ta, tb = _tokens(23, 101), _tokens(24, 21)
+    row_a, row_b = _row(1), _row(2)
+    mixed = jax.jit(lambda c, t, tr, st, vl, ends, ride, tok, ln, lv:
+                    paged_mixed_step(
+                        params, c, t, tr, st, vl, ends, ride, tok, ln, lv,
+                        jnp.zeros((SLOTS,), jnp.float32),
+                        jnp.zeros((SLOTS,), jnp.int32),
+                        jnp.ones((SLOTS,), jnp.float32),
+                        jnp.full((SLOTS,), -1, jnp.int32),
+                        jnp.full((SLOTS,), 99, jnp.int32),
+                        jax.random.PRNGKey(0), cfg, sample_mode="greedy",
+                        attn_impl=impl))
+    dirty = {n: (jnp.full_like(a, 3.0) if n in SSD_PLANES else a)
+             for n, a in _empty_pool(cfg, 80).items()}
+    logits_b, cache = _prefill(cfg, dirty, tb, row_b, 21, impl)
+    fed = [int(jnp.argmax(logits_b[-1]))]
+    table = np.full((SLOTS, MPP), -1, np.int32)
+    table[0] = row_b
+    live = jnp.asarray([True, False, False])
+
+    def program(cache, pos, ride):
+        real = min(CHUNK, 101 - pos)
+        block = np.zeros((1, CHUNK), np.int32)
+        block[0, :real] = ta[pos:pos + real]
+        tok = np.zeros((SLOTS,), np.int32)
+        lens = np.zeros((SLOTS,), np.int32)
+        tok[0], lens[0] = fed[-1], 21 + len(fed) - 1
+        logits, out, cache, *_ = mixed(
+            {**cache, "table": jnp.asarray(table)}, jnp.asarray(block),
+            jnp.asarray(row_a)[None], jnp.asarray([pos], jnp.int32),
+            jnp.asarray([real], jnp.int32),
+            jnp.asarray([pos + real == 101]), jnp.asarray(ride),
+            jnp.asarray(tok), jnp.asarray(lens), live)
+        cache.pop("table")
+        return logits, out, cache
+
+    _, out, alone = program(cache, 0, False)
+    assert np.all(np.asarray(out) == -1)
+    for n in SSD_PLANES:        # entry 2 (the slot's) as the prefill left it
+        np.testing.assert_array_equal(alone[n][:, 2], cache[n][:, 2])
+        assert float(jnp.abs(alone[n][:, 1] - cache[n][:, 1]).max()) > 0.0
+    for pos in range(0, 101, CHUNK):
+        logits, out, cache = program(cache, pos, True)
+        assert np.asarray(out)[1:, 0].tolist() == [-1, -1]
+        fed.append(int(out[0, 0]))
+    stream = np.concatenate([tb, np.asarray(fed, np.int32)])
+    assert len(fed) == 8
+    full_b = _full(cfg, params, stream)
+    assert fed == [int(t) for t in jnp.argmax(full_b[20:28], axis=-1)]
+    np.testing.assert_allclose(logits[0], _full(cfg, params, ta)[100],
+                               rtol=3e-4, atol=3e-4)
+    with jax.default_matmul_precision("highest"):
+        want_a, want_b = (jnp.swapaxes(_reference().carried_states(
+            params, jnp.asarray(t), conf), 2, 3) for t in (ta, stream[:28]))
+    for want, entry in ((want_a, 1), (want_b, 2)):
+        got = cache["ssd_state"][:, entry]
+        assert float(jnp.linalg.norm(got - want)
+                     / jnp.linalg.norm(want)) < 1e-5, entry
+    for n in SSD_PLANES:        # entry 0 was nobody's
+        assert float(jnp.abs(cache[n][:, 0] - 3.0).max()) == 0.0
 
 
 @pytest.mark.parametrize("broken", ["ssd_zeroed", "attention_zeroed",
@@ -615,11 +699,13 @@ def _serve(engine, prompts, n):
 def test_engine_tokens_are_the_full_recomputes(prefills):
     """Four prompts on three slots: chunks interleaved with decode rounds, a
     slot and its entry handed to a second sequence; an iteration with a
-    chunk and live slots is two programs (the chunk program carries no
-    step over a state a sequence). An engine of one prefill at a time (the
-    assistant cell's way: one chunk a program, no step carried) sends every
-    chunk through the program over rows at one row: the ``[C,V]`` program
-    is never called and the head runs at one position a prompt."""
+    chunk and live slots is two programs (on the CPU the kernels are off
+    and the chunk goes the gathered way, which carries no step; where they
+    are on it does since PR 58: tests/test_serve_mixed_program.py). An
+    engine of one prefill at a time (one chunk a program, no step carried:
+    the assistant cell's way until PR 58) sends every chunk through the
+    program over rows at one row: the ``[C,V]`` program is never called and
+    the head runs at one position a prompt."""
     engine = _engine(max_concurrent_prefills=prefills)
     assert not engine._mixed and engine._ring == 1
     assert (engine._chunk_rows, engine._lone_at_last) == (

@@ -13,7 +13,15 @@ A program's spare rows carry the NEXT chunks of the prompts in it (ISSUE
 56): the program, two consecutive chunks of one prompt as two rows of one
 program against one after the other; the engine, a prompt alone two chunks
 a program, what keeps a row dead, what a pass between two programs finds,
-and which stacks never send a chunk ahead."""
+and which stacks never send a chunk ahead.
+
+The kind "parallel" rides too (ISSUE 58): attention beside an SSD mixer in
+every block, the mixer's state a sequence ONE entry of planes of its own,
+written by ``ssd_chunk`` for the chunk's row and by ``ssd_step`` for the
+slots' in one program. It is a fourth stack of ``KINDS`` wherever the case
+does not need rows ahead (``AHEAD``: a state is handed from a chunk's END to
+the next chunk's start, so a parallel stack never sends one), and has the
+cases of an engine ONE row wide, the assistant cell's, to itself."""
 
 import dataclasses
 import functools
@@ -38,7 +46,10 @@ from kubeflow_tpu.serve.paged import (
 
 PAGE, CHUNK, MPP = 16, 32, 8
 SLOTS = 4
-KINDS = ("dense", "dispatch", "latent")
+KINDS = ("dense", "dispatch", "latent", "parallel")
+# the stacks whose every layer is of kind "attention": a program's spare rows
+# may carry the NEXT chunks of the prompts in it (``paged.chunk_rows_follow``)
+AHEAD = KINDS[:3]
 
 
 def _config(kind: str):
@@ -48,6 +59,13 @@ def _config(kind: str):
     if kind == "dispatch":
         # Mixtral-like; the published factor drops rows of crowded chunks
         return preset("tiny-moe", head_dim=128, capacity_factor=1.25, **over)
+    if kind == "parallel":
+        # Falcon-H1-like: attention beside an SSD mixer in every block, one
+        # KV head of 128 (what the kernels take, interpreted here), blocks
+        # of 8 positions: the scene's chunks start inside a page AND end
+        # inside a block
+        return preset("tiny-falconh1", n_heads=2, n_kv_heads=1, head_dim=128,
+                      **over)
     return preset("tiny-glm", **over)
 
 
@@ -219,7 +237,7 @@ def test_where_the_slots_do_not_ride_it_is_the_chunk_program(kind):
 
 
 @pytest.mark.parametrize("ride", [False, True], ids=["alone", "riding"])
-@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("kind", AHEAD)
 def test_a_row_may_be_the_chunk_behind_the_row_in_front(kind, ride):
     """Two consecutive chunks of ONE prompt as the two rows of one program
     (same table row, the second start a chunk on): every layer writes both
@@ -375,13 +393,14 @@ def _recompute(kind: str, j: int, n: int = 12) -> list:
 
 
 @functools.lru_cache(maxsize=None)
-def _recompute_behind(kind: str, prompt: tuple, n: int) -> list:
+def _recompute_behind(kind: str, prompt: tuple, n: int,
+                      pad: int = 256) -> list:
     """``n`` greedy tokens behind ``prompt`` by full recompute: a whole
-    forward pass over what stands so far, padded to one length (causal: what
-    lies behind a position cannot move it). A capacity-dispatch stack drops
-    by the CHUNK a token stands in, which no whole forward pass does: its
-    prompt is served alone by the two programs (the gathered arm, one
-    prefill at a time)."""
+    forward pass over what stands so far, padded to one length, ``pad``
+    (causal: what lies behind a position cannot move it). A
+    capacity-dispatch stack drops by the CHUNK a token stands in, which no
+    whole forward pass does: its prompt is served alone by the two programs
+    (the gathered arm, one prefill at a time)."""
     if kind == "dispatch":
         eng = _engine(kind, "gather", max_concurrent_prefills=1)
         req = eng.submit(list(prompt), SamplingParams(
@@ -392,7 +411,7 @@ def _recompute_behind(kind: str, prompt: tuple, n: int) -> list:
     forward = _padded_forward(kind)
     toks = list(prompt)
     for _ in range(n):
-        block = np.zeros((256,), np.int32)
+        block = np.zeros((pad,), np.int32)
         block[:len(toks)] = toks
         toks.append(int(jnp.argmax(forward(jnp.asarray(block))[
             len(toks) - 1])))
@@ -421,11 +440,16 @@ def test_engine_tokens_are_the_full_recomputes(kind, kw):
     c = eng.counters()
     # fourteen chunks in eight programs; the first two find no slot live.
     # Prompts 2 and 4 each go alone for a while, a chunk ahead a program;
-    # prompts 0 and 4 end in an odd chunk beside a dead row
+    # prompts 0 and 4 end in an odd chunk beside a dead row. A parallel
+    # stack sends no chunk ahead: ten programs, and a prompt alone leaves
+    # the second row of each of its six dead
+    programs, riding, ahead, dead = (10, 8, 0, 6) if kind == "parallel" \
+        else (8, 6, 2, 2)
+    assert eng._ahead == (kind in AHEAD)
     assert (c["prefill_programs_dispatched"],
-            c["mixed_programs_dispatched"]) == (8, 6)
+            c["mixed_programs_dispatched"]) == (programs, riding)
     assert (c["prefill_chunks_dispatched"], c["prefill_rows_ahead"],
-            c["prefill_rows_dead"]) == (14, 2, 2)
+            c["prefill_rows_dead"]) == (14, ahead, dead)
     assert c["mixed_decode_rows_sum"] >= c["mixed_programs_dispatched"]
     # a program that carried a round is one round, one step, one program
     assert c["decode_rounds"] == c["decode_steps_dispatched"]
@@ -467,6 +491,10 @@ OTHER_STACKS = {
     "ssm": ("tiny-phi4flash", dict(max_seq_len=128, page_size=8,
                                    chunked_prefill_tokens=16,
                                    enable_prefix_caching=False)),
+    # the KIND rides since PR 58 (``KINDS`` above: one KV head of 128); the
+    # preset AS IT STANDS has heads of 16, which ``paged_chunk_attention``
+    # does not take, so its chunks stay on the gathered form
+    # (``paged._chunk_in_place``) and THAT keeps its two programs
     "parallel": ("tiny-falconh1", dict(max_seq_len=128, page_size=8,
                                        chunked_prefill_tokens=16,
                                        enable_prefix_caching=False)),
@@ -615,6 +643,276 @@ def test_the_programs_keep_their_names(monkeypatch):
         f"paged_mixed[2x{CHUNK},greedy]"]
 
 
+# -- an engine ONE row wide: the assistant cell's (ISSUE 58) ----------------------
+
+ONE_ROW = ("dense", "parallel")
+
+
+@pytest.mark.parametrize("ends", [True, False], ids=["ends", "goes-on"])
+@pytest.mark.parametrize("kind", ONE_ROW)
+def test_one_row_wide_it_is_the_chunk_program_then_the_decode_step(kind,
+                                                                   ends):
+    """The program as an engine that sends one chunk a program builds it
+    (``R`` = 1): a chunk that ends its prompt (19 tokens from position 64:
+    inside a page, and inside a block of the SSD scan) and one that does
+    not (32 from position 24, mid-page), a dead slot among the riding ones.
+    The row's logits where they are read, the round's tokens, every plane
+    (K and V pages; the SSD state and the conv tail of EVERY entry, the
+    chunk's and the slots') and the slots' carried state."""
+    _, _, state, rows = _scene(kind)
+    state = {**state, "live": np.asarray([True, False, True, True])}
+    want, got = _both_ways(kind, state, [rows[1] if ends else rows[0]])
+    if ends:
+        _close(got[0][0], want[0][0], "the logits of the row that ends")
+    np.testing.assert_array_equal(got[1], want[1])
+    assert (np.asarray(got[1])[:, 0] >= 0).tolist() == [True, False, True,
+                                                        True]
+    for name in want[2]:
+        _close(got[2][name], want[2][name], f"plane {name}")
+    for i, name in enumerate(("tokens", "lengths", "live", "budgets"), 3):
+        np.testing.assert_array_equal(got[i], want[i], err_msg=name)
+
+
+def _one_row_engine(kind, **kw):
+    """Chunks of 256 tokens are over the ridge for a dense feed-forward: one
+    chunk a program whatever ``max_concurrent_prefills`` says."""
+    cfg, params = _model(kind)
+    spec = dict(max_batch_size=3, max_seq_len=1024, page_size=PAGE,
+                chunked_prefill_tokens=256, enable_prefix_caching=False,
+                max_concurrent_prefills=2, paged_attn_impl="pallas",
+                decode_steps=1, prefill_interleave_steps=1)
+    return LLMEngine(cfg, BatchingSpec(**{**spec, **kw}), params=params)
+
+
+LONG = [_tokens(60 + i, n) for i, n in enumerate((300, 520, 270, 700))]
+LONG_ARRIVALS = {0: (0,), 2: (1, 2), 3: (3,)}
+
+
+def _long_recompute(kind: str, j: int, n: int = 10) -> tuple:
+    return tuple(_recompute_behind(kind, tuple(map(int, LONG[j])), n, 1024))
+
+
+@pytest.mark.parametrize("steps", [1, 2], ids=["one-program-a-pass",
+                                               "two-programs-a-pass"])
+@pytest.mark.parametrize("kind", ONE_ROW)
+def test_one_row_wide_every_chunk_beside_a_live_slot_takes_the_program(
+        kind, steps):
+    """Four prompts of two and three chunks on three slots, two prefills in
+    flight: greedy tokens are the full recompute's. With a round of ONE
+    step a pass sends one program and it carries the step; with a round of
+    two it sends two, the step rides the first, and the second, which no
+    step rides with, is the SAME program with every decode row dead
+    (``ride`` false), not the ``[C, V]`` program: that one is called only
+    for the first prompt, alone on an idle engine (``_otherwise_idle``), so
+    the head runs at one position a chunk or at none from then on. The
+    engine builds no program over rows beside it, and sends no chunk
+    ahead."""
+    eng = _one_row_engine(kind, decode_steps=steps,
+                          prefill_interleave_steps=steps)
+    assert eng._mixed and eng._chunk_rows == 1 and not eng._ahead
+    assert not eng._lone_at_last and not hasattr(eng, "_paged_chunks")
+    assert eng._paged_mixed._cache_size() == 1      # warmed when built
+    live_at_call, program = [], eng._paged_chunk
+    eng._paged_chunk = lambda *a: live_at_call.append(
+        sum(s is not None for s in eng.slots)) or program(*a)
+    sp = SamplingParams(max_new_tokens=10, temperature=0.0)
+    reqs = {}
+    for i in range(600):
+        for j in LONG_ARRIVALS.get(i, ()):
+            reqs[j] = eng.submit(list(map(int, LONG[j])), sp)
+        eng.step()
+        if len(reqs) == len(LONG) and all(
+                r.done.is_set() for r in reqs.values()):
+            break
+    assert [tuple(reqs[j].output_tokens) for j in range(len(LONG))] == [
+        _long_recompute(kind, j) for j in range(len(LONG))]
+    c = eng.counters()
+    assert live_at_call == [0, 0]                   # the first prompt's two
+    assert c["prefill_programs_dispatched"] == c[
+        "prefill_chunks_dispatched"] == 10
+    riding = c["mixed_programs_dispatched"]
+    assert riding == (8 if steps == 1 else 6)
+    assert c["prefill_rows_ahead"] == c["prefill_rows_dead"] == 0
+    # the two ``[C, V]`` chunks at every position, a riding program's one
+    # head over its chunk's row too, a program nothing rides with only
+    # where its chunk ends a prompt
+    assert c["prefill_programs_with_end"] == len(LONG)
+    silent = c["prefill_programs_dispatched"] - 2 - riding
+    assert silent == (0 if steps == 1 else 2)
+    assert 2 * 256 + riding <= c["prefill_head_positions"] \
+        <= 2 * 256 + riding + silent
+    assert eng._paged_mixed._cache_size() == 1
+    eng._allocator.assert_quiescent()
+
+
+@pytest.mark.parametrize("kind", ONE_ROW)
+def test_one_row_wide_a_burst_on_an_idle_engine_takes_the_program_too(kind):
+    """Three prompts at once on an idle engine: the pass finds no slot live
+    and sends program after program with no wait between them, so every
+    chunk takes the carrying program with ``ride`` false (``[1, V]`` back)
+    and none the ``[C, V]`` program, whose results would pile up (C x V
+    float32 each, allocated when sent). One prompt alone on an idle engine
+    still takes that one, under its bucket's name."""
+    eng = _one_row_engine(kind)
+    calls, program = [], eng._paged_chunk
+    eng._paged_chunk = lambda *a: calls.append(a[6]) or program(*a)
+    sp = SamplingParams(max_new_tokens=10, temperature=0.0)
+    reqs = [eng.submit(list(map(int, LONG[j])), sp) for j in (0, 1, 2)]
+    while not all(r.done.is_set() for r in reqs):
+        eng.step()
+    assert not calls
+    c = eng.counters()
+    assert c["prefill_programs_dispatched"] == 7
+    assert c["prefill_head_positions"] <= 7
+    assert [tuple(r.output_tokens) for r in reqs] == [
+        _long_recompute(kind, j) for j in (0, 1, 2)]
+    alone = eng.submit(list(map(int, LONG[3])), sp)     # 700: three chunks
+    while not alone.done.is_set():
+        eng.step()
+    assert calls == [16, 32, 64]                        # pages of 16
+    assert tuple(alone.output_tokens) == _long_recompute(kind, 3)
+    eng._allocator.assert_quiescent()
+
+
+@pytest.mark.parametrize("kind", ONE_ROW)
+def test_a_pass_that_ends_a_prompt_sends_the_next_round_before_it_waits(
+        kind):
+    """A prompt of one chunk beside a live stream: the pass's program
+    carries the stream's step AND ends the prompt, so the pass waits for
+    it (the first token). The next round goes out before that wait and is
+    still in flight when the iteration ends (the pipeline is not drained:
+    the device would stand idle through the emit and the next dispatch);
+    the prompt's stream joins the round after it. A pass whose chunk ends
+    nothing waits for nothing and sends nothing more. The tokens are the
+    full recompute's either way."""
+    eng = _one_row_engine(kind)
+    sp = SamplingParams(max_new_tokens=8, temperature=0.0)
+    first = eng.submit(list(map(int, LONG[2][:40])), SamplingParams(
+        max_new_tokens=60, temperature=0.0))
+    while first.first_token_time is None:
+        eng.step()
+    eng.step()
+    assert len(eng._rounds) == 1
+    before = eng.counters()
+    short = eng.submit(list(map(int, LONG[0])), sp)     # 300: two chunks
+    eng.step()
+    c = eng.counters()
+    # its first chunk ends nothing: one program, it carried the one round
+    assert c["mixed_programs_dispatched"] \
+        == before["mixed_programs_dispatched"] + 1
+    assert c["decode_rounds"] == before["decode_rounds"] + 1
+    assert short.first_token_time is None and len(eng._rounds) == 1
+    emitted = len(first.output_tokens)
+    eng.step()
+    # its second ends the prompt: the program carried a round, another went
+    # out ahead of the wait and is the one in flight; both earlier rounds
+    # were emitted in this iteration, the stream's first token too
+    c2 = eng.counters()
+    assert c2["mixed_programs_dispatched"] \
+        == c["mixed_programs_dispatched"] + 1
+    assert c2["decode_rounds"] == c["decode_rounds"] + 2
+    assert short.first_token_time is not None and len(eng._rounds) == 1
+    assert eng._rounds[0].active == [
+        (i, s) for i, s in eng._rounds[0].active if s.request is first]
+    assert len(first.output_tokens) == emitted + 2
+    while not (first.done.is_set() and short.done.is_set()):
+        eng.step()
+    assert tuple(short.output_tokens) == _long_recompute(kind, 0)[:8]
+    one = _one_row_engine(kind, max_concurrent_prefills=1,
+                          pipelined_decode=False)
+    want = one.submit(list(map(int, LONG[2][:40])), SamplingParams(
+        max_new_tokens=60, temperature=0.0))
+    while not want.done.is_set():
+        one.step()
+    assert first.output_tokens == want.output_tokens
+    eng._allocator.assert_quiescent()
+
+
+@pytest.mark.parametrize("kind", ONE_ROW)
+def test_a_pass_that_ends_a_prompt_sends_a_due_chunk_before_it_waits(kind):
+    """Two prompts in flight beside a live stream, one program a pass: the
+    pass that ends the first prompt has the second one's chunk deferred,
+    and sends it BEFORE it waits for the first token, in a pass of its own,
+    the stream's next step riding (not a decode-only round, which would
+    read every weight for a step the next chunk program carries anyway).
+    The tokens are the full recompute's."""
+    eng = _one_row_engine(kind)
+    first = eng.submit(list(map(int, LONG[2][:40])), SamplingParams(
+        max_new_tokens=60, temperature=0.0))
+    while first.first_token_time is None:
+        eng.step()
+    eng.step()
+    sp = SamplingParams(max_new_tokens=8, temperature=0.0)
+    a = eng.submit(list(map(int, LONG[2])), sp)         # 270: 256 + 14
+    b = eng.submit(list(map(int, LONG[0])), sp)         # 300: 256 + 44
+    before = eng.counters()
+    eng.step()
+    assert [ch.pos for ch in eng._chunkings] == [256, 0]
+    emitted = len(first.output_tokens)
+    eng.step()
+    c = eng.counters()
+    assert a.first_token_time is not None and b.first_token_time is None
+    assert [(ch.request, ch.pos) for ch in eng._chunkings] == [(b, 256)]
+    for name, n in (("prefill_programs_dispatched", 3),
+                    ("mixed_programs_dispatched", 3), ("prefill_passes", 3),
+                    ("decode_rounds", 3)):
+        assert c[name] == before[name] + n, name
+    assert len(eng._rounds) == 1 and len(first.output_tokens) == emitted + 2
+    while not all(r.done.is_set() for r in (first, a, b)):
+        eng.step()
+    assert tuple(a.output_tokens) == _long_recompute(kind, 2)[:8]
+    assert tuple(b.output_tokens) == _long_recompute(kind, 0)[:8]
+    c = eng.counters()
+    assert c["prefill_programs_dispatched"] == c["prefill_passes"] \
+        == before["prefill_passes"] + 4
+    eng._allocator.assert_quiescent()
+
+
+def test_one_row_wide_nothing_compiles_after_a_first_run():
+    """The parallel stack's program set is the engine's own: the mixed
+    program (warmed when built, no slot riding) and the decode program; a
+    second run of the same traffic compiles nothing."""
+    eng = _one_row_engine("parallel")
+    sp = SamplingParams(max_new_tokens=4, temperature=0.0)
+
+    def run():
+        reqs = [eng.submit(list(map(int, p)), sp) for p in LONG[:3]]
+        while not all(r.done.is_set() for r in reqs):
+            eng.step()
+
+    run()
+    compiles = CompileCounter()
+    compiles.start()
+    run()
+    assert compiles.stop() == 0, compiles.names
+    assert eng._paged_mixed._cache_size() == 1
+    c = eng.counters()
+    assert 0 < c["mixed_programs_dispatched"] < c[
+        "prefill_programs_dispatched"]
+
+
+def test_a_parallel_stack_sends_no_chunk_ahead():
+    """Two rows a program (a chunk under the ridge) and the step carried,
+    but a prompt alone goes ONE chunk a program: the chunk behind needs the
+    SSD state and the conv tail the chunk in front ENDS in, which the rows
+    of one program do not hand on (``paged.chunk_rows_follow``)."""
+    from kubeflow_tpu.serve.paged import chunk_rows_follow
+
+    assert [chunk_rows_follow(_model(kind)[0]) for kind in KINDS] == [
+        True, True, True, False]
+    eng = _engine("parallel")
+    assert eng._mixed and eng._chunk_rows == 2
+    assert not eng._ahead and not eng._rows_only
+    first = _beside_a_live_stream(eng)
+    before = _chunk_counts(eng)
+    got = _alone(eng)
+    programs, chunks, ahead, dead = (
+        a - b for a, b in zip(_chunk_counts(eng), before))
+    assert (programs, chunks, ahead, dead) == (5, 5, 0, 5)
+    assert got == _alone(_engine("parallel", max_concurrent_prefills=1))
+    assert not first.done.is_set()
+
+
 # -- a program's spare rows: the next chunks of the prompts in it (ISSUE 56) -----
 
 LONE = _tokens(21, 4 * CHUNK + 9)       # five chunks, the last of 9 tokens
@@ -654,7 +952,7 @@ def _odd_alone(kind: str) -> int:
     return int(kind == "latent")
 
 
-@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("kind", AHEAD)
 def test_a_prompt_alone_goes_two_chunks_a_program(kind):
     """Five chunks sent alone: three programs (two of two consecutive
     chunks, no slot riding, and one for the odd last chunk), the tokens of
@@ -705,7 +1003,7 @@ def _beside_a_live_stream(eng, n=60):
     return first
 
 
-@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("kind", AHEAD)
 def test_a_last_chunk_alone_leaves_its_row_dead(kind):
     """Three chunks beside a live stream: two in one program, then the last
     with nothing behind it, a dead row in the program of the one width."""
@@ -803,7 +1101,7 @@ def test_a_preemption_between_passes_registers_what_the_programs_wrote(kind):
     assert eng.kv_pages_in_use() == 0
 
 
-@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("kind", AHEAD)
 def test_a_prompt_alone_compiles_nothing_after_construction(kind):
     """The programs a prompt alone takes are the engine's own, run when it
     was built: the program of the one width with no slot riding and, where
