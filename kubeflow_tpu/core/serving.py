@@ -259,10 +259,9 @@ class BatchingSpec(BaseModel):
     paged_attn_impl: str = "auto"
     # Prompts prefill in chunks with decode interleaving; this many may
     # chunk concurrently (no head-of-line blocking between long prompts).
-    # Where one chunk leaves the model's weights under-used (an
-    # expert layer, a small chunk: engine.chunk_rows_per_weight), the
-    # chunks of all of them go to the device as ONE program a scheduler
-    # pass, so each weight is read once for the pass.
+    # Where one chunk leaves the model's weights under-used, the chunks of
+    # all of them go to the device as ONE program a scheduler pass
+    # (serve/chunk_programs.py: ``plan_chunks``).
     max_concurrent_prefills: int = 2
     # Every admission prefills in chunks of this many tokens (a multiple
     # of page_size: chunk boundaries are page boundaries).

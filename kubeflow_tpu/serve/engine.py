@@ -4,11 +4,10 @@ python/huggingfaceserver; SURVEY.md §3.2 "engine step loop").
 
 Design, driven by XLA's compilation model rather than CUDA streams:
 
-- **Recompile-free shapes.** Two compiled programs serve all traffic: one
-  decode step at a fixed slot count [B, 1], and one chunk prefill per
-  context bucket (powers of two of pages). Admission changes data (slot
-  contents, page-table rows), never shapes — XLA traces once, the MXU
-  sees the same tiles forever.
+- **Recompile-free shapes.** A fixed set of compiled programs serves all
+  traffic: the decode step at a fixed slot count [B, 1] and the chunk
+  programs (serve/chunk_programs.py). Admission changes data (slot contents,
+  page-table rows), never shapes — XLA traces once.
 - **One KV cache: the page pool** (serve/paged.py). [L, P, page, ...] per
   plane with a [B, mpp] page table and per-slot lengths. A slot is the
   unit of admission (continuous batching: new sequences join between
@@ -73,15 +72,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from kubeflow_tpu.core.serving import (
     BatchingSpec, QOS_DEFAULT, QOS_PRIORITY,
 )
+from kubeflow_tpu.serve.chunk_programs import (
+    ChunkPrograms, plan_chunks, program_key,
+)
 from kubeflow_tpu.serve.device_state import DEAD_SLOT, DecodeState
 from kubeflow_tpu.serve.pacing import RoundPacer, decode_ladder
 from kubeflow_tpu.serve.paged import (
     MOE_ROWS, SEQUENCE_PLANES, PageAllocator, PagePoolExhausted,
-    chunk_carries_step, chunk_reads_context, chunk_rows_follow,
-    context_bucket, engine_pool_shapes,
-    paged_chunk_prefill, first_page_ids, own_first_pages,
-    paged_decode_multi, paged_mixed_step, pool_bytes_per_token,
-    pool_shapes, ring_pages,
+    engine_pool_shapes, first_page_ids, own_first_pages, paged_chunk_prefill,
+    paged_decode_multi, pool_bytes_per_token, pool_shapes, ring_pages,
 )
 from kubeflow_tpu.serve.weight_layout import (
     relaid_bytes, relay, weight_formats,
@@ -342,64 +341,6 @@ def _committed(tree):
     """``tree`` with every array committed where it lies (no copy, no
     program)."""
     return jax.device_put(tree, jax.tree.map(lambda x: x.sharding, tree))
-
-
-def _program_key(name: str, *variant) -> str:
-    """A program variant's name in ``program_kernels`` and in
-    ``start_programs()``: the program and what tells its variants apart
-    (the token block's shape, the static arguments)."""
-    return f"{name}[{','.join(map(str, variant))}]"
-
-
-def _row0(out):
-    """A one-row chunk program's ([1,C,V] logits, cache) as ([C,V], cache)."""
-    return (out[0][0],) + tuple(out[1:])
-
-
-class _OneContext:
-    """The one-row chunk program of a pool whose chunk does not read its
-    context bucket (``paged.chunk_reads_context``): whatever bucket a call
-    names, it runs and lowers the program of ``context``, so a start traces,
-    loads and warms ONE program where it had one a bucket. A call with LoRA
-    takes the gathered form, which reads its bucket, and keeps it."""
-
-    def __init__(self, jitted, context: int, at: int = 6):
-        """``at``: where the bucket stands among the arguments (the program
-        over rows takes "this row ends its prompt" in front of it)."""
-        self.jitted, self.context, self.at = jitted, context, at
-
-    def _at_one(self, args):   # (p, c, t, tr, st, vl, ncp[, lora[, aidx]])
-        at = self.at
-        if len(args) > at + 1 and args[at + 1] is not None:
-            return args
-        return args[:at] + (self.context,) + args[at + 1:]
-
-    def __call__(self, *args):
-        return self.jitted(*self._at_one(args))
-
-    def lower(self, *args):
-        return self.jitted.lower(*self._at_one(args))
-
-
-#: Rows a bf16 weight matrix must multiply before the matrix work takes as
-#: long as reading the matrix: a row costs 2 FLOPs a parameter and the
-#: matrix 2 bytes a parameter, so rows = peak FLOP/s over peak bytes/s. On
-#: a v5e that is 197e12 / 819e9 = 240 rows; 256, the next whole tile. Below
-#: it a program is bound by its weights' bytes, and rows added to it are
-#: nearly free; at or above it they cost their own time.
-RIDGE_ROWS = 256
-
-
-def chunk_rows_per_weight(cfg: DecoderConfig, chunk: int) -> float:
-    """Rows the least-used weight matrix of the model multiplies in ONE
-    prefill chunk of ``chunk`` tokens: every token for a dense model, the
-    ``experts_per_token / num_experts`` share of them that one expert of an
-    expert layer sees (Mixtral at 512 tokens: 128; 4 of 64 experts: 32).
-    Against ``RIDGE_ROWS`` it decides whether the engine puts the chunks of
-    all in-flight prefills into one program."""
-    if not cfg.is_moe or cfg.moe_impl == "dense":   # every expert, every row
-        return chunk
-    return chunk * cfg.experts_per_token / cfg.num_experts
 
 
 # -- the engine ----------------------------------------------------------------
@@ -923,9 +864,6 @@ class LLMEngine:
 
         self._chunkings: list[_Chunking] = []   # lockfree: scheduler-confined
         self.max_concurrent_prefills = max(1, int(b.max_concurrent_prefills))
-        # Chunks one prefill program takes: 1 unless the program over
-        # several prompts' chunks is built below.
-        self._chunk_rows = 1
         pattn = b.paged_attn_impl
         if pattn == "auto":
             # Mesh mode: gather (pure XLA ops — GSPMD-partitionable);
@@ -965,125 +903,14 @@ class LLMEngine:
             with start(prof.ENGINE_START_POOL):
                 self.cache = _committed(self.cache)
 
-        def _chunk_rows_fn(p, c, t, tr, st, vl, ncp, lr, ai,
-                           logits_at="all", wanted=None):
-            return _pin2(
-                paged_chunk_prefill(
-                    p, c, t, tr, st, vl, cfg_prefill, context_pages=ncp,
-                    lora=lr, adapter_idx=ai, paged_attn_impl=pattn,
-                    logits_at=logits_at, wanted=wanted),
-                self._pin)
-
-        # ONE prompt's chunk: tokens [1,C], its table row, scalar start
-        # and valid length; [C,V] logits, every position's. For callers
-        # OUTSIDE the engine, which compare them all (the benchmark's
-        # ``correct``, the scripts); of the engine's traffic only a lone
-        # chunk of an engine that sends several chunks a program by the
-        # ridge, or a mixed engine's with no live slot, takes it
-        # (``_dispatch_chunks``).
-        self._paged_chunk = jax.jit(
-            lambda p, c, t, tr, st, vl, ncp, lr=None, ai=None: _row0(
-                _chunk_rows_fn(p, c, t, tr[None], st[None], vl[None],
-                               ncp, lr, ai)),
-            static_argnums=(6,), donate_argnums=(1,))
-        one_context = not chunk_reads_context(
-            self.cache, cfg_prefill, None, pattn)
-        if one_context:
-            self._paged_chunk = _OneContext(self._paged_chunk, self._mpp)
-        # Whether a chunk program CARRIES the decode step (the program is
-        # built further down, where its comment is): where the stack and
-        # the pool allow it (``chunk_carries_step``) and nothing rides the
-        # programs that it does not carry: adapter buffers, a speculative
-        # round.
-        self._mixed = (
-            chunk_carries_step(self.cache, cfg_prefill, None, pattn)
-            and b.speculative.mode == "off" and not b.lora.max_adapters)
-        # The chunks of ALL in-flight prefills in one program (tokens
-        # [B,C], a table row, a start, a valid length and "this row ends
-        # its prompt" a row), so a scheduler pass reads every weight once.
-        # It returns [B,V] logits, the head at each row's LAST valid
-        # position: the one row the engine samples from, and that only of
-        # a prompt's last chunk, so a program in which no row ends one
-        # runs no head at all (the head over every position was 7% of a
-        # long-context cell's device time and 634 MB a result at a
-        # vocabulary of 155k; a third of a one-row program's time and 535
-        # MB at 261k). It takes SEVERAL rows only where one chunk leaves
-        # the weights under-used (``chunk_rows_per_weight``), and is then
-        # dispatched at ONE static context, the whole table, and ONE
-        # width: a row's attention follows its own context whatever the
-        # table's length (the chunk kernels skip the pages behind their
-        # chunk; the gathered form's span ladder,
-        # layers._cached_attention_by_row), so a ladder of context buckets
-        # would spare only the gather of a pool that still takes the
-        # gathered form, and each further program, a bucket's or a
-        # width's, is loaded and run at every start (0.75 s warm, 5 s cold
-        # on a v5e: PERF.md, PR 29; 5.7 s for a width of the long-context
-        # cell's: PR 49, PR 53). Rows that no due prefill fills are not
-        # left to a narrower program: where the chunk meets the pool in
-        # place they carry the NEXT chunks of the prefills that are in the
-        # program (``_ahead`` below, ``_rows_of``).
-        # An engine that sends ONE chunk a program (a dense model at 512
-        # tokens, over the ridge; one prefill at a time) and carries no
-        # step in its chunk programs (where it does, that program takes
-        # this one's place: ``_dispatch_chunks``) sends every chunk through
-        # it all the same, as a group of one row (``_lone_at_last``): the
-        # engine reads one row of a chunk's logits or none, and the ``[C,
-        # V]`` program runs the head at all ``C``. So does, whatever the
-        # ridge says, a
-        # stack that ENDS in layers that keep no state
-        # (``cfg.stateless_tail``): the program over rows runs that tail
-        # at the one position a row whose logits are read, and not at all
-        # where no row ends its prompt (``paged._pool_forward``). A group
-        # of one is dispatched at its own context bucket, as the one-row
-        # program is: as many programs as that had, named alike.
-        tail_at_last = cfg_prefill.stateless_tail > 0
-        by_ridge = self.max_concurrent_prefills > 1 and chunk_rows_per_weight(
-            cfg_prefill, self.chunk_size) < RIDGE_ROWS
-        if (by_ridge or tail_at_last) and self.max_concurrent_prefills > 1:
-            self._chunk_rows = self.max_concurrent_prefills
-        self._lone_at_last = tail_at_last or (
-            self._chunk_rows == 1 and not self._mixed)
-        # Whether a program's spare rows take FURTHER chunks of the
-        # prefills in it (``_rows_of``): a row may be the chunk behind
-        # another row's, of the same prompt, where a layer writes every
-        # row's keys into the pool before any row attends and nothing but
-        # those keys passes from one chunk of a prompt to the next: every
-        # layer of kind "attention" (``paged.chunk_rows_follow``), met in
-        # place (``paged._pool_block``; ``chunk_carries_step`` tests that,
-        # and ``_mixed`` with it). A layer that keeps a state, a ring or a
-        # conv tail hands on the END state of the chunk in front, which one
-        # program cannot, whether or not its chunk program carries the step
-        # (a stack of parallel layers: it does, and sends no chunk ahead);
-        # the gathered form (an int8 pool, a call with LoRA, the "gather"
-        # arm) attends over what the pool held BEFORE the program.
-        # Observed, not set: there is one algorithm, "fill the program the
-        # pass sends", and where this is false a group is the due chunks
-        # and no more.
-        self._ahead = (self._mixed and self._chunk_rows > 1
-                       and chunk_rows_follow(cfg_prefill))
-        # What such an engine's traffic is left with for the one-row
-        # ``[C, V]`` program is a prompt's odd LAST chunk with no slot
-        # live, at any start, and a prompt sent alone, a caller's way to
-        # warm every bucket, reaches ONE bucket with it now (its other
-        # chunks go two a program). So the engine keeps that program only
-        # where it is ONE program whatever bucket a call names, and runs it
-        # under every bucket's name when it is built
-        # (``_warm_lone_program``: one load; the names are what callers
-        # outside ask ``program_kernels`` for); where it would be a program
-        # a bucket (a latent pool: 2-3 s each to trace and load at every
-        # start, PERF.md PR 56) that chunk too goes through the program of
-        # the one width, beside a dead row (``_dispatch_chunks``).
-        self._rows_only = self._ahead and not one_context
-        if by_ridge or self._lone_at_last:
-            self._paged_chunks = jax.jit(
-                lambda p, c, t, tr, st, vl, ends, ncp, lr=None, ai=None:
-                _chunk_rows_fn(p, c, t, tr, st, vl, ncp, lr, ai, "last",
-                               ends),
-                static_argnums=(7,), donate_argnums=(1,))
-            if self._lone_at_last and one_context:
-                # (a group of one names its own bucket: ``_dispatch_chunks``)
-                self._paged_chunks = _OneContext(self._paged_chunks,
-                                                 self._mpp, at=7)
+        # Which chunk program carries a pass's chunks: ``ChunkPlan.send``
+        # (serve/chunk_programs.py). ``_paged_chunk``: the ``[C, V]`` program,
+        # for callers OUTSIDE the engine (the benchmark's ``correct``).
+        self._plan = plan_chunks(cfg_prefill, self.cache, b, pattn)
+        self._programs = ChunkPrograms(
+            self, self._plan, cfg_prefill, pattn,
+            self._introspected if on_tpu else None)
+        self._paged_chunk = self._programs.ask("lone")
 
         def _paged_decode_fn(p, c, st, tbl, key, n, m, lr=None,
                              _impl=pattn):
@@ -1107,40 +934,8 @@ class LLMEngine:
         self._paged_decode_n = jax.jit(
             _paged_decode_fn, static_argnums=(5, 6),
             donate_argnums=(1, 2, 3))
-        # The chunk program that CARRIES the decode step: an admit pass
-        # that has a chunk program to send while slots are live sends the
-        # chunks' rows and the slots' rows in one program, one step of the
-        # round, and every weight is read once an iteration where a chunk
-        # program and a decode step each read it (a step of a sparse model
-        # is its weights' bytes: 40% of the batch cell's iteration). Built
-        # where the stack and the pool allow it (``chunk_carries_step``)
-        # and nothing rides the programs that it does not carry: adapter
-        # buffers, a speculative round. There it is also the program over
-        # several prompts' rows (``ride`` false: every decode row dead) and
-        # has that one width, so such an engine loads no program more than
-        # it did. It is jitted as the chunk programs are, a lambda: what
-        # finds a decode step by its module's name finds decode-only steps.
-        # (``_mixed`` itself: above, in front of the program over rows.)
         self._mixed_pass = -1    # lockfree: scheduler-confined (the admit pass that sent a round)
         self._ahead_pass = -1    # lockfree: scheduler-confined (the admit pass that sent the NEXT round too: ``_round_ahead``)
-
-        def _mixed_fn(p, c, t, tr, s0, vl, ends, ride, st, tbl, key, m):
-            logits, out, cache, tokens, lengths, live, budgets = \
-                paged_mixed_step(
-                    p, {**c, "table": tbl}, t, tr, s0, vl, ends, ride,
-                    st["tokens"], st["lengths"], st["live"], st["temps"],
-                    st["top_k"], st["top_p"], st["stops"], st["budgets"],
-                    key, cfg_prefill, sample_mode=m, attn_impl=pattn)
-            table = cache.pop("table")
-            st = {**st, "tokens": tokens, "lengths": lengths,
-                  "live": live, "budgets": budgets}
-            rows = cache[MOE_ROWS] + 0 if MOE_ROWS in cache else None
-            return logits, out, self._pin(cache), st, table, rows
-
-        self._paged_mixed = jax.jit(
-            lambda p, c, t, tr, s0, vl, ends, ride, st, tbl, key, m:
-            _mixed_fn(p, c, t, tr, s0, vl, ends, ride, st, tbl, key, m),
-            static_argnums=(11,), donate_argnums=(1, 8, 9))
         # Scheduler-confined state (the whole block below): mutated ONLY
         # on the scheduler thread (or by step() when no loop runs — the
         # unthreaded mode never coexists with start()). Cross-thread
@@ -1232,7 +1027,7 @@ class LLMEngine:
                 self._kv_copy_pages([0], [-1])
                 return self.cache
 
-            self._warm(_program_key("kv_copy_pages", 1), cow_copy)
+            self._warm(program_key("kv_copy_pages", 1), cow_copy)
         self._sampler = jax.jit(_sample_batch, static_argnums=(5,))
         # Steps a decode dispatch: sampling happens on-device, and the
         # while_loop exits early when every slot finishes. The two options
@@ -1246,17 +1041,10 @@ class LLMEngine:
             self.decode_steps, self.prefill_interleave_steps))
 
         if on_tpu:
-            # What the chip is given, per program variant, for
-            # /debug/device (_introspected). Wrapped HERE, by name, so the
-            # jit constructors above keep the exact shape `kftpu lint`'s
-            # donation / dispatch-signature rules read.
-            for attr, name in (("_paged_chunk", "paged_chunk_prefill"),
-                               ("_paged_chunks", "paged_chunk_prefill"),
-                               ("_paged_mixed", "paged_mixed"),
-                               ("_paged_decode_n", "paged_decode")):
-                if hasattr(self, attr):
-                    setattr(self, attr,
-                            self._introspected(name, getattr(self, attr)))
+            # (wrapped HERE: the jit constructor above keeps the shape
+            # `kftpu lint`'s donation / dispatch-signature rules read)
+            self._paged_decode_n = self._introspected(
+                "paged_decode", self._paged_decode_n)
 
         # Speculative decoding (draft + batched verify; serve/spec_decode.py).
         # Greedy rounds draft k tokens per slot and verify all k+1 positions
@@ -1464,14 +1252,11 @@ class LLMEngine:
         # None until stop() runs; False = the scheduler thread outlived its
         # join timeout and is leaked (it may hold live device buffers).
         self.stopped_clean: Optional[bool] = None
-        if self._mixed or self._chunk_rows > 1:
-            self._warm_rows_program()
-        if self._ahead and not self._rows_only:
-            self._warm_lone_program()
+        self._programs.warm(self._warm)
         self._warm_decode_ladder()
         # After the ladder: the state lies where a program left it, as
         # every sync of traffic's will find it.
-        self._warm(_program_key("state_sync", self.num_slots, self._mpp),
+        self._warm(program_key("state_sync", self.num_slots, self._mpp),
                    self._dstate.warm)
         if self._weights_relaid_bytes:
             self._warm_first_tokens()
@@ -1499,7 +1284,7 @@ class LLMEngine:
         their first use. The key is not drawn from: a sampled stream is
         what it was."""
         for k in self._pacer.ladder:
-            self._warm(_program_key("paged_decode", k, "greedy"),
+            self._warm(program_key("paged_decode", k, "greedy"),
                        lambda: self._dispatch_decode(k, "greedy", self._rng))
 
     def _warm_first_tokens(self) -> None:
@@ -1514,83 +1299,10 @@ class LLMEngine:
         greedy = SamplingParams(temperature=0.0)
         width = 1
         while width <= self.num_slots:
-            self._warm(_program_key("sample_first", width, "greedy"),
+            self._warm(program_key("sample_first", width, "greedy"),
                        lambda: self._sample_first(
                            [row] * width, [greedy] * width, self._rng))
             width *= 2
-
-    def _warm_rows_program(self) -> None:
-        """Compile and run once, now, the program over several prompts'
-        chunks (where the engine built it, the chunk program that carries
-        the decode step: greedy, no slot riding, the slots' state comes
-        back as it went in), on DEAD rows: no valid position, no page,
-        nothing is written. Traffic reaches several concurrent prefills, or
-        a chunk beside a live slot, only where arrivals fall so, and no
-        warm-up of a caller's can be relied on to; the program set is the
-        engine's own, and fixed from here on. The state and the pool go in
-        as traffic's dispatches hand them in (committed where the weights
-        are); the key is not drawn from. Also warms the read of each row's
-        logits."""
-        rows, C = self._chunk_rows, self.chunk_size
-        dead = (jnp.zeros((rows, C), jnp.int32),
-                jnp.full((rows, self._mpp), -1, jnp.int32),
-                jnp.zeros((rows,), jnp.int32), jnp.zeros((rows,), jnp.int32),
-                jnp.zeros((rows,), jnp.bool_))
-
-        def run():
-            if self._mixed:
-                logits, _ = self._send_mixed(*dead)
-            else:
-                lora = () if self._lora is None else (
-                    self._lora.buffers, jnp.full((rows,), -1, jnp.int32))
-                logits, self.cache = self._paged_chunks(
-                    self.params, self.cache, *dead, self._mpp, *lora)
-            return [logits[r] for r in range(rows)]
-
-        self._warm(_program_key("paged_mixed", f"{rows}x{C}", "greedy")
-                   if self._mixed else
-                   _program_key("paged_chunk_prefill", f"{rows}x{C}",
-                                self._mpp), run)
-
-    def _warm_lone_program(self) -> None:
-        """Compile or load, and run, now, the one-row ``[C, V]`` chunk
-        program under every context bucket's name a chunk can call it by,
-        on a DEAD row (nothing is written), as traffic's dispatches hand it
-        its arguments: ONE program (``_OneContext``), run once a name. An
-        engine that sends chunks ahead needs this of itself (``_ahead``,
-        ``__init__``): no caller's warm-up reaches the names any more."""
-        C = self.chunk_size
-        block = jnp.zeros((1, C), jnp.int32)
-        row = jnp.full((self._mpp,), -1, jnp.int32)
-        for ctx in sorted({context_bucket(pos, C, self.page_size, self._mpp)
-                           for pos in range(0, self.max_len,
-                                            self.page_size)}):
-            def run(ctx=ctx):
-                logits, self.cache = self._paged_chunk(
-                    self.params, self.cache, block, row, jnp.int32(0),
-                    jnp.int32(0), ctx)
-                return logits
-
-            self._warm(_program_key("paged_chunk_prefill", f"1x{C}", ctx),
-                       run)
-
-    def _send_mixed(self, chunk, table, start, valid, ends,
-                    mode: Optional[str] = None):
-        """Enqueue the chunk program that carries the decode step over the
-        device-resident state and adopt the pool and the state it returns.
-        ``mode``: the sampling mode of the live slots' step where they ride
-        (a key is drawn); None where none does (every decode row dead:
-        greedy, the key not drawn from). Returns (the chunk rows' logits,
-        (the round's token buffer, the expert rows' sums as the program
-        leaves them))."""
-        ride = mode is not None
-        logits, out, self.cache, st, tbl, rows = self._paged_mixed(
-            self.params, self.cache, jnp.asarray(chunk), jnp.asarray(table),
-            jnp.asarray(start), jnp.asarray(valid), jnp.asarray(ends),
-            jnp.asarray(ride), self._dstate.arrays, self._dstate.table,
-            self._next_key() if ride else self._rng, mode or "greedy")
-        self._dstate.adopt(st, tbl)
-        return logits, (out, rows)
 
     # -- mesh-mode helpers -----------------------------------------------------
 
@@ -2150,7 +1862,7 @@ class LLMEngine:
             variant = (["x".join(map(str, args[2].shape))]
                        if isinstance(args[2], jax.Array) else [])
             variant += [a for a in args if isinstance(a, (int, str))]
-            key = _program_key(name, *variant)
+            key = program_key(name, *variant)
             if key not in self.program_kernels:
                 self.program_kernels[key] = lowered_kernel_calls(
                     jitted, *args)
@@ -2291,140 +2003,60 @@ class LLMEngine:
     def _dispatch_chunks(
             self, group: "list[tuple[_Chunking, int]]") -> None:
         """ONE program for the chunks in ``group`` (their pages are
-        reserved): row ``r`` carries the chunk of ``group[r]``'s prefill that
-        starts at ``group[r]``'s position, its tokens, table row, start,
-        valid length and whether the chunk ends its prompt (only then are
-        the row's logits read). A row is the next chunk of a prefill or, of
-        a prefill that has a row in front of it already, the chunk after
-        that one (``_rows_of``); what is counted and said of a row (the
-        span's ``context`` and ``selected``, the states started, the tails
-        written) is taken at the row's own start. One prefill alone goes
-        through the program over rows as a group of one row, at its own
-        context bucket, where the engine sends one chunk a program and
-        carries no step in it, or its stack ends in a stateless tail
-        (``_lone_at_last``, ``__init__``): no chunk of such an engine's
-        traffic runs the head at a position nobody reads. It takes the
-        one-row ``[C, V]`` program only as the lone chunk of an engine that
-        sends several chunks a program by the ridge, or of a mixed engine
-        with no live slot (where that engine sends chunks ahead: its odd
-        last chunk, and only where the one-row program is ONE program
-        whatever the bucket: ``_rows_only``, ``__init__``).
-
-        Where the engine built the chunk program that carries the decode
-        step (``_mixed``) and a slot is live, the pass's first program
-        carries the live slots' step, the round of this iteration
-        (``_ready_round``: their pages, their state's sync, as before any
-        round), and says so in both dispatch spans; its rounds' tokens go
-        the way every round's go (``_rounds``). That program has ONE width,
-        as many rows as the engine sends chunks together (one program to
-        load, not two; a prefill alone fills the rows no other prefill wants
-        with its own next chunks where the stack lets a chunk follow a chunk
-        inside one program, ``_ahead``, and a row stays dead only behind a
-        prompt's last chunk or where the pool has no page for it); such an
-        engine sends several prompts' chunks through it too, no slot
-        riding. Where that width is ONE row (a dense model over the ridge:
-        the chat cell's, the assistant cell's) a chunk that no step rides
-        with (the second program of a pass whose round has several steps; a
-        pass that finds no slot live but several prompts) is that program
-        again with every decode row dead (``ride`` false: the head at the
-        chunk's last position if it ends its prompt, else nowhere), and the
-        ``[C, V]`` program is left to a prompt sent alone to an engine with
-        nothing else to do (``_otherwise_idle``)."""
+        reserved; which program, how wide: ``ChunkPlan.send``): row ``r``
+        carries the chunk of ``group[r]``'s prefill that starts at
+        ``group[r]``'s position, the next chunk of a prefill or the one after
+        the row in front (``_rows_of``); what is counted and said of a row is
+        taken at its own start, and its logits are read only if it ends its
+        prompt. Where the program carries the decode step and a slot is live,
+        the pass's first program carries the round of this iteration
+        (``_ready_round``, as before any round) and says so in both dispatch
+        spans; its tokens go the way every round's go (``_rounds``)."""
         C = self.chunk_size
         ride = None
-        if self._mixed and self._mixed_pass != self._admit_pass:
+        if self._plan.carries_step and self._mixed_pass != self._admit_pass:
             ride = self._ready_round(
                 [(i, s) for i, s in enumerate(self.slots) if s is not None],
                 1)
-        # Several chunks, or one that a step rides with, go together in the
-        # program of the engine's one width (rows past the group dead); so
-        # does every chunk where the engine keeps no one-row program for
-        # its traffic (``_rows_only``), and, where that width is ONE row, a
-        # chunk no step rides with unless the engine has nothing else to do
-        # (``_otherwise_idle``): the same rows, no dead row beside them,
-        # the head at one position or none where the ``[C, V]`` program
-        # runs it at all ``C`` and returns them all.
-        together = len(group) > 1 or ride is not None or self._rows_only \
-            or (self._mixed and self._chunk_rows == 1
-                and not self._otherwise_idle())
-        rows = self._chunk_rows if together else 1
-        by_rows = together or self._lone_at_last
+        sent = self._plan.send(len(group), ride is not None,
+                               self._otherwise_idle())
+        rows, by_rows = sent.rows, sent.program != "lone"
         # a row: (its prefill, its start, its real tokens)
         group = [(ch, pos, min(C, len(ch.request.prompt_tokens) - pos))
                  for ch, pos in group]
         ends = [pos + real == len(ch.request.prompt_tokens)
                 for ch, pos, real in group]
-        chunk = np.zeros((rows, C), np.int32)
-        for r, (ch, pos, real) in enumerate(group):
-            chunk[r, :real] = ch.request.prompt_tokens[pos:pos + real]
-        lora = () if self._lora is None else (
-            self._lora.buffers,
-            jnp.asarray(np.asarray(
-                [self._slot_aidx[ch.slot] for ch, _, _ in group]
-                + [-1] * (rows - len(group)), np.int32)))
+        packed = self._programs.pack(
+            [(ch.request.prompt_tokens[pos:pos + real], self._table[ch.slot],
+              pos, end) for (ch, pos, real), end in zip(group, ends)], rows)
         sparse = {}
+        if self.cfg.index_topk or prof.active():
+            # the keys the chunks' queries can see (query ``t``: ``t + 1``)
+            sparse["context"] = sum(real * pos + real * (real + 1) // 2
+                                    for _, pos, real in group)
         if self.cfg.index_topk:
-            # keys the chunks' queries can see (query ``t``: ``t + 1``) and
-            # those of them the indexer selects (``min(index_topk, t + 1)``)
-            sparse = {"context": sum(
-                real * pos + real * (real + 1) // 2
-                for _, pos, real in group),
-                "selected": sum(_keys_selected(
-                    pos, real, self.cfg.index_topk)
-                    for _, pos, real in group)}
+            # and those the indexer selects (``min(index_topk, t + 1)``)
+            sparse["selected"] = sum(
+                _keys_selected(pos, real, self.cfg.index_topk)
+                for _, pos, real in group)
             self._dsa_keys_visible += sparse["context"]
             self._dsa_keys_selected += sparse["selected"]
-        elif prof.active():
-            # the (query, key) pairs the chunks' queries can see
-            sparse = {"context": sum(
-                real * pos + real * (real + 1) // 2
-                for _, pos, real in group)}
         with self._phase(prof.ENGINE_PREFILL_DISPATCH, prof.active() and {
                 "slot": group[0][0].slot, "pos": group[0][1],
                 "chunks": len(group), **sparse, **self._rows_chosen()}):
-            if by_rows:
-                # Rows past the group are DEAD: no valid position, no page.
-                table = np.full((rows, self._mpp), -1, np.int32)
-                start = np.zeros((rows,), np.int32)
-                valid = np.zeros((rows,), np.int32)
-                wanted = np.zeros((rows,), np.bool_)
-                for r, (ch, pos, real) in enumerate(group):
-                    table[r] = self._table[ch.slot]
-                    start[r], valid[r], wanted[r] = pos, real, ends[r]
-            if self._mixed and together:
-                active, mode, gap, context, attrs = ride or (None,) * 5
-                with self._phase(prof.ENGINE_DECODE_DISPATCH,
-                                 prof.active() and attrs) \
-                        if ride else contextlib.nullcontext():
-                    logits, sent = self._send_mixed(
-                        chunk, table, start, valid, wanted, mode)
-                if ride:
-                    self._note_round(*sent, active, 1, self._round_cap(),
-                                     gap, context, alone=False)
-                    self._mixed_pass = self._admit_pass
-                    self._mixed_programs_dispatched += 1
-                    self._mixed_decode_rows_sum += len(active)
-            elif by_rows:
-                logits, self.cache = self._paged_chunks(
-                    self.params, self.cache, jnp.asarray(chunk),
-                    jnp.asarray(table), jnp.asarray(start),
-                    jnp.asarray(valid), jnp.asarray(wanted),
-                    self._mpp if rows > 1 else context_bucket(
-                        group[0][1], C, self.page_size, self._mpp),
-                    *lora)
-            else:
-                # Static context bucket (next power of two covering the
-                # pages this chunk can see): chunk cost tracks its position,
-                # not max_len, with a log-bounded trace set. The chunk's
-                # writes address per token off the table row, so the
-                # position may sit mid-page (the radix COW tail resume).
-                ch, pos, real = group[0]
-                logits, self.cache = self._paged_chunk(
-                    self.params, self.cache, jnp.asarray(chunk),
-                    jnp.asarray(self._table[ch.slot]), jnp.int32(pos),
-                    jnp.int32(real),
-                    context_bucket(pos, C, self.page_size, self._mpp),
-                    *lora)
+            active, mode, gap, context, attrs = ride or (None,) * 5
+            with self._phase(prof.ENGINE_DECODE_DISPATCH,
+                             prof.active() and attrs) \
+                    if ride else contextlib.nullcontext():
+                logits, carried = self._programs.send(
+                    sent, packed, mode,
+                    [self._slot_aidx[ch.slot] for ch, _, _ in group])
+            if ride:
+                self._note_round(*carried, active, 1, self._round_cap(),
+                                 gap, context, alone=False)
+                self._mixed_pass = self._admit_pass
+                self._mixed_programs_dispatched += 1
+                self._mixed_decode_rows_sum += len(active)
         self._prefill_programs_dispatched += 1
         self._prefill_row_programs_dispatched += rows > 1
         self._prefill_programs_with_end += any(ends)
@@ -2464,16 +2096,8 @@ class LLMEngine:
 
     def _otherwise_idle(self) -> bool:
         """Whether the prefill being sent is all the engine has to do: no
-        slot live, no other prefill in flight, nobody waiting. Only then
-        does a mixed engine one row wide leave a chunk to the one-row ``[C,
-        V]`` program (a prompt sent alone to an idle engine: a caller's way
-        to reach every context bucket's name, and nobody waits on it). A
-        pass that finds no slot live but more prompts (a burst after
-        idleness: a closed loop's first pass) sends program after program
-        without a wait between them, and every ``[C, V]`` result, ``C x V``
-        float32, is allocated when its program is sent: 48 one-chunk
-        prompts at a vocabulary of 261120 read 15.5 GB of a chip's 16 where
-        the engine holds 11.7 (PERF.md, PR 58)."""
+        slot live, no other prefill in flight, nobody waiting (a prompt sent
+        alone to an idle engine; a burst after idleness is not)."""
         return (all(s is None for s in self.slots)
                 and len(self._chunkings) == 1 and not self._backlog
                 and self.waiting.empty())
@@ -2482,22 +2106,21 @@ class LLMEngine:
                  ) -> "list[tuple[_Chunking, int]]":
         """The rows of the program that carries the due chunks of ``group``
         (pages reserved): a (prefill, start) each, the prefills' next chunks
-        first. Where the program has rows to spare and the engine may fill
-        them (``_ahead``), they go to the chunks AFTER those, of the same
-        prefills, the first of ``group`` first (the oldest of the highest
-        class: it finishes soonest that way): ``pos + C``, ``pos + 2C``, ...
-        while the prompt has tokens left there (so the row in front is a
-        whole chunk) and its pages can be had. A further chunk whose pages
-        cannot be had leaves its row dead; it is no stall (the chunk that
-        was due has its pages) and is due itself in the next pass."""
+        first. Spare rows that the engine may fill (``ChunkPlan.ahead``) go to
+        the chunks AFTER those, of the same prefills, the first of ``group``
+        first (the oldest of the highest class: it finishes soonest that
+        way): ``pos + C``, ``pos + 2C``, ... while the prompt has tokens left
+        there and its pages can be had. A further chunk whose pages cannot
+        be had leaves its row dead; it is no stall (the chunk that was due
+        has its pages) and is due itself in the next pass."""
         rows = [(ch, ch.pos) for ch in group]
-        if not self._ahead:
+        if not self._plan.ahead:
             return rows
         C = self.chunk_size
         for ch in group:
             plen = len(ch.request.prompt_tokens)
             pos = ch.pos + C
-            while len(rows) < self._chunk_rows and pos < plen \
+            while len(rows) < self._plan.rows and pos < plen \
                     and self._ensure_pages(ch.slot, min(pos + C, plen)):
                 rows.append((ch, pos))
                 pos += C
@@ -2506,18 +2129,17 @@ class LLMEngine:
     def _advance_chunked(self, due: "Optional[list[_Chunking]]" = None,
                          programs: Optional[int] = None) -> int:
         """The next chunk of the in-flight chunked prefills in ``due`` (all
-        of them unless given; decode steps run between calls — that's the
-        whole point), in that order, as few programs as the engine has rows
-        for: where it built the program over several prompts' chunks, they
-        go to the device together and every weight is read once for the
-        pass. At most ``programs`` programs where given: the prefills past
-        that have no turn in this pass. A prefill whose pages cannot be had
-        waits for a later pass, fills no row and holds nobody back. A
-        program with rows left over carries further chunks of the prefills
-        in it (``_rows_of``): a prefill alone advances by as many chunks a
-        program as the program has rows. Returns the chunks dispatched."""
+        of them unless given; decode steps run between calls), in that
+        order, as few programs as the engine has rows for: several prompts'
+        chunks go to the device together where the plan is several rows
+        wide, and every weight is read once for the pass. At most
+        ``programs`` programs where given: the prefills past that have no
+        turn in this pass. A prefill whose pages cannot be had waits for a
+        later pass, fills no row and holds nobody back. Rows left over:
+        ``_rows_of``. Returns the chunks dispatched."""
         due = list(self._chunkings) if due is None else due
-        room = len(due) if programs is None else programs * self._chunk_rows
+        width = self._plan.rows
+        room = len(due) if programs is None else programs * width
         ready: list[_Chunking] = []
         for ch in due:
             if len(ready) == room:
@@ -2526,8 +2148,8 @@ class LLMEngine:
             if self._reserve_chunk_pages(ch):
                 ready.append(ch)
         sent = 0
-        for i in range(0, len(ready), self._chunk_rows):
-            rows = self._rows_of(ready[i:i + self._chunk_rows])
+        for i in range(0, len(ready), width):
+            rows = self._rows_of(ready[i:i + width])
             self._dispatch_chunks(rows)
             sent += len(rows)
         return sent

@@ -90,19 +90,18 @@ def lowered_programs(cfg, batching, dev, *, relaid: bool = True,
     ``mixed``: also "mixed[N]", the chunk program that carries the slots'
     decode step (``paged_mixed_step``) at its one width (as many rows as the
     engine sends chunks together), where the engine builds it
-    (``paged.chunk_carries_step``).
+    (``ChunkPlan.carries_step``).
     The caller has made ``jax.default_backend()`` answer "tpu"."""
     import jax
     import jax.numpy as jnp
     from jax.experimental.layout import Format, Layout
 
     from kubeflow_tpu.models.decoder import init_decoder_params
-    from kubeflow_tpu.serve.engine import (
-        RIDGE_ROWS, chunk_rows_per_weight, serving_configs,
-    )
+    from kubeflow_tpu.serve.chunk_programs import plan_chunks
+    from kubeflow_tpu.serve.engine import serving_configs
     from kubeflow_tpu.serve.paged import (
-        chunk_carries_step, engine_pool_shapes, paged_chunk_prefill,
-        paged_decode_multi, paged_mixed_step,
+        engine_pool_shapes, paged_chunk_prefill, paged_decode_multi,
+        paged_mixed_step,
     )
     from kubeflow_tpu.serve.weight_layout import relay, weight_formats
 
@@ -152,19 +151,12 @@ def lowered_programs(cfg, batching, dev, *, relaid: bool = True,
     # last position's logits, "this row ends its prompt" a row)
     by_rows = rows_logits_at == "last"
     forms = [("chunk[1]", 1, False)]
-    tail = cfg_prefill.stateless_tail > 0
-    wider = b.max_concurrent_prefills > 1 and (tail or chunk_rows_per_weight(
-        cfg_prefill, chunk_tokens) < RIDGE_ROWS)
-    # a stack that ends in a stateless tail, and an engine that sends one
-    # chunk a program and carries no step in it, send a prefill alone
-    # through the program over rows as a group of one
-    # (serve/engine.py: ``_lone_at_last``)
-    if by_rows and (tail or not (wider or chunk_carries_step(
-            cache, cfg_prefill, None, "pallas"))):
+    plan = plan_chunks(cfg_prefill, cache, b, "pallas")
+    # a prefill alone through the program over rows as a group of one
+    if by_rows and plan.lone_at_last:
         forms.append(("rows[1]", 1, True))
-    if wider:
-        n = b.max_concurrent_prefills
-        forms.append((f"chunk[{n}]", n, by_rows))
+    if plan.rows > 1:
+        forms.append((f"chunk[{plan.rows}]", plan.rows, by_rows))
     for key, n, last in forms:
         # (a lambda, as the engine's: the lowered module's name is part of
         # the digests tests/test_chip_compile.py pins)
@@ -176,7 +168,7 @@ def lowered_programs(cfg, batching, dev, *, relaid: bool = True,
             5 + last).lower(
             params, cache, sds((n, chunk_tokens)), sds((n, mpp)), sds((n,)),
             sds((n,)), *([sds((n,), jnp.bool_)] if last else []))
-    if mixed and chunk_carries_step(cache, cfg_prefill, None, "pallas"):
+    if mixed and plan.carries_step:
         for n in (forms[-1][1],):       # its one width: the rows sent together
             out[f"mixed[{n}]"] = jit(
                 lambda p, c, tbl, t, tr, s0, vl, ends, ride, tok, ln, lv,

@@ -85,7 +85,7 @@ def check(workload: str, seed: int) -> int:
     limit = conf["correctness"]["limits"]["prefill_logit_err"]
     eng = LLMEngine(cfg, BatchingSpec(**traffic["engine"]), params=params,
                     seed=seed & 0x7FFFFFFF)
-    if eng._chunk_rows < 2:
+    if eng._plan.rows < 2:
         _log(f"{workload}: the engine built no program over rows")
         return 1
     C, pg, mpp = eng.chunk_size, eng.page_size, eng._mpp
@@ -115,21 +115,17 @@ def check(workload: str, seed: int) -> int:
         return logits
 
     def rows(keys, copy):
-        block = np.zeros((2, C), np.int32)
-        table = np.full((2, mpp), -1, np.int32)
-        start, valid = np.zeros((2,), np.int32), np.zeros((2,), np.int32)
-        for r, k in enumerate(keys):
-            if k is None:
-                continue
-            start[r], valid[r] = plan[k]
-            table[r] = tables[copy, k]
-            block[r, :valid[r]] = toks[k][start[r]:start[r] + valid[r]]
         # every live row's logits are wanted (a program in which no row ends
-        # its prompt runs no head: PR 41)
-        logits, eng.cache = eng._paged_chunks(
-            eng.params, eng.cache, jnp.asarray(block), jnp.asarray(table),
-            jnp.asarray(start), jnp.asarray(valid), jnp.asarray(valid > 0),
-            mpp)
+        # its prompt runs no head: PR 41); a dead row has no token
+        dead = ((), np.full((mpp,), -1, np.int32), 0, False)
+        packed = eng._programs.pack(
+            [dead if k is None else (
+                toks[k][plan[k][0]:sum(plan[k])], tables[copy, k],
+                plan[k][0], True) for k in keys], 2)
+        # (by name: an engine whose chunk program carries the step sends
+        # several rows through that one, and builds this only when asked)
+        logits, eng.cache = eng._programs.ask("rows")(
+            eng.params, eng.cache, *map(jnp.asarray, packed), mpp)
         return logits
 
     def written(copy, k):
@@ -152,7 +148,7 @@ def check(workload: str, seed: int) -> int:
     both = rows(("a", "b"), "rows")
     beside_dead = rows(("a", None), "dead")
     out = {"workload": workload, "seed": seed, "device": dev["kind"],
-           "rows": eng._chunk_rows, "limit": limit}
+           "rows": eng._plan.rows, "limit": limit}
     for r, k in enumerate(plan):
         start, valid = plan[k]
         want = correctness.reference_logits(params, toks[k][:start + valid],

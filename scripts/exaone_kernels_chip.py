@@ -190,16 +190,15 @@ def main(argv=None) -> int:
         return np.asarray(jax.device_get(eng.cache[MOE_ROWS])).astype(
             np.int64)
 
-    block = jnp.asarray(rng.integers(
-        3, conf["vocab_size"], (2, C)).astype(np.int32))
+    block = rng.integers(3, conf["vocab_size"], (2, C)).astype(np.int32)
+    rows_program = eng._programs.ask("rows")
     for start in (0, long_ - C) if "chunk" in args.parts else ():
-        starts = jnp.full((2,), start, jnp.int32)
-        valid = jnp.full((2,), C, jnp.int32)
+        packed = tuple(map(jnp.asarray, eng._programs.pack(
+            [(block[r], table[r], start, True) for r in range(2)], 2)))
 
         def run():
-            logits, eng.cache = eng._paged_chunks(
-                eng.params, eng.cache, block, dtable[:2], starts, valid,
-                valid > 0, mpp)
+            logits, eng.cache = rows_program(
+                eng.params, eng.cache, *packed, mpp)
             return logits
         before = rows_now()
         numbers = traced(run, args.calls)

@@ -22,13 +22,13 @@ five ``ssd_step`` and the five ``paged_decode_attention`` calls, each beside
 its bytes at the bus's peak, and the program's heaviest instructions; the
 one-row ``[C, V]`` chunk program at starts 0 and 1024: the five ``ssd_chunk``
 and ``paged_chunk_attention`` calls; beside it what the engine's traffic runs
-since PR 52, the program over rows at ONE row (``engine._paged_chunks``: the
+since PR 52, the program over rows at ONE row (``ChunkPrograms``' "rows": the
 head at the last valid position, under a ``cond``), with the prompt's end in
 the chunk and without, and the two forms on the same tokens into pages of
 their own: the row traffic reads against ``logits[C - 1]``, the planes
 written. Since PR 58 the cell's engine builds no program over rows: what its
 traffic runs is the chunk program that carries the slots' decode step
-(``engine._paged_mixed``), timed here with 47 slots riding at a context of
+(``ChunkPrograms``' "mixed"), timed here with 47 slots riding at a context of
 800 and with none, a chunk that ends its prompt and one that does not
 (the parent's forms in the same call: ``cd .parent && python3
 scripts/falconh1_kernels_chip.py ...``).
@@ -204,8 +204,9 @@ def main(argv=None) -> int:
             **traced(decode_at(context), args.calls, OPS, top=16)}),
             flush=True)
 
-    block = jnp.asarray(rng.integers(
-        3, conf["vocab_size"], (1, C)).astype(np.int32))
+    tokens = rng.integers(3, conf["vocab_size"], (C,)).astype(np.int32)
+    block = jnp.asarray(tokens[None])
+    sends = eng._plan.programs()    # what this engine's traffic can take
 
     def one_row(start: int):
         def run():
@@ -216,19 +217,22 @@ def main(argv=None) -> int:
             return logits
         return run
 
+    def packed(start: int, ends: bool, slot: int):
+        return tuple(map(jnp.asarray, eng._programs.pack(
+            [(tokens, table[slot], start, ends)], 1)))
+
     def at_last(start: int, ends: bool, slot: int = 0):
         def run():
-            logits, eng.cache = eng._paged_chunks(
-                eng.params, eng.cache, block, jnp.asarray(table[slot][None]),
-                jnp.asarray([start], jnp.int32), jnp.asarray([C], jnp.int32),
-                jnp.asarray([ends]), context_bucket(start, C, pg, mpp))
+            logits, eng.cache = eng._programs.ask("rows")(
+                eng.params, eng.cache, *packed(start, ends, slot),
+                context_bucket(start, C, pg, mpp))
             return logits
         return run
 
-    # what the engine's traffic runs (PR 52): the program over rows at one
-    # row, with the prompt's end in the chunk and without
+    # what the engine's traffic ran from PR 52 to PR 58: the program over
+    # rows at one row, with the prompt's end in the chunk and without
     forms = [("chunk[1] all positions", one_row)]
-    if getattr(eng, "_lone_at_last", False):
+    if "rows" in sends:
         forms += [("rows[1] last position, ends its prompt",
                    lambda start: at_last(start, True)),
                   ("rows[1] last position, ends none",
@@ -249,17 +253,14 @@ def main(argv=None) -> int:
             & riding[:, None], table, -1))
 
         def run():      # (the state and the table are donated: copies)
-            logits, _, eng.cache, _, _, _ = eng._paged_mixed(
-                eng.params, eng.cache, block,
-                jnp.asarray(table[slots - 1][None]),
-                jnp.asarray([start], jnp.int32),
-                jnp.asarray([C], jnp.int32), jnp.asarray([ends]),
+            logits, _, eng.cache, _, _, _ = eng._programs.ask("mixed")(
+                eng.params, eng.cache, *packed(start, ends, slots - 1),
                 jnp.asarray(ride), jax.tree.map(jnp.array, state),
                 jnp.array(tbl), jax.random.PRNGKey(0), "greedy")
             return logits
         return run
 
-    if getattr(eng, "_mixed", False):
+    if "mixed" in sends:
         forms += [(f"mixed[1] {'47 slots riding' if ride else 'none riding'}"
                    f", ends {'its prompt' if ends else 'none'}",
                    lambda start, ends=ends, ride=ride: carrying(
@@ -277,7 +278,7 @@ def main(argv=None) -> int:
                     1e3 * (counts.prefill_flops(conf, start + C)
                            - counts.prefill_flops(conf, start)) / PEAK, 3),
                 **traced(form(start), args.calls, OPS, top=24)}), flush=True)
-    if "chunk" in args.parts and getattr(eng, "_lone_at_last", False):
+    if "chunk" in args.parts and "rows" in sends:
         # the two forms on the same tokens, each into a slot's own pages and
         # entry: the one row traffic reads, and what the pool was left
         every = np.asarray(one_row(0)()[C - 1])

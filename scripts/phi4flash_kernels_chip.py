@@ -139,28 +139,25 @@ def main(argv=None) -> int:
             **traced(decode_at(context), args.calls, OPS, top=16)}),
             flush=True)
 
-    block = jnp.asarray(rng.integers(
-        3, conf["vocab_size"], (2, C)).astype(np.int32))
-    rows = jnp.asarray(table[:2])
+    block = rng.integers(3, conf["vocab_size"], (2, C)).astype(np.int32)
     long_ = contexts[-1]
 
     def rows_program(start: int, ends: bool):
-        starts = jnp.full((2,), start, jnp.int32)
-        valid = jnp.full((2,), C, jnp.int32)
-        wanted = jnp.full((2,), ends, bool)
+        packed = tuple(map(jnp.asarray, eng._programs.pack(
+            [(block[r], table[r], start, ends) for r in range(2)], 2)))
 
         def run():
-            logits, eng.cache = eng._paged_chunks(
-                eng.params, eng.cache, block, rows, starts, valid, wanted,
-                mpp)
+            logits, eng.cache = eng._programs.ask("rows")(
+                eng.params, eng.cache, *packed, mpp)
             return logits
         return run
 
     def one_row(start: int):
         def run():
             logits, eng.cache = eng._paged_chunk(
-                eng.params, eng.cache, block[:1], rows[0], jnp.int32(start),
-                jnp.int32(C), context_bucket(start, C, pg, mpp))
+                eng.params, eng.cache, jnp.asarray(block[:1]),
+                jnp.asarray(table[0]), jnp.int32(start), jnp.int32(C),
+                context_bucket(start, C, pg, mpp))
             return logits
         return run
 

@@ -154,17 +154,15 @@ def main(argv=None) -> int:
             **traced(decode_at(context), args.calls, OPS, top=12)}),
             flush=True)
 
-    block = jnp.asarray(rng.integers(
-        3, conf["vocab_size"], (2, C)).astype(np.int32))
-    rows = jnp.asarray(table[:2])
+    block = rng.integers(3, conf["vocab_size"], (2, C)).astype(np.int32)
+    rows_program = eng._programs.ask("rows")
     for start in (0, long_ - C) if "chunk" in args.parts else ():
-        starts = jnp.full((2,), start, jnp.int32)
-        valid = jnp.full((2,), C, jnp.int32)
+        packed = tuple(map(jnp.asarray, eng._programs.pack(
+            [(block[r], table[r], start, True) for r in range(2)], 2)))
 
         def run():
-            logits, eng.cache = eng._paged_chunks(
-                eng.params, eng.cache, block, rows, starts, valid, valid > 0,
-                mpp)
+            logits, eng.cache = rows_program(
+                eng.params, eng.cache, *packed, mpp)
             return logits
         print(json.dumps({
             **side, "part": "chunk_program", "rows": 2, "start": start,

@@ -504,7 +504,8 @@ def cell_programs(chip):
 
 
 # A dense model at 512 tokens a chunk: the engine builds no program over
-# several prompts' rows (``chunk_rows_per_weight``), so the chat cell has two.
+# several prompts' rows (``chunk_programs.chunk_rows_per_weight``), so the
+# chat cell has two.
 SERVING_PROGRAMS = [
     (cell, program) for cell in sorted(SERVING_CELLS)
     for program in ("decode", "chunk[1]", "chunk[2]")
@@ -684,26 +685,19 @@ def test_mixed_program_compiles_for_v5e_with_both_kernels(cell_programs,
     ("falcon-h1-34b.batch-assistant", True),    # parallel layers: PR 58
 ])
 def test_which_cells_chunk_program_carries_the_step(cell, carries):
-    """The rule reads the stack and the pool (``paged.chunk_carries_step``),
-    at the cells' own shapes: every layer of a kind whose chunk and decode
-    operators are held side by side in one program
+    """The plan reads the stack and the pool (``plan_chunks``, through
+    ``paged.chunk_carries_step``), at the cells' own shapes: every layer of
+    a kind whose chunk and decode operators are held side by side in one
+    program
     (``paged.STEP_CARRYING_KINDS``: "attention" and, since PR 58,
     "parallel"); the four that stay keep a conv tail, a ring, a linear
     state, or an ssm state in front of a stateless tail."""
     from scripts.aot_weight_copies import serving_cell
-
-    from kubeflow_tpu.serve.engine import serving_configs
-    from kubeflow_tpu.serve.paged import (
-        chunk_carries_step, engine_pool_shapes,
-    )
+    from test_serve_chunk_plan import plan_of
 
     cfg, b = serving_cell(cell)
-    pre, dec = serving_configs(cfg, b)
-    cache = {n: jax.ShapeDtypeStruct(shape, dt) for n, (shape, dt) in
-             engine_pool_shapes(dec, b.max_batch_size, int(b.max_pages),
-                                b.page_size).items()}
-    assert chunk_carries_step(cache, pre, None, "pallas") == carries
-    assert not chunk_carries_step(cache, pre, None, "gather")
+    assert plan_of(cfg, b, "pallas").carries_step == carries
+    assert not plan_of(cfg, b, "gather").carries_step
 
 
 @pytest.mark.parametrize("cell", sorted(
@@ -995,7 +989,7 @@ ASSISTANT = "falcon-h1-34b.batch-assistant"
 # a program). A NEW program, pinned as PR 58 left it; it REPLACES "rows[1]",
 # the program over rows at one row that the cell's traffic ran since PR 52
 # (7f8fbb38f1f36822 on the parent): the engine no longer builds that one
-# (``engine._lone_at_last`` is false for an engine whose chunk program
+# (``ChunkPlan.lone_at_last`` is false for an engine whose chunk program
 # carries the step). "decode" and "chunk[1]" (the ``[C, V]`` program of
 # callers outside the engine: the benchmark's ``correct``) lower to what they
 # lowered to on the parent (1fa4a2c), recorded there ahead of any edit.

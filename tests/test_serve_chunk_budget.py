@@ -64,7 +64,7 @@ def test_the_two_counters_from_construction(kind):
 @pytest.mark.parametrize("kind", KINDS)
 def test_a_pass_sends_one_program_a_step_and_the_older_prompt_first(kind):
     eng = _one_step_engine(kind)
-    rows = eng._chunk_rows
+    rows = eng._plan.rows
     assert rows == (1 if kind == "dense" else 2)
     _live_stream(eng)
     assert eng._prefill_budget() == eng._pacer.k == 1
@@ -108,7 +108,7 @@ def test_no_live_slot_no_budget(kind):
     assert all(len(r.output_tokens) == 1 for r in reqs)
     c = eng.counters()
     assert c["prefill_chunks_dispatched"] == 4
-    assert c["prefill_programs_dispatched"] == 4 // eng._chunk_rows
+    assert c["prefill_programs_dispatched"] == 4 // eng._plan.rows
     assert (c["prefill_passes"], c["prefill_chunks_deferred"]) == (1, 0)
     _run(eng, reqs)
 
@@ -122,7 +122,7 @@ def test_a_newcomer_is_admitted_in_the_pass_and_its_chunk_rides_the_next(
     row of the NEXT pass's program, not a one-row program of its own."""
     _, cfg, params = _model(kind)
     eng = _engine(cfg, params, decode_steps=1, prefill_interleave_steps=1)
-    assert eng._chunk_rows == 2
+    assert eng._plan.rows == 2
     _live_stream(eng)
     seen = record_spans(monkeypatch)
     before = eng.counters()
@@ -156,7 +156,7 @@ def test_a_prefill_without_pages_spends_no_budget(kind, monkeypatch):
             eng.submit(_prompt(kind, 5, 3), GREEDY)]
     eng._admit()
     a, b = eng._chunkings
-    first = (C, 0) if eng._chunk_rows == 1 else (C, C)
+    first = (C, 0) if eng._plan.rows == 1 else (C, C)
     assert (a.pos, b.pos) == first
     before = eng.counters()
     ensure = eng._ensure_pages
@@ -190,13 +190,13 @@ def test_a_higher_class_goes_first(kind, monkeypatch):
     slots = [attrs["slot"] for name, attrs in seen
              if name == "engine.prefill_dispatch"]
     assert slots == [b.slot]        # the program's first row is the urgent one
-    if eng._chunk_rows == 1:
+    if eng._plan.rows == 1:
         assert (a.pos, b.pos) == (C, C)
         assert _delta(eng, before)["prefill_chunks_deferred"] == 1
     else:
         assert (a.pos, b.pos) == (2 * C, C)
     _run(eng, [batch, urgent])
-    if eng._chunk_rows == 1:
+    if eng._plan.rows == 1:
         assert urgent.first_token_time < batch.first_token_time
 
 

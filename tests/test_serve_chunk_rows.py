@@ -29,9 +29,10 @@ from kubeflow_tpu.models import layers as L
 from kubeflow_tpu.models.config import preset
 from kubeflow_tpu.models.decoder import init_decoder_params
 from kubeflow_tpu.obs import profiler
-from kubeflow_tpu.serve.engine import (
-    RIDGE_ROWS, LLMEngine, SamplingParams, chunk_rows_per_weight,
+from kubeflow_tpu.serve.chunk_programs import (
+    RIDGE_ROWS, chunk_rows_per_weight,
 )
+from kubeflow_tpu.serve.engine import LLMEngine, SamplingParams
 from kubeflow_tpu.serve.lora import AdapterSpec, init_adapter_weights
 from kubeflow_tpu.ops.attention import multi_head_attention
 from kubeflow_tpu.ops.paged_attention import (
@@ -429,9 +430,9 @@ class TestChunkInPlace:
                           paged_attn_impl=impl)
             one = getattr(eng._paged_chunk, "jitted", eng._paged_chunk)
             # an engine that sends chunks ahead ran it under every
-            # bucket's name when it was built (``_warm_lone_program``): ONE
+            # bucket's name when it was built (``ChunkPrograms.warm``): ONE
             # program
-            assert one._cache_size() == (1 if eng._ahead else 0)
+            assert one._cache_size() == (1 if eng._plan.ahead else 0)
             for start, bucket in ((0, 2), (CHUNK, 4)):
                 logits, eng.cache = eng._paged_chunk(
                     eng.params, eng.cache, block, row, jnp.int32(start),
@@ -444,7 +445,7 @@ class TestChunkInPlace:
         cfg, params = _wide_model("dispatch")
         prompts = [_tokens(4, 3 * CHUNK - 5), _tokens(5, 2 * CHUNK - 9)]
         eng = _engine(cfg, params, paged_attn_impl="pallas")
-        assert eng._chunk_rows == 2
+        assert eng._plan.rows == 2
         assert _chunk_in_place(eng.cache, cfg, None, eng.paged_attn_impl)
         assert _greedy(eng, prompts) == _greedy(
             _engine(cfg, params, paged_attn_impl="gather"), prompts)
@@ -765,10 +766,10 @@ class TestEngineBatchesChunks:
         assert c["prefill_tokens_dispatched"] == sum(map(len, prompts))
         assert c["prefill_chunks_dispatched"] == 3 + 2
         if kind == "dense":
-            assert eng._chunk_rows == 1 and eng._lone_at_last
+            assert eng._plan.rows == 1 and eng._plan.lone_at_last
             assert _chunks_per_program(eng) == 1
         else:
-            assert eng._chunk_rows == 2
+            assert eng._plan.rows == 2
             # two passes carry both prompts' chunks, the third a's last
             assert c["prefill_programs_dispatched"] == 3
             assert _chunks_per_program(eng) > 1
@@ -782,11 +783,9 @@ class TestEngineBatchesChunks:
         eng = _engine(cfg, params)
         compiles = CompileCounter()
         compiles.start()
-        logits, eng.cache = eng._paged_chunks(
-            eng.params, eng.cache, jnp.zeros((2, CHUNK), jnp.int32),
-            jnp.full((2, eng._mpp), -1, jnp.int32),
-            jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
-            jnp.zeros((2,), jnp.bool_), eng._mpp)
+        logits, eng.cache = eng._programs.rows(
+            eng.params, eng.cache,
+            *map(jnp.asarray, eng._programs.pack([], 2)), eng._mpp)
         assert logits.shape == (2, cfg.vocab_size)
         jax.block_until_ready(logits[1])
         sp = SamplingParams(max_new_tokens=2, temperature=0.0)
@@ -840,7 +839,7 @@ class TestEngineBatchesChunks:
         tokens a chunk is as weights-bound as an expert layer."""
         _, cfg, params = _model("dense")
         eng = _engine(cfg, params)
-        assert eng._chunk_rows == 2
+        assert eng._plan.rows == 2
         prompts = [_tokens(4, 91), _tokens(5, 50)]
         together = _greedy(eng, prompts)
         alone = [_greedy(_engine(cfg, params, max_concurrent_prefills=1),
@@ -932,18 +931,20 @@ def _one_chunk_engine(name: str, at_last: bool = True):
     kind, options = ONE_CHUNK[name]
     _, cfg, params = _model(kind)
     eng = _engine(cfg, params, **options)
-    assert eng._chunk_rows == 1 and not eng._mixed and eng._lone_at_last
+    assert eng._plan.rows == 1 and not eng._plan.carries_step \
+        and eng._plan.lone_at_last
     if name == "lora":
         eng._lora.register(AdapterSpec(
             ADAPTER, rank=4, alpha=8.0, weights=init_adapter_weights(
                 jax.random.PRNGKey(11), cfg, 4, ("wq", "wv"))))
-    eng._lone_at_last = at_last
+    eng._plan = dataclasses.replace(eng._plan, lone_at_last=at_last)
     calls = {}
-    for attr in ("_paged_chunk", "_paged_chunks"):
-        def counted(*args, _program=getattr(eng, attr), _attr=attr):
-            calls[_attr] = calls.get(_attr, 0) + 1
+    for which in ("lone", "rows"):
+        def counted(*args, _program=getattr(eng._programs, which),
+                    _which=which):
+            calls[_which] = calls.get(_which, 0) + 1
             return _program(*args)
-        setattr(eng, attr, counted)
+        setattr(eng._programs, which, counted)
     return eng, calls
 
 
@@ -989,7 +990,7 @@ class TestALoneChunkAtOneRow:
             prompts.append((_tokens(5, eng.chunk_size + 3),
                             dict(adapter=ADAPTER)))
         got = [_stream(eng, p, 6, **kw) for p, kw in prompts]
-        assert calls == {"_paged_chunks": 2 * len(prompts)}
+        assert calls == {"rows": 2 * len(prompts)}
         c = eng.counters()
         assert (c["prefill_programs_dispatched"],
                 c["prefill_row_programs_dispatched"],
@@ -998,7 +999,7 @@ class TestALoneChunkAtOneRow:
                     2 * len(prompts), 0, len(prompts), len(prompts))
         before, calls = _one_chunk_engine(name, at_last=False)
         assert got == [_stream(before, p, 6, **kw) for p, kw in prompts]
-        assert calls == {"_paged_chunk": 2 * len(prompts)}
+        assert calls == {"lone": 2 * len(prompts)}
         assert before.counters()["prefill_head_positions"] \
             == 2 * len(prompts) * eng.chunk_size
         if name == "lora":
@@ -1011,7 +1012,7 @@ class TestALoneChunkAtOneRow:
         prompts = [_tokens(4, 2 * eng.chunk_size - 7),
                    _tokens(5, eng.chunk_size // 2)]
         got = [_stream(eng, p, 4) for p in prompts]
-        assert "_paged_chunk" not in calls
+        assert "lone" not in calls
         by_hand, _ = _one_chunk_engine(name)
         assert got == [_by_hand(by_hand, p, 4) for p in prompts]
 
@@ -1041,10 +1042,10 @@ class TestALoneChunkAtOneRow:
                 jnp.int32(start), jnp.int32(real), bucket)
             assert every.shape == (CHUNK, cfg.vocab_size)
             eng = engines["last"]
-            last, eng.cache = eng._paged_chunks(
-                eng.params, eng.cache, jnp.asarray(block),
-                jnp.asarray(row[None]), jnp.asarray([start], jnp.int32),
-                jnp.asarray([real], jnp.int32), jnp.asarray([ends]), bucket)
+            last, eng.cache = eng._programs.rows(
+                eng.params, eng.cache, *map(jnp.asarray, eng._programs.pack(
+                    [(tokens[start:start + real], row, start, ends)], 1)),
+                bucket)
             assert last.shape == (1, cfg.vocab_size)
             if ends:
                 want = np.asarray(every[real - 1])
@@ -1074,11 +1075,11 @@ class TestALoneChunkAtOneRow:
                 jnp.zeros((1, eng._mpp), jnp.int32),
                 jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32))
         traced = jax.make_jaxpr(
-            lambda *a: eng._paged_chunks(*a, eng._mpp))(
+            lambda *a: eng._programs.rows(*a, eng._mpp))(
                 *args, jnp.zeros((1,), bool))
         assert _head_products(traced.jaxpr, cfg.vocab_size, rows=1) == [True]
         all_positions = f"{CHUNK}x{cfg.vocab_size}xf32"
-        assert all_positions not in eng._paged_chunks.lower(
+        assert all_positions not in eng._programs.rows.lower(
             *args, jnp.zeros((1,), bool), eng._mpp).as_text()
         assert all_positions in eng._paged_chunk.lower(
             *args[:3], jnp.zeros((eng._mpp,), jnp.int32), jnp.int32(0),

@@ -707,11 +707,11 @@ def test_engine_tokens_are_the_full_recomputes(prefills):
     program over rows at one row: the ``[C,V]`` program is never called and
     the head runs at one position a prompt."""
     engine = _engine(max_concurrent_prefills=prefills)
-    assert not engine._mixed and engine._ring == 1
-    assert (engine._chunk_rows, engine._lone_at_last) == (
+    assert not engine._plan.carries_step and engine._ring == 1
+    assert (engine._plan.rows, engine._plan.lone_at_last) == (
         prefills, prefills == 1)
-    all_positions, program = [], engine._paged_chunk
-    engine._paged_chunk = lambda *a: all_positions.append(a) or program(*a)
+    all_positions, program = [], engine._programs.lone
+    engine._programs.lone = lambda *a: all_positions.append(a) or program(*a)
     prompts = [_tokens(31, 75), _tokens(32, 5), _tokens(33, 50),
                _tokens(34, 21)]
     reqs = _serve(engine, prompts, 12)

@@ -187,7 +187,7 @@ class TestAgainstTheReference:
         step (``paged_mixed_step``): both groups of rows select."""
         eng = make_engine(cfg, params, paged_attn_impl="pallas",
                           decode_steps=1, prefill_interleave_steps=1)
-        assert eng._mixed
+        assert eng._plan.carries_step
         rng = np.random.default_rng(2)
         prompts = [rng.integers(3, 256, n).tolist() for n in (70, 45, 90)]
         reqs = [eng.submit(p, SamplingParams(max_new_tokens=6,
@@ -465,7 +465,7 @@ class TestCountersAndSpans:
                   prefill_interleave_steps=1, enable_prefix_caching=False,
                   pipelined_decode=False)
         eng = make_engine(cfg, params, **kw)
-        assert eng._ahead and eng._rows_only and eng._chunk_rows == 2
+        assert eng._plan.ahead and eng._plan.rows_only and eng._plan.rows == 2
         prompt = np.random.default_rng(5).integers(3, 256, 137).tolist()
         spans = record_spans(monkeypatch)
         got = greedy(eng, prompt, 6)

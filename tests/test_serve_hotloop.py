@@ -310,7 +310,7 @@ class TestPrefillBudget:
             self, cfg, params, monkeypatch):
         eng = make_engine(cfg, params, pipelined=True, decode_steps=1)
         eng.max_concurrent_prefills = 3     # two rows a program: one waits
-        assert eng._chunk_rows == 2
+        assert eng._plan.rows == 2
         samples = []
         eng._pacer.note_host = lambda k, seconds: samples.append(
             eng.counters()["prefill_programs_dispatched"])

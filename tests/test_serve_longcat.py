@@ -25,7 +25,7 @@ from kubeflow_tpu.models.config import preset
 from kubeflow_tpu.models.decoder import decoder_forward
 from kubeflow_tpu.serve.engine import LLMEngine, SamplingParams
 from kubeflow_tpu.serve.paged import (
-    MOE_ROWS, chunk_carries_step, engine_pool_shapes, mixed_step_rows,
+    MOE_ROWS, engine_pool_shapes, mixed_step_rows,
     paged_chunk_prefill, pool_bytes_per_token,
 )
 from test_serve_chunk_rows import record_spans
@@ -153,8 +153,7 @@ class TestAgainstTheReference:
         group started joins its own stream a block later."""
         eng = make_engine(cfg, params, paged_attn_impl="pallas",
                           decode_steps=1, prefill_interleave_steps=1)
-        assert eng._mixed and chunk_carries_step(
-            eng.cache, eng._cfg_prefill, None, "pallas")
+        assert eng._plan.carries_step
         rng = np.random.default_rng(2)
         prompts = [rng.integers(3, 256, n).tolist() for n in (70, 45, 90)]
         reqs = [eng.submit(p, SamplingParams(max_new_tokens=6,
@@ -170,7 +169,7 @@ class TestAgainstTheReference:
                   prefill_interleave_steps=1, enable_prefix_caching=False,
                   pipelined_decode=False)
         eng = make_engine(cfg, params, **kw)
-        assert eng._ahead and eng._rows_only and eng._chunk_rows == 2
+        assert eng._plan.ahead and eng._plan.rows_only and eng._plan.rows == 2
         prompt = np.random.default_rng(5).integers(3, 256, 137).tolist()
         got = greedy(eng, prompt, 6)
         assert got == full_forward_greedy(params, cfg, prompt, 6)

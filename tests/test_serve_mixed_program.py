@@ -36,6 +36,7 @@ from kubeflow_tpu.core.serving import BatchingSpec
 from kubeflow_tpu.models import layers as L
 from kubeflow_tpu.models.config import preset
 from kubeflow_tpu.models.decoder import decoder_forward, init_decoder_params
+from kubeflow_tpu.serve.chunk_programs import pack_rows
 from kubeflow_tpu.serve.engine import (
     LLMEngine, SamplingParams, serving_configs,
 )
@@ -43,6 +44,7 @@ from kubeflow_tpu.serve.paged import (
     mixed_step_rows, paged_chunk_prefill, paged_decode_multi,
     paged_mixed_step, pool_shapes,
 )
+from test_serve_chunk_plan import plan_of
 
 PAGE, CHUNK, MPP = 16, 32, 8
 SLOTS = 4
@@ -113,17 +115,12 @@ def _programs(kind: str, slots: int = SLOTS):
 
 def _rows(rows):
     """The chunk program's arrays for ``rows``: (tokens, table row, start,
-    valid, ends its prompt) each, None a dead row."""
-    block = np.zeros((len(rows), CHUNK), np.int32)
-    table = np.full((len(rows), MPP), -1, np.int32)
-    start, valid = (np.zeros((len(rows),), np.int32) for _ in range(2))
-    ends = np.zeros((len(rows),), np.bool_)
-    for r, row in enumerate(rows):
-        if row is None:
-            continue
-        toks, table[r], start[r], valid[r], ends[r] = row
-        block[r, :valid[r]] = toks[start[r]:start[r] + valid[r]]
-    return tuple(map(jnp.asarray, (block, table, start, valid, ends)))
+    valid, ends its prompt) each, None a dead row (one with no token)."""
+    dead = ((), np.full((MPP,), -1, np.int32), 0, False)
+    return tuple(map(jnp.asarray, pack_rows(
+        [dead if row is None else (
+            row[0][row[2]:row[2] + row[3]], row[1], row[2], row[4])
+         for row in rows], len(rows), CHUNK, MPP)))
 
 
 @functools.lru_cache(maxsize=None)
@@ -432,7 +429,7 @@ def _padded_forward(kind: str):
 ], ids=[*KINDS, "dispatch-zero_drop"])
 def test_engine_tokens_are_the_full_recomputes(kind, kw):
     eng = _engine(kind, **kw)
-    assert eng._mixed
+    assert eng._plan.carries_step
     c = eng.counters()
     assert (c["mixed_programs_dispatched"], c["mixed_decode_rows_sum"]) \
         == (0, 0)
@@ -445,7 +442,7 @@ def test_engine_tokens_are_the_full_recomputes(kind, kw):
     # the second row of each of its six dead
     programs, riding, ahead, dead = (10, 8, 0, 6) if kind == "parallel" \
         else (8, 6, 2, 2)
-    assert eng._ahead == (kind in AHEAD)
+    assert eng._plan.ahead == (kind in AHEAD)
     assert (c["prefill_programs_dispatched"],
             c["mixed_programs_dispatched"]) == (programs, riding)
     assert (c["prefill_chunks_dispatched"], c["prefill_rows_ahead"],
@@ -511,7 +508,7 @@ def test_a_stack_with_another_kind_of_layer_sends_two_programs(layer):
         prefill_interleave_steps=1, paged_attn_impl="pallas", **opts))
     # nor does a spare row ever carry a chunk ahead: the layer hands a
     # state, a ring or a tail from a chunk's END to the next chunk's start
-    assert not eng._mixed and not eng._ahead
+    assert not eng._plan.carries_step and not eng._plan.ahead
     sp = SamplingParams(max_new_tokens=5, temperature=0.0)
     rng = np.random.default_rng(3)
     reqs = [eng.submit([int(t) for t in rng.integers(3, 200, 40)], sp)]
@@ -544,7 +541,7 @@ def test_what_else_keeps_two_programs(why, kw):
     if "speculative" in kw:
         kw = {"speculative": SpeculativeSpec(**kw["speculative"])}
     eng = _engine("dense", **kw)
-    assert not eng._mixed and not eng._ahead, why
+    assert not eng._plan.carries_step and not eng._plan.ahead, why
     got = _serve(eng, n=4)
     assert [len(o) for o in got] == [4] * len(PROMPTS)
     c = eng.counters()
@@ -559,12 +556,12 @@ def test_no_compile_after_construction(kind):
     among them) compiles nothing once every OTHER program it needs has run
     (a first run of the same traffic)."""
     eng = _engine(kind)
-    assert eng._chunk_rows == 2
+    assert eng._plan.rows == 2
     # the engine's own warm-up reached it, no slot riding
-    sizes = eng._paged_mixed._cache_size()
+    sizes = eng._programs.mixed._cache_size()
     assert sizes == 1
     _serve(eng)
-    assert eng._paged_mixed._cache_size() == sizes
+    assert eng._programs.mixed._cache_size() == sizes
     compiles = CompileCounter()
     compiles.start()
     _serve(eng)
@@ -576,15 +573,16 @@ def test_no_compile_after_construction(kind):
 
 
 def test_a_dense_model_at_its_ridge_builds_it_one_row_wide():
-    cfg, params = _model("dense")
-    eng = LLMEngine(cfg, BatchingSpec(
+    """The plan alone (no engine: ``_one_row_engine`` below is this one
+    built, its program warmed once): the step carried, one row, and no
+    program over rows beside it."""
+    plan = plan_of(_config("dense"), BatchingSpec(
         max_batch_size=2, max_seq_len=1024, page_size=PAGE,
         chunked_prefill_tokens=256, paged_attn_impl="pallas",
         decode_steps=1, prefill_interleave_steps=1,
-        max_concurrent_prefills=2), params=params)
-    assert eng._mixed and eng._chunk_rows == 1
-    assert eng._paged_mixed._cache_size() == 1
-    assert not hasattr(eng, "_paged_chunks")
+        max_concurrent_prefills=2), "pallas")
+    assert plan.carries_step and plan.rows == 1
+    assert plan.programs() == {"mixed", "lone"}
 
 
 def test_both_dispatch_spans_carry_their_attributes(monkeypatch):
@@ -624,13 +622,20 @@ def test_the_programs_keep_their_names(monkeypatch):
         "kubeflow_tpu.runtime.device_report.lowered_kernel_calls",
         lambda jitted, *args: {})
     eng = _engine("dense")
-    for attr, name in (("_paged_chunk", "paged_chunk_prefill"),
-                       ("_paged_chunks", "paged_chunk_prefill"),
-                       ("_paged_mixed", "paged_mixed"),
-                       ("_paged_decode_n", "paged_decode")):
-        setattr(eng, attr, eng._introspected(name, getattr(eng, attr)))
-    eng._warm_rows_program()
-    assert set(eng.program_kernels) == {f"paged_mixed[2x{CHUNK},greedy]"}
+    assert eng._plan.programs() == {"lone", "mixed"}
+    assert not hasattr(eng._programs, "rows")
+    for name, key in (("lone", "paged_chunk_prefill"),
+                      ("mixed", "paged_mixed")):
+        setattr(eng._programs, name, eng._introspected(
+            key, getattr(eng._programs, name)))
+    eng._paged_decode_n = eng._introspected("paged_decode",
+                                            eng._paged_decode_n)
+    eng._programs.warm(eng._warm)
+    # the program of the one width, and the one-row program under every
+    # bucket's name (this engine sends chunks ahead)
+    assert set(eng.program_kernels) == {
+        f"paged_mixed[2x{CHUNK},greedy]",
+        *(f"paged_chunk_prefill[1x{CHUNK},{b}]" for b in (2, 4, 8, 16))}
     _serve(eng, n=3)
     names = set(eng.program_kernels)
     assert "paged_decode[1,greedy]" in names
@@ -709,11 +714,13 @@ def test_one_row_wide_every_chunk_beside_a_live_slot_takes_the_program(
     ahead."""
     eng = _one_row_engine(kind, decode_steps=steps,
                           prefill_interleave_steps=steps)
-    assert eng._mixed and eng._chunk_rows == 1 and not eng._ahead
-    assert not eng._lone_at_last and not hasattr(eng, "_paged_chunks")
-    assert eng._paged_mixed._cache_size() == 1      # warmed when built
-    live_at_call, program = [], eng._paged_chunk
-    eng._paged_chunk = lambda *a: live_at_call.append(
+    assert eng._plan.carries_step and eng._plan.rows == 1
+    assert not eng._plan.ahead
+    assert eng._plan.programs() == {"mixed", "lone"}
+    assert not hasattr(eng._programs, "rows")
+    assert eng._programs.mixed._cache_size() == 1      # warmed when built
+    live_at_call, program = [], eng._programs.lone
+    eng._programs.lone = lambda *a: live_at_call.append(
         sum(s is not None for s in eng.slots)) or program(*a)
     sp = SamplingParams(max_new_tokens=10, temperature=0.0)
     reqs = {}
@@ -741,7 +748,7 @@ def test_one_row_wide_every_chunk_beside_a_live_slot_takes_the_program(
     assert silent == (0 if steps == 1 else 2)
     assert 2 * 256 + riding <= c["prefill_head_positions"] \
         <= 2 * 256 + riding + silent
-    assert eng._paged_mixed._cache_size() == 1
+    assert eng._programs.mixed._cache_size() == 1
     eng._allocator.assert_quiescent()
 
 
@@ -754,8 +761,8 @@ def test_one_row_wide_a_burst_on_an_idle_engine_takes_the_program_too(kind):
     float32 each, allocated when sent). One prompt alone on an idle engine
     still takes that one, under its bucket's name."""
     eng = _one_row_engine(kind)
-    calls, program = [], eng._paged_chunk
-    eng._paged_chunk = lambda *a: calls.append(a[6]) or program(*a)
+    calls, program = [], eng._programs.lone
+    eng._programs.lone = lambda *a: calls.append(a[6]) or program(*a)
     sp = SamplingParams(max_new_tokens=10, temperature=0.0)
     reqs = [eng.submit(list(map(int, LONG[j])), sp) for j in (0, 1, 2)]
     while not all(r.done.is_set() for r in reqs):
@@ -885,7 +892,7 @@ def test_one_row_wide_nothing_compiles_after_a_first_run():
     compiles.start()
     run()
     assert compiles.stop() == 0, compiles.names
-    assert eng._paged_mixed._cache_size() == 1
+    assert eng._programs.mixed._cache_size() == 1
     c = eng.counters()
     assert 0 < c["mixed_programs_dispatched"] < c[
         "prefill_programs_dispatched"]
@@ -901,8 +908,8 @@ def test_a_parallel_stack_sends_no_chunk_ahead():
     assert [chunk_rows_follow(_model(kind)[0]) for kind in KINDS] == [
         True, True, True, False]
     eng = _engine("parallel")
-    assert eng._mixed and eng._chunk_rows == 2
-    assert not eng._ahead and not eng._rows_only
+    assert eng._plan.carries_step and eng._plan.rows == 2
+    assert not eng._plan.ahead and not eng._plan.rows_only
     first = _beside_a_live_stream(eng)
     before = _chunk_counts(eng)
     got = _alone(eng)
@@ -948,7 +955,7 @@ def _odd_alone(kind: str) -> int:
     where the engine keeps the one-row program for it (ONE program whatever
     the bucket: per-head planes), one where that would be a program a
     bucket (the latent pool) and the chunk takes the program of the one
-    width too (``engine._rows_only``)."""
+    width too (``engine._plan.rows_only``)."""
     return int(kind == "latent")
 
 
@@ -958,8 +965,8 @@ def test_a_prompt_alone_goes_two_chunks_a_program(kind):
     chunks, no slot riding, and one for the odd last chunk), the tokens of
     the full recompute and of one prefill at a time."""
     eng = _engine(kind)
-    assert eng._ahead and eng._chunk_rows == 2
-    assert eng._rows_only == (kind == "latent")
+    assert eng._plan.ahead and eng._plan.rows == 2
+    assert eng._plan.rows_only == (kind == "latent")
     assert _alone(eng) == list(_lone_recompute(kind))
     assert _chunk_counts(eng) == (3, 5, 2, _odd_alone(kind))
     c = eng.counters()
@@ -1106,23 +1113,23 @@ def test_a_prompt_alone_compiles_nothing_after_construction(kind):
     """The programs a prompt alone takes are the engine's own, run when it
     was built: the program of the one width with no slot riding and, where
     the engine keeps it for a prompt's odd last chunk, the one-row program
-    under every bucket's name (``_warm_lone_program``: ONE program; where
+    under every bucket's name (``ChunkPrograms.warm``: ONE program; where
     it would be a program a bucket the engine's traffic never takes it).
     Once the first-token sampler has run, a second lone prompt compiles
     nothing, and neither jitted program has grown a variant."""
     eng = _engine(kind)
     one = getattr(eng._paged_chunk, "jitted", eng._paged_chunk)
-    sizes = (eng._paged_mixed._cache_size(), one._cache_size())
-    assert sizes == (1, 0 if eng._rows_only else 1)
+    sizes = (eng._programs.mixed._cache_size(), one._cache_size())
+    assert sizes == (1, 0 if eng._plan.rows_only else 1)
     assert {k for k in eng.start_programs() if k.startswith(
-        "paged_chunk_prefill[1x")} == (set() if eng._rows_only else {
+        "paged_chunk_prefill[1x")} == (set() if eng._plan.rows_only else {
             f"paged_chunk_prefill[1x{CHUNK},{b}]" for b in (2, 4, 8, 16)})
     _alone(eng)
     compiles = CompileCounter()
     compiles.start()
     _alone(eng, _tokens(22, 3 * CHUNK + 1))
     assert compiles.stop() == 0, compiles.names
-    assert (eng._paged_mixed._cache_size(), one._cache_size()) == sizes
+    assert (eng._programs.mixed._cache_size(), one._cache_size()) == sizes
     assert _chunk_counts(eng) == (3 + 2, 5 + 4, 2 + 2, _odd_alone(kind))
 
 

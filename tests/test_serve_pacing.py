@@ -418,7 +418,7 @@ def test_the_prefill_budget_is_the_length_of_the_round_in_force(cfg, params):
     the pass."""
     eng = make_engine(cfg, params, max_batch_size=24,
                       prefill_interleave_steps=8, max_concurrent_prefills=1)
-    assert eng._chunk_rows == 1 and eng._prefill_budget() is None
+    assert eng._plan.rows == 1 and eng._prefill_budget() is None
     pin(eng, *SLOW_HOST)
     live = eng.submit([3, 1, 4], SamplingParams(max_new_tokens=100,
                                                 temperature=0.0))

@@ -681,7 +681,7 @@ def test_engine_tokens_are_the_full_recomputes(prefills):
     rows (a prefill alone as a group of one row), chunks interleaved with
     decode rounds, a slot and its entry handed to a second sequence."""
     engine = _engine(max_concurrent_prefills=prefills)
-    assert engine._lone_at_last and engine._chunk_rows == prefills
+    assert engine._plan.lone_at_last and engine._plan.rows == prefills
     assert engine._ring == RING and engine._window_pages == SLOTS * RING
     prompts = [_tokens(31, 75), _tokens(32, 5), _tokens(33, 50),
                _tokens(34, 21)]

@@ -591,7 +591,7 @@ def test_engine_tokens_are_the_full_recomputes(impl):
     """Four prompts on three slots: the two-row program, chunks interleaved
     with decode rounds, a slot (and its entry) handed to a second sequence."""
     engine = _engine(paged_attn_impl=impl)
-    assert engine._chunk_rows == 2
+    assert engine._plan.rows == 2
     prompts = [_tokens(31, 75), _tokens(32, 5), _tokens(33, 50),
                _tokens(34, 21)]
     reqs = _serve(engine, prompts, 12)
