@@ -103,9 +103,11 @@ class ChunkPlan:
         traffic takes the case (tests/test_serve_chunk_plan.py):
 
         1. the step is carried, and several chunks go together or the step
-           rides or the plan is ``rows_only`` -> "mixed", ``rows`` wide:
-           batch, longctx, agentcontext, voiceturns (two rows, spare rows
-           ahead); chat, assistant (one row).
+           rides (``rides``: the caller's question before this one) or the
+           plan is ``rows_only`` -> "mixed", ``rows`` wide: batch, longctx,
+           agentcontext, voiceturns (two rows, spare rows ahead); chat,
+           assistant (one row); longdoc (two rows and none ahead: a PAIR,
+           with the step riding or with no slot live).
         2. the step is carried, ONE row wide, and the engine has more to do
            than this prefill -> "mixed", one row, no step riding (the head at
            the chunk's last position if it ends its prompt, else nowhere):
@@ -113,17 +115,18 @@ class ChunkPlan:
            whose ``[C, V]`` results, float32 and allocated when sent, would
            pile up (48 prompts at a vocabulary of 261120: 15.5 GB of 16).
         3. several chunks -> "rows", ``rows`` wide, the whole table:
-           longanswer, mixedlength, longdoc (by the ridge), reasoning (its
+           longanswer, mixedlength (by the ridge), reasoning (its
            stateless tail). ONE static context: a row's attention follows
            its own context whatever the table's length (the chunk kernels
            skip the pages behind their chunk): a bucket spares a gather.
         4. one chunk, ``lone_at_last`` -> "rows", one row, its own bucket
            (as many programs as "lone" had, named alike): reasoning.
-        5. one chunk -> "lone", its own bucket: longanswer, mixedlength,
-           longdoc (a prefill alone); batch (a prompt's odd last chunk with
-           no slot live); chat, assistant (a prompt sent alone to an idle
-           engine: a caller's way to reach every bucket's name, and nobody
-           waits on it)."""
+        5. one chunk -> "lone", its own bucket: longanswer, mixedlength
+           (a prefill alone); longdoc (a prefill alone, beside live slots
+           too: no step rides a program with a dead row, ``rides``); batch
+           (a prompt's odd last chunk with no slot live); chat, assistant (a
+           prompt sent alone to an idle engine: a caller's way to reach
+           every bucket's name, and nobody waits on it)."""
         if self.carries_step and (
                 n_chunks > 1 or step_rides or self.rows_only
                 or (self.rows == 1 and not otherwise_idle)):
@@ -132,12 +135,29 @@ class ChunkPlan:
             return Sent("rows", self.rows, WHOLE_TABLE)
         return Sent("rows" if self.lone_at_last else "lone", 1, OWN_BUCKET)
 
+    def rides(self, n_chunks: int) -> bool:
+        """Whether the live slots' step rides the program of ``n_chunks``
+        chunks: the caller asks BEFORE it readies a round, and hands the
+        answer (and whether a slot is live) to ``send`` as ``step_rides``.
+        Only a program whose rows are all filled carries the step. One row
+        wide it always is; where the plan fills spare rows itself (``ahead``)
+        it is as far as the prompts reach, and the odd row left dead is the
+        price of ONE program. Several rows wide with no row to send ahead
+        (a layer hands a state from a chunk's END to the next chunk's start:
+        longdoc) that is ``n_chunks == rows``: a lone chunk beside a dead row
+        would cost a two-row program where the one-row program and a step of
+        its own are cheaper (31 + 2 ms against 18 + 9.5: ROADMAP Speed 0), so
+        it goes "lone" and the iteration's step goes out as its own
+        program."""
+        return self.carries_step and (
+            self.rows == 1 or self.ahead or n_chunks == self.rows)
+
     def programs(self) -> frozenset:
         """The programs ``send`` can name: those the engine builds."""
         return frozenset(
             self.send(n, rides, idle).program
             for n in range(1, self.rows + 1)
-            for rides in (False, self.carries_step)
+            for rides in (False, self.rides(n))
             for idle in (False, n == 1 and not rides))
 
 

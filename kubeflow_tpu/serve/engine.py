@@ -2011,10 +2011,15 @@ class LLMEngine:
         prompt. Where the program carries the decode step and a slot is live,
         the pass's first program carries the round of this iteration
         (``_ready_round``, as before any round) and says so in both dispatch
-        spans; its tokens go the way every round's go (``_rounds``)."""
+        spans; its tokens go the way every round's go (``_rounds``). Whether
+        a program of so many chunks carries it is the plan's answer
+        (``ChunkPlan.rides``), asked before a round is readied: where it says
+        no, the chunks go as they would with no slot live and the iteration's
+        step goes out as its own program (``_decode_once``)."""
         C = self.chunk_size
         ride = None
-        if self._plan.carries_step and self._mixed_pass != self._admit_pass:
+        if self._plan.rides(len(group)) \
+                and self._mixed_pass != self._admit_pass:
             ride = self._ready_round(
                 [(i, s) for i, s in enumerate(self.slots) if s is not None],
                 1)
@@ -3354,9 +3359,11 @@ class LLMEngine:
         next step, in a pass of its own (the prefills this pass's budget
         deferred: a decode-only round in their place would read every
         weight for a step that rides the next chunk program anyway), else
-        the next decode round, over the slots live now. A prompt that ends
-        in this pass joins the program after it. Returns whether a round
-        went out."""
+        the next decode round, over the slots live now (behind a due chunk
+        that no step rides with, ``ChunkPlan.rides``: a lone chunk of an
+        engine whose step rides filled programs only goes ahead as it is and
+        the round behind it). A prompt that ends in this pass joins the
+        program after it. Returns whether a round went out."""
         if not (self.pipelined and self._round_carried()):
             return False
         with self._transfer_guard():

@@ -895,7 +895,8 @@ def _pool_block(bp, xs, groups, pools, layer, num_pages: dict,  # traced
     disjoint and each writes its rows before it attends; so are their
     entries where a layer keeps a state a sequence: a parallel layer's
     ``ssd_chunk`` leaves the chunk row's entry and ``ssd_step`` the slots',
-    two writers of one plane in one program, neither copying it); then
+    a linear layer's ``kda_chunk`` and ``kda_step`` alike: two writers of
+    one plane in one program, neither copying it); then
     ``_feed_forward`` ONCE over every group's tokens; residual:
     ``decoder._block_forward``'s skeleton, the only other copy. Returns (a
     group's output each, the planes as written).
@@ -2064,10 +2065,12 @@ def _chunk_in_place(cache: dict, cfg: DecoderConfig, lora,
 #: "parallel": the same attention beside an SSD mixer whose state a sequence
 #: is ONE entry of planes of its own; the chunk's row and the slots' rows are
 #: different sequences, so ``ssd_chunk``'s one entry and ``ssd_step``'s are
-#: disjoint, as their pages are. The kinds that keep a ring or a conv tail,
-#: a linear or ssm state, or end in a stateless tail are the next names here
-#: (ROADMAP Speed 0).
-STEP_CARRYING_KINDS = frozenset({"attention", "parallel"})
+#: disjoint, as their pages are. "linear": a KDA mixer whose recurrent
+#: matrices and conv tails are ONE entry a sequence alike (``kda_chunk`` and a
+#: scatter of the end state for the chunk's rows, ``kda_step`` in place for the
+#: slots'). The kinds that keep a ring or a conv tail, an ssm state, or end in
+#: a stateless tail are the next names here (ROADMAP Speed 0).
+STEP_CARRYING_KINDS = frozenset({"attention", "parallel", "linear"})
 
 
 def chunk_carries_step(cache: dict, cfg: DecoderConfig, lora,

@@ -347,6 +347,14 @@ VOICETURNS_STATED = {
 }
 
 
+# PR 60's one reader (``engine.step_riding_share.longdoc``: the chunk programs
+# that carried the slots' step over all of them), the same way: the counter it
+# takes beside ``prefill_programs_dispatched``, at rest, and its number for a
+# window in which neither moved.
+RIDING_ENGINE_COUNTERS = {"mixed_programs_dispatched": 0}
+RIDING_STATED = {"engine.step_riding_share.longdoc": 0.0}
+
+
 @pytest.fixture(autouse=True, scope="session")
 def benchmark_suite_tables_know_the_longanswer_cell(request):
     suite = next(
@@ -371,13 +379,13 @@ def benchmark_suite_tables_know_the_longanswer_cell(request):
               **MIXEDLENGTH_ENGINE_COUNTERS, **LONGDOC_ENGINE_COUNTERS,
               **REASONING_ENGINE_COUNTERS, **STARTUP_ENGINE_COUNTERS,
               **SYNC_ENGINE_COUNTERS, **AGENTCONTEXT_ENGINE_COUNTERS,
-              **VOICETURNS_ENGINE_COUNTERS}),
+              **VOICETURNS_ENGINE_COUNTERS, **RIDING_ENGINE_COUNTERS}),
             ((readers.TRAINER0,), STARTUP_TRAINER_COUNTERS),
             ((suite.ADDED_STATED, total.STATED),
              {**LONGANSWER_STATED, **WINDOW_STATED, **MIXEDLENGTH_STATED,
               **LONGDOC_STATED, **REASONING_STATED, **ASSISTANT_STATED,
               **STARTUP_STATED, **SYNC_STATED, **AGENTCONTEXT_STATED,
-              **VOICETURNS_STATED})):
+              **VOICETURNS_STATED, **RIDING_STATED})):
         for table in tables:
             for key, value in added.items():
                 table.setdefault(key, value)
@@ -413,7 +421,8 @@ def benchmark_suite_tables_know_the_longanswer_cell(request):
 # 50's, which pins a COUNT ("nine cells and nine configurations": a
 # ``benchmark`` PR should ask ``>= 9`` there, PERF.md section 7). PR 57
 # appends a configuration, a cell and twelve behind PR 55's, whose own test
-# pins no end: the same tests are handed the manifest without them too.
+# pins no end: the same tests are handed the manifest without them too. PR 60
+# appends ONE per-layer metric of PR 43's cell behind PR 57's: the same again.
 PINS_PR43_AT_THE_END = "test_what_pr_43_added_is_listed_with_the_benchmark"
 PINS_PR28_AT_THE_END = "test_what_this_pr_added_is_listed_with_the_benchmark"
 PINS_PR35S_CELL = \
@@ -458,7 +467,8 @@ def the_manifest_as_it_stood_for_the_tests_that_pin_a_pr(request,
     name = request.node.name
     module = request.node.module
     since_pr50 = set(STARTUP_STATED) | set(SYNC_STATED) \
-        | set(AGENTCONTEXT_STATED) | set(VOICETURNS_STATED)
+        | set(AGENTCONTEXT_STATED) | set(VOICETURNS_STATED) \
+        | set(RIDING_STATED)
     later = set(WINDOW_STATED) | set(MIXEDLENGTH_STATED) \
         | set(LONGDOC_STATED) | set(REASONING_STATED) \
         | set(ASSISTANT_STATED) | since_pr50

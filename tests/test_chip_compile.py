@@ -680,7 +680,7 @@ def test_mixed_program_compiles_for_v5e_with_both_kernels(cell_programs,
     ("glm-4.7-flash.batch-longcontext", True),
     ("lfm2-24b-a2b.batch-longanswer", False),           # conv layers
     ("k-exaone-236b-a23b.batch-mixedlength", False),    # window layers
-    ("solar-open2-250b.batch-longdoc", False),          # linear layers
+    ("solar-open2-250b.batch-longdoc", True),   # linear layers: PR 60
     ("phi-4-mini-flash.batch-reasoning", False),        # ssm, gmu, cross
     ("falcon-h1-34b.batch-assistant", True),    # parallel layers: PR 58
 ])
@@ -689,9 +689,9 @@ def test_which_cells_chunk_program_carries_the_step(cell, carries):
     ``paged.chunk_carries_step``), at the cells' own shapes: every layer of
     a kind whose chunk and decode operators are held side by side in one
     program
-    (``paged.STEP_CARRYING_KINDS``: "attention" and, since PR 58,
-    "parallel"); the four that stay keep a conv tail, a ring, a linear
-    state, or an ssm state in front of a stateless tail."""
+    (``paged.STEP_CARRYING_KINDS``: "attention", since PR 58 "parallel",
+    since PR 60 "linear"); the three that stay keep a conv tail, a ring, or
+    an ssm state in front of a stateless tail."""
     from scripts.aot_weight_copies import serving_cell
     from test_serve_chunk_plan import plan_of
 
@@ -847,6 +847,75 @@ def test_longdoc_program_compiles_for_v5e_with_its_kernels(cell_programs,
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 11e9
     assert mem.temp_size_in_bytes < 0.4e9      # 0.363 GB, two rows (0.65 before PR 44)
+
+
+# PR 60: the long-document cell's chunk program carries the slots' decode
+# step where BOTH its rows hold a chunk ("mixed[2]": ``paged.paged_mixed_step``
+# over a stack of one gated GQA layer and three KDA layers, at the cell's one
+# width, two rows by the ridge). A NEW program, pinned as PR 60 left it; in the
+# engine it REPLACES "rows[2]" (a pair with no slot live takes it with ``ride``
+# false; ``ChunkPlan.programs()`` names no program over rows for this plan).
+# The cell's other digests did not move and stand where they stood
+# (``LOWERED_SINCE_PR46``: "decode", "chunk[1]", the ``[C, V]`` program a lone
+# chunk keeps and the benchmark's ``correct`` drives, and "rows[2]", still
+# built for callers that ask for it by name).
+LONGDOC_SINCE_PR60 = {"mixed[2]": "7dd81f4b4577278a"}
+
+
+def test_longdoc_mixed_program_copies_no_plane_and_no_weight_on_v5e(
+        cell_programs):
+    """The two-row chunk program that carries the 32 slots' step, at the
+    cell's real sizes, parameters as the engine holds them: it fits the chip
+    beside its arguments and runs BOTH groups' kernels in every layer's scan
+    (``kda_operands`` in front of ``kda_chunk`` for the chunks' rows and
+    ``kda_step`` for the slots', three KDA layers a scan iteration; the chunk
+    kernel a row and the decode kernel for the GQA layer; the experts ONCE
+    over both groups' tokens, as many grouped matmuls as the chunk program
+    alone). The KDA state plane (``[3 x 32, 64, 128, 128]`` float32, 1.6 GB)
+    has two writers in one program, ``kda_chunk``'s two entries by a scatter
+    and ``kda_step``'s in place: it is the operand of no ``copy``, nor are K
+    and V; no float32 array of the chunk's size is copied or transposed to
+    feed the chunk kernels (PR 44); no weight is copied but the two small
+    low-rank second halves (``wf2`` / ``wg2``, 2 MB a layer: standing, the
+    chunk program's and the decode program's too). The conv-tail plane (14
+    MB) is laid out again on the way in and out, as in both of those (the
+    parent's: standing). What it returns beyond the buffers it was donated is
+    ``[2, V]`` float32 and a token a slot. It lowers to the pinned digest."""
+    from scripts.aot_weight_copies import (
+        lowered_fingerprint, serving_cell, weight_copies,
+    )
+
+    programs = cell_programs(LONGDOC, True, "last", True)
+    assert sorted(p for p in programs if p.startswith("mixed")) == sorted(
+        LONGDOC_SINCE_PR60)
+    lowered = programs["mixed[2]"]
+    assert lowered_fingerprint(lowered) == LONGDOC_SINCE_PR60["mixed[2]"]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    alone = programs["chunk[2]"].compile().as_text()
+    for kernel, calls in (("kda_operands", 3), ("kda_chunk", 3),
+                          ("kda_step", 3), ("paged_chunk_attention", 2),
+                          ("paged_decode_attention", 1),
+                          ("gmm", _calls(alone, "gmm"))):
+        assert _calls(text, kernel) == calls > 0, kernel
+    assert "f32[2,64,8,64,128]" not in text
+    assert _f32_relayouts(text, 2 * 512 * 64 * 128) == []
+    cfg, batching = serving_cell(LONGDOC)
+    copies = weight_copies(text, lowered.args_info[0][0])
+    assert {leaf for c in copies for leaf in c["leaf"]} <= {
+        "['layers']['linear']['wf2']", "['layers']['linear']['wg2']"}
+    # nothing the size of a layer's pages of K or V, or of a layer's KDA
+    # states (a third of the plane), is copied; the largest copy standing
+    # is the conv-tail plane's (3 x 32 entries of 9 rows of 8192)
+    page = batching.page_size * cfg.n_kv_heads * cfg.head_dim
+    state = cfg.linear_heads * cfg.linear_head_dim ** 2
+    assert max(math.prod(c["shape"]) for c in copies) < min(
+        batching.max_pages * page, batching.max_batch_size * state)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 11e9
+    assert mem.temp_size_in_bytes < 0.4e9      # 0.374 GB (the chunk program: 0.363)
+    result = mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert 2 * cfg.vocab_size * 4 <= result < 2 * cfg.vocab_size * 4 + 2 ** 20
 
 
 def test_ssm_scan_compiles_for_v5e(chip):

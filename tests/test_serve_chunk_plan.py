@@ -4,7 +4,10 @@ function of what the engine observes (``serve/chunk_programs.py``), so no
 engine is built here and nothing is compiled. ``PLANS`` was read off the
 engines of the commit before the module (72f5d8c: its five flags and whether
 its one-row program was ``_OneContext``), once; ``CELLS`` off the plans of
-the benchmark's serving cells, which ``ChunkPlan.send``'s docstring names."""
+the benchmark's serving cells, which ``ChunkPlan.send``'s docstring names.
+Since ISSUE 60 the plan also says whether the slots' step rides a program of
+so many chunks (``rides``): the table's sixth case, several rows wide with no
+row to send ahead, and one engine of that plan on the CPU."""
 
 import dataclasses
 
@@ -39,7 +42,10 @@ BASE = dict(max_batch_size=3, max_seq_len=128, page_size=16,
 # heads of 128: what the chunk kernel takes (a tiny preset's heads of 16 stay
 # on the gathered form, whatever the arm: ``paged._chunk_in_place``)
 WIDE = {"tiny": dict(head_dim=128), "tiny-moe": dict(head_dim=128),
-        "tiny-falconh1": dict(n_heads=2, n_kv_heads=1, head_dim=128)}
+        "tiny-falconh1": dict(n_heads=2, n_kv_heads=1, head_dim=128),
+        "tiny-solar": dict(n_layers=4, n_heads=2, n_kv_heads=1, head_dim=128,
+                           linear_heads=2, linear_head_dim=128,
+                           linear_gate_rank=16)}
 # a dense chunk of 256 tokens is over the ridge: one chunk a program
 RIDGE = dict(max_seq_len=1024, chunked_prefill_tokens=256)
 OPTIONS = {
@@ -114,6 +120,12 @@ PLANS = {
     "tiny-falconh1|pallas|2|wide": (T, 2, F, F, F, T),
     "tiny-falconh1|pallas|2|wide+ridge": (T, 1, F, F, F, T),
     "tiny-falconh1|gather|2|wide+ridge": (F, 1, T, F, F, F),
+    # since PR 60 the kind "linear" carries the step (sorted experts: two
+    # rows by the ridge whatever the chunk); two rows wide it is, with the
+    # parallel stack two rows wide above, the table's sixth case
+    "tiny-solar|pallas|1|wide": (T, 1, F, F, F, T),
+    "tiny-solar|pallas|2|wide": (T, 2, F, F, F, T),
+    "tiny-solar|gather|2|wide": (F, 2, F, F, F, F),
 }
 
 
@@ -160,12 +172,24 @@ def test_every_pass_is_sent_as_it_was_and_through_a_program_that_is_built(
     case names a program ``ChunkPrograms`` was not asked to build
     (``programs()``: what ``LLMEngine.__init__`` asks for)."""
     plan = ChunkPlan(*PLANS[case])
-    # a step rides only where the program carries it; an engine with
-    # nothing else to do has one chunk and no live slot
+    # a step rides only where the plan says so of a program of so many
+    # chunks (``rides``: where the program carries it at all, and its rows
+    # are all filled or the plan fills them itself); an engine with nothing
+    # else to do has one chunk and no live slot
     cases = [(n, rides, idle) for n in range(1, plan.rows + 1)
-             for rides in (False, True)[:1 + plan.carries_step]
+             for rides in (False, True)[:1 + plan.rides(n)]
              for idle in (False, True)[:1 + (n == 1 and not rides)]]
-    assert len(cases) == plan.rows * (1 + plan.carries_step) + 1
+    filled_only = plan.carries_step and plan.rows > 1 and not plan.ahead
+    assert [plan.rides(n) for n in range(1, plan.rows + 1)] == [
+        plan.carries_step and (n == plan.rows or not filled_only)
+        for n in range(1, plan.rows + 1)]
+    assert len(cases) == plan.rows * (1 + plan.carries_step) + 1 - (
+        plan.rows - 1) * filled_only
+    if filled_only:         # the sixth case: one chunk "lone" and no ride
+        assert plan.send(1, False, False) == Sent("lone", 1, OWN_BUCKET)
+        assert plan.send(plan.rows, True, False) == plan.send(
+            plan.rows, False, False) == Sent("mixed", plan.rows, WHOLE_TABLE)
+        assert plan.programs() == {"mixed", "lone"}
     for n, rides, idle in cases:
         sent = plan.send(n, rides, idle)
         assert sent == _before(plan, n, rides, idle), (n, rides, idle)
@@ -195,9 +219,12 @@ CELLS = {
                     "longcat-flash-omni.batch-voiceturns")},
     "lfm2-24b-a2b.batch-longanswer": (
         (F, 2, F, F, F, F), "lone 1", "rows 2", "lone 1"),
-    **{cell: ((F, 2, F, F, F, T), "lone 1", "rows 2", "lone 1")
-       for cell in ("k-exaone-236b-a23b.batch-mixedlength",
-                    "solar-open2-250b.batch-longdoc")},
+    "k-exaone-236b-a23b.batch-mixedlength": (
+        (F, 2, F, F, F, T), "lone 1", "rows 2", "lone 1"),
+    # since PR 60: a pair carries the step, a lone chunk keeps its one-row
+    # program (the step does not ride a program with a dead row)
+    "solar-open2-250b.batch-longdoc": (
+        (T, 2, F, F, F, T), "lone 1", "mixed 2", "lone 1"),
     "phi-4-mini-flash.batch-reasoning": (
         (F, 2, T, F, F, T), "rows 1", "rows 2", "rows 1"),
 }
@@ -217,6 +244,61 @@ def test_each_serving_cell_takes_the_case_the_table_names(cell):
         sent = plan.send(n, rides, otherwise_idle)
         return f"{sent.program} {sent.rows}"
 
-    assert carries(1, plan.carries_step, False) == beside_a_slot
-    assert two is None or carries(2, plan.carries_step, False) == two
+    assert carries(1, plan.rides(1), False) == beside_a_slot
+    assert two is None or carries(2, plan.rides(2), False) == two
     assert carries(1, False, True) == idle
+
+
+def test_a_lone_chunk_sends_the_decode_program_and_a_pair_does_not():
+    """An engine two rows wide that sends no row ahead and carries the step
+    (the long-document cell's plan, a tiny Solar stack with the kernels
+    interpreted): beside a live stream, an iteration whose pass has ONE chunk
+    due sends it through the one-row program and the stream's step as
+    ``paged_decode``; an iteration with a PAIR due sends one program, which
+    carries the step, and no ``paged_decode``."""
+    from test_serve_mixed_program import LONG, _engine
+
+    from kubeflow_tpu.serve.engine import SamplingParams
+
+    eng = _engine("linear")
+    assert dataclasses.astuple(eng._plan) == (T, 2, F, F, F, T)
+    assert eng._plan.programs() == {"mixed", "lone"}
+    assert not hasattr(eng._programs, "rows")
+    sent = []
+    for name, at in (("lone", eng._programs), ("mixed", eng._programs),
+                     ("_paged_decode_n", eng)):
+        def spy(*args, name=name, program=getattr(at, name)):
+            sent.append(name.strip("_"))
+            return program(*args)
+        setattr(at, name, spy)
+    sp = SamplingParams(max_new_tokens=8, temperature=0.0)
+    stream = eng.submit(list(map(int, LONG[0][:40])), SamplingParams(
+        max_new_tokens=100, temperature=0.0))
+    while stream.first_token_time is None:
+        eng.step()
+    eng.step()
+    del sent[:]
+    alone = eng.submit(list(map(int, LONG[0][:90])), sp)    # three chunks
+    eng.step()
+    assert sent == ["lone", "paged_decode_n"]
+    eng.step()
+    assert sent == ["lone", "paged_decode_n"] * 2
+    while not alone.done.is_set():
+        eng.step()
+    del sent[:]
+    before = eng.counters()
+    pair = [eng.submit(list(map(int, LONG[j][:90])), sp) for j in (1, 2)]
+    eng.step()
+    assert sent == ["mixed"]
+    eng.step()
+    assert sent == ["mixed", "mixed"]
+    c = eng.counters()
+    for name, n in (("mixed_programs_dispatched", 2), ("decode_rounds", 2),
+                    ("prefill_programs_dispatched", 2),
+                    ("prefill_chunks_dispatched", 4),
+                    ("prefill_rows_dead", 0)):
+        assert c[name] == before[name] + n, name
+    assert c["mixed_decode_rows_sum"] == before["mixed_decode_rows_sum"] + 2
+    while not all(r.done.is_set() for r in (stream, *pair)):
+        eng.step()
+    eng._allocator.assert_quiescent()

@@ -21,7 +21,15 @@ written by ``ssd_chunk`` for the chunk's row and by ``ssd_step`` for the
 slots' in one program. It is a fourth stack of ``KINDS`` wherever the case
 does not need rows ahead (``AHEAD``: a state is handed from a chunk's END to
 the next chunk's start, so a parallel stack never sends one), and has the
-cases of an engine ONE row wide, the assistant cell's, to itself."""
+cases of an engine ONE row wide, the assistant cell's, to itself.
+
+The kind "linear" rides too (ISSUE 60): a Solar-like stack, KDA layers whose
+recurrent matrices and conv tails are ONE entry a sequence (``kda_chunk`` and
+a scatter of the end state for the chunk's rows, ``kda_step`` in place for
+the slots') beside a gated GQA layer in four, sorted experts of which a share
+is held. A fifth stack of ``KINDS``. An engine several rows wide that sends no
+row ahead (this one, and the parallel stack at two rows) lets the step ride
+only a program whose rows are all filled (``ChunkPlan.rides``)."""
 
 import dataclasses
 import functools
@@ -44,11 +52,11 @@ from kubeflow_tpu.serve.paged import (
     mixed_step_rows, paged_chunk_prefill, paged_decode_multi,
     paged_mixed_step, pool_shapes,
 )
-from test_serve_chunk_plan import plan_of
+from test_serve_chunk_plan import WIDE, plan_of
 
 PAGE, CHUNK, MPP = 16, 32, 8
 SLOTS = 4
-KINDS = ("dense", "dispatch", "latent", "parallel")
+KINDS = ("dense", "dispatch", "latent", "parallel", "linear")
 # the stacks whose every layer is of kind "attention": a program's spare rows
 # may carry the NEXT chunks of the prompts in it (``paged.chunk_rows_follow``)
 AHEAD = KINDS[:3]
@@ -68,6 +76,11 @@ def _config(kind: str):
         # inside a block
         return preset("tiny-falconh1", n_heads=2, n_kv_heads=1, head_dim=128,
                       **over)
+    if kind == "linear":
+        # Solar-like (``rehearsal-tiny-solar``'s stack at the widths the
+        # kernels take): one gated GQA layer in four beside KDA layers, 4 of
+        # 16 sorted experts held and a shared expert
+        return preset("tiny-solar", **WIDE["tiny-solar"], **over)
     return preset("tiny-glm", **over)
 
 
@@ -437,11 +450,13 @@ def test_engine_tokens_are_the_full_recomputes(kind, kw):
     c = eng.counters()
     # fourteen chunks in eight programs; the first two find no slot live.
     # Prompts 2 and 4 each go alone for a while, a chunk ahead a program;
-    # prompts 0 and 4 end in an odd chunk beside a dead row. A parallel
-    # stack sends no chunk ahead: ten programs, and a prompt alone leaves
-    # the second row of each of its six dead
-    programs, riding, ahead, dead = (10, 8, 0, 6) if kind == "parallel" \
-        else (8, 6, 2, 2)
+    # prompts 0 and 4 end in an odd chunk beside a dead row. A parallel or
+    # a linear stack sends no chunk ahead, and the step rides only a program
+    # whose rows are both filled (``ChunkPlan.rides``): ten programs, four
+    # of them pairs (two with no slot live yet), and each of the six lone
+    # chunks the one-row program with the iteration's step behind it
+    programs, riding, ahead, dead = (8, 6, 2, 2) if kind in AHEAD \
+        else (10, 2, 0, 0)
     assert eng._plan.ahead == (kind in AHEAD)
     assert (c["prefill_programs_dispatched"],
             c["mixed_programs_dispatched"]) == (programs, riding)
@@ -488,10 +503,11 @@ OTHER_STACKS = {
     "ssm": ("tiny-phi4flash", dict(max_seq_len=128, page_size=8,
                                    chunked_prefill_tokens=16,
                                    enable_prefix_caching=False)),
-    # the KIND rides since PR 58 (``KINDS`` above: one KV head of 128); the
-    # preset AS IT STANDS has heads of 16, which ``paged_chunk_attention``
-    # does not take, so its chunks stay on the gathered form
-    # (``paged._chunk_in_place``) and THAT keeps its two programs
+    # the KINDS "parallel" and "linear" ride since PR 58 and PR 60 (``KINDS``
+    # above: one KV head of 128); the presets AS THEY STAND have heads of 16,
+    # which ``paged_chunk_attention`` does not take, so their chunks stay on
+    # the gathered form (``paged._chunk_in_place``) and THAT keeps their two
+    # programs
     "parallel": ("tiny-falconh1", dict(max_seq_len=128, page_size=8,
                                        chunked_prefill_tokens=16,
                                        enable_prefix_caching=False)),
@@ -898,26 +914,63 @@ def test_one_row_wide_nothing_compiles_after_a_first_run():
         "prefill_programs_dispatched"]
 
 
-def test_a_parallel_stack_sends_no_chunk_ahead():
+@pytest.mark.parametrize("kind", KINDS[3:])
+def test_a_stack_that_keeps_a_state_sends_no_chunk_ahead(kind):
     """Two rows a program (a chunk under the ridge) and the step carried,
     but a prompt alone goes ONE chunk a program: the chunk behind needs the
-    SSD state and the conv tail the chunk in front ENDS in, which the rows
-    of one program do not hand on (``paged.chunk_rows_follow``)."""
+    state and the conv tail the chunk in front ENDS in, which the rows of one
+    program do not hand on (``paged.chunk_rows_follow``). And a chunk alone
+    fills one row of two, so no step rides with it (``ChunkPlan.rides``): it
+    takes the one-row program, not the two-row one beside a dead row, and
+    the live stream's step goes out as the decode program."""
     from kubeflow_tpu.serve.paged import chunk_rows_follow
 
-    assert [chunk_rows_follow(_model(kind)[0]) for kind in KINDS] == [
-        True, True, True, False]
-    eng = _engine("parallel")
+    assert [chunk_rows_follow(_model(k)[0]) for k in KINDS] == [
+        True, True, True, False, False]
+    eng = _engine(kind)
     assert eng._plan.carries_step and eng._plan.rows == 2
     assert not eng._plan.ahead and not eng._plan.rows_only
+    assert [eng._plan.rides(n) for n in (1, 2)] == [False, True]
     first = _beside_a_live_stream(eng)
-    before = _chunk_counts(eng)
+    before, riding = _chunk_counts(eng), eng.counters()[
+        "mixed_programs_dispatched"]
     got = _alone(eng)
     programs, chunks, ahead, dead = (
         a - b for a, b in zip(_chunk_counts(eng), before))
-    assert (programs, chunks, ahead, dead) == (5, 5, 0, 5)
-    assert got == _alone(_engine("parallel", max_concurrent_prefills=1))
+    assert (programs, chunks, ahead, dead) == (5, 5, 0, 0)
+    assert eng.counters()["mixed_programs_dispatched"] == riding
+    assert got == _alone(_engine(kind, max_concurrent_prefills=1))
     assert not first.done.is_set()
+
+
+@pytest.mark.parametrize("kind", KINDS[3:])
+def test_a_row_that_starts_its_sequence_beside_the_slots(kind):
+    """A chunk from position 0 (``fresh``: its entry's state and conv tail
+    read as zeros, whatever an earlier occupant of the first page left
+    there) beside a dead chunk row and a dead slot among the riding ones:
+    the one program against the chunk program and then the decode step,
+    every plane (K and V pages, the state and the conv tail of EVERY entry,
+    the row's and the slots')."""
+    pool, _, state, rows = _scene(kind)
+    a, row_a = rows[0][:2]
+    # prompt a's 24 tokens are held: its entry is NOT zeros in the pool
+    names = [n for n in pool if n not in ("k", "v", "moe_rows")]
+    assert names and all(float(jnp.abs(pool[n][:, row_a[0]]).max()) > 0
+                         for n in names)
+    state = {**state, "live": np.asarray([True, False, True, True])}
+    want, got = _both_ways(kind, state, [(a, row_a, 0, CHUNK, False), None])
+    np.testing.assert_array_equal(got[1], want[1])
+    for name in want[2]:
+        _close(got[2][name], want[2][name], f"plane {name}")
+    for i, name in enumerate(("tokens", "lengths", "live", "budgets"), 3):
+        np.testing.assert_array_equal(got[i], want[i], err_msg=name)
+    # and it is the state a pool that never held the sequence ends in
+    chunk = _programs(kind)[0]
+    empty = {n: jnp.zeros_like(p) for n, p in pool.items()}
+    _, clean = chunk(_model(kind)[1], empty,
+                     *_rows([(a, row_a, 0, CHUNK, False), None]))
+    for n in names:
+        _close(got[2][n][:, row_a[0]], clean[n][:, row_a[0]], f"entry {n}")
 
 
 # -- a program's spare rows: the next chunks of the prompts in it (ISSUE 56) -----
