@@ -4,8 +4,8 @@ Presets cover the BASELINE.json configs (Llama-3-8B, Gemma-2B, Mixtral-8x7B)
 and the models the benchmark serves at their published widths (GLM-4.7-Flash,
 LFM2-24B-A2B, K-EXAONE-236B-A23B, Solar-Open2-250B,
 Phi-4-mini-flash-reasoning, Falcon-H1-34B-Instruct, GLM-5,
-LongCat-Flash-Omni's language model), plus tiny variants of each structure
-for tests.
+LongCat-Flash-Omni's language model, Nemotron-3-Super-120B-A12B), plus tiny
+variants of each structure for tests.
 Architecture facts are from the public model cards and ``config.json``
 files.
 """
@@ -24,9 +24,9 @@ import jax.numpy as jnp
 STATELESS_KINDS = {"gmu": "ssm", "cross": "attention"}
 SSM_KINDS = ("ssm", *STATELESS_KINDS)
 # A layer of the key's kind ALSO keeps the cache planes of these kinds: a
-# "parallel" layer its K and V rows a token, as an attention layer does,
-# beside the state a sequence that is its own.
-ALSO_HOLDS = {"parallel": ("attention",)}
+# "parallel" layer its K and V rows a token, as an attention layer does, AND
+# the state a sequence that an "ssd" layer keeps (it runs both operators).
+ALSO_HOLDS = {"parallel": ("attention", "ssd")}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -43,7 +43,11 @@ class DecoderConfig:
     max_seq_len: int = 2048
     rope_theta: float = 500000.0
     norm_eps: float = 1e-5
-    hidden_act: str = "silu"       # silu => SwiGLU; gelu => GeGLU (gemma)
+    # silu => SwiGLU; gelu => GeGLU (gemma); relu2 => a plain MLP of TWO
+    # matrices, ``relu(x W_up) ** 2 W_down`` (no gate matrix anywhere: the
+    # dense MLP's, the experts' and the shared expert's trees hold "up" and
+    # "down" alone)
+    hidden_act: str = "silu"
     tie_embeddings: bool = False
     norm_plus_one: bool = False    # gemma-style (1 + w) RMSNorm weight
     embed_scale: bool = False      # gemma-style sqrt(hidden) embedding scale
@@ -65,6 +69,13 @@ class DecoderConfig:
     moe_mlp_dim: int = 0
     shared_experts: int = 0
     leading_dense_layers: int = 0
+    # Experts behind a latent projection (0 = none): the ROUTED experts read
+    # and write rows of this width, ``x W_dn`` once a token in front of the
+    # sort and ``(sum of the chosen experts' outputs) W_up`` once behind the
+    # combine, with nothing between a projection and an expert; the router
+    # and the shared expert read the block's own ``hidden``-wide input
+    # (``layers.moe_block``).
+    moe_latent_dim: int = 0
     # The router's score: "softmax" (Mixtral: top-k of the logits, softmax
     # over the chosen), "sigmoid" (scores sigmoid(logits) in float32,
     # CHOSEN by score plus a learned bias, WEIGHTED by the score alone,
@@ -173,7 +184,16 @@ class DecoderConfig:
     # C]``; the chunked form walks blocks of ``ssd_chunk`` positions. Its
     # layer keeps K and V rows a token AND the state and the convolution's
     # tail, one entry a SEQUENCE. A stack of them holds no other kind.
+    # An "ssd" layer's ONLY operator is that mixer (no K and V rows: its
+    # state and tail are all it keeps), and it stands beside attention
+    # layers in one stack.
+    # ``ffn_free``: the places in ONE PERIOD of ``layer_kinds`` whose block
+    # is its operator alone, ``x + F(N(x))``: no second norm, no feed-forward
+    # part (a published stack that lists mixers and feed-forward parts as
+    # layers of their own, read as blocks: a mixer followed by another
+    # mixer).
     layer_kinds: tuple = ()
+    ffn_free: tuple = ()
     ssd_heads: int = 0
     ssd_head_dim: int = 0
     ssd_state: int = 0
@@ -254,19 +274,39 @@ class DecoderConfig:
             if len(value) not in (0, n):
                 raise ValueError(f"{name} holds {n} multipliers or none")
             object.__setattr__(self, name, value)
+        object.__setattr__(self, "ffn_free",
+                           tuple(int(i) for i in self.ffn_free))
         unknown = set(self.layer_kinds) - {"attention", "window", "conv",
-                                           "linear", "parallel", *SSM_KINDS}
+                                           "linear", "parallel", "ssd",
+                                           *SSM_KINDS}
         if unknown:
             raise ValueError(f"unknown layer kinds {sorted(unknown)}")
-        if "parallel" in self.layer_kinds:
+        if self.ffn_free and not (
+                set(self.ffn_free) <= set(range(len(self.layer_kinds)))
+                and not self.moe_shortcut and not self.leading_dense_layers
+                and not self.stateless_tail):
+            raise ValueError(
+                f"ffn_free={self.ffn_free} names places of the "
+                f"{len(self.layer_kinds)} of layer_kinds; not with a "
+                "shortcut, leading dense layers or a stateless tail")
+        if self.moe_latent_dim and not (
+                self.is_moe and not self.zero_experts
+                and self.moe_impl in ("sorted", "dense")):
+            raise ValueError(
+                "a latent projection (moe_latent_dim) stands round the "
+                "routed experts of a sorted or dense expert layer: a zero "
+                "expert's identity has no width to return, a capacity "
+                "buffer is hidden-wide")
+        if {"parallel", "ssd"} & set(self.layer_kinds):
             if not (self.ssd_heads > 0 and self.ssd_head_dim > 0
                     and self.ssd_state > 0 and self.ssd_groups > 0
                     and self.ssd_chunk > 0
                     and self.ssd_heads % self.ssd_groups == 0):
                 raise ValueError(
-                    "parallel layers need ssd_heads, ssd_head_dim, "
+                    "parallel and ssd layers need ssd_heads, ssd_head_dim, "
                     "ssd_state and ssd_chunk > 0 and ssd_groups dividing "
                     "ssd_heads")
+        if "parallel" in self.layer_kinds:
             if set(self.layer_kinds) != {"parallel"} or self.is_latent \
                     or self.diff_attention or self.kv_heads_packed:
                 raise NotImplementedError(
@@ -345,6 +385,14 @@ class DecoderConfig:
         period = self.period
         return tuple(period[i % len(period)] for i in range(self.n_layers))
 
+    @property
+    def fed(self) -> tuple:
+        """Whether each layer of the stack has its feed-forward part (and
+        the norm in front of it): all but the places ``ffn_free`` names."""
+        p = len(self.period)
+        return tuple(i % p not in self.ffn_free
+                     for i in range(self.n_layers))
+
     def layers_of(self, kind: str) -> int:
         return self.kinds.count(kind)
 
@@ -407,8 +455,23 @@ class DecoderConfig:
 
     def expert_layer(self, block: int) -> int:
         """The expert layer, counted among its group's, that block ``block``
-        of the group holds (under a shortcut: starts or joins)."""
+        of the group holds (under a shortcut: starts or joins); with blocks
+        that have no feed-forward part (``ffn_free``), the feed-forward
+        parts in front of ``block``."""
+        if self.ffn_free:       # (``block`` may be a scan's traced index)
+            p = len(self.period)
+            before = [sum(self.fed[:j]) for j in range(p)]
+            place = block % p
+            return block // p * sum(self.fed[:p]) + (
+                before[place] if isinstance(block, int)
+                else jnp.asarray(before, jnp.int32)[place])
         return block // 2 if self.moe_shortcut else block
+
+    @property
+    def mlp_matrices(self) -> int:
+        """Matrices of one MLP (dense, an expert, the shared expert): gate,
+        up and down, or up and down alone (``hidden_act`` "relu2")."""
+        return 2 if self.hidden_act == "relu2" else 3
 
     def _conv_params(self) -> int:
         """One conv block's operator: in and out projections and the taps."""
@@ -497,6 +560,7 @@ class DecoderConfig:
             + kinds.count("ssm") * self._ssm_params() \
             + kinds.count("parallel") * (self._attn_params()
                                          + self._ssd_params()) \
+            + kinds.count("ssd") * self._ssd_params() \
             + kinds.count("gmu") * 2 * self.hidden * self.ssm_inner \
             + (kinds.count("cross") * self._diff_params(cross=True)
                if "cross" in kinds else 0)
@@ -506,18 +570,21 @@ class DecoderConfig:
         counts the experts one token multiplies against HERE (of its
         ``experts_per_token`` choices the expected share that falls on a held
         expert, ``experts_here / num_experts`` of them), not those held."""
-        d = self.hidden
+        d, mats = self.hidden, self.mlp_matrices
         if not self.is_moe:
-            return 3 * d * self.mlp_dim
-        per_expert = 3 * d * self.expert_mlp_dim
+            return mats * d * self.mlp_dim
+        # a routed expert works at the latent's width where there is one;
+        # the shared expert and the router at the hidden's
+        per_expert = mats * (self.moe_latent_dim or d) * self.expert_mlp_dim
+        shared = self.shared_experts * mats * d * self.expert_mlp_dim
+        latent = 2 * d * self.moe_latent_dim
         if active:      # the router's small product is left out, as before
             met = self.experts_per_token * self.experts_here \
                 / self.router_width
-            return int((met + self.shared_experts) * per_expert)
+            return int(met * per_expert) + shared + latent
         routing = self.router_width * (
             d + (self.router_score != "softmax"))       # the choice's bias
-        return (self.experts_here + self.shared_experts) * per_expert \
-            + routing
+        return self.experts_here * per_expert + shared + latent + routing
 
     def num_params(self) -> int:
         """Parameters HELD (embedding included once if tied): of an expert
@@ -530,8 +597,9 @@ class DecoderConfig:
             k = self.n_layers
         layers = self._operator_params(0, self.n_layers) \
             + self.expert_layer(self.n_layers - self.leading_dense_layers) \
-            * self._mlp_params(False) + self.n_layers * 2 * norm \
-            + k * 3 * d * self.mlp_dim
+            * self._mlp_params(False) \
+            + (self.n_layers + sum(self.fed)) * norm \
+            + k * self.mlp_matrices * d * self.mlp_dim
         embed = v * d if self.tie_embeddings else 2 * v * d
         return layers + embed + norm
 
@@ -545,8 +613,31 @@ class DecoderConfig:
         dense_n = self._operator_params(0, self.n_layers) \
             + self.expert_layer(self.n_layers - self.leading_dense_layers) \
             * self._mlp_params(True) \
-            + k * 3 * d * self.mlp_dim + self.vocab_size * d
+            + k * self.mlp_matrices * d * self.mlp_dim + self.vocab_size * d
         return 6.0 * dense_n
+
+
+def blocks_of(pattern: str) -> tuple:
+    """A published stack that lists every sublayer as a layer of its own
+    ("M" a Mamba-2 mixer, "*" an attention, "E" an expert layer, each ``x +
+    F(N(x))``) as this system's blocks: (a block's kind for every mixer or
+    attention, in order; the places of the blocks whose operator no "E"
+    follows: ``layer_kinds``, ``ffn_free``)."""
+    kinds, free = [], []
+    for i, c in enumerate(pattern):
+        if c == "E":
+            if not kinds or pattern[i - 1] == "E":
+                raise ValueError(f"an expert layer behind no operator at {i}")
+            continue
+        kinds.append({"M": "ssd", "*": "attention"}[c])
+        if pattern[i + 1:i + 2] != "E":
+            free.append(len(kinds) - 1)
+    return tuple(kinds), tuple(free)
+
+
+_NEMOTRON_BLOCKS = blocks_of(
+    "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+    "EMEMEMEM*EMEMEMEME")
 
 
 PRESETS: dict[str, DecoderConfig] = {
@@ -701,6 +792,27 @@ PRESETS: dict[str, DecoderConfig] = {
                          0.25, 0.1767766952966369, 0.5, 0.3535533905932738),
         mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
     ),
+    # Nemotron-3-Super-120B-A12B (nvidia config.json, model_type nemotron_h;
+    # arXiv:2504.03624: 88 published layers, 4096h, each ONE sublayer: a
+    # Mamba-2 mixer (M: 128 heads of 64, state 128, 8 groups, 4 taps), an
+    # expert layer (E) or GQA of 32/2 heads of 128 without position (*),
+    # pattern "MEMEMEM*E..."; read as 48 BLOCKS of a mixer or an attention
+    # and the expert layer behind it, the 8 mixers in front of an attention
+    # without one (``ffn_free``); 512 sigmoid-routed experts of 2688 behind a
+    # latent of 1024, top-22, weights scaled 5, beside one shared expert of
+    # 5376 (two of 2688 in one matrix); squared-ReLU MLPs of two matrices;
+    # untied head. The prediction module is not built)
+    "nemotron-3-super-120b-a12b": DecoderConfig(
+        vocab_size=131072, hidden=4096, n_layers=48, n_heads=32,
+        n_kv_heads=2, head_dim=128, mlp_dim=2688, max_seq_len=262144,
+        rope_theta=10000.0, norm_eps=1e-5, hidden_act="relu2",
+        num_experts=512, experts_per_token=22, moe_impl="sorted",
+        moe_mlp_dim=2688, shared_experts=2, moe_latent_dim=1024,
+        router_score="sigmoid", router_norm_topk=True, router_scale=5.0,
+        layer_kinds=_NEMOTRON_BLOCKS[0], ffn_free=_NEMOTRON_BLOCKS[1],
+        use_rope=False, conv_taps=4, ssd_heads=128, ssd_head_dim=64,
+        ssd_state=128, ssd_groups=8, ssd_chunk=128,
+    ),
     # tiny variants for tests/sim (structure-faithful, sized for 1 CPU core)
     "tiny": DecoderConfig(
         vocab_size=256, hidden=64, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -820,6 +932,23 @@ PRESETS: dict[str, DecoderConfig] = {
         attn_multipliers=(0.75, 0.5, 0.25),
         ssd_multipliers=(0.5, 0.4, 0.7, 0.6, 0.35, 0.8, 0.45),
         mlp_multipliers=(0.6, 0.3),
+    ),
+    # Nemotron-3-Super's structure as one chip of four holds it: the
+    # published pattern "MEM*EME" as four blocks (mixer + experts, mixer
+    # ALONE, attention + experts, mixer + experts): an SSD mixer of 4 heads
+    # of 16 with a state of 16 in 2 groups, blocks of 8 positions; GQA of
+    # 4/2 heads of 16 without position; 16 sigmoid-routed experts of 32
+    # behind a latent of 32, top-4, 4 held, beside a shared expert of 64;
+    # squared-ReLU MLPs of two matrices
+    "tiny-nemotron-h": DecoderConfig(
+        vocab_size=256, hidden=64, n_layers=4, n_heads=4, n_kv_heads=2,
+        head_dim=16, mlp_dim=32, max_seq_len=256, hidden_act="relu2",
+        num_experts=16, experts_per_token=4, moe_impl="sorted",
+        moe_mlp_dim=32, shared_experts=2, moe_latent_dim=32,
+        router_score="sigmoid", router_norm_topk=True, router_scale=5.0,
+        experts_held=4, layer_kinds=("ssd", "ssd", "attention", "ssd"),
+        ffn_free=(1,), use_rope=False, conv_taps=4, ssd_heads=4,
+        ssd_head_dim=16, ssd_state=16, ssd_groups=2, ssd_chunk=8,
     ),
 }
 

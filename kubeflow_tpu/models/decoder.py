@@ -15,6 +15,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from kubeflow_tpu.models.config import ALSO_HOLDS, DecoderConfig
 from kubeflow_tpu.models import layers as L
@@ -32,7 +33,7 @@ Params = dict[str, Any]
 # sequence where a global layer keeps every page, serve/paged.py).
 OPERATOR = {"attention": "attn", "window": "window", "conv": "conv",
             "linear": "linear", "ssm": "ssm", "gmu": "gmu", "cross": "cross",
-            "parallel": "parallel"}
+            "parallel": "parallel", "ssd": "ssd"}
 # a window layer's plane -> the name attention knows it by
 WINDOW_PLANES = {"window_k": "k", "window_v": "v"}
 # a linear layer's planes: a sequence's recurrent matrices and the tails of
@@ -41,14 +42,14 @@ LINEAR_PLANES = ("kda_state", "kda_conv")
 # an ssm layer's planes: a sequence's recurrent state [N, E] and the tail of
 # its convolution (one entry a SEQUENCE, as a linear layer's)
 SSM_PLANES = ("ssm_state", "ssm_conv")
-# a parallel layer's own planes: a sequence's SSD state [H, N, P] and the
-# tail of its convolution (one entry a SEQUENCE); its K and V are the
-# attention planes' rows (``config.ALSO_HOLDS``)
+# an ssd layer's planes: a sequence's SSD state [H, N, P] and the tail of its
+# convolution (one entry a SEQUENCE). A parallel layer keeps them too, beside
+# its K and V, the attention planes' rows (``config.ALSO_HOLDS``)
 SSD_PLANES = ("ssd_state", "ssd_conv")
 PLANE_KINDS = {"conv": "conv", **dict.fromkeys(WINDOW_PLANES, "window"),
                **dict.fromkeys(LINEAR_PLANES, "linear"),
                **dict.fromkeys(SSM_PLANES, "ssm"),
-               **dict.fromkeys(SSD_PLANES, "parallel")}
+               **dict.fromkeys(SSD_PLANES, "ssd")}
 
 
 def plane_kind(name: str) -> str:
@@ -71,7 +72,7 @@ def block_kind(bp: dict) -> str:
 def _init_operator(key, cfg: DecoderConfig, kind: str):
     init = {"conv": L.init_conv, "linear": L.init_linear,
             "ssm": L.init_ssm, "gmu": L.init_gmu,
-            "parallel": L.init_parallel,
+            "parallel": L.init_parallel, "ssd": L.init_ssd,
             "cross": lambda k, c: L.init_diff_attention(k, c, cross=True),
             }.get(kind, L.init_attention)
     return init(jax.random.split(key)[0], cfg)
@@ -83,15 +84,23 @@ def _init_started(key, cfg: DecoderConfig):
     return L.init_moe(jax.random.fold_in(key, 2), cfg)
 
 
-def _init_ffn(key, cfg: DecoderConfig, starts: bool = False):
+# what a block WITH a feed-forward part has beside its operator and first norm
+FED_LEAVES = ("mlp", "ln2", "ln2_b")
+
+
+def _init_ffn(key, cfg: DecoderConfig, starts: bool = False,
+              fed: bool = True):
     """What every kind of block has beside its operator: the feed-forward
     (or expert) layer and the two norms. Under a shortcut the feed-forward
     is the dense MLP, and a block that ``starts`` an expert layer has that
-    too."""
+    too. A block that is not ``fed`` (``cfg.ffn_free``) is its operator
+    behind the first norm and nothing else."""
+    ln1, ln1_s = L.init_norm(cfg, "ln1")
+    if not fed:
+        return ln1, ln1_s
     k_mlp = jax.random.split(key)[1]
     dense = not cfg.is_moe or cfg.moe_shortcut
     mlp_p, mlp_s = (L.init_mlp if dense else L.init_moe)(k_mlp, cfg)
-    ln1, ln1_s = L.init_norm(cfg, "ln1")
     ln2, ln2_s = L.init_norm(cfg, "ln2")
     params, specs = {"mlp": mlp_p, **ln1, **ln2}, {"mlp": mlp_s, **ln1_s,
                                                    **ln2_s}
@@ -101,9 +110,9 @@ def _init_ffn(key, cfg: DecoderConfig, starts: bool = False):
 
 
 def _init_block(key, cfg: DecoderConfig, kind: str = "attention",
-                starts: bool = False):
+                starts: bool = False, fed: bool = True):
     op_p, op_s = _init_operator(key, cfg, kind)
-    ffn_p, ffn_s = _init_ffn(key, cfg, starts)
+    ffn_p, ffn_s = _init_ffn(key, cfg, starts, fed)
     return ({OPERATOR[kind]: op_p, **ffn_p}, {OPERATOR[kind]: op_s, **ffn_s})
 
 
@@ -114,19 +123,29 @@ def _period(kinds: tuple) -> int:
                        for i in range(p, len(kinds))))
 
 
+def _marks(cfg: DecoderConfig) -> tuple:
+    """What tells one layer of the stack from another when it is cut into
+    groups: its kind and, where some blocks have no feed-forward part
+    (``cfg.ffn_free``), whether it has one."""
+    return tuple(zip(cfg.kinds, cfg.fed)) if cfg.ffn_free else cfg.kinds
+
+
 def _periodic(cfg: DecoderConfig, first: int, n: int) -> list:
     """Layers [first, first + n) as whole periods of their shortest pattern,
     and what is left behind them (a cut period) as a group of its own: (the
     group's config, its first layer)."""
-    kinds = cfg.kinds[first:first + n]
-    p = _period(kinds)
+    kinds, fed = cfg.kinds[first:first + n], cfg.fed[first:first + n]
+    p = _period(_marks(cfg)[first:first + n])
     whole = n // p * p
-    out = [(dataclasses.replace(cfg, n_layers=whole, layer_kinds=kinds[:p]),
-            first)]
+
+    def group(lo: int, hi: int, count: int):
+        return dataclasses.replace(
+            cfg, n_layers=count, layer_kinds=kinds[lo:hi],
+            ffn_free=tuple(i for i, f in enumerate(fed[lo:hi]) if not f))
+
+    out = [(group(0, p, whole), first)]
     if whole < n:
-        out.append((dataclasses.replace(
-            cfg, n_layers=n - whole, layer_kinds=kinds[whole:]),
-            first + whole))
+        out.append((group(whole, n, n - whole), first + whole))
     return out
 
 
@@ -170,7 +189,7 @@ def _grouped(name: str, cfg: DecoderConfig, first: int, n: int) -> list:
     head = max(first, min(first + n, cfg.n_layers - cfg.stateless_tail))
     found = []
     for lo, hi in ((first, head), (head, first + n)):
-        for at, length in _stretches(cfg.kinds[lo:hi]):
+        for at, length in _stretches(_marks(cfg)[lo:hi]):
             found += _periodic(cfg, lo + at, length)
     return [(name + ("" if i == 0 else "_rest" + (str(i) if i > 1 else "")),
              gcfg, at) for i, (gcfg, at) in enumerate(found)]
@@ -258,17 +277,27 @@ def unit_blocks(unit, gcfg: DecoderConfig, whole=None, u=None) -> list:
     if len(period) == 1:
         return [(period[0], 0, unit)]
     out, seen = [], {}
+    # A leaf of the feed-forward part (``FED_LEAVES``) is stacked over the
+    # blocks that HAVE one: block ``j``'s lies at ``at[j]`` of the unit's,
+    # and a block without one (``gcfg.ffn_free``) takes none.
+    fed = gcfg.fed[:len(period)]
+    at = [sum(fed[:j]) for j in range(len(period))]
     for j, kind in enumerate(period):
         i = seen[kind] = seen.get(kind, -1) + 1
         op = OPERATOR[kind]
+
+        def own(n):     # (whether block j has leaf n, where in a unit's)
+            return (fed[j], at[j]) if n in FED_LEAVES else (True, j)
+
         out.append((kind, i, {
             **({"moe": jax.tree.map(lambda a: a[0], unit["moe"])}
                if j == 0 and "moe" in unit else {}),
-            **{n: jax.tree.map(lambda a, j=j: a[j], unit[n])
+            **{n: jax.tree.map(lambda a, k=own(n)[1]: a[k], unit[n])
                for n in ("mlp", "ln1", "ln2", "ln1_b", "ln2_b")
-               if n in unit},
-            **{n: jax.tree.map(lambda a, j=j: a[u * len(period) + j], leaves)
-               for n, leaves in (whole or {}).items()},
+               if n in unit and own(n)[0]},
+            **{n: jax.tree.map(
+                lambda a, k=own(n)[1]: a[u * sum(fed) + k], leaves)
+               for n, leaves in (whole or {}).items() if own(n)[0]},
             **({op: jax.tree.map(lambda a, i=i: a[i], unit[op])}
                if op in unit else {})}))
     return out
@@ -285,6 +314,10 @@ def _init_group(keys, gcfg: DecoderConfig):
                 lambda k: _init_started(k, gcfg)[0])(keys[::2])
         return stack
     stack = jax.vmap(lambda k: _init_ffn(k, gcfg)[0])(keys)
+    if gcfg.ffn_free:   # the feed-forward parts over the blocks that have one
+        fed = jnp.asarray([i for i, f in enumerate(gcfg.fed) if f])
+        stack = {n: jax.tree.map(lambda a: a[fed], leaf)
+                 if n in FED_LEAVES else leaf for n, leaf in stack.items()}
     for kind in sorted(set(kinds)):
         own = jnp.asarray([i for i, x in enumerate(kinds) if x == kind])
         stack[OPERATOR[kind]] = jax.vmap(
@@ -306,8 +339,9 @@ def init_decoder_params(key: jax.Array, cfg: DecoderConfig) -> Params:
         else:
             stacks[name] = [
                 _init_block(k, gcfg, kind,
-                            gcfg.moe_shortcut and i % 2 == 0)[0]
-                for i, (k, kind) in enumerate(zip(keys, gcfg.kinds))]
+                            gcfg.moe_shortcut and i % 2 == 0, fed)[0]
+                for i, (k, kind, fed) in enumerate(
+                    zip(keys, gcfg.kinds, gcfg.fed))]
         if cfg.diff_attention:
             _set_lambda_init(stacks[name], gcfg, first)
 
@@ -371,8 +405,9 @@ def decoder_param_specs(cfg: DecoderConfig) -> Params:
         else:   # (under a shortcut a pair's first block alone has "moe")
             stacks[name] = [
                 {k: v for k, v in by_kind[kind].items()
-                 if k != "moe" or i % 2 == 0}
-                for i, kind in enumerate(gcfg.kinds)]
+                 if (k != "moe" or i % 2 == 0)
+                 and (fed or k not in FED_LEAVES)}
+                for i, (kind, fed) in enumerate(zip(gcfg.kinds, gcfg.fed))]
 
     specs: Params = {
         "embed": ("vocab", "embed_table"),
@@ -404,12 +439,22 @@ def _block_forward(block_params, x, positions, cfg: DecoderConfig,
         joining, shared = shared, None
     h = L.rmsnorm(x, block_params["ln1"], cfg, mesh=mesh,
                   bias=block_params.get("ln1_b"))
-    if set(block_params) & {"ssm", "gmu", "cross", "parallel"} and (
+    if set(block_params) & {"ssm", "gmu", "cross", "parallel", "ssd"} and (
             tp_axis is not None or lora is not None):
         raise NotImplementedError(
-            "an ssm, gmu, cross or parallel layer under in-stage tensor "
-            "parallelism or with LoRA adapters")
-    if "parallel" in block_params:
+            "an ssm, gmu, cross, parallel or ssd layer under in-stage "
+            "tensor parallelism or with LoRA adapters")
+    if "ssd" in block_params:
+        # The SSD mixer alone: its cache is its state before ``x`` (the
+        # recurrent state and the convolution's tail); it hands back the
+        # state after the last valid position.
+        attn_out, state = L.ssd_block(
+            block_params["ssd"], h, cfg,
+            None if kv_cache is None else tuple(
+                kv_cache[n] for n in SSD_PLANES), valid_len)
+        attn_out = checkpoint_name(attn_out, "attn_out")
+        new_cache = None if kv_cache is None else dict(zip(SSD_PLANES, state))
+    elif "parallel" in block_params:
         # Two operators on the one normed input: attention over the K and V
         # planes, the SSD mixer from the state before ``x``; both caches
         # come back, the state as it stands after the last valid position.
@@ -490,6 +535,15 @@ def _block_forward(block_params, x, positions, cfg: DecoderConfig,
                 if new_cache is not None \
                 else L.diff_kv(block_params["attn"], h, cfg)
             shared = {**shared, "k": k, "v": v}
+    if "mlp" not in block_params:
+        # A block of ONE sublayer (``cfg.ffn_free``): no second norm, no
+        # feed-forward part.
+        x = x + attn_out
+        if mesh is not None:
+            x = with_logical_constraint(
+                x, ("batch", "act_seq", "act_embed"), mesh, rules)
+        return (x if shared is None else (x, shared)), new_cache, \
+            jnp.float32(0)
     # Residual add + second norm as ONE op: fused kernels run it in a
     # single pass over the stream (layers.add_rmsnorm).
     x, h = L.add_rmsnorm(x, attn_out, block_params["ln2"], cfg, mesh=mesh,

@@ -853,20 +853,23 @@ def ssd_block(p: dict, x: jax.Array, cfg: DecoderConfig,
               state: Optional[tuple] = None, valid_len=None,
               impl: str = "xla"):
     """The SSD mixer over ``x`` [B,S,D] from ``state`` to a state: (the
-    recurrent state [B,H,N,P] float32, the convolution's tail [B,taps-1,C]);
+    recurrent state [B,H,N,P] float32, or as a pool's plane holds it,
+    ``ssd.pack_state``; the convolution's tail [B,taps-1,C]);
     zeros when None (a sequence's start). Returns (out [B,S,D], the state
-    after the last valid position). Rows never mix."""
+    after the last valid position, laid as it came). Rows never mix."""
     from kubeflow_tpu.ops import ssd
 
     mat, tail = state if state is not None else (None, None)
     if mat is None:
         mat = jnp.zeros((x.shape[0], cfg.ssd_heads, cfg.ssd_state,
                          cfg.ssd_head_dim), jnp.float32)
+    packed = cfg.ssd_heads // mat.shape[1]
     xs, z, dt, bm, cm, tail = ssd_inputs(p, x, cfg, tail, valid_len)
     y, mat = ssd.ssd_chunk(xs, dt, ssd_decay(p), bm, cm,
-                           p["d_skip"].astype(jnp.float32), mat, impl=impl,
+                           p["d_skip"].astype(jnp.float32),
+                           ssd.unpack_state(mat, cfg.ssd_heads), impl=impl,
                            block=cfg.ssd_chunk)
-    return ssd_output(p, y, z, cfg), (mat, tail)
+    return ssd_output(p, y, z, cfg), (ssd.pack_state(mat, packed), tail)
 
 
 def parallel_block(p: dict, x: jax.Array, positions: jax.Array,
@@ -1366,16 +1369,28 @@ def kda_block(p: dict, x: jax.Array, cfg: DecoderConfig,
 
 # -- MLP -----------------------------------------------------------------------
 
-def init_mlp(key, cfg: DecoderConfig):
-    kg, ku, kd = jax.random.split(key, 3)
-    d, m = cfg.hidden, cfg.mlp_dim
-    params = {
-        "gate": _init(kg, (d, m), cfg.weight_dtype),
-        "up": _init(ku, (d, m), cfg.weight_dtype),
-        "down": _init(kd, (m, d), cfg.weight_dtype, scale=m ** -0.5),
-    }
-    specs = {"gate": ("embed", "mlp"), "up": ("embed", "mlp"), "down": ("mlp", "embed")}
+def _mlp_tree(keys, lead: tuple, d: int, m: int, cfg: DecoderConfig,
+              axes: tuple):
+    """One MLP's matrices (``lead``: an expert axis in front, or none):
+    "gate", "up" [d, m] and "down" [m, d]; "up" and "down" alone where the
+    activation takes no gate (``cfg.mlp_matrices``). ``axes``: the logical
+    names of (lead..., d, m)."""
+    *la, ad, am = axes
+    wdt = cfg.weight_dtype
+    names = ("gate", "up", "down")      # a key each, gate or no gate
+    params, specs = {}, {}
+    for name, key in zip(names, keys):
+        if name == "gate" and cfg.mlp_matrices == 2:
+            continue
+        fan, out = (m, d) if name == "down" else (d, m)
+        params[name] = _init(key, (*lead, fan, out), wdt, scale=fan ** -0.5)
+        specs[name] = (*la, am, ad) if name == "down" else (*la, ad, am)
     return params, specs
+
+
+def init_mlp(key, cfg: DecoderConfig):
+    return _mlp_tree(jax.random.split(key, 3), (), cfg.hidden, cfg.mlp_dim,
+                     cfg, ("embed", "mlp"))
 
 
 def _act(x: jax.Array, name: str) -> jax.Array:
@@ -1383,6 +1398,8 @@ def _act(x: jax.Array, name: str) -> jax.Array:
         return jax.nn.silu(x)
     if name == "gelu":
         return jax.nn.gelu(x, approximate=True)
+    if name == "relu2":
+        return jnp.square(jax.nn.relu(x))
     raise ValueError(f"unknown activation {name!r}")
 
 
@@ -1393,6 +1410,13 @@ def mlp_block(p: dict, x: jax.Array, cfg: DecoderConfig,
     form for inside shard_map)."""
     dt = cfg.activation_dtype
     gate_m, down_m = cfg.mlp_multipliers or (1.0, 1.0)
+    if "gate" not in p:     # two matrices: the activation on ``up`` itself
+        up = jnp.einsum("bsd,dm->bsm", x, p["up"].astype(dt))
+        out = jnp.einsum("bsm,md->bsd", _act(up, cfg.hidden_act),
+                         p["down"].astype(dt))
+        if tp_axis is not None:
+            out = jax.lax.psum(out, tp_axis)
+        return checkpoint_name(scaled(out, down_m), "mlp_out")
     gate_pre = scaled(jnp.einsum("bsd,dm->bsm", x, p["gate"].astype(dt)),
                       gate_m)
     up = jnp.einsum("bsd,dm->bsm", x, p["up"].astype(dt))
@@ -1419,18 +1443,19 @@ def init_moe(key, cfg: DecoderConfig):
     kr, kg, ku, kd = jax.random.split(key, 4)
     d, m, e = cfg.hidden, cfg.expert_mlp_dim, cfg.router_width
     eh = cfg.experts_here       # the router scores all, the stack holds these
-    params = {
-        "router": _init(kr, (d, e), cfg.weight_dtype),
-        "gate": _init(kg, (eh, d, m), cfg.weight_dtype, scale=d ** -0.5),
-        "up": _init(ku, (eh, d, m), cfg.weight_dtype, scale=d ** -0.5),
-        "down": _init(kd, (eh, m, d), cfg.weight_dtype, scale=m ** -0.5),
-    }
-    specs = {
-        "router": ("embed", None),
-        "gate": ("expert", "embed", "expert_mlp"),
-        "up": ("expert", "embed", "expert_mlp"),
-        "down": ("expert", "expert_mlp", "embed"),
-    }
+    # the routed experts' rows: the hidden's width, or the latent's
+    experts_p, experts_s = _mlp_tree(
+        (kg, ku, kd), (eh,), cfg.moe_latent_dim or d, m, cfg,
+        ("expert", "embed", "expert_mlp"))
+    params = {"router": _init(kr, (d, e), cfg.weight_dtype), **experts_p}
+    specs = {"router": ("embed", None), **experts_s}
+    if cfg.moe_latent_dim:
+        kdn, kup = jax.random.split(jax.random.fold_in(key, 2))
+        r = cfg.moe_latent_dim
+        params["latent_down"] = _init(kdn, (d, r), cfg.weight_dtype)
+        params["latent_up"] = _init(kup, (r, d), cfg.weight_dtype)
+        specs["latent_down"] = ("embed", None)
+        specs["latent_up"] = (None, "embed")
     if cfg.router_score != "softmax":
         # The correction bias moves the CHOICE of experts and never their
         # weights; it is balanced outside the loss, so it starts at zero.
@@ -1439,14 +1464,9 @@ def init_moe(key, cfg: DecoderConfig):
     if cfg.shared_experts:
         # Every token's experts: one MLP as wide as all of them together.
         ms = cfg.shared_experts * m
-        kg2, ku2, kd2 = jax.random.split(jax.random.fold_in(key, 1), 3)
-        params["shared"] = {
-            "gate": _init(kg2, (d, ms), cfg.weight_dtype),
-            "up": _init(ku2, (d, ms), cfg.weight_dtype),
-            "down": _init(kd2, (ms, d), cfg.weight_dtype, scale=ms ** -0.5),
-        }
-        specs["shared"] = {"gate": ("embed", "mlp"), "up": ("embed", "mlp"),
-                           "down": ("mlp", "embed")}
+        params["shared"], specs["shared"] = _mlp_tree(
+            jax.random.split(jax.random.fold_in(key, 1), 3), (), d, ms, cfg,
+            ("embed", "mlp"))
     return params, specs
 
 
@@ -1499,7 +1519,7 @@ def split_expert_stack(layers: dict, cfg: DecoderConfig):
     if not (cfg.is_moe and cfg.moe_impl == "sorted"):
         return layers, None
     at = "moe" if cfg.moe_shortcut else "mlp"
-    whole = {k: layers[at][k] for k in EXPERT_LEAVES}
+    whole = {k: layers[at][k] for k in EXPERT_LEAVES if k in layers[at]}
     return {**layers, at: {k: v for k, v in layers[at].items()
                            if k not in whole}}, whole
 
@@ -1571,6 +1591,9 @@ def moe_block(p: dict, x: jax.Array, cfg: DecoderConfig,
             f"{cfg.moe_impl!r}: a capacity buffer has no row for an expert "
             "without weights, and only the sorted path counts their rows")
     rows = None
+    latent = jnp.einsum(
+        "bsd,dr->bsr", x, p["latent_down"].astype(cfg.activation_dtype)) \
+        if cfg.moe_latent_dim else None
     if cfg.moe_impl == "dispatch":
         out, aux = _moe_dispatch(p, x, cfg, expert_axis=expert_axis,
                                  seq_axis=seq_axis, valid_len=valid_len,
@@ -1582,12 +1605,17 @@ def moe_block(p: dict, x: jax.Array, cfg: DecoderConfig,
                 "moe_impl 'sorted' inside a pipeline stage's shard_map "
                 "(expert or tensor parallel)")
         out, aux, rows = _moe_sorted(p, x, cfg, seq_axis=seq_axis,
-                                     expert_stack=expert_stack)
+                                     expert_stack=expert_stack,
+                                     latent=latent)
     elif cfg.moe_impl == "dense":
         out, aux = _moe_dense(p, x, cfg, expert_axis=expert_axis,
-                              seq_axis=seq_axis, tp_axis=tp_axis)
+                              seq_axis=seq_axis, tp_axis=tp_axis,
+                              latent=latent)
     else:
         raise ValueError(f"unknown moe_impl {cfg.moe_impl!r}")
+    if latent is not None:
+        out = jnp.einsum("bsr,rd->bsd", out,
+                         p["latent_up"].astype(cfg.activation_dtype))
     if cfg.shared_experts and tail is not None:
         # Every token, the tail's too: one pass over the shared weights.
         n = x.shape[0] * x.shape[1]
@@ -1740,7 +1768,7 @@ def _moe_dispatch(p: dict, x: jax.Array, cfg: DecoderConfig,
 
     e_local, offset = e, 0
     if expert_axis is not None:
-        e_local = p["gate"].shape[0]
+        e_local = p["up"].shape[0]
         offset = jax.lax.axis_index(expert_axis) * e_local
         keep = keep & (flat_e >= offset) & (flat_e < offset + e_local)
     # An expert's buffer: group 0's c slots, then group 1's, ..., then the
@@ -1773,10 +1801,14 @@ def _moe_dispatch(p: dict, x: jax.Array, cfg: DecoderConfig,
     buf = jnp.take(xf, row_of_slot, axis=0, mode="fill",
                    fill_value=0).reshape(e_local, per_e, d)
 
-    gate = _act(jnp.einsum("ecd,edm->ecm", buf, p["gate"].astype(dt)),
-                cfg.hidden_act)
-    up = jnp.einsum("ecd,edm->ecm", buf, p["up"].astype(dt))
-    y = jnp.einsum("ecm,emd->ecd", gate * up,
+    if "gate" in p:
+        gate = _act(jnp.einsum("ecd,edm->ecm", buf, p["gate"].astype(dt)),
+                    cfg.hidden_act)
+        inner = gate * jnp.einsum("ecd,edm->ecm", buf, p["up"].astype(dt))
+    else:                       # two matrices: the activation on ``up``
+        inner = _act(jnp.einsum("ecd,edm->ecm", buf, p["up"].astype(dt)),
+                     cfg.hidden_act)
+    y = jnp.einsum("ecm,emd->ecd", inner,
                    p["down"].astype(dt)).reshape(e_local * per_e, d)
 
     back = jnp.take(y, rows, axis=0, mode="fill", fill_value=0)      # [kT,D]
@@ -1872,7 +1904,8 @@ def grouped_matmul(rows: jax.Array, w: jax.Array, sizes: jax.Array,
 
 def _moe_sorted(p: dict, x: jax.Array, cfg: DecoderConfig,
                 seq_axis: Optional[str] = None,
-                expert_stack: Optional[tuple] = None):
+                expert_stack: Optional[tuple] = None,
+                latent: Optional[jax.Array] = None):
     """Drop-free sparse experts: the k rows of every token are sorted by
     expert and each projection is ONE grouped matmul over the sorted rows
     (``grouped_matmul``: group ``e`` holds the rows routed to expert ``e``,
@@ -1897,9 +1930,12 @@ def _moe_sorted(p: dict, x: jax.Array, cfg: DecoderConfig,
     group the same way and costs no matrix work either; what it adds is its
     token's own input: ``(the sum of a token's zero choices' weights) x h``,
     one elementwise product a token. So the matrix work a token costs runs
-    from none of its ``k`` choices to all of them. Returns (out, aux, the
-    rows routed, the rows held and, with zero experts, the rows that chose
-    one)."""
+    from none of its ``k`` choices to all of them.
+
+    ``latent`` [B,S,R] (``moe_block``: experts behind a latent projection):
+    the router reads ``x``, the experts' rows are ``latent``'s, and ``out``
+    comes back ``R`` wide. Returns (out, aux, the rows routed, the rows held
+    and, with zero experts, the rows that chose one)."""
     dt = cfg.activation_dtype
     b, s, d = x.shape
     e, k = cfg.num_experts, cfg.experts_per_token
@@ -1926,13 +1962,21 @@ def _moe_sorted(p: dict, x: jax.Array, cfg: DecoderConfig,
         stack, layer = expert_stack
         w = {n: a.reshape(-1, *a.shape[2:]) for n, a in stack.items()}
         sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros((w["gate"].shape[0],), jnp.int32), sizes,
+            jnp.zeros((w["up"].shape[0],), jnp.int32), sizes,
             (layer * eh,))
-    rows = jnp.take(xf, order // k, axis=0)                          # [Tk,D]
-    gate = _act(grouped_matmul(rows, w["gate"].astype(dt), sizes, cfg),
-                cfg.hidden_act)
-    up = grouped_matmul(rows, w["up"].astype(dt), sizes, cfg)
-    y = grouped_matmul(gate * up, w["down"].astype(dt), sizes, cfg)  # [Tk,D]
+    if latent is not None:      # the experts' rows, and their width
+        xe, d = latent.reshape(t, -1), latent.shape[-1]
+    else:
+        xe = xf
+    rows = jnp.take(xe, order // k, axis=0)                          # [Tk,D]
+    if "gate" in w:
+        gate = _act(grouped_matmul(rows, w["gate"].astype(dt), sizes, cfg),
+                    cfg.hidden_act)
+        inner = gate * grouped_matmul(rows, w["up"].astype(dt), sizes, cfg)
+    else:                       # two matrices: the activation on ``up``
+        inner = _act(grouped_matmul(rows, w["up"].astype(dt), sizes, cfg),
+                     cfg.hidden_act)
+    y = grouped_matmul(inner, w["down"].astype(dt), sizes, cfg)      # [Tk,D]
     # Back to token order: row r of the sorted rows is (token, choice)
     # ``order[r]``; a scalar scatter inverts the permutation.
     inv = jnp.zeros((t * k,), jnp.int32).at[order].set(
@@ -1960,7 +2004,8 @@ def _moe_sorted(p: dict, x: jax.Array, cfg: DecoderConfig,
 def _moe_dense(p: dict, x: jax.Array, cfg: DecoderConfig,
                expert_axis: Optional[str] = None,
                seq_axis: Optional[str] = None,
-               tp_axis: Optional[str] = None):
+               tp_axis: Optional[str] = None,
+               latent: Optional[jax.Array] = None):
     """Einsum-dense formulation: every expert computes every token and a
     one-hot combine weights the results. FLOP-inefficient (E/k overcompute)
     but fully static-shaped and drop-free — under GSPMD the ``expert``
@@ -1974,10 +2019,13 @@ def _moe_dense(p: dict, x: jax.Array, cfg: DecoderConfig,
     offset, and psums the combined output over the axis. The router is
     replicated, so top-k runs on full logits. ``seq_axis`` (sequence-sharded
     activations, PP×SP): the load-balancing fractions pmean over the axis so
-    the aux loss sees full-sequence statistics."""
+    the aux loss sees full-sequence statistics. ``latent`` [B,S,R]: what the
+    experts read in ``x``'s place (``moe_block``); ``out`` is then ``R``
+    wide."""
     dt = cfg.activation_dtype
     e, k = cfg.num_experts, cfg.experts_per_token
     router_logits, topk_idx, topk_w = route(p, x, cfg)               # [B,S,k]
+    xe = x if latent is None else latent
     onehot = jax.nn.one_hot(topk_idx, cfg.router_width,
                             dtype=jnp.float32)                       # [B,S,k,E]
     combine = jnp.einsum("bske,bsk->bse", onehot, topk_w)            # [B,S,E]
@@ -1987,13 +2035,18 @@ def _moe_dense(p: dict, x: jax.Array, cfg: DecoderConfig,
         combine, zero = combine[..., :e], jnp.sum(combine[..., e:], axis=-1)
 
     if expert_axis is not None:
-        e_local = p["gate"].shape[0]
+        e_local = p["up"].shape[0]
         offset = jax.lax.axis_index(expert_axis) * e_local
         combine = jax.lax.dynamic_slice_in_dim(combine, offset, e_local,
                                                axis=-1)
-    gate = _act(jnp.einsum("bsd,edm->ebsm", x, p["gate"].astype(dt)), cfg.hidden_act)
-    up = jnp.einsum("bsd,edm->ebsm", x, p["up"].astype(dt))
-    expert_out = jnp.einsum("ebsm,emd->ebsd", gate * up, p["down"].astype(dt))
+    if "gate" in p:
+        gate = _act(jnp.einsum("bsd,edm->ebsm", xe, p["gate"].astype(dt)),
+                    cfg.hidden_act)
+        inner = gate * jnp.einsum("bsd,edm->ebsm", xe, p["up"].astype(dt))
+    else:
+        inner = _act(jnp.einsum("bsd,edm->ebsm", xe, p["up"].astype(dt)),
+                     cfg.hidden_act)
+    expert_out = jnp.einsum("ebsm,emd->ebsd", inner, p["down"].astype(dt))
     out = jnp.einsum("ebsd,bse->bsd", expert_out, combine.astype(dt))
     axes = tuple(a for a in (expert_axis, tp_axis) if a is not None)
     if axes:

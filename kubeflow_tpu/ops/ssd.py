@@ -41,6 +41,16 @@ Forms of the same sums:
   LIVE row, the row's ``[H, N, P]`` state fetched from and written back to
   its entry of the pool's plane (the plane aliased to the result); a dead
   row moves nothing.
+
+**Heads narrower than the chip's 128 lanes share a tile in the pool's
+plane** (``heads_a_tile``, ``pack_state`` / ``unpack_state``): an array
+whose last axis is 64 is held padded to 128, so a plane ``[E, 128, 128, 64]``
+takes twice its bytes and the chip's compiler copies it whole around the
+step kernel's call (compiled for a described v5e, PR 61: 5.4 GB of
+temporaries beside a plane of 2.7). Two heads of 64 of one group therefore
+lie side by side, ``[E, H/2, N, 2P]``: to ``ssd_step`` a pair is ONE head of
+128 values whose lanes decay at two rates; ``ssd_chunk`` takes and hands back
+``[B, H, N, P]`` and its callers turn the few rows' states either way.
 """
 
 from __future__ import annotations
@@ -63,6 +73,35 @@ BLOCK = 128         # positions a block of the chunked form (mamba_chunk_size)
 # and ``y`` twice (3): over the compiler's default of 16 MiB with its own
 # temporaries, a quarter of a v5e's 128.
 CHUNK_VMEM_BYTES = 32 * 2 ** 20
+
+
+def heads_a_tile(heads: int, groups: int, p: int) -> int:
+    """Heads whose values lie side by side in one 128-lane tile of a state
+    plane: ``128 // p`` where heads of ``p`` values are narrower than a tile
+    and a group's heads go into such sets whole (they share ``B`` and
+    ``C``); else 1, the plane is ``[.., H, N, P]``."""
+    r = 128 // p if p < 128 and 128 % p == 0 else 1
+    return r if (heads // groups) % r == 0 else 1
+
+
+def pack_state(state, r: int):
+    """``[.., H, N, P]`` as a plane holds it: ``[.., H/r, N, r P]``, head ``r
+    j + i`` in lanes ``i P ..`` of packed head ``j``."""
+    if r == 1:
+        return state
+    *lead, h, n, p = state.shape
+    return jnp.swapaxes(state.reshape(*lead, h // r, r, n, p), -3, -2) \
+        .reshape(*lead, h // r, n, r * p)
+
+
+def unpack_state(state, heads: int):
+    """``pack_state``'s inverse, to ``heads`` heads."""
+    *lead, hp, n, wide = state.shape
+    r = heads // hp
+    if r == 1:
+        return state
+    return jnp.swapaxes(state.reshape(*lead, hp, n, r, wide // r), -3, -2) \
+        .reshape(*lead, heads, n, wide // r)
 
 
 def _grouped(m, heads: int):
@@ -268,9 +307,17 @@ def ssd_chunk(x, dt, a, bm, cm, d, state, *, impl: str = "xla",
 
 # -- one token, the state where it lies in the pool ---------------------------
 
+def _lane_tiles(p: int) -> int:
+    """``p`` values in whole tiles of the chip's 128 lanes."""
+    return -(-p // 128) * 128
+
+
 def _step_kernel(idx_ref, n_ref, fresh_ref, cols_ref, rows_ref, s_ref,
                  so_ref, o_ref, *, heads: int, p: int):
+    """``heads`` (packed) heads of ``p`` lanes a grid step: a lane's decay
+    rides in the lane of the same place one tile on (``_step_call``)."""
     bi = pl.program_id(0)
+    at = _lane_tiles(p)
 
     @pl.when(bi < n_ref[0])
     def _():
@@ -278,7 +325,7 @@ def _step_kernel(idx_ref, n_ref, fresh_ref, cols_ref, rows_ref, s_ref,
         b_col, c_col = cols_ref[0, 0, :, 0:1], cols_ref[0, 0, :, 1:2]
         for h in range(heads):
             xdt = rows_ref[0, 0, h:h + 1, :p]                       # [1, P]
-            decay = rows_ref[0, 0, h:h + 1, p:p + 1]                # [1, 1]
+            decay = rows_ref[0, 0, h:h + 1, at:at + p]              # [1, P]
             s = s_ref[0, h] * (decay * keep) + b_col * xdt
             so_ref[0, h] = s
             o_ref[0, 0, h:h + 1, :] = jnp.sum(s * c_col, axis=0,
@@ -288,25 +335,34 @@ def _step_kernel(idx_ref, n_ref, fresh_ref, cols_ref, rows_ref, s_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _step_call(x, dt, a, bm, cm, plane, idx, fresh, live, *,
                interpret: bool):
-    """``ssd_step`` as ONE kernel call over ``plane``, aliased to its first
-    result: a grid step a (live row, group), the group's heads' state block
-    fetched from and written back to the row's entry. What scales a state's
-    ROWS (``B``, ``C``: [N]) rides as columns [N, 2], what scales its
-    columns (``dt x``: [P]) as rows, a head's decay in the lanes behind
-    them. Returns (y [B, H, P] float32 without the skip term, the plane)."""
-    b, h, p = x.shape
+    """``ssd_step`` as ONE kernel call over ``plane`` ([E, H/r, N, r P]:
+    ``pack_state``), aliased to its first result: a grid step a (live row,
+    group), the group's heads' state block fetched from and written back to
+    the row's entry. What scales a state's ROWS (``B``, ``C``: [N]) rides as
+    columns [N, 2], what scales its columns as rows: a packed head's ``dt
+    x`` ([r P]: its ``r`` heads' side by side, as ``x`` lies anyway) and,
+    from the next whole tile of 128 lanes on, each lane's decay (its own
+    head's: a packed head is to the kernel ONE head whose lanes decay at
+    ``r`` rates). Returns (y [B, H, P] float32 without the skip term, the
+    plane)."""
+    b, h, _ = x.shape
     groups, n = bm.shape[1], bm.shape[2]
-    hb = h // groups
+    heads, p = plane.shape[1], plane.shape[3]   # as packed
+    hb = heads // groups
     # Live rows first: the grid walks them and stays on the last one's
     # blocks for the rest (no fetch, no write: the body is skipped).
     order = jnp.argsort(~live, stable=True)
     n_live = jnp.sum(live, dtype=jnp.int32)
     dt = dt.astype(F32)
     decay = jnp.exp(dt * a.astype(F32))
+    wide = _lane_tiles(p) + p
+    xdt = x.astype(F32) * dt[..., None]
     rows = jnp.concatenate(
-        [x.astype(F32) * dt[..., None],
-         jnp.broadcast_to(decay[..., None], (b, h, p))],
-        axis=-1)[order].reshape(b, groups, hb, 2 * p)
+        [xdt.reshape(b, heads, p),
+         *([jnp.zeros((b, heads, wide - 2 * p), F32)] if wide > 2 * p
+           else []),
+         jnp.broadcast_to(decay[..., None], xdt.shape).reshape(b, heads, p)],
+        axis=-1)[order].reshape(b, groups, hb, wide)
     cols = jnp.stack([bm.astype(F32), cm.astype(F32)], axis=-1)[order]
 
     def at(bi, gi, n_ref):
@@ -327,7 +383,7 @@ def _step_call(x, dt, a, bm, cm, plane, idx, fresh, live, *,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(b, groups),
             in_specs=[pl.BlockSpec((1, 1, n, 2), row_map),
-                      pl.BlockSpec((1, 1, hb, 2 * p), row_map),
+                      pl.BlockSpec((1, 1, hb, wide), row_map),
                       pl.BlockSpec((1, hb, n, p), state_map)],
             out_specs=[pl.BlockSpec((1, hb, n, p), state_map),
                        pl.BlockSpec((1, 1, hb, p), row_map)]),
@@ -342,15 +398,16 @@ def _step_call(x, dt, a, bm, cm, plane, idx, fresh, live, *,
         lambda pln: tuple(call(idx_s, n_live[None], fresh[order].astype(
             jnp.int32), cols, rows, pln)),
         lambda pln: (pln, jnp.zeros((b, groups, hb, p), F32)), plane)
-    o = jnp.zeros_like(o).at[order].set(o).reshape(b, h, p)
+    o = jnp.zeros_like(o).at[order].set(o).reshape(x.shape)
     return o, plane
 
 
 def ssd_step(x, dt, a, bm, cm, d, plane, idx, fresh, live, *,
              impl: str = "xla", interpret: Optional[bool] = None):
     """One token a row against the state IN the pool. x [B, H, P]; dt [B,
-    H]; a, d [H]; bm, cm [B, G, N]; ``plane`` [E, H, N, P] float32 (every
-    entry of every layer, flat); ``idx`` [B] the row's entry; ``fresh`` [B]:
+    H]; a, d [H]; bm, cm [B, G, N]; ``plane`` [E, H/r, N, r P] float32
+    (every entry of every layer, flat; ``r`` heads a lane tile:
+    ``pack_state``); ``idx`` [B] the row's entry; ``fresh`` [B]:
     start from zeros (a sequence's first token); ``live`` [B]: a dead row
     reads and writes nothing and gets zeros. ``impl`` "pallas": the kernel
     ``ssd_step``, the plane aliased to the result; "xla": gather, the
@@ -361,11 +418,12 @@ def ssd_step(x, dt, a, bm, cm, d, plane, idx, fresh, live, *,
             interpret=auto_interpret() if interpret is None else interpret)
         y = y + d.astype(F32)[:, None] * x.astype(F32)
     elif impl == "xla":
-        e = plane.shape[0]
-        state = plane[jnp.clip(idx, 0, e - 1)]
+        e, h = plane.shape[0], x.shape[1]
+        state = unpack_state(plane[jnp.clip(idx, 0, e - 1)], h)
         state = jnp.where((fresh | ~live)[:, None, None, None], 0.0, state)
         y, state = ssd_step_xla(x, dt, a, bm, cm, d, state)
-        written = plane.at[jnp.where(live, idx, e)].set(state, mode="drop")
+        written = plane.at[jnp.where(live, idx, e)].set(
+            pack_state(state, h // plane.shape[1]), mode="drop")
     else:
         raise ValueError(f"unknown ssd impl {impl!r}; one of xla|pallas")
     return jnp.where(live[:, None, None], y, 0.0), written

@@ -842,6 +842,14 @@ class LLMEngine:
         self._kv_sequence_pool_bytes = sum(
             by_kind[kind] for kind in SEQUENCE_PLANES)
         self._state_sequences_started = 0       # lockfree: scheduler-confined counter
+        # What ONE live row's decode step moves of those planes: its entry
+        # in every layer that keeps one, read AND written (the planes' own
+        # shapes: bytes an entry, twice).
+        self._state_bytes_a_row = 2 * sum(
+            int(self.cache[n].nbytes) // self.cache[n].shape[1]
+            for kind in SEQUENCE_PLANES for n in SEQUENCE_PLANES[kind]
+            if n in self.cache)
+        self._state_bytes_stepped = 0           # lockfree: scheduler-confined counter
         # Where a layer holds a share of its experts, the rows its expert
         # layers routed and held ride in the cache pytree as running sums
         # (no pool plane: ``paged._planes_of``); the scheduler reads them in
@@ -1338,7 +1346,13 @@ class LLMEngine:
         state-space (ssm) layers for the same two reasons, and over parallel
         layers, whose SSD state a sequence is such a matrix beside the
         layer's own K and V: a matched page's K and V could be shared, the
-        state as it stood at the match cannot. Gated memory
+        state as it stood at the match cannot; and over ssd layers, the same
+        mixer as a block's only operator, for the same two reasons (the
+        prediction module of such a model stays unbuilt: its drafts'
+        verification would have to roll a sequence's state back). A block
+        of one sublayer and experts behind a latent projection change the
+        tree the quantizer, the adapter buffers and the mesh's sharding
+        walk. Gated memory
         units and cross layers keep nothing, but read what a layer in front
         of them computed, which none of the mechanisms above carries, and
         differential attention's paired K/V rows are not the ``[KV, Dh]``
@@ -1376,6 +1390,12 @@ class LLMEngine:
             ("parallel layers (attention beside a Mamba-2 mixer) whose SSD "
              "state a sequence lives in the page pool beside the layer's K "
              "and V", bool(cfg.layers_of("parallel"))),
+            ("ssd layers (a Mamba-2 mixer alone) whose state a sequence "
+             "lives in the page pool", bool(cfg.layers_of("ssd"))),
+            ("blocks of one sublayer (no feed-forward part)",
+             bool(cfg.ffn_free)),
+            (f"experts behind a latent projection of {cfg.moe_latent_dim}",
+             bool(cfg.moe_latent_dim)),
             ("gated memory units and cross-attention layers that read "
              "another layer's output and cache", bool(cfg.stateless_tail)),
             ("differential attention over paired K/V heads",
@@ -1425,6 +1445,10 @@ class LLMEngine:
             "tail over parallel layers: a match needs the SSD state as it "
             "stood at the match)":
                 bool(cfg.layers_of("parallel")) and b.enable_prefix_caching,
+            "enable_prefix_caching (prefix reuse and the radix copy-on-write "
+            "tail over ssd layers: a match needs the SSD state as it stood "
+            "at the match)":
+                bool(cfg.layers_of("ssd")) and b.enable_prefix_caching,
         }
         hit = [name for name, on in refused.items() if on]
         if hit:
@@ -1581,6 +1605,11 @@ class LLMEngine:
             # layer and reads one unless it starts a sequence, a decode step
             # reads and writes one a layer a live stream
             "state_sequences_started": self._state_sequences_started,
+            # bytes of sequence entries that decode steps (a program's own
+            # and those a chunk program carries) read AND wrote: 2 x a live
+            # row's entries over the layers that keep one, a step (beside
+            # ``decode_steps_dispatched``); 0 without such layers
+            "state_bytes_stepped": self._state_bytes_stepped,
             # (token, choice) rows the expert layers of every program
             # routed, and those of them whose expert is held here and was
             # computed (0 and 0 where every expert is held), as of the last
@@ -2046,10 +2075,13 @@ class LLMEngine:
                 for _, pos, real in group)
             self._dsa_keys_visible += sparse["context"]
             self._dsa_keys_selected += sparse["selected"]
+        active, mode, gap, context, attrs = ride or (None,) * 5
         with self._phase(prof.ENGINE_PREFILL_DISPATCH, prof.active() and {
                 "slot": group[0][0].slot, "pos": group[0][1],
-                "chunks": len(group), **sparse, **self._rows_chosen()}):
-            active, mode, gap, context, attrs = ride or (None,) * 5
+                "chunks": len(group), **sparse, **self._rows_chosen(),
+                # of the step the program carries (0 and 0: none rides)
+                "live_rows": attrs["live_rows"] if ride else 0,
+                "state_bytes": attrs["state_bytes"] if ride else 0}):
             with self._phase(prof.ENGINE_DECODE_DISPATCH,
                              prof.active() and attrs) \
                     if ride else contextlib.nullcontext():
@@ -3191,7 +3223,11 @@ class LLMEngine:
             for _, s in active)
         attrs = {"round": self.decode_rounds, "k_steps": k_steps,
                  "live": len(active), "context": context,
-                 **(self._rows_chosen() if prof.active() else {})}
+                 **(self._rows_chosen() if prof.active() else {}),
+                 # what the steps have to move beside the weights
+                 "live_rows": len(active),
+                 "state_bytes": k_steps * len(active)
+                 * self._state_bytes_a_row}
         if self.cfg.index_topk:
             # of those rows the ones an indexer selects for its step
             self._round_selected = attrs["selected"] = sum(
@@ -3224,6 +3260,8 @@ class LLMEngine:
         round_id = self.decode_rounds
         self.decode_rounds += 1
         self._decode_steps_dispatched += k_steps
+        self._state_bytes_stepped += k_steps * len(active) \
+            * self._state_bytes_a_row
         self._decode_rounds_at_cap += k_steps == cap
         self._decode_context_tokens += context
         if self.cfg.index_topk:

@@ -372,7 +372,7 @@ MOE_ROWS = "moe_rows"
 SSM_MEMORY = "ssm_memory"
 # a kind of layer whose state is one entry a SEQUENCE -> its planes
 SEQUENCE_PLANES = {"linear": LINEAR_PLANES, "ssm": SSM_PLANES,
-                   "parallel": SSD_PLANES}
+                   "ssd": SSD_PLANES}
 
 
 def pool_planes(cfg: DecoderConfig, kv_quant: bool = False) -> tuple:
@@ -493,12 +493,16 @@ def sequence_planes(cfg: DecoderConfig) -> tuple:
     changes hands. An ssm layer's entry is found the same way:
     "ssm_state", the recurrent state ``[ssm_state, ssm_inner]`` float32
     (``ops/ssm.py``: channels on the lanes), and "ssm_conv", the
-    ``conv_taps - 1`` projected rows before the next token. A parallel
-    layer's too, beside the K and V rows a token it keeps in the global
-    planes: "ssd_state" ``[ssd_heads, ssd_state, ssd_head_dim]`` float32
-    (``ops/ssd.py``: a head's values on the lanes) and "ssd_conv", the
-    ``conv_taps - 1`` rows of ``[x | B | C]`` before the next token. () for
-    a stack with none of these kinds."""
+    ``conv_taps - 1`` projected rows before the next token. An ssd layer's
+    too, and a parallel layer's beside the K and V rows a token it keeps in
+    the global planes: "ssd_state" ``[ssd_heads, ssd_state, ssd_head_dim]``
+    float32 (``ops/ssd.py``: a head's values on the lanes; heads narrower
+    than the 128 lanes side by side, ``[ssd_heads / r, ssd_state, r
+    ssd_head_dim]``: ``ssd.pack_state``) and "ssd_conv", the ``conv_taps -
+    1`` rows of ``[x | B | C]`` before the next token. () for a stack with
+    none of these kinds."""
+    from kubeflow_tpu.ops.ssd import heads_a_tile
+
     out = ()
     if cfg.layers_of("linear"):
         h, dk = cfg.linear_heads, cfg.linear_head_dim
@@ -510,9 +514,11 @@ def sequence_planes(cfg: DecoderConfig) -> tuple:
                  jnp.dtype(jnp.float32)),
                 (SSM_PLANES[1], (cfg.conv_taps - 1, cfg.ssm_inner),
                  cfg.activation_dtype))
-    if cfg.layers_of("parallel"):
-        out += ((SSD_PLANES[0], (cfg.ssd_heads, cfg.ssd_state,
-                                 cfg.ssd_head_dim), jnp.dtype(jnp.float32)),
+    if cfg.layers_holding("ssd"):
+        r = heads_a_tile(cfg.ssd_heads, cfg.ssd_groups, cfg.ssd_head_dim)
+        out += ((SSD_PLANES[0], (cfg.ssd_heads // r, cfg.ssd_state,
+                                 r * cfg.ssd_head_dim),
+                 jnp.dtype(jnp.float32)),
                 (SSD_PLANES[1], (cfg.conv_taps - 1, cfg.ssd_conv_dim),
                  cfg.activation_dtype))
     return out
@@ -898,8 +904,10 @@ def _pool_block(bp, xs, groups, pools, layer, num_pages: dict,  # traced
     a linear layer's ``kda_chunk`` and ``kda_step`` alike: two writers of
     one plane in one program, neither copying it); then
     ``_feed_forward`` ONCE over every group's tokens; residual:
-    ``decoder._block_forward``'s skeleton, the only other copy. Returns (a
-    group's output each, the planes as written).
+    ``decoder._block_forward``'s skeleton, the only other copy. A block
+    whose parameters hold no feed-forward part (``cfg.ffn_free``: no "mlp")
+    is the operator and its residual alone. Returns (a group's output each,
+    the planes as written).
 
     Under a shortcut (``cfg.moe_shortcut``) the first block of a pair also
     STARTS its expert layer on the normed input of its dense MLP, and a
@@ -967,6 +975,9 @@ def _pool_block(bp, xs, groups, pools, layer, num_pages: dict,  # traced
         x, joining = x if isinstance(x, tuple) else (x, None)
         proj, pools = _operator(bp, x, rows, pools, layer, num_pages,
                                 page_size, cfg, attn_impl, lora)
+        if "mlp" not in bp:     # a block of ONE sublayer (``cfg.ffn_free``)
+            out.append(x + proj)
+            continue
         if x.shape[1] == 1:
             x = x + proj
             h = L.rmsnorm(x, bp["ln2"], cfg, bias=bp.get("ln2_b"))
@@ -974,6 +985,8 @@ def _pool_block(bp, xs, groups, pools, layer, num_pages: dict,  # traced
             x, h = L.add_rmsnorm(x, proj, bp["ln2"], cfg,
                                  bias=bp.get("ln2_b"))
         out.append((x, h, joining))
+    if "mlp" not in bp:
+        return tuple(out), pools
     fed, started, pools = _feed_forward(
         bp, [h for _, h, _ in out], [rows.valid for rows in groups], cfg,
         expert_stack, pools=pools)
@@ -992,8 +1005,10 @@ def _operator(bp, x, rows: _Rows, pools, layer, num_pages: dict,  # traced
     t, pg = x.shape[1], page_size
     # The planes the block meets: its own kind's; a cross layer's are the
     # LAST attention layer's (it writes none); a gated memory unit has none;
-    # a parallel layer meets the attention planes with its own index too.
-    met = {"cross": "attention", "gmu": None}.get(kind, kind)
+    # a parallel layer meets an ssd layer's planes, and the attention planes
+    # with its own index too.
+    met = {"cross": "attention", "gmu": None, "parallel": "ssd"}.get(
+        kind, kind)
 
     def lies(of: str):
         """(``layer``'s first flat page in the planes of kind ``of``, its
@@ -1048,6 +1063,9 @@ def _operator(bp, x, rows: _Rows, pools, layer, num_pages: dict,  # traced
     elif kind == "linear":
         proj, pools = _kda(bp["linear"], h, start, valid, pools, entry(),
                            cfg, attn_impl)
+    elif kind == "ssd":
+        proj, pools = _ssd(bp["ssd"], h, start, valid, pools, entry(), cfg,
+                           attn_impl)
     elif kind == "parallel":
         # Both branches on the one normed input, each between its
         # multipliers: they read and write different planes, so neither
@@ -1226,7 +1244,8 @@ def _ssm(sp, h, start, valid, pools, entry, cfg: DecoderConfig,  # traced
 
 def _ssd(sp, h, start, valid, pools, entry, cfg: DecoderConfig,  # traced
          attn_impl: str):
-    """A parallel layer's SSD mixer over ``T`` tokens a row: from the state
+    """An SSD mixer (an ssd layer's operator, a parallel layer's second
+    branch) over ``T`` tokens a row: from the state
     at ``entry`` [B] of the layer's planes (zeros for a row that starts its
     sequence) to the state after the row's last valid token, written back to
     the entry. One token goes through the recurrence with the state read and
@@ -1250,10 +1269,13 @@ def _ssd(sp, h, start, valid, pools, entry, cfg: DecoderConfig,  # traced
             entry < mats.shape[0], impl=impl)
         y = y[:, None]
     else:
-        y, mat = ssd.ssd_chunk(xs, dt, a, bm, cm, d,
-                               _state_at(mats, entry, fresh), impl=impl,
-                               block=cfg.ssd_chunk)
-        mats = mats.at[entry].set(mat, mode="drop")
+        # (a plane holds narrow heads side by side: ``ssd.pack_state``)
+        y, mat = ssd.ssd_chunk(
+            xs, dt, a, bm, cm, d, ssd.unpack_state(
+                _state_at(mats, entry, fresh), cfg.ssd_heads), impl=impl,
+            block=cfg.ssd_chunk)
+        mats = mats.at[entry].set(
+            ssd.pack_state(mat, cfg.ssd_heads // mats.shape[1]), mode="drop")
     pools = {**pools, SSD_PLANES[0]: mats,
              SSD_PLANES[1]: tails.at[entry].set(tail.astype(tails.dtype),
                                                 mode="drop")}
@@ -2039,7 +2061,7 @@ def _chunk_in_place(cache: dict, cfg: DecoderConfig, lora,
     form: int8 pools (scale planes), packed rows and the conv state beside
     them, a call with LoRA, planes the kernel cannot part by head. A window
     layer's planes are K and V per head like a global layer's and go the
-    same way; a linear, ssm or parallel layer's state planes ride beside
+    same way; a linear, ssm, ssd or parallel layer's state planes ride beside
     them (their operator reads a state a row, not pages)."""
     if cfg.is_latent:
         return True
@@ -2068,9 +2090,11 @@ def _chunk_in_place(cache: dict, cfg: DecoderConfig, lora,
 #: disjoint, as their pages are. "linear": a KDA mixer whose recurrent
 #: matrices and conv tails are ONE entry a sequence alike (``kda_chunk`` and a
 #: scatter of the end state for the chunk's rows, ``kda_step`` in place for the
-#: slots'). The kinds that keep a ring or a conv tail, an ssm state, or end in
-#: a stateless tail are the next names here (ROADMAP Speed 0).
-STEP_CARRYING_KINDS = frozenset({"attention", "parallel", "linear"})
+#: slots'). "ssd": the parallel layer's mixer as a block's only operator, the
+#: same two kernels on the same planes (tests/test_serve_nemotronh.py). The
+#: kinds that keep a ring or a conv tail, an ssm state, or end in a stateless
+#: tail are the next names here (ROADMAP Speed 0).
+STEP_CARRYING_KINDS = frozenset({"attention", "parallel", "linear", "ssd"})
 
 
 def chunk_carries_step(cache: dict, cfg: DecoderConfig, lora,

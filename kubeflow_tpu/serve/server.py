@@ -820,6 +820,7 @@ def serving_metrics_registry(engines: list, *,
     kvq_density = reg.gauge("kftpu_engine_kv_quant_tokens_per_mib")
     pool_bytes = reg.gauge("kftpu_engine_kv_pool_bytes")
     states_started = reg.counter("kftpu_engine_sequence_states_started_total")
+    state_stepped = reg.counter("kftpu_engine_state_bytes_stepped_total")
     ho_bytes_out = reg.counter("kftpu_engine_kv_handoff_bytes_exported_total")
     ho_bytes_in = reg.counter("kftpu_engine_kv_handoff_bytes_adopted_total")
     wire_demote = reg.counter("kftpu_engine_kv_wire_bytes_demoted_total")
@@ -906,6 +907,7 @@ def serving_metrics_registry(engines: list, *,
             pool_bytes.set(counters[f"kv_{planes}_pool_bytes"], model=name,
                            planes=planes)
         states_started.inc(counters["state_sequences_started"], model=name)
+        state_stepped.inc(counters["state_bytes_stepped"], model=name)
         ho_bytes_out.inc(snap.get("handoff_bytes_exported", 0), model=name)
         ho_bytes_in.inc(snap.get("handoff_bytes_adopted", 0), model=name)
         wire_demote.inc(tier.get("demote_wire_bytes", 0), model=name)

@@ -355,6 +355,29 @@ RIDING_ENGINE_COUNTERS = {"mixed_programs_dispatched": 0}
 RIDING_STATED = {"engine.step_riding_share.longdoc": 0.0}
 
 
+# PR 61's cell (nemotron-3-super-120b-a12b.batch-agentturns), the same way:
+# its rehearsal cell (``rehearsal-closed-ssd``: no prefix reuse over a state a
+# sequence), the counter its new reader takes, at rest, and each metric's
+# number for a window without samples.
+AGENTTURNS_CELLS = {
+    "tiny-nemotronh.rehearsal-closed-ssd": (
+        "nemotron-3-super-120b-a12b.batch-agentturns",
+        "rehearsal-tiny-nemotronh", "rehearsal-closed-ssd", 1),
+}
+AGENTTURNS_ENGINE_COUNTERS = {"state_bytes_stepped": 0}
+AGENTTURNS_STATED = {
+    "step.state_bytes_share.agentturns": 0.0,
+    "step.ssd_share.agentturns": 0.0,
+    "kernel.ssd_chunk_roofline_share.agentturns": 0.0,
+    "kernel.ssd_step_bw_share.agentturns": 0.0,
+    "step.expert_matmul_share.agentturns": 0.0,
+    "step.decode_weight_bw_share.agentturns": 0.0,
+    "step.prefill_mfu.agentturns": 0.0,
+    "moe.held_row_share.agentturns": 0.0,
+    "kv.state_share_of_pool.agentturns": 12.5,    # 32768 of 262144 bytes
+}
+
+
 @pytest.fixture(autouse=True, scope="session")
 def benchmark_suite_tables_know_the_longanswer_cell(request):
     suite = next(
@@ -373,19 +396,20 @@ def benchmark_suite_tables_know_the_longanswer_cell(request):
             ((suite.ADDED_CELLS, rehearsal.CELLS),
              {**LONGANSWER_CELLS, **MIXEDLENGTH_CELLS, **LONGDOC_CELLS,
               **REASONING_CELLS, **ASSISTANT_CELLS, **AGENTCONTEXT_CELLS,
-              **VOICETURNS_CELLS}),
+              **VOICETURNS_CELLS, **AGENTTURNS_CELLS}),
             ((suite.ADDED_ENGINE_COUNTERS, readers.ENGINE0),
              {**LONGANSWER_ENGINE_COUNTERS, **WINDOW_ENGINE_COUNTERS,
               **MIXEDLENGTH_ENGINE_COUNTERS, **LONGDOC_ENGINE_COUNTERS,
               **REASONING_ENGINE_COUNTERS, **STARTUP_ENGINE_COUNTERS,
               **SYNC_ENGINE_COUNTERS, **AGENTCONTEXT_ENGINE_COUNTERS,
-              **VOICETURNS_ENGINE_COUNTERS, **RIDING_ENGINE_COUNTERS}),
+              **VOICETURNS_ENGINE_COUNTERS, **RIDING_ENGINE_COUNTERS,
+              **AGENTTURNS_ENGINE_COUNTERS}),
             ((readers.TRAINER0,), STARTUP_TRAINER_COUNTERS),
             ((suite.ADDED_STATED, total.STATED),
              {**LONGANSWER_STATED, **WINDOW_STATED, **MIXEDLENGTH_STATED,
               **LONGDOC_STATED, **REASONING_STATED, **ASSISTANT_STATED,
               **STARTUP_STATED, **SYNC_STATED, **AGENTCONTEXT_STATED,
-              **VOICETURNS_STATED, **RIDING_STATED})):
+              **VOICETURNS_STATED, **RIDING_STATED, **AGENTTURNS_STATED})):
         for table in tables:
             for key, value in added.items():
                 table.setdefault(key, value)
@@ -422,7 +446,8 @@ def benchmark_suite_tables_know_the_longanswer_cell(request):
 # ``benchmark`` PR should ask ``>= 9`` there, PERF.md section 7). PR 57
 # appends a configuration, a cell and twelve behind PR 55's, whose own test
 # pins no end: the same tests are handed the manifest without them too. PR 60
-# appends ONE per-layer metric of PR 43's cell behind PR 57's: the same again.
+# appends ONE per-layer metric of PR 43's cell behind PR 57's: the same again;
+# PR 61 a configuration, a cell and seventeen behind that one: the same again.
 PINS_PR43_AT_THE_END = "test_what_pr_43_added_is_listed_with_the_benchmark"
 PINS_PR28_AT_THE_END = "test_what_this_pr_added_is_listed_with_the_benchmark"
 PINS_PR35S_CELL = \
@@ -442,6 +467,10 @@ TAKES_SIZE_FOR_A_WIDTH = "test_cells_configs_and_files"
 # rehearsal prints, and for PR 40's, 47's and 50's cells the entries in the
 # manifest), as its PR left it. Those tests are handed the manifest without
 # the seventeen; every other test reads them.
+# PR 60's one test pins its metric as the manifest's LAST per-layer entry,
+# which it was; it reads the manifest from its file, so it is handed the
+# file's manifest without PR 61's entries.
+PINS_PR60_AT_THE_END = "test_it_is_declared_for_the_long_document_cell_alone"
 PINS_A_CELLS_LINE = "test_the_cells_path_runs_end_to_end_on_the_cpu"
 PINS_A_CELLS_ENTRIES = {
     f"test_what_pr_{pr}_added_is_listed_with_the_benchmark"
@@ -468,7 +497,7 @@ def the_manifest_as_it_stood_for_the_tests_that_pin_a_pr(request,
     module = request.node.module
     since_pr50 = set(STARTUP_STATED) | set(SYNC_STATED) \
         | set(AGENTCONTEXT_STATED) | set(VOICETURNS_STATED) \
-        | set(RIDING_STATED)
+        | set(RIDING_STATED) | set(AGENTTURNS_STATED)
     later = set(WINDOW_STATED) | set(MIXEDLENGTH_STATED) \
         | set(LONGDOC_STATED) | set(REASONING_STATED) \
         | set(ASSISTANT_STATED) | since_pr50
@@ -477,10 +506,18 @@ def the_manifest_as_it_stood_for_the_tests_that_pin_a_pr(request,
     assistant = next(iter(ASSISTANT_CELLS.values()))[0]
     agentcontext = next(iter(AGENTCONTEXT_CELLS.values()))[0]
     voiceturns = next(iter(VOICETURNS_CELLS.values()))[0]
-    since_pr53 = {agentcontext, voiceturns}
+    agentturns = next(iter(AGENTTURNS_CELLS.values()))[0]
+    since_pr53 = {agentcontext, voiceturns, agentturns}
+    if name == PINS_PR60_AT_THE_END:
+        whole = module.mf.load_manifest()
+        monkeypatch.setattr(module.mf, "load_manifest", lambda: {
+            **whole, "per_layer": [m for m in whole["per_layer"]
+                                   if m["name"] not in AGENTTURNS_STATED]})
+        return
     if request.node.originalname == PINS_A_CELLS_LINE:
         if module.__name__ in ("test_benchmark_glm5",   # the newest cells'
-                               "test_benchmark_longcat"):
+                               "test_benchmark_longcat",
+                               "test_benchmark_nemotronh"):
             return
         if (module.__name__, PINS_A_CELLS_LINE) != PINS_PR35S_LINE:
             later = since_pr50

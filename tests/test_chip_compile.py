@@ -683,6 +683,8 @@ def test_mixed_program_compiles_for_v5e_with_both_kernels(cell_programs,
     ("solar-open2-250b.batch-longdoc", True),   # linear layers: PR 60
     ("phi-4-mini-flash.batch-reasoning", False),        # ssm, gmu, cross
     ("falcon-h1-34b.batch-assistant", True),    # parallel layers: PR 58
+    # ssd layers beside attention, a block of one sublayer: PR 61
+    ("nemotron-3-super-120b-a12b.batch-agentturns", True),
 ])
 def test_which_cells_chunk_program_carries_the_step(cell, carries):
     """The plan reads the stack and the pool (``plan_chunks``, through
@@ -690,7 +692,7 @@ def test_which_cells_chunk_program_carries_the_step(cell, carries):
     a kind whose chunk and decode operators are held side by side in one
     program
     (``paged.STEP_CARRYING_KINDS``: "attention", since PR 58 "parallel",
-    since PR 60 "linear"); the three that stay keep a conv tail, a ring, or
+    since PR 60 "linear", since PR 61 "ssd"); the three that stay keep a conv tail, a ring, or
     an ssm state in front of a stateless tail."""
     from scripts.aot_weight_copies import serving_cell
     from test_serve_chunk_plan import plan_of
@@ -1062,10 +1064,15 @@ ASSISTANT = "falcon-h1-34b.batch-assistant"
 # carries the step). "decode" and "chunk[1]" (the ``[C, V]`` program of
 # callers outside the engine: the benchmark's ``correct``) lower to what they
 # lowered to on the parent (1fa4a2c), recorded there ahead of any edit.
+# PR 61: ``ssd_step`` reads a lane's decay as a ROW ``[1, P]`` one lane tile
+# behind ``dt x`` (one form for heads of 128 and for two heads of 64 side by
+# side in a tile), where it read ``[1, 1]`` and spread it: the two programs
+# that hold the kernel moved (cff3492965d294ad, 91ac85ae8f9e63aa before);
+# "chunk[1]" did not.
 ASSISTANT_SINCE_PR58 = {
-    "decode": "cff3492965d294ad",
+    "decode": "44a5adb2507a7a84",
     "chunk[1]": "7272d6eead7dff51",
-    "mixed[1]": "91ac85ae8f9e63aa",
+    "mixed[1]": "83f7de518505745a",
 }
 
 
@@ -1267,6 +1274,81 @@ def test_voiceturns_program_compiles_for_v5e_with_its_kernels(
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.2e9
     assert mem.temp_size_in_bytes < 0.7e9
+
+
+AGENTTURNS = "nemotron-3-super-120b-a12b.batch-agentturns"
+
+
+def test_ssd_kernels_compile_for_v5e_at_heads_of_64(chip):
+    """``ops/ssd.py`` at Nemotron-3-Super's widths (128 heads of 64 values,
+    8 groups, a state of 128): the chunk kernel over two rows of 512
+    positions, and the step kernel over 128 streams whose state lies in a
+    plane of 640 entries that holds TWO heads a lane tile (``[E, 64, 128,
+    128]``: ``ssd.pack_state``), aliased to the result. As ``[E, 128, 128,
+    64]`` the plane is held padded to twice its bytes and this compile
+    showed 5.45 GB of temporaries beside it (PR 61, before any chip call);
+    a ``[1, 1]`` decay read from inside a lane tile it refused outright."""
+    from kubeflow_tpu.ops import ssd
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    bf = jnp.bfloat16
+    h, p, g, n, b = 128, 64, 8, 128, 128
+    chunk = jax.jit(lambda x, dt, a, bm, cm, d, s: ssd.ssd_chunk(
+        x, dt, a, bm, cm, d, s, impl="pallas", interpret=False)).lower(
+        sds(2, 512, h, p, dtype=bf), sds(2, 512, h), sds(h),
+        sds(2, 512, g, n, dtype=bf), sds(2, 512, g, n, dtype=bf), sds(h),
+        sds(2, h, n, p)).compile()
+    assert _calls(chunk.as_text(), "ssd_chunk") == 1
+    r = ssd.heads_a_tile(h, g, p)
+    assert r == 2
+    step = jax.jit(
+        lambda x, dt, a, bm, cm, d, pl, i, f, lv: ssd.ssd_step(
+            x, dt, a, bm, cm, d, pl, i, f, lv, impl="pallas",
+            interpret=False), donate_argnums=(6,)).lower(
+        sds(b, h, p, dtype=bf), sds(b, h), sds(h), sds(b, g, n, dtype=bf),
+        sds(b, g, n, dtype=bf), sds(h), sds(5 * b, h // r, n, r * p),
+        sds(b, dtype=jnp.int32), sds(b, dtype=jnp.bool_),
+        sds(b, dtype=jnp.bool_)).compile()
+    assert _calls(step.as_text(), "ssd_step") == 1
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= 5 * b * h * n * p * 4
+    assert mem.temp_size_in_bytes < 8 * 2 ** 20, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed[2]"])
+def test_agentturns_program_compiles_for_v5e_with_its_kernels(
+        cell_programs, program):
+    """The agent-turns cell's decode step and the chunk program that carries
+    the slots' step (what its traffic runs: TWO rows, 128 slots riding) at
+    the cell's real sizes, parameters as the engine holds them: each fits
+    the chip beside its arguments, runs both SSD kernels where it has both
+    kinds of row, the paged attention kernels at sixteen query heads to a KV
+    head, and the grouped matmul over 25,344 sorted rows of the LATENT's
+    width; it copies no weight and neither the state plane nor the K and V
+    planes (the conv tail plane, 39 MB, is laid out again on the way in and
+    out, as Falcon-H1's is: PERF.md section 7)."""
+    from scripts.aot_weight_copies import serving_cell, weight_copies
+
+    lowered = cell_programs(AGENTTURNS, mixed=program.startswith("mixed"))[
+        program]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    kernels = {"decode": ("ssd_step", "paged_decode_attention"),
+               "mixed[2]": ("ssd_step", "ssd_chunk", "paged_decode_attention",
+                            "paged_chunk_attention", "gmm")}[program]
+    for kernel in kernels:
+        assert _calls(text, kernel) >= 1, kernel
+    cfg, batching = serving_cell(AGENTTURNS)
+    copies = weight_copies(text, lowered.args_info[0][0])
+    assert {leaf for c in copies for leaf in c["leaf"]} == set()
+    state = cfg.ssd_heads * cfg.ssd_state * cfg.ssd_head_dim
+    assert max([math.prod(c["shape"]) for c in copies] + [0]) \
+        < batching.max_batch_size * state
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.5e9
+    assert mem.temp_size_in_bytes < 0.6e9
 
 
 def test_the_grouped_tile_is_narrower_only_where_the_rows_are_many():

@@ -245,11 +245,14 @@ def test_what_a_parallel_stack_cannot_be_is_refused_by_name():
 
 
 def test_a_layer_holds_k_and_v_and_an_entry_a_sequence():
-    assert [plane_kind(n) for n in SSD_PLANES] == ["parallel"] * 2
+    # the planes are an ssd layer's (the mixer as a block's only operator:
+    # PR 61); a parallel layer holds them beside its K and V
+    assert [plane_kind(n) for n in SSD_PLANES] == ["ssd"] * 2
     assert holds("parallel", "k") and holds("parallel", "ssd_state")
+    assert holds("ssd", "ssd_state") and not holds("ssd", "k")
     assert not holds("attention", "ssd_conv") and holds("attention", "v")
     assert BASE.layers_holding("attention") == BASE.layers_holding(
-        "parallel") == 3 and BASE.layers_of("attention") == 0
+        "ssd") == 3 and BASE.layers_of("attention") == 0
     assert [p[:2] for p in sequence_planes(BASE)] == [
         ("ssd_state", (4, 32, 16)), ("ssd_conv", (3, 192))]
     assert own_first_pages(BASE) == 1 and first_page_ids(BASE, SLOTS) == 0

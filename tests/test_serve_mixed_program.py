@@ -617,9 +617,15 @@ def test_both_dispatch_spans_carry_their_attributes(monkeypatch):
     for i in mixed:
         # (``context``: the pairs its chunks' queries can see, every
         # engine's since PR 57)
-        assert set(seen[i][1]) == {"slot", "pos", "chunks", "context"}
+        # (``live_rows`` / ``state_bytes``: the step a program carries and
+        # what it moves of sequence entries, PR 61: none here)
+        assert set(seen[i][1]) == {"slot", "pos", "chunks", "context",
+                                   "live_rows", "state_bytes"}
         step = seen[i + 1][1]
-        assert set(step) == {"round", "k_steps", "live", "context"}
+        assert set(step) == {"round", "k_steps", "live", "context",
+                             "live_rows", "state_bytes"}
+        assert seen[i][1]["live_rows"] == step["live_rows"] == step["live"]
+        assert seen[i][1]["state_bytes"] == step["state_bytes"] == 0
         assert step["k_steps"] == 1 and step["live"] >= 1
         assert step["context"] >= step["live"]
         rows += step["live"]

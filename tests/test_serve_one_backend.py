@@ -69,8 +69,8 @@ def test_paged_false_is_refused_by_name():
     assert len(BatchingSpec.model_fields) == 30
 
 
-def test_the_benchmark_has_seventeen_serving_mixes():
-    assert len(SERVING_TRAFFIC) == 17, SERVING_TRAFFIC
+def test_the_benchmark_has_eighteen_serving_mixes():
+    assert len(SERVING_TRAFFIC) == 18, SERVING_TRAFFIC
 
 
 @pytest.mark.parametrize("name", SERVING_TRAFFIC)
