@@ -86,12 +86,19 @@ def heads_a_tile(heads: int, groups: int, p: int) -> int:
 
 def pack_state(state, r: int):
     """``[.., H, N, P]`` as a plane holds it: ``[.., H/r, N, r P]``, head ``r
-    j + i`` in lanes ``i P ..`` of packed head ``j``."""
+    j + i`` in lanes ``i P ..`` of packed head ``j``: the heads of a set
+    concatenated on the lanes, and ``unpack_state`` lane slices stacked. A
+    ``swapaxes`` between two reshapes says the same and costs a plane: the
+    chip's compiler carries a transposition through a one-row program's
+    dynamic slice to the program's parameter and lays the WHOLE plane out
+    again on the way in and out (2.7 GB twice a lone chunk program of
+    Nemotron-3-Super: PERF.md section 6, PR 62); a slice of lanes stays on
+    the entry."""
     if r == 1:
         return state
     *lead, h, n, p = state.shape
-    return jnp.swapaxes(state.reshape(*lead, h // r, r, n, p), -3, -2) \
-        .reshape(*lead, h // r, n, r * p)
+    sets = state.reshape(*lead, h // r, r, n, p)
+    return jnp.concatenate([sets[..., i, :, :] for i in range(r)], axis=-1)
 
 
 def unpack_state(state, heads: int):
@@ -100,8 +107,9 @@ def unpack_state(state, heads: int):
     r = heads // hp
     if r == 1:
         return state
-    return jnp.swapaxes(state.reshape(*lead, hp, n, r, wide // r), -3, -2) \
-        .reshape(*lead, heads, n, wide // r)
+    p = wide // r
+    return jnp.stack([state[..., i * p:(i + 1) * p] for i in range(r)],
+                     axis=-3).reshape(*lead, heads, n, p)
 
 
 def _grouped(m, heads: int):

@@ -1317,18 +1317,28 @@ def test_ssd_kernels_compile_for_v5e_at_heads_of_64(chip):
     assert mem.temp_size_in_bytes < 8 * 2 ** 20, mem.temp_size_in_bytes
 
 
-@pytest.mark.parametrize("program", ["decode", "mixed[2]"])
+@pytest.mark.parametrize("program", ["decode", "mixed[2]", "chunk[1]"])
 def test_agentturns_program_compiles_for_v5e_with_its_kernels(
         cell_programs, program):
-    """The agent-turns cell's decode step and the chunk program that carries
-    the slots' step (what its traffic runs: TWO rows, 128 slots riding) at
-    the cell's real sizes, parameters as the engine holds them: each fits
-    the chip beside its arguments, runs both SSD kernels where it has both
-    kinds of row, the paged attention kernels at sixteen query heads to a KV
-    head, and the grouped matmul over 25,344 sorted rows of the LATENT's
-    width; it copies no weight and neither the state plane nor the K and V
-    planes (the conv tail plane, 39 MB, is laid out again on the way in and
-    out, as Falcon-H1's is: PERF.md section 7)."""
+    """The agent-turns cell's decode step, the chunk program that carries
+    the slots' step (TWO rows, 128 slots riding) and the one-row program a
+    lone chunk takes (43% of the cell's chunk programs: ``ChunkPlan.send``,
+    case 5) at the cell's real sizes, parameters as the engine holds them:
+    each fits the chip beside its arguments, runs both SSD kernels where it
+    has both kinds of row, the paged attention kernels at sixteen query
+    heads to a KV head, and the grouped matmul over 25,344 sorted rows of
+    the LATENT's width; it copies no weight and neither the state plane nor
+    the K and V planes (the conv tail plane, 39 MB, is laid out again on the
+    way in and out, as Falcon-H1's is: PERF.md section 7).
+    "chunk[1]" is the program that met ``pack_state``'s transposition: with
+    ONE row the entry's gather is a dynamic slice, and layout assignment
+    carried a ``swapaxes`` on the entry through the slice to the program's
+    parameter, held the whole 2.68 GB plane transposed inside the program
+    and converted it on the way in and out (PR 61's tree: ``temp`` 2.82 GB,
+    two copies of 671 M elements, 15 ms a program on the chip); with two
+    rows the gather is a gather and the transposition stayed on the entry.
+    Since PR 62 pack and unpack are lane slices, which stay on the entry at
+    every number of rows."""
     from scripts.aot_weight_copies import serving_cell, weight_copies
 
     lowered = cell_programs(AGENTTURNS, mixed=program.startswith("mixed"))[
@@ -1337,7 +1347,9 @@ def test_agentturns_program_compiles_for_v5e_with_its_kernels(
     text = compiled.as_text()
     kernels = {"decode": ("ssd_step", "paged_decode_attention"),
                "mixed[2]": ("ssd_step", "ssd_chunk", "paged_decode_attention",
-                            "paged_chunk_attention", "gmm")}[program]
+                            "paged_chunk_attention", "gmm"),
+               "chunk[1]": ("ssd_chunk", "paged_chunk_attention", "gmm")}[
+                   program]
     for kernel in kernels:
         assert _calls(text, kernel) >= 1, kernel
     cfg, batching = serving_cell(AGENTTURNS)

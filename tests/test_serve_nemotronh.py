@@ -97,6 +97,22 @@ def test_an_ssd_layer_holds_an_entry_a_sequence_and_no_rows():
     assert state_bytes_per_sequence(cfg) == 21_278_720
 
 
+def _packed_by_transposition(state, r: int):
+    """``ssd.pack_state`` as it was written up to PR 61: heads in sets of
+    ``r``, the set's axis moved behind the state's rows, the two merged."""
+    *lead, h, n, p = state.shape
+    return jnp.swapaxes(state.reshape(*lead, h // r, r, n, p), -3, -2) \
+        .reshape(*lead, h // r, n, r * p)
+
+
+def _unpacked_by_transposition(state, heads: int):
+    """``ssd.unpack_state`` as it was written up to PR 61."""
+    *lead, hp, n, wide = state.shape
+    r = heads // hp
+    return jnp.swapaxes(state.reshape(*lead, hp, n, r, wide // r), -3, -2) \
+        .reshape(*lead, heads, n, wide // r)
+
+
 @pytest.mark.parametrize("h,g,p,r", [
     (128, 8, 64, 2), (32, 2, 128, 1), (4, 2, 16, 1), (16, 2, 32, 4),
     (6, 2, 64, 1)])
@@ -106,9 +122,15 @@ def test_narrow_heads_of_a_group_share_a_lane_tile(h, g, p, r):
     packed = ssd.pack_state(state, r)
     assert packed.shape == (2, h // r, 8, r * p)
     np.testing.assert_array_equal(ssd.unpack_state(packed, h), state)
+    # the DEFINITION, whatever form the chip's compiler is handed (PR 62)
+    np.testing.assert_array_equal(packed, _packed_by_transposition(state, r))
+    np.testing.assert_array_equal(
+        ssd.unpack_state(packed, h), _unpacked_by_transposition(packed, h))
     if r > 1:       # head r j + i in lanes i p .. of packed head j
         np.testing.assert_array_equal(packed[:, 1, :, p:2 * p],
                                       state[:, r + 1])
+    else:           # nothing to turn: the argument itself
+        assert packed is state and ssd.unpack_state(state, h) is state
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
@@ -239,6 +261,42 @@ def test_the_entry_a_prompt_leaves_is_the_references_carried_state():
     got = ssd.unpack_state(cache["ssd_state"][:, 1], WIDE.ssd_heads)
     assert cache["ssd_state"].shape[2:] == (2, 16, 128)
     assert float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want)) < 1e-5
+
+
+@pytest.mark.parametrize("rows", [1, 2])
+def test_a_chunk_moves_its_entry_as_the_transposed_form_moved_it(
+        rows, monkeypatch):
+    """``paged._ssd`` over a chunk of one row (a dynamic slice of the plane:
+    where the chip's compiler took PR 61's transposition onto the whole
+    plane) and of two (a gather), heads of 64 two to a lane tile, a dirty
+    plane: the mixer's output and both planes as written, entry for entry,
+    against the same call with pack and unpack as PR 61 wrote them. A
+    permutation of float32 entries rounds nothing: EQUAL, not close."""
+    from kubeflow_tpu.serve import paged
+
+    cfg = WIDE
+    sp = jax.tree.map(lambda a: a[1], _params(cfg)["layers"]["ssd"])
+    ks = jax.random.split(jax.random.PRNGKey(62), 3)
+    shapes = engine_pool_shapes(cfg, SLOTS, 8, PAGE)
+    pools = {n: jax.random.normal(k, (2 * SLOTS, *shapes[n][0][2:]),
+                                  shapes[n][1])
+             for n, k in zip(SSD_PLANES, ks)}
+    assert pools["ssd_state"].shape[1:] == (2, 16, 128)
+    h = jax.random.normal(ks[2], (rows, CHUNK, cfg.hidden))
+    entries = [4, 2][:rows]
+    args = (jnp.asarray([16, 0][:rows], jnp.int32),         # start
+            jnp.asarray([13, 16][:rows], jnp.int32), pools,  # valid
+            jnp.asarray(entries, jnp.int32))
+    got_y, got = paged._ssd(sp, h, *args, cfg, "pallas")
+    monkeypatch.setattr(ssd, "pack_state", _packed_by_transposition)
+    monkeypatch.setattr(ssd, "unpack_state", _unpacked_by_transposition)
+    want_y, want = paged._ssd(sp, h, *args, cfg, "pallas")
+    np.testing.assert_array_equal(got_y, want_y)
+    for n in SSD_PLANES:
+        np.testing.assert_array_equal(got[n], want[n])
+        moved = np.any(np.asarray(got[n] != pools[n]),
+                       axis=tuple(range(1, got[n].ndim)))
+        assert moved.tolist() == [e in entries for e in range(2 * SLOTS)]
 
 
 def test_two_rows_of_one_program_do_not_mix():
