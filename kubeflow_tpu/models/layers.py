@@ -774,7 +774,8 @@ def init_parallel(key, cfg: DecoderConfig):
 
 
 def ssd_inputs(p: dict, x: jax.Array, cfg: DecoderConfig,
-               tail: Optional[jax.Array] = None, valid_len=None):
+               tail: Optional[jax.Array] = None, valid_len=None,
+               follows=None):
     """What the recurrence takes of ``x`` [B,S,D], the block's normed input:
     (xs [B,S,H,P]: the convolved, SiLU'd values a head, in the activation
     type; z [B,S,E]: the gate's input; dt [B,S,H] float32, after its
@@ -786,7 +787,10 @@ def ssd_inputs(p: dict, x: jax.Array, cfg: DecoderConfig,
     1`` rows of ``xBC`` before ``x``, zeros at a sequence's start and when
     None), SiLU. A position ``>= valid_len`` ([B])
     is padding: its convolution input and its ``dt`` are 0, so the state
-    passes through it unchanged."""
+    passes through it unchanged. ``follows`` ([B] bool, with ``tail`` and
+    ``S >= taps - 1``): row ``r`` is the chunk behind row ``r - 1``'s, a
+    FULL one, and its tail is that row's last ``taps - 1`` rows of ``xBC``
+    as ``tail``'s type would have kept them, not ``tail[r]``."""
     dt_ = cfg.activation_dtype
     b, s, _ = x.shape
     taps, h, g, n = cfg.conv_taps, cfg.ssd_heads, cfg.ssd_groups, \
@@ -808,6 +812,10 @@ def ssd_inputs(p: dict, x: jax.Array, cfg: DecoderConfig,
         xbc = jnp.where(valid[..., None], xbc, 0)
     if tail is None:
         tail = jnp.zeros((b, taps - 1, c), dt_)
+    if follows is not None:     # (row 0 never follows: what rolls in is unread)
+        front = jnp.roll(xbc[:, s - (taps - 1):], 1, axis=0)
+        tail = jnp.where(follows[:, None, None], front.astype(tail.dtype),
+                         tail)
     us = jnp.concatenate([tail.astype(dt_), xbc], axis=1)  # [B,taps-1+S,C]
     w = p["conv"].astype(jnp.float32)
     conv = jax.nn.silu(sum(w[j] * us[:, j:j + s].astype(jnp.float32)

@@ -37,6 +37,9 @@ Forms of the same sums:
   head's ``block_update`` with its state in VMEM across the blocks (in and
   out once a call), ``B`` and ``C`` read once a GROUP; operands to the
   matrix unit in the activation type, sums, decays and the state in float32;
+  where rows FOLLOW one another (``follows``: consecutive chunks of one
+  sequence as rows of one call) the grid is (group, row, block) and a row
+  behind starts from the state the row in front ended in, kept in VMEM;
 - ``ssd_step`` with ``impl="pallas"``: the kernel ``ssd_step``: one token a
   LIVE row, the row's ``[H, N, P]`` state fetched from and written back to
   its entry of the pool's plane (the plane aliased to the result); a dead
@@ -132,10 +135,33 @@ def ssd_step_xla(x, dt, a, bm, cm, d, state):
     return y + d.astype(F32)[:, None] * x, state
 
 
-def ssd_scan_xla(x, dt, a, bm, cm, d, state):
+def _rows_in_turn(chunk, x, dt, bm, cm, state, follows):
+    """``chunk`` ((x, dt, bm, cm, state) -> (y, the state after), rows alike)
+    ONE ROW BEHIND THE OTHER: row ``r`` starts from the state row ``r - 1``
+    ended in where ``follows[r]`` ([B] bool; never row 0), else from
+    ``state[r]``. What ``follows`` means to every form of a chunk here: the
+    rows are consecutive chunks of ONE sequence, and the state handed on is
+    the float32 one a plane would have held between two programs."""
+    def row(carry, xs):
+        *ops, s0, f = xs
+        y, end = chunk(*(v[None] for v in ops), jnp.where(f, carry, s0)[None])
+        return end[0], (y[0], end[0])
+
+    state = state.astype(F32)
+    _, (y, ends) = jax.lax.scan(row, jnp.zeros_like(state[0]),
+                                (x, dt, bm, cm, state, follows))
+    return y, ends
+
+
+def ssd_scan_xla(x, dt, a, bm, cm, d, state, follows=None):
     """``S`` tokens a row from ``state``, TOKEN BY TOKEN: x [B, S, H, P];
-    dt [B, S, H]; bm, cm [B, S, G, N]. Returns (y [B, S, H, P] float32, the
-    state after the last token)."""
+    dt [B, S, H]; bm, cm [B, S, G, N]; ``follows``: ``_rows_in_turn``.
+    Returns (y [B, S, H, P] float32, the state after the last token)."""
+    if follows is not None:
+        return _rows_in_turn(
+            lambda x, dt, bm, cm, s: ssd_scan_xla(x, dt, a, bm, cm, d, s),
+            x, dt, bm, cm, state, follows)
+
     def step(s, xs):
         xt, dtt, bt, ct = xs
         y, s = ssd_step_xla(xt, dtt, a, bt, ct, d, s)
@@ -201,9 +227,15 @@ def _chunk_operands(x, dt, a, bm, cm, block: int):
             jnp.transpose(bm, (0, 2, 3, 1)), jnp.swapaxes(cm, 1, 2), block)
 
 
-def ssd_blocks_xla(x, dt, a, bm, cm, d, state, *, block: int = BLOCK):
+def ssd_blocks_xla(x, dt, a, bm, cm, d, state, follows=None, *,
+                   block: int = BLOCK):
     """The chunked form in XLA: ``block_update`` a (row, head), block after
     block. Same arguments and results as ``ssd_scan_xla``."""
+    if follows is not None:
+        return _rows_in_turn(
+            lambda x, dt, bm, cm, s: ssd_blocks_xla(x, dt, a, bm, cm, d, s,
+                                                    block=block),
+            x, dt, bm, cm, state, follows)
     s, h = x.shape[1], x.shape[2]
     xdt, l, bt, c, block = _chunk_operands(x, dt, a, bm, cm, block)
     nb = xdt.shape[2] // block
@@ -237,15 +269,11 @@ def ssd_blocks_xla(x, dt, a, bm, cm, d, state, *, block: int = BLOCK):
 
 # -- a chunk: the kernel ------------------------------------------------------
 
-def _chunk_kernel(xdt_ref, l_ref, bt_ref, c_ref, s_ref, y_ref, so_ref, *,
-                  heads: int):
-    """One (row, group, block): the group's ``C B^T`` once, then each of its
-    heads' ``block_update``; ``so_ref``, the state's result block, stays in
-    VMEM across the row's blocks and is the state they carry."""
-    @pl.when(pl.program_id(2) == 0)
-    def _():
-        so_ref[...] = s_ref[...]
-
+def _chunk_blocks(xdt_ref, l_ref, bt_ref, c_ref, y_ref, so_ref, heads: int):
+    """One block of a (row, group): the group's ``C B^T`` once, then each of
+    its heads' ``block_update`` on ``so_ref``, the state's result block,
+    which stays in VMEM across the row's blocks and is the state they
+    carry."""
     bt, c = bt_ref[0, 0], c_ref[0, 0]
     g = _dot(c, bt)
 
@@ -257,58 +285,117 @@ def _chunk_kernel(xdt_ref, l_ref, bt_ref, c_ref, s_ref, y_ref, so_ref, *,
     jax.lax.fori_loop(0, heads, head, 0)
 
 
+def _chunk_kernel(xdt_ref, l_ref, bt_ref, c_ref, s_ref, y_ref, so_ref, *,
+                  heads: int):
+    """One (row, group, block): every row from its own state."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        so_ref[...] = s_ref[...]
+
+    _chunk_blocks(xdt_ref, l_ref, bt_ref, c_ref, y_ref, so_ref, heads)
+
+
+def _chunk_rows_kernel(follows_ref, xdt_ref, l_ref, bt_ref, c_ref, s_ref,
+                       y_ref, so_ref, end_ref, *, heads: int):
+    """One (group, row, block), a group's rows ONE BEHIND THE OTHER: a row
+    that follows (``follows_ref``, prefetched) starts from ``end_ref``, the
+    state the row in front ended in, kept in VMEM from its last block; every
+    other row from its own state."""
+    r, i = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(i == 0)
+    def _():
+        @pl.when(follows_ref[r] == 0)
+        def _():
+            so_ref[...] = s_ref[...]
+
+        @pl.when(follows_ref[r] != 0)
+        def _():
+            so_ref[...] = end_ref[...]
+
+    _chunk_blocks(xdt_ref, l_ref, bt_ref, c_ref, y_ref, so_ref, heads)
+
+    @pl.when(i == pl.num_programs(2) - 1)
+    def _():
+        end_ref[...] = so_ref[...]
+
+
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def _chunk_call(x, dt, a, bm, cm, state, *, block: int, interpret: bool):
+def _chunk_call(x, dt, a, bm, cm, state, follows=None, *, block: int,
+                interpret: bool):
     """``ssd_chunk`` as ONE kernel call, reached through this one cached
     call, so a program traces the kernel's body once however many layers and
-    rows call it. Returns (y [B, S, H, P] float32 without the skip term, the
-    state after)."""
+    rows call it. The grid is (row, group, block); with ``follows`` (group,
+    row, block), the rows of a group in turn, and ``follows`` prefetched.
+    Returns (y [B, S, H, P] float32 without the skip term, the state
+    after)."""
     s, h = x.shape[1], x.shape[2]
     xdt, l, bt, c, block = _chunk_operands(x, dt, a, bm, cm, block)
     b, _, sp, p = xdt.shape
     groups, n = bt.shape[1], bt.shape[2]
     hb = h // groups
+    in_turn = follows is not None
 
-    spec = pl.BlockSpec
+    def spec(shape, at):    # ``at``: (row, group, block) -> the block's index
+        return pl.BlockSpec(shape, (lambda g, r, i, _: at(r, g, i))
+                            if in_turn else at)
+
+    in_specs = [
+        spec((1, hb, block, p), lambda r, g, i: (r, g, i, 0)),
+        spec((1, hb, 1, block), lambda r, g, i: (r, g, 0, i)),
+        spec((1, 1, n, block), lambda r, g, i: (r, g, 0, i)),
+        spec((1, 1, block, n), lambda r, g, i: (r, g, i, 0)),
+        spec((1, hb, n, p), lambda r, g, i: (r, g, 0, 0))]
+    out_specs = [
+        spec((1, hb, block, p), lambda r, g, i: (r, g, i, 0)),
+        spec((1, hb, n, p), lambda r, g, i: (r, g, 0, 0))]
+    if in_turn:
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(groups, b, sp // block),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM((1, hb, n, p), F32)]))
+        kernel, order = _chunk_rows_kernel, ("parallel", "arbitrary",
+                                             "arbitrary")
+        operands = (follows.astype(jnp.int32),)
+    else:
+        grid = dict(grid=(b, groups, sp // block), in_specs=in_specs,
+                    out_specs=out_specs)
+        kernel, order = _chunk_kernel, ("parallel", "parallel", "arbitrary")
+        operands = ()
     y, state = pl.pallas_call(
-        functools.partial(_chunk_kernel, heads=hb),
+        functools.partial(kernel, heads=hb),
         name="ssd_chunk",
-        grid=(b, groups, sp // block),
-        in_specs=[
-            spec((1, hb, block, p), lambda r, g, i: (r, g, i, 0)),
-            spec((1, hb, 1, block), lambda r, g, i: (r, g, 0, i)),
-            spec((1, 1, n, block), lambda r, g, i: (r, g, 0, i)),
-            spec((1, 1, block, n), lambda r, g, i: (r, g, i, 0)),
-            spec((1, hb, n, p), lambda r, g, i: (r, g, 0, 0))],
-        out_specs=[
-            spec((1, hb, block, p), lambda r, g, i: (r, g, i, 0)),
-            spec((1, hb, n, p), lambda r, g, i: (r, g, 0, 0))],
+        **grid,
         out_shape=[jax.ShapeDtypeStruct((b, h, sp, p), F32),
                    jax.ShapeDtypeStruct((b, h, n, p), F32)],
-        input_output_aliases={4: 1},
+        input_output_aliases={len(operands) + 4: 1},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=order,
             vmem_limit_bytes=CHUNK_VMEM_BYTES),
         interpret=interpret,
-    )(xdt, l, bt, c, state.astype(F32))
+    )(*operands, xdt, l, bt, c, state.astype(F32))
     return jnp.swapaxes(y, 1, 2)[:, :s], state
 
 
-def ssd_chunk(x, dt, a, bm, cm, d, state, *, impl: str = "xla",
-              block: int = BLOCK, interpret: Optional[bool] = None):
+def ssd_chunk(x, dt, a, bm, cm, d, state, *, follows=None,
+              impl: str = "xla", block: int = BLOCK,
+              interpret: Optional[bool] = None):
     """A chunk a row, from a state to a state. x [B, S, H, P] (the
     activation type: what the matrix unit's operands are rounded to); dt [B,
     S, H] float32 (0: a position that leaves the state alone); a, d [H];
     bm, cm [B, S, G, N]; state [B, H, N, P] float32; ``S`` any length.
+    ``follows`` ([B] bool, or None: no row does): row ``r`` is the chunk
+    behind row ``r - 1``'s and starts from the state that row ENDS in, not
+    from ``state[r]`` (``_rows_in_turn``); every row's end state comes back.
     ``impl`` "pallas": the kernel ``ssd_chunk`` over blocks of ``block``
     positions; "xla": the recurrence token by token (``ssd_scan_xla``).
     Returns (y [B, S, H, P] float32, the state after)."""
     if impl == "xla":
-        return ssd_scan_xla(x, dt, a, bm, cm, d, state)
+        return ssd_scan_xla(x, dt, a, bm, cm, d, state, follows)
     if impl != "pallas":
         raise ValueError(f"unknown ssd impl {impl!r}; one of xla|pallas")
     y, end = _chunk_call(
-        x, dt, a, bm, cm, state, block=block,
+        x, dt, a, bm, cm, state, follows, block=block,
         interpret=auto_interpret() if interpret is None else interpret)
     return y + d.astype(F32)[:, None] * x.astype(F32), end
 

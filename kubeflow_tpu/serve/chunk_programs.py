@@ -105,9 +105,9 @@ class ChunkPlan:
         1. the step is carried, and several chunks go together or the step
            rides (``rides``: the caller's question before this one) or the
            plan is ``rows_only`` -> "mixed", ``rows`` wide: batch, longctx,
-           agentcontext, voiceturns (two rows, spare rows ahead); chat,
-           assistant (one row); longdoc (two rows and none ahead: a PAIR,
-           with the step riding or with no slot live).
+           agentcontext, voiceturns, agentturns (two rows, spare rows ahead);
+           chat, assistant (one row); longdoc (two rows and none ahead: a
+           PAIR, with the step riding or with no slot live).
         2. the step is carried, ONE row wide, and the engine has more to do
            than this prefill -> "mixed", one row, no step riding (the head at
            the chunk's last position if it ends its prompt, else nowhere):
@@ -123,10 +123,10 @@ class ChunkPlan:
            (as many programs as "lone" had, named alike): reasoning.
         5. one chunk -> "lone", its own bucket: longanswer, mixedlength
            (a prefill alone); longdoc (a prefill alone, beside live slots
-           too: no step rides a program with a dead row, ``rides``); batch
-           (a prompt's odd last chunk with no slot live); chat, assistant (a
-           prompt sent alone to an idle engine: a caller's way to reach
-           every bucket's name, and nobody waits on it)."""
+           too: no step rides a program with a dead row, ``rides``); batch,
+           agentturns (a prompt's odd last chunk with no slot live); chat,
+           assistant (a prompt sent alone to an idle engine: a caller's way
+           to reach every bucket's name, and nobody waits on it)."""
         if self.carries_step and (
                 n_chunks > 1 or step_rides or self.rows_only
                 or (self.rows == 1 and not otherwise_idle)):
@@ -143,8 +143,9 @@ class ChunkPlan:
         wide it always is; where the plan fills spare rows itself (``ahead``)
         it is as far as the prompts reach, and the odd row left dead is the
         price of ONE program. Several rows wide with no row to send ahead
-        (a layer hands a state from a chunk's END to the next chunk's start:
-        longdoc) that is ``n_chunks == rows``: a lone chunk beside a dead row
+        (a layer hands a state from a chunk's END to the next chunk's start
+        and its operator does not do so from row to row: longdoc) that is
+        ``n_chunks == rows``: a lone chunk beside a dead row
         would cost a two-row program where the one-row program and a step of
         its own are cheaper (31 + 2 ms against 18 + 9.5: ROADMAP Speed 0), so
         it goes "lone" and the iteration's step goes out as its own

@@ -2149,7 +2149,10 @@ class LLMEngine:
         way): ``pos + C``, ``pos + 2C``, ... while the prompt has tokens left
         there and its pages can be had. A further chunk whose pages cannot
         be had leaves its row dead; it is no stall (the chunk that was due
-        has its pages) and is due itself in the next pass."""
+        has its pages) and is due itself in the next pass. A prefill's rows
+        stand one behind the other, in the prompt's order: a layer that keeps
+        a state a sequence hands it from a row to the row that FOLLOWS it
+        (``paged._rows_follow``)."""
         rows = [(ch, ch.pos) for ch in group]
         if not self._plan.ahead:
             return rows
@@ -2161,7 +2164,8 @@ class LLMEngine:
                     and self._ensure_pages(ch.slot, min(pos + C, plen)):
                 rows.append((ch, pos))
                 pos += C
-        return rows
+        turn = {id(ch): i for i, ch in enumerate(group)}
+        return sorted(rows, key=lambda row: turn[id(row[0])])
 
     def _advance_chunked(self, due: "Optional[list[_Chunking]]" = None,
                          programs: Optional[int] = None) -> int:

@@ -918,18 +918,20 @@ def _pool_block(bp, xs, groups, pools, layer, num_pages: dict,  # traced
 
     A group's ROWS may be consecutive chunks of ONE sequence (the engine's
     rows ahead, ``LLMEngine._rows_of``: row ``r + 1`` the same table row at
-    ``start[r] + T``) where every layer is of kind "attention": the
-    operator writes EVERY row's keys into the pool (the ``idx`` plane's
-    beside a latent row) before any row attends, a row attends to nothing
-    but the pool, from key 0 of its own table to its own position, and the
-    expert layer takes its capacity a row. So the chunk behind finds, in
-    each layer, the keys of the chunk in front written by the same layer of
-    the same program, which is all causality asks, and computes what it
-    computes a program later. Not so a layer that keeps a state a
-    sequence (a parallel layer's SSD state and conv tail among them, though
-    its chunk program carries the step: ``chunk_rows_follow``), a ring or a
-    conv tail: the chunk behind needs the END state of the chunk in front,
-    which the rows of one program do not hand on.
+    ``start[r] + T``) where every layer's operator hands a chunk's END to the
+    chunk behind INSIDE the program (``chunk_rows_follow``). A layer of kind
+    "attention" does by the pool: the operator writes EVERY row's keys (the
+    ``idx`` plane's beside a latent row) before any row attends, a row
+    attends to nothing but the pool, from key 0 of its own table to its own
+    position, and the expert layer takes its capacity a row. A layer of kind
+    "ssd" does by its operator (``_ssd``): it sees from the rows' entries,
+    starts and valid lengths which row follows which, runs a sequence's rows
+    one behind the other, each from the state and the conv tail the row in
+    front ended in, and lets the last of them write the entry. Either way
+    the chunk behind computes what it computes a program later. Not so, yet,
+    a linear layer's state, an ssm state, a ring or a conv layer's tail: the
+    chunk behind needs the END state of the chunk in front, which their
+    operators do not hand from row to row.
 
     ``pools`` holds every plane of the WHOLE pool viewed flat —
     ``k``/``v`` ``[L*P,pg,KV,Dh]`` (``[L*P,pg,KV*Dh]`` where the heads are
@@ -1242,6 +1244,18 @@ def _ssm(sp, h, start, valid, pools, entry, cfg: DecoderConfig,  # traced
     return L.ssm_output(sp, y, z, cfg), pools
 
 
+def _rows_follow(entry, start, valid, t: int, total: int):  # traced
+    """Which rows of a group are the chunk BEHIND the row in front ([B] bool;
+    never row 0): both live, the same sequence entry (``_sequence_entry``:
+    the same first page), the row's start the start in front plus ``t``,
+    and the row in front FULL (``valid == t``). Observed from what the
+    program is handed: the engine's rows ahead (``LLMEngine._rows_of``) are
+    such rows, and nobody tells the program so."""
+    behind = (entry[1:] == entry[:-1]) & (entry[1:] < total) \
+        & (start[1:] == start[:-1] + t) & (valid[:-1] == t)
+    return jnp.concatenate([jnp.zeros((1,), bool), behind])
+
+
 def _ssd(sp, h, start, valid, pools, entry, cfg: DecoderConfig,  # traced
          attn_impl: str):
     """An SSD mixer (an ssd layer's operator, a parallel layer's second
@@ -1253,15 +1267,27 @@ def _ssd(sp, h, start, valid, pools, entry, cfg: DecoderConfig,  # traced
     same in XLA), several through the chunked form (``ssd_chunk``: "pallas"
     the kernel, "gather" token by token) from the state gathered. An
     ``entry`` past the planes is a dead row: nothing read, nothing written.
-    Returns (the mixer's output [B,T,D], the planes as written)."""
+
+    Several rows of several tokens may be consecutive chunks of ONE sequence
+    (``_rows_follow``). A row behind then takes the END state and the conv
+    tail of the row in front as its start, inside the one ``ssd_chunk`` call
+    and the one ``ssd_inputs``: float32 and the tail plane's type, the bits
+    the entry would have held between two programs. Such rows name the SAME
+    entry, and a scatter with a repeated index has no defined winner: only
+    the LAST row of a run writes it. Returns (the mixer's output [B,T,D],
+    the planes as written)."""
     from kubeflow_tpu.ops import ssd
 
-    t = h.shape[1]
+    b, t = h.shape[:2]
     mats, tails = (pools[n] for n in SSD_PLANES)
     fresh = start == 0
     impl = "pallas" if attn_impl == "pallas" else "xla"
+    follows = None
+    if b > 1 and t >= cfg.conv_taps:    # (chunks; a whole tail to hand on)
+        follows = _rows_follow(entry, start, valid, t, mats.shape[0])
     xs, z, dt, bm, cm, tail = L.ssd_inputs(
-        sp, h, cfg, _state_at(tails, entry, fresh), None if t == 1 else valid)
+        sp, h, cfg, _state_at(tails, entry, fresh), None if t == 1 else valid,
+        follows)
     a, d = L.ssd_decay(sp), sp["d_skip"].astype(jnp.float32)
     if t == 1:
         y, mats = ssd.ssd_step(
@@ -1272,8 +1298,11 @@ def _ssd(sp, h, start, valid, pools, entry, cfg: DecoderConfig,  # traced
         # (a plane holds narrow heads side by side: ``ssd.pack_state``)
         y, mat = ssd.ssd_chunk(
             xs, dt, a, bm, cm, d, ssd.unpack_state(
-                _state_at(mats, entry, fresh), cfg.ssd_heads), impl=impl,
-            block=cfg.ssd_chunk)
+                _state_at(mats, entry, fresh), cfg.ssd_heads),
+            follows=follows, impl=impl, block=cfg.ssd_chunk)
+        if follows is not None:     # a row that is followed writes nothing
+            entry = jnp.where(jnp.roll(follows, -1).at[-1].set(False),
+                              mats.shape[0], entry)
         mats = mats.at[entry].set(
             ssd.pack_state(mat, cfg.ssd_heads // mats.shape[1]), mode="drop")
     pools = {**pools, SSD_PLANES[0]: mats,
@@ -2112,13 +2141,16 @@ def chunk_carries_step(cache: dict, cfg: DecoderConfig, lora,
 def chunk_rows_follow(cfg: DecoderConfig) -> bool:
     """Whether the rows of ONE chunk program may be consecutive chunks of one
     sequence (the engine's rows ahead, ``LLMEngine._rows_of``), given that
-    the chunk meets the pool in place: every layer of kind "attention",
-    which hands nothing but the keys in the pool from one chunk of a prompt
-    to the next (``_pool_block``). A layer that keeps a state a sequence (a
-    parallel layer's SSD state among them), a ring or a conv tail hands on
-    the END state of the chunk in front, which the rows of one program do
-    not."""
-    return set(cfg.kinds) == {"attention"}
+    the chunk meets the pool in place: every layer of a kind whose operator
+    hands a chunk's end to the row behind it inside the program
+    (``_pool_block``). "attention": nothing but the keys in the pool, which
+    every row writes before any attends. "ssd": the state and the conv tail a
+    sequence, which ``_ssd`` hands from a row to the row that follows it
+    (tests/test_serve_nemotronh.py). The kinds that keep a state a sequence
+    by another operator ("linear", "ssm"; "parallel", whose plans are one
+    row wide where it is served), a ring or a conv layer's tail are the next
+    names here, each beside tests of its own operators."""
+    return set(cfg.kinds) <= {"attention", "ssd"}
 
 
 def chunk_reads_context(cache: dict, cfg: DecoderConfig, lora,

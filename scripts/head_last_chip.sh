@@ -45,4 +45,8 @@ print("  device_ops: " + json.dumps([[n, round(s, 4)] for n, s in ops[:12]]))
 PY
   grep -E "compared beside|requests:|window counters|tail program|tail chunk program|tail jit__lambda|NO RESULT|Error|compiled inside" $out.log | tail -n 18
   tail -n 300 $out.log > $out.err; rm -f $out.log
+  # the traced tail's modules and its forty heaviest ops
+  [ "$trace" != 0 ] && cp $dir/benchmark_out/$cell/trace_summary.json \
+    $out.summary.json 2>/dev/null
+  true
 done

@@ -255,15 +255,22 @@ def _tail(record: dict) -> None:
     # program apart from the program over rows): the k-th
     # ``engine.prefill_dispatch`` span sent the k-th long ``jit__lambda``;
     # the capture's edges may cut one program or one span.
-    carried = sorted((t0, attrs.get("chunks", 1))
+    # (ISSUE 63: and by whether the slots' step rode, the span's
+    # ``live_rows``: ONE chunk with the step riding is a program with a dead
+    # row, one without it the one-row program)
+    carried = sorted((t0, f"{attrs.get('chunks', 1)}"
+                      + (" with the step riding"
+                         if attrs.get("live_rows") else ""))
                      for thread in record.get("host_spans") or []
                      for name, t0, _, attrs in thread
                      if name == "engine.prefill_dispatch")
     programs = sorted((t0, dur) for name, t0, dur
                       in trace["devices"][0]["modules"]
                       if name.startswith("jit__lambda") and dur >= 2e-3)
-    if len(programs) == len(carried) + 1:
-        programs = programs[1:]
+    if 0 < len(programs) - len(carried) <= 2:
+        # (sent before the capture began: an engine that sends a round ahead
+        # has two programs in flight, PR 58)
+        programs = programs[len(programs) - len(carried):]
     elif len(carried) == len(programs) + 1:
         carried = carried[:-1]
     if carried and len(carried) == len(programs):
@@ -531,6 +538,17 @@ def main() -> int:
             if "prefill_rows_dead" in d:
                 d["prefill_rows_dead_share"] = d["prefill_rows_dead"] / (
                     d["prefill_chunks_dispatched"] + d["prefill_rows_dead"])
+            # ISSUE 63: the two shares its readers were to print
+            # (``engine.step_riding_share`` as the long-document cell's
+            # reader takes it, ``engine.rows_ahead_share``), here because
+            # BENCHMARK.json's ``per_layer`` list is full (PERF.md section 7)
+            if "mixed_programs_dispatched" in d:
+                d["step_riding_share"] = 100.0 * d[
+                    "mixed_programs_dispatched"] / d[
+                        "prefill_programs_dispatched"]
+            if "prefill_rows_ahead" in d:
+                d["rows_ahead_share"] = 100.0 * d["prefill_rows_ahead"] \
+                    / d["prefill_chunks_dispatched"]
         _log(f"window counters: {json.dumps(d, sort_keys=True)}")
         # ISSUE 39: a constant of the engine's load path (the parent has none)
         _log(f"weights_relaid_bytes: {b.get('weights_relaid_bytes')}")

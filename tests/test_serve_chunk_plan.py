@@ -7,7 +7,11 @@ its one-row program was ``_OneContext``), once; ``CELLS`` off the plans of
 the benchmark's serving cells, which ``ChunkPlan.send``'s docstring names.
 Since ISSUE 60 the plan also says whether the slots' step rides a program of
 so many chunks (``rides``): the table's sixth case, several rows wide with no
-row to send ahead, and one engine of that plan on the CPU."""
+row to send ahead, and one engine of that plan on the CPU. Since ISSUE 63 a
+stack of SSD mixers beside attention sends rows ahead (the mixer hands a
+row's end state to the row behind it): the Nemotron-like preset's rows of
+``PLANS`` and the agent-turns cell's of ``CELLS``, every other row as it
+was."""
 
 import dataclasses
 
@@ -45,7 +49,9 @@ WIDE = {"tiny": dict(head_dim=128), "tiny-moe": dict(head_dim=128),
         "tiny-falconh1": dict(n_heads=2, n_kv_heads=1, head_dim=128),
         "tiny-solar": dict(n_layers=4, n_heads=2, n_kv_heads=1, head_dim=128,
                            linear_heads=2, linear_head_dim=128,
-                           linear_gate_rank=16)}
+                           linear_gate_rank=16),
+        "tiny-nemotron-h": dict(n_heads=2, n_kv_heads=1, head_dim=128,
+                                ssd_heads=4, ssd_head_dim=64, ssd_groups=2)}
 # a dense chunk of 256 tokens is over the ridge: one chunk a program
 RIDGE = dict(max_seq_len=1024, chunked_prefill_tokens=256)
 OPTIONS = {
@@ -126,6 +132,16 @@ PLANS = {
     "tiny-solar|pallas|1|wide": (T, 1, F, F, F, T),
     "tiny-solar|pallas|2|wide": (T, 2, F, F, F, T),
     "tiny-solar|gather|2|wide": (F, 2, F, F, F, F),
+    # the kind "ssd" carries the step since PR 61 (two rows by the ridge:
+    # sorted experts); since PR 63 its rows follow, so two rows wide it sends
+    # its spare row ahead, and keeps the one-row program (ONE context) for a
+    # prompt's odd last chunk with no slot live: the batch cell's plan
+    "tiny-nemotron-h|pallas|1|": (F, 1, T, F, F, F),
+    "tiny-nemotron-h|pallas|2|": (F, 2, F, F, F, F),
+    "tiny-nemotron-h|gather|2|": (F, 2, F, F, F, F),
+    "tiny-nemotron-h|pallas|1|wide": (T, 1, F, F, F, T),
+    "tiny-nemotron-h|pallas|2|wide": (T, 2, F, T, F, T),
+    "tiny-nemotron-h|gather|2|wide": (F, 2, F, F, F, F),
 }
 
 
@@ -208,11 +224,14 @@ def test_every_pass_is_sent_as_it_was_and_through_a_program_that_is_built(
 # cell -> its plan, and what carries (one chunk beside a live slot, two
 # chunks, one chunk of an engine with nothing else to do)
 MIXED1 = ((T, 1, F, F, F, T), "mixed 1", None, "lone 1")
+AHEAD_ONE_CONTEXT = ((T, 2, F, T, F, T), "mixed 2", "mixed 2", "lone 1")
 CELLS = {
     "mistral-7b.chat-open": MIXED1,
     "falcon-h1-34b.batch-assistant": MIXED1,
-    "mixtral-8x7b.batch-longprompt": (
-        (T, 2, F, T, F, T), "mixed 2", "mixed 2", "lone 1"),
+    "mixtral-8x7b.batch-longprompt": AHEAD_ONE_CONTEXT,
+    # since PR 63 (PR 62's: ``(T, 2, F, F, F, T)``, longdoc's row below: a
+    # lone chunk "lone 1" beside a live slot, 40% of its chunk programs)
+    "nemotron-3-super-120b-a12b.batch-agentturns": AHEAD_ONE_CONTEXT,
     **{cell: ((T, 2, F, T, T, F), "mixed 2", "mixed 2", "mixed 2")
        for cell in ("glm-4.7-flash.batch-longcontext",
                     "glm-5.batch-agentcontext",
@@ -232,7 +251,7 @@ CELLS = {
 
 @pytest.mark.parametrize("cell", sorted(CELLS))
 def test_each_serving_cell_takes_the_case_the_table_names(cell):
-    """The benchmark's ten serving cells, at their own sizes (configuration
+    """The benchmark's eleven serving cells, at their own sizes (configuration
     and traffic files; on one chip the arm resolves to "pallas")."""
     from scripts.aot_weight_copies import serving_cell
 

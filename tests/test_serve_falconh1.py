@@ -132,6 +132,35 @@ def test_a_position_whose_step_is_zero_passes_the_state_through(form):
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("follows", [
+    (False, True, False), (False, True, True), (False, False, True),
+    (False, False, False)], ids=lambda f: "".join("-f"[v] for v in f))
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_a_row_that_follows_starts_from_the_end_of_the_row_in_front(
+        form, follows):
+    """``follows`` (ISSUE 63): rows that are consecutive chunks of one
+    sequence run one behind the other inside the ONE call, a row behind from
+    the state the row in front ENDS in (not from its own), every other row
+    from its own; every row's end state comes back. Against the walk, a row
+    at a time; heads of 64, the second row ending inside a block."""
+    x, dt, a, bm, cm, d, s0 = _operands(63, 3, 24, 4, 64, 2, 16)
+    dt = dt.at[1, 21:].set(0.0)
+    want_y, want_s = [], []
+    for r in range(3):
+        start = want_s[-1] if follows[r] else s0[r:r + 1]
+        y, st = _token_by_token(x[r:r + 1], dt[r:r + 1], a, bm[r:r + 1],
+                                cm[r:r + 1], d, start)
+        want_y.append(y)
+        want_s.append(st)
+    form = {**FORMS, "kernel": lambda args, block: ssd.ssd_chunk(
+        *args[:-1], follows=args[-1], impl="pallas", block=block)}[form]
+    y, st = form((x, dt, a, bm, cm, d, s0, jnp.asarray(follows)), 8)
+    np.testing.assert_allclose(y, jnp.concatenate(want_y), rtol=1e-4,
+                               atol=1e-4)
+    np.testing.assert_allclose(st, jnp.concatenate(want_s), rtol=1e-4,
+                               atol=2e-5)
+
+
 def test_the_chunk_kernel_rounds_its_operands_to_the_activation_type():
     """bfloat16 in: the matrix unit's operands are bfloat16, sums, decays and
     the state float32: within bfloat16's rounding of the float32 scan."""

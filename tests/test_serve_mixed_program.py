@@ -29,7 +29,15 @@ a scatter of the end state for the chunk's rows, ``kda_step`` in place for
 the slots') beside a gated GQA layer in four, sorted experts of which a share
 is held. A fifth stack of ``KINDS``. An engine several rows wide that sends no
 row ahead (this one, and the parallel stack at two rows) lets the step ride
-only a program whose rows are all filled (``ChunkPlan.rides``)."""
+only a program whose rows are all filled (``ChunkPlan.rides``).
+
+The kind "ssd" sends rows ahead (ISSUE 63): a Nemotron-like stack, SSD mixers
+alone in their blocks beside one attention block, a block of one sublayer,
+sorted experts behind a latent projection of which a share is held. Its mixer
+hands the END state and the conv tail of a row to the row that follows it
+inside the program (``paged._ssd``), so it is a sixth stack of ``KINDS`` and
+the fourth of ``AHEAD``: a row behind the row in front with the slots riding,
+a prompt alone two chunks a program, the odd last chunk beside a dead row."""
 
 import dataclasses
 import functools
@@ -56,10 +64,13 @@ from test_serve_chunk_plan import WIDE, plan_of
 
 PAGE, CHUNK, MPP = 16, 32, 8
 SLOTS = 4
-KINDS = ("dense", "dispatch", "latent", "parallel", "linear")
-# the stacks whose every layer is of kind "attention": a program's spare rows
-# may carry the NEXT chunks of the prompts in it (``paged.chunk_rows_follow``)
-AHEAD = KINDS[:3]
+KINDS = ("dense", "dispatch", "latent", "parallel", "linear", "ssd")
+# the stacks whose every layer hands a chunk's end to the row behind it (kind
+# "attention": by the pool; "ssd": by its mixer): a program's spare rows may
+# carry the NEXT chunks of the prompts in it (``paged.chunk_rows_follow``)
+AHEAD = (*KINDS[:3], "ssd")
+# two rows wide, the step carried, and no row sent ahead
+FILLED_ONLY = ("parallel", "linear")
 
 
 def _config(kind: str):
@@ -81,6 +92,10 @@ def _config(kind: str):
         # kernels take): one gated GQA layer in four beside KDA layers, 4 of
         # 16 sorted experts held and a shared expert
         return preset("tiny-solar", **WIDE["tiny-solar"], **over)
+    if kind == "ssd":
+        # Nemotron-like: one KV head of 128 for the attention kernels, SSD
+        # heads of 64, TWO to a lane tile of the state plane, blocks of 8
+        return preset("tiny-nemotron-h", **WIDE["tiny-nemotron-h"], **over)
     return preset("tiny-glm", **over)
 
 
@@ -920,19 +935,21 @@ def test_one_row_wide_nothing_compiles_after_a_first_run():
         "prefill_programs_dispatched"]
 
 
-@pytest.mark.parametrize("kind", KINDS[3:])
+@pytest.mark.parametrize("kind", FILLED_ONLY)
 def test_a_stack_that_keeps_a_state_sends_no_chunk_ahead(kind):
     """Two rows a program (a chunk under the ridge) and the step carried,
     but a prompt alone goes ONE chunk a program: the chunk behind needs the
     state and the conv tail the chunk in front ENDS in, which the rows of one
-    program do not hand on (``paged.chunk_rows_follow``). And a chunk alone
+    program do not hand on (``paged.chunk_rows_follow``: a KDA mixer's do
+    not yet, and a parallel stack is served one row wide; an SSD mixer alone
+    in its block does, ``AHEAD``). And a chunk alone
     fills one row of two, so no step rides with it (``ChunkPlan.rides``): it
     takes the one-row program, not the two-row one beside a dead row, and
     the live stream's step goes out as the decode program."""
     from kubeflow_tpu.serve.paged import chunk_rows_follow
 
     assert [chunk_rows_follow(_model(k)[0]) for k in KINDS] == [
-        True, True, True, False, False]
+        True, True, True, False, False, True]
     eng = _engine(kind)
     assert eng._plan.carries_step and eng._plan.rows == 2
     assert not eng._plan.ahead and not eng._plan.rows_only

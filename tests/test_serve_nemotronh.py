@@ -8,7 +8,13 @@ kernels interpreted; the one that carries a chunk AND the slots' step)
 against the benchmark's plain reference, two rows of one program, and through
 the engine: tokens against the full recompute, the state bytes stepped
 against a count by hand and against the model's need, the spans' attributes,
-the refused options by name."""
+the refused options by name.
+
+A row of a chunk program hands its SSD state and conv tail to the row behind
+it (ISSUE 63): two consecutive chunks of ONE prompt as the rows of one
+program against one program after the other, which rows follow and which
+write the entry, the mixed program with a row ahead and the slots riding,
+and an engine that sends a prompt alone two chunks a program."""
 
 import dataclasses
 import functools
@@ -29,11 +35,13 @@ from kubeflow_tpu.models.decoder import (
 from kubeflow_tpu.ops import ssd
 from kubeflow_tpu.serve.engine import LLMEngine, SamplingParams
 from kubeflow_tpu.serve.paged import (
-    STEP_CARRYING_KINDS, _chunk_in_place, _paged_decode_step,
-    chunk_carries_step, chunk_rows_follow, engine_pool_shapes,
-    own_first_pages, paged_chunk_prefill, paged_mixed_step,
-    pool_bytes_per_token, sequence_planes, state_bytes_per_sequence,
+    STEP_CARRYING_KINDS, _chunk_in_place, _paged_chunk_in_place,
+    _paged_decode_step, _rows_follow, chunk_carries_step, chunk_rows_follow,
+    engine_pool_shapes, own_first_pages, paged_chunk_prefill,
+    paged_mixed_step, pool_bytes_per_token, sequence_planes,
+    state_bytes_per_sequence,
 )
+from test_serve_mixed_program import _chunk_counts
 
 PAGE, CHUNK, MPP, SLOTS = 8, 16, 16, 3
 REHEARSAL = load_json("benchmark/configs/rehearsal-tiny-nemotronh.json")
@@ -84,7 +92,10 @@ def test_an_ssd_layer_holds_an_entry_a_sequence_and_no_rows():
         "moe_rows": (2,)}
     assert pool_bytes_per_token(BASE) == 2 * 2 * 16 * 4
     assert state_bytes_per_sequence(BASE) == 3 * (4 * 16 * 16 + 3 * 128) * 4
-    assert "ssd" in STEP_CARRYING_KINDS and not chunk_rows_follow(BASE)
+    # the mixer hands a chunk's end to the row behind it (ISSUE 63); beside a
+    # kind that does not, the stack sends no row ahead
+    assert "ssd" in STEP_CARRYING_KINDS and chunk_rows_follow(BASE)
+    assert not chunk_rows_follow(preset("tiny-falconh1"))
     # at the published widths: heads of 64 lie two to a lane tile
     cfg = architecture.part(REHEARSAL, "program").program_config(
         load_json("benchmark/configs/nemotron-3-super-120b-a12b.json"))
@@ -299,27 +310,220 @@ def test_a_chunk_moves_its_entry_as_the_transposed_form_moved_it(
         assert moved.tolist() == [e in entries for e in range(2 * SLOTS)]
 
 
-def test_two_rows_of_one_program_do_not_mix():
+@functools.lru_cache(maxsize=None)
+def _in_place(cfg, impl):
+    """The chunk program over rows as the engine builds it (the head at a
+    row's last valid position), IN PLACE under either arm: "gather" runs the
+    mixers' XLA forms (``ssd_scan_xla``), "pallas" the kernels interpreted."""
+    params = _params(cfg)
+    return jax.jit(lambda c, t, rows, st, vl: _paged_chunk_in_place(
+        params, c, t, rows, st, vl, cfg, impl, "last"))
+
+
+def _send(cfg, impl, cache, rows):
+    """``rows``: (tokens, table row, start, valid) each, None a dead row."""
+    block = np.zeros((len(rows), CHUNK), np.int32)
+    table = np.full((len(rows), MPP), -1, np.int32)
+    start, valid = (np.zeros((len(rows),), np.int32) for _ in range(2))
+    for r, row in enumerate(rows):
+        if row is not None:
+            tokens, table[r], start[r], valid[r] = row
+            block[r, :valid[r]] = tokens[start[r]:start[r] + valid[r]]
+    return _in_place(cfg, impl)(cache, *map(jnp.asarray, (
+        block, table, start, valid)))
+
+
+def _planes(cache):
+    """The pool's planes: not the expert rows' running sums, which count a
+    program's every row, dead ones too."""
+    return [n for n in cache if n != "moe_rows"]
+
+
+def _dirty(cfg):
+    return {n: (jnp.full_like(a, 3.0) if n in SSD_PLANES else a)
+            for n, a in _empty_pool(cfg).items()}
+
+
+ARMS = [("gather", BASE), ("pallas", WIDE)]
+
+
+def _same(got, want, impl, what):
+    """Bit for bit under the XLA forms against programs of the same width
+    (the handed state is the float32 one, the tail the stored type's: the
+    same operations on the same bits; a program of another width rounds its
+    matrix products another way on the CPU, hand-over or none); the kernel's
+    own tolerance interpreted."""
+    if impl == "gather":
+        np.testing.assert_array_equal(got, want, err_msg=what)
+    else:
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5,
+                                   err_msg=what)
+
+
+@pytest.mark.parametrize("first", [0, CHUNK], ids=["fresh", "held"])
+@pytest.mark.parametrize("impl,cfg", ARMS, ids=[a for a, _ in ARMS])
+def test_a_row_behind_starts_where_the_row_in_front_ends(impl, cfg, first):
+    """Two consecutive chunks of ONE prompt as the two rows of one program
+    (the same table row, the second start a chunk on; the second 11 tokens
+    long and the prompt's last) against two one-row programs sent one after
+    the other, over a dirty pool: every plane (the states, the conv tails, K
+    and V) and the last position's logits. ``fresh``: the row in front
+    starts its sequence; ``held``: a chunk of the prompt is in the pool."""
+    tokens, row = _tokens(63, first + CHUNK + 11), _row(1)
+    cache = _dirty(cfg)
+    if first:
+        _, cache = _send(cfg, impl, cache, [(tokens, row, 0, CHUNK)])
+    front, behind = (tokens, row, first, CHUNK), \
+        (tokens, row, first + CHUNK, 11)
+    _, after = _send(cfg, impl, cache, [front, None])
+    want, after = _send(cfg, impl, after, [None, behind])
+    got, planes = _send(cfg, impl, cache, [front, behind])
+    _same(got[1], want[1], impl, "the logits of the row behind")
+    for n in _planes(after):
+        _same(planes[n], after[n], impl, f"plane {n}")
+    _, after = _send(cfg, impl, cache, [front])
+    want, after = _send(cfg, impl, after, [behind])
+    _same(got[1], want[0], "one row wide", "the logits of the row behind")
+    for n in _planes(after):
+        _same(planes[n], after[n], "one row wide", f"plane {n}")
+    np.testing.assert_allclose(
+        got[1], _reference_logits(cfg, tokens)[first + CHUNK + 10],
+        rtol=5e-4, atol=5e-4)
+    for n in SSD_PLANES:        # entries 0 and 2 were nobody's
+        assert float(jnp.abs(planes[n][:, [0, 2]] - 3.0).max()) == 0.0
+    # three rows of one sequence in one program: a run of two hand-overs
+    longer = _tokens(64, 3 * CHUNK)
+    rows = [(longer, row, i * CHUNK, CHUNK) for i in range(3)]
+    after = cache
+    for i, r in enumerate(rows):
+        want, after = _send(cfg, impl, after,
+                            [None] * i + [r] + [None] * (2 - i))
+    got, planes = _send(cfg, impl, cache, rows)
+    _same(got[2], want[2], impl, "the logits of the third row")
+    for n in _planes(after):
+        _same(planes[n], after[n], impl, f"plane {n}, three rows")
+
+
+@pytest.mark.parametrize("impl,cfg", [("gathered", BASE), *ARMS],
+                         ids=["gathered", *(a for a, _ in ARMS)])
+def test_two_rows_of_one_program_do_not_mix(impl, cfg):
     """The program over rows: two prompts' chunks at their own starts and a
-    dead row between them, against each prompt alone."""
-    ta, tb = _tokens(7, 48), _tokens(8, 48)
+    dead row between them, against each prompt alone. ``gathered``: the form
+    every pool takes without the kernels (``decoder_forward``'s cache path).
+    In place, the second prompt's row starts where the first one's ENDS (32 +
+    16 against 48) under ANOTHER entry: a row follows the row in front only
+    within one sequence, so nothing is handed on."""
+    ta, tb = _tokens(7, 48), _tokens(8, 64)
     ra, rb = _row(0, 8), _row(2, 8)
-    params = _params(BASE)
-    _, cache = _prefill(BASE, _empty_pool(), ta, ra, 32)
-    block = np.zeros((3, CHUNK), np.int32)
-    block[0, :11], block[2] = ta[32:43], tb[:16]
-    rows = np.full((3, MPP), -1, np.int32)
-    rows[0], rows[2] = ra, rb
-    logits, cache = paged_chunk_prefill(
-        params, cache, jnp.asarray(block), jnp.asarray(rows),
-        jnp.asarray([32, 0, 0], jnp.int32),
-        jnp.asarray([11, 0, 16], jnp.int32), BASE, context_pages=MPP)
-    np.testing.assert_allclose(logits[0, :11],
-                               _reference_logits(BASE, ta)[32:43],
+    if impl == "gathered":
+        params = _params(BASE)
+        _, cache = _prefill(BASE, _empty_pool(), ta, ra, 32)
+        block = np.zeros((3, CHUNK), np.int32)
+        block[0, :11], block[2] = ta[32:43], tb[:16]
+        rows = np.full((3, MPP), -1, np.int32)
+        rows[0], rows[2] = ra, rb
+        logits, cache = paged_chunk_prefill(
+            params, cache, jnp.asarray(block), jnp.asarray(rows),
+            jnp.asarray([32, 0, 0], jnp.int32),
+            jnp.asarray([11, 0, 16], jnp.int32), BASE, context_pages=MPP)
+        np.testing.assert_allclose(logits[0, :11],
+                                   _reference_logits(BASE, ta)[32:43],
+                                   rtol=5e-4, atol=5e-4)
+        np.testing.assert_allclose(logits[2],
+                                   _reference_logits(BASE, tb)[:16],
+                                   rtol=5e-4, atol=5e-4)
+        assert float(jnp.abs(cache["ssd_state"][:, 1]).max()) == 0.0
+        return
+    cache = _empty_pool(cfg)
+    for held in ([(ta, ra, 0, CHUNK)], [(ta, ra, CHUNK, CHUNK)],
+                 [(tb, rb, 0, CHUNK)], [(tb, rb, CHUNK, CHUNK)],
+                 [(tb, rb, 2 * CHUNK, CHUNK)]):
+        _, cache = _send(cfg, impl, cache, held)
+    a, b = (ta, ra, 32, CHUNK), (tb, rb, 48, CHUNK)
+    got, planes = _send(cfg, impl, cache, [a, None, b])
+    np.testing.assert_allclose(got[0], _reference_logits(cfg, ta)[47],
                                rtol=5e-4, atol=5e-4)
-    np.testing.assert_allclose(logits[2], _reference_logits(BASE, tb)[:16],
+    np.testing.assert_allclose(got[2], _reference_logits(cfg, tb)[63],
                                rtol=5e-4, atol=5e-4)
-    assert float(jnp.abs(cache["ssd_state"][:, 1]).max()) == 0.0
+    # ... and side by side, where a row in front is all that tells them apart
+    want_a, after = _send(cfg, impl, cache, [a, None])
+    want_b, after = _send(cfg, impl, after, [None, b])
+    got, planes = _send(cfg, impl, cache, [a, b])
+    _same(got[0], want_a[0], impl, "the first prompt's row")
+    _same(got[1], want_b[1], impl, "the second prompt's row")
+    for n in _planes(after):
+        _same(planes[n], after[n], impl, f"plane {n}")
+    assert float(jnp.abs(planes["ssd_state"][:, 1]).max()) == 0.0
+
+
+def test_which_rows_follow_and_which_write():
+    """``_rows_follow`` over what a program is handed, and the entries the
+    mixer writes: a row follows the row in front iff both are live, name the
+    same entry, the start is a whole chunk on and the row in front is FULL;
+    row 0 never does. Only the LAST row of a run writes its sequence's entry:
+    in the planes ``_ssd`` leaves, a run's entry holds the state and the tail
+    its last row ended in, the entry of a followed row that is nobody's last
+    row is untouched, and a poisoned state handed to a FOLLOWED row's write
+    never lands."""
+    from kubeflow_tpu.ops import ssd
+    from kubeflow_tpu.serve import paged
+
+    total, t = 12, CHUNK
+
+    def follows(entry, start, valid):
+        return _rows_follow(*(jnp.asarray(v, jnp.int32) for v in (
+            entry, start, valid)), t, total).tolist()
+
+    assert follows([4, 4], [16, 32], [16, 9]) == [False, True]
+    assert follows([4, 4, 4], [0, 16, 32], [16, 16, 1]) == [False, True, True]
+    assert follows([4, 5], [16, 32], [16, 16]) == [False, False]   # another
+    assert follows([4, 4], [16, 32], [11, 16]) == [False, False]   # not full
+    assert follows([4, 4], [16, 16], [16, 16]) == [False, False]   # no chunk on
+    assert follows([4, 4], [16, 48], [16, 16]) == [False, False]
+    assert follows([12, 12], [0, 16], [16, 16]) == [False, False]  # both dead
+    assert follows([4, 12, 4], [0, 0, 16], [16, 0, 16]) == [False] * 3
+    assert follows([4, 4, 7, 7], [0, 16, 32, 48], [16] * 4) == [
+        False, True, False, True]
+
+    cfg = WIDE
+    sp = jax.tree.map(lambda a: a[1], _params(cfg)["layers"]["ssd"])
+    ks = jax.random.split(jax.random.PRNGKey(63), 3)
+    shapes = engine_pool_shapes(cfg, SLOTS, 8, PAGE)
+    pools = {n: jax.random.normal(k, (2 * SLOTS, *shapes[n][0][2:]),
+                                  shapes[n][1])
+             for n, k in zip(SSD_PLANES, ks)}
+    h = jax.random.normal(ks[2], (3, CHUNK, cfg.hidden))
+    args = (jnp.asarray([16, 32, 0], jnp.int32),            # start
+            jnp.asarray([16, 16, 13], jnp.int32), pools,    # valid
+            jnp.asarray([4, 4, 2], jnp.int32))              # entry
+    _, got = paged._ssd(sp, h, *args, cfg, "pallas")
+    # one row after the other: rows 0 and 1 are entry 4's run, row 2 alone
+    want = pools
+    for r in range(3):
+        _, want = paged._ssd(sp, h[r:r + 1], args[0][r:r + 1],
+                             args[1][r:r + 1], want, args[3][r:r + 1], cfg,
+                             "pallas")
+    for n in SSD_PLANES:
+        np.testing.assert_allclose(got[n], want[n], rtol=2e-5, atol=2e-5)
+        moved = np.any(np.asarray(got[n] != pools[n]),
+                       axis=tuple(range(1, got[n].ndim)))
+        assert moved.tolist() == [e in (4, 2) for e in range(2 * SLOTS)]
+
+    # what the FOLLOWED row would have written is poison: it must not land
+    chunk = ssd.ssd_chunk
+
+    def poisoned(*a, **kw):
+        y, ends = chunk(*a, **kw)
+        return y, ends.at[0].set(jnp.nan)
+
+    try:
+        ssd.ssd_chunk = poisoned
+        _, got = paged._ssd(sp, h, *args, cfg, "pallas")
+    finally:
+        ssd.ssd_chunk = chunk
+    assert not bool(jnp.isnan(got["ssd_state"]).any())
+    np.testing.assert_allclose(got["ssd_state"], want["ssd_state"],
+                               rtol=2e-5, atol=2e-5)
 
 
 def test_the_one_program_carries_a_chunk_and_the_slots_step():
@@ -386,17 +590,19 @@ def _engine(**kw):
 
 
 @functools.lru_cache(maxsize=None)
-def _full_padded():
-    params = _params(BASE)
-    return jax.jit(lambda t: decoder_forward(params, t[None], BASE)[0][0])
+def _full_padded(cfg):
+    params = _params(cfg)
+    return jax.jit(lambda t: decoder_forward(params, t[None], cfg)[0][0])
 
 
-def _greedy(prompt, n):
+def _greedy(prompt, n, cfg=BASE):
+    """``n`` greedy tokens behind ``prompt`` by full recompute."""
     toks, out = list(prompt), []
     for _ in range(n):
         padded = np.zeros((PAGE * MPP,), np.int32)
         padded[:len(toks)] = toks
-        t = int(jnp.argmax(_full_padded()(jnp.asarray(padded))[len(toks) - 1]))
+        t = int(jnp.argmax(_full_padded(cfg)(jnp.asarray(padded))[
+            len(toks) - 1]))
         out.append(t)
         toks.append(t)
     return out
@@ -453,6 +659,77 @@ def test_engine_tokens_are_the_full_recomputes_and_the_state_is_counted(
     # the expert rows: a quarter held, level by the stratified bias
     held = after["expert_rows_held"] / after["expert_rows_routed"]
     assert 0.15 < held < 0.35
+
+
+def _ahead_engine(**kw):
+    spec = dict(max_batch_size=SLOTS, max_seq_len=PAGE * MPP, page_size=PAGE,
+                chunked_prefill_tokens=CHUNK, enable_prefix_caching=False,
+                decode_steps=1, prefill_interleave_steps=1,
+                max_concurrent_prefills=2, paged_attn_impl="pallas")
+    return LLMEngine(WIDE, BatchingSpec(**{**spec, **kw}),
+                     params=_params(WIDE))
+
+
+@pytest.mark.parametrize("chunks", [2, 3, 4, 5])
+def test_a_prompt_alone_goes_two_chunks_a_program_and_hands_its_state_on(
+        chunks):
+    """The kernels interpreted, the plan the agent-turns cell's (two rows,
+    the step carried, spare rows AHEAD): a prompt of so many chunks sent
+    alone goes two chunks a program, the row behind from the state and the
+    tail the row in front ends in; an odd last chunk beside a dead row. Its
+    greedy tokens are the full recompute's."""
+    engine = _ahead_engine()
+    plan = engine._plan
+    assert (plan.carries_step, plan.rows, plan.ahead, plan.rows_only) \
+        == (True, 2, True, False)
+    prompt = _tokens(70 + chunks, (chunks - 1) * CHUNK + 5)
+    (req,) = _serve(engine, [prompt], 6)
+    assert req.output_tokens == _greedy(prompt, 6, WIDE)
+    # no slot is live while it prefills: the odd last chunk of an engine
+    # with nothing else to do takes the one-row program (``ChunkPlan.send``)
+    assert _chunk_counts(engine) == (-(-chunks // 2), chunks, chunks // 2, 0)
+    engine._allocator.assert_quiescent()
+
+
+@pytest.mark.parametrize("prefills", [2, 3])
+def test_prompts_at_once_take_their_rows_and_the_rest_goes_ahead(prefills):
+    """Prompts of 2, 3, 4 and 5 chunks, two at once and then two more beside
+    their streams: a program's rows are the due chunks of the prompts in it
+    and, where rows are spare, the chunks BEHIND them, each right behind its
+    own prompt's row (three rows wide: ``[A, A + C, B]``, never ``[A, B, A +
+    C]``: a row takes the state of the row in front of it). Every prompt's
+    tokens are the full recompute's; rows went ahead, and the odd chunks
+    beside a live stream left theirs dead."""
+    engine = _ahead_engine(max_concurrent_prefills=prefills,
+                           max_batch_size=4)
+    assert engine._plan.ahead and engine._plan.rows == prefills
+    sent_rows, rows_of = [], engine._rows_of
+    engine._rows_of = lambda group: (
+        sent_rows.append([(id(ch), pos) for ch, pos in rows_of(group)]),
+        rows_of(group))[1]
+    prompts = [_tokens(80 + n, (n - 1) * CHUNK + 3 + n) for n in (2, 3, 4, 5)]
+    sp = SamplingParams(temperature=0.0, max_new_tokens=8)
+    reqs = [engine.submit([int(t) for t in p], sp) for p in prompts[2:]]
+    for _ in range(4000):
+        if len(reqs) == 2 and any(r.first_token_time for r in reqs):
+            reqs += [engine.submit([int(t) for t in p], sp)
+                     for p in prompts[:2]]
+        if len(reqs) == 4 and all(r.done.is_set() for r in reqs):
+            break
+        engine.step()
+    for p, r in zip(prompts[2:] + prompts[:2], reqs):
+        assert r.output_tokens == _greedy(p, 8, WIDE)
+    programs, sent, ahead, _ = _chunk_counts(engine)
+    assert sent == 2 + 3 + 4 + 5 and ahead > 0 and programs < sent
+    assert engine.counters()["mixed_programs_dispatched"] > 0
+    for rows in sent_rows:      # a prefill's rows one behind the other
+        for (a, pa), (b, pb) in zip(rows, rows[1:]):
+            assert pb == pa + CHUNK if a == b else b not in [
+                r[0] for r in rows[:rows.index((b, pb))]]
+    if prefills == 3:       # two prompts due, the spare row right behind ITS
+        assert any(len(rows) == 3 and rows[0][0] == rows[1][0] != rows[2][0]
+                   for rows in sent_rows)
+    engine._allocator.assert_quiescent()
 
 
 def test_the_counter_is_zero_for_a_stack_that_keeps_no_state():
